@@ -1,0 +1,555 @@
+#!/usr/bin/env python
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, four phases, no failure caught (the exit code is the run's):
+
+1. *kernels*  — every Pallas kernel on the default TPU path compiled by
+   Mosaic (``interpret=False``) at the shapes the two models below use and
+   compared with the reference that sits beside it.
+2. *trainer*  — GPT-2-1.5B at full width (ZeRO-3, ``adamw8bit``, unrolled
+   stack, micro 2, ``dots_saveable+flash`` remat, chunked head) through
+   ``deepspeed_tpu.initialize`` → ``init_params`` → ``prepare_batch`` →
+   ``train_batch``; every loss finite, the last below the first on the
+   repeated batch, the step built on the flash kernel.
+3. *server*   — gpt2-760m at full width through ``init_inference`` (prefix
+   cache, 64-token pages) → ``ContinuousBatcher(n_slots=8)`` →
+   ``warmup_windows`` → ``run``: every request answered in full, paged,
+   fused decode engaged, zero gathers, zero fallbacks, zero leaks.
+4. *dispatch* — one line per kernel dispatch site saying what it resolved
+   to and why; a site whose guard said "supported" but that ran a
+   reference or an interpreted kernel fails the run.
+
+Random weights make logits too flat for token equality to mean anything,
+so numerics are judged in phase 1 (tolerance ``TOL`` below) and phases 2-3
+judge control flow, kernel engagement and finiteness.
+
+Exits non-zero, printing no result line, when JAX finds no TPU.  The last
+line of stdout is one JSON object:
+``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
+"""
+import gc
+import json
+import os
+import sys
+import time
+
+# Kernel outputs are bf16 (8 mantissa bits); the references run in fp32 at
+# "highest" matmul precision on the same bf16 inputs.  An output passes
+# when  max|kernel - ref| <= TOL * max|ref|  — about five bf16 ulps of
+# the largest value, far below any indexing or masking error (those are
+# O(1) relative).
+TOL = 2e-2
+
+# Adam without warm-up overshoots in its first updates, so the third loss
+# alone is no judge: measured on the chip (PR 21) the repeated batch reads
+# 11.17, 10.51, 11.78, 10.33, 10.30, 10.12, 9.97, 9.78 and falls
+# monotonically from there.  Eight single-step calls, last against first.
+TRAIN_STEPS = 8
+N_SLOTS = 8
+PAGE_TOKENS = 64
+NEW_TOKENS = 64
+SERVE_TICKS = 16
+
+
+def _check_close(name, got, ref):
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    if got.shape != ref.shape:
+        raise AssertionError(f"{name}: shape {got.shape} != {ref.shape}")
+    if not np.isfinite(got).all():
+        raise AssertionError(f"{name}: non-finite kernel output")
+    err = float(np.abs(got - ref).max() / (np.abs(ref).max() + 1e-12))
+    print(f"  {name}: rel-to-max err {err:.2e} (tol {TOL:.0e})", flush=True)
+    if err > TOL:
+        raise AssertionError(f"{name}: err {err:.3e} > {TOL}")
+
+
+# ---------------------------------------------------------------------------
+# phase 1: kernels
+# ---------------------------------------------------------------------------
+
+def kernel_flash():
+    """Flash forward + backward at the 1.5B trainer's per-micro shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.attention import _jnp_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    shape = (2, 1024, 25, 64)
+    q, k, v, ct = (jax.random.normal(kk, shape, jnp.float32)
+                   .astype(jnp.bfloat16) for kk in ks)
+
+    def loss(fn, q, k, v):
+        return (fn(q, k, v).astype(jnp.float32)
+                * ct.astype(jnp.float32)).sum()
+
+    flash = lambda q, k, v: flash_attention(          # noqa: E731
+        q, k, v, causal=True, block_q=512, block_k=512)
+    ref = lambda q, k, v: _jnp_attention(             # noqa: E731
+        q.astype(jnp.float32), k.astype(jnp.float32), v.astype(jnp.float32),
+        causal=True, bias=None, mask=None, dropout_rate=0.0,
+        dropout_rng=None, scale=None)
+    out = jax.jit(flash)(q, k, v)
+    grads = jax.jit(jax.grad(lambda *a: loss(flash, *a), argnums=(0, 1, 2))
+                    )(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        out_r = jax.jit(ref)(q, k, v)
+        grads_r = jax.jit(jax.grad(lambda *a: loss(ref, *a),
+                                   argnums=(0, 1, 2)))(q, k, v)
+    _check_close("flash fwd (2,1024,25,64) 512x512", out, out_r)
+    for n, g, gr in zip(("dq", "dk", "dv"), grads, grads_r):
+        _check_close(f"flash bwd {n}", g, gr)
+
+
+def _decode_ref(q, k_cache, v_cache, lengths):
+    """The masked jnp attention ``cached_decode_attention`` falls back to."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.attention import _jnp_attention
+
+    k_pos = jnp.arange(k_cache.shape[1])[None, None, None, :]
+    mask = k_pos < lengths[:, None, None, None]
+    return _jnp_attention(
+        q.astype(jnp.float32), k_cache.astype(jnp.float32),
+        v_cache.astype(jnp.float32), causal=False, bias=None, mask=mask,
+        dropout_rate=0.0, dropout_rng=None, scale=None)
+
+
+def kernel_decode_attention():
+    """gpt2-760m decode rows: the streamed path (a 1024-token K+V panel at
+    KV=16, D=96 is 6.3 MB against the 4 MB budget) and one whole-panel
+    shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.decode_attention import (decode_attention,
+                                                           fits_vmem)
+
+    for s_max, lengths in ((1024, (1, 64, 65, 500, 777, 1000, 1023, 1024)),
+                           (256, (1, 7, 64, 65, 128, 200, 255, 256))):
+        ks = jax.random.split(jax.random.PRNGKey(s_max), 3)
+        q = jax.random.normal(ks[0], (8, 1, 16, 96), jnp.float32
+                              ).astype(jnp.bfloat16)
+        kc = jax.random.normal(ks[1], (8, s_max, 16, 96), jnp.float32
+                               ).astype(jnp.bfloat16)
+        vc = jax.random.normal(ks[2], (8, s_max, 16, 96), jnp.float32
+                               ).astype(jnp.bfloat16)
+        lens = jnp.asarray(lengths, jnp.int32)
+        path = "panel" if fits_vmem(s_max, 16, 96, 2) else "streamed"
+        out = jax.jit(decode_attention)(q, kc, vc, lens)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(_decode_ref)(q, kc, vc, lens)
+        _check_close(f"decode_attention S={s_max} KV=16 D=96 ({path})",
+                     out, ref)
+
+
+def kernel_paged_attention():
+    """Paged decode at the server's page geometry: ragged lengths that
+    straddle a page boundary, one row of length 1, pages scattered over
+    the arena."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention, paged_reference_attention)
+
+    B, T, pt, KV, D = 8, 16, PAGE_TOKENS, 16, 96
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    n_pages = B * T + 1
+    q = jax.random.normal(ks[0], (B, 1, KV, D), jnp.float32
+                          ).astype(jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (n_pages, pt, KV, D), jnp.float32
+                           ).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (n_pages, pt, KV, D), jnp.float32
+                           ).astype(jnp.bfloat16)
+    table = jnp.asarray(np.random.default_rng(0).permutation(n_pages - 1)
+                        .reshape(B, T).astype(np.int32))
+    lens = jnp.asarray((1, 63, 64, 65, 130, 500, 1000, 1024), jnp.int32)
+    out = jax.jit(paged_decode_attention)(q, kp, vp, table, lens)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda *a: paged_reference_attention(
+            a[0].astype(jnp.float32), a[1].astype(jnp.float32),
+            a[2].astype(jnp.float32), a[3], a[4]))(q, kp, vp, table, lens)
+    _check_close(f"paged_decode_attention pt={pt} KV=16 D=96", out, ref)
+
+
+def _dequant(codes, scale):
+    import jax.numpy as jnp
+
+    G = scale.shape[0]
+    K, N = codes.shape
+    return (codes.reshape(G, K // G, N).astype(jnp.float32)
+            * scale[:, None, :]).reshape(K, N)
+
+
+def kernel_decode_layer():
+    """The two decode megakernels at gpt2-760m widths, bf16 and W8A16."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.decode_layer import (
+        fused_norm_proj, fused_post_attn, norm_proj_supported,
+        post_attn_supported, reference_norm_proj, reference_post_attn)
+    from deepspeed_tpu.ops.w8 import quantize_weight
+
+    rows, E, F = N_SLOTS, 1536, 6144
+    ks = jax.random.split(jax.random.PRNGKey(3), 12)
+    bf = jnp.bfloat16
+
+    def rnd(k, shape, std=1.0, dtype=bf):
+        return (std * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
+
+    x, y = rnd(ks[0], (rows, E)), rnd(ks[1], (rows, E))
+    ns = 1.0 + rnd(ks[2], (E,), 0.1, jnp.float32)
+    nb = rnd(ks[3], (E,), 0.1, jnp.float32)
+    w_qkv, b_qkv = rnd(ks[4], (E, 3 * E), 0.02), rnd(ks[5], (3 * E,), 0.02)
+    wo, bo = rnd(ks[6], (E, E), 0.02), rnd(ks[7], (E,), 0.02)
+    w1, b1 = rnd(ks[8], (E, F), 0.02), rnd(ks[9], (F,), 0.02)
+    w2, b2 = rnd(ks[10], (F, E), 0.02), rnd(ks[11], (E,), 0.02)
+
+    for quant in (False, True):
+        tag = "W8A16" if quant else "bf16"
+        if quant:
+            pack = lambda w: quantize_weight(w.astype(jnp.float32), 128)  # noqa: E731
+            unpack = lambda p: _dequant(*p)                               # noqa: E731
+        else:
+            pack = lambda w: w                                            # noqa: E731
+            unpack = lambda w: w.astype(jnp.float32)                      # noqa: E731
+        g_e, g_f = (E // 128, F // 128) if quant else (1, 1)
+        assert norm_proj_supported(rows, E, 3 * E, 2, quant, g_e)
+        assert post_attn_supported(rows, E, F, 2, quant, g_e, g_f)
+        pq, po, p1, p2 = pack(w_qkv), pack(wo), pack(w1), pack(w2)
+        qkv = jax.jit(lambda x, w: fused_norm_proj(x, ns, nb, w, b_qkv)
+                      )(x, pq)
+        out = jax.jit(lambda y, x, wo, w1, w2: fused_post_attn(
+            y, x, wo, bo, ns, nb, (w1, b1, w2, b2)))(y, x, po, p1, p2)
+        with jax.default_matmul_precision("highest"):
+            qkv_r = jax.jit(lambda x, w: reference_norm_proj(
+                x, ns, nb, w, b_qkv))(x, unpack(pq))
+            out_r = jax.jit(lambda y, x, wo, w1, w2: reference_post_attn(
+                y, x, wo, bo, ns, nb, (w1, b1, w2, b2)))(
+                    y, x, unpack(po), unpack(p1), unpack(p2))
+        _check_close(f"fused_norm_proj rows={rows} E={E} N={3 * E} {tag}",
+                     qkv, qkv_r)
+        _check_close(f"fused_post_attn rows={rows} E={E} F={F} {tag}",
+                     out, out_r)
+
+
+def kernel_w8_matmul():
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.w8_matmul import (supported,
+                                                    w8a16_matmul_pallas)
+    from deepspeed_tpu.ops.w8 import quantize_weight
+
+    for K, N in ((1536, 6144), (6144, 1536)):
+        ks = jax.random.split(jax.random.PRNGKey(K), 2)
+        x = jax.random.normal(ks[0], (N_SLOTS, K), jnp.float32
+                              ).astype(jnp.bfloat16)
+        codes, scale = quantize_weight(
+            0.02 * jax.random.normal(ks[1], (K, N), jnp.float32), 128)
+        assert supported(x.shape, codes.shape, scale.shape[0], mesh_ok=True)
+        out = jax.jit(w8a16_matmul_pallas)(x, codes, scale)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(lambda x, c, s: jnp.dot(
+                x.astype(jnp.float32), _dequant(c, s)))(x, codes, scale)
+        _check_close(f"w8_matmul ({N_SLOTS},{K})x({K},{N}) g=128", out, ref)
+
+
+KERNEL_CASES = (kernel_flash, kernel_decode_attention, kernel_paged_attention,
+                kernel_decode_layer, kernel_w8_matmul)
+
+
+def phase_kernels():
+    for case in KERNEL_CASES:
+        case()
+
+
+# ---------------------------------------------------------------------------
+# phase 2: trainer
+# ---------------------------------------------------------------------------
+
+def phase_trainer():
+    import jax
+    import numpy as np
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+    seq = 1024
+    cfg = gpt2_config("gpt2-1.5b", n_positions=seq, scan_layers=False,
+                      remat=True, remat_policy="dots_saveable+flash",
+                      attn_impl="auto", loss_chunk=8192)
+    model = GPT2LMHeadModel(cfg)
+    engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
+        "train_micro_batch_size_per_gpu": 2,
+        "optimizer": {"type": "adamw8bit",
+                      "params": {"lr": 1e-4, "weight_decay": 0.1}},
+        "zero_optimization": {"stage": 3},
+        "mesh": {"fsdp": -1},
+        "steps_per_print": 10**6,
+    })
+    print(f"  mesh {dict(engine.mesh.shape)}  global batch "
+          f"{engine.train_batch_size} x {seq}", flush=True)
+    engine.init_params()
+    ids = np.random.default_rng(0).integers(
+        0, cfg.vocab_size, size=(engine.train_batch_size, seq)
+    ).astype(np.int32)
+    batch = engine.prepare_batch({"input_ids": ids, "labels": ids})
+    losses = []
+    for i in range(TRAIN_STEPS):
+        t0 = time.perf_counter()
+        loss = float(jax.block_until_ready(engine.train_batch(batch)))
+        print(f"  step {i}: loss {loss:.4f}  "
+              f"{time.perf_counter() - t0:.2f}s", flush=True)
+        losses.append(loss)
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"trainer: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"trainer: loss did not fall on the repeated "
+                             f"batch: {losses}")
+    state_bytes = _per_device_bytes(engine.state)
+    print("  train state bytes per device: "
+          + "  ".join(f"{d}={b / 2**30:.2f}GiB"
+                      for d, b in sorted(state_bytes.items())), flush=True)
+    n_dev = len(jax.devices())
+    if n_dev > 1:
+        total = sum(state_bytes.values())
+        if len(state_bytes) != n_dev or \
+                max(state_bytes.values()) > 0.5 * total:
+            raise AssertionError(
+                f"trainer: ZeRO-3 state is not split over {n_dev} devices: "
+                f"{state_bytes}")
+    # free the 1.5B state and unregister the trainer's mesh: the server
+    # below must neither share HBM with it nor inherit its mesh through
+    # comm.get_mesh
+    engine._state = None
+    del engine, batch
+    gc.collect()
+    from deepspeed_tpu.comm import mesh as mesh_mod
+
+    mesh_mod.set_mesh(None)
+
+
+def _per_device_bytes(tree):
+    """``{device id: resident bytes}`` of a pytree of arrays."""
+    import jax
+
+    from deepspeed_tpu.telemetry.memory import per_device_shard_bytes
+
+    per_dev, _ = per_device_shard_bytes(jax.tree_util.tree_leaves(tree))
+    return {d.id: b for d, b in per_dev.items()}
+
+
+# ---------------------------------------------------------------------------
+# phase 3: server
+# ---------------------------------------------------------------------------
+
+def phase_server():
+    import jax
+    import numpy as np
+
+    import deepspeed_tpu
+    from deepspeed_tpu.inference.serving import ContinuousBatcher
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+    from deepspeed_tpu.telemetry import registry
+
+    cfg = gpt2_config("gpt2-760m")
+    model = GPT2LMHeadModel(cfg)
+    params = jax.jit(lambda r: model.init(
+        r, np.zeros((1, 8), np.int32))["params"])(jax.random.PRNGKey(0))
+    chain = 1024 // PAGE_TOKENS
+    eng = deepspeed_tpu.init_inference(
+        model=model, params=params, max_tokens=1024,
+        prefix_cache={"page_tokens": PAGE_TOKENS,
+                      # every slot's worst-case chain, the trash page, and
+                      # room for the radix tree to keep retired prefixes
+                      "n_pages": N_SLOTS * chain + 4 * chain + 2})
+    del params
+    held = sorted(_per_device_bytes(eng.params))
+    print(f"  server mesh {dict(eng.mesh.shape)}; weights on devices "
+          f"{held} of {len(jax.devices())}", flush=True)
+    if len(held) != 1:
+        raise AssertionError(
+            f"server: a default init_inference replicated or split its "
+            f"weights over devices {held}; replicas are the router's job")
+    batcher = ContinuousBatcher(eng, n_slots=N_SLOTS)
+    if batcher.paged is None:
+        raise AssertionError("server: paged decode did not resolve")
+    batcher.warmup_windows(SERVE_TICKS)
+
+    # a dozen requests in two pow2 prompt buckets (~48 and ~400 tokens);
+    # every third shares a two-page prefix so hit-admission runs (below)
+    rng = np.random.default_rng(0)
+    shared = rng.integers(0, cfg.vocab_size, 2 * PAGE_TOKENS + 9)
+    prompts = []
+    for i in range(12):
+        n = (48, 400)[i % 2] - int(rng.integers(0, 8))
+        p = rng.integers(0, cfg.vocab_size, n)
+        if i % 3 == 0:
+            n = max(n, 2 * PAGE_TOKENS + 40)
+            p = np.concatenate([shared, rng.integers(
+                0, cfg.vocab_size, n - len(shared))])
+        prompts.append(p.astype(np.int32))
+
+    def count(name):
+        return registry.counter(name).total()
+
+    before = {n: count(n) for n in (
+        "serving_gather_pages_total", "decode_fused_qkv_traces_total",
+        "decode_fused_post_attn_traces_total", "decode_fused_fallback_total",
+        "prefix_cache_hit_tokens_total")}
+    t0 = time.perf_counter()
+    # two waves: a retiring request donates its prompt's pages to the
+    # radix tree, so the second wave's shared-prefix requests hit them
+    outs = []
+    for wave in (prompts[:6], prompts[6:]):
+        outs += batcher.run(wave, ticks=SERVE_TICKS,
+                            max_new_tokens=NEW_TOKENS)
+    dt = time.perf_counter() - t0
+    moved = {n: count(n) - v for n, v in before.items()}
+    print(f"  {len(prompts)} requests x {NEW_TOKENS} new tokens in "
+          f"{dt:.2f}s; counters moved: {moved}", flush=True)
+    for p, o in zip(prompts, outs):
+        if o is None or len(o) - len(p) != NEW_TOKENS:
+            raise AssertionError(
+                f"server: request with a {len(p)}-token prompt returned "
+                f"{None if o is None else len(o) - len(p)} new tokens, "
+                f"asked {NEW_TOKENS}")
+        if not ((0 <= o) & (o < cfg.padded_vocab_size)).all():
+            raise AssertionError("server: token id out of range")
+    if moved["serving_gather_pages_total"]:
+        raise AssertionError("server: paged serving gathered pages")
+    # the warm-up above traced the decode windows, so the fused counters
+    # are read against process start, the fallback counter likewise
+    if not count("decode_fused_qkv_traces_total") or \
+            not count("decode_fused_post_attn_traces_total"):
+        raise AssertionError("server: fused decode kernels never traced")
+    if count("decode_fused_fallback_total"):
+        raise AssertionError("server: fused decode fell back")
+    if not moved["prefix_cache_hit_tokens_total"]:
+        raise AssertionError("server: no shared-prefix admission hit")
+    leaks = batcher.leak_counts()
+    if any(leaks.values()):
+        raise AssertionError(f"server: leaked after drain: {leaks}")
+    print(f"  leak_counts {leaks}", flush=True)
+
+
+# ---------------------------------------------------------------------------
+# phase 4: dispatch report
+# ---------------------------------------------------------------------------
+
+# sites whose guards say "supported" at the shapes phases 2 and 3 run:
+# resolving to anything else on the chip fails the run
+EXPECTED = {
+    "attention": "flash",             # 1.5B: head_dim 64, seq 1024
+    "decode_attention": "paged_kernel",
+    "decode_fused": "kernel",         # 760m: n_embd 1536 % 128 == 0
+    "paged_decode": "paged",
+}
+
+
+def phase_dispatch():
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    rows = dispatch_report()
+    for site, impl, reason, n in rows:
+        print(f"  {site}: {impl} ({reason}) x{n}", flush=True)
+    seen = {}
+    for site, impl, _, _ in rows:
+        seen.setdefault(site, set()).add(impl)
+    for site, want in EXPECTED.items():
+        if want not in seen.get(site, ()):
+            raise AssertionError(
+                f"dispatch: {site} never resolved to {want!r} "
+                f"(saw {sorted(seen.get(site, ()))})")
+    bad = [(s, i) for s, impls in seen.items() for i in impls
+           if i == "interpret"]
+    if bad:
+        raise AssertionError(f"dispatch: interpreted kernels on the chip: "
+                             f"{bad}")
+
+
+# ---------------------------------------------------------------------------
+
+def main():
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke: JAX found no TPU (jax.devices()[0].platform "
+                 f"== {dev.platform!r}); this script proves the system on "
+                 f"the chip and does not fall back to another backend")
+    import jaxlib
+
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    try:
+        from importlib.metadata import version
+
+        libtpu = version("libtpu")
+    except Exception:           # metadata only; never gates the run
+        libtpu = "unknown"
+    print(f"device {device}  jax {jax.__version__}  jaxlib "
+          f"{jaxlib.__version__}  libtpu {libtpu}", flush=True)
+    print(f"compile cache: {cache_dir}", flush=True)
+
+    compile_s = [0.0]
+    backend_compiles = []
+    cache_events = {"hits": 0, "misses": 0}
+
+    def on_duration(event, secs, **_):
+        # lowering + backend compile (a cache fetch counts as the latter);
+        # tracing is left out, its events nest and would count twice
+        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
+            compile_s[0] += secs
+        if event == "/jax/core/compile/backend_compile_duration":
+            compile_s[0] += secs
+            backend_compiles.append(secs)
+
+    def on_event(event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            cache_events["hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            cache_events["misses"] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+    t_all = time.perf_counter()
+    for name, phase in (("kernels", phase_kernels), ("trainer", phase_trainer),
+                        ("server", phase_server),
+                        ("dispatch", phase_dispatch)):
+        print(f"[{name}]", flush=True)
+        c0, t0 = compile_s[0], time.perf_counter()
+        phase()
+        wall = time.perf_counter() - t0
+        comp = compile_s[0] - c0
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                 for d in jax.devices()]
+        print(f"[{name}] ok  wall {wall:.1f}s  compile {comp:.1f}s "
+              f"({100 * comp / max(wall, 1e-9):.0f}%)  peak_bytes_in_use "
+              f"so far, per device: "
+              + " ".join(f"{p / 2**30:.2f}GiB" for p in peaks), flush=True)
+    small = [s for s in backend_compiles if s < 1.0]
+    print(f"total {time.perf_counter() - t_all:.1f}s  executables "
+          f"{len(backend_compiles)} ({len(small)} built or fetched in under "
+          f"1.0 s, {sum(small):.1f}s together)  persistent cache hits "
+          f"{cache_events['hits']} misses {cache_events['misses']}",
+          flush=True)
+    entries = os.listdir(cache_dir) if os.path.isdir(cache_dir) else []
+    print(f"compile cache {cache_dir}: {len(entries)} files", flush=True)
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

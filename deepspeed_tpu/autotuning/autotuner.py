@@ -21,17 +21,8 @@ from typing import Any, Optional
 
 import numpy as np
 
+from ..telemetry import attribution
 from ..utils.logging import log_dist, logger
-
-# per-chip HBM + peak flops + HBM bandwidth by device kind
-CHIP_SPECS = {
-    "v4": dict(hbm=32e9, flops=275e12, bw=1.2e12),
-    "v5 lite": dict(hbm=16e9, flops=197e12, bw=0.8e12),
-    "v5e": dict(hbm=16e9, flops=197e12, bw=0.8e12),
-    "v5p": dict(hbm=95e9, flops=459e12, bw=2.8e12),
-    "v6e": dict(hbm=32e9, flops=918e12, bw=1.6e12),
-    "cpu": dict(hbm=8e9, flops=1e12, bw=0.1e12),
-}
 
 
 @dataclasses.dataclass
@@ -58,17 +49,6 @@ def _merge_optimizer(base: dict, override: dict) -> dict:
     if "params" in override:
         out["params"] = dict(out.get("params", {}), **override["params"])
     return out
-
-
-def _chip_spec():
-    import jax
-
-    kind = getattr(jax.devices()[0], "device_kind",
-                   jax.devices()[0].platform).lower()
-    for key, spec in CHIP_SPECS.items():
-        if key in kind:
-            return spec
-    return CHIP_SPECS["cpu"]
 
 
 class Autotuner:
@@ -134,7 +114,11 @@ class Autotuner:
         # int8 Adam moments are THE memory lever for billion-param
         # single-chip regimes, so they are part of the search space
         self.optimizer_options = optimizer_options or [{}]
-        self.hbm_budget = _chip_spec()["hbm"] * hbm_budget_fraction
+        # chip physics come from THE table (telemetry/attribution.py); a
+        # device_kind that is not in it raises — a roofline score
+        # against an invented chip would rank candidates by noise
+        self.hbm_budget = (attribution.device_hbm_bytes()
+                           * hbm_budget_fraction)
         self.seq_len = seq_len
         self.results: list[TrialResult] = []
 
@@ -239,11 +223,10 @@ class Autotuner:
             result.bytes_accessed = float(costs.get("bytes accessed", 0.0))
             result.peak_memory_bytes = peak
             result.fits = np.isnan(peak) or peak <= self.hbm_budget
-            spec = _chip_spec()
             # roofline per device
             result.est_step_time = max(
-                result.flops / spec["flops"],
-                result.bytes_accessed / spec["bw"])
+                result.flops / attribution.device_peak_flops(),
+                result.bytes_accessed / attribution.device_hbm_bytes_s())
         except Exception as e:  # noqa: BLE001 — a failing candidate is data
             result.error = f"{type(e).__name__}: {e}"
         return result

@@ -888,26 +888,31 @@ def resolve_paged_decode(engine, prefix_cache, n_slots: int, specdec=None,
     when speculative decoding is active (its verify step drives the
     contiguous slot-cache layout), or when the model family's decode
     path cannot consume a paged cache (the abstract-trace probe below)."""
+    from ..ops.pallas.spmd import note_dispatch
+
+    def gather(reason: str, warn: bool = True) -> None:
+        if warn:
+            logger.warning(f"paged decode disabled: {reason}; slots keep "
+                           f"the gather path")
+        note_dispatch("paged_decode", "gather", reason)
+
     env = os.environ.get(PAGED_DECODE_ENV, "").strip().lower()
     if env in ("0", "false", "off"):
-        return None
+        return gather(f"{PAGED_DECODE_ENV}={env}", warn=False)
     if prefix_cache is None:
-        return None
+        return gather("no prefix cache resolved (no page arena)", warn=False)
     cfg = override if override is not None else \
         getattr(engine.config, "paged_decode", None)
     if cfg is False:
-        return None
+        return gather("paged_decode=False (argument or engine config)",
+                      warn=False)
     if specdec is not None:
-        logger.warning(
-            "paged decode disabled: speculative decoding's verify step "
-            "drives the contiguous slot-cache layout; slots keep the "
-            "gather path")
-        return None
+        return gather("speculative decoding's verify step drives the "
+                      "contiguous slot-cache layout")
     try:
         state = PagedServingState(prefix_cache, engine, n_slots)
     except ValueError as e:
-        logger.warning(f"paged decode disabled: {e}")
-        return None
+        return gather(str(e))
     # contract probe: a family that consumes the appended cache leaves
     # DIRECTLY instead of through cached_decode_attention (gptneo's
     # windowed-mask math) crashes on the PagedKV carriers the paged
@@ -926,11 +931,10 @@ def resolve_paged_decode(engine, prefix_cache, n_slots: int, specdec=None,
             jax.ShapeDtypeStruct((n_slots, 1), jnp.int32),
             jax.ShapeDtypeStruct((n_slots,), jnp.int32))
     except Exception as e:
-        logger.warning(
-            f"paged decode disabled: this model family's decode path "
-            f"does not consume a paged cache "
-            f"({type(e).__name__}: {str(e)[:160]}); slots keep the "
-            f"gather path")
         state.pool.free([state.trash])   # roll back the reservation
-        return None
+        return gather(f"this model family's decode path does not consume "
+                      f"a paged cache ({type(e).__name__}: {str(e)[:160]})")
+    note_dispatch("paged_decode", "paged",
+                  "prefix cache resolved, pool holds every slot's chain, "
+                  "decode trace consumes the paged cache")
     return state

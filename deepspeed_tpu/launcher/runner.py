@@ -11,7 +11,9 @@ topology itself.  So the launcher's jobs reduce to:
 - single host (default): exec the training script in-process env.
 - multi-host emulation (``--num_processes N``): fork N local processes with
   ``DSTPU_COORDINATOR/NUM_PROCESSES/PROCESS_ID`` env (the MASTER_ADDR/RANK
-  analog) — used for CPU multi-process testing.
+  analog) — used for CPU multi-process testing; refused on a host with
+  TPU chips unless the children are kept off them (a chip belongs to one
+  process).
 - hostfile mode (``--hostfile``): ssh to each host and run the command
   there (pdsh-style fan-out, reference ``multinode_runner.py:45``) — on
   real TPU pods prefer the cloud tooling; this covers bare-metal parity.
@@ -19,6 +21,7 @@ topology itself.  So the launcher's jobs reduce to:
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import re
@@ -529,6 +532,46 @@ def _disarm_own_telemetry() -> None:
         pass
 
 
+_GOOGLE_PCI_VENDOR_ID = "0x1ae0"
+#: set by a caller that has taken charge of which chips each process sees
+_CHIP_VISIBILITY_ENVS = ("TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES")
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, read from the PCI bus — the
+    launcher parent must stay off the JAX backend (a process that has
+    touched it holds the chips, and its children then fail or hang)."""
+    chips = 0
+    for vendor in glob.glob("/sys/bus/pci/devices/*/vendor"):
+        with open(vendor) as fh:
+            chips += fh.read().strip() == _GOOGLE_PCI_VENDOR_ID
+    return chips
+
+
+def _refuse_shared_chips(num_processes: int) -> None:
+    """``--num_processes N`` is CPU multi-process emulation: nothing tells
+    each child which chip is its own, so on a TPU host all N would reach
+    for every chip and all but one would fail or hang.  Refuse, unless
+    the environment keeps the children off the chips (``JAX_PLATFORMS``
+    without ``tpu``) or the caller set the chip visibility itself."""
+    if num_processes <= 1:
+        return
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    if any(os.environ.get(k) for k in _CHIP_VISIBILITY_ENVS):
+        return
+    chips = _local_tpu_chips()
+    if chips:
+        raise SystemExit(
+            f"dstpu: --num_processes {num_processes} on a host with "
+            f"{chips} TPU chip(s): a chip belongs to one process, and the "
+            f"launcher does not assign chips to its children.  Run ONE "
+            f"process per host (it drives every local chip), or set "
+            f"JAX_PLATFORMS=cpu for multi-process emulation, or set "
+            f"{' / '.join(_CHIP_VISIBILITY_ENVS)} yourself.")
+
+
 def main(argv=None) -> int:
     args = _build_parser().parse_args(argv)
     if args.user_args and args.user_args[0] == "--":
@@ -536,6 +579,7 @@ def main(argv=None) -> int:
     _disarm_own_telemetry()
     if args.hostfile:
         return _launch_hostfile(args)
+    _refuse_shared_chips(args.num_processes)
     if args.num_processes > 1 or args.heartbeat_timeout > 0 \
             or args.max_restarts > 0:
         # restart loop: recovery = relaunch + load_checkpoint (the

@@ -323,7 +323,8 @@ def _append_paged_kv_cache(module: nn.Module, k: jax.Array, v: jax.Array,
 # ``decode_fused`` config flag (or the DS_TPU_DECODE_FUSED env override)
 # turns the per-layer decode op chain into two Pallas launches around
 # ``decode_attention``; ``decode_fused_plan`` mirrors ``decode_supported``
-# — unsupported shapes silently keep the XLA path.
+# — unsupported shapes keep the XLA path and say so in the dispatch
+# report (``ops/pallas/spmd.py``).
 
 DECODE_FUSED_ENV = "DS_TPU_DECODE_FUSED"
 
@@ -383,8 +384,13 @@ def decode_fused_plan(cfg, rows: int, e: int, proj_outs: tuple,
     ``f``: MLP hidden width; ``swiglu``: the 3-panel MLP (LLaMA) vs the
     GELU pair.  Returns ``{"interpret": bool}`` or None (caller keeps
     the stock XLA path)."""
+    from ..ops.pallas.spmd import note_dispatch
+
     mode = decode_fused_mode(cfg)
     if mode is None:
+        note_dispatch("decode_fused", "xla",
+                      "decode_fused_mode: off (config flag, env, or the "
+                      "not-a-TPU default)")
         return None
     from ..ops.pallas.decode_layer import (norm_proj_supported,
                                            post_attn_supported)
@@ -398,40 +404,41 @@ def decode_fused_plan(cfg, rows: int, e: int, proj_outs: tuple,
     mesh = get_mesh(required=False)
     if mesh is not None and any(mesh.shape.get(a, 1) > 1
                                 for a in ("tp", "sp", "pp")):
+        refusal = "mesh shards the decode operands (tp/sp/pp > 1)"
+    else:
+        w8 = bool(getattr(cfg, "w8", False))
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+        if not all(norm_proj_supported(rows, e, n, itemsize, w8,
+                                       _w8_groups(cfg, e))
+                   for n in proj_outs):
+            refusal = (f"norm_proj_supported(rows={rows}, e={e}, "
+                       f"n={proj_outs}, w8={w8}) said no")
+        elif not post_attn_supported(rows, e, f, itemsize, w8,
+                                     _w8_groups(cfg, e), _w8_groups(cfg, f),
+                                     swiglu=swiglu):
+            refusal = (f"post_attn_supported(rows={rows}, e={e}, f={f}, "
+                       f"w8={w8}, swiglu={swiglu}) said no")
+        else:
+            refusal = None
+    if refusal is not None:
         _decode_fused_metrics()[2].inc()
+        note_dispatch("decode_fused", "xla", refusal)
         return None
-    w8 = bool(getattr(cfg, "w8", False))
-    itemsize = jnp.dtype(cfg.dtype).itemsize
-    ok = all(norm_proj_supported(rows, e, n, itemsize, w8, _w8_groups(cfg, e))
-             for n in proj_outs)
-    ok = ok and post_attn_supported(rows, e, f, itemsize, w8,
-                                    _w8_groups(cfg, e), _w8_groups(cfg, f),
-                                    swiglu=swiglu)
-    if not ok:
-        _decode_fused_metrics()[2].inc()
-        return None
+    note_dispatch("decode_fused", mode, "both megakernel guards said yes")
     return {"interpret": mode == "interpret"}
 
 
 def fused_decode_qkv(x, norm_scale, norm_bias, weight, bias, *, rms: bool,
                      eps: float, interpret: bool):
-    """norm → projection for the decode tick: Pallas kernel, with the
-    XLA chain as a graceful fallback if the kernel refuses at trace."""
-    from ..ops.pallas.decode_layer import (fused_norm_proj,
-                                           reference_norm_proj)
-    from ..ops.pallas.spmd import _warn_once
+    """norm → projection for the decode tick on the Pallas kernel.
+    ``decode_fused_plan`` has already said the shape is supported, so a
+    kernel error propagates."""
+    from ..ops.pallas.decode_layer import fused_norm_proj
 
-    m_qkv, _, m_fallback = _decode_fused_metrics()
-    try:
-        out = fused_norm_proj(x, norm_scale, norm_bias, weight, bias,
-                              rms=rms, eps=eps, interpret=interpret)
-        m_qkv.inc()
-        return out
-    except Exception as e:   # unsupported shape/backend: keep serving
-        _warn_once("decode_ln_qkv", f"{type(e).__name__}: {e}"[:200])
-        m_fallback.inc()
-        return reference_norm_proj(x, norm_scale, norm_bias, weight, bias,
-                                   rms=rms, eps=eps)
+    out = fused_norm_proj(x, norm_scale, norm_bias, weight, bias,
+                          rms=rms, eps=eps, interpret=interpret)
+    _decode_fused_metrics()[0].inc()
+    return out
 
 
 def fused_decode_post_attn(y, x, wo, bo, norm_scale, norm_bias,
@@ -440,28 +447,17 @@ def fused_decode_post_attn(y, x, wo, bo, norm_scale, norm_bias,
                            exact_gelu: bool = False,
                            parallel_residual: bool = False,
                            interpret: bool = False):
-    """o-proj + residual → norm → MLP → residual for the decode tick,
-    with the exact unfused op chain as fallback."""
-    from ..ops.pallas.decode_layer import (fused_post_attn,
-                                           reference_post_attn)
-    from ..ops.pallas.spmd import _warn_once
+    """o-proj + residual → norm → MLP → residual for the decode tick on
+    the Pallas kernel (see :func:`fused_decode_qkv`)."""
+    from ..ops.pallas.decode_layer import fused_post_attn
 
-    _, m_post, m_fallback = _decode_fused_metrics()
-    try:
-        out = fused_post_attn(y, x, wo, bo, norm_scale, norm_bias,
-                              mlp_weights, swiglu=swiglu, rms=rms, eps=eps,
-                              exact_gelu=exact_gelu,
-                              parallel_residual=parallel_residual,
-                              interpret=interpret)
-        m_post.inc()
-        return out
-    except Exception as e:
-        _warn_once("decode_post_attn", f"{type(e).__name__}: {e}"[:200])
-        m_fallback.inc()
-        return reference_post_attn(
-            y, x, wo, bo, norm_scale, norm_bias, mlp_weights,
-            swiglu=swiglu, rms=rms, eps=eps, exact_gelu=exact_gelu,
-            parallel_residual=parallel_residual)
+    out = fused_post_attn(y, x, wo, bo, norm_scale, norm_bias,
+                          mlp_weights, swiglu=swiglu, rms=rms, eps=eps,
+                          exact_gelu=exact_gelu,
+                          parallel_residual=parallel_residual,
+                          interpret=interpret)
+    _decode_fused_metrics()[1].inc()
+    return out
 
 
 def cross_entropy_loss(

@@ -349,7 +349,8 @@ class Block(nn.Module):
                 and pld_theta is None:
             # single-token tick: try the decode-row megakernel pair
             # (common.decode_fused_plan mirrors decode_supported — None
-            # keeps the stock XLA chain below, silently)
+            # keeps the stock XLA chain below and says why in the
+            # dispatch report)
             from .common import decode_fused_plan, fused_decode_post_attn
 
             plan = decode_fused_plan(cfg, x.shape[0] * x.shape[1],

@@ -23,20 +23,24 @@ import jax.numpy as jnp
 
 
 def on_tpu() -> bool:
-    """Shared backend probe (used by the model zoo's kernel dispatch too)."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    """Shared backend probe (used by the model zoo's kernel dispatch too).
+    Kernels infer interpret mode from it, so a backend that fails to
+    initialise raises here instead of reading as "not a TPU"."""
+    return jax.devices()[0].platform == "tpu"
 
 
-def _pick_impl(impl: str, q) -> str:
+def _pick_impl(impl: str, q) -> tuple:
+    """``(impl, reason)``: the flash kernel needs a TPU and seq/head_dim
+    tiling; ``auto`` picks the XLA path otherwise."""
     if impl != "auto":
-        return impl
-    # flash kernel needs TPU + seq/head_dim tiling; fall back otherwise
-    if on_tpu() and q.shape[1] >= 128 and q.shape[3] in (64, 128, 256):
-        return "flash"
-    return "jnp"
+        return impl, f"impl={impl!r} requested"
+    if not on_tpu():
+        return "jnp", "auto: not a TPU"
+    if q.shape[1] < 128:
+        return "jnp", f"auto: seq {q.shape[1]} < 128"
+    if q.shape[3] not in (64, 128, 256):
+        return "jnp", f"auto: head_dim {q.shape[3]} not in (64, 128, 256)"
+    return "flash", "auto: TPU, seq >= 128, head_dim tiles"
 
 
 def dot_product_attention(
@@ -73,17 +77,25 @@ def dot_product_attention(
                 "q) and exists only for step-time A/B probes; set "
                 "DS_TPU_ALLOW_SKIP_ATTN=1 if that is really what you want")
         return q
-    impl = _pick_impl(impl, q)
-    if impl == "flash" and bias is None and mask is None and dropout_rate == 0.0:
-        out = _flash_spmd(q, k, v, causal=causal, scale=scale,
-                          flash_opts=flash_opts)
-        if out is not None:
-            return out
-    if impl == "flash_jax" and bias is None and mask is None \
-            and dropout_rate == 0.0:
-        out = _flash_jax(q, k, v, causal=causal, scale=scale)
-        if out is not None:
-            return out
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    impl, reason = _pick_impl(impl, q)
+    if impl in ("flash", "flash_jax"):
+        if bias is not None or mask is not None or dropout_rate != 0.0:
+            reason = "bias/mask/dropout need the XLA path"
+        else:
+            out = _flash_spmd(q, k, v, causal=causal, scale=scale,
+                              flash_opts=flash_opts) if impl == "flash" \
+                else _flash_jax(q, k, v, causal=causal, scale=scale)
+            if out is not None:
+                verdict, axes = kernel_mesh_plan(q.shape[0], heads=q.shape[2],
+                                                 allow_tp=True)
+                plan = "one device" if verdict == "direct" \
+                    else f"shard_map over batch axes {axes}"
+                note_dispatch("attention", impl, f"{reason}; {plan}")
+                return out
+            reason = "kernel_mesh_plan refused the mesh"
+    note_dispatch("attention", "jnp", reason)
     return _jnp_attention(q, k, v, causal=causal, bias=bias, mask=mask,
                           dropout_rate=dropout_rate, dropout_rng=dropout_rng,
                           scale=scale)
@@ -93,12 +105,13 @@ def _flash_spmd(q, k, v, *, causal, scale, interpret=False, flash_opts=None):
     """Flash kernel, SPMD-correct: on a multi-device mesh the pallas_call is
     opaque to the partitioner (XLA would gather operands), so shard_map it
     over the batch (dp/fsdp/ep) and head (tp) axes — attention is
-    independent along both.  Returns None when the mesh/shapes are
-    unsupported (caller falls back to the XLA path)."""
+    independent along both.  Returns None when ``kernel_mesh_plan``
+    refuses the mesh (caller takes the XLA path); past that guard the
+    kernel's errors propagate."""
     from functools import partial
 
     from .pallas.flash_attention import flash_attention
-    from .pallas.spmd import kernel_mesh_plan, _warn_once
+    from .pallas.spmd import kernel_mesh_plan
 
     from ..comm.mesh import get_mesh
 
@@ -108,41 +121,37 @@ def _flash_spmd(q, k, v, *, causal, scale, interpret=False, flash_opts=None):
         return None
     kern = partial(flash_attention, causal=causal, scale=scale,
                    interpret=interpret, **(flash_opts or {}))
-    try:
-        if verdict == "direct":
-            return kern(q, k, v)
-        from ..utils.compat import shard_map
-        from jax.sharding import PartitionSpec as P
+    if verdict == "direct":
+        return kern(q, k, v)
+    return _shard_over_batch_heads(kern, batch_axes)(q, k, v)
 
-        mesh = get_mesh()
-        tp = mesh.shape.get("tp", 1)
-        spec = P(batch_axes if batch_axes else None, None,
-                 "tp" if tp > 1 else None, None)
-        # full-manual: the kernel has no collectives, unused axes replicate
-        mapped = shard_map(kern, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)
-        return mapped(q, k, v)
-    except Exception as e:  # unsupported shape/backend for the kernel
-        _warn_once("flash_attention", f"{type(e).__name__}: {e}"[:200])
-        return None
+
+def _shard_over_batch_heads(kern, batch_axes):
+    """Full-manual shard_map of a ``(B, S, H, D)`` attention kernel: batch
+    over ``batch_axes``, heads over ``tp``.  The kernel has no
+    collectives; unused axes replicate."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    mesh = get_mesh()
+    tp = mesh.shape.get("tp", 1)
+    spec = P(batch_axes if batch_axes else None, None,
+             "tp" if tp > 1 else None, None)
+    return jax.shard_map(kern, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def _flash_jax(q, k, v, *, causal, scale):
     """Stock JAX/Pallas TPU flash kernel
     (``jax.experimental.pallas.ops.tpu.flash_attention``) as an alternate
     backend — same dispatch contract as :func:`_flash_spmd` (shard_map
-    over batch/head axes on active meshes; None on unsupported
-    shape/backend so the caller falls back)."""
-    from functools import partial
+    over batch/head axes on active meshes; None when the mesh plan
+    refuses)."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention as jax_flash)
 
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention as jax_flash)
-    except ImportError:
-        return None
-    from .pallas.spmd import kernel_mesh_plan, _warn_once
-
-    from ..comm.mesh import get_mesh
+    from .pallas.spmd import kernel_mesh_plan
 
     B, S, H, D = q.shape
     if scale is None:
@@ -157,22 +166,9 @@ def _flash_jax(q, k, v, *, causal, scale):
                         sm_scale=scale)
         return out.transpose(0, 2, 1, 3)
 
-    try:
-        if verdict == "direct":
-            return kern(q, k, v)
-        from ..utils.compat import shard_map
-        from jax.sharding import PartitionSpec as P
-
-        mesh = get_mesh()
-        tp = mesh.shape.get("tp", 1)
-        spec = P(batch_axes if batch_axes else None, None,
-                 "tp" if tp > 1 else None, None)
-        mapped = shard_map(kern, mesh=mesh, in_specs=(spec, spec, spec),
-                           out_specs=spec, check_vma=False)
-        return mapped(q, k, v)
-    except Exception as e:
-        _warn_once("flash_jax", f"{type(e).__name__}: {e}"[:200])
-        return None
+    if verdict == "direct":
+        return kern(q, k, v)
+    return _shard_over_batch_heads(kern, batch_axes)(q, k, v)
 
 
 def cached_decode_attention(q, k_cache, v_cache, cur, attn_mask=None, *,
@@ -198,16 +194,29 @@ def cached_decode_attention(q, k_cache, v_cache, cur, attn_mask=None, *,
                                          paged_decode_supported,
                                          paged_reference_attention)
 
+    from .pallas.spmd import note_dispatch
+
     B, S, H, D = q.shape
+    if S != 1 or attn_mask is not None:
+        refusal = "multi-token query or attention mask"
+    elif not on_tpu():
+        refusal = "not a TPU"
+    else:
+        refusal = None
     if isinstance(k_cache, PagedKV):
         pages_k, table = k_cache.pages, k_cache.table
         pages_v = v_cache.pages
         pt, KV = pages_k.shape[1], pages_k.shape[2]
         lengths = cur + S          # (B,) valid tokens after the append
-        if S == 1 and attn_mask is None and on_tpu() and \
-                paged_decode_supported(pt, KV, D, pages_k.dtype.itemsize):
+        if refusal is None and not paged_decode_supported(
+                pt, KV, D, pages_k.dtype.itemsize):
+            refusal = f"paged_decode_supported({pt}, {KV}, {D}) said no"
+        if refusal is None:
+            note_dispatch("decode_attention", "paged_kernel",
+                          "single-token tick on a TPU, page geometry fits")
             return paged_decode_attention(q, pages_k, pages_v, table,
                                           lengths, scale=scale)
+        note_dispatch("decode_attention", "paged_reference", refusal)
         return paged_reference_attention(q, pages_k, pages_v, table,
                                          lengths, scale=scale,
                                          attn_mask=attn_mask,
@@ -215,9 +224,14 @@ def cached_decode_attention(q, k_cache, v_cache, cur, attn_mask=None, *,
     S_max, KV = k_cache.shape[1], k_cache.shape[2]
     from .pallas.decode_attention import decode_attention, decode_supported
 
-    if S == 1 and attn_mask is None and on_tpu() and \
-            decode_supported(S_max, KV, D, k_cache.dtype.itemsize):
+    if refusal is None and not decode_supported(
+            S_max, KV, D, k_cache.dtype.itemsize):
+        refusal = f"decode_supported({S_max}, {KV}, {D}) said no"
+    if refusal is None:
+        note_dispatch("decode_attention", "kernel",
+                      "single-token tick on a TPU, a KV block fits VMEM")
         return decode_attention(q, k_cache, v_cache, cur + 1, scale=scale)
+    note_dispatch("decode_attention", "jnp", refusal)
     if KV != H:   # GQA fallback: repeat KV heads for the dense path
         rep = H // KV
         k_cache = jnp.repeat(k_cache, rep, axis=2)
@@ -255,14 +269,15 @@ def sp_flash_spec(mesh, batch_size: int, heads: int):
 def _sp_attention(q, k, v, *, causal, scale, kind):
     from functools import partial
 
-    from ..utils.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..comm.mesh import get_mesh
+    from .pallas.spmd import note_dispatch
 
     mesh = get_mesh(required=False)
     if mesh is None or mesh.shape.get("sp", 1) == 1:
         # no sequence-parallel axis: plain attention
+        note_dispatch("attention", "jnp", f"impl={kind!r} without an sp axis")
         return _jnp_attention(q, k, v, causal=causal, bias=None, mask=None,
                               dropout_rate=0.0, dropout_rng=None, scale=scale)
     from ..parallel.ring_attention import (ring_attention,
@@ -295,22 +310,22 @@ def _sp_attention(q, k, v, *, causal, scale, kind):
                 fn = partial(ulysses_attention, axis_name="sp",
                              causal=causal, scale=scale,
                              attend_fn=flash_attention)
-            try:
-                mapped = shard_map(
-                    fn,
-                    mesh=mesh,
-                    in_specs=(spec, spec, spec),
-                    out_specs=spec,
-                    check_vma=False,
-                )
-                return mapped(q, k, v)
-            except Exception as e:  # unsupported shape/backend: jnp ring below
-                from .pallas.spmd import _warn_once
-
-                _warn_once(f"{kind}_attention_flash",
-                           f"{type(e).__name__}: {e}"[:200])
+            note_dispatch("attention", f"{kind}+flash",
+                          "TPU, head_dim tiles, sp_flash_spec covers the "
+                          "mesh")
+            mapped = jax.shard_map(
+                fn,
+                mesh=mesh,
+                in_specs=(spec, spec, spec),
+                out_specs=spec,
+                check_vma=False,
+            )
+            return mapped(q, k, v)
+    note_dispatch("attention", f"{kind}+jnp",
+                  "not a TPU, head_dim does not tile, or sp_flash_spec "
+                  "refused the mesh")
     fn = ring_attention if kind == "ring" else ulysses_attention
-    mapped = shard_map(
+    mapped = jax.shard_map(
         partial(fn, axis_name="sp", causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),

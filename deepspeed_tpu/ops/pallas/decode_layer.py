@@ -66,7 +66,7 @@ def decode_fused_metrics():
     """(qkv, post_attn, fallback) dispatch counters — created HERE, next
     to the kernels, so the custom_vmap rules can count their own
     reference-path detours and the model-layer dispatch shares the same
-    cells (a fallback that only one layer counted would let the e2e sweep
+    cells (a refusal that only one layer counted would let the e2e sweep
     attribute XLA-path numbers to the fused kernels)."""
     from ...telemetry import registry as telemetry_registry
 
@@ -79,9 +79,17 @@ def decode_fused_metrics():
             "fused o-proj->norm->MLP kernel dispatches (trace-time)"),
         telemetry_registry.counter(
             "decode_fused_fallback_total",
-            "decode_fused enabled but shape unsupported / kernel failed / "
-            "vmap fold past the row guard; XLA path taken"),
+            "decode_fused enabled but a guard refused (shape, mesh, or a "
+            "vmap fold past the row guard); XLA path taken"),
     )
+
+
+def _note_fold_refusal(kernel: str, rows: int) -> None:
+    from .spmd import note_dispatch
+
+    decode_fused_metrics()[2].inc()
+    note_dispatch("decode_fused", "xla",
+                  f"{kernel}: vmap fold to {rows} rows > {_MAX_ROWS}")
 
 
 def _norm_rows(x, scale, bias, *, rms: bool, eps: float):
@@ -102,7 +110,7 @@ def _gelu_exact(u):
 
 # ---------------------------------------------------------------------------
 # Reference XLA math — the unfused op chains the kernels must reproduce.
-# Shared by models/common.py's dispatch fallback AND the custom_vmap rules
+# Shared by the tests, chip_smoke.py's kernel phase AND the custom_vmap rules
 # (a slot-vmapped fold can exceed the row guard the per-slot trace already
 # passed; the rules then compute THIS instead of launching the kernel).
 # ---------------------------------------------------------------------------
@@ -126,7 +134,7 @@ def _ref_dense(a, w, b):
 def reference_norm_proj(x, norm_scale, norm_bias, weight, bias, *,
                         rms: bool = False, eps: float = 1e-5):
     """Unfused ``norm(x) @ W + b`` — the op chain the stock module path
-    emits, byte-for-byte the dispatch fallback."""
+    emits."""
     xn = _norm_apply(x, norm_scale, norm_bias, rms, eps)
     return _ref_dense(xn, weight, bias)
 
@@ -268,7 +276,7 @@ def _norm_proj_op(rms: bool, eps: float, quant: bool, interpret: bool):
         x = fold(x, in_batched[0], axis_size)
         B, M, E = x.shape
         if B * M > _MAX_ROWS:
-            decode_fused_metrics()[2].inc()
+            _note_fold_refusal("fused_norm_proj", B * M)
             w = wargs if quant else wargs[0]
             out = reference_norm_proj(
                 x.reshape(B * M, E), ns[0], None if rms else nb[0], w,
@@ -562,7 +570,7 @@ def _post_attn_op(swiglu: bool, quant: bool, rms: bool, eps: float,
         if B * M > _MAX_ROWS:
             # past the row guard the per-slot trace validated (see
             # _norm_proj_op): reference chain, not an unguarded kernel
-            decode_fused_metrics()[2].inc()
+            _note_fold_refusal("fused_post_attn", B * M)
             out = reference(y.reshape(B * M, E), x.reshape(B * M, E),
                             flat)
         else:

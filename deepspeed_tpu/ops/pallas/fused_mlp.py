@@ -256,35 +256,32 @@ def fused_mlp_spmd(x, w1, b1, w2, b2, *, block_rows: int = 128,
     batch axes with replicated weights (requires tp == 1; under ZeRO-3 the
     per-layer weight all-gather happens at the shard_map boundary, exactly
     where XLA would put it anyway).  Returns None when the mesh shards
-    something this kernel cannot handle (caller falls back to XLA).
-    Dispatch policy (pp/sp/tp guards, no-mesh multi-device) lives in
-    :mod:`.spmd`."""
-    from .spmd import kernel_mesh_plan, _warn_once
+    something this kernel cannot handle (caller takes the XLA path); past
+    that guard the kernel's errors propagate.  Dispatch policy (pp/sp/tp
+    guards, no-mesh multi-device) lives in :mod:`.spmd`."""
+    from .spmd import kernel_mesh_plan, note_dispatch
 
     verdict, batch_axes = kernel_mesh_plan(x.shape[0], allow_tp=False)
     if verdict is None:
+        note_dispatch("fused_mlp", "xla", "kernel_mesh_plan refused the mesh")
         return None
-    try:
-        if verdict == "direct":
-            return fused_mlp(x, w1, b1, w2, b2, block_rows=block_rows,
-                             interpret=interpret)
-        from ...utils.compat import shard_map
-        from jax.sharding import PartitionSpec as P
+    note_dispatch("fused_mlp", "kernel", f"mesh plan {verdict!r}")
+    if verdict == "direct":
+        return fused_mlp(x, w1, b1, w2, b2, block_rows=block_rows,
+                         interpret=interpret)
+    from jax.sharding import PartitionSpec as P
 
-        from ...comm.mesh import get_mesh
+    from ...comm.mesh import get_mesh
 
-        xspec = P(batch_axes, *([None] * (x.ndim - 1)))
-        wspec = P(None, None)
-        bspec = P(None)
-        mapped = shard_map(
-            functools.partial(fused_mlp, block_rows=block_rows,
-                              interpret=interpret),
-            mesh=get_mesh(),
-            in_specs=(xspec, wspec, bspec, wspec, bspec),
-            out_specs=xspec,
-            check_vma=False,
-        )
-        return mapped(x, w1, b1, w2, b2)
-    except Exception as e:  # unsupported shape/backend for the kernel
-        _warn_once("fused_mlp", f"{type(e).__name__}: {e}"[:200])
-        return None
+    xspec = P(batch_axes, *([None] * (x.ndim - 1)))
+    wspec = P(None, None)
+    bspec = P(None)
+    mapped = jax.shard_map(
+        functools.partial(fused_mlp, block_rows=block_rows,
+                          interpret=interpret),
+        mesh=get_mesh(),
+        in_specs=(xspec, wspec, bspec, wspec, bspec),
+        out_specs=xspec,
+        check_vma=False,
+    )
+    return mapped(x, w1, b1, w2, b2)

@@ -210,9 +210,24 @@ def _bias_gelu_fwd(x, bias, block_rows, interpret):
     return y, (x, bias)
 
 
+# Mosaic's default scoped-VMEM limit; the backward kernel below holds
+# three double-buffered (block_rows, D) tiles plus ~4 fp32 temporaries.
+# Measured on the v5e (PR 21): bf16 (128, 6400) tiles asked for 23.09 MB
+# and Mosaic refused — the estimate reproduces that figure.
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
 def _bias_gelu_bwd(block_rows, interpret, res, dy):
     x, bias = res
     R, D = x.shape
+    row_bytes = D * (6 * x.dtype.itemsize + 16)
+    if not interpret and block_rows * row_bytes > _SCOPED_VMEM_BYTES:
+        raise ValueError(
+            f"bias_gelu backward: (block_rows={block_rows}, D={D}) "
+            f"{x.dtype} tiles need ~{block_rows * row_bytes / 2**20:.1f} MiB "
+            f"of scoped VMEM and Mosaic refuses past "
+            f"{_SCOPED_VMEM_BYTES // 2**20} MiB; pass block_rows <= "
+            f"{_SCOPED_VMEM_BYTES // row_bytes // 8 * 8} or use the XLA gelu")
     nb = R // block_rows
     dx, db_part = pl.pallas_call(
         _bias_gelu_bwd_kernel,

@@ -10,17 +10,23 @@ Verdicts:
 - ``("direct", None)`` — single device: call the kernel directly.
 - ``("shard", batch_axes)`` — wrap in full-manual shard_map, batch dim
   sharded over ``batch_axes`` (+ optionally heads over ``tp``).
-- ``(None, None)`` — unsupported (caller falls back to the XLA path).
+- ``(None, None)`` — the mesh refuses the kernel (caller takes the XLA
+  path and says so through :func:`note_dispatch`).
+
+A guard (a shape check, or the mesh plan above) may choose the XLA path;
+once it has said yes, the kernel's exceptions propagate — no dispatch
+site catches them and substitutes a reference.  What each site resolved
+to, and why, is counted at trace time in ``kernel_dispatch_total`` and
+read back by :func:`dispatch_report`.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
 from ...comm.mesh import DATA_AXES, get_mesh
-from ...utils.logging import logger
+from ...telemetry import registry as _registry
 
 
 def kernel_mesh_plan(batch_size: int, *, heads: Optional[int] = None,
@@ -60,9 +66,20 @@ def kernel_mesh_plan(batch_size: int, *, heads: Optional[int] = None,
     return "shard", batch_axes
 
 
-@functools.lru_cache(maxsize=32)
-def _warn_once(kernel: str, err: str) -> None:
-    logger.warning(
-        f"pallas kernel {kernel} dispatch failed ({err}); falling back to "
-        "the XLA path — investigate if this persists, it is a silent "
-        "performance regression")
+def _dispatch_counter():
+    return _registry.counter(
+        "kernel_dispatch_total",
+        "what each kernel dispatch site resolved to, and why (counted at "
+        "trace time, not per call)", labelnames=("site", "impl", "reason"))
+
+
+def note_dispatch(site: str, impl: str, reason: str) -> None:
+    """Record that dispatch ``site`` resolved to ``impl`` because of
+    ``reason`` (the guard that decided)."""
+    _dispatch_counter().labels(site, impl, reason).inc()
+
+
+def dispatch_report() -> List[Tuple[str, str, str, int]]:
+    """``(site, impl, reason, count)`` rows, sorted by site."""
+    return sorted((*labels, int(child.value))
+                  for labels, child in _dispatch_counter().samples())

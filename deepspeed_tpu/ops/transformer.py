@@ -173,7 +173,7 @@ def _layer_body(mod: nn.Module, cfg: DeepSpeedTransformerConfig, x,
         from .pallas.fused_mlp import fits_vmem, fused_mlp_spmd
 
         # fit-gate BEFORE dispatch: a Mosaic VMEM overflow surfaces at the
-        # user's outer jit compile, past any except inside the wrapper
+        # user's outer jit compile
         if fits_vmem(H, cfg.intermediate_size, 128,
                      jnp.dtype(dtype).itemsize):
             out = fused_mlp_spmd(ffn_in, w1.astype(dtype), b1.astype(dtype),

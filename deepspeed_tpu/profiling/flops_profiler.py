@@ -149,14 +149,12 @@ def params_profile(params) -> dict:
 def _device_peak_flops() -> Optional[float]:
     import jax
 
-    # None for unknown kinds: MFU against a guessed peak is noise.  The
-    # shared table carries a nominal "cpu" entry for the live roofline
-    # plane's verdicts; the profiler's historical behavior (no MFU line
-    # off-TPU) is preserved by excluding it here.
+    # None off-TPU: MFU against a guessed peak is noise, so the profile
+    # carries no MFU line there.  On a TPU the kind must be in the table.
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return None
-    return _attribution.device_peak_flops(dev, default=None)
+    return _attribution.device_peak_flops(dev)
 
 
 class FlopsProfiler:

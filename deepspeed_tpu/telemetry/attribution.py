@@ -50,7 +50,8 @@ from . import registry as _registry
 
 __all__ = [
     "ATTRIBUTION_ENV", "SAMPLE_ENV", "PEAK_FLOPS", "HBM_BYTES_S",
-    "device_peak_flops", "device_hbm_bytes_s", "harvest_costs",
+    "HBM_BYTES", "device_known", "device_peak_flops",
+    "device_hbm_bytes_s", "device_hbm_bytes", "harvest_costs",
     "roofline", "decode_stream_floor", "AttributionPlane", "get_plane",
     "enabled", "enable", "should_sample", "note_compiled", "note_measured",
     "note_window", "ensure_costs", "timed_jit_call", "snapshot", "status",
@@ -60,20 +61,25 @@ __all__ = [
 ATTRIBUTION_ENV = "DSTPU_ATTRIBUTION"
 SAMPLE_ENV = "DSTPU_ATTRIBUTION_SAMPLE"
 
-# -- device physics (THE one copy; bench.py + flops_profiler read these)
-# bf16 peak FLOPs per chip by TPU generation; "cpu" is a nominal 1 TF so
-# CPU-mesh runs still produce finite (tiny) MFUs instead of NaNs.
+# -- device physics (THE one copy; bench.py, the flops profiler and the
+# autotuner read these).  Keyed by a substring of ``device_kind``.  A
+# device that is not in the table is an error, not a default: a CPU run
+# has no peak, and a number against an invented one is noise.
+# Source: Google Cloud TPU documentation, system architecture pages
+# (v4, v5e, v5p, v6e): bf16 peak FLOP/s, HBM bytes/s, HBM bytes per chip.
 PEAK_FLOPS = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
-              "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12,
-              "cpu": 1e12}
+              "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12}
 
 # HBM bandwidth per chip (bytes/s) — the decode bandwidth-floor
 # denominator: a decode tick streams every weight byte plus the live KV
 # cache, so floor_ms = bytes / BW is the physics bound serving numbers
 # are judged against.
 HBM_BYTES_S = {"v4": 1228e9, "v5 lite": 819e9, "v5e": 819e9,
-               "v5p": 2765e9, "v6 lite": 1640e9, "v6e": 1640e9,
-               "cpu": 50e9}
+               "v5p": 2765e9, "v6 lite": 1640e9, "v6e": 1640e9}
+
+# HBM capacity per chip (bytes) — the autotuner's fit budget.
+HBM_BYTES = {"v4": 32e9, "v5 lite": 16e9, "v5e": 16e9,
+             "v5p": 95e9, "v6 lite": 32e9, "v6e": 32e9}
 
 # verdict threshold: a roof (mfu or bw_frac) must explain at least this
 # fraction of the measured time to call the executable bound by it;
@@ -84,42 +90,46 @@ _DEFAULT_OVERHEAD_FRAC = 0.10
 _SAMPLE_WINDOW = 32        # timing samples retained per site (median)
 
 
-def _device_lookup(dev, table: dict, default: Optional[float]
-                   ) -> Optional[float]:
-    kind = getattr(dev, "device_kind", "").lower() if dev is not None else ""
+def device_known(dev) -> bool:
+    """Whether ``dev``'s ``device_kind`` has a row in the physics tables
+    (callers that run legitimately off-TPU skip their roofline numbers
+    when it does not)."""
+    kind = dev.device_kind.lower()
+    return any(key in kind for key in PEAK_FLOPS)
+
+
+def _device_lookup(dev, table: dict, what: str) -> float:
+    if dev is None:
+        dev = _device0()
+    kind = dev.device_kind.lower()
     for key, val in table.items():
         if key in kind:
             return val
-    return default
+    raise ValueError(
+        f"no {what} known for device_kind {dev.device_kind!r}; add the "
+        f"chip (with its source) to telemetry/attribution.py")
 
 
-def device_peak_flops(dev=None, default: Optional[float] = 1e12
-                      ) -> Optional[float]:
+def device_peak_flops(dev=None) -> float:
     """Peak bf16 FLOPs/s of ``dev`` (device 0 when None) from
-    :data:`PEAK_FLOPS`; ``default`` for unknown kinds."""
-    if dev is None:
-        dev = _device0()
-    return _device_lookup(dev, PEAK_FLOPS, default)
+    :data:`PEAK_FLOPS`; raises for a ``device_kind`` not in the table."""
+    return _device_lookup(dev, PEAK_FLOPS, "peak FLOP/s")
 
 
-def device_hbm_bytes_s(dev=None, default: Optional[float] = 50e9
-                       ) -> Optional[float]:
+def device_hbm_bytes_s(dev=None) -> float:
     """HBM bandwidth (bytes/s) of ``dev`` from :data:`HBM_BYTES_S`."""
-    if dev is None:
-        dev = _device0()
-    return _device_lookup(dev, HBM_BYTES_S, default)
+    return _device_lookup(dev, HBM_BYTES_S, "HBM bandwidth")
+
+
+def device_hbm_bytes(dev=None) -> float:
+    """HBM capacity (bytes) of ``dev`` from :data:`HBM_BYTES`."""
+    return _device_lookup(dev, HBM_BYTES, "HBM capacity")
 
 
 def _device0():
-    """Local device 0 WITHOUT forcing a jax import/backend init (this
-    module is imported at ``import deepspeed_tpu`` time)."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return None
-    try:
-        return jax.local_devices()[0]
-    except Exception:
-        return None
+    import jax
+
+    return jax.local_devices()[0]
 
 
 def harvest_costs(compiled) -> Optional[dict]:
@@ -386,17 +396,18 @@ class AttributionPlane:
 
     # -- export --------------------------------------------------------
     def _get_physics(self) -> tuple:
-        """(device_kind, peak_flops, hbm_bytes_s); cached once a real
-        device is visible, defaults before jax is up."""
-        if self._physics is not None:
-            return self._physics
-        dev = _device0()
-        if dev is None:
-            return ("unknown", 1e12, 50e9)
-        phys = (getattr(dev, "device_kind", "") or dev.platform,
-                device_peak_flops(dev), device_hbm_bytes_s(dev))
-        self._physics = phys
-        return phys
+        """(device_kind, peak_flops, hbm_bytes_s), cached.  A device
+        without a row in the tables (a CPU mesh) gets ``(kind, None,
+        None)``: its rows keep their costs and times and carry no
+        roofline."""
+        if self._physics is None:
+            dev = _device0()
+            known = device_known(dev)
+            self._physics = (
+                dev.device_kind,
+                device_peak_flops(dev) if known else None,
+                device_hbm_bytes_s(dev) if known else None)
+        return self._physics
 
     def _row(self, site: str, s: dict) -> dict:
         _, peak, bw = self._get_physics()
@@ -415,10 +426,12 @@ class AttributionPlane:
             row["verdict"] = "unmeasured"
         elif flops is None:
             row["verdict"] = "uninstrumented"
+        elif peak is None:
+            row["verdict"] = "unknown-device"
         else:
             rl = roofline(flops, hbm_bytes or 0.0, ms / 1000.0, peak, bw)
-            # 9 decimals: CPU-mesh mfus sit at 1e-4..1e-6 and must stay
-            # recomputable from the row's own fields to ~1e-3 relative
+            # 9 decimals: small mfus (1e-4..1e-6) must stay recomputable
+            # from the row's own fields to ~1e-3 relative
             row["mfu"] = round(rl["mfu"], 9)
             row["bw_frac"] = round(rl["bw_frac"], 9)
             row["verdict"] = rl["verdict"]
@@ -427,9 +440,13 @@ class AttributionPlane:
     def snapshot(self) -> dict:
         """The ``/profilez`` payload: device physics + one row per
         site, measured rows first (slowest first)."""
-        kind, peak, bw = self._get_physics()
         with self._lock:
             sites = list(self._sites.items())
+        # no site, no device probe: a process that never compiled (the
+        # launcher parent, a crash dump before start-up) stays off the
+        # backend
+        kind, peak, bw = self._get_physics() if sites \
+            else (None, None, None)
         rows = [self._row(site, s) for site, s in sites]
         rows.sort(key=lambda r: (r["measured_ms"] is None,
                                  -(r["measured_ms"] or 0.0)))
@@ -443,7 +460,8 @@ class AttributionPlane:
         snap = self.snapshot()
         return {r["site"]: r["verdict"] for r in snap["rows"]
                 if r["measured_ms"] is not None
-                and r["verdict"] not in ("unmeasured", "uninstrumented")}
+                and r["verdict"] not in ("unmeasured", "uninstrumented",
+                                         "unknown-device")}
 
     def status(self) -> dict:
         """Compact ``/statusz`` ``attribution`` section."""

@@ -1,63 +1,15 @@
-"""Version-compat shims for the JAX API surface the repo relies on.
-
-``shard_map`` moved twice across JAX releases: it lives at
-``jax.experimental.shard_map.shard_map`` through the 0.4.x line and was
-promoted to ``jax.shard_map`` later, with two keyword renames on the way
-(``check_rep`` → ``check_vma``; the ``auto`` axis set inverted into
-``axis_names``, the set of axes that ARE manual).  Every call site in the
-repo is written against the NEW surface and imports from here, so one
-module owns the translation instead of eight try/excepts drifting apart.
+"""The manual-SPMD names the repo uses, under the spellings its call sites
+were written against.  Code for the one installation there is (jax 0.9):
+``jax.shard_map`` (``check_vma`` / ``axis_names`` keywords),
+``lax.axis_size`` and ``lax.pcast`` all exist, so nothing here branches
+on a version.
 """
 from __future__ import annotations
 
-try:                                    # jax >= 0.6: top-level, new kwargs
-    from jax import shard_map as _shard_map
-    _LEGACY = False
-except ImportError:                     # jax 0.4.x: experimental, old kwargs
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY = True
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-              axis_names=None, **kwargs):
-    """``jax.shard_map`` with the modern keyword surface on any JAX.
-
-    ``check_vma`` maps to legacy ``check_rep``; ``axis_names`` (the manual
-    axes) maps to legacy ``auto`` (its complement over the mesh axes).
-    """
-    if not _LEGACY:
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-def axis_size(axis):
-    """``jax.lax.axis_size`` on any JAX: older releases spell it
-    ``psum(1, axis)`` (constant-folds to the same static size inside a
-    manual region)."""
-    import jax.lax as lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+from jax import lax, shard_map  # noqa: F401  (re-exported)
+from jax.lax import axis_size  # noqa: F401  (re-exported)
 
 
 def pcast_varying(x, axis):
-    """``lax.pcast(x, (axis,), to="varying")`` where the VMA system exists;
-    identity on legacy JAX (no varying-manual-axes tracking there, and the
-    repo always pairs this with ``check_vma=False``, so the cast is purely
-    a type-system annotation)."""
-    import jax.lax as lax
-
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis,), to="varying")
-    return x
+    """Mark ``x`` as varying over the manual ``axis``."""
+    return lax.pcast(x, (axis,), to="varying")

@@ -16,27 +16,21 @@ from .logging import logger
 
 
 def _sync(x: Any = None) -> None:
-    """Drain the async dispatch queue so host timestamps bracket device work.
+    """Wait for the device so host timestamps bracket device work: for
+    ``x`` when given, else for everything dispatched so far (device
+    queues are FIFO, so a fresh scalar is ready only after all of it).
+    A device error raises here — a dead device must not read as a fast
+    step.
 
-    Fetches ONE scalar element to the host rather than ``block_until_ready``:
-    device queues are FIFO, so a tiny transfer of the newest result is a
-    reliable fence even on remote/tunneled backends where
-    ``block_until_ready`` can return early, and it never pays a full-array
-    transfer.
-    """
-    try:
-        import jax
-        import jax.numpy as jnp
+    ``block_until_ready`` and not a scalar ``device_get``: measured on
+    the TPU v5e (PR 21, 64 chained 8192^3 bf16 matmuls, median of 5) the
+    loop reads 404.6 ms fenced by ``block_until_ready``, 405.6 ms fenced
+    by fetching one element, and 0.3 ms unfenced — both wait for the
+    device, and the fetch adds a transfer."""
+    import jax
+    import jax.numpy as jnp
 
-        if x is not None:
-            leaves = [l for l in jax.tree_util.tree_leaves(x)
-                      if hasattr(l, "ravel")]
-            if leaves:
-                jax.device_get(leaves[0].ravel()[:1])
-                return
-        jax.device_get(jnp.zeros(()) + 0.0)
-    except Exception:
-        pass
+    jax.block_until_ready(x if x is not None else jnp.zeros(()) + 0.0)
 
 
 class _Timer:

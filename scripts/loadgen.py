@@ -612,6 +612,10 @@ def main(argv=None) -> int:
                          sort_keys=True, indent=1))
         return 0
 
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()      # before the first jit
+
     if args.router:
         return run_router_mode(args)
 

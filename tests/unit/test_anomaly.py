@@ -54,16 +54,41 @@ def test_roofline_tie_goes_to_hbm():
     assert r["verdict"] == "hbm-bound"
 
 
-def test_device_tables_shared_and_cpu_entries():
-    # bench.py/flops_profiler read THESE tables; both carry cpu entries
-    assert "cpu" in attribution.PEAK_FLOPS
-    assert "cpu" in attribution.HBM_BYTES_S
+def test_device_tables_shared_and_chips_only():
+    # bench.py/flops_profiler/autotuner read THESE tables; they carry
+    # chips only, and a device that is not in them is an error
+    import jax
+
     from deepspeed_tpu.profiling import flops_profiler
 
     assert flops_profiler.PEAK_TFLOPS is attribution.PEAK_FLOPS
+    for table in (attribution.PEAK_FLOPS, attribution.HBM_BYTES_S,
+                  attribution.HBM_BYTES):
+        assert "cpu" not in table
+    cpu = jax.devices()[0]
+    assert not attribution.device_known(cpu)
+    for lookup in (attribution.device_peak_flops,
+                   attribution.device_hbm_bytes_s,
+                   attribution.device_hbm_bytes):
+        with pytest.raises(ValueError, match="device_kind"):
+            lookup(cpu)
+        with pytest.raises(ValueError, match="device_kind"):
+            lookup()            # device 0 of the CPU mesh
 
 
-def test_decode_stream_floor_hand_math():
+def test_unknown_device_rows_carry_no_roofline():
+    plane = attribution.AttributionPlane()
+    plane.note_costs("s.a", flops=2e9, hbm_bytes=4e8)
+    plane.note_measured("s.a", 0.010)
+    snap = plane.snapshot()
+    assert snap["peak_flops"] is None and snap["hbm_bytes_s"] is None
+    (row,) = snap["rows"]
+    assert row["verdict"] == "unknown-device"
+    assert row["mfu"] is None and row["bw_frac"] is None
+    assert plane.verdicts() == {}
+
+
+def test_decode_stream_floor_hand_math(nominal_cpu_physics):
     params = {"w": np.zeros((10, 10), np.float32)}        # 400 B
     slot_cache = {"k": np.zeros((4, 8), np.float32)}      # 128 B
     d = attribution.decode_stream_floor(params, slot_cache, n_slots=2,
@@ -88,7 +113,7 @@ def test_harvest_costs_real_compiled():
 # ----------------------------------------------------------------------
 # attribution plane
 # ----------------------------------------------------------------------
-def test_plane_snapshot_self_consistent():
+def test_plane_snapshot_self_consistent(nominal_cpu_physics):
     plane = attribution.AttributionPlane()
     plane.note_costs("s.a", flops=2e9, hbm_bytes=4e8)
     plane.note_measured("s.a", 0.010)        # 10 ms
@@ -360,7 +385,7 @@ def test_goodput_drop_waits_for_warmup():
     assert [e["rule"] for e in evs] == ["goodput_drop"]
 
 
-def test_attribution_drift_pulses_per_flip(monkeypatch):
+def test_attribution_drift_pulses_per_flip(monkeypatch, nominal_cpu_physics):
     plane = attribution.AttributionPlane()
     monkeypatch.setattr(attribution, "_default", plane)
     plane.note_costs("s.x", flops=1e15, hbm_bytes=1.0)

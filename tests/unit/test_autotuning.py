@@ -9,6 +9,10 @@ from deepspeed_tpu.autotuning import Autotuner
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
+# the autotuner scores candidates against the chip's physics; on the CPU
+# mesh these tests exercise its search and bookkeeping against a nominal row
+pytestmark = pytest.mark.usefixtures("nominal_cpu_physics")
+
 
 @pytest.fixture(autouse=True)
 def fresh_mesh():

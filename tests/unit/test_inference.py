@@ -387,7 +387,7 @@ def test_continuous_batcher_prefill_ahead_ttft():
 
 def test_continuous_batcher_subwindows_are_pow2():
     """Sub-window scheduling must only compile pow2 window lengths (the
-    executable-count bound that keeps tunneled serving responsive)."""
+    executable-count bound that keeps serving responsive)."""
     from deepspeed_tpu.inference.serving import ContinuousBatcher
     eng = _tiny_engine()
     rng = np.random.default_rng(35)

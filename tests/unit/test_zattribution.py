@@ -28,10 +28,11 @@ VERDICTS = ("compute-bound", "hbm-bound", "overhead-bound")
 
 
 @pytest.fixture
-def fresh_plane(monkeypatch):
+def fresh_plane(monkeypatch, nominal_cpu_physics):
     """A private attribution plane, sampled every window, enabled —
     swapped in for the module singleton so process-wide state from
-    other tests can't leak into row assertions."""
+    other tests can't leak into row assertions.  Rows are judged against
+    the nominal CPU row (the tables themselves carry chips only)."""
     monkeypatch.setenv(attribution.SAMPLE_ENV, "1")
     plane = attribution.AttributionPlane()
     plane.enable(True)
