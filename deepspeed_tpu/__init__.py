@@ -42,19 +42,20 @@ def initialize(args=None, model=None, optimizer=None, model_parameters=None,
         config = config_params
     if config is None and args is not None:
         config = getattr(args, "deepspeed_config", None)
-    engine = Engine(
-        model=model,
-        config=config,
-        optimizer=optimizer,
-        model_parameters=model_parameters,
-        training_data=training_data,
-        lr_scheduler=lr_scheduler,
-        mesh=mesh,
-        loss_fn=loss_fn,
-        rngs=rngs,
-        collate_fn=collate_fn,
-        dist_init_required=dist_init_required,
-    )
+    with telemetry.trace.span("init/engine"):
+        engine = Engine(
+            model=model,
+            config=config,
+            optimizer=optimizer,
+            model_parameters=model_parameters,
+            training_data=training_data,
+            lr_scheduler=lr_scheduler,
+            mesh=mesh,
+            loss_fn=loss_fn,
+            rngs=rngs,
+            collate_fn=collate_fn,
+            dist_init_required=dist_init_required,
+        )
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 
 

@@ -974,9 +974,21 @@ class ContinuousBatcher:
         :meth:`_prefill_batch_paged` instead: the suffix prefill writes
         STRAIGHT into freshly allocated arena pages through the
         request's page table, and the hit prefix is never copied at
-        all — admission is page-ref bookkeeping."""
-        if self.paged is not None:
-            return self._prefill_batch_paged(max_new)
+        all — admission is page-ref bookkeeping.
+
+        One ``serve/prefill-batch`` span a call (``rows`` = requests taken
+        off the queue); each group's chunked forward is a ``serve/prefill``
+        child carrying the group's rows, length and uids."""
+        queued = len(self._queue)
+        with trace.span("serve/prefill-batch", max_new=int(max_new),
+                        queued=queued) as sp:
+            if self.paged is not None:
+                self._prefill_batch_paged(max_new)
+            else:
+                self._prefill_batch_contiguous(max_new)
+            sp.args["rows"] = queued - len(self._queue)
+
+    def _prefill_batch_contiguous(self, max_new: int):
         pc = self.prefix_cache
         while self._queue and max_new > 0:
             if pc is not None:
@@ -1380,6 +1392,16 @@ class ContinuousBatcher:
         free = [i for i in range(self.n_slots) if self._slots[i] is None]
         if len(self._parked) < len(free):
             self._prefill_batch(len(free) - len(self._parked))
+        with trace.span("serve/admit", free=len(free),
+                        parked=len(self._parked)) as sp:
+            sp.args["uids"] = self._place_parked(free)
+            self._shrink_parked()
+        self._update_occupancy_gauges()
+
+    def _place_parked(self, free: List[int]) -> List[int]:
+        """Place parked requests into the ``free`` slots (taken from the
+        front); returns the uids placed."""
+        placed = []
         while self._parked and free:
             req, cacheB, row, firstB, seen1B, first_host = \
                 self._parked.popleft()
@@ -1407,8 +1429,8 @@ class ContinuousBatcher:
                         req.repetition_penalty)
             self._slots[i] = _Active(req, [first_host])
             self._note_lifecycle(req.uid, "place", slot=i)
-        self._shrink_parked()
-        self._update_occupancy_gauges()
+            placed.append(req.uid)
+        return placed
 
     def _shrink_parked(self):
         """Release B-row prefill buffers that only one parked row still
@@ -1435,6 +1457,11 @@ class ContinuousBatcher:
                                      first_host)
 
     def _retire(self, i: int):
+        with trace.span("serve/retire", slot=int(i),
+                        uids=[self._slots[i].req.uid]):
+            self._retire_slot(i)
+
+    def _retire_slot(self, i: int):
         act = self._slots[i]
         self._finished[act.req.uid] = np.concatenate(
             [act.req.prompt, np.asarray(act.emitted, np.int32)])
@@ -1629,9 +1656,21 @@ class ContinuousBatcher:
         acceptance controller allows: a verify tick counts as ONE tick
         against ``ticks`` but may emit up to k+1 tokens per slot.
         Returns {uid: full token array} for requests completed during
-        this call."""
+        this call.
+
+        Host spans: one ``serve/step`` with children ``serve/admit``
+        (placing parked requests into free slots), ``serve/prefill-batch``
+        (grouping and prefilling queued requests; its chunk calls are
+        ``serve/prefill``), ``serve/decode-tick`` (a window's dispatch,
+        and inside it ``serve/fetch``: the token fetch that fences the
+        window) and ``serve/retire``."""
         if ticks < 1:
             raise ValueError(f"ticks must be >= 1, got {ticks}")
+        with trace.span("serve/step", ticks=int(ticks),
+                        queued=len(self._queue), parked=len(self._parked)):
+            return self._step(ticks)
+
+    def _step(self, ticks: int) -> Dict[int, np.ndarray]:
         before = set(self._finished)
         remaining = int(ticks)
         # the SIGTERM drain hook must not re-enter a half-advanced
@@ -1646,12 +1685,10 @@ class ContinuousBatcher:
                     # capacity is reusable this very step
                     self.admission.maybe_step()
                     self._deadline_sweep()
-                with trace.span("serve/admission",
-                                queued=len(self._queue), parked=len(self._parked)):
-                    self._admit()
-                    if self.prefill_ahead and self._queue:
-                        self._prefill_batch(
-                            self.prefill_ahead - len(self._parked))
+                self._admit()
+                if self.prefill_ahead and self._queue:
+                    self._prefill_batch(
+                        self.prefill_ahead - len(self._parked))
                 active = [a for a in self._slots if a is not None]
                 self._update_occupancy_gauges()
                 if not active:
@@ -1743,8 +1780,10 @@ class ContinuousBatcher:
                             self._seen, done = window_fn(*window_args)
                     self._tick_no += int(sub)
                     self._done = done
-                    # the fetch is part of the tick's host wall time
-                    tok_h = np.asarray(jax.device_get(toks))[:, :, 0]
+                    # the fetch is part of the tick's host wall time:
+                    # it waits for the window the dispatch above enqueued
+                    with trace.span("serve/fetch", ticks=int(sub)):
+                        tok_h = np.asarray(jax.device_get(toks))[:, :, 0]
                 if attr_site is not None:
                     # compile-paying windows are discarded inside
                     # note_window; a recorded (steady) window also runs the

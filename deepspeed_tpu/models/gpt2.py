@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import dot_product_attention, on_tpu
+from ..telemetry import trace
 from ..utils import compat as _compat
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
@@ -426,7 +427,9 @@ class GPT2LMHeadModel(nn.Module):
                 raise ValueError("decode mode requires explicit position_ids "
                                  "(the inference engine tracks them)")
             position_ids = jnp.arange(S)[None, :]
-        h = wte.astype(cfg.dtype)[input_ids] + wpe.astype(cfg.dtype)[position_ids]
+        with trace.device_span("embed"):
+            h = (wte.astype(cfg.dtype)[input_ids]
+                 + wpe.astype(cfg.dtype)[position_ids])
         if cfg.embd_pdrop > 0.0 and not deterministic:
             h = nn.Dropout(cfg.embd_pdrop)(h, deterministic=False)
 
@@ -482,34 +485,38 @@ class GPT2LMHeadModel(nn.Module):
 
                 verdict, _ = kernel_mesh_plan(h.shape[0])
                 use_pallas_ce = verdict == "direct"
-            if use_pallas_ce:
-                loss = pallas_lm_loss(
-                    h, wte, tgt, vocab_size=cfg.vocab_size,
-                    padded_vocab_size=cfg.padded_vocab_size,
-                    dtype=cfg.dtype)
-            else:
-                loss = chunked_lm_loss(
-                    h, wte, tgt, vocab_size=cfg.vocab_size,
-                    padded_vocab_size=cfg.padded_vocab_size,
-                    chunk=cfg.loss_chunk, dtype=cfg.dtype,
-                    save_logits=cfg.loss_save_logits)
+            with trace.device_span("loss_head"):
+                if use_pallas_ce:
+                    loss = pallas_lm_loss(
+                        h, wte, tgt, vocab_size=cfg.vocab_size,
+                        padded_vocab_size=cfg.padded_vocab_size,
+                        dtype=cfg.dtype)
+                else:
+                    loss = chunked_lm_loss(
+                        h, wte, tgt, vocab_size=cfg.vocab_size,
+                        padded_vocab_size=cfg.padded_vocab_size,
+                        chunk=cfg.loss_chunk, dtype=cfg.dtype,
+                        save_logits=cfg.loss_save_logits)
             out = ModelOutput(loss=loss)
             if cfg.moe is not None:
                 out["aux_loss"] = aux_loss
                 out["loss"] = loss + aux_loss
             return out
-        logits = jnp.dot(h, wte.astype(cfg.dtype).T)
-        if cfg.padded_vocab_size != cfg.vocab_size:
-            # mask padded vocab columns out of the softmax
-            pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
-            logits = jnp.where(pad_mask, logits, jnp.finfo(logits.dtype).min)
+        with trace.device_span("loss_head"):
+            logits = jnp.dot(h, wte.astype(cfg.dtype).T)
+            if cfg.padded_vocab_size != cfg.vocab_size:
+                # mask padded vocab columns out of the softmax
+                pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
+                logits = jnp.where(pad_mask, logits,
+                                   jnp.finfo(logits.dtype).min)
 
         out = ModelOutput(logits=logits)
         if cfg.moe is not None:
             out["aux_loss"] = aux_loss
         if labels is not None:
             tgt = shift_labels(labels) if shift else labels
-            loss = cross_entropy_loss(logits, tgt)
+            with trace.device_span("loss_head"):
+                loss = cross_entropy_loss(logits, tgt)
             if cfg.moe is not None:
                 loss = loss + aux_loss  # load-balancing loss (engine.py:2154 analog)
             out["loss"] = loss

@@ -222,6 +222,22 @@ def named_shardings(mesh, spec_tree):
                                   is_leaf=lambda x: isinstance(x, P))
 
 
+def scatter_grads(grads, mesh, grad_specs):
+    """State the gradient partitioning of stages 2 and 3 inside the
+    compiled step: each gradient leaf constrained to its shard's spec, which
+    XLA implements as the reduce-scatter.  The constraint carries the device
+    scope ``zero/scatter``.  There is no ``zero/gather`` to match it: no line
+    of this program gathers a parameter; the SPMD partitioner places each
+    all-gather at the operation that consumes the shard, and it carries
+    that operation's module path (``h_3/attn/...``)."""
+    from ..telemetry import trace
+
+    with trace.device_span("zero/scatter"):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.lax.with_sharding_constraint(
+                x, NamedSharding(mesh, s)), grads, grad_specs)
+
+
 def validate_stage_mesh(zero_stage: int, mesh) -> None:
     if zero_stage >= 1 and mesh.shape["fsdp"] == 1 and mesh.shape["dp"] > 1:
         logger.warning(

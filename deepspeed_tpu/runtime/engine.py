@@ -48,7 +48,7 @@ from . import precision
 from .config import Config
 from .dataloader import DeepSpeedDataLoader, RepeatingLoader
 from .lr_schedules import get_lr_schedule
-from .optimizers import build_tx
+from .optimizers import build_tx, clip_by_global_norm
 
 
 @flax.struct.dataclass
@@ -232,7 +232,7 @@ class Engine:
             self.tx = optimizer
             if self.config.gradient_clipping > 0:
                 self.tx = optax.chain(
-                    optax.clip_by_global_norm(self.config.gradient_clipping), self.tx)
+                    clip_by_global_norm(self.config.gradient_clipping), self.tx)
         else:
             self.tx = build_tx(self.config, learning_rate=self.lr_scheduler)
         if self._onebit_comm:
@@ -624,6 +624,10 @@ class Engine:
         """
         if self._state is not None:
             return
+        with trace.span("init/params"):
+            self._init_params(example_batch, params, rng)
+
+    def _init_params(self, example_batch, params, rng):
         if params is None and example_batch is None:
             if hasattr(self.model, "dummy_inputs"):
                 example_batch = self.model.dummy_inputs(
@@ -1025,15 +1029,18 @@ class Engine:
         cfg = self.config
         scale = state.loss_scale.scale if cfg.fp16.enabled else jnp.float32(1.0)
         inv = 1.0 / (denom * scale)
-        grads = jax.tree_util.tree_map(lambda g: (g * inv).astype(jnp.float32), grad_sum)
-        grad_norm = optax.global_norm(grads)
-        if self._fused_opt is not None:
-            new_params, new_opt = self._fused_opt(
-                grads, state.params, state.opt_state, grad_norm)
-        else:
-            updates, new_opt = self.tx.update(grads, state.opt_state,
-                                              state.params)
-            new_params = optax.apply_updates(state.params, updates)
+        with trace.device_span("grad_clip"):
+            grads = jax.tree_util.tree_map(
+                lambda g: (g * inv).astype(jnp.float32), grad_sum)
+            grad_norm = optax.global_norm(grads)
+        with trace.device_span("optimizer"):
+            if self._fused_opt is not None:
+                new_params, new_opt = self._fused_opt(
+                    grads, state.params, state.opt_state, grad_norm)
+            else:
+                updates, new_opt = self.tx.update(grads, state.opt_state,
+                                                  state.params)
+                new_params = optax.apply_updates(state.params, updates)
         if self.quantizer is not None:
             # MoQ: fake-quantize weights at the scheduled precision after the
             # update (reference runtime/quantize.py in-place kernel pass)
@@ -1064,10 +1071,8 @@ class Engine:
                                opt_state=new_opt, loss_scale=ls)
         return new_state, metrics
 
-    def _constrain(self, tree, specs):
-        return jax.tree_util.tree_map(
-            lambda x, s: jax.lax.with_sharding_constraint(x, NamedSharding(self.mesh, s)),
-            tree, specs)
+    def _scatter_grads(self, grads):
+        return zero_lib.scatter_grads(grads, self.mesh, self._grad_specs)
 
     def _split_microbatches(self, batch, gas: int):
         """(B_global, …) → (gas, B_global/gas, …) keeping dp sharding local.
@@ -1118,19 +1123,19 @@ class Engine:
                                                  pld_theta)
                     g_acc = jax.tree_util.tree_map(
                         lambda a, g: a + g.astype(a.dtype), g_acc, grads)
-                    g_acc = self._constrain(g_acc, self._grad_specs)
+                    g_acc = self._scatter_grads(g_acc)
                     return (g_acc, l_acc + loss, i + 1), None
 
                 acc_dt = self._grad_dtype or jnp.float32
                 zeros = jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, acc_dt), state.params)
-                zeros = self._constrain(zeros, self._grad_specs)
+                zeros = self._scatter_grads(zeros)
                 (g_sum, loss_sum, _), _ = jax.lax.scan(
                     body, (zeros, jnp.float32(0.0), jnp.int32(0)), mbs)
             else:
                 loss_sum, g_sum = self._grads_of(
                     state.params, batch, rng, scale, pld_theta)
-                g_sum = self._constrain(g_sum, self._grad_specs)
+                g_sum = self._scatter_grads(g_sum)
             return self._apply_grads(state, g_sum, loss_sum, jnp.float32(gas))
 
         return step_fn
@@ -1265,7 +1270,8 @@ class Engine:
                 raise ValueError(
                     f"batch leading dims {sorted(leads)} != "
                     f"train_batch_size {B}")
-            batches = self._shard_batch(batch)
+            with trace.span("train/device-put", step=self.global_steps):
+                batches = self._shard_batch(batch)
         else:                                 # one batch per step
             if leads != {steps}:
                 raise ValueError(
@@ -1333,7 +1339,7 @@ class Engine:
                     if np.ndim(x) > seq_dim else x, seg)
             seg_thetas = None if thetas is None \
                 else jnp.asarray(thetas[seg_start:seg_stop])
-            with trace.span("train/fwd-bwd", step=self.global_steps,
+            with trace.span("train/dispatch", step=self.global_steps,
                             steps=n):
                 self._state, (losses, ovs) = self._compiled_multi_step(
                     n, stacked)(self._state, seg, seg_thetas)
@@ -1416,14 +1422,12 @@ class Engine:
                     loss, grads = self._grads_of(
                         state.params, mb, jax.random.fold_in(rng, i),
                         jnp.float32(1.0))
-                    g_acc = self._constrain(
-                        jax.tree_util.tree_map(jnp.add, g_acc, grads),
-                        self._grad_specs)
+                    g_acc = self._scatter_grads(
+                        jax.tree_util.tree_map(jnp.add, g_acc, grads))
                     return (g_acc, l_acc + loss, i + 1), None
 
-                zeros = self._constrain(jax.tree_util.tree_map(
-                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params),
-                    self._grad_specs)
+                zeros = self._scatter_grads(jax.tree_util.tree_map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), state.params))
                 (g, loss, _), _ = jax.lax.scan(
                     body, (zeros, jnp.float32(0.0), jnp.int32(0)), mbs)
             else:
@@ -1567,7 +1571,7 @@ class Engine:
                     return loss * scale
 
                 loss, grads = jax.value_and_grad(scaled_loss)(state.params)
-            grads = self._constrain(grads, self._grad_specs)
+            grads = self._scatter_grads(grads)
             return self._apply_grads(state, grads, loss, jnp.float32(1.0))
 
         return step_fn
@@ -1600,7 +1604,7 @@ class Engine:
             if self._has_store_transform:
                 # back to the stored layout for apply/step
                 grads = self._to_stored_params(grads)
-            grads = self._constrain(grads, self._grad_specs)
+            grads = self._scatter_grads(grads)
             return loss / scale, grads
 
         return recompile.watch(jax.jit(grad_fn), name="engine.grad_step")
@@ -1668,14 +1672,25 @@ class Engine:
         ``data_iter`` and the engine pulls ``gradient_accumulation_steps``
         global micro-batches from it (reference ``pipe/engine.py:302``
         semantics).
+
+        One ``train/step`` span with three children: ``train/next-batch``
+        (pull, concatenate, relayout), ``train/device-put`` and
+        ``train/dispatch`` (the call of the compiled step: its enqueue,
+        the recompile watchdog's signature check, and the wait for a free
+        slot once the host runs ahead of the device); what is left is the
+        step's self time (throughput timer, guard, print).
         """
+        with trace.span("train/step", step=self.global_steps):
+            return self._train_batch(batch, data_iter)
+
+    def _train_batch(self, batch, data_iter):
         from ..utils.heartbeat import beat
 
         beat()   # launcher failure detector (no-op unless launched with one)
         if self._param_offload is None:
             self._require_state()
         if batch is None:
-            with trace.span("train/load-batch", step=self.global_steps):
+            with trace.span("train/next-batch", step=self.global_steps):
                 if data_iter is None:
                     data_iter = self._train_iter()
                 micros = [next(data_iter)
@@ -1717,7 +1732,7 @@ class Engine:
             theta = self.progressive_layer_drop.update_state(self.global_steps)
             extra = (jnp.float32(theta),)
         if self._param_offload is not None:
-            with trace.span("train/fwd-bwd", step=self.global_steps,
+            with trace.span("train/dispatch", step=self.global_steps,
                             path="param-offload"):
                 loss = self._param_offload.train_batch(batch)
             self.global_steps += 1
@@ -1729,11 +1744,10 @@ class Engine:
                          f"(param-offload={self.param_offload_device})",
                          ranks=[0])
             return loss
-        with trace.span("train/load-batch", step=self.global_steps,
-                        phase="device-put"):
+        with trace.span("train/device-put", step=self.global_steps):
             batch = self._shard_batch(batch)
         if self.offload_device != "none":
-            with trace.span("train/fwd-bwd", step=self.global_steps,
+            with trace.span("train/dispatch", step=self.global_steps,
                             path="host-offload"):
                 loss = self._host_offload_train_batch(batch)
             self.global_steps += 1
@@ -1754,7 +1768,7 @@ class Engine:
                              "signatures_seen", None) if attr_sample else None
         self._tput.start()
         t_attr = time.perf_counter() if attr_sample else 0.0
-        with trace.span("train/fwd-bwd", step=self.global_steps):
+        with trace.span("train/dispatch", step=self.global_steps):
             self._state, metrics = self._compiled_train_step(
                 self._state, batch, *extra)
         if attr_sample:
@@ -1791,15 +1805,17 @@ class Engine:
         if self._param_offload is not None:
             return self._param_offload.eval_loss(batch)
         self._require_state()
-        return self._compiled_eval_step(self._state.params, self._shard_batch(batch))
+        batch = self._shard_batch(batch)
+        with trace.span("eval/dispatch", step=self.global_steps):
+            return self._compiled_eval_step(self._state.params, batch)
 
     # -- DeepSpeed 3-call compatibility path ---------------------------
     def forward(self, batch):
         """Record the micro-batch; loss returned lazily by backward's grad pass."""
         self._require_state()
-        with trace.span("train/load-batch", micro=self.micro_steps):
+        with trace.span("train/device-put", micro=self.micro_steps):
             self._fwd_batch = self._shard_batch(batch)
-        with trace.span("train/fwd-bwd", micro=self.micro_steps):
+        with trace.span("train/dispatch", micro=self.micro_steps):
             loss, grads = self._compiled_grad_step(
                 self._state, self._fwd_batch, jnp.int32(self.micro_steps))
         self._pending = (loss, grads)

@@ -14,6 +14,7 @@ from typing import Callable, Optional, Union
 
 import optax
 
+from ..telemetry import trace
 from . import constants as C
 from .config import Config, OptimizerConfig
 
@@ -67,6 +68,18 @@ def build_optimizer(cfg: OptimizerConfig,
     raise ValueError(f"unknown optimizer {name!r}; valid: {C.DEEPSPEED_OPTIMIZERS}")
 
 
+def clip_by_global_norm(max_norm: float) -> optax.GradientTransformation:
+    """``optax.clip_by_global_norm`` whose operations carry the device
+    scope ``grad_clip`` (metadata only)."""
+    clip = optax.clip_by_global_norm(max_norm)
+
+    def update(updates, state, params=None):
+        with trace.device_span("grad_clip"):
+            return clip.update(updates, state, params)
+
+    return optax.GradientTransformation(clip.init, update)
+
+
 def build_tx(config: Config, learning_rate: Optional[ScalarOrSchedule] = None
              ) -> optax.GradientTransformation:
     """Full gradient-transformation chain: clip → optimizer.
@@ -77,6 +90,6 @@ def build_tx(config: Config, learning_rate: Optional[ScalarOrSchedule] = None
     """
     parts = []
     if config.gradient_clipping and config.gradient_clipping > 0:
-        parts.append(optax.clip_by_global_norm(config.gradient_clipping))
+        parts.append(clip_by_global_norm(config.gradient_clipping))
     parts.append(build_optimizer(config.optimizer, learning_rate))
     return optax.chain(*parts) if len(parts) > 1 else parts[0]

@@ -8,11 +8,27 @@ Seven coordinated surfaces replacing the reference's scattered
   (``snapshot()``) and Prometheus-text export; every subsystem
   (``MonitorMaster`` events, ``ThroughputTimer``, serving latency,
   heartbeats, the watchdog) publishes here.
-- :mod:`.trace` — host-side span tracing emitting Chrome-trace JSON
-  (Perfetto-viewable), wired into the train-engine phases, the serving
-  loop, and (via ``device_span``/HLO metadata) pipeline stage bodies.
+- :mod:`.trace` — the one tracer.  Every host span (``init/engine``,
+  ``init/params``, ``eval/dispatch``; ``train/step`` with children
+  ``train/next-batch``, ``train/device-put``, ``train/dispatch``;
+  ``serve/step`` with ``serve/admit``, ``serve/prefill-batch`` →
+  ``serve/prefill``, ``serve/decode-tick`` → ``serve/fetch``,
+  ``serve/retire``) is always kept in a bounded ring with its parent
+  (read API: ``trace.spans(prefix, since_s, until_s)`` on the
+  ``perf_counter`` axis, ``trace.totals()``), always mirrored into
+  ``jax.profiler.TraceAnnotation`` so a profiler session shows it on the
+  device trace's clock, and written as Chrome-trace JSON when enabled.
+  Measured cost with the Chrome recorder off: 3-5 us a span.
+  ``device_span`` stamps HLO metadata inside compiled code
+  (``loss_head``, ``grad_clip``, ``optimizer``, ``embed``,
+  ``zero/scatter``, the pipeline stages).
 - :mod:`.recompile` — watchdog over jitted hot loops that counts
-  distinct compile signatures and warns when a warm loop recompiles.
+  distinct compile signatures and warns when a warm loop recompiles;
+  and the compile-event listeners: seconds by phase
+  (``xla_compile_seconds_total{phase,span}``) and executables
+  (``xla_executables_total{how,span}``) by innermost open span, for
+  every executable the process makes, plus a ``compile/backend`` record
+  in the tracer's ring under the span that compiled.
 - :mod:`.exporter` — per-rank HTTP server (``/metrics`` Prometheus
   text, ``/healthz`` liveness JSON, ``/statusz`` operational JSON);
   opt-in via ``dstpu --telemetry_port`` / ``DSTPU_TELEMETRY_PORT``.
@@ -21,9 +37,10 @@ Seven coordinated surfaces replacing the reference's scattered
 - :mod:`.memory` — per-executable HBM accounting
   (``compiled.memory_analysis()`` normalized behind ONE helper) and
   live-array memory gauges sampled at scrape time.
-- :mod:`.flightrec` — always-on crash flight recorder (last spans /
-  logs / metric deltas) dumped on atexit, SIGTERM/SIGABRT, and
-  unhandled exceptions; the launcher pretty-prints it on restart.
+- :mod:`.flightrec` — crash flight recorder (last logs / metric deltas,
+  and the newest spans of the tracer's ring) dumped on atexit,
+  SIGTERM/SIGABRT, and unhandled exceptions; the launcher pretty-prints
+  it on restart.
 - :mod:`.fleet` — the multi-replica rollup: scrapes N per-rank
   exporters (static list / env / the launcher-written ``fleet.json``),
   merges them per metric kind, runs a per-replica health state
@@ -41,9 +58,9 @@ Launcher integration: ``dstpu --metrics_dir DIR`` injects
 ``DSTPU_METRICS_DIR`` so every rank dumps ``metrics_rank<k>.json`` on
 exit (and, with the flight recorder, on SIGTERM) plus
 ``flight_<k>.json`` forensics; ``dstpu --telemetry_port P`` serves the
-live endpoints on ``P + rank``; ``DSTPU_TRACE=/path.json`` auto-enables
-tracing and writes the trace on exit (use ``{rank}`` in the path for
-multi-rank runs).
+live endpoints on ``P + rank``; ``DSTPU_TRACE=/path.json`` also keeps
+Chrome-trace events and writes the file on exit (use ``{rank}`` in the
+path for multi-rank runs).
 """
 from . import recompile, trace  # noqa: F401
 from .registry import (  # noqa: F401
@@ -58,8 +75,10 @@ from . import reqtrace  # noqa: F401  (needs registry + trace above)
 
 # arm the per-rank exit dump when the launcher asked for one
 maybe_install_exit_dump()
-# goodput attribution rides span boundaries; always on (near-free)
+# goodput attribution rides span boundaries; always on
 goodput.install()
+# compile seconds and executables by phase and open span; always on
+recompile.install_compile_events()
 # live-HBM gauges refresh on every scrape/dump
 from .registry import register_collector as _register_collector  # noqa: E402
 

@@ -84,7 +84,7 @@ def register_status_owner(name: str, owner, method: str) -> None:
 
 
 def _collect_status() -> dict:
-    from . import goodput, recompile
+    from . import goodput, recompile, trace
 
     out: dict = {
         "rank": _registry._rank(),
@@ -93,6 +93,8 @@ def _collect_status() -> dict:
         "uptime_s": round(time.monotonic() - _START_MONO, 3),
         "xla_recompiles_total": recompile.total_recompiles(),
         "goodput": goodput.summary(),
+        # count / seconds / self seconds by span name since process start
+        "spans": trace.totals(),
     }
     for name, fn in list(_status_providers.items()):
         try:

@@ -3,11 +3,11 @@
 A crashed worker today leaves nothing: the metrics exit dump needs a
 clean ``atexit``, the trace file needs tracing enabled, and the launcher
 only sees an exit code or a stale heartbeat.  The flight recorder keeps
-a small always-on in-memory ring of the last N completed spans (via the
-tracer's span-observer hook — recording works with Chrome tracing OFF),
-the last N ``deepspeed_tpu`` log records (a ``logging.Handler``), and
+the last N ``deepspeed_tpu`` log records (a ``logging.Handler``) and
 recent metric deltas (counter movement between throttled ``mark()``
 calls — wired off ``goodput.note_step`` and the heartbeat), and writes
+them, with the last N finished spans read from the tracer's always-on
+ring (``trace.spans()`` — there is one span ring, the tracer's), to
 ``<metrics_dir>/flight_<rank>.json`` from:
 
 - ``atexit`` (clean exits — the dump doubles as a "last run" record),
@@ -39,6 +39,7 @@ from typing import Optional
 
 from ..utils.logging import logger
 from . import registry as _registry
+from . import trace as _trace
 
 __all__ = ["FlightRecorder", "get_recorder", "maybe_install", "mark",
            "dump", "pretty", "add_sigterm_hook", "sigterm_managed",
@@ -48,7 +49,7 @@ __all__ = ["FlightRecorder", "get_recorder", "maybe_install", "mark",
 # the metrics dir; defaults to DSTPU_METRICS_DIR
 FLIGHT_DIR_ENV = "DSTPU_FLIGHT_DIR"
 
-_SPAN_RING = 256
+_DUMP_SPANS = 256      # newest spans of the tracer's ring a dump carries
 _LOG_RING = 200
 _DELTA_RING = 120
 _MARK_MIN_INTERVAL_S = 1.0
@@ -75,7 +76,6 @@ class FlightRecorder:
         self.directory = directory
         self._t0_mono = time.monotonic()
         self._t0_unix = time.time()
-        self.spans: deque = deque(maxlen=_SPAN_RING)
         self.logs: deque = deque(maxlen=_LOG_RING)
         self.deltas: deque = deque(maxlen=_DELTA_RING)
         # RLock: a SIGTERM landing inside mark() must not deadlock the
@@ -86,14 +86,14 @@ class FlightRecorder:
         self._dumped_reasons: set = set()
         self._log_handler = _RingLogHandler(self.logs)
 
-    # -- span observer protocol (trace.add_span_observer) --------------
-    def span_enter(self, name: str) -> None:
-        pass
-
-    def span_exit(self, name: str, dur_s: float, args) -> None:
-        self.spans.append({"t": time.time(), "name": name,
-                           "dur_ms": round(dur_s * 1e3, 3),
-                           **({"args": args} if args else {})})
+    @staticmethod
+    def _last_spans() -> list:
+        """The newest finished spans of the tracer's ring in the dump's
+        format (``t``: unix time the span ended)."""
+        return [{"t": _trace.perf_to_unix(s.end_s), "name": s.name,
+                 "dur_ms": round(s.dur_s * 1e3, 3),
+                 **({"args": s.args} if s.args else {})}
+                for s in _trace.spans()[-_DUMP_SPANS:]]
 
     # -- metric deltas ---------------------------------------------------
     def _counter_totals(self) -> dict:
@@ -156,7 +156,7 @@ class FlightRecorder:
                 "heartbeat_age_s":
                     None if hb_age is None else round(hb_age, 3),
                 "goodput": goodput.summary(),
-                "spans": list(self.spans),
+                "spans": self._last_spans(),
                 "logs": list(self.logs),
                 "metric_deltas": list(self.deltas),
                 "metrics": _registry.get_registry().snapshot(),
@@ -233,13 +233,7 @@ def disarm() -> None:
     rank 0's forensics at launcher exit."""
     global _recorder
     if _recorder is not None:
-        try:
-            from . import trace as _trace
-
-            _trace.remove_span_observer(_recorder)
-            logger.removeHandler(_recorder._log_handler)
-        except Exception:
-            pass
+        logger.removeHandler(_recorder._log_handler)
     _recorder = None
 
 
@@ -335,10 +329,6 @@ def maybe_install(directory: Optional[str] = None) -> Optional[FlightRecorder]:
         _recorder.directory = directory
         return _recorder
     _recorder = FlightRecorder(directory)
-
-    from . import trace as _trace
-
-    _trace.add_span_observer(_recorder)
     logger.addHandler(_recorder._log_handler)
     if not _atexit_done:
         atexit.register(_on_atexit)

@@ -16,7 +16,7 @@ splits the host wall clock into phases:
 Attribution rides the tracer's span boundaries (:mod:`.trace` notifies a
 span observer whether or not Chrome-trace recording is on), so the
 engine/serving loops need no extra instrumentation, and it is
-EXCLUSIVE: a ``train/checkpoint`` span nested inside a ``train/fwd-bwd``
+EXCLUSIVE: a ``train/checkpoint`` span nested inside a ``train/dispatch``
 span bills the checkpoint seconds to ``checkpoint`` only, and compile
 seconds reported mid-span are subtracted from the enclosing phase.
 
@@ -44,14 +44,19 @@ PHASES = ("compute", "data_wait", "checkpoint", "recompile")
 # (the serving analog of waiting on input); prefill/decode are the
 # useful serving compute.
 SPAN_PHASE = {
-    "train/fwd-bwd": "compute",
+    # the call of the compiled step: its enqueue, and under run-ahead the
+    # wait for the device to take it — the nearest host-side stand-in for
+    # "the device is computing"
+    "train/dispatch": "compute",
     "train/apply-step": "compute",
-    "train/load-batch": "data_wait",
+    "train/next-batch": "data_wait",
+    "train/device-put": "data_wait",
     "train/checkpoint": "checkpoint",
     "serve/prefill": "compute",
     "serve/decode-tick": "compute",
     "serve/verify-tick": "compute",   # speculative batched verify forward
-    "serve/admission": "data_wait",
+    "serve/admit": "data_wait",
+    "serve/prefill-batch": "data_wait",   # less its serve/prefill children
 }
 
 _tls = threading.local()
@@ -81,6 +86,7 @@ class GoodputTracker:
         self._last_step_mono: Optional[float] = None
         self._last_step_wall: Optional[float] = None
         self._steps_by_kind: Dict[str, int] = {}
+        self._by_phase: dict = {}    # phase -> (histogram, counter) child
         self._h = reg.histogram(
             "goodput_phase_seconds",
             "per-occurrence wall time by phase (exclusive attribution)",
@@ -120,13 +126,17 @@ class GoodputTracker:
             if self._t0 is None:
                 self._t0 = time.monotonic() - dur_s
             self._totals[ph] = self._totals.get(ph, 0.0) + dur_s
-        self._h.labels(phase=ph).observe(dur_s)
-        self._c.labels(phase=ph).inc(dur_s)
+        hc = self._by_phase.get(ph)
+        if hc is None:    # label lookup costs a microsecond: once a phase
+            hc = self._by_phase[ph] = (self._h.labels(phase=ph),
+                                       self._c.labels(phase=ph))
+        hc[0].observe(dur_s)
+        hc[1].inc(dur_s)
 
     def note_compile(self, dur_s: float) -> None:
         """Bill ``dur_s`` of jit trace+compile time to ``recompile`` and
         subtract it from the enclosing span's phase (the compile happens
-        INSIDE e.g. a ``train/fwd-bwd`` interval)."""
+        INSIDE e.g. a ``train/dispatch`` interval)."""
         self._observe("recompile", dur_s)
         stack = _stack()
         if stack:

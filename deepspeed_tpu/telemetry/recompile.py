@@ -30,17 +30,31 @@ compile population lands in ``xla_compiled_signatures_total`` only, so
 
 Disable globally with ``DSTPU_RECOMPILE_WATCHDOG=0`` (``watch`` then
 returns the callable unwrapped).
+
+The watchdog sees only the jits it wraps.  :func:`install_compile_events`
+(called once, at telemetry import) sees every executable the process
+builds, eager ``dynamic_slice`` programs included: listeners over
+``jax.monitoring`` add each compile's seconds to
+``xla_compile_seconds_total{phase=trace|lower|backend|fetch, span}`` and
+count it in ``xla_executables_total{how=built|fetched, span}``, where
+``span`` is the innermost :mod:`.trace` span open on the compiling thread
+(``none`` outside any), and write each backend compile into the tracer's
+ring as a ``compile/backend`` child of that span.  So "where did set-up
+go" and "which step compiled" are one counter read and one span query.
 """
 from __future__ import annotations
 
 import os
+import threading
 import time
 from typing import Any, Optional
 
 from ..utils.logging import logger
 from . import registry as _registry
+from . import trace as _trace
 
-__all__ = ["watch", "RecompileWatchdog", "total_recompiles", "WATCHDOG_ENV"]
+__all__ = ["watch", "RecompileWatchdog", "total_recompiles", "WATCHDOG_ENV",
+           "install_compile_events"]
 
 WATCHDOG_ENV = "DSTPU_RECOMPILE_WATCHDOG"
 
@@ -288,3 +302,105 @@ def total_recompiles() -> float:
     """Sum of ``xla_recompiles_total`` across sites (0.0 when nothing
     recompiled or the watchdog never armed)."""
     return _get_default()._recompiles.total()
+
+
+# ----------------------------------------------------------------------
+# compile events: seconds and executables by phase and by open span
+# ----------------------------------------------------------------------
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_compile_tls = threading.local()
+_compile_events_installed = False
+
+
+def _span_label() -> str:
+    cur = _trace.current()
+    return "none" if cur is None else cur.name
+
+
+def _on_compile_duration(event: str, secs: float, **kw) -> None:
+    """``jax.monitoring`` duration listener.  The phases do not overlap:
+    a jit traced inside another's trace is counted once (in the outer
+    one's seconds), and ``backend_compile_duration``, which JAX takes
+    around build-or-fetch, is booked as ``backend`` less the ``fetch``
+    seconds of the same executable."""
+    tls = _compile_tls
+    if event == _TRACE_EVENT:
+        # ``open_traces`` holds, for each trace open on this thread, the
+        # seconds of the traces that ended inside it (_on_compile_begin
+        # pushes): book this one's seconds less those, once
+        stack = getattr(tls, "open_traces", None)
+        inside = stack.pop() if stack else 0.0
+        if stack:
+            stack[-1] += secs
+        phase, secs = "trace", max(0.0, secs - inside)
+    elif event == _LOWER_EVENT:
+        phase = "lower"
+    elif event == _FETCH_EVENT:
+        tls.fetch_s = secs
+        phase = "fetch"
+    elif event == _BACKEND_EVENT:
+        fetched = getattr(tls, "hit", False)
+        fetch_s = getattr(tls, "fetch_s", 0.0) if fetched else 0.0
+        tls.hit, tls.fetch_s = False, 0.0
+        how = "fetched" if fetched else "built"
+        _executables().labels(how=how, span=_span_label()).inc()
+        _trace.record("compile/backend", secs, fun=kw.get("fun_name"),
+                      how=how)
+        phase, secs = "backend", max(0.0, secs - fetch_s)
+    else:
+        return
+    _seconds().labels(phase=phase, span=_span_label()).inc(secs)
+
+
+def _on_compile_begin(event: str, value, **kw) -> None:
+    # JAX records a scalar (the start time) when a timed phase begins
+    if event == _TRACE_EVENT:
+        try:
+            _compile_tls.open_traces.append(0.0)
+        except AttributeError:
+            _compile_tls.open_traces = [0.0]
+
+
+def _on_compile_event(event: str, **kw) -> None:
+    # a persistent-cache hit is announced before the fetch's duration and
+    # the enclosing backend_compile_duration of the same executable
+    if event == _HIT_EVENT:
+        _compile_tls.hit = True
+
+
+def _seconds():
+    return _registry.counter(
+        "xla_compile_seconds_total",
+        "seconds JAX spent making executables, by phase (trace, lower, "
+        "backend compile, persistent-cache fetch) and innermost open span",
+        labelnames=("phase", "span"))
+
+
+def _executables():
+    return _registry.counter(
+        "xla_executables_total",
+        "executables made, built by the compiler or fetched from the "
+        "persistent cache, by innermost open span",
+        labelnames=("how", "span"))
+
+
+def install_compile_events() -> None:
+    """Register the listeners (idempotent; telemetry import calls it):
+    durations, cache hits, and the scalar JAX records when a timed phase
+    begins, which is what lets nested traces be told from siblings.
+    Listening touches no backend."""
+    global _compile_events_installed
+    if _compile_events_installed:
+        return
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_duration)
+    jax.monitoring.register_event_listener(_on_compile_event)
+    jax.monitoring.register_scalar_listener(_on_compile_begin)
+    _compile_events_installed = True
+

@@ -26,6 +26,7 @@ Design notes
 from __future__ import annotations
 
 import atexit
+import bisect
 import json
 import math
 import os
@@ -168,13 +169,7 @@ class _HistogramChild:
         with self._lock:
             # non-cumulative per-bucket counts internally; rendered
             # cumulatively (Prometheus ``le`` semantics) on export
-            i = 0
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    break
-            else:
-                i = len(self.buckets)
-            self.counts[i] += 1
+            self.counts[bisect.bisect_left(self.buckets, value)] += 1
             self.sum += value
             self.count += 1
 

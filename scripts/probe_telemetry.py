@@ -95,7 +95,7 @@ def main(out_dir=None):
         data = json.load(fh)                       # parseable trace file
     names = sorted({e["name"] for e in data["traceEvents"]})
     assert len(names) >= 3, f"too few span names: {names}"
-    for want in ("train/fwd-bwd", "serve/prefill", "serve/decode-tick"):
+    for want in ("train/dispatch", "serve/prefill", "serve/decode-tick"):
         assert want in names, f"missing span {want!r} in {names}"
 
     reg = get_registry()

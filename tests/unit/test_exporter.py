@@ -58,12 +58,16 @@ def test_exporter_port0_scrape_endpoints():
         assert "heartbeat_age_s" in health and "last_step_age_s" in health
 
         exporter.register_status_provider("unit", lambda: {"x": 1})
+        with trace.span("unit/statusz"):
+            pass
         code, body = _get(ex.port, "/statusz")
         status = json.loads(body)
         assert code == 200
         assert status["unit"] == {"x": 1}
         assert status["pid"] == os.getpid()
         assert "goodput" in status and "xla_recompiles_total" in status
+        # the tracer's totals, through the same read API tests use
+        assert status["spans"]["unit/statusz"]["count"] >= 1
 
         with pytest.raises(urllib.error.HTTPError) as ei:
             _get(ex.port, "/nope")
@@ -114,8 +118,8 @@ def _run_span(tracker, name, secs, inner=None):
 
 def test_goodput_span_classification():
     t = goodput.GoodputTracker(registry=Registry())
-    _run_span(t, "train/load-batch", 0.25)
-    _run_span(t, "train/fwd-bwd", 1.0)
+    _run_span(t, "train/next-batch", 0.25)
+    _run_span(t, "train/dispatch", 1.0)
     s = t.summary()
     assert s["data_wait_s"] == pytest.approx(0.25)
     assert s["compute_s"] == pytest.approx(1.0)
@@ -123,16 +127,16 @@ def test_goodput_span_classification():
 
 
 def test_goodput_nested_exclusive_attribution():
-    """A checkpoint span nested inside fwd-bwd bills checkpoint, not
+    """A checkpoint span nested inside dispatch bills checkpoint, not
     compute; an unclassified middle span propagates its children up."""
     t = goodput.GoodputTracker(registry=Registry())
-    # fwd-bwd(1.0s) > unclassified(0.5s) > checkpoint(0.4s)
-    t.span_enter("train/fwd-bwd")
+    # dispatch(1.0s) > unclassified(0.5s) > checkpoint(0.4s)
+    t.span_enter("train/dispatch")
     t.span_enter("unclassified")
     t.span_enter("train/checkpoint")
     t.span_exit("train/checkpoint", 0.4, None)
     t.span_exit("unclassified", 0.5, None)
-    t.span_exit("train/fwd-bwd", 1.0, None)
+    t.span_exit("train/dispatch", 1.0, None)
     s = t.summary()
     assert s["checkpoint_s"] == pytest.approx(0.4)
     assert s["compute_s"] == pytest.approx(0.6)    # 1.0 - nested 0.4
@@ -140,9 +144,9 @@ def test_goodput_nested_exclusive_attribution():
 
 def test_goodput_note_compile_subtracts_from_enclosing():
     t = goodput.GoodputTracker(registry=Registry())
-    t.span_enter("train/fwd-bwd")
+    t.span_enter("train/dispatch")
     t.note_compile(0.7)
-    t.span_exit("train/fwd-bwd", 1.0, None)
+    t.span_exit("train/dispatch", 1.0, None)
     s = t.summary()
     assert s["recompile_s"] == pytest.approx(0.7)
     assert s["compute_s"] == pytest.approx(0.3)

@@ -193,6 +193,295 @@ def test_trace_decorator():
 
 
 # ----------------------------------------------------------------------
+# the always-on ring, parents, self time, the profiler bridge
+# ----------------------------------------------------------------------
+def test_ring_keeps_spans_while_chrome_tracing_is_off():
+    import time
+
+    assert not trace.enabled()
+    t0 = time.perf_counter()
+    with trace.span("ring/a", step=3):
+        pass
+    t1 = time.perf_counter()
+    with trace.span("other/b"):
+        pass
+    assert trace.to_json()["traceEvents"] == []
+    (a,) = trace.spans(prefix="ring/")
+    assert a.name == "ring/a" and a.args == {"step": 3}
+    # the read axis is time.perf_counter()
+    assert t0 <= a.start_s <= a.end_s <= t1
+    assert [s.name for s in trace.spans(since_s=t1)] == ["other/b"]
+    assert [s.name for s in trace.spans(until_s=t1)] == ["ring/a"]
+    assert trace.spans(prefix="ring/", since_s=t1) == []
+    assert trace.totals()["ring/a"]["count"] == 1
+
+
+def test_span_parents_and_self_time_on_a_nested_trio():
+    import time
+
+    with trace.span("trio/root") as root:
+        with trace.span("trio/mid") as mid:
+            with trace.span("trio/leaf"):
+                time.sleep(0.01)
+            time.sleep(0.005)
+        assert trace.current() is root
+    by = {s.name: s for s in trace.spans(prefix="trio/")}
+    leaf, mid_s, root_s = by["trio/leaf"], by["trio/mid"], by["trio/root"]
+    assert root_s.parent_id == 0 and root_s.parent is None
+    assert (mid_s.parent_id, mid_s.parent) == (root_s.id, "trio/root")
+    assert (leaf.parent_id, leaf.parent) == (mid_s.id, "trio/mid")
+    assert len({leaf.id, mid_s.id, root_s.id}) == 3 and mid.id == mid_s.id
+    tot = trace.totals()
+    # self = duration minus what child spans cover
+    assert tot["trio/leaf"]["self_seconds"] == pytest.approx(leaf.dur_s)
+    assert tot["trio/mid"]["self_seconds"] == pytest.approx(
+        mid_s.dur_s - leaf.dur_s)
+    assert tot["trio/root"]["self_seconds"] == pytest.approx(
+        root_s.dur_s - mid_s.dur_s)
+    assert tot["trio/mid"]["self_seconds"] >= 0.004
+    assert tot["trio/root"]["self_seconds"] < 0.004
+    # the Chrome file carries the same ids when it is on
+    trace.enable()
+    with trace.span("trio/root"):
+        with trace.span("trio/mid"):
+            pass
+    ev = {e["name"]: e for e in trace.to_json()["traceEvents"]}
+    assert ev["trio/mid"]["parent_id"] == ev["trio/root"]["span_id"] != 0
+
+
+def test_ring_is_bounded():
+    assert trace.RING_SIZE >= 16384
+    for i in range(trace.RING_SIZE + 10):
+        trace.record("ring/filler", 0.0, i=i)
+    kept = trace.spans(prefix="ring/filler")
+    assert len(kept) == trace.RING_SIZE
+    assert kept[0].args == {"i": 10}            # the oldest ten fell out
+    # the totals forget nothing
+    assert trace.totals()["ring/filler"]["count"] == trace.RING_SIZE + 10
+
+
+def test_span_lands_on_the_host_plane_of_a_profiler_trace(tmp_path):
+    """Program spans share the device trace's clock by being IN it."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with trace.span("unit/xplane-outer", step=1):
+            with trace.span("unit/xplane-inner"):
+                jnp.ones(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = [pl for pl in ProfileData.from_file(path).planes
+            if pl.name == "/host:CPU"]
+    assert host
+    found = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+             for pl in host for line in pl.lines for e in line.events
+             if e.name.startswith("unit/xplane-")}
+    assert set(found) == {"unit/xplane-outer", "unit/xplane-inner"}
+    (o0, o1), (i0, i1) = found["unit/xplane-outer"], found["unit/xplane-inner"]
+    assert o0 <= i0 <= i1 <= o1
+
+
+# ----------------------------------------------------------------------
+# compile events by phase and by open span
+# ----------------------------------------------------------------------
+def _compile_counters(span):
+    snap = get_registry().snapshot()
+    secs = {s["labels"]["phase"]: s["value"]
+            for s in snap.get("xla_compile_seconds_total",
+                              {"samples": []})["samples"]
+            if s["labels"]["span"] == span}
+    n = sum(s["value"] for s in snap.get("xla_executables_total",
+                                         {"samples": []})["samples"]
+            if s["labels"]["span"] == span)
+    return secs, n
+
+
+def test_compile_events_are_booked_to_the_open_span():
+    import time
+
+    inners = [jax.jit(lambda x, i=i: jnp.sin(x) * i) for i in range(40)]
+
+    @jax.jit
+    def fresh(x):
+        for inner in inners:
+            x = inner(x) + jnp.where(x > 0, x, 0.0)
+        return x
+
+    x = jnp.ones(7)                 # its own executables build out here
+    secs0, n0 = _compile_counters("unit/compiles")
+    assert n0 == 0 and not secs0
+    t0 = time.perf_counter()
+    with trace.span("unit/compiles"):
+        fresh(x).block_until_ready()
+    wall = time.perf_counter() - t0
+    secs, n = _compile_counters("unit/compiles")
+    assert n == 1
+    assert all(secs[ph] > 0 for ph in ("trace", "lower", "backend"))
+    # forty jits and the jnp functions trace INSIDE `fresh`'s trace: each
+    # second is booked once, so the phases together fit in the span
+    assert sum(secs.values()) <= wall
+    (rec,) = [s for s in trace.spans(prefix="compile/backend")
+              if s.parent == "unit/compiles"]
+    assert rec.args["fun"] == "jit(fresh)" and rec.args["how"] == "built"
+    # a second call builds nothing
+    with trace.span("unit/compiles"):
+        fresh(x).block_until_ready()
+    assert _compile_counters("unit/compiles") == (secs, n)
+
+
+# ----------------------------------------------------------------------
+# the trainer's and the scheduler's span trees
+# ----------------------------------------------------------------------
+def test_train_batch_leaves_one_step_span_with_three_children():
+    from deepspeed_tpu.comm import mesh as mesh_mod
+
+    mesh_mod.set_mesh(None)
+    try:
+        import deepspeed_tpu
+        from .simple_model import SimpleModel
+
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=SimpleModel(),
+            config={"train_micro_batch_size_per_gpu": 2,
+                    "optimizer": {"type": "Adam", "params": {"lr": 1e-2}}})
+        engine.init_params()
+        rng = np.random.default_rng(0)
+        b = engine.train_batch_size
+
+        def batches():
+            while True:
+                x = rng.normal(size=(b, 16)).astype(np.float32)
+                yield {"x": x, "y": 0.1 * x}
+
+        it = batches()
+        for _ in range(3):
+            engine.train_batch(data_iter=it)
+        assert [s.name for s in trace.spans(prefix="init/")] == [
+            "init/engine", "init/params"]
+        steps = trace.spans(prefix="train/step")
+        assert [s.args["step"] for s in steps] == [0, 1, 2]
+        for step in steps:
+            kids = [s for s in trace.spans(prefix="train/")
+                    if s.parent_id == step.id]
+            assert [k.name for k in kids] == [
+                "train/next-batch", "train/device-put", "train/dispatch"]
+            assert all(k.parent == "train/step" for k in kids)
+            assert sum(k.dur_s for k in kids) <= step.dur_s
+        # the first step compiled, and says so under its dispatch span
+        secs, n = _compile_counters("train/dispatch")
+        assert n >= 1 and secs["backend"] > 0
+        first = [s for s in trace.spans(prefix="compile/backend")
+                 if s.parent == "train/dispatch"]
+        assert first and first[0].start_s >= steps[0].start_s
+        assert first[-1].end_s <= steps[0].end_s
+    finally:
+        mesh_mod.set_mesh(None)
+
+
+def test_batcher_step_leaves_the_serve_tree_with_the_requests_uid():
+    from deepspeed_tpu.comm import mesh as mesh_mod
+
+    mesh_mod.set_mesh(None)
+    try:
+        import deepspeed_tpu
+        from deepspeed_tpu.inference.serving import ContinuousBatcher
+        from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+        cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
+        model = GPT2LMHeadModel(cfg)
+        params = jax.tree_util.tree_map(
+            lambda x: getattr(x, "value", x),
+            model.init(jax.random.PRNGKey(0),
+                       jnp.zeros((1, 8), jnp.int32))["params"],
+            is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+        eng = deepspeed_tpu.init_inference(
+            model=model, mp_size=1, dtype=jnp.float32, params=params)
+        batcher = ContinuousBatcher(eng, n_slots=2)
+        prompt = np.arange(5, dtype=np.int32)
+        uid = batcher.submit(prompt, max_new_tokens=3)
+        while batcher.pending:
+            batcher.step(2)
+        mine = [s for s in trace.spans(prefix="serve/")
+                if uid in ((s.args or {}).get("uids") or ())]
+        assert {s.name for s in mine} == {
+            "serve/prefill", "serve/admit", "serve/decode-tick",
+            "serve/retire"}
+        steps = trace.spans(prefix="serve/step")
+        assert steps and steps[0].args["ticks"] == 2
+        ids = {s.id for s in steps}
+        by_name = {}
+        for s in trace.spans(prefix="serve/"):
+            by_name.setdefault(s.name, []).append(s)
+        # every child hangs off a step, directly or through its parent
+        for name in ("serve/admit", "serve/prefill-batch",
+                     "serve/decode-tick"):
+            assert all(s.parent_id in ids for s in by_name[name]), name
+        assert all(s.parent == "serve/prefill-batch"
+                   for s in by_name["serve/prefill"])
+        assert all(s.parent == "serve/decode-tick"
+                   for s in by_name["serve/fetch"])
+        assert by_name["serve/prefill-batch"][0].args["rows"] == 1
+        assert set(by_name) == {
+            "serve/step", "serve/admit", "serve/prefill-batch",
+            "serve/prefill", "serve/decode-tick", "serve/fetch",
+            "serve/retire"}
+    finally:
+        mesh_mod.set_mesh(None)
+
+
+def test_device_scopes_are_metadata_only(monkeypatch):
+    """``loss_head`` / ``grad_clip`` / ``optimizer`` / ``embed`` reach the
+    train step's op names and change not one instruction."""
+    import contextlib
+
+    from deepspeed_tpu.comm import mesh as mesh_mod
+
+    def lowered():
+        import deepspeed_tpu
+        from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+        mesh_mod.set_mesh(None)
+        cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32, scan_layers=False,
+                          loss_chunk=64)
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=GPT2LMHeadModel(cfg),
+            config={"train_micro_batch_size_per_gpu": 1,
+                    "gradient_clipping": 1.0,
+                    "zero_optimization": {"stage": 3},
+                    "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                    "mesh": {"fsdp": -1}})
+        engine.init_params()
+        ids = np.zeros((engine.train_batch_size, 16), np.int32)
+        batch = engine._shard_batch({"input_ids": ids, "labels": ids})
+        return engine._compiled_train_step.lower(engine._state, batch)
+
+    try:
+        with_scopes = lowered()
+        monkeypatch.setattr(trace, "device_span",
+                            lambda name: contextlib.nullcontext())
+        without = lowered()
+    finally:
+        mesh_mod.set_mesh(None)
+    named = with_scopes.as_text(debug_info=True)
+    for scope in ("loss_head", "grad_clip", "optimizer", "embed",
+                  "zero/scatter"):
+        assert f"/{scope}/" in named, scope
+        assert f"/{scope}/" not in without.as_text(debug_info=True), scope
+    # flax already stamps the module path; nothing was added for these
+    assert "/h_0/attn/" in named and "/h_1/mlp/" in named
+    a, b = with_scopes.as_text(), without.as_text()
+    assert len(a.splitlines()) == len(b.splitlines())
+    assert a == b
+
+
+# ----------------------------------------------------------------------
 # recompilation watchdog
 # ----------------------------------------------------------------------
 def _site_value(registry, metric, site):
@@ -377,7 +666,7 @@ def test_train_serve_smoke_emits_trace_and_metrics(tmp_path):
             data = json.load(fh)
         names = {e["name"] for e in data["traceEvents"]}
         assert len(names) >= 3, names
-        assert {"train/fwd-bwd", "serve/prefill",
+        assert {"train/dispatch", "serve/prefill",
                 "serve/decode-tick"} <= names
 
         snap = get_registry().snapshot()
