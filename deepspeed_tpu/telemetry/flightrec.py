@@ -339,9 +339,12 @@ def maybe_install(directory: Optional[str] = None) -> Optional[FlightRecorder]:
     for signum in (signal.SIGTERM, signal.SIGABRT):
         try:
             # only from the main thread; a custom handler someone already
-            # installed is chained, not replaced
-            _prev_handlers[signum] = signal.getsignal(signum)
-            signal.signal(signum, _on_signal)
+            # installed is chained, not replaced.  Re-arming after
+            # disarm() finds our own handler still installed: chaining it
+            # to itself would recurse forever on the signal
+            if signal.getsignal(signum) is not _on_signal:
+                _prev_handlers[signum] = signal.getsignal(signum)
+                signal.signal(signum, _on_signal)
         except (ValueError, OSError):     # non-main thread / exotic env
             _prev_handlers.pop(signum, None)
     return _recorder

@@ -131,6 +131,11 @@ def test_async_checkpoint_interval_and_preemption(tmp_path):
     from deepspeed_tpu.runtime.checkpointing import AsyncCheckpointManager
 
     e = make_engine()
+    # an earlier test of this worker may have left the flight recorder's
+    # SIGTERM handler installed; it re-delivers the signal after its dump
+    # and would kill the worker.  This test is about the manager's own
+    # handler (test_checkpoint_durability covers the hook mode)
+    prev_handler = signal.signal(signal.SIGTERM, signal.SIG_DFL)
     mgr = AsyncCheckpointManager(e, str(tmp_path), interval_steps=2,
                                  install_sigterm=True)
     try:
@@ -151,6 +156,7 @@ def test_async_checkpoint_interval_and_preemption(tmp_path):
         assert (tmp_path / "latest").read_text() == "global_step5"
     finally:
         mgr.close()
+        signal.signal(signal.SIGTERM, prev_handler)
 
     e2 = make_engine()
     e2.init_params()

@@ -279,6 +279,20 @@ def test_flightrec_excepthook_captures_traceback(tmp_path):
     assert "RuntimeError" in flightrec.pretty(path)
 
 
+def test_flightrec_rearm_does_not_chain_to_itself(tmp_path):
+    """disarm() leaves the signal hooks installed; arming again must not
+    record our own handler as the previous one (SIGTERM then recursed
+    until the stack ran out, whichever test armed first)."""
+    import signal
+
+    flightrec.maybe_install(str(tmp_path))
+    flightrec.disarm()
+    flightrec.maybe_install(str(tmp_path))
+    assert signal.getsignal(signal.SIGTERM) is flightrec._on_signal
+    assert flightrec._prev_handlers[signal.SIGTERM] \
+        is not flightrec._on_signal
+
+
 def test_flightrec_sigterm_subprocess_leaves_forensics(tmp_path):
     """The acceptance path: SIGTERM (the launcher killing a worker) must
     leave BOTH a final metrics snapshot and a flight dump that replays

@@ -7,7 +7,10 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.ops.attention import _jnp_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention import (
+    DIAGONAL, FULL, VOID, _full_tiles, flash_attention,
+    flash_attention_with_lse, score_tile_schedule)
+from deepspeed_tpu.telemetry import registry
 
 
 def _qkv(B=1, S=256, H=2, D=64, seed=0, dtype=jnp.float32):
@@ -118,7 +121,7 @@ def test_flash_spmd_on_mesh():
 
 def test_flash_heads_per_program_parity():
     """The G>1 head-batched grid must match G=1 numerics for the output and
-    ALL THREE gradients (dq via _dq_kernel, dk/dv via _dkv_kernel)."""
+    ALL THREE gradients (dq, dk and dv all come from _dqkv_kernel)."""
     import numpy as np
 
     q, k, v = _qkv(B=2, H=4)
@@ -137,3 +140,171 @@ def test_flash_heads_per_program_parity():
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-6, atol=1e-6)
+
+
+# S=128/512: one diagonal tile; 1024/1536: the unrolled sweep with void,
+# full and diagonal tiles; 2560: the fori_loop sweep
+_SCHEDULE_SEQS = [128, 512, 1024, 1536, 2560]
+
+
+def _assert_grads_close(f_flash, f_ref, args):
+    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(*args)
+    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(*args)
+    for a, b in zip(g_flash, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("S", _SCHEDULE_SEQS)
+def test_flash_schedule_parity(S, causal):
+    """Output and all three gradients against the dense reference on
+    every tile schedule: single tile, unrolled, fori_loop."""
+    q, k, v = _qkv(S=S, H=1, seed=S)
+    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_ref(q, k, v, causal)),
+                               rtol=2e-5, atol=2e-5)
+    _assert_grads_close(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=causal, interpret=True) ** 2),
+        lambda q, k, v: jnp.sum(_ref(q, k, v, causal) ** 2), (q, k, v))
+
+
+def _ref_with_lse(q, k, v, causal):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        S, Sk = s.shape[-2:]
+        s = jnp.where(jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :],
+                      s, -jnp.inf)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)               # (B,H,S)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+    return out, lse.transpose(0, 2, 1)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("S", _SCHEDULE_SEQS)
+def test_flash_with_lse_schedule_parity(S, causal):
+    """The LSE-exposing variant (ring attention's building block) with a
+    NON-ZERO lse cotangent, on every tile schedule."""
+    q, k, v = _qkv(S=S, H=1, seed=S + 1)
+    w = jnp.asarray(np.random.default_rng(S).normal(size=(1, S, 1)),
+                    jnp.float32)
+    out, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                        interpret=True)
+    ref_out, ref_lse = _ref_with_lse(q, k, v, causal)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=2e-5, atol=2e-5)
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(lse * w)
+        return f
+
+    _assert_grads_close(
+        loss(lambda q, k, v: flash_attention_with_lse(
+            q, k, v, causal=causal, interpret=True)),
+        loss(lambda q, k, v: _ref_with_lse(q, k, v, causal)), (q, k, v))
+
+
+def _tile_counts():
+    fam = registry.counter("flash_score_tiles_total",
+                           labelnames=("pass", "kind"))
+    return {labels: child.value for labels, child in fam.samples()}
+
+
+@pytest.mark.parametrize("causal,want", [
+    (True, {"fwd": {VOID: 1, FULL: 1, DIAGONAL: 2},
+            "bwd": {VOID: 6, FULL: 6, DIAGONAL: 4}}),
+    (False, {"fwd": {FULL: 4}, "bwd": {FULL: 16}}),
+])
+def test_flash_score_tiles_counter(causal, want):
+    """At the benchmark cell's shape (S=1024, 512-blocks) one traced
+    forward and one traced backward each count the sub-tiles of a
+    head-sequence, in their own units: the backward halves its diagonal
+    tiles (256-units: void 6 / full 6 / diagonal 4), the forward leaves
+    them whole (512-units: void 1 / full 1 / diagonal 2)."""
+    sched = score_tile_schedule(1024, 1024, 512, 512, causal, True)
+    assert (sched.sub_q, sched.sub_k) == (256, 256)
+    q, k, v = _qkv(S=1024, H=1)
+    before = _tile_counts()
+    jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, interpret=True))))(q, k, v)
+    after = _tile_counts()
+    delta = {key: after[key] - before.get(key, 0) for key in after}
+    assert {key: n for key, n in delta.items() if n} == {
+        (pass_, kind): n for pass_, kinds in want.items()
+        for kind, n in kinds.items()}
+
+
+@pytest.mark.parametrize("halve", [False, True])
+@pytest.mark.parametrize("S,Sk,bq,bk", [
+    (1024, 1024, 512, 512), (128, 128, 128, 128), (2560, 2560, 512, 512),
+    (1024, 1024, 256, 512), (1024, 1024, 512, 128), (768, 768, 384, 256),
+    (512, 1024, 512, 512), (1024, 512, 256, 512), (100, 100, 100, 100),
+])
+def test_score_tile_schedule_covers_the_triangle(S, Sk, bq, bk, halve):
+    """VOID sub-tiles hold no ``q_pos >= k_pos`` entry, FULL ones nothing
+    else, DIAGONAL ones both; together they tile the score matrix once.
+    What the kernels walk (the per-offset plan of a diagonal tile and the
+    full-tile range of each program) is that same list."""
+    sched = score_tile_schedule(S, Sk, bq, bk, True, halve)
+    sq, sk = sched.sub_q, sched.sub_k
+    keep = np.arange(S)[:, None] >= np.arange(Sk)[None, :]
+    seen = np.zeros((S, Sk), np.int32)
+    for q0, k0, kind in sched.tiles:
+        part = keep[q0:q0 + sq, k0:k0 + sk]
+        seen[q0:q0 + sq, k0:k0 + sk] += 1
+        assert {VOID: not part.any(), FULL: part.all(),
+                DIAGONAL: part.any() and not part.all()}[kind]
+    assert (seen == 1).all()
+    kinds = {(q0, k0): kind for q0, k0, kind in sched.tiles}
+    plan = dict(sched.diagonal)
+    for qi in range(S // bq):
+        lo, hi = _full_tiles(qi, sched, own_is_q=True)
+        for kj in range(Sk // bk):
+            subs = {kinds[qi * bq + r0, kj * bk + c0]
+                    for r0 in range(0, bq, sq) for c0 in range(0, bk, sk)}
+            d0 = qi * bq - kj * bk
+            if lo <= kj < hi:
+                assert subs == {FULL} and d0 not in plan
+            elif d0 in plan:        # the kernel walks its live sub-tiles
+                assert {(r0, c0): kind for r0, c0, kind in plan[d0]} == {
+                    (r0, c0): kinds[qi * bq + r0, kj * bk + c0]
+                    for r0 in range(0, bq, sq) for c0 in range(0, bk, sk)
+                    if kinds[qi * bq + r0, kj * bk + c0] != VOID}
+            else:                   # no code runs
+                assert subs == {VOID}
+    for kj in range(Sk // bk):      # the backward's view of the same tiles
+        lo, hi = _full_tiles(kj, sched, own_is_q=False)
+        for qi in range(S // bq):
+            full = {kinds[qi * bq + r0, kj * bk + c0]
+                    for r0 in range(0, bq, sq)
+                    for c0 in range(0, bk, sk)} == {FULL}
+            assert full == (lo <= qi < hi)
+
+
+def test_score_tile_schedule_non_causal_is_all_full():
+    sched = score_tile_schedule(1024, 512, 512, 512, False, True)
+    assert sched.diagonal == ()
+    assert {kind for _, _, kind in sched.tiles} == {FULL}
+    assert _full_tiles(0, sched, own_is_q=True) == (0, 1)
+    assert _full_tiles(0, sched, own_is_q=False) == (0, 2)
+
+
+@pytest.mark.parametrize("S,bq,bk", [(1024, 256, 512), (1024, 512, 128),
+                                     (768, 384, 256), (2560, 256, 512)])
+def test_flash_unequal_blocks_parity(S, bq, bk):
+    """block_q != block_k: diagonal tiles sit at several offsets, and the
+    two kernels' sweeps differ in length (one unrolled, one looped)."""
+    q, k, v = _qkv(S=S, H=1, seed=S + bq)
+    kw = dict(causal=True, block_q=bq, block_k=bk, interpret=True)
+    np.testing.assert_allclose(np.asarray(flash_attention(q, k, v, **kw)),
+                               np.asarray(_ref(q, k, v, True)),
+                               rtol=2e-5, atol=2e-5)
+    _assert_grads_close(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v, **kw) ** 2),
+        lambda q, k, v: jnp.sum(_ref(q, k, v, True) ** 2), (q, k, v))
