@@ -262,8 +262,70 @@ def kernel_w8_matmul():
         _check_close(f"w8_matmul ({N_SLOTS},{K})x({K},{N}) g=128", out, ref)
 
 
-KERNEL_CASES = (kernel_flash, kernel_decode_attention, kernel_paged_attention,
-                kernel_decode_layer, kernel_w8_matmul)
+def kernel_grouped_matmul():
+    """The expert matmuls of OLMoE-1B-7B's train step (8192 tokens x top-8
+    rows over 64 experts) through ``ops.grouped_matmul``: forward (``gmm``),
+    d-rows (``gmm`` transposed) and d-weights (``tgmm``), with uneven groups
+    off the 512-row tile and two experts nobody chose, against a loop over
+    the groups' own row slices in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    rows, experts = 8192 * 8, 64
+    rng = np.random.default_rng(26)
+    share = rng.dirichlet(np.full(experts, 0.7))
+    share[[5, 63]] = 0.0                                # empty groups
+    sizes = np.floor(share / share.sum() * rows).astype(np.int64)
+    sizes[int(np.argmax(sizes))] += rows - sizes.sum()
+    assert sizes.sum() == rows and (sizes[[5, 63]] == 0).all()
+    ends = np.cumsum(sizes)
+    print(f"  grouped_matmul groups: max {sizes.max()} min "
+          f"{sizes[sizes > 0].min()} rows, {int((sizes % 512 != 0).sum())} "
+          f"of {experts} off the row tile", flush=True)
+
+    def plain(lhs, rhs):
+        return jnp.concatenate([
+            lhs[e - n:e].astype(jnp.float32) @ rhs[g].astype(jnp.float32)
+            for g, (e, n) in enumerate(zip(ends, sizes)) if n])
+
+    for k, n in ((2048, 1024), (1024, 2048)):           # gate/up, down
+        ks = jax.random.split(jax.random.PRNGKey(k), 3)
+        lhs = jax.random.normal(ks[0], (rows, k), jnp.float32
+                                ).astype(jnp.bfloat16)
+        rhs = (0.02 * jax.random.normal(ks[1], (experts, k, n), jnp.float32)
+               ).astype(jnp.bfloat16)
+        ct = jax.random.normal(ks[2], (rows, n), jnp.float32
+                               ).astype(jnp.bfloat16)
+        gs = jnp.asarray(sizes, jnp.int32)
+
+        def loss(fn, lhs, rhs):
+            return (fn(lhs, rhs).astype(jnp.float32)
+                    * ct.astype(jnp.float32)).sum()
+
+        kern = lambda a, w: grouped_matmul(a, w, gs)    # noqa: E731
+        out = jax.jit(kern)(lhs, rhs)
+        d_lhs, d_rhs = jax.jit(jax.grad(lambda *a: loss(kern, *a),
+                                        argnums=(0, 1)))(lhs, rhs)
+        with jax.default_matmul_precision("highest"):
+            out_r = jax.jit(plain)(lhs, rhs)
+            d_lhs_r, d_rhs_r = jax.jit(jax.grad(lambda *a: loss(plain, *a),
+                                                argnums=(0, 1)))(lhs, rhs)
+        name = f"grouped_matmul ({rows},{k})x({experts},{k},{n})"
+        _check_close(f"{name} fwd", out, out_r)
+        _check_close(f"{name} d-rows", d_lhs, d_lhs_r)
+        _check_close(f"{name} d-weights", d_rhs, d_rhs_r)
+        empty = np.asarray(d_rhs, np.float32)[[5, 63]]
+        assert not empty.any(), "an expert with no rows got a gradient"
+    assert any(site == "grouped_matmul" and impl == "megablox" and count
+               for site, impl, _, count in dispatch_report()), dispatch_report()
+
+
+KERNEL_CASES = (kernel_flash, kernel_grouped_matmul, kernel_decode_attention,
+                kernel_paged_attention, kernel_decode_layer, kernel_w8_matmul)
 
 
 def phase_kernels():

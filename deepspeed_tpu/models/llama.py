@@ -5,6 +5,11 @@ the framework expects the dominant open-model family.  Architecture:
 RMSNorm, SwiGLU MLP, full rotary, grouped-query attention
 (``num_key_value_heads``), untied LM head.  Shares the logical-axis
 vocabulary, scan/remat/decode support of the other zoo families.
+
+OLMoE (arXiv:2409.02060) is this block with fields, not a file of its own:
+``moe`` puts a sparse SwiGLU-expert FFN (``parallel/moe.py``) in every
+block, ``qk_norm`` an RMSNorm over the whole q and k projections before
+the split into heads, and ``loss_chunk`` the chunked head.
 """
 from __future__ import annotations
 
@@ -17,6 +22,7 @@ import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
 from ..ops.rotary import apply_rotary_pos_emb
+from ..telemetry import trace
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
 
@@ -44,6 +50,14 @@ class LlamaConfig:
     remat_policy: str = "nothing_saveable"
     attn_impl: str = "auto"
     vocab_pad_multiple: int = 128
+    # sparse FFN: a parallel.moe.MoEConfig replaces the dense SwiGLU MLP of
+    # EVERY block with experts of width ``intermediate_size``
+    moe: Optional[Any] = None
+    # RMSNorm over the whole q / k projection, before heads and rotary
+    qk_norm: bool = False
+    # > 0 with labels: chunked cross-entropy head, logits never materialize
+    # (common.chunked_lm_loss); the output then carries no ``logits``
+    loss_chunk: int = 0
     decode: bool = False
     # weight-only int8 serving (ops/w8.py W8A16); set by init_inference
     w8: bool = False
@@ -103,11 +117,12 @@ def _dense(x, features, names, *, cfg, name, module):
 
 class RMSNorm(nn.Module):
     cfg: LlamaConfig
+    axis: str = "embed"             # logical axis of the normalised width
 
     @nn.compact
     def __call__(self, x, params_only: bool = False):
         scale = self.param("scale", nn.with_partitioning(nn.initializers.ones,
-                                                         ("embed",)),
+                                                         (self.axis,)),
                            (x.shape[-1],), self.cfg.param_dtype)
         if params_only:
             return scale
@@ -167,9 +182,13 @@ class LlamaAttention(nn.Module):
             return self._fused_decode(x, position_ids, attn_mask,
                                       fused_norm)
         q = _dense(x, H * D, ("embed", "qkv"), cfg=cfg, name="q_proj",
-                   module=self).reshape(B, S, H, D)
+                   module=self)
         k = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="k_proj",
-                   module=self).reshape(B, S, KV, D)
+                   module=self)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
+            k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
+        q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         v = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="v_proj",
                    module=self).reshape(B, S, KV, D)
         q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
@@ -203,7 +222,8 @@ class LlamaBlock(nn.Module):
     def __call__(self, x, inputs):
         position_ids, attn_mask = inputs
         cfg = self.cfg
-        if cfg.decode and x.shape[1] == 1:
+        if cfg.decode and x.shape[1] == 1 and cfg.moe is None \
+                and not cfg.qk_norm:
             from .common import decode_fused_plan, fused_decode_post_attn
 
             H, KV, D = (cfg.num_attention_heads, cfg.kv_heads,
@@ -235,6 +255,15 @@ class LlamaBlock(nn.Module):
         x = x + LlamaAttention(cfg, name="self_attn")(
             RMSNorm(cfg, name="input_norm")(x), position_ids, attn_mask)
         h = RMSNorm(cfg, name="post_attention_norm")(x)
+        if cfg.moe is not None:
+            from ..parallel.moe import MoELayer
+
+            ff, aux, stats = MoELayer(
+                cfg.moe, model_dim=cfg.hidden_size,
+                hidden_dim=cfg.intermediate_size, dtype=cfg.dtype,
+                name="moe")(h, train=not self.deterministic,
+                            return_stats=True)
+            return x + ff, dict(stats, aux_loss=aux)
         gate = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
                       name="gate_proj", module=self)
         up = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
@@ -277,26 +306,64 @@ class LlamaForCausalLM(nn.Module):
                             length=cfg.num_hidden_layers,
                             in_axes=nn.broadcast,
                             metadata_params={nn.meta.PARTITION_NAME: "layers"})
-            h, _ = stack(cfg, deterministic, name="layers")(h, (position_ids, mask))
+            h, per_layer = stack(cfg, deterministic, name="layers")(
+                h, (position_ids, mask))
         else:
+            per_layer = []
             for i in range(cfg.num_hidden_layers):
-                h, _ = block_cls(cfg, deterministic, name=f"layers_{i}")(
+                h, ys = block_cls(cfg, deterministic, name=f"layers_{i}")(
                     h, (position_ids, mask))
+                per_layer.append(ys)
+            if cfg.moe is not None:
+                per_layer = jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *per_layer)
+
+        out = ModelOutput()
+        aux_loss = None
+        if cfg.moe is not None:
+            # the auxiliary-loss weights are the paper's, set over the MEAN
+            # of the layers' losses (the depth of a cut model leaves the
+            # scale alone); ``stats`` rides out with the loss for the
+            # registry (record_step_stats), stacked over layers
+            stats = dict(per_layer)
+            aux_loss = out["aux_loss"] = stats.pop("aux_loss").mean()
+            out["stats"] = stats
 
         h = RMSNorm(cfg, name="norm")(h)
         lm_head = self.param("lm_head", nn.with_partitioning(
             nn.initializers.normal(cfg.initializer_range), ("embed", "vocab")),
             (cfg.hidden_size, cfg.padded_vocab_size), cfg.param_dtype)
-        logits = jnp.dot(h, lm_head.astype(cfg.dtype))
-        if cfg.padded_vocab_size != cfg.vocab_size:
-            pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
-            logits = jnp.where(pad_mask, logits, jnp.finfo(logits.dtype).min)
-
-        out = ModelOutput(logits=logits)
+        tgt = None
         if labels is not None:
             tgt = shift_labels(labels) if shift else labels
-            out["loss"] = cross_entropy_loss(logits, tgt)
+        if cfg.loss_chunk and tgt is not None:
+            from .common import chunked_lm_loss
+
+            with trace.device_span("loss_head"):
+                loss = chunked_lm_loss(
+                    h, lm_head.T, tgt, vocab_size=cfg.vocab_size,
+                    padded_vocab_size=cfg.padded_vocab_size,
+                    chunk=cfg.loss_chunk, dtype=cfg.dtype)
+        else:
+            with trace.device_span("loss_head"):
+                logits = jnp.dot(h, lm_head.astype(cfg.dtype))
+                if cfg.padded_vocab_size != cfg.vocab_size:
+                    pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
+                    logits = jnp.where(pad_mask, logits,
+                                       jnp.finfo(logits.dtype).min)
+                out["logits"] = logits
+                loss = None if tgt is None else cross_entropy_loss(logits, tgt)
+        if loss is not None:
+            out["loss"] = loss if aux_loss is None else loss + aux_loss
         return out
+
+    @staticmethod
+    def record_step_stats(stats) -> None:
+        """The engine hands back the host copy of ``out["stats"]`` of each
+        finished step; the routing counters live with the MoE layer."""
+        from ..parallel.moe import record_stats
+
+        record_stats(stats)
 
     def dummy_inputs(self, batch_size: int = 2, seq_len: Optional[int] = None):
         S = seq_len or min(self.cfg.max_position_embeddings, 128)
@@ -307,7 +374,10 @@ class LlamaForCausalLM(nn.Module):
         cfg = self.cfg
         E, L = cfg.hidden_size, cfg.num_hidden_layers
         D = cfg.head_dim
+        # a sparse FFN multiplies by top_k of its experts, and its router
+        ffn = 3 * E * cfg.intermediate_size
+        if cfg.moe is not None:
+            ffn = ffn * cfg.moe.top_k + E * cfg.moe.num_experts
         n = (2 * cfg.padded_vocab_size * E
-             + L * (E * E + 2 * E * cfg.kv_heads * D + E * E
-                    + 3 * E * cfg.intermediate_size))
+             + L * (E * E + 2 * E * cfg.kv_heads * D + E * E + ffn))
         return 6.0 * n + 12 * L * E * cfg.max_position_embeddings

@@ -50,13 +50,25 @@ def _quant_sym(x: jax.Array):
 
 
 def _quant_pos(x: jax.Array):
-    """non-negative fp32 → (uint8 codes, fp32 row scale)."""
+    """non-negative fp32 → (uint8 codes, fp32 row scale), rounded UP.
+
+    ``x`` is Adam's denominator ``sqrt(v)``.  Rounded to nearest, an entry
+    under 1/510 of its row's largest stored 0 while its first moment,
+    scaled by a row maximum of its own, kept a code: the next step divided
+    that moment by ``sqrt((1-b2) g^2)`` of whatever gradient came, and by
+    ``eps`` alone when it was zero.  Rows whose entries differ by orders
+    of magnitude (an untied ``(embed, vocab)`` head under Zipf token
+    frequencies) blew OLMoE up at step 60-80 where fp32 AdamW trained 600
+    steps (v5e, PERF.md section 6, PR 26).  Rounded up, a stored
+    denominator is never under the true one, so an update stays within
+    Adam's own bound ``|m| <= 7.3 sqrt(v)``; entries far under their
+    row's maximum move more slowly instead."""
     if x.ndim == 0:
         amax = x
     else:
         amax = jnp.max(x, axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / 255.0, 1.0).astype(jnp.float32)
-    codes = jnp.clip(jnp.round(x / scale), 0, 255).astype(jnp.uint8)
+    codes = jnp.clip(jnp.ceil(x / scale), 0, 255).astype(jnp.uint8)
     return codes, scale
 
 

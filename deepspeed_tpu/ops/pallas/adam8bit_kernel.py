@@ -83,7 +83,8 @@ def _kernel(b1, b2, eps, wd, l2,
     scmo_ref[:] = jnp.where(amax_m > 0, amax_m * (1.0 / 127.0), 1.0)
     amax_r = jnp.max(r, axis=-1, keepdims=True)
     inv_r = jnp.where(amax_r > 0, 255.0 / amax_r, 1.0)
-    rcode = jnp.clip(jnp.round(r * inv_r), 0, 255).astype(jnp.int32)
+    # rounded up, as adam8bit._quant_pos: never under the true denominator
+    rcode = jnp.clip(jnp.ceil(r * inv_r), 0, 255).astype(jnp.int32)
     rco_ref[:] = jnp.where(rcode > 127, rcode - 256, rcode).astype(jnp.int8)
     scro_ref[:] = jnp.where(amax_r > 0, amax_r * (1.0 / 255.0), 1.0)
 

@@ -16,31 +16,64 @@ autograd shim ``sharded_moe.py:90``, expert/data group math
   is unnecessary: ``ep`` is one of the batch axes (see ``mesh.DATA_AXES``),
   so non-expert params are automatically replicated over it and expert
   grads are automatically reduced only across the right ranks.
+
+Which dispatch serves which case (derived from ``MoEConfig.drop_tokens``
+and the mesh, never from the environment):
+
+- ``drop_tokens=True`` (default): GShard's capacity queues for top-1 /
+  top-2 and the dense one-hot einsums ``sec,sm->ecm`` / ``sec,ecm->sm``.
+  Tokens past an expert's capacity are dropped; with ``ep > 1`` the
+  constrained (E, C, M) tensor is what XLA turns into the all-to-all.
+- ``drop_tokens=False``: any ``top_k``, dropless.  :func:`topk_routing`
+  (softmax in float32, top-k, optional renormalisation, load-balancing and
+  z losses) over all tokens, then ONE sorted dispatch
+  (:func:`sorted_dispatch`): stable argsort of the (token, choice) pairs
+  by expert, group sizes by bincount, a row gather, the grouped matmuls
+  of ``ops/grouped_matmul.py`` over ragged groups, the inverse gather and
+  the weighted sum over each token's choices.  Under data parallelism
+  (dp / fsdp > 1) every rank sorts and multiplies its OWN tokens inside a
+  ``shard_map`` over the batch axes, the expert leaves replicated after
+  the ZeRO gather (``kernel_mesh_plan``, as the flash kernel).  ``ep > 1``
+  raises (PERF.md section 7, row 8), it does not fall back to the
+  capacity path.
+- the opt-in gathered decode path (``DS_TPU_MOE_FAST``) of the capacity
+  layer at <= 32 eval tokens.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Tuple
+import os
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..comm import mesh as mesh_lib
+from ..ops.grouped_matmul import grouped_matmul, repeat_gather, unsort_rows
+from ..telemetry import registry, trace
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     num_experts: int = 8
-    top_k: int = 1                      # 1 or 2 (reference top1gating/top2gating)
+    # drop_tokens=True: 1 or 2 (reference top1gating/top2gating);
+    # drop_tokens=False: any 1 <= top_k <= num_experts
+    top_k: int = 1
     capacity_factor: float = 1.0        # train capacity (sharded_moe.py:178)
     eval_capacity_factor: float = 1.0
     min_capacity: int = 4
     noisy_gate_policy: Optional[str] = None   # None | 'Jitter' | 'RSample'
     aux_loss_weight: float = 0.01
-    drop_tokens: bool = True
+    drop_tokens: bool = True            # False: dropless sorted dispatch
     use_residual: bool = False          # PR-MoE (layer.py:106)
+    # dropless routing only: renormalise the k chosen probabilities to sum
+    # to 1 (the capacity gates keep GShard's rule: top-2 does, top-1 not)
+    norm_topk_prob: bool = False
+    z_loss_weight: float = 0.0          # router z-loss (ST-MoE), dropless only
+    expert_act: str = "gelu"            # 'gelu': wi/wo; 'swiglu': gate/up/down
 
 
 def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: int,
@@ -127,6 +160,30 @@ def top2_gating(logits: jax.Array, capacity: int, rng=None,
     return l_aux, combine, dispatch
 
 
+def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
+                            jax.Array]:
+    """Dropless top-k routing over float32 ``logits`` (S, E).
+
+    Returns ``(weights (S, k), experts (S, k) int32, counts (E,) int32,
+    l_balance, l_z)``: the k largest softmax probabilities of each token
+    (renormalised to sum to 1 only with ``norm_topk_prob``), how many of
+    the S*k assignments each expert received, the load-balancing loss
+    ``E * sum_e f_e * P_e`` (``f_e`` expert e's share of the assignments,
+    ``P_e`` its mean probability; 1 when balanced) and the router z-loss
+    ``mean_s logsumexp(logits_s)^2``."""
+    S, E = logits.shape
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    counts = jnp.bincount(experts.reshape(-1), length=E).astype(jnp.int32)
+    l_balance = E * jnp.sum(counts.astype(jnp.float32) / (S * top_k)
+                            * probs.mean(axis=0))
+    l_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+    return weights, experts.astype(jnp.int32), counts, l_balance, l_z
+
+
 class TopKGate(nn.Module):
     """Gate module (reference ``sharded_moe.py:352``): fp32 linear + top-k."""
 
@@ -134,7 +191,8 @@ class TopKGate(nn.Module):
     model_dim: int
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool, decode_fast: bool = False):
+    def __call__(self, x: jax.Array, train: bool, decode_fast: bool = False,
+                 logits_only: bool = False):
         cfg = self.cfg
         wg = self.param("wg", nn.with_partitioning(
             nn.initializers.normal(0.02), ("embed", "experts_gate")),
@@ -143,7 +201,11 @@ class TopKGate(nn.Module):
         if train and cfg.noisy_gate_policy == "Jitter":
             rng = self.make_rng("gating")
             xf = xf * jax.random.uniform(rng, xf.shape, minval=0.98, maxval=1.02)
-        logits = xf @ wg
+        # a float32 router means float32 products: the TPU's default
+        # precision would round both operands to bf16 first
+        logits = jnp.dot(xf, wg, precision=jax.lax.Precision.HIGHEST)
+        if logits_only:
+            return logits
         if decode_fast:
             # decode path (the Tutel fast-dispatch analog, reference
             # sharded_moe.py:501): no capacity queues at a handful of
@@ -173,7 +235,65 @@ class TopKGate(nn.Module):
             return top1_gating(logits, capacity, rng, cfg.noisy_gate_policy)
         if cfg.top_k == 2:
             return top2_gating(logits, capacity, rng, cfg.noisy_gate_policy)
-        raise ValueError(f"top_k must be 1 or 2, got {cfg.top_k}")
+        raise ValueError(
+            f"the capacity gate (drop_tokens=True) is GShard's top-1/top-2, "
+            f"got top_k={cfg.top_k}; set drop_tokens=False for the dropless "
+            f"top-k dispatch")
+
+
+def _expert_ffn(act: str, ws, x, matmul):
+    """The expert FFN over ``matmul(x, w)``, which pairs every row of ``x``
+    with its own expert's matrix of the (E, ., .) leaf ``w``."""
+    if act == "swiglu":
+        gate, up, down = ws
+        return matmul(nn.silu(matmul(x, gate)) * matmul(x, up), down)
+    wi, wo = ws
+    return matmul(nn.gelu(matmul(x, wi), approximate=True), wo)
+
+
+def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
+                    ws: Tuple[jax.Array, ...], act: str) -> jax.Array:
+    """Dropless expert FFN of tokens ``x`` (S, M), token s going to experts
+    ``chosen[s]`` (k of them) with ``weights[s]``; ``ws`` the (E, ., .)
+    expert leaves.  Rows are sorted by expert, multiplied group by group and
+    sorted back; no (token, choice) pair is left out and an expert nobody
+    chose costs nothing.
+
+    Tokens do not interact, so under data parallelism each rank does this
+    for its own tokens inside a ``shard_map`` over the batch axes (its own
+    argsort, its own group sizes, the Pallas grouped matmul on its own
+    rows), with the expert leaves replicated.  Where ``kernel_mesh_plan``
+    refuses the mesh (tp, sp, pp, or rows the batch axes do not divide) the
+    same code runs on the global arrays with XLA's ragged dot, which the
+    partitioner can split."""
+    from ..ops.pallas.spmd import kernel_mesh_plan
+
+    E = ws[0].shape[0]
+    verdict, batch_axes = kernel_mesh_plan(x.shape[0])
+
+    def one_rank(x, weights, chosen, *ws):
+        S, k = chosen.shape
+        with trace.device_span("moe/route"):
+            flat = chosen.reshape(-1)
+            order = jnp.argsort(flat, stable=True)
+            inv = jnp.argsort(order)
+            sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+        with trace.device_span("moe/dispatch"):
+            rows = repeat_gather(x, order, inv)                   # (S*k, M)
+        with trace.device_span("moe/experts"):
+            rows = _expert_ffn(act, ws, rows, lambda a, w: grouped_matmul(
+                a, w, sizes, per_device=verdict is not None))
+        with trace.device_span("moe/combine"):
+            rows = unsort_rows(rows, order, inv).reshape(S, k, -1)
+            return jnp.einsum("skm,sk->sm", rows, weights.astype(rows.dtype))
+
+    if verdict != "shard":
+        return one_rank(x, weights, chosen, *ws)
+    rows_spec = P(batch_axes)
+    return jax.shard_map(
+        one_rank, mesh=mesh_lib.get_mesh(),
+        in_specs=(rows_spec,) * 3 + (P(),) * len(ws), out_specs=rows_spec,
+        check_vma=False)(x, weights, chosen, *ws)
 
 
 class ExpertsMLP(nn.Module):
@@ -186,16 +306,50 @@ class ExpertsMLP(nn.Module):
     param_dtype: Any = jnp.float32
     w8: bool = False                   # int8 expert weights (ops/w8.py)
     w8_group: int = 128
+    act: str = "gelu"                  # 'gelu' (wi, wo) | 'swiglu' (gate, up, down)
+
+    def _weight(self, name: str, down: bool = False):
+        """One (experts, embed, mlp) leaf, or (experts, mlp, embed) for a
+        ``down`` projection, cast to the compute dtype."""
+        E, M, H = self.num_experts, self.model_dim, self.hidden_dim
+        axes, shape = (("experts", "mlp", "embed"), (E, H, M)) if down \
+            else (("experts", "embed", "mlp"), (E, M, H))
+        return self.param(name, nn.with_partitioning(
+            nn.initializers.normal(0.02), axes), shape,
+            self.param_dtype).astype(self.dtype)
+
+    def _weights(self) -> Tuple[jax.Array, ...]:
+        """The expert leaves in the order :func:`_expert_ffn` takes them."""
+        if self.act == "swiglu":
+            return (self._weight("gate"), self._weight("up"),
+                    self._weight("down", down=True))
+        if self.act != "gelu":
+            raise ValueError(f"expert_act must be 'gelu' or 'swiglu', got "
+                             f"{self.act!r}")
+        return self._weight("wi"), self._weight("wo", down=True)
 
     @nn.compact
     def __call__(self, x: jax.Array, idx: Optional[jax.Array] = None,
-                 gate_w: Optional[jax.Array] = None) -> jax.Array:
-        # (E, C, M) capacity-padded batch, or — when ``idx``/``gate_w``
-        # are given — the gathered decode path: x (S, M), idx (S, k)
-        # expert ids, gate_w (S, k) renormalized gates (Tutel-style fast
-        # dispatch, reference sharded_moe.py:501 + moe_inference.py).
-        # Param declarations are IDENTICAL on both paths, so one trained
-        # tree serves both.
+                 gate_w: Optional[jax.Array] = None,
+                 routing: Optional[Tuple[jax.Array, jax.Array]] = None
+                 ) -> jax.Array:
+        # (E, C, M) capacity-padded batch; or, with ``routing`` = (weights,
+        # experts), both (S, k), x (S, M) tokens through the dropless
+        # sorted dispatch; or — when
+        # ``idx``/``gate_w`` are given — the gathered decode path: x (S, M),
+        # idx (S, k) expert ids, gate_w (S, k) renormalized gates
+        # (Tutel-style fast dispatch, reference sharded_moe.py:501 +
+        # moe_inference.py).  Param declarations are IDENTICAL on every
+        # path, so one trained tree serves them all.
+        if routing is not None:
+            if self.w8:
+                raise NotImplementedError(
+                    "int8 expert weights have no grouped-matmul path")
+            return sorted_dispatch(x, *routing, self._weights(), self.act)
+        if self.act != "gelu" and (self.w8 or idx is not None):
+            raise NotImplementedError(
+                f"{self.act} experts run the capacity einsum or the sorted "
+                f"dispatch; the int8 and gathered decode paths are gelu-only")
         if self.w8:
             from ..ops.w8 import w8a16_expert_matmul
 
@@ -222,25 +376,20 @@ class ExpertsMLP(nn.Module):
             h = nn.gelu(w8a16_expert_matmul(x, wi_q, wi_s),
                         approximate=True)
             return w8a16_expert_matmul(h, wo_q, wo_s)
-        wi = self.param("wi", nn.with_partitioning(
-            nn.initializers.normal(0.02), ("experts", "embed", "mlp")),
-            (self.num_experts, self.model_dim, self.hidden_dim), self.param_dtype)
-        wo = self.param("wo", nn.with_partitioning(
-            nn.initializers.normal(0.02), ("experts", "mlp", "embed")),
-            (self.num_experts, self.hidden_dim, self.model_dim), self.param_dtype)
         if idx is not None:
+            wi, wo = self._weight("wi"), self._weight("wo", down=True)
+
             def ffn(flat):
-                wi_g = jnp.take(wi, flat, axis=0).astype(self.dtype)
-                wo_g = jnp.take(wo, flat, axis=0).astype(self.dtype)
+                wi_g = jnp.take(wi, flat, axis=0)
+                wo_g = jnp.take(wo, flat, axis=0)
                 def apply(xr):   # (Sk, M) → (Sk, M)
                     h = nn.gelu(jnp.einsum("sm,smh->sh", xr, wi_g),
                                 approximate=True)
                     return jnp.einsum("sh,shm->sm", h, wo_g)
                 return apply
             return self._gathered(x, idx, gate_w, ffn)
-        h = jnp.einsum("ecm,emh->ech", x, wi.astype(self.dtype))
-        h = nn.gelu(h, approximate=True)
-        return jnp.einsum("ech,ehm->ecm", h, wo.astype(self.dtype))
+        return _expert_ffn(self.act, self._weights(), x,
+                           lambda a, w: jnp.einsum("ecm,emh->ech", a, w))
 
     def _gathered(self, x, idx, gate_w, make_apply):
         """Run each token through its own top-k experts: one vecmat per
@@ -295,18 +444,41 @@ class MoELayer(nn.Module):
     w8_group: int = 128
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool = False):
+    def __call__(self, x: jax.Array, train: bool = False,
+                 return_stats: bool = False):
+        """``(out, weighted aux loss)``; with ``return_stats`` also the dict
+        of small per-layer statistics that :func:`record_stats` books."""
         cfg = self.cfg
         orig_shape = x.shape
         x2 = x.reshape(-1, self.model_dim)                        # (S, M)
         experts = ExpertsMLP(cfg.num_experts, self.model_dim,
                              self.hidden_dim, dtype=self.dtype, w8=self.w8,
-                             w8_group=self.w8_group, name="experts")
+                             w8_group=self.w8_group, act=cfg.expert_act,
+                             name="experts")
+        gate = TopKGate(cfg, self.model_dim, name="gate")
         mesh = mesh_lib.get_mesh(required=False)
         ep1 = mesh is None or mesh.shape.get("ep", 1) == 1
-        import os
         fast_ok = os.environ.get("DS_TPU_MOE_FAST", "0") == "1"
-        if not train and ep1 and fast_ok and x2.shape[0] <= 32:
+        S, E, k = x2.shape[0], cfg.num_experts, cfg.top_k
+        l_z = jnp.float32(0.0)
+        if not cfg.drop_tokens:
+            if not ep1:
+                raise NotImplementedError(
+                    "MoEConfig(drop_tokens=False) is the one-chip / data-"
+                    "parallel sorted dispatch; with ep > 1 the sorted rows "
+                    "need an all-to-all that is not written yet (PERF.md "
+                    "section 7, row 8). It does not fall back to the "
+                    "capacity path: use drop_tokens=True or ep=1")
+            if not 1 <= k <= E:
+                raise ValueError(f"top_k must be in 1..{E}, got {k}")
+            with trace.device_span("moe/route"):
+                weights, chosen, counts, l_aux, l_z = topk_routing(
+                    gate(x2, train, logits_only=True), k, cfg.norm_topk_prob)
+            out = experts(x2, routing=(weights, chosen))
+            # pairs no expert's group holds (an id outside 0..E-1): the
+            # grouped matmul multiplies exactly counts.sum() rows
+            dropped = jnp.int32(S * k) - counts.sum()
+        elif not train and ep1 and fast_ok and S <= 32:
             # gathered per-token experts (no capacity padding, no dispatch
             # one-hots).  OPT-IN: on TPU the vmapped gather materializes a
             # per-token copy of each expert panel in HBM and LOSES ~25% to
@@ -315,13 +487,12 @@ class MoELayer(nn.Module):
             # Only without ep sharding — sharded experts want tokens moved
             # to weights (all-to-all), not weight panels gathered to
             # tokens.
-            l_aux, idx, gate_w = TopKGate(cfg, self.model_dim,
-                                          name="gate")(x2, train,
-                                                       decode_fast=True)
+            l_aux, idx, gate_w = gate(x2, train, decode_fast=True)
             out = experts(x2, idx=idx, gate_w=gate_w)
+            counts = jnp.bincount(idx.reshape(-1), length=E).astype(jnp.int32)
+            dropped = jnp.int32(0)
         else:
-            l_aux, combine, dispatch = TopKGate(
-                cfg, self.model_dim, name="gate")(x2, train)
+            l_aux, combine, dispatch = gate(x2, train)
             dispatched = jnp.einsum("sec,sm->ecm",
                                     dispatch.astype(self.dtype), x2)
             dispatched = _constrain_ep(dispatched)            # all-to-all in
@@ -329,6 +500,8 @@ class MoELayer(nn.Module):
             expert_out = _constrain_ep(expert_out)            # all-to-all out
             out = jnp.einsum("sec,ecm->sm", combine.astype(self.dtype),
                              expert_out)
+            counts = dispatch.sum(axis=(0, 2)).astype(jnp.int32)
+            dropped = jnp.int32(S * k) - counts.sum()
 
         if cfg.use_residual:
             # PR-MoE: dense MLP branch + learned 2-way mix (layer.py:106-125)
@@ -341,7 +514,42 @@ class MoELayer(nn.Module):
             coef = jax.nn.softmax(coef, axis=-1)
             out = out * coef[..., 0:1] + dense * coef[..., 1:2]
 
-        return out.reshape(orig_shape), l_aux * cfg.aux_loss_weight
+        aux = l_aux * cfg.aux_loss_weight + l_z * cfg.z_loss_weight
+        out = out.reshape(orig_shape)
+        if not return_stats:
+            return out, aux
+        return out, aux, {"tokens_per_expert": counts, "dropped": dropped,
+                          "balance_loss": l_aux, "router_z": l_z}
+
+
+def record_stats(stats: Dict[str, Any]) -> None:
+    """Book one finished step's routing statistics in the registry.
+
+    ``stats`` is the host copy of what :class:`MoELayer` returned with
+    ``return_stats``, stacked over the model's MoE layers:
+    ``tokens_per_expert`` (L, E), ``dropped`` (L,), ``balance_loss`` (L,),
+    ``router_z`` (L,).  Counters ``moe_tokens_per_expert{layer,expert}``
+    (max / mean over a layer's experts is its load imbalance) and
+    ``moe_dropped_tokens_total`` (0 on the dropless path, always); gauges
+    ``moe_aux_loss`` / ``moe_router_z``, the layer means of the last step.
+    """
+    counts = np.asarray(stats["tokens_per_expert"])
+    counts = counts.reshape(-1, counts.shape[-1])
+    per_expert = registry.counter(
+        "moe_tokens_per_expert",
+        "(token, choice) pairs routed to each expert of each MoE layer",
+        ("layer", "expert"))
+    for layer, row in enumerate(counts):
+        for expert, n in enumerate(row):
+            per_expert.labels(layer, expert).inc(float(n))
+    registry.counter(
+        "moe_dropped_tokens_total",
+        "(token, choice) pairs an expert's capacity turned away"
+    ).inc(float(np.sum(stats["dropped"])))
+    registry.gauge("moe_aux_loss", "load-balancing loss, mean over layers, "
+                   "last finished step").set(float(np.mean(stats["balance_loss"])))
+    registry.gauge("moe_router_z", "router z-loss, mean over layers, last "
+                   "finished step").set(float(np.mean(stats["router_z"])))
 
 
 def _constrain_ep(x: jax.Array) -> jax.Array:

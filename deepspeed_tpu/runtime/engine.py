@@ -21,6 +21,7 @@ same user surface — ``engine(batch)`` / ``engine.backward(loss)`` /
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import os
@@ -306,6 +307,8 @@ class Engine:
         self._state: Optional[TrainState] = None
         self._state_shardings = None
         self._grad_buffer = None
+        # model_stats of dispatched steps, until the step has finished
+        self._pending_stats = collections.deque()
         self._fwd_batch = None
         self._tput = ThroughputTimer(
             batch_size=self.config.train_batch_size,
@@ -596,8 +599,16 @@ class Engine:
         return "deterministic" in sig.parameters
 
     def _loss_fn(self, params, batch, rng, deterministic: bool, pld_theta=None):
+        return self._loss_and_stats(params, batch, rng, deterministic,
+                                    pld_theta)[0]
+
+    def _loss_and_stats(self, params, batch, rng, deterministic: bool,
+                        pld_theta=None):
+        """``(loss, stats)``: ``stats`` is the model's ``out["stats"]``, a
+        dict of small arrays it wants booked once the step has finished
+        (MoE routing counts), or ``{}``."""
         if self._user_loss_fn is not None:
-            return self._user_loss_fn(params, batch, rng)
+            return self._user_loss_fn(params, batch, rng), {}
         rngs = {}
         if rng is not None:
             rngs = {"dropout": rng,
@@ -610,10 +621,10 @@ class Engine:
             kwargs["deterministic"] = deterministic
         out = self.model.apply({"params": params}, rngs=rngs, **kwargs)
         if isinstance(out, dict):
-            return out["loss"]
+            return out["loss"], dict(out.get("stats") or {})
         if isinstance(out, (tuple, list)):
-            return out[0]
-        return out
+            return out[0], {}
+        return out, {}
 
     def init_params(self, example_batch=None, params=None, rng=None):
         """Materialize sharded fp32 master params + optimizer state.
@@ -933,22 +944,25 @@ class Engine:
         return None
 
     def _grads_of(self, params, batch, rng, scale, pld_theta=None):
-        """(scaled loss, grads) on one global micro-batch."""
+        """(scaled loss, grads, the model's step statistics) on one global
+        micro-batch."""
         if self.config.sparse_gradients:
-            return self._grads_of_sparse(params, batch, rng, scale, pld_theta)
+            return self._grads_of_sparse(params, batch, rng, scale,
+                                         pld_theta) + ({},)
 
         def scaled_loss_fn(p):
-            loss = self._loss_fn(p, batch, rng, deterministic=False,
-                                 pld_theta=pld_theta)
-            return loss * scale
+            loss, stats = self._loss_and_stats(
+                p, batch, rng, deterministic=False, pld_theta=pld_theta)
+            return loss * scale, stats
 
         gdt = self._grad_dtype
         if gdt is not None:
             params = jax.tree_util.tree_map(
                 lambda x: x.astype(gdt)
                 if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
-        loss, grads = jax.value_and_grad(scaled_loss_fn)(params)
-        return loss, grads
+        (loss, stats), grads = jax.value_and_grad(
+            scaled_loss_fn, has_aux=True)(params)
+        return loss, grads, stats
 
     def _grads_of_sparse(self, params, batch, rng, scale, pld_theta=None):
         """Sparse-gradient micro-batch step (reference ``engine.py:2182``
@@ -1119,24 +1133,32 @@ class Engine:
                 def body(carry, mb):
                     g_acc, l_acc, i = carry
                     mb_rng = jax.random.fold_in(rng, i)
-                    loss, grads = self._grads_of(state.params, mb, mb_rng, scale,
-                                                 pld_theta)
+                    loss, grads, stats = self._grads_of(
+                        state.params, mb, mb_rng, scale, pld_theta)
                     g_acc = jax.tree_util.tree_map(
                         lambda a, g: a + g.astype(a.dtype), g_acc, grads)
                     g_acc = self._scatter_grads(g_acc)
-                    return (g_acc, l_acc + loss, i + 1), None
+                    return (g_acc, l_acc + loss, i + 1), stats
 
                 acc_dt = self._grad_dtype or jnp.float32
                 zeros = jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, acc_dt), state.params)
                 zeros = self._scatter_grads(zeros)
-                (g_sum, loss_sum, _), _ = jax.lax.scan(
+                (g_sum, loss_sum, _), stats = jax.lax.scan(
                     body, (zeros, jnp.float32(0.0), jnp.int32(0)), mbs)
+                # counts add up over the micro-batches, losses average
+                stats = jax.tree_util.tree_map(
+                    lambda x: x.sum(0) if jnp.issubdtype(x.dtype, jnp.integer)
+                    else x.mean(0), stats)
             else:
-                loss_sum, g_sum = self._grads_of(
+                loss_sum, g_sum, stats = self._grads_of(
                     state.params, batch, rng, scale, pld_theta)
                 g_sum = self._scatter_grads(g_sum)
-            return self._apply_grads(state, g_sum, loss_sum, jnp.float32(gas))
+            new_state, metrics = self._apply_grads(
+                state, g_sum, loss_sum, jnp.float32(gas))
+            if stats:
+                metrics["model_stats"] = stats
+            return new_state, metrics
 
         return step_fn
 
@@ -1419,7 +1441,7 @@ class Engine:
 
                 def body(carry, mb):
                     g_acc, l_acc, i = carry
-                    loss, grads = self._grads_of(
+                    loss, grads, _ = self._grads_of(
                         state.params, mb, jax.random.fold_in(rng, i),
                         jnp.float32(1.0))
                     g_acc = self._scatter_grads(
@@ -1431,7 +1453,8 @@ class Engine:
                 (g, loss, _), _ = jax.lax.scan(
                     body, (zeros, jnp.float32(0.0), jnp.int32(0)), mbs)
             else:
-                loss, g = self._grads_of(state.params, batch, rng, jnp.float32(1.0))
+                loss, g, _ = self._grads_of(state.params, batch, rng,
+                                            jnp.float32(1.0))
             g = jax.tree_util.tree_map(lambda x: x / gas, g)
             # global norm computed ON DEVICE so the host never needs the
             # whole grad tree just to decide the clip factor
@@ -1600,7 +1623,7 @@ class Engine:
             params = state.params
             if self._has_store_transform:
                 params = self._to_canonical_params(params)
-            loss, grads = self._grads_of(params, batch, rng, scale)
+            loss, grads, _ = self._grads_of(params, batch, rng, scale)
             if self._has_store_transform:
                 # back to the stored layout for apply/step
                 grads = self._to_stored_params(grads)
@@ -1771,6 +1794,9 @@ class Engine:
         with trace.span("train/dispatch", step=self.global_steps):
             self._state, metrics = self._compiled_train_step(
                 self._state, batch, *extra)
+        if "model_stats" in metrics:
+            self._pending_stats.append(metrics["model_stats"])
+            self.drain_step_stats()
         if attr_sample:
             # compile-paying steps are discarded inside note_window (the
             # serving windows apply the same discipline); costs come
@@ -1797,6 +1823,20 @@ class Engine:
                 logger.warning(f"train guard on_step failed: {e!r}")
         self._maybe_print(metrics)
         return metrics["loss"]
+
+    def drain_step_stats(self, wait: bool = False) -> None:
+        """Hand the model (``record_step_stats``) the statistics of every
+        step that has FINISHED, oldest first.  They came back with the
+        loss, so reading a finished step's costs no fence; a step still
+        running is left for the next call unless ``wait``."""
+        pending = self._pending_stats
+        record = getattr(self.model, "record_step_stats", None)
+        if record is None:      # the model returns stats and books none
+            pending.clear()
+            return
+        while pending and (wait or all(
+                x.is_ready() for x in jax.tree_util.tree_leaves(pending[0]))):
+            record(jax.device_get(pending.popleft()))
 
     def eval_batch(self, batch):
         from ..utils.heartbeat import beat
