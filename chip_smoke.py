@@ -324,8 +324,80 @@ def kernel_grouped_matmul():
                for site, impl, _, count in dispatch_report()), dispatch_report()
 
 
-KERNEL_CASES = (kernel_flash, kernel_grouped_matmul, kernel_decode_attention,
-                kernel_paged_attention, kernel_decode_layer, kernel_w8_matmul)
+def kernel_adam8bit():
+    """The one-pass int8 AdamW update (``ops/pallas/adam8bit_kernel.py``),
+    Mosaic-compiled, at a leaf of each form it takes in the two train
+    cells: rows (XL's c_fc), stored transposed (XL's mlp c_proj), a stack
+    of experts, and OLMoE's untied head whose rows take 32-row blocks.
+    bf16 gradient, moments live (second of two steps), against
+    ``adam8bit._leaf_moments`` + decay + lr compiled by XLA, at the CPU
+    test's tolerances (``tests/unit/test_adam8bit.py``)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.adam8bit import _leaf_moments
+    from deepspeed_tpu.ops.pallas.adam8bit_kernel import (apply_leaf,
+                                                          leaf_refusal)
+
+    b1, b2, eps, wd, lr, gscale = 0.9, 0.999, 1e-8, 0.1, 1e-3, 0.37
+
+    @jax.jit
+    def chain(g, p, mc, rc, sc, c1, c2):
+        upd, mc2, rc2, sc2 = _leaf_moments(
+            g.astype(jnp.float32) * gscale, mc, rc, sc, b1=b1, b2=b2, c1=c1,
+            c2=c2, eps=eps)
+        return p - lr * (upd + wd * p), mc2, rc2, sc2
+
+    # in place, as in the engine's step: the state is donated (undonated,
+    # XLA copies the master first and, with the call pinned to HBM, its
+    # memory-space assignment fails a check in libtpu 0.0.34)
+    @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+    def kernel(g, p, mc, rc, sc, c1, c2):
+        return apply_leaf(g, p, mc, rc, sc,
+                          jnp.stack([jnp.float32(gscale), jnp.float32(lr),
+                                     c1, c2]),
+                          b1=b1, b2=b2, eps=eps, wd=wd, l2=0.0,
+                          interpret=False)
+
+    for shape in ((1600, 6400), (6400, 1600), (64, 2048, 1024),
+                  (2048, 50304)):
+        assert leaf_refusal(shape, jnp.float32) is None
+        ks = jax.random.split(jax.random.PRNGKey(shape[-1]), 4)
+        cols = jnp.exp(2.0 * jax.random.normal(ks[3], shape[-1:]))
+        state = (jax.random.normal(ks[0], shape, jnp.float32),
+                 jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.uint8),
+                 {"m": jnp.ones(shape[:-1] + (1,), jnp.float32),
+                  "r": jnp.ones(shape[:-1] + (1,), jnp.float32)})
+        for t in (1, 2):
+            g = (jax.random.normal(ks[t], shape, jnp.float32) * cols
+                 ).astype(jnp.bfloat16)
+            c = (jnp.float32(1 - b1 ** t), jnp.float32(1 - b2 ** t))
+            want = chain(g, *state, *c)
+            state = kernel(g, *state, *c)
+        (p_k, mc_k, rc_k, sc_k), (p_w, mc_w, rc_w, sc_w) = state, want
+        dp = float(jnp.max(jnp.abs(p_k - p_w) / jnp.maximum(jnp.abs(p_w),
+                                                            1e-3)))
+        flips = [(int(jnp.max(d)), float(jnp.mean(d > 0))) for d in (
+            jnp.abs(a.astype(jnp.int32) - b.astype(jnp.int32))
+            for a, b in ((mc_k, mc_w), (rc_k, rc_w)))]
+        ds = max(float(jnp.max(jnp.abs(sc_k[k] / sc_w[k] - 1)))
+                 for k in ("m", "r"))
+        print(f"  adam8bit {shape}: master {dp:.2e}, m codes off by "
+              f"{flips[0][0]} on {flips[0][1]:.2e}, r codes off by "
+              f"{flips[1][0]} on {flips[1][1]:.2e}, scales {ds:.2e}",
+              flush=True)
+        assert np.isfinite(np.asarray(p_k)).all()
+        assert dp <= 1e-6 and ds <= 1e-6, (shape, dp, ds)
+        assert flips[0][0] <= 1 and flips[0][1] <= 1e-3, (shape, flips)
+        assert flips[1][0] <= 1 and flips[1][1] <= 0.03, (shape, flips)
+
+
+KERNEL_CASES = (kernel_flash, kernel_grouped_matmul, kernel_adam8bit,
+                kernel_decode_attention, kernel_paged_attention,
+                kernel_decode_layer, kernel_w8_matmul)
 
 
 def phase_kernels():

@@ -80,9 +80,10 @@ class Adam8bitState(NamedTuple):
 
 
 def _leaf_moments(g, mc, rc, sc, *, b1, b2, c1, c2, eps):
-    """THE adam8bit per-leaf math (single source for the optax chain and
-    the fused path's fallback): dequant → m/v update → bias-corrected
-    Adam direction → requant."""
+    """THE adam8bit per-leaf math (the optax chain's, the kernel path's
+    small leaves', and what ``ops/pallas/adam8bit_kernel.py`` is the
+    one-pass form of): dequant → m/v update → bias-corrected Adam
+    direction → requant."""
     m = b1 * (mc.astype(jnp.float32) * sc["m"]) + (1.0 - b1) * g
     r0 = rc.astype(jnp.float32) * sc["r"]
     v = b2 * (r0 * r0) + (1.0 - b2) * (g * g)
@@ -150,7 +151,7 @@ def adamw_8bit(learning_rate: ScalarOrSchedule, b1: float = 0.9,
 
 
 # ----------------------------------------------------------------------
-# Fused single-pass update (ops/pallas/adam8bit_kernel.py)
+# The one-pass update (ops/pallas/adam8bit_kernel.py)
 # ----------------------------------------------------------------------
 def _find_state(opt_state) -> Adam8bitState:
     if isinstance(opt_state, Adam8bitState):
@@ -166,7 +167,7 @@ def _find_state(opt_state) -> Adam8bitState:
 def _advance_state(opt_state, new8: Adam8bitState):
     """Rebuild the optax chain state around a stepped Adam8bitState.
 
-    ``ScaleByScheduleState`` counters advance too, so the fused path and
+    ``ScaleByScheduleState`` counters advance too, so the kernel path and
     the stock ``tx.update`` path stay interchangeable (same checkpoint
     layout, same LR-schedule step)."""
     import optax._src.transform as _T
@@ -184,43 +185,85 @@ def _advance_state(opt_state, new8: Adam8bitState):
     return opt_state
 
 
-def fused_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
-                        b2: float, eps: float, weight_decay: float = 0.0,
-                        l2: float = 0.0, clip: float = 0.0):
-    """Build ``apply(grads, params, opt_state, grad_norm) →
-    (new_params, new_opt_state)`` — the one-HBM-pass equivalent of the
-    build_tx chain ``clip → [L2] → adam8bit moments → [AdamW decay] → lr``
-    for the ``adamw8bit`` family.  ``opt_state`` is the UNCHANGED optax
-    chain state (checkpoints stay compatible); this just bypasses its
-    fp32-temporary round trips.  Single-device only — the caller guards
-    (multi-device meshes keep the pjit-partitioned unfused math)."""
-    from .attention import on_tpu
-    from .pallas.adam8bit_kernel import apply_fused_leaf, fused_leaf_supported
+SITE = "adam8bit"
 
-    def apply(grads, params, opt_state, grad_norm):
-        interp = not on_tpu()
+
+def kernel_refusal(*, n_devices: int, offload: bool, fp16: bool
+                   ) -> Optional[str]:
+    """Why a run's update stays ``tx.update`` as a whole, or ``None``
+    where its leaves may take the one-pass kernel: what the engine can
+    observe of the run, no switch.  A ``pallas_call`` is opaque to the
+    SPMD partitioner, so every device has to hold whole leaves (one
+    device today); fp16's overflow skip is a ``where(finite, new, old)``
+    over the state, which would undo the in-place aliasing."""
+    from . import attention
+
+    if offload:
+        return "optimizer offload"
+    if fp16:
+        return "fp16 overflow skip selects over the state"
+    if n_devices > 1:
+        return f"mesh of {n_devices} devices"
+    if not attention.on_tpu():
+        return "not a TPU"
+    return None
+
+
+def note_refusal(params, reason: str) -> None:
+    """Book every leaf of a refused run's update on the XLA chain."""
+    from .pallas.spmd import note_dispatch
+
+    for _ in jax.tree_util.tree_leaves(params):
+        note_dispatch(SITE, "xla", reason)
+
+
+def kernel_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
+                         b2: float, eps: float, weight_decay: float = 0.0,
+                         l2: float = 0.0, clip: float = 0.0):
+    """Build ``apply(grads, params, opt_state, grad_norm, factor) →
+    (new_params, new_opt_state)``: the build_tx chain ``clip → [L2] →
+    adam8bit moments → [AdamW decay] → lr`` of the ``adamw8bit`` family
+    with every leaf the kernel takes updated in place by one pass over
+    HBM, the others by the same ``_leaf_moments`` as the chain.
+
+    ``grads`` are the raw gradient sums in the dtype the backward wrote
+    (no gradient-sized buffer may stand between it and the kernel);
+    ``factor`` is what the engine would have multiplied them by
+    (``1 / (denom * loss scale)``) and ``grad_norm`` the norm after it:
+    factor and clip reach a leaf as one scalar.  ``opt_state`` is the
+    UNCHANGED optax chain state (checkpoints stay compatible).  The
+    caller asks :func:`kernel_refusal` first."""
+    from . import attention
+    from .pallas.adam8bit_kernel import apply_leaf, leaf_refusal
+    from .pallas.spmd import note_dispatch
+
+    def apply(grads, params, opt_state, grad_norm, factor):
+        interp = not attention.on_tpu()
         st = _find_state(opt_state)
         if st is None:
-            raise ValueError("no Adam8bitState found in opt_state; "
-                             "fused adam8bit needs the adamw8bit chain")
+            raise ValueError("no Adam8bitState found in opt_state; the "
+                             "adam8bit kernel needs the adamw8bit chain")
         count = optax.safe_int32_increment(st.count)
         cf = count.astype(jnp.float32)
         c1 = 1.0 - b1 ** cf
         c2 = 1.0 - b2 ** cf
         lr = learning_rate(st.count) if callable(learning_rate) \
             else jnp.float32(learning_rate)
-        gscale = jnp.float32(1.0)
+        gscale = jnp.asarray(factor, jnp.float32)
         if clip and clip > 0:
-            gscale = jnp.where(grad_norm < clip, 1.0, clip / grad_norm)
+            gscale = gscale * jnp.where(grad_norm < clip, 1.0,
+                                        clip / grad_norm)
         scalars = jnp.stack([gscale, jnp.asarray(lr, jnp.float32),
                              c1, c2]).astype(jnp.float32)
 
         def leaf(g, p, mc, rc, sc):
-            if fused_leaf_supported(p.shape):
-                return apply_fused_leaf(
+            why = leaf_refusal(p.shape, p.dtype, g.dtype.itemsize)
+            if why is None:
+                note_dispatch(SITE, "kernel", "one device, whole leaves")
+                return apply_leaf(
                     g, p, mc, rc, sc, scalars, b1=b1, b2=b2, eps=eps,
                     wd=weight_decay, l2=l2, interpret=interp)
-            # scalar / oversize-row leaves: unfused math, identical result
+            note_dispatch(SITE, "xla", why)
             g = g.astype(jnp.float32) * gscale
             if l2:
                 g = g + l2 * p

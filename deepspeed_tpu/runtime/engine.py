@@ -252,35 +252,39 @@ class Engine:
             self.tx = optax.GradientTransformation(
                 functools.partial(_obc.init_state, W=_W), _raise)
         self.optimizer = self.tx  # returned from deepspeed_tpu.initialize
-        # Fused adam8bit: one Pallas HBM pass per leaf instead of the
-        # XLA chain's fp32 moment round trips (the round-2 measured
-        # optimizer bottleneck at 1.5B).  Same opt_state layout — the
-        # fused apply bypasses tx.update, it does not replace tx.
-        # Single-device only: pjit partitions the unfused math on meshes.
-        self._fused_opt = None
+        # The engine's own int8 Adam(W): every leaf the one-pass kernel can
+        # take is updated in place by it (ops/pallas/adam8bit_kernel.py)
+        # where the run allows (ops/adam8bit.py kernel_refusal: one TPU
+        # device, no offload, no fp16 overflow skip); everything else runs
+        # tx.update.  Same opt_state layout: the kernel path bypasses
+        # tx.update, it does not replace tx.  [An earlier form of the
+        # kernel lost to XLA's fusion, "42 ms vs 28 ms on a 0.57B tree",
+        # because it was handed an fp32 copy of the gradient and (R, 1)
+        # scale blocks padded 128-fold: 22 bytes a parameter against the
+        # two-pass chain's 18; this one moves 14.  PERF.md section 6.]
+        self._adam8bit_apply = None     # the kernel path, where it runs
+        self._adam8bit_refusal = None   # or why this run's adam8bit does not
         from . import constants as _C
 
         ocfg = self.config.optimizer
-        if (optimizer is None and self.offload_device == "none"
-                and self.n_devices == 1
-                and ocfg.type in (_C.ADAM8BIT_OPTIMIZER,
-                                  _C.ADAMW8BIT_OPTIMIZER)
-                # opt-in: measured 42 ms vs XLA's 28 ms on a 0.57B tree
-                # (the one-pass kernel loses to XLA's own fusion; see
-                # BENCH_NORTHSTAR.md round-3 notes) — kept for the
-                # multi-pass-regression guard it provides and further
-                # tuning, not as the default path
-                and ocfg.extra.get("fused", False)):
-            from ..ops.adam8bit import fused_apply_factory
+        if optimizer is None and not self._onebit_comm and ocfg.type in (
+                _C.ADAM8BIT_OPTIMIZER, _C.ADAMW8BIT_OPTIMIZER):
+            from ..ops import adam8bit as _a8
 
-            decoupled = ocfg.type == _C.ADAMW8BIT_OPTIMIZER or \
-                ocfg.extra.get("adam_w_mode", False)
-            b1, b2 = ocfg.betas
-            self._fused_opt = fused_apply_factory(
-                learning_rate=self.lr_scheduler, b1=b1, b2=b2, eps=ocfg.eps,
-                weight_decay=ocfg.weight_decay if decoupled else 0.0,
-                l2=0.0 if decoupled else ocfg.weight_decay,
-                clip=self.config.gradient_clipping or 0.0)
+            self._adam8bit_refusal = _a8.kernel_refusal(
+                n_devices=self.n_devices,
+                offload=self.offload_device != "none",
+                fp16=self.config.fp16.enabled)
+            if self._adam8bit_refusal is None:
+                decoupled = ocfg.type == _C.ADAMW8BIT_OPTIMIZER or \
+                    ocfg.extra.get("adam_w_mode", False)
+                b1, b2 = ocfg.betas
+                self._adam8bit_apply = _a8.kernel_apply_factory(
+                    learning_rate=self.lr_scheduler, b1=b1, b2=b2,
+                    eps=ocfg.eps,
+                    weight_decay=ocfg.weight_decay if decoupled else 0.0,
+                    l2=0.0 if decoupled else ocfg.weight_decay,
+                    clip=self.config.gradient_clipping or 0.0)
 
         # ---- loss fn -------------------------------------------------
         self._user_loss_fn = loss_fn
@@ -943,9 +947,17 @@ class Engine:
             return jnp.bfloat16
         return None
 
-    def _grads_of(self, params, batch, rng, scale, pld_theta=None):
+    @property
+    def _adam8bit_kernel(self) -> bool:
+        """The step's update takes the one-pass int8 Adam kernel."""
+        return self._adam8bit_apply is not None
+
+    def _grads_of(self, params, batch, rng, scale, pld_theta=None,
+                  narrow: bool = False):
         """(scaled loss, grads, the model's step statistics) on one global
-        micro-batch."""
+        micro-batch.  ``narrow``: a gradient that is an exact up-cast of
+        what the backward wrote comes back in that dtype (the kernel path
+        of the int8 Adam update reads it once, as written)."""
         if self.config.sparse_gradients:
             return self._grads_of_sparse(params, batch, rng, scale,
                                          pld_theta) + ({},)
@@ -962,6 +974,10 @@ class Engine:
                 if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
         (loss, stats), grads = jax.value_and_grad(
             scaled_loss_fn, has_aux=True)(params)
+        if narrow and gdt is None:
+            from .grad_origin import narrow_grads
+
+            grads = narrow_grads(grads)
         return loss, grads, stats
 
     def _grads_of_sparse(self, params, batch, rng, scale, pld_theta=None):
@@ -1048,10 +1064,15 @@ class Engine:
                 lambda g: (g * inv).astype(jnp.float32), grad_sum)
             grad_norm = optax.global_norm(grads)
         with trace.device_span("optimizer"):
-            if self._fused_opt is not None:
-                new_params, new_opt = self._fused_opt(
-                    grads, state.params, state.opt_state, grad_norm)
+            if self._adam8bit_kernel:
+                # the raw sums: ``inv`` rides in the kernel's one scalar
+                new_params, new_opt = self._adam8bit_apply(
+                    grad_sum, state.params, state.opt_state, grad_norm, inv)
             else:
+                if self._adam8bit_refusal is not None:
+                    from ..ops.adam8bit import note_refusal
+
+                    note_refusal(state.params, self._adam8bit_refusal)
                 updates, new_opt = self.tx.update(grads, state.opt_state,
                                                   state.params)
                 new_params = optax.apply_updates(state.params, updates)
@@ -1152,7 +1173,8 @@ class Engine:
                     else x.mean(0), stats)
             else:
                 loss_sum, g_sum, stats = self._grads_of(
-                    state.params, batch, rng, scale, pld_theta)
+                    state.params, batch, rng, scale, pld_theta,
+                    narrow=self._adam8bit_kernel)
                 g_sum = self._scatter_grads(g_sum)
             new_state, metrics = self._apply_grads(
                 state, g_sum, loss_sum, jnp.float32(gas))

@@ -33,7 +33,6 @@ def main():
     remat = kv.get("remat", "dots_saveable")  # "off" disables
     steps = int(kv.get("steps", 8))
     opt = kv.get("opt", "adamw8bit")
-    fused = kv.get("fused", "0") == "1"
     accum = kv.get("accum", "bf16" if gas > 1 else "fp32")
 
     dev = jax.devices()[0]
@@ -56,8 +55,7 @@ def main():
         "train_micro_batch_size_per_gpu": micro,
         "gradient_accumulation_steps": gas,
         "optimizer": {"type": opt,
-                      "params": {"lr": 1e-4, "weight_decay": 0.1,
-                                 **({"fused": True} if fused else {})}},
+                      "params": {"lr": 1e-4, "weight_decay": 0.1}},
         "zero_optimization": {"stage": 3},
         "data_types": {"grad_accum_dtype": accum},
         "steps_per_print": 10**6,
@@ -82,7 +80,7 @@ def main():
     print(json.dumps({
         "config": {"micro": micro, "gas": gas, "chunk": chunk,
                    "save_logits": save_logits, "remat": remat, "opt": opt,
-                   "fused": fused, "steps": steps},
+                   "steps": steps},
         "tok_s": round(tok_s, 1), "mfu": round(mfu, 4),
         "vs_ref": round(mfu / REF_MFU, 3),
         "step_ms": round(1000 * dt / steps, 1),

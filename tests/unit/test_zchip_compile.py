@@ -96,3 +96,144 @@ def test_sorted_dispatch_compiles_per_rank_on_four_chips(topo, monkeypatch):
     assert "ragged" not in text
     assert "all-gather" in text and ("reduce-scatter" in text
                                      or "all-reduce" in text)
+
+
+# The matrices of the two train cells: GPT-2-XL's block, table and
+# positions; OLMoE's expert stacks, attention projections, table and head.
+# (6400, 1600), (50304, 1600) and (1024, 1600) are stored column-major on
+# the chip, (2048, 50304) has rows too wide for a 128-row block.
+XL_LEAVES = [(1600, 4800), (1600, 1600), (1600, 6400), (6400, 1600),
+             (50304, 1600), (1024, 1600), (1600,)]
+OLMOE_LEAVES = [(64, 2048, 1024), (64, 1024, 2048), (2048, 2048),
+                (50304, 2048), (2048, 50304), (2048, 64), (2048,)]
+
+
+def _compile_update(one_chip, monkeypatch, shapes):
+    """The int8 AdamW update alone (``engine._apply_grads``' optimizer
+    half: global norm, clip, ``kernel_apply_factory``), bf16 gradients,
+    donated state, compiled for the described chip."""
+    import optax
+
+    from deepspeed_tpu.ops import adam8bit as a8, attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    tx = optax.chain(optax.clip_by_global_norm(1.0),
+                     a8.adamw_8bit(1e-4, weight_decay=0.1))
+    apply = a8.kernel_apply_factory(learning_rate=1e-4, b1=0.9, b2=0.999,
+                                    eps=1e-8, weight_decay=0.1, clip=1.0)
+    params = {f"leaf{i}": jax.ShapeDtypeStruct(s, jnp.float32)
+              for i, s in enumerate(shapes)}
+    grads = {k: jax.ShapeDtypeStruct(v.shape, jnp.bfloat16)
+             for k, v in params.items()}
+    opt = jax.eval_shape(tx.init, params)
+
+    def update(grads, params, opt):
+        norm = optax.global_norm(jax.tree_util.tree_map(
+            lambda g: g.astype(jnp.float32), grads))
+        return apply(grads, params, opt, norm, jnp.float32(1.0))
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    return jax.jit(update, donate_argnums=(1, 2)).lower(
+        on_chip(grads), on_chip(params), on_chip(opt)).compile()
+
+
+def _entry(text):
+    """``{name: (result shape, opcode, operand names)}`` of the entry
+    computation of an optimized HLO module."""
+    import re
+
+    ops = {}
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)(?=\s[\w\-]+\()"
+                     r"|\S+) ([\w\-]+)\((.*)", line)
+        if m:
+            body = m.group(4).split("custom_call_target")[0]
+            ops[m.group(1)] = (m.group(2), m.group(3),
+                               re.findall(r"%([\w.\-]+)", body))
+    return ops
+
+
+@pytest.mark.parametrize("cell,shapes,kernels", [
+    ("xl", XL_LEAVES, 6), ("olmoe", OLMOE_LEAVES, 5)])
+def test_adam8bit_update_is_one_in_place_pass_a_leaf(one_chip, monkeypatch,
+                                                     cell, shapes, kernels):
+    """What made the earlier one-pass kernel lose (PERF.md section 6,
+    PR 27), asserted on the executable at no chip time: one custom call a
+    leaf of a block or more; the bf16 gradient reaches it as it is (no
+    leaf-shaped fp32 buffer written by a convert or multiply fusion); no
+    layout copy in or out of a call (scales lane-dense, column-major
+    leaves taken as their transpose); state aliased in to out, so the
+    compiler's temporaries are blocks, not leaves."""
+    import re
+
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    def booked():
+        return {(impl, reason): n for site, impl, reason, n
+                in dispatch_report() if site == "adam8bit"}
+
+    before = booked()
+    compiled = _compile_update(one_chip, monkeypatch, shapes)
+    new = {k: n - before.get(k, 0) for k, n in booked().items()
+           if n > before.get(k, 0)}
+    assert new == {("kernel", "one device, whole leaves"): kernels,
+                   ("xla", "leaf under one block"): len(shapes) - kernels}
+    ops = _entry(compiled.as_text())
+    calls = {n: v for n, v in ops.items()
+             if v[1] == "custom-call" and n.startswith("adam8bit")}
+    assert len(calls) == kernels, sorted(calls)
+    leaf_dims = {",".join(map(str, s)) for s in shapes if len(s) > 1}
+    leaf_dims |= {",".join(map(str, s[::-1])) for s in shapes if len(s) == 2}
+    for name, (shape, op, _) in ops.items():
+        m = re.match(r"f32\[([\d,]+)\]", shape)
+        if m and m.group(1) in leaf_dims:
+            assert op not in ("fusion", "convert", "multiply", "copy"), \
+                f"{name}: a leaf-shaped fp32 buffer written by {op}"
+
+    def through(name):      # views cost nothing; follow them
+        while ops.get(name, ("", "", []))[1] in ("bitcast",
+                                                 "get-tuple-element"):
+            name = ops[name][2][0]
+        return name
+
+    for name, (_, _, operands) in calls.items():
+        for o in operands:
+            assert ops.get(through(o), ("", "parameter"))[1] != "copy", \
+                f"{name}: operand {o} is a copy"
+    for name, (_, op, operands) in ops.items():
+        if op == "copy":
+            assert not through(operands[0]).startswith("adam8bit"), \
+                f"{name} copies a result of {through(operands[0])}"
+    # 64 MB is the issue's bound for the two expert stacks; the XLA chain
+    # reads 0.85 MB there, the kernel as it was before PR 27 1,208.5 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_stored_transposed_is_the_chips_own_layout(one_chip):
+    """``adam8bit_kernel.stored_transposed`` predicts which 2-D leaves the
+    TPU keeps column-major (the minor dimension is the one that pads less
+    on 128 lanes); a wrong guess is not wrong numbers but seven transposing
+    copies around a call.  Held to the compiler's entry layouts, for the
+    three dtypes of a leaf's arrays."""
+    import re
+
+    from deepspeed_tpu.ops.pallas.adam8bit_kernel import stored_transposed
+
+    shapes = [s for s in XL_LEAVES + OLMOE_LEAVES if len(s) == 2] + [
+        (4800, 1600), (1600, 50304), (384, 1000), (1000, 384), (300, 200),
+        (200, 300), (768, 3072), (3072, 768), (50304, 768), (4096, 11008),
+        (11008, 4096), (5120, 13824)]
+    for dtype in (jnp.float32, jnp.bfloat16, jnp.int8):
+        args = [jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+                for s in shapes]
+        text = jax.jit(lambda *a: [x + 1 for x in a]).lower(
+            *args).compile().as_text()
+        layouts = re.search(r"entry_computation_layout=\{\((.*?)\)->", text,
+                            re.S).group(1)
+        minor_to_major = re.findall(r"\w+\[[\d,]*\]\{([\d,]+)", layouts)
+        assert len(minor_to_major) == len(shapes)
+        for s, order in zip(shapes, minor_to_major):
+            assert stored_transposed(s) == (order == "0,1"), (s, dtype, order)
