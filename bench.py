@@ -37,14 +37,12 @@ MODEL = "gpt2-125m"
 SEQ = 1024
 REF_MFU = 64.0 / 125.0  # DeepSpeed BERT-Large on V100: published best single-chip
 
-# Device physics (peak FLOPs, HBM bytes/s) live in ONE place —
-# telemetry/attribution.py — shared with the live roofline plane
-# (/profilez) and the flops profiler, so the bench and the serving
-# telemetry can never report different physics for the same executable.
+# Device physics (peak FLOPs, HBM bytes/s) live in ONE place:
+# profiling/flops_profiler.py, which the autotuner reads too.
 def _peak(dev) -> float:
-    from deepspeed_tpu.telemetry import attribution
+    from deepspeed_tpu.profiling import flops_profiler
 
-    return attribution.device_peak_flops(dev)
+    return flops_profiler.device_peak_flops(dev)
 
 
 def bench_decode():
@@ -201,13 +199,11 @@ def bench_serving():
         # slots' KV caches; floor_ms is that traffic at the chip's HBM
         # bandwidth, and floor_frac says how close steady decode runs
         # to the physics bound (1.0 = bandwidth-bound, done-bar >= 0.5).
-        # The arithmetic lives in telemetry/attribution.py — the SAME
-        # module the live /profilez roofline verdicts read — so bench
-        # and the serving plane cannot disagree on the physics.
+        # The arithmetic lives in profiling/flops_profiler.py.
         from deepspeed_tpu.models import common as model_common
-        from deepspeed_tpu.telemetry import attribution
+        from deepspeed_tpu.profiling import flops_profiler
 
-        floor = attribution.decode_stream_floor(
+        floor = flops_profiler.decode_stream_floor(
             eng.params, jax.eval_shape(lambda: eng.init_cache(1)), slots,
             dev=jax.devices()[0])
         weight_bytes = floor["weight_stream_bytes"]

@@ -21,7 +21,7 @@ from typing import Any, Optional
 
 import numpy as np
 
-from ..telemetry import attribution
+from ..profiling import flops_profiler
 from ..utils.logging import log_dist, logger
 
 
@@ -114,10 +114,10 @@ class Autotuner:
         # int8 Adam moments are THE memory lever for billion-param
         # single-chip regimes, so they are part of the search space
         self.optimizer_options = optimizer_options or [{}]
-        # chip physics come from THE table (telemetry/attribution.py); a
+        # chip physics come from THE table (profiling/flops_profiler.py); a
         # device_kind that is not in it raises — a roofline score
         # against an invented chip would rank candidates by noise
-        self.hbm_budget = (attribution.device_hbm_bytes()
+        self.hbm_budget = (flops_profiler.device_hbm_bytes()
                            * hbm_budget_fraction)
         self.seq_len = seq_len
         self.results: list[TrialResult] = []
@@ -225,8 +225,8 @@ class Autotuner:
             result.fits = np.isnan(peak) or peak <= self.hbm_budget
             # roofline per device
             result.est_step_time = max(
-                result.flops / attribution.device_peak_flops(),
-                result.bytes_accessed / attribution.device_hbm_bytes_s())
+                result.flops / flops_profiler.device_peak_flops(),
+                result.bytes_accessed / flops_profiler.device_hbm_bytes_s())
         except Exception as e:  # noqa: BLE001 — a failing candidate is data
             result.error = f"{type(e).__name__}: {e}"
         return result

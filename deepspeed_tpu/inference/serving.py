@@ -32,8 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import common as model_common
-from ..telemetry import (attribution, flightrec as telemetry_flightrec,
-                         goodput, memory as telemetry_memory,
+from ..telemetry import (flightrec as telemetry_flightrec, goodput,
+                         memory as telemetry_memory,
                          recompile, registry as telemetry_registry,
                          reqtrace as telemetry_reqtrace, trace)
 from ..telemetry.registry import pct as _pct
@@ -894,18 +894,6 @@ class ContinuousBatcher:
         eng = self.engine
         prefill_fn = eng._compiled_prefill_donated if donate \
             else eng._compiled_prefill
-        if attribution.enabled():
-            # roofline attribution (telemetry/attribution.py): sampled
-            # chunks fence + time inside the attribution module (the
-            # block lives there, off this hot path) and lazily harvest
-            # the chunk executable's cost_analysis once per site
-            raw_prefill_fn = prefill_fn
-
-            def prefill_fn(params, cache, seg, positions):   # noqa: F811
-                site = (f"serving.prefill[{int(seg.shape[0])}x"
-                        f"{int(seg.shape[1])}{'d' if donate else ''}]")
-                return attribution.timed_jit_call(
-                    site, raw_prefill_fn, params, cache, seg, positions)
         S = ids.shape[1]
         if start and cache is None:
             # an offset prefill writes at positions [start, start+S) of a
@@ -1567,36 +1555,19 @@ class ContinuousBatcher:
             drafts_np[i, :len(p)] = p
         t_window = time.perf_counter()
         verify_fn = spec.verify_step(int(w), greedy)
-        verify_args = (self.engine.params, self._cache, self._token,
-                       self._pos, np.arange(self.n_slots), self._temp,
-                       self._top_p, self._rep, self._seen, self._done,
-                       jnp.asarray(drafts_np), jnp.int32(self._tick_no),
-                       jnp.int32(self.eos), jnp.int32(self.pad))
-        # roofline attribution: sampled ticks record the window's host
-        # wall — which the token fetch below already fences, no extra
-        # sync; the verify executables have no AOT compile point, so a
-        # recorded (steady) window also harvests cost_analysis lazily,
-        # once per width, after the measured interval
-        attr_site = None
-        attr_sigs0 = None
-        if attribution.enabled():
-            site = specdec_mod.verify_site(int(w), greedy)
-            if attribution.should_sample(site):
-                attr_site = site
-                attr_sigs0 = getattr(verify_fn, "signatures_seen", None)
         with trace.span("serve/verify-tick", width=int(w),
                         active=sum(s is not None for s in self._slots),
                         uids=self._active_uids()):
             toks, n_emit, self._cache, self._token, self._pos, \
-                self._seen, self._done = verify_fn(*verify_args)
+                self._seen, self._done = verify_fn(
+                    self.engine.params, self._cache, self._token,
+                    self._pos, np.arange(self.n_slots), self._temp,
+                    self._top_p, self._rep, self._seen, self._done,
+                    jnp.asarray(drafts_np), jnp.int32(self._tick_no),
+                    jnp.int32(self.eos), jnp.int32(self.pad))
             self._tick_no += 1
             tok_h = np.asarray(jax.device_get(toks))   # (slots, w+1)
             n_h = np.asarray(jax.device_get(n_emit))   # (slots,)
-        if attr_site is not None:
-            # compile-paying windows are discarded inside note_window
-            attribution.note_window(attr_site,
-                                    time.perf_counter() - t_window,
-                                    verify_fn, attr_sigs0, verify_args)
         self._m_ticks.inc(1)
         appended = 0
         accepted_total = 0
@@ -1732,19 +1703,6 @@ class ContinuousBatcher:
                     # running — the input that drives real slo_burn
                     time.sleep(fault.arg if fault.arg is not None else 0.05)
                 t_window = time.perf_counter()
-                # roofline attribution: sampled windows record host wall
-                # against the window executable's AOT-harvested costs
-                # (warmup_windows fed them via record_compiled; ensure_costs
-                # is the un-warmed fallback).  The wall below is already
-                # fenced by the token fetch — sampling adds no sync.
-                sg = f"{int(sub)}{'g' if greedy else 's'}"
-                attr_site = None
-                if attribution.enabled():
-                    site = (f"serving.decode_paged[{sg}]"
-                            if self.paged is not None
-                            else f"serving.decode[{sg}]")
-                    if attribution.should_sample(site):
-                        attr_site = site
                 with trace.span("serve/decode-tick", ticks=int(sub),
                                 active=len(active),
                                 uids=self._active_uids()):
@@ -1753,46 +1711,30 @@ class ContinuousBatcher:
                         # cache tree; the arena rides in donated and comes
                         # back rebound (adopt).  note_window mirrors the
                         # on-device head advance into the host lengths.
-                        window_fn = self._paged_multi_step(int(sub), greedy)
-                        window_args = (
-                            self.engine.params, self.paged.decode_cache(),
-                            self._token, self._pos, slot_ids, self._temp,
-                            self._top_p, self._rep, self._seen,
-                            self._done, jnp.int32(self._tick_no),
-                            jnp.int32(self.eos), jnp.int32(self.pad))
-                        attr_sigs0 = getattr(window_fn, "signatures_seen",
-                                             None) if attr_site else None
                         toks, cache, self._token, self._pos, self._seen, \
-                            done = window_fn(*window_args)
+                            done = self._paged_multi_step(int(sub), greedy)(
+                                self.engine.params, self.paged.decode_cache(),
+                                self._token, self._pos, slot_ids, self._temp,
+                                self._top_p, self._rep, self._seen,
+                                self._done, jnp.int32(self._tick_no),
+                                jnp.int32(self.eos), jnp.int32(self.pad))
                         self.paged.adopt(cache)
                         self.paged.note_window(int(sub))
                     else:
-                        window_fn = self._multi_step(int(sub), greedy)
-                        window_args = (
-                            self.engine.params, self._cache, self._token,
-                            self._pos, slot_ids, self._temp, self._top_p,
-                            self._rep, self._seen, self._done,
-                            jnp.int32(self._tick_no), jnp.int32(self.eos),
-                            jnp.int32(self.pad))
-                        attr_sigs0 = getattr(window_fn, "signatures_seen",
-                                             None) if attr_site else None
                         toks, self._cache, self._token, self._pos, \
-                            self._seen, done = window_fn(*window_args)
+                            self._seen, done = self._multi_step(
+                                int(sub), greedy)(
+                                self.engine.params, self._cache, self._token,
+                                self._pos, slot_ids, self._temp, self._top_p,
+                                self._rep, self._seen, self._done,
+                                jnp.int32(self._tick_no), jnp.int32(self.eos),
+                                jnp.int32(self.pad))
                     self._tick_no += int(sub)
                     self._done = done
                     # the fetch is part of the tick's host wall time:
                     # it waits for the window the dispatch above enqueued
                     with trace.span("serve/fetch", ticks=int(sub)):
                         tok_h = np.asarray(jax.device_get(toks))[:, :, 0]
-                if attr_site is not None:
-                    # compile-paying windows are discarded inside
-                    # note_window; a recorded (steady) window also runs the
-                    # one-shot lazy cost harvest AFTER the measured interval
-                    # (lower only reads avals — the donated arena in
-                    # window_args is safe)
-                    attribution.note_window(attr_site,
-                                            time.perf_counter() - t_window,
-                                            window_fn, attr_sigs0, window_args)
                 self._m_ticks.inc(int(sub))
                 appended = 0
                 emitted_by_uid: Dict[int, int] = {}
@@ -2123,10 +2065,9 @@ class ContinuousBatcher:
                     self._extract_row_fn.lower(
                         cacheB, firstB, seen, 0).compile(),
                     site=f"serving.extract_row[{B}]")
-        # retire is the remaining admission-side executable with no
-        # record point: lower it abstractly too (donation never fires —
-        # lower/compile do not execute), so the attribution plane has
-        # costs for every serving executable, not just the windows
+        # retire is the remaining admission-side executable: lower it
+        # abstractly too (donation never fires — lower/compile do not
+        # execute)
         if self.paged is not None:
             telemetry_memory.record_compiled(
                 self._paged_retire_fn.lower(

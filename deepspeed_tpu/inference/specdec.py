@@ -67,18 +67,10 @@ from .engine import (InferenceEngine, _filtered_logits, _penalized_logits,
                      _sample)
 
 __all__ = ["NGramDrafter", "DraftModelDrafter", "SpecDecodeConfig",
-           "SpecDecoder", "resolve_specdec", "verify_site", "SPECDEC_ENV"]
+           "SpecDecoder", "resolve_specdec", "SPECDEC_ENV"]
 
 SPECDEC_ENV = "DSTPU_SPECDEC"
 
-
-def verify_site(w: int, greedy: bool) -> str:
-    """THE verify-executable site name — shared by the recompile
-    watchdog wrapper below and the serving loop's roofline attribution
-    (``telemetry/attribution.py``), so the watchdog's warnings, the
-    ``/profilez`` rows and the HBM gauges all name one executable one
-    way."""
-    return f"serving.verify[{w}{'g' if greedy else 's'}]"
 
 # accepted drafts per slot per verify tick land in [0, k]; the schema is
 # declared ONCE in registry.BUCKET_SCHEMAS (fleet bucket-wise merge
@@ -498,7 +490,9 @@ class SpecDecoder:
             in_axes=(None, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0, None, None, None))
         # each (w, greedy) is its own executable BY DESIGN (pow2 widths);
         # intra-key drift is a real hot-loop recompile — warn
-        return recompile.watch(jax.jit(vstep), name=verify_site(w, greedy))
+        return recompile.watch(
+            jax.jit(vstep),
+            name=f"serving.verify[{w}{'g' if greedy else 's'}]")
 
     # -- observability -------------------------------------------------
     def _telemetry_status(self) -> dict:

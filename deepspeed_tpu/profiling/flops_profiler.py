@@ -23,13 +23,105 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
-from ..telemetry import attribution as _attribution
 from ..utils.logging import logger
 
-# bf16 peak flops per chip — THE shared table in
-# telemetry/attribution.py (bench.py and the live roofline plane read
-# the same one); kept under the historical name for callers
-PEAK_TFLOPS = _attribution.PEAK_FLOPS
+# -- device physics (THE one copy; bench.py, the autotuner and the
+# profiler below read these).  Keyed by a substring of ``device_kind``.
+# A device that is not in the table is an error, not a default: a CPU
+# run has no peak, and a number against an invented one is noise.
+# Source: Google Cloud TPU documentation, system architecture pages
+# (v4, v5e, v5p, v6e): bf16 peak FLOP/s, HBM bytes/s, HBM bytes per chip.
+PEAK_FLOPS = {"v4": 275e12, "v5 lite": 197e12, "v5e": 197e12,
+              "v5p": 459e12, "v6 lite": 918e12, "v6e": 918e12}
+
+# HBM bandwidth per chip (bytes/s) — the decode bandwidth-floor
+# denominator: a decode tick streams every weight byte plus the live KV
+# cache, so floor_ms = bytes / BW is the physics bound serving numbers
+# are judged against.
+HBM_BYTES_S = {"v4": 1228e9, "v5 lite": 819e9, "v5e": 819e9,
+               "v5p": 2765e9, "v6 lite": 1640e9, "v6e": 1640e9}
+
+# HBM capacity per chip (bytes) — the autotuner's fit budget.
+HBM_BYTES = {"v4": 32e9, "v5 lite": 16e9, "v5e": 16e9,
+             "v5p": 95e9, "v6 lite": 32e9, "v6e": 32e9}
+
+
+def device_known(dev) -> bool:
+    """Whether ``dev``'s ``device_kind`` has a row in the physics tables
+    (callers that run legitimately off-TPU skip their roofline numbers
+    when it does not)."""
+    kind = dev.device_kind.lower()
+    return any(key in kind for key in PEAK_FLOPS)
+
+
+def _device_lookup(dev, table: dict, what: str) -> float:
+    if dev is None:
+        import jax
+
+        dev = jax.local_devices()[0]
+    kind = dev.device_kind.lower()
+    for key, val in table.items():
+        if key in kind:
+            return val
+    raise ValueError(
+        f"no {what} known for device_kind {dev.device_kind!r}; add the "
+        f"chip (with its source) to profiling/flops_profiler.py")
+
+
+def device_peak_flops(dev=None) -> float:
+    """Peak bf16 FLOPs/s of ``dev`` (device 0 when None) from
+    :data:`PEAK_FLOPS`; raises for a ``device_kind`` not in the table."""
+    return _device_lookup(dev, PEAK_FLOPS, "peak FLOP/s")
+
+
+def device_hbm_bytes_s(dev=None) -> float:
+    """HBM bandwidth (bytes/s) of ``dev`` from :data:`HBM_BYTES_S`."""
+    return _device_lookup(dev, HBM_BYTES_S, "HBM bandwidth")
+
+
+def device_hbm_bytes(dev=None) -> float:
+    """HBM capacity (bytes) of ``dev`` from :data:`HBM_BYTES`."""
+    return _device_lookup(dev, HBM_BYTES, "HBM capacity")
+
+
+def harvest_costs(compiled) -> Optional[dict]:
+    """THE ``cost_analysis()`` normalizer: ``{"flops", "bytes_accessed",
+    "transcendentals"}`` (floats) or None when the backend exposes no
+    analysis.  XLA counts no FLOPs inside a Pallas call."""
+    try:
+        costs = compiled.cost_analysis()
+    except Exception:
+        return None
+    if isinstance(costs, (list, tuple)):     # some backends: [dict]
+        costs = costs[0] if costs else None
+    if costs is None:
+        return None
+    costs = dict(costs)
+    return {
+        "flops": float(costs.get("flops", 0.0)),
+        "bytes_accessed": float(costs.get("bytes accessed", 0.0)),
+        "transcendentals": float(costs.get("transcendentals", 0.0)),
+    }
+
+
+def decode_stream_floor(params, slot_cache, n_slots: int, dev=None) -> dict:
+    """The decode-tick HBM bandwidth floor: every stored weight byte
+    plus the slots' KV caches must stream from HBM each tick, so
+    ``bw_floor_ms_per_tick`` is the physics bound a measured
+    ms-per-tick is judged against.  ``slot_cache`` is a ONE-slot cache
+    tree (arrays or ``ShapeDtypeStruct``\\ s — ``eval_shape`` is fine).
+    This is ``bench.py --mode serving``'s accounting."""
+    from ..telemetry import memory as telemetry_memory
+
+    weight_bytes = telemetry_memory.tree_bytes(params)
+    kv_bytes = int(n_slots) * telemetry_memory.tree_bytes(slot_cache)
+    bw = device_hbm_bytes_s(dev)
+    return {
+        "weight_stream_bytes": int(weight_bytes),
+        "kv_stream_bytes_per_tick": int(kv_bytes),
+        "hbm_bytes_s": float(bw),
+        "bw_floor_ms_per_tick": 1000.0 * (weight_bytes + kv_bytes) / bw,
+    }
 
 
 def profile_compiled(fn: Callable, *args, static_argnums=(),
@@ -45,10 +137,7 @@ def profile_compiled(fn: Callable, *args, static_argnums=(),
     if lowered is None:
         lowered = jax.jit(fn, static_argnums=static_argnums).lower(*args)
     compiled = lowered.compile()
-    # the cost normalization is THE shared one (telemetry/attribution.py
-    # harvest_costs) — the profiler, the bench and the live roofline
-    # plane read the compiler's numbers identically
-    out = _attribution.harvest_costs(compiled) or {
+    out = harvest_costs(compiled) or {
         "flops": 0.0, "bytes_accessed": 0.0, "transcendentals": 0.0}
     # per-device bytes, one normalizer shared with the autotuner and the
     # HBM gauges (telemetry/memory.py) — no private memory_analysis math
@@ -154,7 +243,7 @@ def _device_peak_flops() -> Optional[float]:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         return None
-    return _attribution.device_peak_flops(dev)
+    return device_peak_flops(dev)
 
 
 class FlopsProfiler:

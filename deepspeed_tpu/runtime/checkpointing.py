@@ -561,9 +561,9 @@ def save_checkpoint(engine, save_dir: str, tag: Optional[str] = None,
     from ..utils.heartbeat import beat
 
     t0 = time.perf_counter()
-    # attribution: direct module-level saves (scripts, the guard) must
-    # bill `checkpoint` goodput too, not only engine.save_checkpoint's
-    # span — nesting is fine, attribution is exclusive
+    # direct module-level saves (scripts, the guard) must bill
+    # `checkpoint` goodput too, not only engine.save_checkpoint's span —
+    # nesting is fine, goodput books each second to one phase
     with trace.span("train/checkpoint", tag=tag):
         ckptr = _checkpointer()
         state_path = os.path.join(ckpt_dir, MODULE_DIR)

@@ -26,7 +26,6 @@ import dataclasses
 import functools
 import os
 import re
-import time
 from typing import Any, Callable, Optional
 
 import flax
@@ -41,8 +40,7 @@ from .. import comm
 from ..comm.mesh import DATA_AXES, MeshConfig, build_mesh, data_parallel_size, set_mesh
 from ..models.common import TP_RULES
 from ..parallel import zero as zero_lib
-from ..telemetry import (attribution as telemetry_attribution, recompile,
-                         registry as telemetry_registry, trace)
+from ..telemetry import recompile, registry as telemetry_registry, trace
 from ..testing import chaos as chaos_mod
 from ..utils import ThroughputTimer, log_dist, logger
 from . import precision
@@ -1482,7 +1480,7 @@ class Engine:
             # whole grad tree just to decide the clip factor
             return loss / gas, g, optax.global_norm(g)
 
-        return jax.jit(grads_fn)
+        return recompile.watch(jax.jit(grads_fn), name="engine.grads_only")
 
     def _host_offload_train_batch(self, batch):
         """ZeRO-Offload step (reference ``stage_1_and_2.py`` cpu_offload):
@@ -1720,9 +1718,10 @@ class Engine:
 
         One ``train/step`` span with three children: ``train/next-batch``
         (pull, concatenate, relayout), ``train/device-put`` and
-        ``train/dispatch`` (the call of the compiled step: its enqueue,
-        the recompile watchdog's signature check, and the wait for a free
-        slot once the host runs ahead of the device); what is left is the
+        ``train/dispatch`` (the call of the compiled step: its enqueue and
+        the wait for a free slot once the host runs ahead of the device;
+        a call that compiles is signed there by the recompile watchdog);
+        what is left is the
         step's self time (throughput timer, guard, print).
         """
         with trace.span("train/step", step=self.global_steps):
@@ -1802,32 +1801,13 @@ class Engine:
                 log_dist(f"step={self.global_steps} loss={float(jax.device_get(loss)):.4f} "
                          f"(offload={self.offload_device})", ranks=[0])
             return loss
-        # roofline attribution (telemetry/attribution.py, opt-in via
-        # DSTPU_ATTRIBUTION): 1-in-N steps fence the loss and record the
-        # step's host wall against the train step's AOT-harvested costs
-        # (record_memory_profile publishes them).  Unsampled steps keep
-        # async dispatch — the fence is the whole cost of a sample.
-        attr_sample = telemetry_attribution.enabled() and \
-            telemetry_attribution.should_sample("engine.train_step")
-        attr_sigs0 = getattr(self._compiled_train_step,
-                             "signatures_seen", None) if attr_sample else None
         self._tput.start()
-        t_attr = time.perf_counter() if attr_sample else 0.0
         with trace.span("train/dispatch", step=self.global_steps):
             self._state, metrics = self._compiled_train_step(
                 self._state, batch, *extra)
         if "model_stats" in metrics:
             self._pending_stats.append(metrics["model_stats"])
             self.drain_step_stats()
-        if attr_sample:
-            # compile-paying steps are discarded inside note_window (the
-            # serving windows apply the same discipline); costs come
-            # from record_memory_profile's AOT point, so no lazy-harvest
-            # args are passed
-            jax.block_until_ready(metrics["loss"])
-            telemetry_attribution.note_window(
-                "engine.train_step", time.perf_counter() - t_attr,
-                self._compiled_train_step, attr_sigs0)
         self.global_steps += 1
         self.micro_steps += self.gradient_accumulation_steps
         self.global_samples += self.train_batch_size

@@ -22,15 +22,17 @@ Seven coordinated surfaces replacing the reference's scattered
   ``device_span`` stamps HLO metadata inside compiled code
   (``loss_head``, ``grad_clip``, ``optimizer``, ``embed``,
   ``zero/scatter``, the pipeline stages).
-- :mod:`.recompile` — watchdog over jitted hot loops that counts
-  distinct compile signatures and warns when a warm loop recompiles;
-  and the compile-event listeners: seconds by phase
+- :mod:`.recompile` — watchdog over jitted hot loops: a call that made
+  an executable signs its arguments, is counted, and warns when a warm
+  loop recompiled (a call that made none costs two counter reads); and
+  the compile-event listeners: seconds by phase
   (``xla_compile_seconds_total{phase,span}``) and executables
   (``xla_executables_total{how,span}``) by innermost open span, for
   every executable the process makes, plus a ``compile/backend`` record
   in the tracer's ring under the span that compiled.
 - :mod:`.exporter` — per-rank HTTP server (``/metrics`` Prometheus
-  text, ``/healthz`` liveness JSON, ``/statusz`` operational JSON);
+  text, ``/healthz`` liveness JSON, ``/statusz`` operational JSON,
+  ``/alertz``, ``/tracez``);
   opt-in via ``dstpu --telemetry_port`` / ``DSTPU_TELEMETRY_PORT``.
 - :mod:`.goodput` — step-phase wall-time attribution (compute /
   data-wait / checkpoint / recompile / idle) + ``goodput_ratio``.
@@ -41,6 +43,11 @@ Seven coordinated surfaces replacing the reference's scattered
   and the newest spans of the tracer's ring) dumped on atexit,
   SIGTERM/SIGABRT, and unhandled exceptions; the launcher pretty-prints
   it on restart.
+- :mod:`.anomaly` — rolling detectors over registry series (recompile
+  storm, SLO burn, queue runaway, acceptance collapse, goodput drop,
+  loss spike, grad-norm explosion) raising structured alerts:
+  ``alerts_total{rule}``, ``/alertz``, ``subscribe`` (the admission
+  ladder and the ``TrainGuard`` consume them).
 - :mod:`.fleet` — the multi-replica rollup: scrapes N per-rank
   exporters (static list / env / the launcher-written ``fleet.json``),
   merges them per metric kind, runs a per-replica health state
@@ -69,7 +76,7 @@ from .registry import (  # noqa: F401
 )
 from . import goodput, memory  # noqa: F401  (need registry+trace above)
 from . import exporter, flightrec  # noqa: F401
-from . import anomaly, attribution  # noqa: F401  (need exporter above)
+from . import anomaly  # noqa: F401  (needs exporter above)
 from . import fleet  # noqa: F401  (needs registry + anomaly above)
 from . import reqtrace  # noqa: F401  (needs registry + trace above)
 
@@ -83,10 +90,8 @@ recompile.install_compile_events()
 from .registry import register_collector as _register_collector  # noqa: E402
 
 _register_collector(memory.sample_live_hbm)
-# roofline attribution (/profilez, opt-in sampling via
-# DSTPU_ATTRIBUTION) + anomaly/alert detectors (/alertz, evaluated on
-# scrapes and step boundaries)
-attribution.install()
+# anomaly/alert detectors (/alertz, evaluated on scrapes and step
+# boundaries)
 anomaly.install()
 # crash forensics when a dump dir is configured; live endpoints when a
 # port is configured

@@ -16,9 +16,6 @@ series and emits structured alert events:
   floor while verify ticks are still being paid.
 - **goodput_drop** — ``goodput_ratio`` under the floor on a warmed-up
   process.
-- **attribution_drift** — a per-executable roofline verdict flipped
-  (e.g. ``hbm-bound`` → ``overhead-bound``): the executable's
-  character changed even if throughput hasn't visibly regressed yet.
 
 Every fire/clear transition lands in FOUR places: the
 ``alerts_total{rule}`` counter + ``alerts_firing{rule}`` gauge, the
@@ -49,8 +46,8 @@ from . import registry as _registry
 __all__ = [
     "Series", "Detector", "RecompileStormDetector", "SloBurnDetector",
     "QueueRunawayDetector", "AcceptanceCollapseDetector",
-    "GoodputDropDetector", "AttributionDriftDetector",
-    "LossSpikeDetector", "GradNormExplosionDetector", "AnomalyEngine",
+    "GoodputDropDetector", "LossSpikeDetector",
+    "GradNormExplosionDetector", "AnomalyEngine",
     "get_engine", "observe", "subscribe", "active", "recent", "status",
     "install",
 ]
@@ -356,41 +353,6 @@ class GoodputDropDetector(Detector):
         return None
 
 
-class AttributionDriftDetector(Detector):
-    """A measured executable's roofline verdict FLIPPED between
-    evaluations (e.g. ``hbm-bound`` → ``overhead-bound``).  Pulse
-    semantics: each flip emits exactly one ``firing`` event (with the
-    site and both verdicts in ``detail``) and does not stay active —
-    drift is an edge, not a state."""
-
-    name = "attribution_drift"
-
-    def __init__(self):
-        super().__init__()
-        self._last: Dict[str, str] = {}
-
-    def check(self, engine, now):     # unused (step overridden)
-        return None
-
-    def step(self, engine, now) -> List[dict]:
-        try:
-            from . import attribution as _attribution
-
-            verdicts = _attribution.get_plane().verdicts()
-        except Exception:
-            return []
-        events: List[dict] = []
-        for site, verdict in verdicts.items():
-            prev = self._last.get(site)
-            if prev is not None and prev != verdict:
-                events.append(self._event("firing", now, {
-                    "value": None, "threshold": None,
-                    "detail": {"site": site, "from": prev,
-                               "to": verdict}}))
-            self._last[site] = verdict
-        return events
-
-
 def _finite_median(xs: List[float]) -> Optional[float]:
     import math
 
@@ -482,7 +444,7 @@ class GradNormExplosionDetector(_TrailingRatioDetector):
 def default_detectors() -> List[Detector]:
     return [RecompileStormDetector(), SloBurnDetector(),
             QueueRunawayDetector(), AcceptanceCollapseDetector(),
-            GoodputDropDetector(), AttributionDriftDetector(),
+            GoodputDropDetector(),
             LossSpikeDetector(), GradNormExplosionDetector()]
 
 
@@ -585,12 +547,8 @@ class AnomalyEngine:
         one alert PER REPLICA under one rule name) and by the rule
         otherwise."""
         self.events.append(ev)
-        # pulse rules (attribution drift) never stay active
-        pulse = any(d.name == ev["rule"]
-                    and isinstance(d, AttributionDriftDetector)
-                    for d in self.detectors)
         key = ev.get("key", ev["rule"])
-        if ev["state"] == "firing" and not pulse:
+        if ev["state"] == "firing":
             self._active[key] = ev
         else:
             self._active.pop(key, None)

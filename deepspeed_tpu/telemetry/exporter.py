@@ -12,9 +12,6 @@ the smallest one that works — a stdlib ``http.server`` thread serving:
   (rank/pid/uptime/recompile counts/goodput breakdown) merged with
   named provider sections the engine, the serving batcher, the
   inference engine and the monitor register at init;
-- ``/profilez`` — the per-executable roofline attribution table
-  (``telemetry/attribution.py``; ``?capture_ms=N`` for an on-demand
-  ``jax.profiler`` device trace);
 - ``/alertz`` — active + recent structured alerts and detector
   thresholds (``telemetry/anomaly.py``);
 - ``/tracez`` — retained request traces (``telemetry/reqtrace.py``):
@@ -179,26 +176,6 @@ class _Handler(BaseHTTPRequestHandler):
             elif path == "/statusz":
                 self._send(200, json.dumps(_collect_status()).encode(),
                            "application/json")
-            elif path == "/profilez":
-                # per-executable roofline attribution table
-                # (telemetry/attribution.py); ?capture_ms=N additionally
-                # records an on-demand jax.profiler device trace while
-                # the workload keeps running
-                from urllib.parse import parse_qs, urlparse
-
-                from . import attribution
-
-                payload = attribution.snapshot()
-                q = parse_qs(urlparse(self.path).query)
-                if "capture_ms" in q:
-                    try:
-                        ms = int(q["capture_ms"][0])
-                    except ValueError:
-                        ms = 0
-                    if ms > 0:
-                        payload["trace_dir"] = attribution.capture_trace(ms)
-                self._send(200, json.dumps(payload).encode(),
-                           "application/json")
             elif path == "/alertz":
                 # evaluate (throttled) so a scrape never reads detectors
                 # staler than ~1s, then serve active + recent alerts
@@ -238,7 +215,7 @@ class _Handler(BaseHTTPRequestHandler):
                                "application/json")
             else:
                 self._send(404, b"not found: try /metrics /healthz /statusz"
-                                b" /profilez /alertz /tracez\n",
+                                b" /alertz /tracez\n",
                            "text/plain")
         except BrokenPipeError:
             pass                     # scraper went away mid-response
@@ -294,7 +271,7 @@ class TelemetryExporter:
             "bound port of this rank's telemetry HTTP server"
         ).set(float(self.port))
         logger.info(f"telemetry exporter serving /metrics /healthz "
-                    f"/statusz /profilez /alertz /tracez on {self.url}")
+                    f"/statusz /alertz /tracez on {self.url}")
         return self
 
     def stop(self) -> None:

@@ -161,10 +161,8 @@ class FlightRecorder:
                 "metric_deltas": list(self.deltas),
                 "metrics": _registry.get_registry().snapshot(),
             }
-            # what was alerting + what was slow at death: the anomaly
-            # engine's active/recent alerts and the attribution plane's
-            # last per-executable snapshot ride every dump, so a
-            # postmortem answers both without re-running the workload
+            # what was alerting at death: the anomaly engine's active and
+            # recent alerts ride every dump
             try:
                 from . import anomaly as _anomaly
 
@@ -172,14 +170,6 @@ class FlightRecorder:
                 if a["active"] or a["recent"]:
                     payload["alerts"] = {"active": a["active"],
                                          "recent": a["recent"]}
-            except Exception:
-                pass
-            try:
-                from . import attribution as _attribution
-
-                snap = _attribution.snapshot()
-                if snap.get("rows"):
-                    payload["attribution"] = snap
             except Exception:
                 pass
             # retained request traces (telemetry/reqtrace.py): the
@@ -447,18 +437,6 @@ def pretty(path_or_payload, max_spans: int = 8, max_logs: int = 8) -> str:
         ago = round(t_dump - last.get("t", t_dump), 3)
         lines.append(f"  no active alerts; last transition -{ago}s: "
                      f"{last['rule']} {last['state']}")
-    # what was slow: the attribution plane's measured executables with
-    # their roofline verdicts (slowest first, as snapshotted)
-    attr_rows = [r for r in (p.get("attribution") or {}).get("rows", [])
-                 if r.get("measured_ms") is not None]
-    if attr_rows:
-        lines.append("  attribution (measured executables, slowest first):")
-        for r in attr_rows[:5]:
-            mfu = f" mfu={r['mfu']:.4f}" if r.get("mfu") is not None else ""
-            bw = f" bw={r['bw_frac']:.4f}" \
-                if r.get("bw_frac") is not None else ""
-            lines.append(f"    {r['site']:<32} {r['measured_ms']}ms "
-                         f"{r['verdict']}{mfu}{bw}")
     key = {}
     for name in ("train_steps_total", "serving_decode_ticks_total",
                  "serving_requests_completed_total", "xla_recompiles_total",

@@ -74,17 +74,7 @@ def record_compiled(compiled, site: str,
                     registry: Optional[_registry.Registry] = None
                     ) -> Optional[dict]:
     """Publish ``compiled``'s breakdown as per-site HBM gauges; returns
-    the breakdown (None when unavailable — nothing is published).
-
-    Every AOT compile point that records memory also feeds the roofline
-    attribution plane (``telemetry/attribution.py``) its
-    ``cost_analysis()`` FLOPs/bytes — one call site, two surfaces."""
-    try:
-        from . import attribution as _attribution
-
-        _attribution.note_compiled(compiled, site)
-    except Exception:
-        pass        # attribution must never break a compile point
+    the breakdown (None when unavailable — nothing is published)."""
     bd = memory_breakdown(compiled)
     if bd is None:
         return None

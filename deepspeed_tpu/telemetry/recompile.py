@@ -8,28 +8,31 @@ got slow" with no error anywhere.  The reference has nothing comparable
 (CUDA eager mode doesn't recompile); on XLA it is the first thing to
 rule out.
 
-:func:`watch` wraps a jitted callable.  Each call computes a cheap
-host-side signature — the args pytree structure plus every leaf's
-(shape, dtype) — the shape/dtype part of the key ``jax.jit``'s C++
-cache dispatches on; the part it cannot see (shardings, layouts) is
-covered by a post-call ``_cache_size()`` cross-check: executable-count
-growth on an already-known signature is also flagged as a recompile.
-The FIRST distinct signature per watched site is the expected warm-up
-compile; every NEW signature after that means the hot loop recompiled:
+:func:`watch` wraps a jitted callable and OBSERVES compiles; it does not
+predict them.  :func:`install_compile_events` keeps, for every thread,
+a count of the executables that thread has made and no watched call has
+claimed.  Around each call the wrapper reads that count: if it did not
+move, the call returns — no flatten, no hash.  Only a call that made an executable signs its
+arguments (pytree structure plus every leaf's shape, dtype and weak
+type; read after the call, all three survive donation) and applies the
+rules:
 
-- ``xla_recompiles_total{site=...}`` increments (once per new signature);
-- a rate-limited warning names the site and the offending leaf shapes,
-  diffed against the previously seen signature when possible;
-- where the wrapped function exposes ``_cache_size()`` (jitted
-  callables do), the executable count is cross-checked into the log.
+- the site's first compiling call is the expected warm-up;
+- a signature the site has not compiled before is a recompile:
+  ``xla_recompiles_total{site=...}`` increments and a rate-limited
+  warning names the leaf that changed, diffed against the nearest
+  signature the site has compiled;
+- a known signature that compiles AGAIN changed something the
+  signature cannot show (an input's sharding or layout, a static
+  argument's value): a recompile once the site has had one call that
+  compiled nothing, warm-up churn before that (eager-built buffers
+  being replaced by committed jit outputs).
 
 Sites whose signatures legitimately vary (chunked prefill compiles one
 executable per power-of-two chunk BY DESIGN) pass ``warn=False``: their
 compile population lands in ``xla_compiled_signatures_total`` only, so
 ``xla_recompiles_total`` stays a clean page-the-oncall alert metric.
-
-Disable globally with ``DSTPU_RECOMPILE_WATCHDOG=0`` (``watch`` then
-returns the callable unwrapped).
+An executable made inside a nested watched call is the inner site's.
 
 The watchdog sees only the jits it wraps.  :func:`install_compile_events`
 (called once, at telemetry import) sees every executable the process
@@ -44,19 +47,17 @@ go" and "which step compiled" are one counter read and one span query.
 """
 from __future__ import annotations
 
-import os
 import threading
 import time
 from typing import Any, Optional
 
 from ..utils.logging import logger
+from . import goodput as _goodput
 from . import registry as _registry
 from . import trace as _trace
 
-__all__ = ["watch", "RecompileWatchdog", "total_recompiles", "WATCHDOG_ENV",
+__all__ = ["watch", "RecompileWatchdog", "total_recompiles",
            "install_compile_events"]
-
-WATCHDOG_ENV = "DSTPU_RECOMPILE_WATCHDOG"
 
 _WARN_INTERVAL_S = 30.0
 
@@ -79,126 +80,80 @@ def _tree_sig(tree):
     return (treedef, tuple(_leaf_sig(l) for l in leaves))
 
 
-def _leaf_sigs_of(sig):
-    out = []
-    for part in sig:
-        if part is not None:
-            out.extend(part[1])
-    return out
-
-
 def _describe(sig) -> str:
-    shapes = [f"{s[0]}:{s[1]}" for s in _leaf_sigs_of(sig)
-              if isinstance(s, tuple)]
+    shapes = [f"{s[0]}:{s[1]}" for s in sig[1] if isinstance(s, tuple)]
     head = ", ".join(shapes[:8])
     if len(shapes) > 8:
         head += f", … +{len(shapes) - 8} more"
     return head
 
 
-def _diff(old_sig, new_sig) -> Optional[str]:
-    """First differing leaf between two signatures with the same tree
-    structure — usually THE offending argument."""
-    if old_sig is None:
-        return None
-    old_parts = [p[0] for p in old_sig if p is not None]
-    new_parts = [p[0] for p in new_sig if p is not None]
-    if old_parts != new_parts:
-        return None
-    for i, (a, b) in enumerate(zip(_leaf_sigs_of(old_sig),
-                                   _leaf_sigs_of(new_sig))):
-        if a != b:
-            return f"leaf #{i}: {a} -> {b}"
-    return None
+def _nearest_diff(compiled, new_sig) -> tuple:
+    """``(indices, old_leaves)``: the leaves in which ``new_sig`` differs
+    from the nearest (fewest differing leaves) signature of the same tree
+    structure among ``compiled``, and that signature's leaves; ``([],
+    None)`` when none shares the structure."""
+    best, best_old = [], None
+    for old in compiled:
+        if old[0] != new_sig[0]:
+            continue
+        idx = [i for i, (a, b) in enumerate(zip(old[1], new_sig[1]))
+               if a != b]
+        if best_old is None or len(idx) < len(best):
+            best, best_old = idx, old[1]
+    return best, best_old
+
+
+def _what_changed(compiled, sig, args, kwargs) -> str:
+    """The warning's detail: the first leaf in which the call's ``sig``
+    differs from the nearest signature the site has ``compiled``, named
+    by its path in ``(args, kwargs)``."""
+    idx, old_leaves = _nearest_diff(compiled, sig)
+    if not idx:
+        return f"arg shapes now [{_describe(sig)}]"
+    import jax
+
+    paths = jax.tree_util.tree_flatten_with_path((args, kwargs))[0]
+    i = idx[0]
+    more = f", +{len(idx) - 1} more" if len(idx) > 1 else ""
+    return (f"leaf #{i} args{jax.tree_util.keystr(paths[i][0])}: "
+            f"{old_leaves[i]} -> {sig[1][i]}{more}")
 
 
 class _Watched:
-    """Transparent wrapper: forwards ``__call__`` through the signature
-    check, everything else (``lower``, ``_cache_size`` …) to the wrapped
-    callable."""
+    """Transparent wrapper: forwards ``__call__`` through the compile
+    observer, everything else (``lower``, ``_cache_size`` …) to the
+    wrapped callable."""
 
-    __slots__ = ("_fn", "_name", "_warn", "_dog", "_sigs", "_last_sig",
-                 "_arg0_obj", "_arg0_sig", "_max_cache_size", "_settled")
+    __slots__ = ("_fn", "_name", "_warn", "_dog", "_sigs", "_settled")
 
     def __init__(self, fn, name: str, warn: bool, dog: "RecompileWatchdog"):
         self._fn = fn
         self._name = name
         self._warn = warn
         self._dog = dog
-        self._sigs = set()
-        self._last_sig = None          # signature of the PREVIOUS call —
-        self._arg0_obj = None          # the loop that was actually running
-        self._arg0_sig = None
-        self._max_cache_size = None
-        self._settled = False          # saw >=1 call with NO cache growth
-
-    def _signature_of(self, args, kwargs):
-        # (head, rest) pair: the first positional arg signed separately
-        # with an identity memo — serving passes the same params tree
-        # every tick; skip re-flattening its hundreds of leaves
-        if args and args[0] is self._arg0_obj:
-            head = self._arg0_sig
-        elif args:
-            head = _tree_sig((args[0],))
-            self._arg0_obj = args[0]   # strong ref: pins the python tree
-            self._arg0_sig = head      # (donated buffers are already
-        else:                          # deleted; only wrappers persist)
-            head = None
-        return (head, _tree_sig((args[1:], kwargs)))
+        self._sigs = set()             # signatures this site has compiled
+        self._settled = False          # saw >=1 call that compiled nothing
 
     def __call__(self, *args, **kwargs):
-        try:
-            sig = self._signature_of(args, kwargs)
-        except Exception:
-            sig = None   # unhashable leaf etc.: never break the hot path
-        is_new = sig is not None and sig not in self._sigs
-        if is_new:
-            first = not self._sigs
-            self._sigs.add(sig)
-            self._dog._on_new_signature(self, sig, self._last_sig, first)
-        self._last_sig = sig
-        if is_new:
-            # a new signature means this call pays trace+compile before
-            # dispatch returns — bill it to the goodput "recompile" phase
-            # (warm-up included: compile time is not goodput either way)
-            t0 = time.perf_counter()
-            out = self._fn(*args, **kwargs)
-            try:
-                from . import goodput
-
-                goodput.note_compile(time.perf_counter() - t0)
-            except Exception:
-                pass
-        else:
-            out = self._fn(*args, **kwargs)
-        # cross-check: jax.jit's C++ cache also keys on SHARDINGS and
-        # layouts, which the host-side signature cannot see — if the
-        # executable count grew on an already-known signature, the loop
-        # recompiled anyway (e.g. a resharded state after checkpoint load)
-        try:
-            cs = self._fn._cache_size()
-        except Exception:
-            cs = None
-        if cs is not None:
-            if self._max_cache_size is not None and cs > self._max_cache_size:
-                # growth counts only once the site has SETTLED (seen a
-                # call with no growth): the warm-up phase legitimately
-                # compiles per-layout variants as eager-built buffers are
-                # replaced by committed jit outputs
-                if self._settled and not is_new and sig is not None:
-                    self._dog._on_hidden_recompile(self, cs)
-            elif self._max_cache_size is not None:
-                self._settled = True
-            if self._max_cache_size is None or cs > self._max_cache_size:
-                self._max_cache_size = cs
+        tls = _compile_tls
+        before = tls.unclaimed
+        t0 = time.perf_counter()
+        out = self._fn(*args, **kwargs)
+        if tls.unclaimed == before:
+            self._settled = True
+            return out
+        # this call made an executable; taking the count back to where it
+        # stood keeps a watched call that encloses this one from claiming
+        # the same executable
+        tls.unclaimed = before
+        # compile time is not goodput, warm-up included
+        _goodput.note_compile(time.perf_counter() - t0)
+        self._dog._on_compile(self, args, kwargs)
         return out
 
     def __getattr__(self, attr):
         return getattr(self._fn, attr)
-
-    @property
-    def signatures_seen(self) -> int:
-        return len(self._sigs)
 
 
 class RecompileWatchdog:
@@ -207,73 +162,68 @@ class RecompileWatchdog:
         self._registry = registry or _registry.get_registry()
         self._warn_interval_s = warn_interval_s
         self._last_warn: dict = {}
-        self._recompiles = self._registry.counter(
+
+    # get-or-create at every use (a compile is rare): a handle kept from
+    # construction would count into nothing after ``Registry.clear()``
+    @property
+    def _recompiles(self):
+        return self._registry.counter(
             "xla_recompiles_total",
             "post-warm-up distinct jit signatures per watched site",
             labelnames=("site",))
-        self._compiles = self._registry.counter(
+
+    @property
+    def _compiles(self):
+        return self._registry.counter(
             "xla_compiled_signatures_total",
             "all distinct jit signatures per watched site (warm-up "
             "included)", labelnames=("site",))
 
-    def enabled(self) -> bool:
-        return os.environ.get(WATCHDOG_ENV, "1") != "0"
-
     def watch(self, fn, name: str, warn: bool = True):
-        """Wrap ``fn``; returns ``fn`` unchanged when the watchdog is
-        disabled.  ``warn=False`` counts signatures without warning
-        (for sites whose shapes vary by design)."""
-        if not self.enabled():
-            return fn
+        """Wrap ``fn``.  ``warn=False`` counts signatures without
+        warning (for sites whose shapes vary by design)."""
         return _Watched(fn, name, warn, self)
 
-    def _on_new_signature(self, watched: _Watched, sig, prev_call_sig,
-                          first: bool):
-        self._compiles.labels(site=watched._name).inc()
-        if first or not watched._warn:
-            # warn=False sites vary by design: their compile population
-            # stays out of the alert counter, which must mean "a hot loop
-            # recompiled unexpectedly" and nothing else
+    def _on_compile(self, watched: _Watched, args, kwargs):
+        """``watched``'s call just made an executable: sign what it was
+        called with (donated leaves keep shape, dtype and weak type) and
+        say which kind of compile it was."""
+        sig = _tree_sig((args, kwargs))
+        site = watched._name
+        if sig not in watched._sigs:
+            self._compiles.labels(site=site).inc()
+            # the site's first compile is warm-up; warn=False sites vary
+            # by design: their compile population stays out of the alert
+            # counter, which must mean "a hot loop recompiled
+            # unexpectedly" and nothing else
+            if watched._sigs and watched._warn:
+                self._recompiles.labels(site=site).inc()
+                if self._should_warn(site):
+                    logger.warning(
+                        f"XLA RECOMPILE in hot loop {site!r}: signature "
+                        f"#{len(watched._sigs) + 1} after warm-up "
+                        f"({_what_changed(watched._sigs, sig, args, kwargs)})"
+                        f". Each recompile stalls the loop for the full "
+                        f"compile time — check for drifting batch/cache "
+                        f"shapes or dtype flips.")
+            watched._sigs.add(sig)
             return
-        self._recompiles.labels(site=watched._name).inc()
-        if not self._should_warn(watched._name):
+        if not watched._settled:
+            # warm-up legitimately compiles per-layout variants of one
+            # signature as eager-built buffers are replaced by committed
+            # jit outputs
             return
-        # diff against the PREVIOUS CALL's signature — the loop that was
-        # actually running — not the last novel one
-        diff = _diff(prev_call_sig, sig)
-        cache_size = ""
-        try:
-            cs = watched._fn._cache_size()
-            cache_size = f"; jit cache held {cs} executable(s) before this call"
-        except Exception:
-            pass
-        detail = diff if diff is not None else \
-            f"arg shapes now [{_describe(sig)}]"
-        logger.warning(
-            f"XLA RECOMPILE in hot loop {watched._name!r}: signature "
-            f"#{len(watched._sigs)} after warm-up ({detail}){cache_size}. "
-            f"Each recompile stalls the loop for the full compile time — "
-            f"check for drifting batch/cache shapes or dtype flips.")
-
-    def _on_hidden_recompile(self, watched: _Watched, cache_size: int):
-        """Executable count grew on an already-known arg signature: the
-        jit cache keys on shardings/layouts too, so the loop recompiled
-        for a reason the shape signature cannot show."""
-        self._compiles.labels(site=watched._name).inc()
+        self._compiles.labels(site=site).inc()
         if not watched._warn:
-            # by-design-varying sites (per-width placement etc.) hit this
-            # legitimately — e.g. an uncommitted initial buffer becoming a
-            # committed jit output; keep them out of the alert counter
             return
-        self._recompiles.labels(site=watched._name).inc()
-        if not self._should_warn(watched._name):
-            return
-        logger.warning(
-            f"XLA RECOMPILE in hot loop {watched._name!r}: executable "
-            f"count grew to {cache_size} with UNCHANGED arg shapes/dtypes "
-            f"— the jit cache also keys on shardings and layouts; check "
-            f"for a resharded params/state tree (e.g. after checkpoint "
-            f"load or a mesh change).")
+        self._recompiles.labels(site=site).inc()
+        if self._should_warn(site):
+            logger.warning(
+                f"XLA RECOMPILE in hot loop {site!r}: a new executable "
+                f"with UNCHANGED arg shapes/dtypes — the jit cache also "
+                f"keys on shardings, layouts and static arguments; check "
+                f"for a resharded params/state tree (e.g. after checkpoint "
+                f"load or a mesh change).")
 
     def _should_warn(self, site: str) -> bool:
         now = time.monotonic()
@@ -313,7 +263,16 @@ _BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
 _FETCH_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
-_compile_tls = threading.local()
+
+
+class _CompileTLS(threading.local):
+    unclaimed = 0  # executables this thread has made (built or fetched)
+                   # that no watched call has taken as its own
+    hit = False    # the executable being made was found in the persistent
+    fetch_s = 0.0  # cache, and fetching it took this long
+
+
+_compile_tls = _CompileTLS()
 _compile_events_installed = False
 
 
@@ -344,10 +303,11 @@ def _on_compile_duration(event: str, secs: float, **kw) -> None:
         tls.fetch_s = secs
         phase = "fetch"
     elif event == _BACKEND_EVENT:
-        fetched = getattr(tls, "hit", False)
-        fetch_s = getattr(tls, "fetch_s", 0.0) if fetched else 0.0
+        fetched = tls.hit
+        fetch_s = tls.fetch_s if fetched else 0.0
         tls.hit, tls.fetch_s = False, 0.0
         how = "fetched" if fetched else "built"
+        tls.unclaimed += 1
         _executables().labels(how=how, span=_span_label()).inc()
         _trace.record("compile/backend", secs, fun=kw.get("fun_name"),
                       how=how)

@@ -42,13 +42,13 @@ def rng():
 
 @pytest.fixture()
 def nominal_cpu_physics(monkeypatch):
-    """The physics tables (telemetry/attribution.py) carry chips only, and
+    """The physics tables (profiling/flops_profiler.py) carry chips only, and
     a device that is not in them is an error.  Tests that drive the
     roofline machinery itself on the CPU mesh add a nominal ``cpu`` row
     here; its numbers mean nothing and never leave the test."""
-    from deepspeed_tpu.telemetry import attribution
+    from deepspeed_tpu.profiling import flops_profiler
 
-    for table, value in ((attribution.PEAK_FLOPS, 1e12),
-                         (attribution.HBM_BYTES_S, 50e9),
-                         (attribution.HBM_BYTES, 8e9)):
+    for table, value in ((flops_profiler.PEAK_FLOPS, 1e12),
+                         (flops_profiler.HBM_BYTES_S, 50e9),
+                         (flops_profiler.HBM_BYTES, 8e9)):
         monkeypatch.setitem(table, "cpu", value)

@@ -193,7 +193,7 @@ def test_ladder_flap_suppression():
 def test_ladder_ignores_non_overload_rules():
     c = _ladder_controller()
     c._on_alert({"rule": "recompile_storm", "state": "firing"})
-    c._on_alert({"rule": "attribution_drift", "state": "firing"})
+    c._on_alert({"rule": "goodput_drop", "state": "firing"})
     assert c.stage == 0 and not c._firing
 
 

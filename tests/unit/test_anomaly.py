@@ -1,97 +1,61 @@
-"""Fast host units for the perf-attribution plane: roofline math +
-anomaly detectors (telemetry/attribution.py, telemetry/anomaly.py).
+"""Fast host units for the device physics table
+(profiling/flops_profiler.py) and the anomaly detectors
+(telemetry/anomaly.py).
 
 Everything here is hand-built series / tiny-jit work — no models, no
 mesh — so the file stays cheap inside the tier-1 window.  The serving
-e2e (CPU-mesh run publishing real attribution rows, induced alert
-storms) lives z-sorted in ``test_zattribution.py``.
+e2e (induced alert storms on a CPU-mesh run) lives z-sorted in
+``test_zattribution.py``.
 """
 import time
 
 import numpy as np
 import pytest
 
-from deepspeed_tpu.telemetry import anomaly, attribution
+from deepspeed_tpu.profiling import flops_profiler
+from deepspeed_tpu.telemetry import anomaly
 from deepspeed_tpu.telemetry import registry as telemetry_registry
 from deepspeed_tpu.telemetry.anomaly import (
-    AcceptanceCollapseDetector, AnomalyEngine, AttributionDriftDetector,
-    Detector, GoodputDropDetector, QueueRunawayDetector,
-    RecompileStormDetector, Series, SloBurnDetector)
+    AcceptanceCollapseDetector, AnomalyEngine, Detector,
+    GoodputDropDetector, QueueRunawayDetector, RecompileStormDetector,
+    Series, SloBurnDetector)
 
 
 # ----------------------------------------------------------------------
-# roofline math
+# device physics: one table, in profiling/flops_profiler.py
 # ----------------------------------------------------------------------
-def test_roofline_compute_bound():
-    # 1e12 flops in 1 s on a 2e12 peak = mfu 0.5; tiny bytes
-    r = attribution.roofline(1e12, 1e9, 1.0, 2e12, 1e12,
-                             overhead_frac=0.1)
-    assert r["verdict"] == "compute-bound"
-    assert r["mfu"] == pytest.approx(0.5)
-    assert r["bw_frac"] == pytest.approx(1e9 / 1e12)
-
-
-def test_roofline_hbm_bound():
-    r = attribution.roofline(1e9, 8e11, 1.0, 2e12, 1e12,
-                             overhead_frac=0.1)
-    assert r["verdict"] == "hbm-bound"
-    assert r["bw_frac"] == pytest.approx(0.8)
-
-
-def test_roofline_overhead_bound():
-    # neither roof within 10% of explaining the time
-    r = attribution.roofline(1e9, 1e9, 1.0, 2e12, 1e12,
-                             overhead_frac=0.1)
-    assert r["verdict"] == "overhead-bound"
-    assert max(r["mfu"], r["bw_frac"]) < 0.1
-
-
-def test_roofline_tie_goes_to_hbm():
-    # equal fractions: streaming is the actionable bound
-    r = attribution.roofline(1e12, 5e11, 1.0, 2e12, 1e12,
-                             overhead_frac=0.1)
-    assert r["mfu"] == pytest.approx(r["bw_frac"])
-    assert r["verdict"] == "hbm-bound"
-
-
 def test_device_tables_shared_and_chips_only():
-    # bench.py/flops_profiler/autotuner read THESE tables; they carry
-    # chips only, and a device that is not in them is an error
+    # bench.py and the autotuner read THESE tables (both import the
+    # module); they carry chips only
     import jax
 
-    from deepspeed_tpu.profiling import flops_profiler
+    from deepspeed_tpu.autotuning import autotuner
 
-    assert flops_profiler.PEAK_TFLOPS is attribution.PEAK_FLOPS
-    for table in (attribution.PEAK_FLOPS, attribution.HBM_BYTES_S,
-                  attribution.HBM_BYTES):
+    assert autotuner.flops_profiler is flops_profiler
+    for table in (flops_profiler.PEAK_FLOPS, flops_profiler.HBM_BYTES_S,
+                  flops_profiler.HBM_BYTES):
         assert "cpu" not in table
-    cpu = jax.devices()[0]
-    assert not attribution.device_known(cpu)
-    for lookup in (attribution.device_peak_flops,
-                   attribution.device_hbm_bytes_s,
-                   attribution.device_hbm_bytes):
-        with pytest.raises(ValueError, match="device_kind"):
-            lookup(cpu)
-        with pytest.raises(ValueError, match="device_kind"):
-            lookup()            # device 0 of the CPU mesh
+        assert set(table) == set(flops_profiler.PEAK_FLOPS)
+    assert not flops_profiler.device_known(jax.devices()[0])
 
 
-def test_unknown_device_rows_carry_no_roofline():
-    plane = attribution.AttributionPlane()
-    plane.note_costs("s.a", flops=2e9, hbm_bytes=4e8)
-    plane.note_measured("s.a", 0.010)
-    snap = plane.snapshot()
-    assert snap["peak_flops"] is None and snap["hbm_bytes_s"] is None
-    (row,) = snap["rows"]
-    assert row["verdict"] == "unknown-device"
-    assert row["mfu"] is None and row["bw_frac"] is None
-    assert plane.verdicts() == {}
+@pytest.mark.parametrize("lookup", ["device_peak_flops",
+                                    "device_hbm_bytes_s",
+                                    "device_hbm_bytes"])
+def test_device_not_in_the_tables_is_an_error(lookup):
+    import jax
+
+    lookup = getattr(flops_profiler, lookup)
+    with pytest.raises(ValueError, match="device_kind"):
+        lookup(jax.devices()[0])
+    with pytest.raises(ValueError, match="device_kind"):
+        lookup()            # device 0 of the CPU mesh
 
 
 def test_decode_stream_floor_hand_math(nominal_cpu_physics):
     params = {"w": np.zeros((10, 10), np.float32)}        # 400 B
     slot_cache = {"k": np.zeros((4, 8), np.float32)}      # 128 B
-    d = attribution.decode_stream_floor(params, slot_cache, n_slots=2,
+    d = flops_profiler.decode_stream_floor(params, slot_cache, n_slots=2,
                                         dev=None)
     assert d["weight_stream_bytes"] == 400
     assert d["kv_stream_bytes_per_tick"] == 256
@@ -104,110 +68,10 @@ def test_harvest_costs_real_compiled():
     import jax.numpy as jnp
 
     c = jax.jit(lambda x: x @ x).lower(jnp.ones((32, 32))).compile()
-    costs = attribution.harvest_costs(c)
+    costs = flops_profiler.harvest_costs(c)
     assert costs is not None
     assert costs["flops"] > 0
     assert costs["bytes_accessed"] > 0
-
-
-# ----------------------------------------------------------------------
-# attribution plane
-# ----------------------------------------------------------------------
-def test_plane_snapshot_self_consistent(nominal_cpu_physics):
-    plane = attribution.AttributionPlane()
-    plane.note_costs("s.a", flops=2e9, hbm_bytes=4e8)
-    plane.note_measured("s.a", 0.010)        # 10 ms
-    snap = plane.snapshot()
-    (row,) = snap["rows"]
-    assert row["site"] == "s.a"
-    assert row["measured_ms"] == pytest.approx(10.0)
-    # self-consistency: the row's fractions recompute from its own
-    # fields and the snapshot's physics
-    assert row["mfu"] == pytest.approx(
-        row["flops"] / (row["measured_ms"] / 1e3 * snap["peak_flops"]),
-        rel=1e-4)
-    assert row["bw_frac"] == pytest.approx(
-        row["hbm_bytes"] / (row["measured_ms"] / 1e3 * snap["hbm_bytes_s"]),
-        rel=1e-4)
-    assert row["verdict"] in ("compute-bound", "hbm-bound",
-                              "overhead-bound")
-
-
-def test_plane_unmeasured_and_uninstrumented_rows():
-    plane = attribution.AttributionPlane()
-    plane.note_costs("cost.only", flops=1.0, hbm_bytes=1.0)
-    plane.note_measured("time.only", 0.001)
-    by_site = {r["site"]: r for r in plane.snapshot()["rows"]}
-    assert by_site["cost.only"]["verdict"] == "unmeasured"
-    assert by_site["time.only"]["verdict"] == "uninstrumented"
-    # measured rows only in the drift-detector input
-    assert plane.verdicts() == {}
-
-
-def test_plane_should_sample_cadence(monkeypatch):
-    monkeypatch.setenv(attribution.SAMPLE_ENV, "4")
-    plane = attribution.AttributionPlane()
-    hits = [plane.should_sample("s") for _ in range(9)]
-    assert hits == [True, False, False, False, True, False, False,
-                    False, True]
-
-
-def test_plane_enable_overrides_env(monkeypatch):
-    monkeypatch.delenv(attribution.ATTRIBUTION_ENV, raising=False)
-    plane = attribution.AttributionPlane()
-    assert not plane.enabled()
-    plane.enable(True)
-    assert plane.enabled()
-    plane.enable(None)
-    monkeypatch.setenv(attribution.ATTRIBUTION_ENV, "1")
-    assert plane.enabled()
-    monkeypatch.setenv(attribution.ATTRIBUTION_ENV, "0")
-    assert not plane.enabled()
-
-
-def test_should_record_skips_first_without_watchdog_signal():
-    plane = attribution.AttributionPlane()
-    # watchdog disabled ⇒ no signatures_seen: the first sampled call
-    # per site (the one that pays the XLA compile) is skipped, later
-    # ones record — compile wall must never become measured_ms
-    assert not plane._should_record("s", object(), None)
-    assert plane._should_record("s", object(), None)
-
-    # with signature visibility: record iff the call didn't compile
-    class _Fn:
-        signatures_seen = 3
-
-    fn = _Fn()
-    assert plane._should_record("t", fn, 3)
-    fn.signatures_seen = 4
-    assert not plane._should_record("t", fn, 3)
-
-
-def test_note_window_records_and_harvests_after_steady():
-    import jax
-    import jax.numpy as jnp
-
-    plane = attribution.AttributionPlane()
-    fn = jax.jit(lambda x: x @ x)
-    x = jnp.ones((16, 16))
-    fn(x)         # warm
-    # steady window (no sigs available → first skipped, second records
-    # AND lazily harvests costs from the warm executable)
-    assert not plane.note_window("w", 0.001, fn, None, (x,))
-    assert plane.note_window("w", 0.001, fn, None, (x,))
-    (row,) = plane.snapshot()["rows"]
-    assert row["flops"] > 0 and row["measured_ms"] is not None
-    assert row["costs_src"] == "lazy"
-
-
-def test_plane_median_washes_out_one_outlier():
-    plane = attribution.AttributionPlane()
-    plane.note_costs("s", flops=1e9, hbm_bytes=1e9)
-    plane.note_measured("s", 2.0)            # one 2 s outlier
-    for _ in range(8):
-        plane.note_measured("s", 0.004)
-    (row,) = plane.snapshot()["rows"]
-    assert row["measured_ms"] == pytest.approx(4.0)
 
 
 # ----------------------------------------------------------------------
@@ -383,28 +247,6 @@ def test_goodput_drop_waits_for_warmup():
     eng.series["goodput_wall"].add(1.0, 200.0)
     evs = eng.observe(now=1.0, force=True)
     assert [e["rule"] for e in evs] == ["goodput_drop"]
-
-
-def test_attribution_drift_pulses_per_flip(monkeypatch, nominal_cpu_physics):
-    plane = attribution.AttributionPlane()
-    monkeypatch.setattr(attribution, "_default", plane)
-    plane.note_costs("s.x", flops=1e15, hbm_bytes=1.0)
-    plane.note_measured("s.x", 0.001)          # huge mfu: compute-bound
-    det = AttributionDriftDetector()
-    eng = _NoSampleEngine(detectors=[det])
-    assert eng.observe(now=0.0, force=True) == []     # baseline learn
-    # flops drop 6 orders: the verdict flips to overhead-bound
-    plane.note_costs("s.x", flops=1e6, hbm_bytes=1.0)
-    plane.note_measured("s.x", 0.001)
-    evs = eng.observe(now=1.0, force=True)
-    assert len(evs) == 1
-    assert evs[0]["rule"] == "attribution_drift"
-    assert evs[0]["detail"]["site"] == "s.x"
-    assert evs[0]["detail"]["from"] == "compute-bound"
-    assert evs[0]["detail"]["to"] == "overhead-bound"
-    # pulse semantics: never active, no repeat without another flip
-    assert eng.active() == {}
-    assert eng.observe(now=2.0, force=True) == []
 
 
 # ----------------------------------------------------------------------

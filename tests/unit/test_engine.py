@@ -366,3 +366,88 @@ def test_grad_accum_dtype_bf16():
         Config.from_dict({"train_micro_batch_size_per_gpu": 1,
                           "fp16": {"enabled": True},
                           "data_types": {"grad_accum_dtype": "bf16"}})
+
+
+# ----------------------------------------------------------------------
+# every step program carries one observer (telemetry/recompile.py)
+# ----------------------------------------------------------------------
+def _tiny_lm_engine(optimizer):
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+    return make_engine({"train_micro_batch_size_per_gpu": 1,
+                        "optimizer": optimizer,
+                        "zero_optimization": {"stage": 0},
+                        "mesh": {"dp": 8}, "steps_per_print": 10**6},
+                       model=GPT2LMHeadModel(
+                           gpt2_config("gpt2-tiny", scan_layers=True)))
+
+
+def _three_call(engine, batch):
+    engine.forward(batch)
+    engine.backward()
+    engine.step()
+
+
+def _train_then_eval(engine, batch):
+    engine.train_batch(batch)       # eval reads a jit output's params
+    engine.eval_batch(batch)
+
+
+# name -> (engine, one step, the sites the step calls)
+_STEP_PROGRAMS = {
+    "train_step": (make_engine, lambda e, b: e.train_batch(b),
+                   ["engine.train_step"]),
+    "multi_step_window": (
+        make_engine, lambda e, b: e.train_batches(b, 2, stacked=False),
+        ["engine.multi_step[2]"]),
+    "eval": (make_engine, _train_then_eval,
+             ["engine.train_step", "engine.eval_step"]),
+    "grad_and_apply": (make_engine, _three_call,
+                       ["engine.grad_step", "engine.apply_step"]),
+    "host_offload_grads": (
+        lambda: make_engine({
+            "optimizer": {"type": "adamw", "params": {"lr": 1e-2}},
+            "zero_optimization": {"stage": 2, "offload_optimizer":
+                                  {"device": "cpu"}}}),
+        lambda e, b: e.train_batch(b), ["engine.grads_only"]),
+    "onebit": (
+        lambda: _tiny_lm_engine({"type": "onebitadam", "params": {
+            "lr": 1e-3, "freeze_step": 1, "comm_backend": "compressed"}}),
+        lambda e, b: e.train_batch(b), ["engine.train_step"]),
+}
+
+
+@pytest.mark.parametrize("program", list(_STEP_PROGRAMS))
+def test_step_program_compiles_once_on_a_donated_state(program):
+    """Three steps of each step program, the state donated and replaced
+    every step: its sites count one warm-up compile and no recompile,
+    and after the first step no span of the step makes an executable."""
+    from deepspeed_tpu.telemetry import registry as telemetry_registry
+
+    def by(metric, label, keep):
+        snap = telemetry_registry.get_registry().snapshot().get(metric)
+        return {} if snap is None else {
+            s["labels"][label]: s["value"] for s in snap["samples"]
+            if keep(s["labels"][label])}
+
+    def made_in_step_spans():
+        return sum(by("xla_executables_total", "span", lambda span: span in (
+            "train/dispatch", "train/apply-step", "eval/dispatch")).values())
+
+    build, step, sites = _STEP_PROGRAMS[program]
+    compiled0 = by("xla_compiled_signatures_total", "site", sites.__contains__)
+    recompiled0 = by("xla_recompiles_total", "site", sites.__contains__)
+    engine = build()
+    batch = token_batch(engine.train_batch_size, 32, 512, seed=0) \
+        if program == "onebit" else batch_for(engine)
+    step(engine, batch)
+    made = made_in_step_spans()
+    assert made > 0                 # the first step compiled in its spans
+    step(engine, batch)
+    step(engine, batch)
+    assert made_in_step_spans() == made
+    compiled = by("xla_compiled_signatures_total", "site", sites.__contains__)
+    assert {s: compiled[s] - compiled0.get(s, 0) for s in sites} \
+        == dict.fromkeys(sites, 1)
+    assert by("xla_recompiles_total", "site", sites.__contains__) \
+        == recompiled0

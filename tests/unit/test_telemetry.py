@@ -489,26 +489,65 @@ def _site_value(registry, metric, site):
     return c.labels(site=site).value
 
 
-def test_watchdog_counts_forced_shape_change_exactly_once():
-    reg = Registry()
-    dog = recompile.RecompileWatchdog(registry=reg)
-    f = dog.watch(jax.jit(lambda x: x + 1), "unit.step")
-    f(jnp.zeros((4,), jnp.float32))          # warm-up compile
-    assert _site_value(reg, "xla_recompiles_total", "unit.step") == 0
-    f(jnp.zeros((8,), jnp.float32))          # forced shape change
-    assert _site_value(reg, "xla_recompiles_total", "unit.step") == 1
-    f(jnp.zeros((8,), jnp.float32))          # now-known signature
-    f(jnp.zeros((4,), jnp.float32))
-    assert _site_value(reg, "xla_recompiles_total", "unit.step") == 1
+def _replicated_and_sharded():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()), ("d",))
+    x = np.zeros((len(jax.devices()) * 2,), np.float32)
+    return (jax.device_put(x, NamedSharding(mesh, P())),
+            jax.device_put(x, NamedSharding(mesh, P("d"))))
 
 
-def test_watchdog_counts_dtype_change():
+def _cause_shape():
+    return (jax.jit(lambda x: x + 1),
+            (jnp.zeros((4,), jnp.float32),), (jnp.zeros((8,), jnp.float32),))
+
+
+def _cause_dtype():
+    return (jax.jit(lambda x: x + 1),
+            (jnp.zeros((4,), jnp.float32),), (jnp.zeros((4,), jnp.int32),))
+
+
+def _cause_weak_type():
+    # the python float that became an array: jnp.asarray(1.0) is a
+    # weakly typed float32, jnp.float32(1.0) a strongly typed one
+    return (jax.jit(lambda x: x * 2),
+            (jnp.float32(1.0),), (jnp.asarray(1.0),))
+
+
+def _cause_static_argument():
+    x = jnp.zeros((4,), jnp.float32)
+    return (jax.jit(lambda x, n: x * n, static_argnums=1), (x, 2), (x, 3))
+
+
+def _cause_sharding():
+    replicated, sharded = _replicated_and_sharded()
+    return jax.jit(lambda x: x + 1), (replicated,), (sharded,)
+
+
+@pytest.mark.parametrize("cause", [
+    _cause_shape, _cause_dtype, _cause_weak_type, _cause_static_argument,
+    _cause_sharding], ids=lambda c: c.__name__[len("_cause_"):])
+def test_watchdog_counts_a_recompile_by_cause(cause):
+    """Whatever made the warm loop compile again, it is one recompile:
+    a new signature (shape, dtype, weak type), or a known one that the
+    jit cache keys further (a static argument's value, an input's
+    sharding)."""
+    fn, first, changed = cause()
     reg = Registry()
-    dog = recompile.RecompileWatchdog(registry=reg)
-    f = dog.watch(jax.jit(lambda x: x + 1), "unit.dtype")
-    f(jnp.zeros((4,), jnp.float32))
-    f(jnp.zeros((4,), jnp.int32))
-    assert _site_value(reg, "xla_recompiles_total", "unit.dtype") == 1
+    f = recompile.RecompileWatchdog(registry=reg).watch(fn, "unit.cause")
+    f(*first)                                # warm-up compile
+    f(*first)                                # steady: the site settles
+    assert _site_value(reg, "xla_recompiles_total", "unit.cause") == 0
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.cause") == 1
+    f(*changed)                              # the forced change
+    assert _site_value(reg, "xla_recompiles_total", "unit.cause") == 1
+    f(*changed)                              # now-known executables
+    f(*first)
+    assert _site_value(reg, "xla_recompiles_total", "unit.cause") == 1
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.cause") == 2
 
 
 def test_watchdog_silent_on_stable_loop():
@@ -542,47 +581,168 @@ def test_watchdog_wrapper_is_transparent():
     assert w.lower(jnp.float32(1)) is not None     # attr passthrough
 
 
-def test_watchdog_cache_size_cross_check():
-    """Executable-count growth with UNCHANGED arg shapes (the
-    sharding/layout-keyed recompile class the host signature cannot see)
-    is counted via the post-call ``_cache_size`` cross-check."""
+def test_watchdog_known_signature_compiling_before_the_site_settles():
+    """A real ``jax.jit`` whose input sharding changes at unchanged
+    shapes BEFORE the site has had a call that compiled nothing is
+    warm-up churn (eager-built buffers replaced by committed jit
+    outputs), not a recompile; by-cause[sharding] holds the same change
+    after the site settled."""
+    replicated, sharded = _replicated_and_sharded()
     reg = Registry()
-    dog = recompile.RecompileWatchdog(registry=reg)
+    f = recompile.RecompileWatchdog(registry=reg).watch(
+        jax.jit(lambda x: x + 1), "unit.warmup")
+    f(replicated)
+    f(sharded)                  # second executable, same signature
+    assert f._cache_size() == 2
+    f(sharded)
+    f(replicated)
+    assert _site_value(reg, "xla_recompiles_total", "unit.warmup") == 0
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.warmup") == 1
 
-    class Stub:
-        cs = 1
 
-        def __call__(self, x):
-            return x
+def test_watchdog_steady_call_signs_nothing(monkeypatch):
+    """The cost the watchdog used to have: a steady call through it
+    flattens no tree and asks no ``_cache_size()``, however many leaves
+    the arguments have."""
+    signed, cache_size_asked = [], []
+    real_sig = recompile._tree_sig
+    monkeypatch.setattr(recompile, "_tree_sig",
+                        lambda tree: signed.append(1) or real_sig(tree))
+
+    class Jit:
+        fn = staticmethod(jax.jit(
+            lambda tree, x: x + sum(tree[k] for k in ("p0", "p2999"))))
+
+        def __call__(self, *args):
+            return self.fn(*args)
 
         def _cache_size(self):
-            return self.cs
+            cache_size_asked.append(1)
+            return self.fn._cache_size()
 
-    stub = Stub()
-    f = dog.watch(stub, "unit.hidden")
-    f(jnp.zeros((4,)))                     # warm-up: baseline cs=1
-    f(jnp.zeros((4,)))                     # stable call → site settles
-    assert _site_value(reg, "xla_recompiles_total", "unit.hidden") == 0
-    stub.cs = 2
-    f(jnp.zeros((4,)))                     # same signature, cache grew
-    assert _site_value(reg, "xla_recompiles_total", "unit.hidden") == 1
-    f(jnp.zeros((4,)))                     # stable again
-    assert _site_value(reg, "xla_recompiles_total", "unit.hidden") == 1
-    # pre-settle growth (warm-up layout churn) is never counted
-    dog2 = recompile.RecompileWatchdog(registry=reg)
-    stub2 = Stub()
-    g = dog2.watch(stub2, "unit.warmup")
-    stub2.cs = 1
+    tree = {f"p{i}": jnp.float32(i) for i in range(3000)}
+    reg = Registry()
+    f = recompile.RecompileWatchdog(registry=reg).watch(Jit(), "unit.steady")
+    assert float(f(tree, jnp.float32(1))) == 3000.0
+    assert len(signed) == 1                  # the call that compiled
+    for i in range(5):
+        f(tree, jnp.float32(i))
+    assert len(signed) == 1 and not cache_size_asked
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.steady") == 1
+
+
+def test_watchdog_compile_on_another_thread_is_not_this_sites():
+    import threading
+
+    def compile_elsewhere(x):
+        t = threading.Thread(
+            target=lambda: jax.jit(lambda y: y * 3)(jnp.zeros((5,))))
+        t.start()
+        t.join()
+        return x
+
+    reg = Registry()
+    dog = recompile.RecompileWatchdog(registry=reg)
+    f = dog.watch(compile_elsewhere, "unit.other_thread")
+    f(jnp.zeros((4,)))
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.other_thread") == 0
+    # the same compile on the calling thread is this site's
+    g = dog.watch(lambda x: jax.jit(lambda y: y * 5)(x), "unit.same_thread")
     g(jnp.zeros((4,)))
-    stub2.cs = 2
-    g(jnp.zeros((4,)))                     # growth before any stable call
-    assert _site_value(reg, "xla_recompiles_total", "unit.warmup") == 0
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.same_thread") == 1
 
 
-def test_watchdog_env_disable(monkeypatch):
-    monkeypatch.setenv(recompile.WATCHDOG_ENV, "0")
-    f = jax.jit(lambda x: x)
-    assert recompile.watch(f, "unit.disabled") is f
+def test_watchdog_nested_calls_bill_the_inner_site():
+    reg = Registry()
+    dog = recompile.RecompileWatchdog(registry=reg)
+    inner = dog.watch(jax.jit(lambda x: x + 1), "unit.inner")
+    passes_through = dog.watch(lambda x: inner(x), "unit.outer")
+    own = jax.jit(lambda x: x * 2)
+    compiles_too = dog.watch(lambda x: own(inner(x)), "unit.outer_own")
+    passes_through(jnp.zeros((4,)))
+    passes_through(jnp.zeros((4,)))
+    passes_through(jnp.zeros((8,)))          # inner recompiles
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.inner") == 2
+    assert _site_value(reg, "xla_recompiles_total", "unit.inner") == 1
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.outer") == 0
+    compiles_too(jnp.zeros((16,)))           # one executable each
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.inner") == 3
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.outer_own") == 1
+
+
+def test_watchdog_warning_names_the_leaf_that_changed(monkeypatch):
+    warned = []
+    monkeypatch.setattr(recompile.logger, "warning", warned.append)
+    dog = recompile.RecompileWatchdog(registry=Registry())
+    f = dog.watch(jax.jit(lambda batch, step: batch["ids"].sum() + step),
+                  "unit.named")
+    small = {"ids": jnp.zeros((2, 8), jnp.int32),
+             "mask": jnp.ones((2, 8), jnp.int32)}
+    other = {"ids": jnp.zeros((4, 8), jnp.int32),
+             "mask": jnp.ones((4, 8), jnp.int32)}
+    ragged = {"ids": jnp.zeros((2, 8), jnp.int32),
+              "mask": jnp.ones((2, 5), jnp.int32)}
+    f(small, jnp.int32(0))
+    f(other, jnp.int32(1))
+    assert len(warned) == 1 and "+1 more" in warned[0]
+    dog._last_warn.clear()                   # past the rate limit
+    f(ragged, jnp.int32(2))
+    # diffed against the NEAREST compiled signature (small: one leaf
+    # differs), not the last one (other: both differ)
+    assert len(warned) == 2
+    assert "unit.named" in warned[1] and "['mask']" in warned[1]
+    assert "(2, 8)" in warned[1] and "(2, 5)" in warned[1]
+    assert "more" not in warned[1]
+
+
+def test_watchdog_signs_a_donated_state(monkeypatch):
+    """The arguments are signed AFTER the call that compiled, when a
+    donated state's buffers are gone: shape, dtype and weak type survive
+    donation, so the loop below is one signature, and the state that
+    grew is named with the shapes it had."""
+    warned = []
+    monkeypatch.setattr(recompile.logger, "warning", warned.append)
+    reg = Registry()
+    f = recompile.RecompileWatchdog(registry=reg).watch(
+        jax.jit(lambda s, b: ({"w": s["w"] + b, "n": s["n"] + 1}, b.sum()),
+                donate_argnums=(0,)), "unit.donated")
+    state = {"w": jnp.zeros((4,), jnp.bfloat16), "n": jnp.int32(0)}
+    for _ in range(3):
+        donated = state
+        state, _ = f(state, jnp.ones((4,), jnp.bfloat16))
+    assert donated["w"].is_deleted()
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.donated") == 1
+    assert _site_value(reg, "xla_recompiles_total", "unit.donated") == 0
+    grown = {"w": jnp.zeros((8,), jnp.bfloat16), "n": jnp.int32(0)}
+    f(grown, jnp.ones((8,), jnp.bfloat16))
+    assert grown["w"].is_deleted()
+    assert _site_value(reg, "xla_recompiles_total", "unit.donated") == 1
+    assert "['w']: ((4,), 'bfloat16', False) -> ((8,), 'bfloat16', False)" \
+        in warned[0]
+
+
+def test_watchdog_bills_the_compiling_call_to_goodput():
+    from deepspeed_tpu.telemetry import goodput
+
+    f = recompile.watch(jax.jit(lambda x: jnp.tanh(x) @ x.T),
+                        "unit.goodput")
+    x = jnp.ones((16, 16))
+    before = goodput.summary()["recompile_s"]
+    f(x)
+    compiled = goodput.summary()["recompile_s"]
+    assert compiled > before
+    for _ in range(3):
+        f(x)
+    assert goodput.summary()["recompile_s"] == compiled
 
 
 # ----------------------------------------------------------------------

@@ -1,15 +1,15 @@
-"""CPU-mesh e2e for the perf-attribution plane (z-sorted: heavy model
-work stays out of the tier-1 870s window per the repo convention).
+"""CPU-mesh e2e for the alert plane and the server's watched sites
+(z-sorted: heavy model work stays late in the run per the repo
+convention).
 
-Covers the acceptance criteria: a serving run under
-``DSTPU_ATTRIBUTION=1`` publishes per-executable attribution rows with
-self-consistent ``mfu``/``bw_frac`` and bound-class verdicts; the
-``/profilez`` and ``/alertz`` endpoints serve them; an induced
-recompile storm and an induced SLO burn each raise exactly one
-structured alert; and the flight dump embeds what was slow and what
-was firing.
+An induced recompile storm and an induced SLO burn each raise exactly
+one structured alert; ``/alertz`` serves them and ``/profilez`` is gone;
+the flight dump embeds what was firing; and every compiled call of a
+warmed server carries one observer that counts one warm-up compile a
+site, no recompile, and no executable once the shapes have been seen.
 """
 import json
+import urllib.error
 import urllib.request
 
 import jax
@@ -19,25 +19,9 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.inference.serving import ContinuousBatcher
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
-from deepspeed_tpu.telemetry import (anomaly, attribution, flightrec,
-                                     recompile)
+from deepspeed_tpu.telemetry import anomaly, flightrec, recompile
 from deepspeed_tpu.telemetry import registry as telemetry_registry
 from deepspeed_tpu.telemetry.exporter import TelemetryExporter
-
-VERDICTS = ("compute-bound", "hbm-bound", "overhead-bound")
-
-
-@pytest.fixture
-def fresh_plane(monkeypatch, nominal_cpu_physics):
-    """A private attribution plane, sampled every window, enabled —
-    swapped in for the module singleton so process-wide state from
-    other tests can't leak into row assertions.  Rows are judged against
-    the nominal CPU row (the tables themselves carry chips only)."""
-    monkeypatch.setenv(attribution.SAMPLE_ENV, "1")
-    plane = attribution.AttributionPlane()
-    plane.enable(True)
-    monkeypatch.setattr(attribution, "_default", plane)
-    yield plane
 
 
 @pytest.fixture(autouse=True)
@@ -52,7 +36,7 @@ def _fresh_anomaly(monkeypatch):
     yield
 
 
-def _build_batcher(n_slots=2, max_tokens=64):
+def _build_batcher(n_slots=2, max_tokens=64, **kw):
     cfg = gpt2_config("gpt2-tiny")
     model = GPT2LMHeadModel(cfg)
     params = jax.tree_util.tree_map(
@@ -60,9 +44,11 @@ def _build_batcher(n_slots=2, max_tokens=64):
         model.init(jax.random.PRNGKey(0),
                    np.zeros((1, 8), np.int32))["params"],
         is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    paged = {"prefix_cache": {"page_tokens": 8, "n_pages": 64}} \
+        if kw.get("paged_decode") else {}
     eng = deepspeed_tpu.init_inference(model=model, params=params,
-                                       max_tokens=max_tokens)
-    return ContinuousBatcher(eng, n_slots=n_slots), cfg
+                                       max_tokens=max_tokens, **paged)
+    return ContinuousBatcher(eng, n_slots=n_slots, **kw), cfg
 
 
 def _run_some(batcher, cfg, n=6, new=8, ticks=4, seed=0, **kw):
@@ -72,57 +58,23 @@ def _run_some(batcher, cfg, n=6, new=8, ticks=4, seed=0, **kw):
     return batcher.run(prompts, max_new_tokens=new, ticks=ticks, **kw)
 
 
-def test_serving_publishes_selfconsistent_rows(fresh_plane):
-    batcher, cfg = _build_batcher()
-    batcher.warmup_windows(4)
-    _run_some(batcher, cfg)
-    snap = fresh_plane.snapshot()
-    rows = snap["rows"]
-    # AOT compile points alone give a broad cost table: decode windows,
-    # first_token/place admission fns, retire
-    sites = {r["site"] for r in rows}
-    assert any(s.startswith("serving.decode[") for s in sites)
-    assert "serving.retire" in sites
-    assert any(s.startswith("serving.first_token[") for s in sites)
-    measured = [r for r in rows if r["measured_ms"] is not None
-                and r["verdict"] in VERDICTS]
-    assert measured, f"no measured verdict rows in {sites}"
-    for r in measured:
-        # every measured row carries the full tuple and its fractions
-        # recompute from its own fields + the snapshot's physics
-        assert r["flops"] > 0 and r["hbm_bytes"] > 0
-        assert r["mfu"] == pytest.approx(
-            r["flops"] / (r["measured_ms"] / 1e3 * snap["peak_flops"]),
-            rel=1e-3)
-        assert r["bw_frac"] == pytest.approx(
-            r["hbm_bytes"] / (r["measured_ms"] / 1e3
-                              * snap["hbm_bytes_s"]), rel=1e-3)
-    # the decode window must be among the measured rows (the hot path)
-    assert any(r["site"].startswith("serving.decode[") for r in measured)
-    # prefill chunks were sampled via the lazy harvest path
-    assert any(r["site"].startswith("serving.prefill[") for r in measured)
-
-
-def test_profilez_and_alertz_endpoints(fresh_plane):
+def test_profilez_and_alertz_endpoints():
     batcher, cfg = _build_batcher()
     batcher.warmup_windows(2)
     _run_some(batcher, cfg, n=4)
     exp = TelemetryExporter(port=0).start()
     try:
-        with urllib.request.urlopen(f"{exp.url}/profilez", timeout=10) as r:
-            prof = json.load(r)
-        assert prof["enabled"] is True
-        assert prof["rows"] and any(
-            row["measured_ms"] is not None for row in prof["rows"])
+        with pytest.raises(urllib.error.HTTPError) as gone:
+            urllib.request.urlopen(f"{exp.url}/profilez", timeout=10)
+        assert gone.value.code == 404
         with urllib.request.urlopen(f"{exp.url}/alertz", timeout=10) as r:
             alerts = json.load(r)
         assert set(alerts) == {"active", "recent", "rules"}
         assert "recompile_storm" in alerts["rules"]
-        # /statusz carries the compact sections too
+        assert "attribution_drift" not in alerts["rules"]
         with urllib.request.urlopen(f"{exp.url}/statusz", timeout=10) as r:
             statusz = json.load(r)
-        assert "attribution" in statusz and "alerts" in statusz
-        assert statusz["attribution"]["measured"] >= 1
+        assert "alerts" in statusz and "attribution" not in statusz
     finally:
         exp.stop()
 
@@ -152,7 +104,7 @@ def test_induced_recompile_storm_raises_exactly_one_alert():
     assert "recompile_storm" in eng.active()
 
 
-def test_induced_slo_burn_raises_alert(fresh_plane):
+def test_induced_slo_burn_raises_alert():
     batcher, cfg = _build_batcher()
     # SLO bounds no real request can meet: every retirement violates
     batcher.set_slo(ttft_ms=0.0001, tpot_ms=0.0001)
@@ -167,8 +119,7 @@ def test_induced_slo_burn_raises_alert(fresh_plane):
     assert fires[0]["detail"]["events"] >= 4
 
 
-def test_flight_dump_carries_attribution_and_alerts(
-        fresh_plane, monkeypatch, tmp_path):
+def test_flight_dump_carries_alerts(monkeypatch, tmp_path):
     batcher, cfg = _build_batcher()
     batcher.warmup_windows(2)
     _run_some(batcher, cfg, n=4)
@@ -191,24 +142,84 @@ def test_flight_dump_carries_attribution_and_alerts(
     assert path is not None
     payload = json.load(open(path))
     assert payload["alerts"]["active"][0]["rule"] == "recompile_storm"
-    rows = payload["attribution"]["rows"]
-    assert any(r["measured_ms"] is not None for r in rows)
-    # the postmortem renderer answers "what was slow and what was
-    # firing" in text
+    assert "attribution" not in payload
+    # the postmortem renderer answers "what was firing" in text
     text = flightrec.pretty(path)
     assert "ACTIVE alerts at dump" in text
     assert "recompile_storm" in text
-    assert "attribution (measured executables" in text
 
 
-def test_attribution_off_is_default_and_rowless(monkeypatch):
-    monkeypatch.delenv(attribution.ATTRIBUTION_ENV, raising=False)
-    plane = attribution.AttributionPlane()
-    monkeypatch.setattr(attribution, "_default", plane)
-    batcher, cfg = _build_batcher()
-    _run_some(batcher, cfg, n=2, new=4, ticks=2)
-    assert not plane.enabled()
-    # no sampling hooks ran: no measured rows (warmup wasn't called so
-    # no AOT rows either — the plane is fully passive)
-    assert all(r["measured_ms"] is None
-               for r in plane.snapshot()["rows"])
+# ----------------------------------------------------------------------
+# the server's watched sites
+# ----------------------------------------------------------------------
+def _by_site(metric):
+    snap = telemetry_registry.get_registry().snapshot().get(metric)
+    return {} if snap is None else {
+        s["labels"]["site"]: s["value"] for s in snap["samples"]}
+
+
+def _serve_executables():
+    snap = telemetry_registry.get_registry().snapshot().get(
+        "xla_executables_total")
+    return 0 if snap is None else sum(
+        s["value"] for s in snap["samples"]
+        if s["labels"]["span"].startswith("serve/"))
+
+
+def _moved(after, before):
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+@pytest.fixture(scope="module")
+def served():
+    """Each server kind once: warm it up, serve one round (every site's
+    warm-up compile), then a second round of other prompts at the same
+    shapes.  Returns, a kind, what each round moved."""
+    out = {}
+    for kind in ("contiguous", "paged"):
+        before = (_by_site("xla_compiled_signatures_total"),
+                  _by_site("xla_recompiles_total"))
+        batcher, cfg = _build_batcher(paged_decode=(kind == "paged"))
+        batcher.warmup_windows(4)
+        _run_some(batcher, cfg, n=6, seed=1)
+        first = (_by_site("xla_compiled_signatures_total"),
+                 _by_site("xla_recompiles_total"), _serve_executables())
+        _run_some(batcher, cfg, n=6, seed=2)
+        out[kind] = {
+            "warmup": _moved(first[0], before[0]),
+            "recompiled": _moved(first[1], before[1]),
+            "steady_signatures": _moved(
+                _by_site("xla_compiled_signatures_total"), first[0]),
+            "steady_recompiled": _moved(
+                _by_site("xla_recompiles_total"), first[1]),
+            "steady_executables": _serve_executables() - first[2]}
+    return out
+
+
+@pytest.mark.parametrize("kind,site,varies_by_width", [
+    ("contiguous", "serving.decode[", False),
+    ("contiguous", "serving.place", True),
+    ("contiguous", "serving.retire", False),
+    ("contiguous", "serving.first_token", True),
+    ("paged", "serving.decode_paged[", False),
+    ("paged", "serving.place_paged", True),
+    ("paged", "serving.retire_paged", False),
+    ("paged", "serving.first_token", True),
+])
+def test_warmed_server_site_compiles_once_and_never_again(
+        served, kind, site, varies_by_width):
+    moved = served[kind]
+    if site.endswith("["):          # one site a window length
+        warmup = {k: v for k, v in moved["warmup"].items()
+                  if k.startswith(site)}
+    else:
+        warmup = {k: v for k, v in moved["warmup"].items() if k == site}
+    assert warmup, (site, moved["warmup"])
+    if not varies_by_width:         # the one warm-up compile a site
+        assert set(warmup.values()) == {1}, warmup
+    assert moved["recompiled"] == {}
+    assert moved["steady_signatures"] == {}
+    assert moved["steady_recompiled"] == {}
+    assert moved["steady_executables"] == 0
+
