@@ -109,7 +109,7 @@ class Autotuner:
                     {"flash_block": blk}
                     for blk in ((1024, 1024), (512, 512), (256, 256))
                     if blk != tuple(current)
-                ] + [{"flash_heads_per_program": 2}]
+                ]
         # optimizer variants (dicts merged over base optimizer config):
         # int8 Adam moments are THE memory lever for billion-param
         # single-chip regimes, so they are part of the search space

@@ -60,10 +60,9 @@ class GPT2Config:
                                        # scheduling barrier)
     vocab_pad_multiple: int = 128      # MXU/TP-friendly vocab padding
     decode: bool = False               # KV-cache autoregressive mode
-    # flash-kernel tiling knobs (autotuner search space; None = kernel
-    # defaults, see ops/pallas/flash_attention.py)
+    # flash-kernel tiling (autotuner search space; None = kernel
+    # default, see ops/pallas/flash_attention.py)
     flash_block: Optional[tuple] = None          # (block_q, block_k)
-    flash_heads_per_program: Optional[int] = None
     # Mixture-of-Experts FFN (reference deepspeed/moe usage: MoE replaces
     # the MLP).  With scan_layers the stack is homogeneous, so MoE applies
     # to EVERY block (use use_residual=True for the PR-MoE dense+MoE mix).
@@ -260,8 +259,6 @@ class SelfAttention(nn.Module):
         flash_opts = {}
         if cfg.flash_block is not None:
             flash_opts["block_q"], flash_opts["block_k"] = cfg.flash_block
-        if cfg.flash_heads_per_program is not None:
-            flash_opts["heads_per_program"] = cfg.flash_heads_per_program
         y = dot_product_attention(
             q, k, v, causal=True, mask=attn_mask,
             dropout_rate=0.0 if deterministic else cfg.attn_pdrop,

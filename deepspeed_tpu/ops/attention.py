@@ -92,6 +92,14 @@ def dot_product_attention(
                                                  allow_tp=True)
                 plan = "one device" if verdict == "direct" \
                     else f"shard_map over batch axes {axes}"
+                if impl == "flash":     # the layout a shard's kernels run
+                    from ..comm.mesh import get_mesh
+                    from .pallas.flash_attention import flash_lanes
+
+                    tp = 1 if verdict == "direct" \
+                        else get_mesh().shape.get("tp", 1)
+                    plan += "; " + flash_lanes(q.shape[2] // tp,
+                                               q.shape[3]).reason
                 note_dispatch("attention", impl, f"{reason}; {plan}")
                 return out
             reason = "kernel_mesh_plan refused the mesh"

@@ -4,12 +4,19 @@ TPU-native replacement for the reference's fused attention CUDA kernels
 (``csrc/transformer/ds_transformer_cuda.cpp`` softmax/strided-batch-gemm
 path for training; ``csrc/transformer/inference/csrc/softmax.cu``
 triangular-masked softmax for inference).  Design follows the standard
-flash-attention tiling: per (batch·head, q-block) program, stream K/V
-blocks through VMEM with an online-softmax accumulator, so the S×S score
-matrix never materializes in HBM — O(S) memory, MXU-sized matmul tiles.
+flash-attention tiling: per (batch, lane block of heads, q-block) program,
+stream K/V blocks through VMEM with an online-softmax accumulator, so the
+S×S score matrix never materializes in HBM — O(S) memory, MXU-sized
+matmul tiles.
+
+The kernels read and write ``(B, S, H·D)``, the layout of the projections
+on either side of them, a 128-lane block of whole heads a program
+(:class:`Lanes`): no operand is transposed, copied or cast on its way in
+or out.
 
 Backward uses the saved logsumexp to recompute P blockwise in ONE kernel
-per k-block that feeds dq, dk and dv from a single ds.
+per k-block that feeds dq, dk and dv from a single ds, and writes them in
+the operands' own types.
 
 Both kernels follow one causal tile schedule
 (:func:`score_tile_schedule`): a score tile wholly above the diagonal
@@ -30,12 +37,12 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...telemetry import registry as _registry
 
 NEG_INF = float("-inf")
 
-HEADS_PER_PROGRAM = 1   # module knob; see flash_attention()
 UNROLL_MAX = 4          # static-unroll K/Q sweeps at or below this length
 
 VOID, FULL, DIAGONAL = "void", "full", "diagonal"
@@ -136,6 +143,13 @@ def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     return lowest(nq, ((own + 1) * bk - 1 + bq - 1) // bq), nq
 
 
+def _is_looped(sched: TileSchedule, *, own_is_q: bool) -> bool:
+    """Whether a program's sweep is a loop (:func:`_for_program`)."""
+    nq, nk = sched.S // sched.block_q, sched.Sk // sched.block_k
+    n_own, n_swept = (nq, nk) if own_is_q else (nk, nq)
+    return n_swept > UNROLL_MAX or (sched.causal and n_own > UNROLL_MAX)
+
+
 def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
     """Run ``program(sweep, looped)`` for the grid program that owns tile
     ``own`` of its axis (a query tile in the forward, a key tile in the
@@ -195,7 +209,7 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
                     & (t0 < n_swept * swept_block), step, lambda c: c, carry)
         return carry
 
-    if n_swept > UNROLL_MAX or (sched.causal and n_own > UNROLL_MAX):
+    if _is_looped(sched, own_is_q=own_is_q):
         program(dynamic_sweep, True)
     elif not sched.causal or n_own == 1:    # all programs meet the same tiles
         program(static_sweep(0), False)
@@ -231,105 +245,221 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, G):
-    # G heads per program (leading block dim): amortizes per-program
-    # overhead — measured 1.6x faster at G=2 on the bench chip
-    bq, D = q_ref.shape[1:]
+class Lanes(NamedTuple):
+    """How the kernels cut the last dimension of their operands, from
+    shapes alone.  ``rows`` operands are ``(B, S, H·D)``, the free reshape
+    of what the projections write: a program's ``block`` lanes hold
+    ``heads`` whole heads (head_dim 64 → 2 a 128-lane block, 32 → 4, 128
+    and 256 → 1), and the last block may be ragged (25 heads of 64 are
+    12.5 blocks).  A head_dim that does not tile 128 lanes (96, 80) keeps
+    one head a program over ``(B·H, S, D)`` panels: the same kernels, one
+    head wide."""
+    width: int      # lanes of an operand: H·D in rows, D head-major
+    block: int      # lanes of a program
+    head_dim: int
 
-    for g in range(G):
-        def program(sweep, looped, g=g):
-            q = q_ref[g].astype(jnp.float32) * scale            # (bq, D)
+    @property
+    def rows(self) -> bool:
+        return 128 % self.head_dim == 0 or self.head_dim % 128 == 0
+
+    @property
+    def heads(self) -> int:
+        return self.block // self.head_dim
+
+    @property
+    def blocks(self) -> int:
+        return -(-self.width // self.block)
+
+    @property
+    def ragged(self) -> bool:
+        return self.width % self.block != 0
+
+    @property
+    def reason(self) -> str:
+        """What ``kernel_dispatch_total{site="attention"}`` says ran."""
+        if not self.rows:
+            return (f"head-major: head_dim {self.head_dim} does not tile "
+                    f"128 lanes")
+        return (f"rows layout, {self.heads} "
+                f"head{'s' if self.heads > 1 else ''} a {self.block}-lane "
+                f"block")
+
+
+def flash_lanes(H: int, D: int) -> Lanes:
+    """The layout the kernels run for ``H`` heads of ``D``: a function of
+    the shape alone, never of a model or a switch."""
+    lanes = Lanes(H * D, min(max(D, 128), H * D), D)
+    return lanes if lanes.rows else Lanes(D, D, D)
+
+
+def _for_lane_block(lanes: Lanes, body) -> None:
+    """Run ``body(heads, limit)`` for this program's lane block: the
+    heads it holds and the lane they end at.  That is all of the block,
+    except in a ragged last block: its lanes from ``limit`` on may hold
+    anything, and what is stored there is dropped.  The last block is a
+    program of its own, so no other one pays for its edge."""
+    if not lanes.ragged:
+        return body(lanes.heads, lanes.block)
+    last = lanes.blocks - 1
+    left = lanes.width - last * lanes.block
+    c = pl.program_id(1)
+    pl.when(c < last)(functools.partial(body, lanes.heads, lanes.block))
+    pl.when(c == last)(functools.partial(body, left // lanes.head_dim, left))
+
+
+def _keep_lanes(x, lo: int, hi: int):
+    """``x`` with every lane outside ``[lo, hi)`` zeroed (by a select: what
+    is there may be NaN).  Two uses.  A head's lanes of the block:
+    contracted over all lanes with an untouched operand, that gives the
+    head's product alone, in the MXU passes a head_dim-wide contraction
+    padded to the block takes.  And the lanes an operand has, on the other
+    side of such a contraction in a ragged block: 0 · NaN must not meet."""
+    if lo == 0 and hi >= x.shape[1]:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= lo) & (lane < hi), x, 0.0)
+
+
+def _head_lanes(x, h: int, lanes: Lanes):
+    """``x`` with only head ``h``'s lanes of the block kept."""
+    return _keep_lanes(x, h * lanes.head_dim, (h + 1) * lanes.head_dim)
+
+
+def _own_lanes(per_head, lanes: Lanes):
+    """One block-wide array that holds, in each head's lanes, that head's
+    entry of ``per_head`` (each computed over all lanes of the block)."""
+    out = per_head[0]
+    if len(per_head) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for h in range(1, len(per_head)):
+            out = jnp.where(lane >= h * lanes.head_dim, per_head[h], out)
+    return out
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
+    """One (batch, lane block, query tile) program.  The heads of the
+    block are independent online-softmax chains in one basic block, so
+    Mosaic overlaps one head's matmuls with another's vector work; each
+    keeps a block-wide accumulator, and its own lanes are picked once, at
+    the store."""
+    bq, L = q_ref.shape[1:]
+    i = pl.program_id(2)    # read here: not inside a branch
+
+    def lane_block(heads, limit):
+        def program(sweep, looped):
+            q = q_ref[0].astype(jnp.float32) * scale             # (bq, L)
+            qs = [_head_lanes(q, h, lanes) for h in range(heads)]
 
             def fold(k0, carry, d=None):
-                """One online-softmax step: key tile [k0, +block_k) into
-                the carry ``(m, l, acc)``; ``d`` is the mask offset, None
-                for a FULL tile."""
-                m, l, acc = carry
+                """One online-softmax step a head: key tile [k0,
+                +block_k) into each ``(m, l, acc)``; ``d`` is the mask
+                offset, None for a FULL tile."""
                 ks = pl.ds(k0, sched.block_k)
-                k = k_ref[g, ks].astype(jnp.float32)
-                v = v_ref[g, ks].astype(jnp.float32)
-                s = _dot(q, k, ((1,), (1,)))                    # (bq, bk)
-                if d is not None:
-                    s = _causal_mask(s, d)
-                m_new = jnp.maximum(m, s.max(axis=-1))
-                # rows with everything masked keep m=-inf; keep exp
-                # well-defined
-                m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-                p = jnp.exp(s - _col(m_safe))
-                corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - m_safe))
-                return (m_new, l * corr + p.sum(axis=-1),
-                        acc * _col(corr) + _dot(p, v, ((1,), (0,))))
+                k = _keep_lanes(k_ref[0, ks].astype(jnp.float32), 0, limit)
+                v = v_ref[0, ks].astype(jnp.float32)
+                out = []
+                for q_h, (m, l, acc) in zip(qs, carry):
+                    s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
+                    if d is not None:
+                        s = _causal_mask(s, d)
+                    m_new = jnp.maximum(m, s.max(axis=-1))
+                    # rows with everything masked keep m=-inf; keep exp
+                    # well-defined
+                    m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+                    p = jnp.exp(s - _col(m_safe))
+                    corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - m_safe))
+                    out.append((m_new, l * corr + p.sum(axis=-1),
+                                acc * _col(corr) + _dot(p, v, ((1,), (0,)))))
+                return tuple(out)
 
             def diagonal_tile(k0, d0, subs, carry):
                 assert len(subs) == 1   # the forward leaves them whole
                 return fold(k0, carry, d0)
 
-            m, l, acc = sweep((jnp.full((bq,), NEG_INF, jnp.float32),
-                               jnp.zeros((bq,), jnp.float32),
-                               jnp.zeros((bq, D), jnp.float32)),
-                              fold, diagonal_tile)
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[g] = (acc / _col(l_safe)).astype(o_ref.dtype)
-            m_safe = jnp.where(m == NEG_INF, 0.0, m)
-            lse_ref[g, 0] = m_safe + jnp.log(l_safe)
+            carry = sweep(((jnp.full((bq,), NEG_INF, jnp.float32),
+                            jnp.zeros((bq,), jnp.float32),
+                            jnp.zeros((bq, L), jnp.float32)),) * heads,
+                          fold, diagonal_tile)
+            outs = []
+            for h, (m, l, acc) in enumerate(carry):
+                l_safe = jnp.where(l == 0.0, 1.0, l)
+                outs.append(acc / _col(l_safe))
+                m_safe = jnp.where(m == NEG_INF, 0.0, m)
+                lse_ref[0, 0, h] = m_safe + jnp.log(l_safe)
+            for h in range(heads, lanes.heads):      # heads that are not
+                lse_ref[0, 0, h] = jnp.zeros((bq,), jnp.float32)
+            o_ref[0] = _own_lanes(outs, lanes).astype(o_ref.dtype)
 
-        _for_program(pl.program_id(1), sched, program, own_is_q=True)
+        _for_program(i, sched, program, own_is_q=True)
+
+    _for_lane_block(lanes, lane_block)
 
 
 def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dq_ref, dk_ref, dv_ref, *, scale, sched, G):
+                 dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale, sched,
+                 lanes):
     """Backward: dq, dk AND dv in ONE grid pass over k-blocks.
 
-    ds is computed once per score tile and feeds all three cotangents (5
-    MXU ops a tile; K/V streamed once).  dq is accumulated in a
-    VMEM-resident fp32 output block whose index map ignores the k-block
-    grid dim — TPU grids are sequential, so the block is revisited across
-    k-blocks and flushed once per (batch·head) program.  dk and dv are
-    summed as values, one per ``sub_k`` band of the program's keys, and
-    stored once; a looped sweep sums them in their output blocks.  dk
-    carries ``scale`` via the pre-scaled q; dq is scaled by the caller
-    after the final cast."""
-    bk, D = k_ref.shape[1:]
+    ds is computed once per score tile and head and feeds all three
+    cotangents (5 MXU ops; K/V streamed once).  A head's keys and values
+    are the program's block with the other heads' lanes zeroed, once a
+    program, so q and dO are contracted as they are loaded.  dq sums in a
+    float32 scratch across the k-block grid dim (TPU grids are
+    sequential) and is stored once, scaled, in q's type; its output block
+    ignores that dim, so it is flushed once a (batch, lane block).  dk and
+    dv are summed block-wide as values, one a head and ``sub_k`` band of
+    the program's keys, and each head's lanes picked at the store; a
+    looped sweep sums them in ``kv_acc`` (a loop would carry them through
+    VMEM anyway).  dk carries ``scale`` via the pre-scaled q."""
+    bk, L = k_ref.shape[1:]
     sk = sched.sub_k
+    j = pl.program_id(2)
 
-    @pl.when(pl.program_id(1) == 0)
+    @pl.when(j == 0)
     def _init_dq():
-        dq_ref[...] = jnp.zeros(dq_ref.shape, dq_ref.dtype)
+        dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
 
-    for g in range(G):
-        def program(sweep, looped, g=g):
-            k_blk = k_ref[g].astype(jnp.float32)                 # (bk, D)
-            v_blk = v_ref[g].astype(jnp.float32)
+    def lane_block(n_heads, limit):
+        heads = range(n_heads)
+
+        def program(sweep, looped):
+            k_blk = k_ref[0].astype(jnp.float32)                 # (bk, L)
+            v_blk = v_ref[0].astype(jnp.float32)
+            ks = [_head_lanes(k_blk, h, lanes) for h in heads]
+            vs = [_head_lanes(v_blk, h, lanes) for h in heads]
 
             def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk,
                       d=None):
                 """Queries [q0+r0, +rows) against keys [c0, +cols) of the
                 program's block; ``d`` is the mask offset, None for FULL.
-                ``sums`` maps each key band to its ``(dk, dv)`` so far,
-                or is None where they are summed in dk_ref and dv_ref."""
+                ``sums`` maps each head and key band to its ``(dk, dv)``
+                so far, or is None where they are summed in ``kv_acc``."""
                 rs = pl.ds(q0 + r0, rows)
-                k, v = _rows(k_blk, c0, cols), _rows(v_blk, c0, cols)
-                q = q_ref[g, rs].astype(jnp.float32) * scale
-                do = do_ref[g, rs].astype(jnp.float32)
-                lse = lse_ref[g, 0, rs]
-                delta = delta_ref[g, 0, rs]
-                s = _dot(q, k, ((1,), (1,)))                     # (rows, cols)
-                if d is not None:
-                    s = _causal_mask(s, d)
-                p = jnp.exp(s - _col(lse))
-                dv = _dot(p, do, ((0,), (0,)))
-                dp = _dot(do, v, ((1,), (1,)))
-                ds = p * (dp - _col(delta))
-                dk = _dot(ds, q, ((0,), (0,)))
-                dq_ref[g, rs] += _dot(ds, k, ((1,), (0,)))
-                if sums is None:
-                    dk_ref[g, pl.ds(c0, cols)] += dk
-                    dv_ref[g, pl.ds(c0, cols)] += dv
-                    return None
-                sums = dict(sums)
-                for b in range(c0, c0 + cols, sk):
-                    sums[b] = (sums[b][0] + _rows(dk, b - c0, sk),
-                               sums[b][1] + _rows(dv, b - c0, sk))
+                q = _keep_lanes(q_ref[0, rs].astype(jnp.float32) * scale, 0,
+                                limit)
+                do = _keep_lanes(do_ref[0, rs].astype(jnp.float32), 0, limit)
+                sums = None if sums is None else dict(sums)
+                dq = None
+                for h in heads:
+                    k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
+                    s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
+                    if d is not None:
+                        s = _causal_mask(s, d)
+                    p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
+                    dv = _dot(p, do, ((0,), (0,)))
+                    dp = _dot(do, v, ((1,), (1,)))
+                    ds = p * (dp - _col(delta_ref[0, 0, h, rs]))
+                    dk = _dot(ds, q, ((0,), (0,)))
+                    dq_h = _dot(ds, k, ((1,), (0,)))     # zero off the head
+                    dq = dq_h if dq is None else dq + dq_h
+                    if sums is None:
+                        kv_acc[0][h, pl.ds(c0, cols)] += dk
+                        kv_acc[1][h, pl.ds(c0, cols)] += dv
+                        continue
+                    for b in range(c0, c0 + cols, sk):
+                        sums[h, b] = (sums[h, b][0] + _rows(dk, b - c0, sk),
+                                      sums[h, b][1] + _rows(dv, b - c0, sk))
+                dq_acc[rs] += dq
                 return sums
 
             def diagonal_tile(q0, d0, subs, sums):
@@ -338,19 +468,32 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  None if kind == FULL else d0 + r0 - c0)
                 return sums
 
-            if looped:     # a loop would carry the sums through VMEM anyway
-                dk_ref[g] = jnp.zeros(dk_ref.shape[1:], dk_ref.dtype)
-                dv_ref[g] = jnp.zeros(dv_ref.shape[1:], dv_ref.dtype)
-                sweep(None, visit, diagonal_tile)
-                return
-            zero = jnp.zeros((sk, D), jnp.float32)
-            sums = sweep({b: (zero, zero) for b in range(0, bk, sk)},
-                         visit, diagonal_tile)
-            for b, (dk, dv) in sums.items():
-                dk_ref[g, pl.ds(b, sk)] = dk
-                dv_ref[g, pl.ds(b, sk)] = dv
+            def store(ref, rows, per_head):
+                ref[0, rows] = _own_lanes(per_head, lanes).astype(ref.dtype)
 
-        _for_program(pl.program_id(1), sched, program, own_is_q=False)
+            if looped:
+                for acc in kv_acc:
+                    acc[...] = jnp.zeros(acc.shape, acc.dtype)
+                sweep(None, visit, diagonal_tile)
+                store(dk_ref, slice(None), [kv_acc[0][h] for h in heads])
+                store(dv_ref, slice(None), [kv_acc[1][h] for h in heads])
+                return
+            zero = jnp.zeros((sk, L), jnp.float32)
+            sums = sweep({(h, b): (zero, zero) for h in heads
+                          for b in range(0, bk, sk)}, visit, diagonal_tile)
+            for b in range(0, bk, sk):
+                store(dk_ref, pl.ds(b, sk), [sums[h, b][0] for h in heads])
+                store(dv_ref, pl.ds(b, sk), [sums[h, b][1] for h in heads])
+
+        _for_program(j, sched, program, own_is_q=False)
+
+    _for_lane_block(lanes, lane_block)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _store_dq():
+        for r in range(0, sched.S, sched.block_q):   # tile-sized values
+            rs = pl.ds(r, sched.block_q)
+            dq_ref[0, rs] = (dq_acc[rs] * scale).astype(dq_ref.dtype)
 
 
 def _largest_dividing_block(s: int, cap: int) -> int:
@@ -362,14 +505,65 @@ def _largest_dividing_block(s: int, cap: int) -> int:
     return b if s % b == 0 else min(s, 128)
 
 
-def _flatten_bh(x):
-    B, H, S, D = x.shape
-    return x.reshape(B * H, S, D)
+def _pack(x, lanes: Lanes):
+    """``(B, S, H, D)`` as the kernels take it: ``(B, S, H·D)``, a free
+    reshape, or one ``(S, D)`` panel a head."""
+    B, S, H, D = x.shape
+    if lanes.rows:
+        return x.reshape(B, S, H * D)
+    return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+
+
+def _unpack(x, lanes: Lanes, B: int):
+    N, S, _ = x.shape
+    if lanes.rows:
+        return x.reshape(B, S, -1, lanes.head_dim)
+    return x.reshape(B, N // B, S, lanes.head_dim).transpose(0, 2, 1, 3)
+
+
+def _head_rows(x, lanes: Lanes):
+    """A per-row, per-head vector ``(N, S, heads)`` (lse, delta) as the
+    kernels index it: ``(N, lane blocks, heads a block, S)``, the heads of
+    a ragged last block padded with zeros."""
+    N, S, H = x.shape
+    x = x.transpose(0, 2, 1)
+    pad = lanes.blocks * lanes.heads - H
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    return x.reshape(N, lanes.blocks, lanes.heads, S)
+
+
+def _row_heads(x, lanes: Lanes):
+    """Inverse of :func:`_head_rows`: ``(N, S, heads)``."""
+    N, _, _, S = x.shape
+    x = x.reshape(N, lanes.blocks * lanes.heads, S)
+    return x[:, :lanes.width // lanes.head_dim].transpose(0, 2, 1)
+
+
+def _delta(do, out, lanes: Lanes):
+    """Row sums of dO · O a head, float32, laid out as :func:`_head_rows`
+    does.  Where a head is narrower than the 128 lanes an array is tiled
+    in, a reshape to ``(..., H, D)`` costs XLA a float32 copy of the
+    product before it can reduce; there the sum over a head's lanes is a
+    product with a 0/1 matrix instead (one column a head, the padding
+    heads' all zero), which XLA fuses the multiply into: dO and O are
+    read once."""
+    N, S, W = do.shape
+    D = lanes.head_dim
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    if D % 128 == 0 or W == D:
+        return _head_rows(prod.reshape(N, S, W // D, D).sum(axis=-1), lanes)
+    heads = lanes.blocks * lanes.heads
+    own = (jnp.arange(W)[None, :] // D
+           == jnp.arange(heads)[:, None]).astype(jnp.float32)
+    return jnp.einsum("nsw,hw->nhs", prod, own, precision="highest"
+                      ).reshape(N, lanes.blocks, lanes.heads, S)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, G, interpret):
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, G, interpret)
+def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
+                        interpret)
     return out
 
 
@@ -377,39 +571,40 @@ def _flash(q, k, v, causal, scale, block_q, block_k, G, interpret):
 # stays in the caller's jaxpr, but its trace cache means a model traces
 # each kernel body once, not once a layer and remat pass (a step of the
 # 48-layer benchmark model stages ~200 flash calls).
-_STATIC = ("causal", "scale", "block_q", "block_k", "G", "interpret")
+_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, G, interpret):
-    BH, S, D = q.shape
+def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret):
+    N, S, W = q.shape
     Sk = k.shape[1]
+    L, NB, P = lanes.block, lanes.blocks, lanes.heads
     sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, sched=sched, G=G),
-        grid=(BH // G, S // block_q),
+        functools.partial(_fwd_kernel, scale=scale, sched=sched, lanes=lanes),
+        grid=(N, NB, S // block_q),
         in_specs=[
-            pl.BlockSpec((G, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((G, Sk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((G, Sk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
+            pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c)),
+            pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c)),
         ],
         out_specs=[
-            pl.BlockSpec((G, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((G, 1, block_q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
+            pl.BlockSpec((1, 1, P, block_q), lambda n, c, i: (n, c, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, 1, S), jnp.float32),
+            jax.ShapeDtypeStruct((N, S, W), q.dtype),
+            jax.ShapeDtypeStruct((N, NB, P, S), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, G, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
     _note_score_tiles("fwd", score_tile_schedule(
         q.shape[1], k.shape[1], block_q, block_k, causal, False))
     out, lse = _fwd_call(q, k, v, causal=causal, scale=scale,
-                         block_q=block_q, block_k=block_k, G=G,
+                         block_q=block_q, block_k=block_k, lanes=lanes,
                          interpret=interpret)
     # named so a "<policy>+flash" remat policy can SAVE the kernel's
     # residuals: out/lse aren't dot outputs, so dots_saveable alone
@@ -419,54 +614,47 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, G, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, G, interpret, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, res, do):
     q, k, v, out, lse = res
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[:, None, :]
-    return _flash_bwd_impl(causal, scale, block_q, block_k, G, interpret,
-                           q, k, v, lse, do, delta)
+    return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
+                           q, k, v, lse, do, _delta(do, out, lanes))
 
 
-def _flash_bwd_impl(causal, scale, block_q, block_k, G, interpret,
+def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
                     q, k, v, lse, do, delta):
     _note_score_tiles("bwd", score_tile_schedule(
         q.shape[1], k.shape[1], block_q, block_k, causal, True))
-    dq, dk, dv = _bwd_call(q, k, v, do, lse, delta, causal=causal,
-                           scale=scale, block_q=block_q, block_k=block_k,
-                           G=G, interpret=interpret)
-    return ((dq * scale).astype(q.dtype), dk.astype(k.dtype),
-            dv.astype(v.dtype))
+    return _bwd_call(q, k, v, do, lse, delta, causal=causal, scale=scale,
+                     block_q=block_q, block_k=block_k, lanes=lanes,
+                     interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_call(q, k, v, do, lse, delta, *, causal, scale, block_q, block_k,
-              G, interpret):
-    BH, S, D = q.shape
+              lanes, interpret):
+    N, S, W = q.shape
     Sk = k.shape[1]
+    L, NB, P = lanes.block, lanes.blocks, lanes.heads
     sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True)
+    panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
+    block = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
+    rows = pl.BlockSpec((1, 1, P, S), lambda n, c, j: (n, c, 0, 0))
+    scratch = [pltpu.VMEM((S, L), jnp.float32)]          # dq, summed over j
+    if _is_looped(sched, own_is_q=False):                # dk and dv a head
+        scratch += [pltpu.VMEM((P, block_k, L), jnp.float32)] * 2
     return pl.pallas_call(
-        functools.partial(_dqkv_kernel, scale=scale, sched=sched, G=G),
-        grid=(BH // G, Sk // block_k),
-        in_specs=[
-            pl.BlockSpec((G, S, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((G, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((G, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((G, S, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((G, 1, S), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((G, 1, S), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            # dq revisited across j (map ignores the k-block dim):
-            # fp32 VMEM accumulator, flushed once per (batch·head)
-            pl.BlockSpec((G, S, D), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((G, block_k, D), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((G, block_k, D), lambda b, j: (b, j, 0)),
-        ],
+        functools.partial(_dqkv_kernel, scale=scale, sched=sched,
+                          lanes=lanes),
+        grid=(N, NB, Sk // block_k),
+        in_specs=[panel, block, block, panel, rows, rows],
+        # dq's block ignores j: written once, when its sum is complete
+        out_specs=[panel, block, block],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sk, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sk, D), jnp.float32),
+            jax.ShapeDtypeStruct((N, S, W), q.dtype),
+            jax.ShapeDtypeStruct((N, Sk, W), k.dtype),
+            jax.ShapeDtypeStruct((N, Sk, W), v.dtype),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
@@ -474,12 +662,40 @@ def _bwd_call(q, k, v, do, lse, delta, *, causal, scale, block_q, block_k,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _prepare(q, k, scale, block_q, block_k):
+    B, S, H, D = q.shape
+    Sk = k.shape[1]
+    if scale is None:
+        scale = D ** -0.5
+    lanes = flash_lanes(H, D)
+    if lanes.block > 128:
+        # a backward program keeps its keys, values and their gradients
+        # in float32 beside whole-sequence panels of q, dO and dq: at 256
+        # lanes and S 2048 a 512-row key block no longer fits the 16 MB
+        # of VMEM a kernel may use
+        block_k = min(block_k, 256)
+    block_q = _largest_dividing_block(S, block_q)
+    block_k = _largest_dividing_block(Sk, block_k)
+    if S % block_q or Sk % block_k:
+        raise ValueError(f"seq lengths ({S},{Sk}) must divide block sizes "
+                         f"({block_q},{block_k})")
+    return scale, block_q, block_k, lanes
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
-                    heads_per_program: Optional[int] = None,
                     interpret: bool = False) -> jax.Array:
     """Public API, shapes ``(B, S, H, D)`` like ``ops.attention``.
+
+    The kernels read q, k, v and dO and write o, dq, dk and dv as
+    ``(B, S, H·D)``, the layout the projections on either side use, so
+    nothing is transposed, copied or cast around them (:class:`Lanes`; in
+    GPT-2-XL's step the eight layout copies a layer and the float32 round
+    trip of the gradients were 7.7 ms of 191).  A head_dim that does not
+    tile 128 lanes is transposed to one panel a head, as every shape was
+    before; :func:`flash_lanes` decides from the shape, and
+    ``kernel_dispatch_total`` says which ran.
 
     Default blocks are ``min(S, 512)``: large tiles beat the flash-paper-
     style 128x128 by ~1.8x on the bench chip (fewer programs, K/V panel
@@ -489,26 +705,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     double-buffer better); the online-softmax loop engages automatically
     for S > block.
     """
-    B, S, H, D = q.shape
-    Sk = k.shape[1]
-    if scale is None:
-        scale = D ** -0.5
-    block_q = _largest_dividing_block(S, block_q)
-    block_k = _largest_dividing_block(Sk, block_k)
-    if S % block_q or Sk % block_k:
-        raise ValueError(f"seq lengths ({S},{Sk}) must divide block sizes "
-                         f"({block_q},{block_k})")
-    qt = _flatten_bh(q.transpose(0, 2, 1, 3))
-    kt = _flatten_bh(k.transpose(0, 2, 1, 3))
-    vt = _flatten_bh(v.transpose(0, 2, 1, 3))
-    # heads-per-program: G=2 wins ~1.6x on the isolated fwd kernel but is
-    # e2e-neutral-to-negative inside the full training step (XLA already
-    # overlaps programs); default 1, knob kept for other chips/models
-    hpp = HEADS_PER_PROGRAM if heads_per_program is None else heads_per_program
-    G = hpp if (B * H) % hpp == 0 and \
-        hpp * Sk * D * q.dtype.itemsize <= 512 * 1024 else 1
-    out = _flash(qt, kt, vt, causal, scale, block_q, block_k, G, interpret)
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
+    out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), causal,
+                 scale, block_q, block_k, lanes, interpret)
+    return _unpack(out, lanes, q.shape[0])
 
 
 # ---------------------------------------------------------------------------
@@ -516,26 +716,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, G, interpret):
-    out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, G,
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+    return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
+                          interpret)[0]
+
+
+def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
+                   interpret):
+    out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
                           interpret)
-    return out, res[4][:, 0, :]          # lse as (BH, S)
+    return (out, _row_heads(res[4], lanes)), res      # lse as (N, S, heads)
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, G, interpret):
-    out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, G,
-                          interpret)
-    return (out, res[4][:, 0, :]), res
-
-
-def _flash_lse_bwd(causal, scale, block_q, block_k, G, interpret, res, ct):
+def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
+                   ct):
     do, dlse = ct
     q, k, v, out, lse = res
     # the lse cotangent folds into the shared backward exactly:
     # ds = p·(dp - δ') with δ' = δ - dlse, because ∂lse_i/∂s_ij = p_ij
-    delta = (jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                     axis=-1) - dlse.astype(jnp.float32))[:, None, :]
-    return _flash_bwd_impl(causal, scale, block_q, block_k, G, interpret,
+    delta = _delta(do, out, lanes) - _head_rows(dlse.astype(jnp.float32),
+                                                lanes)
+    return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
                            q, k, v, lse, do, delta)
 
 
@@ -551,19 +752,10 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``(B, S, H)`` — differentiable in BOTH outputs, which is what a
     distributed (ring) attention needs to merge per-block results exactly.
     """
-    B, S, H, D = q.shape
-    Sk = k.shape[1]
-    if scale is None:
-        scale = D ** -0.5
-    block_q = _largest_dividing_block(S, block_q)
-    block_k = _largest_dividing_block(Sk, block_k)
-    qt = _flatten_bh(q.transpose(0, 2, 1, 3))
-    kt = _flatten_bh(k.transpose(0, 2, 1, 3))
-    vt = _flatten_bh(v.transpose(0, 2, 1, 3))
-    G = HEADS_PER_PROGRAM if (B * H) % HEADS_PER_PROGRAM == 0 and \
-        HEADS_PER_PROGRAM * Sk * D * q.dtype.itemsize <= 512 * 1024 else 1
-    out, lse = _flash_lse(qt, kt, vt, causal, scale, block_q, block_k, G,
-                          interpret)
-    out = out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-    lse = lse.reshape(B, H, S).transpose(0, 2, 1)
-    return out, lse
+    B, S, H, _ = q.shape
+    scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
+    out, lse = _flash_lse(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes),
+                          causal, scale, block_q, block_k, lanes, interpret)
+    if not lanes.rows:      # (B·H, S, 1): a head a panel
+        lse = lse.reshape(B, H, S).transpose(0, 2, 1)
+    return _unpack(out, lanes, B), lse

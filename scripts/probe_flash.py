@@ -30,14 +30,14 @@ def main():
     # causal useful flops (fwd 2 matmuls + bwd 3) ~ (2+3)*2*B*H*S^2*D/2
     flops = 5 * B * H * S * S * D
 
-    def run(bq, bk, G):
+    def run(bq, bk):
         reps = 50   # one compiled scan: a single dispatch
 
         def f(q, k, v):
             def loss(q, k, v):
                 return flash_attention(
-                    q, k, v, causal=True, block_q=bq, block_k=bk,
-                    heads_per_program=G).astype(jnp.float32).sum()
+                    q, k, v, causal=True, block_q=bq,
+                    block_k=bk).astype(jnp.float32).sum()
 
             def body(carry, _):
                 l, grads = jax.value_and_grad(
@@ -63,18 +63,15 @@ def main():
     for bq, bk in [(512, 512), (256, 512), (512, 256), (256, 256),
                    (1024, 512), (512, 1024), (1024, 1024), (128, 512),
                    (256, 1024)]:
-        for G in (1, 2):
-            if (B * H) % G:
-                continue
-            try:
-                dt = run(bq, bk, G)
-                results.append(((bq, bk, G), dt))
-                print(json.dumps({
-                    "bq": bq, "bk": bk, "G": G, "ms": round(dt * 1e3, 3),
-                    "tflops": round(flops / dt / 1e12, 1)}), flush=True)
-            except Exception as e:
-                print(json.dumps({"bq": bq, "bk": bk, "G": G,
-                                  "error": repr(e)[:160]}), flush=True)
+        try:
+            dt = run(bq, bk)
+            results.append(((bq, bk), dt))
+            print(json.dumps({
+                "bq": bq, "bk": bk, "ms": round(dt * 1e3, 3),
+                "tflops": round(flops / dt / 1e12, 1)}), flush=True)
+        except Exception as e:
+            print(json.dumps({"bq": bq, "bk": bk,
+                              "error": repr(e)[:160]}), flush=True)
     best = min(results, key=lambda r: r[1])
     print(json.dumps({"best": best[0],
                       "ms": round(best[1] * 1e3, 3)}), flush=True)

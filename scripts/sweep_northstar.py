@@ -47,7 +47,6 @@ def main():
         remat_policy=remat if remat != "off" else "nothing_saveable",
         attn_impl=kv.get("attn", "auto"),
         flash_block=tuple(int(x) for x in fb.split("x")) if fb else None,
-        flash_heads_per_program=int(kv["hpp"]) if "hpp" in kv else None,
         loss_chunk=chunk or None, loss_save_logits=save_logits,
         loss_pallas=kv.get("pl", "0") == "1")
     model = GPT2LMHeadModel(cfg)

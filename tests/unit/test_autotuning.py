@@ -99,7 +99,7 @@ def test_autotuner_flash_knobs_probed_and_carried():
                           micro_batches=[1], zero_stages=[1],
                           remat_options=[False],
                           kernel_options=[{"flash_block": (256, 256)},
-                                          {"flash_heads_per_program": 2}])
+                                          {"flash_block": (128, 256)}])
         cfg = tuner.tune()
         assert all(r.error is None for r in tuner.results), \
             [r.error for r in tuner.results]
@@ -108,7 +108,7 @@ def test_autotuner_flash_knobs_probed_and_carried():
         mo_kernel = {k: v for k, v in cfg["model_overrides"].items()
                      if k != "remat"}
         assert mo_kernel in (
-            {"flash_block": (256, 256)}, {"flash_heads_per_program": 2})
+            {"flash_block": (256, 256)}, {"flash_block": (128, 256)})
         assert cfg["model_overrides"]["remat"] is False
         # the override reconfigures the model when fed back to initialize()
         import deepspeed_tpu

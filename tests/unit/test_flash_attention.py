@@ -119,27 +119,96 @@ def test_flash_spmd_on_mesh():
         mesh_mod.set_mesh(None)
 
 
-def test_flash_heads_per_program_parity():
-    """The G>1 head-batched grid must match G=1 numerics for the output and
-    ALL THREE gradients (dq, dk and dv all come from _dqkv_kernel)."""
-    import numpy as np
+ROWS_2, HEAD_MAJOR_96 = ("rows layout, 2 heads a 128-lane block",
+                         "head-major: head_dim 96 does not tile 128 lanes")
 
-    q, k, v = _qkv(B=2, H=4)
 
-    def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+@pytest.fixture()
+def flash_dispatch(monkeypatch):
+    """``dot_product_attention(impl="flash")`` on one CPU device, the
+    kernels in interpret mode; returns ``(attend, booked)`` where
+    ``booked()`` maps the reasons ``kernel_dispatch_total`` holds for the
+    flash kernel to their counts."""
+    import functools
 
-    f1 = lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                         heads_per_program=1, interpret=True)
-    f2 = lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                         heads_per_program=2, interpret=True)
-    np.testing.assert_allclose(np.asarray(f1(q, k, v)),
-                               np.asarray(f2(q, k, v)), rtol=1e-6, atol=1e-6)
-    g1 = jax.grad(loss(f1), argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss(f2), argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g1, g2):
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    monkeypatch.setattr(attention, "_flash_spmd", functools.partial(
+        attention._flash_spmd, interpret=True))
+    mesh_mod.set_mesh(mesh_mod.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+
+    def booked():
+        return {reason: n for site, impl, reason, n in dispatch_report()
+                if (site, impl) == ("attention", "flash")}
+
+    yield functools.partial(attention.dot_product_attention,
+                            impl="flash"), booked
+    mesh_mod.set_mesh(None)
+
+
+# (25, 64): GPT-2-XL's heads, 12.5 lane blocks: the last one is ragged and
+# the interpreter fills its lanes past H·D with NaN, as it does every
+# scratch; (4, 64): whole blocks; (3, 32): three heads in one 96-lane
+# block; (2, 128): a head a block; (2, 96): no tiling, one panel a head
+@pytest.mark.parametrize("S,Sk,causal", [(128, 128, True), (128, 128, False),
+                                         (128, 256, True), (128, 256, False)])
+@pytest.mark.parametrize("H,D,layout", [
+    (25, 64, ROWS_2), (4, 64, ROWS_2),
+    (3, 32, "rows layout, 3 heads a 96-lane block"),
+    (2, 128, "rows layout, 1 head a 128-lane block"),
+    (2, 96, HEAD_MAJOR_96)])
+def test_flash_layout_parity(flash_dispatch, H, D, layout, S, Sk, causal):
+    """Output and all three gradients of every layout the kernels choose
+    from the shape, against the dense reference, and the layout by name
+    in ``dispatch_report()``.  What lies past an operand's last lane must
+    not reach a result: every value stays finite and equal."""
+    from jax._src.pallas import primitives
+
+    assert np.isnan(primitives.uninitialized_value((), jnp.float32))
+    attend, booked = flash_dispatch
+    rng = np.random.default_rng(H * D + S + Sk)
+    q = jnp.asarray(rng.normal(size=(1, S, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, Sk, H, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, Sk, H, D)), jnp.float32)
+    # the kernels count query and key positions from 0 both
+    keep = jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :] if causal \
+        else None
+
+    def ref(q, k, v):
+        return _jnp_attention(q, k, v, causal=False, bias=None, mask=keep,
+                              dropout_rate=0.0, dropout_rng=None, scale=None)
+
+    before = booked()
+    out = attend(q, k, v, causal=causal)
+    new = {r for r, n in booked().items() if n > before.get(r, 0)}
+    assert len(new) == 1 and new.pop().endswith("one device; " + layout)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    w = jnp.asarray(rng.normal(size=out.shape), jnp.float32)
+    g_flash = jax.grad(lambda *a: jnp.sum(attend(*a, causal=causal) * w),
+                       argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+        assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6)
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_flash_backward_writes_its_consumers_types():
+    """dq, dk and dv leave the backward kernel in q's, k's and v's own
+    types: nothing casts or scales them outside it."""
+    q, k, v = _qkv(S=128, H=3, dtype=jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, interpret=True).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)))(q, k, v)
+    calls = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 2
+    assert [o.aval.dtype for o in calls[1].outvars] == [jnp.bfloat16] * 3
+    assert [o.aval.shape for o in calls[1].outvars] == [(1, 128, 192)] * 3
 
 
 # S=128/512: one diagonal tile; 1024/1536: the unrolled sweep with void,

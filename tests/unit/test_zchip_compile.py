@@ -156,6 +156,87 @@ def _entry(text):
     return ops
 
 
+@pytest.mark.parametrize("B,S,H,D,layout", [
+    (2, 1024, 25, 64, "rows layout, 2 heads a 128-lane block"),
+    (2, 4096, 16, 128, "rows layout, 1 head a 128-lane block"),
+    (2, 2048, 8, 256, "rows layout, 1 head a 256-lane block")])
+def test_flash_kernels_compile_at_both_cells_shapes(one_chip, B, S, H, D,
+                                                    layout):
+    """The flash forward and backward of ``train-xl-z3-1chip`` (12.5 lane
+    blocks: the ragged edge) and ``train-olmoe-z3-1chip`` (the largest
+    panels in VMEM: q, dO, the bf16 dq block and its float32 scratch), on
+    operands shaped as the projections write them; and GPT-J's heads at
+    its context, the widest lane block (it fits in 256-row key blocks)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                          flash_lanes)
+
+    assert flash_lanes(H, D).reason == layout
+
+    def loss(q, k, v):
+        split = lambda x: x.reshape(B, S, H, D)
+        return flash_attention(split(q), split(k), split(v)).astype(
+            jnp.float32).sum()
+
+    arg = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        arg, arg, arg).compile().as_text()
+    results = [shape for shape, op, _ in _entry(text).values()
+               if op == "custom-call" and shape.startswith("(")]
+    assert len(results) == 2
+    # o and lse; dq, dk and dv in the operands' type, none in float32
+    assert results[0].count(f"bf16[{B},{S},{H * D}]") == 1
+    assert results[1].count(f"bf16[{B},{S},{H * D}]") == 3
+    assert f"f32[{B},{S}," not in results[1]
+
+
+def test_xl_step_has_no_layout_copy_around_flash(topo, one_chip, monkeypatch):
+    """Loss and gradient of a 2-layer GPT-2 at XL's widths with the cell's
+    ``model_options``, compiled for one described chip: the flash calls
+    take q, k, v and dO and give o, dq, dk and dv as ``[2,1024,1600]``, so
+    the optimized HLO holds no ``[2,1024,25,64]`` copy (before PR 29:
+    eight a layer) and no ``f32[50,1024,64]`` kernel output (three a
+    layer), and each call keeps the name ``attn`` that the benchmark's
+    ``trace_names.flash`` finds it by."""
+    import re
+
+    import flax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+    from deepspeed_tpu.ops import attention
+
+    # the dispatch asks jax.devices() and would see eight CPUs and no mesh
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(mesh_mod, "_CURRENT_MESH", Mesh(
+        np.asarray(topo.devices[:1]).reshape((1,) * len(mesh_mod.MESH_AXES)),
+        mesh_mod.MESH_AXES))
+    model = GPT2LMHeadModel(gpt2_config(
+        "gpt2-xl", n_layer=2, scan_layers=False, remat=True,
+        remat_policy="dots_saveable+flash", attn_impl="auto",
+        loss_chunk=8192))
+    ids = jnp.zeros((2, 1024), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), ids))))
+
+    def loss(params, ids):
+        return model.apply(params, ids, labels=ids)["loss"]
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        params, jax.ShapeDtypeStruct(ids.shape, ids.dtype, sharding=one_chip)
+    ).compile().as_text()
+    ops = _entry(text)
+    calls = [n for n, v in ops.items()
+             if v[1] == "custom-call" and re.fullmatch(r"attn(\.\d+)?", n)]
+    assert len(calls) == 4, sorted(ops)     # forward and backward a layer
+    assert not [n for n, v in ops.items()
+                if v[1] == "copy" and "[2,1024,25,64]" in v[0]]
+    assert "f32[50,1024,64]" not in text and "[50,1024,64]" not in text
+
+
 @pytest.mark.parametrize("cell,shapes,kernels", [
     ("xl", XL_LEAVES, 6), ("olmoe", OLMOE_LEAVES, 5)])
 def test_adam8bit_update_is_one_in_place_pass_a_leaf(one_chip, monkeypatch,
