@@ -393,9 +393,145 @@ def kernel_adam8bit():
         assert dp <= 1e-6 and ds <= 1e-6, (shape, dp, ds)
         assert flips[0][0] <= 1 and flips[0][1] <= 1e-3, (shape, flips)
         assert flips[1][0] <= 1 and flips[1][1] <= 0.03, (shape, flips)
+def kernel_share_dispatch():
+    """A share of an expert layer through the sorted dispatch (the third
+    cell's: experts 4-7 of 16, top-4): the pairs held elsewhere lie behind
+    the last group, in rows the Pallas grouped matmul neither reads nor
+    writes, forward or backward.  Whatever the chip's memory holds there
+    must reach no token and no gradient (``ragged_dot`` on the CPU writes
+    zeros there, so only the chip can show it): output and gradients
+    against a dense float32 loop over the held experts."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
+
+    T, M, I, R, E, first, k = 2048, 512, 256, 16, 4, 4, 4
+    cfg = MoEConfig(num_experts=E, routed_experts=R, first_expert=first,
+                    top_k=k, drop_tokens=False, norm_topk_prob=True,
+                    expert_act="swiglu")
+    layer = MoELayer(cfg, model_dim=M, hidden_dim=I, dtype=jnp.bfloat16)
+    ks = jax.random.split(jax.random.PRNGKey(30), 3)
+    x = jax.random.normal(ks[0], (T, M), jnp.float32).astype(jnp.bfloat16)
+    ct = jax.random.normal(ks[1], (T, M), jnp.float32)
+    p = jax.tree_util.tree_map(
+        lambda a: a.value if hasattr(a, "value") else a,
+        layer.init(ks[2], x)["params"], is_leaf=lambda a: hasattr(a, "value"))
+    p = {"gate": {"wg": p["gate"]["wg"] * 30},
+         "experts": {n: w * 3 for n, w in p["experts"].items()}}
+
+    def plain(p, x):
+        x = x.astype(jnp.float32)
+        probs = jax.nn.softmax(x @ p["gate"]["wg"], -1)
+        w, chosen = jax.lax.top_k(probs, k)
+        w = w / w.sum(-1, keepdims=True)
+        out = 0.0
+        for e in range(E):
+            gate, up, down = (p["experts"][n][e] for n in
+                              ("gate", "up", "down"))
+            mine = jnp.where(chosen == first + e, w, 0.0).sum(-1)
+            out += mine[:, None] * ((jax.nn.silu(x @ gate) * (x @ up)) @ down)
+        return out
+
+    def loss(fn, p, x):
+        return (fn(p, x).astype(jnp.float32) * ct).sum()
+
+    kern = lambda p, x: layer.apply({"params": p}, x)[0]    # noqa: E731
+    out = jax.jit(kern)(p, x)
+    d_p, d_x = jax.jit(jax.grad(lambda *a: loss(kern, *a),
+                                argnums=(0, 1)))(p, x)
+    with jax.default_matmul_precision("highest"):
+        out_r = jax.jit(plain)(p, x)
+        d_p_r, d_x_r = jax.jit(jax.grad(lambda *a: loss(plain, *a),
+                                        argnums=(0, 1)))(p, x)
+    name = f"share dispatch ({T},{M}) top-{k}, {E} of {R} experts held"
+    _check_close(f"{name} fwd", out, out_r)
+    _check_close(f"{name} d-tokens", d_x, d_x_r)
+    for n in ("gate", "up", "down"):
+        _check_close(f"{name} d-{n}", d_p["experts"][n], d_p_r["experts"][n])
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    assert any(site == "grouped_matmul" and impl == "megablox"
+               and "(512, 512, 256)" in reason
+               for site, impl, reason, _ in dispatch_report()), dispatch_report()
 
 
-KERNEL_CASES = (kernel_flash, kernel_grouped_matmul, kernel_adam8bit,
+def kernel_flash_window_gqa():
+    """The third cell's attention at the cell's own shape
+    (``train-mellum2-8k-1chip``: 4 rows of 8192 tokens, 32 query heads on
+    4 key-value heads of head_dim 128), with the 1024-key window and
+    without, forward and backward against a float32 reference computed in
+    query blocks (the whole score matrix would be 34 GB a pass).  Four rows
+    and four key-value heads, because the sum of dk, dv over a group lives
+    on the chip's write-back of an output block whose index stays put until
+    the group's last program and then moves on, to the next key-value head
+    and to the next row: one row of one key-value head never moves it, and
+    interpret mode stores every grid step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, S, H, KV, D, QB = 4, 8192, 32, 4, 128, 256
+    G = H // KV
+    ks = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, ct = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
+             .astype(jnp.bfloat16) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (B, S, KV, D), jnp.float32)
+            .astype(jnp.bfloat16) for kk in ks[2:])
+
+    def ref(q, k, v, window):
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+
+        @jax.checkpoint
+        def block(args):
+            q_blk, i0 = args                                # (B, QB, H, D)
+            q_blk = q_blk.reshape(B, QB, KV, G, D)          # head h: h // G
+            s = jnp.einsum("bqngd,btnd->bngqt", q_blk, k) * D ** -0.5
+            back = (i0 + jnp.arange(QB))[:, None] - jnp.arange(S)[None, :]
+            keep = back >= 0
+            if window is not None:
+                keep &= back < window
+            p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1)
+            return jnp.einsum("bngqt,btnd->bqngd", p, v).reshape(B, QB, H, D)
+
+        blocks = q.reshape(B, S // QB, QB, H, D).transpose(1, 0, 2, 3, 4)
+        out = jax.lax.map(block, (blocks, jnp.arange(0, S, QB)))
+        return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, D)
+
+    def loss(fn, q, k, v):
+        return (fn(q, k, v).astype(jnp.float32)
+                * ct.astype(jnp.float32)).sum()
+
+    for window in (1024, None):
+        flash = lambda q, k, v: flash_attention(q, k, v, window=window)  # noqa: E731
+        plain = lambda q, k, v: ref(q, k, v, window)                     # noqa: E731
+        out = jax.jit(flash)(q, k, v)
+        grads = jax.jit(jax.grad(lambda *a: loss(flash, *a),
+                                 argnums=(0, 1, 2)))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            out_r = jax.jit(plain)(q, k, v)
+            grads_r = jax.jit(jax.grad(lambda *a: loss(plain, *a),
+                                       argnums=(0, 1, 2)))(q, k, v)
+        name = f"flash ({B},{S},{H}/{KV},{D}) window {window}"
+        _check_close(f"{name} fwd", out, out_r)
+        for n, g, gr in zip(("dq", "dk", "dv"), grads, grads_r):
+            _check_close(f"{name} {n}", g, gr)
+            if n == "dq":
+                continue
+            # every row and every key-value head on its own: one whose sum
+            # was written early, or over another's, is small in the whole
+            g, gr = (np.asarray(t, np.float32) for t in (g, gr))
+            worst = (np.abs(g - gr).max(axis=(1, 3))
+                     / np.abs(gr).max(axis=(1, 3)))               # (B, KV)
+            print(f"  {name} {n}: worst of {B} rows x {KV} key-value heads "
+                  f"{worst.max():.2e}", flush=True)
+            assert worst.max() <= TOL, (name, n, worst)
+
+
+KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_grouped_matmul,
+                kernel_share_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,
                 kernel_decode_layer, kernel_w8_matmul)
 

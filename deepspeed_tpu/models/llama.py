@@ -10,9 +10,19 @@ OLMoE (arXiv:2409.02060) is this block with fields, not a file of its own:
 ``moe`` puts a sparse SwiGLU-expert FFN (``parallel/moe.py``) in every
 block, ``qk_norm`` an RMSNorm over the whole q and k projections before
 the split into heads, and ``loss_chunk`` the chunked head.
+
+Mellum 2 (JetBrains, 2026; ``model_type: mellum``) is it with more fields:
+``head_dim`` apart from ``hidden_size / heads``, ``layer_types`` that mix
+``sliding_attention`` (the last ``sliding_window`` keys) and
+``full_attention`` blocks in one stack, ``rope_parameters`` with a rotary
+table a layer type (YaRN on the full layers), and experts of
+``moe_intermediate_size``, of which this instance may hold a contiguous
+share (``MoEConfig.first_expert``).  Keys and values reach attention at
+their own ``num_key_value_heads``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Optional
 
@@ -26,8 +36,19 @@ from ..telemetry import trace
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
 
+SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    """Fields under their Hugging Face ``config.json`` names are what
+    ``benchmark/drivers/train_lm.py`` passes through from a configuration
+    file as they stand: ``vocab_size``, ``max_position_embeddings``,
+    ``hidden_size``, ``num_hidden_layers``, ``num_attention_heads``,
+    ``num_key_value_heads``, ``head_dim``, ``intermediate_size``,
+    ``moe_intermediate_size``, ``rms_norm_eps``, ``rope_theta``,
+    ``rope_parameters``, ``sliding_window``, ``layer_types``,
+    ``initializer_range``.  The rest are this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -39,7 +60,20 @@ class LlamaConfig:
     num_hidden_layers: int = 16
     num_attention_heads: int = 16
     num_key_value_heads: Optional[int] = None   # None → MHA
+    # None → hidden_size // num_attention_heads (resolved at construction)
+    head_dim: Optional[int] = None
     intermediate_size: int = 5632
+    # width of one expert of ``moe``; None → intermediate_size
+    moe_intermediate_size: Optional[int] = None
+    # one entry a layer (more are ignored: a model cut in depth keeps its
+    # source's list), "sliding_attention" | "full_attention"; None → all full
+    layer_types: Optional[tuple] = None
+    # keys a "sliding_attention" layer keeps: 0 <= q_pos - k_pos < window
+    sliding_window: Optional[int] = None
+    # {layer type: {rope_type, rope_theta, factor, ...}} (ops/rotary.py
+    # rotary_table's arguments), or one such dict for every layer;
+    # None → rope_theta alone
+    rope_parameters: Optional[Any] = None
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     initializer_range: float = 0.02
@@ -48,6 +82,10 @@ class LlamaConfig:
     scan_layers: bool = True
     remat: bool = False
     remat_policy: str = "nothing_saveable"
+    # False lets XLA merge a block's recomputation with its forward pass
+    # wherever memory allows (it then keeps what it would recompute); True
+    # fences the two apart, for a stack whose kept values do not fit
+    remat_prevent_cse: bool = False
     attn_impl: str = "auto"
     vocab_pad_multiple: int = 128
     # sparse FFN: a parallel.moe.MoEConfig replaces the dense SwiGLU MLP of
@@ -72,13 +110,72 @@ class LlamaConfig:
         m = self.vocab_pad_multiple
         return ((self.vocab_size + m - 1) // m) * m
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+    def __post_init__(self):
+        def frozen(x):      # json's lists and dicts, hashable
+            if isinstance(x, dict):
+                return tuple(sorted((k, frozen(v)) for k, v in x.items()))
+            return tuple(frozen(v) for v in x) if isinstance(x, list) else x
+
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim",
+                               self.hidden_size // self.num_attention_heads)
+        for name in ("layer_types", "rope_parameters"):
+            object.__setattr__(self, name, frozen(getattr(self, name)))
+        for t in self.layer_types or ():
+            if t not in (SLIDING, FULL_ATTENTION):
+                raise ValueError(f"layer_types holds {t!r}; {SLIDING!r} and "
+                                 f"{FULL_ATTENTION!r} are written")
+        if self.layer_types is not None:
+            if len(self.layer_types) < self.num_hidden_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers of "
+                    f"{self.num_hidden_layers}")
+            if SLIDING in self.kinds and not self.sliding_window:
+                raise ValueError(f"{SLIDING} layers need sliding_window")
+        if self.decode and self.per_layer_type:
+            raise NotImplementedError(
+                "decode=True with a sliding window (layer_types) or a "
+                "per-layer-type rotary table (rope_parameters): the cache "
+                "and the decode kernels know neither, and would run full "
+                "attention with one table")
+        if self.decode and self.moe is not None and not self.moe.holds_all:
+            raise NotImplementedError(
+                "decode=True with a share of the experts "
+                "(MoEConfig.routed_experts): the serving path has no "
+                "partial expert sum")
 
     @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def expert_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def kinds(self) -> tuple:
+        """The layer types of the stack, one a layer; () without any."""
+        return (self.layer_types or ())[:self.num_hidden_layers]
+
+    @property
+    def per_layer_type(self) -> bool:
+        return bool(self.kinds) or self.rope_parameters is not None
+
+    def window(self, kind: Optional[str]) -> Optional[int]:
+        return self.sliding_window if kind == SLIDING else None
+
+    def rotary(self, kind: Optional[str]):
+        """The ``ops.rotary.RotaryTable`` of a layer type, made once a
+        type; None where ``rope_theta`` alone says it."""
+        if self.rope_parameters is None:
+            return None
+        from ..ops.rotary import rotary_table
+
+        entry = dict(self.rope_parameters)
+        if "rope_type" not in entry:        # keyed by layer type
+            entry = dict(entry[kind or FULL_ATTENTION])
+        entry.setdefault("rope_theta", self.rope_theta)
+        return rotary_table(self.head_dim, **entry)
 
 
 PRESETS = {
@@ -133,6 +230,7 @@ class RMSNorm(nn.Module):
 
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
+    kind: Optional[str] = None      # the layer's type; None: full, one table
 
     def _cache_append(self, k, v):
         from .common import append_kv_cache
@@ -191,8 +289,15 @@ class LlamaAttention(nn.Module):
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         v = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="v_proj",
                    module=self).reshape(B, S, KV, D)
-        q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
-                                    theta=cfg.rope_theta)
+        # a layer type's own table and device scopes only where the
+        # configuration names layer types: other models' traces stay as
+        # they were
+        typed = self.kind is not None or cfg.rope_parameters is not None
+        with trace.device_span(f"rope/{self.kind or FULL_ATTENTION}") \
+                if typed else contextlib.nullcontext():
+            q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
+                                        theta=cfg.rope_theta,
+                                        table=cfg.rotary(self.kind))
         if cfg.decode:
             kc, vc, cur = self._cache_append(k, v)
             # shared fused-or-fallback dispatch; GQA-aware (KV panels stay
@@ -203,13 +308,15 @@ class LlamaAttention(nn.Module):
             y = y.reshape(B, S, H * D)
             return _dense(y, E, ("heads", "embed"), cfg=cfg,
                           name="o_proj", module=self)
-        k_full, v_full = k, v
-        if KV != H:  # GQA: repeat kv heads
-            rep = H // KV
-            k_full = jnp.repeat(k_full, rep, axis=2)
-            v_full = jnp.repeat(v_full, rep, axis=2)
-        y = dot_product_attention(q, k_full, v_full, causal=True,
-                                  mask=attn_mask, impl=cfg.attn_impl)
+        # k and v go at their own heads: the kernel and the XLA path read
+        # key-value head h // (H // KV) for query head h.  The scope names
+        # a layer type's kernels in the device trace
+        window = cfg.window(self.kind)
+        scope = "self_attn_window" if window else "self_attn_full"
+        with trace.device_span(scope) if self.kind is not None \
+                else contextlib.nullcontext():
+            y = dot_product_attention(q, k, v, causal=True, mask=attn_mask,
+                                      window=window, impl=cfg.attn_impl)
         y = y.reshape(B, S, H * D)
         return _dense(y, E, ("heads", "embed"), cfg=cfg, name="o_proj", module=self)
 
@@ -217,6 +324,7 @@ class LlamaAttention(nn.Module):
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
+    kind: Optional[str] = None      # this layer's entry of cfg.layer_types
 
     @nn.compact
     def __call__(self, x, inputs):
@@ -252,7 +360,7 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
-        x = x + LlamaAttention(cfg, name="self_attn")(
+        x = x + LlamaAttention(cfg, self.kind, name="self_attn")(
             RMSNorm(cfg, name="input_norm")(x), position_ids, attn_mask)
         h = RMSNorm(cfg, name="post_attention_norm")(x)
         if cfg.moe is not None:
@@ -260,7 +368,7 @@ class LlamaBlock(nn.Module):
 
             ff, aux, stats = MoELayer(
                 cfg.moe, model_dim=cfg.hidden_size,
-                hidden_dim=cfg.intermediate_size, dtype=cfg.dtype,
+                hidden_dim=cfg.expert_size, dtype=cfg.dtype,
                 name="moe")(h, train=not self.deterministic,
                             return_stats=True)
             return x + ff, dict(stats, aux_loss=aux)
@@ -297,8 +405,14 @@ class LlamaForCausalLM(nn.Module):
         if cfg.remat:
             block_cls = nn.remat(
                 LlamaBlock, policy=resolve_remat_policy(cfg.remat_policy),
-                prevent_cse=False)
+                prevent_cse=cfg.remat_prevent_cse)
+        kinds = cfg.kinds
         if cfg.scan_layers:
+            if len(set(kinds)) > 1:
+                raise NotImplementedError(
+                    f"scan_layers=True scans one kind of block, and "
+                    f"layer_types mixes {sorted(set(kinds))}: set "
+                    f"scan_layers=False (the stack is then unrolled)")
             stack = nn.scan(block_cls,
                             variable_axes={"params": 0, "cache": 0},
                             split_rngs={"params": True, "dropout": True,
@@ -306,13 +420,13 @@ class LlamaForCausalLM(nn.Module):
                             length=cfg.num_hidden_layers,
                             in_axes=nn.broadcast,
                             metadata_params={nn.meta.PARTITION_NAME: "layers"})
-            h, per_layer = stack(cfg, deterministic, name="layers")(
-                h, (position_ids, mask))
+            h, per_layer = stack(cfg, deterministic, *kinds[:1],
+                                 name="layers")(h, (position_ids, mask))
         else:
             per_layer = []
             for i in range(cfg.num_hidden_layers):
-                h, ys = block_cls(cfg, deterministic, name=f"layers_{i}")(
-                    h, (position_ids, mask))
+                h, ys = block_cls(cfg, deterministic, *kinds[i:i + 1],
+                                  name=f"layers_{i}")(h, (position_ids, mask))
                 per_layer.append(ys)
             if cfg.moe is not None:
                 per_layer = jax.tree_util.tree_map(
@@ -373,11 +487,20 @@ class LlamaForCausalLM(nn.Module):
     def flops_per_token(self) -> float:
         cfg = self.cfg
         E, L = cfg.hidden_size, cfg.num_hidden_layers
-        D = cfg.head_dim
-        # a sparse FFN multiplies by top_k of its experts, and its router
+        D, H = cfg.head_dim, cfg.num_attention_heads
+        # a sparse FFN multiplies by the experts of a token's top_k that
+        # live here (all of them unless this instance holds a share), and
+        # its router
         ffn = 3 * E * cfg.intermediate_size
         if cfg.moe is not None:
-            ffn = ffn * cfg.moe.top_k + E * cfg.moe.num_experts
+            ffn = (3 * E * cfg.expert_size * cfg.moe.top_k
+                   * cfg.moe.num_experts / cfg.moe.routed
+                   + E * cfg.moe.routed)
         n = (2 * cfg.padded_vocab_size * E
-             + L * (E * E + 2 * E * cfg.kv_heads * D + E * E + ffn))
-        return 6.0 * n + 12 * L * E * cfg.max_position_embeddings
+             + L * (2 * E * H * D + 2 * E * cfg.kv_heads * D + ffn))
+        # QK^T and AV over the keys a layer keeps: all positions, or the
+        # window where that is shorter
+        S = cfg.max_position_embeddings
+        keys = sum(min(S, cfg.window(k) or S) for k in cfg.kinds) \
+            if cfg.kinds else L * S
+        return 6.0 * n + 12 * H * D * keys

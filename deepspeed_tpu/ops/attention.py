@@ -45,10 +45,11 @@ def _pick_impl(impl: str, q) -> tuple:
 
 def dot_product_attention(
     q: jax.Array,  # (B, S, H, D)
-    k: jax.Array,  # (B, T, H, D)
-    v: jax.Array,  # (B, T, H, D)
+    k: jax.Array,  # (B, T, KV, D): KV divides H, query head h reads h // (H // KV)
+    v: jax.Array,  # (B, T, KV, D)
     *,
     causal: bool = True,
+    window: Optional[int] = None,           # keep keys < window back (causal)
     bias: Optional[jax.Array] = None,       # broadcastable to (B, H, S, T)
     mask: Optional[jax.Array] = None,       # bool, True = attend
     dropout_rate: float = 0.0,
@@ -63,7 +64,26 @@ def dot_product_attention(
     sequence dim must be sharded on the ``sp`` mesh axis (the engine does
     this when ``mesh sp > 1``); a partial-manual shard_map runs the ring /
     all-to-all exchange while every other axis stays automatic.
+
+    Grouped queries and a sliding ``window`` are the flash kernel's and
+    the XLA path's; where neither takes them as they are (the sequence-
+    parallel and stock-JAX paths, a head_dim the kernel cannot group, heads
+    split over ``tp``), k and v are repeated to q's heads first, and a
+    window raises rather than run full attention.
     """
+    group = q.shape[2] // k.shape[2]
+    if q.shape[2] != group * k.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads are no multiple of "
+                         f"{k.shape[2]} key-value heads")
+    if window is not None and not causal:
+        raise ValueError("a sliding window is causal")
+    if impl in ("ring", "ulysses", "flash_jax"):
+        if window is not None:
+            raise NotImplementedError(
+                f"impl={impl!r} has no sliding window; 'flash', 'jnp' and "
+                f"'auto' do")
+        k, v = _repeat_kv(k, v, group)
+        group = 1
     if impl in ("ring", "ulysses"):
         return _sp_attention(q, k, v, causal=causal, scale=scale, kind=impl)
     if impl == "skip":
@@ -85,7 +105,8 @@ def dot_product_attention(
             reason = "bias/mask/dropout need the XLA path"
         else:
             out = _flash_spmd(q, k, v, causal=causal, scale=scale,
-                              flash_opts=flash_opts) if impl == "flash" \
+                              window=window, flash_opts=flash_opts) \
+                if impl == "flash" \
                 else _flash_jax(q, k, v, causal=causal, scale=scale)
             if out is not None:
                 verdict, axes = kernel_mesh_plan(q.shape[0], heads=q.shape[2],
@@ -100,16 +121,38 @@ def dot_product_attention(
                         else get_mesh().shape.get("tp", 1)
                     plan += "; " + flash_lanes(q.shape[2] // tp,
                                                q.shape[3]).reason
+                    if window is not None:
+                        plan += f"; window {window}"
+                    if group > 1:
+                        plan += (f"; {group} query heads a key-value head"
+                                 if _grouped_in_kernel(q, k, tp) else
+                                 f"; k and v repeated {group}x to q's heads")
                 note_dispatch("attention", impl, f"{reason}; {plan}")
                 return out
             reason = "kernel_mesh_plan refused the mesh"
     note_dispatch("attention", "jnp", reason)
     return _jnp_attention(q, k, v, causal=causal, bias=bias, mask=mask,
                           dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-                          scale=scale)
+                          scale=scale, window=window)
 
 
-def _flash_spmd(q, k, v, *, causal, scale, interpret=False, flash_opts=None):
+def _repeat_kv(k, v, group: int):
+    """k and v at q's heads: each key-value head ``group`` times over."""
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+def _grouped_in_kernel(q, k, tp: int) -> bool:
+    """Whether the flash kernel takes k and v at their own heads: one head
+    a lane block, and whole key-value heads on every ``tp`` rank."""
+    from .pallas.flash_attention import grouped_in_kernel
+
+    return grouped_in_kernel(q.shape[3]) and k.shape[2] % tp == 0
+
+
+def _flash_spmd(q, k, v, *, causal, scale, window=None, interpret=False,
+                flash_opts=None):
     """Flash kernel, SPMD-correct: on a multi-device mesh the pallas_call is
     opaque to the partitioner (XLA would gather operands), so shard_map it
     over the batch (dp/fsdp/ep) and head (tp) axes — attention is
@@ -129,6 +172,11 @@ def _flash_spmd(q, k, v, *, causal, scale, interpret=False, flash_opts=None):
         return None
     kern = partial(flash_attention, causal=causal, scale=scale,
                    interpret=interpret, **(flash_opts or {}))
+    if window is not None:      # an absent keyword leaves old traces alone
+        kern = partial(kern, window=window)
+    tp = 1 if verdict == "direct" else get_mesh().shape.get("tp", 1)
+    if not _grouped_in_kernel(q, k, tp):
+        k, v = _repeat_kv(k, v, H // k.shape[2])
     if verdict == "direct":
         return kern(q, k, v)
     return _shard_over_batch_heads(kern, batch_axes)(q, k, v)
@@ -344,20 +392,30 @@ def _sp_attention(q, k, v, *, causal, scale, kind):
     return mapped(q, k, v)
 
 
-def _jnp_attention(q, k, v, *, causal, bias, mask, dropout_rate, dropout_rng, scale):
-    _, s_q, _, d = q.shape
-    s_k = k.shape[1]
+def _jnp_attention(q, k, v, *, causal, bias, mask, dropout_rate, dropout_rng,
+                   scale, window=None):
+    b, s_q, h, d = q.shape
+    s_k, kv = k.shape[1], k.shape[2]
     if scale is None:
         scale = d ** -0.5
     # fp32 softmax for stability (the reference kernel does fp32 accumulation
     # in its fused softmax, softmax_kernels.cu)
-    scores = jnp.einsum("bshd,bthd->bhst", q, k,
-                        preferred_element_type=jnp.float32) * scale
+    if kv != h:     # grouped queries: a key-value head's queries together
+        scores = jnp.einsum("bskgd,btkd->bkgst",
+                            q.reshape(b, s_q, kv, h // kv, d), k,
+                            preferred_element_type=jnp.float32
+                            ).reshape(b, h, s_q, s_k) * scale
+    else:
+        scores = jnp.einsum("bshd,bthd->bhst", q, k,
+                            preferred_element_type=jnp.float32) * scale
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     neg = jnp.finfo(scores.dtype).min
     if causal:
         causal_mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:      # ... and less than ``window`` back
+            causal_mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                                     k=s_k - s_q - window)
         scores = jnp.where(causal_mask[None, None, :, :], scores, neg)
     if mask is not None:
         scores = jnp.where(mask, scores, neg)
@@ -366,4 +424,8 @@ def _jnp_attention(q, k, v, *, causal, bias, mask, dropout_rate, dropout_rng, sc
         keep = jax.random.bernoulli(dropout_rng, 1.0 - dropout_rate, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_rate), 0.0)
     probs = probs.astype(v.dtype)
+    if kv != h:
+        return jnp.einsum("bkgst,btkd->bskgd",
+                          probs.reshape(b, kv, h // kv, s_q, s_k), v
+                          ).reshape(b, s_q, h, d)
     return jnp.einsum("bhst,bthd->bshd", probs, v)

@@ -27,10 +27,12 @@ at shapes the tiles do not divide, it is ``jax.lax.ragged_dot``;
 ``repeat_gather`` / ``unsort_rows`` move rows into expert order and back.
 Each is a row gather whose transpose XLA would write as a scatter-add;
 both know the inverse permutation, so their backward passes are gathers
-too.
+too.  For a share of the experts the permutation is partial (``absent``):
+pairs held elsewhere have no row and rows past the groups no pair.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -48,8 +50,10 @@ def _tiles(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
     rows come in whole tiles; the backward reuses the tiles with the
     contraction and column sizes swapped."""
     def fit(size, tile):
-        return next((t for t in (tile, tile // 2, tile // 4, 128)
-                     if t <= size and size % t == 0), None)
+        # the largest multiple of 128 up to ``tile`` that divides: 1024 of
+        # 2048, 768 of 2304, 896 of 896
+        return next((t for t in range(min(tile, size) // 128 * 128, 0, -128)
+                     if size % t == 0), None)
 
     tm, tk, tn = fit(m, TILES[0]), fit(k, TILES[1]), fit(n, TILES[2])
     return None if None in (tm, tk, tn) else (tm, tk, tn)
@@ -93,39 +97,55 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                tiling=tiles)
 
 
-@jax.custom_vjp
-def repeat_gather(x: jax.Array, order: jax.Array, inv: jax.Array) -> jax.Array:
+def _rows(a: jax.Array, index: jax.Array, absent: bool) -> jax.Array:
+    """``a[index]`` by rows.  With ``absent`` an index of ``a.shape[0]``
+    stands for "no row" and reads zeros; without, every index is a row
+    (the gather the sorted dispatch has always made)."""
+    if absent:
+        return jnp.take(a, index, axis=0, mode="fill", fill_value=0)
+    return jnp.take(a, index, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def repeat_gather(x: jax.Array, order: jax.Array, inv: jax.Array,
+                  absent: bool = False) -> jax.Array:
     """``x`` (S, M), each row wanted ``k`` times -> (S*k, M) with row ``j``
     = ``x[order[j] // k]``; ``order`` is a permutation of ``range(S*k)``
-    and ``inv`` its inverse."""
-    return jnp.take(x, order // (order.shape[0] // x.shape[0]), axis=0)
+    and ``inv`` its inverse.  With ``absent`` (a share of the experts)
+    both are partial: ``order[j] = S*k`` where row j holds no pair (it
+    reads zeros) and ``inv[p] = S*k`` where pair p has no row (it gives
+    its token no gradient)."""
+    return _rows(x, order // (order.shape[0] // x.shape[0]), absent)
 
 
-def _repeat_gather_fwd(x, order, inv):
-    return repeat_gather(x, order, inv), (inv, x.shape[0])
+def _repeat_gather_fwd(x, order, inv, absent):
+    return repeat_gather(x, order, inv, absent), (inv, x.shape[0])
 
 
-def _repeat_gather_bwd(res, g):
+def _repeat_gather_bwd(absent, res, g):
     inv, S = res
-    gx = jnp.take(g, inv, axis=0).reshape(S, -1, g.shape[-1])
+    gx = _rows(g, inv, absent).reshape(S, -1, g.shape[-1])
     return gx.sum(axis=1).astype(g.dtype), None, None
 
 
 repeat_gather.defvjp(_repeat_gather_fwd, _repeat_gather_bwd)
 
 
-@jax.custom_vjp
-def unsort_rows(y: jax.Array, order: jax.Array, inv: jax.Array) -> jax.Array:
-    """Undo the sort: ``out[order[j]] = y[j]``, computed as ``y[inv]``."""
-    return jnp.take(y, inv, axis=0)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def unsort_rows(y: jax.Array, order: jax.Array, inv: jax.Array,
+                absent: bool = False) -> jax.Array:
+    """Undo the sort: ``out[order[j]] = y[j]``, computed as ``y[inv]``
+    (``absent``: as :func:`repeat_gather`; a pair without a row reads
+    zeros, whatever the rows past the groups hold)."""
+    return _rows(y, inv, absent)
 
 
-def _unsort_rows_fwd(y, order, inv):
-    return unsort_rows(y, order, inv), order
+def _unsort_rows_fwd(y, order, inv, absent):
+    return unsort_rows(y, order, inv, absent), order
 
 
-def _unsort_rows_bwd(order, g):
-    return jnp.take(g, order, axis=0), None, None
+def _unsort_rows_bwd(absent, order, g):
+    return _rows(g, order, absent), None, None
 
 
 unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)

@@ -18,11 +18,17 @@ Backward uses the saved logsumexp to recompute P blockwise in ONE kernel
 per k-block that feeds dq, dk and dv from a single ds, and writes them in
 the operands' own types.
 
-Both kernels follow one causal tile schedule
-(:func:`score_tile_schedule`): a score tile wholly above the diagonal
-runs no code, one wholly below it builds no mask, and one the diagonal
-crosses is masked (the backward walks it in half-edge sub-tiles, each
-classed the same way).
+Both kernels follow one tile schedule (:func:`score_tile_schedule`): a
+score tile wholly above the diagonal, or wholly past a sliding ``window``
+below it, runs no code, one wholly inside the band builds no mask, and one
+that the diagonal or the band's lower edge crosses is masked (the backward
+walks it in half-edge sub-tiles, each classed the same way).
+
+Grouped queries (``k`` and ``v`` narrower than ``q``, head_dim a multiple
+of 128): query lane block ``c`` reads key-value lane block ``c // group``,
+and the backward sums one key-value head's ``dk`` and ``dv`` over the
+programs of its query heads in VMEM, so nothing key- or value-shaped is
+ever as wide as ``q``.
 
 All kernels run under ``interpret=True`` on CPU for tests.
 """
@@ -46,19 +52,30 @@ NEG_INF = float("-inf")
 UNROLL_MAX = 4          # static-unroll K/Q sweeps at or below this length
 
 VOID, FULL, DIAGONAL = "void", "full", "diagonal"
+# the band's lower edge crosses the tile; both edges do (window < a tile)
+BAND_EDGE, CROSSED = "band_edge", "diagonal+band_edge"
+MASKED = (DIAGONAL, BAND_EDGE, CROSSED)
 
 
 # ---------------------------------------------------------------------------
 # The causal tile schedule: which score tiles exist, and what each needs
 # ---------------------------------------------------------------------------
 
-def _tile_kind(d: int, rows: int, cols: int, causal: bool) -> str:
+def _tile_kind(d: int, rows: int, cols: int, causal: bool,
+               window: Optional[int] = None) -> str:
     """Class of the rows×cols score tile whose first query position lies
-    ``d`` after its first key position: VOID has no ``q_pos >= k_pos``
-    entry, FULL has nothing else, DIAGONAL has both."""
-    if not causal or d >= cols - 1:
-        return FULL
-    return VOID if d <= -rows else DIAGONAL
+    ``d`` after its first key position, by the entries it keeps: those
+    with ``0 <= q_pos - k_pos`` (causal) ``< window`` (a sliding window).
+    VOID keeps none, FULL all; DIAGONAL loses some above the diagonal,
+    BAND_EDGE some past the window, CROSSED some of both."""
+    lo, hi = d - (cols - 1), d + rows - 1       # extremes of q_pos - k_pos
+    if (causal and hi < 0) or (window is not None and lo >= window):
+        return VOID
+    above = causal and lo < 0
+    below = window is not None and hi >= window
+    if above:
+        return CROSSED if below else DIAGONAL
+    return BAND_EDGE if below else FULL
 
 
 class TileSchedule(NamedTuple):
@@ -69,48 +86,57 @@ class TileSchedule(NamedTuple):
     causal: bool
     sub_q: int
     sub_k: int
-    # what a kernel walks inside a (block_q × block_k) tile the diagonal
-    # crosses, keyed by the tile's offset d0 = q0 - k0:
+    # what a kernel walks inside a (block_q × block_k) tile that an edge of
+    # the band crosses, keyed by the tile's offset d0 = q0 - k0:
     # ((d0, ((r0, c0, kind), ...)), ...), void sub-tiles left out
     diagonal: tuple
     # every (sub_q × sub_k) sub-tile of one head-sequence, (q0, k0, kind):
     # what the kernels' sweeps amount to, and what the counter counts
     tiles: tuple
+    window: Optional[int] = None
 
 
 @functools.lru_cache(maxsize=None)
 def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
-                        causal: bool, halve_diagonal: bool) -> TileSchedule:
+                        causal: bool, halve_diagonal: bool,
+                        window: Optional[int] = None) -> TileSchedule:
     """The score tiles of one head-sequence, from shapes alone.  The DMA
-    blocks stay (block_q × block_k).  With ``halve_diagonal`` a tile the
-    diagonal crosses is walked in sub-tiles of half its edge (while that
-    stays a multiple of the 128-lane tile), so that of its four quarters
-    one is void, one full and two are masked; the backward does, the
-    forward does not (its online-softmax steps chain, and three quarter
-    steps cost more than one whole one: PERF.md §6, PR 25)."""
+    blocks stay (block_q × block_k).  With ``halve_diagonal`` a tile that
+    an edge of the band crosses is walked in sub-tiles of half its edge
+    (while that stays a multiple of the 128-lane tile), so that of its
+    four quarters one is void, one full and two are masked; the backward
+    does, the forward does not (its online-softmax steps chain, and three
+    quarter steps cost more than one whole one: PERF.md §6, PR 25).
+    ``window`` keeps, of the causal entries, those less than ``window``
+    positions back: per 512-row query block at window 1024 one diagonal,
+    one full and one band-edge tile, however long the sequence."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"a sliding window ({window}) is causal and >= 1")
+
     def half(block):
         return block // 2 if halve_diagonal and block % 256 == 0 else block
 
     sq, sk = half(block_q), half(block_k)
 
     def subs(d0):
-        return tuple((r0, c0, _tile_kind(d0 + r0 - c0, sq, sk, causal))
+        return tuple((r0, c0, _tile_kind(d0 + r0 - c0, sq, sk, causal, window))
                      for r0 in range(0, block_q, sq)
                      for c0 in range(0, block_k, sk))
 
     step = math.gcd(block_q, block_k)
+    last = block_k if window is None else window + block_k
     diagonal = tuple(
         (d0, tuple(s for s in subs(d0) if s[2] != VOID))
-        for d0 in range(-(block_q // step - 1) * step, block_k, step)
-        if _tile_kind(d0, block_q, block_k, causal) == DIAGONAL)
+        for d0 in range(-(block_q // step - 1) * step, last, step)
+        if _tile_kind(d0, block_q, block_k, causal, window) in MASKED)
     tiles = []
     for q0 in range(0, S, block_q):
         for k0 in range(0, Sk, block_k):
-            kind = _tile_kind(q0 - k0, block_q, block_k, causal)
-            tiles += [(q0 + r0, k0 + c0, sub if kind == DIAGONAL else kind)
+            kind = _tile_kind(q0 - k0, block_q, block_k, causal, window)
+            tiles += [(q0 + r0, k0 + c0, sub if kind in MASKED else kind)
                       for r0, c0, sub in subs(q0 - k0)]
     return TileSchedule(S, Sk, block_q, block_k, causal, sq, sk, diagonal,
-                        tuple(tiles))
+                        tuple(tiles), window)
 
 
 def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
@@ -120,7 +146,8 @@ def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
         "flash_score_tiles_total",
         "score sub-tiles of one head-sequence by what the flash kernel "
         "does with them: void runs no code, full builds no mask, diagonal "
-        "is masked (counted at trace time, not per call)",
+        "and band_edge (a sliding window's lower edge) are masked (counted "
+        "at trace time, not per call)",
         labelnames=("pass", "kind"))
     for kind, n in collections.Counter(t[2] for t in sched.tiles).items():
         family.labels(pass_, kind).inc(n)
@@ -129,18 +156,29 @@ def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
 def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     """``(lo, hi)``: the swept tiles ``[lo, hi)`` that are FULL for the
     program that owns tile ``own`` (a python int or the traced program
-    id).  The forward owns a query tile and sweeps key tiles, the full
-    ones come first; the backward owns a key tile and sweeps query tiles,
-    the full ones come last."""
+    id); empty where ``lo >= hi``.  The forward owns a query tile and
+    sweeps key tiles, the full ones come first; the backward owns a key
+    tile and sweeps query tiles, the full ones come last.  A window cuts
+    the run at its other end."""
     bq, bk = sched.block_q, sched.block_k
     nq, nk = sched.S // bq, sched.Sk // bk
     if not sched.causal:
         return 0, (nk if own_is_q else nq)
-    lowest = jnp.minimum if isinstance(own, jax.Array) else min
+    traced = isinstance(own, jax.Array)
+    lowest, highest = (jnp.minimum, jnp.maximum) if traced else (min, max)
+    w = sched.window
     if own_is_q:    # FULL: k0 + bk - 1 <= q0
-        return 0, lowest(nk, (own * bq + 1) // bk)
+        hi = lowest(nk, (own * bq + 1) // bk)
+        if w is None:
+            return 0, hi
+        # ... and q0 + bq - 1 - k0 < window
+        return highest(0, (own * bq + bq - w + bk - 1) // bk), hi
     # FULL: q0 >= k0 + bk - 1, from the first such query tile on
-    return lowest(nq, ((own + 1) * bk - 1 + bq - 1) // bq), nq
+    lo = lowest(nq, ((own + 1) * bk - 1 + bq - 1) // bq)
+    if w is None:
+        return lo, nq
+    # ... to the last with q0 + bq - 1 - k0 < window
+    return lo, lowest(nq, (own * bk + w - bq) // bq + 1)
 
 
 def _is_looped(sched: TileSchedule, *, own_is_q: bool) -> bool:
@@ -219,12 +257,19 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
                 functools.partial(program, static_sweep(o), False))
 
 
-def _causal_mask(s, d: int):
-    """Void the entries of score tile ``s`` with ``q_pos < k_pos``, its
-    first query position lying ``d`` after its first key position."""
+def _band_mask(s, d: int, kind: str, window: Optional[int]):
+    """Void the entries of score tile ``s`` outside the band, its first
+    query position lying ``d`` after its first key position: those with
+    ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
+    window`` in a BAND_EDGE one, both in a CROSSED one."""
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(row + d >= col, s, NEG_INF)
+    if kind == DIAGONAL:
+        return jnp.where(row + d >= col, s, NEG_INF)
+    inside = row + (d - window) < col
+    if kind == CROSSED:
+        inside &= row + d >= col
+    return jnp.where(inside, s, NEG_INF)
 
 
 def _col(x):
@@ -283,6 +328,12 @@ class Lanes(NamedTuple):
         return (f"rows layout, {self.heads} "
                 f"head{'s' if self.heads > 1 else ''} a {self.block}-lane "
                 f"block")
+
+
+def grouped_in_kernel(D: int) -> bool:
+    """Whether the kernels take fewer key-value heads than query heads at
+    this head_dim (one head a lane block), from the shape alone."""
+    return D % 128 == 0
 
 
 def flash_lanes(H: int, D: int) -> Lanes:
@@ -350,10 +401,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
             q = q_ref[0].astype(jnp.float32) * scale             # (bq, L)
             qs = [_head_lanes(q, h, lanes) for h in range(heads)]
 
-            def fold(k0, carry, d=None):
+            def fold(k0, carry, d=None, kind=None):
                 """One online-softmax step a head: key tile [k0,
                 +block_k) into each ``(m, l, acc)``; ``d`` is the mask
-                offset, None for a FULL tile."""
+                offset and ``kind`` the tile's, None for a FULL tile."""
                 ks = pl.ds(k0, sched.block_k)
                 k = _keep_lanes(k_ref[0, ks].astype(jnp.float32), 0, limit)
                 v = v_ref[0, ks].astype(jnp.float32)
@@ -361,7 +412,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
                 for q_h, (m, l, acc) in zip(qs, carry):
                     s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
                     if d is not None:
-                        s = _causal_mask(s, d)
+                        s = _band_mask(s, d, kind, sched.window)
                     m_new = jnp.maximum(m, s.max(axis=-1))
                     # rows with everything masked keep m=-inf; keep exp
                     # well-defined
@@ -374,7 +425,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
 
             def diagonal_tile(k0, d0, subs, carry):
                 assert len(subs) == 1   # the forward leaves them whole
-                return fold(k0, carry, d0)
+                return fold(k0, carry, d0, subs[0][2])
 
             carry = sweep(((jnp.full((bq,), NEG_INF, jnp.float32),
                             jnp.zeros((bq,), jnp.float32),
@@ -397,7 +448,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
 
 def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                  dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale, sched,
-                 lanes):
+                 lanes, group=1):
     """Backward: dq, dk AND dv in ONE grid pass over k-blocks.
 
     ds is computed once per score tile and head and feeds all three
@@ -410,10 +461,22 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv are summed block-wide as values, one a head and ``sub_k`` band of
     the program's keys, and each head's lanes picked at the store; a
     looped sweep sums them in ``kv_acc`` (a loop would carry them through
-    VMEM anyway).  dk carries ``scale`` via the pre-scaled q."""
+    VMEM anyway).  dk carries ``scale`` via the pre-scaled q.
+
+    Grouped queries (``group`` query heads a key-value head, one head a
+    lane block): the ``group`` consecutive lane-block programs of one
+    key-value head add their dk and dv, key tile by key tile, in the
+    whole-sequence float32 scratch that ends ``kv_acc``; every program
+    stores the running sum, and the output's block index moves on from
+    key tile 0 only under the group's last program (:func:`_bwd_call`),
+    so what reaches HBM is each tile's complete sum, once."""
     bk, L = k_ref.shape[1:]
     sk = sched.sub_k
     j = pl.program_id(2)
+    group_dk = group_dv = None
+    if group > 1:
+        *kv_acc, group_dk, group_dv = kv_acc
+        first_of_group = pl.program_id(1) % group == 0
 
     @pl.when(j == 0)
     def _init_dq():
@@ -429,9 +492,10 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             vs = [_head_lanes(v_blk, h, lanes) for h in heads]
 
             def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk,
-                      d=None):
+                      d=None, kind=None):
                 """Queries [q0+r0, +rows) against keys [c0, +cols) of the
-                program's block; ``d`` is the mask offset, None for FULL.
+                program's block; ``d`` is the mask offset and ``kind`` the
+                tile's, None for FULL.
                 ``sums`` maps each head and key band to its ``(dk, dv)``
                 so far, or is None where they are summed in ``kv_acc``."""
                 rs = pl.ds(q0 + r0, rows)
@@ -444,7 +508,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
                     s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
                     if d is not None:
-                        s = _causal_mask(s, d)
+                        s = _band_mask(s, d, kind, sched.window)
                     p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
                     dv = _dot(p, do, ((0,), (0,)))
                     dp = _dot(do, v, ((1,), (1,)))
@@ -465,25 +529,34 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             def diagonal_tile(q0, d0, subs, sums):
                 for r0, c0, kind in subs:
                     sums = visit(q0, sums, r0, c0, sched.sub_q, sk,
-                                 None if kind == FULL else d0 + r0 - c0)
+                                 None if kind == FULL else d0 + r0 - c0, kind)
                 return sums
 
-            def store(ref, rows, per_head):
-                ref[0, rows] = _own_lanes(per_head, lanes).astype(ref.dtype)
+            def store(ref, group_acc, b, size, per_head):
+                """Keys [b, +size) of the program's block, summed over the
+                key-value head's query heads where those are grouped."""
+                val = _own_lanes(per_head, lanes)
+                if group > 1:
+                    at = pl.ds(j * bk + b, size)
+                    # a select: the scratch holds anything before the
+                    # group's first program has written it
+                    val = val + jnp.where(first_of_group, 0.0, group_acc[at])
+                    group_acc[at] = val
+                ref[0, pl.ds(b, size)] = val.astype(ref.dtype)
 
             if looped:
                 for acc in kv_acc:
                     acc[...] = jnp.zeros(acc.shape, acc.dtype)
                 sweep(None, visit, diagonal_tile)
-                store(dk_ref, slice(None), [kv_acc[0][h] for h in heads])
-                store(dv_ref, slice(None), [kv_acc[1][h] for h in heads])
+                store(dk_ref, group_dk, 0, bk, [kv_acc[0][h] for h in heads])
+                store(dv_ref, group_dv, 0, bk, [kv_acc[1][h] for h in heads])
                 return
             zero = jnp.zeros((sk, L), jnp.float32)
             sums = sweep({(h, b): (zero, zero) for h in heads
                           for b in range(0, bk, sk)}, visit, diagonal_tile)
             for b in range(0, bk, sk):
-                store(dk_ref, pl.ds(b, sk), [sums[h, b][0] for h in heads])
-                store(dv_ref, pl.ds(b, sk), [sums[h, b][1] for h in heads])
+                store(dk_ref, group_dk, b, sk, [sums[h, b][0] for h in heads])
+                store(dv_ref, group_dv, b, sk, [sums[h, b][1] for h in heads])
 
         _for_program(j, sched, program, own_is_q=False)
 
@@ -560,10 +633,11 @@ def _delta(do, out, lanes: Lanes):
                       ).reshape(N, lanes.blocks, lanes.heads, S)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
+           window=None):
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                        interpret)
+                        interpret, window)
     return out
 
 
@@ -571,22 +645,53 @@ def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
 # stays in the caller's jaxpr, but its trace cache means a model traces
 # each kernel body once, not once a layer and remat pass (a step of the
 # 48-layer benchmark model stages ~200 flash calls).
-_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret")
+_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret",
+           "window")
+# Mosaic gives a kernel 16 MB of VMEM unless told otherwise; the v5e has
+# 128.  A kernel whose blocks and scratch come near the default asks for
+# what it needs and this much again for the values of its body.
+_VMEM_DEFAULT, _VMEM_HEADROOM = 12 << 20, 16 << 20
+
+
+def _vmem(need: int) -> dict:
+    """``pallas_call`` keywords for a kernel that holds ``need`` bytes of
+    blocks (double-buffered) and scratch: none below the default."""
+    if need <= _VMEM_DEFAULT:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need + _VMEM_HEADROOM)}
+
+
+def _group(q, k, lanes: Lanes) -> int:
+    """Query heads a key-value head, from the operands' widths."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1 and (lanes.heads != 1 or not lanes.rows):
+        raise ValueError(
+            f"grouped queries in the kernel need one head a lane block "
+            f"(head_dim a multiple of 128), got head_dim {lanes.head_dim}; "
+            f"repeat k and v to q's heads instead")
+    return group
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret):
+def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret,
+              window=None):
     N, S, W = q.shape
     Sk = k.shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
-    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False)
+    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False,
+                                window)
+    group = _group(q, k, lanes)
+    panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c))
+    if group > 1:       # fetched once a key-value head: its index holds
+        panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c // group))
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, sched=sched, lanes=lanes),
         grid=(N, NB, S // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
-            pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c)),
-            pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c)),
+            panel,
+            panel,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
@@ -597,15 +702,17 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret):
             jax.ShapeDtypeStruct((N, NB, P, S), jnp.float32),
         ],
         interpret=interpret,
+        **_vmem(4 * Sk * L * k.dtype.itemsize + 4 * block_q * L * 4),
     )(q, k, v)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
+               window=None):
     _note_score_tiles("fwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, False))
+        q.shape[1], k.shape[1], block_q, block_k, causal, False, window))
     out, lse = _fwd_call(q, k, v, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k, lanes=lanes,
-                         interpret=interpret)
+                         interpret=interpret, window=window)
     # named so a "<policy>+flash" remat policy can SAVE the kernel's
     # residuals: out/lse aren't dot outputs, so dots_saveable alone
     # recomputes the whole fwd kernel inside every backward pass
@@ -614,48 +721,66 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, res, do):
+def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, window,
+               res, do):
     q, k, v, out, lse = res
     return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, _delta(do, out, lanes))
+                           q, k, v, lse, do, _delta(do, out, lanes), window)
 
 
 def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                    q, k, v, lse, do, delta):
+                    q, k, v, lse, do, delta, window=None):
     _note_score_tiles("bwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, True))
+        q.shape[1], k.shape[1], block_q, block_k, causal, True, window))
     return _bwd_call(q, k, v, do, lse, delta, causal=causal, scale=scale,
                      block_q=block_q, block_k=block_k, lanes=lanes,
-                     interpret=interpret)
+                     interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_call(q, k, v, do, lse, delta, *, causal, scale, block_q, block_k,
-              lanes, interpret):
+              lanes, interpret, window=None):
     N, S, W = q.shape
     Sk = k.shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
-    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True)
+    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True, window)
+    group = _group(q, k, lanes)
     panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
-    block = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
+    block = grads = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
     rows = pl.BlockSpec((1, 1, P, S), lambda n, c, j: (n, c, 0, 0))
     scratch = [pltpu.VMEM((S, L), jnp.float32)]          # dq, summed over j
     if _is_looped(sched, own_is_q=False):                # dk and dv a head
         scratch += [pltpu.VMEM((P, block_k, L), jnp.float32)] * 2
+    kernel = functools.partial(_dqkv_kernel, scale=scale, sched=sched,
+                               lanes=lanes)
+    need = (4 * 2 + 2 * q.dtype.itemsize + 4) * S * L + 4 * 4 * 8 * S
+    if group > 1:
+        kernel = functools.partial(kernel, group=group)
+        block = pl.BlockSpec((1, block_k, L),
+                             lambda n, c, j: (n, j, c // group))
+        # dk and dv of a key-value head are whole under its last query
+        # head: until then the block stays at key tile 0 and nothing of
+        # it is written back
+        grads = pl.BlockSpec(
+            (1, block_k, L),
+            lambda n, c, j: (n, jnp.where(c % group == group - 1, j, 0),
+                             c // group))
+        scratch += [pltpu.VMEM((Sk, L), jnp.float32)] * 2
+        need += 2 * 4 * Sk * L
     return pl.pallas_call(
-        functools.partial(_dqkv_kernel, scale=scale, sched=sched,
-                          lanes=lanes),
+        kernel,
         grid=(N, NB, Sk // block_k),
         in_specs=[panel, block, block, panel, rows, rows],
         # dq's block ignores j: written once, when its sum is complete
-        out_specs=[panel, block, block],
+        out_specs=[panel, grads, grads],
         out_shape=[
             jax.ShapeDtypeStruct((N, S, W), q.dtype),
-            jax.ShapeDtypeStruct((N, Sk, W), k.dtype),
-            jax.ShapeDtypeStruct((N, Sk, W), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        **_vmem(need),
     )(q, k, v, do, lse, delta)
 
 
@@ -668,6 +793,11 @@ def _prepare(q, k, scale, block_q, block_k):
     if scale is None:
         scale = D ** -0.5
     lanes = flash_lanes(H, D)
+    if k.shape[2] != H and (H % k.shape[2] or not grouped_in_kernel(D)):
+        raise ValueError(
+            f"{H} query heads over {k.shape[2]} key-value heads of {D}: the "
+            f"kernel groups whole heads of a multiple of 128 lanes; repeat "
+            f"k and v to q's heads for any other shape")
     if lanes.block > 128:
         # a backward program keeps its keys, values and their gradients
         # in float32 beside whole-sequence panels of q, dO and dq: at 256
@@ -685,8 +815,12 @@ def _prepare(q, k, scale, block_q, block_k):
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = False) -> jax.Array:
-    """Public API, shapes ``(B, S, H, D)`` like ``ops.attention``.
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
+    """Public API, shapes ``(B, S, H, D)`` like ``ops.attention``; ``k``
+    and ``v`` may have fewer heads than ``q`` (query head h reads
+    key-value head ``h // (H // KV)``; head_dim a multiple of 128), and
+    ``window`` keeps, of the causal keys, the last ``window``.
 
     The kernels read q, k, v and dO and write o, dq, dk and dv as
     ``(B, S, H·D)``, the layout the projections on either side use, so
@@ -707,7 +841,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
     out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), causal,
-                 scale, block_q, block_k, lanes, interpret)
+                 scale, block_q, block_k, lanes, interpret, window)
     return _unpack(out, lanes, q.shape[0])
 
 

@@ -36,6 +36,16 @@ and the mesh, never from the environment):
   the ZeRO gather (``kernel_mesh_plan``, as the flash kernel).  ``ep > 1``
   raises (PERF.md section 7, row 8), it does not fall back to the
   capacity path.
+- ``drop_tokens=False`` with a share (``routed_experts`` more than
+  ``num_experts``): this instance holds experts ``first_expert`` to
+  ``first_expert + num_experts - 1`` of the ``routed_experts`` that the
+  router scores, as one chip of an expert-parallel group does.  Routing,
+  renormalisation, the losses and the counts are over all of them; the
+  same sorted dispatch sorts the pairs routed to a held expert to the
+  front of its row buffer, multiplies them in ``num_experts`` groups and
+  sums them back into their tokens; what the absent experts would have
+  added is left out, and there is no exchange and nothing that stands in
+  for one.
 - the opt-in gathered decode path (``DS_TPU_MOE_FAST``) of the capacity
   layer at <= 32 eval tokens.
 """
@@ -58,6 +68,8 @@ from ..telemetry import registry, trace
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    # experts whose leaves this instance holds (the leading dim of every
+    # expert leaf); all that there are, unless ``routed_experts`` says more
     num_experts: int = 8
     # drop_tokens=True: 1 or 2 (reference top1gating/top2gating);
     # drop_tokens=False: any 1 <= top_k <= num_experts
@@ -74,6 +86,34 @@ class MoEConfig:
     norm_topk_prob: bool = False
     z_loss_weight: float = 0.0          # router z-loss (ST-MoE), dropless only
     expert_act: str = "gelu"            # 'gelu': wi/wo; 'swiglu': gate/up/down
+    # a share of an expert-parallel layer (dropless only): the router
+    # scores ``routed_experts`` (None: ``num_experts``, no share) and this
+    # instance holds ``num_experts`` of them from ``first_expert`` on
+    routed_experts: Optional[int] = None
+    first_expert: int = 0
+
+    def __post_init__(self):
+        if self.routed_experts is None:
+            if self.first_expert:
+                raise ValueError("first_expert without routed_experts")
+            return
+        if not 0 <= self.first_expert <= self.routed - self.num_experts:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.num_experts - 1}"
+                f" are not among the {self.routed} the router scores")
+        if not self.holds_all and self.drop_tokens:
+            raise NotImplementedError(
+                "a share of the experts is written for the dropless sorted "
+                "dispatch (drop_tokens=False) only")
+
+    @property
+    def routed(self) -> int:
+        """Experts the router scores."""
+        return self.routed_experts or self.num_experts
+
+    @property
+    def holds_all(self) -> bool:
+        return self.routed == self.num_experts
 
 
 def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: int,
@@ -196,7 +236,7 @@ class TopKGate(nn.Module):
         cfg = self.cfg
         wg = self.param("wg", nn.with_partitioning(
             nn.initializers.normal(0.02), ("embed", "experts_gate")),
-            (self.model_dim, cfg.num_experts), jnp.float32)
+            (self.model_dim, cfg.routed), jnp.float32)
         xf = x.astype(jnp.float32)
         if train and cfg.noisy_gate_policy == "Jitter":
             rng = self.make_rng("gating")
@@ -252,12 +292,22 @@ def _expert_ffn(act: str, ws, x, matmul):
 
 
 def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
-                    ws: Tuple[jax.Array, ...], act: str) -> jax.Array:
+                    ws: Tuple[jax.Array, ...], act: str,
+                    first_expert: Optional[int] = None) -> jax.Array:
     """Dropless expert FFN of tokens ``x`` (S, M), token s going to experts
     ``chosen[s]`` (k of them) with ``weights[s]``; ``ws`` the (E, ., .)
     expert leaves.  Rows are sorted by expert, multiplied group by group and
     sorted back; no (token, choice) pair is left out and an expert nobody
     chose costs nothing.
+
+    ``first_expert`` (a share): ``ws`` holds experts ``first_expert`` to
+    ``first_expert + E - 1`` of those ``chosen`` names.  Pairs held
+    elsewhere are sorted behind the last group, where no matmul tile
+    covers them, and add nothing to their token: the buffer keeps a row
+    for every pair, so no routing, however collapsed onto the held
+    experts, drops one.  (A buffer of twice the share's even part of the
+    pairs ran 13% faster on the v5e and drops pairs once a layer collapses
+    onto the held experts: PERF.md section 6, PR 30.)
 
     Tokens do not interact, so under data parallelism each rank does this
     for its own tokens inside a ``shard_map`` over the batch axes (its own
@@ -269,22 +319,33 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
     from ..ops.pallas.spmd import kernel_mesh_plan
 
     E = ws[0].shape[0]
+    share = first_expert is not None
     verdict, batch_axes = kernel_mesh_plan(x.shape[0])
 
     def one_rank(x, weights, chosen, *ws):
         S, k = chosen.shape
         with trace.device_span("moe/route"):
             flat = chosen.reshape(-1)
+            if share:
+                flat = flat - first_expert
+                flat = jnp.where((flat >= 0) & (flat < E), flat, E)
             order = jnp.argsort(flat, stable=True)
             inv = jnp.argsort(order)
             sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+            if share:
+                # past the groups a row holds no pair, and a pair held
+                # elsewhere has no row: the gathers read zeros there,
+                # whatever the grouped matmul leaves in rows it skips
+                order = jnp.where(jnp.arange(S * k) < sizes.sum(), order,
+                                  S * k)
+                inv = jnp.where(flat < E, inv, S * k)
         with trace.device_span("moe/dispatch"):
-            rows = repeat_gather(x, order, inv)                   # (S*k, M)
+            rows = repeat_gather(x, order, inv, share)            # (S*k, M)
         with trace.device_span("moe/experts"):
             rows = _expert_ffn(act, ws, rows, lambda a, w: grouped_matmul(
                 a, w, sizes, per_device=verdict is not None))
         with trace.device_span("moe/combine"):
-            rows = unsort_rows(rows, order, inv).reshape(S, k, -1)
+            rows = unsort_rows(rows, order, inv, share).reshape(S, k, -1)
             return jnp.einsum("skm,sk->sm", rows, weights.astype(rows.dtype))
 
     if verdict != "shard":
@@ -307,6 +368,8 @@ class ExpertsMLP(nn.Module):
     w8: bool = False                   # int8 expert weights (ops/w8.py)
     w8_group: int = 128
     act: str = "gelu"                  # 'gelu' (wi, wo) | 'swiglu' (gate, up, down)
+    # a share: the first of the routed experts that this instance holds
+    first_expert: Optional[int] = None
 
     def _weight(self, name: str, down: bool = False):
         """One (experts, embed, mlp) leaf, or (experts, mlp, embed) for a
@@ -345,7 +408,8 @@ class ExpertsMLP(nn.Module):
             if self.w8:
                 raise NotImplementedError(
                     "int8 expert weights have no grouped-matmul path")
-            return sorted_dispatch(x, *routing, self._weights(), self.act)
+            return sorted_dispatch(x, *routing, self._weights(), self.act,
+                                   self.first_expert)
         if self.act != "gelu" and (self.w8 or idx is not None):
             raise NotImplementedError(
                 f"{self.act} experts run the capacity einsum or the sorted "
@@ -454,13 +518,16 @@ class MoELayer(nn.Module):
         experts = ExpertsMLP(cfg.num_experts, self.model_dim,
                              self.hidden_dim, dtype=self.dtype, w8=self.w8,
                              w8_group=self.w8_group, act=cfg.expert_act,
+                             first_expert=None if cfg.holds_all
+                             else cfg.first_expert,
                              name="experts")
         gate = TopKGate(cfg, self.model_dim, name="gate")
         mesh = mesh_lib.get_mesh(required=False)
         ep1 = mesh is None or mesh.shape.get("ep", 1) == 1
         fast_ok = os.environ.get("DS_TPU_MOE_FAST", "0") == "1"
-        S, E, k = x2.shape[0], cfg.num_experts, cfg.top_k
+        S, E, k = x2.shape[0], cfg.routed, cfg.top_k
         l_z = jnp.float32(0.0)
+        elsewhere = jnp.int32(0)
         if not cfg.drop_tokens:
             if not ep1:
                 raise NotImplementedError(
@@ -476,8 +543,14 @@ class MoELayer(nn.Module):
                     gate(x2, train, logits_only=True), k, cfg.norm_topk_prob)
             out = experts(x2, routing=(weights, chosen))
             # pairs no expert's group holds (an id outside 0..E-1): the
-            # grouped matmul multiplies exactly counts.sum() rows
+            # grouped matmul multiplies exactly counts.sum() rows, or, of
+            # a share, those of its own experts; the pairs routed to
+            # experts held elsewhere are nobody's loss
             dropped = jnp.int32(S * k) - counts.sum()
+            if not cfg.holds_all:
+                here = counts[cfg.first_expert:
+                              cfg.first_expert + cfg.num_experts]
+                elsewhere = counts.sum() - here.sum()
         elif not train and ep1 and fast_ok and S <= 32:
             # gathered per-token experts (no capacity padding, no dispatch
             # one-hots).  OPT-IN: on TPU the vmapped gather materializes a
@@ -518,8 +591,11 @@ class MoELayer(nn.Module):
         out = out.reshape(orig_shape)
         if not return_stats:
             return out, aux
-        return out, aux, {"tokens_per_expert": counts, "dropped": dropped,
-                          "balance_loss": l_aux, "router_z": l_z}
+        stats = {"tokens_per_expert": counts, "dropped": dropped,
+                 "balance_loss": l_aux, "router_z": l_z}
+        if not cfg.holds_all:
+            stats["elsewhere"] = elsewhere
+        return out, aux, stats
 
 
 def record_stats(stats: Dict[str, Any]) -> None:
@@ -528,9 +604,12 @@ def record_stats(stats: Dict[str, Any]) -> None:
     ``stats`` is the host copy of what :class:`MoELayer` returned with
     ``return_stats``, stacked over the model's MoE layers:
     ``tokens_per_expert`` (L, E), ``dropped`` (L,), ``balance_loss`` (L,),
-    ``router_z`` (L,).  Counters ``moe_tokens_per_expert{layer,expert}``
-    (max / mean over a layer's experts is its load imbalance) and
-    ``moe_dropped_tokens_total`` (0 on the dropless path, always); gauges
+    ``router_z`` (L,), and from a layer that holds a share of its experts
+    ``elsewhere`` (L,).  Counters ``moe_tokens_per_expert{layer,expert}``
+    (max / mean over a layer's experts is its load imbalance),
+    ``moe_dropped_tokens_total`` (0 on the dropless path, always) and
+    ``moe_pairs_elsewhere_total`` (pairs routed to experts that another
+    instance holds: not dropped, not multiplied here); gauges
     ``moe_aux_loss`` / ``moe_router_z``, the layer means of the last step.
     """
     counts = np.asarray(stats["tokens_per_expert"])
@@ -546,6 +625,12 @@ def record_stats(stats: Dict[str, Any]) -> None:
         "moe_dropped_tokens_total",
         "(token, choice) pairs an expert's capacity turned away"
     ).inc(float(np.sum(stats["dropped"])))
+    if "elsewhere" in stats:
+        registry.counter(
+            "moe_pairs_elsewhere_total",
+            "(token, choice) pairs routed to an expert that another "
+            "instance of the expert-parallel layer holds"
+        ).inc(float(np.sum(stats["elsewhere"])))
     registry.gauge("moe_aux_loss", "load-balancing loss, mean over layers, "
                    "last finished step").set(float(np.mean(stats["balance_loss"])))
     registry.gauge("moe_router_z", "router z-loss, mean over layers, last "

@@ -379,7 +379,9 @@ def test_grouped_matmul_tiles_and_cpu_dispatch():
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 
     assert _tiles(65536, 2048, 1024) == _tiles(65536, 1024, 2048) == TILES
-    assert _tiles(1280, 512, 384) == (256, 512, 128)
+    # the largest multiple of 128 up to the cap that divides (PR 30: 384
+    # of 384, where halving from 1024 found only 128)
+    assert _tiles(1280, 512, 384) == (256, 512, 384)
     assert _tiles(96, 64, 32) is None           # the tiny CPU shapes
     x = jnp.ones((12, 8), jnp.float32)
     w = jnp.stack([jnp.full((8, 4), float(g)) for g in range(3)])

@@ -318,3 +318,50 @@ def test_stored_transposed_is_the_chips_own_layout(one_chip):
         assert len(minor_to_major) == len(shapes)
         for s, order in zip(shapes, minor_to_major):
             assert stored_transposed(s) == (order == "0,1"), (s, dtype, order)
+
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window):
+    """The flash forward and backward of ``train-mellum2-8k-1chip``: 4 rows
+    of 8192, 32 query heads on 4 key-value heads of 128, with the 1024-key
+    window and without.  The backward's panels of q, dO and dq beside the
+    float32 sums of a key-value head's dk and dv pass Mosaic's default 16
+    MB of VMEM, so the call asks for what it holds; k, v, dk and dv are
+    ``[4,8192,512]`` on both sides of both calls and nothing key- or
+    value-shaped is 4096 wide; the calls carry the layer type's name."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                          flash_lanes)
+
+    B, S, H, KV, D = 4, 8192, 32, 4, 128
+    assert flash_lanes(H, D).reason == "rows layout, 1 head a 128-lane block"
+    scope = "self_attn_window" if window else "self_attn_full"
+
+    def loss(q, k, v):
+        # as in the model: the layer type's scope inside the module's
+        with jax.named_scope("self_attn"), jax.named_scope(scope):
+            out = flash_attention(q.reshape(B, S, H, D),
+                                  k.reshape(B, S, KV, D),
+                                  v.reshape(B, S, KV, D), window=window)
+        return out.astype(jnp.float32).sum()
+
+    wide = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((B, S, KV * D), jnp.bfloat16,
+                                  sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        wide, narrow, narrow).compile().as_text()
+    calls = {name: (shape, ops) for name, (shape, op, ops)
+             in _entry(text).items() if op == "custom-call"
+             and shape.startswith("(")}
+    assert len(calls) == 2 and all(n.startswith(scope) for n in calls)
+    fwd, bwd = sorted(calls.values(), key=lambda c: c[0].count("bf16["))
+    assert fwd[0].count(f"bf16[{B},{S},{H * D}]") == 1          # o
+    assert bwd[0].count(f"bf16[{B},{S},{H * D}]") == 1          # dq
+    assert bwd[0].count(f"bf16[{B},{S},{KV * D}]") == 2         # dk, dv
+    # nothing 4096 wide is made from k or v: no repeat to the query heads
+    entry = _entry(text)
+    params = {n for n, (_, op, _) in entry.items() if op == "parameter"}
+    assert {"k.1", "v.1"} <= params, params
+    for name, (shape, op, operands) in entry.items():
+        if op != "custom-call" and f"{H * D}]" in shape.split("{")[0]:
+            assert not {"k.1", "v.1"} & set(operands), (name, shape)
+    assert f"[{B},{S},{KV},{H // KV},{D}]" not in text
