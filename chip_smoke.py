@@ -393,22 +393,20 @@ def kernel_adam8bit():
         assert dp <= 1e-6 and ds <= 1e-6, (shape, dp, ds)
         assert flips[0][0] <= 1 and flips[0][1] <= 1e-3, (shape, flips)
         assert flips[1][0] <= 1 and flips[1][1] <= 0.03, (shape, flips)
-def kernel_share_dispatch():
-    """A share of an expert layer through the sorted dispatch (the third
-    cell's: experts 4-7 of 16, top-4): the pairs held elsewhere lie behind
-    the last group, in rows the Pallas grouped matmul neither reads nor
-    writes, forward or backward.  Whatever the chip's memory holds there
-    must reach no token and no gradient (``ragged_dot`` on the CPU writes
-    zeros there, so only the chip can show it): output and gradients
-    against a dense float32 loop over the held experts."""
+def _dispatch_case(T, M, I, R, E, first, k, tiles, rows_impl):
+    """One expert layer (``E`` of ``R`` routed experts held from ``first``
+    on, top-``k``) through the sorted dispatch against a dense float32
+    loop over the held experts: output, d-tokens, the experts' gradients
+    and the router's (where the d-weights of the combine end up)."""
     import jax
     import jax.numpy as jnp
 
     from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
 
-    T, M, I, R, E, first, k = 2048, 512, 256, 16, 4, 4, 4
-    cfg = MoEConfig(num_experts=E, routed_experts=R, first_expert=first,
-                    top_k=k, drop_tokens=False, norm_topk_prob=True,
+    share = E < R
+    cfg = MoEConfig(num_experts=E, routed_experts=R if share else None,
+                    first_expert=first if share else None, top_k=k,
+                    drop_tokens=False, norm_topk_prob=True,
                     expert_act="swiglu")
     layer = MoELayer(cfg, model_dim=M, hidden_dim=I, dtype=jnp.bfloat16)
     ks = jax.random.split(jax.random.PRNGKey(30), 3)
@@ -416,9 +414,11 @@ def kernel_share_dispatch():
     ct = jax.random.normal(ks[1], (T, M), jnp.float32)
     p = jax.tree_util.tree_map(
         lambda a: a.value if hasattr(a, "value") else a,
-        layer.init(ks[2], x)["params"], is_leaf=lambda a: hasattr(a, "value"))
-    p = {"gate": {"wg": p["gate"]["wg"] * 30},
-         "experts": {n: w * 3 for n, w in p["experts"].items()}}
+        layer.init(ks[2], x[:256])["params"],
+        is_leaf=lambda a: hasattr(a, "value"))
+    p = {"gate": {"wg": p["gate"]["wg"] * 30 * (512 / M) ** 0.5},
+         "experts": {n: w * 3 * (512 / M) ** 0.5
+                     for n, w in p["experts"].items()}}
 
     def plain(p, x):
         x = x.astype(jnp.float32)
@@ -429,7 +429,8 @@ def kernel_share_dispatch():
         for e in range(E):
             gate, up, down = (p["experts"][n][e] for n in
                               ("gate", "up", "down"))
-            mine = jnp.where(chosen == first + e, w, 0.0).sum(-1)
+            mine = jnp.where(chosen == (first if share else 0) + e, w,
+                             0.0).sum(-1)
             out += mine[:, None] * ((jax.nn.silu(x @ gate) * (x @ up)) @ down)
         return out
 
@@ -444,16 +445,89 @@ def kernel_share_dispatch():
         out_r = jax.jit(plain)(p, x)
         d_p_r, d_x_r = jax.jit(jax.grad(lambda *a: loss(plain, *a),
                                         argnums=(0, 1)))(p, x)
-    name = f"share dispatch ({T},{M}) top-{k}, {E} of {R} experts held"
+    name = f"dispatch ({T},{M}) top-{k}, {E} of {R} experts held"
     _check_close(f"{name} fwd", out, out_r)
     _check_close(f"{name} d-tokens", d_x, d_x_r)
     for n in ("gate", "up", "down"):
         _check_close(f"{name} d-{n}", d_p["experts"][n], d_p_r["experts"][n])
+    _check_close(f"{name} d-router", d_p["gate"]["wg"], d_p_r["gate"]["wg"])
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 
+    report = dispatch_report()
     assert any(site == "grouped_matmul" and impl == "megablox"
-               and "(512, 512, 256)" in reason
-               for site, impl, reason, _ in dispatch_report()), dispatch_report()
+               and tiles in reason for site, impl, reason, _ in report), report
+    assert any(site == "moe_rows" and impl == rows_impl[0]
+               and reason.startswith(rows_impl[1])
+               for site, impl, reason, _ in report), report
+
+
+def _skipped_rows(S, M, k, live_share):
+    """What the chip leaves in the rows of a share's buffer that hold no
+    pair: the row kernel writes zeros up to the end of the block of 1024
+    that holds the last pair and nothing past it; the grouped matmul
+    neither reads nor writes such rows, so there both hold whatever memory
+    held (reported, not judged: nothing downstream reads it)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.grouped_matmul import grouped_matmul, repeat_gather
+
+    R = S * k
+    live = int(R * live_share) // 512 * 512 - 512      # ends inside a block
+    rng = np.random.default_rng(0)
+    order = rng.permutation(R).astype(np.int32)
+    order[live:] = R
+    inv = np.full(R, R, np.int32)
+    inv[order[:live]] = np.arange(live, dtype=np.int32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (S, M),
+                          jnp.float32).astype(jnp.bfloat16)
+    rows = jax.jit(lambda x, o, i: repeat_gather(x, o, i, True,
+                                                 per_device=True))(
+        x, jnp.asarray(order), jnp.asarray(inv))
+    block_end = -(-live // 1024) * 1024
+    if np.asarray(rows[live:block_end], np.float32).any():
+        raise AssertionError(f"rows {live} to {block_end} are not zero")
+    tail = np.asarray(rows[block_end:], np.float32)
+    want = np.asarray(x, np.float32)[order[:live] // k]
+    if not (np.asarray(rows[:live], np.float32) == want).all():
+        raise AssertionError("gathered rows are not copies of their tokens")
+    w = jnp.ones((16, M, 128), jnp.bfloat16)
+    sizes = jnp.full((16,), live // 16, jnp.int32)
+    y = np.asarray(jax.jit(lambda a, b, c: grouped_matmul(
+        a, b, c, per_device=True))(rows, w, sizes)[live:], np.float32)
+    def held(a):
+        return (f"{np.count_nonzero(~np.isfinite(a))} non-finite and "
+                f"{np.count_nonzero(a)} non-zero of {a.size} entries")
+
+    print(f"  [rows past the groups] ({S} x {k}, {M}), {live} live: the "
+          f"gather's unwritten blocks hold {held(tail)}; the grouped "
+          f"matmul's output there holds {held(y)}", flush=True)
+
+
+def kernel_share_dispatch():
+    """A share of an expert layer through the sorted dispatch: the pairs
+    held elsewhere lie behind the last group, in rows the Pallas grouped
+    matmul neither reads nor writes, forward or backward.  Whatever the
+    chip's memory holds there must reach no token and no gradient
+    (``ragged_dot`` on the CPU writes zeros there, so only the chip can
+    show it).  At a small shape (experts 4-7 of 16, top-4, rows of 512)
+    and at the third cell's own (``train-mellum2-8k-1chip``: 32768 tokens
+    x top-8, rows of 2304, experts 16-31 of 64), where the rows move
+    through the Pallas row kernels (``ops/pallas/moe_rows.py``)."""
+    _dispatch_case(2048, 512, 256, 16, 4, 4, 4, "(512, 512, 256)",
+                   ("pallas", "rows 8192 x 512"))
+    _dispatch_case(32768, 2304, 896, 64, 16, 16, 8, "(512, 768, 896)",
+                   ("pallas", "rows 262144 x 2304"))
+    _skipped_rows(32768, 2304, 8, 0.25)
+
+
+def kernel_full_dispatch():
+    """The twin with every expert held, at the second cell's shape
+    (``train-olmoe-z3-1chip``: 8192 tokens x top-8 of 64, rows of 2048): a
+    full permutation, which the guard leaves to XLA's gathers."""
+    _dispatch_case(8192, 2048, 1024, 64, 64, 0, 8, "(512, 1024, 1024)",
+                   ("xla", "every row holds a pair"))
 
 
 def kernel_flash_window_gqa():
@@ -531,7 +605,7 @@ def kernel_flash_window_gqa():
 
 
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_grouped_matmul,
-                kernel_share_dispatch, kernel_adam8bit,
+                kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,
                 kernel_decode_layer, kernel_w8_matmul)
 

@@ -24,11 +24,22 @@ operands are global arrays of a mesh that ``kernel_mesh_plan`` refused, or
 at shapes the tiles do not divide, it is ``jax.lax.ragged_dot``;
 ``kernel_dispatch_total{site="grouped_matmul"}`` says which, and why.
 
-``repeat_gather`` / ``unsort_rows`` move rows into expert order and back.
-Each is a row gather whose transpose XLA would write as a scatter-add;
-both know the inverse permutation, so their backward passes are gathers
-too.  For a share of the experts the permutation is partial (``absent``):
-pairs held elsewhere have no row and rows past the groups no pair.
+``repeat_gather`` / ``combine_rows`` move rows into expert order and back
+(``unsort_rows`` + a weighted sum where no kernel runs).  Each is a row
+gather whose transpose XLA would write as a scatter-add; both know the
+inverse permutation, so their backward passes are gathers too.  For a
+share of the experts the permutation is partial (``absent``): pairs held
+elsewhere have no row and rows past the groups no pair.  On one TPU device
+(or a rank of the ``shard_map``), for bf16 rows of a width that is a
+multiple of 256 in whole blocks, the movements are the Pallas kernels of
+``ops/pallas/moe_rows.py`` - one DMA a row that holds a pair, none for a
+row that holds none, the weighted sum over a token's k rows in VMEM with
+no ``(S, k, M)`` array: HLO custom calls ``moe_rows_out`` (into expert
+order) and ``moe_rows_back`` (back to token order).  Anywhere else they
+are ``jnp.take``; ``kernel_dispatch_total{site="moe_rows"}`` says which,
+and why.  A row without a pair reads zeros, with one exception: going out,
+the kernel writes nothing past the block that holds the last pair, because
+the one reader of those rows, the grouped matmul, skips them.
 """
 from __future__ import annotations
 
@@ -106,29 +117,112 @@ def _rows(a: jax.Array, index: jax.Array, absent: bool) -> jax.Array:
     return jnp.take(a, index, axis=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_plan(x, k: int, per_device: Optional[bool], absent: bool) -> bool:
+    """Whether the Pallas row kernels move ``k`` rows a token of ``x``
+    (S, M); counted, with the guard that decided, in
+    ``kernel_dispatch_total{site="moe_rows"}``.  They run for a share
+    (``absent``), where three rows in four hold no pair and cost them
+    nothing.  A full permutation stays with XLA: its gather of 4 KB rows
+    already runs at the rate one DMA a row can be issued (v5e, 65,536
+    rows of 2048: 1.35 ms against the kernel's 2.8; PERF.md section 6,
+    PR 32)."""
+    from .attention import on_tpu
+    from .pallas import moe_rows
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    S, M = x.shape
+    if not on_tpu():
+        reason = "no TPU"
+    elif not absent:
+        reason = "every row holds a pair"
+    else:
+        if per_device is None:
+            per_device = kernel_mesh_plan(S)[0] == "direct"
+        reason = moe_rows.supported(S, k, M, x.dtype) if per_device \
+            else "global arrays of a mesh of several devices"
+    if reason is not None:
+        note_dispatch("moe_rows", "xla", reason)
+        return False
+    note_dispatch("moe_rows", "pallas",
+                  f"rows {S * k} x {M}, block {moe_rows.STEP}")
+    return True
+
+
+def _live(order: jax.Array, absent: bool) -> jax.Array:
+    """(1,) int32: the rows that hold a pair (they come first)."""
+    R = order.shape[0]
+    if not absent:
+        return jnp.full((1,), R, jnp.int32)
+    return jnp.sum(order < R, dtype=jnp.int32)[None]
+
+
+def _tokens_of(order: jax.Array, S: int) -> jax.Array:
+    """Row j's token ``order[j] // k``; S ("no row") where it holds no
+    pair."""
+    R = order.shape[0]
+    return jnp.where(order < R, order // (R // S), S)
+
+
 def repeat_gather(x: jax.Array, order: jax.Array, inv: jax.Array,
-                  absent: bool = False) -> jax.Array:
+                  absent: bool = False, *,
+                  per_device: Optional[bool] = None) -> jax.Array:
     """``x`` (S, M), each row wanted ``k`` times -> (S*k, M) with row ``j``
     = ``x[order[j] // k]``; ``order`` is a permutation of ``range(S*k)``
     and ``inv`` its inverse.  With ``absent`` (a share of the experts)
     both are partial: ``order[j] = S*k`` where row j holds no pair (it
-    reads zeros) and ``inv[p] = S*k`` where pair p has no row (it gives
-    its token no gradient)."""
+    reads zeros; the kernel leaves whole blocks of such rows unwritten,
+    ``ops/pallas/moe_rows.py gather_rows``) and ``inv[p] = S*k`` where
+    pair p has no row (it gives its token no gradient).  ``per_device`` as
+    :func:`grouped_matmul`."""
+    if _rows_plan(x, order.shape[0] // x.shape[0], per_device, absent):
+        return _gather_pallas(x, order, inv, absent)
+    return _gather_xla(x, order, inv, absent)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_xla(x, order, inv, absent):
     return _rows(x, order // (order.shape[0] // x.shape[0]), absent)
 
 
-def _repeat_gather_fwd(x, order, inv, absent):
-    return repeat_gather(x, order, inv, absent), (inv, x.shape[0])
+def _gather_xla_fwd(x, order, inv, absent):
+    return _gather_xla(x, order, inv, absent), (inv, x.shape[0])
 
 
-def _repeat_gather_bwd(absent, res, g):
+def _gather_xla_bwd(absent, res, g):
     inv, S = res
     gx = _rows(g, inv, absent).reshape(S, -1, g.shape[-1])
     return gx.sum(axis=1).astype(g.dtype), None, None
 
 
-repeat_gather.defvjp(_repeat_gather_fwd, _repeat_gather_bwd)
+_gather_xla.defvjp(_gather_xla_fwd, _gather_xla_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_pallas(x, order, inv, absent):
+    from .pallas import moe_rows
+
+    S = x.shape[0]
+    packed = moe_rows.pack_rows(x, jnp.full((1,), S, jnp.int32),
+                                name="moe_rows_out")
+    return moe_rows.gather_rows(packed, _tokens_of(order, S),
+                                _live(order, absent), name="moe_rows_out")
+
+
+def _gather_pallas_fwd(x, order, inv, absent):
+    return _gather_pallas(x, order, inv, absent), (order, inv, x.shape[0])
+
+
+def _gather_pallas_bwd(absent, res, g):
+    from .pallas import moe_rows
+
+    order, inv, S = res
+    packed = moe_rows.pack_rows(g, _live(order, absent), name="moe_rows_back")
+    ones = jnp.ones((S, inv.shape[0] // S), jnp.float32)
+    return moe_rows.combine_rows(packed, inv, ones,
+                                 name="moe_rows_back"), None, None
+
+
+_gather_pallas.defvjp(_gather_pallas_fwd, _gather_pallas_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -149,3 +243,56 @@ def _unsort_rows_bwd(absent, order, g):
 
 
 unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
+
+
+def combine_rows(y: jax.Array, weights: jax.Array, order: jax.Array,
+                 inv: jax.Array, absent: bool = False, *,
+                 per_device: Optional[bool] = None) -> jax.Array:
+    """Back to token order and summed: ``out[s] = sum_j weights[s, j] *
+    y[inv[s*k + j]]``, (S, M) in ``y``'s type; a pair without a row adds
+    nothing, whatever the rows past the groups hold.  The kernel sums in
+    float32 and rounds once; the XLA path is :func:`unsort_rows` and an
+    einsum over the ``(S, k, M)`` array it makes."""
+    S, k = weights.shape
+    if _rows_plan(jax.ShapeDtypeStruct((S, y.shape[1]), y.dtype), k,
+                  per_device, absent):
+        return _combine_pallas(y, weights.astype(jnp.float32), order, inv,
+                               absent).astype(y.dtype)
+    rows = unsort_rows(y, order, inv, absent).reshape(S, k, -1)
+    return jnp.einsum("skm,sk->sm", rows, weights.astype(rows.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine_pallas(y, weights, order, inv, absent):
+    return _combine_pallas_fwd(y, weights, order, inv, absent)[0]
+
+
+def _combine_pallas_fwd(y, weights, order, inv, absent):
+    from .pallas import moe_rows
+
+    packed = moe_rows.pack_rows(y, _live(order, absent), name="moe_rows_back")
+    out = moe_rows.combine_rows(packed, inv, weights, name="moe_rows_back")
+    return out, (packed, weights, order, inv)
+
+
+def _combine_pallas_bwd(absent, res, g):
+    """d-rows: row j gets its pair's weight times its token's cotangent, a
+    scaled gather into expert order; d-weights: the rows a token combined,
+    gathered once more and multiplied with its cotangent in VMEM."""
+    from .pallas import moe_rows
+
+    packed, weights, order, inv = res
+    S = g.shape[0]
+    scale = jnp.take(weights.reshape(-1), order, mode="fill",
+                     fill_value=0)[:, None]
+    d_rows = moe_rows.gather_rows(
+        moe_rows.pack_rows(g, jnp.full((1,), S, jnp.int32),
+                           name="moe_rows_out"),
+        _tokens_of(order, S), _live(order, absent), scale,
+        name="moe_rows_out")
+    d_weights = moe_rows.combine_rows(packed, inv, weights, g,
+                                      name="moe_rows_back")
+    return d_rows, d_weights, None, None
+
+
+_combine_pallas.defvjp(_combine_pallas_fwd, _combine_pallas_bwd)

@@ -1,0 +1,409 @@
+"""Row movements of the sorted MoE dispatch as Pallas kernels: one DMA a
+row that holds a pair, none for a row that holds none.
+
+**Why a row form.**  Mosaic (jax 0.9.0) slices an HBM or VMEM array along
+its second-minor dimension only by whole tiles of 8 rows (``Slice shape
+along dimension 0 must be aligned to tiling (8), but is 1``): a 2-D
+``bf16[N, M]`` is stored in (8, 128) tiles with two rows packed into each
+32-bit word, so one row is not an addressable piece of memory and no DMA
+moves it.  A row becomes addressable as a *leading* index, so the kernels
+move rows in the **row form** ``uint32[N, 1, M/2]``: word ``w`` of row
+``n`` holds ``x[n, w]`` in its low half and ``x[n, M/2 + w]`` in its high
+half, tiled (1, 128) - ``M/256`` contiguous 512-byte pieces a row, for any
+``M`` that is a multiple of 256 (2048: 8, 2304: 9).  XLA converts 2-D to row
+form and back at HBM speed, a full read and write each way
+(``bf16[262144, 2304]``: 5.1 and 3.8 ms on the v5e, as long as the gather
+it serves); here the conversion happens in VMEM, beside the DMAs:
+
+- :func:`pack_rows` reads a 2-D array block by block and writes its row
+  form (two shifts and an or a word); blocks past the ``live`` rows are
+  not read.
+- :func:`gather_rows` (``out[j] = src[idx[j]]``, optionally scaled a
+  row) DMAs rows of a row-form source into a VMEM stage and *assembles*
+  the 2-D output block from it: in the stage a row's 128-word pieces lie
+  ``M/256`` sublanes apart, so one strided load brings the same piece of
+  8 rows, its halves unpack to two (8, 128) float32 tiles (exact), and two
+  of those pack to one (16, 128) bf16 tile of the output.
+- :func:`combine_rows` (``out[s] = sum_j w[s, j] * y[inv[s, j]]``) DMAs
+  the k rows of each token the same way; the strided load brings choice
+  ``j`` of 8 tokens, products and the sum stay in float32 and are rounded
+  once.  In ``dw`` mode the same rows are multiplied with the token's
+  cotangent instead and summed over the width:
+  ``dw[s, j] = <g[s], y[inv[s, j]]>``.  No ``(S, k, M)`` array exists.
+
+Indices arrive in SMEM a block of :data:`STEP` a grid step (XLA lays
+``s32[n]`` out in tiles of 1024 and the whole of 262,144 indices is the
+chip's entire 1 MB of SMEM, so they cannot be scalar-prefetched whole);
+only counts are prefetched.  Rows are in flight :data:`SUB` at a time on
+one DMA semaphore a stage, two stages: the next sub-block's DMAs are
+issued before this one's are awaited and consumed.
+
+An index of ``src.shape[0]`` means "no row" (a share's ``absent``).  No DMA is issued
+for it and it reads zeros (the stage is cleared before a sub-block's DMAs
+land in it).  Going out, blocks wholly past the ``live`` rows are not
+written at all (:func:`gather_rows` says who reads them: nobody).  The loops that issue DMAs hold no branch - measured
+on the v5e a branch a row costs what the DMA it spares would (16 against
+25 ns) - so each is told how many rows to bring: going out the rows that
+hold a pair come first, and coming back :func:`_fetch_list` sorts each
+sub-block's pairs that have a row to its front (one XLA sort of packed
+words, inside the same jit).
+
+Every ``pallas_call`` sits behind a ``jax.jit`` of this module, so a
+kernel body is traced once a process, distinct signature and tracing
+context (the plain one and the one under ``grad`` of a remat block differ)
+- not once a layer, remat pass, ``eval_shape``, init, eval step and expert
+check - and lowered once a module (``moe_rows_traces_total`` counts the
+traces).  The loops over a row's pieces stay unrolled in the gather and
+the combine: rolled they trace in half the time and run 1.2 to 2.3 ms a
+call slower at Mellum 2's shape (v5e, PERF.md section 6, PR 32).
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ...telemetry import registry as _registry
+
+# rows (or pairs) a grid step: one SMEM block of indices
+STEP = 1024
+# rows in flight on one semaphore; two stages of this many rows
+SUB = 256
+# rows a block of pack_rows
+PACK = 256
+_HIGH = 0xFFFF0000
+
+
+def supported(n_tokens: int, k: int, width: int, dtype) -> Optional[str]:
+    """``None`` where the kernels take ``(n_tokens, width)`` tokens wanted
+    ``k`` times each, else the reason they do not."""
+    if dtype != jnp.bfloat16:
+        return f"rows of {jnp.dtype(dtype).name}"
+    if width % 256:
+        return f"row width {width} is no multiple of 256"
+    if k not in (1, 2, 4, 8, 16):
+        return f"top-{k} is no power of two up to 16"
+    if n_tokens % PACK or (n_tokens * k) % STEP:
+        return (f"{n_tokens} tokens x {k} are no whole blocks of {PACK} "
+                f"tokens and {STEP} rows")
+    return None
+
+
+def _note_trace(kernel: str, *signature) -> None:
+    """Count, at trace time, one entry into a kernel's builder."""
+    _registry.counter(
+        "moe_rows_traces_total",
+        "times a moe_rows kernel body was traced, by kernel and signature "
+        "(once a process, signature and tracing context: the calls sit "
+        "behind jax.jit)",
+        labelnames=("kernel", "signature")).labels(
+            kernel, " ".join(str(s) for s in signature)).inc()
+
+
+def _unpack(words):
+    """The two bf16 halves of uint32 ``words`` as float32, exactly."""
+    low = lax.bitcast_convert_type(words << 16, jnp.float32)
+    high = lax.bitcast_convert_type(words & jnp.uint32(_HIGH), jnp.float32)
+    return low, high
+
+
+def _live_block(rows: int):
+    """Index map of a (``rows``, ...) block over an array whose first
+    ``live`` rows alone matter: a step past them stays on the last live
+    block, so that it neither fetches nor writes one."""
+    def block(i, live):
+        return jnp.minimum(i, jnp.maximum(live[0] - 1, 0) // rows)
+    return block
+
+
+def _pack_kernel(live_ref, x_ref, out_ref):
+    rows, half = out_ref.shape[0], out_ref.shape[2]
+
+    def group(i, carry):
+        r0 = pl.multiple_of(i * 16, 16)
+
+        def bits(col):
+            tile = x_ref[pl.ds(r0, 16), pl.ds(col, 128)]
+            return lax.bitcast_convert_type(tile.astype(jnp.float32),
+                                            jnp.uint32)
+
+        def piece(c, carry):
+            col = pl.multiple_of(c * 128, 128)
+            words = (bits(col) >> 16) | bits(half + col)
+            out_ref[pl.ds(r0, 8), 0, pl.ds(col, 128)] = words[:8]
+            out_ref[pl.ds(r0 + 8, 8), 0, pl.ds(col, 128)] = words[8:]
+            return carry
+
+        return lax.fori_loop(0, half // 128, piece, carry)
+
+    @pl.when(pl.program_id(0) * rows < live_ref[0])
+    def _():
+        lax.fori_loop(0, rows // 16, group, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("name", "interpret"))
+def pack_rows(x: jax.Array, live: jax.Array, *, name: str,
+              interpret: bool = False) -> jax.Array:
+    """The row form ``uint32[N, 1, M/2]`` of ``x`` (N, M) bf16.  Blocks
+    past the first ``live`` (1,) int32 rows are neither read nor written
+    (they hold whatever memory held)."""
+    N, M = x.shape
+    _note_trace("pack", name, N, M)
+
+    block = _live_block(PACK)
+    return pl.pallas_call(
+        _pack_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(N // PACK,),
+            in_specs=[pl.BlockSpec((PACK, M),
+                                   lambda i, live: (block(i, live), 0))],
+            out_specs=pl.BlockSpec((PACK, 1, M // 2),
+                                   lambda i, live: (block(i, live), 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((N, 1, M // 2), jnp.uint32),
+        cost_estimate=pl.CostEstimate(flops=0, transcendentals=0,
+                                      bytes_accessed=4 * N * M),
+        name=name, interpret=interpret,
+    )(live, x)
+
+
+def _stream(src_ref, stage, sem, count, fetch, consume):
+    """Bring this grid step's :data:`STEP` rows into ``stage`` a sub-block
+    of :data:`SUB` at a time and hand each landed sub-block to
+    ``consume(q, slot)``.  Sub-block ``q`` has ``count(q)`` rows to bring;
+    its ``t``-th is ``src[row]`` and lands in stage row ``at``, with
+    ``(row, at) = fetch(q, t)``.  The loop holds no branch: a branch a row
+    costs as much as the DMA it would spare."""
+
+    def issue(q, slot):
+        # a row without a DMA reads zeros
+        stage[slot] = jnp.zeros(stage.shape[1:], stage.dtype)
+
+        def one(t):
+            row, at = fetch(q, t)
+            pltpu.make_async_copy(src_ref.at[row], stage.at[slot, at],
+                                  sem.at[slot]).start()
+
+        def eight(i, carry):    # the loop's own scalar work rivals a DMA's
+            for u in range(8):
+                one(i * 8 + u)
+            return carry
+
+        n = count(q)
+        lax.fori_loop(0, n // 8, eight, 0)
+        lax.fori_loop(n // 8 * 8, n, lambda t, carry: one(t), None)
+
+    def sub_block(q, carry):
+        slot = q % 2
+        pl.when(q + 1 < STEP // SUB)(lambda: issue(q + 1, 1 - slot))
+
+        def wait(t, carry):     # every row is as long as the first
+            pltpu.make_async_copy(src_ref.at[0], stage.at[slot, 0],
+                                  sem.at[slot]).wait()
+            return carry
+
+        lax.fori_loop(0, count(q), wait, 0)
+        consume(q, slot)
+        return carry
+
+    issue(0, 0)
+    lax.fori_loop(0, STEP // SUB, sub_block, 0)
+
+
+def _gather_kernel(live_ref, idx_ref, src_ref, *rest, scaled):
+    scale_ref = rest[0] if scaled else None
+    out_ref, stage, sem = rest[-3:]
+    half = stage.shape[-1]
+    first = pl.program_id(0) * STEP
+
+    def assemble(q, slot):
+        def group(i, carry):
+            t0 = pl.multiple_of(i * 16, 16)
+            r0 = pl.multiple_of(q * SUB + t0, 16)
+            for c in range(half // 128):
+                parts = [_unpack(stage[slot, pl.ds(t0 + h, 8), 0,
+                                       pl.ds(c * 128, 128)])
+                         for h in (0, 8)]
+                if scaled:
+                    parts = [tuple(p * scale_ref[pl.ds(r0 + h, 8), :]
+                                   for p in part)
+                             for part, h in zip(parts, (0, 8))]
+                for side, col in ((0, c * 128), (1, half + c * 128)):
+                    tile = jnp.concatenate([part[side] for part in parts], 0)
+                    out_ref[pl.ds(r0, 16), pl.ds(col, 128)] = tile.astype(
+                        out_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, SUB // 16, group, 0)
+
+    def count(q):       # the rows that hold a pair come first
+        return jnp.clip(live_ref[0] - first - q * SUB, 0, SUB)
+
+    @pl.when(first < live_ref[0])   # a step past the live rows does nothing
+    def _():
+        _stream(src_ref, stage, sem, count,
+                lambda q, t: (idx_ref[q * SUB + t], t), assemble)
+
+
+def _stage(half):
+    return [pltpu.VMEM((2, SUB, 1, half), jnp.uint32),
+            pltpu.SemaphoreType.DMA((2,))]
+
+
+@functools.partial(jax.jit, static_argnames=("name", "interpret"))
+def gather_rows(src: jax.Array, idx: jax.Array, live: jax.Array,
+                scale: Optional[jax.Array] = None, *, name: str,
+                interpret: bool = False) -> jax.Array:
+    """``out[j] = src[idx[j]] * scale[j]`` as 2-D bf16 (R, M): ``src`` in
+    row form (N, 1, M/2), ``idx`` (R,) int32, ``scale`` (R, 1) float32 or
+    none.  Rows from ``live`` (1,) int32 on hold no pair (``idx[j] = N``
+    there).  Up to the end of the block of :data:`STEP`
+    that holds the last live row they are written as zeros; the blocks
+    past it are **not written** and hold whatever memory held: their one
+    reader, the grouped matmul, reads no row past its groups, and three
+    quarters of a share's buffer are such rows (zeros there cost the v5e
+    1.7 ms of a 4.3 ms call at Mellum 2's shape)."""
+    N, _, half = src.shape
+    R = idx.shape[0]
+    _note_trace("gather", name, N, R, 2 * half, scale is not None)
+    block = _live_block(STEP)
+    in_specs = [pl.BlockSpec((STEP,), lambda i, live: (block(i, live),),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    if scale is not None:
+        in_specs.append(pl.BlockSpec((STEP, 1),
+                                     lambda i, live: (block(i, live), 0)))
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, scaled=scale is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R // STEP,), in_specs=in_specs,
+            out_specs=pl.BlockSpec((STEP, 2 * half),
+                                   lambda i, live: (block(i, live), 0)),
+            scratch_shapes=_stage(half)),
+        out_shape=jax.ShapeDtypeStruct((R, 2 * half), jnp.bfloat16),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * R * half * (scale is not None), transcendentals=0,
+            bytes_accessed=8 * R * half + 8 * R),
+        name=name, interpret=interpret,
+    )(live, idx, src, *(() if scale is None else (scale,)))
+
+
+def _combine_kernel(count_ref, list_ref, src_ref, w_ref, *rest, k, dw):
+    g_ref = rest[0] if dw else None
+    out_ref, stage, sem = rest[-3:]
+    half = stage.shape[-1]
+    tokens = SUB // k           # a sub-block's
+
+    def choice(slot, t0, j, c):
+        """Piece ``c`` of choice ``j`` of the sub-block's 8 tokens from
+        ``t0`` on, as two (8, 128) float32 halves: the stage holds a
+        sub-block choice by choice, so they are 8 rows in a run."""
+        return _unpack(stage[slot, pl.ds(j * tokens + t0, 8), 0,
+                             pl.ds(c * 128, 128)])
+
+    def consume(q, slot):
+        def group(i, carry):
+            t0 = pl.multiple_of(q * tokens + i * 16, 16)
+            s0 = pl.multiple_of(i * 16, 16)
+            if dw:
+                dots = [[jnp.zeros((8, 128), jnp.float32)] * k
+                        for _ in (0, 8)]
+                for c in range(half // 128):
+                    g = [g_ref[pl.ds(t0, 16), pl.ds(col, 128)].astype(
+                        jnp.float32) for col in (c * 128, half + c * 128)]
+                    for n, h in enumerate((0, 8)):
+                        for j in range(k):
+                            low, high = choice(slot, s0 + h, j, c)
+                            dots[n][j] = (dots[n][j] + low * g[0][h:h + 8]
+                                          + high * g[1][h:h + 8])
+                for n, h in enumerate((0, 8)):
+                    for j in range(k):
+                        out_ref[pl.ds(t0 + h, 8), pl.ds(j, 1)] = \
+                            dots[n][j].sum(axis=1, keepdims=True)
+                return carry
+            w = [[w_ref[pl.ds(t0 + h, 8), pl.ds(j, 1)] for j in range(k)]
+                 for h in (0, 8)]
+            for c in range(half // 128):
+                sums = []
+                for n, h in enumerate((0, 8)):
+                    low = high = jnp.zeros((8, 128), jnp.float32)
+                    for j in range(k):
+                        a, b = choice(slot, s0 + h, j, c)
+                        low, high = low + a * w[n][j], high + b * w[n][j]
+                    sums.append((low, high))
+                for side, col in ((0, c * 128), (1, half + c * 128)):
+                    tile = jnp.concatenate([s[side] for s in sums], 0)
+                    out_ref[pl.ds(t0, 16), pl.ds(col, 128)] = tile.astype(
+                        out_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, tokens // 16, group, 0)
+
+    first = pl.program_id(0) * (STEP // SUB)
+
+    def fetch(q, t):
+        entry = list_ref[q * SUB + t]
+        return entry >> 8, entry & (SUB - 1)
+
+    _stream(src_ref, stage, sem, lambda q: count_ref[first + q], fetch,
+            consume)
+
+
+def _fetch_list(idx, n_rows: int, k: int):
+    """What :func:`_combine_kernel` brings, sub-block by sub-block of
+    :data:`SUB` pairs: ``counts`` (pairs that have a row) and ``entries``,
+    those pairs first, each its row ``<< 8 |`` its place in the stage -
+    choice j of the sub-block's token i at ``j * tokens + i``, so that a
+    choice of 8 tokens is 8 stage rows in a run.  Made here with one sort
+    a sub-block, so that the kernel's loop meets no absent pair: a branch
+    a pair costs as much as the DMA it would spare."""
+    t = jnp.arange(SUB, dtype=jnp.int32)
+    place = (t % k) * (SUB // k) + t // k
+    entries = (idx.reshape(-1, SUB) << 8) | place
+    counts = jnp.sum(idx.reshape(-1, SUB) < n_rows, axis=1, dtype=jnp.int32)
+    return counts, jnp.sort(entries, axis=1).reshape(-1)     # n_rows last
+
+
+@functools.partial(jax.jit, static_argnames=("name", "interpret"))
+def combine_rows(src: jax.Array, idx: jax.Array, weights: jax.Array,
+                 g: Optional[jax.Array] = None, *, name: str,
+                 interpret: bool = False) -> jax.Array:
+    """``out[s] = sum_j weights[s, j] * src[idx[s*k + j]]`` as 2-D bf16
+    (S, M), summed in float32 and rounded once: ``src`` in row form
+    (N, 1, M/2), ``idx`` (S*k,) int32, ``weights`` (S, k) float32.  With
+    ``g`` (S, M) bf16 instead ``out[s, j] = <g[s], src[idx[s*k + j]]>``,
+    (S, k) float32 (the weights are not read).  An index of N is "no row"
+    and adds (or gives) zero."""
+    N, _, half = src.shape
+    S, k = weights.shape
+    dw = g is not None
+    _note_trace("combine", name, N, S, k, 2 * half, dw)
+    tokens = STEP // k
+    counts, entries = _fetch_list(idx, N, k)
+    in_specs = [pl.BlockSpec((STEP,), lambda i, counts: (i,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((tokens, k), lambda i, counts: (i, 0))]
+    if dw:
+        in_specs.append(pl.BlockSpec((tokens, 2 * half),
+                                     lambda i, counts: (i, 0)))
+        out_spec = pl.BlockSpec((tokens, k), lambda i, counts: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((S, k), jnp.float32)
+    else:
+        out_spec = pl.BlockSpec((tokens, 2 * half), lambda i, counts: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((S, 2 * half), jnp.bfloat16)
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, k=k, dw=dw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(S * k // STEP,), in_specs=in_specs,
+            out_specs=out_spec, scratch_shapes=_stage(half)),
+        out_shape=out_shape,
+        cost_estimate=pl.CostEstimate(
+            flops=4 * S * k * half, transcendentals=0,
+            bytes_accessed=4 * S * k * half + 4 * S * half + 8 * S * k),
+        name=name, interpret=interpret,
+    )(counts, entries, src, weights, *((g,) if dw else ()))

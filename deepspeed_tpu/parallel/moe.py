@@ -62,7 +62,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..comm import mesh as mesh_lib
-from ..ops.grouped_matmul import grouped_matmul, repeat_gather, unsort_rows
+from ..ops.grouped_matmul import combine_rows, grouped_matmul, repeat_gather
 from ..telemetry import registry, trace
 
 
@@ -309,6 +309,15 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
     pairs ran 13% faster on the v5e and drops pairs once a layer collapses
     onto the held experts: PERF.md section 6, PR 30.)
 
+    The rows move through ``ops/grouped_matmul.py repeat_gather`` (into
+    expert order) and ``combine_rows`` (back, weighted and summed a token):
+    for a share on one TPU device, or a rank of the ``shard_map`` below,
+    Pallas row kernels (``ops/pallas/moe_rows.py``: one DMA a row that
+    holds a pair, none for the three in four that hold none, the weighted
+    sum in VMEM with no ``(S, k, M)`` array); with every expert held, or
+    anywhere else, XLA's gathers (``kernel_dispatch_total{site="moe_rows"}``
+    says which and why).
+
     Tokens do not interact, so under data parallelism each rank does this
     for its own tokens inside a ``shard_map`` over the batch axes (its own
     argsort, its own group sizes, the Pallas grouped matmul on its own
@@ -339,14 +348,16 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
                 order = jnp.where(jnp.arange(S * k) < sizes.sum(), order,
                                   S * k)
                 inv = jnp.where(flat < E, inv, S * k)
+        own = verdict is not None           # one device's own operands
         with trace.device_span("moe/dispatch"):
-            rows = repeat_gather(x, order, inv, share)            # (S*k, M)
+            rows = repeat_gather(x, order, inv, share,            # (S*k, M)
+                                 per_device=own)
         with trace.device_span("moe/experts"):
             rows = _expert_ffn(act, ws, rows, lambda a, w: grouped_matmul(
-                a, w, sizes, per_device=verdict is not None))
+                a, w, sizes, per_device=own))
         with trace.device_span("moe/combine"):
-            rows = unsort_rows(rows, order, inv, share).reshape(S, k, -1)
-            return jnp.einsum("skm,sk->sm", rows, weights.astype(rows.dtype))
+            return combine_rows(rows, weights, order, inv, share,
+                                per_device=own)
 
     if verdict != "shard":
         return one_rank(x, weights, chosen, *ws)

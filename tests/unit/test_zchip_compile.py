@@ -98,6 +98,66 @@ def test_sorted_dispatch_compiles_per_rank_on_four_chips(topo, monkeypatch):
                                      or "all-reduce" in text)
 
 
+@pytest.mark.parametrize("first_expert,kernels", [(16, True), (None, False)])
+def test_a_shares_dispatch_moves_rows_with_the_row_kernels(
+        topo, monkeypatch, first_expert, kernels):
+    """One expert layer forward and backward on one described v5e at
+    Mellum 2's widths (8192 tokens x top-8 of 64, rows of 2304, experts of
+    896).  A share (16 held): the rows move through the custom calls
+    ``moe_rows_out`` / ``moe_rows_back`` and no XLA gather or scatter of a
+    ``bf16[S k, M]`` or ``bf16[S, k, M]`` array is left beside them.  Every
+    expert held (a full permutation): the guard keeps XLA's gathers, and
+    says why."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+    from deepspeed_tpu.parallel.moe import sorted_dispatch
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1, 1, 1, 1, 1),
+                mesh_mod.MESH_AXES)
+    monkeypatch.setattr(mesh_mod, "_CURRENT_MESH", mesh)
+    tokens, k, embed, mlp = 8192, 8, 2304, 896
+    experts = 16 if kernels else 64
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    def loss(x, weights, chosen, *ws):
+        return sorted_dispatch(x, weights, chosen, ws, "swiglu", first_expert
+                               ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        arg((tokens, embed), jnp.bfloat16), arg((tokens, k), jnp.float32),
+        arg((tokens, k), jnp.int32),
+        arg((experts, embed, mlp), jnp.bfloat16),
+        arg((experts, embed, mlp), jnp.bfloat16),
+        arg((experts, mlp, embed), jnp.bfloat16)).compile().as_text()
+    calls = re.findall(r"%(moe_rows_\w+?)[.\d]* = ", text)
+    rows = rf"bf16\[(?:{tokens * k},{embed}|{tokens},{k},{embed})\]"
+    moved_by_xla = [line for line in text.splitlines()
+                    if re.search(rf"= {rows}\S* (?:gather|scatter)\(", line)]
+    reasons = {(impl, reason) for site, impl, reason, _ in dispatch_report()
+               if site == "moe_rows"}
+    if not kernels:
+        assert not calls and moved_by_xla
+        assert ("xla", "every row holds a pair") in reasons
+        return
+    # forward: x packed and gathered out, y packed and combined; backward:
+    # the cotangent packed and gathered out scaled, the d-weights, and the
+    # dispatch's d-tokens (packed and combined)
+    assert calls.count("moe_rows_out") == 4, calls
+    assert calls.count("moe_rows_back") == 5, calls
+    assert not moved_by_xla, moved_by_xla
+    assert ("pallas", f"rows {tokens * k} x {embed}, block 1024") in reasons
+
+
 # The matrices of the two train cells: GPT-2-XL's block, table and
 # positions; OLMoE's expert stacks, attention projections, table and head.
 # (6400, 1600), (50304, 1600) and (1024, 1600) are stored column-major on
