@@ -19,6 +19,18 @@ table a layer type (YaRN on the full layers), and experts of
 ``moe_intermediate_size``, of which this instance may hold a contiguous
 share (``MoEConfig.first_expert``).  Keys and values reach attention at
 their own ``num_key_value_heads``.
+
+AFMoE (Arcee Trinity, 2025; ``model_type: afmoe``) is it with more again:
+``num_dense_layers`` leading blocks keep the dense SwiGLU of
+``intermediate_size`` and the rest take ``moe`` (sigmoid scores, a
+selection bias that the step moves and no gradient does, a shared expert:
+``parallel/moe.py``), ``mup_enabled`` multiplies the embedding by
+``sqrt(hidden_size)``, and four fields the family's released code has and
+its config has no key for: ``qk_norm="head"`` (an RMSNorm over each head's
+channels), ``attn_gate`` (the attention output times the sigmoid of a
+fourth projection, before ``o_proj``), ``rope_layer_types`` (the layer
+types that rotate q and k: the others carry no position at all) and
+``sandwich_norm`` (a norm after each branch as well as before it).
 """
 from __future__ import annotations
 
@@ -48,7 +60,8 @@ class LlamaConfig:
     ``num_key_value_heads``, ``head_dim``, ``intermediate_size``,
     ``moe_intermediate_size``, ``rms_norm_eps``, ``rope_theta``,
     ``rope_parameters``, ``sliding_window``, ``layer_types``,
-    ``initializer_range``.  The rest are this program's own."""
+    ``initializer_range``, ``num_dense_layers``, ``mup_enabled``.  The rest
+    are this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -89,10 +102,26 @@ class LlamaConfig:
     attn_impl: str = "auto"
     vocab_pad_multiple: int = 128
     # sparse FFN: a parallel.moe.MoEConfig replaces the dense SwiGLU MLP of
-    # EVERY block with experts of width ``intermediate_size``
+    # every block after the first ``num_dense_layers`` with experts of
+    # width ``expert_size``; those first keep ``intermediate_size``
     moe: Optional[Any] = None
-    # RMSNorm over the whole q / k projection, before heads and rotary
-    qk_norm: bool = False
+    num_dense_layers: int = 0
+    # the embedding times sqrt(hidden_size) (the one muP multiplier of
+    # AFMoE's forward)
+    mup_enabled: bool = False
+    # True: RMSNorm over the whole q / k projection, before heads and
+    # rotary (OLMoE); "head": over each head's channels, one scale of
+    # head_dim for q and one for k (AFMoE)
+    qk_norm: Any = False
+    # attention's output times sigmoid(x W_g), W_g as wide as q, before o_proj
+    attn_gate: bool = False
+    # the layer types whose q and k are rotated; None: all.  A type left
+    # out attends with no positional encoding
+    rope_layer_types: Optional[tuple] = None
+    # x += Norm(Attn(Norm(x))); x += Norm(FFN(Norm(x))): ``post_attention_
+    # norm`` then normalises the attention branch's OUTPUT and the FFN
+    # reads ``pre_mlp_norm``, its output through ``post_mlp_norm``
+    sandwich_norm: bool = False
     # > 0 with labels: chunked cross-entropy head, logits never materialize
     # (common.chunked_lm_loss); the output then carries no ``logits``
     loss_chunk: int = 0
@@ -119,8 +148,14 @@ class LlamaConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_attention_heads)
-        for name in ("layer_types", "rope_parameters"):
+        for name in ("layer_types", "rope_parameters", "rope_layer_types"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm is False, True (the whole projection)"
+                             f" or 'head', got {self.qk_norm!r}")
+        if self.num_dense_layers and self.moe is None:
+            raise ValueError("num_dense_layers counts the blocks that moe "
+                             "leaves dense; there is no moe")
         for t in self.layer_types or ():
             if t not in (SLIDING, FULL_ATTENTION):
                 raise ValueError(f"layer_types holds {t!r}; {SLIDING!r} and "
@@ -143,6 +178,11 @@ class LlamaConfig:
                 "decode=True with a share of the experts "
                 "(MoEConfig.routed_experts): the serving path has no "
                 "partial expert sum")
+        if self.decode and self.afmoe_fields:
+            raise NotImplementedError(
+                f"decode=True with {', '.join(self.afmoe_fields)}: the "
+                f"cache, the fused decode kernels and the capacity gate "
+                f"know none of them")
 
     @property
     def kv_heads(self) -> int:
@@ -160,6 +200,26 @@ class LlamaConfig:
     @property
     def per_layer_type(self) -> bool:
         return bool(self.kinds) or self.rope_parameters is not None
+
+    @property
+    def afmoe_fields(self) -> tuple:
+        """AFMoE's fields that are set, by name (``moe.`` for the routing's)."""
+        mine = tuple(name for name, on in (
+            ("num_dense_layers", self.num_dense_layers),
+            ("mup_enabled", self.mup_enabled),
+            ("qk_norm", self.qk_norm == "head"),
+            ("attn_gate", self.attn_gate),
+            ("rope_layer_types", self.rope_layer_types is not None),
+            ("sandwich_norm", self.sandwich_norm)) if on)
+        routing = () if self.moe is None else self.moe.afmoe_fields
+        return mine + tuple("moe." + f for f in routing)
+
+    def sparse(self, layer: int) -> bool:
+        """Whether block ``layer``'s FFN is ``moe``."""
+        return self.moe is not None and layer >= self.num_dense_layers
+
+    def rotates(self, kind: Optional[str]) -> bool:
+        return self.rope_layer_types is None or kind in self.rope_layer_types
 
     def window(self, kind: Optional[str]) -> Optional[int]:
         return self.sliding_window if kind == SLIDING else None
@@ -283,21 +343,26 @@ class LlamaAttention(nn.Module):
                    module=self)
         k = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="k_proj",
                    module=self)
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
             k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         v = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="v_proj",
                    module=self).reshape(B, S, KV, D)
+        if cfg.qk_norm == "head":
+            with trace.device_span("attn/qk_norm"):
+                q = RMSNorm(cfg, axis="head_dim", name="q_norm")(q)
+                k = RMSNorm(cfg, axis="head_dim", name="k_norm")(k)
         # a layer type's own table and device scopes only where the
         # configuration names layer types: other models' traces stay as
         # they were
         typed = self.kind is not None or cfg.rope_parameters is not None
-        with trace.device_span(f"rope/{self.kind or FULL_ATTENTION}") \
-                if typed else contextlib.nullcontext():
-            q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
-                                        theta=cfg.rope_theta,
-                                        table=cfg.rotary(self.kind))
+        if cfg.rotates(self.kind):
+            with trace.device_span(f"rope/{self.kind or FULL_ATTENTION}") \
+                    if typed else contextlib.nullcontext():
+                q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
+                                            theta=cfg.rope_theta,
+                                            table=cfg.rotary(self.kind))
         if cfg.decode:
             kc, vc, cur = self._cache_append(k, v)
             # shared fused-or-fallback dispatch; GQA-aware (KV panels stay
@@ -318,6 +383,13 @@ class LlamaAttention(nn.Module):
             y = dot_product_attention(q, k, v, causal=True, mask=attn_mask,
                                       window=window, impl=cfg.attn_impl)
         y = y.reshape(B, S, H * D)
+        if cfg.attn_gate:
+            # elementwise in the kernels' own (B, S, H*D) layout: XLA may
+            # fuse it into o_proj's operand
+            gate = _dense(x, H * D, ("embed", "qkv"), cfg=cfg,
+                          name="gate_proj", module=self)
+            with trace.device_span("attn/gate"):
+                y = y * jax.nn.sigmoid(gate)
         return _dense(y, E, ("heads", "embed"), cfg=cfg, name="o_proj", module=self)
 
 
@@ -325,6 +397,16 @@ class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
     kind: Optional[str] = None      # this layer's entry of cfg.layer_types
+    sparse: bool = True             # False: dense FFN although cfg.moe is set
+
+    def _dense_ffn(self, h):
+        cfg = self.cfg
+        gate = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
+                      name="gate_proj", module=self)
+        up = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
+                    name="up_proj", module=self)
+        return _dense(nn.silu(gate) * up, cfg.hidden_size, ("mlp", "embed"),
+                      cfg=cfg, name="down_proj", module=self)
 
     @nn.compact
     def __call__(self, x, inputs):
@@ -360,10 +442,16 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
-        x = x + LlamaAttention(cfg, self.kind, name="self_attn")(
+        attn = LlamaAttention(cfg, self.kind, name="self_attn")(
             RMSNorm(cfg, name="input_norm")(x), position_ids, attn_mask)
-        h = RMSNorm(cfg, name="post_attention_norm")(x)
-        if cfg.moe is not None:
+        if cfg.sandwich_norm:
+            x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
+            h = RMSNorm(cfg, name="pre_mlp_norm")(x)
+        else:
+            x = x + attn
+            h = RMSNorm(cfg, name="post_attention_norm")(x)
+        ys = None
+        if cfg.moe is not None and self.sparse:
             from ..parallel.moe import MoELayer
 
             ff, aux, stats = MoELayer(
@@ -371,14 +459,14 @@ class LlamaBlock(nn.Module):
                 hidden_dim=cfg.expert_size, dtype=cfg.dtype,
                 name="moe")(h, train=not self.deterministic,
                             return_stats=True)
-            return x + ff, dict(stats, aux_loss=aux)
-        gate = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
-                      name="gate_proj", module=self)
-        up = _dense(h, cfg.intermediate_size, ("embed", "mlp"), cfg=cfg,
-                    name="up_proj", module=self)
-        ff = _dense(nn.silu(gate) * up, cfg.hidden_size, ("mlp", "embed"),
-                    cfg=cfg, name="down_proj", module=self)
-        return x + ff, None
+            ys = dict(stats, aux_loss=aux)
+        else:       # named only as a leading dense block of a sparse stack
+            with trace.device_span("mlp_dense") if cfg.moe is not None \
+                    else contextlib.nullcontext():
+                ff = self._dense_ffn(h)
+        if cfg.sandwich_norm:
+            ff = RMSNorm(cfg, name="post_mlp_norm")(ff)
+        return x + ff, ys
 
 
 class LlamaForCausalLM(nn.Module):
@@ -397,6 +485,8 @@ class LlamaForCausalLM(nn.Module):
                 raise ValueError("decode mode requires explicit position_ids")
             position_ids = jnp.arange(S)[None, :]
         h = embed.astype(cfg.dtype)[input_ids]
+        if cfg.mup_enabled:
+            h = h * (cfg.hidden_size ** 0.5)
         mask = None
         if attention_mask is not None:
             mask = attention_mask[:, None, None, :].astype(bool)
@@ -408,10 +498,11 @@ class LlamaForCausalLM(nn.Module):
                 prevent_cse=cfg.remat_prevent_cse)
         kinds = cfg.kinds
         if cfg.scan_layers:
-            if len(set(kinds)) > 1:
+            if len(set(kinds)) > 1 or cfg.num_dense_layers:
                 raise NotImplementedError(
                     f"scan_layers=True scans one kind of block, and "
-                    f"layer_types mixes {sorted(set(kinds))}: set "
+                    f"layer_types mixes {sorted(set(kinds))} with "
+                    f"{cfg.num_dense_layers} leading dense blocks: set "
                     f"scan_layers=False (the stack is then unrolled)")
             stack = nn.scan(block_cls,
                             variable_axes={"params": 0, "cache": 0},
@@ -425,12 +516,16 @@ class LlamaForCausalLM(nn.Module):
         else:
             per_layer = []
             for i in range(cfg.num_hidden_layers):
+                dense = {} if cfg.sparse(i) or cfg.moe is None \
+                    else {"sparse": False}
                 h, ys = block_cls(cfg, deterministic, *kinds[i:i + 1],
-                                  name=f"layers_{i}")(h, (position_ids, mask))
+                                  name=f"layers_{i}", **dense)(
+                    h, (position_ids, mask))
                 per_layer.append(ys)
-            if cfg.moe is not None:
+            if cfg.moe is not None:     # stacked over the MoE layers alone
                 per_layer = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *per_layer)
+                    lambda *xs: jnp.stack(xs),
+                    *per_layer[cfg.num_dense_layers:])
 
         out = ModelOutput()
         aux_loss = None
@@ -479,6 +574,34 @@ class LlamaForCausalLM(nn.Module):
 
         record_stats(stats)
 
+    @staticmethod
+    def is_state_leaf(path: tuple) -> bool:
+        """Leaves of ``params`` that are state and no parameter (the
+        engine keeps them from the optimizer and hands them, with the
+        step's statistics, to :meth:`update_state_leaves`)."""
+        from ..parallel.moe import STATE_LEAF
+
+        return path[-1] == STATE_LEAF
+
+    def update_state_leaves(self, held: dict, stats: dict) -> dict:
+        """Each MoE layer's selection bias after a step that routed
+        ``stats["tokens_per_expert"]`` (MoE layers, experts), summed over
+        the step's micro-batches: traced inside the compiled step."""
+        from ..parallel.moe import STATE_LEAF, bias_update
+
+        cfg = self.cfg
+        counts = stats["tokens_per_expert"]
+        with trace.device_span("moe/bias_update"):
+            if "layers" in held:        # a scanned stack: leading layer axis
+                gate = held["layers"]["moe"]["gate"]
+                new = jax.vmap(lambda c, b: bias_update(
+                    c, b, cfg.moe.bias_update_rate))(counts, gate[STATE_LEAF])
+                return {"layers": {"moe": {"gate": {STATE_LEAF: new}}}}
+            return {name: {"moe": {"gate": {STATE_LEAF: bias_update(
+                counts[int(name.rsplit("_", 1)[1]) - cfg.num_dense_layers],
+                sub["moe"]["gate"][STATE_LEAF], cfg.moe.bias_update_rate)}}}
+                for name, sub in held.items()}
+
     def dummy_inputs(self, batch_size: int = 2, seq_len: Optional[int] = None):
         S = seq_len or min(self.cfg.max_position_embeddings, 128)
         ids = jnp.zeros((batch_size, S), jnp.int32)
@@ -491,13 +614,17 @@ class LlamaForCausalLM(nn.Module):
         # a sparse FFN multiplies by the experts of a token's top_k that
         # live here (all of them unless this instance holds a share), and
         # its router
-        ffn = 3 * E * cfg.intermediate_size
+        dense = ffn = 3 * E * cfg.intermediate_size
         if cfg.moe is not None:
-            ffn = (3 * E * cfg.expert_size * cfg.moe.top_k
-                   * cfg.moe.num_experts / cfg.moe.routed
+            ffn = (3 * E * cfg.expert_size
+                   * (cfg.moe.top_k * cfg.moe.num_experts / cfg.moe.routed
+                      + cfg.moe.num_shared_experts)
                    + E * cfg.moe.routed)
-        n = (2 * cfg.padded_vocab_size * E
-             + L * (2 * E * H * D + 2 * E * cfg.kv_heads * D + ffn))
+        attn = (3 if cfg.attn_gate else 2) * E * H * D \
+            + 2 * E * cfg.kv_heads * D
+        n = (2 * cfg.padded_vocab_size * E + L * attn
+             + cfg.num_dense_layers * dense
+             + (L - cfg.num_dense_layers) * ffn)
         # QK^T and AV over the keys a layer keeps: all positions, or the
         # window where that is shorter
         S = cfg.max_position_embeddings

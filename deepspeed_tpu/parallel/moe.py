@@ -48,6 +48,15 @@ and the mesh, never from the environment):
   for one.
 - the opt-in gathered decode path (``DS_TPU_MOE_FAST``) of the capacity
   layer at <= 32 eval tokens.
+
+AFMoE's routing (Trinity, ``model_type: afmoe``; the DeepSeek-V3 lineage)
+is the dropless path with fields: ``score_func="sigmoid"``, its
+``route_norm`` is ``norm_topk_prob``, ``route_scale`` multiplies the chosen
+scores, ``bias_update_rate`` (its ``load_balance_coeff``) a selection bias
+that picks and does not weigh (the state leaf ``expert_bias``, moved by
+:func:`bias_update` from each step's counts and by no gradient),
+``num_shared_experts`` a dense SwiGLU beside the routed sum, computed
+whole by every share.
 """
 from __future__ import annotations
 
@@ -91,8 +100,33 @@ class MoEConfig:
     # instance holds ``num_experts`` of them from ``first_expert`` on
     routed_experts: Optional[int] = None
     first_expert: int = 0
+    # dropless routing only, under AFMoE's config.json names.  How a token
+    # scores the experts: "softmax" over all of them, or each expert's own
+    # "sigmoid"
+    score_func: str = "softmax"
+    # the k chosen scores (renormalised first under norm_topk_prob, which
+    # is AFMoE's route_norm) times this
+    route_scale: float = 1.0
+    # not None: the top-k is taken of score + ``expert_bias``, a leaf that
+    # picks and never weighs, has no gradient and that the optimizer skips
+    # (STATE_LEAF); the step moves it by this rate against each expert's
+    # load (:func:`bias_update`; AFMoE's load_balance_coeff).  The only
+    # balancing mechanism it needs: set aux_loss_weight to 0 beside it
+    bias_update_rate: Optional[float] = None
+    # experts every token runs, one dense SwiGLU of num_shared_experts x
+    # the experts' width added to the routed sum; a share computes it whole
+    num_shared_experts: int = 0
 
     def __post_init__(self):
+        if self.score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"score_func must be 'softmax' or 'sigmoid', "
+                             f"got {self.score_func!r}")
+        if self.drop_tokens and self.afmoe_fields:
+            raise NotImplementedError(
+                f"{', '.join(self.afmoe_fields)}: written for the dropless "
+                f"routing (drop_tokens=False) only")
+        if self.num_shared_experts and self.expert_act != "swiglu":
+            raise NotImplementedError("shared experts are SwiGLU")
         if self.routed_experts is None:
             if self.first_expert:
                 raise ValueError("first_expert without routed_experts")
@@ -114,6 +148,14 @@ class MoEConfig:
     @property
     def holds_all(self) -> bool:
         return self.routed == self.num_experts
+
+    @property
+    def afmoe_fields(self) -> Tuple[str, ...]:
+        """The routing fields set away from their defaults, by name."""
+        return tuple(f for f, off in (
+            ("score_func", "softmax"), ("route_scale", 1.0),
+            ("bias_update_rate", None),
+            ("num_shared_experts", 0)) if getattr(self, f) != off)
 
 
 def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: int,
@@ -200,28 +242,55 @@ def top2_gating(logits: jax.Array, capacity: int, rng=None,
     return l_aux, combine, dispatch
 
 
-def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False
+def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
+                 *, score_func: str = "softmax",
+                 bias: Optional[jax.Array] = None, route_scale: float = 1.0
                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                             jax.Array]:
     """Dropless top-k routing over float32 ``logits`` (S, E).
 
     Returns ``(weights (S, k), experts (S, k) int32, counts (E,) int32,
-    l_balance, l_z)``: the k largest softmax probabilities of each token
-    (renormalised to sum to 1 only with ``norm_topk_prob``), how many of
-    the S*k assignments each expert received, the load-balancing loss
-    ``E * sum_e f_e * P_e`` (``f_e`` expert e's share of the assignments,
-    ``P_e`` its mean probability; 1 when balanced) and the router z-loss
-    ``mean_s logsumexp(logits_s)^2``."""
+    l_balance, l_z)``: the k largest scores of each token (softmax
+    probabilities, renormalised to sum to 1 only with ``norm_topk_prob``),
+    how many of the S*k assignments each expert received, the
+    load-balancing loss ``E * sum_e f_e * P_e`` (``f_e`` expert e's share
+    of the assignments, ``P_e`` its mean score; 1 when balanced under
+    softmax) and the router z-loss ``mean_s logsumexp(logits_s)^2``.
+
+    ``score_func="sigmoid"`` scores each expert on its own.  With ``bias``
+    (E,) the k experts are those of the largest ``score + bias`` and the
+    weights stay their scores: the bias picks, it does not weigh, and no
+    gradient reaches it.  ``route_scale`` multiplies the weights last."""
     S, E = logits.shape
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)
+    probs = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
+        else jax.nn.sigmoid(logits)
+    if bias is None:
+        weights, experts = jax.lax.top_k(probs, top_k)
+    else:
+        _, experts = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), top_k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
         weights = weights / weights.sum(axis=-1, keepdims=True)
+    if route_scale != 1.0:
+        weights = weights * route_scale
     counts = jnp.bincount(experts.reshape(-1), length=E).astype(jnp.int32)
     l_balance = E * jnp.sum(counts.astype(jnp.float32) / (S * top_k)
                             * probs.mean(axis=0))
     l_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
     return weights, experts.astype(jnp.int32), counts, l_balance, l_z
+
+
+# the leaf of a gate that is state and no parameter: the selection bias
+STATE_LEAF = "expert_bias"
+
+
+def bias_update(counts: jax.Array, bias: jax.Array, rate: float) -> jax.Array:
+    """The selection bias after a step that routed ``counts`` (E,) pairs
+    to the experts of its layer: up by ``rate`` where an expert received
+    fewer than the mean, down where more (the loss-free balancing of
+    arXiv:2408.15664, as AFMoE's trainer applies it)."""
+    c = counts.astype(jnp.float32)
+    return bias + rate * jnp.sign(c.mean() - c)
 
 
 class TopKGate(nn.Module):
@@ -244,8 +313,12 @@ class TopKGate(nn.Module):
         # a float32 router means float32 products: the TPU's default
         # precision would round both operands to bf16 first
         logits = jnp.dot(xf, wg, precision=jax.lax.Precision.HIGHEST)
-        if logits_only:
-            return logits
+        if logits_only:     # with the selection bias, where there is one
+            bias = None if cfg.bias_update_rate is None else self.param(
+                STATE_LEAF, nn.with_partitioning(
+                    nn.initializers.zeros, ("experts_gate",)), (cfg.routed,),
+                jnp.float32)
+            return logits, bias
         if decode_fast:
             # decode path (the Tutel fast-dispatch analog, reference
             # sharded_moe.py:501): no capacity queues at a handful of
@@ -502,6 +575,29 @@ class ExpertsMLP(nn.Module):
         return apply
 
 
+class SharedExpert(nn.Module):
+    """The SwiGLU every token runs beside its routed experts (AFMoE's
+    ``shared_experts``): dense leaves, whole on every share."""
+
+    model_dim: int
+    hidden_dim: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        def weight(name, axes, shape):
+            return self.param(name, nn.with_partitioning(
+                nn.initializers.normal(0.02), axes), shape,
+                self.param_dtype).astype(self.dtype)
+
+        M, H = self.model_dim, self.hidden_dim
+        gate = weight("gate", ("embed", "mlp"), (M, H))
+        up = weight("up", ("embed", "mlp"), (M, H))
+        down = weight("down", ("mlp", "embed"), (H, M))
+        return jnp.dot(nn.silu(jnp.dot(x, gate)) * jnp.dot(x, up), down)
+
+
 class MoELayer(nn.Module):
     """Drop-in MoE FFN (reference ``MOELayer`` ``sharded_moe.py:440`` +
     ``MoE`` wrapper ``layer.py:18``).
@@ -539,6 +635,7 @@ class MoELayer(nn.Module):
         S, E, k = x2.shape[0], cfg.routed, cfg.top_k
         l_z = jnp.float32(0.0)
         elsewhere = jnp.int32(0)
+        bias = None
         if not cfg.drop_tokens:
             if not ep1:
                 raise NotImplementedError(
@@ -550,9 +647,17 @@ class MoELayer(nn.Module):
             if not 1 <= k <= E:
                 raise ValueError(f"top_k must be in 1..{E}, got {k}")
             with trace.device_span("moe/route"):
+                logits, bias = gate(x2, train, logits_only=True)
                 weights, chosen, counts, l_aux, l_z = topk_routing(
-                    gate(x2, train, logits_only=True), k, cfg.norm_topk_prob)
+                    logits, k, cfg.norm_topk_prob, score_func=cfg.score_func,
+                    bias=bias, route_scale=cfg.route_scale)
             out = experts(x2, routing=(weights, chosen))
+            if cfg.num_shared_experts:
+                with trace.device_span("moe/shared"):
+                    out = out + SharedExpert(
+                        self.model_dim,
+                        self.hidden_dim * cfg.num_shared_experts,
+                        dtype=self.dtype, name="shared")(x2)
             # pairs no expert's group holds (an id outside 0..E-1): the
             # grouped matmul multiplies exactly counts.sum() rows, or, of
             # a share, those of its own experts; the pairs routed to
@@ -606,6 +711,8 @@ class MoELayer(nn.Module):
                  "balance_loss": l_aux, "router_z": l_z}
         if not cfg.holds_all:
             stats["elsewhere"] = elsewhere
+        if bias is not None:
+            stats[STATE_LEAF] = bias
         return out, aux, stats
 
 
@@ -616,12 +723,16 @@ def record_stats(stats: Dict[str, Any]) -> None:
     ``return_stats``, stacked over the model's MoE layers:
     ``tokens_per_expert`` (L, E), ``dropped`` (L,), ``balance_loss`` (L,),
     ``router_z`` (L,), and from a layer that holds a share of its experts
-    ``elsewhere`` (L,).  Counters ``moe_tokens_per_expert{layer,expert}``
+    ``elsewhere`` (L,), and from a layer routed under a selection bias
+    ``expert_bias`` (L, E), the bias the step selected with.  Counters ``moe_tokens_per_expert{layer,expert}``
     (max / mean over a layer's experts is its load imbalance),
     ``moe_dropped_tokens_total`` (0 on the dropless path, always) and
     ``moe_pairs_elsewhere_total`` (pairs routed to experts that another
     instance holds: not dropped, not multiplied here); gauges
     ``moe_aux_loss`` / ``moe_router_z``, the layer means of the last step.
+    With a bias: gauge ``moe_expert_bias{layer, stat=min|max}``, counter
+    ``moe_bias_updates_total`` (one a layer a step: the engine applies
+    :func:`bias_update` in the step that returned these statistics).
     """
     counts = np.asarray(stats["tokens_per_expert"])
     counts = counts.reshape(-1, counts.shape[-1])
@@ -642,6 +753,17 @@ def record_stats(stats: Dict[str, Any]) -> None:
             "(token, choice) pairs routed to an expert that another "
             "instance of the expert-parallel layer holds"
         ).inc(float(np.sum(stats["elsewhere"])))
+    if STATE_LEAF in stats:
+        bias = np.asarray(stats[STATE_LEAF]).reshape(counts.shape)
+        spread = registry.gauge(
+            "moe_expert_bias", "the selection bias over a layer's routed "
+            "experts, last finished step", ("layer", "stat"))
+        for layer, row in enumerate(bias):
+            spread.labels(layer, "min").set(float(row.min()))
+            spread.labels(layer, "max").set(float(row.max()))
+        registry.counter(
+            "moe_bias_updates_total", "selection-bias updates the compiled "
+            "step made, one a biased layer a step").inc(float(len(bias)))
     registry.gauge("moe_aux_loss", "load-balancing loss, mean over layers, "
                    "last finished step").set(float(np.mean(stats["balance_loss"])))
     registry.gauge("moe_router_z", "router z-loss, mean over layers, last "

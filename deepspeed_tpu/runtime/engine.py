@@ -43,7 +43,7 @@ from ..parallel import zero as zero_lib
 from ..telemetry import recompile, registry as telemetry_registry, trace
 from ..testing import chaos as chaos_mod
 from ..utils import ThroughputTimer, log_dist, logger
-from . import precision
+from . import precision, state_leaves
 from .config import Config
 from .dataloader import DeepSpeedDataLoader, RepeatingLoader
 from .lr_schedules import get_lr_schedule
@@ -702,7 +702,8 @@ class Engine:
             # dstpu-lint: disable-next-line=DSTPU005 -- one-shot sharded param init at engine construction; intentionally single-use
             placed = jax.jit(_init_unboxed, out_shardings=param_sh)(rng)
         # dstpu-lint: disable-next-line=DSTPU005 -- one-shot optimizer-state init, same single-use pattern
-        opt_state = jax.jit(self.tx.init, out_shardings=opt_sh)(placed)
+        opt_state = jax.jit(self.tx.init, out_shardings=opt_sh)(
+            self._split_state_leaves(placed)[0])
         ls_state = precision.init_loss_scale(self.config.fp16)
         ls_state = jax.device_put(ls_state, repl)
 
@@ -718,26 +719,53 @@ class Engine:
                  f"{self.zero_stage} | offload {self.offload_device} | "
                  f"mesh {dict(self.mesh.shape)}", ranks=[0])
 
+    def _split_state_leaves(self, params):
+        """``(what the optimizer sees, the model's state leaves)`` of a
+        parameter tree (``runtime/state_leaves.py``); ``params`` itself
+        and ``{}`` for a model that declares none."""
+        is_state = getattr(self.model, "is_state_leaf", None)
+        if is_state is None:
+            return params, {}
+        rest, held = state_leaves.split(params, is_state)
+        if not held:
+            return params, {}
+        steps_elsewhere = [why for why, on in (
+            ("pipeline parallelism", self.pp_size > 1),
+            ("the compressed 1-bit collective", self._onebit_comm),
+            ("optimizer offload", self.offload_device != "none"),
+            ("parameter offload", self.param_offload_device != "none"),
+            ("sparse_gradients", self.config.sparse_gradients)) if on]
+        if steps_elsewhere:
+            raise NotImplementedError(
+                f"{type(self.model).__name__} declares state leaves, which "
+                f"the train_batch step updates; not written for "
+                f"{', '.join(steps_elsewhere)}")
+        return rest, held
+
     def _build_specs(self, boxed_abstract_params) -> None:
         """Sharding specs for params/grads/opt state from the ZeRO stage +
-        TP rules (no device arrays touched)."""
+        TP rules (no device arrays touched).  Gradients and optimizer
+        state have the parameters' tree less the model's state leaves."""
         stage = self.zero_stage
         if self.pp_size > 1:
             # pipeline stages own their slice of the stacked layer dim
             self._partition_rules = dict(self._partition_rules, layers="pp")
         self._param_specs = zero_lib.param_partition_specs(
             boxed_abstract_params, self.mesh, stage, rules=self._partition_rules)
-        stage3_like = zero_lib.shard_like_stage3(boxed_abstract_params, self.mesh,
+        trained, held = self._split_state_leaves(boxed_abstract_params)
+        trained_specs = self._param_specs if not held \
+            else self._split_state_leaves(self._param_specs)[0]
+        stage3_like = zero_lib.shard_like_stage3(trained, self.mesh,
                                                  rules=self._partition_rules)
-        self._grad_specs = stage3_like if stage >= 2 else self._param_specs
-        opt_like = stage3_like if stage >= 1 else self._param_specs
+        self._grad_specs = stage3_like if stage >= 2 else trained_specs
+        opt_like = stage3_like if stage >= 1 else trained_specs
         if self._onebit_comm:
             from . import onebit_comm as _obc
 
             self._opt_specs = _obc.state_specs(_unbox(boxed_abstract_params))
         else:
             self._opt_specs = zero_lib.opt_state_specs(
-                self.tx, boxed_abstract_params, opt_like)
+                self.tx, trained, opt_like)
 
     def abstract_state(self, example_batch=None) -> "TrainState":
         """Abstract (ShapeDtypeStruct + sharding) TrainState — compile-time
@@ -764,7 +792,8 @@ class Engine:
         a_params = jax.tree_util.tree_map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
             unboxed, param_sh)
-        a_opt = jax.eval_shape(self.tx.init, unboxed)
+        a_opt = jax.eval_shape(self.tx.init,
+                               self._split_state_leaves(unboxed)[0])
         a_opt = jax.tree_util.tree_map(
             lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
             a_opt, opt_sh)
@@ -951,18 +980,21 @@ class Engine:
         return self._adam8bit_apply is not None
 
     def _grads_of(self, params, batch, rng, scale, pld_theta=None,
-                  narrow: bool = False):
+                  narrow: bool = False, held=None):
         """(scaled loss, grads, the model's step statistics) on one global
         micro-batch.  ``narrow``: a gradient that is an exact up-cast of
         what the backward wrote comes back in that dtype (the kernel path
-        of the int8 Adam update reads it once, as written)."""
+        of the int8 Adam update reads it once, as written).  ``held``:
+        the model's state leaves, which ``params`` then lacks; the loss
+        reads them and no gradient is taken for them."""
         if self.config.sparse_gradients:
             return self._grads_of_sparse(params, batch, rng, scale,
                                          pld_theta) + ({},)
 
         def scaled_loss_fn(p):
             loss, stats = self._loss_and_stats(
-                p, batch, rng, deterministic=False, pld_theta=pld_theta)
+                state_leaves.merge(p, held) if held else p, batch, rng,
+                deterministic=False, pld_theta=pld_theta)
             return loss * scale, stats
 
         gdt = self._grad_dtype
@@ -1146,6 +1178,10 @@ class Engine:
             pld_theta = extra[0] if pld_on else None
             rng = jax.random.fold_in(self._base_rng, state.step)
             scale = state.loss_scale.scale if cfg.fp16.enabled else jnp.float32(1.0)
+            # from here to the update ``state`` is the optimizer's view:
+            # the parameters without the model's state leaves
+            trained, held = self._split_state_leaves(state.params)
+            state = state.replace(params=trained)
             if gas > 1:
                 mbs = self._split_microbatches(batch, gas)
 
@@ -1153,7 +1189,8 @@ class Engine:
                     g_acc, l_acc, i = carry
                     mb_rng = jax.random.fold_in(rng, i)
                     loss, grads, stats = self._grads_of(
-                        state.params, mb, mb_rng, scale, pld_theta)
+                        state.params, mb, mb_rng, scale, pld_theta,
+                        held=held)
                     g_acc = jax.tree_util.tree_map(
                         lambda a, g: a + g.astype(a.dtype), g_acc, grads)
                     g_acc = self._scatter_grads(g_acc)
@@ -1172,10 +1209,20 @@ class Engine:
             else:
                 loss_sum, g_sum, stats = self._grads_of(
                     state.params, batch, rng, scale, pld_theta,
-                    narrow=self._adam8bit_kernel)
+                    narrow=self._adam8bit_kernel, held=held)
                 g_sum = self._scatter_grads(g_sum)
             new_state, metrics = self._apply_grads(
                 state, g_sum, loss_sum, jnp.float32(gas))
+            if held:
+                # the model's own rule, from the whole step's statistics
+                from ..ops.pallas.spmd import note_dispatch
+
+                for _ in jax.tree_util.tree_leaves(held):
+                    note_dispatch("state_leaf", "compiled_step",
+                                  "the model's rule; the optimizer skips it")
+                new_state = new_state.replace(params=state_leaves.merge(
+                    new_state.params,
+                    self.model.update_state_leaves(held, stats)))
             if stats:
                 metrics["model_stats"] = stats
             return new_state, metrics
@@ -1641,6 +1688,10 @@ class Engine:
                 jax.random.fold_in(self._base_rng, state.step), micro_idx)
             scale = state.loss_scale.scale if self.config.fp16.enabled else jnp.float32(1.0)
             params = state.params
+            if self._split_state_leaves(params)[1]:
+                raise NotImplementedError(
+                    "forward/backward/step with a model that declares state "
+                    "leaves: train_batch's step updates them")
             if self._has_store_transform:
                 params = self._to_canonical_params(params)
             loss, grads, _ = self._grads_of(params, batch, rng, scale)

@@ -1,6 +1,6 @@
-"""What the three Mellum 2 probes share: the cell ``train-mellum2-8k-1chip``
-as the benchmark loads it, and the trainer built on it as the cell's driver
-builds it (weights from the seed, the file's ``init_scale``, the packed
+"""What the probes of a share's cell share (Mellum 2's three, Trinity's
+two): the cell, ``train-mellum2-8k-1chip`` unless named, as the benchmark
+loads it, and the trainer built on it as the cell's driver builds it (weights from the seed, the file's ``init_scale``, the packed
 batches), with the configuration edited first where a probe varies it."""
 import os
 import sys
@@ -11,7 +11,8 @@ sys.path.insert(0, ROOT)
 CELL = "train-mellum2-8k-1chip"
 
 
-def build(seed: int, rehearse: bool = False, edit=None, init_scale=None):
+def build(seed: int, rehearse: bool = False, edit=None, init_scale=None,
+          cell: str = CELL):
     """``(cell, driver, engine, cfg, conf, batches)``; ``edit(conf)`` may
     change the sized configuration before the engine is built, and
     ``rehearse`` takes the file's CPU sizes (control flow only)."""
@@ -20,7 +21,7 @@ def build(seed: int, rehearse: bool = False, edit=None, init_scale=None):
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    cell = M.load_cell(M.load_manifest(ROOT), CELL, ROOT)
+    cell = M.load_cell(M.load_manifest(ROOT), cell, ROOT)
 
     def sized(section):
         out = {k: v for k, v in section.items() if k != "rehearse"}

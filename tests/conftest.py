@@ -73,11 +73,36 @@ _PINNED_TO_THE_OLMOE_CELL = (
 )
 
 
+# Three tests of ``tests/benchmark/test_mellum2_cell.py`` (PR 30) in turn pin
+# lists to the three cells of their day: one holds ``train_step_ms``'s
+# ``workloads`` to exactly three cells and the sparse metrics' to two, and
+# both cases of another hold every ``reduced`` inside a set that lacks
+# ``num_dense_layers``.  ``train-trinity-mini-8k-1chip`` (PR 33) is appended
+# to those lists and cuts its leading dense layers 2 -> 1, so they fail by
+# construction, and are expected to, strictly;
+# ``tests/benchmark/test_trinity_cell.py`` holds their versions over
+# prefixes and subsets, which the next cell needs no copy of.  The same
+# ROADMAP.md job removes these marks with the others.
+_PINNED_TO_THREE_CELLS = (
+    "test_every_accepted_cell_is_still_there_with_its_values",
+    "test_every_cell_loads_and_is_cut_only_as_the_guide_allows[manifest]",
+    "test_every_cell_loads_and_is_cut_only_as_the_guide_allows[with_pending]",
+)
+_SUPERSEDED = {
+    "test_olmoe_cell.py": (
+        _PINNED_TO_THE_OLMOE_CELL,
+        "pins the manifest's tail to the OLMoE cell; superseded by "
+        "test_mellum2_cell.py (PR 30)"),
+    "test_mellum2_cell.py": (
+        _PINNED_TO_THREE_CELLS,
+        "pins the manifest's lists to three cells and the cuts to a set "
+        "without num_dense_layers; superseded by test_trinity_cell.py "
+        "(PR 33)"),
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.fspath.basename == "test_olmoe_cell.py" \
-                and item.name in _PINNED_TO_THE_OLMOE_CELL:
-            item.add_marker(pytest.mark.xfail(
-                reason="pins the manifest's tail to the OLMoE cell; "
-                       "superseded by test_mellum2_cell.py (PR 30)",
-                strict=True))
+        names, reason = _SUPERSEDED.get(item.fspath.basename, ((), ""))
+        if item.name in names:
+            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))
