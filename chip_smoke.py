@@ -604,7 +604,81 @@ def kernel_flash_window_gqa():
             assert worst.max() <= TOL, (name, n, worst)
 
 
-KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_grouped_matmul,
+def kernel_qk_rows():
+    """q and k as ``(B, S, H*D)`` rows through ``ops/pallas/qk_rows.py`` at
+    the third and fourth cells' shapes (4 and 3 rows of 8192, 32 / 4 heads
+    of 128): the rotation alone, the per-head norm + rotation, the norm
+    alone, forward and ``jax.vjp``, each against ``rms_norm`` and
+    ``apply_rotary`` on float32 operands in the ``(B, S, H, D)`` view.
+    Positions as the model passes them, ``arange(S)[None]`` for every row,
+    and a row of its own each; every row of the batch is held apart (a
+    table block read past its array is garbage in rows 1.. only)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.models.common import rms_norm
+    from deepspeed_tpu.ops import rotary
+
+    S, H, KV, D, eps = 8192, 32, 4, 128, 1e-5
+    for B, norm, rotate, own in ((4, False, True, False), (3, True, True, True),
+                                 (3, True, False, False)):
+        ks = jax.random.split(jax.random.PRNGKey(B + norm + rotate), 6)
+        q, gq = (jax.random.normal(kk, (B, S, H * D), jnp.float32)
+                 .astype(jnp.bfloat16) for kk in ks[:2])
+        k, gk = (jax.random.normal(kk, (B, S, KV * D), jnp.float32)
+                 .astype(jnp.bfloat16) for kk in ks[2:4])
+        scales = 1 + 0.2 * jax.random.normal(ks[4], (2, D), jnp.float32)
+        pos = jnp.arange(S)[None, :]
+        if own:     # packed rows: each its own positions
+            pos = (pos + 1000 * jnp.arange(B)[:, None]) % 8192
+        q_plan = jax.ShapeDtypeStruct(q.shape, q.dtype)
+        k_plan = jax.ShapeDtypeStruct(k.shape, k.dtype)
+        plan = rotary.rows_plan(q_plan, k_plan, D, norm=norm)
+        assert plan == ("direct", None), plan
+
+        def rows(q, k, scales):
+            return rotary.rotate_rows(
+                q, k, pos if rotate else None, D, plan,
+                q_scale=scales[0] if norm else None,
+                k_scale=scales[1] if norm else None, eps=eps)
+
+        def ref(q, k, scales):
+            q4 = q.astype(jnp.float32).reshape(B, S, H, D)
+            k4 = k.astype(jnp.float32).reshape(B, S, KV, D)
+            if norm:
+                q4 = rms_norm(q4, scales[0], eps)
+                k4 = rms_norm(k4, scales[1], eps)
+            if rotate:
+                q4, k4 = rotary.apply_rotary_pos_emb(
+                    q4, k4, jnp.broadcast_to(pos, (B, S)), rotary_dim=D)
+            return q4.reshape(q.shape), k4.reshape(k.shape)
+
+        def both(fn):
+            def run(q, k, scales):
+                out, vjp = jax.vjp(fn, q, k, scales)
+                return out, vjp(tuple(g.astype(o.dtype)
+                                      for g, o in zip((gq, gk), out)))
+            return jax.jit(run)
+
+        got, want = both(rows)(q, k, scales), both(ref)(q, k, scales)
+        name = f"qk_rows B={B} norm={norm} rotate={rotate}"
+        for n, g, w in zip(("q'", "k'", "dq", "dk", "dscale"),
+                           jax.tree_util.tree_leaves(got),
+                           jax.tree_util.tree_leaves(want)):
+            if g.ndim != 3:
+                _check_close(f"{name} {n}", g, w)
+                continue
+            g, w = (np.asarray(t, np.float32) for t in (g, w))
+            worst = np.abs(g - w).max(axis=(1, 2)) / np.abs(w).max(axis=(1, 2))
+            print(f"  {name} {n}: worst of {B} rows {worst.max():.2e}",
+                  flush=True)
+            assert np.isfinite(g).all() and worst.max() <= TOL, (name, n,
+                                                                 worst)
+
+
+KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_qk_rows,
+                kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,
                 kernel_decode_layer, kernel_w8_matmul)

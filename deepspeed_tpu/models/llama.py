@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.rotary import apply_rotary_pos_emb
+from ..ops.rotary import apply_rotary_pos_emb, rotate_rows, rows_plan
 from ..telemetry import trace
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
@@ -333,6 +333,24 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, position_ids, attn_mask, fused_norm=None):
+        """Which shapes take which path between the projections and the
+        attention call.  The projections write q and k as ``(B, S, H*D)``
+        rows and the flash kernels read exactly those, so wherever
+        ``ops/rotary.py rows_plan`` allows - ``head_dim`` a multiple of
+        128 (OLMoE, Mellum 2, Trinity), no decode cache, a TPU, one
+        device's own operands - the per-head norm of ``qk_norm="head"``
+        and the rotation are one pass over the rows
+        (``ops/pallas/qk_rows.py``) and the reshapes to ``(B, S, H, D)``
+        that ``dot_product_attention``'s signature asks for fold away
+        between two row-major kernels.  On the chip that view is no
+        bitcast of the rows: formed for the rotation it cost a copy each
+        way, forward, recomputation and backward (PERF.md section 6,
+        PR 34).  Every other shape - head_dim 64 / 80 / 96, ``decode``
+        (the cache append and ``_fused_decode`` keep ``(B, S, KV, D)``),
+        heads split over ``tp``, the CPU - rotates and normalises in the
+        ``(B, S, H, D)`` view as before;
+        ``kernel_dispatch_total{site="qk_rows"}`` says which, and why.
+        ``qk_norm=True`` (over the whole projection) is flat already."""
         cfg = self.cfg
         B, S, E = x.shape
         H, KV, D = cfg.num_attention_heads, cfg.kv_heads, cfg.head_dim
@@ -346,20 +364,32 @@ class LlamaAttention(nn.Module):
         if cfg.qk_norm is True:
             q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
             k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
+        rotates, head_norm = cfg.rotates(self.kind), cfg.qk_norm == "head"
+        plan = rows_plan(q, k, D, decode=cfg.decode, norm=head_norm) \
+            if rotates or head_norm else None
+        # a layer type's own table and device scopes only where the
+        # configuration names layer types
+        typed = self.kind is not None or cfg.rope_parameters is not None
+        rope = "rope/" + (self.kind or FULL_ATTENTION) if typed else "rope"
+        if plan is not None:
+            scales = [RMSNorm(cfg, axis="head_dim", name=name)(
+                jax.ShapeDtypeStruct((D,), cfg.dtype), params_only=True)
+                for name in ("q_norm", "k_norm")] if head_norm else [None] * 2
+            with trace.device_span(rope if rotates else "attn/qk_norm"):
+                q, k = rotate_rows(
+                    q, k, position_ids if rotates else None, D, plan,
+                    theta=cfg.rope_theta, table=cfg.rotary(self.kind),
+                    q_scale=scales[0], k_scale=scales[1],
+                    eps=cfg.rms_norm_eps)
         q, k = q.reshape(B, S, H, D), k.reshape(B, S, KV, D)
         v = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="v_proj",
                    module=self).reshape(B, S, KV, D)
-        if cfg.qk_norm == "head":
+        if head_norm and plan is None:
             with trace.device_span("attn/qk_norm"):
                 q = RMSNorm(cfg, axis="head_dim", name="q_norm")(q)
                 k = RMSNorm(cfg, axis="head_dim", name="k_norm")(k)
-        # a layer type's own table and device scopes only where the
-        # configuration names layer types: other models' traces stay as
-        # they were
-        typed = self.kind is not None or cfg.rope_parameters is not None
-        if cfg.rotates(self.kind):
-            with trace.device_span(f"rope/{self.kind or FULL_ATTENTION}") \
-                    if typed else contextlib.nullcontext():
+        if rotates and plan is None:
+            with trace.device_span(rope):
                 q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
                                             theta=cfg.rope_theta,
                                             table=cfg.rotary(self.kind))

@@ -615,16 +615,18 @@ def _row_heads(x, lanes: Lanes):
 
 def _delta(do, out, lanes: Lanes):
     """Row sums of dO · O a head, float32, laid out as :func:`_head_rows`
-    does.  Where a head is narrower than the 128 lanes an array is tiled
-    in, a reshape to ``(..., H, D)`` costs XLA a float32 copy of the
-    product before it can reduce; there the sum over a head's lanes is a
-    product with a 0/1 matrix instead (one column a head, the padding
-    heads' all zero), which XLA fuses the multiply into: dO and O are
-    read once."""
+    does.  A reshape to ``(..., H, D)`` costs XLA a float32 copy of the
+    product before it can reduce - where a head is narrower than the 128
+    lanes an array is tiled in, and at 128 too, because XLA keeps the
+    product feature-major (``f32[4096,8,32,128]`` a layer of Mellum 2:
+    2.6 ms by the compiler's own estimate, PR 34).  So the sum over a
+    head's lanes is a product with a 0/1 matrix instead (one column a
+    head, the padding heads' all zero), which XLA fuses the multiply
+    into: dO and O are read once."""
     N, S, W = do.shape
     D = lanes.head_dim
     prod = do.astype(jnp.float32) * out.astype(jnp.float32)
-    if D % 128 == 0 or W == D:
+    if W == D:
         return _head_rows(prod.reshape(N, S, W // D, D).sum(axis=-1), lanes)
     heads = lanes.blocks * lanes.heads
     own = (jnp.arange(W)[None, :] // D

@@ -5,6 +5,22 @@ Kernel-parity analog of reference
 rotate the leading ``rotary_dim`` channels of q/k by position-dependent
 angles.  One fused XLA computation; supports GPT-NeoX style (half-split)
 rotation and partial rotary (``rotary_pct``).
+
+Which shapes take which path.  :func:`apply_rotary` and
+:func:`apply_rotary_interleaved` work in the ``(B, S, H, D)`` view and take
+every shape.  On the chip that view is no bitcast of the ``(B, S, H*D)``
+rows a projection writes and the flash kernels read, so where the rows
+allow it a model keeps q and k flat and calls :func:`rotate_rows` (the
+Pallas pass of ``ops/pallas/qk_rows.py``, which can also normalise each
+head first): :func:`rows_plan` says yes where ``head_dim`` is a multiple of
+128 (a head is whole lane blocks), the rotation is half-split over all of
+``head_dim``, the call is no decode step (the cache keeps ``(B, S, KV,
+D)``), the process is on a TPU and the operands are one device's own
+(``kernel_mesh_plan``: one device, or a ``shard_map`` over the batch
+axes).  Anything else - head_dim 64 / 80 / 96, ``rotary_dim < head_dim``,
+interleaved pairs, heads split over ``tp``, the CPU - keeps the ``(B, S,
+H, D)`` functions; ``kernel_dispatch_total{site="qk_rows"}`` counts each
+decision with the guard that made it.
 """
 from __future__ import annotations
 
@@ -138,3 +154,85 @@ def apply_rotary_pos_emb(q: jax.Array, k: jax.Array, positions: jax.Array,
     cos, sin = rotary_angles(positions, rd, theta, table)
     rot = apply_rotary_interleaved if interleaved else apply_rotary
     return (rot(q, cos, sin, rd), rot(k, cos, sin, rd))
+
+
+def rows_plan(q: jax.Array, k: jax.Array, head_dim: int, *,
+              rotary_dim: Optional[int] = None, interleaved: bool = False,
+              decode: bool = False, norm: bool = False) -> Optional[tuple]:
+    """Whether q (B, S, H*D) and k (B, S, KV*D) stay rows through their
+    rotation (and per-head ``norm``): ``kernel_mesh_plan``'s verdict and
+    batch axes where :func:`rotate_rows` takes them, None where the
+    ``(B, S, H, D)`` functions do.  Counted, with the guard that decided,
+    in ``kernel_dispatch_total{site="qk_rows"}``."""
+    from .attention import on_tpu
+    from .pallas import qk_rows
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    verdict = axes = None
+    if head_dim % 128:
+        reason = f"head_dim {head_dim} is no multiple of 128"
+    elif rotary_dim not in (None, head_dim):
+        reason = f"rotary_dim {rotary_dim} < head_dim {head_dim}"
+    elif interleaved:
+        reason = "interleaved pairs"
+    elif decode:
+        reason = "decode: the cache keeps (B, S, KV, D)"
+    elif not on_tpu():
+        reason = "no TPU"
+    else:
+        reason = qk_rows.supported(q.shape[1], q.shape[2], k.shape[2],
+                                   q.dtype, norm)
+        if reason is None:
+            verdict, axes = kernel_mesh_plan(q.shape[0])
+            if verdict is None:
+                reason = "kernel_mesh_plan refused the mesh"
+    if reason is not None:
+        note_dispatch("qk_rows", "xla", reason)
+        return None
+    note_dispatch("qk_rows", "pallas",
+                  f"head_dim {head_dim}, rows {q.shape[2]} + {k.shape[2]}; "
+                  + ("one device" if verdict == "direct"
+                     else f"shard_map over batch axes {axes}"))
+    return verdict, axes
+
+
+def row_table(positions: jax.Array, head_dim: int, theta: float = 10000.0,
+              table: Optional[RotaryTable] = None) -> jax.Array:
+    """``cos || sin``, (B, S, head_dim) float32: a position's angles as the
+    row kernels read them (a ``table``'s factor is in both halves)."""
+    return jnp.concatenate(rotary_angles(positions, head_dim, theta, table),
+                           axis=-1)
+
+
+def rotate_rows(q: jax.Array, k: jax.Array, positions: Optional[jax.Array],
+                head_dim: int, plan: tuple, *, theta: float = 10000.0,
+                table: Optional[RotaryTable] = None,
+                q_scale: Optional[jax.Array] = None,
+                k_scale: Optional[jax.Array] = None, eps: float = 0.0,
+                interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """q (B, S, H*D) and k (B, S, KV*D) as rows, under a ``plan`` of
+    :func:`rows_plan`: each head normalised under ``q_scale`` / ``k_scale``
+    (D,) where given (``models/common.py rms_norm`` over the head), then
+    turned half-split by ``positions`` (B, S), or (1, S) for every row,
+    where given.  Float32 arithmetic, rounded once."""
+    from .pallas.qk_rows import qk_rows
+
+    angles = None if positions is None \
+        else row_table(positions, head_dim, theta, table)
+    verdict, axes = plan
+    if verdict == "direct":
+        return qk_rows(q, k, angles, q_scale, k_scale, head_dim, eps,
+                       interpret)
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    rows = P(axes if axes else None, None, None)
+    args = (q, k, angles, q_scale, k_scale)
+    # (1, S) positions serve every row: their table goes to every rank
+    specs = tuple(None if a is None else rows
+                  if a.ndim == 3 and a.shape[0] == q.shape[0] else P()
+                  for a in args)
+    return jax.shard_map(
+        lambda *a: qk_rows(*a, head_dim, eps, interpret), mesh=get_mesh(),
+        in_specs=specs, out_specs=(rows, rows), check_vma=False)(*args)

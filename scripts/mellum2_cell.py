@@ -37,9 +37,10 @@ def build(seed: int, rehearse: bool = False, edit=None, init_scale=None,
                                 sized=lambda sec: {k: v for k, v in sec.items()
                                                    if k != "rehearse"})
     driver = cell.driver()
-    engine, cfg, _ = driver.train_lm.build(ctx)
+    train_lm = getattr(driver, "train_lm", driver)      # OLMoE's is it
+    engine, cfg, _ = train_lm.build(ctx)
     engine.init_params()
-    driver.train_lm.scale_init(
+    train_lm.scale_init(
         engine, conf.get("init_scale", {}) if init_scale is None
         else {"embed_tokens": init_scale})
     batches = loadgen.packed_batches(sized(cell.traffic), seed,

@@ -425,3 +425,76 @@ def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window):
         if op != "custom-call" and f"{H * D}]" in shape.split("{")[0]:
             assert not {"k.1", "v.1"} & set(operands), (name, shape)
     assert f"[{B},{S},{KV},{H // KV},{D}]" not in text
+
+
+@pytest.mark.parametrize("family,rows,seq,heads,kv,fields", [
+    ("olmoe", 2, 4096, 16, 16, dict(qk_norm=True)),
+    ("mellum2", 4, 8192, 32, 4, dict(
+        layer_types=("sliding_attention",), sliding_window=1024,
+        rope_parameters={"rope_type": "yarn", "rope_theta": 500000.0,
+                         "factor": 16.0,
+                         "original_max_position_embeddings": 8192})),
+    ("trinity", 3, 8192, 32, 4, dict(
+        layer_types=("sliding_attention",), sliding_window=2048,
+        qk_norm="head", attn_gate=True)),
+])
+def test_q_and_k_stay_rows_from_projection_to_flash(topo, one_chip,
+                                                    monkeypatch, family, rows,
+                                                    seq, heads, kv, fields):
+    """Loss and gradient of one remat block at a cell's attention shape
+    (its FFN cut to 1024), compiled for one described chip (PR 34): the
+    rotation (and Trinity's per-head norm) is the ``qk_rows`` custom call,
+    forward, the remat's forward and ``qk_rows_back``, between the
+    projection and the flash call; the optimized HLO holds no copy and no
+    64-lane half of anything ``(rows, seq, heads, 128)``-shaped (before:
+    a reshape copy each way and four half slices a pass), and the flash
+    calls keep their names."""
+    import re
+
+    import flax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from deepspeed_tpu.comm import mesh as mesh_mod
+    from deepspeed_tpu.models.llama import LlamaBlock, LlamaConfig
+    from deepspeed_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(mesh_mod, "_CURRENT_MESH", Mesh(
+        np.asarray(topo.devices[:1]).reshape((1,) * len(mesh_mod.MESH_AXES)),
+        mesh_mod.MESH_AXES))
+    cfg = LlamaConfig(vocab_size=1024, hidden_size=2048,
+                      intermediate_size=1024, num_hidden_layers=1,
+                      num_attention_heads=heads, num_key_value_heads=kv,
+                      head_dim=128, max_position_embeddings=seq, **fields)
+    kind = (cfg.layer_types or (None,))[0]
+    block = LlamaBlock(cfg, kind=kind)
+    x = jax.ShapeDtypeStruct((rows, seq, cfg.hidden_size), cfg.dtype,
+                             sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            block.init, jax.random.PRNGKey(0), x, (pos, None))["params"]))
+
+    @jax.checkpoint
+    def layer(p, x, pos):
+        return block.apply({"params": p}, x, (pos, None))[0]
+
+    def loss(p, x, pos):
+        return (layer(p, x, pos).astype(jnp.float32) ** 2).mean()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x, pos).compile().as_text()
+    ops = _entry(text)
+    calls = sorted(re.sub(r"\.\d+$", "", n) for n, v in ops.items()
+                   if v[1] == "custom-call")
+    scope = "self_attn_window" if kind else "self_attn"
+    assert [c for c in calls if c.startswith("qk_rows")] == \
+        ["qk_rows", "qk_rows", "qk_rows_back"], calls
+    assert len([c for c in calls if c.startswith(scope)]) == 3, calls
+    four_d = re.compile(rf"\[{rows},{seq},({heads}|{kv}),(128|64)\]")
+    assert not [(n, v[0]) for n, v in ops.items()
+                if v[1] in ("copy", "slice", "concatenate")
+                and four_d.search(v[0])]
+    assert not re.search(rf"bf16\[{rows},{seq},({heads}|{kv}),64\]", text)
