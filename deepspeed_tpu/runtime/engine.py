@@ -26,7 +26,7 @@ import dataclasses
 import functools
 import os
 import re
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Sequence
 
 import flax
 import flax.linen as nn
@@ -930,35 +930,77 @@ class Engine:
             "params_initialized": self._state is not None,
         }
 
-    def record_memory_profile(self, batch=None) -> Optional[dict]:
-        """AOT-compile the train step against ABSTRACT args and publish
-        its per-device HBM breakdown as ``hbm_exec_*_bytes{site=
-        "engine.train_step"}`` gauges (telemetry/memory.py).
+    # the watched steps by the cached attribute that holds each
+    _STEP_SITES = {"engine.train_step": "_compiled_train_step",
+                   "engine.eval_step": "_compiled_eval_step",
+                   "engine.grads_only": "_compiled_grads_only",
+                   "engine.grad_step": "_compiled_grad_step",
+                   "engine.apply_step": "_compiled_apply_step"}
 
-        Uses the autotuner's abstract-lowering path, so no state is
-        materialized or donated; costs one compile — call it once after
-        init (or from the flops profiler), not per step.  Returns the
-        breakdown dict (None when the backend exposes no analysis)."""
+    def compiled_step(self, site: str = "engine.train_step"):
+        """The ``jax.stages.Compiled`` that ``site``'s last call ran
+        (``memory_analysis()``, ``cost_analysis()``, ``as_text()``), or
+        None before its first call.  Sites: the five of ``_STEP_SITES``
+        and ``engine.multi_step[<steps>]``; the recompile watchdog
+        (``telemetry/recompile.py`` ``staged``) keeps the handle where it
+        sees the compile, so asking costs nothing."""
+        attr = self._STEP_SITES.get(site)
+        if attr is not None:
+            watched = self.__dict__.get(attr)
+        else:
+            watched = next(
+                (w for w in self.__dict__.get("_multi_step_cache",
+                                              {}).values()
+                 if w._name == site), None)
+        return getattr(watched, "compiled", None)
+
+    def record_memory_profile(self) -> Optional[dict]:
+        """The per-device HBM breakdown of the train step that runs
+        (``telemetry/memory.py memory_breakdown``: ``reserved`` = args +
+        output − alias + temp is what it holds on the device), or None
+        before the first ``train_batch`` and where the backend exposes no
+        analysis.  The step's first call published the same numbers as
+        ``hbm_exec_*_bytes{site="engine.train_step"}``; nothing is
+        lowered or compiled here."""
         from ..telemetry import memory as telemetry_memory
 
-        if batch is None:
-            if not hasattr(self.model, "dummy_inputs"):
-                raise ValueError(
-                    "record_memory_profile needs an example batch: the "
-                    "model exposes no dummy_inputs(batch_size=...)")
-            batch = self.model.dummy_inputs(batch_size=self.train_batch_size)
-        abstract = self.abstract_state(batch)
-        a_batch = jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), batch)
-        extra = ()
-        if self.progressive_layer_drop is not None:
-            # the step body takes theta positionally (same scalar kind
-            # train_batch passes); lowering without it would IndexError
-            extra = (jnp.float32(self.progressive_layer_drop.get_theta()),)
-        compiled = self._compiled_train_step.lower(
-            abstract, a_batch, *extra).compile()
-        return telemetry_memory.record_compiled(compiled,
-                                                site="engine.train_step")
+        compiled = self.compiled_step()
+        return None if compiled is None \
+            else telemetry_memory.memory_breakdown(compiled)
+
+    def profile_device_scopes(self, data_iter, steps: int = 6, depth: int = 3,
+                              top: Sequence[str] = ()) -> dict:
+        """Device time of ``steps`` train steps by the program's own
+        scopes (``telemetry/device_scopes.py``): a short ``jax.profiler``
+        session of its own around ``train_batch(data_iter=...)``, every
+        device instruction named through the optimized HLO of the
+        executable that ran.  Returns ``scope_table``'s dict (device ms a
+        step by scope, ``depth`` names deep, and pass; what has no
+        ``op_name`` by kind); ``top`` names scopes whose ten heaviest
+        instructions are listed too.  An operator's call on a warm
+        engine: it trains ``steps`` steps, reads the first device that
+        ran anything, and the first call parses the step's HLO text."""
+        from ..telemetry import device_scopes
+
+        if self.compiled_step() is None:
+            raise RuntimeError(
+                "profile_device_scopes reads the executable of the train "
+                "step that runs: call train_batch once first")
+
+        def run():
+            for _ in range(steps):
+                loss = self.train_batch(data_iter=data_iter)
+            jax.block_until_ready(loss)
+
+        by_device = device_scopes.capture(run)
+        if not by_device:
+            raise RuntimeError(
+                "the profiler's trace holds no /device:TPU plane: device "
+                "time by scope is read on the chip")
+        return device_scopes.scope_table(
+            by_device[min(by_device)],
+            device_scopes.instruction_scopes(self.compiled_step()),
+            steps, depth=depth, top=top)
 
     # ------------------------------------------------------------------
     # compiled pieces
@@ -1272,7 +1314,8 @@ class Engine:
         return recompile.watch(
             jax.jit(self._train_step_body, donate_argnums=(0,),
                     out_shardings=(self._state_shardings, None)),
-            name="engine.train_step", warn=self._hot_loop_shapes_static)
+            name="engine.train_step", warn=self._hot_loop_shapes_static,
+            staged=True)
 
     def _compiled_multi_step(self, steps: int, stacked: bool):
         """``steps`` optimizer steps as ONE compiled scan — one host
@@ -1310,7 +1353,7 @@ class Engine:
                 jax.jit(multi, donate_argnums=(0,),
                         out_shardings=(self._state_shardings, None)),
                 name=f"engine.multi_step[{steps}]",
-                warn=self._hot_loop_shapes_static)
+                warn=self._hot_loop_shapes_static, staged=True)
         return cache[key]
 
     def train_batches(self, batch, steps: int, stacked: Optional[bool] = None):
@@ -1527,7 +1570,8 @@ class Engine:
             # whole grad tree just to decide the clip factor
             return loss / gas, g, optax.global_norm(g)
 
-        return recompile.watch(jax.jit(grads_fn), name="engine.grads_only")
+        return recompile.watch(jax.jit(grads_fn), name="engine.grads_only",
+                               staged=True)
 
     def _host_offload_train_batch(self, batch):
         """ZeRO-Offload step (reference ``stage_1_and_2.py`` cpu_offload):
@@ -1677,7 +1721,7 @@ class Engine:
         # eval batch shapes legitimately vary with the caller → no warning,
         # but the compile population still lands in the registry
         return recompile.watch(jax.jit(eval_fn), name="engine.eval_step",
-                               warn=False)
+                               warn=False, staged=True)
 
     @functools.cached_property
     def _compiled_grad_step(self):
@@ -1701,7 +1745,8 @@ class Engine:
             grads = self._scatter_grads(grads)
             return loss / scale, grads
 
-        return recompile.watch(jax.jit(grad_fn), name="engine.grad_step")
+        return recompile.watch(jax.jit(grad_fn), name="engine.grad_step",
+                               staged=True)
 
     @functools.cached_property
     def _compiled_apply_step(self):
@@ -1713,7 +1758,7 @@ class Engine:
         return recompile.watch(
             jax.jit(apply_fn, donate_argnums=(0, 1),
                     out_shardings=(self._state_shardings, None)),
-            name="engine.apply_step")
+            name="engine.apply_step", staged=True)
 
     # ------------------------------------------------------------------
     # public API

@@ -29,7 +29,12 @@ Seven coordinated surfaces replacing the reference's scattered
   (``xla_compile_seconds_total{phase,span}``) and executables
   (``xla_executables_total{how,span}``) by innermost open span, for
   every executable the process makes, plus a ``compile/backend`` record
-  in the tracer's ring under the span that compiled.
+  in the tracer's ring under the span that compiled.  A ``staged``
+  site (the training engine's steps) keeps the executable it runs and
+  books its memory when it is made.
+- :mod:`.device_scopes` — device time by the program's own scopes: the
+  instruction → ``op_name`` map of a kept executable and the reduction
+  of a profiler trace over it (``engine.profile_device_scopes``).
 - :mod:`.exporter` — per-rank HTTP server (``/metrics`` Prometheus
   text, ``/healthz`` liveness JSON, ``/statusz`` operational JSON,
   ``/alertz``, ``/tracez``);

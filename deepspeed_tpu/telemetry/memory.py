@@ -13,10 +13,14 @@ gauges:
   program, so the numbers compare against one chip's HBM directly —
   no further division (see ``autotuner.py`` trial-fit logic).
 - :func:`record_compiled` — publish a breakdown as
-  ``hbm_exec_{args,output,temp,generated_code,total}_bytes{site=...}``
-  gauges; wired at the AOT compile points (engine
-  ``record_memory_profile``, serving ``warmup_windows`` /
-  ``_warmup_admission``) where a Compiled object exists anyway.
+  ``hbm_exec_{args,output,alias,temp,generated_code,reserved,total}_bytes
+  {site=...}`` gauges (and ``hbm_exec_peak_bytes`` where the backend
+  reports a peak).  Wired where an executable is made: the recompile
+  watchdog books every executable a ``staged`` site makes
+  (``telemetry/recompile.py``: the training engine's steps, so the step
+  that runs is booked on its first call and ``engine.
+  record_memory_profile()`` only reads it back), and serving's
+  ``warmup_windows`` / ``_warmup_admission`` book their AOT handles.
 - :func:`sample_live_hbm` — ``live_hbm_bytes`` (max per-device bytes
   pinned by live ``jax.Array``\\ s) + allocator stats where the backend
   exposes them; registered as a scrape-time collector so ``/metrics``
@@ -35,20 +39,27 @@ __all__ = ["memory_breakdown", "peak_bytes", "record_compiled",
 _FIELDS = (
     ("args", "argument_size_in_bytes"),
     ("output", "output_size_in_bytes"),
+    ("alias", "alias_size_in_bytes"),
     ("temp", "temp_size_in_bytes"),
     ("generated_code", "generated_code_size_in_bytes"),
+    ("peak", "peak_memory_in_bytes"),
 )
 
 
 def memory_breakdown(compiled) -> Optional[dict]:
     """Normalized per-device byte breakdown of a compiled executable.
 
-    Returns ``{"args": .., "output": .., "temp": .., "generated_code":
-    .., "total": ..}`` (floats, bytes) or None when the backend exposes
-    no analysis.  ``total`` = args + output + temp — the data working
-    set the program reserves in device memory, matching the fit checks
-    the autotuner and flops profiler already apply (generated code
-    lives in its own arena and is reported separately).
+    Returns ``{"args": .., "output": .., "alias": .., "temp": ..,
+    "generated_code": .., "reserved": .., "total": ..}`` (floats, bytes;
+    ``"peak"`` too where the backend reports one that is not 0) or None
+    when the backend exposes no analysis.  ``reserved`` = args + output −
+    alias + temp is what the program holds on the device while it runs:
+    an output that takes a donated argument's buffer (``alias``) is one
+    buffer, not two.  ``total`` = args + output + temp is the same for a
+    program that donates nothing, which is what the autotuner's and the
+    flops profiler's fit checks lower; on a step that donates its state
+    it counts the state twice (generated code lives in its own arena and
+    is reported separately).
     """
     try:
         ma = compiled.memory_analysis()
@@ -59,7 +70,10 @@ def memory_breakdown(compiled) -> Optional[dict]:
     if ma is None:
         return None
     out = {key: float(getattr(ma, attr, 0) or 0) for key, attr in _FIELDS}
+    if not out["peak"]:
+        del out["peak"]
     out["total"] = out["args"] + out["output"] + out["temp"]
+    out["reserved"] = out["total"] - out["alias"]
     return out
 
 

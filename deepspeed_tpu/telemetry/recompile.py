@@ -28,6 +28,17 @@ rules:
   compiled nothing, warm-up churn before that (eager-built buffers
   being replaced by committed jit outputs).
 
+``watch(..., staged=True)`` (the training engine's steps) also KEEPS what
+it observes: such a site makes its executable itself, through
+``fn.trace(*args).lower().compile()`` (one trace, one lowering and one
+build-or-fetch a signature, under the persistent-cache key a plain call
+has), calls the kept ``Compiled`` from then on, and books its memory
+once (:func:`memory.record_compiled`).  Only a call the kept executable
+refuses (its own argument check: ``TypeError`` / ``ValueError`` before
+anything is donated) is signed; it then goes to an executable the site
+made earlier for that signature, or makes a new one under the rules
+above.  ``handle.compiled`` is the executable that runs.
+
 Sites whose signatures legitimately vary (chunked prefill compiles one
 executable per power-of-two chunk BY DESIGN) pass ``warn=False``: their
 compile population lands in ``xla_compiled_signatures_total`` only, so
@@ -53,6 +64,7 @@ from typing import Any, Optional
 
 from ..utils.logging import logger
 from . import goodput as _goodput
+from . import memory as _memory
 from . import registry as _registry
 from . import trace as _trace
 
@@ -156,6 +168,67 @@ class _Watched:
         return getattr(self._fn, attr)
 
 
+class _Staged(_Watched):
+    """A watched ``jax.jit`` that keeps the executable it runs.  The
+    steady call is the kept ``Compiled`` and nothing else: no flatten, no
+    hash, no signature (its C++ fast path checks the arguments as
+    ``jax.jit``'s does)."""
+
+    __slots__ = ("compiled", "_made")
+
+    def __init__(self, fn, name: str, warn: bool, dog: "RecompileWatchdog"):
+        super().__init__(fn, name, warn, dog)
+        self.compiled = None           # the executable the last call ran
+        self._made = {}                # signature -> executables made for it
+
+    def __call__(self, *args, **kwargs):
+        compiled = self.compiled
+        if compiled is not None:
+            try:
+                out = compiled(*args, **kwargs)
+            except (TypeError, ValueError):
+                # the argument check refused: another signature, sharding
+                # or layout, or tracers; nothing was donated
+                pass
+            else:
+                self._settled = True
+                return out
+        return self._refused(args, kwargs)
+
+    def _refused(self, args, kwargs):
+        import jax
+
+        if any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves((args, kwargs))):
+            # under someone's trace (the flops profiler lowers a function
+            # that calls the step): the jit inlines itself
+            return self._fn(*args, **kwargs)
+        sig = _tree_sig((args, kwargs))
+        for compiled in self._made.get(sig, ()):
+            if compiled is self.compiled:
+                continue
+            try:
+                out = compiled(*args, **kwargs)
+            except (TypeError, ValueError):
+                continue
+            self.compiled = compiled
+            self._settled = True
+            return out
+        tls = _compile_tls
+        before = tls.unclaimed
+        t0 = time.perf_counter()
+        compiled = self._fn.trace(*args, **kwargs).lower().compile()
+        out = compiled(*args, **kwargs)
+        self._made.setdefault(sig, []).append(compiled)
+        self.compiled = compiled
+        tls.unclaimed = before         # as _Watched: the executable is ours
+        _goodput.note_compile(time.perf_counter() - t0)
+        self._dog._on_compile(self, args, kwargs, sig)
+        _memory.record_compiled(compiled, site=self._name,
+                                registry=self._dog._registry)
+        return out
+
+
 class RecompileWatchdog:
     def __init__(self, registry: Optional[_registry.Registry] = None,
                  warn_interval_s: float = _WARN_INTERVAL_S):
@@ -179,16 +252,19 @@ class RecompileWatchdog:
             "all distinct jit signatures per watched site (warm-up "
             "included)", labelnames=("site",))
 
-    def watch(self, fn, name: str, warn: bool = True):
+    def watch(self, fn, name: str, warn: bool = True, staged: bool = False):
         """Wrap ``fn``.  ``warn=False`` counts signatures without
-        warning (for sites whose shapes vary by design)."""
-        return _Watched(fn, name, warn, self)
+        warning (for sites whose shapes vary by design).  ``staged``:
+        ``fn`` is a ``jax.jit`` with no static arguments, and the site
+        keeps the executable it runs (:class:`_Staged`)."""
+        return (_Staged if staged else _Watched)(fn, name, warn, self)
 
-    def _on_compile(self, watched: _Watched, args, kwargs):
+    def _on_compile(self, watched: _Watched, args, kwargs, sig=None):
         """``watched``'s call just made an executable: sign what it was
-        called with (donated leaves keep shape, dtype and weak type) and
-        say which kind of compile it was."""
-        sig = _tree_sig((args, kwargs))
+        called with (donated leaves keep shape, dtype and weak type),
+        unless the caller has, and say which kind of compile it was."""
+        if sig is None:
+            sig = _tree_sig((args, kwargs))
         site = watched._name
         if sig not in watched._sigs:
             self._compiles.labels(site=site).inc()
@@ -243,9 +319,9 @@ def _get_default() -> RecompileWatchdog:
     return _default_watchdog
 
 
-def watch(fn, name: str, warn: bool = True):
+def watch(fn, name: str, warn: bool = True, staged: bool = False):
     """Module-level convenience over the default watchdog."""
-    return _get_default().watch(fn, name, warn=warn)
+    return _get_default().watch(fn, name, warn=warn, staged=staged)
 
 
 def total_recompiles() -> float:

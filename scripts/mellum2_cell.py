@@ -40,9 +40,10 @@ def build(seed: int, rehearse: bool = False, edit=None, init_scale=None,
     train_lm = getattr(driver, "train_lm", driver)      # OLMoE's is it
     engine, cfg, _ = train_lm.build(ctx)
     engine.init_params()
-    train_lm.scale_init(
-        engine, conf.get("init_scale", {}) if init_scale is None
-        else {"embed_tokens": init_scale})
+    factors = conf.get("init_scale", {}) if init_scale is None \
+        else {"embed_tokens": init_scale}
+    if factors:                     # the XL cell's driver has no such step
+        train_lm.scale_init(engine, factors)
     batches = loadgen.packed_batches(sized(cell.traffic), seed,
                                      engine.train_batch_size, cfg.vocab_size)
     return cell, driver, engine, cfg, conf, batches

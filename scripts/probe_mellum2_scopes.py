@@ -1,101 +1,26 @@
 #!/usr/bin/env python3
-"""Device time of ``train-mellum2-8k-1chip``'s step by the program's own
-scopes.  The v5e's trace names a device event by its HLO instruction and
-carries no ``op_name``, and the benchmark's reduction adds instructions up
-by kind (``fusion`` is half of this step): this probe traces a few steps,
-keeps every instruction's own name, and maps it to the ``op_name`` of the
-same executable's optimized HLO (``jit(step_fn)/.../layers_1/moe/combine/
-...``), then adds up by scope and by pass (forward, the backward's
-recomputation, backward).
+"""Device time of a cell's step by the program's own scopes: the cell's
+trainer as the benchmark builds it, three warm steps, then
+``engine.profile_device_scopes`` (``deepspeed_tpu/telemetry/
+device_scopes.py``), which traces a few steps and names every device
+instruction through the optimized HLO of the executable that ran.  Nothing
+is lowered or compiled for the reading.
 
     chiprun -- python3 scripts/probe_mellum2_scopes.py [--steps 6]
-        [--cell train-trinity-mini-8k-1chip]
+        [--cell train-trinity-mini-8k-1chip] [--depth 3] [--top moe/route]
 
-The first line also gives the ``copy`` instructions' own total, the
-ledger's ``breakdown`` ``copy``.
+One JSON line each: the step's booked memory, the seconds reading it took
+and whether its executable was built or fetched; device ms a step, the
+seconds ``as_text()`` and its parse took and the executables the two
+readings made (0); (scope, pass) rows; what has no
+``op_name`` by kind; each ``--top`` scope by op and its ten heaviest
+instructions.
 """
 import argparse
-import collections
 import json
-import os
-import re
-import shutil
+import time
 
-from mellum2_cell import ROOT, build
-
-SCOPES = ("moe/combine", "moe/dispatch", "moe/route", "moe/experts",
-          "self_attn_window", "self_attn_full", "rope", "attn/qk_norm",
-          "attn/gate", "loss_head",
-          "self_attn", "post_attention_norm", "input_norm", "moe", "norm",
-          "embed")
-
-
-def scope_of(op_name: str) -> str:
-    if "/layers_" not in op_name and "loss_head" not in op_name \
-            and "LlamaForCausalLM" not in op_name:
-        return "optimizer and the rest of the step"
-    return next((s for s in SCOPES if s in op_name), "model, other")
-
-
-def pass_of(op_name: str) -> str:
-    if "transpose(" not in op_name:
-        return "forward"
-    return "recompute" if "checkpoint" in op_name.split("transpose(", 1)[1] \
-        and "/jvp(" in op_name.split("transpose(", 1)[1] else "backward"
-
-
-def measure(engine, batches, steps: int) -> list:
-    """Trace ``steps`` steps of a warm ``engine``; the lines to print:
-    device ms a step by scope and pass, by instruction kind for what has
-    no ``op_name``, and the ``copy`` instructions' own total."""
-    import jax
-    import numpy as np
-
-    from benchmark import trace_reduce
-
-    batch = next(batches)
-    a_batch = jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype), batch)
-    text = engine._compiled_train_step.lower(
-        engine.abstract_state(batch), a_batch).compile().as_text()
-    op_names = dict(re.findall(
-        r"%?([\w.\-]+) = [^\n]*?metadata=\{op_name=\"([^\"]*)\"", text))
-
-    out = os.path.join(ROOT, ".bench_out", "probe_scopes")
-    shutil.rmtree(out, ignore_errors=True)
-    jax.profiler.start_trace(out)
-    for _ in range(steps):
-        loss = engine.train_batch(data_iter=batches)
-    jax.block_until_ready(loss)
-    jax.profiler.stop_trace()
-    named = trace_reduce._op_name
-    trace_reduce._op_name = lambda e: e.name.split(" = ")[0].lstrip("%")
-    try:
-        dev_ops, _, _ = trace_reduce.read_xplane(trace_reduce.find_xplane(out))
-    finally:
-        trace_reduce._op_name = named
-    events = next(iter(dev_ops.values()))
-    by = collections.Counter()
-    unnamed = collections.Counter()
-    kinds = collections.Counter()
-    for name, _, dur in trace_reduce.self_times(events):
-        kinds[re.sub(r"[.\-_]\d+$", "", name)] += dur
-        op = op_names.get(name)
-        if op is None:
-            unnamed[re.sub(r"[.\-_]\d+$", "", name)] += dur
-            continue
-        by[scope_of(op), pass_of(op)] += dur
-    ms = lambda ns: round(ns / steps / 1e6, 2)
-    total = sum(by.values()) + sum(unnamed.values())
-    lines = [{"steps": steps, "device_ms_a_step": ms(total),
-              "copy_ms_a_step": ms(kinds["copy"])}]
-    lines += [{"scope": scope, "pass": pass_, "ms_a_step": ms(ns)}
-              for (scope, pass_), ns in sorted(by.items(),
-                                               key=lambda kv: -kv[1])]
-    lines += [{"no_op_name": name, "ms_a_step": ms(ns)}
-              for name, ns in unnamed.most_common(8)]
-    shutil.rmtree(out, ignore_errors=True)
-    return lines
+from mellum2_cell import build
 
 
 def main():
@@ -103,15 +28,58 @@ def main():
     ap.add_argument("--steps", type=int, default=6)
     ap.add_argument("--seed", type=int, default=3000000029)
     ap.add_argument("--cell", default="train-mellum2-8k-1chip")
+    ap.add_argument("--depth", type=int, default=3)
+    ap.add_argument("--top", action="append", default=[])
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU sizes: runs up to the trace, which needs the chip")
     args = ap.parse_args()
 
     import jax
 
-    _, _, engine, _, _, batches = build(args.seed, cell=args.cell)
+    from deepspeed_tpu.telemetry import device_scopes, get_registry
+
+    _, _, engine, _, _, batches = build(args.seed, rehearse=args.rehearse,
+                                        cell=args.cell)
     for _ in range(3):
         jax.block_until_ready(engine.train_batch(data_iter=batches))
-    for line in measure(engine, batches, args.steps):
-        print(json.dumps(line))
+    def executables(span=None):
+        return {how: sum(s["value"] for s in get_registry().snapshot()
+                         ["xla_executables_total"]["samples"]
+                         if s["labels"]["how"] == how
+                         and span in (None, s["labels"]["span"]))
+                for how in ("built", "fetched")}
+
+    def timed(fn):
+        t0 = time.perf_counter()
+        out = fn()
+        return out, round(time.perf_counter() - t0, 3)
+
+    before = executables()
+    memory, memory_s = timed(engine.record_memory_profile)
+    print(json.dumps({"cell": args.cell, "memory": memory,
+                      "memory_analysis_s": memory_s,
+                      "step_executables": executables("train/dispatch")}))
+    # the one parse of the step's text, which the table would make itself
+    _, parse_s = timed(lambda: device_scopes.instruction_scopes(
+        engine.compiled_step()))
+    table = engine.profile_device_scopes(batches, steps=args.steps,
+                                         depth=args.depth, top=args.top)
+    after = executables()
+    print(json.dumps({
+        "steps": table["steps"], "device_ms_a_step": table["device_ms_a_step"],
+        "as_text_and_parse_s": parse_s,
+        "no_op_name_ms_a_step": sum(r["ms_a_step"]
+                                    for r in table["no_op_name"]),
+        "executables_made_by_the_two_readings":
+            sum(after.values()) - sum(before.values())}))
+    for row in table["scopes"]:
+        print(json.dumps(row))
+    for row in table["no_op_name"][:12]:
+        print(json.dumps({"no_op_name": row["kind"],
+                          "ms_a_step": row["ms_a_step"]}))
+    for scope, split in table["top"].items():
+        for row in split["ops"] + split["instructions"]:
+            print(json.dumps({"top": scope, **row}))
     # what this process's traces resolved each q / k site to (init, the
     # eval step and the train step: a layer is traced more than once)
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report

@@ -88,21 +88,40 @@ _PINNED_TO_THREE_CELLS = (
     "test_every_cell_loads_and_is_cut_only_as_the_guide_allows[manifest]",
     "test_every_cell_loads_and_is_cut_only_as_the_guide_allows[with_pending]",
 )
-_SUPERSEDED = {
-    "test_olmoe_cell.py": (
-        _PINNED_TO_THE_OLMOE_CELL,
-        "pins the manifest's tail to the OLMoE cell; superseded by "
-        "test_mellum2_cell.py (PR 30)"),
-    "test_mellum2_cell.py": (
-        _PINNED_TO_THREE_CELLS,
-        "pins the manifest's lists to three cells and the cuts to a set "
-        "without num_dense_layers; superseded by test_trinity_cell.py "
-        "(PR 33)"),
-}
+
+
+# Three more pin a cell's own list of per-layer metrics to its day: the XL
+# cell's ends with PR 24's six (``test_program_readers.py``), and a cell's
+# list is its predecessors' plus what it added (``test_mellum2_cell.py``,
+# ``test_trinity_cell.py``).  ``peak_hbm_gib`` and ``step_temp_hbm_gib``
+# (PR 35) are the first metrics appended to EVERY cell's list, so the three
+# fail by construction, and are expected to, strictly;
+# ``tests/benchmark/test_hbm_readers.py`` holds their versions over "what a
+# cell added, then what every cell gained since".  The same ROADMAP.md job
+# removes these marks with the others.
+_PINNED_TO_A_CELLS_OWN_TAIL = "pins a cell's list of per-layer metrics " \
+    "to its day; superseded by test_hbm_readers.py (PR 35)"
+_SUPERSEDED = [
+    ("test_olmoe_cell.py", _PINNED_TO_THE_OLMOE_CELL,
+     "pins the manifest's tail to the OLMoE cell; superseded by "
+     "test_mellum2_cell.py (PR 30)"),
+    ("test_mellum2_cell.py", _PINNED_TO_THREE_CELLS,
+     "pins the manifest's lists to three cells and the cuts to a set "
+     "without num_dense_layers; superseded by test_trinity_cell.py (PR 33)"),
+    ("test_program_readers.py",
+     ("test_the_manifest_names_the_six_and_only_appends",),
+     _PINNED_TO_A_CELLS_OWN_TAIL),
+    ("test_mellum2_cell.py",
+     ("test_no_metric_lost_a_cell_and_the_xl_cell_kept_its_own",),
+     _PINNED_TO_A_CELLS_OWN_TAIL),
+    ("test_trinity_cell.py",
+     ("test_no_metric_lost_a_cell_and_each_cell_kept_its_own",),
+     _PINNED_TO_A_CELLS_OWN_TAIL),
+]
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        names, reason = _SUPERSEDED.get(item.fspath.basename, ((), ""))
-        if item.name in names:
-            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))
+        for file, names, reason in _SUPERSEDED:
+            if item.fspath.basename == file and item.name in names:
+                item.add_marker(pytest.mark.xfail(reason=reason, strict=True))

@@ -177,8 +177,10 @@ def test_memory_breakdown_is_the_one_normalizer():
         jnp.zeros((64, 64), jnp.float32)).compile()
     bd = tmemory.memory_breakdown(compiled)
     assert bd is not None
-    assert set(bd) == {"args", "output", "temp", "generated_code", "total"}
+    assert set(bd) - {"peak"} == {"args", "output", "alias", "temp",
+                                  "generated_code", "total", "reserved"}
     assert bd["total"] == bd["args"] + bd["output"] + bd["temp"]
+    assert bd["reserved"] == bd["total"] - bd["alias"]
     assert bd["args"] >= 64 * 64 * 4
     assert tmemory.peak_bytes(compiled) == bd["total"]
 
