@@ -28,7 +28,8 @@ and the mesh, never from the environment):
   (softmax in float32, top-k, optional renormalisation, load-balancing and
   z losses) over all tokens, then ONE sorted dispatch
   (:func:`sorted_dispatch`): stable argsort of the (token, choice) pairs
-  by expert, group sizes by bincount, a row gather, the grouped matmuls
+  by expert, group sizes by a compare-and-sum over the expert axis
+  (:func:`_count_ids`: no scatter), a row gather, the grouped matmuls
   of ``ops/grouped_matmul.py`` over ragged groups, the inverse gather and
   the weighted sum over each token's choices.  Under data parallelism
   (dp / fsdp > 1) every rank sorts and multiplies its OWN tokens inside a
@@ -172,6 +173,21 @@ def _one_hot(x, n):
     return jax.nn.one_hot(x, n, dtype=jnp.float32)
 
 
+def _count_ids(ids: jax.Array, n: int) -> jax.Array:
+    """How many entries of the flat ``ids`` equal each of ``0..n-1``, int32
+    (n,).  An id outside that range (a share's "held elsewhere" marker
+    ``n``) is counted nowhere, as ``jnp.bincount(ids, length=n)`` has it.
+
+    A compare against every bin and a sum along the ids, which XLA fuses
+    into one pass over ``n * len(ids)`` lanes with no such array: the TPU
+    runs ``bincount``'s scatter-add one index at a time (2.29 ms for
+    262,144 ids into 64 bins on the v5e against 0.03, PERF.md section 6,
+    PR 36).  The scatter would win again from some thousands of bins,
+    which no configuration has."""
+    return (ids == jnp.arange(n, dtype=ids.dtype)[:, None]).sum(
+        1, dtype=jnp.int32)
+
+
 def top1_gating(logits: jax.Array, capacity: int, rng=None,
                 noise_policy: Optional[str] = None
                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -264,16 +280,20 @@ def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
     S, E = logits.shape
     probs = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
         else jax.nn.sigmoid(logits)
-    if bias is None:
-        weights, experts = jax.lax.top_k(probs, top_k)
-    else:
-        _, experts = jax.lax.top_k(probs + jax.lax.stop_gradient(bias), top_k)
-        weights = jnp.take_along_axis(probs, experts, axis=-1)
+    _, experts = jax.lax.top_k(
+        probs if bias is None else probs + jax.lax.stop_gradient(bias), top_k)
+    # each chosen expert's own score, as top_k's values or
+    # take_along_axis(probs, experts) give it bit for bit (one term of a
+    # sum is not zero), with no gather and, in the backward, no scatter-add
+    # into (S, E); the expert axis leads, so the sum runs over whole vector
+    # registers of tokens and not across the lanes
+    hit = experts.T[None] == jnp.arange(E)[:, None, None]        # (E, k, S)
+    weights = jnp.where(hit, probs.T[:, None], 0.0).sum(0).T      # (S, k)
     if norm_topk_prob:
         weights = weights / weights.sum(axis=-1, keepdims=True)
     if route_scale != 1.0:
         weights = weights * route_scale
-    counts = jnp.bincount(experts.reshape(-1), length=E).astype(jnp.int32)
+    counts = _count_ids(experts.reshape(-1), E)
     l_balance = E * jnp.sum(counts.astype(jnp.float32) / (S * top_k)
                             * probs.mean(axis=0))
     l_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
@@ -413,7 +433,7 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
                 flat = jnp.where((flat >= 0) & (flat < E), flat, E)
             order = jnp.argsort(flat, stable=True)
             inv = jnp.argsort(order)
-            sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+            sizes = _count_ids(flat, E)
             if share:
                 # past the groups a row holds no pair, and a pair held
                 # elsewhere has no row: the gathers read zeros there,
@@ -678,7 +698,7 @@ class MoELayer(nn.Module):
             # tokens.
             l_aux, idx, gate_w = gate(x2, train, decode_fast=True)
             out = experts(x2, idx=idx, gate_w=gate_w)
-            counts = jnp.bincount(idx.reshape(-1), length=E).astype(jnp.int32)
+            counts = _count_ids(idx.reshape(-1), E)
             dropped = jnp.int32(0)
         else:
             l_aux, combine, dispatch = gate(x2, train)
