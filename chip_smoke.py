@@ -604,6 +604,77 @@ def kernel_flash_window_gqa():
             assert worst.max() <= TOL, (name, n, worst)
 
 
+def kernel_flash_mla():
+    """The fifth cell's attention at the cell's own shape
+    (``train-joyai-flash-8k-1chip``: 2 rows of 8192 tokens, 32 heads of 128
+    nope + 64 rope channels, ONE rope key for all heads, values 128 wide):
+    the two-product flash kernels, forward and all five gradients, against
+    a float32 reference computed in query blocks.  Two rows, because the
+    sum of ``dk_rope`` over the 32 heads lives on the chip's write-back of
+    an output block whose index stays put until the last head's programs
+    and then moves on to the next row: one row never moves it, and
+    interpret mode stores every grid step.  Each row of ``dk_rope`` and
+    each (row, head) of ``dq_rope`` (two heads share a lane block) is also
+    checked on its own."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_mla
+
+    B, S, H, D, R, QB = 2, 8192, 32, 128, 64, 256
+    ks = jax.random.split(jax.random.PRNGKey(38), 6)
+    shapes = ((B, S, H, D), (B, S, H, R), (B, S, H, D), (B, S, 1, R),
+              (B, S, H, D), (B, S, H, D))
+    *ops, ct = (jax.random.normal(kk, s, jnp.float32).astype(jnp.bfloat16)
+                for kk, s in zip(ks, shapes))
+
+    def ref(qn, qr, kn, kr, v):
+        qn, qr, kn, kr, v = (t.astype(jnp.float32)
+                             for t in (qn, qr, kn, kr, v))
+
+        @jax.checkpoint
+        def block(args):
+            qn_blk, qr_blk, i0 = args                       # (B, QB, H, .)
+            s = (jnp.einsum("bqhd,bthd->bhqt", qn_blk, kn)
+                 + jnp.einsum("bqhr,btr->bhqt", qr_blk, kr[:, :, 0])
+                 ) * (D + R) ** -0.5
+            keep = (i0 + jnp.arange(QB))[:, None] >= jnp.arange(S)[None, :]
+            p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), -1)
+            return jnp.einsum("bhqt,bthd->bqhd", p, v)
+
+        def blocks(x):
+            return x.reshape(B, S // QB, QB, H, -1).transpose(1, 0, 2, 3, 4)
+
+        out = jax.lax.map(block, (blocks(qn), blocks(qr),
+                                  jnp.arange(0, S, QB)))
+        return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, D)
+
+    def loss(fn, *a):
+        return (fn(*a).astype(jnp.float32) * ct.astype(jnp.float32)).sum()
+
+    out = jax.jit(flash_attention_mla)(*ops)
+    grads = jax.jit(jax.grad(lambda *a: loss(flash_attention_mla, *a),
+                             argnums=range(5)))(*ops)
+    with jax.default_matmul_precision("highest"):
+        out_r = jax.jit(ref)(*ops)
+        grads_r = jax.jit(jax.grad(lambda *a: loss(ref, *a),
+                                   argnums=range(5)))(*ops)
+    name = f"flash mla ({B},{S},{H},{D}+{R})"
+    _check_close(f"{name} fwd", out, out_r)
+    for n, g, gr in zip(("dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"),
+                        grads, grads_r):
+        _check_close(f"{name} {n}", g, gr)
+        if n not in ("dq_rope", "dk_rope"):
+            continue
+        g, gr = (np.asarray(t, np.float32) for t in (g, gr))
+        worst = (np.abs(g - gr).max(axis=(1, 3))
+                 / np.abs(gr).max(axis=(1, 3)))             # (B, heads)
+        print(f"  {name} {n}: worst of {worst.size} (row, head) slices "
+              f"{worst.max():.2e}", flush=True)
+        assert worst.max() <= TOL, (name, n, worst)
+
+
 def kernel_qk_rows():
     """q and k as ``(B, S, H*D)`` rows through ``ops/pallas/qk_rows.py`` at
     the third and fourth cells' shapes (4 and 3 rows of 8192, 32 / 4 heads
@@ -677,7 +748,8 @@ def kernel_qk_rows():
                                                                  worst)
 
 
-KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_qk_rows,
+KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_flash_mla,
+                kernel_qk_rows,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

@@ -31,6 +31,21 @@ channels), ``attn_gate`` (the attention output times the sigmoid of a
 fourth projection, before ``o_proj``), ``rope_layer_types`` (the layer
 types that rotate q and k: the others carry no position at all) and
 ``sandwich_norm`` (a norm after each branch as well as before it).
+
+The DeepSeek-V3 family (JoyAI-LLM-Flash, 2026; ``model_type:
+joyai_llm_flash``) is it with two more modules.  **Latent attention**
+(``kv_lora_rank`` and its five sister fields, under their ``config.json``
+names): queries and keys-and-values are projected down to a latent,
+normalised and projected up again; each head has ``qk_nope_head_dim``
+channels of its own and ``qk_rope_head_dim`` rotated ones, the rotated KEY
+is one for all heads, and values are ``v_head_dim`` wide
+(:class:`LlamaLatentAttention`; the score is a sum of two products,
+``ops/attention.py``).  **Multi-token prediction**
+(``num_nextn_predict_layers``): after the stack, a projection of [the next
+token's embedding ; the stack's output], one more whole block and a norm
+predict the token two ahead through the model's own table and head; the
+loss is ``lm_loss + mtp_loss_weight * mtp_loss`` (:class:`MTPModule`,
+arXiv:2412.19437 section 2.2).
 """
 from __future__ import annotations
 
@@ -49,6 +64,9 @@ from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift
 
 
 SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
+# the widths latent attention takes together (their config.json names)
+_MLA_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+               "qk_rope_head_dim", "v_head_dim")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,6 +140,28 @@ class LlamaConfig:
     # norm`` then normalises the attention branch's OUTPUT and the FFN
     # reads ``pre_mlp_norm``, its output through ``post_mlp_norm``
     sandwich_norm: bool = False
+    # latent attention (DeepSeek-V2/V3's MLA), set by ``kv_lora_rank``:
+    # c_q = Norm(x W_qa) (q_lora_rank wide), [q_nope | q_rope] = c_q W_qb;
+    # [c_kv | k_rope] = x W_kva, c_kv = Norm(c_kv) (kv_lora_rank wide),
+    # [k_nope | v] = c_kv W_kvb; rotary on q_rope and on k_rope, ONE key
+    # of qk_rope_head_dim for all heads; scores over qk_nope_head_dim +
+    # qk_rope_head_dim channels, values v_head_dim wide.  ``head_dim`` and
+    # ``num_key_value_heads`` say nothing there
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    # rotary over channel pairs (2i, 2i+1) instead of (i, i + d/2);
+    # written for the latent attention's rope channels alone
+    rope_interleave: bool = False
+    # multi-token prediction: this many extra blocks after the stack (1 is
+    # written), each predicting one token further ahead through the
+    # model's own embedding table and head; their cross-entropy joins the
+    # loss under ``mtp_loss_weight`` (this program's name: the family's
+    # config has no key for it)
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     # > 0 with labels: chunked cross-entropy head, logits never materialize
     # (common.chunked_lm_loss); the output then carries no ``logits``
     loss_chunk: int = 0
@@ -167,6 +207,27 @@ class LlamaConfig:
                     f"{self.num_hidden_layers}")
             if SLIDING in self.kinds and not self.sliding_window:
                 raise ValueError(f"{SLIDING} layers need sliding_window")
+        if self.mla_fields and not all(getattr(self, f) for f in _MLA_WIDTHS):
+            raise ValueError(
+                f"latent attention takes {', '.join(_MLA_WIDTHS)} "
+                f"together; set: {', '.join(self.mla_fields)}")
+        if self.mla_fields and (self.qk_norm or self.attn_gate
+                                or self.per_layer_type):
+            raise NotImplementedError(
+                "latent attention with qk_norm, attn_gate, layer_types or "
+                "rope_parameters: none of them is written for it")
+        if self.num_nextn_predict_layers not in (0, 1):
+            raise NotImplementedError(
+                f"num_nextn_predict_layers "
+                f"{self.num_nextn_predict_layers}: one multi-token-"
+                f"prediction block is written")
+        if self.decode and (self.mla_fields
+                            or self.num_nextn_predict_layers):
+            raise NotImplementedError(
+                f"decode=True with "
+                f"{', '.join(self.mla_fields + self.mtp_fields)}: the cache "
+                f"holds keys and values a head, not a latent and a rope "
+                f"key, and no decode path drafts with a prediction block")
         if self.decode and self.per_layer_type:
             raise NotImplementedError(
                 "decode=True with a sliding window (layer_types) or a "
@@ -200,6 +261,17 @@ class LlamaConfig:
     @property
     def per_layer_type(self) -> bool:
         return bool(self.kinds) or self.rope_parameters is not None
+
+    @property
+    def mla_fields(self) -> tuple:
+        """The latent attention's fields that are set, by name."""
+        return tuple(f for f in _MLA_WIDTHS + ("rope_interleave",)
+                     if getattr(self, f))
+
+    @property
+    def mtp_fields(self) -> tuple:
+        return ("num_nextn_predict_layers",) \
+            if self.num_nextn_predict_layers else ()
 
     @property
     def afmoe_fields(self) -> tuple:
@@ -423,6 +495,67 @@ class LlamaAttention(nn.Module):
         return _dense(y, E, ("heads", "embed"), cfg=cfg, name="o_proj", module=self)
 
 
+class LlamaLatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    2.1; the leaves carry the released weights' names).  The up-projections'
+    columns are laid out for the kernels that read them: ``q_b_proj``'s are
+    all heads' nope channels, then all heads' rope channels (``H·nope |
+    H·rope``), ``kv_b_proj``'s all heads' keys, then all heads' values - a
+    fixed permutation of the released ``(H, nope + rope)`` and ``(H, nope +
+    v)`` orders.  So q_nope, k_nope and v leave their matmuls as the ``(B,
+    S, H·128)`` rows the two-product flash kernels read, one head a lane
+    block, and nothing is split, transposed or padded in between."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, position_ids, attn_mask):
+        from ..ops.rotary import rotate_rope_rows
+
+        cfg = self.cfg
+        B, S, E = x.shape
+        H = cfg.num_attention_heads
+        Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        init = nn.initializers.normal(cfg.initializer_range)
+
+        def weight(name, names, shape):
+            return self.param(name + "_kernel", nn.with_partitioning(
+                init, names), shape, cfg.param_dtype).astype(cfg.dtype)
+
+        with trace.device_span("attn/mla_q"):
+            c_q = RMSNorm(cfg, axis="latent", name="q_a_layernorm")(
+                jnp.dot(x, weight("q_a_proj", ("embed", "latent"),
+                                  (E, cfg.q_lora_rank))))
+            w_qb = weight("q_b_proj", ("latent", "qkv"),
+                          (cfg.q_lora_rank, H * (Dn + Dr)))
+            # two products of one leaf: each lands where its reader wants it
+            q_nope = jnp.dot(c_q, w_qb[:, :H * Dn])
+            q_rope = jnp.dot(c_q, w_qb[:, H * Dn:])
+        with trace.device_span("attn/mla_kv"):
+            kv_a = jnp.dot(x, weight("kv_a_proj_with_mqa", ("embed", "latent"),
+                                     (E, cfg.kv_lora_rank + Dr)))
+            c_kv = RMSNorm(cfg, axis="latent", name="kv_a_layernorm")(
+                kv_a[..., :cfg.kv_lora_rank])
+            k_rope = kv_a[..., cfg.kv_lora_rank:]          # (B, S, Dr)
+            w_kvb = weight("kv_b_proj", ("latent", "kv"),
+                           (cfg.kv_lora_rank, H * (Dn + Dv)))
+            k_nope = jnp.dot(c_kv, w_kvb[:, :H * Dn])
+            v = jnp.dot(c_kv, w_kvb[:, H * Dn:])
+        with trace.device_span("rope/mla"):
+            q_rope, k_rope = (rotate_rope_rows(
+                t, position_ids, Dr, theta=cfg.rope_theta,
+                interleaved=cfg.rope_interleave) for t in (q_rope, k_rope))
+        with trace.device_span("self_attn_mla"):
+            y = dot_product_attention(
+                q_nope.reshape(B, S, H, Dn), k_nope.reshape(B, S, H, Dn),
+                v.reshape(B, S, H, Dv), causal=True, mask=attn_mask,
+                scale=(Dn + Dr) ** -0.5, impl=cfg.attn_impl,
+                q_rope=q_rope.reshape(B, S, H, Dr),
+                k_rope=k_rope.reshape(B, S, 1, Dr))
+        return jnp.dot(y.reshape(B, S, H * Dv),
+                       weight("o_proj", ("heads", "embed"), (H * Dv, E)))
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
@@ -472,8 +605,11 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
-        attn = LlamaAttention(cfg, self.kind, name="self_attn")(
-            RMSNorm(cfg, name="input_norm")(x), position_ids, attn_mask)
+        self_attn = LlamaLatentAttention(cfg, name="self_attn") \
+            if cfg.kv_lora_rank \
+            else LlamaAttention(cfg, self.kind, name="self_attn")
+        attn = self_attn(RMSNorm(cfg, name="input_norm")(x), position_ids,
+                         attn_mask)
         if cfg.sandwich_norm:
             x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
             h = RMSNorm(cfg, name="pre_mlp_norm")(x)
@@ -497,6 +633,31 @@ class LlamaBlock(nn.Module):
         if cfg.sandwich_norm:
             ff = RMSNorm(cfg, name="post_mlp_norm")(ff)
         return x + ff, ys
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction depth (DeepSeek-V3, arXiv:2412.19437
+    section 2.2, eq. 21-23; leaves under the released weights' names):
+    ``x_i = [enorm(E[t_{i+1}]) ; hnorm(h_i)] eh_proj``, one whole block of
+    the model's sparse kind, ``shared_head_norm``.  The table that embeds
+    ``t_{i+1}`` and the head that reads the result are the CALLER's: the
+    main model's own leaves, whose gradient is the sum of both uses."""
+    cfg: LlamaConfig
+    deterministic: bool = True
+
+    @nn.compact
+    def __call__(self, h, next_embed, inputs):
+        cfg = self.cfg
+        with trace.device_span("mtp/embed_proj"):
+            x = jnp.concatenate(
+                [RMSNorm(cfg, name="enorm")(next_embed),
+                 RMSNorm(cfg, name="hnorm")(h)], axis=-1)
+            x = _dense(x, cfg.hidden_size, ("mlp", "embed"), cfg=cfg,
+                       name="eh_proj", module=self)
+        with trace.device_span("mtp/block"):
+            x, ys = LlamaBlock(cfg, self.deterministic, name="block")(
+                x, inputs)
+        return RMSNorm(cfg, name="shared_head_norm")(x), ys
 
 
 class LlamaForCausalLM(nn.Module):
@@ -557,6 +718,26 @@ class LlamaForCausalLM(nn.Module):
                     lambda *xs: jnp.stack(xs),
                     *per_layer[cfg.num_dense_layers:])
 
+        h_mtp = None
+        if cfg.num_nextn_predict_layers and labels is not None:
+            # the stack's output BEFORE the final norm, beside the next
+            # token's embedding (the last position has none: its label is
+            # ignored below, so what it embeds is never read)
+            mtp_cls = MTPModule
+            if cfg.remat:
+                mtp_cls = nn.remat(
+                    MTPModule, policy=resolve_remat_policy(cfg.remat_policy),
+                    prevent_cse=cfg.remat_prevent_cse)
+            next_ids = jnp.concatenate(
+                [input_ids[:, 1:], jnp.zeros_like(input_ids[:, :1])], axis=1)
+            h_mtp, ys = mtp_cls(cfg, deterministic, name="mtp_0")(
+                h, embed.astype(cfg.dtype)[next_ids], (position_ids, mask))
+            if cfg.moe is not None:     # the block's row follows the stack's
+                ys = jax.tree_util.tree_map(lambda x: x[None], ys)
+                per_layer = jax.tree_util.tree_map(
+                    lambda a, b: jnp.concatenate([a, b]), per_layer, ys) \
+                    if cfg.num_dense_layers < cfg.num_hidden_layers else ys
+
         out = ModelOutput()
         aux_loss = None
         if cfg.moe is not None:
@@ -583,15 +764,36 @@ class LlamaForCausalLM(nn.Module):
                     h, lm_head.T, tgt, vocab_size=cfg.vocab_size,
                     padded_vocab_size=cfg.padded_vocab_size,
                     chunk=cfg.loss_chunk, dtype=cfg.dtype)
+            if h_mtp is not None:
+                # the same head, on labels one further ahead: positions
+                # without a label two ahead are left out of the mean
+                with trace.device_span("mtp/loss_head"):
+                    mtp_loss = chunked_lm_loss(
+                        h_mtp, lm_head.T, shift_labels(tgt),
+                        vocab_size=cfg.vocab_size,
+                        padded_vocab_size=cfg.padded_vocab_size,
+                        chunk=cfg.loss_chunk, dtype=cfg.dtype)
         else:
-            with trace.device_span("loss_head"):
+            def head(h):
                 logits = jnp.dot(h, lm_head.astype(cfg.dtype))
                 if cfg.padded_vocab_size != cfg.vocab_size:
                     pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
                     logits = jnp.where(pad_mask, logits,
                                        jnp.finfo(logits.dtype).min)
-                out["logits"] = logits
+                return logits
+
+            with trace.device_span("loss_head"):
+                logits = out["logits"] = head(h)
                 loss = None if tgt is None else cross_entropy_loss(logits, tgt)
+            if h_mtp is not None and tgt is not None:
+                with trace.device_span("mtp/loss_head"):
+                    mtp_loss = cross_entropy_loss(head(h_mtp),
+                                                  shift_labels(tgt))
+        if h_mtp is not None and loss is not None:
+            out["lm_loss"], out["mtp_loss"] = loss, mtp_loss
+            out["stats"] = dict(out.get("stats") or {}, lm_loss=loss,
+                                mtp_loss=mtp_loss)
+            loss = loss + cfg.mtp_loss_weight * mtp_loss
         if loss is not None:
             out["loss"] = loss if aux_loss is None else loss + aux_loss
         return out
@@ -600,9 +802,21 @@ class LlamaForCausalLM(nn.Module):
     def record_step_stats(stats) -> None:
         """The engine hands back the host copy of ``out["stats"]`` of each
         finished step; the routing counters live with the MoE layer."""
-        from ..parallel.moe import record_stats
+        if "mtp_loss" in stats:
+            from ..telemetry import registry
 
-        record_stats(stats)
+            registry.gauge("lm_loss", "next-token cross-entropy of the main "
+                           "head, last finished step").set(
+                float(stats["lm_loss"]))
+            registry.gauge(
+                "mtp_loss", "cross-entropy of a multi-token-prediction "
+                "block (depth d predicts the token d + 1 ahead), last "
+                "finished step", ("depth",)).labels("1").set(
+                float(stats["mtp_loss"]))
+        if "tokens_per_expert" in stats:
+            from ..parallel.moe import record_stats
+
+            record_stats(stats)
 
     @staticmethod
     def is_state_leaf(path: tuple) -> bool:
@@ -621,16 +835,27 @@ class LlamaForCausalLM(nn.Module):
 
         cfg = self.cfg
         counts = stats["tokens_per_expert"]
+        rate = cfg.moe.bias_update_rate
         with trace.device_span("moe/bias_update"):
-            if "layers" in held:        # a scanned stack: leading layer axis
-                gate = held["layers"]["moe"]["gate"]
-                new = jax.vmap(lambda c, b: bias_update(
-                    c, b, cfg.moe.bias_update_rate))(counts, gate[STATE_LEAF])
-                return {"layers": {"moe": {"gate": {STATE_LEAF: new}}}}
-            return {name: {"moe": {"gate": {STATE_LEAF: bias_update(
-                counts[int(name.rsplit("_", 1)[1]) - cfg.num_dense_layers],
-                sub["moe"]["gate"][STATE_LEAF], cfg.moe.bias_update_rate)}}}
-                for name, sub in held.items()}
+            new = {}
+            for name, sub in held.items():
+                if name == "layers":    # a scanned stack: leading layer axis
+                    gate = sub["moe"]["gate"]
+                    n = gate[STATE_LEAF].shape[0]
+                    new[name] = {"moe": {"gate": {STATE_LEAF: jax.vmap(
+                        lambda c, b: bias_update(c, b, rate))(
+                            counts[:n], gate[STATE_LEAF])}}}
+                elif name.startswith("mtp_"):   # its row follows the stack's
+                    new[name] = {"block": {"moe": {"gate": {
+                        STATE_LEAF: bias_update(
+                            counts[-1],
+                            sub["block"]["moe"]["gate"][STATE_LEAF], rate)}}}}
+                else:
+                    new[name] = {"moe": {"gate": {STATE_LEAF: bias_update(
+                        counts[int(name.rsplit("_", 1)[1])
+                               - cfg.num_dense_layers],
+                        sub["moe"]["gate"][STATE_LEAF], rate)}}}
+            return new
 
     def dummy_inputs(self, batch_size: int = 2, seq_len: Optional[int] = None):
         S = seq_len or min(self.cfg.max_position_embeddings, 128)
@@ -652,12 +877,22 @@ class LlamaForCausalLM(nn.Module):
                    + E * cfg.moe.routed)
         attn = (3 if cfg.attn_gate else 2) * E * H * D \
             + 2 * E * cfg.kv_heads * D
+        score = 2 * D               # channels a kept key costs a head
+        if cfg.kv_lora_rank:        # latent attention: five projections
+            Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+            attn = (E * cfg.q_lora_rank + cfg.q_lora_rank * H * (Dn + Dr)
+                    + E * (cfg.kv_lora_rank + Dr)
+                    + cfg.kv_lora_rank * H * (Dn + Dv) + H * Dv * E)
+            score = Dn + Dr + Dv
+        mtp = cfg.num_nextn_predict_layers   # a block, eh_proj, the head
         n = (2 * cfg.padded_vocab_size * E + L * attn
              + cfg.num_dense_layers * dense
-             + (L - cfg.num_dense_layers) * ffn)
+             + (L - cfg.num_dense_layers) * ffn
+             + mtp * (attn + ffn + 2 * E * E + cfg.padded_vocab_size * E))
         # QK^T and AV over the keys a layer keeps: all positions, or the
         # window where that is shorter
         S = cfg.max_position_embeddings
         keys = sum(min(S, cfg.window(k) or S) for k in cfg.kinds) \
-            if cfg.kinds else L * S
-        return 6.0 * n + 12 * H * D * keys
+            if cfg.kinds else (L + mtp) * S
+        return 6.0 * n + 6 * H * score * keys

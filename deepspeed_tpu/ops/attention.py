@@ -57,8 +57,16 @@ def dot_product_attention(
     scale: Optional[float] = None,
     impl: str = "auto",
     flash_opts: Optional[dict] = None,
+    q_rope: Optional[jax.Array] = None,     # (B, S, H, R)
+    k_rope: Optional[jax.Array] = None,     # (B, T, 1, R): one key, all heads
 ) -> jax.Array:
     """Multi-head scaled dot-product attention; returns ``(B, S, H, D)``.
+
+    With ``q_rope`` and ``k_rope`` the score is a sum of two products
+    (latent attention): ``q_h · k_h + q_rope_h · k_rope``, the second
+    against ONE rotated key that all heads share, scaled by
+    ``(D + R) ** -0.5`` unless ``scale`` says otherwise
+    (:func:`_two_product_attention`).
 
     ``impl="ring"`` / ``"ulysses"`` are the sequence-parallel paths: the
     sequence dim must be sharded on the ``sp`` mesh axis (the engine does
@@ -71,6 +79,13 @@ def dot_product_attention(
     split over ``tp``), k and v are repeated to q's heads first, and a
     window raises rather than run full attention.
     """
+    if q_rope is not None:
+        if bias is not None or dropout_rate != 0.0 or window is not None:
+            raise NotImplementedError(
+                "a second score product (q_rope, k_rope) with a bias, "
+                "dropout or a sliding window: none is written for it")
+        return _two_product_attention(q, k, v, q_rope, k_rope, causal=causal,
+                                      scale=scale, impl=impl, mask=mask)
     group = q.shape[2] // k.shape[2]
     if q.shape[2] != group * k.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads are no multiple of "
@@ -134,6 +149,80 @@ def dot_product_attention(
     return _jnp_attention(q, k, v, causal=causal, bias=bias, mask=mask,
                           dropout_rate=dropout_rate, dropout_rng=dropout_rng,
                           scale=scale, window=window)
+
+
+def _two_product_attention(q, k, v, q_rope, k_rope, *, causal, scale, impl,
+                           mask=None, interpret=False):
+    """Latent attention's dispatch: the two-product flash kernels
+    (``ops/pallas/flash_attention.py flash_attention_mla``) on a TPU where
+    the widths tile (values as wide as the per-head keys, a multiple of
+    128 lanes; rope heads that fill 128-lane blocks) and the operands are
+    one device's own or split over batch axes alone; float32-softmax XLA
+    otherwise.  ``kernel_dispatch_total{site="attention"}`` says which."""
+    from .pallas.flash_attention import flash_attention_mla, mla_lanes
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    B, S, H, D = q.shape
+    R = q_rope.shape[-1]
+    if scale is None:
+        scale = (D + R) ** -0.5
+    lanes = mla_lanes(H, D, R, v.shape[-1])
+    if impl not in ("auto", "flash", "jnp"):
+        raise NotImplementedError(
+            f"impl={impl!r} with a second score product (q_rope, k_rope): "
+            f"'auto', 'flash' and 'jnp' are written")
+    if impl == "jnp":
+        reason = "impl='jnp' requested"
+    elif mask is not None:
+        reason = "a mask needs the XLA path"
+    elif lanes is None:
+        reason = (f"no two-product kernel at {D} + {R} rope lanes, v "
+                  f"{v.shape[-1]}")
+    elif impl == "auto" and not (interpret or on_tpu()):
+        reason = "auto: not a TPU"
+    elif impl == "auto" and S < 128:
+        reason = f"auto: seq {S} < 128"
+    else:
+        verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=False)
+        kern = functools.partial(flash_attention_mla, causal=causal,
+                                 scale=scale, interpret=interpret)
+        if verdict is not None:
+            how = "auto: TPU, seq >= 128, head_dim tiles" if impl == "auto" \
+                else f"impl={impl!r} requested"
+            plan = "one device" if verdict == "direct" \
+                else f"shard_map over batch axes {axes}"
+            note_dispatch("attention", "flash",
+                          f"{how}; {plan}; {lanes.reason}")
+            if verdict == "direct":
+                return kern(q, q_rope, k, k_rope, v)
+            return _shard_over_batch(kern, axes, 5)(q, q_rope, k, k_rope, v)
+        reason = "kernel_mesh_plan refused the mesh"
+    note_dispatch("attention", "jnp", reason)
+    s = (jnp.einsum("bshd,bthd->bhst", q, k,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bshr,btr->bhst", q_rope, k_rope[:, :, 0],
+                      preferred_element_type=jnp.float32)) * scale
+    neg = jnp.finfo(s.dtype).min
+    if causal:
+        T = k.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((S, T), bool), k=T - S)[None, None],
+                      s, neg)
+    if mask is not None:
+        s = jnp.where(mask, s, neg)
+    return jnp.einsum("bhst,bthd->bshd",
+                      jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
+
+
+def _shard_over_batch(kern, batch_axes, n_args: int):
+    """Full-manual shard_map of a kernel over ``(B, S, ·, ·)`` operands:
+    the batch over ``batch_axes``, everything else whole."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    spec = P(batch_axes if batch_axes else None, None, None, None)
+    return jax.shard_map(kern, mesh=get_mesh(), in_specs=(spec,) * n_args,
+                         out_specs=spec, check_vma=False)
 
 
 def _repeat_kv(k, v, group: int):

@@ -895,3 +895,374 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if not lanes.rows:      # (B·H, S, 1): a head a panel
         lse = lse.reshape(B, H, S).transpose(0, 2, 1)
     return _unpack(out, lanes, B), lse
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA): the score is a sum of two products
+# ---------------------------------------------------------------------------
+#
+# ``s_h = q_nope_h · k_nope_h + q_rope_h · k_rope``: each head has keys of
+# its own (``nope``, as wide as its values) and all heads share one rotated
+# key.  Operands are the rows the projections write: ``q_nope``, ``k_nope``,
+# ``v``, ``o`` as ``(N, S, H·D)`` with D a multiple of 128 (one head a lane
+# block, as at head_dim 128 above), ``q_rope`` ``(N, S, H·R)`` and the shared
+# key tiled to one 128-lane block, ``(N, S, 128 // R · R)``: a program reads
+# the 128-lane block of ``q_rope`` that holds its head (two heads at R = 64),
+# keeps its head's lanes and contracts over the block, so the shared key is
+# never as wide as the heads and is fetched once a row, not once a head.
+# The tile schedule, the mask and the online softmax are the kernels' above.
+
+def _rope_head(x, c, per: int, rope_dim: int):
+    """``x`` (rows, per·rope_dim), a block of ``per`` heads' rope lanes,
+    with only those of head ``c`` (a program id) kept."""
+    if per == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    lo = (c % per) * rope_dim
+    return jnp.where((lane >= lo) & (lane < lo + rope_dim), x, 0.0)
+
+
+def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, *,
+                    scale, sched, per, rope_dim):
+    """One (row, head, query tile) program."""
+    bq, L = qn_ref.shape[1:]
+    c, i = pl.program_id(1), pl.program_id(2)
+
+    def program(sweep, looped):
+        qn = qn_ref[0].astype(jnp.float32) * scale               # (bq, L)
+        qr = _rope_head(qr_ref[0].astype(jnp.float32) * scale, c, per,
+                        rope_dim)
+
+        def fold(k0, carry, d=None, kind=None):
+            ks = pl.ds(k0, sched.block_k)
+            s = _dot(qn, kn_ref[0, ks].astype(jnp.float32), ((1,), (1,))) \
+                + _dot(qr, kr_ref[0, ks].astype(jnp.float32), ((1,), (1,)))
+            if d is not None:
+                s = _band_mask(s, d, kind, sched.window)
+            m, l, acc = carry
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+            p = jnp.exp(s - _col(m_safe))
+            corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - m_safe))
+            v = v_ref[0, ks].astype(jnp.float32)
+            return (m_new, l * corr + p.sum(axis=-1),
+                    acc * _col(corr) + _dot(p, v, ((1,), (0,))))
+
+        def diagonal_tile(k0, d0, subs, carry):
+            assert len(subs) == 1   # the forward leaves them whole
+            return fold(k0, carry, d0, subs[0][2])
+
+        m, l, acc = sweep((jnp.full((bq,), NEG_INF, jnp.float32),
+                           jnp.zeros((bq,), jnp.float32),
+                           jnp.zeros((bq, L), jnp.float32)),
+                          fold, diagonal_tile)
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        lse_ref[0, 0, 0] = jnp.where(m == NEG_INF, 0.0, m) + jnp.log(l_safe)
+        o_ref[0] = (acc / _col(l_safe)).astype(o_ref.dtype)
+
+    _for_program(i, sched, program, own_is_q=True)
+
+
+def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
+                    delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
+                    dqn_acc, dqr_acc, heads_dkr, *kv_acc, scale, sched, per,
+                    rope_dim):
+    """Backward, one (row, head, key tile) program: one ds a score tile
+    feeds all five cotangents.  ``dq_nope`` sums over the key tiles in a
+    float32 scratch, ``dq_rope`` over the key tiles AND the ``per`` heads
+    that share its 128-lane block (each adds its own lanes), stored once
+    when whole.  ``dk_rope`` is the sum over every head: the heads'
+    programs add theirs, key tile by key tile, in the whole-sequence
+    scratch ``heads_dkr``, and the output's block index moves on from key
+    tile 0 only under the last head, so what reaches HBM is each tile's
+    complete sum, once (the grouped-query kernels' way, above)."""
+    bk, L = kn_ref.shape[1:]
+    sk = sched.sub_k
+    c, j = pl.program_id(1), pl.program_id(2)
+    last_j = pl.num_programs(2) - 1
+    first_head = c == 0
+    first_of_block, last_of_block = c % per == 0, c % per == per - 1
+
+    @pl.when(j == 0)
+    def _init_dqn():
+        dqn_acc[...] = jnp.zeros(dqn_acc.shape, dqn_acc.dtype)
+
+    @pl.when((j == 0) & first_of_block)
+    def _init_dqr():
+        dqr_acc[...] = jnp.zeros(dqr_acc.shape, dqr_acc.dtype)
+
+    def program(sweep, looped):
+        kn_blk = kn_ref[0].astype(jnp.float32)                   # (bk, L)
+        kr_blk = kr_ref[0].astype(jnp.float32)                   # (bk, 128)
+        v_blk = v_ref[0].astype(jnp.float32)
+
+        def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk, d=None,
+                  kind=None):
+            rs = pl.ds(q0 + r0, rows)
+            qn = qn_ref[0, rs].astype(jnp.float32) * scale
+            qr = _rope_head(qr_ref[0, rs].astype(jnp.float32) * scale, c,
+                            per, rope_dim)
+            do = do_ref[0, rs].astype(jnp.float32)
+            kn, kr = _rows(kn_blk, c0, cols), _rows(kr_blk, c0, cols)
+            v = _rows(v_blk, c0, cols)
+            s = _dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
+            if d is not None:
+                s = _band_mask(s, d, kind, sched.window)
+            p = jnp.exp(s - _col(lse_ref[0, 0, 0, rs]))
+            dv = _dot(p, do, ((0,), (0,)))
+            dp = _dot(do, v, ((1,), (1,)))
+            ds = p * (dp - _col(delta_ref[0, 0, 0, rs]))
+            dkn = _dot(ds, qn, ((0,), (0,)))
+            dkr = _dot(ds, qr, ((0,), (0,)))     # zero off the head's lanes
+            dqn_acc[rs] += _dot(ds, kn, ((1,), (0,)))
+            dqr_acc[rs] += _rope_head(_dot(ds, kr, ((1,), (0,))), c, per,
+                                      rope_dim)
+            if sums is None:
+                at = pl.ds(c0, cols)
+                kv_acc[0][at] += dkn
+                kv_acc[1][at] += dv
+                kv_acc[2][at] += dkr
+                return None
+            sums = dict(sums)
+            for b in range(c0, c0 + cols, sk):
+                sums[b] = tuple(t + _rows(x, b - c0, sk) for t, x in
+                                zip(sums[b], (dkn, dv, dkr)))
+            return sums
+
+        def diagonal_tile(q0, d0, subs, sums):
+            for r0, c0, kind in subs:
+                sums = visit(q0, sums, r0, c0, sched.sub_q, sk,
+                             None if kind == FULL else d0 + r0 - c0, kind)
+            return sums
+
+        def store(b, size, dkn, dv, dkr):
+            """Keys [b, +size) of the program's tile."""
+            at, whole = pl.ds(b, size), pl.ds(j * bk + b, size)
+            dkn_ref[0, at] = dkn.astype(dkn_ref.dtype)
+            dv_ref[0, at] = dv.astype(dv_ref.dtype)
+            # a select: the scratch holds anything before head 0 wrote it
+            dkr = dkr + jnp.where(first_head, 0.0, heads_dkr[whole])
+            heads_dkr[whole] = dkr
+            dkr_ref[0, at] = dkr.astype(dkr_ref.dtype)
+
+        if looped:
+            for acc in kv_acc:
+                acc[...] = jnp.zeros(acc.shape, acc.dtype)
+            sweep(None, visit, diagonal_tile)
+            store(0, bk, kv_acc[0][...], kv_acc[1][...], kv_acc[2][...])
+            return
+        zero = (jnp.zeros((sk, L), jnp.float32),) * 2 \
+            + (jnp.zeros((sk, kr_blk.shape[1]), jnp.float32),)
+        sums = sweep({b: zero for b in range(0, bk, sk)}, visit,
+                     diagonal_tile)
+        for b in range(0, bk, sk):
+            store(b, sk, *sums[b])
+
+    _for_program(j, sched, program, own_is_q=False)
+
+    @pl.when(j == last_j)
+    def _store_dqn():
+        for r in range(0, sched.S, sched.block_q):   # tile-sized values
+            rs = pl.ds(r, sched.block_q)
+            dqn_ref[0, rs] = (dqn_acc[rs] * scale).astype(dqn_ref.dtype)
+
+    @pl.when((j == last_j) & last_of_block)
+    def _store_dqr():
+        for r in range(0, sched.S, sched.block_q):
+            rs = pl.ds(r, sched.block_q)
+            dqr_ref[0, rs] = (dqr_acc[rs] * scale).astype(dqr_ref.dtype)
+
+
+class MLALanes(NamedTuple):
+    """The two-product kernels' layout, from shapes alone."""
+    heads: int
+    nope_dim: int       # of q_nope, k_nope AND v: one head a lane block
+    rope_dim: int
+
+    @property
+    def per(self) -> int:
+        """Heads a 128-lane block of ``q_rope``."""
+        return max(1, 128 // self.rope_dim)
+
+    @property
+    def rope_block(self) -> int:
+        return self.per * self.rope_dim
+
+    @property
+    def reason(self) -> str:
+        return (f"rows layout, 1 head a {self.nope_dim}-lane block; "
+                f"{self.nope_dim} + {self.rope_dim} shared rope lanes, "
+                f"v {self.nope_dim}")
+
+
+def mla_lanes(heads: int, nope_dim: int, rope_dim: int,
+              v_dim: int) -> Optional[MLALanes]:
+    """The layout for these widths, or None where the kernels have none:
+    they want values as wide as the nope keys, a multiple of 128 lanes,
+    and rope heads that fill 128-lane blocks whole."""
+    lanes = MLALanes(heads, nope_dim, rope_dim)
+    if v_dim != nope_dim or nope_dim % 128:
+        return None
+    if (rope_dim % 128 and 128 % rope_dim) or rope_dim < 8 \
+            or heads % lanes.per:
+        return None
+    return lanes
+
+
+_MLA_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC, inline=True)
+def _mla_fwd_call(qn, qr, kn, kr, v, *, causal, scale, block_q, block_k,
+                  lanes, interpret):
+    N, S, W = qn.shape
+    Sk = kn.shape[1]
+    L, H, per, R = lanes.nope_dim, lanes.heads, lanes.per, lanes.rope_block
+    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False)
+    tile = pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c))
+    panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c))
+    return pl.pallas_call(
+        functools.partial(_mla_fwd_kernel, scale=scale, sched=sched, per=per,
+                          rope_dim=lanes.rope_dim),
+        grid=(N, H, S // block_q),
+        in_specs=[
+            tile,
+            pl.BlockSpec((1, block_q, R), lambda n, c, i: (n, i, c // per)),
+            panel,
+            # the shared key: its index holds over every head and query
+            # tile of a row, so it is fetched once a row
+            pl.BlockSpec((1, Sk, R), lambda n, c, i: (n, 0, 0)),
+            panel,
+        ],
+        out_specs=[
+            tile,
+            pl.BlockSpec((1, 1, 1, block_q), lambda n, c, i: (n, c, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((N, S, W), qn.dtype),
+            jax.ShapeDtypeStruct((N, H, 1, S), jnp.float32),
+        ],
+        interpret=interpret,
+        **_vmem(2 * Sk * (2 * L + R) * kn.dtype.itemsize
+                + 4 * block_q * (L + R) * 4),
+    )(qn, qr, kn, kr, v)
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC, inline=True)
+def _mla_bwd_call(qn, qr, kn, kr, v, do, lse, delta, *, causal, scale,
+                  block_q, block_k, lanes, interpret):
+    N, S, W = qn.shape
+    Sk = kn.shape[1]
+    L, H, per, R = lanes.nope_dim, lanes.heads, lanes.per, lanes.rope_block
+    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True)
+    panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
+    rope_panel = pl.BlockSpec((1, S, R), lambda n, c, j: (n, 0, c // per))
+    block = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
+    rows = pl.BlockSpec((1, 1, 1, S), lambda n, c, j: (n, c, 0, 0))
+    scratch = [pltpu.VMEM((S, L), jnp.float32),          # dq_nope over j
+               pltpu.VMEM((S, R), jnp.float32),          # dq_rope, j and heads
+               pltpu.VMEM((Sk, R), jnp.float32)]         # dk_rope over heads
+    if _is_looped(sched, own_is_q=False):
+        scratch += [pltpu.VMEM((block_k, L), jnp.float32)] * 2 \
+            + [pltpu.VMEM((block_k, R), jnp.float32)]
+    item = qn.dtype.itemsize
+    need = (S * L * (4 + 3 * 2 * item) + S * R * (4 + 2 * 2 * item)
+            + Sk * R * 4 + 4 * 4 * 8 * S
+            + block_k * (2 * L + R) * (4 + 4 * item))
+    return pl.pallas_call(
+        functools.partial(_mla_bwd_kernel, scale=scale, sched=sched, per=per,
+                          rope_dim=lanes.rope_dim),
+        grid=(N, H, Sk // block_k),
+        in_specs=[panel, rope_panel, block,
+                  pl.BlockSpec((1, block_k, R), lambda n, c, j: (n, j, 0)),
+                  block, panel, rows, rows],
+        out_specs=[
+            panel, rope_panel, block,
+            # whole under the last head: until then the block stays at key
+            # tile 0 and nothing of it is written back
+            pl.BlockSpec((1, block_k, R),
+                         lambda n, c, j: (n, jnp.where(c == H - 1, j, 0), 0)),
+            block,
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(qn.shape, qn.dtype),
+            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+            jax.ShapeDtypeStruct(kn.shape, kn.dtype),
+            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=scratch,
+        interpret=interpret,
+        **_vmem(need),
+    )(qn, qr, kn, kr, v, do, lse, delta)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _flash_mla(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
+               interpret):
+    return _flash_mla_fwd(qn, qr, kn, kr, v, causal, scale, block_q, block_k,
+                          lanes, interpret)[0]
+
+
+def _flash_mla_fwd(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
+                   interpret):
+    _note_score_tiles("fwd", score_tile_schedule(
+        qn.shape[1], kn.shape[1], block_q, block_k, causal, False))
+    out, lse = _mla_fwd_call(qn, qr, kn, kr, v, causal=causal, scale=scale,
+                             block_q=block_q, block_k=block_k, lanes=lanes,
+                             interpret=interpret)
+    out = checkpoint_name(out, "flash_out")     # as _flash_fwd's
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (qn, qr, kn, kr, v, out, lse)
+
+
+def _flash_mla_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
+                   do):
+    qn, qr, kn, kr, v, out, lse = res
+    _note_score_tiles("bwd", score_tile_schedule(
+        qn.shape[1], kn.shape[1], block_q, block_k, causal, True))
+    delta = _delta(do, out, flash_lanes(lanes.heads, lanes.nope_dim))
+    return _mla_bwd_call(qn, qr, kn, kr, v, do, lse, delta, causal=causal,
+                         scale=scale, block_q=block_q, block_k=block_k,
+                         lanes=lanes, interpret=interpret)
+
+
+_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
+
+
+def flash_attention_mla(q_nope: jax.Array, q_rope: jax.Array,
+                        k_nope: jax.Array, k_rope: jax.Array, v: jax.Array,
+                        *, causal: bool = True,
+                        scale: Optional[float] = None, block_q: int = 512,
+                        block_k: int = 512,
+                        interpret: bool = False) -> jax.Array:
+    """Attention whose score is two products, ``softmax((q_nope_h ·
+    k_nope_h + q_rope_h · k_rope) · scale) v_h``: ``q_nope``, ``k_nope``,
+    ``v`` ``(B, S, H, D)``, ``q_rope`` ``(B, S, H, R)`` and ONE rotated key
+    for all heads, ``k_rope`` ``(B, S, 1, R)``; returns ``(B, S, H, D)``.
+    ``scale`` defaults to ``(D + R) ** -0.5``.  See :func:`mla_lanes` for
+    the widths the kernels take; the ``(B, S, H, ·)`` views are free
+    reshapes of the rows the kernels read and write."""
+    B, S, H, D = q_nope.shape
+    R = q_rope.shape[-1]
+    lanes = mla_lanes(H, D, R, v.shape[-1])
+    if lanes is None or k_rope.shape[2] != 1 or k_nope.shape[2] != H:
+        raise ValueError(
+            f"no two-product kernel for {H} heads of {D} + {R} rope lanes, "
+            f"values {v.shape[-1]} wide, {k_rope.shape[2]} rope keys")
+    if scale is None:
+        scale = (D + R) ** -0.5
+    block_q = _largest_dividing_block(S, block_q)
+    block_k = _largest_dividing_block(k_nope.shape[1], block_k)
+    if S % block_q or k_nope.shape[1] % block_k:
+        raise ValueError(f"seq lengths ({S},{k_nope.shape[1]}) must divide "
+                         f"block sizes ({block_q},{block_k})")
+    # the shared key fills one lane block: a copy per heads, never H
+    kr = k_rope.reshape(B, -1, R)
+    if lanes.per > 1:
+        kr = jnp.tile(kr, (1, 1, lanes.per))
+    out = _flash_mla(q_nope.reshape(B, S, H * D), q_rope.reshape(B, S, H * R),
+                     k_nope.reshape(B, -1, H * D), kr,
+                     v.reshape(B, -1, H * D), causal, scale, block_q, block_k,
+                     lanes, interpret)
+    return out.reshape(B, S, H, D)

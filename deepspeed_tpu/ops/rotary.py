@@ -236,3 +236,33 @@ def rotate_rows(q: jax.Array, k: jax.Array, positions: Optional[jax.Array],
     return jax.shard_map(
         lambda *a: qk_rows(*a, head_dim, eps, interpret), mesh=get_mesh(),
         in_specs=specs, out_specs=(rows, rows), check_vma=False)(*args)
+
+
+def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,
+                     theta: float = 10000.0,
+                     interleaved: bool = False) -> jax.Array:
+    """Rotate ``x`` (B, S, n·rotary_dim), the rows a projection writes for
+    ``n`` heads of ``rotary_dim`` channels each (n = 1: one key for all
+    heads), every channel of a head by its position: pairs ``(2i, 2i+1)``
+    when ``interleaved``, ``(i, i + rotary_dim/2)`` otherwise, by
+    ``theta^(-2i/rotary_dim)``.  Elementwise on the rows as they lie (a
+    channel's partner is one lane roll away), in float32: no ``(B, S, n,
+    d/2, 2)`` view is formed, which on the chip is a copy each way.  What
+    the latent attention's rope channels take; ``rotate_rows`` is the
+    whole-head path."""
+    n, half = x.shape[-1] // rotary_dim, rotary_dim // 2
+    lane = np.arange(rotary_dim)
+    if interleaved:
+        shift, first, freq = 1, lane % 2 == 0, lane // 2
+    else:
+        shift, first, freq = half, lane < half, lane % half
+    # one head's table, a channel a lane (no gather: the chip runs one an
+    # index at a time), then the same for every head
+    inv_freq = jnp.asarray(theta ** (-2.0 * freq / rotary_dim), jnp.float32)
+    ang = positions[..., None].astype(jnp.float32) * inv_freq   # (B, S, d)
+    cos = jnp.tile(jnp.cos(ang), (1, 1, n))
+    sin = jnp.tile(jnp.where(first, -jnp.sin(ang), jnp.sin(ang)), (1, 1, n))
+    xf = x.astype(jnp.float32)
+    partner = jnp.where(np.tile(first, n), jnp.roll(xf, -shift, axis=-1),
+                        jnp.roll(xf, shift, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)

@@ -31,6 +31,9 @@ def main():
     ap.add_argument("--seed", type=int, default=3000000023)
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--cell", default=CELL,
+                    help="another cell routed under a selection bias "
+                         "(train-joyai-flash-8k-1chip)")
     args = ap.parse_args()
 
     import numpy as np
@@ -46,7 +49,7 @@ def main():
 
     t0 = time.perf_counter()
     cell, driver, engine, cfg, conf, batches = build(
-        args.seed, args.rehearse, edit, args.init_scale, cell=CELL)
+        args.seed, args.rehearse, edit, args.init_scale, cell=args.cell)
     print(json.dumps({"built_s": time.perf_counter() - t0}), flush=True)
     if args.check:
         ctx = Context(cell, args.seed, 0.0, False, args.rehearse, None, t0)

@@ -99,6 +99,23 @@ _PINNED_TO_THREE_CELLS = (
 # ``tests/benchmark/test_hbm_readers.py`` holds their versions over "what a
 # cell added, then what every cell gained since".  The same ROADMAP.md job
 # removes these marks with the others.
+# Seven pin the manifest to the four cells of PR 35's day: both cases of
+# ``test_trinity_cell.py``'s cut test hold every ``reduced`` inside a set
+# that lacks ``n_routed_experts`` (the DeepSeek-V3 family's name for the
+# experts held), and five of ``test_hbm_readers.py`` hold the list of cells
+# and the tail of the per-layer metrics to exactly what they were.
+# ``train-joyai-flash-8k-1chip`` (PR 38) is appended to those lists, cuts
+# under its source's own key and adds ``mtp_loss_excess`` behind the two
+# HBM entries, so they fail by construction, and are expected to, strictly;
+# ``tests/benchmark/test_joyai_cell.py`` holds their position-free
+# versions.  The same ROADMAP.md job removes these marks with the others.
+_PINNED_TO_FOUR_CELLS = (
+    "test_the_two_entries_are_appended_and_nothing_else_moved",
+    "test_every_cell_loads_the_two_entries[train-xl-z3-1chip]",
+    "test_every_cell_loads_the_two_entries[train-olmoe-z3-1chip]",
+    "test_every_cell_loads_the_two_entries[train-mellum2-8k-1chip]",
+    "test_every_cell_loads_the_two_entries[train-trinity-mini-8k-1chip]",
+)
 _PINNED_TO_A_CELLS_OWN_TAIL = "pins a cell's list of per-layer metrics " \
     "to its day; superseded by test_hbm_readers.py (PR 35)"
 _SUPERSEDED = [
@@ -117,6 +134,15 @@ _SUPERSEDED = [
     ("test_trinity_cell.py",
      ("test_no_metric_lost_a_cell_and_each_cell_kept_its_own",),
      _PINNED_TO_A_CELLS_OWN_TAIL),
+    ("test_trinity_cell.py",
+     ("test_every_cell_loads_and_is_cut_only_as_the_guide_allows[manifest]",
+      "test_every_cell_loads_and_is_cut_only_as_the_guide_allows"
+      "[with_pending]"),
+     "holds every cut inside a set without n_routed_experts; superseded "
+     "by test_joyai_cell.py (PR 38)"),
+    ("test_hbm_readers.py", _PINNED_TO_FOUR_CELLS,
+     "pins the manifest's cells and the metrics' tail to four cells; "
+     "superseded by test_joyai_cell.py (PR 38)"),
 ]
 
 

@@ -56,6 +56,29 @@ def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, k, n):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def test_two_product_flash_compiles_at_the_fifth_cells_shape(one_chip):
+    """The latent attention of ``train-joyai-flash-8k-1chip`` (PR 38): one
+    8192-token row of 32 heads, 128 nope + 64 rope channels with one rope
+    key for all heads, values 128 wide; the forward and the one backward
+    kernel, whose whole-sequence float32 scratch (dq_nope, dq_rope and the
+    heads' dk_rope) and double-buffered panels ask Mosaic for more VMEM
+    than its default."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_mla
+
+    B, S, H, D, R = 1, 8192, 32, 128, 64
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def loss(*ops):
+        return flash_attention_mla(*ops).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
+        arg(B, S, H, D), arg(B, S, H, R), arg(B, S, H, D), arg(B, S, 1, R),
+        arg(B, S, H, D)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
 def test_sorted_dispatch_compiles_per_rank_on_four_chips(topo, monkeypatch):
     """``fsdp 4`` over the host's 2x2 chips, OLMoE's widths, 2 x 4096 tokens
     a chip: every rank runs the Pallas grouped matmuls on its own 65,536
