@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..telemetry import registry as _registry
+
 # Mapping logical axis name -> mesh axis (or tuple), per parallelism style.
 # ``None`` = replicated along that dim.
 TP_RULES = {
@@ -481,94 +483,97 @@ def cross_entropy_loss(
     return nll.sum() / count
 
 
+def _note_head_products(pass_: str, n: int) -> None:
+    """Count, at trace time, the head-sized ``(C, E) x (E, V)`` matrix
+    products a chunk in the :func:`_fused_ce` rule being traced."""
+    _registry.counter(
+        "lm_head_products_total",
+        "head-sized matrix products a token chunk in a traced rule of the "
+        "chunked language-model loss: primal (no gradient asked), forward "
+        "and backward rule of its custom_vjp (counted at trace time, not "
+        "per call)", labelnames=("pass",)).labels(pass_).inc(n)
+
+
 @functools.lru_cache(maxsize=None)
 def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
-              save_logits: bool):
-    """Build the custom-vjp chunked cross-entropy core (cached per config).
+              h_dtype, w_dtype):
+    """Build the custom-vjp chunked cross-entropy core (cached per config
+    and the operands' types, which the backward rule rounds to).
 
-    Forward scans token chunks: each chunk's ``(C, V)`` fp32 logits exist
-    only inside its scan step (matmul → logsumexp → gather, fused by XLA);
-    the residuals are O(N) scalars-per-token (logz), never O(N·V).  The
-    backward pass either recomputes chunk logits (``save_logits=False``,
-    +1 head matmul of FLOPs, zero O(N·V) residency — the 1.5B regime where
-    the head is ~5% of FLOPs and HBM is the binding constraint) or replays
-    bf16 logits saved in forward (``save_logits=True``, zero extra FLOPs —
-    the 125M regime where the head is ~30% of FLOPs).  Either way the fp32
-    ``(N, V)`` cotangent of the stock autodiff path — the exact 1.6 GB
-    margin that OOMs GPT-2-1.5B at micro=4 on a 16 GB chip — is never
-    materialized: d_logits is built and consumed chunk-local.
+    It scans token chunks: each chunk's ``(C, V)`` fp32 logits exist only
+    inside its scan step, never O(N·V), and are multiplied out ONCE.  The
+    result is one scalar, so its cotangent ``g`` is a scalar and the
+    backward is linear in it: the forward rule makes the cotangents at
+    ``g = 1`` from the logits it has in hand (``dlog``, ``dh``, ``dW``
+    below) and the backward rule only scales them.  Three head-sized
+    products a chunk (``lm_head_products_total``: forward 3, backward 0),
+    the three a linear layer requires; the primal (``eval_batch``, any
+    call without a gradient) runs the one of the loss.  The residuals are
+    fp32 ``dh`` ``(N, E)`` and ``dW`` ``(E, V)`` and nothing else — not
+    ``h``, not ``W``, no logits: a caller that keeps a ``jax.vjp`` of the
+    loss between two calls keeps those two (the engine's ``forward`` /
+    ``backward`` / ``step`` path runs loss and gradient in one program),
+    and on a mesh the backward reads no weight.
     """
     Vp = padded_vocab_size
-    padded = padded_vocab_size != vocab_size
 
-    def _mask_pad(logits):
-        """Exclude padded vocab columns from the softmax (single source
-        of truth for fwd and both bwd modes)."""
-        if padded:
-            mask = jnp.arange(Vp) < vocab_size
-            logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
-        return logits
-
-    def _chunk_stats(hc, wteT, tc):
-        """(C, E) × (E, Vp) → per-token logz/label-logit, fp32 math."""
-        logits = _mask_pad(jnp.dot(hc, wteT,
-                                   preferred_element_type=jnp.float32))
+    def _chunk(hc, wteT, tc):
+        """(C, E) × (E, Vp) → fp32 logits (padded vocab columns out of the
+        softmax), per-token logz, the valid mask, safe labels, nll."""
+        logits = jnp.dot(hc, wteT, preferred_element_type=jnp.float32)
+        if Vp != vocab_size:
+            logits = jnp.where(jnp.arange(Vp) < vocab_size, logits,
+                               jnp.finfo(jnp.float32).min)
         valid = tc != ignore_index
         safe = jnp.where(valid, tc, 0)
         logz = jax.nn.logsumexp(logits, axis=-1)
         lbl = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        return logits, logz, jnp.where(valid, logz - lbl, 0.0)
+        return logits, logz, valid, safe, jnp.where(valid, logz - lbl, 0.0)
 
     @jax.custom_vjp
     def ce(hf, wteT, tf):
-        def body(acc, xs):
-            hc, tc = xs
-            _, _, nll = _chunk_stats(hc, wteT, tc)
-            return acc + nll.sum(), None
+        _note_head_products("primal", 1)
 
-        nll_sum, _ = jax.lax.scan(body, jnp.float32(0.0), (hf, tf))
-        return nll_sum
+        def body(acc, xs):
+            return acc + _chunk(xs[0], wteT, xs[1])[-1].sum(), None
+
+        return jax.lax.scan(body, jnp.float32(0.0), (hf, tf))[0]
 
     def ce_fwd(hf, wteT, tf):
-        def body(acc, xs):
+        _note_head_products("forward", 3)
+
+        def body(carry, xs):
+            acc, dwteT = carry
             hc, tc = xs
-            logits, logz, nll = _chunk_stats(hc, wteT, tc)
-            saved = logits.astype(hf.dtype) if save_logits else jnp.zeros(
-                (), hf.dtype)
-            return acc + nll.sum(), (logz, saved)
-
-        nll_sum, (logzs, saved) = jax.lax.scan(
-            body, jnp.float32(0.0), (hf, tf))
-        return nll_sum, (hf, wteT, tf, logzs, saved)
-
-    def ce_bwd(res, g):
-        hf, wteT, tf, logzs, saved = res
-        K, C, E = hf.shape
-
-        def body(dwteT, xs):
-            hc, tc, logz, sv = xs
-            logits = _mask_pad(
-                sv.astype(jnp.float32) if save_logits
-                else jnp.dot(hc, wteT, preferred_element_type=jnp.float32))
-            valid = tc != ignore_index
-            safe = jnp.where(valid, tc, 0)
-            coeff = (g * valid).astype(jnp.float32)          # (C,)
+            logits, logz, valid, safe, nll = _chunk(hc, wteT, tc)
             p = jnp.exp(logits - logz[:, None])              # softmax rows
             onehot = (jnp.arange(Vp)[None, :] == safe[:, None])
-            dlog = (p - onehot) * coeff[:, None]             # (C, Vp) fp32
-            dlogb = dlog.astype(hc.dtype)
+            dlog = (p - onehot) * valid[:, None]             # (C, Vp) fp32
+            dlogb = dlog.astype(hc.dtype)       # rounded once, before both
             # d h_c = dlog @ wteT^T ; d wteT += h_c^T @ dlog (fp32 accum)
             dh_c = jax.lax.dot_general(
-                dlogb, wteT, (((1,), (1,)), ((), ())))       # (C, E)
+                dlogb, wteT, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)          # (C, E)
             dwteT = dwteT + jnp.dot(hc.T, dlogb,
                                     preferred_element_type=jnp.float32)
-            return dwteT, dh_c.astype(hc.dtype)
+            return (acc + nll.sum(), dwteT), dh_c
 
-        dwteT, dhs = jax.lax.scan(
-            body, jnp.zeros((E, Vp), jnp.float32),
-            (hf, tf, logzs, saved))
-        return dhs, dwteT.astype(wteT.dtype), \
-            np.zeros(tf.shape, jax.dtypes.float0)
+        (nll_sum, dwteT), dhs = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.zeros(wteT.shape, jnp.float32)),
+            (hf, tf))
+        return nll_sum, (dhs, dwteT)
+
+    def ce_bwd(res, g):
+        _note_head_products("backward", 0)       # the label reads 0
+        dhs, dwteT = res
+        # each rounded to its operand's type once, after the scale; both
+        # finished before either is used: a scan of one trip is inlined,
+        # and XLA's scheduler, left free, puts dW off to the end of the step
+        # and multiplies the logits out a second time for it rather than
+        # keep them through the layers' backward
+        dh, dw = jax.lax.optimization_barrier(
+            ((g * dhs).astype(h_dtype), (g * dwteT).astype(w_dtype)))
+        return dh, dw, np.zeros(dhs.shape[:2], jax.dtypes.float0)
 
     ce.defvjp(ce_fwd, ce_bwd)
     return ce
@@ -576,15 +581,16 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
 
 def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
                     vocab_size: int, padded_vocab_size: int, chunk: int,
-                    dtype, ignore_index: int = -100,
-                    save_logits: bool = False) -> jax.Array:
+                    dtype, ignore_index: int = -100) -> jax.Array:
     """Tied-head cross-entropy WITHOUT materializing the (B, S, V) fp32
-    logits or their cotangent (see :func:`_fused_ce`).  Exact same loss as
-    the dense path (fp32 logsumexp); ``chunk >= B·S`` degenerates to one
-    full-width chunk, which keeps the single big MXU matmul but still
-    skips the O(N·V) fp32 residency (the round-2 ``lax.map`` version
-    serialized 512-row matmuls and LOST 17% e2e — this one is
-    measurement-driven: big chunks, custom vjp, no per-chunk remat)."""
+    logits or their cotangent, and with the logits multiplied out once a
+    step (see :func:`_fused_ce`: the forward makes ``dh`` and ``dW``, the
+    backward scales them).  Exact same loss as the dense path (fp32
+    logsumexp); ``chunk >= B·S`` degenerates to one full-width chunk,
+    which keeps the single big MXU matmul but still skips the O(N·V) fp32
+    residency (the round-2 ``lax.map`` version serialized 512-row matmuls
+    and LOST 17% e2e — this one is measurement-driven: big chunks, custom
+    vjp, no per-chunk remat)."""
     B, S, E = h.shape
     N = B * S
     chunk = min(chunk, N)
@@ -599,7 +605,7 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     tf = tf.reshape(-1, chunk)
     wteT = wte.astype(dtype).T        # (E, V)
     ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
-                   bool(save_logits))
+                   hf.dtype, wteT.dtype)
     nll_sum = ce(hf, wteT, tf)
     count = (tf != ignore_index).sum()
     return nll_sum / jnp.maximum(count, 1)

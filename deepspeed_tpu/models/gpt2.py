@@ -82,14 +82,11 @@ class GPT2Config:
     # chunked tied-head loss (common.chunked_lm_loss): token rows per
     # chunk; None = dense logits.  Saves the (B,S,V) fp32 logits+cotangent
     # at large micro sizes; the model output then carries no "logits".
+    # Each chunk's logits are multiplied out once: the loss's forward rule
+    # makes dh and dW from them and its backward scales the two (see
+    # models/common.py _fused_ce), so there is neither a second product
+    # nor O(N·V) of saved logits to choose between.
     loss_chunk: Optional[int] = None
-    # chunked head backward: replay bf16 logits saved in forward (True;
-    # zero extra FLOPs — small models where the head dominates) vs
-    # recompute them (False; zero O(N·V) residency — large models where
-    # HBM is the binding constraint).  See models/common.py _fused_ce.
-    # (round-3 measured: replay LOSES 20% e2e at 125M — bf16 logits
-    # traffic costs more than the recompute matmul; keep False)
-    loss_save_logits: bool = False
     # Pallas fused CE head (ops/pallas/fused_ce.py): matmul + online
     # logsumexp in VMEM, logits never in HBM either pass.  Engages only
     # with loss_chunk set (the chunked-loss output contract) on TPU.
@@ -492,8 +489,7 @@ class GPT2LMHeadModel(nn.Module):
                     loss = chunked_lm_loss(
                         h, wte, tgt, vocab_size=cfg.vocab_size,
                         padded_vocab_size=cfg.padded_vocab_size,
-                        chunk=cfg.loss_chunk, dtype=cfg.dtype,
-                        save_logits=cfg.loss_save_logits)
+                        chunk=cfg.loss_chunk, dtype=cfg.dtype)
             out = ModelOutput(loss=loss)
             if cfg.moe is not None:
                 out["aux_loss"] = aux_loss

@@ -4,10 +4,13 @@ logits.
 The LM head is the single largest non-attention cost of small-model
 training (GPT-2-125M: the (N,V)=(24576,50304) fp32 logits are ~4.9 GB
 written+re-read per pass).  The XLA chunked head (``models/common.py
-_fused_ce``) bounds residency but still materializes each chunk's fp32
-logits in HBM.  This kernel computes per-token ``logsumexp`` and the
-label logit ONLINE while streaming vocab blocks through VMEM — logits
-never touch HBM, in either pass (reference analog:
+_fused_ce``) bounds residency and multiplies each chunk's logits out
+once (its forward rule makes ``dh`` and ``dW`` from them, its backward
+scales the two) but still materializes the chunk's fp32 logits in HBM,
+between that product and the passes that read them.  This kernel
+computes per-token ``logsumexp`` and the label logit ONLINE while
+streaming vocab blocks through VMEM — logits never touch HBM, in either
+pass (reference analog:
 ``csrc/transformer/general_kernels.cu`` fused logits/softmax path).
 
 Layout contract (Mosaic tiling): per-token vectors ride as

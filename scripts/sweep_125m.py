@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """One 125M-headline config measurement per invocation (mirrors
 bench_train's config).  Usage:
-  python scripts/sweep_125m.py micro=24 fb=1024x1024 save_logits=1
+  python scripts/sweep_125m.py micro=24 fb=1024x1024
 Prints one JSON line.
 """
 import json
@@ -26,7 +26,6 @@ def main():
     kv = dict(a.split("=", 1) for a in sys.argv[1:])
     micro = int(kv.get("micro", 24))
     chunk = int(kv.get("chunk", 1 << 30))
-    save_logits = kv.get("save_logits", "0") == "1"
     remat = kv.get("remat", "off")
     fb = kv.get("fb")
     steps = int(kv.get("steps", 8))
@@ -44,7 +43,7 @@ def main():
         remat_policy=remat if remat != "off" else "nothing_saveable",
         attn_impl=kv.get("attn", "auto"),
         flash_block=tuple(int(x) for x in fb.split("x")) if fb else None,
-        loss_chunk=chunk or None, loss_save_logits=save_logits,
+        loss_chunk=chunk or None,
         loss_pallas=kv.get("pl", "0") == "1",
         **({"vocab_size": vocab} if vocab else {}))
     model = GPT2LMHeadModel(cfg)
@@ -79,8 +78,7 @@ def main():
     mfu = tok_s * model.flops_per_token() / (PEAK if on_tpu else 1e12)
     print(json.dumps({
         "config": {"micro": micro, "gas": gas, "chunk": chunk,
-                   "save_logits": save_logits, "remat": remat, "fb": fb,
-                   "clip": clip},
+                   "remat": remat, "fb": fb, "clip": clip},
         "tok_s": round(tok_s, 1), "mfu": round(mfu, 4),
         "vs_ref": round(mfu / REF_MFU, 3),
         "windows": [round(w, 1) for w in windows],

@@ -2,7 +2,7 @@
 """One north-star (GPT-2-1.5B) config measurement per invocation.
 
 Usage: python scripts/sweep_northstar.py micro=4 gas=1 chunk=8192 \
-           save_logits=0 remat=dots_saveable steps=8
+           remat=dots_saveable steps=8
 Prints one JSON line; run sequentially from a shell loop for a sweep
 (fresh process per config keeps HBM fragmentation out of the numbers).
 """
@@ -29,7 +29,6 @@ def main():
     micro = int(kv.get("micro", 2))
     gas = int(kv.get("gas", 1))
     chunk = int(kv.get("chunk", 0))          # 0 = dense head
-    save_logits = kv.get("save_logits", "0") == "1"
     remat = kv.get("remat", "dots_saveable")  # "off" disables
     steps = int(kv.get("steps", 8))
     opt = kv.get("opt", "adamw8bit")
@@ -47,7 +46,7 @@ def main():
         remat_policy=remat if remat != "off" else "nothing_saveable",
         attn_impl=kv.get("attn", "auto"),
         flash_block=tuple(int(x) for x in fb.split("x")) if fb else None,
-        loss_chunk=chunk or None, loss_save_logits=save_logits,
+        loss_chunk=chunk or None,
         loss_pallas=kv.get("pl", "0") == "1")
     model = GPT2LMHeadModel(cfg)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
@@ -78,8 +77,7 @@ def main():
     mfu = tok_s * model.flops_per_token() / (PEAK if on_tpu else 1e12)
     print(json.dumps({
         "config": {"micro": micro, "gas": gas, "chunk": chunk,
-                   "save_logits": save_logits, "remat": remat, "opt": opt,
-                   "steps": steps},
+                   "remat": remat, "opt": opt, "steps": steps},
         "tok_s": round(tok_s, 1), "mfu": round(mfu, 4),
         "vs_ref": round(mfu / REF_MFU, 3),
         "step_ms": round(1000 * dt / steps, 1),

@@ -258,42 +258,84 @@ def test_chunked_lm_loss_matches_dense():
     l0, g0 = loss_and_gradsum(None)
     l1, g1 = loss_and_gradsum(16)   # 64 rows -> 4 chunks
     assert abs(l1 - l0) / abs(l0) < 1e-4
-    assert abs(g1 - g0) / g0 < 1e-3
+    # one bf16 step: the dense head rounds dlog after the 1 / count scale,
+    # the chunked one before it (its backward scales the finished products)
+    assert abs(g1 - g0) / g0 < 2 ** -8
 
 
-def test_chunked_lm_loss_save_logits_and_full_chunk():
-    """The custom-vjp head is exact in both backward modes (recompute vs
-    saved bf16 logits) and when one chunk covers all rows."""
+@pytest.mark.parametrize("scale", ["constant", "traced", "2^-15"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("chunk", [8, 32])
+def test_chunked_lm_loss_makes_its_cotangents_in_the_forward(chunk, dtype,
+                                                             scale):
+    """The custom-vjp head, whose forward rule makes ``dh`` and ``dW`` and
+    whose backward scales them: 4 chunks of 8 and one whole chunk, a padded
+    vocabulary, an ignored label, the loss scaled by a constant, by a traced
+    scalar and so that the head's cotangent is a power of two.  float32
+    operands against the dense ``cross_entropy_loss``; bf16 operands against
+    the formula the backward ran before (the scale applied before ``dlog``
+    is rounded, ``dh`` out of its product in bf16): within one bf16 step,
+    and equal where the cotangent is a power of two."""
     from deepspeed_tpu.models.common import chunked_lm_loss, \
         cross_entropy_loss
 
     rng = np.random.default_rng(3)
     B, S, E, V, Vp = 2, 16, 32, 101, 128
-    h = jnp.asarray(rng.normal(size=(B, S, E)), jnp.float32)
-    wte = jnp.asarray(rng.normal(size=(Vp, E)), jnp.float32)
+    h = jnp.asarray(rng.normal(size=(B, S, E)), dtype)
+    wte = jnp.asarray(rng.normal(size=(Vp, E)), dtype)
     lbl = jnp.asarray(rng.integers(0, V, size=(B, S)), jnp.int32)
     lbl = lbl.at[0, 3].set(-100)
+    # "2^-15" is the head's cotangent: the scale times 1 / (31 valid labels)
+    c = jnp.float32({"constant": 3.0, "traced": 0.37, "2^-15": 31 * 2.0 ** -15}
+                    [scale])
 
-    def dense(h, wte):
-        logits = jnp.dot(h, wte.T)
-        logits = jnp.where(jnp.arange(Vp) < V, logits,
-                           jnp.finfo(jnp.float32).min)
-        return cross_entropy_loss(logits, lbl)
+    def logits_of(h, wte):
+        logits = jnp.dot(h, wte.T, preferred_element_type=jnp.float32)
+        return jnp.where(jnp.arange(Vp) < V, logits,
+                         jnp.finfo(jnp.float32).min)
 
-    l0, (gh0, gw0) = jax.value_and_grad(dense, (0, 1))(h, wte)
-    for chunk in (8, B * S):
-        for save in (False, True):
-            def fused(h, wte):
-                return chunked_lm_loss(
-                    h, wte, lbl, vocab_size=V, padded_vocab_size=Vp,
-                    chunk=chunk, dtype=jnp.float32, save_logits=save)
+    def dense(h, wte, c):
+        return c * cross_entropy_loss(logits_of(h, wte), lbl)
 
-            l1, (gh1, gw1) = jax.value_and_grad(fused, (0, 1))(h, wte)
-            np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
-            np.testing.assert_allclose(np.asarray(gh0), np.asarray(gh1),
-                                       atol=1e-6)
-            np.testing.assert_allclose(np.asarray(gw0), np.asarray(gw1),
-                                       atol=1e-6)
+    def fused(h, wte, c):
+        return c * chunked_lm_loss(h, wte, lbl, vocab_size=V,
+                                   padded_vocab_size=Vp, chunk=chunk,
+                                   dtype=dtype)
+
+    if scale == "traced":
+        fused, dense = jax.jit(fused), jax.jit(dense)
+    l0, (gh0, gw0) = jax.value_and_grad(dense, (0, 1))(h, wte, c)
+    l1, (gh1, gw1) = jax.value_and_grad(fused, (0, 1))(h, wte, c)
+    np.testing.assert_allclose(float(l0), float(l1), rtol=1e-5)
+    np.testing.assert_allclose(float(fused(h, wte, c)), float(l1), rtol=1e-6)
+    assert gh1.dtype == gw1.dtype == dtype
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(gh0), np.asarray(gh1),
+                                   atol=1e-6 * max(float(c), 1.0))
+        np.testing.assert_allclose(np.asarray(gw0), np.asarray(gw1),
+                                   atol=1e-6 * max(float(c), 1.0))
+        return
+    # the parent's formula, chunk by chunk, on the same bf16 operands
+    valid = (lbl != -100).reshape(-1)
+    g = c / valid.sum()
+    logits = logits_of(h.reshape(-1, E), wte)
+    p = jax.nn.softmax(logits, axis=-1)
+    onehot = jax.nn.one_hot(jnp.where(valid, lbl.reshape(-1), 0), Vp)
+    dlog = ((p - onehot) * (g * valid)[:, None]).astype(dtype)
+    want_h = jnp.dot(dlog, wte).reshape(B, S, E)
+    want_w = sum(jnp.dot(dlog[i:i + chunk].T, h.reshape(-1, E)[i:i + chunk],
+                         preferred_element_type=jnp.float32)
+                 for i in range(0, B * S, chunk)).astype(dtype)
+    for got, want in ((gh1, want_h), (gw1, want_w)):
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        if scale == "2^-15":
+            np.testing.assert_array_equal(got, want)
+        else:
+            # one bf16 step of the larger of the two (8 bits of mantissa),
+            # and the rounding of dlog itself summed over a row
+            step = np.maximum(np.abs(got), np.abs(want)) * 2.0 ** -7
+            assert (np.abs(got - want) <= step + np.abs(want).max() * 2e-3
+                    ).all()
 
 
 def test_train_batches_matches_per_step_calls():

@@ -538,11 +538,14 @@ def test_flops_per_token_counts_the_dense_layer_the_gate_and_the_shared():
 # pinned again, on purpose, to PR 36's own lowering (parent d6ba3a1 reads
 # olmoe 76ffe05e..., mellum2 856fc992...): that PR changed what every
 # dropless router traces (``_count_ids`` for both ``jnp.bincount``s, the
-# masked sum for ``lax.top_k``'s values).  The point stands: a later
+# masked sum for ``lax.top_k``'s values); and to PR 39's (parent 0f53bdd
+# reads olmoe a4404718..., mellum2 168196c5...), which changed what every
+# chunked head traces (``_fused_ce``: ``dh`` and ``dW`` made in the forward
+# rule).  The point stands: a later
 # model_config PR's new branches must not reach the older cells' programs
 PARENT_STABLEHLO = {
-    "olmoe": "a440471807c587a8efc8135359f924f1f107c341395aea3eb641ee1b85df7eca",
-    "mellum2": "168196c593e7cfe3a97b2406d31ee6fd178f9d065adf3ab82f8dd5baeaab1f53",
+    "olmoe": "46a9075039aef6b65dcb1ed43409f3824c7d90a410b27b7f651504d6e3b5b44c",
+    "mellum2": "68de2c369a27798f5bdafa062805e3327b58755c5d6834175a20ef395bd9971d",
 }
 
 

@@ -521,3 +521,67 @@ def test_q_and_k_stay_rows_from_projection_to_flash(topo, one_chip,
                 if v[1] in ("copy", "slice", "concatenate")
                 and four_d.search(v[0])]
     assert not re.search(rf"bf16\[{rows},{seq},({heads}|{kv}),64\]", text)
+
+
+@pytest.mark.parametrize("chunk", [128, 8192])
+@pytest.mark.parametrize("family", ["llama", "gpt2"])
+def test_the_head_multiplies_its_logits_out_once(one_chip, family, chunk):
+    """Loss and gradient of a 2-layer model whose chunked head runs over
+    2 x 256 tokens in four chunks of 128, or in one (a scan of one trip is
+    inlined, and XLA's scheduler is then free to put ``dW`` off and make
+    the logits a second time for it), compiled for one described chip: the
+    optimized HLO holds three matrix products under ``loss_head`` — the
+    logits, ``dh`` and ``dW`` — all in the forward rule's scope and none in
+    the backward's (before PR 39: one and three), the eval step holds the
+    one of the logits, and ``lm_head_products_total`` says the same of the
+    rules as they are traced."""
+    import re
+
+    import flax
+
+    from deepspeed_tpu.telemetry import registry
+
+    if family == "llama":
+        from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=1000, hidden_size=256, intermediate_size=512,
+            num_hidden_layers=2, num_attention_heads=2,
+            num_key_value_heads=2, max_position_embeddings=256,
+            loss_chunk=chunk))
+    else:
+        from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+        model = GPT2LMHeadModel(gpt2_config(
+            "gpt2-tiny", n_layer=2, n_positions=256, scan_layers=False,
+            loss_chunk=chunk))
+    ids = jnp.zeros((2, 256), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        flax.core.meta.unbox(jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), ids))))
+    ids = jax.ShapeDtypeStruct(ids.shape, ids.dtype, sharding=one_chip)
+
+    def loss(params, ids):
+        return model.apply(params, ids, labels=ids)["loss"]
+
+    counter = registry.counter("lm_head_products_total", labelnames=("pass",))
+
+    def products():
+        return {p: counter.labels(p).value
+                for p in ("primal", "forward", "backward")}
+
+    def head_dots(fn):
+        before = products()
+        text = jax.jit(fn).lower(params, ids).compile().as_text()
+        traced = {k: v - before[k] for k, v in products().items()}
+        return traced, re.findall(
+            r' (?:convolution|dot)\(.*op_name="([^"]*loss_head[^"]*)"', text)
+
+    traced, dots = head_dots(jax.value_and_grad(loss))
+    assert len(dots) == 3, dots
+    assert not [d for d in dots if "transpose(" in d], dots
+    assert traced == {"primal": 0, "forward": 3, "backward": 0}
+    traced, dots = head_dots(loss)
+    assert len(dots) == 1, dots
+    assert traced == {"primal": 1, "forward": 0, "backward": 0}
