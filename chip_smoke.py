@@ -675,6 +675,78 @@ def kernel_flash_mla():
         assert worst.max() <= TOL, (name, n, worst)
 
 
+def kernel_flash_blockdiff():
+    """The sixth cell's attention at the cell's own shape
+    (``train-sdar-blockdiff-8k-1chip``: 2 rows of [noisy ; clean] = 16,384
+    positions, 32 query heads on 4 key-value heads of 128, blocks of 4):
+    ``ops/attention.py block_diffusion_attention`` (the clean half under
+    the block-granular diagonal, the noisy half against the clean keys
+    under its strict form merged with its own block's XLA term), forward
+    and the gradients of q, k and v, against the dense float32 mask
+    computed in query blocks, a row at a time.  Each (row, key-value head,
+    half) slice of dk and dv is also checked on its own: a key-value
+    head's sums over its eight query heads and over two kernels' calls
+    (the clean keys serve both halves) live in scratch that the next row
+    must not inherit.  The bound and every reading are printed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.attention import (block_diffusion_attention,
+                                             block_diffusion_mask)
+
+    B, L, H, KV, D, G, QB = 2, 8192, 32, 4, 128, 4, 256
+    S = 2 * L
+    ks = jax.random.split(jax.random.PRNGKey(40), 4)
+    shapes = ((B, S, H, D), (B, S, KV, D), (B, S, KV, D), (B, S, H, D))
+    q, k, v, ct = (jax.random.normal(kk, s, jnp.float32).astype(jnp.bfloat16)
+                   for kk, s in zip(ks, shapes))
+    keep = block_diffusion_mask(L, G).reshape(S // QB, QB, S)
+
+    def ref(q, k, v):                       # one row: (1, S, ., D)
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+        kk, vv = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
+
+        @jax.checkpoint
+        def block(args):
+            q_blk, rows = args                              # (1, QB, H, D)
+            s = jnp.einsum("bqhd,bthd->bhqt", q_blk, kk) * D ** -0.5
+            p = jax.nn.softmax(jnp.where(rows[None, None], s, -jnp.inf), -1)
+            return jnp.einsum("bhqt,bthd->bqhd", p, vv)
+
+        out = jax.lax.map(block, (
+            q.reshape(1, S // QB, QB, H, D).transpose(1, 0, 2, 3, 4), keep))
+        return out.transpose(1, 0, 2, 3, 4).reshape(1, S, H, D)
+
+    def both(fn):
+        def run(q, k, v, ct):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(ct)
+        return jax.jit(run)
+
+    got = both(lambda q, k, v: block_diffusion_attention(
+        q, k, v, block=G, impl="flash"))(q, k, v, ct)
+    with jax.default_matmul_precision("highest"):
+        one = both(ref)
+        rows = [one(q[r:r + 1], k[r:r + 1], v[r:r + 1],
+                    ct[r:r + 1].astype(jnp.float32)) for r in range(B)]
+    want = [jnp.concatenate(parts) for parts in zip(*rows)]
+    name = f"flash blockdiff ({B},2x{L},{H}/{KV},{D}) g={G}"
+    for n, g, gr in zip(("fwd", "dq", "dk", "dv"), got, want):
+        for half, lo in (("noisy", 0), ("clean", L)):
+            _check_close(f"{name} {n} {half}", g[:, lo:lo + L],
+                         gr[:, lo:lo + L])
+        if n not in ("dk", "dv"):
+            continue
+        g, gr = (np.asarray(t, np.float32).reshape(B, 2, L, KV, D)
+                 for t in (g, gr))
+        worst = (np.abs(g - gr).max(axis=(2, 4))
+                 / np.abs(gr).max(axis=(2, 4)))         # (B, half, KV)
+        print(f"  {name} {n}: worst of {worst.size} (row, half, key-value "
+              f"head) slices {worst.max():.2e} (tol {TOL:.0e})", flush=True)
+        assert np.isfinite(g).all() and worst.max() <= TOL, (name, n, worst)
+
+
 def kernel_qk_rows():
     """q and k as ``(B, S, H*D)`` rows through ``ops/pallas/qk_rows.py`` at
     the third and fourth cells' shapes (4 and 3 rows of 8192, 32 / 4 heads
@@ -749,7 +821,7 @@ def kernel_qk_rows():
 
 
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_flash_mla,
-                kernel_qk_rows,
+                kernel_flash_blockdiff, kernel_qk_rows,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

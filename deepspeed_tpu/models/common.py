@@ -467,8 +467,12 @@ def cross_entropy_loss(
     labels: jax.Array,           # (...,) int
     ignore_index: int = -100,
     z_loss: float = 0.0,
+    weights: Optional[jax.Array] = None,    # (...,) float, no gradient
+    denominator=None,
 ) -> jax.Array:
-    """Mean token cross-entropy with ignore-index masking, fp32 softmax."""
+    """Mean token cross-entropy with ignore-index masking, fp32 softmax;
+    with ``weights`` and ``denominator``, ``sum_i w_i nll_i / denominator``
+    (:func:`chunked_lm_loss`'s)."""
     logits = logits.astype(jnp.float32)
     valid = labels != ignore_index
     safe_labels = jnp.where(valid, labels, 0)
@@ -479,6 +483,10 @@ def cross_entropy_loss(
     if z_loss > 0.0:
         nll = nll + z_loss * jnp.square(logz)
     nll = jnp.where(valid, nll, 0.0)
+    if weights is not None:
+        nll = nll * jax.lax.stop_gradient(weights.astype(jnp.float32))
+    if denominator is not None:
+        return nll.sum() / denominator
     count = jnp.maximum(valid.sum(), 1)
     return nll.sum() / count
 
@@ -496,7 +504,7 @@ def _note_head_products(pass_: str, n: int) -> None:
 
 @functools.lru_cache(maxsize=None)
 def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
-              h_dtype, w_dtype):
+              h_dtype, w_dtype, weighted: bool = False):
     """Build the custom-vjp chunked cross-entropy core (cached per config
     and the operands' types, which the backward rule rounds to).
 
@@ -514,12 +522,19 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
     loss between two calls keeps those two (the engine's ``forward`` /
     ``backward`` / ``step`` path runs loss and gradient in one program),
     and on a mesh the backward reads no weight.
+
+    ``weighted``: a fourth operand, one float32 weight a token (chunked as
+    the labels are), multiplies each token's term of the sum and of the
+    cotangents (block diffusion's ``1 / t`` on the masked positions, 0
+    elsewhere); it has no gradient.  Without it the rule is traced as it
+    was: no operand, no multiply.
     """
     Vp = padded_vocab_size
 
-    def _chunk(hc, wteT, tc):
+    def _chunk(hc, wteT, tc, wc=None):
         """(C, E) × (E, Vp) → fp32 logits (padded vocab columns out of the
-        softmax), per-token logz, the valid mask, safe labels, nll."""
+        softmax), per-token logz, each token's factor on its cotangent
+        (the valid mask, times its weight), safe labels, nll."""
         logits = jnp.dot(hc, wteT, preferred_element_type=jnp.float32)
         if Vp != vocab_size:
             logits = jnp.where(jnp.arange(Vp) < vocab_size, logits,
@@ -528,24 +543,27 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
         safe = jnp.where(valid, tc, 0)
         logz = jax.nn.logsumexp(logits, axis=-1)
         lbl = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        return logits, logz, valid, safe, jnp.where(valid, logz - lbl, 0.0)
+        nll = jnp.where(valid, logz - lbl, 0.0)
+        if wc is not None:
+            valid, nll = valid * wc, nll * wc
+        return logits, logz, valid, safe, nll
 
     @jax.custom_vjp
-    def ce(hf, wteT, tf):
+    def ce(hf, wteT, tf, *wf):
         _note_head_products("primal", 1)
 
         def body(acc, xs):
-            return acc + _chunk(xs[0], wteT, xs[1])[-1].sum(), None
+            return acc + _chunk(xs[0], wteT, *xs[1:])[-1].sum(), None
 
-        return jax.lax.scan(body, jnp.float32(0.0), (hf, tf))[0]
+        return jax.lax.scan(body, jnp.float32(0.0), (hf, tf) + wf)[0]
 
-    def ce_fwd(hf, wteT, tf):
+    def ce_fwd(hf, wteT, tf, *wf):
         _note_head_products("forward", 3)
 
         def body(carry, xs):
             acc, dwteT = carry
-            hc, tc = xs
-            logits, logz, valid, safe, nll = _chunk(hc, wteT, tc)
+            hc, tc = xs[:2]
+            logits, logz, valid, safe, nll = _chunk(hc, wteT, tc, *xs[2:])
             p = jnp.exp(logits - logz[:, None])              # softmax rows
             onehot = (jnp.arange(Vp)[None, :] == safe[:, None])
             dlog = (p - onehot) * valid[:, None]             # (C, Vp) fp32
@@ -560,7 +578,7 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
 
         (nll_sum, dwteT), dhs = jax.lax.scan(
             body, (jnp.float32(0.0), jnp.zeros(wteT.shape, jnp.float32)),
-            (hf, tf))
+            (hf, tf) + wf)
         return nll_sum, (dhs, dwteT)
 
     def ce_bwd(res, g):
@@ -573,7 +591,10 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
         # keep them through the layers' backward
         dh, dw = jax.lax.optimization_barrier(
             ((g * dhs).astype(h_dtype), (g * dwteT).astype(w_dtype)))
-        return dh, dw, np.zeros(dhs.shape[:2], jax.dtypes.float0)
+        no_grad = (np.zeros(dhs.shape[:2], jax.dtypes.float0),)
+        if weighted:            # the weights are data: no gradient
+            no_grad += (jnp.zeros(dhs.shape[:2], jnp.float32),)
+        return (dh, dw) + no_grad
 
     ce.defvjp(ce_fwd, ce_bwd)
     return ce
@@ -581,7 +602,9 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
 
 def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
                     vocab_size: int, padded_vocab_size: int, chunk: int,
-                    dtype, ignore_index: int = -100) -> jax.Array:
+                    dtype, ignore_index: int = -100,
+                    weights: Optional[jax.Array] = None,
+                    denominator=None) -> jax.Array:
     """Tied-head cross-entropy WITHOUT materializing the (B, S, V) fp32
     logits or their cotangent, and with the logits multiplied out once a
     step (see :func:`_fused_ce`: the forward makes ``dh`` and ``dW``, the
@@ -590,7 +613,13 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     which keeps the single big MXU matmul but still skips the O(N·V) fp32
     residency (the round-2 ``lax.map`` version serialized 512-row matmuls
     and LOST 17% e2e — this one is measurement-driven: big chunks, custom
-    vjp, no per-chunk remat)."""
+    vjp, no per-chunk remat).
+
+    ``weights`` ``(B, S)`` multiplies each token's negative log-likelihood
+    (float32; no gradient flows to it) and ``denominator`` divides the sum
+    in place of the count of labelled tokens: ``sum_i w_i nll_i /
+    denominator``.  Weight 1 everywhere and the count as denominator is
+    the unweighted value bit for bit."""
     B, S, E = h.shape
     N = B * S
     chunk = min(chunk, N)
@@ -604,9 +633,19 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     hf = hf.reshape(-1, chunk, E)
     tf = tf.reshape(-1, chunk)
     wteT = wte.astype(dtype).T        # (E, V)
-    ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
-                   hf.dtype, wteT.dtype)
-    nll_sum = ce(hf, wteT, tf)
+    if weights is None:
+        ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
+                       hf.dtype, wteT.dtype)
+        nll_sum = ce(hf, wteT, tf)
+    else:
+        wf = jax.lax.stop_gradient(weights.astype(jnp.float32)).reshape(N)
+        if pad:
+            wf = jnp.concatenate([wf, jnp.zeros((pad,), wf.dtype)])
+        ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
+                       hf.dtype, wteT.dtype, True)
+        nll_sum = ce(hf, wteT, tf, wf.reshape(-1, chunk))
+    if denominator is not None:
+        return nll_sum / denominator
     count = (tf != ignore_index).sum()
     return nll_sum / jnp.maximum(count, 1)
 

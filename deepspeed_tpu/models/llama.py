@@ -46,6 +46,18 @@ token's embedding ; the stack's output], one more whole block and a norm
 predict the token two ahead through the model's own table and head; the
 loss is ``lm_loss + mtp_loss_weight * mtp_loss`` (:class:`MTPModule`,
 arXiv:2412.19437 section 2.2).
+
+SDAR (JetLM, 2025; ``model_type: sdar_moe``, arXiv:2510.06303) is the
+sparse block with ``qk_norm="head"`` under another OBJECTIVE: block
+diffusion (arXiv:2503.09573), the ``diffusion`` section
+(:class:`BlockDiffusionConfig`).  With it and ``labels`` the model draws a
+noise level a block of ``block_length`` tokens, replaces each token by the
+mask id with that probability, runs the stack ONCE over ``[noisy ; clean]``
+(``2L`` positions, both halves at positions ``0 .. L-1``) under the mask
+of ``ops/attention.py block_diffusion_mask``, and takes a cross-entropy
+weighted by ``1 / t`` from the masked positions of the noisy half, read at
+their own position (no shift); the clean half has no loss and exists to
+give keys and values.
 """
 from __future__ import annotations
 
@@ -67,6 +79,21 @@ SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
 # the widths latent attention takes together (their config.json names)
 _MLA_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionConfig:
+    """The objective of block-diffusion training (arXiv:2503.09573, which
+    the SDAR family adapts an autoregressive model to).  A row of L tokens
+    is cut into blocks of ``block_length``; a block draws ``t ~ U(t_min,
+    1]`` and each of its tokens becomes ``mask_token_id`` with probability
+    ``t``; the loss is ``sum_i 1[masked_i] w(t_b(i)) nll_i / (B L)`` with
+    ``w = 1 / t`` (``loss_weight="inv_t"``, the linear schedule's) or 1
+    (``"one"``)."""
+    block_length: int = 4
+    mask_token_id: int = 0
+    t_min: float = 1e-3
+    loss_weight: str = "inv_t"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,6 +189,10 @@ class LlamaConfig:
     # config has no key for it)
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
+    # block-diffusion training: a BlockDiffusionConfig, or a dict of its
+    # fields (a configuration file's section); None: next-token training,
+    # and nothing of the section is traced
+    diffusion: Optional[Any] = None
     # > 0 with labels: chunked cross-entropy head, logits never materialize
     # (common.chunked_lm_loss); the output then carries no ``logits``
     loss_chunk: int = 0
@@ -221,6 +252,36 @@ class LlamaConfig:
                 f"num_nextn_predict_layers "
                 f"{self.num_nextn_predict_layers}: one multi-token-"
                 f"prediction block is written")
+        if isinstance(self.diffusion, dict):
+            object.__setattr__(self, "diffusion",
+                               BlockDiffusionConfig(**self.diffusion))
+        if self.diffusion is not None:
+            dif = self.diffusion
+            if dif.block_length < 1 or 128 % dif.block_length:
+                raise ValueError(
+                    f"diffusion.block_length {dif.block_length} does not "
+                    f"divide 128, the tile the attention schedule is cut in")
+            if not 0 <= dif.mask_token_id < self.vocab_size:
+                raise ValueError(
+                    f"diffusion.mask_token_id {dif.mask_token_id} is no id "
+                    f"of a vocabulary of {self.vocab_size}")
+            if not 0.0 < dif.t_min <= 1.0 \
+                    or dif.loss_weight not in ("inv_t", "one"):
+                raise ValueError(
+                    f"diffusion: t_min in (0, 1] and loss_weight 'inv_t' or "
+                    f"'one', got {dif.t_min!r} and {dif.loss_weight!r}")
+            if self.decode:
+                raise NotImplementedError(
+                    "decode=True with diffusion (block-diffusion training): "
+                    "generation by denoising a block is not written; the "
+                    "cache and the batcher yield one token a sequence a step")
+            if self.mla_fields or self.num_nextn_predict_layers \
+                    or SLIDING in self.kinds:
+                raise NotImplementedError(
+                    "diffusion (block-diffusion training) with latent "
+                    "attention, a multi-token-prediction block or a sliding "
+                    "window: the block mask is written for full grouped-"
+                    "query attention alone")
         if self.decode and (self.mla_fields
                             or self.num_nextn_predict_layers):
             raise NotImplementedError(
@@ -363,6 +424,9 @@ class RMSNorm(nn.Module):
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
     kind: Optional[str] = None      # the layer's type; None: full, one table
+    # the rows are [noisy ; clean] of block-diffusion training: attention
+    # under ops/attention.py block_diffusion_mask
+    blockdiff: bool = False
 
     def _cache_append(self, k, v):
         from .common import append_kv_cache
@@ -480,10 +544,19 @@ class LlamaAttention(nn.Module):
         # a layer type's kernels in the device trace
         window = cfg.window(self.kind)
         scope = "self_attn_window" if window else "self_attn_full"
-        with trace.device_span(scope) if self.kind is not None \
-                else contextlib.nullcontext():
-            y = dot_product_attention(q, k, v, causal=True, mask=attn_mask,
-                                      window=window, impl=cfg.attn_impl)
+        if self.blockdiff:
+            from ..ops.attention import block_diffusion_attention
+
+            with trace.device_span("self_attn_blockdiff"):
+                y = block_diffusion_attention(
+                    q, k, v, block=cfg.diffusion.block_length,
+                    impl=cfg.attn_impl)
+        else:
+            with trace.device_span(scope) if self.kind is not None \
+                    else contextlib.nullcontext():
+                y = dot_product_attention(q, k, v, causal=True,
+                                          mask=attn_mask, window=window,
+                                          impl=cfg.attn_impl)
         y = y.reshape(B, S, H * D)
         if cfg.attn_gate:
             # elementwise in the kernels' own (B, S, H*D) layout: XLA may
@@ -561,6 +634,7 @@ class LlamaBlock(nn.Module):
     deterministic: bool = True
     kind: Optional[str] = None      # this layer's entry of cfg.layer_types
     sparse: bool = True             # False: dense FFN although cfg.moe is set
+    blockdiff: bool = False         # LlamaAttention's
 
     def _dense_ffn(self, h):
         cfg = self.cfg
@@ -607,7 +681,8 @@ class LlamaBlock(nn.Module):
                 return x, None
         self_attn = LlamaLatentAttention(cfg, name="self_attn") \
             if cfg.kv_lora_rank \
-            else LlamaAttention(cfg, self.kind, name="self_attn")
+            else LlamaAttention(cfg, self.kind, self.blockdiff,
+                                name="self_attn")
         attn = self_attn(RMSNorm(cfg, name="input_norm")(x), position_ids,
                          attn_mask)
         if cfg.sandwich_norm:
@@ -663,14 +738,78 @@ class MTPModule(nn.Module):
 class LlamaForCausalLM(nn.Module):
     cfg: LlamaConfig
 
+    @property
+    def rng_streams(self) -> tuple:
+        """The random streams the engine folds for this model beside its
+        own three (``runtime/engine.py _loss_and_stats``)."""
+        return ("diffusion",) if self.cfg.diffusion is not None else ()
+
+    def _diffusion_noise(self, input_ids, diffusion_mask, diffusion_t):
+        """``(masked (B, L) bool, t (B, L / block_length))``: as given, or
+        drawn from the ``diffusion`` stream (the engine folds it from the
+        step and the micro-batch, beside ``dropout`` and ``gating``)."""
+        dif = self.cfg.diffusion
+        B, L = input_ids.shape
+        g = dif.block_length
+        if L % g:
+            raise ValueError(f"rows of {L} tokens are no whole blocks of {g}")
+        if (diffusion_mask is None) != (diffusion_t is None):
+            raise ValueError(
+                "diffusion_mask (B, L) and diffusion_t (B, L / "
+                "block_length) come together: the weight reads the levels")
+        if diffusion_mask is not None:
+            return diffusion_mask.astype(bool), diffusion_t.astype(jnp.float32)
+        if self.has_rng("diffusion"):
+            key = self.make_rng("diffusion")
+        elif self.is_initializing():    # shapes alone: any noise will do
+            key = jax.random.PRNGKey(0)
+        else:
+            raise ValueError(
+                "block-diffusion training draws its noise from the "
+                "'diffusion' random stream, which only a training step has: "
+                "give diffusion_mask and diffusion_t in the batch instead")
+        key_t, key_mask = jax.random.split(key)
+        t = 1.0 - jax.random.uniform(key_t, (B, L // g)) * (1.0 - dif.t_min)
+        masked = jax.random.uniform(key_mask, (B, L)) \
+            < jnp.repeat(t, g, axis=1)
+        return masked, t
+
     @nn.compact
     def __call__(self, input_ids, attention_mask=None, position_ids=None,
-                 labels=None, deterministic: bool = True, shift: bool = True):
+                 labels=None, deterministic: bool = True, shift: bool = True,
+                 diffusion_mask=None, diffusion_t=None):
+        """With ``cfg.diffusion``: ``labels`` (the clean tokens; -100 takes
+        a position out of the loss) makes the training loss over the noisy
+        half under drawn or given noise; ``diffusion_mask`` and
+        ``diffusion_t`` without labels return the logits of both halves,
+        ``(B, 2L, V)``, noisy first."""
         cfg = self.cfg
         B, S = input_ids.shape
         embed = self.param("embed_tokens", nn.with_partitioning(
             nn.initializers.normal(cfg.initializer_range), ("vocab", "embed")),
             (cfg.padded_vocab_size, cfg.hidden_size), cfg.param_dtype)
+        dif = cfg.diffusion
+        if dif is not None:
+            if labels is None and diffusion_mask is None:
+                raise NotImplementedError(
+                    "a model with the diffusion section and neither labels "
+                    "nor diffusion_mask: a plain forward over a clean "
+                    "context (generation by denoising a block) is not "
+                    "written")
+            if attention_mask is not None or position_ids is not None:
+                raise NotImplementedError(
+                    "block-diffusion training with an attention_mask or "
+                    "position_ids of the caller's: the two halves' "
+                    "positions and mask are the objective's own")
+            with trace.device_span("diffusion/noise"):
+                masked, t = self._diffusion_noise(input_ids, diffusion_mask,
+                                                  diffusion_t)
+            with trace.device_span("diffusion/halves"):
+                input_ids = jnp.concatenate(
+                    [jnp.where(masked, dif.mask_token_id, input_ids),
+                     input_ids], axis=1)                    # [noisy ; clean]
+                position_ids = jnp.concatenate(
+                    [jnp.arange(S), jnp.arange(S)])[None, :]
         if position_ids is None:
             if cfg.decode:
                 raise ValueError("decode mode requires explicit position_ids")
@@ -703,14 +842,16 @@ class LlamaForCausalLM(nn.Module):
                             in_axes=nn.broadcast,
                             metadata_params={nn.meta.PARTITION_NAME: "layers"})
             h, per_layer = stack(cfg, deterministic, *kinds[:1],
-                                 name="layers")(h, (position_ids, mask))
+                                 name="layers", blockdiff=dif is not None)(
+                h, (position_ids, mask))
         else:
             per_layer = []
             for i in range(cfg.num_hidden_layers):
                 dense = {} if cfg.sparse(i) or cfg.moe is None \
                     else {"sparse": False}
                 h, ys = block_cls(cfg, deterministic, *kinds[i:i + 1],
-                                  name=f"layers_{i}", **dense)(
+                                  name=f"layers_{i}", **dense,
+                                  blockdiff=dif is not None)(
                     h, (position_ids, mask))
                 per_layer.append(ys)
             if cfg.moe is not None:     # stacked over the MoE layers alone
@@ -749,6 +890,22 @@ class LlamaForCausalLM(nn.Module):
             aux_loss = out["aux_loss"] = stats.pop("aux_loss").mean()
             out["stats"] = stats
 
+        weighted = {}
+        if dif is not None and labels is not None:
+            # the head reads the noisy half alone; the clean half has
+            # passed every layer held, as in an inner stage of a pipeline
+            with trace.device_span("diffusion/halves"):
+                h = h[:, :S]
+                weight = (masked & (labels != -100)).astype(jnp.float32)
+                if dif.loss_weight == "inv_t":
+                    weight = weight / jnp.repeat(t, dif.block_length, axis=1)
+            weighted = {"weights": weight, "denominator": float(B * S)}
+            shift = False       # read at the noisy half's own position
+            out["stats"] = dict(
+                out.get("stats") or {},
+                diffusion_masked=masked.sum().astype(jnp.int32),
+                diffusion_kept=(~masked).sum().astype(jnp.int32),
+                diffusion_t_mean=t.mean())
         h = RMSNorm(cfg, name="norm")(h)
         lm_head = self.param("lm_head", nn.with_partitioning(
             nn.initializers.normal(cfg.initializer_range), ("embed", "vocab")),
@@ -763,7 +920,7 @@ class LlamaForCausalLM(nn.Module):
                 loss = chunked_lm_loss(
                     h, lm_head.T, tgt, vocab_size=cfg.vocab_size,
                     padded_vocab_size=cfg.padded_vocab_size,
-                    chunk=cfg.loss_chunk, dtype=cfg.dtype)
+                    chunk=cfg.loss_chunk, dtype=cfg.dtype, **weighted)
             if h_mtp is not None:
                 # the same head, on labels one further ahead: positions
                 # without a label two ahead are left out of the mean
@@ -784,7 +941,8 @@ class LlamaForCausalLM(nn.Module):
 
             with trace.device_span("loss_head"):
                 logits = out["logits"] = head(h)
-                loss = None if tgt is None else cross_entropy_loss(logits, tgt)
+                loss = None if tgt is None \
+                    else cross_entropy_loss(logits, tgt, **weighted)
             if h_mtp is not None and tgt is not None:
                 with trace.device_span("mtp/loss_head"):
                     mtp_loss = cross_entropy_loss(head(h_mtp),
@@ -813,6 +971,20 @@ class LlamaForCausalLM(nn.Module):
                 "block (depth d predicts the token d + 1 ahead), last "
                 "finished step", ("depth",)).labels("1").set(
                 float(stats["mtp_loss"]))
+        if "diffusion_masked" in stats:
+            from ..telemetry import registry
+
+            tokens = registry.counter(
+                "diffusion_tokens_total", "data tokens of block-diffusion "
+                "training by what the step's noise made of them: masked "
+                "(replaced by the mask id; the loss reads these) or kept",
+                ("kind",))
+            tokens.labels("masked").inc(float(stats["diffusion_masked"]))
+            tokens.labels("kept").inc(float(stats["diffusion_kept"]))
+            registry.gauge(
+                "diffusion_t_mean", "mean noise level drawn over the "
+                "blocks of the last finished step").set(
+                float(stats["diffusion_t_mean"]))
         if "tokens_per_expert" in stats:
             from ..parallel.moe import record_stats
 

@@ -151,6 +151,139 @@ def dot_product_attention(
                           scale=scale, window=window)
 
 
+def block_diffusion_mask(length: int, block: int):
+    """The ``(2L, 2L)`` boolean mask of block-diffusion training
+    (arXiv:2503.09573) over the positions ``[noisy ; clean]`` of one row,
+    True = attend, with ``b(i) = i // block``: a noisy query keeps the
+    noisy keys of its own block and the clean keys of earlier blocks; a
+    clean query keeps the clean keys of its own and earlier blocks and no
+    noisy key."""
+    b = jnp.arange(length) // block
+    qb, kb = b[:, None], b[None, :]
+    none = jnp.zeros((length, length), bool)
+    return jnp.block([[qb == kb, qb > kb], [none, qb >= kb]])
+
+
+def _own_block_scores(qn, kn, block: int, scale: float):
+    """Each noisy query against the ``block`` noisy keys of its own block:
+    ``(B, L, H, block)`` float32 (grouped queries read their key-value
+    head); ``L x block`` scores a head, plain XLA."""
+    B, L, H, D = qn.shape
+    KV = kn.shape[2]
+    n = L // block
+    s = jnp.einsum("bnakgd,bnckd->bnakgc",
+                   qn.reshape(B, n, block, KV, H // KV, D),
+                   kn.reshape(B, n, block, KV, D),
+                   preferred_element_type=jnp.float32)
+    return s.reshape(B, L, H, block) * scale
+
+
+def _own_block_values(p, vn, block: int):
+    """``p`` (B, L, H, block) float32 over each query's own block of
+    values: ``(B, L, H, D)`` float32."""
+    B, L, H, _ = p.shape
+    KV, D = vn.shape[2:]
+    n = L // block
+    o = jnp.einsum("bnakgc,bnckd->bnakgd",
+                   p.reshape(B, n, block, KV, H // KV, block),
+                   vn.reshape(B, n, block, KV, D).astype(jnp.float32),
+                   precision="highest")
+    return o.reshape(B, L, H, D)
+
+
+def _block_diffusion_flash(q, k, v, *, block, scale, interpret=False):
+    """The two halves on the flash kernels.  Clean half: one call under
+    the block-granular diagonal.  Noisy half: one call against the CLEAN
+    keys under its strict form, merged through its log-sum-exp with the
+    own block's ``L x block`` scores a head, which are XLA's."""
+    from .pallas.flash_attention import (flash_attention,
+                                         flash_attention_with_lse,
+                                         grouped_in_kernel)
+
+    L = q.shape[1] // 2
+    if not grouped_in_kernel(q.shape[3]):
+        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
+    (qn, qc), (kn, kc), (vn, vc) = ((t[:, :L], t[:, L:]) for t in (q, k, v))
+    clean = flash_attention(qc, kc, vc, causal=True, scale=scale,
+                            interpret=interpret, block=block)
+    past, lse = flash_attention_with_lse(qn, kc, vc, causal=True, scale=scale,
+                                         interpret=interpret, block=block,
+                                         strict=True)
+    # the first block has no earlier one: its rows kept no clean key
+    lse = jnp.where((jnp.arange(L) < block)[None, :, None],
+                    jnp.finfo(jnp.float32).min, lse)
+    s = _own_block_scores(qn, kn, block, scale)
+    m = jnp.maximum(lse, s.max(axis=-1))
+    w_past = jnp.exp(lse - m)[..., None]
+    p = jnp.exp(s - m[..., None])
+    noisy = (w_past * past.astype(jnp.float32)
+             + _own_block_values(p, vn, block)) \
+        / (w_past + p.sum(axis=-1, keepdims=True))
+    return jnp.concatenate([noisy.astype(q.dtype), clean], axis=1)
+
+
+def block_diffusion_attention(q, k, v, *, block: int,
+                              scale: Optional[float] = None,
+                              impl: str = "auto",
+                              interpret: bool = False) -> jax.Array:
+    """Attention of block-diffusion training over ``[noisy ; clean]``:
+    ``q`` ``(B, 2L, H, D)``, ``k`` and ``v`` ``(B, 2L, KV, D)``, the mask
+    :func:`block_diffusion_mask`; returns ``(B, 2L, H, D)``.
+
+    ``"flash"`` (``"auto"`` on a TPU where the shapes tile) runs the two
+    halves on the flash kernels (:func:`_block_diffusion_flash`): no
+    ``2L x 2L`` score exists, grouped queries stay at their key-value
+    heads in the kernel, void tiles run no code and full tiles build no
+    mask.  ``"jnp"`` (the CPU, tests) applies the dense mask to XLA
+    scores.  Heads over ``tp`` and sequence-parallel forms are not
+    written: ``tp`` takes the XLA path, ``ring`` / ``ulysses`` raise."""
+    from .pallas.flash_attention import _diag, flash_lanes
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    B, S2, H, D = q.shape
+    if S2 % 2 or (S2 // 2) % block:
+        raise ValueError(f"{S2} positions are not two halves of whole "
+                         f"blocks of {block}")
+    if H % k.shape[2]:
+        raise ValueError(f"{H} query heads are no multiple of "
+                         f"{k.shape[2]} key-value heads")
+    if impl not in ("auto", "flash", "jnp"):
+        raise NotImplementedError(
+            f"impl={impl!r} with the block-diffusion mask: 'auto', 'flash' "
+            f"and 'jnp' are written (no sequence-parallel form)")
+    _diag(block, False)         # a divisor of 128, for every path alike
+    if scale is None:
+        scale = D ** -0.5
+    L = S2 // 2
+    how = f"impl={impl!r} requested"
+    if impl == "auto":
+        impl, how = _pick_impl(impl, q[:, :L])
+    if impl == "flash":
+        verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=False)
+        if verdict is not None:
+            plan = "one device" if verdict == "direct" \
+                else f"shard_map over batch axes {axes}"
+            group = H // k.shape[2]
+            note_dispatch(
+                "attention", "flash",
+                f"{how}; {plan}; {flash_lanes(H, D).reason}; block diffusion "
+                f"over [noisy ; clean], block length {block}"
+                + (f"; {group} query heads a key-value head" if group > 1
+                   else ""))
+            kern = functools.partial(_block_diffusion_flash, block=block,
+                                     scale=scale, interpret=interpret)
+            if verdict == "direct":
+                return kern(q, k, v)
+            return _shard_over_batch(kern, axes, 3)(q, k, v)
+        how = "kernel_mesh_plan refused the mesh"
+    note_dispatch("attention", "jnp",
+                  f"{how}; block diffusion over [noisy ; clean], block "
+                  f"length {block}, dense mask")
+    return _jnp_attention(q, k, v, causal=False, bias=None,
+                          mask=block_diffusion_mask(L, block)[None, None],
+                          dropout_rate=0.0, dropout_rng=None, scale=scale)
+
+
 def _two_product_attention(q, k, v, q_rope, k_rope, *, causal, scale, impl,
                            mask=None, interpret=False):
     """Latent attention's dispatch: the two-product flash kernels

@@ -22,7 +22,9 @@ Both kernels follow one tile schedule (:func:`score_tile_schedule`): a
 score tile wholly above the diagonal, or wholly past a sliding ``window``
 below it, runs no code, one wholly inside the band builds no mask, and one
 that the diagonal or the band's lower edge crosses is masked (the backward
-walks it in half-edge sub-tiles, each classed the same way).
+walks it in half-edge sub-tiles, each classed the same way).  The diagonal
+may be block-granular (``block``: block diffusion's masks, ``q_pos // g >=
+k_pos // g`` or its strict form ``>``): the same schedule in units of blocks.
 
 Grouped queries (``k`` and ``v`` narrower than ``q``, head_dim a multiple
 of 128): query lane block ``c`` reads key-value lane block ``c // group``,
@@ -54,7 +56,10 @@ UNROLL_MAX = 4          # static-unroll K/Q sweeps at or below this length
 VOID, FULL, DIAGONAL = "void", "full", "diagonal"
 # the band's lower edge crosses the tile; both edges do (window < a tile)
 BAND_EDGE, CROSSED = "band_edge", "diagonal+band_edge"
-MASKED = (DIAGONAL, BAND_EDGE, CROSSED)
+# a block-granular diagonal crosses the tile (``block``: q_pos // g against
+# k_pos // g)
+BLOCK_DIAGONAL = "block_diagonal"
+MASKED = (DIAGONAL, BAND_EDGE, CROSSED, BLOCK_DIAGONAL)
 
 
 # ---------------------------------------------------------------------------
@@ -62,12 +67,21 @@ MASKED = (DIAGONAL, BAND_EDGE, CROSSED)
 # ---------------------------------------------------------------------------
 
 def _tile_kind(d: int, rows: int, cols: int, causal: bool,
-               window: Optional[int] = None) -> str:
+               window: Optional[int] = None, diag: Optional[tuple] = None
+               ) -> str:
     """Class of the rows×cols score tile whose first query position lies
     ``d`` after its first key position, by the entries it keeps: those
     with ``0 <= q_pos - k_pos`` (causal) ``< window`` (a sliding window).
     VOID keeps none, FULL all; DIAGONAL loses some above the diagonal,
-    BAND_EDGE some past the window, CROSSED some of both."""
+    BAND_EDGE some past the window, CROSSED some of both.  ``diag = (g,
+    strict)`` keeps ``q_pos // g >= k_pos // g`` (``>`` when strict)
+    instead: tiles start at multiples of g, so that is the causal class of
+    the tile counted in blocks, one block further back when strict, and
+    what the diagonal crosses is BLOCK_DIAGONAL."""
+    if diag is not None:
+        g, strict = diag
+        kind = _tile_kind(d // g - int(strict), rows // g, cols // g, True)
+        return BLOCK_DIAGONAL if kind == DIAGONAL else kind
     lo, hi = d - (cols - 1), d + rows - 1       # extremes of q_pos - k_pos
     if (causal and hi < 0) or (window is not None and lo >= window):
         return VOID
@@ -94,12 +108,15 @@ class TileSchedule(NamedTuple):
     # what the kernels' sweeps amount to, and what the counter counts
     tiles: tuple
     window: Optional[int] = None
+    # (g, strict): the diagonal in blocks of g positions (:func:`_tile_kind`)
+    diag: Optional[tuple] = None
 
 
 @functools.lru_cache(maxsize=None)
 def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
                         causal: bool, halve_diagonal: bool,
-                        window: Optional[int] = None) -> TileSchedule:
+                        window: Optional[int] = None,
+                        diag: Optional[tuple] = None) -> TileSchedule:
     """The score tiles of one head-sequence, from shapes alone.  The DMA
     blocks stay (block_q × block_k).  With ``halve_diagonal`` a tile that
     an edge of the band crosses is walked in sub-tiles of half its edge
@@ -112,6 +129,16 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
     one full and one band-edge tile, however long the sequence."""
     if window is not None and (not causal or window < 1):
         raise ValueError(f"a sliding window ({window}) is causal and >= 1")
+    if diag is not None:
+        g = diag[0]
+        if not causal or window is not None:
+            raise NotImplementedError(
+                f"a block-granular diagonal (block {g}) is causal and has "
+                f"no sliding window")
+        if g < 1 or 128 % g:
+            raise ValueError(
+                f"block length {g} does not divide the 128-position tile "
+                f"the schedule is cut in")
 
     def half(block):
         return block // 2 if halve_diagonal and block % 256 == 0 else block
@@ -119,7 +146,8 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
     sq, sk = half(block_q), half(block_k)
 
     def subs(d0):
-        return tuple((r0, c0, _tile_kind(d0 + r0 - c0, sq, sk, causal, window))
+        return tuple((r0, c0, _tile_kind(d0 + r0 - c0, sq, sk, causal, window,
+                                         diag))
                      for r0 in range(0, block_q, sq)
                      for c0 in range(0, block_k, sk))
 
@@ -128,15 +156,16 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
     diagonal = tuple(
         (d0, tuple(s for s in subs(d0) if s[2] != VOID))
         for d0 in range(-(block_q // step - 1) * step, last, step)
-        if _tile_kind(d0, block_q, block_k, causal, window) in MASKED)
+        if _tile_kind(d0, block_q, block_k, causal, window, diag) in MASKED)
     tiles = []
     for q0 in range(0, S, block_q):
         for k0 in range(0, Sk, block_k):
-            kind = _tile_kind(q0 - k0, block_q, block_k, causal, window)
+            kind = _tile_kind(q0 - k0, block_q, block_k, causal, window,
+                              diag)
             tiles += [(q0 + r0, k0 + c0, sub if kind in MASKED else kind)
                       for r0, c0, sub in subs(q0 - k0)]
     return TileSchedule(S, Sk, block_q, block_k, causal, sq, sk, diagonal,
-                        tuple(tiles), window)
+                        tuple(tiles), window, diag)
 
 
 def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
@@ -145,9 +174,10 @@ def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
     family = _registry.counter(
         "flash_score_tiles_total",
         "score sub-tiles of one head-sequence by what the flash kernel "
-        "does with them: void runs no code, full builds no mask, diagonal "
-        "and band_edge (a sliding window's lower edge) are masked (counted "
-        "at trace time, not per call)",
+        "does with them: void runs no code, full builds no mask, diagonal, "
+        "band_edge (a sliding window's lower edge) and block_diagonal (a "
+        "diagonal in blocks of positions: block diffusion) are masked "
+        "(counted at trace time, not per call)",
         labelnames=("pass", "kind"))
     for kind, n in collections.Counter(t[2] for t in sched.tiles).items():
         family.labels(pass_, kind).inc(n)
@@ -167,14 +197,17 @@ def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     traced = isinstance(own, jax.Array)
     lowest, highest = (jnp.minimum, jnp.maximum) if traced else (min, max)
     w = sched.window
-    if own_is_q:    # FULL: k0 + bk - 1 <= q0
-        hi = lowest(nk, (own * bq + 1) // bk)
+    # the keys a tile's first query keeps past its own position, plus one:
+    # 1 on the plain diagonal, g on a block-granular one, 0 on its strict form
+    e = 1 if sched.diag is None else sched.diag[0] * (not sched.diag[1])
+    if own_is_q:    # FULL: k0 + bk - e <= q0
+        hi = lowest(nk, (own * bq + e) // bk)
         if w is None:
             return 0, hi
         # ... and q0 + bq - 1 - k0 < window
         return highest(0, (own * bq + bq - w + bk - 1) // bk), hi
-    # FULL: q0 >= k0 + bk - 1, from the first such query tile on
-    lo = lowest(nq, ((own + 1) * bk - 1 + bq - 1) // bq)
+    # FULL: q0 >= k0 + bk - e, from the first such query tile on
+    lo = lowest(nq, ((own + 1) * bk - e + bq - 1) // bq)
     if w is None:
         return lo, nq
     # ... to the last with q0 + bq - 1 - k0 < window
@@ -257,13 +290,21 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
                 functools.partial(program, static_sweep(o), False))
 
 
-def _band_mask(s, d: int, kind: str, window: Optional[int]):
+def _band_mask(s, d: int, kind: str, sched: TileSchedule):
     """Void the entries of score tile ``s`` outside the band, its first
     query position lying ``d`` after its first key position: those with
     ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
-    window`` in a BAND_EDGE one, both in a CROSSED one."""
+    window`` in a BAND_EDGE one, both in a CROSSED one; in a
+    BLOCK_DIAGONAL one those whose key block lies after the query's block
+    (at or after it when strict)."""
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    window = sched.window
+    if kind == BLOCK_DIAGONAL:
+        g, strict = sched.diag
+        shift = g.bit_length() - 1          # g divides 128: a power of two
+        return jnp.where((row >> shift) + (d // g - int(strict))
+                         >= (col >> shift), s, NEG_INF)
     if kind == DIAGONAL:
         return jnp.where(row + d >= col, s, NEG_INF)
     inside = row + (d - window) < col
@@ -412,7 +453,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
                 for q_h, (m, l, acc) in zip(qs, carry):
                     s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
                     if d is not None:
-                        s = _band_mask(s, d, kind, sched.window)
+                        s = _band_mask(s, d, kind, sched)
                     m_new = jnp.maximum(m, s.max(axis=-1))
                     # rows with everything masked keep m=-inf; keep exp
                     # well-defined
@@ -508,7 +549,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
                     s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
                     if d is not None:
-                        s = _band_mask(s, d, kind, sched.window)
+                        s = _band_mask(s, d, kind, sched)
                     p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
                     dv = _dot(p, do, ((0,), (0,)))
                     dp = _dot(do, v, ((1,), (1,)))
@@ -635,11 +676,12 @@ def _delta(do, out, lanes: Lanes):
                       ).reshape(N, lanes.blocks, lanes.heads, S)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
-           window=None):
+           window=None, diag=None):
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                        interpret, window)
+                        interpret, window, diag)
     return out
 
 
@@ -648,7 +690,7 @@ def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
 # each kernel body once, not once a layer and remat pass (a step of the
 # 48-layer benchmark model stages ~200 flash calls).
 _STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret",
-           "window")
+           "window", "diag")
 # Mosaic gives a kernel 16 MB of VMEM unless told otherwise; the v5e has
 # 128.  A kernel whose blocks and scratch come near the default asks for
 # what it needs and this much again for the values of its body.
@@ -677,12 +719,12 @@ def _group(q, k, lanes: Lanes) -> int:
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret,
-              window=None):
+              window=None, diag=None):
     N, S, W = q.shape
     Sk = k.shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
     sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False,
-                                window)
+                                window, diag)
     group = _group(q, k, lanes)
     panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c))
     if group > 1:       # fetched once a key-value head: its index holds
@@ -709,12 +751,13 @@ def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret,
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
-               window=None):
+               window=None, diag=None):
     _note_score_tiles("fwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, False, window))
+        q.shape[1], k.shape[1], block_q, block_k, causal, False, window,
+        diag))
     out, lse = _fwd_call(q, k, v, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k, lanes=lanes,
-                         interpret=interpret, window=window)
+                         interpret=interpret, window=window, diag=diag)
     # named so a "<policy>+flash" remat policy can SAVE the kernel's
     # residuals: out/lse aren't dot outputs, so dots_saveable alone
     # recomputes the whole fwd kernel inside every backward pass
@@ -724,28 +767,30 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
 
 
 def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, window,
-               res, do):
+               diag, res, do):
     q, k, v, out, lse = res
     return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, _delta(do, out, lanes), window)
+                           q, k, v, lse, do, _delta(do, out, lanes), window,
+                           diag)
 
 
 def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                    q, k, v, lse, do, delta, window=None):
+                    q, k, v, lse, do, delta, window=None, diag=None):
     _note_score_tiles("bwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, True, window))
+        q.shape[1], k.shape[1], block_q, block_k, causal, True, window, diag))
     return _bwd_call(q, k, v, do, lse, delta, causal=causal, scale=scale,
                      block_q=block_q, block_k=block_k, lanes=lanes,
-                     interpret=interpret, window=window)
+                     interpret=interpret, window=window, diag=diag)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _bwd_call(q, k, v, do, lse, delta, *, causal, scale, block_q, block_k,
-              lanes, interpret, window=None):
+              lanes, interpret, window=None, diag=None):
     N, S, W = q.shape
     Sk = k.shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
-    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True, window)
+    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True, window,
+                                diag)
     group = _group(q, k, lanes)
     panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
     block = grads = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
@@ -818,11 +863,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    block: Optional[int] = None,
+                    strict: bool = False) -> jax.Array:
     """Public API, shapes ``(B, S, H, D)`` like ``ops.attention``; ``k``
     and ``v`` may have fewer heads than ``q`` (query head h reads
     key-value head ``h // (H // KV)``; head_dim a multiple of 128), and
-    ``window`` keeps, of the causal keys, the last ``window``.
+    ``window`` keeps, of the causal keys, the last ``window``.  ``block``
+    (a divisor of 128) makes the diagonal block-granular: query i keeps
+    key j iff ``i // block >= j // block``, ``>`` when ``strict`` (block
+    diffusion's clean half, and its noisy half against the clean keys).
 
     The kernels read q, k, v and dO and write o, dq, dk and dv as
     ``(B, S, H·D)``, the layout the projections on either side use, so
@@ -843,29 +893,43 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
     out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), causal,
-                 scale, block_q, block_k, lanes, interpret, window)
+                 scale, block_q, block_k, lanes, interpret, window,
+                 _diag(block, strict))
     return _unpack(out, lanes, q.shape[0])
+
+
+def _diag(block: Optional[int], strict: bool = False) -> Optional[tuple]:
+    """The schedule's ``diag`` of a block length, checked; None of None."""
+    if block is None:
+        return None
+    if not isinstance(block, int) or block < 1 or 128 % block:
+        raise ValueError(
+            f"block length {block!r}: the block-granular diagonal takes a "
+            f"divisor of 128 (the schedule's tiles start at multiples of "
+            f"128 positions and must start at multiples of a block)")
+    return int(block), bool(strict)
 
 
 # ---------------------------------------------------------------------------
 # LSE-exposing variant — building block for distributed (ring) attention
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
+               diag=None):
     return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret)[0]
+                          interpret, diag)[0]
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                   interpret):
+                   interpret, diag=None):
     out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret)
+                          interpret, None, diag)
     return (out, _row_heads(res[4], lanes)), res      # lse as (N, S, heads)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
-                   ct):
+def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, diag,
+                   res, ct):
     do, dlse = ct
     q, k, v, out, lse = res
     # the lse cotangent folds into the shared backward exactly:
@@ -873,7 +937,7 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
     delta = _delta(do, out, lanes) - _head_rows(dlse.astype(jnp.float32),
                                                 lanes)
     return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, delta)
+                           q, k, v, lse, do, delta, None, diag)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -883,15 +947,23 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              causal: bool = True,
                              scale: Optional[float] = None,
                              block_q: int = 512, block_k: int = 512,
-                             interpret: bool = False):
+                             interpret: bool = False,
+                             block: Optional[int] = None,
+                             strict: bool = False):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(B, S, H)`` — differentiable in BOTH outputs, which is what a
-    distributed (ring) attention needs to merge per-block results exactly.
+    distributed (ring) attention needs to merge per-block results exactly,
+    and what block diffusion's noisy half needs to add its own block's
+    keys to the clean ones (``block``, ``strict``: as
+    :func:`flash_attention`'s).  A row that keeps no key (the first block
+    under ``strict``) comes back as output 0 and logsumexp 0: the caller
+    knows which those are.
     """
     B, S, H, _ = q.shape
     scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
     out, lse = _flash_lse(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes),
-                          causal, scale, block_q, block_k, lanes, interpret)
+                          causal, scale, block_q, block_k, lanes, interpret,
+                          _diag(block, strict))
     if not lanes.rows:      # (B·H, S, 1): a head a panel
         lse = lse.reshape(B, H, S).transpose(0, 2, 1)
     return _unpack(out, lanes, B), lse
@@ -938,7 +1010,7 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, *,
             s = _dot(qn, kn_ref[0, ks].astype(jnp.float32), ((1,), (1,))) \
                 + _dot(qr, kr_ref[0, ks].astype(jnp.float32), ((1,), (1,)))
             if d is not None:
-                s = _band_mask(s, d, kind, sched.window)
+                s = _band_mask(s, d, kind, sched)
             m, l, acc = carry
             m_new = jnp.maximum(m, s.max(axis=-1))
             m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
@@ -1007,7 +1079,7 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
             v = _rows(v_blk, c0, cols)
             s = _dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
             if d is not None:
-                s = _band_mask(s, d, kind, sched.window)
+                s = _band_mask(s, d, kind, sched)
             p = jnp.exp(s - _col(lse_ref[0, 0, 0, rs]))
             dv = _dot(p, do, ((0,), (0,)))
             dp = _dot(do, v, ((1,), (1,)))

@@ -616,6 +616,11 @@ class Engine:
             rngs = {"dropout": rng,
                     "gating": jax.random.fold_in(rng, 1),
                     "pld": jax.random.fold_in(rng, 2)}
+            # streams a model names for itself (``rng_streams``: block-
+            # diffusion training's noise), folded as those above are, so a
+            # resumed run redraws what the step drew
+            for i, name in enumerate(getattr(self.model, "rng_streams", ()), 3):
+                rngs[name] = jax.random.fold_in(rng, i)
         kwargs = dict(batch)
         if pld_theta is not None:
             kwargs["layer_drop_theta"] = pld_theta

@@ -1,0 +1,258 @@
+"""The flash kernels under block diffusion's masks (PR 40) in interpret
+mode against the dense mask: the block-granular diagonal ``q // g >= k //
+g``, its strict form, and the whole ``[noisy ; clean]`` attention
+(``ops/attention.py block_diffusion_attention``: two flash calls and the
+own block's XLA term) against ``block_diffusion_mask``: block lengths 4,
+16 and 32, sequences that are no multiple of the default tile, a looped
+sweep, grouped and ungrouped heads, forward and all three gradients, two
+rows that do not mix, the tile counter's classes against a brute-force
+count of kept entries, and what is refused by name.
+"""
+import collections
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.comm import mesh as mesh_lib
+from deepspeed_tpu.ops import attention as attention_lib
+from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+from deepspeed_tpu.telemetry import get_registry
+
+fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+
+
+@pytest.fixture
+def one_device():
+    """The dispatcher hands a kernel its operands only where it knows their
+    sharding: a mesh of one device (the tests' eight have none set)."""
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    yield
+    mesh_lib.set_mesh(None)
+
+
+def _flash_dispatches(g):
+    return sum(n for s, i, r, n in dispatch_report()
+               if (s, i) == ("attention", "flash")
+               and f"block length {g}" in r)
+
+
+def _operands(B, S, H, KV, D, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shapes = ((B, S, H, D), (B, S, KV, D), (B, S, KV, D), (B, S, H, D))
+    return [jax.random.normal(k, s, dtype) for k, s in zip(ks, shapes)]
+
+
+def _keep(S, g, strict):
+    qb, kb = np.arange(S)[:, None] // g, np.arange(S)[None, :] // g
+    return qb > kb if strict else qb >= kb
+
+
+def _plain(q, k, v, keep):
+    """Dense masked softmax attention; a row that keeps nothing gives 0."""
+    H, KV = q.shape[2], k.shape[2]
+    k, v = (jnp.repeat(t, H // KV, axis=2) for t in (k, v))
+    s = jnp.einsum("bshd,bthd->bhst", q, k, precision="highest") \
+        * q.shape[-1] ** -0.5
+    s = jnp.where(jnp.asarray(keep), s, -jnp.inf)
+    m = s.max(-1, keepdims=True)
+    p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+    l = p.sum(-1, keepdims=True)
+    return jnp.einsum("bhst,bthd->bshd", p / jnp.where(l == 0, 1.0, l), v,
+                      precision="highest")
+
+
+# (S, H, KV, D, g, strict, block): three 128-tiles (S no multiple of 512);
+# grouped heads under the strict form; an eight-tile looped sweep; the
+# halved diagonal tile of the backward (512 -> 256) with blocks of 32
+DIAGONALS = [(384, 2, 2, 64, 4, False, 512), (384, 4, 2, 128, 16, True, 512),
+             (1024, 2, 1, 128, 4, True, 128), (1024, 2, 2, 64, 32, False, 512),
+             (512, 2, 2, 64, 16, True, 512), (640, 2, 1, 128, 32, False, 128)]
+
+
+@pytest.mark.parametrize("S,H,KV,D,g,strict,block", DIAGONALS)
+def test_block_granular_diagonal_forward_and_gradients(S, H, KV, D, g, strict,
+                                                       block):
+    q, k, v, w = _operands(2, S, H, KV, D, seed=S + g)
+    kern = lambda q, k, v: fa.flash_attention(
+        q, k, v, interpret=True, block=g, strict=strict, block_q=block,
+        block_k=block)
+    plain = lambda q, k, v: _plain(q, k, v, _keep(S, g, strict))
+    np.testing.assert_allclose(kern(q, k, v), plain(q, k, v), atol=2e-5)
+    got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(a, b, atol=5e-5, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("g,strict", [(4, False), (16, True), (32, False),
+                                      (128, True)])
+def test_tile_classes_match_a_brute_force_count(g, strict):
+    """Each sub-tile's class against the kept entries counted one by one:
+    void keeps none, full all, block_diagonal some; forward (whole tiles)
+    and backward (the crossed tile in halves)."""
+    S, bq = 1024, 256
+    keep = _keep(S, g, strict)
+    for halve in (False, True):
+        sched = fa.score_tile_schedule(S, S, bq, bq, True, halve, None,
+                                       (g, strict))
+        rows, cols = sched.sub_q, sched.sub_k
+        assert len(sched.tiles) == (S // rows) * (S // cols)
+        for q0, k0, kind in sched.tiles:
+            kept = int(keep[q0:q0 + rows, k0:k0 + cols].sum())
+            want = fa.VOID if kept == 0 else fa.FULL \
+                if kept == rows * cols else fa.BLOCK_DIAGONAL
+            assert kind == want, (q0, k0, kind, kept)
+
+
+@pytest.mark.parametrize("g", [4, 16, 32])
+def test_the_counter_names_the_new_kind(g):
+    """``flash_score_tiles_total{pass, kind="block_diagonal"}`` counts the
+    crossed sub-tiles of the traced kernels, and no plain ``diagonal``."""
+    S, block = 512, 128
+    q, k, v, w = _operands(1, S, 2, 2, 64, seed=g)
+
+    def count():
+        entry = get_registry().snapshot().get("flash_score_tiles_total")
+        out = collections.Counter()
+        for s in (entry or {"samples": []})["samples"]:
+            out[s["labels"]["pass"], s["labels"]["kind"]] += s["value"]
+        return out
+
+    before = count()
+    jax.grad(lambda q: (fa.flash_attention(
+        q, k, v, interpret=True, block=g, block_q=block, block_k=block)
+        * w).sum())(q)
+    got = count() - before
+    n = S // block
+    assert got["fwd", "block_diagonal"] == n
+    assert got["bwd", "block_diagonal"] == n
+    assert got["fwd", "full"] == got["fwd", "void"] == n * (n - 1) // 2
+    assert got["fwd", "diagonal"] == got["bwd", "diagonal"] == 0
+
+
+# (L, H, KV, D, g): grouped in the kernel (head_dim 128); head_dim 64, where
+# k and v are repeated first; L = 384 is three tiles a half
+HALVES = [(256, 4, 2, 128, 4), (384, 2, 2, 64, 16), (256, 4, 1, 64, 32),
+          (640, 2, 1, 128, 4)]
+
+
+@pytest.mark.parametrize("L,H,KV,D,g", HALVES)
+def test_noisy_and_clean_halves_match_the_dense_mask(one_device, L, H, KV,
+                                                     D, g):
+    q, k, v, w = _operands(2, 2 * L, H, KV, D, seed=L + g)
+    mask = np.asarray(attention_lib.block_diffusion_mask(L, g))
+    before = _flash_dispatches(g)
+    kern = lambda *a: attention_lib.block_diffusion_attention(
+        *a, block=g, impl="flash", interpret=True)
+    plain = lambda *a: _plain(*a, mask)
+    np.testing.assert_allclose(kern(q, k, v), plain(q, k, v), atol=2e-5)
+    assert _flash_dispatches(g) == before + 1       # the kernels ran
+    got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (plain(*a) * w).sum(), argnums=(0, 1, 2))(
+        q, k, v)
+    for name, a, b in zip("qkv", got, want):
+        assert bool(jnp.isfinite(a).all()), name
+        for half, x, y in zip(("noisy", "clean"), np.split(a, 2, 1),
+                              np.split(b, 2, 1)):
+            np.testing.assert_allclose(x, y, atol=5e-5,
+                                       err_msg=f"d{name} {half}")
+
+
+def test_the_mask_is_the_four_sentences():
+    """L 4, g 2 written out by hand: rows and columns [noisy ; clean]."""
+    want = np.array([
+        [1, 1, 0, 0, 0, 0, 0, 0],       # noisy block 0: its own noisy block
+        [1, 1, 0, 0, 0, 0, 0, 0],
+        [0, 0, 1, 1, 1, 1, 0, 0],       # noisy block 1: + clean block 0
+        [0, 0, 1, 1, 1, 1, 0, 0],
+        [0, 0, 0, 0, 1, 1, 0, 0],       # clean block 0: clean block 0
+        [0, 0, 0, 0, 1, 1, 0, 0],
+        [0, 0, 0, 0, 1, 1, 1, 1],       # clean block 1: clean blocks 0, 1
+        [0, 0, 0, 0, 1, 1, 1, 1]], bool)
+    got = np.asarray(attention_lib.block_diffusion_mask(4, 2))
+    assert (got == want).all()
+    for L, g in ((64, 4), (96, 32)):
+        m = np.asarray(attention_lib.block_diffusion_mask(L, g))
+        assert m.sum() == L * (L + g)       # kept pairs of a data row
+        assert m[:L, :L].sum() == L * g and not m[L:, :L].any()
+
+
+def test_xla_path_takes_the_same_mask_and_rows_do_not_mix(one_device):
+    L, g = 128, 4
+    q, k, v, _ = _operands(2, 2 * L, 4, 2, 32, seed=5)
+    both = attention_lib.block_diffusion_attention(q, k, v, block=g,
+                                                   impl="jnp")
+    mask = np.asarray(attention_lib.block_diffusion_mask(L, g))
+    np.testing.assert_allclose(both, _plain(q, k, v, mask), atol=2e-5)
+    alone = attention_lib.block_diffusion_attention(
+        q[1:], k[1:], v[1:], block=g, impl="jnp")
+    np.testing.assert_allclose(both[1:], alone, atol=1e-6)
+    kern = attention_lib.block_diffusion_attention(
+        q, k, v, block=g, impl="flash", interpret=True)
+    np.testing.assert_allclose(kern[1:], attention_lib.block_diffusion_attention(
+        q[1:], k[1:], v[1:], block=g, impl="flash", interpret=True), atol=1e-6)
+    assert float(jnp.abs(kern[0] - kern[1]).max()) > 0.1
+
+
+def test_the_dispatch_reason_names_the_block_length(one_device):
+    L, g = 128, 16
+    q, k, v, _ = _operands(1, 2 * L, 4, 2, 128, seed=2)
+    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="flash",
+                                            interpret=True)
+    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="jnp")
+    mesh_lib.set_mesh(None)     # eight devices and no mesh: refused, by name
+    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="flash",
+                                            interpret=True)
+    assert any(i == "jnp" and "refused the mesh" in r and f"length {g}" in r
+               for s, i, r, n in dispatch_report())
+    flash = [r for s, i, r, n in dispatch_report()
+             if (s, i) == ("attention", "flash") and f"block length {g}" in r
+             and "2 query heads a key-value head" in r]
+    assert flash and all("one device" in r and "rows layout, 1 head a "
+                         "128-lane block" in r for r in flash)
+    assert any(i == "jnp" and "dense mask" in r and f"length {g}" in r
+               for s, i, r, n in dispatch_report())
+
+
+@pytest.mark.parametrize("g", [3, 24, 48, 256, 0])
+def test_a_block_length_that_does_not_divide_128_raises(g):
+    q, k, v, _ = _operands(1, 768, 2, 2, 64)
+    with pytest.raises(ValueError, match="128"):
+        fa.flash_attention(q, k, v, interpret=True, block=g)
+    if g:
+        with pytest.raises(ValueError):
+            attention_lib.block_diffusion_attention(q, k, v, block=g,
+                                                    impl="jnp")
+
+
+def test_what_is_not_written_raises_by_name():
+    q, k, v, _ = _operands(1, 256, 2, 2, 64)
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        fa.flash_attention(q, k, v, interpret=True, block=4, window=64)
+    with pytest.raises(NotImplementedError, match="causal"):
+        fa.flash_attention(q, k, v, interpret=True, block=4, causal=False)
+    for impl in ("ring", "ulysses"):
+        with pytest.raises(NotImplementedError, match="sequence-parallel"):
+            attention_lib.block_diffusion_attention(q, k, v, block=4,
+                                                    impl=impl)
+    with pytest.raises(ValueError, match="whole blocks"):
+        attention_lib.block_diffusion_attention(q[:, :250], k[:, :250],
+                                                v[:, :250], block=4)
+
+
+def test_without_a_block_the_schedule_is_the_causal_one():
+    """The block-granular forms are keyed apart: a schedule without
+    ``diag`` is the one every other model runs, tile for tile."""
+    plain = fa.score_tile_schedule(1024, 1024, 512, 512, True, True)
+    assert plain.diag is None
+    assert {t[2] for t in plain.tiles} == {fa.VOID, fa.FULL, fa.DIAGONAL}
+    blockwise = fa.score_tile_schedule(1024, 1024, 512, 512, True, True, None,
+                                       (4, False))
+    assert [t[:2] for t in plain.tiles] == [t[:2] for t in blockwise.tiles]
+    assert [t[2].replace("block_", "") for t in blockwise.tiles] \
+        == [t[2] for t in plain.tiles]

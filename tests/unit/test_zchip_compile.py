@@ -585,3 +585,52 @@ def test_the_head_multiplies_its_logits_out_once(one_chip, family, chunk):
     traced, dots = head_dots(loss)
     assert len(dots) == 1, dots
     assert traced == {"primal": 1, "forward": 0, "backward": 0}
+
+
+def test_the_sixth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-sdar-blockdiff-8k-1chip`` (PR 40) as the benchmark builds it,
+    its whole train step compiled for the described chip: Mosaic takes the
+    two block-diagonal flash calls a layer at (2, 8192, 32 / 4, 128) each,
+    forward and backward, every one named ``self_attn_blockdiff``; k and v
+    stay 512 wide; and what the step reserves (arguments + outputs - aliases
+    + temporaries) stays under the chip's 15.75 GiB with the room the
+    set-up's comparisons need."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-sdar-blockdiff-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        compiled = engine._compiled_train_step.lower(
+            engine.abstract_state(batch), batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.diffusion.block_length) == (2, 8192, 4)
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 4.0 < reserved < 13.5, reserved      # 12.3 at PR 40; 15.75 a chip
+    text = compiled.as_text()
+    calls = re.findall(r"self_attn_blockdiff[.\d]* = (\(.*?\)) custom-call\(",
+                       text)
+    # a layer: the clean and the noisy forward, and their two backwards
+    fwd = [c for c in calls if "f32[2,32,1,8192]" in c]
+    bwd = [c for c in calls if c.count("bf16[2,8192,512]") == 2]
+    assert len(fwd) == len(bwd) == 2 * cfg.num_hidden_layers, len(calls)
+    assert len(calls) == len(fwd) + len(bwd)
+    assert not re.search(r"bf16\[2,8192,4,8,128\]", text)   # no k/v repeat
