@@ -679,15 +679,17 @@ def kernel_flash_blockdiff():
     """The sixth cell's attention at the cell's own shape
     (``train-sdar-blockdiff-8k-1chip``: 2 rows of [noisy ; clean] = 16,384
     positions, 32 query heads on 4 key-value heads of 128, blocks of 4):
-    ``ops/attention.py block_diffusion_attention`` (the clean half under
-    the block-granular diagonal, the noisy half against the clean keys
-    under its strict form merged with its own block's XLA term), forward
-    and the gradients of q, k and v, against the dense float32 mask
-    computed in query blocks, a row at a time.  Each (row, key-value head,
-    half) slice of dk and dv is also checked on its own: a key-value
-    head's sums over its eight query heads and over two kernels' calls
-    (the clean keys serve both halves) live in scratch that the next row
-    must not inherit.  The bound and every reading are printed."""
+    ``ops/attention.py block_diffusion_attention`` (one flash call a pass
+    over all 16,384 rows: a noisy query tile folds the tile of its own
+    noisy blocks into the softmax of the clean keys' tiles), forward and
+    the gradients of q, k and v, on two rows of different content, against
+    the dense float32 mask computed in query blocks, a row at a time.  The
+    noisy half's first block, whose rows keep no clean key, is reported
+    apart.  Each (row, key-value head, half) slice of dk and dv is also
+    checked on its own: a key-value head's sums over its eight query heads
+    live in scratch that the next row must not inherit, and the clean keys
+    serve the query tiles of both halves.  The bound and every reading
+    are printed."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -736,6 +738,7 @@ def kernel_flash_blockdiff():
         for half, lo in (("noisy", 0), ("clean", L)):
             _check_close(f"{name} {n} {half}", g[:, lo:lo + L],
                          gr[:, lo:lo + L])
+        _check_close(f"{name} {n} noisy, first block", g[:, :G], gr[:, :G])
         if n not in ("dk", "dv"):
             continue
         g, gr = (np.asarray(t, np.float32).reshape(B, 2, L, KV, D)

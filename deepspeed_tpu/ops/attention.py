@@ -164,62 +164,19 @@ def block_diffusion_mask(length: int, block: int):
     return jnp.block([[qb == kb, qb > kb], [none, qb >= kb]])
 
 
-def _own_block_scores(qn, kn, block: int, scale: float):
-    """Each noisy query against the ``block`` noisy keys of its own block:
-    ``(B, L, H, block)`` float32 (grouped queries read their key-value
-    head); ``L x block`` scores a head, plain XLA."""
-    B, L, H, D = qn.shape
-    KV = kn.shape[2]
-    n = L // block
-    s = jnp.einsum("bnakgd,bnckd->bnakgc",
-                   qn.reshape(B, n, block, KV, H // KV, D),
-                   kn.reshape(B, n, block, KV, D),
-                   preferred_element_type=jnp.float32)
-    return s.reshape(B, L, H, block) * scale
-
-
-def _own_block_values(p, vn, block: int):
-    """``p`` (B, L, H, block) float32 over each query's own block of
-    values: ``(B, L, H, D)`` float32."""
-    B, L, H, _ = p.shape
-    KV, D = vn.shape[2:]
-    n = L // block
-    o = jnp.einsum("bnakgc,bnckd->bnakgd",
-                   p.reshape(B, n, block, KV, H // KV, block),
-                   vn.reshape(B, n, block, KV, D).astype(jnp.float32),
-                   precision="highest")
-    return o.reshape(B, L, H, D)
-
-
 def _block_diffusion_flash(q, k, v, *, block, scale, interpret=False):
-    """The two halves on the flash kernels.  Clean half: one call under
-    the block-granular diagonal.  Noisy half: one call against the CLEAN
-    keys under its strict form, merged through its log-sum-exp with the
-    own block's ``L x block`` scores a head, which are XLA's."""
-    from .pallas.flash_attention import (flash_attention,
-                                         flash_attention_with_lse,
+    """The rows as the projections wrote them through one flash call a
+    pass (``ops/pallas/flash_attention.py flash_attention_halves``): the
+    noisy queries' own blocks are a tile of the kernels' schedule, folded
+    into the same online softmax as the clean keys' tiles, so nothing is
+    sliced, merged or concatenated around the kernels."""
+    from .pallas.flash_attention import (flash_attention_halves,
                                          grouped_in_kernel)
 
-    L = q.shape[1] // 2
     if not grouped_in_kernel(q.shape[3]):
         k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
-    (qn, qc), (kn, kc), (vn, vc) = ((t[:, :L], t[:, L:]) for t in (q, k, v))
-    clean = flash_attention(qc, kc, vc, causal=True, scale=scale,
-                            interpret=interpret, block=block)
-    past, lse = flash_attention_with_lse(qn, kc, vc, causal=True, scale=scale,
-                                         interpret=interpret, block=block,
-                                         strict=True)
-    # the first block has no earlier one: its rows kept no clean key
-    lse = jnp.where((jnp.arange(L) < block)[None, :, None],
-                    jnp.finfo(jnp.float32).min, lse)
-    s = _own_block_scores(qn, kn, block, scale)
-    m = jnp.maximum(lse, s.max(axis=-1))
-    w_past = jnp.exp(lse - m)[..., None]
-    p = jnp.exp(s - m[..., None])
-    noisy = (w_past * past.astype(jnp.float32)
-             + _own_block_values(p, vn, block)) \
-        / (w_past + p.sum(axis=-1, keepdims=True))
-    return jnp.concatenate([noisy.astype(q.dtype), clean], axis=1)
+    return flash_attention_halves(q, k, v, block=block, scale=scale,
+                                  interpret=interpret)
 
 
 def block_diffusion_attention(q, k, v, *, block: int,
@@ -230,13 +187,15 @@ def block_diffusion_attention(q, k, v, *, block: int,
     ``q`` ``(B, 2L, H, D)``, ``k`` and ``v`` ``(B, 2L, KV, D)``, the mask
     :func:`block_diffusion_mask`; returns ``(B, 2L, H, D)``.
 
-    ``"flash"`` (``"auto"`` on a TPU where the shapes tile) runs the two
-    halves on the flash kernels (:func:`_block_diffusion_flash`): no
-    ``2L x 2L`` score exists, grouped queries stay at their key-value
-    heads in the kernel, void tiles run no code and full tiles build no
-    mask.  ``"jnp"`` (the CPU, tests) applies the dense mask to XLA
-    scores.  Heads over ``tp`` and sequence-parallel forms are not
-    written: ``tp`` takes the XLA path, ``ring`` / ``ulysses`` raise."""
+    ``"flash"`` (``"auto"`` on a TPU where the shapes tile) runs all
+    ``2L`` rows through one flash call a pass
+    (:func:`_block_diffusion_flash`), whose tile schedule is the mask's four
+    quadrants: no ``2L x 2L`` score exists, no score is computed outside
+    the kernels, grouped queries stay at their key-value heads in the
+    kernel, void tiles run no code and full tiles build no mask.
+    ``"jnp"`` (the CPU, tests) applies the dense mask to XLA scores.
+    Heads over ``tp`` and sequence-parallel forms are not written: ``tp``
+    takes the XLA path, ``ring`` / ``ulysses`` raise."""
     from .pallas.flash_attention import _diag, flash_lanes
     from .pallas.spmd import kernel_mesh_plan, note_dispatch
 

@@ -25,6 +25,11 @@ that the diagonal or the band's lower edge crosses is masked (the backward
 walks it in half-edge sub-tiles, each classed the same way).  The diagonal
 may be block-granular (``block``: block diffusion's masks, ``q_pos // g >=
 k_pos // g`` or its strict form ``>``): the same schedule in units of blocks.
+Block diffusion's whole mask over ``[noisy ; clean]`` rows is one schedule
+too (``diag = (g, HALVES)``, :func:`flash_attention_halves`): a noisy
+query tile folds the tile of its own noisy blocks (``q_pos // g == k_pos //
+g``) into the same online softmax as the clean keys' tiles, and the
+backward program of that noisy key tile owns its ``dk`` and ``dv`` whole.
 
 Grouped queries (``k`` and ``v`` narrower than ``q``, head_dim a multiple
 of 128): query lane block ``c`` reads key-value lane block ``c // group``,
@@ -59,7 +64,13 @@ BAND_EDGE, CROSSED = "band_edge", "diagonal+band_edge"
 # a block-granular diagonal crosses the tile (``block``: q_pos // g against
 # k_pos // g)
 BLOCK_DIAGONAL = "block_diagonal"
-MASKED = (DIAGONAL, BAND_EDGE, CROSSED, BLOCK_DIAGONAL)
+# noisy queries against the noisy keys of their own blocks (block diffusion
+# over [noisy ; clean] rows): the tile keeps ``q_pos // g == k_pos // g``
+OWN_BLOCK = "own_block"
+MASKED = (DIAGONAL, BAND_EDGE, CROSSED, BLOCK_DIAGONAL, OWN_BLOCK)
+# in ``strict``'s place in ``diag``: the rows are the two halves [noisy ;
+# clean] of one sequence under the whole of block diffusion's mask
+HALVES = "halves"
 
 
 # ---------------------------------------------------------------------------
@@ -92,6 +103,26 @@ def _tile_kind(d: int, rows: int, cols: int, causal: bool,
     return BAND_EDGE if below else FULL
 
 
+def _halves_tile_kind(q0: int, k0: int, rows: int, cols: int, half: int,
+                      g: int) -> str:
+    """Class of the rows×cols score tile at ``(q0, k0)`` of block
+    diffusion's mask over ``[noisy ; clean]`` rows of ``half`` positions
+    each (``ops/attention.py block_diffusion_mask``; tiles start at
+    multiples of g and lie in one half).  A noisy key is kept by the noisy
+    queries of its own block alone: OWN_BLOCK where the tile holds such
+    pairs.  A clean key is kept under the block-granular diagonal, strict
+    for a noisy query.  One program serves both halves, the strictness a
+    traced offset of its mask, so a tile that the two forms class apart (a
+    block as long as the tile) is masked under both."""
+    if k0 < half:
+        meets = q0 < half and q0 < k0 + cols and k0 < q0 + rows
+        return OWN_BLOCK if meets else VOID
+    d = q0 % half - (k0 - half)
+    loose, strict = (_tile_kind(d, rows, cols, True, None, (g, s))
+                     for s in (False, True))
+    return loose if loose == strict else BLOCK_DIAGONAL
+
+
 class TileSchedule(NamedTuple):
     S: int
     Sk: int
@@ -102,14 +133,21 @@ class TileSchedule(NamedTuple):
     sub_k: int
     # what a kernel walks inside a (block_q × block_k) tile that an edge of
     # the band crosses, keyed by the tile's offset d0 = q0 - k0:
-    # ((d0, ((r0, c0, kind), ...)), ...), void sub-tiles left out
+    # ((d0, ((r0, c0, kind), ...)), ...), void sub-tiles left out; of the
+    # halves' schedule, keyed by the tile's kind (OWN_BLOCK, BLOCK_DIAGONAL)
     diagonal: tuple
     # every (sub_q × sub_k) sub-tile of one head-sequence, (q0, k0, kind):
     # what the kernels' sweeps amount to, and what the counter counts
     tiles: tuple
     window: Optional[int] = None
-    # (g, strict): the diagonal in blocks of g positions (:func:`_tile_kind`)
+    # (g, strict): the diagonal in blocks of g positions (:func:`_tile_kind`);
+    # (g, HALVES): block diffusion's mask over [noisy ; clean] rows
+    # (:func:`_halves_tile_kind`)
     diag: Optional[tuple] = None
+
+    @property
+    def halves(self) -> bool:
+        return self.diag is not None and self.diag[1] == HALVES
 
 
 @functools.lru_cache(maxsize=None)
@@ -126,7 +164,12 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
     quarter steps cost more than one whole one: PERF.md §6, PR 25).
     ``window`` keeps, of the causal entries, those less than ``window``
     positions back: per 512-row query block at window 1024 one diagonal,
-    one full and one band-edge tile, however long the sequence."""
+    one full and one band-edge tile, however long the sequence.  ``diag =
+    (g, HALVES)`` is block diffusion's schedule over ``S = Sk = 2L`` rows
+    ``[noisy ; clean]`` in square tiles that divide L: a noisy query tile
+    meets its OWN_BLOCK tile, the clean key tiles before it (FULL) and the
+    clean one at it (BLOCK_DIAGONAL, strict); a clean query tile the same
+    clean tiles under the loose diagonal; everything else is VOID."""
     if window is not None and (not causal or window < 1):
         raise ValueError(f"a sliding window ({window}) is causal and >= 1")
     if diag is not None:
@@ -139,31 +182,47 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
             raise ValueError(
                 f"block length {g} does not divide the 128-position tile "
                 f"the schedule is cut in")
+    halves = diag is not None and diag[1] == HALVES
+    if halves and (S != Sk or block_q != block_k or S % (2 * block_q)):
+        raise ValueError(
+            f"[noisy ; clean] rows ({S} against {Sk}) in tiles of {block_q} "
+            f"x {block_k}: the halves' schedule takes square tiles that "
+            f"divide a half")
 
     def half(block):
         return block // 2 if halve_diagonal and block % 256 == 0 else block
 
     sq, sk = half(block_q), half(block_k)
 
-    def subs(d0):
-        return tuple((r0, c0, _tile_kind(d0 + r0 - c0, sq, sk, causal, window,
-                                         diag))
+    def kind_of(q0, k0, rows, cols):
+        if halves:
+            return _halves_tile_kind(q0, k0, rows, cols, S // 2, diag[0])
+        return _tile_kind(q0 - k0, rows, cols, causal, window, diag)
+
+    def subs(q0, k0):
+        return tuple((r0, c0, kind_of(q0 + r0, k0 + c0, sq, sk))
                      for r0 in range(0, block_q, sq)
                      for c0 in range(0, block_k, sk))
 
-    step = math.gcd(block_q, block_k)
-    last = block_k if window is None else window + block_k
-    diagonal = tuple(
-        (d0, tuple(s for s in subs(d0) if s[2] != VOID))
-        for d0 in range(-(block_q // step - 1) * step, last, step)
-        if _tile_kind(d0, block_q, block_k, causal, window, diag) in MASKED)
+    def kept(q0, k0):
+        return tuple(s for s in subs(q0, k0) if s[2] != VOID)
+
+    if halves:      # the noisy half's first tile; the clean half's
+        diagonal = ((OWN_BLOCK, kept(0, 0)),
+                    (BLOCK_DIAGONAL, kept(S // 2, S // 2)))
+    else:
+        step = math.gcd(block_q, block_k)
+        last = block_k if window is None else window + block_k
+        diagonal = tuple(
+            (d0, kept(d0, 0))
+            for d0 in range(-(block_q // step - 1) * step, last, step)
+            if kind_of(d0, 0, block_q, block_k) in MASKED)
     tiles = []
     for q0 in range(0, S, block_q):
         for k0 in range(0, Sk, block_k):
-            kind = _tile_kind(q0 - k0, block_q, block_k, causal, window,
-                              diag)
+            kind = kind_of(q0, k0, block_q, block_k)
             tiles += [(q0 + r0, k0 + c0, sub if kind in MASKED else kind)
-                      for r0, c0, sub in subs(q0 - k0)]
+                      for r0, c0, sub in subs(q0, k0)]
     return TileSchedule(S, Sk, block_q, block_k, causal, sq, sk, diagonal,
                         tuple(tiles), window, diag)
 
@@ -175,9 +234,10 @@ def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
         "flash_score_tiles_total",
         "score sub-tiles of one head-sequence by what the flash kernel "
         "does with them: void runs no code, full builds no mask, diagonal, "
-        "band_edge (a sliding window's lower edge) and block_diagonal (a "
-        "diagonal in blocks of positions: block diffusion) are masked "
-        "(counted at trace time, not per call)",
+        "band_edge (a sliding window's lower edge), block_diagonal (a "
+        "diagonal in blocks of positions: block diffusion) and own_block "
+        "(block diffusion's noisy queries on the noisy keys of their own "
+        "blocks) are masked (counted at trace time, not per call)",
         labelnames=("pass", "kind"))
     for kind, n in collections.Counter(t[2] for t in sched.tiles).items():
         family.labels(pass_, kind).inc(n)
@@ -214,8 +274,56 @@ def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     return lo, lowest(nq, (own * bk + w - bq) // bq + 1)
 
 
+def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool):
+    """The ``sweep`` (:func:`_for_program`) of the program that owns tile
+    ``own`` (the traced program id) of the halves' schedule, one body for
+    both halves: which half ``own`` lies in decides at run time what it
+    meets, and the mask of a BLOCK_DIAGONAL tile takes its strictness as
+    the offset ``-g`` (a noisy query tile) or 0 (a clean one).
+
+    Forward, a query tile: a noisy one folds its OWN_BLOCK tile, first, so
+    that its first block's rows, which keep no clean key, never meet a
+    running maximum of nothing; then either half folds the clean key tiles
+    before it and the one at it.  Backward, a key tile: a noisy one meets
+    its own query tile alone (its dk and dv are whole there), walked in
+    the sub-tiles on its diagonal; a clean one meets the query tiles past
+    it and the one at it, of both halves."""
+    b, g = sched.block_q, sched.diag[0]
+    n = sched.S // (2 * b)                          # tiles a half
+    subs = dict(sched.diagonal)
+    noisy = own < n
+    r = jnp.where(noisy, own, own - n)              # the tile of its half
+
+    def sweep(carry, full_tile, diagonal_tile):
+        def own_block(c):
+            return diagonal_tile(r * b, 0, subs[OWN_BLOCK], c)
+
+        if own_is_q:
+            carry = jax.lax.cond(noisy, own_block, lambda c: c, carry)
+            carry = jax.lax.fori_loop(
+                0, r, lambda t, c: full_tile((n + t) * b, c), carry)
+            return diagonal_tile((n + r) * b, jnp.where(noisy, -g, 0),
+                                 subs[BLOCK_DIAGONAL], carry)
+
+        def clean_keys(c):
+            def past(u, c):     # [r + 1, n) of the noisy, then of the clean
+                t = r + 1 + u
+                return full_tile(jnp.where(t < n, t, t + r + 1) * b, c)
+
+            c = jax.lax.fori_loop(0, 2 * (n - 1 - r), past, c)
+            return jax.lax.fori_loop(
+                0, 2, lambda h, c: diagonal_tile(
+                    (h * n + r) * b, (h - 1) * g, subs[BLOCK_DIAGONAL], c), c)
+
+        return jax.lax.cond(noisy, own_block, clean_keys, carry)
+
+    return sweep
+
+
 def _is_looped(sched: TileSchedule, *, own_is_q: bool) -> bool:
     """Whether a program's sweep is a loop (:func:`_for_program`)."""
+    if sched.halves:
+        return True
     nq, nk = sched.S // sched.block_q, sched.Sk // sched.block_k
     n_own, n_swept = (nq, nk) if own_is_q else (nk, nq)
     return n_swept > UNROLL_MAX or (sched.causal and n_own > UNROLL_MAX)
@@ -237,7 +345,10 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
     static and nothing separates the tiles, so Mosaic overlaps one tile's
     matmuls with its neighbour's vector work (a branch per tile was 50%
     slower on the v5e than computing the void tile as well).  Long sweeps
-    loop over the full tiles and place the diagonal ones from ``own``."""
+    loop over the full tiles and place the diagonal ones from ``own``.
+    The halves' schedule has a sweep of its own (:func:`_halves_sweep`)."""
+    if sched.halves:
+        return program(_halves_sweep(own, sched, own_is_q=own_is_q), True)
     bq, bk = sched.block_q, sched.block_k
     own_block, swept_block = (bq, bk) if own_is_q else (bk, bq)
     n_own = (sched.S // bq) if own_is_q else (sched.Sk // bk)
@@ -296,15 +407,20 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule):
     ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
     window`` in a BAND_EDGE one, both in a CROSSED one; in a
     BLOCK_DIAGONAL one those whose key block lies after the query's block
-    (at or after it when strict)."""
+    (at or after it when strict; in the halves' schedule the strictness is
+    in ``d``, traced: :func:`_halves_sweep`); in an OWN_BLOCK one those of
+    another block than the query's."""
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     window = sched.window
-    if kind == BLOCK_DIAGONAL:
+    if kind in (BLOCK_DIAGONAL, OWN_BLOCK):
         g, strict = sched.diag
         shift = g.bit_length() - 1          # g divides 128: a power of two
-        return jnp.where((row >> shift) + (d // g - int(strict))
-                         >= (col >> shift), s, NEG_INF)
+        if kind == OWN_BLOCK:
+            return jnp.where((row >> shift) + d // g == (col >> shift), s,
+                             NEG_INF)
+        back = d >> shift if strict == HALVES else d // g - int(strict)
+        return jnp.where((row >> shift) + back >= (col >> shift), s, NEG_INF)
     if kind == DIAGONAL:
         return jnp.where(row + d >= col, s, NEG_INF)
     inside = row + (d - window) < col
@@ -871,8 +987,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     key-value head ``h // (H // KV)``; head_dim a multiple of 128), and
     ``window`` keeps, of the causal keys, the last ``window``.  ``block``
     (a divisor of 128) makes the diagonal block-granular: query i keeps
-    key j iff ``i // block >= j // block``, ``>`` when ``strict`` (block
-    diffusion's clean half, and its noisy half against the clean keys).
+    key j iff ``i // block >= j // block``, ``>`` when ``strict`` (one
+    quadrant of block diffusion's mask: the clean half, the noisy half
+    against the clean keys; :func:`flash_attention_halves` runs the whole).
 
     The kernels read q, k, v and dO and write o, dq, dk and dv as
     ``(B, S, H·D)``, the layout the projections on either side use, so
@@ -910,26 +1027,53 @@ def _diag(block: Optional[int], strict: bool = False) -> Optional[tuple]:
     return int(block), bool(strict)
 
 
+def flash_attention_halves(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                           block: int, scale: Optional[float] = None,
+                           block_q: int = 512, block_k: int = 512,
+                           interpret: bool = False) -> jax.Array:
+    """Block diffusion's attention over ``[noisy ; clean]`` rows in one
+    forward and one backward call: ``q`` ``(B, 2L, H, D)``, ``k`` and ``v``
+    ``(B, 2L, KV, D)`` as the projections wrote them, the mask
+    ``ops/attention.py block_diffusion_mask`` with blocks of ``block``
+    positions (a divisor of 128 and of L).  Heads, layouts and types are
+    :func:`flash_attention`'s; the tiles are square and divide L, and the
+    schedule is ``diag = (block, HALVES)`` (:func:`score_tile_schedule`)."""
+    B, S2, _, _ = q.shape
+    if S2 % 2 or k.shape[1] != S2:
+        raise ValueError(f"{S2} query and {k.shape[1]} key positions are "
+                         f"not the two halves of one sequence")
+    g, _ = _diag(block)
+
+    def half(x):
+        return jax.ShapeDtypeStruct((B, S2 // 2) + x.shape[2:], x.dtype)
+
+    scale, block_q, block_k, lanes = _prepare(half(q), half(k), scale,
+                                              block_q, block_k)
+    tile = min(block_q, block_k)
+    out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), True,
+                 scale, tile, tile, lanes, interpret, None, (g, HALVES))
+    return _unpack(out, lanes, B)
+
+
 # ---------------------------------------------------------------------------
 # LSE-exposing variant — building block for distributed (ring) attention
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
-               diag=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
     return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret, diag)[0]
+                          interpret)[0]
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                   interpret, diag=None):
+                   interpret):
     out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret, None, diag)
+                          interpret)
     return (out, _row_heads(res[4], lanes)), res      # lse as (N, S, heads)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, diag,
-                   res, ct):
+def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
+                   ct):
     do, dlse = ct
     q, k, v, out, lse = res
     # the lse cotangent folds into the shared backward exactly:
@@ -937,7 +1081,7 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, diag,
     delta = _delta(do, out, lanes) - _head_rows(dlse.astype(jnp.float32),
                                                 lanes)
     return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, delta, None, diag)
+                           q, k, v, lse, do, delta)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -947,23 +1091,15 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              causal: bool = True,
                              scale: Optional[float] = None,
                              block_q: int = 512, block_k: int = 512,
-                             interpret: bool = False,
-                             block: Optional[int] = None,
-                             strict: bool = False):
+                             interpret: bool = False):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(B, S, H)`` — differentiable in BOTH outputs, which is what a
-    distributed (ring) attention needs to merge per-block results exactly,
-    and what block diffusion's noisy half needs to add its own block's
-    keys to the clean ones (``block``, ``strict``: as
-    :func:`flash_attention`'s).  A row that keeps no key (the first block
-    under ``strict``) comes back as output 0 and logsumexp 0: the caller
-    knows which those are.
+    distributed (ring) attention needs to merge per-block results exactly.
     """
     B, S, H, _ = q.shape
     scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
     out, lse = _flash_lse(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes),
-                          causal, scale, block_q, block_k, lanes, interpret,
-                          _diag(block, strict))
+                          causal, scale, block_q, block_k, lanes, interpret)
     if not lanes.rows:      # (B·H, S, 1): a head a panel
         lse = lse.reshape(B, H, S).transpose(0, 2, 1)
     return _unpack(out, lanes, B), lse

@@ -1,12 +1,15 @@
 """The flash kernels under block diffusion's masks (PR 40) in interpret
 mode against the dense mask: the block-granular diagonal ``q // g >= k //
 g``, its strict form, and the whole ``[noisy ; clean]`` attention
-(``ops/attention.py block_diffusion_attention``: two flash calls and the
-own block's XLA term) against ``block_diffusion_mask``: block lengths 4,
-16 and 32, sequences that are no multiple of the default tile, a looped
-sweep, grouped and ungrouped heads, forward and all three gradients, two
-rows that do not mix, the tile counter's classes against a brute-force
-count of kept entries, and what is refused by name.
+(``ops/attention.py block_diffusion_attention``: one flash call a pass
+over all ``2L`` rows, the noisy queries' own blocks a tile of the kernels'
+schedule, PR 41) against ``block_diffusion_mask``: block lengths 4, 16 and
+32, sequences that are no multiple of the default tile, one tile a half
+and several, the backward's halved tiles, grouped and ungrouped heads,
+forward and all three gradients on two rows of different content, the
+first block's rows (which keep no clean key) apart, the halves' sweep
+against its schedule tile by tile, the tile counter's classes against a
+brute-force count of kept entries, and what is refused by name.
 """
 import collections
 import importlib
@@ -133,12 +136,130 @@ def test_the_counter_names_the_new_kind(g):
     assert got["bwd", "block_diagonal"] == n
     assert got["fwd", "full"] == got["fwd", "void"] == n * (n - 1) // 2
     assert got["fwd", "diagonal"] == got["bwd", "diagonal"] == 0
+    # no call without the whole mask has a tile of a query's own block
+    assert got["fwd", "own_block"] == got["bwd", "own_block"] == 0
+
+
+@pytest.mark.parametrize("g", [4, 16, 32])
+@pytest.mark.parametrize("L,block", [(512, 512), (1024, 256), (384, 128)])
+def test_the_counter_names_the_own_tiles_kind(L, block, g):
+    """``flash_score_tiles_total{kind="own_block"}`` of one head-sequence
+    of ``[noisy ; clean]`` rows: ``L / block`` own tiles forward, the
+    sub-tiles on their diagonals backward (two a tile where the backward
+    halves it), beside the two block diagonals of the clean keys."""
+    q, k, v, w = _operands(1, 2 * L, 2, 1, 128, seed=g)
+
+    def count():
+        entry = get_registry().snapshot().get("flash_score_tiles_total")
+        out = collections.Counter()
+        for s in (entry or {"samples": []})["samples"]:
+            out[s["labels"]["pass"], s["labels"]["kind"]] += s["value"]
+        return out
+
+    before = count()
+    jax.make_jaxpr(jax.grad(lambda q: (fa.flash_attention_halves(
+        q, k, v, interpret=True, block=g, block_q=block, block_k=block)
+        * w).sum()))(q)
+    got = count() - before
+    n = L // block
+    halved = 2 if block % 256 == 0 else 1
+    assert got["fwd", "own_block"] == n
+    assert got["bwd", "own_block"] == halved * n
+    assert got["fwd", "block_diagonal"] == 2 * n
+    assert got["bwd", "block_diagonal"] == 2 * halved * n
+    assert got["fwd", "full"] == n * (n - 1)
+    assert got["fwd", "void"] == 4 * n * n - 3 * n - n * (n - 1)
+    assert got["fwd", "diagonal"] == got["bwd", "diagonal"] == 0
+
+
+@pytest.mark.parametrize("g", [4, 16, 32, 128])
+@pytest.mark.parametrize("L,block", [(512, 256), (384, 128), (512, 512)])
+def test_halves_tile_classes_match_a_brute_force_count(L, block, g):
+    """Each sub-tile of the halves' schedule against the kept entries of
+    ``block_diffusion_mask`` counted one by one, forward and backward: void
+    keeps none, full all, own_block some of noisy keys, block_diagonal some
+    of clean ones.  A block as long as a sub-tile is the one place where
+    the schedule masks a tile that keeps all or nothing (one body serves
+    both halves: ``_halves_tile_kind``)."""
+    keep = np.asarray(attention_lib.block_diffusion_mask(L, g))
+    assert keep.sum() == L * (L + g)
+    for halve in (False, True):
+        sched = fa.score_tile_schedule(2 * L, 2 * L, block, block, True,
+                                       halve, None, (g, fa.HALVES))
+        rows, cols = sched.sub_q, sched.sub_k
+        assert len(sched.tiles) == (2 * L // rows) * (2 * L // cols)
+        kept_by_class = collections.Counter()
+        for q0, k0, kind in sched.tiles:
+            kept = int(keep[q0:q0 + rows, k0:k0 + cols].sum())
+            kept_by_class[kind] += kept
+            masked = fa.OWN_BLOCK if k0 < L else fa.BLOCK_DIAGONAL
+            want = fa.VOID if kept == 0 else fa.FULL \
+                if kept == rows * cols else masked
+            if g == rows and kind == masked:
+                continue        # masked under both halves, whatever it keeps
+            assert kind == want, (q0, k0, kind, kept)
+        assert kept_by_class[fa.VOID] == 0
+        assert kept_by_class[fa.OWN_BLOCK] == L * g
+        assert sum(kept_by_class.values()) == L * (L + g)
+
+
+@pytest.mark.parametrize("own_is_q", [True, False])
+@pytest.mark.parametrize("L,block,halve", [(512, 128, False), (1024, 512, True),
+                                           (256, 256, True)])
+def test_the_halves_sweep_visits_what_the_schedule_lists(L, block, halve,
+                                                         own_is_q):
+    """``_halves_sweep`` for every program of the grid (a query tile
+    forward, a key tile backward): the full tiles it loops over, the masked
+    tiles it places and the strictness it hands their masks, against the
+    schedule's own tiles of that row or column."""
+    g = 4
+    sched = fa.score_tile_schedule(2 * L, 2 * L, block, block, True,
+                                   halve and not own_is_q, None,
+                                   (g, fa.HALVES))
+    n2 = 2 * L // block
+    whole = {(q0 // block, k0 // block): kind
+             for q0, k0, kind in fa.score_tile_schedule(
+                 2 * L, 2 * L, block, block, True, False, None,
+                 (g, fa.HALVES)).tiles}
+
+    def visited(own):
+        def full_tile(t0, c):
+            return c.at[t0 // block, 0].add(1)
+
+        def diagonal_tile(t0, d0, subs, c):
+            kinds = {kind for _, _, kind in subs} - {fa.FULL}
+            assert len(kinds) == 1
+            own_block = kinds == {fa.OWN_BLOCK}
+            return c.at[t0 // block, 1 + own_block].add(1).at[
+                t0 // block, 3].add(d0)
+
+        return np.asarray(jax.jit(lambda o: fa._halves_sweep(
+            o, sched, own_is_q=own_is_q)(
+            jnp.zeros((n2, 4), jnp.int32), full_tile, diagonal_tile))(own))
+
+    for own in range(n2):
+        want = np.zeros((n2, 4), np.int32)
+        for t in range(n2):
+            q_tile, k_tile = (own, t) if own_is_q else (t, own)
+            kind = whole[q_tile, k_tile]
+            if kind == fa.FULL:
+                want[t, 0] = 1
+            elif kind == fa.BLOCK_DIAGONAL:
+                want[t, 1] = 1
+                want[t, 3] = -g if q_tile < n2 // 2 else 0     # strict
+            elif kind == fa.OWN_BLOCK:
+                want[t, 2] = 1
+        np.testing.assert_array_equal(visited(jnp.int32(own)), want,
+                                      err_msg=f"program {own}")
 
 
 # (L, H, KV, D, g): grouped in the kernel (head_dim 128); head_dim 64, where
-# k and v are repeated first; L = 384 is three tiles a half
+# k and v are repeated first; L = 256, 384 and 512 are one tile a half, 640
+# five of 128, 768 three of 256 and 1024 two of 512 (the last two and 256,
+# 512 with the backward's halved tiles)
 HALVES = [(256, 4, 2, 128, 4), (384, 2, 2, 64, 16), (256, 4, 1, 64, 32),
-          (640, 2, 1, 128, 4)]
+          (640, 2, 1, 128, 4), (640, 2, 2, 64, 16), (768, 2, 1, 128, 32),
+          (1024, 2, 2, 128, 4), (512, 4, 2, 128, 16), (1024, 2, 1, 128, 32)]
 
 
 @pytest.mark.parametrize("L,H,KV,D,g", HALVES)
@@ -150,8 +271,13 @@ def test_noisy_and_clean_halves_match_the_dense_mask(one_device, L, H, KV,
     kern = lambda *a: attention_lib.block_diffusion_attention(
         *a, block=g, impl="flash", interpret=True)
     plain = lambda *a: _plain(*a, mask)
-    np.testing.assert_allclose(kern(q, k, v), plain(q, k, v), atol=2e-5)
+    out, want = kern(q, k, v), plain(q, k, v)
+    np.testing.assert_allclose(out, want, atol=2e-5)
     assert _flash_dispatches(g) == before + 1       # the kernels ran
+    # the first block's rows keep their own block's keys and no clean key
+    np.testing.assert_allclose(out[:, :g], want[:, :g], atol=2e-5,
+                               err_msg="first block")
+    assert float(jnp.abs(out[0] - out[1]).max()) > 0.1      # two contents
     got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: (plain(*a) * w).sum(), argnums=(0, 1, 2))(
         q, k, v)
@@ -161,6 +287,9 @@ def test_noisy_and_clean_halves_match_the_dense_mask(one_device, L, H, KV,
                               np.split(b, 2, 1)):
             np.testing.assert_allclose(x, y, atol=5e-5,
                                        err_msg=f"d{name} {half}")
+        np.testing.assert_allclose(a[:, :g], b[:, :g], atol=5e-5,
+                                   err_msg=f"d{name} first block")
+        assert float(np.abs(b[:, :g]).max()) > 1e-3
 
 
 def test_the_mask_is_the_four_sentences():
