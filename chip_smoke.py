@@ -533,9 +533,10 @@ def kernel_full_dispatch():
 def kernel_flash_window_gqa():
     """The third cell's attention at the cell's own shape
     (``train-mellum2-8k-1chip``: 4 rows of 8192 tokens, 32 query heads on
-    4 key-value heads of head_dim 128), with the 1024-key window and
-    without, forward and backward against a float32 reference computed in
-    query blocks (the whole score matrix would be 34 GB a pass).  Four rows
+    4 key-value heads of head_dim 128), with the 1024-key window, the
+    fourth cell's 2048 and without, forward and backward against a float32
+    reference computed in query blocks (the whole score matrix would be
+    34 GB a pass).  Four rows
     and four key-value heads, because the sum of dk, dv over a group lives
     on the chip's write-back of an output block whose index stays put until
     the group's last program and then moves on, to the next key-value head
@@ -578,7 +579,10 @@ def kernel_flash_window_gqa():
         return (fn(q, k, v).astype(jnp.float32)
                 * ct.astype(jnp.float32)).sum()
 
-    for window in (1024, None):
+    # 1024 and 2048 (the fourth cell's): straight-line sweeps of three and
+    # five tiles a program, the first query tiles' and the last key tiles'
+    # missing ones computed void (PR 43); None: the loop over pairs
+    for window in (1024, 2048, None):
         flash = lambda q, k, v: flash_attention(q, k, v, window=window)  # noqa: E731
         plain = lambda q, k, v: ref(q, k, v, window)                     # noqa: E731
         out = jax.jit(flash)(q, k, v)

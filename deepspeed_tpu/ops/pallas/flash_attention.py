@@ -57,6 +57,16 @@ from ...telemetry import registry as _registry
 NEG_INF = float("-inf")
 
 UNROLL_MAX = 4          # static-unroll K/Q sweeps at or below this length
+# A long windowed sweep meets the same few tiles in every program (window /
+# block + 1 or + 2 of them): at or below this many it is ONE straight-line
+# block (:func:`_sweep_form`).  Six covers a window of five blocks; the
+# forward and backward kernels of such a sweep at (3, 8192, 32 / 4, 128)
+# compile in 4.9 s against 3.9 as a loop (three tiles, Mellum 2: 6.2
+# against 5.5; five, Trinity: 6.3 against 3.4; sandbox compile for a
+# described v5e, PR 43), and a program at the start of a row computes up to
+# five of its six tiles void: past that a loop over the FULL tiles is the
+# better trade.
+STRAIGHT_MAX = 6
 
 VOID, FULL, DIAGONAL = "void", "full", "diagonal"
 # the band's lower edge crosses the tile; both edges do (window < a tile)
@@ -227,9 +237,11 @@ def score_tile_schedule(S: int, Sk: int, block_q: int, block_k: int,
                         tuple(tiles), window, diag)
 
 
-def _note_score_tiles(pass_: str, sched: TileSchedule) -> None:
+def _note_score_tiles(pass_: str, sched: TileSchedule, heads: int) -> None:
     """Count, at trace time, the sub-tiles a head-sequence visits or skips
-    in the kernel being traced (``pass_`` is ``"fwd"`` or ``"bwd"``)."""
+    in the kernel being traced (``pass_`` is ``"fwd"`` or ``"bwd"``), and
+    the basic blocks its sweeps are cut into (``heads`` a lane block)."""
+    _note_sweep_blocks(pass_, sched, heads)
     family = _registry.counter(
         "flash_score_tiles_total",
         "score sub-tiles of one head-sequence by what the flash kernel "
@@ -274,7 +286,58 @@ def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     return lo, lowest(nq, (own * bk + w - bq) // bq + 1)
 
 
-def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool):
+def _tiles_a_body(heads: int) -> int:
+    """FULL tiles a loop body folds, one or two.  Mosaic schedules one basic block at a
+    time, and a tile's fold is one dependent chain (scores, maximum,
+    exponential, product): alone in its block it has nothing to overlap
+    (2.5 us a 512 x 512 tile alone against 1.2 overlapped, PR 25).  A block
+    wants two chains: the heads of a lane block are independent ones, so two
+    heads a block (head_dim 64) keep one tile a body, and one head a block
+    (head_dim 128 and up, the two-product kernels) folds two."""
+    return max(1, 2 // heads)
+
+
+def _fold_run(n, fold_at, carry, per: int, *, whole: bool = False,
+              branch: bool = False):
+    """Thread ``carry`` through ``fold_at(u, carry, live)`` for ``u`` in
+    ``[0, n)``, in order, ``per`` a loop body.  What is left over when a
+    traced ``n`` is no multiple of ``per`` follows the loop as ``per - 1``
+    tiles, in one of two ways, both measured (v5e, PR 43, a call at (4,
+    8192, 32 / 4, 128), one tile a trip → void / branch).  Computed VOID
+    where ``u >= n`` (``live`` is the traced ``u < n``; None for a tile that
+    is always there), in straight-line code: one block with the sweep's
+    masked tiles that follow.  The forward's way: 20.32 → 19.08 / 19.46 ms
+    (two-product 13.32 → 12.69 / 12.69, the halves' 22.69 → 21.17 / 21.57).
+    Or under a BRANCH: the backward's way, 37.18 → 36.30 / 35.59 ms
+    (two-product 27.17 → 27.47 / 26.43): its tile is five products, two and
+    a half times the forward's, so a void one wastes more than a boundary
+    costs, and the block that follows already holds the diagonal tile's
+    three sub-tiles.  ``whole`` says that ``n`` is a multiple of ``per`` by
+    construction."""
+    def body(p, c):
+        for u in range(per):
+            c = fold_at(p * per + u, c, None)
+        return c
+
+    carry = jax.lax.fori_loop(0, n // per, body, carry)
+    if whole or per == 1:
+        return carry
+    if isinstance(n, int):
+        for u in range(n - n % per, n):
+            carry = fold_at(u, carry, None)
+        return carry
+    for u in range(per - 1):
+        at = n // per * per + u
+        if branch:
+            carry = jax.lax.cond(
+                at < n, functools.partial(fold_at, at, live=None),
+                lambda c: c, carry)
+        else:
+            carry = fold_at(at, carry, at < n)
+    return carry
+
+
+def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool, per: int = 1):
     """The ``sweep`` (:func:`_for_program`) of the program that owns tile
     ``own`` (the traced program id) of the halves' schedule, one body for
     both halves: which half ``own`` lies in decides at run time what it
@@ -284,10 +347,11 @@ def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool):
     Forward, a query tile: a noisy one folds its OWN_BLOCK tile, first, so
     that its first block's rows, which keep no clean key, never meet a
     running maximum of nothing; then either half folds the clean key tiles
-    before it and the one at it.  Backward, a key tile: a noisy one meets
-    its own query tile alone (its dk and dv are whole there), walked in
-    the sub-tiles on its diagonal; a clean one meets the query tiles past
-    it and the one at it, of both halves."""
+    before it, ``per`` a loop body (:func:`_fold_run`), and the one at it.
+    Backward, a key tile: a noisy one meets its own query tile alone (its dk
+    and dv are whole there), walked in the sub-tiles on its diagonal; a
+    clean one meets the query tiles past it, always an even number, and the
+    one at it, of both halves."""
     b, g = sched.block_q, sched.diag[0]
     n = sched.S // (2 * b)                          # tiles a half
     subs = dict(sched.diagonal)
@@ -299,18 +363,20 @@ def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool):
             return diagonal_tile(r * b, 0, subs[OWN_BLOCK], c)
 
         if own_is_q:
+            def before(t, c, live):     # clean key tile t <= r: in range
+                return full_tile((n + t) * b, c, live=live)
+
             carry = jax.lax.cond(noisy, own_block, lambda c: c, carry)
-            carry = jax.lax.fori_loop(
-                0, r, lambda t, c: full_tile((n + t) * b, c), carry)
+            carry = _fold_run(r, before, carry, per)
             return diagonal_tile((n + r) * b, jnp.where(noisy, -g, 0),
                                  subs[BLOCK_DIAGONAL], carry)
 
         def clean_keys(c):
-            def past(u, c):     # [r + 1, n) of the noisy, then of the clean
+            def past(u, c, live):   # [r + 1, n) of the noisy, then of the clean
                 t = r + 1 + u
                 return full_tile(jnp.where(t < n, t, t + r + 1) * b, c)
 
-            c = jax.lax.fori_loop(0, 2 * (n - 1 - r), past, c)
+            c = _fold_run(2 * (n - 1 - r), past, c, per, whole=True)
             return jax.lax.fori_loop(
                 0, 2, lambda h, c: diagonal_tile(
                     (h * n + r) * b, (h - 1) * g, subs[BLOCK_DIAGONAL], c), c)
@@ -321,7 +387,10 @@ def _halves_sweep(own, sched: TileSchedule, *, own_is_q: bool):
 
 
 def _is_looped(sched: TileSchedule, *, own_is_q: bool) -> bool:
-    """Whether a program's sweep is a loop (:func:`_for_program`)."""
+    """Whether a program's sweep places its tiles from the traced program
+    id (:func:`_for_program`): one body for every program, a loop or a
+    straight-line block (:func:`_sweep_form`), its backward summing dk and
+    dv in scratch."""
     if sched.halves:
         return True
     nq, nk = sched.S // sched.block_q, sched.Sk // sched.block_k
@@ -329,66 +398,173 @@ def _is_looped(sched: TileSchedule, *, own_is_q: bool) -> bool:
     return n_swept > UNROLL_MAX or (sched.causal and n_own > UNROLL_MAX)
 
 
-def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
+def _own_axis(sched: TileSchedule, own_is_q: bool):
+    """``(n_own, own_block, n_swept, swept_block, sign)`` of a sweep, with
+    ``own * own_block = t0 + sign * d0`` where ``d0 = q0 - k0``."""
+    nq, nk = sched.S // sched.block_q, sched.Sk // sched.block_k
+    if own_is_q:
+        return nq, sched.block_q, nk, sched.block_k, 1
+    return nk, sched.block_k, nq, sched.block_q, -1
+
+
+def _diagonal_of(o: int, sched: TileSchedule, own_is_q: bool):
+    """[(t0, d0, subs)] of the program that owns tile ``o`` (an int): the
+    masked tiles it meets."""
+    _, own_block, n_swept, swept_block, sign = _own_axis(sched, own_is_q)
+    met = [(o * own_block - sign * d0, d0, subs)
+           for d0, subs in sched.diagonal]
+    return [m for m in met if m[0] % swept_block == 0
+            and 0 <= m[0] < n_swept * swept_block]
+
+
+def _sweep_form(sched: TileSchedule, *, own_is_q: bool):
+    """``(straight, slots)`` of a sweep placed from the program id
+    (:func:`_is_looped`), from the schedule alone.  ``slots`` is the most
+    FULL tiles a program meets.  ``straight``: under a sliding window every
+    program but the first few (forward; the last few, backward) meets the
+    same ``slots`` FULL tiles and the same masked ones, so at ``STRAIGHT_MAX``
+    tiles or fewer the sweep is one straight-line block, the tiles a program
+    does not meet computed void.  Any other sweep loops over its FULL
+    tiles."""
+    n_own = _own_axis(sched, own_is_q)[0]
+    runs = [_full_tiles(o, sched, own_is_q=own_is_q) for o in range(n_own)]
+    slots = max(max(hi - lo, 0) for lo, hi in runs)
+    straight = (sched.window is not None
+                and slots + len(sched.diagonal) <= STRAIGHT_MAX)
+    return straight, slots
+
+
+def sweep_blocks(sched: TileSchedule, *, own_is_q: bool, heads: int) -> dict:
+    """The basic blocks that the sweeps of one head-sequence are cut into,
+    by form: ``straight`` a run of tiles in straight-line code (any number
+    of them: what Mosaic's scheduler overlaps), ``paired_loop`` and
+    ``single_loop`` one trip of a loop that folds two tiles or one,
+    ``branch`` a run under a ``lax.cond`` of the sweep, where a program
+    takes it.  From shapes alone, as the kernels decide."""
+    blocks = collections.Counter()
+    per = _tiles_a_body(heads)
+    loop = "paired_loop" if per > 1 else "single_loop"
+    n_own = _own_axis(sched, own_is_q)[0]
+    if sched.halves:
+        n = n_own // 2
+        for r in range(n):
+            if own_is_q:    # a noisy and a clean query tile r
+                blocks["branch"] += 1
+                blocks[loop] += 2 * (r // per)
+                blocks["straight"] += 2
+            else:           # a noisy key tile; a clean one
+                blocks["branch"] += 1
+                blocks[loop] += 2 * (n - 1 - r) // per
+                blocks["single_loop"] += 2
+        return {form: n for form, n in blocks.items() if n}
+    if not _is_looped(sched, own_is_q=own_is_q):
+        return {"straight": n_own}
+    straight, _ = _sweep_form(sched, own_is_q=own_is_q)
+    for o in range(n_own):
+        lo, hi = _full_tiles(o, sched, own_is_q=own_is_q)
+        blocks["straight"] += 1
+        if not straight:
+            blocks[loop] += max(hi - lo, 0) // per
+            if (sched.causal and sched.window is None and not own_is_q
+                    and (hi - lo) % per):
+                blocks["branch"] += 1   # the odd tile out (:func:`_fold_run`)
+    return {form: n for form, n in blocks.items() if n}
+
+
+def _note_sweep_blocks(pass_: str, sched: TileSchedule, heads: int) -> None:
+    """Count, at trace time beside :func:`_note_score_tiles`, the basic
+    blocks of one head-sequence's sweeps in the kernel being traced."""
+    family = _registry.counter(
+        "flash_sweep_blocks_total",
+        "basic blocks that the flash kernel's sweeps of one head-sequence "
+        "are cut into, by form: straight (a run of tiles in straight-line "
+        "code), paired_loop / single_loop (one trip of a loop over full "
+        "tiles that folds two / one), branch (a run under a lax.cond); with "
+        "flash_score_tiles_total, tiles a block (counted at trace time, not "
+        "per call)",
+        labelnames=("pass", "form"))
+    blocks = sweep_blocks(sched, own_is_q=pass_ == "fwd", heads=heads)
+    for form in ("straight", "paired_loop", "single_loop", "branch"):
+        family.labels(pass_, form).inc(blocks.get(form, 0))
+
+
+def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool,
+                 heads: int = 1):
     """Run ``program(sweep, looped)`` for the grid program that owns tile
     ``own`` of its axis (a query tile in the forward, a key tile in the
-    backward); ``looped`` says that the sweep is a loop.
+    backward); ``looped`` says that the sweep is placed from the traced
+    ``own`` (:func:`_is_looped`); ``heads`` is how many independent chains
+    a tile's fold already holds (:func:`_tiles_a_body`).
 
     ``sweep(carry, full_tile, diagonal_tile)`` threads ``carry`` through
-    ``full_tile(t0, carry)`` for every FULL tile the program meets and
-    ``diagonal_tile(t0, d0, subs, carry)`` for every DIAGONAL one; ``t0``
-    is the first position of the tile on the swept axis.  VOID tiles get
-    no code at all.
+    ``full_tile(t0, carry, live=None)`` for every FULL tile the program
+    meets and ``diagonal_tile(t0, d0, subs, carry, live=None)`` for every
+    masked one; ``t0`` is the first position of the tile on the swept axis.
+    VOID tiles get no code at all, but for one case: where ``live`` is
+    given (a traced flag) the tile is computed at a position clamped into
+    range and wholly masked where the flag is false (:func:`_band_mask`).
 
-    Short sweeps (S=1024, block 512 → 2 tiles) get one straight-line
-    program per value of ``own`` under a ``pl.when``: every position is
-    static and nothing separates the tiles, so Mosaic overlaps one tile's
-    matmuls with its neighbour's vector work (a branch per tile was 50%
-    slower on the v5e than computing the void tile as well).  Long sweeps
-    loop over the full tiles and place the diagonal ones from ``own``.
-    The halves' schedule has a sweep of its own (:func:`_halves_sweep`)."""
+    Mosaic schedules one basic block at a time, so every block of a sweep
+    should hold two tiles' worth of independent work.  Short sweeps
+    (S=1024, block 512 → 2 tiles) get one straight-line program per value
+    of ``own`` under a ``pl.when``: every position is static and nothing
+    separates the tiles, so Mosaic overlaps one tile's matmuls with its
+    neighbour's vector work (a branch per tile was 50% slower on the v5e
+    than computing the void tile as well).  Long sweeps are one body for
+    every program: under a sliding window one straight-line block
+    (:func:`_sweep_form`); else a loop over the FULL tiles, two a trip at
+    one head a lane block (:func:`_fold_run`), then the masked tiles placed
+    from ``own``.  A masked tile that only some programs meet is computed
+    void by the others; the one branch left is around the backward loop's
+    odd tile out, where the chip read it faster (:func:`_fold_run`), and
+    never under a window.  The halves' schedule has a sweep of its own
+    (:func:`_halves_sweep`)."""
+    per = _tiles_a_body(heads)
     if sched.halves:
-        return program(_halves_sweep(own, sched, own_is_q=own_is_q), True)
-    bq, bk = sched.block_q, sched.block_k
-    own_block, swept_block = (bq, bk) if own_is_q else (bk, bq)
-    n_own = (sched.S // bq) if own_is_q else (sched.Sk // bk)
-    n_swept = (sched.Sk // bk) if own_is_q else (sched.S // bq)
-    # own*own_block = t0 + sign*d0, with d0 = q0 - k0
-    sign = 1 if own_is_q else -1
-
-    def diagonal_of(o):
-        """[(t0, d0, subs)] of the program that owns tile ``o`` (an int)."""
-        met = [(o * own_block - sign * d0, d0, subs)
-               for d0, subs in sched.diagonal]
-        return [m for m in met if m[0] % swept_block == 0
-                and 0 <= m[0] < n_swept * swept_block]
+        return program(
+            _halves_sweep(own, sched, own_is_q=own_is_q, per=per), True)
+    n_own, own_block, n_swept, swept_block, sign = _own_axis(sched, own_is_q)
 
     def static_sweep(o):
         def sweep(carry, full_tile, diagonal_tile):
             for t in range(*_full_tiles(o, sched, own_is_q=own_is_q)):
                 carry = full_tile(t * swept_block, carry)
-            for t0, d0, subs in diagonal_of(o):
+            for t0, d0, subs in _diagonal_of(o, sched, own_is_q):
                 carry = diagonal_tile(t0, d0, subs, carry)
             return carry
         return sweep
 
     def dynamic_sweep(carry, full_tile, diagonal_tile):
+        straight, slots = _sweep_form(sched, own_is_q=own_is_q)
         lo, hi = _full_tiles(own, sched, own_is_q=own_is_q)
-        carry = jax.lax.fori_loop(
-            lo, hi, lambda t, c: full_tile(t * swept_block, c), carry)
+
+        def full_at(u, c, live):        # FULL tile lo + u of the sweep
+            t = lo + u
+            if live is not None:
+                t = jnp.clip(t, 0, n_swept - 1)
+            return full_tile(t * swept_block, c, live=live)
+
+        if straight:
+            for u in range(slots):
+                carry = full_at(u, carry, lo + u < hi)
+        else:   # traced bounds under a diagonal; no branch under a window
+            n = jnp.maximum(hi - lo, 0) if sched.causal else hi - lo
+            carry = _fold_run(n, full_at, carry, per,
+                              branch=not own_is_q and sched.window is None)
         for d0, subs in sched.diagonal:
-            # programs that meet a diagonal tile at this offset: all of
-            # them (the usual case: no branch), none, or some
-            meets = [any(m[1] == d0 for m in diagonal_of(o))
+            # programs that meet a masked tile at this offset: all of them
+            # (the usual case), none, or some: those compute it void
+            meets = [any(m[1] == d0 for m in _diagonal_of(o, sched, own_is_q))
                      for o in range(n_own)]
             t0 = own * own_block - sign * d0
-            step = functools.partial(diagonal_tile, t0, d0, subs)
             if all(meets):
-                carry = step(carry)
+                carry = diagonal_tile(t0, d0, subs, carry)
             elif any(meets):
-                carry = jax.lax.cond(
-                    (t0 >= 0) & (t0 % swept_block == 0)
-                    & (t0 < n_swept * swept_block), step, lambda c: c, carry)
+                live = ((t0 >= 0) & (t0 % swept_block == 0)
+                        & (t0 < n_swept * swept_block))
+                at = jnp.clip(t0 // swept_block, 0, n_swept - 1)
+                carry = diagonal_tile(at * swept_block, d0, subs, carry,
+                                      live=live)
         return carry
 
     if _is_looped(sched, own_is_q=own_is_q):
@@ -401,7 +577,7 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool):
                 functools.partial(program, static_sweep(o), False))
 
 
-def _band_mask(s, d: int, kind: str, sched: TileSchedule):
+def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None):
     """Void the entries of score tile ``s`` outside the band, its first
     query position lying ``d`` after its first key position: those with
     ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
@@ -409,7 +585,17 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule):
     BLOCK_DIAGONAL one those whose key block lies after the query's block
     (at or after it when strict; in the halves' schedule the strictness is
     in ``d``, traced: :func:`_halves_sweep`); in an OWN_BLOCK one those of
-    another block than the query's."""
+    another block than the query's; none in a FULL one (``kind`` None too).
+    ``live`` (a traced flag) voids all of them where it is false: this
+    program does not meet the tile and computes it in place of a branch
+    (:func:`_for_program`)."""
+    def keep(inside):
+        if live is not None:
+            inside = live if inside is None else inside & live
+        return jnp.where(inside, s, NEG_INF)
+
+    if kind in (None, FULL):
+        return keep(None)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     window = sched.window
@@ -417,16 +603,15 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule):
         g, strict = sched.diag
         shift = g.bit_length() - 1          # g divides 128: a power of two
         if kind == OWN_BLOCK:
-            return jnp.where((row >> shift) + d // g == (col >> shift), s,
-                             NEG_INF)
+            return keep((row >> shift) + d // g == (col >> shift))
         back = d >> shift if strict == HALVES else d // g - int(strict)
-        return jnp.where((row >> shift) + back >= (col >> shift), s, NEG_INF)
+        return keep((row >> shift) + back >= (col >> shift))
     if kind == DIAGONAL:
-        return jnp.where(row + d >= col, s, NEG_INF)
+        return keep(row + d >= col)
     inside = row + (d - window) < col
     if kind == CROSSED:
         inside &= row + d >= col
-    return jnp.where(inside, s, NEG_INF)
+    return keep(inside)
 
 
 def _col(x):
@@ -558,18 +743,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
             q = q_ref[0].astype(jnp.float32) * scale             # (bq, L)
             qs = [_head_lanes(q, h, lanes) for h in range(heads)]
 
-            def fold(k0, carry, d=None, kind=None):
+            def fold(k0, carry, d=None, kind=None, live=None):
                 """One online-softmax step a head: key tile [k0,
                 +block_k) into each ``(m, l, acc)``; ``d`` is the mask
-                offset and ``kind`` the tile's, None for a FULL tile."""
+                offset and ``kind`` the tile's, None for a FULL tile;
+                ``live`` is false where this program computes the tile
+                void (:func:`_band_mask`), None where it never does."""
                 ks = pl.ds(k0, sched.block_k)
                 k = _keep_lanes(k_ref[0, ks].astype(jnp.float32), 0, limit)
                 v = v_ref[0, ks].astype(jnp.float32)
                 out = []
                 for q_h, (m, l, acc) in zip(qs, carry):
                     s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
-                    if d is not None:
-                        s = _band_mask(s, d, kind, sched)
+                    if d is not None or live is not None:
+                        s = _band_mask(s, d, kind, sched, live)
                     m_new = jnp.maximum(m, s.max(axis=-1))
                     # rows with everything masked keep m=-inf; keep exp
                     # well-defined
@@ -580,9 +767,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
                                 acc * _col(corr) + _dot(p, v, ((1,), (0,)))))
                 return tuple(out)
 
-            def diagonal_tile(k0, d0, subs, carry):
+            def diagonal_tile(k0, d0, subs, carry, live=None):
                 assert len(subs) == 1   # the forward leaves them whole
-                return fold(k0, carry, d0, subs[0][2])
+                return fold(k0, carry, d0, subs[0][2], live)
 
             carry = sweep(((jnp.full((bq,), NEG_INF, jnp.float32),
                             jnp.zeros((bq,), jnp.float32),
@@ -598,7 +785,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
                 lse_ref[0, 0, h] = jnp.zeros((bq,), jnp.float32)
             o_ref[0] = _own_lanes(outs, lanes).astype(o_ref.dtype)
 
-        _for_program(i, sched, program, own_is_q=True)
+        _for_program(i, sched, program, own_is_q=True, heads=lanes.heads)
 
     _for_lane_block(lanes, lane_block)
 
@@ -649,10 +836,11 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             vs = [_head_lanes(v_blk, h, lanes) for h in heads]
 
             def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk,
-                      d=None, kind=None):
+                      d=None, kind=None, live=None):
                 """Queries [q0+r0, +rows) against keys [c0, +cols) of the
                 program's block; ``d`` is the mask offset and ``kind`` the
-                tile's, None for FULL.
+                tile's, None for FULL; ``live`` is false where this program
+                computes the tile void, None where it never does.
                 ``sums`` maps each head and key band to its ``(dk, dv)``
                 so far, or is None where they are summed in ``kv_acc``."""
                 rs = pl.ds(q0 + r0, rows)
@@ -664,8 +852,8 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 for h in heads:
                     k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
                     s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
-                    if d is not None:
-                        s = _band_mask(s, d, kind, sched)
+                    if d is not None or live is not None:
+                        s = _band_mask(s, d, kind, sched, live)
                     p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
                     dv = _dot(p, do, ((0,), (0,)))
                     dp = _dot(do, v, ((1,), (1,)))
@@ -683,10 +871,11 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_acc[rs] += dq
                 return sums
 
-            def diagonal_tile(q0, d0, subs, sums):
+            def diagonal_tile(q0, d0, subs, sums, live=None):
                 for r0, c0, kind in subs:
                     sums = visit(q0, sums, r0, c0, sched.sub_q, sk,
-                                 None if kind == FULL else d0 + r0 - c0, kind)
+                                 None if kind == FULL else d0 + r0 - c0, kind,
+                                 live)
                 return sums
 
             def store(ref, group_acc, b, size, per_head):
@@ -715,7 +904,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 store(dk_ref, group_dk, b, sk, [sums[h, b][0] for h in heads])
                 store(dv_ref, group_dv, b, sk, [sums[h, b][1] for h in heads])
 
-        _for_program(j, sched, program, own_is_q=False)
+        _for_program(j, sched, program, own_is_q=False, heads=lanes.heads)
 
     _for_lane_block(lanes, lane_block)
 
@@ -870,7 +1059,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
                window=None, diag=None):
     _note_score_tiles("fwd", score_tile_schedule(
         q.shape[1], k.shape[1], block_q, block_k, causal, False, window,
-        diag))
+        diag), lanes.heads)
     out, lse = _fwd_call(q, k, v, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k, lanes=lanes,
                          interpret=interpret, window=window, diag=diag)
@@ -893,7 +1082,8 @@ def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, window,
 def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
                     q, k, v, lse, do, delta, window=None, diag=None):
     _note_score_tiles("bwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, True, window, diag))
+        q.shape[1], k.shape[1], block_q, block_k, causal, True, window, diag),
+        lanes.heads)
     return _bwd_call(q, k, v, do, lse, delta, causal=causal, scale=scale,
                      block_q=block_q, block_k=block_k, lanes=lanes,
                      interpret=interpret, window=window, diag=diag)
@@ -1141,12 +1331,12 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, *,
         qr = _rope_head(qr_ref[0].astype(jnp.float32) * scale, c, per,
                         rope_dim)
 
-        def fold(k0, carry, d=None, kind=None):
+        def fold(k0, carry, d=None, kind=None, live=None):
             ks = pl.ds(k0, sched.block_k)
             s = _dot(qn, kn_ref[0, ks].astype(jnp.float32), ((1,), (1,))) \
                 + _dot(qr, kr_ref[0, ks].astype(jnp.float32), ((1,), (1,)))
-            if d is not None:
-                s = _band_mask(s, d, kind, sched)
+            if d is not None or live is not None:
+                s = _band_mask(s, d, kind, sched, live)
             m, l, acc = carry
             m_new = jnp.maximum(m, s.max(axis=-1))
             m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
@@ -1156,9 +1346,9 @@ def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, *,
             return (m_new, l * corr + p.sum(axis=-1),
                     acc * _col(corr) + _dot(p, v, ((1,), (0,))))
 
-        def diagonal_tile(k0, d0, subs, carry):
+        def diagonal_tile(k0, d0, subs, carry, live=None):
             assert len(subs) == 1   # the forward leaves them whole
-            return fold(k0, carry, d0, subs[0][2])
+            return fold(k0, carry, d0, subs[0][2], live)
 
         m, l, acc = sweep((jnp.full((bq,), NEG_INF, jnp.float32),
                            jnp.zeros((bq,), jnp.float32),
@@ -1205,7 +1395,7 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
         v_blk = v_ref[0].astype(jnp.float32)
 
         def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk, d=None,
-                  kind=None):
+                  kind=None, live=None):
             rs = pl.ds(q0 + r0, rows)
             qn = qn_ref[0, rs].astype(jnp.float32) * scale
             qr = _rope_head(qr_ref[0, rs].astype(jnp.float32) * scale, c,
@@ -1214,8 +1404,8 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
             kn, kr = _rows(kn_blk, c0, cols), _rows(kr_blk, c0, cols)
             v = _rows(v_blk, c0, cols)
             s = _dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
-            if d is not None:
-                s = _band_mask(s, d, kind, sched)
+            if d is not None or live is not None:
+                s = _band_mask(s, d, kind, sched, live)
             p = jnp.exp(s - _col(lse_ref[0, 0, 0, rs]))
             dv = _dot(p, do, ((0,), (0,)))
             dp = _dot(do, v, ((1,), (1,)))
@@ -1237,10 +1427,11 @@ def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
                                 zip(sums[b], (dkn, dv, dkr)))
             return sums
 
-        def diagonal_tile(q0, d0, subs, sums):
+        def diagonal_tile(q0, d0, subs, sums, live=None):
             for r0, c0, kind in subs:
                 sums = visit(q0, sums, r0, c0, sched.sub_q, sk,
-                             None if kind == FULL else d0 + r0 - c0, kind)
+                             None if kind == FULL else d0 + r0 - c0, kind,
+                             live)
             return sums
 
         def store(b, size, dkn, dv, dkr):
@@ -1415,7 +1606,7 @@ def _flash_mla(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
 def _flash_mla_fwd(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
                    interpret):
     _note_score_tiles("fwd", score_tile_schedule(
-        qn.shape[1], kn.shape[1], block_q, block_k, causal, False))
+        qn.shape[1], kn.shape[1], block_q, block_k, causal, False), 1)
     out, lse = _mla_fwd_call(qn, qr, kn, kr, v, causal=causal, scale=scale,
                              block_q=block_q, block_k=block_k, lanes=lanes,
                              interpret=interpret)
@@ -1428,7 +1619,7 @@ def _flash_mla_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
                    do):
     qn, qr, kn, kr, v, out, lse = res
     _note_score_tiles("bwd", score_tile_schedule(
-        qn.shape[1], kn.shape[1], block_q, block_k, causal, True))
+        qn.shape[1], kn.shape[1], block_q, block_k, causal, True), 1)
     delta = _delta(do, out, flash_lanes(lanes.heads, lanes.nope_dim))
     return _mla_bwd_call(qn, qr, kn, kr, v, do, lse, delta, causal=causal,
                          scale=scale, block_q=block_q, block_k=block_k,

@@ -377,3 +377,71 @@ def test_flash_unequal_blocks_parity(S, bq, bk):
     _assert_grads_close(
         lambda q, k, v: jnp.sum(flash_attention(q, k, v, **kw) ** 2),
         lambda q, k, v: jnp.sum(_ref(q, k, v, True) ** 2), (q, k, v))
+
+
+# --- the paired loops against the parent's one tile a trip (PR 43) ---------
+
+# (S, Sk, bq, bk, causal, H, D): eight tiles a row, so a causal program
+# meets 0..7 FULL tiles, odd and even counts, and the odd one left over is
+# computed void in the diagonal tile's block; seven (an odd static count
+# without the diagonal); cross lengths; two heads a lane block (head_dim 64
+# keeps one tile a trip); unequal blocks, where only some programs meet a
+# diagonal offset and the others compute it void instead of branching
+_PAIRED = [(1024, 1024, 128, 128, True, 1, 128),
+           (896, 896, 128, 128, True, 1, 128),
+           (1024, 1024, 128, 128, False, 1, 128),
+           (640, 896, 128, 128, False, 1, 128),
+           (1024, 1024, 128, 128, True, 2, 64),
+           (1024, 1024, 256, 128, True, 1, 128),
+           (1024, 1024, 128, 256, True, 1, 128)]
+
+
+def _paired_passes(S, Sk, bq, bk, causal, H, D):
+    from tests.unit.flash_parent_sweep import fa as fa_, passes
+
+    q, k, v = _qkv(B=2, S=S, H=H, D=D, seed=11)
+    do = q[::-1] * 0.5
+    if Sk != S:
+        k = jax.random.normal(jax.random.PRNGKey(12), (2, Sk, H, D))
+        v = jax.random.normal(jax.random.PRNGKey(13), (2, Sk, H, D))
+    if bq == bk:
+        return lambda: passes(q, k, v, do, block=bq, causal=causal)
+
+    def run():      # the public call picks the blocks it is given
+        out, vjp = jax.vjp(lambda *a: fa_.flash_attention(
+            *a, causal=causal, block_q=bq, block_k=bk, interpret=True),
+            q, k, v)
+        return (out, *vjp(do))
+
+    return run
+
+
+@pytest.mark.parametrize("S,Sk,bq,bk,causal,H,D", _PAIRED)
+def test_paired_sweeps_equal_the_parents_exactly(S, Sk, bq, bk, causal, H, D):
+    from tests.unit.flash_parent_sweep import assert_equal_to_the_parents
+
+    names = ("out", "lse", "dq", "dk", "dv") if bq == bk else (
+        "out", "dq", "dk", "dv")
+    assert_equal_to_the_parents(_paired_passes(S, Sk, bq, bk, causal, H, D),
+                                names)
+
+
+def test_a_short_sweep_is_the_parents_program():
+    """S 1024 in 512-tiles (the first cell): no loop and no new form, the
+    kernels' jaxprs are the parent's to the letter."""
+    from tests.unit.flash_parent_sweep import kernel_primitives, parent_sweeps
+
+    q, k, v = _qkv(B=1, S=1024, H=3, D=64)
+
+    def text():
+        return str(jax.make_jaxpr(lambda q, k, v: jax.vjp(
+            lambda *a: flash_attention(*a, interpret=True), q, k, v)[1](q))(
+                q, k, v))
+
+    new = text()
+    with parent_sweeps():
+        old = text()
+    assert new == old and "pallas_call" in new
+    run = lambda: jax.grad(lambda q: flash_attention(
+        q, k, v, interpret=True).sum())(q)
+    assert "while" not in kernel_primitives(run)

@@ -203,15 +203,17 @@ def test_halves_tile_classes_match_a_brute_force_count(L, block, g):
         assert sum(kept_by_class.values()) == L * (L + g)
 
 
+@pytest.mark.parametrize("per", [1, 2])
 @pytest.mark.parametrize("own_is_q", [True, False])
 @pytest.mark.parametrize("L,block,halve", [(512, 128, False), (1024, 512, True),
-                                           (256, 256, True)])
+                                           (256, 256, True), (896, 128, False)])
 def test_the_halves_sweep_visits_what_the_schedule_lists(L, block, halve,
-                                                         own_is_q):
+                                                         own_is_q, per):
     """``_halves_sweep`` for every program of the grid (a query tile
     forward, a key tile backward): the full tiles it loops over, the masked
     tiles it places and the strictness it hands their masks, against the
-    schedule's own tiles of that row or column."""
+    schedule's own tiles of that row or column; one FULL tile a loop trip
+    and two (PR 43: the odd one left over is folded void)."""
     g = 4
     sched = fa.score_tile_schedule(2 * L, 2 * L, block, block, True,
                                    halve and not own_is_q, None,
@@ -223,8 +225,9 @@ def test_the_halves_sweep_visits_what_the_schedule_lists(L, block, halve,
                  (g, fa.HALVES)).tiles}
 
     def visited(own):
-        def full_tile(t0, c):
-            return c.at[t0 // block, 0].add(1)
+        def full_tile(t0, c, live=None):    # a void tile counts nothing
+            return c.at[t0 // block, 0].add(
+                1 if live is None else live.astype(jnp.int32))
 
         def diagonal_tile(t0, d0, subs, c):
             kinds = {kind for _, _, kind in subs} - {fa.FULL}
@@ -234,7 +237,7 @@ def test_the_halves_sweep_visits_what_the_schedule_lists(L, block, halve,
                 t0 // block, 3].add(d0)
 
         return np.asarray(jax.jit(lambda o: fa._halves_sweep(
-            o, sched, own_is_q=own_is_q)(
+            o, sched, own_is_q=own_is_q, per=per)(
             jnp.zeros((n2, 4), jnp.int32), full_tile, diagonal_tile))(own))
 
     for own in range(n2):
@@ -385,3 +388,17 @@ def test_without_a_block_the_schedule_is_the_causal_one():
     assert [t[:2] for t in plain.tiles] == [t[:2] for t in blockwise.tiles]
     assert [t[2].replace("block_", "") for t in blockwise.tiles] \
         == [t[2] for t in plain.tiles]
+
+
+@pytest.mark.parametrize("L", [1024, 896])
+def test_the_halves_paired_sweeps_equal_the_parents_exactly(L):
+    """The halves' loops over clean FULL tiles fold two a trip (PR 43): out,
+    lse, dq, dk and dv equal the parent's one-tile-a-trip sweeps entry for
+    entry, at eight and at seven tiles a half (noisy and clean programs
+    with odd and even counts of FULL tiles before them)."""
+    from tests.unit.flash_parent_sweep import assert_equal_to_the_parents, passes
+
+    q, k, v, do = _operands(2, 2 * L, 2, 1, 128, seed=9)
+    assert_equal_to_the_parents(
+        lambda: passes(q, k, v, do, block=128, halves=4),
+        ("out", "lse", "dq", "dk", "dv"))

@@ -165,3 +165,17 @@ def test_the_dispatcher_says_what_ran_and_why():
     with pytest.raises(ValueError, match="no two-product kernel"):
         fa.flash_attention_mla(small[0], small[3], small[1], small[4],
                                small[2], interpret=True)
+
+
+@pytest.mark.parametrize("S", [1024, 896])
+def test_paired_sweeps_equal_the_parents_exactly(S):
+    """The two-product kernels fold two FULL tiles a loop trip (PR 43): all
+    seven results equal the parent's one-tile-a-trip sweep entry for entry,
+    at eight tiles a row (programs with odd and even counts of FULL tiles)
+    and at seven."""
+    from tests.unit.flash_parent_sweep import assert_equal_to_the_parents, mla_passes
+
+    ops = _operands(2, S, seed=7, heads=2)
+    assert_equal_to_the_parents(
+        lambda: mla_passes(*ops, block=128),
+        ("out", "lse", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"))

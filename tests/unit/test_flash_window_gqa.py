@@ -158,3 +158,103 @@ def test_tile_kind(d, rows, cols, window, kind):
     keep = (back >= 0) & (back < (window or 10 ** 9))
     assert {VOID: not keep.any(), FULL: keep.all()}.get(
         kind, keep.any() and not keep.all())
+
+
+# --- the straight-line windowed sweeps against the parent's (PR 43) --------
+
+def _passes(S, H, KV, block, window, B=2, seed=5):
+    from tests.unit.flash_parent_sweep import passes
+
+    q, k, v, do = _qkv(S, H, KV, B=B, seed=seed)
+    return lambda: passes(q, k, v, do, block=block, window=window)
+
+
+# the cells' schedules (S 8192 in 512-blocks) cut to 128-blocks: a window
+# under a block (every masked tile crossed by both edges), of one block
+# (Mellum 2's 1024: one FULL, one DIAGONAL, one BAND_EDGE tile a program),
+# of no whole number of blocks, of two (Trinity's 2048: three FULL tiles),
+# and one of seven tiles a program, past STRAIGHT_MAX: a loop, its masked
+# tiles void where a program does not meet them.  Every program is in the
+# comparison: the first query tiles and the last key tiles, whose void
+# tiles are computed now, too.
+@pytest.mark.parametrize("H,KV", [(2, 1), (2, 2)])
+@pytest.mark.parametrize("S,window", [(1024, 64), (1024, 128), (1024, 200),
+                                      (1024, 256), (896, 256), (1024, 768)])
+def test_windowed_sweeps_equal_the_parents_exactly(S, window, H, KV):
+    """out, lse, dq, dk and dv of the straight-line sweep, entry for entry:
+    it folds the parent's tiles in the parent's order, and a tile computed
+    void adds exact zeros."""
+    from tests.unit.flash_parent_sweep import assert_equal_to_the_parents
+
+    assert_equal_to_the_parents(_passes(S, H, KV, 128, window),
+                                ("out", "lse", "dq", "dk", "dv"))
+
+
+@pytest.mark.parametrize("window,straight", [(64, True), (128, True),
+                                             (256, True), (768, False)])
+def test_no_branch_and_no_one_trip_loop_is_left_in_a_windowed_sweep(
+        window, straight):
+    """The kernels' jaxprs: the parent's sweep holds a ``while`` a pass
+    (the loop over FULL tiles, one trip at a window of one block) and a
+    ``cond`` a pass around the band-edge tile; the change's holds neither
+    up to STRAIGHT_MAX tiles a program, and past it the loop alone.  The
+    two ``cond`` that stay are the backward's ``pl.when`` around dq's
+    scratch, once a program."""
+    from tests.unit.flash_parent_sweep import kernel_primitives, parent_sweeps
+
+    run = _passes(1024, 2, 1, 128, window, B=1)
+    assert kernel_primitives(run) == (
+        {"cond": 2} if straight else {"cond": 2, "while": 2})
+    with parent_sweeps():
+        assert kernel_primitives(run) == {"cond": 4, "while": 2}
+
+
+def _blocks(S, window, heads=1):
+    from deepspeed_tpu.ops.pallas.flash_attention import sweep_blocks
+
+    return {pass_: sweep_blocks(
+        score_tile_schedule(S, S, 512, 512, True, pass_ == "bwd", window),
+        own_is_q=pass_ == "fwd", heads=heads) for pass_ in ("fwd", "bwd")}
+
+
+@pytest.mark.parametrize("cell,window,full_pairs", [
+    ("train-mellum2-8k-1chip", 1024, 56),
+    ("train-trinity-mini-8k-1chip", 2048, 56),
+    ("train-joyai-flash-8k-1chip", None, 56)])
+def test_sweep_blocks_at_the_cells_shapes(cell, window, full_pairs):
+    """``flash_sweep_blocks_total`` a head-sequence of 8192 in 512-tiles, one
+    head a lane block: a window layer is 16 straight-line blocks a pass and
+    nothing else (the parent's: 16 + 15 loop trips + 14 taken branches at
+    window 1024 for the same 45 tiles); a full layer (the two-product
+    kernels' too) loops over pairs, sum of floor(n / 2) for n = 0..15, and
+    ends every program in one straight-line block; the backward's odd tile
+    out is the one branch left (``_fold_run`` has both readings)."""
+    from deepspeed_tpu.telemetry import get_registry
+
+    if window is not None:
+        assert _blocks(8192, window) == {"fwd": {"straight": 16},
+                                         "bwd": {"straight": 16}}
+    want = {"straight": 16, "paired_loop": full_pairs}
+    # the backward's odd tile out sits under a branch: 8 of its 16 programs
+    assert _blocks(8192, None) == {"fwd": want, "bwd": {**want, "branch": 8}}
+    # two heads a lane block are two chains already: one tile a trip
+    assert _blocks(8192, None, heads=2)["fwd"] == {"straight": 16,
+                                                   "single_loop": 120}
+
+    def counts():
+        entry = get_registry().snapshot().get("flash_sweep_blocks_total")
+        return {} if not entry else {
+            (s["labels"]["pass"], s["labels"]["form"]): s["value"]
+            for s in entry["samples"]}
+
+    q, k, v, _ = _qkv(8192, 2, 1)
+    before = counts()
+    jax.eval_shape(jax.grad(lambda q: flash_attention(
+        q, k, v, window=window).sum()), q)
+    delta = {key: n - before.get(key, 0) for key, n in counts().items()}
+    want = {"straight": 16} if window else {"straight": 16,
+                                            "paired_loop": full_pairs}
+    assert delta == {(p, form): {**want, "branch": 0 if window or p == "fwd"
+                                 else 8}.get(form, 0) for p in ("fwd", "bwd")
+                     for form in ("straight", "paired_loop", "single_loop",
+                                  "branch")}

@@ -403,19 +403,23 @@ def test_stored_transposed_is_the_chips_own_layout(one_chip):
             assert stored_transposed(s) == (order == "0,1"), (s, dtype, order)
 
 
-@pytest.mark.parametrize("window", [1024, None])
-def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window):
+@pytest.mark.parametrize("window,B", [(1024, 4), (None, 4), (2048, 3)])
+def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window, B):
     """The flash forward and backward of ``train-mellum2-8k-1chip``: 4 rows
     of 8192, 32 query heads on 4 key-value heads of 128, with the 1024-key
-    window and without.  The backward's panels of q, dO and dq beside the
+    window and without; and of ``train-trinity-mini-8k-1chip``'s window
+    layers (3 rows, 2048 keys).  Since PR 43 a windowed sweep is ONE
+    straight-line block of three (five) tiles and the loop over full tiles
+    folds two a trip: the bodies' values must still fit the VMEM the calls
+    ask for.  The backward's panels of q, dO and dq beside the
     float32 sums of a key-value head's dk and dv pass Mosaic's default 16
     MB of VMEM, so the call asks for what it holds; k, v, dk and dv are
-    ``[4,8192,512]`` on both sides of both calls and nothing key- or
+    ``[B,8192,512]`` on both sides of both calls and nothing key- or
     value-shaped is 4096 wide; the calls carry the layer type's name."""
     from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
                                                           flash_lanes)
 
-    B, S, H, KV, D = 4, 8192, 32, 4, 128
+    S, H, KV, D = 8192, 32, 4, 128
     assert flash_lanes(H, D).reason == "rows layout, 1 head a 128-lane block"
     scope = "self_attn_window" if window else "self_attn_full"
 
@@ -707,3 +711,57 @@ def test_the_sixth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert len(calls) == len(fwd) + len(bwd)
     assert not re.search(r"bf16\[2,(8192|16384),4,8,128\]", text)   # no k/v repeat
     assert "bf16[2,8192,4096]" not in text       # no half of q sliced out
+
+
+def test_the_halves_kernels_compile_at_the_sixth_cells_shape(one_chip):
+    """The flash forward and backward of ``train-sdar-blockdiff-8k-1chip``
+    alone: 2 rows of ``[noisy ; clean]`` = 16,384 positions, 32 query heads
+    on 4 key-value heads of 128, blocks of 4.  Their loops over clean FULL
+    tiles fold two a trip (PR 43) beside 2L-row panels that already ask
+    Mosaic for 68 MB of VMEM."""
+    from deepspeed_tpu.ops.pallas.flash_attention import \
+        flash_attention_halves
+
+    B, L, H, KV, D = 2, 8192, 32, 4, 128
+
+    def loss(q, k, v):
+        return flash_attention_halves(
+            q.reshape(B, 2 * L, H, D), k.reshape(B, 2 * L, KV, D),
+            v.reshape(B, 2 * L, KV, D), block=4).astype(jnp.float32).sum()
+
+    wide = jax.ShapeDtypeStruct((B, 2 * L, H * D), jnp.bfloat16,
+                                sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((B, 2 * L, KV * D), jnp.bfloat16,
+                                  sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        wide, narrow, narrow).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+
+
+def test_the_first_cells_flash_kernels_are_the_parents(one_chip):
+    """``train-xl-z3-1chip``'s attention, (2, 1024, 25, 64) in 512-tiles: a
+    sweep of two tiles is no loop and takes neither new form (PR 43), so the
+    kernels trace to the parent's jaxprs, to the letter (the parent's sweeps
+    are kept beside the tests), and still compile."""
+    from tests.unit.flash_parent_sweep import (kernel_primitives,
+                                               parent_sweeps)
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, S, H, D = 2, 1024, 25, 64
+    arg = jax.ShapeDtypeStruct((B, S, H, D), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    def text():
+        return str(jax.make_jaxpr(lambda *a: grads(*a))(arg, arg, arg))
+
+    new = text()
+    with parent_sweeps():
+        old = text()
+    assert new == old and new.count("pallas_call") == 2
+    found = kernel_primitives(grads, arg, arg, arg)
+    assert "while" not in found and "scan" not in found
+    jax.jit(grads).lower(arg, arg, arg).compile()
