@@ -608,12 +608,13 @@ def kernel_flash_window_gqa():
             assert worst.max() <= TOL, (name, n, worst)
 
 
-def kernel_flash_mla():
+def kernel_flash_two_products():
     """The fifth cell's attention at the cell's own shape
     (``train-joyai-flash-8k-1chip``: 2 rows of 8192 tokens, 32 heads of 128
     nope + 64 rope channels, ONE rope key for all heads, values 128 wide):
-    the two-product flash kernels, forward and all five gradients, against
-    a float32 reference computed in query blocks.  Two rows, because the
+    the flash kernels under a score of two products (``flash_attention(...,
+    q_rope=, k_rope=)``), forward and all five gradients, against a float32
+    reference computed in query blocks.  Two rows, because the
     sum of ``dk_rope`` over the 32 heads lives on the chip's write-back of
     an output block whose index stays put until the last head's programs
     and then moves on to the next row: one row never moves it, and
@@ -624,7 +625,7 @@ def kernel_flash_mla():
     import jax.numpy as jnp
     import numpy as np
 
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_mla
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     B, S, H, D, R, QB = 2, 8192, 32, 128, 64, 256
     ks = jax.random.split(jax.random.PRNGKey(38), 6)
@@ -654,11 +655,14 @@ def kernel_flash_mla():
                                   jnp.arange(0, S, QB)))
         return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, D)
 
+    def flash(qn, qr, kn, kr, v):
+        return flash_attention(qn, kn, v, q_rope=qr, k_rope=kr)
+
     def loss(fn, *a):
         return (fn(*a).astype(jnp.float32) * ct.astype(jnp.float32)).sum()
 
-    out = jax.jit(flash_attention_mla)(*ops)
-    grads = jax.jit(jax.grad(lambda *a: loss(flash_attention_mla, *a),
+    out = jax.jit(flash)(*ops)
+    grads = jax.jit(jax.grad(lambda *a: loss(flash, *a),
                              argnums=range(5)))(*ops)
     with jax.default_matmul_precision("highest"):
         out_r = jax.jit(ref)(*ops)
@@ -827,8 +831,9 @@ def kernel_qk_rows():
                                                                  worst)
 
 
-KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa, kernel_flash_mla,
-                kernel_flash_blockdiff, kernel_qk_rows,
+KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
+                kernel_flash_two_products, kernel_flash_blockdiff,
+                kernel_qk_rows,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

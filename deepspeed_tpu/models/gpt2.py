@@ -470,7 +470,7 @@ class GPT2LMHeadModel(nn.Module):
             tgt = shift_labels(labels) if shift else labels
             # pallas CE has no shard_map wrapper: its (E,Vp) dw reduction
             # would replicate on a sharded mesh.  Same dispatch contract
-            # as _flash_spmd — "direct" (single device) only, else the
+            # as the flash kernels' — "direct" (single device) only, else the
             # SPMD-safe chunked XLA head.
             use_pallas_ce = (cfg.loss_pallas and on_tpu()
                              and _ce_supported(cfg.padded_vocab_size))

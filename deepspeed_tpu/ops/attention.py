@@ -4,22 +4,33 @@ The reference ships attention as fused CUDA (training kernel
 ``csrc/transformer/ds_transformer_cuda.cpp``; inference softmax w/
 triangular masking + KV-cache ``csrc/transformer/inference/csrc/softmax.cu``)
 and Triton block-sparse (``deepspeed/ops/sparse_attention/``).  Here the
-same surface dispatches between:
+same surface dispatches between the implementations :data:`IMPLS` names,
+and no other:
 
-- ``"jnp"``   — XLA-fused reference implementation (also the CPU-test path)
-- ``"flash"`` — Pallas flash-attention kernel (``ops/pallas/flash_attention.py``)
-- ``"auto"``  — flash on TPU when shapes allow, else jnp
+- ``"jnp"``     — XLA-fused reference implementation (also the CPU-test path)
+- ``"flash"``   — Pallas flash-attention kernels (``ops/pallas/flash_attention.py``)
+- ``"auto"``    — flash on a TPU when shapes allow, else jnp
+- ``"ring"``    — sequence-parallel ring exchange over the ``sp`` mesh axis
+- ``"ulysses"`` — sequence-parallel all-to-all over the ``sp`` mesh axis
+
+What is asked of :func:`dot_product_attention` is data, not a choice of
+entry point: causal or not, a sliding ``window``, block diffusion's mask
+over ``[noisy ; clean]`` rows (``block_diffusion``, a block length), a
+second score product (``q_rope``, ``k_rope``), a dense ``mask`` / ``bias`` /
+dropout (XLA only).  One function decides which implementation runs a call
+and tells ``kernel_dispatch_total{site="attention"}`` (:func:`_plan`).
 
 Shapes follow the JAX convention ``(batch, seq, heads, head_dim)``.
 """
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+IMPLS = ("auto", "flash", "jnp", "ring", "ulysses")
 
 
 def on_tpu() -> bool:
@@ -29,18 +40,80 @@ def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def _pick_impl(impl: str, q) -> tuple:
-    """``(impl, reason)``: the flash kernel needs a TPU and seq/head_dim
-    tiling; ``auto`` picks the XLA path otherwise."""
-    if impl != "auto":
-        return impl, f"impl={impl!r} requested"
-    if not on_tpu():
-        return "jnp", "auto: not a TPU"
-    if q.shape[1] < 128:
-        return "jnp", f"auto: seq {q.shape[1]} < 128"
-    if q.shape[3] not in (64, 128, 256):
-        return "jnp", f"auto: head_dim {q.shape[3]} not in (64, 128, 256)"
-    return "flash", "auto: TPU, seq >= 128, head_dim tiles"
+class _Plan(NamedTuple):
+    impl: str           # "flash" or "jnp"
+    reason: str         # kernel_dispatch_total's label
+    # flash: a shard_map over these mesh axes (heads over ``tp``), or None:
+    # the operands are one device's own
+    batch_axes: Optional[tuple] = None
+    tp: int = 1
+    # flash: k and v go to q's heads first (the kernels group whole heads of
+    # a multiple of 128 lanes, and whole key-value heads a ``tp`` rank)
+    repeat_kv: bool = False
+
+
+def _plan(impl: str, q, k, v, *, window, block, q_rope, dense: bool,
+          interpret: bool) -> _Plan:
+    """Which implementation runs this attention, and why: the flash kernels
+    where they were asked for (``"auto"``: on a TPU, at a sequence of 128
+    and more, at widths that tile), take the form and know the operands'
+    sharding (``kernel_mesh_plan``: one device's own, or split over batch
+    axes, heads over ``tp`` where the form is written for it: the plain
+    one); XLA otherwise.  The one caller notes the verdict."""
+    from .pallas.flash_attention import (flash_lanes, grouped_in_kernel,
+                                         mla_lanes)
+    from .pallas.spmd import kernel_mesh_plan
+
+    B, S, H, D = q.shape
+    group = H // k.shape[2]
+    form = "" if block is None else \
+        f"; block diffusion over [noisy ; clean], block length {block}"
+
+    def xla(reason):
+        return _Plan("jnp", reason + (form and form + ", dense mask"))
+
+    how = f"impl={impl!r} requested"
+    if impl == "jnp":
+        return xla(how)
+    if impl == "auto":
+        seq = S if block is None else S // 2
+        if not (interpret or on_tpu()):
+            return xla("auto: not a TPU")
+        if seq < 128:
+            return xla(f"auto: seq {seq} < 128")
+        if q_rope is None and D not in (64, 128, 256):
+            return xla(f"auto: head_dim {D} not in (64, 128, 256)")
+        how = "auto: TPU, seq >= 128, head_dim tiles"
+    if q_rope is not None and mla_lanes(H, D, q_rope.shape[-1],
+                                        v.shape[-1]) is None:
+        return xla(f"no two-product kernel at {D} + {q_rope.shape[-1]} rope "
+                   f"lanes, v {v.shape[-1]}")
+    if dense:
+        return xla("bias/mask/dropout need the XLA path")
+    plain = block is None and q_rope is None    # written for heads over tp
+    verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=plain)
+    if verdict is None:
+        return xla("kernel_mesh_plan refused the mesh")
+    tp = 1
+    if verdict == "shard":
+        from ..comm.mesh import get_mesh
+
+        tp = get_mesh().shape.get("tp", 1)
+    said = [how, "one device" if verdict == "direct"
+            else f"shard_map over batch axes {axes}",
+            flash_lanes(H // tp, D).reason]     # the layout a shard runs
+    if q_rope is not None:
+        said.append(f"{D} + {q_rope.shape[-1]} shared rope lanes, "
+                    f"v {v.shape[-1]}")
+    if window is not None:
+        said.append(f"window {window}")
+    reason = "; ".join(said) + form
+    repeat = group > 1 and not (grouped_in_kernel(D) and k.shape[2] % tp == 0)
+    if group > 1:
+        reason += f"; k and v repeated {group}x to q's heads" if repeat \
+            else f"; {group} query heads a key-value head"
+    return _Plan("flash", reason, axes if verdict == "shard" else None, tp,
+                 repeat)
 
 
 def dot_product_attention(
@@ -59,14 +132,19 @@ def dot_product_attention(
     flash_opts: Optional[dict] = None,
     q_rope: Optional[jax.Array] = None,     # (B, S, H, R)
     k_rope: Optional[jax.Array] = None,     # (B, T, 1, R): one key, all heads
+    block_diffusion: Optional[int] = None,  # block length; rows [noisy ; clean]
+    interpret: bool = False,                # the kernels' interpreter (tests)
 ) -> jax.Array:
     """Multi-head scaled dot-product attention; returns ``(B, S, H, D)``.
 
     With ``q_rope`` and ``k_rope`` the score is a sum of two products
     (latent attention): ``q_h · k_h + q_rope_h · k_rope``, the second
     against ONE rotated key that all heads share, scaled by
-    ``(D + R) ** -0.5`` unless ``scale`` says otherwise
-    (:func:`_two_product_attention`).
+    ``(D + R) ** -0.5`` unless ``scale`` says otherwise.
+
+    With ``block_diffusion`` the rows are the two halves ``[noisy ; clean]``
+    of one sequence under :func:`block_diffusion_mask`
+    (:func:`block_diffusion_attention` checks the rows first).
 
     ``impl="ring"`` / ``"ulysses"`` are the sequence-parallel paths: the
     sequence dim must be sharded on the ``sp`` mesh axis (the engine does
@@ -75,80 +153,76 @@ def dot_product_attention(
 
     Grouped queries and a sliding ``window`` are the flash kernel's and
     the XLA path's; where neither takes them as they are (the sequence-
-    parallel and stock-JAX paths, a head_dim the kernel cannot group, heads
-    split over ``tp``), k and v are repeated to q's heads first, and a
-    window raises rather than run full attention.
+    parallel paths, a head_dim the kernel cannot group, heads split over
+    ``tp``), k and v are repeated to q's heads first, and a window raises
+    rather than run full attention.
     """
-    if q_rope is not None:
-        if bias is not None or dropout_rate != 0.0 or window is not None:
-            raise NotImplementedError(
-                "a second score product (q_rope, k_rope) with a bias, "
-                "dropout or a sliding window: none is written for it")
-        return _two_product_attention(q, k, v, q_rope, k_rope, causal=causal,
-                                      scale=scale, impl=impl, mask=mask)
+    from .pallas.spmd import note_dispatch
+
+    if impl not in IMPLS:
+        raise ValueError(f"attention impl {impl!r}: one of {IMPLS}")
+    dense = bias is not None or mask is not None or dropout_rate != 0.0
+    if q_rope is not None and (bias is not None or dropout_rate != 0.0
+                               or window is not None
+                               or block_diffusion is not None):
+        raise NotImplementedError(
+            "a second score product (q_rope, k_rope) with a bias, dropout, "
+            "a sliding window or block diffusion: none is written for it")
+    if block_diffusion is not None and (dense or window is not None):
+        raise NotImplementedError(
+            "block diffusion with a mask, bias, dropout or a sliding "
+            "window: none is written for it")
     group = q.shape[2] // k.shape[2]
     if q.shape[2] != group * k.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads are no multiple of "
                          f"{k.shape[2]} key-value heads")
     if window is not None and not causal:
         raise ValueError("a sliding window is causal")
-    if impl in ("ring", "ulysses", "flash_jax"):
-        if window is not None:
-            raise NotImplementedError(
-                f"impl={impl!r} has no sliding window; 'flash', 'jnp' and "
-                f"'auto' do")
-        k, v = _repeat_kv(k, v, group)
-        group = 1
     if impl in ("ring", "ulysses"):
-        return _sp_attention(q, k, v, causal=causal, scale=scale, kind=impl)
-    if impl == "skip":
-        # measurement probe ONLY: attention replaced by identity-on-q so
-        # an e2e A/B isolates the attention kernel's true step-time share
-        # (isolated kernel probes mislead — see BENCH_NORTHSTAR.md).
-        # Gated: outside the probe harness this silently produces garbage.
-        if not os.environ.get("DS_TPU_ALLOW_SKIP_ATTN"):
-            raise ValueError(
-                "attn impl='skip' disables attention entirely (identity on "
-                "q) and exists only for step-time A/B probes; set "
-                "DS_TPU_ALLOW_SKIP_ATTN=1 if that is really what you want")
-        return q
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+        if window is not None or q_rope is not None \
+                or block_diffusion is not None:
+            raise NotImplementedError(
+                f"impl={impl!r} has no sliding window, second score product "
+                f"or block-diffusion mask (no sequence-parallel form); "
+                f"'flash', 'jnp' and 'auto' do")
+        return _sp_attention(q, *_repeat_kv(k, v, group), causal=causal,
+                             scale=scale, kind=impl)
+    plan = _plan(impl, q, k, v, window=window, block=block_diffusion,
+                 q_rope=q_rope, dense=dense, interpret=interpret)
+    note_dispatch("attention", plan.impl, plan.reason)
+    if plan.impl == "jnp":
+        if block_diffusion is not None:
+            causal = False
+            mask = block_diffusion_mask(q.shape[1] // 2,
+                                        block_diffusion)[None, None]
+        return _jnp_attention(q, k, v, causal=causal, bias=bias, mask=mask,
+                              dropout_rate=dropout_rate,
+                              dropout_rng=dropout_rng, scale=scale,
+                              window=window, q_rope=q_rope, k_rope=k_rope)
+    from .pallas.flash_attention import (flash_attention,
+                                         flash_attention_halves)
 
-    impl, reason = _pick_impl(impl, q)
-    if impl in ("flash", "flash_jax"):
-        if bias is not None or mask is not None or dropout_rate != 0.0:
-            reason = "bias/mask/dropout need the XLA path"
-        else:
-            out = _flash_spmd(q, k, v, causal=causal, scale=scale,
-                              window=window, flash_opts=flash_opts) \
-                if impl == "flash" \
-                else _flash_jax(q, k, v, causal=causal, scale=scale)
-            if out is not None:
-                verdict, axes = kernel_mesh_plan(q.shape[0], heads=q.shape[2],
-                                                 allow_tp=True)
-                plan = "one device" if verdict == "direct" \
-                    else f"shard_map over batch axes {axes}"
-                if impl == "flash":     # the layout a shard's kernels run
-                    from ..comm.mesh import get_mesh
-                    from .pallas.flash_attention import flash_lanes
+    opts = dict(scale=scale, interpret=interpret, **(flash_opts or {}))
+    if block_diffusion is not None:
+        # all 2L rows as the projections wrote them through one flash call
+        # a pass: the noisy queries' own blocks are a tile of the kernels'
+        # schedule, so nothing is sliced, merged or concatenated around them
+        kern = functools.partial(flash_attention_halves,
+                                 block=block_diffusion, **opts)
+    else:
+        kern = functools.partial(flash_attention, causal=causal,
+                                 window=window, **opts)
+    if plan.repeat_kv:
+        k, v = _repeat_kv(k, v, group)
+    rope = () if q_rope is None else (q_rope, k_rope)
 
-                    tp = 1 if verdict == "direct" \
-                        else get_mesh().shape.get("tp", 1)
-                    plan += "; " + flash_lanes(q.shape[2] // tp,
-                                               q.shape[3]).reason
-                    if window is not None:
-                        plan += f"; window {window}"
-                    if group > 1:
-                        plan += (f"; {group} query heads a key-value head"
-                                 if _grouped_in_kernel(q, k, tp) else
-                                 f"; k and v repeated {group}x to q's heads")
-                note_dispatch("attention", impl, f"{reason}; {plan}")
-                return out
-            reason = "kernel_mesh_plan refused the mesh"
-    note_dispatch("attention", "jnp", reason)
-    return _jnp_attention(q, k, v, causal=causal, bias=bias, mask=mask,
-                          dropout_rate=dropout_rate, dropout_rng=dropout_rng,
-                          scale=scale, window=window)
+    def run(q, k, v, *rope):    # by position, as shard_map hands them
+        return kern(q, k, v, **dict(zip(("q_rope", "k_rope"), rope)))
+
+    if plan.batch_axes is None:
+        return run(q, k, v, *rope)
+    return _shard_over_batch(run, plan.batch_axes, plan.tp,
+                             3 + len(rope))(q, k, v, *rope)
 
 
 def block_diffusion_mask(length: int, block: int):
@@ -164,155 +238,47 @@ def block_diffusion_mask(length: int, block: int):
     return jnp.block([[qb == kb, qb > kb], [none, qb >= kb]])
 
 
-def _block_diffusion_flash(q, k, v, *, block, scale, interpret=False):
-    """The rows as the projections wrote them through one flash call a
-    pass (``ops/pallas/flash_attention.py flash_attention_halves``): the
-    noisy queries' own blocks are a tile of the kernels' schedule, folded
-    into the same online softmax as the clean keys' tiles, so nothing is
-    sliced, merged or concatenated around the kernels."""
-    from .pallas.flash_attention import (flash_attention_halves,
-                                         grouped_in_kernel)
-
-    if not grouped_in_kernel(q.shape[3]):
-        k, v = _repeat_kv(k, v, q.shape[2] // k.shape[2])
-    return flash_attention_halves(q, k, v, block=block, scale=scale,
-                                  interpret=interpret)
-
-
 def block_diffusion_attention(q, k, v, *, block: int,
                               scale: Optional[float] = None,
                               impl: str = "auto",
                               interpret: bool = False) -> jax.Array:
     """Attention of block-diffusion training over ``[noisy ; clean]``:
     ``q`` ``(B, 2L, H, D)``, ``k`` and ``v`` ``(B, 2L, KV, D)``, the mask
-    :func:`block_diffusion_mask`; returns ``(B, 2L, H, D)``.
+    :func:`block_diffusion_mask`; returns ``(B, 2L, H, D)``.  The rows are
+    checked here; :func:`dot_product_attention` does the rest.
 
     ``"flash"`` (``"auto"`` on a TPU where the shapes tile) runs all
     ``2L`` rows through one flash call a pass
-    (:func:`_block_diffusion_flash`), whose tile schedule is the mask's four
-    quadrants: no ``2L x 2L`` score exists, no score is computed outside
-    the kernels, grouped queries stay at their key-value heads in the
-    kernel, void tiles run no code and full tiles build no mask.
-    ``"jnp"`` (the CPU, tests) applies the dense mask to XLA scores.
-    Heads over ``tp`` and sequence-parallel forms are not written: ``tp``
-    takes the XLA path, ``ring`` / ``ulysses`` raise."""
-    from .pallas.flash_attention import _diag, flash_lanes
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+    (``ops/pallas/flash_attention.py flash_attention_halves``), whose tile
+    schedule is the mask's four quadrants: no ``2L x 2L`` score exists, no
+    score is computed outside the kernels, grouped queries stay at their
+    key-value heads in the kernel, void tiles run no code and full tiles
+    build no mask.  ``"jnp"`` (the CPU, tests) applies the dense mask to
+    XLA scores.  Heads over ``tp`` and sequence-parallel forms are not
+    written: ``tp`` takes the XLA path, ``ring`` / ``ulysses`` raise."""
+    from .pallas.flash_attention import block_length
 
-    B, S2, H, D = q.shape
+    S2 = q.shape[1]
     if S2 % 2 or (S2 // 2) % block:
         raise ValueError(f"{S2} positions are not two halves of whole "
                          f"blocks of {block}")
-    if H % k.shape[2]:
-        raise ValueError(f"{H} query heads are no multiple of "
-                         f"{k.shape[2]} key-value heads")
-    if impl not in ("auto", "flash", "jnp"):
-        raise NotImplementedError(
-            f"impl={impl!r} with the block-diffusion mask: 'auto', 'flash' "
-            f"and 'jnp' are written (no sequence-parallel form)")
-    _diag(block, False)         # a divisor of 128, for every path alike
-    if scale is None:
-        scale = D ** -0.5
-    L = S2 // 2
-    how = f"impl={impl!r} requested"
-    if impl == "auto":
-        impl, how = _pick_impl(impl, q[:, :L])
-    if impl == "flash":
-        verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=False)
-        if verdict is not None:
-            plan = "one device" if verdict == "direct" \
-                else f"shard_map over batch axes {axes}"
-            group = H // k.shape[2]
-            note_dispatch(
-                "attention", "flash",
-                f"{how}; {plan}; {flash_lanes(H, D).reason}; block diffusion "
-                f"over [noisy ; clean], block length {block}"
-                + (f"; {group} query heads a key-value head" if group > 1
-                   else ""))
-            kern = functools.partial(_block_diffusion_flash, block=block,
-                                     scale=scale, interpret=interpret)
-            if verdict == "direct":
-                return kern(q, k, v)
-            return _shard_over_batch(kern, axes, 3)(q, k, v)
-        how = "kernel_mesh_plan refused the mesh"
-    note_dispatch("attention", "jnp",
-                  f"{how}; block diffusion over [noisy ; clean], block "
-                  f"length {block}, dense mask")
-    return _jnp_attention(q, k, v, causal=False, bias=None,
-                          mask=block_diffusion_mask(L, block)[None, None],
-                          dropout_rate=0.0, dropout_rng=None, scale=scale)
+    block_length(block)         # a divisor of 128, for every path alike
+    return dot_product_attention(q, k, v, causal=True, scale=scale,
+                                 impl=impl, block_diffusion=block,
+                                 interpret=interpret)
 
 
-def _two_product_attention(q, k, v, q_rope, k_rope, *, causal, scale, impl,
-                           mask=None, interpret=False):
-    """Latent attention's dispatch: the two-product flash kernels
-    (``ops/pallas/flash_attention.py flash_attention_mla``) on a TPU where
-    the widths tile (values as wide as the per-head keys, a multiple of
-    128 lanes; rope heads that fill 128-lane blocks) and the operands are
-    one device's own or split over batch axes alone; float32-softmax XLA
-    otherwise.  ``kernel_dispatch_total{site="attention"}`` says which."""
-    from .pallas.flash_attention import flash_attention_mla, mla_lanes
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
-
-    B, S, H, D = q.shape
-    R = q_rope.shape[-1]
-    if scale is None:
-        scale = (D + R) ** -0.5
-    lanes = mla_lanes(H, D, R, v.shape[-1])
-    if impl not in ("auto", "flash", "jnp"):
-        raise NotImplementedError(
-            f"impl={impl!r} with a second score product (q_rope, k_rope): "
-            f"'auto', 'flash' and 'jnp' are written")
-    if impl == "jnp":
-        reason = "impl='jnp' requested"
-    elif mask is not None:
-        reason = "a mask needs the XLA path"
-    elif lanes is None:
-        reason = (f"no two-product kernel at {D} + {R} rope lanes, v "
-                  f"{v.shape[-1]}")
-    elif impl == "auto" and not (interpret or on_tpu()):
-        reason = "auto: not a TPU"
-    elif impl == "auto" and S < 128:
-        reason = f"auto: seq {S} < 128"
-    else:
-        verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=False)
-        kern = functools.partial(flash_attention_mla, causal=causal,
-                                 scale=scale, interpret=interpret)
-        if verdict is not None:
-            how = "auto: TPU, seq >= 128, head_dim tiles" if impl == "auto" \
-                else f"impl={impl!r} requested"
-            plan = "one device" if verdict == "direct" \
-                else f"shard_map over batch axes {axes}"
-            note_dispatch("attention", "flash",
-                          f"{how}; {plan}; {lanes.reason}")
-            if verdict == "direct":
-                return kern(q, q_rope, k, k_rope, v)
-            return _shard_over_batch(kern, axes, 5)(q, q_rope, k, k_rope, v)
-        reason = "kernel_mesh_plan refused the mesh"
-    note_dispatch("attention", "jnp", reason)
-    s = (jnp.einsum("bshd,bthd->bhst", q, k,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bshr,btr->bhst", q_rope, k_rope[:, :, 0],
-                      preferred_element_type=jnp.float32)) * scale
-    neg = jnp.finfo(s.dtype).min
-    if causal:
-        T = k.shape[1]
-        s = jnp.where(jnp.tril(jnp.ones((S, T), bool), k=T - S)[None, None],
-                      s, neg)
-    if mask is not None:
-        s = jnp.where(mask, s, neg)
-    return jnp.einsum("bhst,bthd->bshd",
-                      jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
-
-
-def _shard_over_batch(kern, batch_axes, n_args: int):
-    """Full-manual shard_map of a kernel over ``(B, S, ·, ·)`` operands:
-    the batch over ``batch_axes``, everything else whole."""
+def _shard_over_batch(kern, batch_axes, tp: int, n_args: int):
+    """Full-manual shard_map of a kernel over ``n_args`` operands ``(B, S,
+    heads, ·)``: the batch over ``batch_axes``, heads over ``tp`` where the
+    plan let a mesh with one through, everything else whole.  The kernel
+    has no collectives; unused axes replicate."""
     from jax.sharding import PartitionSpec as P
 
     from ..comm.mesh import get_mesh
 
-    spec = P(batch_axes if batch_axes else None, None, None, None)
+    spec = P(batch_axes if batch_axes else None, None,
+             "tp" if tp > 1 else None, None)
     return jax.shard_map(kern, mesh=get_mesh(), in_specs=(spec,) * n_args,
                          out_specs=spec, check_vma=False)
 
@@ -322,90 +288,6 @@ def _repeat_kv(k, v, group: int):
     if group == 1:
         return k, v
     return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-
-
-def _grouped_in_kernel(q, k, tp: int) -> bool:
-    """Whether the flash kernel takes k and v at their own heads: one head
-    a lane block, and whole key-value heads on every ``tp`` rank."""
-    from .pallas.flash_attention import grouped_in_kernel
-
-    return grouped_in_kernel(q.shape[3]) and k.shape[2] % tp == 0
-
-
-def _flash_spmd(q, k, v, *, causal, scale, window=None, interpret=False,
-                flash_opts=None):
-    """Flash kernel, SPMD-correct: on a multi-device mesh the pallas_call is
-    opaque to the partitioner (XLA would gather operands), so shard_map it
-    over the batch (dp/fsdp/ep) and head (tp) axes — attention is
-    independent along both.  Returns None when ``kernel_mesh_plan``
-    refuses the mesh (caller takes the XLA path); past that guard the
-    kernel's errors propagate."""
-    from functools import partial
-
-    from .pallas.flash_attention import flash_attention
-    from .pallas.spmd import kernel_mesh_plan
-
-    from ..comm.mesh import get_mesh
-
-    B, S, H, D = q.shape
-    verdict, batch_axes = kernel_mesh_plan(B, heads=H, allow_tp=True)
-    if verdict is None:
-        return None
-    kern = partial(flash_attention, causal=causal, scale=scale,
-                   interpret=interpret, **(flash_opts or {}))
-    if window is not None:      # an absent keyword leaves old traces alone
-        kern = partial(kern, window=window)
-    tp = 1 if verdict == "direct" else get_mesh().shape.get("tp", 1)
-    if not _grouped_in_kernel(q, k, tp):
-        k, v = _repeat_kv(k, v, H // k.shape[2])
-    if verdict == "direct":
-        return kern(q, k, v)
-    return _shard_over_batch_heads(kern, batch_axes)(q, k, v)
-
-
-def _shard_over_batch_heads(kern, batch_axes):
-    """Full-manual shard_map of a ``(B, S, H, D)`` attention kernel: batch
-    over ``batch_axes``, heads over ``tp``.  The kernel has no
-    collectives; unused axes replicate."""
-    from jax.sharding import PartitionSpec as P
-
-    from ..comm.mesh import get_mesh
-
-    mesh = get_mesh()
-    tp = mesh.shape.get("tp", 1)
-    spec = P(batch_axes if batch_axes else None, None,
-             "tp" if tp > 1 else None, None)
-    return jax.shard_map(kern, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)
-
-
-def _flash_jax(q, k, v, *, causal, scale):
-    """Stock JAX/Pallas TPU flash kernel
-    (``jax.experimental.pallas.ops.tpu.flash_attention``) as an alternate
-    backend — same dispatch contract as :func:`_flash_spmd` (shard_map
-    over batch/head axes on active meshes; None when the mesh plan
-    refuses)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as jax_flash)
-
-    from .pallas.spmd import kernel_mesh_plan
-
-    B, S, H, D = q.shape
-    if scale is None:
-        scale = D ** -0.5
-    verdict, batch_axes = kernel_mesh_plan(B, heads=H, allow_tp=True)
-    if verdict is None:
-        return None
-
-    def kern(q, k, v):
-        out = jax_flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                        v.transpose(0, 2, 1, 3), causal=causal,
-                        sm_scale=scale)
-        return out.transpose(0, 2, 1, 3)
-
-    if verdict == "direct":
-        return kern(q, k, v)
-    return _shard_over_batch_heads(kern, batch_axes)(q, k, v)
 
 
 def cached_decode_attention(q, k_cache, v_cache, cur, attn_mask=None, *,
@@ -574,21 +456,25 @@ def _sp_attention(q, k, v, *, causal, scale, kind):
 
 
 def _jnp_attention(q, k, v, *, causal, bias, mask, dropout_rate, dropout_rng,
-                   scale, window=None):
+                   scale, window=None, q_rope=None, k_rope=None):
     b, s_q, h, d = q.shape
     s_k, kv = k.shape[1], k.shape[2]
     if scale is None:
-        scale = d ** -0.5
+        scale = (d if q_rope is None else d + q_rope.shape[-1]) ** -0.5
     # fp32 softmax for stability (the reference kernel does fp32 accumulation
     # in its fused softmax, softmax_kernels.cu)
     if kv != h:     # grouped queries: a key-value head's queries together
         scores = jnp.einsum("bskgd,btkd->bkgst",
                             q.reshape(b, s_q, kv, h // kv, d), k,
                             preferred_element_type=jnp.float32
-                            ).reshape(b, h, s_q, s_k) * scale
+                            ).reshape(b, h, s_q, s_k)
     else:
         scores = jnp.einsum("bshd,bthd->bhst", q, k,
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32)
+    if q_rope is not None:      # one rotated key for all heads
+        scores = scores + jnp.einsum("bshr,btr->bhst", q_rope, k_rope[:, :, 0],
+                                     preferred_element_type=jnp.float32)
+    scores = scores * scale
     if bias is not None:
         scores = scores + bias.astype(scores.dtype)
     neg = jnp.finfo(scores.dtype).min

@@ -22,20 +22,25 @@ Both kernels follow one tile schedule (:func:`score_tile_schedule`): a
 score tile wholly above the diagonal, or wholly past a sliding ``window``
 below it, runs no code, one wholly inside the band builds no mask, and one
 that the diagonal or the band's lower edge crosses is masked (the backward
-walks it in half-edge sub-tiles, each classed the same way).  The diagonal
-may be block-granular (``block``: block diffusion's masks, ``q_pos // g >=
-k_pos // g`` or its strict form ``>``): the same schedule in units of blocks.
-Block diffusion's whole mask over ``[noisy ; clean]`` rows is one schedule
-too (``diag = (g, HALVES)``, :func:`flash_attention_halves`): a noisy
-query tile folds the tile of its own noisy blocks (``q_pos // g == k_pos //
-g``) into the same online softmax as the clean keys' tiles, and the
-backward program of that noisy key tile owns its ``dk`` and ``dv`` whole.
+walks it in half-edge sub-tiles, each classed the same way).  Block
+diffusion's whole mask over ``[noisy ; clean]`` rows is one schedule too
+(``diag = (g, HALVES)``, :func:`flash_attention_halves`; the diagonal in
+blocks of g positions, ``q_pos // g >= k_pos // g`` or its strict form
+``>``): a noisy query tile folds the tile of its own noisy blocks (``q_pos
+// g == k_pos // g``) into the same online softmax as the clean keys'
+tiles, and the backward program of that noisy key tile owns its ``dk`` and
+``dv`` whole.
 
-Grouped queries (``k`` and ``v`` narrower than ``q``, head_dim a multiple
-of 128): query lane block ``c`` reads key-value lane block ``c // group``,
-and the backward sums one key-value head's ``dk`` and ``dv`` over the
-programs of its query heads in VMEM, so nothing key- or value-shaped is
-ever as wide as ``q``.
+A score tile is a sum of products, ``s = Σ_t q_t · k_t`` (:class:`Term`):
+one for plain attention; two for latent attention, ``q_h · k_h + q_rope_h ·
+k_rope`` against ONE rotated key that all heads share.  A term says how
+many consecutive head programs read one block of its keys: 1, or ``group``
+for grouped queries (``k`` and ``v`` narrower than ``q``, head_dim a
+multiple of 128: query lane block ``c`` reads key-value lane block ``c //
+group``), or all of them.  The backward sums such a block's gradient over
+the programs that share it in VMEM, so nothing key- or value-shaped is ever
+as wide as ``q``.  Everything after ``s`` (mask, online softmax, ``p``,
+``dp``, ``ds``) is written once.
 
 All kernels run under ``interpret=True`` on CPU for tests.
 """
@@ -269,17 +274,14 @@ def _full_tiles(own, sched: TileSchedule, *, own_is_q: bool):
     traced = isinstance(own, jax.Array)
     lowest, highest = (jnp.minimum, jnp.maximum) if traced else (min, max)
     w = sched.window
-    # the keys a tile's first query keeps past its own position, plus one:
-    # 1 on the plain diagonal, g on a block-granular one, 0 on its strict form
-    e = 1 if sched.diag is None else sched.diag[0] * (not sched.diag[1])
-    if own_is_q:    # FULL: k0 + bk - e <= q0
-        hi = lowest(nk, (own * bq + e) // bk)
+    if own_is_q:    # FULL: k0 + bk - 1 <= q0
+        hi = lowest(nk, (own * bq + 1) // bk)
         if w is None:
             return 0, hi
         # ... and q0 + bq - 1 - k0 < window
         return highest(0, (own * bq + bq - w + bk - 1) // bk), hi
-    # FULL: q0 >= k0 + bk - e, from the first such query tile on
-    lo = lowest(nq, ((own + 1) * bk - e + bq - 1) // bq)
+    # FULL: q0 >= k0 + bk - 1, from the first such query tile on
+    lo = lowest(nq, ((own + 1) * bk - 1 + bq - 1) // bq)
     if w is None:
         return lo, nq
     # ... to the last with q0 + bq - 1 - k0 < window
@@ -583,9 +585,9 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None):
     ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
     window`` in a BAND_EDGE one, both in a CROSSED one; in a
     BLOCK_DIAGONAL one those whose key block lies after the query's block
-    (at or after it when strict; in the halves' schedule the strictness is
-    in ``d``, traced: :func:`_halves_sweep`); in an OWN_BLOCK one those of
-    another block than the query's; none in a FULL one (``kind`` None too).
+    (at or after it when strict: the strictness is in ``d``, traced,
+    :func:`_halves_sweep`); in an OWN_BLOCK one those of another block than
+    the query's; none in a FULL one (``kind`` None too).
     ``live`` (a traced flag) voids all of them where it is false: this
     program does not meet the tile and computes it in place of a branch
     (:func:`_for_program`)."""
@@ -600,11 +602,11 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None):
     col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     window = sched.window
     if kind in (BLOCK_DIAGONAL, OWN_BLOCK):
-        g, strict = sched.diag
+        g = sched.diag[0]
         shift = g.bit_length() - 1          # g divides 128: a power of two
         if kind == OWN_BLOCK:
             return keep((row >> shift) + d // g == (col >> shift))
-        back = d >> shift if strict == HALVES else d // g - int(strict)
+        back = d >> shift
         return keep((row >> shift) + back >= (col >> shift))
     if kind == DIAGONAL:
         return keep(row + d >= col)
@@ -729,19 +731,85 @@ def _own_lanes(per_head, lanes: Lanes):
     return out
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
-    """One (batch, lane block, query tile) program.  The heads of the
-    block are independent online-softmax chains in one basic block, so
-    Mosaic overlaps one head's matmuls with another's vector work; each
-    keeps a block-wide accumulator, and its own lanes are picked once, at
-    the store."""
+class Term(NamedTuple):
+    """One product ``q_t · k_t`` of a score tile ``s = Σ_t q_t · k_t``, as
+    the grid's head programs read its operands (one program a lane block of
+    the first term's ``q``, the :class:`Lanes` of the call).  The first term
+    is the per-head product; a further one is taken at one head a lane block
+    (:func:`mla_lanes`)."""
+    block: int      # lanes of a program's block of q_t and of k_t
+    # consecutive head programs that read ONE block of k_t: 1, their own
+    # keys; ``group``, grouped queries; H, one key that all heads share
+    keys: int = 1
+    # head programs that share one lane block of q_t, each keeping its own
+    # head's ``block // heads`` lanes: 128 // R for rope lanes R < 128 wide
+    heads: int = 1
+
+
+def _key_grads(terms: tuple) -> tuple:
+    """The term whose key blocks each gradient of a backward program is
+    shaped and shared as, in the order the kernel keeps them: dk of the
+    first term, dv (values go with the first term's keys), dk of every
+    further term."""
+    return (terms[0], *terms)
+
+
+def _shared(c, n: int):
+    """Index of the block that ``n`` consecutive head programs share (an
+    index map with nothing shared stays the plain ``c``: no ``// 1``)."""
+    return c if n == 1 else c // n
+
+
+def _last_of(c, n: int, at):
+    """Row index of a gradient block that ``n`` consecutive head programs
+    sum: ``at`` under the last of them, where the block is whole; until then
+    it stays at tile 0 and nothing of it is written back."""
+    return at if n == 1 else jnp.where(c % n == n - 1, at, 0)
+
+
+def _own_head(x, c, term: Term):
+    """``x`` (rows, ``term.block``), a lane block that ``term.heads`` head
+    programs share, with only the lanes of program ``c``'s head kept."""
+    if term.heads == 1:
+        return x
+    width = term.block // term.heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    lo = (c % term.heads) * width
+    return jnp.where((lane >= lo) & (lane < lo + width), x, 0.0)
+
+
+def _split(refs, *counts):
+    """``refs`` cut into consecutive runs of ``counts`` refs, and the rest."""
+    runs, at = [], 0
+    for n in counts:
+        runs.append(refs[at:at + n])
+        at += n
+    return (*runs, refs[at:])
+
+
+def _zero(ref) -> None:
+    ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+
+def _fwd_kernel(*refs, scale, sched, lanes, terms):
+    """One (batch, lane block, query tile) program; ``refs`` are q of every
+    term, k of every term, v, o and lse.  The heads of the block are
+    independent online-softmax chains in one basic block, so Mosaic overlaps
+    one head's matmuls with another's vector work; each keeps a block-wide
+    accumulator, and its own lanes are picked once, at the store."""
+    n = len(terms)
+    (q_ref, *more_q), (k_ref, *more_k), (v_ref, o_ref, lse_ref) = _split(
+        refs, n, n)
     bq, L = q_ref.shape[1:]
     i = pl.program_id(2)    # read here: not inside a branch
+    c = pl.program_id(1) if any(t.heads > 1 for t in terms) else None
 
     def lane_block(heads, limit):
         def program(sweep, looped):
             q = q_ref[0].astype(jnp.float32) * scale             # (bq, L)
             qs = [_head_lanes(q, h, lanes) for h in range(heads)]
+            more = [(_own_head(r[0].astype(jnp.float32) * scale, c, t), kr)
+                    for r, kr, t in zip(more_q, more_k, terms[1:])]
 
             def fold(k0, carry, d=None, kind=None, live=None):
                 """One online-softmax step a head: key tile [k0,
@@ -755,6 +823,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
                 out = []
                 for q_h, (m, l, acc) in zip(qs, carry):
                     s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
+                    for q_t, k_t in more:
+                        s = s + _dot(q_t, k_t[0, ks].astype(jnp.float32),
+                                     ((1,), (1,)))
                     if d is not None or live is not None:
                         s = _band_mask(s, d, kind, sched, live)
                     m_new = jnp.maximum(m, s.max(axis=-1))
@@ -790,41 +861,57 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, sched, lanes):
     _for_lane_block(lanes, lane_block)
 
 
-def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                 dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale, sched,
-                 lanes, group=1):
-    """Backward: dq, dk AND dv in ONE grid pass over k-blocks.
+def _dqkv_kernel(*refs, scale, sched, lanes, terms):
+    """Backward: dq and dk of every term AND dv in ONE grid pass over
+    k-blocks; ``refs`` are q of every term, k of every term, v, dO, lse and
+    delta, then dq of every term, dk of every term and dv, then the scratch
+    (:func:`_bwd_call`).
 
-    ds is computed once per score tile and head and feeds all three
-    cotangents (5 MXU ops; K/V streamed once).  A head's keys and values
+    ds is computed once per score tile and head and feeds all cotangents
+    (3 + 2 a term MXU ops; K/V streamed once).  A head's keys and values
     are the program's block with the other heads' lanes zeroed, once a
-    program, so q and dO are contracted as they are loaded.  dq sums in a
-    float32 scratch across the k-block grid dim (TPU grids are
-    sequential) and is stored once, scaled, in q's type; its output block
-    ignores that dim, so it is flushed once a (batch, lane block).  dk and
-    dv are summed block-wide as values, one a head and ``sub_k`` band of
-    the program's keys, and each head's lanes picked at the store; a
-    looped sweep sums them in ``kv_acc`` (a loop would carry them through
-    VMEM anyway).  dk carries ``scale`` via the pre-scaled q.
+    program, so q and dO are contracted as they are loaded.  A term's dq
+    sums in a float32 scratch across the k-block grid dim (TPU grids are
+    sequential) and over the head programs that share its lane block, and
+    is stored once, scaled, in q's type; its output block ignores the
+    k-block dim, so it is flushed once, when whole.  The gradients a key
+    tile owns (:func:`_key_grads`) are summed block-wide as values, one a
+    head and ``sub_k`` band of the program's keys, and each head's lanes
+    picked at the store; a looped sweep sums them in scratch (a loop would
+    carry them through VMEM anyway).  dk carries ``scale`` via the
+    pre-scaled q.
 
-    Grouped queries (``group`` query heads a key-value head, one head a
-    lane block): the ``group`` consecutive lane-block programs of one
-    key-value head add their dk and dv, key tile by key tile, in the
-    whole-sequence float32 scratch that ends ``kv_acc``; every program
-    stores the running sum, and the output's block index moves on from
-    key tile 0 only under the group's last program (:func:`_bwd_call`),
-    so what reaches HBM is each tile's complete sum, once."""
+    A gradient whose block several consecutive head programs share (a
+    key-value head's dk and dv under grouped queries; dk of the one key all
+    heads read): the programs add theirs, key tile by key tile, in a
+    whole-sequence float32 scratch; every program stores the running sum,
+    and the output's block index moves on from key tile 0 only under the
+    last of them (:func:`_last_of`), so what reaches HBM is each tile's
+    complete sum, once."""
+    n = len(terms)
+    grads = _key_grads(terms)
+    ((q_ref, *more_q), (k_ref, *more_k), (v_ref, do_ref, lse_ref, delta_ref),
+     dq_refs, (dk_ref, *more_dk), (dv_ref,), dq_accs, scratch) = _split(
+        refs, n, n, 4, n, n, 1, n)
+    grad_refs = (dk_ref, dv_ref, *more_dk)
     bk, L = k_ref.shape[1:]
     sk = sched.sub_k
     j = pl.program_id(2)
-    group_dk = group_dv = None
-    if group > 1:
-        *kv_acc, group_dk, group_dv = kv_acc
-        first_of_group = pl.program_id(1) % group == 0
+    c = pl.program_id(1) if any(t.keys > 1 or t.heads > 1 for t in terms) \
+        else None
+    # whether this is the first of the programs that share a block, once
+    # for every number of them
+    first = {m: c % m == 0 for m in dict.fromkeys(t.keys for t in grads)
+             if m > 1}
+    # a looped sweep's sums, one a gradient, or none; then the sums over
+    # the programs that share a block, one a gradient that has them
+    kv_acc = scratch[:len(scratch) - sum(t.keys > 1 for t in grads)]
+    rest = iter(scratch[len(kv_acc):])
+    shared = [next(rest) if t.keys > 1 else None for t in grads]
 
-    @pl.when(j == 0)
-    def _init_dq():
-        dq_acc[...] = jnp.zeros(dq_acc.shape, dq_acc.dtype)
+    for acc, t in zip(dq_accs, terms):
+        pl.when(j == 0 if t.heads == 1 else (j == 0) & (c % t.heads == 0))(
+            functools.partial(_zero, acc))
 
     def lane_block(n_heads, limit):
         heads = range(n_heads)
@@ -834,6 +921,7 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             v_blk = v_ref[0].astype(jnp.float32)
             ks = [_head_lanes(k_blk, h, lanes) for h in heads]
             vs = [_head_lanes(v_blk, h, lanes) for h in heads]
+            more_blk = [r[0].astype(jnp.float32) for r in more_k]
 
             def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk,
                       d=None, kind=None, live=None):
@@ -841,17 +929,23 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 program's block; ``d`` is the mask offset and ``kind`` the
                 tile's, None for FULL; ``live`` is false where this program
                 computes the tile void, None where it never does.
-                ``sums`` maps each head and key band to its ``(dk, dv)``
-                so far, or is None where they are summed in ``kv_acc``."""
+                ``sums`` maps each head and key band to its gradients so
+                far (:func:`_key_grads`), or is None where they are summed
+                in ``kv_acc``."""
                 rs = pl.ds(q0 + r0, rows)
                 q = _keep_lanes(q_ref[0, rs].astype(jnp.float32) * scale, 0,
                                 limit)
                 do = _keep_lanes(do_ref[0, rs].astype(jnp.float32), 0, limit)
+                q_more = [_own_head(r[0, rs].astype(jnp.float32) * scale, c, t)
+                          for r, t in zip(more_q, terms[1:])]
+                k_more = [_rows(x, c0, cols) for x in more_blk]
                 sums = None if sums is None else dict(sums)
                 dq = None
                 for h in heads:
                     k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
                     s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
+                    for q_t, k_t in zip(q_more, k_more):
+                        s = s + _dot(q_t, k_t, ((1,), (1,)))
                     if d is not None or live is not None:
                         s = _band_mask(s, d, kind, sched, live)
                     p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
@@ -861,14 +955,20 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk = _dot(ds, q, ((0,), (0,)))
                     dq_h = _dot(ds, k, ((1,), (0,)))     # zero off the head
                     dq = dq_h if dq is None else dq + dq_h
+                    made = [dk, dv]
+                    for q_t, k_t, t, acc in zip(q_more, k_more, terms[1:],
+                                                dq_accs[1:]):
+                        made.append(_dot(ds, q_t, ((0,), (0,))))
+                        acc[rs] += _own_head(_dot(ds, k_t, ((1,), (0,))), c, t)
                     if sums is None:
-                        kv_acc[0][h, pl.ds(c0, cols)] += dk
-                        kv_acc[1][h, pl.ds(c0, cols)] += dv
+                        for acc, g in zip(kv_acc, made):
+                            acc[h, pl.ds(c0, cols)] += g
                         continue
                     for b in range(c0, c0 + cols, sk):
-                        sums[h, b] = (sums[h, b][0] + _rows(dk, b - c0, sk),
-                                      sums[h, b][1] + _rows(dv, b - c0, sk))
-                dq_acc[rs] += dq
+                        sums[h, b] = tuple(
+                            a + _rows(g, b - c0, sk)
+                            for a, g in zip(sums[h, b], made))
+                dq_accs[0][rs] += dq
                 return sums
 
             def diagonal_tile(q0, d0, subs, sums, live=None):
@@ -878,41 +978,49 @@ def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  live)
                 return sums
 
-            def store(ref, group_acc, b, size, per_head):
-                """Keys [b, +size) of the program's block, summed over the
-                key-value head's query heads where those are grouped."""
+            def store(g, b, size, per_head):
+                """Keys [b, +size) of the program's block of gradient
+                ``g``, summed over the programs that share the block."""
+                ref, acc = grad_refs[g], shared[g]
                 val = _own_lanes(per_head, lanes)
-                if group > 1:
+                if acc is not None:
                     at = pl.ds(j * bk + b, size)
                     # a select: the scratch holds anything before the
-                    # group's first program has written it
-                    val = val + jnp.where(first_of_group, 0.0, group_acc[at])
-                    group_acc[at] = val
+                    # first of the programs has written it
+                    val = val + jnp.where(first[grads[g].keys], 0.0, acc[at])
+                    acc[at] = val
                 ref[0, pl.ds(b, size)] = val.astype(ref.dtype)
 
             if looped:
                 for acc in kv_acc:
-                    acc[...] = jnp.zeros(acc.shape, acc.dtype)
+                    _zero(acc)
                 sweep(None, visit, diagonal_tile)
-                store(dk_ref, group_dk, 0, bk, [kv_acc[0][h] for h in heads])
-                store(dv_ref, group_dv, 0, bk, [kv_acc[1][h] for h in heads])
+                for g, acc in enumerate(kv_acc):
+                    store(g, 0, bk, [acc[h] for h in heads])
                 return
-            zero = jnp.zeros((sk, L), jnp.float32)
-            sums = sweep({(h, b): (zero, zero) for h in heads
+            zeros = {w: jnp.zeros((sk, w), jnp.float32)
+                     for w in dict.fromkeys(t.block for t in grads)}
+            zero = tuple(zeros[t.block] for t in grads)
+            sums = sweep({(h, b): zero for h in heads
                           for b in range(0, bk, sk)}, visit, diagonal_tile)
             for b in range(0, bk, sk):
-                store(dk_ref, group_dk, b, sk, [sums[h, b][0] for h in heads])
-                store(dv_ref, group_dv, b, sk, [sums[h, b][1] for h in heads])
+                for g in range(len(grads)):
+                    store(g, b, sk, [sums[h, b][g] for h in heads])
 
         _for_program(j, sched, program, own_is_q=False, heads=lanes.heads)
 
     _for_lane_block(lanes, lane_block)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _store_dq():
+    def store_dq(ref, acc):
         for r in range(0, sched.S, sched.block_q):   # tile-sized values
             rs = pl.ds(r, sched.block_q)
-            dq_ref[0, rs] = (dq_acc[rs] * scale).astype(dq_ref.dtype)
+            ref[0, rs] = (acc[rs] * scale).astype(ref.dtype)
+
+    whole = j == pl.num_programs(2) - 1
+    for ref, acc, t in zip(dq_refs, dq_accs, terms):
+        pl.when(whole if t.heads == 1
+                else whole & (c % t.heads == t.heads - 1))(
+            functools.partial(store_dq, ref, acc))
 
 
 def _largest_dividing_block(s: int, cap: int) -> int:
@@ -982,11 +1090,12 @@ def _delta(do, out, lanes: Lanes):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
-           window=None, diag=None):
-    out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                        interpret, window, diag)
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+def _flash(qs, ks, v, causal, scale, block_q, block_k, lanes, terms,
+           interpret, window=None, diag=None):
+    """``qs`` and ``ks``: the packed q and k of every term, tuples."""
+    out, _ = _flash_fwd(qs, ks, v, causal, scale, block_q, block_k, lanes,
+                        terms, interpret, window, diag)
     return out
 
 
@@ -994,11 +1103,12 @@ def _flash(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
 # stays in the caller's jaxpr, but its trace cache means a model traces
 # each kernel body once, not once a layer and remat pass (a step of the
 # 48-layer benchmark model stages ~200 flash calls).
-_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret",
-           "window", "diag")
+_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "terms",
+           "interpret", "window", "diag")
 # Mosaic gives a kernel 16 MB of VMEM unless told otherwise; the v5e has
-# 128.  A kernel whose blocks and scratch come near the default asks for
-# what it needs and this much again for the values of its body.
+# 128.  A kernel whose whole-sequence panels (double-buffered) and scratch
+# come near the default asks for what they need and this much again for its
+# key-tile blocks and the values of its body.
 _VMEM_DEFAULT, _VMEM_HEADROOM = 12 << 20, 16 << 20
 
 
@@ -1011,146 +1121,180 @@ def _vmem(need: int) -> dict:
         vmem_limit_bytes=need + _VMEM_HEADROOM)}
 
 
-def _group(q, k, lanes: Lanes) -> int:
-    """Query heads a key-value head, from the operands' widths."""
-    group = q.shape[2] // k.shape[2]
-    if group > 1 and (lanes.heads != 1 or not lanes.rows):
-        raise ValueError(
-            f"grouped queries in the kernel need one head a lane block "
-            f"(head_dim a multiple of 128), got head_dim {lanes.head_dim}; "
-            f"repeat k and v to q's heads instead")
-    return group
-
-
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _fwd_call(q, k, v, *, causal, scale, block_q, block_k, lanes, interpret,
-              window=None, diag=None):
-    N, S, W = q.shape
-    Sk = k.shape[1]
+def _fwd_call(qs, ks, v, *, causal, scale, block_q, block_k, lanes, terms,
+              interpret, window=None, diag=None):
+    N, S, W = qs[0].shape
+    Sk = ks[0].shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
     sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False,
                                 window, diag)
-    group = _group(q, k, lanes)
-    panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c))
-    if group > 1:       # fetched once a key-value head: its index holds
-        panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c // group))
+
+    def tile(t):        # a program's rows of q_t; of o, as the first term's
+        return pl.BlockSpec((1, block_q, t.block),
+                            lambda n, c, i: (n, i, _shared(c, t.heads)))
+
+    def panel(t):       # fetched once where its index holds: a key-value
+        return pl.BlockSpec(    # head's queries, or every head of a row
+            (1, Sk, t.block), lambda n, c, i: (n, 0, _shared(c, t.keys)))
+
+    q_lanes = sum(t.block for t in terms)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, sched=sched, lanes=lanes),
+        functools.partial(_fwd_kernel, scale=scale, sched=sched, lanes=lanes,
+                          terms=terms),
         grid=(N, NB, S // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
-            panel,
-            panel,
-        ],
+        in_specs=[*map(tile, terms), *map(panel, terms), panel(terms[0])],
         out_specs=[
-            pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c)),
+            tile(Term(L)),
             pl.BlockSpec((1, 1, P, block_q), lambda n, c, i: (n, c, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((N, S, W), q.dtype),
+            jax.ShapeDtypeStruct((N, S, W), qs[0].dtype),
             jax.ShapeDtypeStruct((N, NB, P, S), jnp.float32),
         ],
         interpret=interpret,
-        **_vmem(4 * Sk * L * k.dtype.itemsize + 4 * block_q * L * 4),
-    )(q, k, v)
+        **_vmem(2 * Sk * (q_lanes + L) * v.dtype.itemsize
+                + 4 * block_q * q_lanes * 4),
+    )(*qs, *ks, v)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes, interpret,
-               window=None, diag=None):
+def _flash_fwd(qs, ks, v, causal, scale, block_q, block_k, lanes, terms,
+               interpret, window=None, diag=None):
     _note_score_tiles("fwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, False, window,
-        diag), lanes.heads)
-    out, lse = _fwd_call(q, k, v, causal=causal, scale=scale,
+        qs[0].shape[1], ks[0].shape[1], block_q, block_k, causal, False,
+        window, diag), lanes.heads)
+    out, lse = _fwd_call(qs, ks, v, causal=causal, scale=scale,
                          block_q=block_q, block_k=block_k, lanes=lanes,
-                         interpret=interpret, window=window, diag=diag)
+                         terms=terms, interpret=interpret, window=window,
+                         diag=diag)
     # named so a "<policy>+flash" remat policy can SAVE the kernel's
     # residuals: out/lse aren't dot outputs, so dots_saveable alone
     # recomputes the whole fwd kernel inside every backward pass
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
-    return out, (q, k, v, out, lse)
+    return out, (qs, ks, v, out, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, lanes, interpret, window,
-               diag, res, do):
-    q, k, v, out, lse = res
-    return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, _delta(do, out, lanes), window,
-                           diag)
+def _flash_bwd(causal, scale, block_q, block_k, lanes, terms, interpret,
+               window, diag, res, do):
+    qs, ks, v, out, lse = res
+    return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, terms,
+                           interpret, qs, ks, v, lse, do,
+                           _delta(do, out, lanes), window, diag)
 
 
-def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                    q, k, v, lse, do, delta, window=None, diag=None):
+def _flash_bwd_impl(causal, scale, block_q, block_k, lanes, terms, interpret,
+                    qs, ks, v, lse, do, delta, window=None, diag=None):
+    """``(dqs, dks, dv)``, the first two tuples as ``qs`` and ``ks``."""
     _note_score_tiles("bwd", score_tile_schedule(
-        q.shape[1], k.shape[1], block_q, block_k, causal, True, window, diag),
-        lanes.heads)
-    return _bwd_call(q, k, v, do, lse, delta, causal=causal, scale=scale,
-                     block_q=block_q, block_k=block_k, lanes=lanes,
-                     interpret=interpret, window=window, diag=diag)
+        qs[0].shape[1], ks[0].shape[1], block_q, block_k, causal, True,
+        window, diag), lanes.heads)
+    n = len(terms)
+    grads = _bwd_call(qs, ks, v, do, lse, delta, causal=causal, scale=scale,
+                      block_q=block_q, block_k=block_k, lanes=lanes,
+                      terms=terms, interpret=interpret, window=window,
+                      diag=diag)
+    return tuple(grads[:n]), tuple(grads[n:2 * n]), grads[2 * n]
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
-def _bwd_call(q, k, v, do, lse, delta, *, causal, scale, block_q, block_k,
-              lanes, interpret, window=None, diag=None):
-    N, S, W = q.shape
-    Sk = k.shape[1]
+def _bwd_call(qs, ks, v, do, lse, delta, *, causal, scale, block_q, block_k,
+              lanes, terms, interpret, window=None, diag=None):
+    N, S, W = qs[0].shape
+    Sk = ks[0].shape[1]
     L, NB, P = lanes.block, lanes.blocks, lanes.heads
     sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True, window,
                                 diag)
-    group = _group(q, k, lanes)
-    panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
-    block = grads = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
+    grads = _key_grads(terms)
+
+    def panel(t):       # whole rows of q_t and dq_t; of dO, as the first's
+        return pl.BlockSpec((1, S, t.block),
+                            lambda n, c, j: (n, 0, _shared(c, t.heads)))
+
+    def block(t):       # the program's key tile of k_t; of v
+        return pl.BlockSpec((1, block_k, t.block),
+                            lambda n, c, j: (n, j, _shared(c, t.keys)))
+
+    def grad(t):        # ... and of its gradient (:func:`_last_of`)
+        return pl.BlockSpec(
+            (1, block_k, t.block),
+            lambda n, c, j: (n, _last_of(c, t.keys, j), _shared(c, t.keys)))
+
     rows = pl.BlockSpec((1, 1, P, S), lambda n, c, j: (n, c, 0, 0))
-    scratch = [pltpu.VMEM((S, L), jnp.float32)]          # dq, summed over j
-    if _is_looped(sched, own_is_q=False):                # dk and dv a head
-        scratch += [pltpu.VMEM((P, block_k, L), jnp.float32)] * 2
-    kernel = functools.partial(_dqkv_kernel, scale=scale, sched=sched,
-                               lanes=lanes)
-    need = (4 * 2 + 2 * q.dtype.itemsize + 4) * S * L + 4 * 4 * 8 * S
-    if group > 1:
-        kernel = functools.partial(kernel, group=group)
-        block = pl.BlockSpec((1, block_k, L),
-                             lambda n, c, j: (n, j, c // group))
-        # dk and dv of a key-value head are whole under its last query
-        # head: until then the block stays at key tile 0 and nothing of
-        # it is written back
-        grads = pl.BlockSpec(
-            (1, block_k, L),
-            lambda n, c, j: (n, jnp.where(c % group == group - 1, j, 0),
-                             c // group))
-        scratch += [pltpu.VMEM((Sk, L), jnp.float32)] * 2
-        need += 2 * 4 * Sk * L
+    # dq_t, summed over j (and over the programs that share its lanes)
+    scratch = [pltpu.VMEM((S, t.block), jnp.float32) for t in terms]
+    if _is_looped(sched, own_is_q=False):                # a sweep's sums
+        scratch += [pltpu.VMEM((P, block_k, t.block), jnp.float32)
+                    for t in grads]
+    # ... and over the programs that share a key block
+    scratch += [pltpu.VMEM((Sk, t.block), jnp.float32)
+                for t in grads if t.keys > 1]
+    item = qs[0].dtype.itemsize
+    need = (2 * item * S * L + 4 * 4 * 8 * S
+            + sum((4 + 2 * 2 * item) * S * t.block for t in terms)
+            + sum(4 * Sk * t.block for t in grads if t.keys > 1))
+    first, *more = terms
     return pl.pallas_call(
-        kernel,
+        functools.partial(_dqkv_kernel, scale=scale, sched=sched,
+                          lanes=lanes, terms=terms),
         grid=(N, NB, Sk // block_k),
-        in_specs=[panel, block, block, panel, rows, rows],
+        in_specs=[*map(panel, terms), *map(block, terms), block(first),
+                  panel(Term(L)), rows, rows],
         # dq's block ignores j: written once, when its sum is complete
-        out_specs=[panel, grads, grads],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, S, W), q.dtype),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
+        out_specs=[*map(panel, terms), *map(grad, terms), grad(first)],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (*qs, *ks, v)],
         scratch_shapes=scratch,
         interpret=interpret,
         **_vmem(need),
-    )(q, k, v, do, lse, delta)
+    )(*qs, *ks, v, do, lse, delta)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _prepare(q, k, scale, block_q, block_k):
+def mla_lanes(heads: int, nope_dim: int, rope_dim: int,
+              v_dim: int) -> Optional[Term]:
+    """The second term of latent attention's score, ``q_rope_h · k_rope``
+    against ONE rotated key for all heads, or None where the kernels have
+    no layout for these widths: a further term is taken at one head a lane
+    block, so values as wide as the per-head (``nope``) keys, a multiple of
+    128 lanes, and rope heads that fill 128-lane blocks whole.  A program
+    reads the 128-lane block of ``q_rope`` that holds its head (two heads at
+    R = 64), keeps its head's lanes and contracts over the block against the
+    shared key tiled to one block: never as wide as the heads, and fetched
+    once a row, not once a head."""
+    if v_dim != nope_dim or nope_dim % 128 or rope_dim < 8:
+        return None
+    per = max(1, 128 // rope_dim)       # heads a 128-lane block of q_rope
+    if (rope_dim % 128 and 128 % rope_dim) or heads % per:
+        return None
+    return Term(per * rope_dim, heads, per)
+
+
+def _prepare(q, k, scale, block_q, block_k, v=None, q_rope=None, k_rope=None):
+    """``(scale, block_q, block_k, lanes, terms)`` of a call, checked."""
     B, S, H, D = q.shape
     Sk = k.shape[1]
-    if scale is None:
-        scale = D ** -0.5
     lanes = flash_lanes(H, D)
     if k.shape[2] != H and (H % k.shape[2] or not grouped_in_kernel(D)):
         raise ValueError(
             f"{H} query heads over {k.shape[2]} key-value heads of {D}: the "
             f"kernel groups whole heads of a multiple of 128 lanes; repeat "
             f"k and v to q's heads for any other shape")
+    terms = (Term(lanes.block, H // k.shape[2]),)
+    if q_rope is not None:
+        R = q_rope.shape[-1]
+        rope = mla_lanes(H, D, R, v.shape[-1])
+        if rope is None or k_rope.shape[2] != 1 or k.shape[2] != H:
+            raise ValueError(
+                f"no two-product kernel for {H} heads of {D} + {R} rope "
+                f"lanes, values {v.shape[-1]} wide, {k_rope.shape[2]} rope "
+                f"keys on {k.shape[2]} key heads")
+        terms += (rope,)
+        D += R
+    if scale is None:
+        scale = D ** -0.5
     if lanes.block > 128:
         # a backward program keeps its keys, values and their gradients
         # in float32 beside whole-sequence panels of q, dO and dq: at 256
@@ -1162,24 +1306,26 @@ def _prepare(q, k, scale, block_q, block_k):
     if S % block_q or Sk % block_k:
         raise ValueError(f"seq lengths ({S},{Sk}) must divide block sizes "
                          f"({block_q},{block_k})")
-    return scale, block_q, block_k, lanes
+    return scale, block_q, block_k, lanes, terms
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    q_rope: Optional[jax.Array] = None,
+                    k_rope: Optional[jax.Array] = None,
                     causal: bool = True, scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     interpret: bool = False,
-                    window: Optional[int] = None,
-                    block: Optional[int] = None,
-                    strict: bool = False) -> jax.Array:
+                    window: Optional[int] = None) -> jax.Array:
     """Public API, shapes ``(B, S, H, D)`` like ``ops.attention``; ``k``
     and ``v`` may have fewer heads than ``q`` (query head h reads
     key-value head ``h // (H // KV)``; head_dim a multiple of 128), and
-    ``window`` keeps, of the causal keys, the last ``window``.  ``block``
-    (a divisor of 128) makes the diagonal block-granular: query i keeps
-    key j iff ``i // block >= j // block``, ``>`` when ``strict`` (one
-    quadrant of block diffusion's mask: the clean half, the noisy half
-    against the clean keys; :func:`flash_attention_halves` runs the whole).
+    ``window`` keeps, of the causal keys, the last ``window``.
+
+    With ``q_rope`` ``(B, S, H, R)`` and ``k_rope`` ``(B, S, 1, R)`` the
+    score is two products, ``softmax((q_h · k_h + q_rope_h · k_rope) ·
+    scale) v_h`` (latent attention: ONE rotated key for all heads; see
+    :func:`mla_lanes` for the widths), and ``scale`` defaults to ``(D + R)
+    ** -0.5``; all five gradients come from the one backward kernel.
 
     The kernels read q, k, v and dO and write o, dq, dk and dv as
     ``(B, S, H·D)``, the layout the projections on either side use, so
@@ -1198,23 +1344,28 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     double-buffer better); the online-softmax loop engages automatically
     for S > block.
     """
-    scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
-    out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), causal,
-                 scale, block_q, block_k, lanes, interpret, window,
-                 _diag(block, strict))
+    scale, block_q, block_k, lanes, terms = _prepare(
+        q, k, scale, block_q, block_k, v, q_rope, k_rope)
+    qs, ks = (_pack(q, lanes),), (_pack(k, lanes),)
+    if q_rope is not None:
+        B, S, H, R = q_rope.shape
+        kr = k_rope.reshape(B, -1, R)
+        if terms[1].heads > 1:  # the shared key fills one lane block: a
+            kr = jnp.tile(kr, (1, 1, terms[1].heads))   # copy per heads
+        qs, ks = qs + (q_rope.reshape(B, S, H * R),), ks + (kr,)
+    out = _flash(qs, ks, _pack(v, lanes), causal, scale, block_q, block_k,
+                 lanes, terms, interpret, window, None)
     return _unpack(out, lanes, q.shape[0])
 
 
-def _diag(block: Optional[int], strict: bool = False) -> Optional[tuple]:
-    """The schedule's ``diag`` of a block length, checked; None of None."""
-    if block is None:
-        return None
+def block_length(block) -> int:
+    """A block length of block diffusion, checked."""
     if not isinstance(block, int) or block < 1 or 128 % block:
         raise ValueError(
             f"block length {block!r}: the block-granular diagonal takes a "
             f"divisor of 128 (the schedule's tiles start at multiples of "
             f"128 positions and must start at multiples of a block)")
-    return int(block), bool(strict)
+    return int(block)
 
 
 def flash_attention_halves(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -1232,16 +1383,17 @@ def flash_attention_halves(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if S2 % 2 or k.shape[1] != S2:
         raise ValueError(f"{S2} query and {k.shape[1]} key positions are "
                          f"not the two halves of one sequence")
-    g, _ = _diag(block)
+    g = block_length(block)
 
     def half(x):
         return jax.ShapeDtypeStruct((B, S2 // 2) + x.shape[2:], x.dtype)
 
-    scale, block_q, block_k, lanes = _prepare(half(q), half(k), scale,
-                                              block_q, block_k)
+    scale, block_q, block_k, lanes, terms = _prepare(half(q), half(k), scale,
+                                                     block_q, block_k)
     tile = min(block_q, block_k)
-    out = _flash(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes), True,
-                 scale, tile, tile, lanes, interpret, None, (g, HALVES))
+    out = _flash((_pack(q, lanes),), (_pack(k, lanes),), _pack(v, lanes),
+                 True, scale, tile, tile, lanes, terms, interpret, None,
+                 (g, HALVES))
     return _unpack(out, lanes, B)
 
 
@@ -1249,29 +1401,32 @@ def flash_attention_halves(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # LSE-exposing variant — building block for distributed (ring) attention
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_lse(q, k, v, causal, scale, block_q, block_k, lanes, terms,
+               interpret):
     return _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret)[0]
+                          terms, interpret)[0]
 
 
-def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
+def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, lanes, terms,
                    interpret):
-    out, res = _flash_fwd(q, k, v, causal, scale, block_q, block_k, lanes,
-                          interpret)
+    out, res = _flash_fwd((q,), (k,), v, causal, scale, block_q, block_k,
+                          lanes, terms, interpret)
     return (out, _row_heads(res[4], lanes)), res      # lse as (N, S, heads)
 
 
-def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
-                   ct):
+def _flash_lse_bwd(causal, scale, block_q, block_k, lanes, terms, interpret,
+                   res, ct):
     do, dlse = ct
-    q, k, v, out, lse = res
+    qs, ks, v, out, lse = res
     # the lse cotangent folds into the shared backward exactly:
     # ds = p·(dp - δ') with δ' = δ - dlse, because ∂lse_i/∂s_ij = p_ij
     delta = _delta(do, out, lanes) - _head_rows(dlse.astype(jnp.float32),
                                                 lanes)
-    return _flash_bwd_impl(causal, scale, block_q, block_k, lanes, interpret,
-                           q, k, v, lse, do, delta)
+    (dq,), (dk,), dv = _flash_bwd_impl(causal, scale, block_q, block_k, lanes,
+                                       terms, interpret, qs, ks, v, lse, do,
+                                       delta)
+    return dq, dk, dv
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -1287,381 +1442,11 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     distributed (ring) attention needs to merge per-block results exactly.
     """
     B, S, H, _ = q.shape
-    scale, block_q, block_k, lanes = _prepare(q, k, scale, block_q, block_k)
+    scale, block_q, block_k, lanes, terms = _prepare(q, k, scale, block_q,
+                                                     block_k)
     out, lse = _flash_lse(_pack(q, lanes), _pack(k, lanes), _pack(v, lanes),
-                          causal, scale, block_q, block_k, lanes, interpret)
+                          causal, scale, block_q, block_k, lanes, terms,
+                          interpret)
     if not lanes.rows:      # (B·H, S, 1): a head a panel
         lse = lse.reshape(B, H, S).transpose(0, 2, 1)
     return _unpack(out, lanes, B), lse
-
-
-# ---------------------------------------------------------------------------
-# Latent attention (MLA): the score is a sum of two products
-# ---------------------------------------------------------------------------
-#
-# ``s_h = q_nope_h · k_nope_h + q_rope_h · k_rope``: each head has keys of
-# its own (``nope``, as wide as its values) and all heads share one rotated
-# key.  Operands are the rows the projections write: ``q_nope``, ``k_nope``,
-# ``v``, ``o`` as ``(N, S, H·D)`` with D a multiple of 128 (one head a lane
-# block, as at head_dim 128 above), ``q_rope`` ``(N, S, H·R)`` and the shared
-# key tiled to one 128-lane block, ``(N, S, 128 // R · R)``: a program reads
-# the 128-lane block of ``q_rope`` that holds its head (two heads at R = 64),
-# keeps its head's lanes and contracts over the block, so the shared key is
-# never as wide as the heads and is fetched once a row, not once a head.
-# The tile schedule, the mask and the online softmax are the kernels' above.
-
-def _rope_head(x, c, per: int, rope_dim: int):
-    """``x`` (rows, per·rope_dim), a block of ``per`` heads' rope lanes,
-    with only those of head ``c`` (a program id) kept."""
-    if per == 1:
-        return x
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    lo = (c % per) * rope_dim
-    return jnp.where((lane >= lo) & (lane < lo + rope_dim), x, 0.0)
-
-
-def _mla_fwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, lse_ref, *,
-                    scale, sched, per, rope_dim):
-    """One (row, head, query tile) program."""
-    bq, L = qn_ref.shape[1:]
-    c, i = pl.program_id(1), pl.program_id(2)
-
-    def program(sweep, looped):
-        qn = qn_ref[0].astype(jnp.float32) * scale               # (bq, L)
-        qr = _rope_head(qr_ref[0].astype(jnp.float32) * scale, c, per,
-                        rope_dim)
-
-        def fold(k0, carry, d=None, kind=None, live=None):
-            ks = pl.ds(k0, sched.block_k)
-            s = _dot(qn, kn_ref[0, ks].astype(jnp.float32), ((1,), (1,))) \
-                + _dot(qr, kr_ref[0, ks].astype(jnp.float32), ((1,), (1,)))
-            if d is not None or live is not None:
-                s = _band_mask(s, d, kind, sched, live)
-            m, l, acc = carry
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
-            p = jnp.exp(s - _col(m_safe))
-            corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - m_safe))
-            v = v_ref[0, ks].astype(jnp.float32)
-            return (m_new, l * corr + p.sum(axis=-1),
-                    acc * _col(corr) + _dot(p, v, ((1,), (0,))))
-
-        def diagonal_tile(k0, d0, subs, carry, live=None):
-            assert len(subs) == 1   # the forward leaves them whole
-            return fold(k0, carry, d0, subs[0][2], live)
-
-        m, l, acc = sweep((jnp.full((bq,), NEG_INF, jnp.float32),
-                           jnp.zeros((bq,), jnp.float32),
-                           jnp.zeros((bq, L), jnp.float32)),
-                          fold, diagonal_tile)
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        lse_ref[0, 0, 0] = jnp.where(m == NEG_INF, 0.0, m) + jnp.log(l_safe)
-        o_ref[0] = (acc / _col(l_safe)).astype(o_ref.dtype)
-
-    _for_program(i, sched, program, own_is_q=True)
-
-
-def _mla_bwd_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dqn_ref, dqr_ref, dkn_ref, dkr_ref, dv_ref,
-                    dqn_acc, dqr_acc, heads_dkr, *kv_acc, scale, sched, per,
-                    rope_dim):
-    """Backward, one (row, head, key tile) program: one ds a score tile
-    feeds all five cotangents.  ``dq_nope`` sums over the key tiles in a
-    float32 scratch, ``dq_rope`` over the key tiles AND the ``per`` heads
-    that share its 128-lane block (each adds its own lanes), stored once
-    when whole.  ``dk_rope`` is the sum over every head: the heads'
-    programs add theirs, key tile by key tile, in the whole-sequence
-    scratch ``heads_dkr``, and the output's block index moves on from key
-    tile 0 only under the last head, so what reaches HBM is each tile's
-    complete sum, once (the grouped-query kernels' way, above)."""
-    bk, L = kn_ref.shape[1:]
-    sk = sched.sub_k
-    c, j = pl.program_id(1), pl.program_id(2)
-    last_j = pl.num_programs(2) - 1
-    first_head = c == 0
-    first_of_block, last_of_block = c % per == 0, c % per == per - 1
-
-    @pl.when(j == 0)
-    def _init_dqn():
-        dqn_acc[...] = jnp.zeros(dqn_acc.shape, dqn_acc.dtype)
-
-    @pl.when((j == 0) & first_of_block)
-    def _init_dqr():
-        dqr_acc[...] = jnp.zeros(dqr_acc.shape, dqr_acc.dtype)
-
-    def program(sweep, looped):
-        kn_blk = kn_ref[0].astype(jnp.float32)                   # (bk, L)
-        kr_blk = kr_ref[0].astype(jnp.float32)                   # (bk, 128)
-        v_blk = v_ref[0].astype(jnp.float32)
-
-        def visit(q0, sums, r0=0, c0=0, rows=sched.block_q, cols=bk, d=None,
-                  kind=None, live=None):
-            rs = pl.ds(q0 + r0, rows)
-            qn = qn_ref[0, rs].astype(jnp.float32) * scale
-            qr = _rope_head(qr_ref[0, rs].astype(jnp.float32) * scale, c,
-                            per, rope_dim)
-            do = do_ref[0, rs].astype(jnp.float32)
-            kn, kr = _rows(kn_blk, c0, cols), _rows(kr_blk, c0, cols)
-            v = _rows(v_blk, c0, cols)
-            s = _dot(qn, kn, ((1,), (1,))) + _dot(qr, kr, ((1,), (1,)))
-            if d is not None or live is not None:
-                s = _band_mask(s, d, kind, sched, live)
-            p = jnp.exp(s - _col(lse_ref[0, 0, 0, rs]))
-            dv = _dot(p, do, ((0,), (0,)))
-            dp = _dot(do, v, ((1,), (1,)))
-            ds = p * (dp - _col(delta_ref[0, 0, 0, rs]))
-            dkn = _dot(ds, qn, ((0,), (0,)))
-            dkr = _dot(ds, qr, ((0,), (0,)))     # zero off the head's lanes
-            dqn_acc[rs] += _dot(ds, kn, ((1,), (0,)))
-            dqr_acc[rs] += _rope_head(_dot(ds, kr, ((1,), (0,))), c, per,
-                                      rope_dim)
-            if sums is None:
-                at = pl.ds(c0, cols)
-                kv_acc[0][at] += dkn
-                kv_acc[1][at] += dv
-                kv_acc[2][at] += dkr
-                return None
-            sums = dict(sums)
-            for b in range(c0, c0 + cols, sk):
-                sums[b] = tuple(t + _rows(x, b - c0, sk) for t, x in
-                                zip(sums[b], (dkn, dv, dkr)))
-            return sums
-
-        def diagonal_tile(q0, d0, subs, sums, live=None):
-            for r0, c0, kind in subs:
-                sums = visit(q0, sums, r0, c0, sched.sub_q, sk,
-                             None if kind == FULL else d0 + r0 - c0, kind,
-                             live)
-            return sums
-
-        def store(b, size, dkn, dv, dkr):
-            """Keys [b, +size) of the program's tile."""
-            at, whole = pl.ds(b, size), pl.ds(j * bk + b, size)
-            dkn_ref[0, at] = dkn.astype(dkn_ref.dtype)
-            dv_ref[0, at] = dv.astype(dv_ref.dtype)
-            # a select: the scratch holds anything before head 0 wrote it
-            dkr = dkr + jnp.where(first_head, 0.0, heads_dkr[whole])
-            heads_dkr[whole] = dkr
-            dkr_ref[0, at] = dkr.astype(dkr_ref.dtype)
-
-        if looped:
-            for acc in kv_acc:
-                acc[...] = jnp.zeros(acc.shape, acc.dtype)
-            sweep(None, visit, diagonal_tile)
-            store(0, bk, kv_acc[0][...], kv_acc[1][...], kv_acc[2][...])
-            return
-        zero = (jnp.zeros((sk, L), jnp.float32),) * 2 \
-            + (jnp.zeros((sk, kr_blk.shape[1]), jnp.float32),)
-        sums = sweep({b: zero for b in range(0, bk, sk)}, visit,
-                     diagonal_tile)
-        for b in range(0, bk, sk):
-            store(b, sk, *sums[b])
-
-    _for_program(j, sched, program, own_is_q=False)
-
-    @pl.when(j == last_j)
-    def _store_dqn():
-        for r in range(0, sched.S, sched.block_q):   # tile-sized values
-            rs = pl.ds(r, sched.block_q)
-            dqn_ref[0, rs] = (dqn_acc[rs] * scale).astype(dqn_ref.dtype)
-
-    @pl.when((j == last_j) & last_of_block)
-    def _store_dqr():
-        for r in range(0, sched.S, sched.block_q):
-            rs = pl.ds(r, sched.block_q)
-            dqr_ref[0, rs] = (dqr_acc[rs] * scale).astype(dqr_ref.dtype)
-
-
-class MLALanes(NamedTuple):
-    """The two-product kernels' layout, from shapes alone."""
-    heads: int
-    nope_dim: int       # of q_nope, k_nope AND v: one head a lane block
-    rope_dim: int
-
-    @property
-    def per(self) -> int:
-        """Heads a 128-lane block of ``q_rope``."""
-        return max(1, 128 // self.rope_dim)
-
-    @property
-    def rope_block(self) -> int:
-        return self.per * self.rope_dim
-
-    @property
-    def reason(self) -> str:
-        return (f"rows layout, 1 head a {self.nope_dim}-lane block; "
-                f"{self.nope_dim} + {self.rope_dim} shared rope lanes, "
-                f"v {self.nope_dim}")
-
-
-def mla_lanes(heads: int, nope_dim: int, rope_dim: int,
-              v_dim: int) -> Optional[MLALanes]:
-    """The layout for these widths, or None where the kernels have none:
-    they want values as wide as the nope keys, a multiple of 128 lanes,
-    and rope heads that fill 128-lane blocks whole."""
-    lanes = MLALanes(heads, nope_dim, rope_dim)
-    if v_dim != nope_dim or nope_dim % 128:
-        return None
-    if (rope_dim % 128 and 128 % rope_dim) or rope_dim < 8 \
-            or heads % lanes.per:
-        return None
-    return lanes
-
-
-_MLA_STATIC = ("causal", "scale", "block_q", "block_k", "lanes", "interpret")
-
-
-@functools.partial(jax.jit, static_argnames=_MLA_STATIC, inline=True)
-def _mla_fwd_call(qn, qr, kn, kr, v, *, causal, scale, block_q, block_k,
-                  lanes, interpret):
-    N, S, W = qn.shape
-    Sk = kn.shape[1]
-    L, H, per, R = lanes.nope_dim, lanes.heads, lanes.per, lanes.rope_block
-    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, False)
-    tile = pl.BlockSpec((1, block_q, L), lambda n, c, i: (n, i, c))
-    panel = pl.BlockSpec((1, Sk, L), lambda n, c, i: (n, 0, c))
-    return pl.pallas_call(
-        functools.partial(_mla_fwd_kernel, scale=scale, sched=sched, per=per,
-                          rope_dim=lanes.rope_dim),
-        grid=(N, H, S // block_q),
-        in_specs=[
-            tile,
-            pl.BlockSpec((1, block_q, R), lambda n, c, i: (n, i, c // per)),
-            panel,
-            # the shared key: its index holds over every head and query
-            # tile of a row, so it is fetched once a row
-            pl.BlockSpec((1, Sk, R), lambda n, c, i: (n, 0, 0)),
-            panel,
-        ],
-        out_specs=[
-            tile,
-            pl.BlockSpec((1, 1, 1, block_q), lambda n, c, i: (n, c, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((N, S, W), qn.dtype),
-            jax.ShapeDtypeStruct((N, H, 1, S), jnp.float32),
-        ],
-        interpret=interpret,
-        **_vmem(2 * Sk * (2 * L + R) * kn.dtype.itemsize
-                + 4 * block_q * (L + R) * 4),
-    )(qn, qr, kn, kr, v)
-
-
-@functools.partial(jax.jit, static_argnames=_MLA_STATIC, inline=True)
-def _mla_bwd_call(qn, qr, kn, kr, v, do, lse, delta, *, causal, scale,
-                  block_q, block_k, lanes, interpret):
-    N, S, W = qn.shape
-    Sk = kn.shape[1]
-    L, H, per, R = lanes.nope_dim, lanes.heads, lanes.per, lanes.rope_block
-    sched = score_tile_schedule(S, Sk, block_q, block_k, causal, True)
-    panel = pl.BlockSpec((1, S, L), lambda n, c, j: (n, 0, c))
-    rope_panel = pl.BlockSpec((1, S, R), lambda n, c, j: (n, 0, c // per))
-    block = pl.BlockSpec((1, block_k, L), lambda n, c, j: (n, j, c))
-    rows = pl.BlockSpec((1, 1, 1, S), lambda n, c, j: (n, c, 0, 0))
-    scratch = [pltpu.VMEM((S, L), jnp.float32),          # dq_nope over j
-               pltpu.VMEM((S, R), jnp.float32),          # dq_rope, j and heads
-               pltpu.VMEM((Sk, R), jnp.float32)]         # dk_rope over heads
-    if _is_looped(sched, own_is_q=False):
-        scratch += [pltpu.VMEM((block_k, L), jnp.float32)] * 2 \
-            + [pltpu.VMEM((block_k, R), jnp.float32)]
-    item = qn.dtype.itemsize
-    need = (S * L * (4 + 3 * 2 * item) + S * R * (4 + 2 * 2 * item)
-            + Sk * R * 4 + 4 * 4 * 8 * S
-            + block_k * (2 * L + R) * (4 + 4 * item))
-    return pl.pallas_call(
-        functools.partial(_mla_bwd_kernel, scale=scale, sched=sched, per=per,
-                          rope_dim=lanes.rope_dim),
-        grid=(N, H, Sk // block_k),
-        in_specs=[panel, rope_panel, block,
-                  pl.BlockSpec((1, block_k, R), lambda n, c, j: (n, j, 0)),
-                  block, panel, rows, rows],
-        out_specs=[
-            panel, rope_panel, block,
-            # whole under the last head: until then the block stays at key
-            # tile 0 and nothing of it is written back
-            pl.BlockSpec((1, block_k, R),
-                         lambda n, c, j: (n, jnp.where(c == H - 1, j, 0), 0)),
-            block,
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(qn.shape, qn.dtype),
-            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
-            jax.ShapeDtypeStruct(kn.shape, kn.dtype),
-            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **_vmem(need),
-    )(qn, qr, kn, kr, v, do, lse, delta)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_mla(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
-               interpret):
-    return _flash_mla_fwd(qn, qr, kn, kr, v, causal, scale, block_q, block_k,
-                          lanes, interpret)[0]
-
-
-def _flash_mla_fwd(qn, qr, kn, kr, v, causal, scale, block_q, block_k, lanes,
-                   interpret):
-    _note_score_tiles("fwd", score_tile_schedule(
-        qn.shape[1], kn.shape[1], block_q, block_k, causal, False), 1)
-    out, lse = _mla_fwd_call(qn, qr, kn, kr, v, causal=causal, scale=scale,
-                             block_q=block_q, block_k=block_k, lanes=lanes,
-                             interpret=interpret)
-    out = checkpoint_name(out, "flash_out")     # as _flash_fwd's
-    lse = checkpoint_name(lse, "flash_lse")
-    return out, (qn, qr, kn, kr, v, out, lse)
-
-
-def _flash_mla_bwd(causal, scale, block_q, block_k, lanes, interpret, res,
-                   do):
-    qn, qr, kn, kr, v, out, lse = res
-    _note_score_tiles("bwd", score_tile_schedule(
-        qn.shape[1], kn.shape[1], block_q, block_k, causal, True), 1)
-    delta = _delta(do, out, flash_lanes(lanes.heads, lanes.nope_dim))
-    return _mla_bwd_call(qn, qr, kn, kr, v, do, lse, delta, causal=causal,
-                         scale=scale, block_q=block_q, block_k=block_k,
-                         lanes=lanes, interpret=interpret)
-
-
-_flash_mla.defvjp(_flash_mla_fwd, _flash_mla_bwd)
-
-
-def flash_attention_mla(q_nope: jax.Array, q_rope: jax.Array,
-                        k_nope: jax.Array, k_rope: jax.Array, v: jax.Array,
-                        *, causal: bool = True,
-                        scale: Optional[float] = None, block_q: int = 512,
-                        block_k: int = 512,
-                        interpret: bool = False) -> jax.Array:
-    """Attention whose score is two products, ``softmax((q_nope_h ·
-    k_nope_h + q_rope_h · k_rope) · scale) v_h``: ``q_nope``, ``k_nope``,
-    ``v`` ``(B, S, H, D)``, ``q_rope`` ``(B, S, H, R)`` and ONE rotated key
-    for all heads, ``k_rope`` ``(B, S, 1, R)``; returns ``(B, S, H, D)``.
-    ``scale`` defaults to ``(D + R) ** -0.5``.  See :func:`mla_lanes` for
-    the widths the kernels take; the ``(B, S, H, ·)`` views are free
-    reshapes of the rows the kernels read and write."""
-    B, S, H, D = q_nope.shape
-    R = q_rope.shape[-1]
-    lanes = mla_lanes(H, D, R, v.shape[-1])
-    if lanes is None or k_rope.shape[2] != 1 or k_nope.shape[2] != H:
-        raise ValueError(
-            f"no two-product kernel for {H} heads of {D} + {R} rope lanes, "
-            f"values {v.shape[-1]} wide, {k_rope.shape[2]} rope keys")
-    if scale is None:
-        scale = (D + R) ** -0.5
-    block_q = _largest_dividing_block(S, block_q)
-    block_k = _largest_dividing_block(k_nope.shape[1], block_k)
-    if S % block_q or k_nope.shape[1] % block_k:
-        raise ValueError(f"seq lengths ({S},{k_nope.shape[1]}) must divide "
-                         f"block sizes ({block_q},{block_k})")
-    # the shared key fills one lane block: a copy per heads, never H
-    kr = k_rope.reshape(B, -1, R)
-    if lanes.per > 1:
-        kr = jnp.tile(kr, (1, 1, lanes.per))
-    out = _flash_mla(q_nope.reshape(B, S, H * D), q_rope.reshape(B, S, H * R),
-                     k_nope.reshape(B, -1, H * D), kr,
-                     v.reshape(B, -1, H * D), causal, scale, block_q, block_k,
-                     lanes, interpret)
-    return out.reshape(B, S, H, D)

@@ -95,27 +95,25 @@ def kernels(fa, case, tag, rng, interpret, shrink):
     def normal(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
 
-    if kind == "mla":
-        R = 64
-        lanes = fa.mla_lanes(H, D, R, D)
-        ops = (normal(B, S, H * D), normal(B, S, H * R), normal(B, S, H * D),
-               normal(B, S, lanes.rope_block), normal(B, S, H * D))
-        static = dict(causal=True, scale=(D + R) ** -0.5, block_q=block,
-                      block_k=block, lanes=lanes, interpret=interpret)
-        fwd_call, bwd_call = fa._mla_fwd_call, fa._mla_bwd_call
-        head_lanes = fa.flash_lanes(H, D)
-    else:
-        shape = jax.ShapeDtypeStruct((B, S // 2 if kind == "halves" else S,
-                                      H, D), jnp.bfloat16)
-        scale, bq, bk, lanes = fa._prepare(
-            shape, jax.ShapeDtypeStruct(shape.shape[:2] + (KV, D),
-                                        jnp.bfloat16), None, block, block)
-        ops = (normal(B, S, H * D), normal(B, S, KV * D), normal(B, S, KV * D))
-        static = dict(causal=True, scale=scale, block_q=bq, block_k=bk,
-                      lanes=lanes, interpret=interpret, window=window,
-                      diag=(4, fa.HALVES) if kind == "halves" else None)
-        fwd_call, bwd_call = fa._fwd_call, fa._bwd_call
-        head_lanes = lanes
+    shape = (B, S // 2 if kind == "halves" else S, H, D)
+
+    def like(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    rope = (like(B, S, H, 64), like(B, S, 1, 64)) if kind == "mla" else ()
+    scale, bq, bk, lanes, terms = fa._prepare(
+        like(*shape), like(*shape[:2], KV, D), None, block, block,
+        like(*shape[:2], KV, D), *rope)
+    qs, ks = (normal(B, S, H * D),), (normal(B, S, KV * D),)
+    if rope:
+        qs, ks = qs + (normal(B, S, H * 64),), ks + (normal(B, S,
+                                                            terms[1].block),)
+    ops = (qs, ks, normal(B, S, KV * D))
+    static = dict(causal=True, scale=scale, block_q=bq, block_k=bk,
+                  lanes=lanes, terms=terms, interpret=interpret,
+                  window=window,
+                  diag=(4, fa.HALVES) if kind == "halves" else None)
+    fwd_call, bwd_call = fa._fwd_call, fa._bwd_call
 
     def fwd(*ops):
         with jax.named_scope(f"{tag}_fwd"):
@@ -128,7 +126,7 @@ def kernels(fa, case, tag, rng, interpret, shrink):
     def rest(out, lse):
         do = normal(*out.shape)
         return do, lse, jax.jit(
-            lambda do, out: fa._delta(do, out, head_lanes))(do, out)
+            lambda do, out: fa._delta(do, out, lanes))(do, out)
 
     return jax.jit(fwd), jax.jit(bwd), ops, rest
 

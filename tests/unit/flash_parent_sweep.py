@@ -7,7 +7,9 @@ package.  :func:`parent_sweeps` puts it in the kernels' place."""
 import collections
 import contextlib
 import functools
+import hashlib
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,8 +105,7 @@ def _for_program(own, sched, program, *, own_is_q, heads=1):
                 functools.partial(program, static_sweep(o), False))
 
 
-_CALLS = {"_fwd_call": fa._STATIC, "_bwd_call": fa._STATIC,
-          "_mla_fwd_call": fa._MLA_STATIC, "_mla_bwd_call": fa._MLA_STATIC}
+_CALLS = ("_fwd_call", "_bwd_call")
 
 
 @contextlib.contextmanager
@@ -117,7 +118,7 @@ def parent_sweeps():
     def fresh(name):
         inner = kept[name].__wrapped__
         return jax.jit(lambda *a, **k: inner(*a, **k),
-                       static_argnames=_CALLS[name], inline=True)
+                       static_argnames=fa._STATIC, inline=True)
 
     try:
         fa._for_program = _for_program
@@ -135,39 +136,40 @@ def passes(q, k, v, do, *, block, window=None, causal=True, halves=None):
     heads); ``halves`` is the block length of ``[noisy ; clean]`` rows."""
     B = q.shape[0]
     if halves is None:
-        scale, bq, bk, lanes = fa._prepare(q, k, None, block, block)
+        scale, bq, bk, lanes, terms = fa._prepare(q, k, None, block, block)
         diag = None
     else:
         half = lambda x: jax.ShapeDtypeStruct(
             (B, x.shape[1] // 2) + x.shape[2:], x.dtype)
-        scale, bq, bk, lanes = fa._prepare(half(q), half(k), None, block,
-                                           block)
+        scale, bq, bk, lanes, terms = fa._prepare(half(q), half(k), None,
+                                                  block, block)
         diag = (halves, fa.HALVES)
     static = dict(causal=causal, scale=scale, block_q=bq, block_k=bk,
-                  lanes=lanes, interpret=True, window=window, diag=diag)
+                  lanes=lanes, terms=terms, interpret=True, window=window,
+                  diag=diag)
     q, k, v, do = (fa._pack(x, lanes) for x in (q, k, v, do))
-    out, lse = fa._fwd_call(q, k, v, **static)
-    grads = fa._bwd_call(q, k, v, do, lse, fa._delta(do, out, lanes),
+    out, lse = fa._fwd_call((q,), (k,), v, **static)
+    grads = fa._bwd_call((q,), (k,), v, do, lse, fa._delta(do, out, lanes),
                          **static)
     return (out, lse, *grads)
 
 
 def mla_passes(qn, qr, kn, kr, v, do, *, block):
     """``(out, lse, dq_nope, dq_rope, dk_nope, dk_rope, dv)`` of the
-    two-product kernels, operands as ``flash_attention_mla`` takes them."""
+    two-product kernels, operands ``(B, S, H, ·)`` and ONE rope key."""
     B, S, H, D = qn.shape
     R = qr.shape[-1]
-    lanes = fa.mla_lanes(H, D, R, D)
-    static = dict(causal=True, scale=(D + R) ** -0.5, block_q=block,
-                  block_k=block, lanes=lanes, interpret=True)
-    ops = (qn.reshape(B, S, H * D), qr.reshape(B, S, H * R),
-           kn.reshape(B, S, H * D),
-           jnp.tile(kr.reshape(B, S, R), (1, 1, lanes.per)),
-           v.reshape(B, S, H * D))
-    do = do.reshape(B, S, H * D)
-    out, lse = fa._mla_fwd_call(*ops, **static)
-    delta = fa._delta(do, out, fa.flash_lanes(H, D))
-    return (out, lse, *fa._mla_bwd_call(*ops, do, lse, delta, **static))
+    scale, bq, bk, lanes, terms = fa._prepare(qn, kn, None, block, block, v,
+                                              qr, kr)
+    static = dict(causal=True, scale=scale, block_q=bq, block_k=bk,
+                  lanes=lanes, terms=terms, interpret=True)
+    qs = (qn.reshape(B, S, H * D), qr.reshape(B, S, H * R))
+    ks = (kn.reshape(B, S, H * D),
+          jnp.tile(kr.reshape(B, S, R), (1, 1, terms[1].heads)))
+    v, do = v.reshape(B, S, H * D), do.reshape(B, S, H * D)
+    out, lse = fa._fwd_call(qs, ks, v, **static)
+    return (out, lse, *fa._bwd_call(qs, ks, v, do, lse,
+                                    fa._delta(do, out, lanes), **static))
 
 
 def assert_equal_to_the_parents(run, names):
@@ -182,15 +184,25 @@ def assert_equal_to_the_parents(run, names):
                                       err_msg=name)
 
 
-def kernel_primitives(fn, *args):
-    """How often each control-flow primitive appears inside the Pallas
-    kernels that ``fn(*args)`` traces (``cond``, ``while``, ``scan``)."""
+def traced_digest(fn, *args) -> str:
+    """sha256 of the jaxpr that ``fn(*args)`` traces to, as text without
+    source lines: what a pin computed on another commit can be held to."""
+    # a function of its own: see kernel_primitives
+    text = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+    return hashlib.sha256(re.sub(r" at /\S+:\d+", "", text).encode()
+                          ).hexdigest()
+
+
+def kernel_primitives(fn, *args, names=("cond", "while", "scan")):
+    """How often each primitive of ``names`` (the control-flow ones, unless
+    told otherwise) appears inside the Pallas kernels that ``fn(*args)``
+    traces."""
     found = collections.Counter()
 
     def walk(jaxpr, inside):
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            if inside and name in ("cond", "while", "scan"):
+            if inside and name in names:
                 found[name] += 1
             for value in eqn.params.values():
                 for sub in (value if isinstance(value, (tuple, list))

@@ -99,7 +99,8 @@ def test_flash_spmd_on_mesh():
     import jax.numpy as jnp
 
     from deepspeed_tpu.comm import mesh as mesh_mod
-    from deepspeed_tpu.ops.attention import _flash_spmd, _jnp_attention
+    from deepspeed_tpu.ops.attention import (_jnp_attention,
+                                             dot_product_attention)
 
     mesh_mod.set_mesh(None)
     mesh = mesh_mod.build_mesh({"dp": 4, "tp": 2})
@@ -109,8 +110,7 @@ def test_flash_spmd_on_mesh():
         q = jnp.asarray(rng.normal(size=(4, 128, 4, 64)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(4, 128, 4, 64)), jnp.float32)
         v = jnp.asarray(rng.normal(size=(4, 128, 4, 64)), jnp.float32)
-        out = _flash_spmd(q, k, v, causal=True, scale=None, interpret=True)
-        assert out is not None
+        out = dot_product_attention(q, k, v, impl="flash", interpret=True)
         ref = _jnp_attention(q, k, v, causal=True, bias=None, mask=None,
                              dropout_rate=0.0, dropout_rng=None, scale=None)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -135,8 +135,6 @@ def flash_dispatch(monkeypatch):
     from deepspeed_tpu.ops import attention
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 
-    monkeypatch.setattr(attention, "_flash_spmd", functools.partial(
-        attention._flash_spmd, interpret=True))
     mesh_mod.set_mesh(mesh_mod.build_mesh({"dp": 1},
                                           devices=jax.devices()[:1]))
 
@@ -144,8 +142,8 @@ def flash_dispatch(monkeypatch):
         return {reason: n for site, impl, reason, n in dispatch_report()
                 if (site, impl) == ("attention", "flash")}
 
-    yield functools.partial(attention.dot_product_attention,
-                            impl="flash"), booked
+    yield functools.partial(attention.dot_product_attention, impl="flash",
+                            interpret=True), booked
     mesh_mod.set_mesh(None)
 
 

@@ -1,10 +1,10 @@
-"""The flash kernels under block diffusion's masks (PR 40) in interpret
-mode against the dense mask: the block-granular diagonal ``q // g >= k //
-g``, its strict form, and the whole ``[noisy ; clean]`` attention
+"""The flash kernels under block diffusion's mask (PR 40) in interpret
+mode against the dense mask: the whole ``[noisy ; clean]`` attention
 (``ops/attention.py block_diffusion_attention``: one flash call a pass
 over all ``2L`` rows, the noisy queries' own blocks a tile of the kernels'
-schedule, PR 41) against ``block_diffusion_mask``: block lengths 4, 16 and
-32, sequences that are no multiple of the default tile, one tile a half
+schedule, PR 41; both strictnesses of the block-granular diagonal ``q // g
+>= k // g`` are its clean keys' tiles) against ``block_diffusion_mask``:
+block lengths 4, 16 and 32, sequences that are no multiple of the default tile, one tile a half
 and several, the backward's halved tiles, grouped and ungrouped heads,
 forward and all three gradients on two rows of different content, the
 first block's rows (which keep no clean key) apart, the halves' sweep
@@ -68,30 +68,6 @@ def _plain(q, k, v, keep):
                       precision="highest")
 
 
-# (S, H, KV, D, g, strict, block): three 128-tiles (S no multiple of 512);
-# grouped heads under the strict form; an eight-tile looped sweep; the
-# halved diagonal tile of the backward (512 -> 256) with blocks of 32
-DIAGONALS = [(384, 2, 2, 64, 4, False, 512), (384, 4, 2, 128, 16, True, 512),
-             (1024, 2, 1, 128, 4, True, 128), (1024, 2, 2, 64, 32, False, 512),
-             (512, 2, 2, 64, 16, True, 512), (640, 2, 1, 128, 32, False, 128)]
-
-
-@pytest.mark.parametrize("S,H,KV,D,g,strict,block", DIAGONALS)
-def test_block_granular_diagonal_forward_and_gradients(S, H, KV, D, g, strict,
-                                                       block):
-    q, k, v, w = _operands(2, S, H, KV, D, seed=S + g)
-    kern = lambda q, k, v: fa.flash_attention(
-        q, k, v, interpret=True, block=g, strict=strict, block_q=block,
-        block_k=block)
-    plain = lambda q, k, v: _plain(q, k, v, _keep(S, g, strict))
-    np.testing.assert_allclose(kern(q, k, v), plain(q, k, v), atol=2e-5)
-    got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (plain(*a) * w).sum(), argnums=(0, 1, 2))(
-        q, k, v)
-    for name, a, b in zip("qkv", got, want):
-        np.testing.assert_allclose(a, b, atol=5e-5, err_msg="d" + name)
-
-
 @pytest.mark.parametrize("g,strict", [(4, False), (16, True), (32, False),
                                       (128, True)])
 def test_tile_classes_match_a_brute_force_count(g, strict):
@@ -110,34 +86,6 @@ def test_tile_classes_match_a_brute_force_count(g, strict):
             want = fa.VOID if kept == 0 else fa.FULL \
                 if kept == rows * cols else fa.BLOCK_DIAGONAL
             assert kind == want, (q0, k0, kind, kept)
-
-
-@pytest.mark.parametrize("g", [4, 16, 32])
-def test_the_counter_names_the_new_kind(g):
-    """``flash_score_tiles_total{pass, kind="block_diagonal"}`` counts the
-    crossed sub-tiles of the traced kernels, and no plain ``diagonal``."""
-    S, block = 512, 128
-    q, k, v, w = _operands(1, S, 2, 2, 64, seed=g)
-
-    def count():
-        entry = get_registry().snapshot().get("flash_score_tiles_total")
-        out = collections.Counter()
-        for s in (entry or {"samples": []})["samples"]:
-            out[s["labels"]["pass"], s["labels"]["kind"]] += s["value"]
-        return out
-
-    before = count()
-    jax.grad(lambda q: (fa.flash_attention(
-        q, k, v, interpret=True, block=g, block_q=block, block_k=block)
-        * w).sum())(q)
-    got = count() - before
-    n = S // block
-    assert got["fwd", "block_diagonal"] == n
-    assert got["bwd", "block_diagonal"] == n
-    assert got["fwd", "full"] == got["fwd", "void"] == n * (n - 1) // 2
-    assert got["fwd", "diagonal"] == got["bwd", "diagonal"] == 0
-    # no call without the whole mask has a tile of a query's own block
-    assert got["fwd", "own_block"] == got["bwd", "own_block"] == 0
 
 
 @pytest.mark.parametrize("g", [4, 16, 32])
@@ -263,27 +211,38 @@ def test_the_halves_sweep_visits_what_the_schedule_lists(L, block, halve,
 HALVES = [(256, 4, 2, 128, 4), (384, 2, 2, 64, 16), (256, 4, 1, 64, 32),
           (640, 2, 1, 128, 4), (640, 2, 2, 64, 16), (768, 2, 1, 128, 32),
           (1024, 2, 2, 128, 4), (512, 4, 2, 128, 16), (1024, 2, 1, 128, 32)]
+# ... and with a tile: the shapes that the per-quadrant entry of PR 40
+# (``flash_attention(block=, strict=)``, gone with PR 44) was tested at, a
+# half each: three 128-tiles (no multiple of 512); grouped heads; an
+# eight-tile looped sweep; the backward's halved tile with blocks of 32
+HALVES = [(*case, 512) for case in HALVES] + [
+    (384, 2, 2, 64, 4, 512), (384, 4, 2, 128, 16, 512),
+    (1024, 2, 1, 128, 4, 128), (1024, 2, 2, 64, 32, 512),
+    (512, 2, 2, 64, 16, 512), (640, 2, 1, 128, 32, 128)]
 
 
-@pytest.mark.parametrize("L,H,KV,D,g", HALVES)
+@pytest.mark.parametrize("L,H,KV,D,g,tile", HALVES)
 def test_noisy_and_clean_halves_match_the_dense_mask(one_device, L, H, KV,
-                                                     D, g):
+                                                     D, g, tile):
+    """Forward and gradients of all ``2L`` rows; the clean keys' tiles are
+    the block-granular diagonal, strict under the noisy queries and loose
+    under the clean ones."""
     q, k, v, w = _operands(2, 2 * L, H, KV, D, seed=L + g)
     mask = np.asarray(attention_lib.block_diffusion_mask(L, g))
     before = _flash_dispatches(g)
-    kern = lambda *a: attention_lib.block_diffusion_attention(
-        *a, block=g, impl="flash", interpret=True)
+    kern = lambda *a: attention_lib.dot_product_attention(
+        *a, block_diffusion=g, impl="flash", interpret=True,
+        flash_opts={"block_q": tile, "block_k": tile})
     plain = lambda *a: _plain(*a, mask)
-    out, want = kern(q, k, v), plain(q, k, v)
+    (out, pull), (want, pull_plain) = (jax.vjp(f, q, k, v)
+                                       for f in (kern, plain))
     np.testing.assert_allclose(out, want, atol=2e-5)
     assert _flash_dispatches(g) == before + 1       # the kernels ran
     # the first block's rows keep their own block's keys and no clean key
     np.testing.assert_allclose(out[:, :g], want[:, :g], atol=2e-5,
                                err_msg="first block")
     assert float(jnp.abs(out[0] - out[1]).max()) > 0.1      # two contents
-    got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: (plain(*a) * w).sum(), argnums=(0, 1, 2))(
-        q, k, v)
+    got, want = pull(w), pull_plain(w)   # the gradients of (out * w).sum()
     for name, a, b in zip("qkv", got, want):
         assert bool(jnp.isfinite(a).all()), name
         for half, x, y in zip(("noisy", "clean"), np.split(a, 2, 1),
@@ -331,31 +290,11 @@ def test_xla_path_takes_the_same_mask_and_rows_do_not_mix(one_device):
     assert float(jnp.abs(kern[0] - kern[1]).max()) > 0.1
 
 
-def test_the_dispatch_reason_names_the_block_length(one_device):
-    L, g = 128, 16
-    q, k, v, _ = _operands(1, 2 * L, 4, 2, 128, seed=2)
-    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="flash",
-                                            interpret=True)
-    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="jnp")
-    mesh_lib.set_mesh(None)     # eight devices and no mesh: refused, by name
-    attention_lib.block_diffusion_attention(q, k, v, block=g, impl="flash",
-                                            interpret=True)
-    assert any(i == "jnp" and "refused the mesh" in r and f"length {g}" in r
-               for s, i, r, n in dispatch_report())
-    flash = [r for s, i, r, n in dispatch_report()
-             if (s, i) == ("attention", "flash") and f"block length {g}" in r
-             and "2 query heads a key-value head" in r]
-    assert flash and all("one device" in r and "rows layout, 1 head a "
-                         "128-lane block" in r for r in flash)
-    assert any(i == "jnp" and "dense mask" in r and f"length {g}" in r
-               for s, i, r, n in dispatch_report())
-
-
 @pytest.mark.parametrize("g", [3, 24, 48, 256, 0])
 def test_a_block_length_that_does_not_divide_128_raises(g):
     q, k, v, _ = _operands(1, 768, 2, 2, 64)
     with pytest.raises(ValueError, match="128"):
-        fa.flash_attention(q, k, v, interpret=True, block=g)
+        fa.flash_attention_halves(q, k, v, interpret=True, block=g)
     if g:
         with pytest.raises(ValueError):
             attention_lib.block_diffusion_attention(q, k, v, block=g,
@@ -365,9 +304,13 @@ def test_a_block_length_that_does_not_divide_128_raises(g):
 def test_what_is_not_written_raises_by_name():
     q, k, v, _ = _operands(1, 256, 2, 2, 64)
     with pytest.raises(NotImplementedError, match="sliding window"):
-        fa.flash_attention(q, k, v, interpret=True, block=4, window=64)
+        fa.score_tile_schedule(256, 256, 128, 128, True, False, 64, (4, False))
     with pytest.raises(NotImplementedError, match="causal"):
-        fa.flash_attention(q, k, v, interpret=True, block=4, causal=False)
+        fa.score_tile_schedule(256, 256, 128, 128, False, False, None,
+                               (4, False))
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        attention_lib.dot_product_attention(q, k, v, block_diffusion=4,
+                                            window=64)
     for impl in ("ring", "ulysses"):
         with pytest.raises(NotImplementedError, match="sequence-parallel"):
             attention_lib.block_diffusion_attention(q, k, v, block=4,

@@ -1,6 +1,7 @@
-"""The two-product flash kernels (PR 38; latent attention: ``q_nope_h ·
-k_nope_h + q_rope_h · k_rope``, ONE rope key for all heads) in interpret
-mode against ``jnp``: the forward and all five gradients, causal, a
+"""The flash kernels under a score of two products (PR 38; since PR 44 a
+second term of the one kernel family, ``flash_attention(q, k, v, q_rope=,
+k_rope=)``; latent attention: ``q_nope_h · k_nope_h + q_rope_h · k_rope``,
+ONE rope key for all heads) in interpret mode against ``jnp``: the forward and all five gradients, causal, a
 sequence that is no multiple of the default block, a looped key sweep, two
 rows with different contents (nothing else of the model holds a batch
 dimension), the score-tile counter as at the same S without the second
@@ -32,6 +33,10 @@ def _operands(B, S, seed=0, heads=H, dtype=jnp.float32):
     return [jax.random.normal(k, s, dtype) for k, s in zip(ks, shapes)]
 
 
+def _two_products(qn, qr, kn, kr, v, **kw):
+    return fa.flash_attention(qn, kn, v, q_rope=qr, k_rope=kr, **kw)
+
+
 def _plain(qn, qr, kn, kr, v):
     s = (jnp.einsum("bshd,bthd->bhst", qn, kn, precision="highest")
          + jnp.einsum("bshr,btr->bhst", qr, kr[:, :, 0], precision="highest")
@@ -50,7 +55,7 @@ CASES = [(2, 256, 128), (1, 640, 128), (2, 384, 512)]
 @pytest.mark.parametrize("B,S,block", CASES)
 def test_forward_and_all_five_gradients_match_jnp(B, S, block):
     *ops, w = _operands(B, S, seed=S)
-    kern = lambda *a: fa.flash_attention_mla(
+    kern = lambda *a: _two_products(
         *a, interpret=True, block_q=block, block_k=block)
     np.testing.assert_allclose(kern(*ops), _plain(*ops), atol=2e-5)
     got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=range(5))(*ops)
@@ -64,8 +69,8 @@ def test_two_rows_with_different_contents_do_not_mix():
     """Row 1 alone gives row 1 of the pair, forward and dk_rope (the sum
     over heads lives in a scratch that the next row must not inherit)."""
     *ops, w = _operands(2, 256, seed=7)
-    kern = lambda *a: fa.flash_attention_mla(*a, interpret=True,
-                                             block_q=128, block_k=128)
+    kern = lambda *a: _two_products(*a, interpret=True, block_q=128,
+                                    block_k=128)
     one = [x[1:] for x in ops]
     np.testing.assert_allclose(kern(*ops)[1:], kern(*one), atol=1e-6)
     both = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=3)(*ops)
@@ -76,7 +81,7 @@ def test_two_rows_with_different_contents_do_not_mix():
 
 def test_bf16_operands_keep_their_types():
     *ops, _ = _operands(1, 256, seed=3, dtype=jnp.bfloat16)
-    kern = lambda *a: fa.flash_attention_mla(*a, interpret=True)
+    kern = lambda *a: _two_products(*a, interpret=True)
     out = kern(*ops)
     assert out.dtype == jnp.bfloat16
     grads = jax.grad(lambda *a: kern(*a).astype(jnp.float32).sum(),
@@ -106,7 +111,7 @@ def test_the_tile_counter_reads_as_at_the_same_S_without_the_second_product():
     q, k, v = ops[0], ops[2], ops[4]
     plain = delta(lambda: jax.grad(lambda q: fa.flash_attention(
         q, k, v, interpret=True).sum())(q))
-    two = delta(lambda: jax.grad(lambda q: fa.flash_attention_mla(
+    two = delta(lambda: jax.grad(lambda q: _two_products(
         q, ops[1], k, ops[3], v, interpret=True).sum())(q))
     assert plain == two and plain
 
@@ -118,11 +123,19 @@ def test_the_tile_counter_reads_as_at_the_same_S_without_the_second_product():
     (3, 128, 64, 128, False),       # a rope block would hold half a head pair
     (4, 16, 8, 16, False)])
 def test_which_widths_the_kernels_take(H_, D_, R_, Dv, ok):
-    lanes = fa.mla_lanes(H_, D_, R_, Dv)
-    assert (lanes is not None) == ok
+    """``mla_lanes``: the second term as the head programs read it (a
+    128-lane block of ``q_rope`` holds ``128 // R`` heads; every head reads
+    the one key), or None; the entry raises where it is None."""
+    term = fa.mla_lanes(H_, D_, R_, Dv)
+    assert (term is not None) == ok
     if ok:
-        assert lanes.reason == (f"rows layout, 1 head a {D_}-lane block; "
-                                f"{D_} + {R_} shared rope lanes, v {Dv}")
+        per = max(1, 128 // R_)
+        assert term == fa.Term(block=per * R_, keys=H_, heads=per)
+        return
+    with pytest.raises(ValueError, match="no two-product kernel"):
+        fa.flash_attention(*(jnp.zeros((1, 128, H_, w)) for w in (D_, D_, Dv)),
+                           q_rope=jnp.zeros((1, 128, H_, R_)),
+                           k_rope=jnp.zeros((1, 128, 1, R_)), interpret=True)
 
 
 def test_the_dispatcher_says_what_ran_and_why():
@@ -137,14 +150,14 @@ def test_the_dispatcher_says_what_ran_and_why():
     mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
                                           devices=jax.devices()[:1]))
     try:
-        flash = attention_lib._two_product_attention(
-            qn, kn, v, qr, kr, causal=True, scale=None, impl="auto",
-            interpret=True)
+        flash = attention_lib.dot_product_attention(
+            qn, kn, v, q_rope=qr, k_rope=kr, interpret=True)
         # eight rows over the eight devices of the test mesh: a shard_map
         mesh_lib.set_mesh(mesh_lib.build_mesh({"fsdp": 8}))
-        rows = [jnp.concatenate([x] * 8) for x in (qn, kn, v, qr, kr)]
-        sharded = attention_lib._two_product_attention(
-            *rows, causal=True, scale=None, impl="auto", interpret=True)
+        qn8, kn8, v8, qr8, kr8 = (jnp.concatenate([x] * 8)
+                                  for x in (qn, kn, v, qr, kr))
+        sharded = attention_lib.dot_product_attention(
+            qn8, kn8, v8, q_rope=qr8, k_rope=kr8, interpret=True)
     finally:
         mesh_lib.set_mesh(None)
     np.testing.assert_allclose(flash, auto, atol=2e-5)
@@ -163,8 +176,8 @@ def test_the_dispatcher_says_what_ran_and_why():
     attention_lib.dot_product_attention(*small[:3], q_rope=small[3],
                                         k_rope=small[4], impl="auto")
     with pytest.raises(ValueError, match="no two-product kernel"):
-        fa.flash_attention_mla(small[0], small[3], small[1], small[4],
-                               small[2], interpret=True)
+        fa.flash_attention(*small[:3], q_rope=small[3], k_rope=small[4],
+                           interpret=True)
 
 
 @pytest.mark.parametrize("S", [1024, 896])

@@ -57,7 +57,7 @@ def build(norm_topk_prob=False, scan=False, loss_chunk=0, **moe_kw):
                       num_attention_heads=H, intermediate_size=I,
                       max_position_embeddings=S, moe=moe, qk_norm=True,
                       loss_chunk=loss_chunk, scan_layers=scan,
-                      dtype=jnp.float32, attn_impl="xla")
+                      dtype=jnp.float32, attn_impl="jnp")
     model = LlamaForCausalLM(cfg)
     rng = np.random.default_rng(7)
     ids = jnp.asarray(rng.integers(0, V, (2, S)), jnp.int32)

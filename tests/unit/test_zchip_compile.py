@@ -30,6 +30,36 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+# sha256 of what the flash call's loss and gradients trace to at the cells'
+# attention shapes (``flash_parent_sweep.traced_digest`` of the functions the
+# tests below compile), computed on commit 3fb1f7b, the parent of PR 44: with
+# one product a score the one kernel family traces to the jaxprs of the
+# parent's, to the letter.  The fifth cell's (two products) is pinned anew,
+# beside the count of its kernels' primitives on that commit.
+PARENT_TRACES = {
+    # (rows, positions, heads, head_dim[, window | "halves" | rope lanes])
+    (2, 1024, 25, 64):
+        "e1d6c255bc942f13acea2b13ccea9ced1a9e669b552c308b36b3710c376d658f",
+    (2, 4096, 16, 128):
+        "e35537593b0eef8ae219359de1b5780f1f72fd41804fd94111d81d75d85e84ca",
+    (2, 2048, 8, 256):
+        "39f4875babb6fc684e5168b0af3886400d7ef22a6604e33c2a6c8d7cd4e068f4",
+    (4, 8192, 32, 128, 1024):
+        "10b324ae919cd0cccc04eacc35a5cef591c5afd0403afed2f685537b68218a3e",
+    (4, 8192, 32, 128, None):
+        "17a40f5338868824fa591185168dabf6ff252d6636bad89e07e1d226abf50553",
+    (3, 8192, 32, 128, 2048):
+        "9e9614a9657f8cc97b944516f312a9aaffc67ba2324509b462584dea5a99f905",
+    (3, 8192, 32, 128, None):
+        "9ddac0932d32c55a79f6ff99ad95ed1d6007141dab111c7709a89aa4428febf7",
+    (2, 16384, 32, 128, "halves"):
+        "4d4ba315317a87f558b9b160e3b064925580b0e56529f5c01212be2af0c38673",
+    (2, 8192, 32, 128, 64):
+        "8d24d44e62c91e947aff6910504ef50746ab6feb48653fc315a1cfd3bc0882f6",
+}
+PARENT_TWO_PRODUCTS = {"while": 2, "cond": 5, "dot_general": 60, "exp": 14}
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
 def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, k, n):
     """The expert matmuls of ``train-olmoe-z3-1chip``: 8192 tokens x top-8
@@ -57,25 +87,35 @@ def test_grouped_matmul_compiles_at_olmoe_widths(one_chip, k, n):
 
 
 def test_two_product_flash_compiles_at_the_fifth_cells_shape(one_chip):
-    """The latent attention of ``train-joyai-flash-8k-1chip`` (PR 38): one
-    8192-token row of 32 heads, 128 nope + 64 rope channels with one rope
+    """The latent attention of ``train-joyai-flash-8k-1chip`` (PR 38): two
+    8192-token rows of 32 heads, 128 nope + 64 rope channels with one rope
     key for all heads, values 128 wide; the forward and the one backward
     kernel, whose whole-sequence float32 scratch (dq_nope, dq_rope and the
     heads' dk_rope) and double-buffered panels ask Mosaic for more VMEM
-    than its default."""
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_mla
+    than its default.  Since PR 44 a second term of the one kernel family:
+    its kernels hold the loops, branches, products and exponentials that
+    the two-product family's held on the parent commit (counted there)."""
+    from tests.unit.flash_parent_sweep import (kernel_primitives,
+                                               traced_digest)
 
-    B, S, H, D, R = 1, 8192, 32, 128, 64
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    B, S, H, D, R = 2, 8192, 32, 128, 64
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
-    def loss(*ops):
-        return flash_attention_mla(*ops).astype(jnp.float32).sum()
+    def loss(qn, qr, kn, kr, v):
+        return flash_attention(qn, kn, v, q_rope=qr, k_rope=kr).astype(
+            jnp.float32).sum()
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(5))).lower(
-        arg(B, S, H, D), arg(B, S, H, R), arg(B, S, H, D), arg(B, S, 1, R),
-        arg(B, S, H, D)).compile()
+    grads = jax.value_and_grad(loss, argnums=range(5))
+    args = (arg(B, S, H, D), arg(B, S, H, R), arg(B, S, H, D),
+            arg(B, S, 1, R), arg(B, S, H, D))
+    assert traced_digest(grads, *args) == PARENT_TRACES[B, S, H, D, R]
+    assert kernel_primitives(grads, *args, names=(
+        "cond", "while", "scan", "dot_general", "exp")) == PARENT_TWO_PRODUCTS
+    compiled = jax.jit(grads).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
@@ -250,6 +290,8 @@ def test_flash_kernels_compile_at_both_cells_shapes(one_chip, B, S, H, D,
     panels in VMEM: q, dO, the bf16 dq block and its float32 scratch), on
     operands shaped as the projections write them; and GPT-J's heads at
     its context, the widest lane block (it fits in 256-row key blocks)."""
+    from tests.unit.flash_parent_sweep import traced_digest
+
     from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
                                                           flash_lanes)
 
@@ -261,8 +303,9 @@ def test_flash_kernels_compile_at_both_cells_shapes(one_chip, B, S, H, D,
             jnp.float32).sum()
 
     arg = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        arg, arg, arg).compile().as_text()
+    grads = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert traced_digest(grads, arg, arg, arg) == PARENT_TRACES[B, S, H, D]
+    text = jax.jit(grads).lower(arg, arg, arg).compile().as_text()
     results = [shape for shape, op, _ in _entry(text).values()
                if op == "custom-call" and shape.startswith("(")]
     assert len(results) == 2
@@ -403,12 +446,13 @@ def test_stored_transposed_is_the_chips_own_layout(one_chip):
             assert stored_transposed(s) == (order == "0,1"), (s, dtype, order)
 
 
-@pytest.mark.parametrize("window,B", [(1024, 4), (None, 4), (2048, 3)])
+@pytest.mark.parametrize("window,B", [(1024, 4), (None, 4), (2048, 3),
+                                      (None, 3)])
 def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window, B):
     """The flash forward and backward of ``train-mellum2-8k-1chip``: 4 rows
     of 8192, 32 query heads on 4 key-value heads of 128, with the 1024-key
     window and without; and of ``train-trinity-mini-8k-1chip``'s window
-    layers (3 rows, 2048 keys).  Since PR 43 a windowed sweep is ONE
+    layers (3 rows, 2048 keys) and full ones.  Since PR 43 a windowed sweep is ONE
     straight-line block of three (five) tiles and the loop over full tiles
     folds two a trip: the bodies' values must still fit the VMEM the calls
     ask for.  The backward's panels of q, dO and dq beside the
@@ -416,6 +460,8 @@ def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window, B):
     MB of VMEM, so the call asks for what it holds; k, v, dk and dv are
     ``[B,8192,512]`` on both sides of both calls and nothing key- or
     value-shaped is 4096 wide; the calls carry the layer type's name."""
+    from tests.unit.flash_parent_sweep import traced_digest
+
     from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
                                                           flash_lanes)
 
@@ -434,8 +480,10 @@ def test_flash_kernels_compile_at_the_third_cells_shape(one_chip, window, B):
     wide = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
     narrow = jax.ShapeDtypeStruct((B, S, KV * D), jnp.bfloat16,
                                   sharding=one_chip)
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        wide, narrow, narrow).compile().as_text()
+    grads = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert traced_digest(grads, wide, narrow, narrow) \
+        == PARENT_TRACES[B, S, H, D, window]
+    text = jax.jit(grads).lower(wide, narrow, narrow).compile().as_text()
     calls = {name: (shape, ops) for name, (shape, op, ops)
              in _entry(text).items() if op == "custom-call"
              and shape.startswith("(")}
@@ -719,6 +767,8 @@ def test_the_halves_kernels_compile_at_the_sixth_cells_shape(one_chip):
     on 4 key-value heads of 128, blocks of 4.  Their loops over clean FULL
     tiles fold two a trip (PR 43) beside 2L-row panels that already ask
     Mosaic for 68 MB of VMEM."""
+    from tests.unit.flash_parent_sweep import traced_digest
+
     from deepspeed_tpu.ops.pallas.flash_attention import \
         flash_attention_halves
 
@@ -733,8 +783,10 @@ def test_the_halves_kernels_compile_at_the_sixth_cells_shape(one_chip):
                                 sharding=one_chip)
     narrow = jax.ShapeDtypeStruct((B, 2 * L, KV * D), jnp.bfloat16,
                                   sharding=one_chip)
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        wide, narrow, narrow).compile()
+    grads = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    assert traced_digest(grads, wide, narrow, narrow) \
+        == PARENT_TRACES[B, 2 * L, H, D, "halves"]
+    compiled = jax.jit(grads).lower(wide, narrow, narrow).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2
 
 
