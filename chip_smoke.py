@@ -831,9 +831,75 @@ def kernel_qk_rows():
                                                                  worst)
 
 
+def kernel_short_conv(time_it: bool = True):
+    """The gated short convolution at the seventh cell's shape, ``(4, 8192,
+    3 x 2048)`` rows and 3 taps (PR 45): the Pallas row kernels, forward and
+    ``jax.vjp`` (d rows and d taps), against ``benchmark/reference/lfm2.py``'s
+    explicit loop over taps in float32; every row of the batch held apart (a
+    halo read from the row before shows in rows 1.. only) and the first and
+    last positions of each row alone (where the halo is all of the filter's
+    reach).  XLA's shifted form is held to the same reference, and both are
+    timed, forward + backward: which one ``auto`` takes is a reading
+    (PERF.md section 6)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.harness.manifest import ROOT, load_module
+    from deepspeed_tpu.ops.short_conv import short_conv_rows
+
+    reference = load_module(ROOT, "reference", "lfm2")
+    B, S, C, L = 4, 8192, 2048, 3
+    ks = jax.random.split(jax.random.PRNGKey(45), 3)
+    bcu = jax.random.normal(ks[0], (B, S, 3 * C), jnp.float32).astype(
+        jnp.bfloat16)
+    w = jax.random.normal(ks[1], (C, L), jnp.float32)
+    dy = jax.random.normal(ks[2], (B, S, C), jnp.float32).astype(jnp.bfloat16)
+
+    def ref(bcu, w):
+        f = reference._f32
+        z = f(bcu[..., :C]) * f(bcu[..., 2 * C:])
+        return f(bcu[..., C:2 * C]) * reference._filter(z, f(w), None, None)
+
+    def both(fn):
+        def run(bcu, w):
+            out, vjp = jax.vjp(fn, bcu, w)
+            return (out,) + vjp(dy.astype(out.dtype))
+        return jax.jit(run)
+
+    want = both(ref)(bcu, w)
+    edge = list(range(8)) + list(range(S - 8, S))
+    for impl in ("pallas", "shift"):
+        run = both(lambda bcu, w: short_conv_rows(bcu, w, impl))
+        got = jax.block_until_ready(run(bcu, w))
+        for n, g, r in zip(("y", "d rows", "d taps"), got, want):
+            name = f"short_conv {impl} {n}"
+            if g.ndim != 3:
+                _check_close(name, g, r)
+                continue
+            g, r = (np.asarray(t, np.float32) for t in (g, r))
+            worst = np.abs(g - r).max(axis=(1, 2)) / np.abs(r).max(axis=(1, 2))
+            ends = np.abs(g - r)[:, edge].max() / np.abs(r)[:, edge].max()
+            print(f"  {name}: worst of {B} rows {worst.max():.2e}, first and "
+                  f"last 8 positions {ends:.2e}", flush=True)
+            assert np.isfinite(g).all() and max(worst.max(), ends) <= TOL, (
+                name, worst, ends)
+        if time_it:
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = run(bcu, w)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / 20 * 1e3
+            print(f"  short_conv {impl}: forward + backward {ms:.3f} ms "
+                  f"(least for 11 vectors of bf16 at 819 GB/s: "
+                  f"{11 * B * S * C * 2 / 819e9 * 1e3:.3f} ms)", flush=True)
+
+
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
-                kernel_qk_rows,
+                kernel_qk_rows, kernel_short_conv,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

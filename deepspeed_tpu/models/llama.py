@@ -58,6 +58,20 @@ of ``ops/attention.py block_diffusion_mask``, and takes a cross-entropy
 weighted by ``1 / t`` from the masked positions of the noisy half, read at
 their own position (no shift); the clean half has no loss and exists to
 give keys and values.
+
+LFM2 (Liquid AI, 2025; ``model_type: lfm2_moe``) is the first stack whose
+layers are not all attention: ``layer_types`` holds ``"conv"`` where the
+token mixer is the family's double-gated short convolution
+(:class:`ShortConv`, ``ops/short_conv.py``: one projection to three
+thirds, a causal depthwise filter of ``conv_L_cache`` taps between two
+elementwise gates, one projection back) and ``"full_attention"`` where it
+is grouped-query attention with ``qk_norm="head"``.  Everything after the
+mixer is the block that stands.  A window, a rotary table, the per-head
+norm and the output gate are asked of the attention layers alone; a conv
+layer has no positional encoding and no cache leaf yet, so ``decode=True``
+with one raises.  ``tie_word_embeddings`` makes the head read
+``embed_tokens`` (no ``lm_head`` leaf; the table's gradient is the sum of
+both uses).
 """
 from __future__ import annotations
 
@@ -76,6 +90,7 @@ from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift
 
 
 SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
+CONV = "conv"       # a layer whose token mixer is ShortConv, not attention
 # the widths latent attention takes together (their config.json names)
 _MLA_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
@@ -105,8 +120,9 @@ class LlamaConfig:
     ``num_key_value_heads``, ``head_dim``, ``intermediate_size``,
     ``moe_intermediate_size``, ``rms_norm_eps``, ``rope_theta``,
     ``rope_parameters``, ``sliding_window``, ``layer_types``,
-    ``initializer_range``, ``num_dense_layers``, ``mup_enabled``.  The rest
-    are this program's own."""
+    ``initializer_range``, ``num_dense_layers``, ``mup_enabled``,
+    ``conv_L_cache``, ``conv_bias``, ``tie_word_embeddings``.  The rest are
+    this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -124,8 +140,17 @@ class LlamaConfig:
     # width of one expert of ``moe``; None → intermediate_size
     moe_intermediate_size: Optional[int] = None
     # one entry a layer (more are ignored: a model cut in depth keeps its
-    # source's list), "sliding_attention" | "full_attention"; None → all full
+    # source's list), "sliding_attention" | "full_attention" | "conv" (a
+    # short-convolution mixer in attention's place); None → all full
     layer_types: Optional[tuple] = None
+    # taps of a "conv" layer's causal depthwise filter (the family's name:
+    # the positions its serving cache would hold); the last is the current
+    # position
+    conv_L_cache: int = 3
+    # a bias on the conv layer's two projections and its filter: not written
+    conv_bias: bool = False
+    # the head reads ``embed_tokens``: no ``lm_head`` leaf
+    tie_word_embeddings: bool = False
     # keys a "sliding_attention" layer keeps: 0 <= q_pos - k_pos < window
     sliding_window: Optional[int] = None
     # {layer type: {rope_type, rope_theta, factor, ...}} (ops/rotary.py
@@ -228,9 +253,10 @@ class LlamaConfig:
             raise ValueError("num_dense_layers counts the blocks that moe "
                              "leaves dense; there is no moe")
         for t in self.layer_types or ():
-            if t not in (SLIDING, FULL_ATTENTION):
-                raise ValueError(f"layer_types holds {t!r}; {SLIDING!r} and "
-                                 f"{FULL_ATTENTION!r} are written")
+            if t not in (SLIDING, FULL_ATTENTION, CONV):
+                raise ValueError(f"layer_types holds {t!r}; {SLIDING!r}, "
+                                 f"{FULL_ATTENTION!r} and {CONV!r} are "
+                                 f"written")
         if self.layer_types is not None:
             if len(self.layer_types) < self.num_hidden_layers:
                 raise ValueError(
@@ -238,6 +264,28 @@ class LlamaConfig:
                     f"{self.num_hidden_layers}")
             if SLIDING in self.kinds and not self.sliding_window:
                 raise ValueError(f"{SLIDING} layers need sliding_window")
+        if self.conv_bias:
+            raise NotImplementedError(
+                "conv_bias=True: the short convolution's projections and "
+                "filter are written without a bias")
+        if CONV in self.kinds:
+            if self.conv_L_cache < 1:
+                raise ValueError(f"conv_L_cache {self.conv_L_cache}: a conv "
+                                 f"layer's filter has at least one tap")
+            if self.decode:
+                raise NotImplementedError(
+                    "decode=True with a conv layer (layer_types): the cache "
+                    "holds keys and values, and a short convolution's state "
+                    "(its last conv_L_cache - 1 inputs) is no leaf of it yet")
+            if self.diffusion is not None:
+                raise NotImplementedError(
+                    "diffusion (block-diffusion training) with a conv "
+                    "layer: the filter would run across the two halves "
+                    "[noisy ; clean] and no block mask is written for it")
+            if self.mla_fields:
+                raise NotImplementedError(
+                    "latent attention with a conv layer (layer_types): "
+                    "LlamaLatentAttention takes no layer type")
         if self.mla_fields and not all(getattr(self, f) for f in _MLA_WIDTHS):
             raise ValueError(
                 f"latent attention takes {', '.join(_MLA_WIDTHS)} "
@@ -629,6 +677,35 @@ class LlamaLatentAttention(nn.Module):
                        weight("o_proj", ("heads", "embed"), (H * Dv, E)))
 
 
+class ShortConv(nn.Module):
+    """The LFM2 family's token mixer of a ``"conv"`` layer (released code:
+    ``Lfm2MoeShortConv``): ``[B ; C ; u] = h W_in`` (in this order),
+    ``y = (C * filter(B * u)) W_out`` with a causal depthwise filter of
+    ``conv_L_cache`` taps a channel whose last tap is the current position
+    (``ops/short_conv.py``).  No bias, no activation, no position.  The
+    channels shard as the attention projections' do: ``in_proj`` like the
+    fused q, k, v, the taps and ``out_proj`` like ``o_proj``'s input."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.short_conv import short_conv_rows
+
+        cfg = self.cfg
+        E = cfg.hidden_size
+        with trace.device_span("short_conv/in_proj"):
+            bcu = _dense(x, 3 * E, ("embed", "qkv"), cfg=cfg, name="in_proj",
+                         module=self)
+        taps = self.param("conv_kernel", nn.with_partitioning(
+            nn.initializers.normal(cfg.initializer_range), ("heads", None)),
+            (E, cfg.conv_L_cache), cfg.param_dtype)
+        with trace.device_span("short_conv/filter"):
+            y = short_conv_rows(bcu, taps)
+        with trace.device_span("short_conv/out_proj"):
+            return _dense(y, E, ("heads", "embed"), cfg=cfg, name="out_proj",
+                          module=self)
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
@@ -679,12 +756,16 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
-        self_attn = LlamaLatentAttention(cfg, name="self_attn") \
-            if cfg.kv_lora_rank \
-            else LlamaAttention(cfg, self.kind, self.blockdiff,
-                                name="self_attn")
-        attn = self_attn(RMSNorm(cfg, name="input_norm")(x), position_ids,
-                         attn_mask)
+        if self.kind == CONV:       # the mixer reads no position and no mask
+            attn = ShortConv(cfg, name="conv")(
+                RMSNorm(cfg, name="input_norm")(x))
+        else:
+            self_attn = LlamaLatentAttention(cfg, name="self_attn") \
+                if cfg.kv_lora_rank \
+                else LlamaAttention(cfg, self.kind, self.blockdiff,
+                                    name="self_attn")
+            attn = self_attn(RMSNorm(cfg, name="input_norm")(x),
+                             position_ids, attn_mask)
         if cfg.sandwich_norm:
             x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
             h = RMSNorm(cfg, name="pre_mlp_norm")(x)
@@ -907,9 +988,13 @@ class LlamaForCausalLM(nn.Module):
                 diffusion_kept=(~masked).sum().astype(jnp.int32),
                 diffusion_t_mean=t.mean())
         h = RMSNorm(cfg, name="norm")(h)
-        lm_head = self.param("lm_head", nn.with_partitioning(
-            nn.initializers.normal(cfg.initializer_range), ("embed", "vocab")),
-            (cfg.hidden_size, cfg.padded_vocab_size), cfg.param_dtype)
+        if cfg.tie_word_embeddings:     # the head reads the table
+            lm_head = embed.T
+        else:
+            lm_head = self.param("lm_head", nn.with_partitioning(
+                nn.initializers.normal(cfg.initializer_range),
+                ("embed", "vocab")),
+                (cfg.hidden_size, cfg.padded_vocab_size), cfg.param_dtype)
         tgt = None
         if labels is not None:
             tgt = shift_labels(labels) if shift else labels
@@ -1058,13 +1143,17 @@ class LlamaForCausalLM(nn.Module):
                     + cfg.kv_lora_rank * H * (Dn + Dv) + H * Dv * E)
             score = Dn + Dr + Dv
         mtp = cfg.num_nextn_predict_layers   # a block, eh_proj, the head
-        n = (2 * cfg.padded_vocab_size * E + L * attn
+        # a conv layer's mixer: in_proj to three thirds, out_proj, the taps
+        convs = cfg.kinds.count(CONV)
+        conv = 3 * E * E + E * E + E * cfg.conv_L_cache
+        table = (1 if cfg.tie_word_embeddings else 2) * cfg.padded_vocab_size
+        n = (table * E + (L - convs) * attn + convs * conv
              + cfg.num_dense_layers * dense
              + (L - cfg.num_dense_layers) * ffn
              + mtp * (attn + ffn + 2 * E * E + cfg.padded_vocab_size * E))
-        # QK^T and AV over the keys a layer keeps: all positions, or the
-        # window where that is shorter
+        # QK^T and AV over the keys an attention layer keeps: all
+        # positions, or the window where that is shorter
         S = cfg.max_position_embeddings
-        keys = sum(min(S, cfg.window(k) or S) for k in cfg.kinds) \
-            if cfg.kinds else (L + mtp) * S
+        keys = sum(min(S, cfg.window(k) or S) for k in cfg.kinds
+                   if k != CONV) if cfg.kinds else (L + mtp) * S
         return 6.0 * n + 6 * H * score * keys

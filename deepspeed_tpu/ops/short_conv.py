@@ -1,0 +1,113 @@
+"""The double-gated short convolution of the LFM2 family (Liquid AI, 2025;
+``model_type: lfm2`` / ``lfm2_moe``): a causal depthwise filter of ``L`` taps
+a channel between two elementwise gates,
+
+    z   = bg * u
+    c_t = sum_{j=0..L-1} w[:, j] * z_{t-(L-1)+j}        (z before 0 is 0)
+    y   = cg * c
+
+``bg``, ``cg`` and ``u`` are the three thirds of one projection of the
+hidden states, ``(B, S, C)`` each; ``w`` is ``(C, L)`` and its LAST tap
+multiplies the current position.  No activation, no bias, nothing carried
+from one row of the batch to the next.
+
+Two forms:
+
+- ``"pallas"`` (what ``"auto"`` takes on a TPU where the shape allows):
+  ``ops/pallas/short_conv.py``, one pass over the projection's rows forward
+  and one backward; its text says why XLA's form is not enough.
+- ``"shift"`` (``"auto"`` everywhere else, and what the tests hold the
+  kernels to): ``L`` shifted multiply-adds along the sequence axis in plain
+  XLA, the backward by autodiff.
+
+At ``(4, 8192, 3 x 2048)`` on the v5e, forward + backward: the kernels 2.30
+ms, ``shift`` 9.03, the HBM rate's least 1.80; a depthwise
+``lax.conv_general_dilated`` between the gates read 19.84 and is not kept
+(my chip run, PR 45, PERF.md section 6).
+
+:func:`short_conv_rows` takes the projection's output whole, ``(B, S, 3C)``
+= ``[Bg ; Cg ; u]``, which is what a model has and what the kernels read.
+The arithmetic inside is float32 whatever the operands are and the result
+has the operands' type.  ``kernel_dispatch_total{site="short_conv"}`` says
+which form a call resolved to, and why.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+IMPLS = ("auto", "pallas", "shift")
+
+
+def _filter_shift(z: jax.Array, w: jax.Array) -> jax.Array:
+    """``c_t = sum_j w[:, j] z_{t-(L-1)+j}`` by shifts of ``z`` (B, S, C)."""
+    S, L = z.shape[1], w.shape[1]
+    c = z * w[:, L - 1]
+    for back in range(1, min(L, S)):       # the tap ``back`` positions ago
+        shifted = jnp.pad(z[:, :S - back], ((0, 0), (back, 0), (0, 0)))
+        c = c + shifted * w[:, L - 1 - back]
+    return c
+
+
+def _shift(bcu: jax.Array, w: jax.Array) -> jax.Array:
+    f32 = jnp.float32
+    C = w.shape[0]
+    bg, cg, u = (bcu[..., i * C:(i + 1) * C].astype(f32) for i in range(3))
+    return (cg * _filter_shift(bg * u, w.astype(f32))).astype(bcu.dtype)
+
+
+def _plan(bcu, w, impl):
+    """``(impl, reason, batch axes of a shard_map or None)``."""
+    from .attention import on_tpu
+    from .pallas import short_conv as kernel
+    from .pallas.spmd import kernel_mesh_plan
+
+    if impl == "shift":
+        return impl, "impl='shift' asked for", None
+    C, L = w.shape
+    reason = kernel.supported(bcu.shape[1], C, L, bcu.dtype)
+    if reason is None and impl == "auto" and not on_tpu():
+        reason = "no TPU"
+    verdict = axes = None
+    if reason is None:
+        verdict, axes = kernel_mesh_plan(bcu.shape[0])
+        if verdict is None:
+            reason = "kernel_mesh_plan refused the mesh"
+    if reason is not None:
+        if impl == "pallas":
+            raise NotImplementedError(f"short_conv impl='pallas': {reason}")
+        return "shift", reason, None
+    return "pallas", (f"rows {bcu.shape[1]} x 3 x {C}, {L} taps; "
+                      + ("one device" if verdict == "direct" else
+                         f"shard_map over batch axes {axes}")), axes
+
+
+def short_conv_rows(bcu: jax.Array, w: jax.Array, impl: str = "auto",
+                    interpret: bool = False) -> jax.Array:
+    """``Cg * filter(Bg * u)`` (B, S, C) of ``bcu`` (B, S, 3C) = ``[Bg ; Cg
+    ; u]``, a projection's output as it lies, with the taps ``w`` (C, L);
+    see the module's text."""
+    from .pallas.spmd import note_dispatch
+
+    if impl not in IMPLS:
+        raise ValueError(f"short_conv impl {impl!r}: one of {IMPLS}")
+    if bcu.ndim != 3 or w.ndim != 2 or bcu.shape[-1] != 3 * w.shape[0]:
+        raise ValueError(
+            f"short_conv_rows takes (B, S, 3C) rows and (C, L) taps, got "
+            f"{bcu.shape} and {w.shape}")
+    impl, reason, axes = _plan(bcu, w, impl)
+    note_dispatch("short_conv", impl, reason)
+    if impl != "pallas":
+        return _shift(bcu, w)
+    from .pallas.short_conv import short_conv_rows as kernel
+
+    if axes is None:
+        return kernel(bcu, w, interpret)
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    rows = P(axes if axes else None, None, None)
+    return jax.shard_map(lambda b, w: kernel(b, w, interpret),
+                         mesh=get_mesh(), in_specs=(rows, P()),
+                         out_specs=rows, check_vma=False)(bcu, w)

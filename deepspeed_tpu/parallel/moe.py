@@ -94,6 +94,10 @@ class MoEConfig:
     # dropless routing only: renormalise the k chosen probabilities to sum
     # to 1 (the capacity gates keep GShard's rule: top-2 does, top-1 not)
     norm_topk_prob: bool = False
+    # added to the sum the k chosen scores are renormalised by (the LFM2
+    # family's released routing has 1e-6 there: about 4 ulps of a float32
+    # sum of four sigmoid scores, so it is computed and not argued away)
+    norm_topk_eps: float = 0.0
     z_loss_weight: float = 0.0          # router z-loss (ST-MoE), dropless only
     expert_act: str = "gelu"            # 'gelu': wi/wo; 'swiglu': gate/up/down
     # a share of an expert-parallel layer (dropless only): the router
@@ -155,8 +159,8 @@ class MoEConfig:
         """The routing fields set away from their defaults, by name."""
         return tuple(f for f, off in (
             ("score_func", "softmax"), ("route_scale", 1.0),
-            ("bias_update_rate", None),
-            ("num_shared_experts", 0)) if getattr(self, f) != off)
+            ("bias_update_rate", None), ("num_shared_experts", 0),
+            ("norm_topk_eps", 0.0)) if getattr(self, f) != off)
 
 
 def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: int,
@@ -260,14 +264,16 @@ def top2_gating(logits: jax.Array, capacity: int, rng=None,
 
 def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
                  *, score_func: str = "softmax",
-                 bias: Optional[jax.Array] = None, route_scale: float = 1.0
+                 bias: Optional[jax.Array] = None, route_scale: float = 1.0,
+                 norm_eps: float = 0.0
                  ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
                             jax.Array]:
     """Dropless top-k routing over float32 ``logits`` (S, E).
 
     Returns ``(weights (S, k), experts (S, k) int32, counts (E,) int32,
     l_balance, l_z)``: the k largest scores of each token (softmax
-    probabilities, renormalised to sum to 1 only with ``norm_topk_prob``),
+    probabilities, renormalised to sum to 1 only with ``norm_topk_prob``:
+    over ``sum + norm_eps``),
     how many of the S*k assignments each expert received, the
     load-balancing loss ``E * sum_e f_e * P_e`` (``f_e`` expert e's share
     of the assignments, ``P_e`` its mean score; 1 when balanced under
@@ -290,7 +296,8 @@ def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
     hit = experts.T[None] == jnp.arange(E)[:, None, None]        # (E, k, S)
     weights = jnp.where(hit, probs.T[:, None], 0.0).sum(0).T      # (S, k)
     if norm_topk_prob:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
+        total = weights.sum(axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
     if route_scale != 1.0:
         weights = weights * route_scale
     counts = _count_ids(experts.reshape(-1), E)
@@ -670,7 +677,8 @@ class MoELayer(nn.Module):
                 logits, bias = gate(x2, train, logits_only=True)
                 weights, chosen, counts, l_aux, l_z = topk_routing(
                     logits, k, cfg.norm_topk_prob, score_func=cfg.score_func,
-                    bias=bias, route_scale=cfg.route_scale)
+                    bias=bias, route_scale=cfg.route_scale,
+                    norm_eps=cfg.norm_topk_eps)
             out = experts(x2, routing=(weights, chosen))
             if cfg.num_shared_experts:
                 with trace.device_span("moe/shared"):

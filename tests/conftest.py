@@ -54,6 +54,32 @@ def nominal_cpu_physics(monkeypatch):
         monkeypatch.setitem(table, "cpu", value)
 
 
+@pytest.fixture(autouse=True)
+def _a_reader_that_finds_nothing_reads_an_empty_registry(request):
+    """``tests/benchmark/test_benchmark.py``'s test of that name asks every
+    reader for ``None`` on an observation that holds nothing, and the
+    readers of ``program_counter`` metrics read the program's process-wide
+    registry: a test file that trained a model earlier on the same xdist
+    worker (which file lands on which worker moves with every file a PR
+    adds) has booked ``diffusion_tokens_total`` there, and the reader
+    rightly reads it.  The registry is emptied for that one test and put
+    back after it (files under ``tests/benchmark/`` that exist are not
+    edited)."""
+    if request.node.name != "test_a_reader_that_finds_nothing_returns_nothing":
+        yield
+        return
+    from deepspeed_tpu.telemetry import get_registry
+
+    reg = get_registry()
+    with reg._lock:
+        kept = dict(reg._metrics)
+        reg._metrics.clear()
+    yield
+    with reg._lock:
+        reg._metrics.clear()
+        reg._metrics.update(kept)
+
+
 # Three tests of ``tests/benchmark/test_olmoe_cell.py`` (PR 26) pin the
 # manifest's tail to the OLMoE cell: two assert that no configuration is cut
 # in anything but depth and context, one that OLMoE's entries are the last
@@ -116,6 +142,14 @@ _PINNED_TO_FOUR_CELLS = (
     "test_every_cell_loads_the_two_entries[train-mellum2-8k-1chip]",
     "test_every_cell_loads_the_two_entries[train-trinity-mini-8k-1chip]",
 )
+# One of ``tests/benchmark/test_sdar_cell.py`` (PR 40) holds the LAST two
+# entries of the manifest's per-layer metrics to the two that cell brought.
+# ``train-lfm2-hybrid-8k-1chip`` (PR 45) brings two of its own behind them,
+# so it fails by construction, and is expected to, strictly;
+# ``tests/benchmark/test_lfm2_cell.py`` holds its position-free version
+# (what a cell brought stands behind what older cells brought or joined,
+# in its order, and lists that cell alone), which the next cell needs no
+# copy of.  The same ROADMAP.md job removes this mark with the others.
 _PINNED_TO_A_CELLS_OWN_TAIL = "pins a cell's list of per-layer metrics " \
     "to its day; superseded by test_hbm_readers.py (PR 35)"
 _SUPERSEDED = [
@@ -143,6 +177,10 @@ _SUPERSEDED = [
     ("test_hbm_readers.py", _PINNED_TO_FOUR_CELLS,
      "pins the manifest's cells and the metrics' tail to four cells; "
      "superseded by test_joyai_cell.py (PR 38)"),
+    ("test_sdar_cell.py",
+     ("test_the_two_new_metrics_stand_last_and_list_this_cell_alone",),
+     "pins the last two per-layer metrics to the sixth cell's; superseded "
+     "by test_lfm2_cell.py (PR 45)"),
 ]
 
 

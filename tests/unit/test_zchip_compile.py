@@ -817,3 +817,85 @@ def test_the_first_cells_flash_kernels_are_the_parents(one_chip):
     found = kernel_primitives(grads, arg, arg, arg)
     assert "while" not in found and "scan" not in found
     jax.jit(grads).lower(arg, arg, arg).compile()
+
+
+def test_the_short_conv_kernels_compile_at_the_seventh_cells_shape(one_chip):
+    """The row kernels of ``ops/pallas/short_conv.py`` (PR 45) at
+    ``train-lfm2-hybrid-8k-1chip``'s shape: four rows of 8192 positions,
+    3 x 2048 channels as ``in_proj`` wrote them, 3 taps; the forward and the
+    one backward kernel, whose float32 scratches (a block and its 8 rows of
+    halo, twice in the backward) sit beside double-buffered 256-row blocks
+    of the whole 6144-lane row."""
+    from deepspeed_tpu.ops.pallas import short_conv as kernel
+
+    B, S, C, L = 4, 8192, 2048, 3
+    assert kernel.supported(S, C, L, jnp.bfloat16) is None
+
+    def loss(bcu, w):
+        return kernel.short_conv_rows(bcu, w).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((B, S, 3 * C), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((C, L), jnp.float32, sharding=one_chip)
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "short_conv_rows_back" in text
+    # d rows come back whole, as wide as the projection's output
+    assert f"bf16[{B},{S},{3 * C}]" in text
+
+
+def test_the_seventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-lfm2-hybrid-8k-1chip`` (PR 45) as the benchmark builds it,
+    its whole train step compiled for the described chip: three kinds of
+    block in one unrolled stack; each of the four conv layers runs the
+    filter's forward kernel (under the remat too) and its backward once, on
+    ``in_proj``'s ``(4, 8192, 6144)`` rows as they lie; the one attention
+    layer's flash kernels take grouped queries at head_dim 64 with k and v
+    repeated to 32 heads; the tied table has no second leaf; and what the
+    step reserves stays under the chip's 15.75 GiB with the room the
+    set-up's comparisons need."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-lfm2-hybrid-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.kinds.count("conv")) == (4, 8192, 4)
+    assert "lm_head" not in state.params and "embed_tokens" in state.params
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 4.0 < reserved < 12.6, reserved      # 12.33 at PR 45; 15.75 a chip
+    text = compiled.as_text()
+    forward = re.findall(r"short_conv_rows[.\d]* = (\S+) custom-call\(", text)
+    backward = re.findall(r"short_conv_rows_back[.\d]* = (\(.*?\)) "
+                          r"custom-call\(", text)
+    assert len(backward) == 4 and len(forward) == 8, (forward, backward)
+    assert all(f.startswith("bf16[4,8192,2048]") for f in forward)
+    assert all("bf16[4,8192,6144]" in b for b in backward)
+    assert len(re.findall(r"self_attn_full[.\d]* = ", text)) >= 2
+    sites = {(s, i): r for s, i, r, n in dispatch_report() if n}
+    assert "k and v repeated 4x" in sites["attention", "flash"]
+    assert "rows 8192 x 3 x 2048, 3 taps; one device" \
+        in sites["short_conv", "pallas"]

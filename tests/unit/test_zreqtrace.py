@@ -20,7 +20,7 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference.serving import ContinuousBatcher
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.telemetry import (anomaly, exporter, fleet, flightrec,
-                                     registry, reqtrace)
+                                     goodput, registry, reqtrace)
 
 MAX_TOKENS = 48
 
@@ -32,8 +32,14 @@ def _fresh_anomaly(monkeypatch):
     another suite left active on the process singleton (the
     ``test_zattribution`` induced SLO burn was the observed source)
     would promote every trace here and break the sampling/retention
-    assertions."""
+    assertions.  A fresh goodput tracker with it: the singleton's wall
+    clock runs since the worker's first span, so late in a tier-1 run it
+    is past ``goodput_drop``'s warm-up with a ratio under its floor, and
+    the alert fires on the fresh engine's second observation (~1 s): a
+    test that takes longer than that on a loaded machine retained every
+    trace as ``alert`` (seen at PR 45)."""
     monkeypatch.setattr(anomaly, "_default", anomaly.AnomalyEngine())
+    monkeypatch.setattr(goodput, "_default", goodput.GoodputTracker())
     yield
 
 
