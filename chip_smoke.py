@@ -505,7 +505,151 @@ def _skipped_rows(S, M, k, live_share):
           f"matmul's output there holds {held(y)}", flush=True)
 
 
-def kernel_share_dispatch():
+ROW_KERNEL_SHAPES = {         # tokens, top-k, row width, routed experts
+    "mellum2": (32768, 8, 2304, 64),
+    "lfm2": (32768, 4, 2048, 64),
+}
+
+
+def row_kernel_times(builds, reps=5, shapes=ROW_KERNEL_SHAPES):
+    """Device time a call of each row kernel (``ops/pallas/moe_rows.py``:
+    ``pack``, ``gather`` plain and scaled, ``combine`` and its d-weights)
+    at the shapes of the cells that hold a share (experts 16-31 of the
+    routed ones, a seeded even routing), under every module of ``builds``
+    (name -> module: this tree's, a parent checkout's) in one process and
+    one profiler trace, each custom call under a name of its own; every
+    output equal, bit for bit, to the first build's over the rows a call
+    writes.  Prints a line a (shape, build) with the ms a call and the
+    share of the gather's row DMAs started inside a vector block, where
+    the build counts it (``gather_starts``)."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.trace_reduce import read_xplane
+
+    out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "chiprun_out", "row_kernel_times")
+    runs, lines = [], {}
+    for shape, (S, k, M, routed) in shapes.items():
+        R = S * k
+        rng = np.random.default_rng(46)
+        chosen = np.argsort(rng.random((S, routed)), axis=1)[:, :k]
+        flat = (chosen - 16).reshape(-1)
+        flat = np.where((flat >= 0) & (flat < 16), flat, 16)
+        order = np.argsort(flat, kind="stable").astype(np.int32)
+        inv = np.argsort(order).astype(np.int32)
+        n_live = int((flat < 16).sum())
+        inv = jnp.asarray(np.where(flat < 16, inv, R).astype(np.int32))
+        tokens = jnp.asarray(np.where(np.arange(R) < n_live, order // k,
+                                      S).astype(np.int32))
+        live = jnp.array([n_live], jnp.int32)
+        ks = jax.random.split(jax.random.PRNGKey(46), 5)
+        x, g = (jax.random.normal(kk, (S, M), jnp.float32).astype(
+            jnp.bfloat16) for kk in ks[:2])
+        y = jax.random.normal(ks[2], (R, M), jnp.float32).astype(jnp.bfloat16)
+        w = jax.random.uniform(ks[3], (S, k), jnp.float32)
+        scale = jax.random.uniform(ks[4], (R, 1), jnp.float32)
+        written = -(-n_live // 1024) * 1024     # the gather's last block
+        first = None
+        for build, rows in builds.items():
+            tag = f"{shape}_{build}"
+
+            def named(kernel, tag=tag):
+                return f"{tag}_{kernel}"
+
+            calls = {
+                "pack": (jax.jit(lambda x, n: rows.pack_rows(
+                    x, n, name=named("pack"))),
+                    (x, jnp.array([S], jnp.int32))),
+                "pack_live": (jax.jit(lambda y, n: rows.pack_rows(
+                    y, n, name=named("pack_live"))), (y, live)),
+            }
+            px = calls["pack"][0](*calls["pack"][1])
+            py = calls["pack_live"][0](*calls["pack_live"][1])
+            calls.update({
+                "gather": (jax.jit(lambda p, i, n: rows.gather_rows(
+                    p, i, n, name=named("gather"))), (px, tokens, live)),
+                "gather_scaled": (jax.jit(
+                    lambda p, i, n, s: rows.gather_rows(
+                        p, i, n, s, name=named("gather_scaled"))),
+                    (px, tokens, live, scale)),
+                "combine": (jax.jit(lambda p, i, w: rows.combine_rows(
+                    p, i, w, name=named("combine"))), (py, inv, w)),
+                "combine_dw": (jax.jit(
+                    lambda p, i, w, g: rows.combine_rows(
+                        p, i, w, g, name=named("combine_dw"))),
+                    (py, inv, w, g)),
+            })
+            got = {}
+            for kernel, (fn, args) in calls.items():
+                res = jax.block_until_ready(fn(*args))
+                if kernel.startswith("gather"):
+                    res = res[:written]
+                elif kernel == "pack_live":
+                    res = res[:-(-n_live // 256) * 256]
+                got[kernel] = np.asarray(res.astype(jnp.float32)
+                                         if res.dtype == jnp.bfloat16 else res)
+                print(f"  [row kernels] {tag} {kernel} ran", flush=True)
+            first = first or got
+            for kernel in got:
+                if not np.array_equal(got[kernel], first[kernel],
+                                      equal_nan=True):
+                    raise AssertionError(
+                        f"{tag} {kernel}: not the first build's output, "
+                        f"{np.count_nonzero(got[kernel] != first[kernel])} "
+                        f"entries differ")
+            want = np.asarray(x, np.float32)[np.asarray(tokens)[:n_live]]
+            if not np.array_equal(got["gather"][:n_live], want):
+                raise AssertionError(f"{tag} gather: not jnp.take's rows")
+            line = lines[tag] = {"shape": shape, "build": build,
+                                 "live_rows": n_live}
+            if hasattr(rows, "gather_starts"):
+                block, loop = rows.gather_starts(n_live, R)
+                line["gather_block_share"] = round(
+                    block / max(block + loop, 1), 4)
+            runs.append((tag, calls))
+    os.makedirs(out, exist_ok=True)
+    with jax.profiler.trace(out):
+        for _ in range(reps):           # builds interleaved within a rep
+            for tag, calls in runs:
+                for fn, args in calls.values():
+                    jax.block_until_ready(fn(*args))
+    path = max(glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    dev_ops, _, _ = read_xplane(path)
+    times = {}
+    for name, _, dur in next(iter(dev_ops.values())):
+        times.setdefault(name, []).append(dur)
+    for tag, calls in runs:
+        for kernel in calls:
+            durs = times.get(f"{tag}_{kernel}", ())
+            if len(durs) != reps:       # say what the trace did hold
+                lines[tag][f"{kernel}_events"] = len(durs)
+            if durs:
+                lines[tag][f"{kernel}_ms"] = round(
+                    float(np.median(durs)) / 1e6, 4)
+        print("  [row kernels] " + json.dumps(lines[tag]), flush=True)
+    return lines
+
+
+def _load_rows_module(name, root):
+    """``ops/pallas/moe_rows.py`` of the checkout at ``root`` under a name
+    of its own (fresh jits; its relative imports resolve in this tree)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        f"deepspeed_tpu.ops.pallas._rows_{name}", os.path.join(
+            root, "deepspeed_tpu", "ops", "pallas", "moe_rows.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def kernel_share_dispatch(parent: str = ""):
     """A share of an expert layer through the sorted dispatch: the pairs
     held elsewhere lie behind the last group, in rows the Pallas grouped
     matmul neither reads nor writes, forward or backward.  Whatever the
@@ -514,12 +658,24 @@ def kernel_share_dispatch():
     show it).  At a small shape (experts 4-7 of 16, top-4, rows of 512)
     and at the third cell's own (``train-mellum2-8k-1chip``: 32768 tokens
     x top-8, rows of 2304, experts 16-31 of 64), where the rows move
-    through the Pallas row kernels (``ops/pallas/moe_rows.py``)."""
+    through the Pallas row kernels (``ops/pallas/moe_rows.py``).  With
+    ``parent``, the root of a parent checkout, then each row kernel alone,
+    timed a call at Mellum 2's and the seventh cell's shapes under that
+    checkout's module and this tree's (:func:`row_kernel_times`), every
+    output held to the parent's bit for bit:
+    ``python3 -c "import chip_smoke; chip_smoke.kernel_share_dispatch('.chip_archive/parent')"``."""
     _dispatch_case(2048, 512, 256, 16, 4, 4, 4, "(512, 512, 256)",
                    ("pallas", "rows 8192 x 512"))
     _dispatch_case(32768, 2304, 896, 64, 16, 16, 8, "(512, 768, 896)",
                    ("pallas", "rows 262144 x 2304"))
     _skipped_rows(32768, 2304, 8, 0.25)
+    if parent:
+        from deepspeed_tpu.ops.pallas import moe_rows
+
+        root = os.path.dirname(os.path.abspath(__file__))
+        row_kernel_times({
+            "parent": _load_rows_module("parent", os.path.join(root, parent)),
+            "change": moe_rows})
 
 
 def kernel_full_dispatch():

@@ -35,8 +35,20 @@ Indices arrive in SMEM a block of :data:`STEP` a grid step (XLA lays
 ``s32[n]`` out in tiles of 1024 and the whole of 262,144 indices is the
 chip's entire 1 MB of SMEM, so they cannot be scalar-prefetched whole);
 only counts are prefetched.  Rows are in flight :data:`SUB` at a time on
-one DMA semaphore a stage, two stages: the next sub-block's DMAs are
-issued before this one's are awaited and consumed.
+one DMA semaphore a stage, two stages (:func:`_stream`): a sub-block's
+rows are awaited a group of :data:`WAIT` at a time (all of a full one in
+one wait: the semaphore counts bytes, not DMAs), then the next
+sub-block's DMAs are started while this one is consumed.  Going out, a
+trip of the vector work that assembles 16 rows also starts the 16 that
+take their place in the other stage, one or two after each piece's loads,
+where the scheduler puts their scalar work beside the unpacking (v5e, a
+call of 65,655 rows of 2304: 2.40 ms with loops of starts and a wait a
+row, 1.79 with the waits grouped, 1.57 with the starts dealt through the
+trips, 24 ns a row).  Coming back the starts stay in a loop of their own:
+a DMA start orders the loads and stores around it, and the combine's
+trips are all loads and stores (:func:`_combine_kernel`).  Index a stage
+as ``stage[slot, ...]``: through a view ``stage.at[slot]`` the same loads
+ran the kernels at half the speed.
 
 An index of ``src.shape[0]`` means "no row" (a share's ``absent``).  No DMA is issued
 for it and it reads zeros (the stage is cleared before a sub-block's DMAs
@@ -60,7 +72,7 @@ call slower at Mellum 2's shape (v5e, PERF.md section 6, PR 32).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -76,6 +88,8 @@ STEP = 1024
 SUB = 256
 # rows a block of pack_rows
 PACK = 256
+# rows one semaphore wait awaits
+WAIT = 16
 _HIGH = 0xFFFF0000
 
 
@@ -171,47 +185,117 @@ def pack_rows(x: jax.Array, live: jax.Array, *, name: str,
     )(live, x)
 
 
-def _stream(src_ref, stage, sem, count, fetch, consume):
+def _deal(starts, places: int):
+    """``starts`` dealt over ``places`` points of a trip's vector work, in
+    order and as evenly as they go."""
+    return [starts[len(starts) * p // places:len(starts) * (p + 1) // places]
+            for p in range(places)]
+
+
+def _stream(src_ref, stage, sem, count, fetch, trips, work, chunk=0):
     """Bring this grid step's :data:`STEP` rows into ``stage`` a sub-block
-    of :data:`SUB` at a time and hand each landed sub-block to
-    ``consume(q, slot)``.  Sub-block ``q`` has ``count(q)`` rows to bring;
-    its ``t``-th is ``src[row]`` and lands in stage row ``at``, with
-    ``(row, at) = fetch(q, t)``.  The loop holds no branch: a branch a row
-    costs as much as the DMA it would spare."""
+    of :data:`SUB` at a time and run the vector work of each landed
+    sub-block: ``work(q, slot, i, starts)`` for ``i`` in ``range(trips)``.
+    Sub-block ``q`` has ``count(q)`` rows to bring; its ``t``-th is
+    ``src[row]`` and lands in stage row ``at``, with ``(row, at) =
+    fetch(q, t)``.
 
-    def issue(q, slot):
-        # a row without a DMA reads zeros
-        stage[slot] = jnp.zeros(stage.shape[1:], stage.dtype)
+    The rows of a slot are awaited a group at a time: every row's DMA
+    signals the slot's one semaphore and is as long as the first, so one
+    wait on a descriptor of ``g`` rows awaits any ``g`` of them.
 
-        def one(t):
-            row, at = fetch(q, t)
-            pltpu.make_async_copy(src_ref.at[row], stage.at[slot, at],
-                                  sem.at[slot]).start()
+    ``chunk`` is the gather's alone (the combine passes one trip and no
+    chunk: :func:`_combine_kernel` says what the chip read).  With it, the
+    next sub-block's starts sit inside this one's trips, where the
+    scheduler (it fills a bundle from one basic block) can put their
+    scalar work beside vector work: as many trips as ``count(q + 1)``
+    covers whole chunks run a body that is handed ``chunk`` ``starts`` to
+    call between its pieces, the others the bare body, and what no chunk
+    covers - and all of a grid step's first sub-block, which has no trip
+    before it - is started in a loop of its own.  No loop holds a branch:
+    a branch a row costs as much as the DMA it would spare."""
+    subs = STEP // SUB
 
+    def start(q, slot, t):
+        row, at = fetch(q, t)
+        pltpu.make_async_copy(src_ref.at[row], stage.at[slot, at],
+                              sem.at[slot]).start()
+
+    def start_loop(q, slot, lo, hi):
         def eight(i, carry):    # the loop's own scalar work rivals a DMA's
             for u in range(8):
-                one(i * 8 + u)
+                start(q, slot, lo + i * 8 + u)
             return carry
 
-        n = count(q)
-        lax.fori_loop(0, n // 8, eight, 0)
-        lax.fori_loop(n // 8 * 8, n, lambda t, carry: one(t), None)
+        eights = (hi - lo) // 8
+        lax.fori_loop(0, eights, eight, 0)
+        lax.fori_loop(lo + eights * 8, hi,
+                      lambda t, carry: start(q, slot, t), None)
+
+    def wait(n, slot):
+        def rows(g):
+            pltpu.make_async_copy(src_ref.at[pl.ds(0, g)],
+                                  stage.at[slot, pl.ds(0, g)],
+                                  sem.at[slot]).wait()
+
+        def grouped():
+            groups = n // WAIT
+            lax.fori_loop(0, groups, lambda i, carry: rows(WAIT), None)
+            lax.fori_loop(groups * WAIT, n, lambda i, carry: rows(1), None)
+
+        lax.cond(n == SUB, lambda: rows(SUB), grouped)
+
+    def clear(slot):            # a row without a DMA reads zeros
+        stage[slot] = jnp.zeros(stage.shape[1:], stage.dtype)
 
     def sub_block(q, carry):
         slot = q % 2
-        pl.when(q + 1 < STEP // SUB)(lambda: issue(q + 1, 1 - slot))
+        wait(count(q), slot)
+        ahead = jnp.where(q + 1 < subs, count(jnp.minimum(q + 1, subs - 1)),
+                          0)
+        # a full sub-block lands on every row of the stage
+        pl.when((ahead < SUB) & (q + 1 < subs))(lambda: clear(1 - slot))
+        covered = jnp.minimum(ahead // chunk, trips) if chunk else 0
+        start_loop(q + 1, 1 - slot, covered * chunk, ahead)
 
-        def wait(t, carry):     # every row is as long as the first
-            pltpu.make_async_copy(src_ref.at[0], stage.at[slot, 0],
-                                  sem.at[slot]).wait()
+        def both(i, carry):
+            work(q, slot, i, [
+                functools.partial(start, q + 1, 1 - slot, i * chunk + u)
+                for u in range(chunk)])
             return carry
 
-        lax.fori_loop(0, count(q), wait, 0)
-        consume(q, slot)
+        def bare(i, carry):
+            work(q, slot, i, [])
+            return carry
+
+        if chunk:
+            lax.fori_loop(0, covered, both, 0)
+        lax.fori_loop(covered, trips, bare, 0)
         return carry
 
-    issue(0, 0)
-    lax.fori_loop(0, STEP // SUB, sub_block, 0)
+    clear(0)
+    start_loop(0, 0, 0, count(0))
+    lax.fori_loop(0, subs, sub_block, 0)
+
+
+# the gather: a trip assembles 16 rows and starts the 16 that take their place
+GATHER_TRIPS, GATHER_CHUNK = SUB // 16, 16
+
+
+def gather_starts(live: int, rows: int) -> Tuple[int, int]:
+    """``(block, loop)``: the row DMAs of one :func:`gather_rows` call of
+    ``rows`` rows, the first ``live`` of which hold a pair, by where
+    :func:`_stream` starts them: handed to a trip of the sub-block before
+    (every whole chunk of a sub-block that is not its grid step's first),
+    in a loop of their own.  A function of ``live`` alone, so the program
+    counts nothing: ``chip_smoke.py`` and the scope probes book
+    ``moe_rows_dma_starts_total`` from it on the host.  The combine has no
+    such split: every pair that has a row starts in a loop
+    (:func:`_combine_kernel`)."""
+    block = sum(
+        min(max(live - q * SUB, 0), SUB) // GATHER_CHUNK * GATHER_CHUNK
+        for q in range(rows // SUB) if q % (STEP // SUB))
+    return block, live - block
 
 
 def _gather_kernel(live_ref, idx_ref, src_ref, *rest, scaled):
@@ -220,25 +304,25 @@ def _gather_kernel(live_ref, idx_ref, src_ref, *rest, scaled):
     half = stage.shape[-1]
     first = pl.program_id(0) * STEP
 
-    def assemble(q, slot):
-        def group(i, carry):
-            t0 = pl.multiple_of(i * 16, 16)
-            r0 = pl.multiple_of(q * SUB + t0, 16)
-            for c in range(half // 128):
-                parts = [_unpack(stage[slot, pl.ds(t0 + h, 8), 0,
-                                       pl.ds(c * 128, 128)])
-                         for h in (0, 8)]
-                if scaled:
-                    parts = [tuple(p * scale_ref[pl.ds(r0 + h, 8), :]
-                                   for p in part)
-                             for part, h in zip(parts, (0, 8))]
-                for side, col in ((0, c * 128), (1, half + c * 128)):
-                    tile = jnp.concatenate([part[side] for part in parts], 0)
-                    out_ref[pl.ds(r0, 16), pl.ds(col, 128)] = tile.astype(
-                        out_ref.dtype)
-            return carry
-
-        lax.fori_loop(0, SUB // 16, group, 0)
+    def assemble(q, slot, i, starts):
+        t0 = pl.multiple_of(i * 16, 16)
+        r0 = pl.multiple_of(q * SUB + t0, 16)
+        # a start after each piece's loads: beside the unpacking, not in
+        # a burst at the trip's head (v5e: 1.57 against 1.74 ms a call)
+        for c, some in enumerate(_deal(starts, half // 128)):
+            parts = [_unpack(stage[slot, pl.ds(t0 + h, 8), 0,
+                                   pl.ds(c * 128, 128)])
+                     for h in (0, 8)]
+            for go in some:
+                go()
+            if scaled:
+                parts = [tuple(p * scale_ref[pl.ds(r0 + h, 8), :]
+                               for p in part)
+                         for part, h in zip(parts, (0, 8))]
+            for side, col in ((0, c * 128), (1, half + c * 128)):
+                tile = jnp.concatenate([part[side] for part in parts], 0)
+                out_ref[pl.ds(r0, 16), pl.ds(col, 128)] = tile.astype(
+                    out_ref.dtype)
 
     def count(q):       # the rows that hold a pair come first
         return jnp.clip(live_ref[0] - first - q * SUB, 0, SUB)
@@ -246,7 +330,8 @@ def _gather_kernel(live_ref, idx_ref, src_ref, *rest, scaled):
     @pl.when(first < live_ref[0])   # a step past the live rows does nothing
     def _():
         _stream(src_ref, stage, sem, count,
-                lambda q, t: (idx_ref[q * SUB + t], t), assemble)
+                lambda q, t: (idx_ref[q * SUB + t], t), GATHER_TRIPS,
+                assemble, GATHER_CHUNK)
 
 
 def _stage(half):
@@ -349,8 +434,16 @@ def _combine_kernel(count_ref, list_ref, src_ref, w_ref, *rest, k, dw):
         entry = list_ref[q * SUB + t]
         return entry >> 8, entry & (SUB - 1)
 
-    _stream(src_ref, stage, sem, lambda q: count_ref[first + q], fetch,
-            consume)
+    # One trip, the whole of ``consume``, and no starts inside it (chunk 0).
+    # Measured on the v5e at Mellum 2's shape (PERF.md section 6, PR 46): a
+    # DMA start orders the loads and stores around it, so a quarter of a
+    # sub-block's starts dealt through the trips ran 5.48 ms a call against
+    # 4.39 with every start in its loop, at a trip's head 4.29, and 4.00
+    # only with the slots static (the sub-blocks unrolled in pairs: the
+    # bodies four times over, twice the set-up of the bodies twice over,
+    # which is already past its budget).
+    _stream(src_ref, stage, sem, lambda q: count_ref[first + q], fetch, 1,
+            lambda q, slot, i, starts: consume(q, slot))
 
 
 def _fetch_list(idx, n_rows: int, k: int):

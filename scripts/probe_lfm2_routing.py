@@ -39,6 +39,9 @@ def main():
     import numpy as np
 
     from benchmark.layer_metrics import moe_load_imbalance
+    from probe_mellum2_scopes import count_row_dma_starts, row_kernel_counters
+
+    count_row_dma_starts()
 
     def edit(conf):
         if args.rate is not None:
@@ -92,6 +95,7 @@ def main():
         for row in table["scopes"][:40]:
             print(json.dumps(row))
         print(json.dumps(table["top"]))
+    print(json.dumps(row_kernel_counters(engine)))
     print(json.dumps({"peak_bytes": max(int((d.memory_stats() or {}).get(
         "peak_bytes_in_use", 0)) for d in jax.devices())}), flush=True)
 

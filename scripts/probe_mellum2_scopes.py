@@ -38,6 +38,7 @@ def main():
 
     from deepspeed_tpu.telemetry import device_scopes, get_registry
 
+    count_row_dma_starts()
     _, _, engine, _, _, batches = build(args.seed, rehearse=args.rehearse,
                                         cell=args.cell)
     for _ in range(3):
@@ -88,6 +89,76 @@ def main():
         if site == "qk_rows":
             print(json.dumps({"site": site, "impl": impl, "reason": reason,
                               "count": count}))
+    print(json.dumps(row_kernel_counters(engine)))
+
+
+def count_row_dma_starts():
+    """Stand in front of ``parallel/moe.py record_stats`` (the model looks
+    it up at each finished step) and book ``moe_rows_dma_starts_total
+    {kernel="gather", where=block|loop}`` from the step's counts, on the
+    host: a layer's pairs held here are its gather's live rows, and where
+    the kernel starts them is a function of those alone
+    (``ops/pallas/moe_rows.py gather_starts``).  One call a layer a step
+    (a training step makes three on the same routing); the program itself
+    books nothing."""
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas import moe_rows
+    from deepspeed_tpu.parallel import moe
+    from deepspeed_tpu.telemetry import get_registry
+
+    booked = moe.record_stats
+
+    def record_stats(stats):
+        booked(stats)
+        if "elsewhere" not in stats:    # every expert held: XLA's gather
+            return
+        counts = np.asarray(stats["tokens_per_expert"])
+        rows = counts.reshape(-1, counts.shape[-1]).sum(axis=1)
+        live = rows - np.asarray(stats["elsewhere"]).reshape(-1)
+        starts = get_registry().counter(
+            "moe_rows_dma_starts_total", "row DMAs of one gather call a "
+            "layer a step, by where the kernel starts them: inside a "
+            "vector block or in a loop of their own", ("kernel", "where"))
+        for n, r in zip(live, rows):
+            for where, v in zip(("block", "loop"),
+                                moe_rows.gather_starts(int(n), int(r))):
+                starts.labels("gather", where).inc(float(v))
+
+    moe.record_stats = record_stats
+
+
+def row_kernel_counters(engine):
+    """What the registry holds of the MoE row kernels once every step's
+    statistics are booked: ``moe_rows_dma_starts_total`` (after
+    :func:`count_row_dma_starts`, and only where the kernels moved the
+    rows) with the gather's share started inside a vector block, the
+    kernel bodies traced, and the seconds of tracing and lowering so far
+    (every span)."""
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+    from deepspeed_tpu.telemetry import get_registry
+
+    engine.drain_step_stats(wait=True)
+    snap = get_registry().snapshot()
+
+    def samples(name):
+        return (snap.get(name) or {"samples": ()})["samples"]
+
+    out = {}
+    if any(site == "moe_rows" and impl == "pallas" and n
+           for site, impl, _, n in dispatch_report()):
+        starts = {s["labels"]["where"]: s["value"]
+                  for s in samples("moe_rows_dma_starts_total")}
+        out["moe_rows_dma_starts_total"] = starts
+        if sum(starts.values()):
+            out["gather_block_share"] = round(
+                starts["block"] / sum(starts.values()), 4)
+    out["moe_rows_traces_total"] = sum(
+        s["value"] for s in samples("moe_rows_traces_total"))
+    out["trace_lower_s"] = round(sum(
+        s["value"] for s in samples("xla_compile_seconds_total")
+        if s["labels"]["phase"] in ("trace", "lower")), 3)
+    return out
 
 
 if __name__ == "__main__":

@@ -257,3 +257,262 @@ def test_a_model_traces_each_kernel_once_a_signature(monkeypatch):
     assert new and all(n <= 2 for n in new.values()), new
     assert len(new) == 6, new
     assert {kernel for kernel, _ in new} == {"pack", "gather", "combine"}
+
+
+# ----------------------------------------------------------------------
+# PR 46: the starts inside the vector blocks, a group of rows a wait
+# ----------------------------------------------------------------------
+def _parent_stream(src_ref, stage, sem, count, fetch, trips, work, chunk=0):
+    """What PR 32's ``_stream`` did, restated under this tree's signature
+    (not the parent's text: its issue loop was unrolled by eight and it
+    took one ``consume``): every sub-block's starts in a loop of their own
+    before this one's waits, a semaphore wait a row, then the vector work.
+    The kernels must equal it bit for bit; ``jnp.take`` and the dense
+    float32 loop above are the independent judges, and the parent's own
+    module is held to on the chip (``chip_smoke.py row_kernel_times``)."""
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    STEP, SUB = moe_rows.STEP, moe_rows.SUB
+
+    def issue(q, slot):
+        stage[slot] = jnp.zeros(stage.shape[1:], stage.dtype)
+
+        def one(t, carry):
+            row, at = fetch(q, t)
+            pltpu.make_async_copy(src_ref.at[row], stage.at[slot, at],
+                                  sem.at[slot]).start()
+
+        lax.fori_loop(0, count(q), one, None)
+
+    def sub_block(q, carry):
+        slot = q % 2
+        pl.when(q + 1 < STEP // SUB)(lambda: issue(q + 1, 1 - slot))
+
+        def wait(t, carry):
+            pltpu.make_async_copy(src_ref.at[0], stage.at[slot, 0],
+                                  sem.at[slot]).wait()
+            return carry
+
+        lax.fori_loop(0, count(q), wait, 0)
+        lax.fori_loop(0, trips, lambda i, c: work(q, slot, i, []), None)
+        return carry
+
+    issue(0, 0)
+    lax.fori_loop(0, STEP // SUB, sub_block, 0)
+
+
+def _kernels(parent):
+    """``(gather_rows, combine_rows)`` interpreted, jitted anew (no trace
+    of the other build answers from jit's cache), under this tree's
+    ``_stream`` or the parent's."""
+    def fresh(fn):
+        inner = fn.__wrapped__
+
+        def call(*a, **k):
+            kept = moe_rows._stream
+            moe_rows._stream = _parent_stream if parent else kept
+            try:
+                return inner(*a, interpret=True, **k)
+            finally:
+                moe_rows._stream = kept
+
+        return jax.jit(call, static_argnames=("name",))
+
+    return fresh(moe_rows.gather_rows), fresh(moe_rows.combine_rows)
+
+
+# rows 2048: two grid steps of four sub-blocks
+ROWS = 2 * moe_rows.STEP
+# the gather's live rows: none, the first sub-block alone and not whole, a
+# whole sub-block, one more chunk exactly, that less one row, two whole
+# sub-blocks (a multiple of SUB), into the second grid step, every row
+LIVE = [0, 100, 256, 272, 271, 512, 1324, 2048]
+SHAPES = [(2048, 4), (2304, 8)]     # the seventh cell's and Mellum 2's
+
+
+def _pairs_of(k):
+    """Pairs with a row in each of the combine's eight sub-blocks: none,
+    one group of rows a wait exactly, a group less one, every pair, a
+    quarter (a share's), one pair more, a few, some."""
+    return [0, moe_rows.WAIT, moe_rows.WAIT - 1, moe_rows.SUB,
+            moe_rows.SUB // 4, moe_rows.SUB // 4 + 1, 7, 100]
+
+
+@functools.lru_cache(maxsize=None)
+def _both_gathers(M):
+    """``{(live, scaled): (new, parent)}``: the rows each build writes."""
+    S = 512
+    rng = np.random.default_rng(M)
+    x = jax.random.normal(jax.random.PRNGKey(M), (S, M),
+                          jnp.float32).astype(jnp.bfloat16)
+    packed = moe_rows.pack_rows(x, jnp.array([S], jnp.int32),
+                                name="moe_rows_out", interpret=True)
+    scale = jnp.asarray(rng.random((ROWS, 1)), jnp.float32)
+    gathers = _kernels(False)[0], _kernels(True)[0]
+    out = {}
+    for live in LIVE:
+        idx = np.full(ROWS, S, np.int32)
+        idx[:live] = rng.integers(0, S, live)
+        written = -(-live // moe_rows.STEP) * moe_rows.STEP
+        for scaled in (False, True):
+            got = [np.asarray(gather(
+                packed, jnp.asarray(idx), jnp.array([live], jnp.int32),
+                scale if scaled else None, name="moe_rows_out"),
+                np.float32)[:written]
+                for gather in gathers]
+            want = np.asarray(x, np.float32)[idx[:live]]
+            if scaled:
+                want = (want * np.asarray(scale)[:live]).astype(
+                    jnp.bfloat16).astype(np.float32)
+            out[live, scaled] = (*got, want)
+    return out
+
+
+@pytest.mark.parametrize("scaled", [False, True])
+@pytest.mark.parametrize("live", LIVE)
+@pytest.mark.parametrize("M", [M for M, _ in SHAPES])
+def test_gather_equals_the_parents_bit_for_bit(M, live, scaled):
+    new, parent, want = _both_gathers(M)[live, scaled]
+    assert new.shape == parent.shape and new.shape[0] >= live
+    np.testing.assert_array_equal(new, parent)
+    np.testing.assert_array_equal(new[:live], want)
+    assert not new[live:].any()
+
+
+@functools.lru_cache(maxsize=None)
+def _both_combines(M, k):
+    """``(counts, {dw: (new, parent)})`` of one call whose sub-blocks hold
+    :func:`_pairs_of` pairs with a row; the rows past them hold NaN."""
+    S = ROWS // k
+    rng = np.random.default_rng(M + k)
+    counts = _pairs_of(k)
+    n_live = sum(counts)
+    rows = rng.permutation(n_live).astype(np.int32)
+    idx = np.full(ROWS, ROWS, np.int32)
+    at = 0
+    for q, n in enumerate(counts):
+        where = q * moe_rows.SUB + rng.permutation(moe_rows.SUB)[:n]
+        idx[where] = rows[at:at + n]
+        at += n
+    ks = jax.random.split(jax.random.PRNGKey(M + k), 3)
+    y = jax.random.normal(ks[0], (ROWS, M), jnp.float32)
+    y = jnp.where((jnp.arange(ROWS) >= n_live)[:, None], jnp.nan, y).astype(
+        jnp.bfloat16)
+    w = jax.random.uniform(ks[1], (S, k), jnp.float32)
+    g = jax.random.normal(ks[2], (S, M), jnp.float32).astype(jnp.bfloat16)
+    packed = moe_rows.pack_rows(y, jnp.array([n_live], jnp.int32),
+                                name="moe_rows_back", interpret=True)
+    combines = _kernels(False)[1], _kernels(True)[1]
+    out = {dw: tuple(
+        np.asarray(combine(packed, jnp.asarray(idx), w, g if dw else None,
+                           name="moe_rows_back"), np.float32)
+        for combine in combines)
+        for dw in (False, True)}
+    return counts, out
+
+
+@pytest.mark.parametrize("dw", [False, True])
+@pytest.mark.parametrize("sub_block", range(8))
+@pytest.mark.parametrize("M,k", SHAPES)
+def test_combine_equals_the_parents_bit_for_bit(M, k, sub_block, dw):
+    """Forward (and, over ones, the dispatch's d-tokens) and d-weights, the
+    tokens of one sub-block at a time: each holds another count of pairs
+    with a row, on either side of what decides how its rows are awaited."""
+    counts, out = _both_combines(M, k)
+    new, parent = out[dw]
+    tokens = moe_rows.SUB // k
+    mine = slice(sub_block * tokens, (sub_block + 1) * tokens)
+    assert np.isfinite(new[mine]).all()
+    np.testing.assert_array_equal(new[mine], parent[mine])
+    assert bool(new[mine].any()) == bool(counts[sub_block])
+
+
+@pytest.mark.parametrize("live", LIVE)
+def test_starts_are_counted_where_the_kernel_starts_them(live):
+    """``moe_rows_dma_starts_total``'s numbers: the gather starts a grid
+    step's first sub-block in a loop and hides whole chunks of the others
+    inside the trips before them."""
+    per = [min(max(live - q * 256, 0), 256) for q in range(ROWS // 256)]
+    block = sum(n // 16 * 16 for q, n in enumerate(per) if q % 4)
+    assert moe_rows.gather_starts(live, ROWS) == (block, live - block)
+
+
+def test_the_probes_book_the_starts_from_a_steps_counts(monkeypatch):
+    """``scripts/probe_mellum2_scopes.py count_row_dma_starts`` stands in
+    front of ``record_stats``: a layer's held pairs are its gather's live
+    rows; a layer that holds every expert books nothing."""
+    import os
+
+    from deepspeed_tpu.parallel import moe
+
+    monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+        "scripts"))
+    import probe_mellum2_scopes
+
+    def read():
+        family = get_registry().snapshot().get("moe_rows_dma_starts_total")
+        return {(s["labels"]["kernel"], s["labels"]["where"]): s["value"]
+                for s in (family["samples"] if family else ())}
+
+    monkeypatch.setattr(moe, "record_stats", moe.record_stats)
+    probe_mellum2_scopes.count_row_dma_starts()
+    before = read()
+    stats = {"tokens_per_expert": np.full((2, 4), ROWS // 4),
+             "dropped": np.zeros(2), "balance_loss": np.zeros(2),
+             "router_z": np.zeros(2)}
+    moe.record_stats(stats)
+    assert read() == before
+    moe.record_stats(dict(stats, elsewhere=np.array([ROWS - 272,
+                                                     ROWS - 1324])))
+    new = {key: n - before.get(key, 0) for key, n in read().items()}
+    want = [moe_rows.gather_starts(live, ROWS) for live in (272, 1324)]
+    assert new == {("gather", "block"): want[0][0] + want[1][0],
+                   ("gather", "loop"): want[0][1] + want[1][1]}
+
+
+# sha256 of what one expert layer with EVERY expert held (OLMoE's kind: a
+# full permutation, which the guard leaves to XLA) traces to on a TPU,
+# forward + backward with its statistics, taken at the parent commit
+# (db2bc0b): nothing of this PR reaches a program without the kernels
+PARENT_FULL_LAYER = \
+    "d81e26556fe03270e6e4704e46f108e7bff7d0878cf2450795057de26343b44f"
+
+
+@pytest.mark.parametrize("share", [False, True])
+def test_a_layers_program_counts_no_starts(monkeypatch, share):
+    """The counter is the probes' (on the host): a share's layer returns
+    the parent's statistics and no more, and a layer that holds every
+    expert traces to the parent's program."""
+    import hashlib
+    import re
+
+    from deepspeed_tpu.ops.pallas import spmd
+    from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(spmd, "kernel_mesh_plan",
+                        lambda *a, **kw: ("direct", None))
+    held = dict(routed_experts=32, first_expert=8) if share else {}
+    cfg = MoEConfig(num_experts=8, top_k=8 if share else 4,
+                    drop_tokens=False, norm_topk_prob=True,
+                    expert_act="swiglu", **held)
+    layer = MoELayer(cfg, model_dim=2048, hidden_dim=128, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2048, 2048), jnp.bfloat16)
+    p = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"]
+
+    def loss(p, x):
+        out, aux, stats = layer.apply({"params": p}, x, train=True,
+                                      return_stats=True)
+        return out.astype(jnp.float32).sum() + aux, stats
+
+    if share:
+        stats = jax.eval_shape(loss, p, x)[1]
+        assert set(stats) == {"tokens_per_expert", "dropped", "balance_loss",
+                              "router_z", "elsewhere"}
+        return
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(p, x)
+    text = re.sub(r" at /\S+:\d+", "", str(jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_FULL_LAYER
