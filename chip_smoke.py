@@ -70,6 +70,38 @@ def _check_close(name, got, ref):
 # phase 1: kernels
 # ---------------------------------------------------------------------------
 
+PARENT_CHECKOUT = ".chip_archive/parent"    # where a builder unpacks one
+
+
+def _diff_from_the_parents(name, names, got, run):
+    """Where a checkout of the parent commit is unpacked beside this file
+    (``git archive <parent> | tar -x -C .chip_archive/parent``): print the
+    largest difference of each of ``got`` (named ``names``) from what
+    ``run(fa)`` gives with ``fa`` that checkout's flash module."""
+    import jax.numpy as jnp
+
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        PARENT_CHECKOUT)
+    if not os.path.isdir(root):
+        print(f"  {name}: no parent checkout at {PARENT_CHECKOUT}, nothing "
+              f"to compare with", flush=True)
+        return
+    old = run(_load_pallas_module("parent", root, "flash_attention.py"))
+    diffs = {n: float(jnp.abs(a.astype(jnp.float32)
+                              - b.astype(jnp.float32)).max())
+             for n, a, b in zip(names, got, old)}
+    print(f"  {name}: largest difference from the parent's kernels "
+          + json.dumps(diffs), flush=True)
+
+
+def _out_and_grads(fn, loss, args):
+    """``fn(*args)`` and the gradients of ``loss(fn, *args)``, each jitted."""
+    import jax
+
+    return (jax.jit(fn)(*args), *jax.jit(jax.grad(
+        lambda *a: loss(fn, *a), argnums=range(len(args))))(*args))
+
+
 def kernel_flash():
     """Flash forward + backward at the 1.5B trainer's per-micro shape."""
     import jax
@@ -103,6 +135,114 @@ def kernel_flash():
     _check_close("flash fwd (2,1024,25,64) 512x512", out, out_r)
     for n, g, gr in zip(("dq", "dk", "dv"), grads, grads_r):
         _check_close(f"flash bwd {n}", g, gr)
+
+    _diff_from_the_parents(
+        "flash (2,1024,25,64)", ("fwd", "dq", "dk", "dv"), (out, *grads),
+        lambda fa: _out_and_grads(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512), loss, (q, k, v)))
+
+
+def kernel_mxu_operand_rounding(reps: int = 5):
+    """What the MXU does with a float32 operand under Mosaic's default
+    contract precision (PR 47): the products a flash tile makes, each with
+    float32 operands (bf16 values widened, float32 probabilities) and with
+    the operands in bf16, compared bit for bit and timed a call from one
+    profiler trace.  ``qk`` is ``a · bᵀ`` of two bf16 ``(512, 128)`` tiles,
+    ``pv`` is ``p · b`` with a float32 ``p (512, 512)``, ``pTa`` is ``pᵀ · a``
+    (the transposed contraction of the backward's ``pᵀ · dO``, ``dSᵀ · q``).
+    A product is made 16 times a program over the tiles of a resident
+    ``(16 · 512, 128)`` operand and summed in float32, as a sweep does; ``p``
+    is another a tile (``p + t``, a float32 sum), so its cast is made a
+    tile, as a kernel's is.
+    Where the two forms differ, the float32 form's and the bf16 form's
+    distance from the float64 product of the UNROUNDED operands say whether
+    the chip multiplies float32 at more than bf16 precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    T, G, N, D = 16, 32, 512, 128
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(47), 3)
+    a = jax.random.normal(ks[0], (G * N, D), f32).astype(bf16)
+    b = jax.random.normal(ks[1], (T * N, D), f32).astype(bf16)
+    p = jax.random.uniform(ks[2], (G * N, N), f32)
+
+    def dot(x, y, contract):
+        return jax.lax.dot_general(x, y, (contract, ((), ())),
+                                   preferred_element_type=f32)
+
+    def body(form, narrow):
+        def cast(x):        # the operand's type: float32 (today) or bf16
+            return x.astype(bf16 if narrow else f32)
+
+        def kernel(a_ref, b_ref, p_ref, o_ref):
+            acc = jnp.zeros(o_ref.shape, f32)
+            for t in range(T):
+                tile = b_ref[pl.ds(t * N, N)]
+                if form == "qk":
+                    acc += dot(cast(a_ref[...]), cast(tile), ((1,), (1,)))
+                    continue
+                p_t = cast(p_ref[...] + f32(t))
+                acc += dot(p_t, cast(tile),
+                           ((1,), (0,)) if form == "pv" else ((0,), (0,)))
+            o_ref[...] = acc
+        return kernel
+
+    calls = {}
+    for form, width in (("qk", N), ("pv", D), ("pTa", D)):
+        for narrow in (False, True):
+            name = f"mxu_{form}_{'bf16' if narrow else 'f32'}"
+            calls[name] = jax.jit(pl.pallas_call(
+                body(form, narrow), grid=(G,),
+                in_specs=[pl.BlockSpec((N, D), lambda g: (g, 0)),
+                          pl.BlockSpec((T * N, D), lambda g: (0, 0)),
+                          pl.BlockSpec((N, N), lambda g: (g, 0))],
+                out_specs=pl.BlockSpec((N, width), lambda g: (g, 0)),
+                out_shape=jax.ShapeDtypeStruct((G * N, width), f32),
+                name=name))
+    got = {n: np.asarray(jax.block_until_ready(fn(a, b, p)))
+           for n, fn in calls.items()}
+
+    # float64 products of the first program's operands as they are
+    a64, b64 = (np.asarray(x, np.float64) for x in (a[:N], b))
+    tiles = b64.reshape(T, N, D)
+    p_ts = [np.asarray(np.asarray(p[:N]) + np.float32(t), np.float64)
+            for t in range(T)]
+    exact = {"qk": sum(a64 @ t.T for t in tiles),
+             "pv": sum(x @ t for x, t in zip(p_ts, tiles)),
+             "pTa": sum(x.T @ t for x, t in zip(p_ts, tiles))}
+    verdict = {}
+    for form in ("qk", "pv", "pTa"):
+        wide, narrow = got[f"mxu_{form}_f32"], got[f"mxu_{form}_bf16"]
+        line = verdict[form] = {
+            "bitwise_equal": bool(np.array_equal(wide, narrow)),
+            "max_abs_diff": float(np.abs(wide - narrow).max()),
+            "entries_differing": int(np.count_nonzero(wide != narrow)),
+            "max_abs": float(np.abs(wide).max())}
+        for tag, res in (("f32", wide), ("bf16", narrow)):
+            line[f"{tag}_err_to_float64_of_unrounded"] = float(
+                np.abs(res[:N] - exact[form]).max())
+
+    out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                       "chiprun_out", "mxu_operand_rounding")
+    os.makedirs(out, exist_ok=True)
+    with jax.profiler.trace(out):
+        for _ in range(reps):
+            for fn in calls.values():
+                jax.block_until_ready(fn(a, b, p))
+    times = _traced_op_times(out)
+    for name in calls:
+        durs = [d for n, ds in times.items() if name in n for d in ds]
+        form = name.split("_")[1]
+        # microseconds a (512 x 512 x 128) product
+        verdict[form][f"{name.split('_')[2]}_us_a_product"] = (
+            float(np.median(durs)) / 1e3 / (G * T) if durs else None)
+    for form, line in verdict.items():
+        print(f"  [mxu operand rounding] {form}: " + json.dumps(line),
+              flush=True)
+    return verdict
 
 
 def _decode_ref(q, k_cache, v_cache, lengths):
@@ -511,6 +651,22 @@ ROW_KERNEL_SHAPES = {         # tokens, top-k, row width, routed experts
 }
 
 
+def _traced_op_times(out):
+    """``{op name: [ns, ...]}`` of the first device in the newest profiler
+    trace under ``out``."""
+    import glob
+
+    from benchmark.trace_reduce import read_xplane
+
+    path = max(glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                      "*.xplane.pb")), key=os.path.getmtime)
+    dev_ops, _, _ = read_xplane(path)
+    times = {}
+    for name, _, dur in next(iter(dev_ops.values())):
+        times.setdefault(name, []).append(dur)
+    return times
+
+
 def row_kernel_times(builds, reps=5, shapes=ROW_KERNEL_SHAPES):
     """Device time a call of each row kernel (``ops/pallas/moe_rows.py``:
     ``pack``, ``gather`` plain and scaled, ``combine`` and its d-weights)
@@ -522,13 +678,9 @@ def row_kernel_times(builds, reps=5, shapes=ROW_KERNEL_SHAPES):
     writes.  Prints a line a (shape, build) with the ms a call and the
     share of the gather's row DMAs started inside a vector block, where
     the build counts it (``gather_starts``)."""
-    import glob
-
     import jax
     import jax.numpy as jnp
     import numpy as np
-
-    from benchmark.trace_reduce import read_xplane
 
     out = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                        "chiprun_out", "row_kernel_times")
@@ -617,12 +769,7 @@ def row_kernel_times(builds, reps=5, shapes=ROW_KERNEL_SHAPES):
             for tag, calls in runs:
                 for fn, args in calls.values():
                     jax.block_until_ready(fn(*args))
-    path = max(glob.glob(os.path.join(out, "plugins", "profile", "*",
-                                      "*.xplane.pb")), key=os.path.getmtime)
-    dev_ops, _, _ = read_xplane(path)
-    times = {}
-    for name, _, dur in next(iter(dev_ops.values())):
-        times.setdefault(name, []).append(dur)
+    times = _traced_op_times(out)
     for tag, calls in runs:
         for kernel in calls:
             durs = times.get(f"{tag}_{kernel}", ())
@@ -635,14 +782,14 @@ def row_kernel_times(builds, reps=5, shapes=ROW_KERNEL_SHAPES):
     return lines
 
 
-def _load_rows_module(name, root):
-    """``ops/pallas/moe_rows.py`` of the checkout at ``root`` under a name
-    of its own (fresh jits; its relative imports resolve in this tree)."""
+def _load_pallas_module(name, root, file):
+    """``ops/pallas/<file>`` of the checkout at ``root`` under a name of its
+    own (fresh jits; its relative imports resolve in this tree)."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
-        f"deepspeed_tpu.ops.pallas._rows_{name}", os.path.join(
-            root, "deepspeed_tpu", "ops", "pallas", "moe_rows.py"))
+        f"deepspeed_tpu.ops.pallas._{file[:-3]}_{name}", os.path.join(
+            root, "deepspeed_tpu", "ops", "pallas", file))
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
@@ -674,7 +821,8 @@ def kernel_share_dispatch(parent: str = ""):
 
         root = os.path.dirname(os.path.abspath(__file__))
         row_kernel_times({
-            "parent": _load_rows_module("parent", os.path.join(root, parent)),
+            "parent": _load_pallas_module(
+                "parent", os.path.join(root, parent), "moe_rows.py"),
             "change": moe_rows})
 
 
@@ -763,6 +911,11 @@ def kernel_flash_window_gqa():
                   f"{worst.max():.2e}", flush=True)
             assert worst.max() <= TOL, (name, n, worst)
 
+        _diff_from_the_parents(
+            name, ("fwd", "dq", "dk", "dv"), (out, *grads),
+            lambda fa: _out_and_grads(lambda q, k, v: fa.flash_attention(
+                q, k, v, window=window), loss, (q, k, v)))
+
 
 def kernel_flash_two_products():
     """The fifth cell's attention at the cell's own shape
@@ -838,6 +991,12 @@ def kernel_flash_two_products():
               f"{worst.max():.2e}", flush=True)
         assert worst.max() <= TOL, (name, n, worst)
 
+    _diff_from_the_parents(
+        name, ("fwd", "dq_nope", "dq_rope", "dk_nope", "dk_rope", "dv"),
+        (out, *grads), lambda fa: _out_and_grads(
+            lambda qn, qr, kn, kr, v: fa.flash_attention(
+                qn, kn, v, q_rope=qr, k_rope=kr), loss, ops))
+
 
 def kernel_flash_blockdiff():
     """The sixth cell's attention at the cell's own shape
@@ -912,6 +1071,10 @@ def kernel_flash_blockdiff():
         print(f"  {name} {n}: worst of {worst.size} (row, half, key-value "
               f"head) slices {worst.max():.2e} (tol {TOL:.0e})", flush=True)
         assert np.isfinite(g).all() and worst.max() <= TOL, (name, n, worst)
+    _diff_from_the_parents(
+        name, ("fwd", "dq", "dk", "dv"), got,
+        lambda fa: both(lambda q, k, v: fa.flash_attention_halves(
+            q, k, v, block=G))(q, k, v, ct))
 
 
 def kernel_qk_rows():

@@ -579,8 +579,10 @@ def _for_program(own, sched: TileSchedule, program, *, own_is_q: bool,
                 functools.partial(program, static_sweep(o), False))
 
 
-def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None):
-    """Void the entries of score tile ``s`` outside the band, its first
+def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None,
+               keys_first: bool = False):
+    """Void the entries of score tile ``s`` (queries by keys; keys by
+    queries with ``keys_first``) outside the band, its first
     query position lying ``d`` after its first key position: those with
     ``q_pos < k_pos`` in a DIAGONAL tile, those with ``q_pos - k_pos >=
     window`` in a BAND_EDGE one, both in a CROSSED one; in a
@@ -598,8 +600,8 @@ def _band_mask(s, d: int, kind: str, sched: TileSchedule, live=None):
 
     if kind in (None, FULL):
         return keep(None)
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(keys_first))
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, int(not keys_first))
     window = sched.window
     if kind in (BLOCK_DIAGONAL, OWN_BLOCK):
         g = sched.diag[0]
@@ -622,6 +624,11 @@ def _col(x):
     return jax.lax.expand_dims(x, (1,))
 
 
+def _row(x):
+    """(lanes,) → (1, lanes), as cheaply as :func:`_col`."""
+    return jax.lax.expand_dims(x, (0,))
+
+
 def _rows(x, start: int, size: int):
     """``x[start:start + size]`` (static), traced as cheaply."""
     if start == 0 and size == x.shape[0]:
@@ -629,7 +636,40 @@ def _rows(x, start: int, size: int):
     return jax.lax.slice_in_dim(x, start, start + size, axis=0)
 
 
-def _dot(a, b, contract):
+def _sum_lanes(width: int) -> int:
+    """Lanes of the forward's running row sums for score tiles ``width``
+    keys wide (:func:`_lane_blocks_sum`)."""
+    return 128 if width % 128 == 0 else width
+
+
+def _lane_blocks_sum(p):
+    """The 128-lane column blocks of ``p`` added up: ``(rows, 128)`` whose
+    sum over lanes is ``p``'s.  A sum over lanes a tile was 4 to 10% of a
+    forward call on the v5e (PR 47: the unit that moves data across lanes
+    is what the kernels wait for); these adds run on a vector unit with
+    slots to spare."""
+    w = _sum_lanes(p.shape[1])
+    out = p[:, :w]
+    for c in range(w, p.shape[1], w):
+        out = out + p[:, c:c + w]
+    return out
+
+
+def _dot(a, b, contract, pass_: str):
+    """``a · b`` over ``contract``, summed in float32, in the kernel of
+    ``pass_`` (``"fwd"`` or ``"bwd"``); counted at trace time by the type of
+    its operands.  They are float32 whatever the call's type, and that costs
+    nothing: under Mosaic's default contract precision the v5e rounds a
+    float32 operand to bf16 on its way into the MXU, bit for bit what
+    ``astype`` gives and at a bf16 operand's rate (PR 47, ``chip_smoke.py
+    kernel_mxu_operand_rounding``); with bf16 operands a forward call read
+    0 to 2% slower and a backward call 3 to 8% (PERF.md section 6)."""
+    _registry.counter(
+        "flash_mxu_operands_total",
+        "products of the flash kernel body being traced by the type of "
+        "their operands (the sum is float32 whatever it is); counted at "
+        "trace time, not per call",
+        labelnames=("pass", "dtype")).labels(pass_, a.dtype.name).inc()
     return jax.lax.dot_general(a, b, (contract, ((), ())),
                                preferred_element_type=jnp.float32)
 
@@ -796,11 +836,14 @@ def _fwd_kernel(*refs, scale, sched, lanes, terms):
     term, k of every term, v, o and lse.  The heads of the block are
     independent online-softmax chains in one basic block, so Mosaic overlaps
     one head's matmuls with another's vector work; each keeps a block-wide
-    accumulator, and its own lanes are picked once, at the store."""
+    accumulator, and its own lanes are picked once, at the store.  The
+    softmax denominator is kept a partial sum a lane (:func:`_lane_blocks_sum`)
+    and summed over lanes once a program."""
     n = len(terms)
     (q_ref, *more_q), (k_ref, *more_k), (v_ref, o_ref, lse_ref) = _split(
         refs, n, n)
     bq, L = q_ref.shape[1:]
+    dot = functools.partial(_dot, pass_="fwd")
     i = pl.program_id(2)    # read here: not inside a branch
     c = pl.program_id(1) if any(t.heads > 1 for t in terms) else None
 
@@ -822,9 +865,9 @@ def _fwd_kernel(*refs, scale, sched, lanes, terms):
                 v = v_ref[0, ks].astype(jnp.float32)
                 out = []
                 for q_h, (m, l, acc) in zip(qs, carry):
-                    s = _dot(q_h, k, ((1,), (1,)))               # (bq, bk)
+                    s = dot(q_h, k, ((1,), (1,)))               # (bq, bk)
                     for q_t, k_t in more:
-                        s = s + _dot(q_t, k_t[0, ks].astype(jnp.float32),
+                        s = s + dot(q_t, k_t[0, ks].astype(jnp.float32),
                                      ((1,), (1,)))
                     if d is not None or live is not None:
                         s = _band_mask(s, d, kind, sched, live)
@@ -833,9 +876,10 @@ def _fwd_kernel(*refs, scale, sched, lanes, terms):
                     # well-defined
                     m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
                     p = jnp.exp(s - _col(m_safe))
-                    corr = jnp.where(m == NEG_INF, 0.0, jnp.exp(m - m_safe))
-                    out.append((m_new, l * corr + p.sum(axis=-1),
-                                acc * _col(corr) + _dot(p, v, ((1,), (0,)))))
+                    corr = _col(jnp.where(m == NEG_INF, 0.0,
+                                          jnp.exp(m - m_safe)))
+                    out.append((m_new, l * corr + _lane_blocks_sum(p),
+                                acc * corr + dot(p, v, ((1,), (0,)))))
                 return tuple(out)
 
             def diagonal_tile(k0, d0, subs, carry, live=None):
@@ -843,11 +887,13 @@ def _fwd_kernel(*refs, scale, sched, lanes, terms):
                 return fold(k0, carry, d0, subs[0][2], live)
 
             carry = sweep(((jnp.full((bq,), NEG_INF, jnp.float32),
-                            jnp.zeros((bq,), jnp.float32),
+                            jnp.zeros((bq, _sum_lanes(sched.block_k)),
+                                      jnp.float32),
                             jnp.zeros((bq, L), jnp.float32)),) * heads,
                           fold, diagonal_tile)
             outs = []
             for h, (m, l, acc) in enumerate(carry):
+                l = l.sum(axis=-1)      # over lanes: once a program
                 l_safe = jnp.where(l == 0.0, 1.0, l)
                 outs.append(acc / _col(l_safe))
                 m_safe = jnp.where(m == NEG_INF, 0.0, m)
@@ -868,7 +914,14 @@ def _dqkv_kernel(*refs, scale, sched, lanes, terms):
     (:func:`_bwd_call`).
 
     ds is computed once per score tile and head and feeds all cotangents
-    (3 + 2 a term MXU ops; K/V streamed once).  A head's keys and values
+    (3 + 2 a term MXU ops; K/V streamed once).  A score tile stands KEYS BY
+    QUERIES here (``k · qᵀ``): dv = p · dO and dk = ds · q contract it as it
+    stands, lse and delta lie along its lanes as they are stored, and only
+    dq wants it turned, once a tile and head.  Queries by keys, p and ds
+    were each turned for a transposed contraction and lse and delta for the
+    subtraction: 5 to 14% of a call on the v5e, whose unit for moving data
+    across lanes, not the MXU, is what these kernels wait for (PR 47).  A
+    head's keys and values
     are the program's block with the other heads' lanes zeroed, once a
     program, so q and dO are contracted as they are loaded.  A term's dq
     sums in a float32 scratch across the k-block grid dim (TPU grids are
@@ -896,6 +949,7 @@ def _dqkv_kernel(*refs, scale, sched, lanes, terms):
     grad_refs = (dk_ref, dv_ref, *more_dk)
     bk, L = k_ref.shape[1:]
     sk = sched.sub_k
+    dot = functools.partial(_dot, pass_="bwd")
     j = pl.program_id(2)
     c = pl.program_id(1) if any(t.keys > 1 or t.heads > 1 for t in terms) \
         else None
@@ -943,23 +997,24 @@ def _dqkv_kernel(*refs, scale, sched, lanes, terms):
                 dq = None
                 for h in heads:
                     k, v = _rows(ks[h], c0, cols), _rows(vs[h], c0, cols)
-                    s = _dot(q, k, ((1,), (1,)))                 # (rows, cols)
+                    s = dot(k, q, ((1,), (1,)))                 # (cols, rows)
                     for q_t, k_t in zip(q_more, k_more):
-                        s = s + _dot(q_t, k_t, ((1,), (1,)))
+                        s = s + dot(k_t, q_t, ((1,), (1,)))
                     if d is not None or live is not None:
-                        s = _band_mask(s, d, kind, sched, live)
-                    p = jnp.exp(s - _col(lse_ref[0, 0, h, rs]))
-                    dv = _dot(p, do, ((0,), (0,)))
-                    dp = _dot(do, v, ((1,), (1,)))
-                    ds = p * (dp - _col(delta_ref[0, 0, h, rs]))
-                    dk = _dot(ds, q, ((0,), (0,)))
-                    dq_h = _dot(ds, k, ((1,), (0,)))     # zero off the head
+                        s = _band_mask(s, d, kind, sched, live,
+                                       keys_first=True)
+                    p = jnp.exp(s - _row(lse_ref[0, 0, h, rs]))
+                    dv = dot(p, do, ((1,), (0,)))
+                    dp = dot(v, do, ((1,), (1,)))
+                    ds = p * (dp - _row(delta_ref[0, 0, h, rs]))
+                    dk = dot(ds, q, ((1,), (0,)))
+                    made = [dk, dv] + [dot(ds, q_t, ((1,), (0,)))
+                                       for q_t in q_more]
+                    ds = ds.T                                   # (rows, cols)
+                    dq_h = dot(ds, k, ((1,), (0,)))     # zero off the head
                     dq = dq_h if dq is None else dq + dq_h
-                    made = [dk, dv]
-                    for q_t, k_t, t, acc in zip(q_more, k_more, terms[1:],
-                                                dq_accs[1:]):
-                        made.append(_dot(ds, q_t, ((0,), (0,))))
-                        acc[rs] += _own_head(_dot(ds, k_t, ((1,), (0,))), c, t)
+                    for k_t, t, acc in zip(k_more, terms[1:], dq_accs[1:]):
+                        acc[rs] += _own_head(dot(ds, k_t, ((1,), (0,))), c, t)
                     if sums is None:
                         for acc, g in zip(kv_acc, made):
                             acc[h, pl.ds(c0, cols)] += g

@@ -10,7 +10,13 @@ straight-line windowed sweeps alone) and ``other`` (the odd FULL tile left
 over by the paired loop placed the other way: under a ``lax.cond`` forward,
 computed void in the masked tiles' block backward; ``_fold_run``); and
 ``looped`` (not in the default list: ``STRAIGHT_MAX`` 0, the windowed sweeps
-as loops over pairs with their masked tiles computed void).  Every kernel runs under a device scope of its own, so
+as loops over pairs with their masked tiles computed void); and, for what
+a tile's exponentials cost (PR 47: nothing), ``noexp`` (``x / 2 + 1`` in
+their place: wrong numbers, the time alone), ``polyexp`` (all of them a
+polynomial on the vector unit) and ``split128`` / ``split256`` (that share
+of a tile's columns).  ``load(name, root, edits)`` makes a build from the
+module's source with pieces replaced, which is how PR 47 found the sums over
+lanes and the transposed contractions.  Every kernel runs under a device scope of its own, so
 one profiler trace holds them all; the time is the custom call's, from the
 trace (`benchmark/trace_reduce.py read_xplane`), never a wall clock.
 
@@ -21,8 +27,8 @@ Cases are the cells' shapes: ``w1024`` Mellum 2's window layers (4 x 8192,
 32 / 4 heads of 128), ``w2048`` Trinity's (3 rows), ``full`` their full
 layers, ``mla`` JoyAI's two-product kernels (2 x 8192, 32 heads of 128 + 64),
 ``halves`` SDAR's [noisy ; clean] rows (2 x 16384), ``olmoe`` (2 x 4096, 16
-heads of 128), ``xl`` (2 x 1024, 25 heads of 64: the control, its kernels
-are the parent's).
+heads of 128), ``xl`` (2 x 1024, 25 heads of 64), ``lfm2`` (4 x 8192, 32
+heads of 64, the key-value heads repeated as the model does).
 """
 import argparse
 import glob
@@ -48,19 +54,69 @@ CASES = {
     "halves": (2, 16384, 32, 4, 128, None, "halves"),
     "olmoe": (2, 4096, 16, 16, 128, None, "flash"),
     "xl": (2, 1024, 25, 25, 64, None, "flash"),
+    "lfm2": (4, 8192, 32, 32, 64, None, "flash"),
 }
 
 
-def load(name: str, root: str):
+def load(name: str, root: str, edits=()):
     """The flash module of the checkout at ``root`` under a name of its own
     (its relative imports resolve in this tree's package): fresh jits, so
-    no build answers from another's trace cache."""
+    no build answers from another's trace cache.  ``edits`` are ``(old,
+    new)`` pairs replaced in its source first, each of which must be there:
+    a build that leaves a piece of a tile's work out, for its time alone."""
+    path = os.path.join(root, MODULE)
     spec = importlib.util.spec_from_file_location(
-        f"deepspeed_tpu.ops.pallas._probe_{name}", os.path.join(root, MODULE))
+        f"deepspeed_tpu.ops.pallas._probe_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
+    with open(path) as f:
+        source = f.read()
+    for old, new in edits:
+        assert old in source, old
+        source = source.replace(old, new)
+    exec(compile(source, path, "exec"), mod.__dict__)
     return mod
+
+
+def poly_exp(x):
+    """exp(x) for x <= 0 on the vector unit alone (Cephes' expf: two-part
+    reduction by ln 2, a degree-5 polynomial, the power of two added to the
+    exponent bits): what a tile's exponentials cost off the EUP."""
+    f32 = jnp.float32
+    xc = jnp.maximum(x, f32(-87.0))
+    n = jnp.floor(xc * f32(1.44269504088896341) + f32(0.5))
+    r = xc - n * f32(0.693359375) - n * f32(-2.12194440e-4)
+    p = f32(1.9875691500e-4)
+    for c in (1.3981999507e-3, 8.3334519073e-3, 4.1665795894e-2,
+              1.6666665459e-1, 5.0000001201e-1):
+        p = p * r + f32(c)
+    y = p * (r * r) + r + f32(1.0)
+    two_n = jax.lax.bitcast_convert_type(
+        jax.lax.shift_left(n.astype(jnp.int32) + 127, 23), f32)
+    return jnp.where(x < f32(-87.0), f32(0.0), y * two_n)
+
+
+class _Numpy:
+    """``jax.numpy`` with another ``exp``: given to a build's module."""
+
+    def __init__(self, exp):
+        self.exp = exp
+
+    def __getattr__(self, name):
+        return getattr(jnp, name)
+
+
+def split_exp(lanes: int):
+    """The first ``lanes`` columns of a score tile by :func:`poly_exp`, the
+    rest (and every smaller array) by the EUP."""
+    def exp(x):
+        if x.ndim != 2 or x.shape[1] <= lanes:
+            return jnp.exp(x)
+        if not lanes:
+            return poly_exp(x)
+        return jnp.concatenate([poly_exp(x[:, :lanes]),
+                                jnp.exp(x[:, lanes:])], axis=1)
+    return exp
 
 
 def builds(parent: str):
@@ -76,6 +132,13 @@ def builds(parent: str):
     fold_run = other._fold_run
     other._fold_run = lambda *a, branch=False, **k: fold_run(
         *a, branch=not branch, **k)
+    # what a tile's exponentials cost: none (wrong numbers, the time
+    # alone), all on the vector unit, a share of the tile's columns there
+    for name, exp in (("noexp", lambda x: x * 0.5 + 1.0),
+                      ("polyexp", split_exp(0)), ("split128", split_exp(128)),
+                      ("split256", split_exp(256))):
+        out[name] = load(name, ROOT)
+        out[name].jnp = _Numpy(exp)
     return out
 
 

@@ -10,6 +10,7 @@ import functools
 import hashlib
 import importlib
 import re
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -204,14 +205,115 @@ def kernel_primitives(fn, *args, names=("cond", "while", "scan")):
             name = eqn.primitive.name
             if inside and name in names:
                 found[name] += 1
-            for value in eqn.params.values():
-                for sub in (value if isinstance(value, (tuple, list))
-                            else (value,)):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        walk(sub, inside or name == "pallas_call")
+            for sub in _subjaxprs(eqn):
+                walk(sub, inside or name == "pallas_call")
 
     # a function of its own: make_jaxpr answers from jit's cache for one
     # it has traced, whatever sweeps the kernels had then
     walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr, False)
     return dict(found)
+
+
+def kernel_jaxprs(fn, *args):
+    """The jaxpr of every Pallas kernel that ``fn(*args)`` traces, in the
+    order of their calls."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["jaxpr"])
+                continue
+            for sub in _subjaxprs(eqn):
+                walk(sub)
+
+    walk(jax.make_jaxpr(lambda *a: fn(*a))(*args).jaxpr)
+    return found
+
+
+def _subjaxprs(eqn):
+    for value in eqn.params.values():
+        for sub in (value if isinstance(value, (tuple, list)) else (value,)):
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield sub
+
+
+def _inner_names(eqn, names):
+    """For each jaxpr inside ``eqn``, its variables' names taken from the
+    names of ``eqn``'s operands (``names``: variable -> name)."""
+    ins, p = eqn.invars, eqn.params
+    if eqn.primitive.name == "cond":
+        pairs = [(b.jaxpr, ins[1:]) for b in p["branches"]]
+    elif eqn.primitive.name == "while":
+        nc, nb = p["cond_nconsts"], p["body_nconsts"]
+        pairs = [(p["cond_jaxpr"].jaxpr, ins[:nc] + ins[nc + nb:]),
+                 (p["body_jaxpr"].jaxpr, ins[nc:])]
+    else:
+        pairs = [(sub, ins) for sub in _subjaxprs(eqn)]
+    for sub, outer in pairs:
+        assert len(sub.invars) == len(outer), eqn.primitive.name
+        yield sub, {i: names[o] for i, o in zip(sub.invars, outer)
+                    if not hasattr(o, "val") and o in names}
+
+
+class Products(NamedTuple):
+    """What a kernel's jaxpr does around the MXU."""
+    dots: list      # (lhs type, rhs type, result type) of every dot_general
+    widened: set    # refs a value loaded from which is cast to float32 whole
+    narrowed: int   # casts from a float type to a narrower one
+    turned: int     # products that contract their left operand over its
+    #                 rows (transposed: the chip turns the operand first)
+
+
+def kernel_products_and_casts(kernel, ref_names) -> Products:
+    """:class:`Products` of a kernel's jaxpr; ``ref_names`` names its
+    leading refs in order."""
+    dots, widened, narrowed, turned = [], set(), 0, 0
+
+    def walk(jaxpr, refs):
+        nonlocal narrowed, turned
+        loaded = {}
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            if name == "get" and eqn.invars[0] in refs:
+                loaded[eqn.outvars[0]] = refs[eqn.invars[0]]
+            elif name == "dot_general":
+                dots.append((*(str(v.aval.dtype) for v in eqn.invars),
+                             str(eqn.outvars[0].aval.dtype)))
+                (lhs, _), _ = eqn.params["dimension_numbers"]
+                turned += tuple(lhs) == (0,)
+            elif name == "convert_element_type":
+                src, to = eqn.invars[0].aval.dtype, eqn.params["new_dtype"]
+                if (jnp.issubdtype(src, jnp.floating)
+                        and jnp.issubdtype(to, jnp.floating)
+                        and jnp.dtype(to).itemsize < src.itemsize):
+                    narrowed += 1
+                if to == jnp.float32 and eqn.invars[0] in loaded:
+                    widened.add(loaded[eqn.invars[0]])
+            for sub, inner in _inner_names(eqn, refs):
+                walk(sub, inner)
+
+    walk(kernel, dict(zip(kernel.invars, ref_names)))
+    return Products(dots, widened, narrowed, turned)
+
+
+def mxu_operand_counts():
+    """``flash_mxu_operands_total`` as ``{(pass, dtype): count}``."""
+    from deepspeed_tpu.telemetry import get_registry
+
+    entry = get_registry().snapshot().get("flash_mxu_operands_total")
+    return collections.Counter() if not entry else collections.Counter({
+        (s["labels"]["pass"], s["labels"]["dtype"]): s["value"]
+        for s in entry["samples"]})
+
+
+@contextlib.contextmanager
+def traced_operand_types():
+    """Yields a set that holds, after the block, the operand types of the
+    products of every flash kernel body traced inside it
+    (``flash_mxu_operands_total``)."""
+    types, before = set(), mxu_operand_counts()
+    yield types
+    types.update(dtype for (_, dtype), n in
+                 (mxu_operand_counts() - before).items() if n)

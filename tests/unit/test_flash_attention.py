@@ -443,3 +443,110 @@ def test_a_short_sweep_is_the_parents_program():
     run = lambda: jax.grad(lambda q: flash_attention(
         q, k, v, interpret=True).sum())(q)
     assert "while" not in kernel_primitives(run)
+
+
+# --- the type of the products' operands (PR 47) ----------------------------
+
+def _form(name, dtype, seed=47):
+    """``(fn, operands, terms)`` of one form of the kernels at a small
+    shape: ``fn(*operands)`` is the interpreted flash call."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        flash_attention_halves)
+
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):     # bf16 values, so every type holds the same ones
+        return jnp.asarray(rng.normal(size=shape), jnp.bfloat16).astype(dtype)
+
+    kw = dict(interpret=True, block_q=128, block_k=128)
+    if name in ("plain64", "plain128"):
+        D = int(name[5:])
+        ops = [normal(1, 256, 2, D) for _ in range(3)]
+        return (lambda q, k, v: flash_attention(q, k, v, **kw)), ops, 1
+    if name == "window_gqa":
+        ops = [normal(1, 512, 4, 128), normal(1, 512, 2, 128),
+               normal(1, 512, 2, 128)]
+        return (lambda q, k, v: flash_attention(q, k, v, window=192, **kw)
+                ), ops, 1
+    if name == "two_products":
+        ops = [normal(1, 256, 2, 128), normal(1, 256, 2, 64),
+               normal(1, 256, 2, 128), normal(1, 256, 1, 64),
+               normal(1, 256, 2, 128)]
+        return (lambda qn, qr, kn, kr, v: flash_attention(
+            qn, kn, v, q_rope=qr, k_rope=kr, **kw)), ops, 2
+    assert name == "halves"
+    ops = [normal(1, 512, 4, 128), normal(1, 512, 2, 128),
+           normal(1, 512, 2, 128)]
+    return (lambda q, k, v: flash_attention_halves(q, k, v, block=4, **kw)
+            ), ops, 1
+
+
+def _form_grads(name, dtype):
+    fn, ops, terms = _form(name, dtype)
+    ct = _form(name, jnp.float32, seed=48)[1][0]    # shaped as the output
+
+    def both(*ops):
+        out, vjp = jax.vjp(fn, *ops)
+        return (out, *vjp(ct.astype(out.dtype)))
+
+    return both, ops, terms
+
+
+FORMS = ("plain64", "plain128", "window_gqa", "two_products", "halves")
+# Products of a bf16 call's kernels whose operands are bf16: none.  Every
+# product keeps float32 operands (ISSUE 47, point 6) by two readings on the
+# chip (PERF.md section 6, PR 47): the v5e rounds a float32 operand to bf16
+# on its way into the MXU, bit for bit what ``astype`` gives and at a bf16
+# operand's rate, so the narrow form buys nothing; and a transposed
+# contraction (p^T dO, dS^T q) of a packed bf16 operand ran a backward call
+# 3 to 8% SLOWER at the seven cells' shapes (the backward has held its
+# score tiles keys by queries since, and contracts none transposed: not read
+# again).  A later change that narrows a product says here which,
+# {form: {pass: count}}, and reads the chip again.
+NARROW = {}
+
+
+@pytest.mark.parametrize("name", FORMS)
+def test_products_take_the_operands_the_chip_read_fastest(name):
+    """In the kernels a bf16 call traces to every product has a float32 sum
+    and two operands of one type: float32, but for the products ``NARROW``
+    lists, and ``flash_mxu_operands_total`` counts each by that type; the
+    backward contracts no operand transposed (it turns dS itself, once a
+    tile and head); in a float32 call's kernels there is no narrowing cast;
+    the bf16 call stays within the bf16 tolerance of the float32 one,
+    forward and every gradient."""
+    from tests.unit.flash_parent_sweep import (
+        kernel_jaxprs, kernel_products_and_casts, mxu_operand_counts)
+
+    both, ops, terms = _form_grads(name, jnp.bfloat16)
+    refs = {"fwd": [f"q{t}" for t in range(terms)]
+            + [f"k{t}" for t in range(terms)] + ["v"]}
+    refs["bwd"] = refs["fwd"] + ["do"]
+    before = mxu_operand_counts()
+    kernels = dict(zip(("fwd", "bwd"), kernel_jaxprs(both, *ops)))
+    counted = mxu_operand_counts() - before
+    assert set(kernels) == {"fwd", "bwd"}
+    for pass_, kernel in kernels.items():
+        dots, widened, _, turned = kernel_products_and_casts(
+            kernel, refs[pass_])
+        assert turned == 0, pass_
+        narrow = NARROW.get(name, {}).get(pass_, 0)
+        assert dots.count(("bfloat16", "bfloat16", "float32")) == narrow
+        assert dots.count(("float32",) * 3) == len(dots) - narrow > 0
+        # the counter saw each product of the traced body, by type
+        assert counted[pass_, "bfloat16"] == narrow
+        assert counted[pass_, "float32"] == len(dots) - narrow
+        if not narrow:      # every operand is widened as it is loaded
+            assert widened == set(refs[pass_]), pass_
+
+    wide, ops32, _ = _form_grads(name, jnp.float32)
+    before = mxu_operand_counts()
+    for kernel in kernel_jaxprs(wide, *ops32):
+        dots, _, narrowed, _ = kernel_products_and_casts(kernel, ())
+        assert set(dots) == {("float32",) * 3} and narrowed == 0
+    assert {d for _, d in mxu_operand_counts() - before} == {"float32"}
+
+    for got, want in zip(both(*ops), wide(*ops32)):
+        assert got.dtype == jnp.bfloat16
+        got, want = (np.asarray(x, np.float32) for x in (got, want))
+        assert np.abs(got - want).max() <= 2e-2 * np.abs(want).max()

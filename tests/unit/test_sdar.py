@@ -389,7 +389,10 @@ def test_the_scopes_are_emitted_where_the_section_is_set(setup):
 # of a two-layer model with its chunked loss head, of the chunked head alone
 # and of the flash kernels in interpret mode, taken at the parent commit
 # (2b3f471) with the functions below: a new field, operand or mask that
-# leaks into an old path changes one
+# leaks into an old path changes one.  The two flash pins were taken anew on
+# PR 47's tree (parent 4f98df5), which changes every flash kernel by design:
+# the forward's denominator a partial sum a lane, the backward's score tiles
+# keys by queries
 PARENT = {
     "block olmoe":
         "c5383225cfca851b7e812bf4da2e835179e0390246ada61e2601454196e7bb03",
@@ -401,9 +404,9 @@ PARENT = {
         "b8e6d11268aab82c6e66f9572421e23fd4c6ad9bfda15c45e964e2ea8cf468a8",
     "head": "ae02e7599077772b44fb9c559ea17680529f80ac2245884f1fdf3c8864b48f0a",
     "flash causal":
-        "ce378490856b1fd4fabad1588524a48da4a3db7ab51312dbfbb4416e0ea03481",
+        "a21d84f3c51d9be6ff64ddb8626d7badd39544a5cac0ad948ea0d54d5a15d5a9",
     "flash window":
-        "8a66b9ee87a6cbc285be19a74d79bb7beaa2483efce83b7bf741f2c017ea433a",
+        "557c584d1be9f117133aed4f647b5a4f669632dc6e0c5b1129a58109cbaed86d",
 }
 _MOE = dict(num_experts=4, top_k=2, drop_tokens=False, expert_act="swiglu")
 
