@@ -834,7 +834,8 @@ def kernel_full_dispatch():
                    ("xla", "every row holds a pair"))
 
 
-def kernel_flash_window_gqa():
+def kernel_flash_window_gqa(B=4, S=8192, H=32, KV=4, D=128,
+                            windows=(1024, 2048, None)):
     """The third cell's attention at the cell's own shape
     (``train-mellum2-8k-1chip``: 4 rows of 8192 tokens, 32 query heads on
     4 key-value heads of head_dim 128), with the 1024-key window, the
@@ -845,14 +846,19 @@ def kernel_flash_window_gqa():
     on the chip's write-back of an output block whose index stays put until
     the group's last program and then moves on, to the next key-value head
     and to the next row: one row of one key-value head never moves it, and
-    interpret mode stores every grid step."""
+    interpret mode stores every grid step.
+
+    ``kernel_flash_lanes_256`` runs the same comparison at the eighth
+    cell's shape (``train-qwen3next-gdn-8k-1chip``: 16 query heads on 2
+    key-value heads of head_dim 256, one head a 256-lane block, no
+    window)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
-    B, S, H, KV, D, QB = 4, 8192, 32, 4, 128, 256
+    QB = 256
     G = H // KV
     ks = jax.random.split(jax.random.PRNGKey(2), 4)
     q, ct = (jax.random.normal(kk, (B, S, H, D), jnp.float32)
@@ -886,7 +892,7 @@ def kernel_flash_window_gqa():
     # 1024 and 2048 (the fourth cell's): straight-line sweeps of three and
     # five tiles a program, the first query tiles' and the last key tiles'
     # missing ones computed void (PR 43); None: the loop over pairs
-    for window in (1024, 2048, None):
+    for window in windows:
         flash = lambda q, k, v: flash_attention(q, k, v, window=window)  # noqa: E731
         plain = lambda q, k, v: ref(q, k, v, window)                     # noqa: E731
         out = jax.jit(flash)(q, k, v)
@@ -915,6 +921,90 @@ def kernel_flash_window_gqa():
             name, ("fwd", "dq", "dk", "dv"), (out, *grads),
             lambda fa: _out_and_grads(lambda q, k, v: fa.flash_attention(
                 q, k, v, window=window), loss, (q, k, v)))
+
+
+def kernel_flash_lanes_256():
+    """:func:`kernel_flash_window_gqa` at (4, 8192, 16 / 2, 256), no window."""
+    kernel_flash_window_gqa(H=16, KV=2, D=256, windows=(None,))
+
+
+def kernel_gated_delta(time_it: bool = True):
+    """The gated delta rule at the eighth cell's shape, ``(4, 8192)`` rows,
+    16 key heads and 32 value heads of 128 channels, chunk 64 (PR 48): the
+    chunked form forward and ``jax.vjp`` (five cotangents) in bf16 against
+    ``benchmark/reference/qwen3next.py``'s recurrence one position a step
+    in float32, every row of the batch held apart; decays drawn so that
+    states outlive chunks.  Timed forward and forward + backward, beside
+    the least the recurrence's own traffic allows
+    (``benchmark/flops_qwen3next.py``)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.harness.manifest import ROOT, load_module
+    from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+
+    reference = load_module(ROOT, "reference", "qwen3next")
+    B, S, Hk, Hv, d = 4, 8192, 16, 32, 128
+    ks = jax.random.split(jax.random.PRNGKey(48), 6)
+
+    def unit(key, H, scale):
+        x = jax.random.normal(key, (B, S, H, d), jnp.float32)
+        x = x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6) * scale
+        return x.reshape(B, S, H * d).astype(jnp.bfloat16)
+
+    q, k = unit(ks[0], Hk, d ** -0.5), unit(ks[1], Hk, 1.0)
+    v, do = (jax.random.normal(kk, (B, S, Hv * d), jnp.float32).astype(
+        jnp.bfloat16) for kk in ks[2:4])
+    # exp(g) between 0.5 and 0.999 a token: memories of 2 to 1000 tokens
+    g = jnp.log(jax.random.uniform(ks[4], (B, S, Hv), jnp.float32, 0.5, 0.999))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, S, Hv), jnp.float32))
+
+    def ref(q, k, v, g, beta):
+        f = reference._f32
+        qh, kh = (jnp.repeat(f(t).reshape(B, S, Hk, d), Hv // Hk, axis=2)
+                  for t in (q, k))
+        return reference.delta_rule(qh, kh, f(v).reshape(B, S, Hv, d), g,
+                                    beta).reshape(B, S, Hv * d)
+
+    def both(fn):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(do.astype(out.dtype))
+        return jax.jit(run)
+
+    args = (q, k, v, g, beta)
+    want = both(ref)(*args)
+    rule = lambda *a: gated_delta_rule(*a, chunk=64)        # noqa: E731
+    run = both(rule)
+    got = jax.block_until_ready(run(*args))
+    for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+        a, b = (np.asarray(t, np.float32) for t in (a, b))
+        rows = [float(np.linalg.norm(a[r] - b[r]) / np.linalg.norm(b[r]))
+                for r in range(B)]
+        print(f"  gated_delta xla {n}: |chunked - recurrence| / |recurrence| "
+              f"worst of {B} rows {max(rows):.2e}", flush=True)
+        assert np.isfinite(a).all() and max(rows) <= TOL, (n, rows)
+    if time_it:
+        from benchmark import flops_qwen3next as fl
+
+        conf = {"linear_num_key_heads": Hk, "linear_num_value_heads": Hv,
+                "linear_key_head_dim": d, "linear_value_head_dim": d,
+                "layer_types": ["linear_attention"], "num_hidden_layers": 1}
+        least = fl.gated_delta_bytes_per_step(conf, B * S) / 819e9 * 1e3
+        for name, fn in (("forward", jax.jit(rule)), ("forward + backward",
+                                                      run)):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / 5 * 1e3
+            print(f"  gated_delta xla: {name} {ms:.3f} ms (least for the "
+                  f"recurrence's traffic forward + backward at 819 GB/s: "
+                  f"{least:.3f} ms)", flush=True)
 
 
 def kernel_flash_two_products():
@@ -1218,6 +1308,7 @@ def kernel_short_conv(time_it: bool = True):
 
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
+                kernel_flash_lanes_256, kernel_gated_delta,
                 kernel_qk_rows, kernel_short_conv,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,

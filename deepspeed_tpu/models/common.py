@@ -61,7 +61,8 @@ def resolve_remat_policy(name: str):
 
     - ``"<base>+flash"`` combines the base policy with saving the
       flash-attention kernel's named residuals (``flash_out`` /
-      ``flash_lse``): pallas outputs are not dot outputs, so every
+      ``flash_lse``; and ``gated_delta_out``, the gated delta rule's
+      output): pallas outputs are not dot outputs, so every
       dot-based policy discards them and remat re-runs the whole forward
       kernel inside each backward — "+flash" trades that recompute for
       O(B·S·E) bf16 of saved activations per layer.
@@ -118,7 +119,10 @@ def resolve_remat_policy(name: str):
     return pol
 
 
-_FLASH_RESIDUALS = ("flash_out", "flash_lse")
+# ``gated_delta_out`` (ops/gated_delta.py): a Gated DeltaNet layer's rule
+# is no dot output either, and without it the remat runs the whole chunked
+# form a second time for the norm and the projection behind it
+_FLASH_RESIDUALS = ("flash_out", "flash_lse", "gated_delta_out")
 
 
 def offloadable_policy_name(name: str) -> str:

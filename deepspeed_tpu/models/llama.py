@@ -72,6 +72,26 @@ layer has no positional encoding and no cache leaf yet, so ``decode=True``
 with one raises.  ``tie_word_embeddings`` makes the head read
 ``embed_tokens`` (no ``lm_head`` leaf; the table's gradient is the sum of
 both uses).
+
+Qwen3-Next (Qwen, 2025; ``model_type: qwen3_next``) is the first stack
+with a state carried ALONG the sequence: ``layer_types`` holds
+``"linear_attention"`` where the token mixer is a Gated DeltaNet
+(:class:`GatedDeltaNet`: one projection to ``[q | k | v | z]`` and one to
+``[b | a]``, a causal depthwise filter of ``linear_conv_kernel_dim`` taps
+with SiLU over ``[q | k | v]``, the gated delta rule of
+``ops/gated_delta.py`` over ``linear_num_value_heads`` states of
+``linear_key_head_dim x linear_value_head_dim``, a per-head RMSNorm gated
+by ``silu(z)``, one projection back) and ``"full_attention"`` where it is
+grouped-query attention with ``qk_norm="head"``, ``attn_gate`` (the
+source writes q and the gate as ONE projection of ``2 H D`` columns split a
+head; here they are the two leaves ``q_proj`` and ``gate_proj``, a column
+permutation a loader makes) and ``partial_rotary_factor``: the rotation
+turns the first ``head_dim * factor`` channels of a head and the rest
+pass.  ``norm_zero_centered`` makes every RMSNorm multiply by ``1 + w``
+with ``w`` from zeros (the gated norm inside the DeltaNet layer alone
+keeps ``w`` from ones).  ``MoEConfig.shared_expert_gate`` is the family's
+scalar sigmoid gate on the shared expert.  No cache leaf holds the state
+or the filter's tail yet: ``decode=True`` with such a layer raises.
 """
 from __future__ import annotations
 
@@ -91,6 +111,8 @@ from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift
 
 SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
 CONV = "conv"       # a layer whose token mixer is ShortConv, not attention
+LINEAR = "linear_attention"     # ... is GatedDeltaNet
+MIXERS = (CONV, LINEAR)         # the layer types that are no attention
 # the widths latent attention takes together (their config.json names)
 _MLA_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
                "qk_rope_head_dim", "v_head_dim")
@@ -121,7 +143,10 @@ class LlamaConfig:
     ``moe_intermediate_size``, ``rms_norm_eps``, ``rope_theta``,
     ``rope_parameters``, ``sliding_window``, ``layer_types``,
     ``initializer_range``, ``num_dense_layers``, ``mup_enabled``,
-    ``conv_L_cache``, ``conv_bias``, ``tie_word_embeddings``.  The rest are
+    ``conv_L_cache``, ``conv_bias``, ``tie_word_embeddings``,
+    ``linear_num_key_heads``, ``linear_num_value_heads``,
+    ``linear_key_head_dim``, ``linear_value_head_dim``,
+    ``linear_conv_kernel_dim``, ``partial_rotary_factor``.  The rest are
     this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
@@ -141,8 +166,26 @@ class LlamaConfig:
     moe_intermediate_size: Optional[int] = None
     # one entry a layer (more are ignored: a model cut in depth keeps its
     # source's list), "sliding_attention" | "full_attention" | "conv" (a
-    # short-convolution mixer in attention's place); None → all full
+    # short-convolution mixer in attention's place) | "linear_attention" (a
+    # Gated DeltaNet there); None → all full
     layer_types: Optional[tuple] = None
+    # a "linear_attention" layer: key heads (q and k), value heads (v, the
+    # states, the output; a multiple of the key heads), their channels
+    # (one number: a state is square), the taps of the filter over
+    # [q | k | v] (the last is the current position)
+    linear_num_key_heads: int = 16
+    linear_num_value_heads: int = 32
+    linear_key_head_dim: int = 128
+    linear_value_head_dim: int = 128
+    linear_conv_kernel_dim: int = 4
+    # positions the delta rule solves together (ops/gated_delta.py)
+    linear_chunk_size: int = 64
+    # the share of a head's channels, from the first on, that the rotation
+    # turns; the rest pass
+    partial_rotary_factor: float = 1.0
+    # every RMSNorm multiplies by 1 + w, w from zeros (the gated norm of a
+    # "linear_attention" layer alone keeps w from ones)
+    norm_zero_centered: bool = False
     # taps of a "conv" layer's causal depthwise filter (the family's name:
     # the positions its serving cache would hold); the last is the current
     # position
@@ -253,10 +296,10 @@ class LlamaConfig:
             raise ValueError("num_dense_layers counts the blocks that moe "
                              "leaves dense; there is no moe")
         for t in self.layer_types or ():
-            if t not in (SLIDING, FULL_ATTENTION, CONV):
+            if t not in (SLIDING, FULL_ATTENTION, CONV, LINEAR):
                 raise ValueError(f"layer_types holds {t!r}; {SLIDING!r}, "
-                                 f"{FULL_ATTENTION!r} and {CONV!r} are "
-                                 f"written")
+                                 f"{FULL_ATTENTION!r}, {CONV!r} and "
+                                 f"{LINEAR!r} are written")
         if self.layer_types is not None:
             if len(self.layer_types) < self.num_hidden_layers:
                 raise ValueError(
@@ -286,6 +329,56 @@ class LlamaConfig:
                 raise NotImplementedError(
                     "latent attention with a conv layer (layer_types): "
                     "LlamaLatentAttention takes no layer type")
+        if LINEAR in self.kinds:
+            Hk, Hv = self.linear_num_key_heads, self.linear_num_value_heads
+            if Hk < 1 or Hv % Hk:
+                raise ValueError(
+                    f"linear_num_value_heads {Hv} is no multiple of "
+                    f"linear_num_key_heads {Hk}")
+            if self.linear_key_head_dim != self.linear_value_head_dim:
+                raise NotImplementedError(
+                    f"linear_key_head_dim {self.linear_key_head_dim} != "
+                    f"linear_value_head_dim {self.linear_value_head_dim}: "
+                    f"the delta rule is written for square states")
+            if self.linear_conv_kernel_dim < 1 or self.linear_chunk_size < 1:
+                raise ValueError(
+                    f"linear_conv_kernel_dim {self.linear_conv_kernel_dim} "
+                    f"and linear_chunk_size {self.linear_chunk_size}: at "
+                    f"least one tap and one position a chunk")
+            if self.decode:
+                raise NotImplementedError(
+                    "decode=True with a linear_attention layer "
+                    "(layer_types): the cache holds keys and values, and a "
+                    "Gated DeltaNet's recurrent state and its filter's tail "
+                    "are no leaves of it yet")
+            if self.diffusion is not None:
+                raise NotImplementedError(
+                    "diffusion (block-diffusion training) with a "
+                    "linear_attention layer: the state would run across "
+                    "the two halves [noisy ; clean] and no block mask is "
+                    "written for it")
+            if self.mla_fields:
+                raise NotImplementedError(
+                    "latent attention with a linear_attention layer "
+                    "(layer_types): LlamaLatentAttention takes no layer "
+                    "type")
+            if self.scan_layers:
+                raise NotImplementedError(
+                    "scan_layers=True with a linear_attention layer "
+                    "(layer_types): scan_layers scans one kind of block; "
+                    "set scan_layers=False (the stack is then unrolled)")
+        if not 0.0 < self.partial_rotary_factor <= 1.0 \
+                or self.rotary_dim % 2:
+            raise ValueError(
+                f"partial_rotary_factor {self.partial_rotary_factor} of "
+                f"head_dim {self.head_dim}: a share in (0, 1] that leaves "
+                f"an even number of channels")
+        if self.partial_rotary_factor != 1.0 and (self.rope_interleave
+                                                  or self.mla_fields):
+            raise NotImplementedError(
+                "partial_rotary_factor with rope_interleave or latent "
+                "attention: the partial rotation is written for half-split "
+                "pairs of grouped-query attention")
         if self.mla_fields and not all(getattr(self, f) for f in _MLA_WIDTHS):
             raise ValueError(
                 f"latent attention takes {', '.join(_MLA_WIDTHS)} "
@@ -355,6 +448,11 @@ class LlamaConfig:
                 f"know none of them")
 
     @property
+    def rotary_dim(self) -> int:
+        """Channels of a head, from the first on, that the rotation turns."""
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads or self.num_attention_heads
 
@@ -416,7 +514,7 @@ class LlamaConfig:
         if "rope_type" not in entry:        # keyed by layer type
             entry = dict(entry[kind or FULL_ATTENTION])
         entry.setdefault("rope_theta", self.rope_theta)
-        return rotary_table(self.head_dim, **entry)
+        return rotary_table(self.rotary_dim, **entry)
 
 
 PRESETS = {
@@ -459,9 +557,12 @@ class RMSNorm(nn.Module):
 
     @nn.compact
     def __call__(self, x, params_only: bool = False):
-        scale = self.param("scale", nn.with_partitioning(nn.initializers.ones,
-                                                         (self.axis,)),
-                           (x.shape[-1],), self.cfg.param_dtype)
+        centred = self.cfg.norm_zero_centered
+        scale = self.param("scale", nn.with_partitioning(
+            nn.initializers.zeros if centred else nn.initializers.ones,
+            (self.axis,)), (x.shape[-1],), self.cfg.param_dtype)
+        if centred:         # the multiplier reaches every reader as data
+            scale = 1.0 + scale
         if params_only:
             return scale
         from .common import rms_norm
@@ -504,7 +605,8 @@ class LlamaAttention(nn.Module):
         q = proj("q_proj", ("embed", "qkv"), H * D).reshape(B, S, H, D)
         k = proj("k_proj", ("embed", "kv"), KV * D).reshape(B, S, KV, D)
         v = proj("v_proj", ("embed", "kv"), KV * D).reshape(B, S, KV, D)
-        q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
+        q, k = apply_rotary_pos_emb(q, k, position_ids,
+                                    rotary_dim=cfg.rotary_dim,
                                     theta=cfg.rope_theta)
         kc, vc, cur = self._cache_append(k, v)
         from ..ops.attention import cached_decode_attention
@@ -549,7 +651,8 @@ class LlamaAttention(nn.Module):
             q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
             k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
         rotates, head_norm = cfg.rotates(self.kind), cfg.qk_norm == "head"
-        plan = rows_plan(q, k, D, decode=cfg.decode, norm=head_norm) \
+        plan = rows_plan(q, k, D, rotary_dim=cfg.rotary_dim,
+                         decode=cfg.decode, norm=head_norm) \
             if rotates or head_norm else None
         # a layer type's own table and device scopes only where the
         # configuration names layer types
@@ -574,7 +677,8 @@ class LlamaAttention(nn.Module):
                 k = RMSNorm(cfg, axis="head_dim", name="k_norm")(k)
         if rotates and plan is None:
             with trace.device_span(rope):
-                q, k = apply_rotary_pos_emb(q, k, position_ids, rotary_dim=D,
+                q, k = apply_rotary_pos_emb(q, k, position_ids,
+                                            rotary_dim=cfg.rotary_dim,
                                             theta=cfg.rope_theta,
                                             table=cfg.rotary(self.kind))
         if cfg.decode:
@@ -706,6 +810,92 @@ class ShortConv(nn.Module):
                           module=self)
 
 
+class GatedDeltaNet(nn.Module):
+    """The Qwen3-Next family's token mixer of a ``"linear_attention"``
+    layer (released code: ``Qwen3NextGatedDeltaNet``), Hk key heads and Hv
+    value heads of d channels::
+
+        [q | k | v | z] = h W_qkvz      # Hk d | Hk d | Hv d | Hv d, contiguous
+        [b | a]         = h W_ba        # Hv | Hv
+        [q | k | v]    <- silu(filter([q | k | v]))     # ops/short_conv.py
+        beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias)
+        q <- q / |q| * d^-1/2;  k <- k / |k|            # a head, eps 1e-6
+        o = gated_delta_rule(q, k, v, g, beta)          # ops/gated_delta.py
+        y = (o * rsqrt(mean(o^2) + eps) * w_o) * silu(z)    # a head
+        out = y W_out
+
+    The source's ``in_proj_qkvz`` orders its columns a key-head group
+    (``[q ; k ; v ; z]`` of group 0, then of group 1, ...) and
+    ``in_proj_ba`` likewise; here each part is contiguous, a column
+    permutation a loader makes, so that the filter and the rule read rows
+    as the projection wrote them.  ``in_proj_qkvz`` is one leaf read by
+    two products (``[q | k | v]`` for the filter, ``z`` for the gate): each
+    lands where its reader wants it.  No positional encoding, no bias;
+    every row of a batch starts from a zero state and an empty filter.
+    The channels shard as the attention projections' do."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops.gated_delta import gated_delta_rule
+        from ..ops.short_conv import causal_conv_rows
+
+        cfg = self.cfg
+        B, S, E = x.shape
+        Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        d = cfg.linear_value_head_dim
+        conv_dim = (2 * Hk + Hv) * d
+        init = nn.initializers.normal(cfg.initializer_range)
+        f32 = jnp.float32
+        with trace.device_span("linear_attn/in_proj"):
+            w_in = self.param("in_proj_qkvz_kernel", nn.with_partitioning(
+                init, ("embed", "qkv")), (E, conv_dim + Hv * d),
+                cfg.param_dtype).astype(cfg.dtype)
+            qkv = jnp.dot(x, w_in[:, :conv_dim])
+            z = jnp.dot(x, w_in[:, conv_dim:])
+            ba = _dense(x, 2 * Hv, ("embed", "qkv"), cfg=cfg,
+                        name="in_proj_ba", module=self)
+        taps = self.param("conv_kernel", nn.with_partitioning(
+            init, ("heads", None)), (conv_dim, cfg.linear_conv_kernel_dim),
+            cfg.param_dtype)
+        with trace.device_span("linear_attn/conv"):
+            qkv = causal_conv_rows(qkv, taps, activation="silu")
+        # A_log = log(U(0, 16)), dt_bias ones (released code)
+        a_log = self.param("A_log", nn.with_partitioning(
+            lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                key, shape, dtype, 1e-3, 16.0)), ("heads",)), (Hv,), f32)
+        dt_bias = self.param("dt_bias", nn.with_partitioning(
+            nn.initializers.ones, ("heads",)), (Hv,), f32)
+        with trace.device_span("linear_attn/delta_rule"):
+            def unit(t, H):         # each head's channels to length 1
+                t = t.astype(f32).reshape(B, S, H, d)
+                return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True)
+                                         + 1e-6)
+
+            q = (unit(qkv[..., :Hk * d], Hk) * d ** -0.5).astype(cfg.dtype)
+            k = unit(qkv[..., Hk * d:2 * Hk * d], Hk).astype(cfg.dtype)
+            beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
+            g = -jnp.exp(a_log) * jax.nn.softplus(
+                ba[..., Hv:].astype(f32) + dt_bias)
+            o = gated_delta_rule(
+                q.reshape(B, S, Hk * d), k.reshape(B, S, Hk * d),
+                qkv[..., 2 * Hk * d:], g, beta, chunk=cfg.linear_chunk_size)
+        w_o = self.param("o_norm", nn.with_partitioning(
+            nn.initializers.ones, ("head_dim",)), (d,), cfg.param_dtype)
+        with trace.device_span("linear_attn/gated_norm"):
+            from .common import rms_norm
+
+            # norm first, gate second; w from ones whatever the other
+            # norms of the model are
+            y = rms_norm(o.reshape(B, S, Hv, d).astype(f32), w_o,
+                         cfg.rms_norm_eps)
+            y = (y * jax.nn.silu(
+                z.reshape(B, S, Hv, d).astype(f32))).astype(cfg.dtype)
+        with trace.device_span("linear_attn/out_proj"):
+            return _dense(y.reshape(B, S, Hv * d), E, ("heads", "embed"),
+                          cfg=cfg, name="out_proj", module=self)
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
@@ -758,6 +948,9 @@ class LlamaBlock(nn.Module):
                 return x, None
         if self.kind == CONV:       # the mixer reads no position and no mask
             attn = ShortConv(cfg, name="conv")(
+                RMSNorm(cfg, name="input_norm")(x))
+        elif self.kind == LINEAR:
+            attn = GatedDeltaNet(cfg, name="linear_attn")(
                 RMSNorm(cfg, name="input_norm")(x))
         else:
             self_attn = LlamaLatentAttention(cfg, name="self_attn") \
@@ -1146,8 +1339,18 @@ class LlamaForCausalLM(nn.Module):
         # a conv layer's mixer: in_proj to three thirds, out_proj, the taps
         convs = cfg.kinds.count(CONV)
         conv = 3 * E * E + E * E + E * cfg.conv_L_cache
+        # a linear_attention layer's mixer: two projections in, the taps,
+        # one projection out; its recurrence a token a value head is three
+        # products of d x d forward (S^T k, k (x) delta, S^T q)
+        linears = cfg.kinds.count(LINEAR)
+        Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+        d = cfg.linear_value_head_dim
+        conv_dim = (2 * Hk + Hv) * d
+        linear = (E * (conv_dim + Hv * d) + E * 2 * Hv
+                  + conv_dim * cfg.linear_conv_kernel_dim + Hv * d * E)
         table = (1 if cfg.tie_word_embeddings else 2) * cfg.padded_vocab_size
-        n = (table * E + (L - convs) * attn + convs * conv
+        n = (table * E + (L - convs - linears) * attn + convs * conv
+             + linears * (linear + 3 * Hv * d * d)
              + cfg.num_dense_layers * dense
              + (L - cfg.num_dense_layers) * ffn
              + mtp * (attn + ffn + 2 * E * E + cfg.padded_vocab_size * E))
@@ -1155,5 +1358,5 @@ class LlamaForCausalLM(nn.Module):
         # positions, or the window where that is shorter
         S = cfg.max_position_embeddings
         keys = sum(min(S, cfg.window(k) or S) for k in cfg.kinds
-                   if k != CONV) if cfg.kinds else (L + mtp) * S
+                   if k not in MIXERS) if cfg.kinds else (L + mtp) * S
         return 6.0 * n + 6 * H * score * keys

@@ -1,0 +1,308 @@
+"""The gated delta rule over a sequence (Gated DeltaNet, arXiv:2412.06464;
+the token mixer of three layers in four of ``model_type: qwen3_next``): a
+state a value head, ``S`` (keys x values, ``d x d``), carried ALONG the
+sequence,
+
+    S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T
+    o_t = S_t^T q_t                                   S_0 = 0 at every row
+
+with ``g_t <= 0`` a log-decay and ``beta_t`` in (0, 1) a value head a
+token.  ``q`` and ``k`` arrive normalised and scaled (the caller's); key
+head ``j`` serves value heads ``j * r .. j * r + r - 1``, ``r = Hv / Hk``.
+
+**The chunked form** (what runs; the per-token recurrence above is
+``benchmark/reference/qwen3next.py``'s and the tests').  In a chunk of
+``C`` positions, ``gamma_i = sum_{j<=i} g_j``, ``G_ij = exp(gamma_i -
+gamma_j)`` for i >= j (all <= 1), ``S`` the state entering the chunk:
+
+    A  = strict_lower(diag(beta) (K K^T * G))
+    [U | W] = (I + A)^-1 diag(beta) [V | K * exp(gamma)]
+    V' = U - W S
+    O  = (Q * exp(gamma)) S + lower_incl(Q K^T * G) V'
+    S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
+
+``A``, ``U``, ``W`` and ``Q K^T`` depend on no state and are computed for
+all chunks at once as batched products; the last three lines are a
+``lax.scan`` over the chunks of a row.  ``(I + A)^-1`` is never formed:
+``I + A`` is unit lower triangular and the system is solved by forward
+substitution (:func:`_solve_unit_lower`: rows inside blocks of 16, blocks
+by products), which is exact for any keys; the product form ``(I - A)(I +
+A^2)(I + A^4)...`` is the same matrix in exact arithmetic and cancels
+catastrophically in float32 once keys correlate (powers of a 64 x 64 ``A``
+with entries near 1 reach 1e18).  Decays, ``gamma``, the solve and ``S``
+are float32; the operands of the large products have the type the call
+arrived in (float32 accumulation).
+
+One ``custom_vjp``: the forward keeps its five inputs and nothing else, so
+a block's ``dots_saveable`` policy sees none of the inner products; the
+backward runs the chunked form again and transposes it (``jax.vjp``), which
+keeps the per-chunk states for the length of a row's backward (``N x Hv x
+d x d`` float32: 128 x 32 x 64 KB = 256 MiB at S 8192).  Both passes walk
+the batch a row at a time.
+
+``impl``: ``"xla"`` is the above with the last three lines a ``lax.scan``,
+on every backend and what the tests hold the kernels to.  ``"pallas"``
+(what ``"auto"`` takes on a TPU where the shape allows) hands those three
+lines, forward and backward, to ``ops/pallas/gated_delta.py`` (HLO custom
+calls ``gated_delta_fwd`` / ``gated_delta_bwd``, the state resident in
+VMEM across the chunk axis of the grid); what depends on no state stays
+XLA's batched products either way.  At ``(4, 8192, 32 heads of 128)`` on
+the v5e the scan as XLA's while loop read 93.6 ms forward and 211 forward +
+backward a layer (my chip run, PR 48; PERF.md section 6 has the kernels').
+``kernel_dispatch_total{site="gated_delta"}`` says what a call resolved to
+and why; ``gated_delta_chunks_total{pass}`` counts, at trace time, the
+chunks of one head-sequence a traced pass walks.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from ..telemetry import registry as _registry
+
+IMPLS = ("auto", "pallas", "xla")
+_HI = lax.Precision.HIGHEST
+SOLVE_BLOCK = 16
+
+
+def _note_chunks(pass_: str, n: int) -> None:
+    _registry.counter(
+        "gated_delta_chunks_total",
+        "chunks of one head-sequence that a traced pass of the gated delta "
+        "rule walks in sequence (counted at trace time, not per call)",
+        labelnames=("pass",)).labels(pass_).inc(n)
+
+
+def _solve_unit_lower(a: jax.Array, rhs: jax.Array) -> jax.Array:
+    """``x`` with ``(I + a) x = rhs``: ``a`` (..., C, C) strictly lower
+    triangular, ``rhs`` (..., C, n), float32.  Block forward substitution
+    over blocks of :data:`SOLVE_BLOCK` rows: block row ``i`` of ``x`` from
+    the block rows before it by products, through the inverse of its own
+    diagonal block ``I + D``.  ``D`` is strictly lower triangular, so
+    ``D^16 = 0`` and ``(I + D)^-1 = (I - D)(I + D^2)(I + D^4)(I + D^8)``
+    exactly: three squarings of 16 x 16 matrices in float32, whose largest
+    power's entries stay under ``C(15, 7) = 6435`` for any keys (the same
+    product over a whole 64 x 64 ``a`` would reach 1e18 and cancel to
+    nothing)."""
+    C = a.shape[-1]
+    b = min(SOLVE_BLOCK, C)
+    assert C % b == 0, (C, b)
+    nb = C // b
+
+    def mm(x, y):
+        return jnp.einsum("...ij,...jn->...in", x, y, precision=_HI)
+
+    # the diagonal blocks together, (..., nb, b, b)
+    diag = jnp.stack([a[..., i * b:(i + 1) * b, i * b:(i + 1) * b]
+                      for i in range(nb)], axis=-3)
+    eye = jnp.eye(b, dtype=a.dtype)
+    inv, power = eye - diag, mm(diag, diag)
+    for _ in range(max(b - 1, 1).bit_length() - 1):     # D^2, D^4, D^8
+        inv, power = mm(inv, eye + power), mm(power, power)
+    xs = []
+    for i in range(nb):
+        lo, hi = i * b, (i + 1) * b
+        r_i = rhs[..., lo:hi, :]
+        if i:
+            r_i = r_i - mm(a[..., lo:hi, :lo], jnp.concatenate(xs, axis=-2))
+        xs.append(mm(inv[..., i, :, :], r_i))
+    return jnp.concatenate(xs, axis=-2)
+
+
+def _prepare(q, k, v, g, beta, chunk: int):
+    """What of the chunked form depends on no state, for every chunk at
+    once: ``(u, w, p, qg, kd, g_last)`` as ``(B, Hv, N, C, .)`` (``g_last``
+    ``(B, Hv, N)``), ``u`` and ``g_last`` float32, the others in ``v``'s
+    type: ``U``, ``W``, ``lower_incl(Q K^T * G)``, ``Q * exp(gamma)``, ``K *
+    exp(gamma_C - gamma)`` and ``exp(gamma_C)`` of the module's text."""
+    f32 = jnp.float32
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    Hk = k.shape[-1] // d
+    C, N = chunk, S // chunk
+    cdt = v.dtype                   # operands of the large products
+
+    def heads(x, H):                # (B, S, H*d) -> (B, H, N, C, d)
+        return x.reshape(B, N, C, H, d).transpose(0, 3, 1, 2, 4)
+
+    def per_head(x):                # (B, S, Hv) -> (B, Hv, N, C) float32
+        return x.astype(f32).reshape(B, N, C, Hv).transpose(0, 3, 1, 2)
+
+    r = Hv // Hk
+    q_, k_ = (jnp.repeat(heads(x, Hk), r, axis=1) if r > 1 else heads(x, Hk)
+              for x in (q, k))
+    v_ = heads(v, Hv)
+    beta_ = per_head(beta)
+    gamma = jnp.cumsum(per_head(g), axis=-1)
+    idx = jnp.arange(C)
+    lower = idx[:, None] >= idx[None, :]
+    # exp of a masked difference: above the diagonal the difference is
+    # positive and could overflow before a mask multiplies it away
+    decay = jnp.exp(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :],
+                              -jnp.inf))
+    kk = jnp.einsum("bhnid,bhnjd->bhnij", k_, k_, preferred_element_type=f32)
+    a = jnp.where(idx[:, None] > idx[None, :],
+                  beta_[..., None] * kk * decay, 0.0)
+    e_gamma = jnp.exp(gamma)[..., None]
+    rhs = jnp.concatenate([v_.astype(f32), k_.astype(f32) * e_gamma],
+                          axis=-1) * beta_[..., None]
+    uw = _solve_unit_lower(a, rhs)
+    u, w = uw[..., :d], uw[..., d:].astype(cdt)
+    p = (jnp.einsum("bhnid,bhnjd->bhnij", q_, k_, preferred_element_type=f32)
+         * decay).astype(cdt)
+    qg = (q_.astype(f32) * e_gamma).astype(cdt)
+    kd = (k_.astype(f32)
+          * jnp.exp(gamma[..., -1:] - gamma)[..., None]).astype(cdt)
+    return u, w, p, qg, kd, jnp.exp(gamma[..., -1])
+
+
+def _scan_xla(u, w, p, qg, kd, g_last):
+    """The sequential part, ``lax.scan`` over the chunk axis: ``o`` (B, Hv,
+    N, C, d) in ``w``'s type."""
+    f32 = jnp.float32
+    cdt = w.dtype
+
+    def mm(eq, x, y):
+        return jnp.einsum(eq, x, y.astype(cdt), preferred_element_type=f32)
+
+    def step(state, xs):            # state (B, Hv, d, d) float32
+        u_n, w_n, p_n, qg_n, kd_n, gl_n = xs
+        v_new = u_n - mm("bhcd,bhde->bhce", w_n, state)
+        o_n = mm("bhcd,bhde->bhce", qg_n, state) \
+            + mm("bhic,bhce->bhie", p_n, v_new)
+        state = gl_n[..., None, None] * state \
+            + mm("bhcd,bhce->bhde", kd_n, v_new)
+        return state, o_n.astype(cdt)
+
+    B, Hv, _, _, d = u.shape
+    chunks = tuple(jnp.moveaxis(x, 2, 0) for x in (u, w, p, qg, kd, g_last))
+    _, o = lax.scan(step, jnp.zeros((B, Hv, d, d), f32), chunks)
+    return jnp.moveaxis(o, 0, 2)
+
+
+def _chunked(q, k, v, g, beta, chunk: int, scan=_scan_xla):
+    """The module's chunked form; shapes as :func:`gated_delta_rule`."""
+    B, S, Hv = g.shape
+    o = scan(*_prepare(q, k, v, g, beta, chunk))    # (B, Hv, N, C, d)
+    return o.transpose(0, 2, 3, 1, 4).reshape(B, S, v.shape[-1])
+
+
+def _row(chunk: int, scan):
+    """:func:`_chunked` of one row of the batch, without the batch axis."""
+    return lambda *xs: _chunked(*(x[None] for x in xs), chunk, scan)[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, g, beta, chunk, scan):
+    """A row of the batch at a time (``lax.map``), forward and backward: a
+    row's heads and chunks fill the chip, and what the form keeps between
+    its products - and, in the backward, for its transposition - is one
+    row's and not the batch's (a quarter at four rows: 3 GiB less of a
+    step's peak).  The backward maps ``vjp`` itself: the transpose of a
+    mapped forward would keep every row's residuals stacked."""
+    _note_chunks("fwd", g.shape[1] // chunk)
+    return lax.map(lambda xs: _row(chunk, scan)(*xs), (q, k, v, g, beta))
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, scan):
+    return _rule(q, k, v, g, beta, chunk, scan), (q, k, v, g, beta)
+
+
+def _rule_bwd(chunk, scan, res, do):
+    _note_chunks("bwd", 2 * (res[3].shape[1] // chunk))
+
+    def one(xs):
+        _, pull = jax.vjp(_row(chunk, scan), *xs[:-1])
+        return pull(xs[-1])
+
+    return lax.map(one, (*res, do))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+def _plan(g, v, chunk: int, impl: str):
+    """``(impl, reason, batch axes of a shard_map or None)``."""
+    from .attention import on_tpu
+    from .pallas import gated_delta as kernel
+    from .pallas.spmd import kernel_mesh_plan
+
+    if impl == "xla":
+        return impl, "impl='xla' asked for", None
+    B, S, Hv = g.shape
+    reason = kernel.supported(S // chunk, chunk, v.shape[-1] // Hv, v.dtype)
+    if reason is None and impl == "auto" and not on_tpu():
+        reason = "no TPU"
+    verdict = axes = None
+    if reason is None:
+        verdict, axes = kernel_mesh_plan(B)
+        if verdict is None:
+            reason = "kernel_mesh_plan refused the mesh"
+    if reason is not None:
+        if impl == "pallas":
+            raise NotImplementedError(f"gated_delta impl='pallas': {reason}")
+        return "xla", reason, None
+    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hv} heads of "
+                      f"{v.shape[-1] // Hv}; "
+                      + ("one device" if verdict == "direct" else
+                         f"shard_map over batch axes {axes}")), axes
+
+
+def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                     beta: jax.Array, *, chunk: int = 64,
+                     impl: str = "auto", interpret: bool = False
+                     ) -> jax.Array:
+    """``o`` (B, S, Hv*d) of the gated delta rule: ``q``, ``k`` (B, S,
+    Hk*d) normalised and scaled by the caller, ``v`` (B, S, Hv*d), ``g``
+    (B, S, Hv) float32 log-decays (<= 0), ``beta`` (B, S, Hv), as a layer's
+    projections and filter wrote them.  Each row of the batch starts from
+    a zero state; ``S`` is a multiple of ``chunk``.  See the module's
+    text."""
+    from .pallas.spmd import note_dispatch
+
+    if impl not in IMPLS:
+        raise ValueError(f"gated_delta impl {impl!r}: one of {IMPLS}")
+    B, S, Hv = g.shape
+    if beta.shape != g.shape or v.ndim != 3 or v.shape[-1] % Hv \
+            or q.shape != k.shape or k.shape[-1] % (v.shape[-1] // Hv) \
+            or q.shape[:2] != (B, S) or v.shape[:2] != (B, S):
+        raise ValueError(
+            f"gated_delta_rule takes q, k (B, S, Hk*d), v (B, S, Hv*d), g "
+            f"and beta (B, S, Hv), got {q.shape}, {k.shape}, {v.shape}, "
+            f"{g.shape}, {beta.shape}")
+    Hk = k.shape[-1] // (v.shape[-1] // Hv)
+    if Hv % Hk:
+        raise ValueError(f"{Hv} value heads are no multiple of {Hk} key "
+                         f"heads")
+    if S % chunk:
+        raise ValueError(f"rows of {S} positions are no whole chunks of "
+                         f"{chunk}")
+    impl, reason, axes = _plan(g, v, chunk, impl)
+    note_dispatch("gated_delta", impl, reason)
+    scan = _scan_xla
+    if impl == "pallas":
+        from .pallas.gated_delta import scan_chunks
+
+        scan = functools.partial(scan_chunks, interpret=interpret)
+
+    def run(*args):
+        return _rule(*args, chunk, scan)
+
+    if axes is not None:
+        from jax.sharding import PartitionSpec as P
+
+        from ..comm.mesh import get_mesh
+
+        rows = P(axes if axes else None, None, None)
+        run = jax.shard_map(run, mesh=get_mesh(), in_specs=(rows,) * 5,
+                            out_specs=rows, check_vma=False)
+    # under the name a ``+flash`` remat policy keeps (``models/common.py
+    # resolve_remat_policy``): the output is no dot output, and recomputed it
+    # is the whole chunked form a second time (a seventh of a step at the
+    # eighth cell's shape) for 8 KB a token a layer kept
+    return checkpoint_name(run(q, k, v, g.astype(jnp.float32), beta),
+                           "gated_delta_out")

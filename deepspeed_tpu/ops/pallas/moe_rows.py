@@ -100,12 +100,22 @@ def supported(n_tokens: int, k: int, width: int, dtype) -> Optional[str]:
         return f"rows of {jnp.dtype(dtype).name}"
     if width % 256:
         return f"row width {width} is no multiple of 256"
-    if k not in (1, 2, 4, 8, 16):
-        return f"top-{k} is no power of two up to 16"
+    if not 1 <= k <= 16:
+        return f"top-{k} is not in 1..16"
     if n_tokens % PACK or (n_tokens * k) % STEP:
         return (f"{n_tokens} tokens x {k} are no whole blocks of {PACK} "
                 f"tokens and {STEP} rows")
     return None
+
+
+def _dealt(k: int) -> int:
+    """The choices a token :func:`combine_rows` deals its blocks by: ``k``
+    where it is a power of two, else the next one (top-10: 16), the choices
+    past ``k`` "no row" pairs of weight 0 - the kernels' own absent form,
+    which costs them no DMA and ``16 / k`` of the combine's vector work (a
+    sub-block of :data:`SUB` pairs is ``SUB / k`` whole tokens only where k
+    divides it)."""
+    return 1 << (k - 1).bit_length()
 
 
 def _note_trace(kernel: str, *signature) -> None:
@@ -472,7 +482,12 @@ def combine_rows(src: jax.Array, idx: jax.Array, weights: jax.Array,
     (S, k) float32 (the weights are not read).  An index of N is "no row"
     and adds (or gives) zero."""
     N, _, half = src.shape
-    S, k = weights.shape
+    S, k_given = weights.shape
+    k = _dealt(k_given)
+    if k != k_given:        # "no row" pairs of weight 0 up to a power of two
+        idx = jnp.pad(idx.reshape(S, k_given), ((0, 0), (0, k - k_given)),
+                      constant_values=N).reshape(-1)
+        weights = jnp.pad(weights, ((0, 0), (0, k - k_given)))
     dw = g is not None
     _note_trace("combine", name, N, S, k, 2 * half, dw)
     tokens = STEP // k
@@ -489,7 +504,7 @@ def combine_rows(src: jax.Array, idx: jax.Array, weights: jax.Array,
     else:
         out_spec = pl.BlockSpec((tokens, 2 * half), lambda i, counts: (i, 0))
         out_shape = jax.ShapeDtypeStruct((S, 2 * half), jnp.bfloat16)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_combine_kernel, k=k, dw=dw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S * k // STEP,), in_specs=in_specs,
@@ -500,3 +515,4 @@ def combine_rows(src: jax.Array, idx: jax.Array, weights: jax.Array,
             bytes_accessed=4 * S * k * half + 4 * S * half + 8 * S * k),
         name=name, interpret=interpret,
     )(counts, entries, src, weights, *((g,) if dw else ()))
+    return out[:, :k_given] if dw else out

@@ -30,6 +30,13 @@ ms, ``shift`` 9.03, the HBM rate's least 1.80; a depthwise
 The arithmetic inside is float32 whatever the operands are and the result
 has the operands' type.  ``kernel_dispatch_total{site="short_conv"}`` says
 which form a call resolved to, and why.
+
+:func:`causal_conv_rows` is the same filter without the gates, with an
+activation after it: what a Gated DeltaNet layer (``model_type:
+qwen3_next``) runs over the channels of ``[q ; k ; v]`` before its
+recurrence.  It has the shifted form alone (the Pallas body is written for
+the gated form; ROADMAP.md A has the chip's reading that would ask for
+more).
 """
 from __future__ import annotations
 
@@ -40,12 +47,16 @@ IMPLS = ("auto", "pallas", "shift")
 
 
 def _filter_shift(z: jax.Array, w: jax.Array) -> jax.Array:
-    """``c_t = sum_j w[:, j] z_{t-(L-1)+j}`` by shifts of ``z`` (B, S, C)."""
+    """``c_t = sum_j w[:, j] z_{t-(L-1)+j}`` by shifts of ``z`` (B, S, C),
+    float32 with float32 taps.  The shifts are taken of ``z`` as it arrived
+    and each term is widened on its own: shifted float32 copies of bf16
+    ``(4, 8192, 8192)`` rows are 1 GiB apiece where XLA keeps one."""
+    f32 = jnp.float32
     S, L = z.shape[1], w.shape[1]
-    c = z * w[:, L - 1]
+    c = z.astype(f32) * w[:, L - 1]
     for back in range(1, min(L, S)):       # the tap ``back`` positions ago
         shifted = jnp.pad(z[:, :S - back], ((0, 0), (back, 0), (0, 0)))
-        c = c + shifted * w[:, L - 1 - back]
+        c = c + shifted.astype(f32) * w[:, L - 1 - back]
     return c
 
 
@@ -111,3 +122,27 @@ def short_conv_rows(bcu: jax.Array, w: jax.Array, impl: str = "auto",
     return jax.shard_map(lambda b, w: kernel(b, w, interpret),
                          mesh=get_mesh(), in_specs=(rows, P()),
                          out_specs=rows, check_vma=False)(bcu, w)
+
+
+def causal_conv_rows(x: jax.Array, w: jax.Array,
+                     activation: str = "silu") -> jax.Array:
+    """``act(c)`` with ``c_t = sum_j w[:, j] x_{t-(L-1)+j}`` of rows ``x``
+    (B, S, C) and taps ``w`` (C, L): the plain causal depthwise filter,
+    ``x`` before position 0 is 0 and the last tap is the current position.
+    ``activation`` is ``"silu"`` or ``None``.  ``L`` shifted multiply-adds
+    that XLA fuses, float32 inside, the backward by autodiff."""
+    from .pallas.spmd import note_dispatch
+
+    if activation not in ("silu", None):
+        raise ValueError(f"causal_conv_rows activation {activation!r}: "
+                         f"'silu' or None")
+    if x.ndim != 3 or w.ndim != 2 or x.shape[-1] != w.shape[0]:
+        raise ValueError(
+            f"causal_conv_rows takes (B, S, C) rows and (C, L) taps, got "
+            f"{x.shape} and {w.shape}")
+    note_dispatch("short_conv", "shift", "the ungated filter has this form "
+                                         "alone")
+    c = _filter_shift(x, w.astype(jnp.float32))
+    if activation == "silu":
+        c = jax.nn.silu(c)
+    return c.astype(x.dtype)

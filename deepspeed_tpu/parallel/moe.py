@@ -57,7 +57,8 @@ scores, ``bias_update_rate`` (its ``load_balance_coeff``) a selection bias
 that picks and does not weigh (the state leaf ``expert_bias``, moved by
 :func:`bias_update` from each step's counts and by no gradient),
 ``num_shared_experts`` a dense SwiGLU beside the routed sum, computed
-whole by every share.
+whole by every share.  ``shared_expert_gate`` (Qwen3-Next's) multiplies
+that SwiGLU by ``sigmoid(x . w_g)``, one scalar a token.
 """
 from __future__ import annotations
 
@@ -121,6 +122,9 @@ class MoEConfig:
     # experts every token runs, one dense SwiGLU of num_shared_experts x
     # the experts' width added to the routed sum; a share computes it whole
     num_shared_experts: int = 0
+    # the shared SwiGLU times sigmoid(x . w_g): a leaf of model_dim, one
+    # scalar a token (the Qwen3-Next family's shared_expert_gate)
+    shared_expert_gate: bool = False
 
     def __post_init__(self):
         if self.score_func not in ("softmax", "sigmoid"):
@@ -132,6 +136,9 @@ class MoEConfig:
                 f"routing (drop_tokens=False) only")
         if self.num_shared_experts and self.expert_act != "swiglu":
             raise NotImplementedError("shared experts are SwiGLU")
+        if self.shared_expert_gate and not self.num_shared_experts:
+            raise ValueError("shared_expert_gate without a shared expert "
+                             "(num_shared_experts)")
         if self.routed_experts is None:
             if self.first_expert:
                 raise ValueError("first_expert without routed_experts")
@@ -160,7 +167,8 @@ class MoEConfig:
         return tuple(f for f, off in (
             ("score_func", "softmax"), ("route_scale", 1.0),
             ("bias_update_rate", None), ("num_shared_experts", 0),
-            ("norm_topk_eps", 0.0)) if getattr(self, f) != off)
+            ("norm_topk_eps", 0.0), ("shared_expert_gate", False))
+            if getattr(self, f) != off)
 
 
 def _capacity(num_tokens: int, num_experts: int, factor: float, min_capacity: int,
@@ -604,12 +612,14 @@ class ExpertsMLP(nn.Module):
 
 class SharedExpert(nn.Module):
     """The SwiGLU every token runs beside its routed experts (AFMoE's
-    ``shared_experts``): dense leaves, whole on every share."""
+    ``shared_experts``): dense leaves, whole on every share.  ``gated``:
+    times ``sigmoid(x . token_gate)``, a scalar a token."""
 
     model_dim: int
     hidden_dim: int
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -622,7 +632,12 @@ class SharedExpert(nn.Module):
         gate = weight("gate", ("embed", "mlp"), (M, H))
         up = weight("up", ("embed", "mlp"), (M, H))
         down = weight("down", ("mlp", "embed"), (H, M))
-        return jnp.dot(nn.silu(jnp.dot(x, gate)) * jnp.dot(x, up), down)
+        out = jnp.dot(nn.silu(jnp.dot(x, gate)) * jnp.dot(x, up), down)
+        if self.gated:
+            token_gate = weight("token_gate", ("embed",), (M,))
+            score = jnp.dot(x, token_gate, preferred_element_type=jnp.float32)
+            out = (out * jax.nn.sigmoid(score)[:, None]).astype(out.dtype)
+        return out
 
 
 class MoELayer(nn.Module):
@@ -685,7 +700,8 @@ class MoELayer(nn.Module):
                     out = out + SharedExpert(
                         self.model_dim,
                         self.hidden_dim * cfg.num_shared_experts,
-                        dtype=self.dtype, name="shared")(x2)
+                        dtype=self.dtype, gated=cfg.shared_expert_gate,
+                        name="shared")(x2)
             # pairs no expert's group holds (an id outside 0..E-1): the
             # grouped matmul multiplies exactly counts.sum() rows, or, of
             # a share, those of its own experts; the pairs routed to

@@ -1,0 +1,254 @@
+"""The gated delta rule in chunks (``ops/gated_delta.py``, PR 48) against
+the recurrence one position a step (``benchmark/reference/qwen3next.py
+delta_rule``), forward and every gradient; the kernels of its sequential
+part (``ops/pallas/gated_delta.py``) in the interpreter against XLA's scan;
+the ungated causal filter (``ops/short_conv.py causal_conv_rows``) against
+a loop over its taps.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.harness.manifest import ROOT, load_module
+from deepspeed_tpu.ops import gated_delta as ops
+from deepspeed_tpu.ops.gated_delta import (_solve_unit_lower,
+                                           gated_delta_rule)
+from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+from deepspeed_tpu.ops.short_conv import causal_conv_rows
+from deepspeed_tpu.telemetry import get_registry
+
+reference = load_module(ROOT, "reference", "qwen3next")
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+def _inputs(B, S, Hk, Hv, d, seed=0, g_scale=0.5):
+    rng = np.random.default_rng(seed)
+
+    def unit(x, H):
+        x = x.reshape(B, S, H, d)
+        return (x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)).reshape(
+            B, S, H * d)
+
+    q = unit(rng.standard_normal((B, S, Hk * d)), Hk) * d ** -0.5
+    k = unit(rng.standard_normal((B, S, Hk * d)), Hk)
+    v = rng.standard_normal((B, S, Hv * d))
+    g = -np.abs(rng.standard_normal((B, S, Hv))) * g_scale
+    beta = 1.0 / (1.0 + np.exp(-rng.standard_normal((B, S, Hv))))
+    return tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
+
+
+def _recurrence(q, k, v, g, beta):
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    r = Hv // (k.shape[-1] // d)
+    q, k = (jnp.repeat(x.reshape(B, S, -1, d), r, axis=2) for x in (q, k))
+    return reference.delta_rule(q, k, v.reshape(B, S, Hv, d), g,
+                                beta).reshape(B, S, Hv * d)
+
+
+def _chunked(dtype, chunk):
+    def run(q, k, v, g, beta):
+        return gated_delta_rule(q.astype(dtype), k.astype(dtype),
+                                v.astype(dtype), g, beta,
+                                chunk=chunk).astype(jnp.float32)
+    return run
+
+
+def _rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("chunk,Hk,Hv,dtype,tol", [
+    (16, 2, 2, jnp.float32, 2e-5), (8, 1, 2, jnp.bfloat16, 2e-2),
+])
+def test_the_chunked_form_is_the_recurrence_forward_and_backward(
+        chunk, Hk, Hv, dtype, tol):
+    args = _inputs(2, 64, Hk, Hv, 8)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (2, 64, Hv * 8)), jnp.float32)
+    run = _chunked(dtype, chunk)
+    got = jax.jit(jax.value_and_grad(
+        lambda *a: (run(*a) * probe).sum(), range(5)))(*args)
+    want = jax.jit(jax.value_and_grad(
+        lambda *a: (_recurrence(*a) * probe).sum(), range(5)))(*args)
+    assert abs(float(got[0]) - float(want[0])) < tol * 64 * 8
+    for name, a, b in zip(NAMES, got[1], want[1]):
+        assert _rel(a, b) < tol, name
+
+
+RUN_64 = jax.jit(_chunked(jnp.float32, 64))
+
+
+def test_a_chunk_of_64_takes_the_solve_through_its_four_blocks():
+    args = _inputs(2, 128, 2, 2, 16)
+    assert _rel(RUN_64(*args), _recurrence(*args)) < 2e-5
+
+
+# one shape for the forward-only cases below: one executable a side
+SHAPE = (2, 64, 2, 4, 8)
+RUN = jax.jit(_chunked(jnp.float32, 16))
+RECURRENCE = jax.jit(_recurrence)
+
+
+@pytest.mark.parametrize("corner", ["g=0", "beta=1", "fast_decay"])
+def test_the_corners_of_the_gates(corner):
+    """No decay (the plain delta rule), a full write every token, and a
+    decay that underflows inside one chunk (exp(gamma) reaches 0: nothing
+    is divided by it)."""
+    q, k, v, g, beta = _inputs(
+        *SHAPE, g_scale=40.0 if corner == "fast_decay" else 0.5)
+    if corner == "g=0":
+        g = jnp.zeros_like(g)
+    if corner == "beta=1":
+        beta = jnp.ones_like(beta)
+    got = RUN(q, k, v, g, beta)
+    assert np.isfinite(np.asarray(got)).all()
+    assert _rel(got, RECURRENCE(q, k, v, g, beta)) < 2e-5
+    if corner == "fast_decay":
+        grads = jax.jit(jax.grad(
+            lambda *a: _chunked(jnp.float32, 16)(*a).sum(),
+            range(5)))(q, k, v, g, beta)
+        assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+
+
+def test_rows_are_independent_and_positions_causal():
+    args = _inputs(*SHAPE)
+    out = RUN(*args)
+    # a row alone gives what it gives beside another: no state crosses rows
+    swapped = RUN(*(x[::-1] for x in args))
+    np.testing.assert_allclose(out[1], swapped[0], atol=1e-6)
+    alone = RUN(*(jnp.concatenate([x[1:], x[1:] * 0.0 + 0.3]) for x in args))
+    np.testing.assert_allclose(out[1], alone[0], atol=1e-6)
+    # what follows position 40 changes nothing before it, across a chunk
+    # boundary too
+    later = [x.at[:, 40:].set(x[:, 40:] * 0.5 + 0.1) for x in args[:3]]
+    moved = RUN(*later, *args[3:])
+    np.testing.assert_array_equal(np.asarray(out[:, :40]),
+                                  np.asarray(moved[:, :40]))
+    assert np.abs(np.asarray(out[:, 40:] - moved[:, 40:])).max() > 1e-3
+
+
+def test_correlated_keys_do_not_cancel():
+    """Keys that are nearly one direction make ``A`` nearly all ones below
+    the diagonal, where a product form of ``(I + A)^-1`` loses everything
+    in float32; the substitution does not."""
+    q, k, v, g, beta = _inputs(2, 128, 2, 2, 16)
+    k = k * 0.05 + jnp.ones_like(k) / 4.0       # |k| ~ 1, all alike
+    g, beta = jnp.zeros_like(g), jnp.ones_like(beta) * 0.99
+    assert _rel(RUN_64(q, k, v, g, beta),
+                RECURRENCE(q, k, v, g, beta)) < 1e-3
+
+
+def test_the_triangular_solve_against_numpy():
+    rng = np.random.default_rng(0)
+    a = np.tril(rng.standard_normal((3, 32, 32)), -1).astype(np.float32)
+    rhs = rng.standard_normal((3, 32, 5)).astype(np.float32)
+    want = np.linalg.solve(np.eye(32) + a.astype(np.float64), rhs)
+    np.testing.assert_allclose(_solve_unit_lower(jnp.asarray(a),
+                                                 jnp.asarray(rhs)),
+                               want, rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("kw,said", [
+    (dict(chunk=48), "no whole chunks of 48"),
+    (dict(impl="triton"), "one of"),
+])
+def test_what_the_rule_refuses(kw, said):
+    with pytest.raises(ValueError, match=said):
+        gated_delta_rule(*_inputs(1, 64, 2, 2, 16), **kw)
+    q, k, v, g, beta = _inputs(1, 64, 2, 2, 16)
+    with pytest.raises(ValueError, match="no multiple of 2 key heads"):
+        gated_delta_rule(q, k, jnp.concatenate([v, v[..., :16]], -1),
+                         jnp.concatenate([g, g[..., :1]], -1),
+                         jnp.concatenate([beta, beta[..., :1]], -1))
+
+
+def test_the_kernels_in_the_interpreter_are_the_xla_scan():
+    """``impl="pallas"`` at one small shape, two rows of two heads-on-one-
+    key-head of 128 channels, 8 chunks of 16 (two grid steps a head-
+    sequence, so the state crosses a step's edge both ways): the forward is
+    XLA's scan to the bit, the five gradients to the rounding of the
+    state's cotangent to bf16 as an operand."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert kernel.supported(8, 16, 128, jnp.bfloat16) is None
+    args = [x.astype(jnp.bfloat16) if i < 3 else x
+            for i, x in enumerate(_inputs(2, 128, 1, 2, 128))]
+    probe = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (2, 128, 256)), jnp.float32)
+
+    def run(scan):      # past the plan: eight CPU devices and no mesh
+        return jax.jit(jax.value_and_grad(lambda *a: (ops._rule(
+            *a, 16, scan).astype(jnp.float32) * probe).sum(),
+            range(5)))(*args)
+
+    (want, want_g), (got, got_g) = run(ops._scan_xla), run(
+        functools.partial(kernel.scan_chunks, interpret=True))
+    assert float(got) == float(want)
+    for name, a, b in zip(NAMES, got_g, want_g):
+        assert _rel(a, b) < 5e-3, name
+
+
+@pytest.mark.parametrize("n,chunk,d,dtype,said", [
+    (8, 16, 128, jnp.float32, "operands of float32"),
+    (8, 16, 64, jnp.bfloat16, "head channels 64 are no multiple of 128"),
+    (8, 8, 128, jnp.bfloat16, "chunks of 8 positions"),
+    (6, 16, 128, jnp.bfloat16, "6 chunks are no whole groups of 4"),
+])
+def test_what_the_kernels_refuse_falls_to_xla_and_says_why(n, chunk, d, dtype,
+                                                           said):
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert said in kernel.supported(n, chunk, d, dtype)
+    args = [x.astype(dtype) if i < 3 else x
+            for i, x in enumerate(_inputs(1, n * chunk, 1, 1, d))]
+    with pytest.raises(NotImplementedError, match=said):
+        gated_delta_rule(*args, chunk=chunk, impl="pallas")
+    jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=chunk), *args)
+    assert any(site == "gated_delta" and impl == "xla" and said in why
+               for site, impl, why, _ in dispatch_report())
+
+
+def test_the_dispatch_and_the_chunks_are_booked():
+    def chunks():
+        family = get_registry().snapshot().get("gated_delta_chunks_total")
+        return {s["labels"]["pass"]: s["value"]
+                for s in (family["samples"] if family else ())}
+
+    before = chunks()
+    args = _inputs(1, 64, 1, 1, 16, seed=5)
+    jax.make_jaxpr(jax.grad(
+        lambda *a: gated_delta_rule(*a, chunk=16).sum()))(*args)
+    after = chunks()
+    assert after["fwd"] - before.get("fwd", 0) >= 4     # 64 / 16 a trace
+    assert after["bwd"] - before.get("bwd", 0) == 8     # again, and back
+    assert any(site == "gated_delta" and impl == "xla" and n
+               for site, impl, _, n in dispatch_report())
+
+
+@pytest.mark.parametrize("L,activation", [(4, "silu"), (3, None), (1, "silu")])
+def test_the_ungated_filter_against_a_loop_over_taps(L, activation):
+    rng = np.random.default_rng(L)
+    x = rng.standard_normal((2, 12, 8)).astype(np.float32)
+    w = rng.standard_normal((8, L)).astype(np.float32)
+    want = np.zeros_like(x)
+    for t in range(12):
+        for j in range(L):
+            src = t - (L - 1) + j       # the last tap is the current position
+            if src >= 0:
+                want[:, t] += w[:, j] * x[:, src]
+    if activation == "silu":
+        want = want / (1.0 + np.exp(-want))
+    got = causal_conv_rows(jnp.asarray(x), jnp.asarray(w), activation)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    bf = causal_conv_rows(jnp.asarray(x, jnp.bfloat16), jnp.asarray(w),
+                          activation)
+    assert bf.dtype == jnp.bfloat16 and _rel(bf, want) < 2e-2
+    with pytest.raises(ValueError, match="'silu' or None"):
+        causal_conv_rows(jnp.asarray(x), jnp.asarray(w), "gelu")
+    with pytest.raises(ValueError, match=r"\(B, S, C\) rows"):
+        causal_conv_rows(jnp.asarray(x), jnp.asarray(w[:4]))

@@ -340,8 +340,8 @@ def test_the_engine_trains_it_under_zero3_with_the_new_leaves_sharded():
           qk_rope_head_dim=8, v_head_dim=8, qk_norm=False),
      NotImplementedError, "latent attention with a conv layer"),
     (dict(conv_L_cache=0), ValueError, "at least one tap"),
-    (dict(layer_types=["conv", "linear_attention"] * 3), ValueError,
-     "'conv' are written"),
+    (dict(layer_types=["conv", "mamba"] * 3), ValueError,
+     "'conv' and 'linear_attention' are written"),
 ])
 def test_what_is_not_written_raises_by_name(kw, error, said):
     with pytest.raises(error, match=said):

@@ -186,7 +186,8 @@ def _reasons():
     ((256, 2048), 4, jnp.bfloat16, False, "every row holds a pair"),
     ((256, 2048), 4, jnp.float32, True, "rows of float32"),
     ((256, 2176), 4, jnp.bfloat16, True, "row width 2176 is no multiple"),
-    ((256, 2048), 3, jnp.bfloat16, True, "top-3 is no power of two"),
+    ((256, 2048), 17, jnp.bfloat16, True, "top-17 is not in 1..16"),
+    ((256, 2048), 3, jnp.bfloat16, True, "256 tokens x 3 are no whole"),
     ((192, 2048), 4, jnp.bfloat16, True, "192 tokens x 4 are no whole"),
 ])
 def test_a_refused_shape_falls_back_and_says_why(monkeypatch, shape, k, dtype,

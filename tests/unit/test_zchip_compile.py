@@ -947,3 +947,113 @@ def test_the_seventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert any((s, i) == ("short_conv", "pallas")
                and "rows 8192 x 3 x 2048, 3 taps; one device" in r
                for s, i, r in sites), sites
+
+
+def test_the_gated_delta_kernels_compile_at_the_eighth_cells_shape(one_chip):
+    """The two kernels of ``ops/pallas/gated_delta.py`` (PR 48) at
+    ``train-qwen3next-gdn-8k-1chip``'s shape, one row of the batch as the
+    rule walks it: 32 head-sequences of 128 chunks of 64 positions, heads
+    of 128 channels; four chunks a grid step, a ``(64, 64)`` bf16 block of
+    ``P`` beside ``(64, 128)`` ones, the state and its cotangent in a
+    ``(128, 128)`` float32 scratch, the forward writing the 64 KB state
+    entering each chunk for the backward to read."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    B, H, N, C, d = 1, 32, 128, 64, 128
+    assert kernel.supported(N, C, d, jnp.bfloat16) is None
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    wide = sd((B, H, N, C, d), jnp.bfloat16)
+    args = (sd((B, H, N, C, d), jnp.float32), wide,
+            sd((B, H, N, C, C), jnp.bfloat16), wide, wide,
+            sd((B, H, N), jnp.float32))
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: kernel.scan_chunks(*a).astype(jnp.float32).sum(),
+        range(6))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
+    assert f"f32[{B * H},{N},{d},{d}]" in text      # the states, kept once
+
+
+def test_flash_kernels_compile_at_the_eighth_cells_shape(one_chip):
+    """The flash forward and backward of ``train-qwen3next-gdn-8k-1chip``'s
+    one attention layer: 3 rows of 8192, 16 query heads on 2 key-value
+    heads of 256 channels, one head a 256-lane block, the backward's key
+    block cut to 256; k, v, dk and dv stay ``[3,8192,512]``."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (flash_attention,
+                                                          flash_lanes)
+
+    B, S, H, KV, D = 3, 8192, 16, 2, 256
+    assert flash_lanes(H, D).reason == "rows layout, 1 head a 256-lane block"
+
+    def loss(q, k, v):
+        with jax.named_scope("self_attn"), jax.named_scope("self_attn_full"):
+            out = flash_attention(q.reshape(B, S, H, D),
+                                  k.reshape(B, S, KV, D),
+                                  v.reshape(B, S, KV, D))
+        return out.astype(jnp.float32).sum()
+
+    wide = jax.ShapeDtypeStruct((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    narrow = jax.ShapeDtypeStruct((B, S, KV * D), jnp.bfloat16,
+                                  sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        wide, narrow, narrow).compile().as_text()
+    calls = {name: shape for name, (shape, op, _) in _entry(text).items()
+             if op == "custom-call" and shape.startswith("(")}
+    assert len(calls) == 2 and all(n.startswith("self_attn_full")
+                                   for n in calls)
+    fwd, bwd = sorted(calls.values(), key=lambda c: c.count("bf16["))
+    assert bwd.count(f"bf16[{B},{S},{KV * D}]") == 2            # dk, dv
+    assert f"[{B},{S},{KV},{H // KV},{D}]" not in text   # no k / v repeat
+
+
+@pytest.mark.slow
+def test_the_eighth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-qwen3next-gdn-8k-1chip`` (PR 48) as the benchmark builds it,
+    its whole train step compiled for the described chip: two kinds of
+    block in one unrolled stack of four; each Gated DeltaNet layer runs
+    ``gated_delta_fwd`` and ``gated_delta_bwd`` a row at a time (inside a
+    while loop: once in the text a pass); the attention layer's flash
+    kernels take grouped queries at 256 lanes a head; the rows move
+    through the row kernels at top-10; and what the step reserves stays
+    under the chip's 15.75 GiB.  Marked slow: the compile takes ~3 minutes
+    of the ~25 the tier-1 command may take, in the file the command runs
+    last; ``compile_said`` in the configuration file holds its reading."""
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-qwen3next-gdn-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.kinds.count("linear_attention")) == (3, 8192, 3)
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 4.0 < reserved < 15.75, reserved
+    text = compiled.as_text()
+    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
+    assert "self_attn_full" in text and "moe_rows_back" in text
+    sites = {(s, i) for s, i, _, n in dispatch_report() if n}
+    assert {("attention", "flash"), ("gated_delta", "pallas"),
+            ("moe_rows", "pallas")} <= sites, sites
