@@ -929,14 +929,19 @@ def kernel_flash_lanes_256():
 
 
 def kernel_gated_delta(time_it: bool = True):
-    """The gated delta rule at the eighth cell's shape, ``(4, 8192)`` rows,
-    16 key heads and 32 value heads of 128 channels, chunk 64 (PR 48): the
-    chunked form forward and ``jax.vjp`` (five cotangents) in bf16 against
+    """The gated delta rule at the eighth cell's shape, ``(3, 8192)`` rows,
+    16 key heads and 32 value heads of 128 channels, chunk 64: the fused
+    kernels (PR 49: what ``auto`` takes on the chip) and the XLA form,
+    forward and ``jax.vjp`` (five cotangents) in bf16, against
     ``benchmark/reference/qwen3next.py``'s recurrence one position a step
     in float32, every row of the batch held apart; decays drawn so that
-    states outlive chunks.  Timed forward and forward + backward, beside
-    the least the recurrence's own traffic allows
-    (``benchmark/flops_qwen3next.py``)."""
+    states outlive chunks.  The kernels' path is timed a layer, forward and
+    forward + backward, beside the least the recurrence's own traffic allows
+    (``benchmark/flops_qwen3next.py``), and its custom calls a row from a
+    profiler trace (``gated_delta_fwd`` runs once a row forward and once
+    more, for the states, in the backward)."""
+    import functools
+    import tempfile
     import time
 
     import jax
@@ -947,7 +952,7 @@ def kernel_gated_delta(time_it: bool = True):
     from deepspeed_tpu.ops.gated_delta import gated_delta_rule
 
     reference = load_module(ROOT, "reference", "qwen3next")
-    B, S, Hk, Hv, d = 4, 8192, 16, 32, 128
+    B, S, Hk, Hv, d = 3, 8192, 16, 32, 128
     ks = jax.random.split(jax.random.PRNGKey(48), 6)
 
     def unit(key, H, scale):
@@ -977,17 +982,20 @@ def kernel_gated_delta(time_it: bool = True):
 
     args = (q, k, v, g, beta)
     want = both(ref)(*args)
-    rule = lambda *a: gated_delta_rule(*a, chunk=64)        # noqa: E731
-    run = both(rule)
-    got = jax.block_until_ready(run(*args))
-    for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
-        a, b = (np.asarray(t, np.float32) for t in (a, b))
-        rows = [float(np.linalg.norm(a[r] - b[r]) / np.linalg.norm(b[r]))
-                for r in range(B)]
-        print(f"  gated_delta xla {n}: |chunked - recurrence| / |recurrence| "
-              f"worst of {B} rows {max(rows):.2e}", flush=True)
-        assert np.isfinite(a).all() and max(rows) <= TOL, (n, rows)
-    if time_it:
+    for impl in ("pallas", "xla"):
+        rule = functools.partial(gated_delta_rule, chunk=64, impl=impl)
+        run = both(rule)
+        got = jax.block_until_ready(run(*args))
+        for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+            a, b = (np.asarray(t, np.float32) for t in (a, b))
+            rows = [float(np.linalg.norm(a[r] - b[r]) / np.linalg.norm(b[r]))
+                    for r in range(B)]
+            print(f"  gated_delta {impl} {n}: |chunked - recurrence| / "
+                  f"|recurrence| worst of {B} rows {max(rows):.2e}",
+                  flush=True)
+            assert np.isfinite(a).all() and max(rows) <= TOL, (n, rows)
+        if not time_it:
+            continue
         from benchmark import flops_qwen3next as fl
 
         conf = {"linear_num_key_heads": Hk, "linear_num_value_heads": Hv,
@@ -1002,9 +1010,17 @@ def kernel_gated_delta(time_it: bool = True):
                 out = fn(*args)
             jax.block_until_ready(out)
             ms = (time.perf_counter() - t0) / 5 * 1e3
-            print(f"  gated_delta xla: {name} {ms:.3f} ms (least for the "
-                  f"recurrence's traffic forward + backward at 819 GB/s: "
-                  f"{least:.3f} ms)", flush=True)
+            print(f"  gated_delta {impl}: {name} {ms:.3f} ms a layer of {B} "
+                  f"rows (least for the recurrence's traffic forward + "
+                  f"backward at 819 GB/s: {least:.3f} ms)", flush=True)
+        if impl == "pallas":
+            out = tempfile.mkdtemp(prefix="gated_delta_trace_")
+            with jax.profiler.trace(out):
+                jax.block_until_ready(run(*args))
+            for op, ns in sorted(_traced_op_times(out).items()):
+                if "gated_delta" in op:
+                    print(f"  gated_delta pallas: {op} {len(ns)} calls, "
+                          f"{np.mean(ns) / 1e6:.3f} ms a row", flush=True)
 
 
 def kernel_flash_two_products():

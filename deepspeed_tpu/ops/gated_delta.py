@@ -21,13 +21,13 @@ gamma_j)`` for i >= j (all <= 1), ``S`` the state entering the chunk:
     O  = (Q * exp(gamma)) S + lower_incl(Q K^T * G) V'
     S <- exp(gamma_C) S + (K * exp(gamma_C - gamma))^T V'
 
-``A``, ``U``, ``W`` and ``Q K^T`` depend on no state and are computed for
-all chunks at once as batched products; the last three lines are a
-``lax.scan`` over the chunks of a row.  ``(I + A)^-1`` is never formed:
-``I + A`` is unit lower triangular and the system is solved by forward
-substitution (:func:`_solve_unit_lower`: rows inside blocks of 16, blocks
-by products), which is exact for any keys; the product form ``(I - A)(I +
-A^2)(I + A^4)...`` is the same matrix in exact arithmetic and cancels
+``A``, ``U``, ``W`` and ``Q K^T`` depend on no state; the last three lines
+are sequential over the chunks of a row.  ``(I + A)^-1`` is never a product
+over the whole chunk: ``I + A`` is unit lower triangular and the system is
+solved by block forward substitution over 16-row blocks (:func:`_solve_unit_lower`:
+rows inside a block by its nilpotent product, blocks by products), which is
+exact for any keys; the product form ``(I - A)(I + A^2)(I + A^4)...`` over
+the whole ``A`` is the same matrix in exact arithmetic and cancels
 catastrophically in float32 once keys correlate (powers of a 64 x 64 ``A``
 with entries near 1 reach 1e18).  Decays, ``gamma``, the solve and ``S``
 are float32; the operands of the large products have the type the call
@@ -35,23 +35,38 @@ arrived in (float32 accumulation).
 
 One ``custom_vjp``: the forward keeps its five inputs and nothing else, so
 a block's ``dots_saveable`` policy sees none of the inner products; the
-backward runs the chunked form again and transposes it (``jax.vjp``), which
-keeps the per-chunk states for the length of a row's backward (``N x Hv x
-d x d`` float32: 128 x 32 x 64 KB = 256 MiB at S 8192).  Both passes walk
-the batch a row at a time.
+backward walks the forward again and then back, which keeps the per-chunk
+states for the length of a row's backward (``N x Hv x d x d`` float32: 128
+x 32 x 64 KB = 256 MiB at S 8192).  Both passes walk the batch a row at a
+time (``lax.map``).
 
-``impl``: ``"xla"`` is the above with the last three lines a ``lax.scan``,
-on every backend and what the tests hold the kernels to.  ``"pallas"``
-(what ``"auto"`` takes on a TPU where the shape allows) hands those three
-lines, forward and backward, to ``ops/pallas/gated_delta.py`` (HLO custom
-calls ``gated_delta_fwd`` / ``gated_delta_bwd``, the state resident in
-VMEM across the chunk axis of the grid); what depends on no state stays
-XLA's batched products either way.  At ``(4, 8192, 32 heads of 128)`` on
-the v5e the scan as XLA's while loop read 93.6 ms forward and 211 forward +
-backward a layer (my chip run, PR 48; PERF.md section 6 has the kernels').
-``kernel_dispatch_total{site="gated_delta"}`` says what a call resolved to
-and why; ``gated_delta_chunks_total{pass}`` counts, at trace time, the
-chunks of one head-sequence a traced pass walks.
+``impl``: ``"xla"`` is the above as XLA's own program, on every backend and
+what the tests hold the kernels to: :func:`_prepare` makes what depends on
+no state for all chunks at once as batched products (``U``, ``W``, ``P``,
+the decayed ``q`` and ``k`` through HBM), :func:`_scan_xla` is a
+``lax.scan`` over the chunks, the backward is ``jax.vjp`` of both.
+``"pallas"`` (what ``"auto"`` takes on a TPU where the operands allow: bf16,
+heads of a multiple of 128 channels, chunks of 32 / 64 / 128 in whole
+groups of four, at most four value heads a key head, a mesh that
+``kernel_mesh_plan`` takes) hands the WHOLE chunked form to
+``ops/pallas/gated_delta.py`` (HLO custom calls ``gated_delta_fwd`` /
+``gated_delta_bwd``, PR 49): a grid step reads ``q``, ``k``, ``v`` in the
+layout the layer wrote, makes ``A``, the inverse, ``U``, ``W``, ``P`` and
+the decayed ``q`` and ``k`` in VMEM and scans, the states resident across
+the chunk axis; the backward runs ``gated_delta_fwd`` once more for the
+state entering each chunk, then ``gated_delta_bwd`` recomputes a chunk's
+preparation beside the transposed scan and transposes it in place.  XLA
+then prepares ``gamma`` (the cumulative sum of ``g`` a chunk) and ``beta``
+as ``(B, Hk, 8, S)`` float32 and turns ``dgamma`` into ``dg``, nothing
+else: :func:`_prepare` runs zero times.  At ``(4, 8192, 32 heads of 128)``
+on the v5e the scan as XLA's while loop read 93.6 ms forward and 211 forward
++ backward a layer (my chip run, PR 48; PERF.md section 6 has the
+kernels').  ``kernel_dispatch_total{site="gated_delta"}`` says what a call
+resolved to and why (the kernels' reason names the tile: ``128 chunks of 64
+x 16 key heads x 2 value heads of 128, fused; one device``);
+``gated_delta_chunks_total{pass}`` counts, at trace time, the chunks of one
+head-sequence a traced pass walks in sequence: ``fwd`` N, ``bwd`` 2 N (the
+forward's walk again, then the walk back), under either ``impl``.
 """
 from __future__ import annotations
 
@@ -184,20 +199,27 @@ def _scan_xla(u, w, p, qg, kd, g_last):
     return jnp.moveaxis(o, 0, 2)
 
 
-def _chunked(q, k, v, g, beta, chunk: int, scan=_scan_xla):
-    """The module's chunked form; shapes as :func:`gated_delta_rule`."""
+def _chunked(q, k, v, g, beta, chunk: int):
+    """The module's chunked form by XLA; shapes as :func:`gated_delta_rule`."""
     B, S, Hv = g.shape
-    o = scan(*_prepare(q, k, v, g, beta, chunk))    # (B, Hv, N, C, d)
+    o = _scan_xla(*_prepare(q, k, v, g, beta, chunk))   # (B, Hv, N, C, d)
     return o.transpose(0, 2, 3, 1, 4).reshape(B, S, v.shape[-1])
 
 
-def _row(chunk: int, scan):
-    """:func:`_chunked` of one row of the batch, without the batch axis."""
-    return lambda *xs: _chunked(*(x[None] for x in xs), chunk, scan)[0]
+def _row(chunk: int, fused):
+    """The chunked form of one row of the batch, without the batch axis:
+    XLA's where ``fused`` is None, else the kernels' (``fused`` their
+    ``interpret``)."""
+    if fused is None:
+        return lambda *xs: _chunked(*(x[None] for x in xs), chunk)[0]
+    from .pallas.gated_delta import forward
+
+    return lambda *xs: forward(*(x[None] for x in xs), chunk=chunk,
+                               interpret=fused)[0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _rule(q, k, v, g, beta, chunk, scan):
+def _rule(q, k, v, g, beta, chunk, fused):
     """A row of the batch at a time (``lax.map``), forward and backward: a
     row's heads and chunks fill the chip, and what the form keeps between
     its products - and, in the backward, for its transposition - is one
@@ -205,18 +227,25 @@ def _rule(q, k, v, g, beta, chunk, scan):
     step's peak).  The backward maps ``vjp`` itself: the transpose of a
     mapped forward would keep every row's residuals stacked."""
     _note_chunks("fwd", g.shape[1] // chunk)
-    return lax.map(lambda xs: _row(chunk, scan)(*xs), (q, k, v, g, beta))
+    return lax.map(lambda xs: _row(chunk, fused)(*xs), (q, k, v, g, beta))
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, scan):
-    return _rule(q, k, v, g, beta, chunk, scan), (q, k, v, g, beta)
+def _rule_fwd(q, k, v, g, beta, chunk, fused):
+    return _rule(q, k, v, g, beta, chunk, fused), (q, k, v, g, beta)
 
 
-def _rule_bwd(chunk, scan, res, do):
+def _rule_bwd(chunk, fused, res, do):
+    # the forward's walk again (XLA: under ``jax.vjp``; the kernels: for the
+    # states entering the chunks), then the walk back
     _note_chunks("bwd", 2 * (res[3].shape[1] // chunk))
 
     def one(xs):
-        _, pull = jax.vjp(_row(chunk, scan), *xs[:-1])
+        if fused is not None:
+            from .pallas.gated_delta import backward
+
+            return tuple(x[0] for x in backward(
+                *(x[None] for x in xs), chunk=chunk, interpret=fused))
+        _, pull = jax.vjp(_row(chunk, None), *xs[:-1])
         return pull(xs[-1])
 
     return lax.map(one, (*res, do))
@@ -225,7 +254,7 @@ def _rule_bwd(chunk, scan, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _plan(g, v, chunk: int, impl: str):
+def _plan(g, k, v, chunk: int, impl: str):
     """``(impl, reason, batch axes of a shard_map or None)``."""
     from .attention import on_tpu
     from .pallas import gated_delta as kernel
@@ -234,7 +263,9 @@ def _plan(g, v, chunk: int, impl: str):
     if impl == "xla":
         return impl, "impl='xla' asked for", None
     B, S, Hv = g.shape
-    reason = kernel.supported(S // chunk, chunk, v.shape[-1] // Hv, v.dtype)
+    d = v.shape[-1] // Hv
+    r = Hv // (k.shape[-1] // d)
+    reason = kernel.supported(S // chunk, chunk, d, v.dtype, r)
     if reason is None and impl == "auto" and not on_tpu():
         reason = "no TPU"
     verdict = axes = None
@@ -246,8 +277,8 @@ def _plan(g, v, chunk: int, impl: str):
         if impl == "pallas":
             raise NotImplementedError(f"gated_delta impl='pallas': {reason}")
         return "xla", reason, None
-    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hv} heads of "
-                      f"{v.shape[-1] // Hv}; "
+    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hv // r} key heads "
+                      f"x {r} value heads of {d}, fused; "
                       + ("one device" if verdict == "direct" else
                          f"shard_map over batch axes {axes}")), axes
 
@@ -281,16 +312,12 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     if S % chunk:
         raise ValueError(f"rows of {S} positions are no whole chunks of "
                          f"{chunk}")
-    impl, reason, axes = _plan(g, v, chunk, impl)
+    impl, reason, axes = _plan(g, k, v, chunk, impl)
     note_dispatch("gated_delta", impl, reason)
-    scan = _scan_xla
-    if impl == "pallas":
-        from .pallas.gated_delta import scan_chunks
-
-        scan = functools.partial(scan_chunks, interpret=interpret)
+    fused = interpret if impl == "pallas" else None
 
     def run(*args):
-        return _rule(*args, chunk, scan)
+        return _rule(*args, chunk, fused)
 
     if axes is not None:
         from jax.sharding import PartitionSpec as P

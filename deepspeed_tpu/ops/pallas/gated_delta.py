@@ -1,32 +1,74 @@
-"""The sequential part of the chunked gated delta rule (``ops/gated_delta.py``)
-as two Pallas kernels, the state resident in VMEM across the chunk axis of
-the grid, as the flash kernels keep their accumulators.
+"""The chunked gated delta rule (``ops/gated_delta.py``) as two Pallas
+kernels that make the chunks' preparation in VMEM beside the scan, from the
+layer's own ``q``, ``k``, ``v`` and gates: no ``U``, ``W``, ``P``, decayed
+``q`` / ``k`` or solve operand is ever an array in HBM.
 
-What depends on no state (``U``, ``W``, ``P = lower_incl(Q K^T * G)``, ``Q *
-exp(gamma)``, ``K * exp(gamma_C - gamma)``, ``exp(gamma_C)``; ``_prepare``
-there) is XLA's, batched over all chunks, and so is its transposition.  What
-is sequential, a head-sequence ``(b, h)`` and a chunk ``n`` with ``S`` the
-state entering it (keys x values, float32):
+**What XLA still prepares** (:func:`_gates`): ``gamma``, the cumulative sum
+of ``g`` inside each chunk, beside ``beta``, as ``(B, Hk, 8, S)`` float32
+(rows ``gamma`` of the key head's ``r = Hv / Hk`` value heads, then their
+``beta``; 32 B a position a key head), and in the backward the reverse
+cumulative sum ``dgamma -> dg`` on what comes back in the same layout.
 
-    V' = U - W S            O = Qg S + P V'            S <- gl S + Kd^T V'
+**A grid step** ``(row, key head, group)`` holds :data:`GROUP` chunks of
+``C`` positions of ONE key head and its ``r`` value heads, read in the
+layout the layer wrote (``q``, ``k`` ``(B, S, Hk d)`` blocked ``(1, GROUP C,
+d)`` at the head's lane offset, ``v`` and ``o`` ``(1, GROUP C, r d)``), the
+``r`` states (keys x values, float32) resident in a ``(r, d, d)`` scratch
+across the group axis, which is ``arbitrary``.  The gates arrive positions
+on lanes; their column form is a transpose of a padded ``(128, 128)`` tile
+in VMEM.  A chunk: ``K K^T`` and ``Q K^T`` once for the key head, then a
+value head
 
-runs here: ``gated_delta_fwd`` walks the chunks of a head-sequence in
-order, :data:`GROUP` chunks a grid step (a chunk is ~5 MFLOP of products,
-far less than a grid step's fixed cost), its state in a ``(d, d)`` float32
-scratch that is zeroed at a head-sequence's first step.  Under ``jax.vjp``
-it also writes the state ENTERING each chunk (``(BH, N, d, d)`` float32:
-64 KB a chunk a head), which ``gated_delta_bwd`` reads walking the chunks
-backwards with the state's cotangent resident the same way:
+    G  = exp(gamma_i - gamma_j), i >= j     (masked before the exp)
+    A  = strict_lower(beta_i K K^T G)
+    T  = (I + A)^-1                         (:func:`_inverses`, float32)
+    [U | W] = T beta [V | K exp(gamma)]     (float32, HIGHEST)
+    V' = U - W S      O = (Q exp(gamma)) S + (Q K^T G) V'
+    S <- exp(gamma_C) S + (K exp(gamma_C - gamma))^T V'
+
+``T`` is block forward substitution on the identity: the 16-row diagonal
+blocks of ``I + A`` inverted by ``(I + D^8)(I + D^4)(I + D^2)(I - D)``
+(exact: ``D^16 = 0``), then pairs of blocks joined, ``[[P, 0], [R, Q]]^-1 =
+[[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]``, 16 -> 32 -> 64 rows; every product
+float32 at ``HIGHEST`` like the solve of the XLA form, never a product form
+over the whole chunk.  What these products cost on the chip is the ROWS of
+their left operand (a pass of the array takes them one a cycle, whatever
+the other two sizes up to 128), and the left operands here are blocks on a
+diagonal: summed into one block's rows (:func:`_pack`) they go through in
+16 or 32 rows instead of 64, and a power times ``[factor | power]`` gives
+the next factor's share and the next power in one pass of 128 lanes: eight
+products of 16, 16, 16, 16, 16, 16, 32, 32 rows a chunk-head where the plain
+form streams ten of 64 (my chip runs, PR 49: 4.50 -> 2.67 ms a row forward),
+every result the same to the bit.  All of a grid step's chunk-heads go
+through a stage together (eight independent chains: 8.29 -> 4.50 ms).  The
+large products (those with ``d`` in them) take operands in the arrays' type
+(bf16; the state and ``V'`` rounded to it as operands) and sum in float32.
+
+``gated_delta_fwd`` writes ``o``; asked for ``states`` (the backward's first
+walk) it writes the state ENTERING each chunk instead, ``(B, Hv, N, d, d)``
+float32, and leaves ``Q`` out.  ``gated_delta_bwd`` walks the groups and
+their chunks backwards with the states' cotangents resident, makes each
+chunk's preparation again from the same inputs and the saved state, and
+transposes scan and preparation in place:
 
     dV' = P^T dO + Kd dS'   dP = dO V'^T   dQg = dO S^T   dKd = V' dS'^T
-    dU = dV'   dW = -dV' S^T   dgl = <dS', S>   dS = gl dS' + Qg^T dO - W^T dV'
+    dW = -dV' S^T   dgl = <dS', S>   dS = gl dS' + Qg^T dO - W^T dV'
+    dR = T^T [dV' | dW]     dA = -strict_lower(dR [U | W]^T)    (HIGHEST)
+    dv = beta dR_u          dbeta = <dR, [V | K e^gamma]> + <dA, K K^T G>
+    d(K K^T) = beta dA G    d(Q K^T) = dP G     E = A dA + (Q K^T G) dP
+    dgamma_i = sum_j E_ij - sum_j E_ji + e^gamma_i <beta dR_w, K>_i
+               + e^gamma_i <dQg, Q>_i - e^(gamma_C - gamma_i) <dKd, K>_i
+    dgamma_C += gl dgl + sum_i e^(gamma_C - gamma_i) <dKd, K>_i
 
-(``V'`` is recomputed from ``U``, ``W`` and ``S``: one product).  Operands
-of every product are in the arrays' own type (bf16 in a bf16 model; the
-state and ``V'`` are rounded to it as operands), sums float32.
+``dq`` and ``dk`` are summed over the key head's value heads inside the
+step.  VMEM a step: the double-buffered blocks (forward 1.3 MB with the
+saved states, backward 2.5 MB at ``C`` 64, ``d`` 128, ``r`` 2) and what the
+compiler keeps of eight chunk-heads' ``C x C`` and ``C x 2d`` float32 tiles
+(~1.5 MB); the limit asked is 64 MB.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -38,195 +80,442 @@ from jax.experimental.pallas import tpu as pltpu
 
 # chunks a grid step
 GROUP = 4
+# rows of a diagonal block that is inverted by its nilpotent product
+SOLVE_BLOCK = 16
+_SQUARINGS = (SOLVE_BLOCK - 1).bit_length() - 1     # D^2, D^4, D^8
+CHUNKS = (32, 64, 128)
+_LANES = 128
+_GATE_ROWS = 8
 _VMEM_LIMIT = 64 * 1024 * 1024
+_HI = lax.Precision.HIGHEST
+_F32 = jnp.float32
 
 
-def supported(n_chunks: int, chunk: int, d: int, dtype) -> Optional[str]:
+def supported(n_chunks: int, chunk: int, d: int, dtype,
+              heads_a_key: int = 1) -> Optional[str]:
     """``None`` where the kernels take ``n_chunks`` chunks of ``chunk``
-    positions and heads of ``d`` channels, else the reason they do not."""
+    positions, heads of ``d`` channels and ``heads_a_key`` value heads a key
+    head, else the reason they do not."""
     if dtype != jnp.bfloat16:
         return f"operands of {jnp.dtype(dtype).name}"
-    if d % 128:
-        return f"head channels {d} are no multiple of 128"
-    if chunk % 16:
-        return f"chunks of {chunk} positions are no whole bf16 tiles of 16"
+    if d % _LANES:
+        return f"head channels {d} are no multiple of {_LANES}"
+    if chunk not in CHUNKS:
+        return (f"chunks of {chunk} positions: the kernels take "
+                f"{', '.join(map(str, CHUNKS))} (a group of {GROUP} fills "
+                f"whole tiles of {_LANES} lanes, 16-row blocks join in pairs)")
     if n_chunks % GROUP:
         return f"{n_chunks} chunks are no whole groups of {GROUP}"
+    if 2 * heads_a_key > _GATE_ROWS:
+        return (f"{heads_a_key} value heads a key head: their gamma and beta "
+                f"fill more than one tile of {_GATE_ROWS} rows")
     return None
 
 
 def _nn(a, b):
-    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+    return jnp.dot(a, b, preferred_element_type=_F32)
 
 
 def _nt(a, b):          # a @ b.T
     return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=_F32)
 
 
 def _tn(a, b):          # a.T @ b
     return lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=_F32)
 
 
-def _fwd_kernel(gl_ref, u_ref, w_ref, p_ref, qg_ref, kd_ref, o_ref, *rest,
-                n_chunks, save):
-    state = rest[-1]
-    head, step = pl.program_id(0), pl.program_id(1)
-    cdt = w_ref.dtype
+def _hi(a, b, dims=((1,), (0,))):
+    """A float32 product at ``HIGHEST``: the solve's."""
+    return lax.dot_general(a, b, (dims, ((), ())), precision=_HI,
+                           preferred_element_type=_F32)
 
-    @pl.when(step == 0)
+
+class _Masks:
+    """The index masks of a ``(C, C)`` tile, made once a kernel body."""
+
+    def __init__(self, C: int):
+        row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
+        col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
+        self.lower, self.strict = row >= col, row > col
+        self.eye = (row == col).astype(_F32)
+        n = SOLVE_BLOCK
+        self.diagonal = (row // n) == (col // n)
+        # joining diagonal blocks of n rows in pairs: the block below the
+        # diagonal of each pair, and the second diagonal block of each
+        self.joins = []
+        while n < C:
+            pair = (row // (2 * n)) == (col // (2 * n))
+            second = (row // n) % 2 == 1
+            self.joins.append((n, pair & second & ((col // n) % 2 == 0),
+                               second & ((row // n) == (col // n))))
+            n *= 2
+        self.last_row = lax.broadcasted_iota(jnp.int32, (C, 1), 0) == C - 1
+
+
+def _pack(x, n: int):
+    """``(n, C)``: the sum of the ``n``-row blocks of ``x`` (C, C).  Where
+    each block of rows is zero outside a column block of its own, nothing
+    is lost, and as the LEFT operand of a product the ``n`` rows do the work
+    of all ``C`` (the array's time goes by the rows that stream through)."""
+    out = x[:n]
+    for s in range(n, x.shape[0], n):
+        out = out + x[s:s + n]
+    return out
+
+
+def _unpack(x, mask):
+    """``(C, C)`` of a packed ``(n, C)``: every ``n``-row block a copy,
+    kept where ``mask`` says that block's columns lie."""
+    C = x.shape[1]
+    return jnp.where(mask, jnp.concatenate([x] * (C // x.shape[0]), axis=0),
+                     0.0)
+
+
+def _inverses(mats, m: _Masks):
+    """``(I + a)^-1`` of each strictly lower triangular ``a`` (C, C) float32
+    of ``mats`` (the module's text), the independent chains written a stage
+    of all at a time: a product's result is some hundred cycles away, and
+    the next stage of the same chain can only wait for it."""
+    C, n = mats[0].shape[0], SOLVE_BLOCK
+    xs = [jnp.where(m.diagonal, -a, 0.0) for a in mats]          # -D
+    invs = [m.eye + x for x in xs]
+    packed = [_hi(_pack(x, n), x) for x in xs]                   # D^2
+    for s in range(_SQUARINGS):         # (I + D^2), (I + D^4), (I + D^8)
+        if s + 1 < _SQUARINGS:
+            # one product with the power on the left gives the next
+            # factor's share and the next power: 128 lanes of one pass
+            both = [_hi(p, jnp.concatenate(
+                [inv, _unpack(p, m.diagonal)], axis=1))
+                for p, inv in zip(packed, invs)]
+            invs = [inv + _unpack(b[:, :C], m.diagonal)
+                    for inv, b in zip(invs, both)]
+            packed = [b[:, C:] for b in both]
+        else:
+            invs = [inv + _unpack(_hi(p, inv), m.diagonal)
+                    for p, inv in zip(packed, invs)]
+    for n, below, second in m.joins:
+        lower = [_unpack(_hi(_pack(jnp.where(below, a, 0.0), n), inv), below)
+                 for a, inv in zip(mats, invs)]
+        invs = [inv - _unpack(_hi(_pack(jnp.where(second, inv, 0.0), n), b),
+                              below)
+                for inv, b in zip(invs, lower)]
+    return invs
+
+
+def _columns(rows):
+    """``(L, 128)`` of gate rows ``(8, L)``: lane ``c`` of position ``p`` is
+    row ``c`` at lane ``p``, a transpose a tile of 128 positions."""
+    pad = jnp.zeros((_LANES - rows.shape[0], _LANES), rows.dtype)
+    tiles = [jnp.concatenate([rows[:, t:t + _LANES], pad], axis=0).T
+             for t in range(0, rows.shape[1], _LANES)]
+    return jnp.concatenate(tiles, axis=0)
+
+
+def _last_row(gcol, d: int, m: _Masks):
+    """``exp(gamma_C)`` as a ``(1, d)`` row, to scale a state: Mosaic has no
+    broadcast of one element over sublanes and lanes at once, so the column
+    goes over the lanes first and its last row is picked by a sum."""
+    wide = jnp.broadcast_to(gcol, (gcol.shape[0], d))
+    return jnp.exp(jnp.sum(jnp.where(m.last_row, wide, 0.0), axis=0,
+                           keepdims=True))
+
+
+@dataclasses.dataclass
+class _Head:
+    """What of a chunk-head depends on no state and on no query, float32:
+    ``decay``, ``a``, ``e_gamma``, ``rhs`` (before ``beta``), ``t`` and ``x
+    = [U | W]`` of the module's text, beside its gates as columns ``gcol``
+    and ``bcol``, its index ``n`` among the key head's value heads and its
+    lanes ``on`` in the ``v`` / ``o`` blocks."""
+    n: int
+    on: slice
+    gcol: jax.Array
+    bcol: jax.Array
+    decay: jax.Array
+    a: jax.Array
+    e_gamma: jax.Array
+    rhs: jax.Array
+    t: Optional[jax.Array] = None
+    x: Optional[jax.Array] = None
+
+
+@dataclasses.dataclass
+class _Key:
+    """A chunk of the key head of a grid step: its rows ``at`` in the
+    blocks, ``k`` as it arrived and in float32, ``kk = K K^T``, and the
+    :class:`_Head` of each of its value heads."""
+    at: slice
+    k: jax.Array
+    kf: jax.Array
+    kk: jax.Array
+    heads: list
+
+
+def _prepare(k_ref, v_ref, gate_ref, m: _Masks, r: int):
+    """The :class:`_Key` of each chunk of a grid step, all of the step's
+    chunk-heads a stage at a time (:func:`_inverses`)."""
+    C, d = m.eye.shape[0], k_ref.shape[2]
+    rows = gate_ref[0, 0]
+    cols = _columns(rows)
+    chunks = []
+    for i in range(GROUP):
+        at = slice(i * C, (i + 1) * C)
+        k = k_ref[0, at, :]
+        key = _Key(at, k, k.astype(_F32), _nt(k, k), [])
+        for n in range(r):
+            on = slice(n * d, (n + 1) * d)
+            gcol, bcol = cols[at, n:n + 1], cols[at, r + n:r + n + 1]
+            decay = jnp.exp(jnp.where(m.lower, gcol - rows[n:n + 1, at],
+                                      -jnp.inf))
+            e_gamma = jnp.exp(gcol)
+            key.heads.append(_Head(
+                n, on, gcol, bcol, decay,
+                a=jnp.where(m.strict, bcol * key.kk * decay, 0.0),
+                e_gamma=e_gamma, rhs=jnp.concatenate(
+                    [v_ref[0, at, on].astype(_F32), key.kf * e_gamma],
+                    axis=1)))
+        chunks.append(key)
+    flat = [it for key in chunks for it in key.heads]
+    for it, t in zip(flat, _inverses([it.a for it in flat], m)):
+        it.t = t
+    for it in flat:
+        it.x = _hi(it.t, it.rhs * it.bcol)
+    return chunks
+
+
+def _fwd_kernel(*refs, chunk, r, states):
+    if states:
+        k_ref, v_ref, gate_ref, out_ref, state = refs
+    else:
+        q_ref, k_ref, v_ref, gate_ref, out_ref, state = refs
+    C, d = chunk, k_ref.shape[2]
+    cdt = k_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros(state.shape, state.dtype)
 
-    for i in range(GROUP):
-        s = state[...]
-        if save:
-            rest[0][0, i] = s
-        sb = s.astype(cdt)
-        v_new = u_ref[0, i] - _nn(w_ref[0, i], sb)
-        vb = v_new.astype(cdt)
-        o = _nn(qg_ref[0, i], sb) + _nn(p_ref[0, i], vb)
-        o_ref[0, i] = o.astype(o_ref.dtype)
-        gl = gl_ref[head * n_chunks + step * GROUP + i]
-        state[...] = gl * s + _tn(kd_ref[0, i], vb)
+    m = _Masks(C)
+    for i, key in enumerate(_prepare(k_ref, v_ref, gate_ref, m, r)):
+        if not states:
+            q = q_ref[0, key.at, :]
+            qk, qf = _nt(q, key.k), q.astype(_F32)
+        for it in key.heads:
+            g_last = it.gcol[C - 1:C, :]
+            s = state[it.n]
+            if states:
+                out_ref[0, it.n, i] = s
+            sb = s.astype(cdt)
+            vb = (it.x[:, :d] - _nn(it.x[:, d:].astype(cdt), sb)).astype(cdt)
+            if not states:
+                o = _nn((qf * it.e_gamma).astype(cdt), sb) \
+                    + _nn((qk * it.decay).astype(cdt), vb)
+                out_ref[0, key.at, it.on] = o.astype(out_ref.dtype)
+            kd = (key.kf * jnp.exp(g_last - it.gcol)).astype(cdt)
+            state[it.n] = _last_row(it.gcol, d, m) * s + _tn(kd, vb)
 
 
-def _bwd_kernel(gl_ref, u_ref, w_ref, p_ref, qg_ref, kd_ref, s_ref, do_ref,
-                du_ref, dw_ref, dp_ref, dqg_ref, dkd_ref, dgl_ref, dstate, *,
-                n_chunks):
-    head, step = pl.program_id(0), pl.program_id(1)
-    cdt = w_ref.dtype
-    last = n_chunks // GROUP - 1
+def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
+                dv_ref, dgate_ref, dstate, *, chunk, r):
+    C, d = chunk, k_ref.shape[2]
+    cdt = k_ref.dtype
 
-    @pl.when(step == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
 
+    m = _Masks(C)
+    chunks = _prepare(k_ref, v_ref, gate_ref, m, r)
+    lane = lax.broadcasted_iota(jnp.int32, (C, _LANES), 1)
+    dcols, drows = [None] * GROUP, []
     for i in reversed(range(GROUP)):
-        s = s_ref[0, i]
-        sb = s.astype(cdt)
-        ds1 = dstate[...]
-        ds1b = ds1.astype(cdt)
-        w, kd, do = w_ref[0, i], kd_ref[0, i], do_ref[0, i]
-        vb = (u_ref[0, i] - _nn(w, sb)).astype(cdt)
-        dv = _tn(p_ref[0, i], do) + _nn(kd, ds1b)
-        dvb = dv.astype(cdt)
-        du_ref[0, i] = dv
-        dw_ref[0, i] = (-_nt(dvb, sb)).astype(dw_ref.dtype)
-        dp_ref[0, i] = _nt(do, vb).astype(dp_ref.dtype)
-        dqg_ref[0, i] = _nt(do, sb).astype(dqg_ref.dtype)
-        dkd_ref[0, i] = _nt(vb, ds1b).astype(dkd_ref.dtype)
-        dgl_ref[0, i] = jnp.full(dgl_ref.shape[2:], jnp.sum(ds1 * s),
-                                 jnp.float32)
-        gl = gl_ref[head * n_chunks + (last - step) * GROUP + i]
-        dstate[...] = gl * ds1 + _tn(qg_ref[0, i], do) - _tn(w, dvb)
+        key = chunks[i]
+        at, k, kf, kk = key.at, key.k, key.kf, key.kk
+        q = q_ref[0, at, :]
+        qf, qk = q.astype(_F32), _nt(q, k)
+        dq = dk = jnp.zeros((C, d), _F32)
+        dkk = dqk = jnp.zeros((C, C), _F32)
+        dcol = jnp.zeros((C, _LANES), _F32)
+        for it in key.heads:
+            gcol, bcol, decay, x = it.gcol, it.bcol, it.decay, it.x
+            g_last = gcol[C - 1:C, :]
+            e_last, gl = jnp.exp(g_last - gcol), jnp.exp(g_last)
+            w = x[:, d:].astype(cdt)
+            pf = qk * decay
+            p, qg = pf.astype(cdt), (qf * it.e_gamma).astype(cdt)
+            kd = (kf * e_last).astype(cdt)
+            # the scan, transposed
+            s, ds1 = s_ref[0, it.n, i], dstate[it.n]
+            do = do_ref[0, at, it.on]
+            sb, ds1b = s.astype(cdt), ds1.astype(cdt)
+            vb = (x[:, :d] - _nn(w, sb)).astype(cdt)
+            dv_new = _tn(p, do) + _nn(kd, ds1b)
+            dvb = dv_new.astype(cdt)
+            dw, dp = -_nt(dvb, sb), _nt(do, vb)
+            dqg, dkd = _nt(do, sb), _nt(vb, ds1b)
+            dgl = jnp.sum(jnp.sum(ds1 * s, axis=1, keepdims=True), axis=0,
+                          keepdims=True)
+            dstate[it.n] = _last_row(gcol, d, m) * ds1 + _tn(qg, do) \
+                - _tn(w, dvb)
+            # the preparation, transposed
+            dr = _hi(it.t, jnp.concatenate([dv_new, dw], axis=1),
+                     ((0,), (0,)))
+            da = jnp.where(m.strict, -_hi(dr, x, ((1,), (1,))), 0.0)
+            dr_u, dr_w = dr[:, :d], dr[:, d:] * bcol
+            dv_ref[0, at, it.on] = (dr_u * bcol).astype(dv_ref.dtype)
+            dkd_k = dkd * kf * e_last
+            dk = dk + dr_w * it.e_gamma + dkd * e_last
+            dq = dq + dqg * it.e_gamma
+            weighed = da * decay
+            dkk, dqk = dkk + weighed * bcol, dqk + dp * decay
+            dbeta = jnp.sum(dr * it.rhs, axis=1, keepdims=True) \
+                + jnp.sum(weighed * kk, axis=1, keepdims=True)
+            e = it.a * da + pf * dp
+            decayed = jnp.sum(dkd_k, axis=1, keepdims=True)
+            dgamma = jnp.sum((dr_w * kf + dqg * qf) * it.e_gamma - dkd_k,
+                             axis=1, keepdims=True) \
+                + jnp.sum(e, axis=1, keepdims=True) \
+                + jnp.where(m.last_row, gl * dgl + jnp.sum(
+                    decayed, axis=0, keepdims=True), 0.0)
+            drows.append((it.n, at, -jnp.sum(e, axis=0, keepdims=True)))
+            dcol = jnp.where(lane == it.n, dgamma,
+                             jnp.where(lane == r + it.n, dbeta, dcol))
+        dkkb, dqkb = dkk.astype(cdt), dqk.astype(cdt)
+        dq_ref[0, at, :] = (dq + _nn(dqkb, k)).astype(dq_ref.dtype)
+        dk_ref[0, at, :] = (dk + _nn(dkkb, k) + _tn(dkkb, k)
+                            + _tn(dqkb, q)).astype(dk_ref.dtype)
+        dcols[i] = dcol
+    # the gates' cotangents go back positions on lanes, as the gates came
+    dcols = jnp.concatenate(dcols, axis=0)
+    for t in range(0, GROUP * C, _LANES):
+        dgate_ref[0, 0, :, t:t + _LANES] = dcols[t:t + _LANES].T[:_GATE_ROWS]
+    for h, at, row in drows:
+        dgate_ref[0, 0, h:h + 1, at] = dgate_ref[0, 0, h:h + 1, at] + row
 
 
-def _specs(C, d, index):
-    """Block specs of ``(u | w | qg | kd | o, p)`` under ``index``."""
-    return (pl.BlockSpec((1, GROUP, C, d), index),
-            pl.BlockSpec((1, GROUP, C, C), index))
+def _gates(g, beta, chunk: int, Hk: int):
+    """``(B, Hk, 8, S)`` float32: a key head's rows are the in-chunk
+    cumulative sums of ``g`` of its value heads, then their ``beta``, then
+    zeros."""
+    B, S, Hv = g.shape
+    gamma = jnp.cumsum(g.astype(_F32).reshape(B, S // chunk, chunk, Hv),
+                       axis=2).reshape(B, S, Hv)
+    r = Hv // Hk
+    both = jnp.stack([gamma, beta.astype(_F32)], axis=1)    # (B, 2, S, Hv)
+    both = both.reshape(B, 2, S, Hk, r).transpose(0, 3, 1, 4, 2)
+    return jnp.pad(both.reshape(B, Hk, 2 * r, S),
+                   ((0, 0), (0, 0), (0, _GATE_ROWS - 2 * r), (0, 0)))
+
+
+def _ungates(dgates, chunk: int, r: int):
+    """``(dg, dbeta)`` (B, S, Hv) of :func:`_gates`'s cotangent: the reverse
+    cumulative sum inside each chunk turns ``dgamma`` into ``dg``."""
+    B, Hk, _, S = dgates.shape
+    both = dgates[:, :, :2 * r].reshape(B, Hk, 2, r, S).transpose(
+        2, 0, 4, 1, 3).reshape(2, B, S, Hk * r)
+    dgamma = both[0].reshape(B, S // chunk, chunk, Hk * r)
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dgamma, 2), axis=2), 2)
+    return dg.reshape(B, S, Hk * r), both[1]
+
+
+def _specs(span: int, d: int, r: int, group):
+    """The blocks of a grid step ``(b, j, n)``, which holds group
+    ``group(n)`` of key head ``j``: ``(q | k, v | o, gates, states)``."""
+    return (pl.BlockSpec((1, span, d), lambda b, j, n: (b, group(n), j)),
+            pl.BlockSpec((1, span, r * d), lambda b, j, n: (b, group(n), j)),
+            pl.BlockSpec((1, 1, _GATE_ROWS, span),
+                         lambda b, j, n: (b, j, 0, group(n))),
+            pl.BlockSpec((1, r, GROUP, d, d),
+                         lambda b, j, n: (b, j, group(n), 0, 0)))
 
 
 def _params():
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary"),
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
-@functools.partial(jax.jit, static_argnames=("save", "interpret"))
-def forward(u, w, p, qg, kd, g_last, *, save: bool = False,
+def _products(C: int, d: int) -> int:
+    """Multiply-adds of a chunk-head's preparation as the array sees them:
+    the ``HIGHEST`` products at their six passes, the packed ones by the
+    rows that stream (:func:`_pack`)."""
+    n = SOLVE_BLOCK
+    rows = n * (_SQUARINGS + 1)
+    while n < C:
+        rows, n = rows + 2 * n, 2 * n
+    return 6 * (rows * C * C + 2 * C * C * d)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "states", "interpret"))
+def forward(q, k, v, g, beta, *, chunk: int, states: bool = False,
             interpret: bool = False):
-    """``o`` (BH, N, C, d) in ``w``'s type of ``u`` (float32), ``w``, ``qg``,
-    ``kd`` (BH, N, C, d), ``p`` (BH, N, C, C) and ``g_last`` (BH, N)
-    float32; with ``save`` also the states entering the chunks, (BH, N, d,
-    d) float32."""
-    BH, N, C, d = u.shape
-    wide, square = _specs(C, d, lambda h, j, gl: (h, j, 0, 0))
-    out_specs, out_shape = [wide], [jax.ShapeDtypeStruct(u.shape, w.dtype)]
-    if save:
-        out_specs.append(pl.BlockSpec((1, GROUP, d, d),
-                                      lambda h, j, gl: (h, j, 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((BH, N, d, d), jnp.float32))
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, n_chunks=N, save=save),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(BH, N // GROUP),
-            in_specs=[wide, wide, square, wide, wide], out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)]),
-        out_shape=out_shape, compiler_params=_params(),
-        cost_estimate=pl.CostEstimate(
-            flops=2 * BH * N * C * d * (3 * d + C), transcendentals=0,
-            bytes_accessed=BH * N * C * (14 * d + 2 * C)
-            + (4 * BH * N * d * d if save else 0)),
-        name="gated_delta_fwd", interpret=interpret,
-    )(g_last.reshape(-1), u, w, p, qg, kd)
-    return tuple(out) if save else out[0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def backward(u, w, p, qg, kd, g_last, states, do, *, interpret: bool = False):
-    """The cotangents ``(du, dw, dp, dqg, dkd, dg_last)`` of
-    :func:`forward`'s operands under ``do``, in the operands' types."""
-    BH, N, C, d = u.shape
-    steps = N // GROUP
-
-    def back(h, j, gl):
-        return (h, steps - 1 - j, 0, 0)
-
-    wide, square = _specs(C, d, back)
-    state = pl.BlockSpec((1, GROUP, d, d), back)
-    scalar = pl.BlockSpec((1, GROUP, 8, 128), back)
-    du, dw, dp, dqg, dkd, dgl = pl.pallas_call(
-        functools.partial(_bwd_kernel, n_chunks=N),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(BH, steps),
-            in_specs=[wide, wide, square, wide, wide, state, wide],
-            out_specs=[wide, wide, square, wide, wide, scalar],
-            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct(u.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(w.shape, w.dtype),
-                   jax.ShapeDtypeStruct(p.shape, p.dtype),
-                   jax.ShapeDtypeStruct(qg.shape, qg.dtype),
-                   jax.ShapeDtypeStruct(kd.shape, kd.dtype),
-                   jax.ShapeDtypeStruct((BH, N, 8, 128), jnp.float32)],
+    """``o`` (B, S, Hv*d) in ``v``'s type of ``q``, ``k`` (B, S, Hk*d), ``v``
+    (B, S, Hv*d), ``g`` and ``beta`` (B, S, Hv); with ``states`` the state
+    entering each chunk instead, (B, Hv, N, d, d) float32."""
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    Hk = k.shape[-1] // d
+    r, C, N = Hv // Hk, chunk, S // chunk
+    span = GROUP * C
+    key, values, gates, state = _specs(span, d, r, lambda n: n)
+    if states:
+        out_spec, out_shape = state, jax.ShapeDtypeStruct((B, Hv, N, d, d),
+                                                          _F32)
+    else:
+        out_spec, out_shape = values, jax.ShapeDtypeStruct(v.shape, v.dtype)
+    heads = B * Hv * N
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=C, r=r, states=states),
+        grid=(B, Hk, N // GROUP),
+        in_specs=([] if states else [key]) + [key, values, gates],
+        out_specs=out_spec, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32)],
         compiler_params=_params(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * BH * N * C * d * (7 * d + 2 * C), transcendentals=0,
-            bytes_accessed=BH * N * (C * (26 * d + 4 * C) + 4 * d * d)),
+            flops=2 * heads * (_products(C, d) + C * d * (3 * d + 2 * C)),
+            transcendentals=heads * C * (C + 2),
+            bytes_accessed=2 * B * S * d * (2 * Hk + 2 * Hv)
+            + 4 * B * Hk * _GATE_ROWS * S
+            + (4 * heads * d * d if states else 0)),
+        name="gated_delta_fwd", interpret=interpret,
+    )(*(() if states else (q,)), k, v, _gates(g, beta, C, Hk))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def backward(q, k, v, g, beta, do, *, chunk: int, interpret: bool = False):
+    """The cotangents ``(dq, dk, dv, dg, dbeta)`` of :func:`forward`'s
+    operands under ``do``, in the operands' types: the forward's walk again
+    for the states entering the chunks, then the walk back."""
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    Hk = k.shape[-1] // d
+    r, C, N = Hv // Hk, chunk, S // chunk
+    span, steps = GROUP * C, N // GROUP
+    saved = forward(q, k, v, g, beta, chunk=C, states=True,
+                    interpret=interpret)
+    key, values, gates, state = _specs(span, d, r,
+                                       lambda n: steps - 1 - n)
+    heads = B * Hv * N
+    dq, dk, dv, dgates = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=C, r=r),
+        grid=(B, Hk, steps),
+        in_specs=[key, key, values, gates, state, values],
+        out_specs=[key, key, values, gates],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((B, Hk, _GATE_ROWS, S), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32)],
+        compiler_params=_params(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * heads * (_products(C, d) + 12 * C * C * d
+                               + C * d * (8 * d + 6 * C)),
+            transcendentals=heads * C * (C + 2),
+            bytes_accessed=2 * B * S * d * (4 * Hk + 3 * Hv)
+            + 8 * B * Hk * _GATE_ROWS * S + 4 * heads * d * d),
         name="gated_delta_bwd", interpret=interpret,
-    )(g_last.reshape(-1), u, w, p, qg, kd, states, do)
-    return du, dw, dp, dqg, dkd, dgl[:, :, 0, 0]
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def scan_chunks(u, w, p, qg, kd, g_last, interpret=False):
-    """:func:`ops.gated_delta._scan_xla` by the kernels: operands (B, Hv, N,
-    C, .), ``o`` (B, Hv, N, C, d)."""
-    return _merged(u, w, p, qg, kd, g_last, interpret, False)[0]
-
-
-def _merged(u, w, p, qg, kd, g_last, interpret, save):
-    B, H = u.shape[:2]
-    flat = [x.reshape((B * H,) + x.shape[2:])
-            for x in (u, w, p, qg, kd, g_last)]
-    out = forward(*flat, save=save, interpret=interpret)
-    o, states = out if save else (out, None)
-    return o.reshape(u.shape[:2] + o.shape[1:]), flat, states
-
-
-def _scan_fwd(u, w, p, qg, kd, g_last, interpret):
-    o, flat, states = _merged(u, w, p, qg, kd, g_last, interpret, True)
-    return o, (flat, states)
-
-
-def _scan_bwd(interpret, res, do):
-    flat, states = res
-    B, H = do.shape[:2]
-    grads = backward(*flat, states, do.reshape((B * H,) + do.shape[2:]),
-                     interpret=interpret)
-    return tuple(g.reshape((B, H) + g.shape[1:]) for g in grads)
-
-
-scan_chunks.defvjp(_scan_fwd, _scan_bwd)
+    )(q, k, v, _gates(g, beta, C, Hk), saved, do)
+    dg, dbeta = _ungates(dgates, C, r)
+    return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)

@@ -1,12 +1,11 @@
 """The gated delta rule in chunks (``ops/gated_delta.py``, PR 48) against
 the recurrence one position a step (``benchmark/reference/qwen3next.py
-delta_rule``), forward and every gradient; the kernels of its sequential
-part (``ops/pallas/gated_delta.py``) in the interpreter against XLA's scan;
-the ungated causal filter (``ops/short_conv.py causal_conv_rows``) against
-a loop over its taps.
+delta_rule``), forward and every gradient; the fused kernels
+(``ops/pallas/gated_delta.py``, PR 49: preparation and scan in one body) in
+the interpreter against the XLA form and the recurrence; the ungated causal
+filter (``ops/short_conv.py causal_conv_rows``) against a loop over its
+taps.
 """
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,50 +166,140 @@ def test_what_the_rule_refuses(kw, said):
                          jnp.concatenate([beta, beta[..., :1]], -1))
 
 
-def test_the_kernels_in_the_interpreter_are_the_xla_scan():
-    """``impl="pallas"`` at one small shape, two rows of two heads-on-one-
-    key-head of 128 channels, 8 chunks of 16 (two grid steps a head-
-    sequence, so the state crosses a step's edge both ways): the forward is
-    XLA's scan to the bit, the five gradients to the rounding of the
-    state's cotangent to bf16 as an operand."""
+def _bf16(args):
+    return [x.astype(jnp.bfloat16) if i < 3 else x for i, x in enumerate(args)]
+
+
+def _fused(chunk, fused=True):
+    """Past the plan (eight CPU devices and no mesh): the rule itself, by the
+    kernels in the interpreter, or by XLA where ``fused`` is None."""
+    return jax.jit(lambda *a: ops._rule(*a, chunk, fused).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("Hk,Hv", [(2, 2), (1, 2)])
+def test_the_kernels_in_the_interpreter_are_the_xla_form(Hk, Hv):
+    """``impl="pallas"`` at one small shape, two rows, one or two value
+    heads a key head of 128 channels, 8 chunks of 32 (two grid steps a
+    head-sequence, so the states and their cotangents cross a step's edge
+    both ways; two 16-row blocks a chunk, joined once): the forward and the
+    five gradients are the XLA form's to the rounding of bf16 operands, and
+    as near the per-token recurrence as it is."""
     from deepspeed_tpu.ops.pallas import gated_delta as kernel
 
-    assert kernel.supported(8, 16, 128, jnp.bfloat16) is None
-    args = [x.astype(jnp.bfloat16) if i < 3 else x
-            for i, x in enumerate(_inputs(2, 128, 1, 2, 128))]
+    assert kernel.supported(8, 32, 128, jnp.bfloat16, Hv // Hk) is None
+    args = _bf16(_inputs(2, 256, Hk, Hv, 128))
     probe = jnp.asarray(np.random.default_rng(3).standard_normal(
-        (2, 128, 256)), jnp.float32)
+        (2, 256, Hv * 128)), jnp.float32)
 
-    def run(scan):      # past the plan: eight CPU devices and no mesh
-        return jax.jit(jax.value_and_grad(lambda *a: (ops._rule(
-            *a, 16, scan).astype(jnp.float32) * probe).sum(),
-            range(5)))(*args)
+    def run(rule):
+        return jax.jit(jax.value_and_grad(lambda *a: (
+            rule(*a).astype(jnp.float32) * probe).sum(), range(5)))
 
-    (want, want_g), (got, got_g) = run(ops._scan_xla), run(
-        functools.partial(kernel.scan_chunks, interpret=True))
-    assert float(got) == float(want)
-    for name, a, b in zip(NAMES, got_g, want_g):
-        assert _rel(a, b) < 5e-3, name
+    (want, want_g), (got, got_g) = (run(_fused(32, how))(*args)
+                                    for how in (None, True))
+    assert abs(float(got) - float(want)) < 2e-3 * abs(float(want))
+    exact = run(_recurrence)(*(x.astype(jnp.float32) for x in args))[1]
+    for name, a, b, c in zip(NAMES, got_g, want_g, exact):
+        assert _rel(a, b) < 6e-3, name
+        assert _rel(a, c) < max(6e-3, 1.2 * _rel(b, c)), name
 
 
-@pytest.mark.parametrize("n,chunk,d,dtype,said", [
-    (8, 16, 128, jnp.float32, "operands of float32"),
-    (8, 16, 64, jnp.bfloat16, "head channels 64 are no multiple of 128"),
-    (8, 8, 128, jnp.bfloat16, "chunks of 8 positions"),
-    (6, 16, 128, jnp.bfloat16, "6 chunks are no whole groups of 4"),
+@pytest.mark.parametrize("corner", ["g=0", "beta=1", "fast_decay",
+                                    "correlated_keys"])
+def test_the_corners_of_the_gates_in_the_kernels(corner):
+    """The gate corners of the XLA form above, and keys that are nearly one
+    direction, through the kernels at chunks of 64 (four 16-row blocks,
+    joined twice): the forward against the recurrence on the same bf16
+    inputs and against the XLA form; under a decay that underflows inside a chunk every cotangent
+    stays finite."""
+    q, k, v, g, beta = _inputs(
+        1, 256, 1, 2, 128, g_scale=40.0 if corner == "fast_decay" else 0.5)
+    if corner == "g=0":
+        g = jnp.zeros_like(g)
+    if corner == "beta=1":
+        beta = jnp.ones_like(beta)
+    if corner == "correlated_keys":
+        k = k * 0.05 + jnp.ones_like(k) / 128 ** 0.5
+        g, beta = jnp.zeros_like(g), jnp.ones_like(beta) * 0.99
+    args = _bf16((q, k, v, g, beta))
+    got = _fused(64)(*args)
+    assert np.isfinite(np.asarray(got)).all()
+    exact = [x.astype(jnp.float32) for x in args]
+    want, by_xla = _recurrence(*exact), _fused(64, None)(*args)
+    # bf16 operands: as near the recurrence as the XLA form is
+    assert _rel(got, want) < max(1e-2, 1.1 * _rel(by_xla, want))
+    assert _rel(got, by_xla) < 6e-3
+    if corner == "fast_decay":
+        grads = jax.jit(jax.grad(lambda *a: _fused(64)(*a).sum(),
+                                 range(5)))(*args)
+        assert all(np.isfinite(np.asarray(x, np.float32)).all()
+                   for x in grads)
+
+
+def test_rows_are_independent_in_the_kernels():
+    """A row beside another gives what it gives alone, to the bit: no state,
+    gate or block index of the kernels crosses rows or key heads."""
+    args = _bf16(_inputs(2, 128, 2, 4, 128, seed=7))
+    run = _fused(32)
+    out = run(*args)
+    swapped = run(*(x[::-1] for x in args))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(swapped[0]))
+    alone = run(*(x[1:] for x in args))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(alone[0]))
+    assert np.abs(np.asarray(out[0] - out[1])).max() > 1e-3
+
+
+def test_the_inverse_in_the_kernels_against_numpy():
+    """``(I + A)^-1`` as the kernels build it (16-row diagonal blocks by
+    their nilpotent product, then joined in pairs) for 2, 4 and 8 blocks."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    rng = np.random.default_rng(1)
+    for C in kernel.CHUNKS:
+        a = np.tril(rng.standard_normal((C, C)), -1).astype(np.float32) * 0.3
+        got = jax.jit(lambda a: kernel._inverses([a], kernel._Masks(C))[0])(a)
+        want = np.linalg.inv(np.eye(C) + a.astype(np.float64))
+        np.testing.assert_allclose(got, want, rtol=2e-3,
+                                   atol=2e-3 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("n,chunk,d,dtype,r,said", [
+    (8, 32, 128, jnp.float32, 1, "operands of float32"),
+    (8, 32, 64, jnp.bfloat16, 1, "head channels 64 are no multiple of 128"),
+    (8, 16, 128, jnp.bfloat16, 1, "chunks of 16 positions"),
+    (8, 256, 128, jnp.bfloat16, 1, "chunks of 256 positions"),
+    (6, 32, 128, jnp.bfloat16, 1, "6 chunks are no whole groups of 4"),
+    (8, 32, 128, jnp.bfloat16, 8, "8 value heads a key head"),
 ])
 def test_what_the_kernels_refuse_falls_to_xla_and_says_why(n, chunk, d, dtype,
-                                                           said):
+                                                           r, said):
     from deepspeed_tpu.ops.pallas import gated_delta as kernel
 
-    assert said in kernel.supported(n, chunk, d, dtype)
+    assert said in kernel.supported(n, chunk, d, dtype, r)
     args = [x.astype(dtype) if i < 3 else x
-            for i, x in enumerate(_inputs(1, n * chunk, 1, 1, d))]
+            for i, x in enumerate(_inputs(1, n * chunk, 1, r, d))]
     with pytest.raises(NotImplementedError, match=said):
         gated_delta_rule(*args, chunk=chunk, impl="pallas")
     jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=chunk), *args)
     assert any(site == "gated_delta" and impl == "xla" and said in why
                for site, impl, why, _ in dispatch_report())
+
+
+def test_the_plan_names_the_fused_form_and_its_tile(monkeypatch):
+    """On one device the plan takes the kernels and says what a grid step
+    holds; the reason is the counter's label."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        q, k, v, g, beta = _bf16(_inputs(1, 256, 1, 2, 128))
+        impl, reason, axes = ops._plan(g, k, v, 64, "pallas")
+    finally:
+        mesh_lib.set_mesh(None)
+    assert impl == "pallas"
+    assert reason.startswith(
+        "4 chunks of 64 x 1 key heads x 2 value heads of 128, fused; ")
 
 
 def test_the_dispatch_and_the_chunks_are_booked():

@@ -950,31 +950,41 @@ def test_the_seventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
 
 
 def test_the_gated_delta_kernels_compile_at_the_eighth_cells_shape(one_chip):
-    """The two kernels of ``ops/pallas/gated_delta.py`` (PR 48) at
+    """The two fused kernels of ``ops/pallas/gated_delta.py`` (PR 49) at
     ``train-qwen3next-gdn-8k-1chip``'s shape, one row of the batch as the
-    rule walks it: 32 head-sequences of 128 chunks of 64 positions, heads
-    of 128 channels; four chunks a grid step, a ``(64, 64)`` bf16 block of
-    ``P`` beside ``(64, 128)`` ones, the state and its cotangent in a
-    ``(128, 128)`` float32 scratch, the forward writing the 64 KB state
-    entering each chunk for the backward to read."""
+    rule walks it: 16 key heads x 2 value heads of 128 channels, 128 chunks
+    of 64 positions, four chunks a grid step, operands in the layout the
+    layer writes.  Forward and backward are three custom calls:
+    ``gated_delta_fwd`` for ``o``, ``gated_delta_fwd`` again for the 64 KB
+    state entering each chunk (written once, read once), ``gated_delta_bwd``.
+    What the preparation makes stays in VMEM: the compiled text holds no
+    ``U`` / ``W`` / solve operand (``f32[.., 64, 256]`` or ``[.., 64, 128]``
+    a chunk) and no ``P`` or ``A`` (``[.., 64, 64]``)."""
+    import re
+
+    from deepspeed_tpu.ops import gated_delta as ops
     from deepspeed_tpu.ops.pallas import gated_delta as kernel
 
-    B, H, N, C, d = 1, 32, 128, 64, 128
-    assert kernel.supported(N, C, d, jnp.bfloat16) is None
+    B, S, Hk, Hv, d, C = 1, 8192, 16, 32, 128, 64
+    N = S // C
+    assert kernel.supported(N, C, d, jnp.bfloat16, Hv // Hk) is None
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    wide = sd((B, H, N, C, d), jnp.bfloat16)
-    args = (sd((B, H, N, C, d), jnp.float32), wide,
-            sd((B, H, N, C, C), jnp.bfloat16), wide, wide,
-            sd((B, H, N), jnp.float32))
+    args = (sd((B, S, Hk * d), jnp.bfloat16), sd((B, S, Hk * d), jnp.bfloat16),
+            sd((B, S, Hv * d), jnp.bfloat16), sd((B, S, Hv), jnp.float32),
+            sd((B, S, Hv), jnp.float32))
     text = jax.jit(jax.value_and_grad(
-        lambda *a: kernel.scan_chunks(*a).astype(jnp.float32).sum(),
-        range(6))).lower(*args).compile().as_text()
-    assert text.count("tpu_custom_call") == 2
-    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
-    assert f"f32[{B * H},{N},{d},{d}]" in text      # the states, kept once
+        lambda *a: ops._rule(*a, C, False).astype(jnp.float32).sum(),
+        range(5))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(re.findall(r"gated_delta_fwd[.\d]* = ", text)) == 2
+    assert len(re.findall(r"gated_delta_bwd[.\d]* = ", text)) == 1
+    states = f"f32[{B},{Hv},{N},{d},{d}]"
+    assert len(re.findall(r"gated_delta_fwd[.\d]* = " + re.escape(states),
+                          text)) == 1                   # kept once
+    assert not re.search(rf"\[[\d,]*{N},{C},({C}|{d}|{2 * d})\]", text)
 
 
 def test_flash_kernels_compile_at_the_eighth_cells_shape(one_chip):
