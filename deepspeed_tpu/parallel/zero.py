@@ -238,6 +238,55 @@ def scatter_grads(grads, mesh, grad_specs):
                 x, NamedSharding(mesh, s)), grads, grad_specs)
 
 
+def required_recv_bytes(abstract_leaves, spec_tree, mesh, dtype=None,
+                        axis: str = "fsdp") -> int:
+    """Bytes ONE device has to receive to hold (a gather) or to have
+    reduced (a scatter) every leaf that ``spec_tree`` shards over ``axis``,
+    ONCE, by the partition alone: the leaf's bytes in ``dtype`` (its own
+    where ``None`` or not floating), less what the other axes of its spec
+    keep elsewhere, times ``(n - 1) / n``.  What the compiled step really
+    moves is in its executable (``telemetry/device_scopes.py
+    collective_ledger``); the ratio of the two is how many times it moves
+    it."""
+    n = mesh.shape[axis]
+    total = 0
+    leaves = jax.tree_util.tree_leaves(abstract_leaves)
+    specs = jax.tree_util.tree_leaves(spec_tree,
+                                      is_leaf=lambda x: isinstance(x, P))
+    for leaf, spec in zip(leaves, specs):
+        axes = [a for entry in spec if entry is not None
+                for a in (entry if isinstance(entry, tuple) else (entry,))]
+        if axis not in axes:
+            continue
+        narrowed = dtype is not None and jax.numpy.issubdtype(
+            leaf.dtype, jax.numpy.floating)
+        itemsize = np.dtype(dtype if narrowed else leaf.dtype).itemsize
+        held_elsewhere = _axis_size(mesh, [a for a in axes if a != axis])
+        total += int(np.prod(leaf.shape)) * itemsize // held_elsewhere
+    return total * (n - 1) // n
+
+
+def record_required_recv(abstract_params, param_specs, abstract_grads,
+                         grad_specs, mesh, compute_dtype, grad_dtype,
+                         registry=None) -> None:
+    """Book ``zero_required_recv_bytes{what=gather|scatter}`` when the
+    engine places its state: one gather pass of the sharded parameters in
+    the compute type, one scatter of the sharded gradients in the type
+    they are reduced in.  Nothing on a mesh whose ``fsdp`` axis is 1."""
+    if mesh.shape["fsdp"] <= 1:
+        return
+    from ..telemetry import get_registry
+
+    gauge = (registry or get_registry()).gauge(
+        "zero_required_recv_bytes",
+        "bytes one device must receive for ONE pass over the ZeRO "
+        "partition", labelnames=("what",))
+    gauge.labels(what="gather").set(float(required_recv_bytes(
+        abstract_params, param_specs, mesh, compute_dtype)))
+    gauge.labels(what="scatter").set(float(required_recv_bytes(
+        abstract_grads, grad_specs, mesh, grad_dtype)))
+
+
 def validate_stage_mesh(zero_stage: int, mesh) -> None:
     if zero_stage >= 1 and mesh.shape["fsdp"] == 1 and mesh.shape["dp"] > 1:
         logger.warning(

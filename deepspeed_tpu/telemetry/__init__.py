@@ -21,7 +21,8 @@ Seven coordinated surfaces replacing the reference's scattered
   Measured cost with the Chrome recorder off: 3-5 us a span.
   ``device_span`` stamps HLO metadata inside compiled code
   (``loss_head``, ``grad_clip``, ``optimizer``, ``embed``,
-  ``zero/scatter``, the pipeline stages).
+  ``zero/scatter``, the pipeline stages; a ZeRO gather has no scope of
+  its own: its time is booked under the module that consumes the shard).
 - :mod:`.recompile` — watchdog over jitted hot loops: a call that made
   an executable signs its arguments, is counted, and warns when a warm
   loop recompiled (a call that made none costs two counter reads); and
@@ -34,7 +35,12 @@ Seven coordinated surfaces replacing the reference's scattered
   books its memory when it is made.
 - :mod:`.device_scopes` — device time by the program's own scopes: the
   instruction → ``op_name`` map of a kept executable and the reduction
-  of a profiler trace over it (``engine.profile_device_scopes``).
+  of a profiler trace over it (``engine.profile_device_scopes``); and,
+  from the same parse, the ledger of the executable's collectives
+  (``collective_ledger``: op, group, bytes a device receives, consumer
+  scope and pass), booked for every executable of more than one device
+  as ``step_collectives`` / ``step_collective_recv_bytes{site, op}``
+  beside ``parallel/zero.py``'s ``zero_required_recv_bytes{what}``.
 - :mod:`.exporter` — per-rank HTTP server (``/metrics`` Prometheus
   text, ``/healthz`` liveness JSON, ``/statusz`` operational JSON,
   ``/alertz``, ``/tracez``);

@@ -33,9 +33,10 @@ it observes: such a site makes its executable itself, through
 ``fn.trace(*args).lower().compile()`` (one trace, one lowering and one
 build-or-fetch a signature, under the persistent-cache key a plain call
 has), calls the kept ``Compiled`` from then on, and books its memory
-once (:func:`memory.record_compiled`).  Only a call the kept executable
-refuses (its own argument check: ``TypeError`` / ``ValueError`` before
-anything is donated) is signed; it then goes to an executable the site
+once (:func:`memory.record_compiled`) and, where it runs on more than one
+device, its collectives (:func:`device_scopes.record_collectives`).  Only
+a call the kept executable refuses (its own argument check: ``TypeError``
+/ ``ValueError`` before anything is donated) is signed; it then goes to an executable the site
 made earlier for that signature, or makes a new one under the rules
 above.  ``handle.compiled`` is the executable that runs.
 
@@ -63,6 +64,7 @@ import time
 from typing import Any, Optional
 
 from ..utils.logging import logger
+from . import device_scopes as _device_scopes
 from . import goodput as _goodput
 from . import memory as _memory
 from . import registry as _registry
@@ -226,6 +228,8 @@ class _Staged(_Watched):
         self._dog._on_compile(self, args, kwargs, sig)
         _memory.record_compiled(compiled, site=self._name,
                                 registry=self._dog._registry)
+        _device_scopes.record_collectives(compiled, site=self._name,
+                                          registry=self._dog._registry)
         return out
 
 

@@ -35,9 +35,13 @@ there is no "off".
 
 :func:`device_span` is the other half: a ``jax.named_scope`` for use
 INSIDE traced code (``loss_head``, ``grad_clip``, ``optimizer``,
-``zero/gather``, ``zero/scatter``, the pipeline stages).  The name lands
-in the HLO's ``op_name`` metadata, where a device trace attributes
-operations to it; it changes no instruction.
+``zero/scatter``, the pipeline stages).  The name lands in the HLO's
+``op_name`` metadata, where a device trace attributes operations to it;
+it changes no instruction.  There is no ``zero/gather``: no line of the
+program gathers a ZeRO shard, the partitioner places each all-gather at
+the operation that consumes it, so a gather's time is booked under that
+module's scope (``h_3/attn``), and ``device_scopes.collective_ledger``
+lists the gathers of a compiled step by it.
 """
 from __future__ import annotations
 
