@@ -1257,15 +1257,20 @@ def kernel_qk_rows():
 
 
 def kernel_short_conv(time_it: bool = True):
-    """The gated short convolution at the seventh cell's shape, ``(4, 8192,
-    3 x 2048)`` rows and 3 taps (PR 45): the Pallas row kernels, forward and
-    ``jax.vjp`` (d rows and d taps), against ``benchmark/reference/lfm2.py``'s
-    explicit loop over taps in float32; every row of the batch held apart (a
-    halo read from the row before shows in rows 1.. only) and the first and
-    last positions of each row alone (where the halo is all of the filter's
-    reach).  XLA's shifted form is held to the same reference, and both are
-    timed, forward + backward: which one ``auto`` takes is a reading
-    (PERF.md section 6)."""
+    """The causal depthwise filter of ``ops/short_conv.py`` in both its forms,
+    each at its cell's shape: the gated one at the seventh cell's ``(4, 8192,
+    3 x 2048)`` rows and 3 taps (PR 45), the ungated one with silu at the
+    eighth cell's ``(3, 8192, 8192)`` rows and 4 taps (PR 51).  The Pallas
+    row kernels, forward and ``jax.vjp`` (d rows and d taps), against
+    ``benchmark/reference/lfm2.py``'s explicit loop over taps in float32;
+    every row of the batch held apart (a halo read from the row before shows
+    in rows 1.. only) and the first and last positions of each row alone
+    (where the halo is all of the filter's reach).  XLA's shifted form is
+    held to the same reference, and both are timed, forward + backward,
+    against the least the HBM rate allows for the vectors the filter has to
+    move; the kernels' custom calls also one by one from a profiler trace:
+    which one ``auto`` takes is a reading (PERF.md section 6)."""
+    import tempfile
     import time
 
     import jax
@@ -1273,53 +1278,77 @@ def kernel_short_conv(time_it: bool = True):
     import numpy as np
 
     from benchmark.harness.manifest import ROOT, load_module
-    from deepspeed_tpu.ops.short_conv import short_conv_rows
+    from deepspeed_tpu.ops.short_conv import causal_conv_rows, short_conv_rows
 
     reference = load_module(ROOT, "reference", "lfm2")
-    B, S, C, L = 4, 8192, 2048, 3
-    ks = jax.random.split(jax.random.PRNGKey(45), 3)
-    bcu = jax.random.normal(ks[0], (B, S, 3 * C), jnp.float32).astype(
-        jnp.bfloat16)
-    w = jax.random.normal(ks[1], (C, L), jnp.float32)
-    dy = jax.random.normal(ks[2], (B, S, C), jnp.float32).astype(jnp.bfloat16)
+    f, loop = reference._f32, reference._filter
 
-    def ref(bcu, w):
-        f = reference._f32
+    def gated_ref(bcu, w):
+        C = w.shape[0]
         z = f(bcu[..., :C]) * f(bcu[..., 2 * C:])
-        return f(bcu[..., C:2 * C]) * reference._filter(z, f(w), None, None)
+        return f(bcu[..., C:2 * C]) * loop(z, f(w), None, None)
 
-    def both(fn):
-        def run(bcu, w):
-            out, vjp = jax.vjp(fn, bcu, w)
-            return (out,) + vjp(dy.astype(out.dtype))
-        return jax.jit(run)
+    # name, the op, its reference, (B, S, C, L), thirds a row, vectors moved
+    forms = (("short_conv", short_conv_rows, gated_ref,
+              (4, 8192, 2048, 3), 3, 11),
+             ("causal_conv silu",
+              lambda x, w, impl: causal_conv_rows(x, w, "silu", impl),
+              lambda x, w: jax.nn.silu(loop(f(x), f(w), None, None)),
+              (3, 8192, 8192, 4), 1, 5))
+    for form, op, ref, (B, S, C, L), thirds, vectors in forms:
+        ks = jax.random.split(jax.random.PRNGKey(45), 3)
+        rows = jax.random.normal(ks[0], (B, S, thirds * C),
+                                 jnp.float32).astype(jnp.bfloat16)
+        w = jax.random.normal(ks[1], (C, L), jnp.float32)
+        dy = jax.random.normal(ks[2], (B, S, C),
+                               jnp.float32).astype(jnp.bfloat16)
 
-    want = both(ref)(bcu, w)
-    edge = list(range(8)) + list(range(S - 8, S))
-    for impl in ("pallas", "shift"):
-        run = both(lambda bcu, w: short_conv_rows(bcu, w, impl))
-        got = jax.block_until_ready(run(bcu, w))
-        for n, g, r in zip(("y", "d rows", "d taps"), got, want):
-            name = f"short_conv {impl} {n}"
-            if g.ndim != 3:
-                _check_close(name, g, r)
+        def both(fn):
+            def run(rows, w):
+                out, vjp = jax.vjp(fn, rows, w)
+                return (out,) + vjp(dy.astype(out.dtype))
+            return jax.jit(run)
+
+        want = [np.asarray(t, np.float32) for t in both(ref)(rows, w)]
+        edge = list(range(8)) + list(range(S - 8, S))
+        for impl in ("pallas", "shift"):
+            run = both(lambda rows, w: op(rows, w, impl))
+            got = jax.block_until_ready(run(rows, w))
+            for n, g, r in zip(("y", "d rows", "d taps"), got, want):
+                name = f"{form} {impl} {n}"
+                if g.ndim != 3:
+                    _check_close(name, g, r)
+                    continue
+                g = np.asarray(g, np.float32)
+                worst = np.abs(g - r).max(axis=(1, 2)) \
+                    / np.abs(r).max(axis=(1, 2))
+                ends = np.abs(g - r)[:, edge].max() / np.abs(r)[:, edge].max()
+                print(f"  {name}: worst of {B} rows {worst.max():.2e}, first "
+                      f"and last 8 positions {ends:.2e}", flush=True)
+                assert np.isfinite(g).all() \
+                    and max(worst.max(), ends) <= TOL, (name, worst, ends)
+            if not time_it:
                 continue
-            g, r = (np.asarray(t, np.float32) for t in (g, r))
-            worst = np.abs(g - r).max(axis=(1, 2)) / np.abs(r).max(axis=(1, 2))
-            ends = np.abs(g - r)[:, edge].max() / np.abs(r)[:, edge].max()
-            print(f"  {name}: worst of {B} rows {worst.max():.2e}, first and "
-                  f"last 8 positions {ends:.2e}", flush=True)
-            assert np.isfinite(g).all() and max(worst.max(), ends) <= TOL, (
-                name, worst, ends)
-        if time_it:
             t0 = time.perf_counter()
             for _ in range(20):
-                out = run(bcu, w)
+                out = run(rows, w)
             jax.block_until_ready(out)
             ms = (time.perf_counter() - t0) / 20 * 1e3
-            print(f"  short_conv {impl}: forward + backward {ms:.3f} ms "
-                  f"(least for 11 vectors of bf16 at 819 GB/s: "
-                  f"{11 * B * S * C * 2 / 819e9 * 1e3:.3f} ms)", flush=True)
+            print(f"  {form} {impl}: forward + backward {ms:.3f} ms "
+                  f"(least for {vectors} vectors of bf16 at 819 GB/s: "
+                  f"{vectors * B * S * C * 2 / 819e9 * 1e3:.3f} ms)",
+                  flush=True)
+            if impl == "pallas":
+                out = tempfile.mkdtemp(prefix="short_conv_trace_")
+                with jax.profiler.trace(out):
+                    for _ in range(5):
+                        jax.block_until_ready(run(rows, w))
+                for call, ns in sorted(_traced_op_times(out).items()):
+                    if "conv_rows" in call:
+                        print(f"  {form} pallas: {call} {len(ns)} calls, "
+                              f"{np.mean(ns) / 1e6:.3f} ms a call",
+                              flush=True)
+        del want, got, rows, dy
 
 
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,

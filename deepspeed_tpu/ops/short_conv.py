@@ -34,9 +34,14 @@ which form a call resolved to, and why.
 :func:`causal_conv_rows` is the same filter without the gates, with an
 activation after it: what a Gated DeltaNet layer (``model_type:
 qwen3_next``) runs over the channels of ``[q ; k ; v]`` before its
-recurrence.  It has the shifted form alone (the Pallas body is written for
-the gated form; ROADMAP.md A has the chip's reading that would ask for
-more).
+recurrence.  It has the same two forms behind the same plan (PR 51): the
+row kernels ``causal_conv_rows`` / ``causal_conv_rows_back`` where a TPU and
+the shape allow, ``shift`` (the backward by autodiff) elsewhere and for the
+tests.  At ``(3, 8192, 8192)``, 4 taps, silu, on the v5e, forward +
+backward: the kernels 3.88 ms, ``shift`` 13.98, the HBM rate's least for the
+five vectors 2.46 (my chip run, PR 51).  The counter's ``pallas`` row of
+such a call starts ``ungated`` and gives rows, channels, taps and the
+activation.
 """
 from __future__ import annotations
 
@@ -67,8 +72,9 @@ def _shift(bcu: jax.Array, w: jax.Array) -> jax.Array:
     return (cg * _filter_shift(bg * u, w.astype(f32))).astype(bcu.dtype)
 
 
-def _plan(bcu, w, impl):
-    """``(impl, reason, batch axes of a shard_map or None)``."""
+def _plan(x, w, impl, form):
+    """``(impl, reason, batch axes of a shard_map or None)`` for rows ``x``
+    (B, S, width) and what a ``pallas`` row says of the call, ``form``."""
     from .attention import on_tpu
     from .pallas import short_conv as kernel
     from .pallas.spmd import kernel_mesh_plan
@@ -76,21 +82,43 @@ def _plan(bcu, w, impl):
     if impl == "shift":
         return impl, "impl='shift' asked for", None
     C, L = w.shape
-    reason = kernel.supported(bcu.shape[1], C, L, bcu.dtype)
+    reason = kernel.supported(x.shape[1], C, L, x.dtype)
     if reason is None and impl == "auto" and not on_tpu():
         reason = "no TPU"
     verdict = axes = None
     if reason is None:
-        verdict, axes = kernel_mesh_plan(bcu.shape[0])
+        verdict, axes = kernel_mesh_plan(x.shape[0])
         if verdict is None:
             reason = "kernel_mesh_plan refused the mesh"
     if reason is not None:
         if impl == "pallas":
             raise NotImplementedError(f"short_conv impl='pallas': {reason}")
         return "shift", reason, None
-    return "pallas", (f"rows {bcu.shape[1]} x 3 x {C}, {L} taps; "
+    return "pallas", (f"{form}; "
                       + ("one device" if verdict == "direct" else
                          f"shard_map over batch axes {axes}")), axes
+
+
+def _rows(kernel, x, w, impl, form, shift):
+    """``kernel(x, w)`` on this rank's rows of the batch where
+    :func:`_plan` takes the Pallas form, else ``shift(x, w)``; booked."""
+    from .pallas.spmd import note_dispatch
+
+    if impl not in IMPLS:
+        raise ValueError(f"short_conv impl {impl!r}: one of {IMPLS}")
+    impl, reason, axes = _plan(x, w, impl, form)
+    note_dispatch("short_conv", impl, reason)
+    if impl != "pallas":
+        return shift(x, w)
+    if axes is None:
+        return kernel(x, w)
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    rows = P(axes if axes else None, None, None)
+    return jax.shard_map(kernel, mesh=get_mesh(), in_specs=(rows, P()),
+                         out_specs=rows, check_vma=False)(x, w)
 
 
 def short_conv_rows(bcu: jax.Array, w: jax.Array, impl: str = "auto",
@@ -98,40 +126,26 @@ def short_conv_rows(bcu: jax.Array, w: jax.Array, impl: str = "auto",
     """``Cg * filter(Bg * u)`` (B, S, C) of ``bcu`` (B, S, 3C) = ``[Bg ; Cg
     ; u]``, a projection's output as it lies, with the taps ``w`` (C, L);
     see the module's text."""
-    from .pallas.spmd import note_dispatch
+    from .pallas.short_conv import short_conv_rows as kernel
 
-    if impl not in IMPLS:
-        raise ValueError(f"short_conv impl {impl!r}: one of {IMPLS}")
     if bcu.ndim != 3 or w.ndim != 2 or bcu.shape[-1] != 3 * w.shape[0]:
         raise ValueError(
             f"short_conv_rows takes (B, S, 3C) rows and (C, L) taps, got "
             f"{bcu.shape} and {w.shape}")
-    impl, reason, axes = _plan(bcu, w, impl)
-    note_dispatch("short_conv", impl, reason)
-    if impl != "pallas":
-        return _shift(bcu, w)
-    from .pallas.short_conv import short_conv_rows as kernel
-
-    if axes is None:
-        return kernel(bcu, w, interpret)
-    from jax.sharding import PartitionSpec as P
-
-    from ..comm.mesh import get_mesh
-
-    rows = P(axes if axes else None, None, None)
-    return jax.shard_map(lambda b, w: kernel(b, w, interpret),
-                         mesh=get_mesh(), in_specs=(rows, P()),
-                         out_specs=rows, check_vma=False)(bcu, w)
+    C, L = w.shape
+    return _rows(lambda b, w: kernel(b, w, interpret), bcu, w, impl,
+                 f"rows {bcu.shape[1]} x 3 x {C}, {L} taps", _shift)
 
 
-def causal_conv_rows(x: jax.Array, w: jax.Array,
-                     activation: str = "silu") -> jax.Array:
+def causal_conv_rows(x: jax.Array, w: jax.Array, activation: str = "silu",
+                     impl: str = "auto", interpret: bool = False
+                     ) -> jax.Array:
     """``act(c)`` with ``c_t = sum_j w[:, j] x_{t-(L-1)+j}`` of rows ``x``
     (B, S, C) and taps ``w`` (C, L): the plain causal depthwise filter,
     ``x`` before position 0 is 0 and the last tap is the current position.
-    ``activation`` is ``"silu"`` or ``None``.  ``L`` shifted multiply-adds
-    that XLA fuses, float32 inside, the backward by autodiff."""
-    from .pallas.spmd import note_dispatch
+    ``activation`` is ``"silu"`` or ``None``.  Float32 inside, one rounding
+    to ``x``'s type; see the module's text."""
+    from .pallas.short_conv import causal_conv_rows as kernel
 
     if activation not in ("silu", None):
         raise ValueError(f"causal_conv_rows activation {activation!r}: "
@@ -140,9 +154,14 @@ def causal_conv_rows(x: jax.Array, w: jax.Array,
         raise ValueError(
             f"causal_conv_rows takes (B, S, C) rows and (C, L) taps, got "
             f"{x.shape} and {w.shape}")
-    note_dispatch("short_conv", "shift", "the ungated filter has this form "
-                                         "alone")
-    c = _filter_shift(x, w.astype(jnp.float32))
-    if activation == "silu":
-        c = jax.nn.silu(c)
-    return c.astype(x.dtype)
+
+    def shift(x, w):
+        c = _filter_shift(x, w.astype(jnp.float32))
+        if activation == "silu":
+            c = jax.nn.silu(c)
+        return c.astype(x.dtype)
+
+    C, L = w.shape
+    return _rows(lambda x, w: kernel(x, w, activation, interpret), x, w, impl,
+                 f"ungated, rows {x.shape[1]} x {C}, {L} taps, "
+                 f"{activation or 'no activation'}", shift)

@@ -3,8 +3,13 @@
 taps in the XLA form and both types; rows of a batch independent; nothing
 after position t reaches the output at t; the Pallas row kernels
 (``ops/pallas/short_conv.py``, interpreted) over rows that span blocks, so
-that both halos are read; what is refused; what is booked.
+that both halos are read, in the gated form and (PR 51) the ungated one with
+its activation; the gated kernels' jaxpr held to the parent's; what is
+refused; what is booked.
 """
+import hashlib
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +17,8 @@ import pytest
 
 from benchmark.harness.manifest import ROOT, load_module
 from deepspeed_tpu.ops.pallas import short_conv as kernel
-from deepspeed_tpu.ops.short_conv import IMPLS, short_conv_rows
+from deepspeed_tpu.ops.short_conv import (IMPLS, causal_conv_rows,
+                                          short_conv_rows)
 
 reference = load_module(ROOT, "reference", "lfm2")
 B, S, C = 3, 40, 16
@@ -104,14 +110,34 @@ def test_a_sequence_shorter_than_the_filter():
 
 
 # ----------------------------------------------------------------------
-# the row kernels, interpreted: rows of two and three blocks
+# the row kernels, interpreted: rows of two and three blocks, in the gated
+# form (LFM2's ``[Bg ; Cg ; u]``) and the ungated one with its activation
+# (the Gated DeltaNet's ``[q ; k ; v]``, PR 51): one body, ``FORMS`` apart
 # ----------------------------------------------------------------------
-def _rows(L, S, dtype=jnp.float32, C=kernel.LANES, B=2):
+def _plain(activation):
+    """The interpreter makes the approximate reciprocal of the kernels'
+    sigmoid by rounding to bfloat16, so the Newton step after it leaves 2^-17
+    of the value where the chip leaves what the division does (``chip_smoke.py
+    kernel_short_conv`` holds that): 20 times the room with silu."""
+    act = jax.nn.silu if activation == "silu" else (lambda c: c)
+    f = reference._f32
+    return (lambda x, w: kernel.causal_conv_rows(x, w, activation, True),
+            lambda x, w: act(reference._filter(f(x), f(w), None, None)), 1,
+            20 if activation else 1)
+
+
+# form: (the interpreted kernels, the float32 loop, thirds of a row, room)
+FORMS = {"gated": (lambda bcu, w: kernel.short_conv_rows(bcu, w, True),
+                   lambda bcu, w: _loop(*_thirds(bcu), w), 3, 1),
+         "silu": _plain("silu"), "plain": _plain(None)}
+
+
+def _rows(L, S, dtype=jnp.float32, C=kernel.LANES, B=2, thirds=3):
     rng = np.random.default_rng([7, L, S])
-    bcu = jnp.asarray(rng.normal(0, 1, (B, S, 3 * C)), dtype)
+    rows = jnp.asarray(rng.normal(0, 1, (B, S, thirds * C)), dtype)
     w = jnp.asarray(rng.normal(0, 1, (C, L)), jnp.float32)
     probe = jnp.asarray(rng.normal(0, 1, (B, S, C)), jnp.float32)
-    return bcu, w, probe
+    return rows, w, probe
 
 
 def _thirds(bcu):
@@ -119,45 +145,53 @@ def _thirds(bcu):
     return bcu[..., :C], bcu[..., C:2 * C], bcu[..., 2 * C:]
 
 
-@pytest.mark.parametrize("L,S", [(2, 2 * kernel.BLOCK), (3, 3 * kernel.BLOCK),
-                                 (4, 2 * kernel.BLOCK)])
-def test_the_row_kernels_match_the_loop_across_blocks(L, S):
-    bcu, w, probe = _rows(L, S)
-    got = kernel.short_conv_rows(bcu, w, True)
-    want = _loop(*_thirds(bcu), w)
-    np.testing.assert_allclose(got, want, atol=2e-5)
+@pytest.mark.parametrize("form,L,S", [
+    ("gated", 2, 2 * kernel.BLOCK), ("gated", 3, 3 * kernel.BLOCK),
+    ("gated", 4, 2 * kernel.BLOCK), ("silu", 4, 3 * kernel.BLOCK),
+    ("plain", 3, 2 * kernel.BLOCK), ("silu", 1, 2 * kernel.BLOCK)])
+def test_the_row_kernels_match_the_loop_across_blocks(form, L, S):
+    run, loop, thirds, room = FORMS[form]
+    rows, w, probe = _rows(L, S, thirds=thirds)
+    got = run(rows, w)
+    want = loop(rows, w)
+    np.testing.assert_allclose(got, want, atol=2e-5 * room)
     edges = [kernel.BLOCK - 1, kernel.BLOCK, kernel.BLOCK + 1, 0, S - 1]
-    np.testing.assert_allclose(got[:, edges], want[:, edges], atol=2e-5)
-    grads = jax.grad(lambda b, w: (kernel.short_conv_rows(
-        b, w, True) * probe).sum(), (0, 1))(bcu, w)
-    ref = jax.grad(lambda b, w: (_loop(*_thirds(b), w) * probe).sum(),
-                   (0, 1))(bcu, w)
-    assert grads[0].shape == bcu.shape and grads[1].shape == w.shape
+    np.testing.assert_allclose(got[:, edges], want[:, edges],
+                               atol=2e-5 * room)
+    grads = jax.grad(lambda r, w: (run(r, w) * probe).sum(), (0, 1))(rows, w)
+    ref = jax.grad(lambda r, w: (loop(r, w) * probe).sum(), (0, 1))(rows, w)
+    assert grads[0].shape == rows.shape and grads[1].shape == w.shape
     for name, g, r in zip(("d rows", "d taps"), grads, ref):
         err = np.abs(np.asarray(g - r)).max() / np.abs(np.asarray(r)).max()
-        assert err < 1e-5, (name, err)
-    # a row alone is the row in the batch: no halo crosses rows
-    np.testing.assert_array_equal(
-        kernel.short_conv_rows(bcu[1:], w, True), got[1:])
+        assert err < 1e-5 * room, (name, err)
+    # a row alone is the row in the batch: no halo crosses rows; and what
+    # lies after a block's second position changes nothing up to it
+    t = kernel.BLOCK + 1
+    alone = run(rows[1:].at[:, t + 1:].add(1.0), w)
+    np.testing.assert_array_equal(alone[:, :t + 1], got[1:, :t + 1])
+    assert np.abs(np.asarray(alone - got[1:])[:, t + 1]).max() > 1e-3
 
 
-def test_the_row_kernels_round_once_from_float32():
-    bcu, w, probe = _rows(3, 2 * kernel.BLOCK, jnp.bfloat16)
-    got = kernel.short_conv_rows(bcu, w, True)
+@pytest.mark.parametrize("form,L", [("gated", 3), ("silu", 4)])
+def test_the_row_kernels_round_once_from_float32(form, L):
+    run, loop, thirds, _ = FORMS[form]
+    rows, w, probe = _rows(L, 2 * kernel.BLOCK, jnp.bfloat16, thirds=thirds)
+    got = run(rows, w)
     assert got.dtype == jnp.bfloat16
-    want = _loop(*_thirds(bcu), w)
+    want = loop(rows, w)
     err = np.linalg.norm(np.asarray(got, np.float32) - want) \
         / np.linalg.norm(want)
     assert err < 4e-3, err
-    other = np.asarray(short_conv_rows(bcu, w, "shift"), np.float32)
+    shift = short_conv_rows(rows, w, "shift") if form == "gated" \
+        else causal_conv_rows(rows, w, form, "shift")
+    other = np.asarray(shift, np.float32)
     assert np.linalg.norm(np.asarray(got, np.float32) - other) \
         / np.linalg.norm(other) < 4e-3     # the sum's order, one ulp
-    db, dw = jax.grad(lambda b, w: (kernel.short_conv_rows(
-        b, w, True).astype(jnp.float32) * probe).sum(),
-        (0, 1))(bcu, w)
+    db, dw = jax.grad(lambda r, w: (run(r, w).astype(jnp.float32)
+                                    * probe).sum(), (0, 1))(rows, w)
     assert db.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
-    rb, rw = jax.grad(lambda b, w: (_loop(*_thirds(b), w) * probe).sum(),
-                      (0, 1))(bcu.astype(jnp.float32), w)
+    rb, rw = jax.grad(lambda r, w: (loop(r, w) * probe).sum(),
+                      (0, 1))(rows.astype(jnp.float32), w)
     assert np.linalg.norm(np.asarray(db, np.float32) - rb) \
         / np.linalg.norm(rb) < 4e-3
     # the cotangent arrives rounded to bf16; the sum over positions is float32
@@ -166,15 +200,60 @@ def test_the_row_kernels_round_once_from_float32():
 
 def test_which_shapes_the_kernels_take():
     assert kernel.supported(8192, 2048, 3, jnp.bfloat16) is None
+    assert kernel.supported(8192, 8192, 4, jnp.bfloat16) is None
     assert "multiple of 512" in kernel.supported(8192, 640, 3, jnp.bfloat16)
     assert "multiple of 256" in kernel.supported(100, 512, 3, jnp.bfloat16)
     assert "taps" in kernel.supported(512, 512, 9, jnp.float32)
     assert "float16" in kernel.supported(512, 512, 3, jnp.float16)
+    # the gated rows go whole; the ungated channels by blocks that divide them
+    assert kernel._grid(4, 8192, 2048, True) == ((4, 32), 2048)
+    assert kernel._grid(3, 8192, 8192, False) == ((3, 32, 4), 2048)
+    assert kernel._grid(2, 512, 2560, False) == ((2, 2, 5), 512)
+
+
+def gated_trace():
+    """The jaxpr of the gated kernels, forward and ``jax.vjp``, over two lane
+    blocks and two row blocks, with each block's index map (the jaxpr's text
+    leaves them out) and without source positions."""
+    B, S, C, L = 2, 2 * kernel.BLOCK, 2 * kernel.LANES, 3
+    shapes = (jax.ShapeDtypeStruct((B, S, 3 * C), jnp.bfloat16),
+              jax.ShapeDtypeStruct((C, L), jnp.float32),
+              jax.ShapeDtypeStruct((B, S, C), jnp.bfloat16))
+
+    def run(bcu, w, dy):
+        out, vjp = jax.vjp(lambda b, w: kernel.short_conv_rows(b, w, False),
+                           bcu, w)
+        return (out,) + vjp(dy)
+
+    closed = jax.make_jaxpr(run)(*shapes)
+    text = [str(closed)]
+    for eqn in closed.jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            text += [str(m.index_map_jaxpr)
+                     for m in eqn.params["grid_mapping"].block_mappings]
+    return re.sub(r" at /\S+:\d+", "", "\n".join(text))
+
+
+def test_the_gated_kernels_trace_to_the_parents_jaxpr():
+    """LFM2's instance of the body is what commit ``bf2df90`` (the parent of
+    PR 51, which gave the body its ungated form) traces: equations, shapes,
+    block specs and index maps, scratch, names, cost estimate.  The digest
+    was computed there with this function.  ONE body holds both forms; the
+    gates and the activation are static options of it."""
+    text = gated_trace()
+    assert text.count("pallas_call") == 2 and "short_conv_rows_back" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "3d28cad5c145f5c766c701915a6af75daba90e89831b4c189c9212aab1afb7d9")
+
+
+def _booked():
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    return {(i, r): n for s, i, r, n in dispatch_report()
+            if s == "short_conv"}
 
 
 def test_what_is_refused_and_what_is_booked():
-    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
-
     bg, cg, u, w = _operands(3)
     bcu = jnp.concatenate([bg, cg, u], -1)
     with pytest.raises(ValueError, match="one of"):
@@ -187,16 +266,52 @@ def test_what_is_refused_and_what_is_booked():
         short_conv_rows(bcu, w, impl="pallas")      # asked for, not a shape
     assert IMPLS == ("auto", "pallas", "shift")
 
-    def booked():
-        return {(i, r): n for s, i, r, n in dispatch_report()
-                if s == "short_conv"}
-
-    before = booked()
+    before = _booked()
     short_conv_rows(bcu, w)                         # a shape it cannot take
     big = _rows(3, kernel.BLOCK)
     short_conv_rows(big[0], big[1])                 # one it can: no TPU here
     short_conv_rows(bcu, w, "shift")
-    after = booked()
+    after = _booked()
     for key in (("shift", "16 channels are no multiple of 512"),
                 ("shift", "no TPU"), ("shift", "impl='shift' asked for")):
         assert after[key] == before.get(key, 0) + 1, key
+
+
+def test_what_the_ungated_call_refuses_and_books():
+    """The ungated filter goes through the same plan: ``shift`` with the
+    guard that refused, ``pallas`` (on one device; interpreted here) with a
+    reason that says it is the ungated form, its rows, channels, taps and
+    activation."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+
+    x, _, _, w = _operands(4)
+    with pytest.raises(ValueError, match="one of"):
+        causal_conv_rows(x, w, "silu", impl="conv")
+    with pytest.raises(NotImplementedError, match="16 channels"):
+        causal_conv_rows(x, w, "silu", impl="pallas")
+    rows, taps, _ = _rows(4, kernel.BLOCK, thirds=1)
+    with pytest.raises(NotImplementedError, match="refused the mesh"):
+        causal_conv_rows(rows, taps, impl="pallas")     # eight devices, none
+    before = _booked()
+    causal_conv_rows(x, w)
+    causal_conv_rows(rows, taps, None)
+    causal_conv_rows(rows, taps, "silu", "shift")
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        got = causal_conv_rows(rows, taps, "silu", "pallas", interpret=True)
+        jax.eval_shape(lambda x, w: causal_conv_rows(x, w, None, "pallas"),
+                       rows, taps)
+    finally:
+        mesh_lib.set_mesh(None)
+    np.testing.assert_allclose(
+        got, causal_conv_rows(rows, taps, "silu", "shift"), atol=4e-4)
+    after = _booked()
+    for key, n in ((("shift", "16 channels are no multiple of 512"), 1),
+                   (("shift", "no TPU"), 1),
+                   (("shift", "impl='shift' asked for"), 2),
+                   (("pallas", "ungated, rows 256 x 512, 4 taps, silu; "
+                               "one device"), 1),
+                   (("pallas", "ungated, rows 256 x 512, 4 taps, no "
+                               "activation; one device"), 1)):
+        assert after[key] == before.get(key, 0) + n, key

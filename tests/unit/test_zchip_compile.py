@@ -890,6 +890,49 @@ def test_the_short_conv_kernels_compile_at_the_seventh_cells_shape(one_chip):
     assert f"bf16[{B},{S},{3 * C}]" in text
 
 
+def test_the_ungated_conv_kernels_compile_at_the_eighth_cells_shape(
+        one_chip, monkeypatch):
+    """The same body without the gates and with silu after the filter
+    (PR 51) at ``train-qwen3next-gdn-8k-1chip``'s shape: three rows of 8192
+    positions, the 8192 channels of ``[q ; k ; v]``, 4 taps, through
+    ``ops/short_conv.py causal_conv_rows`` as ``GatedDeltaNet`` calls it.  The
+    channels go by blocks of 2048 on a third grid axis, so the float32
+    scratches cost what the seventh cell's do; the custom calls have names of
+    their own, which LFM2's ``trace_names`` cannot match."""
+    import re
+
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import short_conv as kernel
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+    from deepspeed_tpu.ops.short_conv import causal_conv_rows
+
+    B, S, C, L = 3, 8192, 8192, 4
+    assert kernel._grid(B, S, C, False) == ((B, S // kernel.BLOCK, 4), 2048)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+
+    def loss(x, w):
+        return causal_conv_rows(x, w, "silu").astype(jnp.float32).sum()
+
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+            jax.ShapeDtypeStruct((B, S, C), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((C, L), jnp.float32, sharding=one_chip)
+        ).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    calls = set(re.findall(r"%(\w+?)[.\d]* = [^=]*? custom-call\(", text))
+    assert calls == {"causal_conv_rows", "causal_conv_rows_back"}, calls
+    assert not re.search("^short_conv_rows(_back)?$", "causal_conv_rows")
+    assert any((s, i) == ("short_conv", "pallas") and r == (
+        "ungated, rows 8192 x 8192, 4 taps, silu; one device")
+        for s, i, r, n in dispatch_report() if n)
+
+
 def test_the_seventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     """``train-lfm2-hybrid-8k-1chip`` (PR 45) as the benchmark builds it,
     its whole train step compiled for the described chip: three kinds of
@@ -1031,6 +1074,7 @@ def test_the_eighth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     under the chip's 15.75 GiB.  Marked slow: the compile takes ~3 minutes
     of the ~25 the tier-1 command may take, in the file the command runs
     last; ``compile_said`` in the configuration file holds its reading."""
+    import re
     import types
 
     from benchmark.harness import manifest as M
@@ -1064,6 +1108,10 @@ def test_the_eighth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     text = compiled.as_text()
     assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
     assert "self_attn_full" in text and "moe_rows_back" in text
+    # the filter over [q ; k ; v] is the row kernels' since PR 51: forward,
+    # the remat's forward and one backward a Gated DeltaNet layer
+    assert len(re.findall(r"causal_conv_rows[.\d]* = ", text)) == 6
+    assert len(re.findall(r"causal_conv_rows_back[.\d]* = ", text)) == 3
     sites = {(s, i) for s, i, _, n in dispatch_report() if n}
     assert {("attention", "flash"), ("gated_delta", "pallas"),
-            ("moe_rows", "pallas")} <= sites, sites
+            ("moe_rows", "pallas"), ("short_conv", "pallas")} <= sites, sites
