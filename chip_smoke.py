@@ -928,7 +928,16 @@ def kernel_flash_lanes_256():
     kernel_flash_window_gqa(H=16, KV=2, D=256, windows=(None,))
 
 
-def kernel_gated_delta(time_it: bool = True):
+def kernel_gated_delta_wide(time_it: bool = True):
+    """:func:`kernel_gated_delta` at the ninth cell's shape (PR 52): ``(2,
+    8192)`` rows, 30 key heads of 96 and 30 value heads of 192 channels
+    (states of 96 x 192, read by the kernels in lane slots of 128 x 256),
+    ``beta = 2 sigmoid(.)`` in (0, 2), against
+    ``benchmark/reference/olmo_hybrid.py``'s recurrence."""
+    kernel_gated_delta(time_it, wide=True)
+
+
+def kernel_gated_delta(time_it: bool = True, wide: bool = False):
     """The gated delta rule at the eighth cell's shape, ``(3, 8192)`` rows,
     16 key heads and 32 value heads of 128 channels, chunk 64: the fused
     kernels (PR 49: what ``auto`` takes on the chip) and the XLA form,
@@ -951,25 +960,32 @@ def kernel_gated_delta(time_it: bool = True):
     from benchmark.harness.manifest import ROOT, load_module
     from deepspeed_tpu.ops.gated_delta import gated_delta_rule
 
-    reference = load_module(ROOT, "reference", "qwen3next")
-    B, S, Hk, Hv, d = 3, 8192, 16, 32, 128
+    reference = load_module(ROOT, "reference",
+                            "olmo_hybrid" if wide else "qwen3next")
+    B, S, Hk, Hv, dk, d = (2, 8192, 30, 30, 96, 192) if wide \
+        else (3, 8192, 16, 32, 128, 128)
     ks = jax.random.split(jax.random.PRNGKey(48), 6)
 
     def unit(key, H, scale):
-        x = jax.random.normal(key, (B, S, H, d), jnp.float32)
+        x = jax.random.normal(key, (B, S, H, dk), jnp.float32)
         x = x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6) * scale
-        return x.reshape(B, S, H * d).astype(jnp.bfloat16)
+        return x.reshape(B, S, H * dk).astype(jnp.bfloat16)
 
-    q, k = unit(ks[0], Hk, d ** -0.5), unit(ks[1], Hk, 1.0)
+    q, k = unit(ks[0], Hk, dk ** -0.5), unit(ks[1], Hk, 1.0)
     v, do = (jax.random.normal(kk, (B, S, Hv * d), jnp.float32).astype(
         jnp.bfloat16) for kk in ks[2:4])
     # exp(g) between 0.5 and 0.999 a token: memories of 2 to 1000 tokens
     g = jnp.log(jax.random.uniform(ks[4], (B, S, Hv), jnp.float32, 0.5, 0.999))
     beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, S, Hv), jnp.float32))
+    if wide:        # linear_allow_neg_eigval: in (0, 2), a third above 1.5
+        beta = 2.0 * jax.nn.sigmoid(
+            2.0 * jax.random.normal(ks[5], (B, S, Hv), jnp.float32) + 0.3)
+        print(f"  gated_delta: {100 * float((beta > 1.5).mean()):.1f}% of "
+              f"beta above 1.5, largest {float(beta.max()):.4f}", flush=True)
 
     def ref(q, k, v, g, beta):
         f = reference._f32
-        qh, kh = (jnp.repeat(f(t).reshape(B, S, Hk, d), Hv // Hk, axis=2)
+        qh, kh = (jnp.repeat(f(t).reshape(B, S, Hk, dk), Hv // Hk, axis=2)
                   for t in (q, k))
         return reference.delta_rule(qh, kh, f(v).reshape(B, S, Hv, d), g,
                                     beta).reshape(B, S, Hv * d)
@@ -983,7 +999,8 @@ def kernel_gated_delta(time_it: bool = True):
     args = (q, k, v, g, beta)
     want = both(ref)(*args)
     for impl in ("pallas", "xla"):
-        rule = functools.partial(gated_delta_rule, chunk=64, impl=impl)
+        rule = functools.partial(gated_delta_rule, chunk=64, impl=impl,
+                                 key_heads=Hk)
         run = both(rule)
         got = jax.block_until_ready(run(*args))
         for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
@@ -996,10 +1013,10 @@ def kernel_gated_delta(time_it: bool = True):
             assert np.isfinite(a).all() and max(rows) <= TOL, (n, rows)
         if not time_it:
             continue
-        from benchmark import flops_qwen3next as fl
+        from benchmark import flops_olmo_hybrid as fl   # the general count
 
         conf = {"linear_num_key_heads": Hk, "linear_num_value_heads": Hv,
-                "linear_key_head_dim": d, "linear_value_head_dim": d,
+                "linear_key_head_dim": dk, "linear_value_head_dim": d,
                 "layer_types": ["linear_attention"], "num_hidden_layers": 1}
         least = fl.gated_delta_bytes_per_step(conf, B * S) / 819e9 * 1e3
         for name, fn in (("forward", jax.jit(rule)), ("forward + backward",
@@ -1354,7 +1371,7 @@ def kernel_short_conv(time_it: bool = True):
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
                 kernel_flash_lanes_256, kernel_gated_delta,
-                kernel_qk_rows, kernel_short_conv,
+                kernel_gated_delta_wide, kernel_qk_rows, kernel_short_conv,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

@@ -92,6 +92,18 @@ with ``w`` from zeros (the gated norm inside the DeltaNet layer alone
 keeps ``w`` from ones).  ``MoEConfig.shared_expert_gate`` is the family's
 scalar sigmoid gate on the shared expert.  No cache leaf holds the state
 or the filter's tail yet: ``decode=True`` with such a layer raises.
+
+Olmo-Hybrid (Ai2, 2026; ``model_type: olmo_hybrid``) is that stack with no
+experts at all (``moe=None`` beside ``layer_types``: every block keeps the
+dense SwiGLU of ``intermediate_size``) under the OLMo 2 / 3 family's
+**reordered norm** (``reordered_norm``: ``x + Norm(mixer(x))``, ``x +
+Norm(FFN(x))``, nothing before a branch; arXiv:2501.00656).  Its Gated
+DeltaNet is fla's at ``expand_v`` 2: ``linear_key_head_dim`` 96 and
+``linear_value_head_dim`` 192, a state of 96 x 192 a head, and
+``linear_allow_neg_eigval`` (``beta = 2 sigmoid(b)``, so the transition ``I
+- beta k k^T`` has the eigenvalue ``1 - beta`` in (-1, 1)); no output gate
+on attention, ``qk_norm=True`` (OLMoE's, over the whole projection) and
+``rope_layer_types=()``: no layer carries a position.
 """
 from __future__ import annotations
 
@@ -146,8 +158,8 @@ class LlamaConfig:
     ``conv_L_cache``, ``conv_bias``, ``tie_word_embeddings``,
     ``linear_num_key_heads``, ``linear_num_value_heads``,
     ``linear_key_head_dim``, ``linear_value_head_dim``,
-    ``linear_conv_kernel_dim``, ``partial_rotary_factor``.  The rest are
-    this program's own."""
+    ``linear_conv_kernel_dim``, ``linear_allow_neg_eigval``,
+    ``partial_rotary_factor``.  The rest are this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -170,14 +182,17 @@ class LlamaConfig:
     # Gated DeltaNet there); None → all full
     layer_types: Optional[tuple] = None
     # a "linear_attention" layer: key heads (q and k), value heads (v, the
-    # states, the output; a multiple of the key heads), their channels
-    # (one number: a state is square), the taps of the filter over
+    # states, the output; a multiple of the key heads), the channels of
+    # each (a state is key x value channels), the taps of the filter over
     # [q | k | v] (the last is the current position)
     linear_num_key_heads: int = 16
     linear_num_value_heads: int = 32
     linear_key_head_dim: int = 128
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
+    # beta = 2 sigmoid(b) instead of sigmoid(b): the state's transition
+    # I - beta k k^T may flip a direction (eigenvalue 1 - beta in (-1, 1))
+    linear_allow_neg_eigval: bool = False
     # positions the delta rule solves together (ops/gated_delta.py)
     linear_chunk_size: int = 64
     # the share of a head's channels, from the first on, that the rotation
@@ -235,6 +250,10 @@ class LlamaConfig:
     # norm`` then normalises the attention branch's OUTPUT and the FFN
     # reads ``pre_mlp_norm``, its output through ``post_mlp_norm``
     sandwich_norm: bool = False
+    # x += Norm(mixer(x)); x += Norm(FFN(x)), nothing before a branch (the
+    # OLMo 2 / 3 family's): ``post_attention_norm`` normalises the mixer's
+    # OUTPUT and ``post_mlp_norm`` the FFN's; no ``input_norm`` leaf
+    reordered_norm: bool = False
     # latent attention (DeepSeek-V2/V3's MLA), set by ``kv_lora_rank``:
     # c_q = Norm(x W_qa) (q_lora_rank wide), [q_nope | q_rope] = c_q W_qb;
     # [c_kv | k_rope] = x W_kva, c_kv = Norm(c_kv) (kv_lora_rank wide),
@@ -292,6 +311,15 @@ class LlamaConfig:
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm is False, True (the whole projection)"
                              f" or 'head', got {self.qk_norm!r}")
+        if self.reordered_norm and self.sandwich_norm:
+            raise ValueError(
+                "reordered_norm (a norm after each branch alone) beside "
+                "sandwich_norm (one before and one after): a block has one "
+                "placement")
+        if self.reordered_norm and self.decode:
+            raise NotImplementedError(
+                "decode=True with reordered_norm: the fused decode kernels "
+                "fold a norm BEFORE each branch into its projections")
         if self.num_dense_layers and self.moe is None:
             raise ValueError("num_dense_layers counts the blocks that moe "
                              "leaves dense; there is no moe")
@@ -335,11 +363,11 @@ class LlamaConfig:
                 raise ValueError(
                     f"linear_num_value_heads {Hv} is no multiple of "
                     f"linear_num_key_heads {Hk}")
-            if self.linear_key_head_dim != self.linear_value_head_dim:
-                raise NotImplementedError(
-                    f"linear_key_head_dim {self.linear_key_head_dim} != "
+            if self.linear_key_head_dim < 1 or self.linear_value_head_dim < 1:
+                raise ValueError(
+                    f"linear_key_head_dim {self.linear_key_head_dim} and "
                     f"linear_value_head_dim {self.linear_value_head_dim}: "
-                    f"the delta rule is written for square states")
+                    f"at least one channel a head")
             if self.linear_conv_kernel_dim < 1 or self.linear_chunk_size < 1:
                 raise ValueError(
                     f"linear_conv_kernel_dim {self.linear_conv_kernel_dim} "
@@ -648,8 +676,9 @@ class LlamaAttention(nn.Module):
         k = _dense(x, KV * D, ("embed", "kv"), cfg=cfg, name="k_proj",
                    module=self)
         if cfg.qk_norm is True:
-            q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
-            k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
+            with trace.device_span("attn/qk_norm"):
+                q = RMSNorm(cfg, axis="qkv", name="q_norm")(q)
+                k = RMSNorm(cfg, axis="kv", name="k_norm")(k)
         rotates, head_norm = cfg.rotates(self.kind), cfg.qk_norm == "head"
         plan = rows_plan(q, k, D, rotary_dim=cfg.rotary_dim,
                          decode=cfg.decode, norm=head_norm) \
@@ -811,15 +840,17 @@ class ShortConv(nn.Module):
 
 
 class GatedDeltaNet(nn.Module):
-    """The Qwen3-Next family's token mixer of a ``"linear_attention"``
-    layer (released code: ``Qwen3NextGatedDeltaNet``), Hk key heads and Hv
-    value heads of d channels::
+    """The Qwen3-Next and Olmo-Hybrid families' token mixer of a
+    ``"linear_attention"`` layer (released code: ``Qwen3NextGatedDeltaNet``;
+    fla's ``GatedDeltaNet``), Hk key heads of dk channels and Hv value heads
+    of dv (128 and 128; 96 and 192)::
 
-        [q | k | v | z] = h W_qkvz      # Hk d | Hk d | Hv d | Hv d, contiguous
+        [q | k | v | z] = h W_qkvz      # Hk dk | Hk dk | Hv dv | Hv dv, contiguous
         [b | a]         = h W_ba        # Hv | Hv
         [q | k | v]    <- silu(filter([q | k | v]))     # ops/short_conv.py
-        beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias)
-        q <- q / |q| * d^-1/2;  k <- k / |k|            # a head, eps 1e-6
+        beta = sigmoid(b)  (x 2 under linear_allow_neg_eigval)
+        g = -exp(A_log) * softplus(a + dt_bias)
+        q <- q / |q| * dk^-1/2;  k <- k / |k|           # a head, eps 1e-6
         o = gated_delta_rule(q, k, v, g, beta)          # ops/gated_delta.py
         y = (o * rsqrt(mean(o^2) + eps) * w_o) * silu(z)    # a head
         out = y W_out
@@ -843,8 +874,8 @@ class GatedDeltaNet(nn.Module):
         cfg = self.cfg
         B, S, E = x.shape
         Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        d = cfg.linear_value_head_dim
-        conv_dim = (2 * Hk + Hv) * d
+        dk, d = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        conv_dim = 2 * Hk * dk + Hv * d
         init = nn.initializers.normal(cfg.initializer_range)
         f32 = jnp.float32
         with trace.device_span("linear_attn/in_proj"):
@@ -868,18 +899,21 @@ class GatedDeltaNet(nn.Module):
             nn.initializers.ones, ("heads",)), (Hv,), f32)
         with trace.device_span("linear_attn/delta_rule"):
             def unit(t, H):         # each head's channels to length 1
-                t = t.astype(f32).reshape(B, S, H, d)
+                t = t.astype(f32).reshape(B, S, H, dk)
                 return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True)
                                          + 1e-6)
 
-            q = (unit(qkv[..., :Hk * d], Hk) * d ** -0.5).astype(cfg.dtype)
-            k = unit(qkv[..., Hk * d:2 * Hk * d], Hk).astype(cfg.dtype)
+            q = (unit(qkv[..., :Hk * dk], Hk) * dk ** -0.5).astype(cfg.dtype)
+            k = unit(qkv[..., Hk * dk:2 * Hk * dk], Hk).astype(cfg.dtype)
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 ba[..., Hv:].astype(f32) + dt_bias)
             o = gated_delta_rule(
-                q.reshape(B, S, Hk * d), k.reshape(B, S, Hk * d),
-                qkv[..., 2 * Hk * d:], g, beta, chunk=cfg.linear_chunk_size)
+                q.reshape(B, S, Hk * dk), k.reshape(B, S, Hk * dk),
+                qkv[..., 2 * Hk * dk:], g, beta, key_heads=Hk,
+                chunk=cfg.linear_chunk_size)
         w_o = self.param("o_norm", nn.with_partitioning(
             nn.initializers.ones, ("head_dim",)), (d,), cfg.param_dtype)
         with trace.device_span("linear_attn/gated_norm"):
@@ -946,20 +980,21 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
+        # under the reordered norm a branch reads the residual stream itself
+        h = x if cfg.reordered_norm else RMSNorm(cfg, name="input_norm")(x)
         if self.kind == CONV:       # the mixer reads no position and no mask
-            attn = ShortConv(cfg, name="conv")(
-                RMSNorm(cfg, name="input_norm")(x))
+            attn = ShortConv(cfg, name="conv")(h)
         elif self.kind == LINEAR:
-            attn = GatedDeltaNet(cfg, name="linear_attn")(
-                RMSNorm(cfg, name="input_norm")(x))
+            attn = GatedDeltaNet(cfg, name="linear_attn")(h)
         else:
             self_attn = LlamaLatentAttention(cfg, name="self_attn") \
                 if cfg.kv_lora_rank \
                 else LlamaAttention(cfg, self.kind, self.blockdiff,
                                     name="self_attn")
-            attn = self_attn(RMSNorm(cfg, name="input_norm")(x),
-                             position_ids, attn_mask)
-        if cfg.sandwich_norm:
+            attn = self_attn(h, position_ids, attn_mask)
+        if cfg.reordered_norm:
+            h = x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
+        elif cfg.sandwich_norm:
             x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
             h = RMSNorm(cfg, name="pre_mlp_norm")(x)
         else:
@@ -975,11 +1010,10 @@ class LlamaBlock(nn.Module):
                 name="moe")(h, train=not self.deterministic,
                             return_stats=True)
             ys = dict(stats, aux_loss=aux)
-        else:       # named only as a leading dense block of a sparse stack
-            with trace.device_span("mlp_dense") if cfg.moe is not None \
-                    else contextlib.nullcontext():
+        else:
+            with trace.device_span("mlp_dense"):
                 ff = self._dense_ffn(h)
-        if cfg.sandwich_norm:
+        if cfg.sandwich_norm or cfg.reordered_norm:
             ff = RMSNorm(cfg, name="post_mlp_norm")(ff)
         return x + ff, ys
 
@@ -1341,16 +1375,16 @@ class LlamaForCausalLM(nn.Module):
         conv = 3 * E * E + E * E + E * cfg.conv_L_cache
         # a linear_attention layer's mixer: two projections in, the taps,
         # one projection out; its recurrence a token a value head is three
-        # products of d x d forward (S^T k, k (x) delta, S^T q)
+        # products of dk x dv forward (S^T k, k (x) delta, S^T q)
         linears = cfg.kinds.count(LINEAR)
         Hk, Hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        d = cfg.linear_value_head_dim
-        conv_dim = (2 * Hk + Hv) * d
+        dk, d = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        conv_dim = 2 * Hk * dk + Hv * d
         linear = (E * (conv_dim + Hv * d) + E * 2 * Hv
                   + conv_dim * cfg.linear_conv_kernel_dim + Hv * d * E)
         table = (1 if cfg.tie_word_embeddings else 2) * cfg.padded_vocab_size
         n = (table * E + (L - convs - linears) * attn + convs * conv
-             + linears * (linear + 3 * Hv * d * d)
+             + linears * (linear + 3 * Hv * dk * d)
              + cfg.num_dense_layers * dense
              + (L - cfg.num_dense_layers) * ffn
              + mtp * (attn + ffn + 2 * E * E + cfg.padded_vocab_size * E))

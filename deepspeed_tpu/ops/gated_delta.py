@@ -1,14 +1,18 @@
 """The gated delta rule over a sequence (Gated DeltaNet, arXiv:2412.06464;
-the token mixer of three layers in four of ``model_type: qwen3_next``): a
-state a value head, ``S`` (keys x values, ``d x d``), carried ALONG the
+the token mixer of three layers in four of ``model_type: qwen3_next`` and
+``olmo_hybrid``): a state a value head, ``S`` (keys x values, ``dk x dv``:
+128 x 128 in the first, 96 x 192 in the second), carried ALONG the
 sequence,
 
     S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T
     o_t = S_t^T q_t                                   S_0 = 0 at every row
 
-with ``g_t <= 0`` a log-decay and ``beta_t`` in (0, 1) a value head a
-token.  ``q`` and ``k`` arrive normalised and scaled (the caller's); key
-head ``j`` serves value heads ``j * r .. j * r + r - 1``, ``r = Hv / Hk``.
+with ``g_t <= 0`` a log-decay and ``beta_t`` a value head a token, in (0,
+1) or, where the caller allows the transition ``I - beta k k^T`` a negative
+eigenvalue, in (0, 2): nothing here knows which (``A`` below doubles; the
+block solve is exact for either).  ``q`` and ``k`` arrive normalised and
+scaled (the caller's); key head ``j`` serves value heads ``j * r .. j * r +
+r - 1``, ``r = Hv / Hk``.
 
 **The chunked form** (what runs; the per-token recurrence above is
 ``benchmark/reference/qwen3next.py``'s and the tests').  In a chunk of
@@ -36,8 +40,8 @@ arrived in (float32 accumulation).
 One ``custom_vjp``: the forward keeps its five inputs and nothing else, so
 a block's ``dots_saveable`` policy sees none of the inner products; the
 backward walks the forward again and then back, which keeps the per-chunk
-states for the length of a row's backward (``N x Hv x d x d`` float32: 128
-x 32 x 64 KB = 256 MiB at S 8192).  Both passes walk the batch a row at a
+states for the length of a row's backward (``N x Hv x dk x dv`` float32:
+128 x 32 x 64 KB = 256 MiB at S 8192).  Both passes walk the batch a row at a
 time (``lax.map``).
 
 ``impl``: ``"xla"`` is the above as XLA's own program, on every backend and
@@ -46,9 +50,10 @@ no state for all chunks at once as batched products (``U``, ``W``, ``P``,
 the decayed ``q`` and ``k`` through HBM), :func:`_scan_xla` is a
 ``lax.scan`` over the chunks, the backward is ``jax.vjp`` of both.
 ``"pallas"`` (what ``"auto"`` takes on a TPU where the operands allow: bf16,
-heads of a multiple of 128 channels, chunks of 32 / 64 / 128 in whole
-groups of four, at most four value heads a key head, a mesh that
-``kernel_mesh_plan`` takes) hands the WHOLE chunked form to
+chunks of 32 / 64 / 128 in whole groups of four, at most four value heads a
+key head, a mesh that ``kernel_mesh_plan`` takes; heads of any width, those
+that are no multiple of 128 channels in lane slots of the next one, zeros
+behind them) hands the WHOLE chunked form to
 ``ops/pallas/gated_delta.py`` (HLO custom calls ``gated_delta_fwd`` /
 ``gated_delta_bwd``, PR 49): a grid step reads ``q``, ``k``, ``v`` in the
 layout the layer wrote, makes ``A``, the inverse, ``U``, ``W``, ``P`` and
@@ -63,10 +68,13 @@ on the v5e the scan as XLA's while loop read 93.6 ms forward and 211 forward
 + backward a layer (my chip run, PR 48; PERF.md section 6 has the
 kernels').  ``kernel_dispatch_total{site="gated_delta"}`` says what a call
 resolved to and why (the kernels' reason names the tile: ``128 chunks of 64
-x 16 key heads x 2 value heads of 128, fused; one device``);
+x 16 key heads x 2 value heads of 128, fused; one device``, or ``... x 30
+key heads of 96 x 1 value heads of 192, ...``);
 ``gated_delta_chunks_total{pass}`` counts, at trace time, the chunks of one
 head-sequence a traced pass walks in sequence: ``fwd`` N, ``bwd`` 2 N (the
-forward's walk again, then the walk back), under either ``impl``.
+forward's walk again, then the walk back), under either ``impl``;
+``gated_delta_state_elems{dk, dv}`` is ``dk * dv`` of the rule a traced
+pass ran, so that a snapshot says which shape of state ran.
 """
 from __future__ import annotations
 
@@ -90,6 +98,15 @@ def _note_chunks(pass_: str, n: int) -> None:
         "chunks of one head-sequence that a traced pass of the gated delta "
         "rule walks in sequence (counted at trace time, not per call)",
         labelnames=("pass",)).labels(pass_).inc(n)
+
+
+def _note_state(dk: int, dv: int) -> None:
+    _registry.gauge(
+        "gated_delta_state_elems",
+        "elements of one state (keys x values) of the gated delta rule that "
+        "a traced pass ran, by the key and value heads' channels (set at "
+        "trace time)", labelnames=("dk", "dv")).labels(
+            str(dk), str(dv)).set(dk * dv)
 
 
 def _solve_unit_lower(a: jax.Array, rhs: jax.Array) -> jax.Array:
@@ -128,7 +145,7 @@ def _solve_unit_lower(a: jax.Array, rhs: jax.Array) -> jax.Array:
     return jnp.concatenate(xs, axis=-2)
 
 
-def _prepare(q, k, v, g, beta, chunk: int):
+def _prepare(q, k, v, g, beta, chunk: int, key_heads=None):
     """What of the chunked form depends on no state, for every chunk at
     once: ``(u, w, p, qg, kd, g_last)`` as ``(B, Hv, N, C, .)`` (``g_last``
     ``(B, Hv, N)``), ``u`` and ``g_last`` float32, the others in ``v``'s
@@ -136,13 +153,13 @@ def _prepare(q, k, v, g, beta, chunk: int):
     exp(gamma_C - gamma)`` and ``exp(gamma_C)`` of the module's text."""
     f32 = jnp.float32
     B, S, Hv = g.shape
-    d = v.shape[-1] // Hv
-    Hk = k.shape[-1] // d
+    dv = v.shape[-1] // Hv
+    Hk = key_heads or k.shape[-1] // dv
     C, N = chunk, S // chunk
     cdt = v.dtype                   # operands of the large products
 
     def heads(x, H):                # (B, S, H*d) -> (B, H, N, C, d)
-        return x.reshape(B, N, C, H, d).transpose(0, 3, 1, 2, 4)
+        return x.reshape(B, N, C, H, -1).transpose(0, 3, 1, 2, 4)
 
     def per_head(x):                # (B, S, Hv) -> (B, Hv, N, C) float32
         return x.astype(f32).reshape(B, N, C, Hv).transpose(0, 3, 1, 2)
@@ -166,7 +183,7 @@ def _prepare(q, k, v, g, beta, chunk: int):
     rhs = jnp.concatenate([v_.astype(f32), k_.astype(f32) * e_gamma],
                           axis=-1) * beta_[..., None]
     uw = _solve_unit_lower(a, rhs)
-    u, w = uw[..., :d], uw[..., d:].astype(cdt)
+    u, w = uw[..., :dv], uw[..., dv:].astype(cdt)
     p = (jnp.einsum("bhnid,bhnjd->bhnij", q_, k_, preferred_element_type=f32)
          * decay).astype(cdt)
     qg = (q_.astype(f32) * e_gamma).astype(cdt)
@@ -177,14 +194,14 @@ def _prepare(q, k, v, g, beta, chunk: int):
 
 def _scan_xla(u, w, p, qg, kd, g_last):
     """The sequential part, ``lax.scan`` over the chunk axis: ``o`` (B, Hv,
-    N, C, d) in ``w``'s type."""
+    N, C, dv) in ``w``'s type."""
     f32 = jnp.float32
     cdt = w.dtype
 
     def mm(eq, x, y):
         return jnp.einsum(eq, x, y.astype(cdt), preferred_element_type=f32)
 
-    def step(state, xs):            # state (B, Hv, d, d) float32
+    def step(state, xs):            # state (B, Hv, dk, dv) float32
         u_n, w_n, p_n, qg_n, kd_n, gl_n = xs
         v_new = u_n - mm("bhcd,bhde->bhce", w_n, state)
         o_n = mm("bhcd,bhde->bhce", qg_n, state) \
@@ -193,33 +210,34 @@ def _scan_xla(u, w, p, qg, kd, g_last):
             + mm("bhcd,bhce->bhde", kd_n, v_new)
         return state, o_n.astype(cdt)
 
-    B, Hv, _, _, d = u.shape
+    B, Hv, _, _, dv = u.shape
     chunks = tuple(jnp.moveaxis(x, 2, 0) for x in (u, w, p, qg, kd, g_last))
-    _, o = lax.scan(step, jnp.zeros((B, Hv, d, d), f32), chunks)
+    _, o = lax.scan(step, jnp.zeros((B, Hv, w.shape[-1], dv), f32), chunks)
     return jnp.moveaxis(o, 0, 2)
 
 
-def _chunked(q, k, v, g, beta, chunk: int):
+def _chunked(q, k, v, g, beta, chunk: int, key_heads=None):
     """The module's chunked form by XLA; shapes as :func:`gated_delta_rule`."""
     B, S, Hv = g.shape
-    o = _scan_xla(*_prepare(q, k, v, g, beta, chunk))   # (B, Hv, N, C, d)
+    o = _scan_xla(*_prepare(q, k, v, g, beta, chunk, key_heads))
     return o.transpose(0, 2, 3, 1, 4).reshape(B, S, v.shape[-1])
 
 
-def _row(chunk: int, fused):
+def _row(chunk: int, fused, key_heads=None):
     """The chunked form of one row of the batch, without the batch axis:
     XLA's where ``fused`` is None, else the kernels' (``fused`` their
     ``interpret``)."""
     if fused is None:
-        return lambda *xs: _chunked(*(x[None] for x in xs), chunk)[0]
+        return lambda *xs: _chunked(*(x[None] for x in xs), chunk,
+                                    key_heads)[0]
     from .pallas.gated_delta import forward
 
     return lambda *xs: forward(*(x[None] for x in xs), chunk=chunk,
-                               interpret=fused)[0]
+                               key_heads=key_heads, interpret=fused)[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _rule(q, k, v, g, beta, chunk, fused):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _rule(q, k, v, g, beta, chunk, fused, key_heads=None):
     """A row of the batch at a time (``lax.map``), forward and backward: a
     row's heads and chunks fill the chip, and what the form keeps between
     its products - and, in the backward, for its transposition - is one
@@ -227,14 +245,16 @@ def _rule(q, k, v, g, beta, chunk, fused):
     step's peak).  The backward maps ``vjp`` itself: the transpose of a
     mapped forward would keep every row's residuals stacked."""
     _note_chunks("fwd", g.shape[1] // chunk)
-    return lax.map(lambda xs: _row(chunk, fused)(*xs), (q, k, v, g, beta))
+    return lax.map(lambda xs: _row(chunk, fused, key_heads)(*xs),
+                   (q, k, v, g, beta))
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, fused):
-    return _rule(q, k, v, g, beta, chunk, fused), (q, k, v, g, beta)
+def _rule_fwd(q, k, v, g, beta, chunk, fused, key_heads=None):
+    return (_rule(q, k, v, g, beta, chunk, fused, key_heads),
+            (q, k, v, g, beta))
 
 
-def _rule_bwd(chunk, fused, res, do):
+def _rule_bwd(chunk, fused, key_heads, res, do):
     # the forward's walk again (XLA: under ``jax.vjp``; the kernels: for the
     # states entering the chunks), then the walk back
     _note_chunks("bwd", 2 * (res[3].shape[1] // chunk))
@@ -244,8 +264,9 @@ def _rule_bwd(chunk, fused, res, do):
             from .pallas.gated_delta import backward
 
             return tuple(x[0] for x in backward(
-                *(x[None] for x in xs), chunk=chunk, interpret=fused))
-        _, pull = jax.vjp(_row(chunk, None), *xs[:-1])
+                *(x[None] for x in xs), chunk=chunk, key_heads=key_heads,
+                interpret=fused))
+        _, pull = jax.vjp(_row(chunk, None, key_heads), *xs[:-1])
         return pull(xs[-1])
 
     return lax.map(one, (*res, do))
@@ -254,7 +275,7 @@ def _rule_bwd(chunk, fused, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _plan(g, k, v, chunk: int, impl: str):
+def _plan(g, k, v, chunk: int, impl: str, key_heads=None):
     """``(impl, reason, batch axes of a shard_map or None)``."""
     from .attention import on_tpu
     from .pallas import gated_delta as kernel
@@ -263,9 +284,10 @@ def _plan(g, k, v, chunk: int, impl: str):
     if impl == "xla":
         return impl, "impl='xla' asked for", None
     B, S, Hv = g.shape
-    d = v.shape[-1] // Hv
-    r = Hv // (k.shape[-1] // d)
-    reason = kernel.supported(S // chunk, chunk, d, v.dtype, r)
+    dv = v.shape[-1] // Hv
+    Hk = key_heads or k.shape[-1] // dv
+    dk, r = k.shape[-1] // Hk, Hv // Hk
+    reason = kernel.supported(S // chunk, chunk, dk, dv, v.dtype, r)
     if reason is None and impl == "auto" and not on_tpu():
         reason = "no TPU"
     verdict = axes = None
@@ -277,47 +299,51 @@ def _plan(g, k, v, chunk: int, impl: str):
         if impl == "pallas":
             raise NotImplementedError(f"gated_delta impl='pallas': {reason}")
         return "xla", reason, None
-    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hv // r} key heads "
-                      f"x {r} value heads of {d}, fused; "
+    of = "" if dk == dv else f"of {dk} "
+    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}"
+                      f"x {r} value heads of {dv}, fused; "
                       + ("one device" if verdict == "direct" else
                          f"shard_map over batch axes {axes}")), axes
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, *, chunk: int = 64,
-                     impl: str = "auto", interpret: bool = False
-                     ) -> jax.Array:
-    """``o`` (B, S, Hv*d) of the gated delta rule: ``q``, ``k`` (B, S,
-    Hk*d) normalised and scaled by the caller, ``v`` (B, S, Hv*d), ``g``
+                     key_heads: int = None, impl: str = "auto",
+                     interpret: bool = False) -> jax.Array:
+    """``o`` (B, S, Hv*dv) of the gated delta rule: ``q``, ``k`` (B, S,
+    Hk*dk) normalised and scaled by the caller, ``v`` (B, S, Hv*dv), ``g``
     (B, S, Hv) float32 log-decays (<= 0), ``beta`` (B, S, Hv), as a layer's
-    projections and filter wrote them.  Each row of the batch starts from
-    a zero state; ``S`` is a multiple of ``chunk``.  See the module's
-    text."""
+    projections and filter wrote them.  ``key_heads`` is ``Hk``; None where
+    key and value heads are as wide (``dk = dv``), which then says it.
+    Each row of the batch starts from a zero state; ``S`` is a multiple of
+    ``chunk``.  See the module's text."""
     from .pallas.spmd import note_dispatch
 
     if impl not in IMPLS:
         raise ValueError(f"gated_delta impl {impl!r}: one of {IMPLS}")
     B, S, Hv = g.shape
     if beta.shape != g.shape or v.ndim != 3 or v.shape[-1] % Hv \
-            or q.shape != k.shape or k.shape[-1] % (v.shape[-1] // Hv) \
+            or q.shape != k.shape \
+            or k.shape[-1] % (key_heads or v.shape[-1] // Hv) \
             or q.shape[:2] != (B, S) or v.shape[:2] != (B, S):
         raise ValueError(
-            f"gated_delta_rule takes q, k (B, S, Hk*d), v (B, S, Hv*d), g "
+            f"gated_delta_rule takes q, k (B, S, Hk*dk), v (B, S, Hv*dv), g "
             f"and beta (B, S, Hv), got {q.shape}, {k.shape}, {v.shape}, "
-            f"{g.shape}, {beta.shape}")
-    Hk = k.shape[-1] // (v.shape[-1] // Hv)
+            f"{g.shape}, {beta.shape} at key_heads {key_heads}")
+    Hk = key_heads or k.shape[-1] // (v.shape[-1] // Hv)
     if Hv % Hk:
         raise ValueError(f"{Hv} value heads are no multiple of {Hk} key "
                          f"heads")
     if S % chunk:
         raise ValueError(f"rows of {S} positions are no whole chunks of "
                          f"{chunk}")
-    impl, reason, axes = _plan(g, k, v, chunk, impl)
+    impl, reason, axes = _plan(g, k, v, chunk, impl, Hk)
     note_dispatch("gated_delta", impl, reason)
+    _note_state(k.shape[-1] // Hk, v.shape[-1] // Hv)
     fused = interpret if impl == "pallas" else None
 
     def run(*args):
-        return _rule(*args, chunk, fused)
+        return _rule(*args, chunk, fused, Hk)
 
     if axes is not None:
         from jax.sharding import PartitionSpec as P

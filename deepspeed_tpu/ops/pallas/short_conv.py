@@ -40,7 +40,10 @@ gated forward's body, the gates and the activation static options of it
 ``tests/unit/test_short_conv.py``).  Channels are independent, so they go by
 blocks of at most :data:`CHANNELS` on a third grid axis and 8192 of them cost
 the VMEM that LFM2's 3 x 2048 do; one operand a chunk instead of three lets a
-chunk be :data:`WIDE`.  The backward is a body of its own
+chunk be :data:`WIDE`.  A count that is whole lane tiles but no multiple of
+:data:`LANES` (Olmo-Hybrid's 11,520 = 90 x 128) goes by the widest of 384,
+256 or 128 lanes at a time that divides a block (:func:`_grid`: six blocks
+of 1,920 channels, 384 lanes a chunk).  The backward is a body of its own
 (:func:`_causal_backward_kernel`): ``dc = dy * silu'(c)`` with ``silu'(c) =
 s (1 + c (1 - s))``, ``s = sigmoid(c)``, needs ``c`` again, also for the 8
 rows AFTER the block (their ``dc`` reaches this block's ``dx``, and their
@@ -74,13 +77,18 @@ LANES = 512     # lanes worked on at a time
 BLOCK = 256     # rows of a block (a grid step)
 CHANNELS = 2048  # most channels of a block of the ungated filter (a grid axis)
 WIDE = 32       # rows at a time where a chunk holds one operand, not three
+WIDTHS = (LANES, 384, 256, 128)     # lanes at a time of the ungated filter
 _VMEM_LIMIT = 48 << 20
 
 
-def supported(S: int, C: int, L: int, dtype) -> Optional[str]:
-    """None where the kernels take the shape, else why not."""
-    if C % LANES:
-        return f"{C} channels are no multiple of {LANES}"
+def supported(S: int, C: int, L: int, dtype,
+              gated: bool = True) -> Optional[str]:
+    """None where the kernels take the shape, else why not.  The gated
+    rows' thirds go by :data:`LANES`; the ungated filter's channels by the
+    widest of :data:`WIDTHS` that divides a block of them."""
+    lanes = LANES if gated else WIDTHS[-1]
+    if C % lanes:
+        return f"{C} channels are no multiple of {lanes}"
     if S % BLOCK:
         return f"{S} positions are no multiple of {BLOCK}"
     if not 1 <= L <= HALO:
@@ -100,12 +108,13 @@ def _taps(w_ref, lanes, L):
     return [_f32(w_ref[pl.ds(j, 1), lanes]) for j in range(L)]
 
 
-def _fill(buf, C, rows, block, near, skip, near_at, at, step=CHUNK):
+def _fill(buf, C, rows, block, near, skip, near_at, at, step=CHUNK,
+          width=LANES):
     """``buf[at : at + rows] = block(rows, lane block)`` chunk by chunk and
     ``buf[near_at : near_at + HALO] = near(all 8 rows, lane block)``, zeros
     where ``skip`` (the 8-row view then lies outside the row)."""
-    for l0 in range(0, C, LANES):
-        lanes = pl.ds(l0, LANES)
+    for l0 in range(0, C, width):
+        lanes = pl.ds(l0, width)
         buf[pl.ds(near_at, HALO), lanes] = jnp.where(
             skip, 0.0, near(slice(None), l0))
 
@@ -134,10 +143,10 @@ def _dc(ref, dy_ref, C):
     return make
 
 
-def _x(ref, C):
+def _x(ref, C, width=LANES):
     """Rows ``at`` of a (1, rows, C) ref as they are, float32."""
     def make(at, l0):
-        return _f32(ref[0, at, pl.ds(l0, LANES)])
+        return _f32(ref[0, at, pl.ds(l0, width)])
     return make
 
 
@@ -176,11 +185,13 @@ def _forward_kernel(x_ref, before_ref, w_ref, y_ref, z_buf, *, C, L, rows,
     """``x_ref`` holds ``[Bg ; Cg ; u]`` (``gated``, C channels each) or the
     C channels of a block of ``x``; ``z_buf`` what the filter reads."""
     first = pl.program_id(1) == 0
-    z = _z if gated else _x
+    width = LANES if gated else _width(C)
+    z = _z if gated else functools.partial(_x, width=width)
     step = CHUNK if gated else WIDE
-    _fill(z_buf, C, rows, z(x_ref, C), z(before_ref, C), first, 0, HALO, step)
-    for l0 in range(0, C, LANES):
-        lanes = pl.ds(l0, LANES)
+    _fill(z_buf, C, rows, z(x_ref, C), z(before_ref, C), first, 0, HALO, step,
+          width)
+    for l0 in range(0, C, width):
+        lanes = pl.ds(l0, width)
         w = _taps(w_ref, lanes, L)
 
         def chunk(r, _):
@@ -258,12 +269,13 @@ def _causal_backward_kernel(x_ref, before_ref, after_ref, dy_ref,
     parent's jaxpr."""
     first = pl.program_id(1) == 0
     last = pl.program_id(1) == pl.num_programs(1) - 1
-    _fill(x_buf, C, rows, _x(x_ref, C), _x(before_ref, C), first, 0, HALO,
-          WIDE)
+    width = _width(C)
+    _fill(x_buf, C, rows, _x(x_ref, C, width), _x(before_ref, C, width),
+          first, 0, HALO, WIDE, width)
     dw_ref[...] = jnp.zeros(dw_ref.shape, dw_ref.dtype)
     steps = rows // WIDE
-    for l0 in range(0, C, LANES):
-        lanes = pl.ds(l0, LANES)
+    for l0 in range(0, C, width):
+        lanes = pl.ds(l0, width)
         w = _taps(w_ref, lanes, L)
 
         def cotangent(ext, dy):
@@ -294,7 +306,7 @@ def _causal_backward_kernel(x_ref, before_ref, after_ref, dy_ref,
             dx = dc * w[L - 1]
             for k in range(L):
                 dw[L - 1 - k] = dw[L - 1 - k] + (dc * back[k]).reshape(
-                    WIDE // 8, 8, LANES).sum(0)
+                    WIDE // 8, 8, width).sum(0)
                 if k:                                               # dc_{t+k}
                     dx = dx + pltpu.roll(dc_ext, WIDE + HALO - k,
                                          0)[:WIDE] * w[L - 1 - k]
@@ -303,7 +315,7 @@ def _causal_backward_kernel(x_ref, before_ref, after_ref, dy_ref,
 
         out = lax.fori_loop(
             0, steps, chunk, (jnp.where(last, 0.0, ahead),)
-            + (jnp.zeros((8, LANES), jnp.float32),) * L)
+            + (jnp.zeros((8, width), jnp.float32),) * L)
         for j in range(L):
             dw_ref[0, pl.ds(j, 1), lanes] = out[1 + j].sum(0, keepdims=True)
 
@@ -332,14 +344,24 @@ def _padded_taps(w):
     return jnp.zeros((HALO, C), jnp.float32).at[:L].set(_f32(w).T)
 
 
+def _width(Cb):
+    """Lanes the ungated kernels work on at a time in a block of ``Cb``
+    channels: the widest of :data:`WIDTHS` that divides it."""
+    return next(w for w in WIDTHS if Cb % w == 0)
+
+
 def _grid(B, S, C, gated):
     """``(grid, channels of a block)``.  The gated rows go whole, their three
     thirds lie side by side; the ungated filter's channels are independent
     and go by blocks of at most :data:`CHANNELS`, so that 8192 of them cost
-    the VMEM that 3 x 2048 do."""
+    the VMEM that 3 x 2048 do: the largest block of the widest lanes
+    (:data:`WIDTHS`) that divides them (8192: 2048 by 512; 11,520: 1920 by
+    384)."""
     if gated:
         return (B, S // BLOCK), C
-    Cb = max(c for c in range(LANES, CHANNELS + 1, LANES) if C % c == 0)
+    Cb = next(max(fits) for fits in (
+        [c for c in range(w, CHANNELS + 1, w) if C % c == 0] for w in WIDTHS)
+        if fits)
     return (B, S // BLOCK, C // Cb), Cb
 
 

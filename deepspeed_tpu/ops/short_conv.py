@@ -74,7 +74,8 @@ def _shift(bcu: jax.Array, w: jax.Array) -> jax.Array:
 
 def _plan(x, w, impl, form):
     """``(impl, reason, batch axes of a shard_map or None)`` for rows ``x``
-    (B, S, width) and what a ``pallas`` row says of the call, ``form``."""
+    (B, S, width) and what a ``pallas`` row says of the call, ``form``; the
+    gated rows are three thirds of the taps' channels wide."""
     from .attention import on_tpu
     from .pallas import short_conv as kernel
     from .pallas.spmd import kernel_mesh_plan
@@ -82,7 +83,8 @@ def _plan(x, w, impl, form):
     if impl == "shift":
         return impl, "impl='shift' asked for", None
     C, L = w.shape
-    reason = kernel.supported(x.shape[1], C, L, x.dtype)
+    reason = kernel.supported(x.shape[1], C, L, x.dtype,
+                              gated=x.shape[-1] == 3 * C)
     if reason is None and impl == "auto" and not on_tpu():
         reason = "no TPU"
     verdict = axes = None

@@ -1,4 +1,12 @@
-"""The chunked gated delta rule (``ops/gated_delta.py``) as two Pallas
+"""ORACLE, not a module of the program: ``deepspeed_tpu/ops/pallas/
+gated_delta.py`` as it stood at the parent of PR 52 (commit 8fff69e), kept
+as ``tests/unit/test_gated_delta.py``'s oracle.  PR 52 gave the kernels
+``(dk, dv)`` and lane slots; where key and value heads are 128 channels each
+(``train-qwen3next-gdn-8k-1chip``) they must trace to what this file traces
+to, primitive for primitive, and give the same bits.  Do not edit.  The
+parent's own text follows.
+
+The chunked gated delta rule (``ops/gated_delta.py``) as two Pallas
 kernels that make the chunks' preparation in VMEM beside the scan, from the
 layer's own ``q``, ``k``, ``v`` and gates: no ``U``, ``W``, ``P``, decayed
 ``q`` / ``k`` or solve operand is ever an array in HBM.
@@ -10,25 +18,11 @@ of ``g`` inside each chunk, beside ``beta``, as ``(B, Hk, 8, S)`` float32
 cumulative sum ``dgamma -> dg`` on what comes back in the same layout.
 
 **A grid step** ``(row, key head, group)`` holds :data:`GROUP` chunks of
-``C`` positions of ONE key head of ``dk`` channels and its ``r`` value
-heads of ``dv``, read in the layout the layer wrote (``q``, ``k`` ``(B, S,
-Hk dk)`` blocked ``(1, GROUP C, dk)`` at the head's lane offset, ``v`` and
-``o`` ``(1, GROUP C, r dv)``), the ``r`` states (keys x values, ``dk x
-dv``, float32) resident in a ``(r, dk, dv)`` scratch across the group axis,
-which is ``arbitrary``.
-
-**Heads that are no whole lane tiles** (Olmo-Hybrid: keys of 96 channels,
-values of 192) reach the kernels in SLOTS (:func:`_slots`): each head's
-channels from the first lane of a slot of the next multiple of 128 (128 and
-256), zeros behind them, written by XLA beside what made ``q``, ``k`` and
-``v``, and ``o`` and the cotangents cut back the same way.  A zero key
-channel adds nothing to ``K K^T`` or ``Q K^T`` and its row of the state
-stays zero; a zero value channel's column of ``U``, ``V'`` and the state
-stays zero: the rule over the slots is the rule over the heads, to the bit
-of each sum's order.  The array takes 128 rows or columns a pass whatever
-they hold, so a product over 96 or 192 channels costs what one over 128 or
-256 does; the slots cost HBM bytes (a third more of q, k, v, o and their
-cotangents) and the pass that writes them.  The gates arrive positions
+``C`` positions of ONE key head and its ``r`` value heads, read in the
+layout the layer wrote (``q``, ``k`` ``(B, S, Hk d)`` blocked ``(1, GROUP C,
+d)`` at the head's lane offset, ``v`` and ``o`` ``(1, GROUP C, r d)``), the
+``r`` states (keys x values, float32) resident in a ``(r, d, d)`` scratch
+across the group axis, which is ``arbitrary``.  The gates arrive positions
 on lanes; their column form is a transpose of a padded ``(128, 128)`` tile
 in VMEM.  A chunk: ``K K^T`` and ``Q K^T`` once for the key head, then a
 value head
@@ -59,8 +53,8 @@ large products (those with ``d`` in them) take operands in the arrays' type
 (bf16; the state and ``V'`` rounded to it as operands) and sum in float32.
 
 ``gated_delta_fwd`` writes ``o``; asked for ``states`` (the backward's first
-walk) it writes the state ENTERING each chunk instead, ``(B, Hv, N, dk,
-dv)`` float32 (of the slots), and leaves ``Q`` out.  ``gated_delta_bwd`` walks the groups and
+walk) it writes the state ENTERING each chunk instead, ``(B, Hv, N, d, d)``
+float32, and leaves ``Q`` out.  ``gated_delta_bwd`` walks the groups and
 their chunks backwards with the states' cotangents resident, makes each
 chunk's preparation again from the same inputs and the saved state, and
 transposes scan and preparation in place:
@@ -101,32 +95,19 @@ CHUNKS = (32, 64, 128)
 _LANES = 128
 _GATE_ROWS = 8
 _VMEM_LIMIT = 64 * 1024 * 1024
-# what the resident states and the double-buffered block of saved ones may
-# take of it (128 x 128 at two heads a key head: 2.25 MiB)
-_STATE_BYTES = 24 * 1024 * 1024
 _HI = lax.Precision.HIGHEST
 _F32 = jnp.float32
 
 
-def _slot(d: int) -> int:
-    """Lanes of the slot a head of ``d`` channels is written into."""
-    return -(-d // _LANES) * _LANES
-
-
-def supported(n_chunks: int, chunk: int, dk: int, dv: int, dtype,
+def supported(n_chunks: int, chunk: int, d: int, dtype,
               heads_a_key: int = 1) -> Optional[str]:
     """``None`` where the kernels take ``n_chunks`` chunks of ``chunk``
-    positions, key heads of ``dk`` and value heads of ``dv`` channels (any
-    widths: :func:`_slots`) and ``heads_a_key`` value heads a key head, else
-    the reason they do not."""
+    positions, heads of ``d`` channels and ``heads_a_key`` value heads a key
+    head, else the reason they do not."""
     if dtype != jnp.bfloat16:
         return f"operands of {jnp.dtype(dtype).name}"
-    held = 4 * heads_a_key * _slot(dk) * _slot(dv) * (1 + 2 * GROUP)
-    if held > _STATE_BYTES:
-        return (f"{heads_a_key} states of {_slot(dk)} x {_slot(dv)} float32 "
-                f"(heads of {dk} and {dv} channels in their lane slots): "
-                f"resident beside two blocks of {GROUP} saved ones they are "
-                f"{held >> 20} MiB of VMEM, more than {_STATE_BYTES >> 20}")
+    if d % _LANES:
+        return f"head channels {d} are no multiple of {_LANES}"
     if chunk not in CHUNKS:
         return (f"chunks of {chunk} positions: the kernels take "
                 f"{', '.join(map(str, CHUNKS))} (a group of {GROUP} fills "
@@ -255,8 +236,7 @@ class _Head:
     ``decay``, ``a``, ``e_gamma``, ``rhs`` (before ``beta``), ``t`` and ``x
     = [U | W]`` of the module's text, beside its gates as columns ``gcol``
     and ``bcol``, its index ``n`` among the key head's value heads and its
-    lanes ``on`` in the ``v`` / ``o`` blocks.  ``x``'s first ``dv`` columns
-    are ``U``, the ``dk`` behind them ``W``."""
+    lanes ``on`` in the ``v`` / ``o`` blocks."""
     n: int
     on: slice
     gcol: jax.Array
@@ -284,7 +264,7 @@ class _Key:
 def _prepare(k_ref, v_ref, gate_ref, m: _Masks, r: int):
     """The :class:`_Key` of each chunk of a grid step, all of the step's
     chunk-heads a stage at a time (:func:`_inverses`)."""
-    C, dv = m.eye.shape[0], v_ref.shape[2] // r
+    C, d = m.eye.shape[0], k_ref.shape[2]
     rows = gate_ref[0, 0]
     cols = _columns(rows)
     chunks = []
@@ -293,7 +273,7 @@ def _prepare(k_ref, v_ref, gate_ref, m: _Masks, r: int):
         k = k_ref[0, at, :]
         key = _Key(at, k, k.astype(_F32), _nt(k, k), [])
         for n in range(r):
-            on = slice(n * dv, (n + 1) * dv)
+            on = slice(n * d, (n + 1) * d)
             gcol, bcol = cols[at, n:n + 1], cols[at, r + n:r + n + 1]
             decay = jnp.exp(jnp.where(m.lower, gcol - rows[n:n + 1, at],
                                       -jnp.inf))
@@ -318,7 +298,7 @@ def _fwd_kernel(*refs, chunk, r, states):
         k_ref, v_ref, gate_ref, out_ref, state = refs
     else:
         q_ref, k_ref, v_ref, gate_ref, out_ref, state = refs
-    C, dv = chunk, v_ref.shape[2] // r
+    C, d = chunk, k_ref.shape[2]
     cdt = k_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -336,19 +316,18 @@ def _fwd_kernel(*refs, chunk, r, states):
             if states:
                 out_ref[0, it.n, i] = s
             sb = s.astype(cdt)
-            vb = (it.x[:, :dv]
-                  - _nn(it.x[:, dv:].astype(cdt), sb)).astype(cdt)
+            vb = (it.x[:, :d] - _nn(it.x[:, d:].astype(cdt), sb)).astype(cdt)
             if not states:
                 o = _nn((qf * it.e_gamma).astype(cdt), sb) \
                     + _nn((qk * it.decay).astype(cdt), vb)
                 out_ref[0, key.at, it.on] = o.astype(out_ref.dtype)
             kd = (key.kf * jnp.exp(g_last - it.gcol)).astype(cdt)
-            state[it.n] = _last_row(it.gcol, dv, m) * s + _tn(kd, vb)
+            state[it.n] = _last_row(it.gcol, d, m) * s + _tn(kd, vb)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
                 dv_ref, dgate_ref, dstate, *, chunk, r):
-    C, dk_, dv = chunk, k_ref.shape[2], v_ref.shape[2] // r
+    C, d = chunk, k_ref.shape[2]
     cdt = k_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -364,14 +343,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
         at, k, kf, kk = key.at, key.k, key.kf, key.kk
         q = q_ref[0, at, :]
         qf, qk = q.astype(_F32), _nt(q, k)
-        dq = dk = jnp.zeros((C, dk_), _F32)
+        dq = dk = jnp.zeros((C, d), _F32)
         dkk = dqk = jnp.zeros((C, C), _F32)
         dcol = jnp.zeros((C, _LANES), _F32)
         for it in key.heads:
             gcol, bcol, decay, x = it.gcol, it.bcol, it.decay, it.x
             g_last = gcol[C - 1:C, :]
             e_last, gl = jnp.exp(g_last - gcol), jnp.exp(g_last)
-            w = x[:, dv:].astype(cdt)
+            w = x[:, d:].astype(cdt)
             pf = qk * decay
             p, qg = pf.astype(cdt), (qf * it.e_gamma).astype(cdt)
             kd = (kf * e_last).astype(cdt)
@@ -379,20 +358,20 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gate_ref, s_ref, do_ref, dq_ref, dk_ref,
             s, ds1 = s_ref[0, it.n, i], dstate[it.n]
             do = do_ref[0, at, it.on]
             sb, ds1b = s.astype(cdt), ds1.astype(cdt)
-            vb = (x[:, :dv] - _nn(w, sb)).astype(cdt)
+            vb = (x[:, :d] - _nn(w, sb)).astype(cdt)
             dv_new = _tn(p, do) + _nn(kd, ds1b)
             dvb = dv_new.astype(cdt)
             dw, dp = -_nt(dvb, sb), _nt(do, vb)
             dqg, dkd = _nt(do, sb), _nt(vb, ds1b)
             dgl = jnp.sum(jnp.sum(ds1 * s, axis=1, keepdims=True), axis=0,
                           keepdims=True)
-            dstate[it.n] = _last_row(gcol, dv, m) * ds1 + _tn(qg, do) \
+            dstate[it.n] = _last_row(gcol, d, m) * ds1 + _tn(qg, do) \
                 - _tn(w, dvb)
             # the preparation, transposed
             dr = _hi(it.t, jnp.concatenate([dv_new, dw], axis=1),
                      ((0,), (0,)))
             da = jnp.where(m.strict, -_hi(dr, x, ((1,), (1,))), 0.0)
-            dr_u, dr_w = dr[:, :dv], dr[:, dv:] * bcol
+            dr_u, dr_w = dr[:, :d], dr[:, d:] * bcol
             dv_ref[0, at, it.on] = (dr_u * bcol).astype(dv_ref.dtype)
             dkd_k = dkd * kf * e_last
             dk = dk + dr_w * it.e_gamma + dkd * e_last
@@ -449,14 +428,14 @@ def _ungates(dgates, chunk: int, r: int):
     return dg.reshape(B, S, Hk * r), both[1]
 
 
-def _specs(span: int, dk: int, dv: int, r: int, group):
+def _specs(span: int, d: int, r: int, group):
     """The blocks of a grid step ``(b, j, n)``, which holds group
     ``group(n)`` of key head ``j``: ``(q | k, v | o, gates, states)``."""
-    return (pl.BlockSpec((1, span, dk), lambda b, j, n: (b, group(n), j)),
-            pl.BlockSpec((1, span, r * dv), lambda b, j, n: (b, group(n), j)),
+    return (pl.BlockSpec((1, span, d), lambda b, j, n: (b, group(n), j)),
+            pl.BlockSpec((1, span, r * d), lambda b, j, n: (b, group(n), j)),
             pl.BlockSpec((1, 1, _GATE_ROWS, span),
                          lambda b, j, n: (b, j, 0, group(n))),
-            pl.BlockSpec((1, r, GROUP, dk, dv),
+            pl.BlockSpec((1, r, GROUP, d, d),
                          lambda b, j, n: (b, j, group(n), 0, 0)))
 
 
@@ -466,7 +445,7 @@ def _params():
         vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _products(C: int, dk: int, dv: int) -> int:
+def _products(C: int, d: int) -> int:
     """Multiply-adds of a chunk-head's preparation as the array sees them:
     the ``HIGHEST`` products at their six passes, the packed ones by the
     rows that stream (:func:`_pack`)."""
@@ -474,100 +453,60 @@ def _products(C: int, dk: int, dv: int) -> int:
     rows = n * (_SQUARINGS + 1)
     while n < C:
         rows, n = rows + 2 * n, 2 * n
-    return 6 * (rows * C * C + C * C * (dk + dv))
+    return 6 * (rows * C * C + 2 * C * C * d)
 
 
-def _slots(x, heads: int, d: int):
-    """``x`` (B, S, heads * d) with each head's ``d`` channels from the
-    first lane of a slot of :func:`_slot` ``(d)`` lanes, zeros behind them;
-    ``x`` itself where a head is whole tiles already."""
-    if d == _slot(d):
-        return x
-    B, S, _ = x.shape
-    return jnp.pad(x.reshape(B, S, heads, d),
-                   ((0, 0), (0, 0), (0, 0), (0, _slot(d) - d))
-                   ).reshape(B, S, heads * _slot(d))
-
-
-def _unslots(x, heads: int, d: int):
-    """:func:`_slots` back: the heads' own ``d`` channels of each slot."""
-    if d == _slot(d):
-        return x
-    B, S, _ = x.shape
-    return x.reshape(B, S, heads, _slot(d))[..., :d].reshape(B, S, heads * d)
-
-
-def _heads(k, v, g, key_heads: Optional[int]):
-    """``(Hk, Hv, dk, dv)`` of the operands; ``key_heads`` None where a key
-    head is as wide as a value head."""
-    Hv = g.shape[-1]
-    dv = v.shape[-1] // Hv
-    Hk = key_heads or k.shape[-1] // dv
-    return Hk, Hv, k.shape[-1] // Hk, dv
-
-
-@functools.partial(jax.jit, static_argnames=("chunk", "key_heads", "states",
-                                             "interpret"))
-def forward(q, k, v, g, beta, *, chunk: int, key_heads: Optional[int] = None,
-            states: bool = False, interpret: bool = False):
-    """``o`` (B, S, Hv*dv) in ``v``'s type of ``q``, ``k`` (B, S, Hk*dk),
-    ``v`` (B, S, Hv*dv), ``g`` and ``beta`` (B, S, Hv); ``key_heads`` is
-    ``Hk`` where ``dk != dv``.  With ``states`` the state entering each
-    chunk instead, (B, Hv, N, dk, dv) float32 of the heads' slots."""
-    B, S, _ = g.shape
-    Hk, Hv, dk, dv = _heads(k, v, g, key_heads)
-    if not states:
-        q = _slots(q, Hk, dk)
-    k, v = _slots(k, Hk, dk), _slots(v, Hv, dv)
-    sk, sv = _slot(dk), _slot(dv)
+@functools.partial(jax.jit, static_argnames=("chunk", "states", "interpret"))
+def forward(q, k, v, g, beta, *, chunk: int, states: bool = False,
+            interpret: bool = False):
+    """``o`` (B, S, Hv*d) in ``v``'s type of ``q``, ``k`` (B, S, Hk*d), ``v``
+    (B, S, Hv*d), ``g`` and ``beta`` (B, S, Hv); with ``states`` the state
+    entering each chunk instead, (B, Hv, N, d, d) float32."""
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    Hk = k.shape[-1] // d
     r, C, N = Hv // Hk, chunk, S // chunk
     span = GROUP * C
-    key, values, gates, state = _specs(span, sk, sv, r, lambda n: n)
+    key, values, gates, state = _specs(span, d, r, lambda n: n)
     if states:
-        out_spec, out_shape = state, jax.ShapeDtypeStruct((B, Hv, N, sk, sv),
+        out_spec, out_shape = state, jax.ShapeDtypeStruct((B, Hv, N, d, d),
                                                           _F32)
     else:
         out_spec, out_shape = values, jax.ShapeDtypeStruct(v.shape, v.dtype)
     heads = B * Hv * N
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_fwd_kernel, chunk=C, r=r, states=states),
         grid=(B, Hk, N // GROUP),
         in_specs=([] if states else [key]) + [key, values, gates],
         out_specs=out_spec, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((r, sk, sv), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32)],
         compiler_params=_params(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * heads * (_products(C, sk, sv)
-                               + C * (3 * sk * sv + C * (sk + sv))),
+            flops=2 * heads * (_products(C, d) + C * d * (3 * d + 2 * C)),
             transcendentals=heads * C * (C + 2),
-            bytes_accessed=2 * B * S * (2 * Hk * sk + 2 * Hv * sv)
+            bytes_accessed=2 * B * S * d * (2 * Hk + 2 * Hv)
             + 4 * B * Hk * _GATE_ROWS * S
-            + (4 * heads * sk * sv if states else 0)),
+            + (4 * heads * d * d if states else 0)),
         name="gated_delta_fwd", interpret=interpret,
     )(*(() if states else (q,)), k, v, _gates(g, beta, C, Hk))
-    return out if states else _unslots(out, Hv, dv)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "key_heads",
-                                             "interpret"))
-def backward(q, k, v, g, beta, do, *, chunk: int,
-             key_heads: Optional[int] = None, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def backward(q, k, v, g, beta, do, *, chunk: int, interpret: bool = False):
     """The cotangents ``(dq, dk, dv, dg, dbeta)`` of :func:`forward`'s
     operands under ``do``, in the operands' types: the forward's walk again
     for the states entering the chunks, then the walk back."""
-    B, S, _ = g.shape
-    Hk, Hv, dk, dv = _heads(k, v, g, key_heads)
-    saved = forward(q, k, v, g, beta, chunk=chunk, key_heads=key_heads,
-                    states=True, interpret=interpret)
-    q, k = _slots(q, Hk, dk), _slots(k, Hk, dk)
-    v, do = _slots(v, Hv, dv), _slots(do, Hv, dv)
-    sk, sv = _slot(dk), _slot(dv)
+    B, S, Hv = g.shape
+    d = v.shape[-1] // Hv
+    Hk = k.shape[-1] // d
     r, C, N = Hv // Hk, chunk, S // chunk
     span, steps = GROUP * C, N // GROUP
-    key, values, gates, state = _specs(span, sk, sv, r,
+    saved = forward(q, k, v, g, beta, chunk=C, states=True,
+                    interpret=interpret)
+    key, values, gates, state = _specs(span, d, r,
                                        lambda n: steps - 1 - n)
     heads = B * Hv * N
-    dq, dk_, dv_, dgates = pl.pallas_call(
+    dq, dk, dv, dgates = pl.pallas_call(
         functools.partial(_bwd_kernel, chunk=C, r=r),
         grid=(B, Hk, steps),
         in_specs=[key, key, values, gates, state, values],
@@ -576,17 +515,15 @@ def backward(q, k, v, g, beta, do, *, chunk: int,
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((B, Hk, _GATE_ROWS, S), _F32)],
-        scratch_shapes=[pltpu.VMEM((r, sk, sv), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, d, d), _F32)],
         compiler_params=_params(),
         cost_estimate=pl.CostEstimate(
-            flops=2 * heads * (_products(C, sk, sv) + 6 * C * C * (sk + sv)
-                               + C * (8 * sk * sv + 3 * C * (sk + sv))),
+            flops=2 * heads * (_products(C, d) + 12 * C * C * d
+                               + C * d * (8 * d + 6 * C)),
             transcendentals=heads * C * (C + 2),
-            bytes_accessed=2 * B * S * (4 * Hk * sk + 3 * Hv * sv)
-            + 8 * B * Hk * _GATE_ROWS * S + 4 * heads * sk * sv),
+            bytes_accessed=2 * B * S * d * (4 * Hk + 3 * Hv)
+            + 8 * B * Hk * _GATE_ROWS * S + 4 * heads * d * d),
         name="gated_delta_bwd", interpret=interpret,
     )(q, k, v, _gates(g, beta, C, Hk), saved, do)
     dg, dbeta = _ungates(dgates, C, r)
-    return (_unslots(dq, Hk, dk), _unslots(dk_, Hk, dk),
-            _unslots(dv_, Hv, dv), dg.astype(g.dtype),
-            dbeta.astype(beta.dtype))
+    return dq, dk, dv, dg.astype(g.dtype), dbeta.astype(beta.dtype)

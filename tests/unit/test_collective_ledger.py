@@ -420,10 +420,15 @@ def fresh_registry():
     from deepspeed_tpu.telemetry import get_registry
 
     mesh_mod.set_mesh(None)
-    get_registry().clear()
-    yield get_registry()
+    reg = get_registry()
+    with reg._lock:     # emptied for the test, then as it was: a metric
+        kept = dict(reg._metrics)   # another test's module holds (the
+        reg._metrics.clear()        # goodput gauges) must outlive this one
+    yield reg
     mesh_mod.set_mesh(None)
-    get_registry().clear()
+    with reg._lock:
+        reg._metrics.clear()
+        reg._metrics.update(kept)
 
 
 def test_a_one_device_engine_books_nothing_and_fetches_no_text(

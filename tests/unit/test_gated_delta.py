@@ -4,7 +4,11 @@ delta_rule``), forward and every gradient; the fused kernels
 (``ops/pallas/gated_delta.py``, PR 49: preparation and scan in one body) in
 the interpreter against the XLA form and the recurrence; the ungated causal
 filter (``ops/short_conv.py causal_conv_rows``) against a loop over its
-taps.
+taps.  Since PR 52 also key heads of ``dk`` and value heads of ``dv``
+channels (states ``dk x dv``, neither a multiple of 128 lanes: the kernels
+read them in lane slots) under ``beta`` in (0, 2), against
+``benchmark/reference/olmo_hybrid.py delta_rule``, and the 128 x 128 case
+against the parent's kernels kept as ``_gated_delta_parent.py``.
 """
 import jax
 import jax.numpy as jnp
@@ -20,6 +24,7 @@ from deepspeed_tpu.ops.short_conv import causal_conv_rows
 from deepspeed_tpu.telemetry import get_registry
 
 reference = load_module(ROOT, "reference", "qwen3next")
+wide = load_module(ROOT, "reference", "olmo_hybrid")    # dk != dv
 NAMES = ("q", "k", "v", "g", "beta")
 
 
@@ -186,7 +191,7 @@ def test_the_kernels_in_the_interpreter_are_the_xla_form(Hk, Hv):
     as near the per-token recurrence as it is."""
     from deepspeed_tpu.ops.pallas import gated_delta as kernel
 
-    assert kernel.supported(8, 32, 128, jnp.bfloat16, Hv // Hk) is None
+    assert kernel.supported(8, 32, 128, 128, jnp.bfloat16, Hv // Hk) is None
     args = _bf16(_inputs(2, 256, Hk, Hv, 128))
     probe = jnp.asarray(np.random.default_rng(3).standard_normal(
         (2, 256, Hv * 128)), jnp.float32)
@@ -265,7 +270,7 @@ def test_the_inverse_in_the_kernels_against_numpy():
 
 @pytest.mark.parametrize("n,chunk,d,dtype,r,said", [
     (8, 32, 128, jnp.float32, 1, "operands of float32"),
-    (8, 32, 64, jnp.bfloat16, 1, "head channels 64 are no multiple of 128"),
+    (8, 32, 512, jnp.bfloat16, 4, "4 states of 512 x 512 float32"),
     (8, 16, 128, jnp.bfloat16, 1, "chunks of 16 positions"),
     (8, 256, 128, jnp.bfloat16, 1, "chunks of 256 positions"),
     (6, 32, 128, jnp.bfloat16, 1, "6 chunks are no whole groups of 4"),
@@ -275,7 +280,7 @@ def test_what_the_kernels_refuse_falls_to_xla_and_says_why(n, chunk, d, dtype,
                                                            r, said):
     from deepspeed_tpu.ops.pallas import gated_delta as kernel
 
-    assert said in kernel.supported(n, chunk, d, dtype, r)
+    assert said in kernel.supported(n, chunk, d, d, dtype, r)
     args = [x.astype(dtype) if i < 3 else x
             for i, x in enumerate(_inputs(1, n * chunk, 1, r, d))]
     with pytest.raises(NotImplementedError, match=said):
@@ -317,6 +322,139 @@ def test_the_dispatch_and_the_chunks_are_booked():
     assert after["bwd"] - before.get("bwd", 0) == 8     # again, and back
     assert any(site == "gated_delta" and impl == "xla" and n
                for site, impl, _, n in dispatch_report())
+
+
+# ----------------------------------------------------------------------
+# dk != dv (PR 52)
+# ----------------------------------------------------------------------
+def _wide_inputs(B, S, Hk, Hv, dk, dv, seed=0):
+    """As :func:`_inputs` at key heads of ``dk`` and value heads of ``dv``
+    channels, ``beta = 2 sigmoid(.)`` drawn so that a third lies above 1.5
+    and ``g`` small enough that a state lives for tens of positions."""
+    rng = np.random.default_rng(seed)
+
+    def unit(x, H, d):
+        x = x.reshape(B, S, H, d)
+        return (x / np.sqrt((x * x).sum(-1, keepdims=True) + 1e-6)).reshape(
+            B, S, H * d)
+
+    q = unit(rng.standard_normal((B, S, Hk * dk)), Hk, dk) * dk ** -0.5
+    k = unit(rng.standard_normal((B, S, Hk * dk)), Hk, dk)
+    v = rng.standard_normal((B, S, Hv * dv))
+    g = -np.abs(rng.standard_normal((B, S, Hv))) * 0.1
+    beta = 2.0 / (1.0 + np.exp(-rng.standard_normal((B, S, Hv)) * 2.0 - 0.3))
+    assert 0.2 < (beta > 1.5).mean() < 0.6 and beta.max() > 1.9
+    return tuple(jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta))
+
+
+def _wide_recurrence(Hk):
+    def run(q, k, v, g, beta):
+        B, S, Hv = g.shape
+        r = Hv // Hk
+        q, k = (jnp.repeat(x.reshape(B, S, Hk, -1), r, axis=2)
+                for x in (q, k))
+        return wide.delta_rule(q, k, v.reshape(B, S, Hv, -1), g,
+                               beta).reshape(v.shape)
+    return run
+
+
+def _value_and_grads(rule, probe):
+    return jax.jit(jax.value_and_grad(lambda *a: (
+        rule(*a).astype(jnp.float32) * probe).sum(), range(5)))
+
+
+@pytest.mark.parametrize("Hk,Hv,dk,dv", [(2, 2, 12, 24), (1, 2, 20, 8)])
+def test_the_xla_form_at_unequal_widths_is_the_recurrence(Hk, Hv, dk, dv):
+    """Float32, chunks of 16, ``beta`` in (0, 2) with a third above 1.5, 1
+    and 2 value heads a key head: the forward and all five cotangents, and
+    through the public call (``key_heads``), which books the state's shape."""
+    args = _wide_inputs(2, 64, Hk, Hv, dk, dv)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (2, 64, Hv * dv)), jnp.float32)
+    got = _value_and_grads(lambda *a: gated_delta_rule(
+        *a, chunk=16, key_heads=Hk), probe)(*args)
+    want = _value_and_grads(_wide_recurrence(Hk), probe)(*args)
+    assert abs(float(got[0]) - float(want[0])) < 2e-5 * 64 * dv
+    for name, a, b in zip(NAMES, got[1], want[1]):
+        assert _rel(a, b) < 2e-5, name
+    family = get_registry().snapshot()["gated_delta_state_elems"]
+    assert {(s["labels"]["dk"], s["labels"]["dv"]): s["value"]
+            for s in family["samples"]}[(str(dk), str(dv))] == dk * dv
+    with pytest.raises(ValueError, match="key_heads 7"):
+        gated_delta_rule(*args, chunk=16, key_heads=7)
+
+
+@pytest.mark.parametrize("Hk,Hv,dk,dv", [(1, 1, 24, 136), (1, 2, 40, 24)])
+def test_the_kernels_take_heads_that_are_no_lane_tiles_in_slots(Hk, Hv, dk,
+                                                                dv):
+    """The kernels in the interpreter at key and value heads of different
+    widths, neither a multiple of the 128 lanes (slots of 128 x 256 and of
+    128 x 128), ``beta`` in (0, 2), 1 and 2 value heads a key head, 4 chunks
+    of 32: the forward and the five cotangents are the XLA form's to the
+    rounding of bf16 operands and as near the per-token recurrence as it
+    is; nothing of a slot's zeros comes back."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert kernel.supported(4, 32, dk, dv, jnp.bfloat16, Hv // Hk) is None
+    assert (kernel._slot(dk), kernel._slot(dv)) == (128, -(-dv // 128) * 128)
+    args = _bf16(_wide_inputs(1, 128, Hk, Hv, dk, dv, seed=4))
+    probe = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (1, 128, Hv * dv)), jnp.float32)
+
+    def rule(how):
+        return lambda *a: ops._rule(*a, 32, how, Hk)
+
+    (want, want_g), (got, got_g) = (
+        _value_and_grads(rule(how), probe)(*args) for how in (None, True))
+    assert abs(float(got) - float(want)) < 4e-3 * abs(float(want))
+    exact = _value_and_grads(_wide_recurrence(Hk), probe)(
+        *(x.astype(jnp.float32) for x in args))[1]
+    for name, a, b, c in zip(NAMES, got_g, want_g, exact):
+        assert a.shape == c.shape and a.dtype == b.dtype, name
+        assert _rel(a, b) < 8e-3, name
+        assert _rel(a, c) < max(8e-3, 1.2 * _rel(b, c)), name
+
+
+def test_square_heads_of_128_trace_to_the_parents_kernels_bit_for_bit():
+    """``train-qwen3next-gdn-8k-1chip``'s rule (16 key heads x 2 value heads
+    of 128, chunks of 64) traces to the same program as the parent's
+    kernels (``_gated_delta_parent.py``: before ``(dk, dv)`` and the slots),
+    forward, saved states and backward, primitive for primitive with the
+    same blocks, grid and cost; and at a small shape of the same kind the
+    outputs are the parent's to the bit."""
+    import re
+
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    from . import _gated_delta_parent as parent
+
+    def traced(mod, shapes, **kw):
+        def both(q, k, v, g, beta, do):
+            return (mod.forward(q, k, v, g, beta, **kw),
+                    mod.backward(q, k, v, g, beta, do, **kw))
+        text = str(jax.make_jaxpr(both)(*shapes))
+        return re.sub(r" at /[^ \n]*|name=\w+", "", text)
+
+    def shapes(B, S, Hk, Hv, d):
+        return tuple(jax.ShapeDtypeStruct(s, t) for s, t in (
+            ((B, S, Hk * d), jnp.bfloat16), ((B, S, Hk * d), jnp.bfloat16),
+            ((B, S, Hv * d), jnp.bfloat16), ((B, S, Hv), jnp.float32),
+            ((B, S, Hv), jnp.float32), ((B, S, Hv * d), jnp.bfloat16)))
+
+    cell = shapes(1, 8192, 16, 32, 128)
+    assert traced(kernel, cell, chunk=64) == traced(parent, cell, chunk=64)
+    assert traced(kernel, cell, chunk=64, key_heads=16) \
+        == traced(parent, cell, chunk=64)
+    args = _bf16(_inputs(1, 128, 1, 2, 128, seed=11))
+    do = jnp.asarray(np.random.default_rng(12).standard_normal(
+        (1, 128, 256)), jnp.bfloat16)
+    for got, want in zip(
+            kernel.backward(*args, do, chunk=32, interpret=True)
+            + (kernel.forward(*args, chunk=32, interpret=True),),
+            parent.backward(*args, do, chunk=32, interpret=True)
+            + (parent.forward(*args, chunk=32, interpret=True),)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 @pytest.mark.parametrize("L,activation", [(4, "silu"), (3, None), (1, "silu")])

@@ -375,7 +375,13 @@ def _both_gathers(M):
 @pytest.mark.parametrize("live", LIVE)
 @pytest.mark.parametrize("M", [M for M, _ in SHAPES])
 def test_gather_equals_the_parents_bit_for_bit(M, live, scaled):
-    new, parent, want = _both_gathers(M)[live, scaled]
+    """At a quarter of each cell's row width, in the whole 256 channels the
+    kernels take (512 and 768; the ids keep the cells' 2048 and 2304): which rows the stream moves, in
+    which order and behind which wait is what the two builds could differ
+    in, and no row's width enters it; interpreted, a call's time goes by the
+    bytes it copies (PR 52: 155 s and 142 s of the suite at the full widths,
+    a tenth of that here)."""
+    new, parent, want = _both_gathers(-(-M // 1024) * 256)[live, scaled]
     assert new.shape == parent.shape and new.shape[0] >= live
     np.testing.assert_array_equal(new, parent)
     np.testing.assert_array_equal(new[:live], want)

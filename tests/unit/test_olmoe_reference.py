@@ -318,7 +318,21 @@ def test_engine_trains_olmoe_and_feeds_the_routing_counters():
     import deepspeed_tpu
     from deepspeed_tpu.telemetry import get_registry
 
-    get_registry().clear()
+    # emptied for this test, and as it was after it: what another test's
+    # module registered once (the goodput gauges) must outlive this one
+    reg = get_registry()
+    with reg._lock:
+        kept = dict(reg._metrics)
+        reg._metrics.clear()
+    try:
+        _trains_and_feeds_the_counters(deepspeed_tpu, get_registry)
+    finally:
+        with reg._lock:
+            reg._metrics.clear()
+            reg._metrics.update(kept)
+
+
+def _trains_and_feeds_the_counters(deepspeed_tpu, get_registry):
     _, cfg, _, _ = build(loss_chunk=32)
     engine, _, _, _ = deepspeed_tpu.initialize(
         model=LlamaForCausalLM(cfg), config={

@@ -162,7 +162,7 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids):
      "scan_layers=True with a linear_attention layer"),
     (dict(linear_num_value_heads=3), ValueError,
      "linear_num_value_heads 3 is no multiple of linear_num_key_heads 2"),
-    (dict(linear_key_head_dim=16), NotImplementedError, "square states"),
+    (dict(linear_key_head_dim=0), ValueError, "at least one channel a head"),
     (dict(linear_conv_kernel_dim=0), ValueError, "at least one tap"),
     (dict(partial_rotary_factor=0.0), ValueError, "a share in \\(0, 1\\]"),
     (dict(partial_rotary_factor=0.5, rope_interleave=True,

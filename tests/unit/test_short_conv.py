@@ -145,13 +145,15 @@ def _thirds(bcu):
     return bcu[..., :C], bcu[..., C:2 * C], bcu[..., 2 * C:]
 
 
-@pytest.mark.parametrize("form,L,S", [
-    ("gated", 2, 2 * kernel.BLOCK), ("gated", 3, 3 * kernel.BLOCK),
-    ("gated", 4, 2 * kernel.BLOCK), ("silu", 4, 3 * kernel.BLOCK),
-    ("plain", 3, 2 * kernel.BLOCK), ("silu", 1, 2 * kernel.BLOCK)])
-def test_the_row_kernels_match_the_loop_across_blocks(form, L, S):
+@pytest.mark.parametrize("form,L,S,C", [
+    ("gated", 2, 2 * kernel.BLOCK, 512), ("gated", 3, 3 * kernel.BLOCK, 512),
+    ("gated", 4, 2 * kernel.BLOCK, 512), ("silu", 4, 3 * kernel.BLOCK, 512),
+    ("plain", 3, 2 * kernel.BLOCK, 512), ("silu", 1, 2 * kernel.BLOCK, 512),
+    # whole lane tiles, no multiple of 512 (PR 52): three chunks of 384 lanes
+    ("silu", 4, 2 * kernel.BLOCK, 1152)])
+def test_the_row_kernels_match_the_loop_across_blocks(form, L, S, C):
     run, loop, thirds, room = FORMS[form]
-    rows, w, probe = _rows(L, S, thirds=thirds)
+    rows, w, probe = _rows(L, S, C=C, thirds=thirds)
     got = run(rows, w)
     want = loop(rows, w)
     np.testing.assert_allclose(got, want, atol=2e-5 * room)
@@ -209,6 +211,15 @@ def test_which_shapes_the_kernels_take():
     assert kernel._grid(4, 8192, 2048, True) == ((4, 32), 2048)
     assert kernel._grid(3, 8192, 8192, False) == ((3, 32, 4), 2048)
     assert kernel._grid(2, 512, 2560, False) == ((2, 2, 5), 512)
+    # ... and by the widest lanes that divide a block (Olmo-Hybrid's 11,520
+    # channels of [q ; k ; v] are 90 lane tiles, 22.5 x 512)
+    assert kernel.supported(8192, 11520, 4, jnp.bfloat16, gated=False) is None
+    assert "multiple of 512" in kernel.supported(8192, 11520, 4, jnp.bfloat16)
+    assert "multiple of 128" in kernel.supported(8192, 11456, 4, jnp.bfloat16,
+                                                 gated=False)
+    assert kernel._grid(2, 8192, 11520, False) == ((2, 32, 6), 1920)
+    assert [kernel._width(c) for c in (2048, 1920, 1152, 1280, 640)] \
+        == [512, 384, 384, 256, 128]
 
 
 def gated_trace():
@@ -307,7 +318,7 @@ def test_what_the_ungated_call_refuses_and_books():
     np.testing.assert_allclose(
         got, causal_conv_rows(rows, taps, "silu", "shift"), atol=4e-4)
     after = _booked()
-    for key, n in ((("shift", "16 channels are no multiple of 512"), 1),
+    for key, n in ((("shift", "16 channels are no multiple of 128"), 1),
                    (("shift", "no TPU"), 1),
                    (("shift", "impl='shift' asked for"), 2),
                    (("pallas", "ungated, rows 256 x 512, 4 taps, silu; "

@@ -757,6 +757,7 @@ def test_block_diffusion_leaves_nothing_of_the_own_block_to_xla(
     assert compiled.count("self_attn_blockdiff") >= 2 * layers
 
 
+@pytest.mark.slow
 def test_the_sixth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     """``train-sdar-blockdiff-8k-1chip`` (PR 40) as the benchmark builds it,
     its whole train step compiled for the described chip: Mosaic takes the
@@ -933,6 +934,7 @@ def test_the_ungated_conv_kernels_compile_at_the_eighth_cells_shape(
         for s, i, r, n in dispatch_report() if n)
 
 
+@pytest.mark.slow
 def test_the_seventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     """``train-lfm2-hybrid-8k-1chip`` (PR 45) as the benchmark builds it,
     its whole train step compiled for the described chip: three kinds of
@@ -1010,7 +1012,7 @@ def test_the_gated_delta_kernels_compile_at_the_eighth_cells_shape(one_chip):
 
     B, S, Hk, Hv, d, C = 1, 8192, 16, 32, 128, 64
     N = S // C
-    assert kernel.supported(N, C, d, jnp.bfloat16, Hv // Hk) is None
+    assert kernel.supported(N, C, d, d, jnp.bfloat16, Hv // Hk) is None
 
     def sd(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -1115,3 +1117,116 @@ def test_the_eighth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     sites = {(s, i) for s, i, _, n in dispatch_report() if n}
     assert {("attention", "flash"), ("gated_delta", "pallas"),
             ("moe_rows", "pallas"), ("short_conv", "pallas")} <= sites, sites
+
+
+def test_the_kernels_compile_at_the_ninth_cells_shape(one_chip, monkeypatch):
+    """``train-olmo-hybrid-8k-1chip``'s two new shapes (PR 52), one row as
+    the layer walks it.  The delta rule at 30 key heads of 96 and 30 value
+    heads of 192 channels reaches the kernels in lane slots: the custom
+    calls read ``bf16[1,8192,3840]`` (30 x 128) and ``[1,8192,7680]`` (30 x
+    256), the saved states are ``f32[1,30,128,128,256]``, and ``o`` and the
+    cotangents come back at 2880 and 5760.  The filter over the 11,520
+    channels of ``[q ; k ; v]`` (90 lane tiles, 22.5 x 512) goes by six
+    blocks of 1920 channels, 384 lanes a chunk."""
+    import re
+
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops import gated_delta as ops
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+    from deepspeed_tpu.ops.pallas import short_conv
+    from deepspeed_tpu.ops.short_conv import causal_conv_rows
+
+    B, S, H, dk, dv, C = 1, 8192, 30, 96, 192, 64
+    N = S // C
+    assert kernel.supported(N, C, dk, dv, jnp.bfloat16, 1) is None
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((B, S, H * dk), jnp.bfloat16), sd((B, S, H * dk), jnp.bfloat16),
+            sd((B, S, H * dv), jnp.bfloat16), sd((B, S, H), jnp.float32),
+            sd((B, S, H), jnp.float32))
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: ops._rule(*a, C, False, H).astype(jnp.float32).sum(),
+        range(5))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(re.findall(r"gated_delta_fwd[.\d]* = ", text)) == 2
+    assert len(re.findall(r"gated_delta_bwd[.\d]* = ", text)) == 1
+    assert len(re.findall(r"gated_delta_fwd[.\d]* = " + re.escape(
+        f"f32[{B},{H},{N},128,256]"), text)) == 1             # kept once
+    assert f"bf16[{B},{S},{H * 128}]" in text and f"bf16[{B},{S},{H * 256}]" \
+        in text
+    # the filter
+    Cq = 2 * H * dk + H * dv
+    assert short_conv._grid(2, S, Cq, False) == ((2, S // short_conv.BLOCK, 6),
+                                                 1920)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        text = jax.jit(jax.value_and_grad(
+            lambda x, w: causal_conv_rows(x, w, "silu").astype(
+                jnp.float32).sum(), argnums=(0, 1))).lower(
+            sd((2, S, Cq), jnp.bfloat16), sd((Cq, 4), jnp.float32)
+        ).compile().as_text()
+    finally:
+        mesh_lib.set_mesh(None)
+    calls = set(re.findall(r"%(\w+?)[.\d]* = [^=]*? custom-call\(", text))
+    assert calls == {"causal_conv_rows", "causal_conv_rows_back"}, calls
+
+
+@pytest.mark.slow
+def test_the_ninth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-olmo-hybrid-8k-1chip`` (PR 52) as the benchmark builds it, its
+    whole train step compiled for the described chip: four blocks under the
+    reordered norm, three Gated DeltaNet layers at 96 x 192 states (the
+    kernels a row at a time: once in the text a pass) behind the Pallas
+    filter over 11,520 channels, one position-free attention layer through
+    flash at 30 heads on 30, a dense SwiGLU everywhere; 928,862,196
+    parameters in the leaves; and what the step reserves stays under the
+    chip's 15.75 GiB.  Marked slow, as the eighth's is: ~1 minute of
+    compile; ``compile_said`` in the configuration file holds its
+    reading."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-olmo-hybrid-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.kinds.count("linear_attention")) == (2, 8192, 3)
+    assert sum(int(x.size) for x in jax.tree_util.tree_leaves(
+        state.params)) == 928_862_196
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 10.0 < reserved < 15.75, reserved
+    text = compiled.as_text()
+    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
+    assert "self_attn_full" in text
+    assert len(re.findall(r"causal_conv_rows[.\d]* = ", text)) == 6
+    assert len(re.findall(r"causal_conv_rows_back[.\d]* = ", text)) == 3
+    sites = {(s, i) for s, i, _, n in dispatch_report() if n}
+    assert {("attention", "flash"), ("gated_delta", "pallas"),
+            ("short_conv", "pallas")} <= sites, sites
