@@ -1040,6 +1040,108 @@ def kernel_gated_delta(time_it: bool = True, wide: bool = False):
                           f"{np.mean(ns) / 1e6:.3f} ms a row", flush=True)
 
 
+def kernel_gated_norm(time_it: bool = True):
+    """A Gated DeltaNet layer's gated output norm on the rows (PR 53;
+    ``ops/pallas/qk_rows.py gated_norm_rows`` behind ``ops/rotary.py
+    gated_norm_plan``) at the eighth cell's shape, ``(3, 8192, 32 heads of
+    128)``: ``y = rms_norm(o, w, eps) * silu(z)``, forward and ``jax.vjp``
+    (``do``, ``dz``, ``dw``), beside the model's own lines on the ``(B, S,
+    H, d)`` float32 view, both against the same arithmetic in float64 on
+    the host, every row of the batch held apart.  In bf16 (what the cell
+    runs) the result's one rounding decides; in float32 the kernel's
+    sigmoid (the approximate reciprocal and one Newton step) stands against
+    XLA's division.  Timed in bf16: forward + backward against the least
+    the HBM rate allows for the eight vectors the two passes move, and the
+    two custom calls one by one from a profiler trace."""
+    import tempfile
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.models.common import rms_norm
+    from deepspeed_tpu.ops import rotary
+
+    B, S, H, d, eps = 3, 8192, 32, 128, 1e-6
+    ks = jax.random.split(jax.random.PRNGKey(53), 4)
+    w = 1 + 0.2 * jax.random.normal(ks[3], (d,), jnp.float32)
+    plan = rotary.gated_norm_plan(
+        jax.ShapeDtypeStruct((B, S, H * d), jnp.bfloat16), d)
+    assert plan == ("direct", None), plan
+
+    def rows(o, z, w):
+        return rotary.gated_norm_rows(o, z, w, d, plan, eps=eps)
+
+    def view(o, z, w):
+        y = rms_norm(o.reshape(B, S, H, d).astype(jnp.float32), w, eps)
+        return (y * jax.nn.silu(z.reshape(B, S, H, d).astype(jnp.float32))
+                ).astype(o.dtype).reshape(o.shape)
+
+    def float64(o, z, w, dy):
+        """``(y, do, dz, dw)`` of one row of the batch."""
+        o, z, dy = (np.asarray(t, np.float64).reshape(S, H, d)
+                    for t in (o, z, dy))
+        w = np.asarray(w, np.float64)
+        inv = 1.0 / np.sqrt((o * o).mean(-1, keepdims=True) + eps)
+        unit, s = o * inv, 1.0 / (1.0 + np.exp(-z))
+        g = dy * z * s
+        dz = dy * unit * w * s * (1.0 + z * (1.0 - s))
+        gw = g * w
+        do = inv * (gw - unit * (gw * unit).mean(-1, keepdims=True))
+        return [t.reshape(S, H * d) for t in (unit * w * z * s, do, dz)] \
+            + [(g * unit).sum((0, 1))]
+
+    for dtype in (jnp.bfloat16, jnp.float32):
+        o, z, dy = (jax.random.normal(kk, (B, S, H * d), jnp.float32)
+                    .astype(dtype) for kk in ks[:3])
+        z = (2 * z.astype(jnp.float32)).astype(dtype)   # gates out to +-8
+        want = [float64(o[r], z[r], w, dy[r]) for r in range(B)]
+
+        def both(fn):
+            def run(o, z, w):
+                out, vjp = jax.vjp(fn, o, z, w)
+                return (out,) + vjp(dy)
+            return jax.jit(run)
+
+        for impl, fn in (("pallas", rows), ("view", view)):
+            run = both(fn)
+            got = jax.block_until_ready(run(o, z, w))
+            for i, n in enumerate(("y", "do", "dz")):
+                g = np.asarray(got[i], np.float64)
+                err = [np.abs(g[r] - want[r][i]) for r in range(B)]
+                size = [np.abs(want[r][i]).max() for r in range(B)]
+                worst = max(e.max() / m for e, m in zip(err, size))
+                mean = max(e.mean() / m for e, m in zip(err, size))
+                print(f"  gated_norm {dtype.__name__} {impl} {n}: |. - "
+                      f"float64| / max worst of {B} rows {worst:.2e}, mean "
+                      f"{mean:.2e}", flush=True)
+                assert np.isfinite(g).all() and worst <= TOL, (impl, n, worst)
+            _check_close(f"gated_norm {dtype.__name__} {impl} dw", got[3],
+                         sum(want[r][3] for r in range(B)))
+            if not time_it or dtype != jnp.bfloat16:
+                continue
+            t0 = time.perf_counter()
+            for _ in range(20):
+                out = run(o, z, w)
+            jax.block_until_ready(out)
+            ms = (time.perf_counter() - t0) / 20 * 1e3
+            print(f"  gated_norm {impl}: forward + backward {ms:.3f} ms "
+                  f"(least for 8 vectors of bf16 at 819 GB/s: "
+                  f"{8 * B * S * H * d * 2 / 819e9 * 1e3:.3f} ms)", flush=True)
+            if impl == "pallas":
+                out = tempfile.mkdtemp(prefix="gated_norm_trace_")
+                with jax.profiler.trace(out):
+                    for _ in range(5):
+                        jax.block_until_ready(run(o, z, w))
+                for call, ns in sorted(_traced_op_times(out).items()):
+                    if "gated_norm" in call:
+                        print(f"  gated_norm pallas: {call} {len(ns)} calls, "
+                              f"{np.mean(ns) / 1e6:.3f} ms a call",
+                              flush=True)
+        del want, got
+
+
 def kernel_flash_two_products():
     """The fifth cell's attention at the cell's own shape
     (``train-joyai-flash-8k-1chip``: 2 rows of 8192 tokens, 32 heads of 128
@@ -1371,7 +1473,8 @@ def kernel_short_conv(time_it: bool = True):
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
                 kernel_flash_lanes_256, kernel_gated_delta,
-                kernel_gated_delta_wide, kernel_qk_rows, kernel_short_conv,
+                kernel_gated_delta_wide, kernel_gated_norm, kernel_qk_rows,
+                kernel_short_conv,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,

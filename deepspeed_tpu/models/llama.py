@@ -116,7 +116,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.rotary import apply_rotary_pos_emb, rotate_rows, rows_plan
+from ..ops.rotary import (apply_rotary_pos_emb, gated_norm_plan,
+                          gated_norm_rows, rotate_rows, rows_plan)
 from ..telemetry import trace
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
@@ -863,7 +864,20 @@ class GatedDeltaNet(nn.Module):
     two products (``[q | k | v]`` for the filter, ``z`` for the gate): each
     lands where its reader wants it.  No positional encoding, no bias;
     every row of a batch starts from a zero state and an empty filter.
-    The channels shard as the attention projections' do."""
+    The channels shard as the attention projections' do.
+
+    Both per-head norms stand between kernels that read and write ``(B, S,
+    H d)`` rows (the filter before, the rule between, ``out_proj`` after),
+    and on the chip the ``(B, S, H, d)`` float32 view they are written on
+    above is no bitcast of such rows: each cost a copy in and a reshape
+    out, forward, recomputation and backward.  So where a head is whole
+    lane tiles (128 x 128: Qwen3-Next) both are made on the rows by the
+    kernels of ``ops/pallas/qk_rows.py``: q and k by ``rotate_rows`` under
+    constant scales and no positions (``ops/rotary.py rows_plan``), the
+    gated norm by ``gated_norm_rows`` (``gated_norm_plan``).  Heads of 96 /
+    192 channels (Olmo-Hybrid), the CPU and a mesh that refuses keep the
+    view; ``kernel_dispatch_total{site="qk_rows" | "gated_norm_rows"}``
+    says which, and why."""
     cfg: LlamaConfig
 
     @nn.compact
@@ -903,8 +917,18 @@ class GatedDeltaNet(nn.Module):
                 return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True)
                                          + 1e-6)
 
-            q = (unit(qkv[..., :Hk * dk], Hk) * dk ** -0.5).astype(cfg.dtype)
-            k = unit(qkv[..., Hk * dk:2 * Hk * dk], Hk).astype(cfg.dtype)
+            rows = jax.ShapeDtypeStruct((B, S, Hk * dk), qkv.dtype)
+            plan = rows_plan(rows, rows, dk, norm=True)
+            if plan is not None:
+                # x / |x| = rms_norm(x, dk^-1/2, eps / dk), on the rows
+                q, k = rotate_rows(
+                    qkv[..., :Hk * dk], qkv[..., Hk * dk:2 * Hk * dk], None,
+                    dk, plan, q_scale=jnp.full((dk,), 1 / dk, f32),
+                    k_scale=jnp.full((dk,), dk ** -0.5, f32), eps=1e-6 / dk)
+            else:
+                q = (unit(qkv[..., :Hk * dk], Hk) * dk ** -0.5).astype(
+                    cfg.dtype)
+                k = unit(qkv[..., Hk * dk:2 * Hk * dk], Hk).astype(cfg.dtype)
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
             if cfg.linear_allow_neg_eigval:
                 beta = 2.0 * beta
@@ -917,14 +941,18 @@ class GatedDeltaNet(nn.Module):
         w_o = self.param("o_norm", nn.with_partitioning(
             nn.initializers.ones, ("head_dim",)), (d,), cfg.param_dtype)
         with trace.device_span("linear_attn/gated_norm"):
-            from .common import rms_norm
-
             # norm first, gate second; w from ones whatever the other
             # norms of the model are
-            y = rms_norm(o.reshape(B, S, Hv, d).astype(f32), w_o,
-                         cfg.rms_norm_eps)
-            y = (y * jax.nn.silu(
-                z.reshape(B, S, Hv, d).astype(f32))).astype(cfg.dtype)
+            plan = gated_norm_plan(o, d)
+            if plan is not None:
+                y = gated_norm_rows(o, z, w_o, d, plan, eps=cfg.rms_norm_eps)
+            else:
+                from .common import rms_norm
+
+                y = rms_norm(o.reshape(B, S, Hv, d).astype(f32), w_o,
+                             cfg.rms_norm_eps)
+                y = (y * jax.nn.silu(
+                    z.reshape(B, S, Hv, d).astype(f32))).astype(cfg.dtype)
         with trace.device_span("linear_attn/out_proj"):
             return _dense(y.reshape(B, S, Hv * d), E, ("heads", "embed"),
                           cfg=cfg, name="out_proj", module=self)

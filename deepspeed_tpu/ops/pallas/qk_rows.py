@@ -37,6 +37,17 @@ Mellum 2 and +0.26 GB of temporaries, compiled for a described v5e.)  The HLO cu
 context, not once a layer and remat pass (``qk_rows_traces_total``
 counts), and lowers it once a module; inside a body the arithmetic of one
 head sits behind a ``jit`` of its own, traced once for all the heads.
+
+**Who else normalises here** (PR 53).  A Gated DeltaNet layer
+(``models/llama.py GatedDeltaNet``) has two per-head norms between kernels
+that read and write rows, and wrote both on the ``(B, S, H, d)`` float32
+view, where each cost a ``copy`` into the 4-D tiling and a ``reshape``
+back.  Its l2-norms of q and k are this file's norm under constant scales
+and no table (``x / |x| = rms_norm(x, d^-1/2, eps / d)``: no new body).
+Its output norm, ``rms_norm(o, w, eps) * silu(z)`` a value head, is the
+body :func:`_gated_kernel` beside the two above: the same blocks, chunks
+and :func:`_mean`, one more operand (the gate ``z``), custom calls
+``gated_norm_rows`` and ``gated_norm_rows_back`` (:func:`gated_norm_rows`).
 """
 from __future__ import annotations
 
@@ -50,6 +61,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...telemetry import registry as _registry
+from .short_conv import _dsilu, _silu
 
 # rows of a block that share one load of cos and sin
 CHUNK = 32
@@ -70,15 +82,20 @@ def block_rows(seq: int, width: int, itemsize: int, arrays: int
     return None
 
 
+def _refusal(seq: int, width: int, dtype, arrays: int) -> Optional[str]:
+    """``None`` where a pass over ``arrays`` rows of ``width`` lanes has a
+    block, else why not."""
+    if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return f"rows of {jnp.dtype(dtype).name}"
+    if block_rows(seq, width, jnp.dtype(dtype).itemsize, arrays) is None:
+        return f"sequence {seq} is no whole number of {CHUNK}-row chunks"
+    return None
+
+
 def supported(seq: int, q_width: int, k_width: int, dtype,
               norm: bool = False) -> Optional[str]:
     """``None`` where the kernels take rows of these widths, else why not."""
-    if jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
-        return f"rows of {jnp.dtype(dtype).name}"
-    if block_rows(seq, q_width + k_width, jnp.dtype(dtype).itemsize,
-                  3 if norm else 2) is None:
-        return f"sequence {seq} is no whole number of {CHUNK}-row chunks"
-    return None
+    return _refusal(seq, q_width + k_width, dtype, 3 if norm else 2)
 
 
 def _note_trace(kernel: str, *signature) -> None:
@@ -283,3 +300,128 @@ def _qk_rows_bwd(head_dim, eps, interpret, res, g):
 
 
 qk_rows.defvjp(_qk_rows_fwd, _qk_rows_bwd)
+
+
+# -- rms_norm(o, w, eps) * silu(z), a value head of a Gated DeltaNet ---------
+
+def gated_norm_supported(seq: int, width: int, dtype) -> Optional[str]:
+    """``None`` where :func:`gated_norm_rows` takes rows of this width,
+    else why not (the backward's five blocks decide)."""
+    return _refusal(seq, width, dtype, 5)
+
+
+def _gated_kernel(*refs, head_dim: int, backward: bool, eps: float):
+    """One block of rows of ``o`` and of the gate ``z``, then the scale
+    ``w`` (1, head_dim); forward the result ``y``; ``backward`` the
+    cotangent ``dy`` in, ``do``, ``dz`` and this block's ``dw`` out."""
+    o_ref, z_ref, w_ref, *rest = refs
+    dy_ref = rest.pop(0) if backward else None
+    rows = o_ref.shape[1]
+    ones = jnp.ones((head_dim, head_dim), jnp.bfloat16)
+    terms = 2 if o_ref.dtype == jnp.bfloat16 else 3
+
+    @jax.jit
+    def head(o, z, w, dy, total):
+        """A head's chunk in float32, traced once for all the heads."""
+        inv = lax.rsqrt(_mean(o * o, ones, terms) + eps)
+        unit = o * inv
+        if not backward:
+            return (unit * w * _silu(z),), total
+        g = dy * _silu(z)                   # the norm's cotangent
+        dz = dy * (unit * w) * _dsilu(z)
+        total = total + g * unit
+        g = g * w
+        return (inv * (g - unit * _mean(g * unit, ones, terms)), dz), total
+
+    def chunk(i, total):
+        r = pl.ds(pl.multiple_of(i * CHUNK, CHUNK), CHUNK)
+        w = w_ref[...]
+        for h in range(o_ref.shape[2] // head_dim):
+            at = (0, r, pl.ds(h * head_dim, head_dim))
+            outs, total = head(
+                o_ref[at].astype(jnp.float32), z_ref[at].astype(jnp.float32),
+                w, dy_ref[at].astype(jnp.float32) if backward else None,
+                total)
+            for ref, out in zip(rest, outs):
+                ref[at] = out.astype(ref.dtype)
+        return total
+
+    total = lax.fori_loop(
+        0, rows // CHUNK, chunk,
+        jnp.zeros((CHUNK, head_dim), jnp.float32) if backward else None)
+    if backward:
+        rest[2][0] = total.sum(axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "eps", "interpret"))
+def gated_norm_call(o, z, w, dy=None, *, head_dim: int, eps: float,
+                    interpret: bool = False):
+    """``y = rms_norm(o, w, eps) * silu(z)`` over each head of ``head_dim``
+    lanes of ``o`` and ``z`` (B, S, H * head_dim), ``w`` (head_dim,);
+    with the cotangent ``dy``: ``(do, dz, dw)``, ``dw`` float32."""
+    B, S, width = o.shape
+    if z.shape != o.shape or z.dtype != o.dtype:
+        raise ValueError(f"a gate of {z.shape} {z.dtype.name} for rows of "
+                         f"{o.shape} {o.dtype.name}")
+    if width % head_dim:
+        raise ValueError(f"rows of {width} lanes are no heads of {head_dim}")
+    backward = dy is not None
+    _note_trace("gated_norm_back" if backward else "gated_norm", o.shape,
+                o.dtype.name)
+    rows = block_rows(S, width, o.dtype.itemsize, 5 if backward else 3)
+    steps = S // rows
+    row_block = pl.BlockSpec((1, rows, width), lambda b, i: (b, i, 0))
+    rows_out = jax.ShapeDtypeStruct(o.shape, o.dtype)
+    operands = [o, z, w.astype(jnp.float32).reshape(1, head_dim)]
+    in_specs = [row_block, row_block,
+                pl.BlockSpec((1, head_dim), lambda b, i: (0, 0))]
+    out_specs, out_shape = [row_block], [rows_out]
+    if backward:    # a grid step's own sum of dw; XLA adds the steps up
+        operands.append(dy)
+        in_specs.append(row_block)
+        out_specs += [row_block, pl.BlockSpec(
+            (1, 1, head_dim), lambda b, i: (b * steps + i, 0, 0))]
+        out_shape += [rows_out, jax.ShapeDtypeStruct(
+            (B * steps, 1, head_dim), jnp.float32)]
+    outs = pl.pallas_call(
+        functools.partial(_gated_kernel, head_dim=head_dim,
+                          backward=backward, eps=eps),
+        grid=(B, steps), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        cost_estimate=pl.CostEstimate(
+            flops=(40 if backward else 16) * o.size,
+            transcendentals=o.size + o.size // head_dim,
+            bytes_accessed=(5 if backward else 3) * o.size
+            * o.dtype.itemsize),
+        name="gated_norm_rows_back" if backward else "gated_norm_rows",
+        interpret=interpret,
+    )(*operands)
+    if not backward:
+        return outs[0]
+    do, dz, dw = outs
+    return do, dz, dw.sum(axis=(0, 1))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def gated_norm_rows(o, z, w, head_dim: int, eps: float,
+                    interpret: bool = False):
+    """``rms_norm(o, w, eps) * silu(z)`` a head of :func:`gated_norm_call`,
+    differentiable in ``o``, ``z`` and ``w``."""
+    return gated_norm_call(o, z, w, head_dim=head_dim, eps=eps,
+                           interpret=interpret)
+
+
+def _gated_norm_fwd(o, z, w, head_dim, eps, interpret):
+    return gated_norm_rows(o, z, w, head_dim, eps, interpret), (o, z, w)
+
+
+def _gated_norm_bwd(head_dim, eps, interpret, res, dy):
+    o, z, w = res
+    do, dz, dw = gated_norm_call(o, z, w, dy, head_dim=head_dim, eps=eps,
+                                 interpret=interpret)
+    return do, dz, dw.astype(w.dtype)
+
+
+gated_norm_rows.defvjp(_gated_norm_fwd, _gated_norm_bwd)

@@ -21,6 +21,12 @@ axes).  Anything else - head_dim 64 / 80 / 96, ``rotary_dim < head_dim``,
 interleaved pairs, heads split over ``tp``, the CPU - keeps the ``(B, S,
 H, D)`` functions; ``kernel_dispatch_total{site="qk_rows"}`` counts each
 decision with the guard that made it.
+
+The same pass without positions is any per-head norm of rows: a Gated
+DeltaNet's l2-norms of q and k are ``rotate_rows`` under constant scales
+(PR 53), and its gated output norm has a guard and a pass of its own,
+:func:`gated_norm_plan` / :func:`gated_norm_rows`
+(``kernel_dispatch_total{site="gated_norm_rows"}``).
 """
 from __future__ import annotations
 
@@ -166,9 +172,7 @@ def rows_plan(q: jax.Array, k: jax.Array, head_dim: int, *,
     in ``kernel_dispatch_total{site="qk_rows"}``."""
     from .attention import on_tpu
     from .pallas import qk_rows
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
 
-    verdict = axes = None
     if head_dim % 128:
         reason = f"head_dim {head_dim} is no multiple of 128"
     elif rotary_dim not in (None, head_dim):
@@ -182,18 +186,51 @@ def rows_plan(q: jax.Array, k: jax.Array, head_dim: int, *,
     else:
         reason = qk_rows.supported(q.shape[1], q.shape[2], k.shape[2],
                                    q.dtype, norm)
-        if reason is None:
-            verdict, axes = kernel_mesh_plan(q.shape[0])
-            if verdict is None:
-                reason = "kernel_mesh_plan refused the mesh"
+    return _mesh_plan("qk_rows", reason, q.shape[0],
+                      f"head_dim {head_dim}, rows {q.shape[2]} + {k.shape[2]}")
+
+
+def _mesh_plan(site: str, reason: Optional[str], batch: int, rows: str
+               ) -> Optional[tuple]:
+    """What a row guard ends on: where no ``reason`` has refused the shape,
+    ``kernel_mesh_plan``'s verdict and batch axes for ``batch`` rows, or
+    None; booked under ``site`` with the guard that decided, or with
+    ``rows`` and how the mesh runs them."""
+    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+
+    verdict = axes = None
+    if reason is None:
+        verdict, axes = kernel_mesh_plan(batch)
+        if verdict is None:
+            reason = "kernel_mesh_plan refused the mesh"
     if reason is not None:
-        note_dispatch("qk_rows", "xla", reason)
+        note_dispatch(site, "xla", reason)
         return None
-    note_dispatch("qk_rows", "pallas",
-                  f"head_dim {head_dim}, rows {q.shape[2]} + {k.shape[2]}; "
-                  + ("one device" if verdict == "direct"
-                     else f"shard_map over batch axes {axes}"))
+    note_dispatch(site, "pallas", f"{rows}; " + (
+        "one device" if verdict == "direct"
+        else f"shard_map over batch axes {axes}"))
     return verdict, axes
+
+
+def _over_batch(kernel, plan: tuple, args: tuple, outs: int):
+    """``kernel(*args)`` under a ``plan``: directly on one device, else a
+    ``shard_map`` over the plan's batch axes.  Of ``args`` (None where
+    absent) those with the batch's leading dimension are split, the rest -
+    scales, a ``(1, S)`` table that serves every row - go to every rank."""
+    verdict, axes = plan
+    if verdict == "direct":
+        return kernel(*args)
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm.mesh import get_mesh
+
+    rows = P(axes if axes else None, None, None)
+    specs = tuple(None if a is None else rows
+                  if a.ndim == 3 and a.shape[0] == args[0].shape[0] else P()
+                  for a in args)
+    return jax.shard_map(kernel, mesh=get_mesh(), in_specs=specs,
+                         out_specs=(rows,) * outs if outs > 1 else rows,
+                         check_vma=False)(*args)
 
 
 def row_table(positions: jax.Array, head_dim: int, theta: float = 10000.0,
@@ -219,23 +256,40 @@ def rotate_rows(q: jax.Array, k: jax.Array, positions: Optional[jax.Array],
 
     angles = None if positions is None \
         else row_table(positions, head_dim, theta, table)
-    verdict, axes = plan
-    if verdict == "direct":
-        return qk_rows(q, k, angles, q_scale, k_scale, head_dim, eps,
-                       interpret)
-    from jax.sharding import PartitionSpec as P
+    return _over_batch(lambda *a: qk_rows(*a, head_dim, eps, interpret),
+                       plan, (q, k, angles, q_scale, k_scale), 2)
 
-    from ..comm.mesh import get_mesh
 
-    rows = P(axes if axes else None, None, None)
-    args = (q, k, angles, q_scale, k_scale)
-    # (1, S) positions serve every row: their table goes to every rank
-    specs = tuple(None if a is None else rows
-                  if a.ndim == 3 and a.shape[0] == q.shape[0] else P()
-                  for a in args)
-    return jax.shard_map(
-        lambda *a: qk_rows(*a, head_dim, eps, interpret), mesh=get_mesh(),
-        in_specs=specs, out_specs=(rows, rows), check_vma=False)(*args)
+def gated_norm_plan(o: jax.Array, head_dim: int) -> Optional[tuple]:
+    """Whether a Gated DeltaNet's output ``o`` (B, S, H*D) and its gate stay
+    rows through the gated per-head norm: as :func:`rows_plan`, for
+    :func:`gated_norm_rows`, counted in
+    ``kernel_dispatch_total{site="gated_norm_rows"}``."""
+    from .attention import on_tpu
+    from .pallas import qk_rows
+
+    if head_dim % 128:
+        reason = f"head_dim {head_dim} is no multiple of 128"
+    elif not on_tpu():
+        reason = "no TPU"
+    else:
+        reason = qk_rows.gated_norm_supported(o.shape[1], o.shape[2], o.dtype)
+    return _mesh_plan("gated_norm_rows", reason, o.shape[0],
+                      f"head_dim {head_dim}, rows {o.shape[2]}")
+
+
+def gated_norm_rows(o: jax.Array, z: jax.Array, w: jax.Array, head_dim: int,
+                    plan: tuple, *, eps: float, interpret: bool = False
+                    ) -> jax.Array:
+    """``rms_norm(o, w, eps) * silu(z)`` over each head of ``head_dim``
+    lanes of the rows ``o`` and ``z`` (B, S, H*D), ``w`` (D,), under a
+    ``plan`` of :func:`gated_norm_plan` (the Pallas pass
+    ``ops/pallas/qk_rows.py gated_norm_rows``, forward and backward).
+    Float32 arithmetic, rounded once."""
+    from .pallas.qk_rows import gated_norm_rows as kernel
+
+    return _over_batch(lambda *a: kernel(*a, head_dim, eps, interpret),
+                       plan, (o, z, w), 1)
 
 
 def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,

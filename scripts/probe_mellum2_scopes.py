@@ -81,12 +81,12 @@ def main():
     for scope, split in table["top"].items():
         for row in split["ops"] + split["instructions"]:
             print(json.dumps({"top": scope, **row}))
-    # what this process's traces resolved each q / k site to (init, the
-    # eval step and the train step: a layer is traced more than once)
+    # what this process's traces resolved each per-head norm site to (init,
+    # the eval step and the train step: a layer is traced more than once)
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 
     for site, impl, reason, count in dispatch_report():
-        if site == "qk_rows":
+        if site in ("qk_rows", "gated_norm_rows"):
             print(json.dumps({"site": site, "impl": impl, "reason": reason,
                               "count": count}))
     print(json.dumps(row_kernel_counters(engine)))

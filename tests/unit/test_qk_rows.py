@@ -5,6 +5,10 @@ PR 34), the kernels in interpret mode: the rotation against
 the per-head norm + rotation against ``rms_norm`` then ``apply_rotary``,
 every guard's reason, one trace a signature, the three families' attention
 layers with and without the rows path, and what a block's jaxpr holds.
+Since PR 53 also a Gated DeltaNet's two per-head norms: the l2-norm as the
+same pass under constant scales, the gated output norm's own pass
+(``gated_norm_plan`` / ``gated_norm_rows``), and the jaxpr digests of the
+layers of other families that run this code.
 """
 import functools
 
@@ -181,17 +185,23 @@ def test_head_norm_on_rows(rotate, dtype, part):
         _check(run, 0 if part == "values" else 1, index, dtype)
 
 
+def _booked(site, guard):
+    """``(plan, impl, reason)``: what ``guard()`` gave and the one row it
+    booked under ``site``."""
+    before = {r[:3]: r[3] for r in dispatch_report() if r[0] == site}
+    plan = guard()
+    new = [r[:3] for r in dispatch_report() if r[0] == site
+           and r[3] == before.get(r[:3], 0) + 1]
+    assert len(new) == 1
+    return plan, new[0][1], new[0][2]
+
+
 def _plan(monkeypatch, head_dim=128, tpu=True, heads=(4, 2), seq=S,
           dtype=jnp.bfloat16, **kw):
     monkeypatch.setattr(attention, "on_tpu", lambda: tpu)
     q = jax.ShapeDtypeStruct((B, seq, heads[0] * head_dim), dtype)
     k = jax.ShapeDtypeStruct((B, seq, heads[1] * head_dim), dtype)
-    before = {r[:3]: r[3] for r in dispatch_report() if r[0] == "qk_rows"}
-    plan = rotary.rows_plan(q, k, head_dim, **kw)
-    new = [r[:3] for r in dispatch_report() if r[0] == "qk_rows"
-           and r[3] == before.get(r[:3], 0) + 1]
-    assert len(new) == 1
-    return plan, new[0][1], new[0][2]
+    return _booked("qk_rows", lambda: rotary.rows_plan(q, k, head_dim, **kw))
 
 
 @pytest.mark.parametrize("case,kw,reason", [
@@ -202,6 +212,7 @@ def _plan(monkeypatch, head_dim=128, tpu=True, heads=(4, 2), seq=S,
     ("decode", dict(decode=True), "decode: the cache keeps (B, S, KV, D)"),
     ("cpu", dict(tpu=False), "no TPU"),
     ("int8", dict(dtype=jnp.int8), "rows of int8"),
+    ("float16", dict(dtype=jnp.float16), "rows of float16"),
     ("odd_rows", dict(seq=40), "sequence 40 is no whole number of 32-row "
                                "chunks"),
     ("mesh", dict(), "kernel_mesh_plan refused the mesh"),
@@ -256,6 +267,207 @@ def test_sharded_over_the_batch_it_matches_one_device():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32), rtol=2e-5,
                                    atol=1e-5)
+
+
+# -- a Gated DeltaNet's per-head norms (PR 53) -------------------------------
+
+def _unit(t, d):
+    """``GatedDeltaNet``'s l2-norm on the ``(B, S, H, d)`` float32 view."""
+    t = t.astype(jnp.float32).reshape(B, S, -1, d)
+    return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+
+@functools.lru_cache(maxsize=None)
+def _l2_run(d, dtype):
+    """As :func:`_run`: q and k to length 1 a head (q also by ``d^-1/2``) by
+    the rows pass under constant scales, by the 4-D lines, and by those on
+    float32 operands."""
+    dtype = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(d), 4)
+    q, k = (jax.random.normal(key, (B, S, 4 * d), jnp.float32).astype(dtype)
+            for key in ks[:2])
+    cts = tuple(jax.random.normal(key, q.shape, jnp.float32)
+                for key in ks[2:])
+
+    def today(q, k, _):
+        return ((_unit(q, d) * d ** -0.5).astype(q.dtype).reshape(q.shape),
+                _unit(k, d).astype(k.dtype).reshape(k.shape))
+
+    def rows(q, k, _):
+        return rotary.rotate_rows(
+            q, k, None, d, ("direct", None),
+            q_scale=jnp.full((d,), 1 / d, jnp.float32),
+            k_scale=jnp.full((d,), d ** -0.5, jnp.float32), eps=1e-6 / d,
+            interpret=True)
+
+    out = {}
+    for name, fn, cast in (("rows", rows, dtype), ("today", today, dtype),
+                           ("f32", today, jnp.float32)):
+        args = (q.astype(cast), k.astype(cast), None)
+        out[name] = (fn(*args), jax.grad(_weighted(fn, cts),
+                                         argnums=(0, 1))(*args))
+    return out
+
+
+@pytest.mark.parametrize("part", ["values", "grad"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("d", [128, 256])
+def test_constant_scales_make_the_l2_norm(d, dtype, part):
+    """``x / |x| = rms_norm(x, d^-1/2, eps / d)``: the norm pass with
+    ``q_scale = 1 / d``, ``k_scale = d^-1/2``, ``eps = 1e-6 / d`` and no
+    positions IS ``unit(q) d^-1/2`` and ``unit(k)`` of ``GatedDeltaNet``."""
+    run = _l2_run(d, dtype)
+    for index in (0, 1):
+        _check(run, 0 if part == "values" else 1, index, dtype)
+        if (dtype, part) == ("float32", "values"):
+            assert _worst(run["rows"][0][index], run["today"][0][index]) \
+                <= 1e-6
+
+
+GATED_EPS = 1e-6
+
+
+def _gated_operands(rows, dtype, d=D, heads=4):
+    ks = jax.random.split(jax.random.PRNGKey(rows), 4)
+    shape = (rows, S, heads * d)
+    o = jax.random.normal(ks[0], shape, jnp.float32).astype(dtype)
+    z = (2 * jax.random.normal(ks[1], shape, jnp.float32)).astype(dtype)
+    w = 1 + 0.2 * jax.random.normal(ks[2], (d,), jnp.float32)
+    return o, z, w, jax.random.normal(ks[3], shape, jnp.float32)
+
+
+def _gated_today(o, z, w, d=D):
+    """``GatedDeltaNet``'s lines on the ``(B, S, H, d)`` float32 view."""
+    four = (*o.shape[:2], -1, d)
+    y = rms_norm(o.reshape(four).astype(jnp.float32), w, GATED_EPS)
+    return (y * jax.nn.silu(z.reshape(four).astype(jnp.float32))).astype(
+        o.dtype).reshape(o.shape)
+
+
+def _gated_rows(o, z, w, d=D, plan=("direct", None)):
+    return rotary.gated_norm_rows(o, z, w, d, plan, eps=GATED_EPS,
+                                  interpret=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _gated_run(rows, dtype, d=D):
+    """``(y, do, dz, dw)`` of the kernels, of today's lines, and of those on
+    float32 operands."""
+    dtype = jnp.dtype(dtype)
+    o, z, w, ct = _gated_operands(rows, dtype, d)
+    out = {}
+    for name, fn, cast in (("rows", _gated_rows, dtype),
+                           ("today", _gated_today, dtype),
+                           ("f32", _gated_today, jnp.float32)):
+        call = lambda o, z, w, fn=fn: fn(o, z, w, d)
+        args = (o.astype(cast), z.astype(cast), w)
+        out[name] = (call(*args), *jax.grad(
+            lambda *a: (call(*a).astype(jnp.float32) * ct).sum(),
+            argnums=(0, 1, 2))(*args))
+    return out
+
+
+@pytest.mark.parametrize("part", ["y", "do", "dz", "dw"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("rows,d", [(1, 128), (3, 128), (2, 256)])
+def test_gated_norm_on_rows(rows, d, dtype, part):
+    """``rms_norm(o, w, eps) * silu(z)`` a head on the rows against the
+    lines on the ``(B, S, H, d)`` view: the result and the three gradients,
+    a scale that is not ones, one row and three, heads of 128 and 256."""
+    index = ("y", "do", "dz", "dw").index(part)
+    got, today, want = (_gated_run(rows, dtype, d)[n][index]
+                        for n in ("rows", "today", "f32"))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    size = float(np.abs(np.asarray(want)).max())
+    if part == "dw":
+        assert got.shape == (d,) and got.dtype == jnp.float32
+        assert _worst(got, want) <= 1e-2 * size
+        assert _worst(got, want) <= _worst(today, want) + 2e-3 * size
+        return
+    assert got.shape == want.shape and got.dtype == jnp.dtype(dtype)
+    # one rounding of the result (two for a gradient, as _check); in
+    # float32 the interpreter's approximate reciprocal, which it rounds to
+    # bfloat16 before the Newton step: 2^-17 of the sigmoid
+    one = size * (2.0 ** -8 if dtype == "bfloat16" else 2.0 ** -15)
+    assert _worst(got, want) <= one * (1 + (part != "y"))
+    assert _rms(got, want) <= _rms(today, want) + 4e-6 * size
+
+
+def test_gated_norm_sharded_over_the_batch_matches_one_device():
+    o, z, w, ct = _gated_operands(2, jnp.bfloat16)
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"fsdp": 2, "dp": 1},
+                                          devices=jax.devices()[:2]))
+    try:
+        def call(plan):
+            fn = lambda o, z, w: _gated_rows(o, z, w, plan=plan)
+            return fn(o, z, w), jax.grad(
+                lambda *a: (fn(*a).astype(jnp.float32) * ct).sum(),
+                argnums=(0, 1, 2))(o, z, w)
+
+        one, two = call(("direct", None)), call(("shard", ("fsdp",)))
+    finally:
+        mesh_lib.set_mesh(prev)
+    for a, b in zip(jax.tree_util.tree_leaves(one),
+                    jax.tree_util.tree_leaves(two)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=2e-5,
+                                   atol=1e-5)
+
+
+def test_a_gate_or_a_head_of_another_shape_is_refused():
+    o, z, w, _ = _gated_operands(2, jnp.bfloat16)
+    with pytest.raises(ValueError, match="a gate of"):
+        _gated_rows(o, z[:1], w)
+    with pytest.raises(ValueError, match="a gate of"):
+        _gated_rows(o, z.astype(jnp.float32), w)
+    with pytest.raises(ValueError, match="512 lanes are no heads of 384"):
+        _gated_rows(o, z, jnp.ones((384,)), d=384)
+
+
+def _gated_plan(monkeypatch, head_dim=128, tpu=True, heads=4, seq=S,
+                dtype=jnp.bfloat16):
+    monkeypatch.setattr(attention, "on_tpu", lambda: tpu)
+    o = jax.ShapeDtypeStruct((B, seq, heads * head_dim), dtype)
+    return _booked("gated_norm_rows",
+                   lambda: rotary.gated_norm_plan(o, head_dim))
+
+
+@pytest.mark.parametrize("case,kw,reason", [
+    ("head_dim_96", dict(head_dim=96), "head_dim 96 is no multiple of 128"),
+    ("head_dim_192", dict(head_dim=192),
+     "head_dim 192 is no multiple of 128"),
+    ("cpu", dict(tpu=False), "no TPU"),
+    ("float16", dict(dtype=jnp.float16), "rows of float16"),
+    ("odd_rows", dict(seq=40), "sequence 40 is no whole number of 32-row "
+                               "chunks"),
+    ("mesh", dict(), "kernel_mesh_plan refused the mesh"),
+])
+def test_the_gated_norms_guard_keeps_todays_lines_and_says_why(
+        monkeypatch, case, kw, reason):
+    prev = mesh_lib.get_mesh(required=False)
+    if case == "mesh":      # heads over tp: no batch-parallel kernel
+        mesh_lib.set_mesh(mesh_lib.build_mesh({"tp": 2, "dp": -1}))
+    try:
+        got = _gated_plan(monkeypatch, **kw)
+    finally:
+        mesh_lib.set_mesh(prev)
+    assert got == (None, "xla", reason)
+
+
+@pytest.mark.parametrize("mesh,verdict", [
+    (None, ("direct", None)), ({"fsdp": 2, "dp": 1}, ("shard", ("fsdp",)))])
+def test_the_gated_norms_plan_engages_on_one_devices_own_rows(
+        monkeypatch, mesh, verdict):
+    prev = mesh_lib.get_mesh(required=False)
+    devices = jax.devices()[:2 if mesh else 1]
+    mesh_lib.set_mesh(mesh_lib.build_mesh(mesh or {"dp": 1}, devices=devices))
+    try:
+        plan, impl, reason = _gated_plan(monkeypatch, head_dim=256)
+    finally:
+        mesh_lib.set_mesh(prev)
+    assert plan == verdict and impl == "pallas"
+    assert reason.startswith("head_dim 256, rows 1024; ")
 
 
 # -- the three families' attention layers -----------------------------------
@@ -460,6 +672,68 @@ def test_each_kernel_body_is_traced_once_a_signature():
     assert {kernel for kernel, _ in got} == {"fwd", "back"}
     # the plain context and the one under grad
     assert all(n <= 2 for n in got.values()), got
+
+
+def _who_else(case):
+    """``traced_digest`` of forward and gradient of one remat layer of a
+    family that runs the code PR 53 touched and must not feel it, as the
+    chip traces it (the guards see a TPU; nothing is lowered)."""
+    from deepspeed_tpu.models.llama import GatedDeltaNet
+    from tests.unit.flash_parent_sweep import traced_digest
+
+    if case == "olmo_hybrid_mixer":     # states of 96 x 192: both norms stay
+        cfg = LlamaConfig(
+            vocab_size=256, hidden_size=256, intermediate_size=256,
+            num_hidden_layers=1, num_attention_heads=2, head_dim=128,
+            max_position_embeddings=256, linear_num_key_heads=2,
+            linear_num_value_heads=2, linear_key_head_dim=96,
+            linear_value_head_dim=192, linear_conv_kernel_dim=4,
+            linear_allow_neg_eigval=True, scan_layers=False)
+        layer = GatedDeltaNet(cfg)
+        args = (jax.ShapeDtypeStruct((B, 256, cfg.hidden_size), cfg.dtype),)
+    else:                               # rotate_rows as its callers call it
+        cfg = _family(case[0])
+        layer = LlamaBlock(cfg, kind=case[1])
+        args = (jax.ShapeDtypeStruct((B, 128, cfg.hidden_size), cfg.dtype),
+                (jax.ShapeDtypeStruct((B, 128), jnp.int32), None))
+    params = meta.unbox(jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), *args)["params"])
+
+    @jax.checkpoint
+    def run(p, *a):
+        out = layer.apply({"params": p}, *a)
+        return out[0] if isinstance(out, tuple) else out
+
+    def loss(p, *a):
+        return (run(p, *a).astype(jnp.float32) ** 2).mean()
+
+    return traced_digest(jax.grad(loss, argnums=(0, 1)), params, *args)
+
+
+@pytest.mark.parametrize("case,digest", [
+    ("olmo_hybrid_mixer",
+     "50aa14d5485701588263394160410285f36931ed1f35747b3bf6cff1a64e23cf"),
+    (("mellum2", "sliding_attention"),
+     "f9a3797369c672382678c9734c1f72e2be774d5c1c0da0df97908748d10dedba"),
+    (("trinity", "full_attention"),
+     "7a2d63ab09f0b7273aad3f58e63ed2f2b9b99d90a8e4bcb17cb18f467f090828"),
+], ids=["olmo_hybrid_mixer", "mellum2_sliding", "trinity_full"])
+def test_who_else_runs_the_code_traces_to_the_parents_jaxpr(monkeypatch,
+                                                            case, digest):
+    """PR 53 put ``GatedDeltaNet``'s norms behind ``rows_plan`` /
+    ``gated_norm_plan`` and ``rotate_rows``' ``shard_map`` behind a helper.
+    The Olmo-Hybrid mixer (heads of 96 and 192 channels: both guards
+    refuse), Mellum 2's rotation and Trinity's norm without rotation still
+    trace, forward and backward, to what commit ``d2fe5c3`` (the parent)
+    traces: the digests were computed there with :func:`_who_else`."""
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    try:
+        assert _who_else(case) == digest
+    finally:
+        mesh_lib.set_mesh(prev)
 
 
 PARENT_GPT2_STABLEHLO = \

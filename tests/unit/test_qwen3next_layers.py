@@ -3,7 +3,9 @@ model): the DeltaNet mixer, the gated attention and the expert layer against
 ``benchmark/reference/qwen3next.py`` and against each of its named faults,
 under leaves moved off their initial values; the shares of an expert layer
 adding up to the uncut layer with the gated shared expert counted once; the
-row kernels at top-10.
+row kernels at top-10; since PR 53 the mixer with its two per-head norms
+made on the rows (``ops/pallas/qk_rows.py``) against the mixer on the
+``(B, S, H, d)`` view, and what a linear block's jaxpr holds then.
 """
 import dataclasses
 import functools
@@ -14,10 +16,14 @@ import numpy as np
 import pytest
 from flax.core import meta
 
+from deepspeed_tpu.comm import mesh as mesh_lib
+from deepspeed_tpu.models import llama
 from deepspeed_tpu.models.llama import (FULL_ATTENTION, GatedDeltaNet,
-                                        LlamaAttention)
+                                        LlamaAttention, LlamaBlock)
+from deepspeed_tpu.ops import attention, rotary
 from deepspeed_tpu.ops.pallas import moe_rows
 from deepspeed_tpu.parallel.moe import MoELayer
+from tests.unit.test_qk_rows import _eqns
 from tests.unit.test_qwen3next import (ROUTED, TOP_K, _config, _moe, _moved, _rel,
                             reference)
 
@@ -55,6 +61,99 @@ def test_the_deltanet_mixer_alone_against_each_named_fault(fault):
         assert err < 1e-4, err
         return
     assert err > 0.02, (fault, err)
+
+
+MIXER_PARTS = ("output", "A_log", "conv_kernel", "dt_bias", "in_proj_ba",
+               "in_proj_qkvz_kernel", "o_norm", "out_proj", "input")
+
+
+@functools.lru_cache(maxsize=None)
+def _mixer_on_rows():
+    """``MIXER_PARTS`` - the output and the gradients of every leaf and of
+    the input - of the mixer at heads of 128 x 128 on the ``(B, S, H, d)``
+    view, and with both norms' plans forced (the kernels in the
+    interpreter)."""
+    cfg = _config(linear_key_head_dim=128, linear_value_head_dim=128)
+    mixer = GatedDeltaNet(cfg)
+    h = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
+    p = _moved(meta.unbox(mixer.init(jax.random.PRNGKey(0), h)["params"]))
+    ct = jax.random.normal(jax.random.PRNGKey(6), h.shape)
+
+    def measure():
+        return jax.tree_util.tree_leaves(jax.jit(lambda p, h: (
+            mixer.apply({"params": p}, h), jax.grad(
+                lambda p, h: (mixer.apply({"params": p}, h) * ct).sum(),
+                argnums=(0, 1))(p, h)))(p, h))
+
+    view = measure()
+    mp = pytest.MonkeyPatch()
+    try:
+        for plan in ("rows_plan", "gated_norm_plan"):
+            mp.setattr(llama, plan, lambda *a, **kw: ("direct", None))
+        for rows in ("rotate_rows", "gated_norm_rows"):
+            mp.setattr(llama, rows, functools.partial(getattr(rotary, rows),
+                                                      interpret=True))
+        rows = measure()
+    finally:
+        mp.undo()
+    assert len(view) == len(rows) == len(MIXER_PARTS)
+    return dict(zip(MIXER_PARTS, zip(view, rows)))
+
+
+@pytest.mark.parametrize("part", MIXER_PARTS)
+def test_the_mixer_with_both_norms_on_the_rows_is_the_mixer(part):
+    view, rows = _mixer_on_rows()[part]
+    assert view.shape == rows.shape and view.dtype == rows.dtype
+    assert np.isfinite(np.asarray(rows)).all()
+    # the interpreter's approximate reciprocal: 2^-17 of a sigmoid
+    assert _rel(rows, view) < 1e-4
+
+
+def test_no_norm_of_a_linear_block_sees_b_s_h_d(monkeypatch):
+    """Forward + backward of one remat ``"linear_attention"`` block at heads
+    of 128 x 128 as the chip traces it: both norms are ``pallas_call``
+    equations on the rows (the forward, the remat's forward, the backward),
+    and no operation at all has a ``(B, S, heads, 128)`` operand or
+    result."""
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg = _config(linear_key_head_dim=128, linear_value_head_dim=128,
+                  linear_chunk_size=64, dtype=jnp.bfloat16)
+    block = LlamaBlock(cfg, kind="linear_attention")
+    B, s = 2, 256
+    x = jax.ShapeDtypeStruct((B, s, cfg.hidden_size), cfg.dtype)
+    pos = jax.ShapeDtypeStruct((B, s), jnp.int32)
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    try:
+        params = meta.unbox(jax.eval_shape(
+            block.init, jax.random.PRNGKey(0), x, (pos, None))["params"])
+
+        @jax.checkpoint
+        def layer(p, x, pos):
+            return block.apply({"params": p}, x, (pos, None))[0]
+
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p, x, pos: (layer(p, x, pos).astype(jnp.float32) ** 2
+                               ).mean(), argnums=(0, 1)))(params, x, pos)
+    finally:
+        mesh_lib.set_mesh(prev)
+    heads = {cfg.linear_num_key_heads, cfg.linear_num_value_heads}
+    seen, four_d = {}, set()
+    for eqn in _eqns(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            name = eqn.params["name"]
+            seen[name] = seen.get(name, 0) + 1
+        for var in (*eqn.invars, *eqn.outvars):
+            shape = getattr(var.aval, "shape", ())
+            if len(shape) == 4 and shape[:2] == (B, s) \
+                    and shape[2] in heads and shape[3] == 128:
+                four_d.add(eqn.primitive.name)
+    assert not four_d, four_d
+    rows = {k: n for k, n in seen.items() if "rows" in k}
+    assert rows == {"causal_conv_rows": 2, "causal_conv_rows_back": 1,
+                    "qk_rows": 2, "qk_rows_back": 1, "gated_norm_rows": 2,
+                    "gated_norm_rows_back": 1}, seen
 
 
 @pytest.mark.parametrize("fault", [None, *reference.FAULTS])

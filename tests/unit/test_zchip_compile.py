@@ -1032,6 +1032,66 @@ def test_the_gated_delta_kernels_compile_at_the_eighth_cells_shape(one_chip):
     assert not re.search(rf"\[[\d,]*{N},{C},({C}|{d}|{2 * d})\]", text)
 
 
+@pytest.mark.parametrize("site", ["qk_rows", "gated_norm_rows"])
+def test_the_norms_row_kernels_compile_at_the_eighth_cells_shape(
+        one_chip, monkeypatch, site):
+    """A Gated DeltaNet layer's two per-head norms on the rows (PR 53) at
+    ``train-qwen3next-gdn-8k-1chip``'s shape, through the guards as the
+    model calls them.  ``qk_rows``: the l2-norms of q and k, 16 heads of 128
+    each, as the norm pass under constant scales and no table - a signature
+    of ``qk_rows`` / ``qk_rows_back`` that no attention layer has.
+    ``gated_norm_rows``: ``rms_norm(o, w) * silu(z)`` over 32 value heads,
+    the pair ``gated_norm_rows`` / ``gated_norm_rows_back``, ``dw`` a grid
+    step's own sum.  No operand or result is a ``(3, 8192, heads, 128)``
+    array, which on the chip would be a copy either way."""
+    import re
+
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.ops import attention, rotary
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    B, S, d = 3, 8192, 128
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if site == "qk_rows":
+        args = (sd((B, S, 16 * d)), sd((B, S, 16 * d)))
+
+        def loss(q, k):
+            plan = rotary.rows_plan(q, k, d, norm=True)
+            q, k = rotary.rotate_rows(
+                q, k, None, d, plan,
+                q_scale=jnp.full((d,), 1 / d, jnp.float32),
+                k_scale=jnp.full((d,), d ** -0.5, jnp.float32), eps=1e-6 / d)
+            return (q.astype(jnp.float32) * k.astype(jnp.float32)).sum()
+
+        said = f"head_dim {d}, rows 2048 + 2048; one device"
+    else:
+        args = (sd((B, S, 32 * d)), sd((B, S, 32 * d)), sd((d,), jnp.float32))
+
+        def loss(o, z, w):
+            plan = rotary.gated_norm_plan(o, d)
+            y = rotary.gated_norm_rows(o, z, w, d, plan, eps=1e-6)
+            return (y.astype(jnp.float32) ** 2).sum()
+
+        said = f"head_dim {d}, rows 4096; one device"
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        text = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(len(args))))).lower(*args).compile(
+                ).as_text()
+    finally:
+        mesh_lib.set_mesh(None)
+    calls = re.findall(r"%(\w+?)[.\d]* = [^=]*? custom-call\(", text)
+    assert sorted(calls) == [site, site + "_back"], calls
+    assert not re.search(rf"\[{B},{S},\d+,{d}\]", text)
+    assert (site, "pallas", said) in {r[:3] for r in dispatch_report()
+                                      if r[3]}
+
+
 def test_flash_kernels_compile_at_the_eighth_cells_shape(one_chip):
     """The flash forward and backward of ``train-qwen3next-gdn-8k-1chip``'s
     one attention layer: 3 rows of 8192, 16 query heads on 2 key-value
@@ -1114,9 +1174,15 @@ def test_the_eighth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     # the remat's forward and one backward a Gated DeltaNet layer
     assert len(re.findall(r"causal_conv_rows[.\d]* = ", text)) == 6
     assert len(re.findall(r"causal_conv_rows_back[.\d]* = ", text)) == 3
+    # both per-head norms of those layers are row kernels since PR 53
+    for name, n in (("qk_rows", 6), ("qk_rows_back", 3),
+                    ("gated_norm_rows", 6), ("gated_norm_rows_back", 3)):
+        assert len(re.findall(rf"{name}[.\d]* = ", text)) == n, name
     sites = {(s, i) for s, i, _, n in dispatch_report() if n}
     assert {("attention", "flash"), ("gated_delta", "pallas"),
-            ("moe_rows", "pallas"), ("short_conv", "pallas")} <= sites, sites
+            ("moe_rows", "pallas"), ("short_conv", "pallas"),
+            ("qk_rows", "pallas"), ("gated_norm_rows", "pallas")} <= sites, \
+        sites
 
 
 def test_the_kernels_compile_at_the_ninth_cells_shape(one_chip, monkeypatch):
@@ -1230,3 +1296,10 @@ def test_the_ninth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     sites = {(s, i) for s, i, _, n in dispatch_report() if n}
     assert {("attention", "flash"), ("gated_delta", "pallas"),
             ("short_conv", "pallas")} <= sites, sites
+    # heads of 96 and 192 channels are no lane tiles: both per-head norms
+    # keep the (B, S, H, d) lines, the step is the parent's program (PR 53)
+    assert "qk_rows" not in text and "gated_norm_rows" not in text
+    refused = {r[:3] for r in dispatch_report() if r[3]}
+    assert ("qk_rows", "xla", "head_dim 96 is no multiple of 128") in refused
+    assert ("gated_norm_rows", "xla",
+            "head_dim 192 is no multiple of 128") in refused
