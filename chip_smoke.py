@@ -1302,6 +1302,111 @@ def kernel_flash_blockdiff():
             q, k, v, block=G))(q, k, v, ct))
 
 
+def kernel_indexed_attention(S: int = 32768, time_it: bool = True):
+    """The tenth cell's attention at the cell's own shape
+    (``train-keye-dsa-32k-1chip``: one row of 32,768 positions, 32 query
+    heads on 4 key-value heads of 128, an indexer of 16 heads of 64 channels
+    with one key, 2,048 keys a query): ``ops/indexed_attention.py``'s
+    kernels against its plain form in float32 at "highest" precision on the
+    same bf16 operands.  The selection first: the kernels keep exactly
+    ``sum_t min(t + 1, 2048)`` pairs and their threshold ``tau`` is the
+    plain form's ``lax.top_k`` value at that rank.  Then the output, the
+    row's KL and the gradients of all six operands under a seeded
+    cotangent, with the plain form's selection handed to the kernels (a
+    pair that flips at the threshold is the indexer's rounding, not the
+    kernels' fault) and once under their own.  Each kernel's device time a
+    call from a profiler trace."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.indexed_attention import (
+        indexed_attention, indexer_scores, plain_selection, select)
+
+    B, H, KV, D, NI, DI, K = 1, 32, 4, 128, 16, 64, 2048
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(54), 8)
+    q, k, v = (jax.random.normal(kk, (B, S, n, D), jnp.float32).astype(bf)
+               for kk, n in zip(ks, (H, KV, KV)))
+    qi = jax.random.normal(ks[3], (B, S, NI, DI), jnp.float32).astype(bf)
+    ki = jax.random.normal(ks[4], (B, S, DI), jnp.float32).astype(bf)
+    w = jax.random.normal(ks[5], (B, S, NI), jnp.float32) * (NI * DI) ** -0.5
+    ct = jax.random.normal(ks[6], (B, S, H, D), jnp.float32).astype(bf)
+    ckl = jax.random.uniform(ks[7], (B, S), jnp.float32)
+    ops = (q, k, v, qi, ki, w)
+    name = f"indexed attention ({B},{S},{H}/{KV},{D}) k={K}"
+
+    def both(impl, f32=False):
+        def run(ops, selection, ct, ckl):
+            def loss(*ops):
+                r = indexed_attention(*ops, topk=K, impl=impl,
+                                      selection=selection)
+                return ((r.out.astype(jnp.float32)
+                         * ct.astype(jnp.float32)).sum()
+                        + (r.kl * ckl).sum(), r)
+            if f32:
+                ops = tuple(t.astype(jnp.float32) for t in ops)
+            (_, r), grads = jax.value_and_grad(
+                loss, argnums=range(6), has_aux=True)(*ops)
+            return (r.out, r.kl) + tuple(grads), r.tile_counts
+        return jax.jit(run)
+
+    # the selection
+    tau, cut = jax.jit(lambda qi, ki, w: select(qi, ki, w, K))(qi, ki, w)
+    with jax.default_matmul_precision("highest"):
+        mask = jax.jit(lambda qi, ki, w: plain_selection(qi, ki, w, K))(
+            *(t.astype(jnp.float32) for t in (qi, ki, w)))
+
+        @jax.jit
+        def row_tau(t0, qi, ki, w, mask):   # the plain form's value at
+            at = lambda x: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+                x, t0, 512, 1)              # the kept rank
+            sc = indexer_scores(at(qi).astype(jnp.float32),
+                                ki.astype(jnp.float32), at(w))
+            return jnp.where(at(mask), sc, jnp.inf).min(-1)
+
+        tau_ref = jnp.concatenate([row_tau(t0, qi, ki, w, mask)
+                                   for t0 in range(0, S, 512)], axis=1)
+    gap = float(jnp.abs(tau - tau_ref).max() / jnp.abs(tau_ref).max())
+    want_pairs = K * (K + 1) // 2 + (S - K) * K if S > K \
+        else S * (S + 1) // 2
+    print(f"  {name}: tau differs from the plain form's by {gap:.2e} of its "
+          f"largest; {int((cut < S).sum())} rows cut a tie", flush=True)
+    assert gap < 1e-3, gap
+    for label, selection in (("the plain form's selection", mask),
+                             ("its own selection", None)):
+        got, counts = both("pallas")(ops, selection, ct, ckl)
+        counts = np.asarray(counts, np.float64)     # float32 sums round here
+        assert int(counts.sum()) == want_pairs, (label, counts.sum(),
+                                                 want_pairs)
+        if selection is None:
+            print(f"  {name}: {int(counts.sum())} pairs kept, "
+                  f"{100.0 * counts.sum() / (S * (S + 1) / 2):.2f}% of the "
+                  f"causal ones; {int((counts > 0).sum())} of "
+                  f"{counts.shape[1] * (counts.shape[1] + 1) // 2} causal "
+                  f"512 x 512 tiles hold one", flush=True)
+            want = want[:2]     # the gradients see the flipped pairs
+        else:
+            with jax.default_matmul_precision("highest"):
+                want, _ = both("jnp", f32=True)(ops, selection, ct, ckl)
+        for n, g, gr in zip(("out", "kl", "dq", "dk", "dv", "dqI", "dkI",
+                             "dw"), got, want):
+            _check_close(f"{name} under {label}, {n}", g, gr)
+    if not time_it:
+        return
+    out = os.path.join("chiprun_out", "trace_indexed_attention")
+    run = both("pallas")
+    jax.block_until_ready(run(ops, None, ct, ckl))
+    jax.profiler.start_trace(out)
+    for _ in range(3):
+        jax.block_until_ready(run(ops, None, ct, ckl))
+    jax.profiler.stop_trace()
+    for op, ns in sorted(_traced_op_times(out).items()):
+        if op.startswith(("indexer_select", "indexed_attn")):
+            print(f"  {name}: {op} {np.median(ns) / 1e6:.2f} ms a call "
+                  f"({len(ns)} calls)", flush=True)
+
+
 def kernel_qk_rows():
     """q and k as ``(B, S, H*D)`` rows through ``ops/pallas/qk_rows.py`` at
     the third and fourth cells' shapes (4 and 3 rows of 8192, 32 / 4 heads
@@ -1472,7 +1577,8 @@ def kernel_short_conv(time_it: bool = True):
 
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
-                kernel_flash_lanes_256, kernel_gated_delta,
+                kernel_flash_lanes_256, kernel_indexed_attention,
+                kernel_gated_delta,
                 kernel_gated_delta_wide, kernel_gated_norm, kernel_qk_rows,
                 kernel_short_conv,
                 kernel_grouped_matmul,

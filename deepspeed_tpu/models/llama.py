@@ -104,6 +104,20 @@ DeltaNet is fla's at ``expand_v`` 2: ``linear_key_head_dim`` 96 and
 - beta k k^T`` has the eigenvalue ``1 - beta`` in (-1, 1)); no output gate
 on attention, ``qk_norm=True`` (OLMoE's, over the whole projection) and
 ``rope_layer_types=()``: no layer carries a position.
+
+Keye-VL-2.0 (Kwai-Keye, 2026; ``model_type: KeyeVL2``; the language model
+alone) is the sparse block with ``qk_norm="head"`` and, on every attention
+layer, a **learned sparse selection** (``sa_config``,
+:class:`SparseAttentionConfig`: DeepSeek-Sparse-Attention over grouped
+queries).  An :class:`Indexer` beside q, k and v reads the layer's
+normalised input under a stop-gradient, scores every causal key of a query
+(``indexer_num_heads`` heads of ``indexer_head_dim`` channels against ONE
+key head, a learned weight a head, ReLU between) and attention keeps the
+``topk`` best (``ops/indexed_attention.py``).  The indexer learns from a
+loss of its own, ``KL(mean-over-heads attention probabilities || softmax of
+its scores)`` over the kept keys, which joins the model's loss under
+``indexer_loss_weight`` summed over the layers; the selection passes no
+gradient.  No cache leaf holds the indexer's keys: ``decode=True`` raises.
 """
 from __future__ import annotations
 
@@ -147,6 +161,25 @@ class BlockDiffusionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SparseAttentionConfig:
+    """``sa_config`` under its ``config.json`` names: an indexer of
+    ``indexer_num_heads`` heads of ``indexer_head_dim`` channels on
+    ``indexer_num_kv_heads`` (1) key heads picks ``topk`` causal keys a
+    query.  The source's ``q_chunk_size`` and ``kv_chunk_size`` are its
+    tiling of that computation: no result depends on them, the kernels tile
+    by their own blocks, and a dict that carries them loads without them."""
+    indexer_head_dim: int = 64
+    indexer_num_heads: int = 16
+    indexer_num_kv_heads: int = 1
+    topk: int = 2048
+
+
+# what an indexed attention layer hands up beside its output, a layer
+_INDEXER_STATS = ("indexer_loss", "indexer_kept_share",
+                  "indexer_live_tile_share")
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     """Fields under their Hugging Face ``config.json`` names are what
     ``benchmark/drivers/train_lm.py`` passes through from a configuration
@@ -160,7 +193,8 @@ class LlamaConfig:
     ``linear_num_key_heads``, ``linear_num_value_heads``,
     ``linear_key_head_dim``, ``linear_value_head_dim``,
     ``linear_conv_kernel_dim``, ``linear_allow_neg_eigval``,
-    ``partial_rotary_factor``.  The rest are this program's own."""
+    ``partial_rotary_factor``, ``sa_config``.  The rest are this program's
+    own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -281,6 +315,12 @@ class LlamaConfig:
     # fields (a configuration file's section); None: next-token training,
     # and nothing of the section is traced
     diffusion: Optional[Any] = None
+    # a learned sparse selection on every attention layer: a
+    # SparseAttentionConfig, or a dict of its fields (the source's
+    # ``sa_config``); None: every causal key.  Its loss joins the model's
+    # under ``indexer_loss_weight`` (this program's name), summed over layers
+    sa_config: Optional[Any] = None
+    indexer_loss_weight: float = 1.0
     # > 0 with labels: chunked cross-entropy head, logits never materialize
     # (common.chunked_lm_loss); the output then carries no ``logits``
     loss_chunk: int = 0
@@ -307,7 +347,8 @@ class LlamaConfig:
         if self.head_dim is None:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_attention_heads)
-        for name in ("layer_types", "rope_parameters", "rope_layer_types"):
+        for name in ("layer_types", "rope_parameters", "rope_layer_types",
+                     "sa_config"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm is False, True (the whole projection)"
@@ -452,6 +493,45 @@ class LlamaConfig:
                     "attention, a multi-token-prediction block or a sliding "
                     "window: the block mask is written for full grouped-"
                     "query attention alone")
+        if isinstance(self.sa_config, (dict, tuple)):
+            object.__setattr__(self, "sa_config", SparseAttentionConfig(**{
+                k: v for k, v in dict(self.sa_config).items()
+                if k not in ("q_chunk_size", "kv_chunk_size")}))
+        if self.sa_config is not None:
+            sa = self.sa_config
+            if sa.indexer_num_kv_heads != 1 or sa.topk < 1 \
+                    or sa.indexer_num_heads < 1 or sa.indexer_head_dim % 2:
+                raise ValueError(
+                    f"sa_config: one indexer key head, topk >= 1 and an "
+                    f"even indexer_head_dim are written, got {sa}")
+            if self.decode:
+                raise NotImplementedError(
+                    "decode=True with sa_config (a learned sparse "
+                    "selection): the cache holds keys and values, and the "
+                    "indexer's keys are no leaf of it yet")
+            if self.mla_fields:
+                raise NotImplementedError(
+                    "sa_config with latent attention: the indexer is "
+                    "written beside grouped-query attention alone")
+            if self.sliding_window or SLIDING in self.kinds:
+                raise NotImplementedError(
+                    "sa_config with a sliding window: the selection is "
+                    "written over every causal key")
+            if self.diffusion is not None:
+                raise NotImplementedError(
+                    "sa_config with diffusion (block-diffusion training): "
+                    "the selection is written under the causal mask alone")
+            if self.attn_impl in ("ring", "ulysses"):
+                raise NotImplementedError(
+                    f"sa_config under sequence parallelism (attn_impl "
+                    f"{self.attn_impl!r}): a query's top keys are chosen "
+                    f"over its whole row, on one device")
+            if any(t in MIXERS for t in self.kinds) \
+                    or self.num_nextn_predict_layers:
+                raise NotImplementedError(
+                    "sa_config beside a conv or linear_attention layer or "
+                    "a multi-token-prediction block: the indexer's loss is "
+                    "gathered from a stack of attention layers alone")
         if self.decode and (self.mla_fields
                             or self.num_nextn_predict_layers):
             raise NotImplementedError(
@@ -599,6 +679,43 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, self.cfg.rms_norm_eps)
 
 
+class Indexer(nn.Module):
+    """``sa_config``'s scorer (released code: DeepSeek-V3.2-Exp's
+    ``Indexer``, without its Hadamard rotation, which is orthogonal and
+    cancels in the product, and without its fp8, an inference detail)::
+
+        qI = rotary(h W_q)                  # heads x channels
+        kI = rotary(LayerNorm(h W_k))       # ONE key head; weight and bias
+        w  = (h W_w) * heads^-1/2 * channels^-1/2       # float32
+
+    on ``h`` under a stop-gradient: these leaves learn from the indexer's
+    own loss alone.  Rotary turns all channels, by halves, at the model's
+    ``rope_theta``."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, h, position_ids):
+        cfg, sa = self.cfg, self.cfg.sa_config
+        B, S, _ = h.shape
+        NI, DI = sa.indexer_num_heads, sa.indexer_head_dim
+        qi = _dense(h, NI * DI, ("embed", "qkv"), cfg=cfg, name="wq",
+                    module=self).reshape(B, S, NI, DI)
+        ki = _dense(h, DI, ("embed", None), cfg=cfg, name="wk", module=self)
+        scale = self.param("k_norm_scale", nn.with_partitioning(
+            nn.initializers.ones, (None,)), (DI,), cfg.param_dtype)
+        bias = self.param("k_norm_bias", nn.with_partitioning(
+            nn.initializers.zeros, (None,)), (DI,), cfg.param_dtype)
+        ki = ki.astype(jnp.float32)
+        ki = ki - ki.mean(-1, keepdims=True)
+        ki = (ki * jax.lax.rsqrt((ki * ki).mean(-1, keepdims=True) + 1e-6)
+              * scale + bias).astype(cfg.dtype)
+        qi, ki = apply_rotary_pos_emb(qi, ki[:, :, None], position_ids,
+                                      theta=cfg.rope_theta)
+        w = _dense(h, NI, ("embed", None), cfg=cfg, name="weights_proj",
+                   module=self).astype(jnp.float32) * (NI * DI) ** -0.5
+        return qi, ki[:, :, 0], w
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
     kind: Optional[str] = None      # the layer's type; None: full, one table
@@ -726,7 +843,24 @@ class LlamaAttention(nn.Module):
         # a layer type's kernels in the device trace
         window = cfg.window(self.kind)
         scope = "self_attn_window" if window else "self_attn_full"
-        if self.blockdiff:
+        ys = None
+        if cfg.sa_config is not None:
+            from ..ops.indexed_attention import impl_of, indexed_attention
+
+            with trace.device_span("attn/indexer"):
+                qi, ki, w = Indexer(cfg, name="indexer")(
+                    jax.lax.stop_gradient(x), position_ids)
+            y, kl, counts = indexed_attention(
+                q, k, v, qi, ki, w, topk=cfg.sa_config.topk,
+                impl=impl_of(cfg.attn_impl))
+            with trace.device_span("attn/indexer_loss"):
+                tiles = counts.shape[1]
+                ys = {
+                    "indexer_loss": kl.mean(),
+                    "indexer_kept_share": counts.sum() / (B * S * (S + 1) / 2),
+                    "indexer_live_tile_share": (counts > 0).sum() / (
+                        B * tiles * (tiles + 1) / 2)}
+        elif self.blockdiff:
             from ..ops.attention import block_diffusion_attention
 
             with trace.device_span("self_attn_blockdiff"):
@@ -747,7 +881,10 @@ class LlamaAttention(nn.Module):
                           name="gate_proj", module=self)
             with trace.device_span("attn/gate"):
                 y = y * jax.nn.sigmoid(gate)
-        return _dense(y, E, ("heads", "embed"), cfg=cfg, name="o_proj", module=self)
+        y = _dense(y, E, ("heads", "embed"), cfg=cfg, name="o_proj",
+                   module=self)
+        # with sa_config: the layer's _INDEXER_STATS ride beside its output
+        return y if ys is None else (y, ys)
 
 
 class LlamaLatentAttention(nn.Module):
@@ -1020,6 +1157,9 @@ class LlamaBlock(nn.Module):
                 else LlamaAttention(cfg, self.kind, self.blockdiff,
                                     name="self_attn")
             attn = self_attn(h, position_ids, attn_mask)
+        indexed = None
+        if cfg.sa_config is not None:
+            attn, indexed = attn
         if cfg.reordered_norm:
             h = x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
         elif cfg.sandwich_norm:
@@ -1043,6 +1183,8 @@ class LlamaBlock(nn.Module):
                 ff = self._dense_ffn(h)
         if cfg.sandwich_norm or cfg.reordered_norm:
             ff = RMSNorm(cfg, name="post_mlp_norm")(ff)
+        if indexed is not None:
+            ys = dict(ys or {}, **indexed)
         return x + ff, ys
 
 
@@ -1163,6 +1305,7 @@ class LlamaForCausalLM(nn.Module):
                 LlamaBlock, policy=resolve_remat_policy(cfg.remat_policy),
                 prevent_cse=cfg.remat_prevent_cse)
         kinds = cfg.kinds
+        indexed = []        # the layers' _INDEXER_STATS, then stacked
         if cfg.scan_layers:
             if len(set(kinds)) > 1 or cfg.num_dense_layers:
                 raise NotImplementedError(
@@ -1180,6 +1323,9 @@ class LlamaForCausalLM(nn.Module):
             h, per_layer = stack(cfg, deterministic, *kinds[:1],
                                  name="layers", blockdiff=dif is not None)(
                 h, (position_ids, mask))
+            if cfg.sa_config is not None:
+                per_layer = dict(per_layer)
+                indexed = {k: per_layer.pop(k) for k in _INDEXER_STATS}
         else:
             per_layer = []
             for i in range(cfg.num_hidden_layers):
@@ -1189,7 +1335,14 @@ class LlamaForCausalLM(nn.Module):
                                   name=f"layers_{i}", **dense,
                                   blockdiff=dif is not None)(
                     h, (position_ids, mask))
+                if cfg.sa_config is not None:   # every layer has these
+                    ys = dict(ys)
+                    indexed.append({k: ys.pop(k) for k in _INDEXER_STATS})
+                    ys = ys or None
                 per_layer.append(ys)
+            if indexed:
+                indexed = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                                 *indexed)
             if cfg.moe is not None:     # stacked over the MoE layers alone
                 per_layer = jax.tree_util.tree_map(
                     lambda *xs: jnp.stack(xs),
@@ -1225,6 +1378,13 @@ class LlamaForCausalLM(nn.Module):
             stats = dict(per_layer)
             aux_loss = out["aux_loss"] = stats.pop("aux_loss").mean()
             out["stats"] = stats
+        if cfg.sa_config is not None:
+            # the paper's sum over the layers, under one weight; the two
+            # shares are the stack's means
+            indexer_loss = out["indexer_loss"] = indexed["indexer_loss"].sum()
+            out["stats"] = dict(
+                out.get("stats") or {}, indexer_loss=indexer_loss,
+                **{k: indexed[k].mean() for k in _INDEXER_STATS[1:]})
 
         weighted = {}
         if dif is not None and labels is not None:
@@ -1293,6 +1453,8 @@ class LlamaForCausalLM(nn.Module):
                                 mtp_loss=mtp_loss)
             loss = loss + cfg.mtp_loss_weight * mtp_loss
         if loss is not None:
+            if cfg.sa_config is not None:
+                loss = loss + cfg.indexer_loss_weight * indexer_loss
             out["loss"] = loss if aux_loss is None else loss + aux_loss
         return out
 
@@ -1325,6 +1487,24 @@ class LlamaForCausalLM(nn.Module):
                 "diffusion_t_mean", "mean noise level drawn over the "
                 "blocks of the last finished step").set(
                 float(stats["diffusion_t_mean"]))
+        if "indexer_loss" in stats:
+            from ..telemetry import registry
+
+            registry.gauge(
+                "indexer_loss", "KL(attention's head-mean probabilities || "
+                "softmax of the indexer's scores) over the kept keys, summed "
+                "over the layers, last finished step").set(
+                float(stats["indexer_loss"]))
+            registry.gauge(
+                "sparse_attention_kept_share", "kept (query, key) pairs "
+                "over the causal pairs, mean of the layers, last finished "
+                "step").set(float(stats["indexer_kept_share"]))
+            registry.gauge(
+                "sparse_attention_live_tile_share", "512 x 512 causal score "
+                "tiles that hold at least one kept pair over all of them "
+                "(what block skipping could skip is the rest), mean of the "
+                "layers, last finished step").set(
+                float(stats["indexer_live_tile_share"]))
         if "tokens_per_expert" in stats:
             from ..parallel.moe import record_stats
 

@@ -1303,3 +1303,169 @@ def test_the_ninth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert ("qk_rows", "xla", "head_dim 96 is no multiple of 128") in refused
     assert ("gated_norm_rows", "xla",
             "head_dim 192 is no multiple of 128") in refused
+
+
+def test_the_kernels_compile_at_the_tenth_cells_shape(one_chip):
+    """``train-keye-dsa-32k-1chip``'s attention (PR 54), one row of 32,768
+    positions as the layer walks it: 32 query heads on 4 key-value heads of
+    128, an indexer of 16 heads of 64 channels with one key, 2,048 keys a
+    query.  Four custom calls, each once forward and backward: the
+    selection (a ``(256, 32768)`` int32 panel of sortable keys in VMEM),
+    the two-phase forward, and the backward a block of queries and a block
+    of keys at a time; nothing of ``S x S`` among the step's buffers."""
+    import re
+
+    from deepspeed_tpu.ops.indexed_attention import indexed_attention
+
+    B, S, H, KV, D, NI, DI, K = 1, 32768, 32, 4, 128, 16, 64, 2048
+
+    def sd(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*ops):
+        r = indexed_attention(*ops, topk=K, impl="pallas")
+        return r.out.astype(jnp.float32).sum() + r.kl.sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=range(6))).lower(
+        sd((B, S, H, D)), sd((B, S, KV, D)), sd((B, S, KV, D)),
+        sd((B, S, NI, DI)), sd((B, S, DI)),
+        sd((B, S, NI), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in ("indexer_select", "indexed_attn_fwd", "indexed_attn_dq",
+                 "indexed_attn_dkv"):
+        assert len(re.findall(name + r"[.\d]* = ", text)) == 1, name
+    assert f"[{B},{S},{S}]" not in text and f"[{S},{S}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    # the second stage under a selection from outside: an int8 mask tile in
+    # tau's and cut's place (Mosaic refused the mask's compare until the
+    # tile was widened first: my chip run, PR 54)
+    S = 4096
+
+    def given(*ops):
+        r = indexed_attention(*ops[:6], topk=K, impl="pallas",
+                              selection=ops[6])
+        return r.out.astype(jnp.float32).sum() + r.kl.sum()
+
+    text = jax.jit(jax.value_and_grad(given, argnums=range(6))).lower(
+        sd((B, S, H, D)), sd((B, S, KV, D)), sd((B, S, KV, D)),
+        sd((B, S, NI, DI)), sd((B, S, DI)), sd((B, S, NI), jnp.float32),
+        sd((B, S, S), jnp.bool_)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 and "indexer_select" not in text
+
+
+@pytest.mark.parametrize("policy,forward_kernels", [
+    ("dots_saveable+flash", 2),     # the cell's: a kernel once a layer
+    ("dots_saveable", 4),           # the names not kept: the forward twice
+])
+def test_a_blocks_backward_runs_no_indexed_forward_kernel_again(
+        topo, one_chip, monkeypatch, policy, forward_kernels):
+    """What ``test_the_tenth_cells_step_compiles_and_fits_the_chip`` counts
+    in the compiled step (marked slow), at a size that lowers in seconds:
+    a two-layer model under the cell's remat policy, fenced as the cell's,
+    lowered for the described chip.  The forward kernels' results (the
+    output, both ``lse`` and the selection's ``tau`` and ``cut``) are named
+    as the flash kernels' residuals, so ``dots_saveable+flash`` keeps them
+    and each of the four kernels stands in the gradient's program once a
+    layer; under the policy without the names the selection and the
+    forward run again in the backward, which is what an edit to that naming
+    would bring back silently."""
+    import re
+
+    from flax.core import meta
+
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.parallel.moe import MoEConfig
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    S = 1024
+    cfg = LlamaConfig(
+        vocab_size=512, hidden_size=256, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=128,
+        intermediate_size=512, moe_intermediate_size=128,
+        max_position_embeddings=S, qk_norm="head", scan_layers=False,
+        dtype=jnp.bfloat16, attn_impl="auto", vocab_pad_multiple=128,
+        remat=True, remat_policy=policy, remat_prevent_cse=True,
+        moe=MoEConfig(num_experts=4, top_k=2, drop_tokens=False,
+                      expert_act="swiglu", routed_experts=8, first_expert=2),
+        sa_config={"indexer_head_dim": 64, "indexer_num_heads": 4,
+                   "indexer_num_kv_heads": 1, "topk": 256})
+    model = LlamaForCausalLM(cfg)
+    ids = jnp.zeros((1, S), jnp.int32)
+    shapes = jax.eval_shape(lambda: meta.unbox(model.init(
+        jax.random.PRNGKey(0), ids, labels=ids)["params"]))
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=topo.devices[:1]))
+    try:
+        text = jax.jit(jax.grad(lambda p, ids: model.apply(
+            {"params": p}, ids, labels=ids)["loss"])).lower(
+            jax.tree_util.tree_map(on_chip, shapes), on_chip(ids)).as_text()
+    finally:
+        mesh_lib.set_mesh(None)
+
+    def count(name):
+        return len(re.findall(f'kernel_name = "{name}"', text))
+
+    assert (count("indexer_select"), count("indexed_attn_fwd")) == (
+        forward_kernels, forward_kernels)
+    assert (count("indexed_attn_dq"), count("indexed_attn_dkv")) == (2, 2)
+
+
+@pytest.mark.slow
+def test_the_tenth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-keye-dsa-32k-1chip`` (PR 54) as the benchmark builds it, its
+    whole train step compiled for the described chip: four sparse blocks
+    whose attention keeps 2,048 keys a query of one 32,768-token row, 16 of
+    128 experts held; 465,718,784 parameters in the leaves; each of the
+    four kernels once a layer (under ``dots_saveable+flash`` a block's
+    backward runs no forward kernel again); and what the step reserves stays
+    under the chip's 15.75 GiB.  Marked slow, as the ninth's is: ~1 minute
+    of compile; ``compile_said`` in the configuration file holds its
+    reading."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-keye-dsa-32k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.sa_config.topk) == (1, 32768, 2048)
+    assert sum(int(x.size) for x in jax.tree_util.tree_leaves(
+        state.params)) == 465_718_784
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 0.25 * 15.75 < reserved < 15.75, reserved
+    text = compiled.as_text()
+    for name in ("indexer_select", "indexed_attn_fwd", "indexed_attn_dq",
+                 "indexed_attn_dkv"):
+        assert len(re.findall(name + r"[.\d]* = ", text)) == 4, name
+    sites = {(s, i) for s, i, _, n in dispatch_report() if n}
+    assert {("indexed_attention", "pallas"), ("grouped_matmul", "megablox"),
+            ("qk_rows", "pallas"), ("moe_rows", "pallas")} <= sites, sites
+    assert ("indexed_attention", "jnp") not in sites
