@@ -931,8 +931,9 @@ def kernel_flash_lanes_256():
 def kernel_gated_delta_wide(time_it: bool = True):
     """:func:`kernel_gated_delta` at the ninth cell's shape (PR 52): ``(2,
     8192)`` rows, 30 key heads of 96 and 30 value heads of 192 channels
-    (states of 96 x 192, read by the kernels in lane slots of 128 x 256),
-    ``beta = 2 sigmoid(.)`` in (0, 2), against
+    (states of 96 x 192, read by the kernels in lane slots of 128 x 256:
+    since PR 55 the operands arrive in them, ``slots=(96, 192)``, and the
+    timing holds no pad or cut), ``beta = 2 sigmoid(.)`` in (0, 2), against
     ``benchmark/reference/olmo_hybrid.py``'s recurrence."""
     kernel_gated_delta(time_it, wide=True)
 
@@ -990,19 +991,31 @@ def kernel_gated_delta(time_it: bool = True, wide: bool = False):
         return reference.delta_rule(qh, kh, f(v).reshape(B, S, Hv, d), g,
                                     beta).reshape(B, S, Hv * d)
 
-    def both(fn):
+    def both(fn, do=do):
         def run(*args):
             out, vjp = jax.vjp(fn, *args)
             return (out,) + vjp(do.astype(out.dtype))
         return jax.jit(run)
 
-    args = (q, k, v, g, beta)
-    want = both(ref)(*args)
+    plain = (q, k, v, g, beta)
+    want = both(ref)(*plain)
     for impl in ("pallas", "xla"):
         rule = functools.partial(gated_delta_rule, chunk=64, impl=impl,
                                  key_heads=Hk)
-        run = both(rule)
+        args, run = plain, both(rule)
+        if wide and impl == "pallas":
+            # as the layer hands them over since PR 55: a head a lane slot,
+            # o and the three cotangents come back so
+            from deepspeed_tpu.ops.pallas.gated_delta import _slots, _unslots
+
+            rule = functools.partial(rule, slots=(dk, d))
+            args = (_slots(q, Hk, dk), _slots(k, Hk, dk), _slots(v, Hv, d),
+                    g, beta)
+            run = both(rule, _slots(do, Hv, d))
         got = jax.block_until_ready(run(*args))
+        if args is not plain:
+            got = tuple(_unslots(t, H, w) for t, H, w in zip(
+                got, (Hv, Hk, Hk, Hv), (d, dk, dk, d))) + got[4:]
         for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
             a, b = (np.asarray(t, np.float32) for t in (a, b))
             rows = [float(np.linalg.norm(a[r] - b[r]) / np.linalg.norm(b[r]))
@@ -1140,6 +1153,125 @@ def kernel_gated_norm(time_it: bool = True):
                               f"{np.mean(ns) / 1e6:.3f} ms a call",
                               flush=True)
         del want, got
+
+
+def kernel_head_slots(time_it: bool = True):
+    """Olmo-Hybrid's heads in lane slots from the filter to ``out_proj``
+    (PR 55) at the ninth cell's shape, ``(2, 8192)`` rows, 30 key heads of
+    96 and 30 value heads of 192 channels.  ``slot_rows``: the filter's
+    11,520 lanes ``[q | k | v]`` to ``q / |q| 96^-1/2``, ``k / |k|`` (3,840
+    lanes: 30 slots of 128) and ``v`` (7,680: 30 of 256), and the rows'
+    cotangent from three cotangents that are random behind the heads too.
+    ``gated_norm_rows``: ``rms_norm(o, w) * silu(z)`` with ``o`` read from
+    its slots, ``z`` and ``y`` rows of 5,760 lanes.  Each against the same
+    arithmetic in float64 on the host, a row of the batch at a time; the
+    lanes behind a head read exactly zero; the four custom calls timed one
+    by one from a profiler trace, beside the least the HBM rate allows for
+    the lanes each moves."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import rotary
+    from deepspeed_tpu.ops.pallas.gated_delta import _slots
+
+    B, S, H, dk, dv, eps = 2, 8192, 30, 96, 192, 1e-6
+    sk, sv, width = 128, 256, 2 * H * dk + H * dv
+    ks = jax.random.split(jax.random.PRNGKey(55), 8)
+    bf = lambda key, n, scale=1.0: (scale * jax.random.normal(
+        key, (B, S, n), jnp.float32)).astype(jnp.bfloat16)
+    x, dq, dkey, dval = (bf(ks[0], width), bf(ks[1], H * sk),
+                         bf(ks[2], H * sk), bf(ks[3], H * sv))
+    plan = rotary.slots_plan(x, H, dk, H, dv, 64)
+    assert plan == ("direct", None), plan
+
+    @jax.jit
+    def slots(x):
+        out, vjp = jax.vjp(
+            lambda x: rotary.slot_rows(x, H, dk, H, dv, plan), x)
+        return (*out, *vjp((dq, dkey, dval)))
+
+    def live(t, d):         # a row's slots (S, H * slot) -> (S, H, d)
+        t = np.asarray(t, np.float64).reshape(S, H, -1)
+        return t[..., :d], t[..., d:]
+
+    got = jax.block_until_ready(slots(x))
+    for r in range(B):
+        xr = np.asarray(x[r], np.float64)
+        want_dx = []
+        for n, (lo, c) in enumerate(((0, dk ** -0.5), (H * dk, 1.0))):
+            head = xr[:, lo:lo + H * dk].reshape(S, H, dk)
+            inv = 1.0 / np.sqrt((head * head).sum(-1, keepdims=True) + eps)
+            out, behind = live(got[n][r], dk)
+            g, _ = live((dq, dkey)[n][r], dk)
+            unit = head * inv
+            want_dx.append((c * inv * (g - unit * (g * unit).sum(
+                -1, keepdims=True))).reshape(S, H * dk))
+            err = np.abs(out - c * unit).max() / np.abs(c * unit).max()
+            print(f"  head_slots {'qk'[n]} row {r}: |. - float64| / max "
+                  f"{err:.2e}", flush=True)
+            assert err <= TOL and (behind == 0).all(), (n, r, err)
+        out, behind = live(got[2][r], dv)
+        assert (out.reshape(S, H * dv) == xr[:, 2 * H * dk:]).all() \
+            and (behind == 0).all(), r
+        want_dx.append(live(dval[r], dv)[0].reshape(S, H * dv))
+        _check_close(f"head_slots dx row {r}", got[3][r],
+                     np.concatenate(want_dx, axis=1))
+
+    o, z, dy = bf(ks[4], H * dv), bf(ks[5], H * dv, 2.0), bf(ks[6], H * dv)
+    w = 1 + 0.2 * jax.random.normal(ks[7], (dv,), jnp.float32)
+    o_slots = _slots(o, H, dv)
+
+    @jax.jit
+    def norm(o_slots, z, w):
+        out, vjp = jax.vjp(lambda *a: rotary.gated_norm_rows(
+            *a, dv, plan, eps=eps), o_slots, z, w)
+        return (out,) + vjp(dy)
+
+    got_n = jax.block_until_ready(norm(o_slots, z, w))
+    dw = 0.0
+    for r in range(B):
+        o_, z_, dy_ = (np.asarray(t[r], np.float64).reshape(S, H, dv)
+                       for t in (o, z, dy))
+        w_ = np.asarray(w, np.float64)
+        inv = 1.0 / np.sqrt((o_ * o_).mean(-1, keepdims=True) + eps)
+        unit, sg = o_ * inv, 1.0 / (1.0 + np.exp(-z_))
+        g = dy_ * z_ * sg
+        gw = g * w_
+        do, behind = live(got_n[1][r], dv)
+        assert (behind == 0).all(), r
+        for n, a, b in (
+                ("y", got_n[0][r], unit * w_ * z_ * sg),
+                ("do", do, inv * (gw - unit * (gw * unit).mean(
+                    -1, keepdims=True))),
+                ("dz", got_n[2][r], dy_ * unit * w_ * sg * (
+                    1.0 + z_ * (1.0 - sg)))):
+            _check_close(f"head_slots gated_norm {n} row {r}",
+                         np.asarray(a, np.float64).reshape(S, H * dv),
+                         b.reshape(S, H * dv))
+        dw = dw + (g * unit).sum((0, 1))
+    _check_close("head_slots gated_norm dw", got_n[3], dw)
+    if not time_it:
+        return
+    out = tempfile.mkdtemp(prefix="head_slots_trace_")
+    with jax.profiler.trace(out):
+        for _ in range(5):
+            jax.block_until_ready((slots(x), norm(o_slots, z, w)))
+    lanes = {"slot_rows": width + 2 * H * sk + H * sv,
+             "slot_rows_back": 2 * width + 2 * H * sk + H * sv,
+             "gated_norm_rows": H * sv + 2 * H * dv,
+             "gated_norm_rows_back": 2 * H * sv + 3 * H * dv}
+    for call, ns in sorted(_traced_op_times(out).items()):
+        name = call.split(".")[0].lstrip("%")
+        if name in lanes:
+            least = B * S * lanes[name] * 2 / 819e9 * 1e3
+            ms = np.mean(ns) / 1e6
+            print(f"  head_slots: {call} {len(ns)} calls, {ms:.3f} ms a call "
+                  f"(least for its {lanes[name]} lanes of bf16 at 819 GB/s: "
+                  f"{least:.3f} ms, {100 * least / ms:.0f}% of the HBM rate)",
+                  flush=True)
 
 
 def kernel_flash_two_products():
@@ -1579,7 +1711,8 @@ KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
                 kernel_flash_lanes_256, kernel_indexed_attention,
                 kernel_gated_delta,
-                kernel_gated_delta_wide, kernel_gated_norm, kernel_qk_rows,
+                kernel_gated_delta_wide, kernel_gated_norm, kernel_head_slots,
+                kernel_qk_rows,
                 kernel_short_conv,
                 kernel_grouped_matmul,
                 kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,

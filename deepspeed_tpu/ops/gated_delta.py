@@ -53,7 +53,11 @@ the decayed ``q`` and ``k`` through HBM), :func:`_scan_xla` is a
 chunks of 32 / 64 / 128 in whole groups of four, at most four value heads a
 key head, a mesh that ``kernel_mesh_plan`` takes; heads of any width, those
 that are no multiple of 128 channels in lane slots of the next one, zeros
-behind them) hands the WHOLE chunked form to
+behind them: padded and cut here by XLA, or - ``slots=(dk, dv)``, what
+``models/llama.py GatedDeltaNet`` does since PR 55 - written so by the
+caller's row kernel and handed on so to the next, ``o`` and the cotangents
+in the same slots and nothing padded or cut inside the row loop) hands the
+WHOLE chunked form to
 ``ops/pallas/gated_delta.py`` (HLO custom calls ``gated_delta_fwd`` /
 ``gated_delta_bwd``, PR 49): a grid step reads ``q``, ``k``, ``v`` in the
 layout the layer wrote, makes ``A``, the inverse, ``U``, ``W``, ``P`` and
@@ -74,11 +78,14 @@ key heads of 96 x 1 value heads of 192, ...``);
 head-sequence a traced pass walks in sequence: ``fwd`` N, ``bwd`` 2 N (the
 forward's walk again, then the walk back), under either ``impl``;
 ``gated_delta_state_elems{dk, dv}`` is ``dk * dv`` of the rule a traced
-pass ran, so that a snapshot says which shape of state ran.
+pass ran, so that a snapshot says which shape of state ran (the heads' own
+widths, 96 and 192, also where the operands arrive in slots of 128 and
+256).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -275,21 +282,28 @@ def _rule_bwd(chunk, fused, key_heads, res, do):
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def _plan(g, k, v, chunk: int, impl: str, key_heads=None):
-    """``(impl, reason, batch axes of a shard_map or None)``."""
+def kernels_refusal(S: int, chunk: int, Hk: int, Hv: int, dk: int, dv: int,
+                    dtype, auto: bool = True) -> Optional[str]:
+    """``None`` where the kernels take ``Hk`` key heads of ``dk`` and ``Hv``
+    value heads of ``dv`` channels over ``S`` positions (the mesh apart)
+    and, under ``auto``, ``impl="auto"`` hands them over, else why XLA's
+    form runs."""
     from .attention import on_tpu
     from .pallas import gated_delta as kernel
+
+    if S % chunk:
+        return f"rows of {S} positions are no whole chunks of {chunk}"
+    reason = kernel.supported(S // chunk, chunk, dk, dv, dtype, Hv // Hk)
+    return "no TPU" if reason is None and auto and not on_tpu() else reason
+
+
+def _plan(B, S, Hk, Hv, dk, dv, dtype, chunk: int, impl: str):
+    """``(impl, reason, batch axes of a shard_map or None)``."""
     from .pallas.spmd import kernel_mesh_plan
 
     if impl == "xla":
         return impl, "impl='xla' asked for", None
-    B, S, Hv = g.shape
-    dv = v.shape[-1] // Hv
-    Hk = key_heads or k.shape[-1] // dv
-    dk, r = k.shape[-1] // Hk, Hv // Hk
-    reason = kernel.supported(S // chunk, chunk, dk, dv, v.dtype, r)
-    if reason is None and impl == "auto" and not on_tpu():
-        reason = "no TPU"
+    reason = kernels_refusal(S, chunk, Hk, Hv, dk, dv, dtype, impl == "auto")
     verdict = axes = None
     if reason is None:
         verdict, axes = kernel_mesh_plan(B)
@@ -299,6 +313,7 @@ def _plan(g, k, v, chunk: int, impl: str, key_heads=None):
         if impl == "pallas":
             raise NotImplementedError(f"gated_delta impl='pallas': {reason}")
         return "xla", reason, None
+    r = Hv // Hk
     of = "" if dk == dv else f"of {dk} "
     return "pallas", (f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}"
                       f"x {r} value heads of {dv}, fused; "
@@ -309,14 +324,21 @@ def _plan(g, k, v, chunk: int, impl: str, key_heads=None):
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, *, chunk: int = 64,
                      key_heads: int = None, impl: str = "auto",
-                     interpret: bool = False) -> jax.Array:
+                     interpret: bool = False,
+                     slots: Optional[Tuple[int, int]] = None) -> jax.Array:
     """``o`` (B, S, Hv*dv) of the gated delta rule: ``q``, ``k`` (B, S,
     Hk*dk) normalised and scaled by the caller, ``v`` (B, S, Hv*dv), ``g``
     (B, S, Hv) float32 log-decays (<= 0), ``beta`` (B, S, Hv), as a layer's
     projections and filter wrote them.  ``key_heads`` is ``Hk``; None where
     key and value heads are as wide (``dk = dv``), which then says it.
     Each row of the batch starts from a zero state; ``S`` is a multiple of
-    ``chunk``.  See the module's text."""
+    ``chunk``.  ``slots = (dk, dv)``: the operands hold each head in the
+    kernels' lane slot already (``q``, ``k`` (B, S, Hk slot(dk)), ``v`` (B,
+    S, Hv slot(dv)), a head's own ``dk`` / ``dv`` channels from the slot's
+    first lane, zeros behind) and ``o`` comes back so; only the kernels take
+    them (``kernels_refusal`` says beforehand), the widths are named for
+    the dispatch reason and the gauge.  See the module's text."""
+    from .pallas.gated_delta import _slot
     from .pallas.spmd import note_dispatch
 
     if impl not in IMPLS:
@@ -337,9 +359,18 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     if S % chunk:
         raise ValueError(f"rows of {S} positions are no whole chunks of "
                          f"{chunk}")
-    impl, reason, axes = _plan(g, k, v, chunk, impl, Hk)
+    dk, dv = slots or (k.shape[-1] // Hk, v.shape[-1] // Hv)
+    if (k.shape[-1], v.shape[-1]) != ((Hk * _slot(dk), Hv * _slot(dv))
+                                      if slots else (Hk * dk, Hv * dv)):
+        raise ValueError(f"k {k.shape} and v {v.shape} are no {Hk} and {Hv} "
+                         f"lane slots of heads of {slots} channels")
+    impl, reason, axes = _plan(B, S, Hk, Hv, dk, dv, v.dtype, chunk, impl)
+    if slots and impl != "pallas":
+        raise NotImplementedError(
+            f"gated_delta_rule: slotted operands are the kernels' layout, "
+            f"and XLA's form runs ({reason})")
     note_dispatch("gated_delta", impl, reason)
-    _note_state(k.shape[-1] // Hk, v.shape[-1] // Hv)
+    _note_state(dk, dv)
     fused = interpret if impl == "pallas" else None
 
     def run(*args):

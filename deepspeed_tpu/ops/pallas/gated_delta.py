@@ -20,8 +20,11 @@ which is ``arbitrary``.
 **Heads that are no whole lane tiles** (Olmo-Hybrid: keys of 96 channels,
 values of 192) reach the kernels in SLOTS (:func:`_slots`): each head's
 channels from the first lane of a slot of the next multiple of 128 (128 and
-256), zeros behind them, written by XLA beside what made ``q``, ``k`` and
-``v``, and ``o`` and the cotangents cut back the same way.  A zero key
+256), zeros behind them: as the operands arrive where the caller keeps its
+heads so (``models/llama.py GatedDeltaNet`` through ``ops/pallas/qk_rows.py
+slot_rows``, PR 55: :func:`_slots` is then the identity and ``o`` and the
+cotangents go back in slots), else written by XLA beside what made ``q``,
+``k`` and ``v``, and ``o`` and the cotangents cut back the same way.  A zero key
 channel adds nothing to ``K K^T`` or ``Q K^T`` and its row of the state
 stays zero; a zero value channel's column of ``U``, ``V'`` and the state
 stays zero: the rule over the slots is the rule over the heads, to the bit

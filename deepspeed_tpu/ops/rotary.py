@@ -26,7 +26,12 @@ The same pass without positions is any per-head norm of rows: a Gated
 DeltaNet's l2-norms of q and k are ``rotate_rows`` under constant scales
 (PR 53), and its gated output norm has a guard and a pass of its own,
 :func:`gated_norm_plan` / :func:`gated_norm_rows`
-(``kernel_dispatch_total{site="gated_norm_rows"}``).
+(``kernel_dispatch_total{site="gated_norm_rows"}``).  Where that layer's
+heads are no whole lane tiles (Olmo-Hybrid's 96 x 192, PR 55) one guard,
+:func:`slots_plan`, decides for both norms and the rule between them: the
+heads then lie in lane slots from :func:`slot_rows` to
+:func:`gated_norm_rows`.  Attention's heads of 64 / 80 / 96 channels keep
+the ``(B, S, H, D)`` functions: a rotation over a slot is not written.
 """
 from __future__ import annotations
 
@@ -285,11 +290,62 @@ def gated_norm_rows(o: jax.Array, z: jax.Array, w: jax.Array, head_dim: int,
     lanes of the rows ``o`` and ``z`` (B, S, H*D), ``w`` (D,), under a
     ``plan`` of :func:`gated_norm_plan` (the Pallas pass
     ``ops/pallas/qk_rows.py gated_norm_rows``, forward and backward).
+    Under a plan of :func:`slots_plan` ``o`` holds a head a lane slot, as
+    the delta rule's kernels wrote it; ``z`` and the result stay rows.
     Float32 arithmetic, rounded once."""
     from .pallas.qk_rows import gated_norm_rows as kernel
 
     return _over_batch(lambda *a: kernel(*a, head_dim, eps, interpret),
                        plan, (o, z, w), 1)
+
+
+def slots_plan(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
+               dv: int, chunk: int) -> Optional[tuple]:
+    """Whether a Gated DeltaNet layer whose heads are no whole lane tiles
+    (``dk`` or ``dv`` no multiple of 128) keeps them in LANE SLOTS from the
+    filter's ``rows`` (B, S, 2 Hk dk + Hv dv) to ``out_proj``'s operand:
+    :func:`slot_rows` writes the normalised q, k and v into the slots the
+    delta rule's kernels read (``ops/pallas/gated_delta.py``), the rule takes
+    and returns slots, :func:`gated_norm_rows` reads them.  As
+    :func:`rows_plan`; it needs all three, so it is also None where the
+    rule would keep XLA's form (``ops/gated_delta.py kernels_refusal``),
+    and the ``(B, S, H, d)`` lines run.  Counted in
+    ``kernel_dispatch_total`` under both ``site="qk_rows"`` and
+    ``site="gated_norm_rows"``."""
+    from .gated_delta import kernels_refusal
+    from .pallas import qk_rows
+
+    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
+    sk, sv = qk_rows.slot(dk), qk_rows.slot(dv)
+    B, S, _ = rows.shape
+    rule = kernels_refusal(S, chunk, key_heads, value_heads, dk, dv,
+                           rows.dtype)
+    reason = f"the delta rule keeps XLA's form: {rule}" if rule else (
+        qk_rows.slot_rows_supported(S, heads, rows.dtype)
+        or qk_rows.gated_norm_supported(S, value_heads * dv, rows.dtype, dv))
+    plan = _mesh_plan(
+        "qk_rows", reason, B, f"heads of {dk} and {dv} in slots of {sk} and "
+        f"{sv}, rows {heads.width}")
+    _mesh_plan("gated_norm_rows", reason, B, f"head_dim {dv} in slots of "
+               f"{sv}, rows {value_heads * dv}")
+    return plan
+
+
+def slot_rows(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
+              dv: int, plan: tuple, *, eps: float = 1e-6,
+              interpret: bool = False
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Of a Gated DeltaNet's filtered rows ``[q | k | v]`` (B, S, 2 Hk dk +
+    Hv dv), under a ``plan`` of :func:`slots_plan`: ``q / |q| dk^-1/2`` and
+    ``k / |k|`` a head (float32 sums over the head's own channels) as (B, S,
+    Hk slot(dk)) and ``v`` as (B, S, Hv slot(dv)), each head from the first
+    lane of its slot, zeros behind (the Pallas pass
+    ``ops/pallas/qk_rows.py slot_rows``, forward and backward)."""
+    from .pallas import qk_rows
+
+    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
+    return _over_batch(lambda x: qk_rows.slot_rows(x, heads, eps, interpret),
+                       plan, (rows,), 3)
 
 
 def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,

@@ -299,7 +299,8 @@ def test_the_plan_names_the_fused_form_and_its_tile(monkeypatch):
     mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
     try:
         q, k, v, g, beta = _bf16(_inputs(1, 256, 1, 2, 128))
-        impl, reason, axes = ops._plan(g, k, v, 64, "pallas")
+        impl, reason, axes = ops._plan(1, 256, 1, 2, 128, 128, v.dtype, 64,
+                                       "pallas")
     finally:
         mesh_lib.set_mesh(None)
     assert impl == "pallas"

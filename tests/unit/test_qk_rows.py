@@ -8,7 +8,11 @@ layers with and without the rows path, and what a block's jaxpr holds.
 Since PR 53 also a Gated DeltaNet's two per-head norms: the l2-norm as the
 same pass under constant scales, the gated output norm's own pass
 (``gated_norm_plan`` / ``gated_norm_rows``), and the jaxpr digests of the
-layers of other families that run this code.
+layers of other families that run this code.  Since PR 55 heads that are no
+whole lane tiles (Olmo-Hybrid's 96 x 192) in lane slots: ``slot_rows``, the
+gated norm reading ``o`` from slots, ``slots_plan``'s guards, the mixer
+through the slots against the mixer through the ``(B, S, H, d)`` lines, and
+what its jaxpr no longer holds.
 """
 import functools
 
@@ -470,6 +474,384 @@ def test_the_gated_norms_plan_engages_on_one_devices_own_rows(
     assert reason.startswith("head_dim 256, rows 1024; ")
 
 
+# -- heads that are no whole lane tiles, in lane slots (PR 55) ---------------
+
+# (key heads, dk, value heads, dv): Olmo-Hybrid's widths - k begins half a
+# tile in, a head at every lane offset 0 / 32 / 64 / 96 - and a second pair
+SLOTTED = {"96x192": (2, 96, 2, 192), "64x160": (2, 64, 4, 160)}
+
+
+def _slotted(x, heads, d):
+    """XLA's pad of ``ops/pallas/gated_delta.py``: a head a slot."""
+    from deepspeed_tpu.ops.pallas.gated_delta import _slots
+
+    return _slots(x, heads, d)
+
+
+def _cut(x, heads, d):
+    from deepspeed_tpu.ops.pallas.gated_delta import _unslots
+
+    return _unslots(x, heads, d)
+
+
+def _slot_lines(x, Hk, dk, Hv, dv):
+    """``GatedDeltaNet``'s lines on the ``(B, S, H, d)`` float32 view, then
+    XLA's pad into slots."""
+    q, k, v = x[..., :Hk * dk], x[..., Hk * dk:2 * Hk * dk], \
+        x[..., 2 * Hk * dk:]
+    q = (_unit(q, dk) * dk ** -0.5).astype(x.dtype).reshape(q.shape)
+    k = _unit(k, dk).astype(x.dtype).reshape(k.shape)
+    return _slotted(q, Hk, dk), _slotted(k, Hk, dk), _slotted(v, Hv, dv)
+
+
+@functools.lru_cache(maxsize=None)
+def _slot_run(pair, dtype):
+    """``((q, k, v), dx)`` of the slot kernels, of today's lines, and of
+    those on float32 operands; the cotangents are random in EVERY lane of
+    the slots."""
+    from deepspeed_tpu.ops.pallas.qk_rows import slot
+
+    dtype = jnp.dtype(dtype)
+    Hk, dk, Hv, dv = SLOTTED[pair]
+    ks = jax.random.split(jax.random.PRNGKey(dk), 4)
+    x = jax.random.normal(ks[0], (B, S, 2 * Hk * dk + Hv * dv),
+                          jnp.float32).astype(dtype)
+    cts = tuple(jax.random.normal(key, (B, S, n), jnp.float32) for key, n in
+                zip(ks[1:], (Hk * slot(dk), Hk * slot(dk), Hv * slot(dv))))
+
+    def rows(x):
+        return rotary.slot_rows(x, Hk, dk, Hv, dv, ("direct", None),
+                                interpret=True)
+
+    def today(x):
+        return _slot_lines(x, Hk, dk, Hv, dv)
+
+    out = {}
+    for name, fn, cast in (("rows", rows, dtype), ("today", today, dtype),
+                           ("f32", today, jnp.float32)):
+        arg = x.astype(cast)
+        out[name] = (fn(arg), jax.grad(lambda x: sum(
+            (o.astype(jnp.float32) * g).sum()
+            for o, g in zip(fn(x), cts)))(arg))
+    return out
+
+
+@pytest.mark.parametrize("part", ["q", "k", "v", "dx"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("pair", sorted(SLOTTED))
+def test_the_filters_rows_reach_their_slots_normalised(pair, dtype, part):
+    """One row kernel reads ``[q | k | v]`` as the filter wrote them and
+    writes ``q / |q| dk^-1/2``, ``k / |k|`` and ``v`` a head a lane slot:
+    what the ``(B, S, H, d)`` lines and XLA's pad give, the lanes behind a
+    head exactly zero; its backward gives the rows' cotangent and reads no
+    lane behind a head (the cotangents here are random there)."""
+    from deepspeed_tpu.ops.pallas.qk_rows import slot
+
+    run = _slot_run(pair, dtype)
+    Hk, dk, Hv, dv = SLOTTED[pair]
+    if part == "dx":
+        got, today, want = (run[n][1] for n in ("rows", "today", "f32"))
+    else:
+        index = "qkv".index(part)
+        got, today, want = (run[n][0][index]
+                            for n in ("rows", "today", "f32"))
+        heads, d = (Hv, dv) if part == "v" else (Hk, dk)
+        behind = np.asarray(got, np.float32).reshape(B, S, heads, slot(d))
+        assert (behind[..., d:] == 0).all()
+    assert got.shape == want.shape and got.dtype == jnp.dtype(dtype)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    if part == "v":
+        assert (np.asarray(got) == np.asarray(today)).all()
+        return
+    size = float(np.abs(np.asarray(want)).max())
+    one = size * (2.0 ** -8 if dtype == "bfloat16" else 2.0 ** -21)
+    assert _worst(got, want) <= one * (1 + (part == "dx"))
+    assert _rms(got, want) <= _rms(today, want) + 1e-7 * size
+
+
+@functools.lru_cache(maxsize=None)
+def _gated_slot_run(pair, dtype):
+    """``(y, do, dz, dw)`` of the gated norm that reads ``o`` from slots, of
+    today's lines on the cut ``o``, and of those on float32 operands."""
+    dtype = jnp.dtype(dtype)
+    _, _, H, d = SLOTTED[pair]
+    o, z, w, ct = _gated_operands(B, dtype, d, H)
+
+    def rows(o, z, w):
+        return rotary.gated_norm_rows(_slotted(o, H, d), z, w, d,
+                                      ("direct", None), eps=GATED_EPS,
+                                      interpret=True)
+
+    out = {}
+    for name, fn, cast in (("rows", rows, dtype),
+                           ("today", lambda *a: _gated_today(*a, d), dtype),
+                           ("f32", lambda *a: _gated_today(*a, d),
+                            jnp.float32)):
+        args = (o.astype(cast), z.astype(cast), w)
+        out[name] = (fn(*args), *jax.grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * ct).sum(),
+            argnums=(0, 1, 2))(*args))
+    return out
+
+
+@pytest.mark.parametrize("part", ["y", "do", "dz", "dw"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("pair", sorted(SLOTTED))
+def test_the_gated_norm_reads_o_from_its_slots(pair, dtype, part):
+    """``rms_norm(o, w, eps) * silu(z)`` with ``o`` a head a slot (as the
+    delta rule's kernels hand it on) and ``z`` and ``y`` rows of heads side
+    by side (as ``in_proj`` wrote and ``out_proj`` reads): the lines on the
+    ``(B, S, H, d)`` view, the mean over the head's own channels; ``do``
+    (through the pad's transpose: the slots' live lanes), ``dz``, ``dw``."""
+    index = ("y", "do", "dz", "dw").index(part)
+    got, today, want = (_gated_slot_run(pair, dtype)[n][index]
+                        for n in ("rows", "today", "f32"))
+    d = SLOTTED[pair][3]
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    size = float(np.abs(np.asarray(want)).max())
+    if part == "dw":
+        assert got.shape == (d,) and got.dtype == jnp.float32
+        assert _worst(got, want) <= 1e-2 * size
+        assert _worst(got, want) <= _worst(today, want) + 2e-3 * size
+        return
+    assert got.shape == want.shape and got.dtype == jnp.dtype(dtype)
+    one = size * (2.0 ** -8 if dtype == "bfloat16" else 2.0 ** -15)
+    assert _worst(got, want) <= one * (1 + (part != "y"))
+    assert _rms(got, want) <= _rms(today, want) + 4e-6 * size
+
+
+def test_the_gated_norms_cotangent_is_zero_behind_a_head():
+    """``do`` comes back a head a slot, exactly zero behind it."""
+    from deepspeed_tpu.ops.pallas import qk_rows
+
+    H, d = 2, 192
+    o, z, w, ct = _gated_operands(1, jnp.bfloat16, d, H)
+    do, dz, dw = qk_rows.gated_norm_call(
+        _slotted(o, H, d), z, w, ct.astype(o.dtype), head_dim=d,
+        eps=GATED_EPS, interpret=True)
+    assert do.shape == (1, S, H * 256) and dz.shape == z.shape
+    assert (np.asarray(do, np.float32).reshape(1, S, H, 256)[..., d:]
+            == 0).all()
+    assert dw.shape == (d,)
+
+
+def _slots_plan(monkeypatch, pair="96x192", tpu=True, seq=256,
+                dtype=jnp.bfloat16, chunk=64, heads=None):
+    monkeypatch.setattr(attention, "on_tpu", lambda: tpu)
+    Hk, dk, Hv, dv = heads or SLOTTED[pair]
+    rows = jax.ShapeDtypeStruct((B, seq, 2 * Hk * dk + Hv * dv), dtype)
+    guard = lambda: rotary.slots_plan(rows, Hk, dk, Hv, dv, chunk)
+    # one decision, booked under both sites
+    norm = {}
+    plan, impl, reason = _booked("qk_rows", lambda: norm.update(
+        zip(("plan", "impl", "reason"), _booked("gated_norm_rows", guard)))
+        or norm["plan"])
+    assert (norm["plan"], norm["impl"]) == (plan, impl)
+    return plan, impl, reason, norm["reason"]
+
+
+@pytest.mark.parametrize("case,kw,reason", [
+    ("cpu", dict(tpu=False), "the delta rule keeps XLA's form: no TPU"),
+    ("float32", dict(dtype=jnp.float32),
+     "the delta rule keeps XLA's form: operands of float32"),
+    ("chunk", dict(chunk=16), "the delta rule keeps XLA's form: chunks of 16 "
+     "positions: the kernels take 32, 64, 128 (a group of 4 fills whole "
+     "tiles of 128 lanes, 16-row blocks join in pairs)"),
+    ("half_a_period", dict(heads=(1, 96, 2, 192)),
+     "[q | k] of 192 lanes and v of 384 are no whole lane tiles"),
+    ("mesh", dict(), "kernel_mesh_plan refused the mesh"),
+])
+def test_the_slots_guard_keeps_todays_lines_and_says_why(monkeypatch, case,
+                                                         kw, reason):
+    prev = mesh_lib.get_mesh(required=False)
+    if case == "mesh":      # heads over tp: no batch-parallel kernel
+        mesh_lib.set_mesh(mesh_lib.build_mesh({"tp": 2, "dp": -1}))
+    try:
+        got = _slots_plan(monkeypatch, **kw)
+    finally:
+        mesh_lib.set_mesh(prev)
+    assert got == (None, "xla", reason, reason)
+
+
+@pytest.mark.parametrize("mesh,verdict", [
+    (None, ("direct", None)), ({"fsdp": 2, "dp": 1}, ("shard", ("fsdp",)))])
+def test_the_slots_plan_engages_on_one_devices_own_rows(monkeypatch, mesh,
+                                                        verdict):
+    prev = mesh_lib.get_mesh(required=False)
+    devices = jax.devices()[:2 if mesh else 1]
+    mesh_lib.set_mesh(mesh_lib.build_mesh(mesh or {"dp": 1}, devices=devices))
+    try:
+        plan, impl, rows, norm = _slots_plan(monkeypatch)
+    finally:
+        mesh_lib.set_mesh(prev)
+    assert plan == verdict and impl == "pallas"
+    assert rows.startswith(
+        "heads of 96 and 192 in slots of 128 and 256, rows 768; ")
+    assert norm.startswith("head_dim 192 in slots of 256, rows 384; ")
+
+
+def test_slots_sharded_over_the_batch_match_one_device():
+    Hk, dk, Hv, dv = SLOTTED["96x192"]
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, S, 768),
+                          jnp.float32).astype(jnp.bfloat16)
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"fsdp": 2, "dp": 1},
+                                          devices=jax.devices()[:2]))
+    try:
+        def call(plan):
+            fn = lambda x: rotary.slot_rows(x, Hk, dk, Hv, dv, plan,
+                                            interpret=True)
+            return fn(x), jax.grad(lambda x: sum(
+                (o.astype(jnp.float32) ** 2).sum() for o in fn(x)))(x)
+
+        one, two = call(("direct", None)), call(("shard", ("fsdp",)))
+    finally:
+        mesh_lib.set_mesh(prev)
+    for a, b in zip(jax.tree_util.tree_leaves(one),
+                    jax.tree_util.tree_leaves(two)):
+        assert (np.asarray(a) == np.asarray(b)).all()
+
+
+def _olmo_mixer(seq=256):
+    """A Gated DeltaNet layer at Olmo-Hybrid's head widths, cut in count."""
+    cfg = LlamaConfig(
+        vocab_size=256, hidden_size=256, intermediate_size=256,
+        num_hidden_layers=1, num_attention_heads=2, head_dim=128,
+        max_position_embeddings=256, linear_num_key_heads=2,
+        linear_num_value_heads=2, linear_key_head_dim=96,
+        linear_value_head_dim=192, linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True, scan_layers=False)
+    return cfg, llama.GatedDeltaNet(cfg), jax.ShapeDtypeStruct(
+        (B, seq, cfg.hidden_size), cfg.dtype)
+
+
+MIXER_PARTS = ("output", "A_log", "conv_kernel", "dt_bias", "in_proj_ba",
+               "in_proj_qkvz_kernel", "o_norm", "out_proj", "input")
+
+
+@functools.lru_cache(maxsize=None)
+def _olmo_mixer_run():
+    """``MIXER_PARTS`` of the mixer through today's lines (the rule's
+    kernels behind XLA's pads and cuts) and through the slots (the plan
+    forced, every kernel in the interpreter)."""
+    from deepspeed_tpu.ops import gated_delta
+
+    cfg, mixer, shape = _olmo_mixer()
+    h = jax.random.normal(jax.random.PRNGKey(5), shape.shape,
+                          jnp.float32).astype(cfg.dtype)
+    p = meta.unbox(mixer.init(jax.random.PRNGKey(0), h)["params"])
+    p = dict(p, o_norm=p["o_norm"] * (1 + 0.1 * jnp.cos(jnp.arange(192.0))))
+    ct = jax.random.normal(jax.random.PRNGKey(6), h.shape)
+
+    def measure():
+        return jax.tree_util.tree_leaves(jax.jit(lambda p, h: (
+            mixer.apply({"params": p}, h), jax.grad(
+                lambda p, h: (mixer.apply({"params": p}, h).astype(
+                    jnp.float32) * ct).sum(), argnums=(0, 1))(p, h)))(p, h))
+
+    mp = pytest.MonkeyPatch()
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    try:
+        mp.setattr(gated_delta, "gated_delta_rule", functools.partial(
+            gated_delta.gated_delta_rule, impl="pallas", interpret=True))
+        today = measure()
+        mp.setattr(llama, "slots_plan", lambda *a, **kw: ("direct", None))
+        for rows in ("slot_rows", "gated_norm_rows"):
+            mp.setattr(llama, rows, functools.partial(getattr(rotary, rows),
+                                                      interpret=True))
+        slots = measure()
+    finally:
+        mp.undo()
+        mesh_lib.set_mesh(prev)
+    assert len(today) == len(slots) == len(MIXER_PARTS)
+    return dict(zip(MIXER_PARTS, zip(today, slots)))
+
+
+@pytest.mark.parametrize("part", MIXER_PARTS)
+def test_the_mixer_through_the_slots_is_the_mixer(part):
+    """Olmo-Hybrid's mixer (heads of 96 x 192, beta in (0, 2)) with q, k, v
+    written into slots by ``slot_rows``, the rule on slotted operands and
+    the gated norm reading slotted ``o``, against today's lines around the
+    same rule's kernels: the output and every gradient, within the limit
+    the attention families' blocks are held to."""
+    today, slots = _olmo_mixer_run()[part]
+    assert today.shape == slots.shape and today.dtype == slots.dtype
+    a, b = np.asarray(today, np.float32), np.asarray(slots, np.float32)
+    assert np.isfinite(b).all()
+    assert np.abs(a - b).max() <= 2e-2 * max(np.abs(a).max(), 1e-6)
+
+
+def test_no_operation_of_the_slotted_mixer_sees_b_s_h_d(monkeypatch):
+    """Forward + backward of one remat Olmo-Hybrid mixer as the chip traces
+    it: under ``linear_attn/delta_rule`` and ``/gated_norm`` no ``pad``, no
+    4-D ``reshape`` of q, k, v, o and no ``(B, S, H, d)`` operand at all -
+    the slot kernels, the rule's two and the gated norm's pair read and
+    write ``(B, S, lanes)``; what is 4-D there is the gates' ``(B, Hk, 8,
+    S)`` and the saved states."""
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg, mixer, x = _olmo_mixer()
+    s = x.shape[1]
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    try:
+        params = meta.unbox(jax.eval_shape(
+            mixer.init, jax.random.PRNGKey(0), x)["params"])
+
+        @jax.checkpoint
+        def layer(p, x):
+            return mixer.apply({"params": p}, x)
+
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p, x: (layer(p, x).astype(jnp.float32) ** 2).mean(),
+            argnums=(0, 1)))(params, x)
+        engaged = {r[:2] for r in dispatch_report() if r[3]
+                   and "slots of" in r[2]}
+    finally:
+        mesh_lib.set_mesh(prev)
+    seen, found = {}, set()
+
+    def walk(jaxpr, inside):        # a called jaxpr's stacks are relative
+        for eqn in jaxpr.eqns:
+            name = eqn.primitive.name
+            scope = str(eqn.source_info.name_stack)
+            within = inside or "linear_attn/delta_rule" in scope \
+                or "linear_attn/gated_norm" in scope
+            if name == "pallas_call":
+                seen[eqn.params["name"]] = seen.get(eqn.params["name"], 0) + 1
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, within)
+            if not within:
+                continue
+            for var in (*eqn.invars, *eqn.outvars):
+                shape = getattr(var.aval, "shape", ())
+                # q, k, v, o and their cotangents, whole or a row of the
+                # batch: (.., S, lanes) padded, or a head an axis
+                if name == "pad" and len(shape) >= 2 and shape[-2] == s \
+                        and shape[-1] >= 96:
+                    found.add((name, shape))
+                if len(shape) >= 3 and shape[-3] == s and shape[-2] == 2 \
+                        and shape[-1] in (96, 128, 192, 256):
+                    found.add((name, shape))
+
+    walk(jaxpr.jaxpr, False)
+    assert not found, found
+    assert engaged == {("qk_rows", "pallas"), ("gated_norm_rows", "pallas")}
+    # the forward, the remat's forward, the backward; the rule's forward
+    # again for the states
+    assert seen == {"causal_conv_rows": 2, "causal_conv_rows_back": 1,
+                    "slot_rows": 2, "slot_rows_back": 1,
+                    "gated_delta_fwd": 2 * B + B, "gated_delta_bwd": B,
+                    "gated_norm_rows": 2, "gated_norm_rows_back": 1} or \
+        seen == {"causal_conv_rows": 2, "causal_conv_rows_back": 1,
+                 "slot_rows": 2, "slot_rows_back": 1,
+                 "gated_delta_fwd": 3, "gated_delta_bwd": 1,
+                 "gated_norm_rows": 2, "gated_norm_rows_back": 1}, seen
+
+
 # -- the three families' attention layers -----------------------------------
 
 def _family(name, head_dim=D):
@@ -681,14 +1063,14 @@ def _who_else(case):
     from deepspeed_tpu.models.llama import GatedDeltaNet
     from tests.unit.flash_parent_sweep import traced_digest
 
-    if case == "olmo_hybrid_mixer":     # states of 96 x 192: both norms stay
+    if case == "qwen3next_mixer":   # states of 128 x 128: a slot is the head
         cfg = LlamaConfig(
             vocab_size=256, hidden_size=256, intermediate_size=256,
             num_hidden_layers=1, num_attention_heads=2, head_dim=128,
             max_position_embeddings=256, linear_num_key_heads=2,
-            linear_num_value_heads=2, linear_key_head_dim=96,
-            linear_value_head_dim=192, linear_conv_kernel_dim=4,
-            linear_allow_neg_eigval=True, scan_layers=False)
+            linear_num_value_heads=4, linear_key_head_dim=128,
+            linear_value_head_dim=128, linear_conv_kernel_dim=4,
+            scan_layers=False)
         layer = GatedDeltaNet(cfg)
         args = (jax.ShapeDtypeStruct((B, 256, cfg.hidden_size), cfg.dtype),)
     else:                               # rotate_rows as its callers call it
@@ -711,13 +1093,13 @@ def _who_else(case):
 
 
 @pytest.mark.parametrize("case,digest", [
-    ("olmo_hybrid_mixer",
-     "50aa14d5485701588263394160410285f36931ed1f35747b3bf6cff1a64e23cf"),
+    ("qwen3next_mixer",
+     "f3648aba7f5c371f95d3f0f4bd97b33bd0a8f38b2c14b610edbdaeddd680a2ec"),
     (("mellum2", "sliding_attention"),
      "f9a3797369c672382678c9734c1f72e2be774d5c1c0da0df97908748d10dedba"),
     (("trinity", "full_attention"),
      "7a2d63ab09f0b7273aad3f58e63ed2f2b9b99d90a8e4bcb17cb18f467f090828"),
-], ids=["olmo_hybrid_mixer", "mellum2_sliding", "trinity_full"])
+], ids=["qwen3next_mixer", "mellum2_sliding", "trinity_full"])
 def test_who_else_runs_the_code_traces_to_the_parents_jaxpr(monkeypatch,
                                                             case, digest):
     """PR 53 put ``GatedDeltaNet``'s norms behind ``rows_plan`` /

@@ -1241,6 +1241,47 @@ def test_the_kernels_compile_at_the_ninth_cells_shape(one_chip, monkeypatch):
         mesh_lib.set_mesh(None)
     calls = set(re.findall(r"%(\w+?)[.\d]* = [^=]*? custom-call\(", text))
     assert calls == {"causal_conv_rows", "causal_conv_rows_back"}, calls
+    # the heads in lane slots from the filter to out_proj (PR 55): one row
+    # kernel reads the filter's 11,520 lanes and writes q, k (3,840: 30
+    # slots of 128) and v (7,680: 30 of 256); the gated norm reads o from
+    # its slots and the gate and the result as rows of 5,760 lanes
+    from deepspeed_tpu.ops import rotary
+
+    B = 2
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    try:
+        def slots(x):
+            plan = rotary.slots_plan(x, H, dk, H, dv, C)
+            return sum((o.astype(jnp.float32) ** 2).sum() for o in
+                       rotary.slot_rows(x, H, dk, H, dv, plan))
+
+        def norm(o, z, w):
+            y = rotary.gated_norm_rows(o, z, w, dv, ("direct", None),
+                                       eps=1e-6)
+            return (y.astype(jnp.float32) ** 2).sum()
+
+        texts = [jax.jit(jax.value_and_grad(fn, argnums=tuple(range(len(
+            args))))).lower(*args).compile().as_text() for fn, args in (
+                (slots, (sd((B, S, Cq), jnp.bfloat16),)),
+                (norm, (sd((B, S, H * 256), jnp.bfloat16),
+                        sd((B, S, H * dv), jnp.bfloat16),
+                        sd((dv,), jnp.float32))))]
+    finally:
+        mesh_lib.set_mesh(None)
+    for text, pair, shapes in zip(
+            texts, (("slot_rows", "slot_rows_back"),
+                    ("gated_norm_rows", "gated_norm_rows_back")),
+            ((Cq, H * 128, H * 256), (H * 256, H * dv))):
+        calls = re.findall(r"%(\w+?)[.\d]* = [^=]*? custom-call\(", text)
+        assert sorted(calls) == sorted(pair), calls
+        assert all(f"bf16[{B},{S},{n}]" in text for n in shapes)
+        assert not re.search(rf"\[{B},{S},{H},\d+\]", text)
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+    said = {r[:3] for r in dispatch_report() if r[3]}
+    assert ("qk_rows", "pallas", "heads of 96 and 192 in slots of 128 and "
+            "256, rows 11520; one device") in said
+    assert ("gated_norm_rows", "pallas", "head_dim 192 in slots of 256, rows "
+            "5760; one device") in said
 
 
 @pytest.mark.slow
@@ -1296,13 +1337,21 @@ def test_the_ninth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     sites = {(s, i) for s, i, _, n in dispatch_report() if n}
     assert {("attention", "flash"), ("gated_delta", "pallas"),
             ("short_conv", "pallas")} <= sites, sites
-    # heads of 96 and 192 channels are no lane tiles: both per-head norms
-    # keep the (B, S, H, d) lines, the step is the parent's program (PR 53)
-    assert "qk_rows" not in text and "gated_norm_rows" not in text
-    refused = {r[:3] for r in dispatch_report() if r[3]}
-    assert ("qk_rows", "xla", "head_dim 96 is no multiple of 128") in refused
-    assert ("gated_norm_rows", "xla",
-            "head_dim 192 is no multiple of 128") in refused
+    # heads of 96 and 192 channels lie in lane slots from the filter to
+    # out_proj (PR 55): the slot kernel and the gated norm's pair stand
+    # where the filter does, and nothing pads or views a head
+    for name, n in (("slot_rows", 6), ("slot_rows_back", 3),
+                    ("gated_norm_rows", 6), ("gated_norm_rows_back", 3)):
+        assert len(re.findall(rf"{name}[.\d]* = ", text)) == n, name
+    assert not re.search(r"\[2,8192,30,(96|128|192|256)\]", text)
+    said = {r[:3] for r in dispatch_report() if r[3]}
+    assert ("qk_rows", "pallas", "heads of 96 and 192 in slots of 128 and "
+            "256, rows 11520; one device") in said
+    assert ("gated_norm_rows", "pallas", "head_dim 192 in slots of 256, rows "
+            "5760; one device") in said
+    assert ("gated_delta", "pallas", "128 chunks of 64 x 30 key heads of 96 "
+            "x 1 value heads of 192, fused; one device") in said
+    print(f"reserved {reserved:.3f} GiB")
 
 
 def test_the_kernels_compile_at_the_tenth_cells_shape(one_chip):
