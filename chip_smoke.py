@@ -1006,9 +1006,11 @@ def kernel_gated_delta(time_it: bool = True, wide: bool = False):
         if wide and impl == "pallas":
             # as the layer hands them over since PR 55: a head a lane slot,
             # o and the three cotangents come back so
+            from deepspeed_tpu.ops.gated_delta import _dispatch
             from deepspeed_tpu.ops.pallas.gated_delta import _slots, _unslots
 
-            rule = functools.partial(rule, slots=(dk, d))
+            rule = lambda *a: _dispatch(*a, 64, Hk, impl, False,  # noqa: E731
+                                        slots=(dk, d))
             args = (_slots(q, Hk, dk), _slots(k, Hk, dk), _slots(v, Hv, d),
                     g, beta)
             run = both(rule, _slots(do, Hv, d))
@@ -1055,7 +1057,7 @@ def kernel_gated_delta(time_it: bool = True, wide: bool = False):
 
 def kernel_gated_norm(time_it: bool = True):
     """A Gated DeltaNet layer's gated output norm on the rows (PR 53;
-    ``ops/pallas/qk_rows.py gated_norm_rows`` behind ``ops/rotary.py
+    ``ops/pallas/qk_rows.py gated_norm_rows`` behind ``ops/gated_delta.py
     gated_norm_plan``) at the eighth cell's shape, ``(3, 8192, 32 heads of
     128)``: ``y = rms_norm(o, w, eps) * silu(z)``, forward and ``jax.vjp``
     (``do``, ``dz``, ``dw``), beside the model's own lines on the ``(B, S,
@@ -1074,17 +1076,17 @@ def kernel_gated_norm(time_it: bool = True):
     import numpy as np
 
     from deepspeed_tpu.models.common import rms_norm
-    from deepspeed_tpu.ops import rotary
+    from deepspeed_tpu.ops import gated_delta
 
     B, S, H, d, eps = 3, 8192, 32, 128, 1e-6
     ks = jax.random.split(jax.random.PRNGKey(53), 4)
     w = 1 + 0.2 * jax.random.normal(ks[3], (d,), jnp.float32)
-    plan = rotary.gated_norm_plan(
+    plan = gated_delta.gated_norm_plan(
         jax.ShapeDtypeStruct((B, S, H * d), jnp.bfloat16), d)
     assert plan == ("direct", None), plan
 
     def rows(o, z, w):
-        return rotary.gated_norm_rows(o, z, w, d, plan, eps=eps)
+        return gated_delta.gated_norm_rows(o, z, w, d, plan, eps=eps)
 
     def view(o, z, w):
         y = rms_norm(o.reshape(B, S, H, d).astype(jnp.float32), w, eps)
@@ -1174,7 +1176,7 @@ def kernel_head_slots(time_it: bool = True):
     import jax.numpy as jnp
     import numpy as np
 
-    from deepspeed_tpu.ops import rotary
+    from deepspeed_tpu.ops import gated_delta
     from deepspeed_tpu.ops.pallas.gated_delta import _slots
 
     B, S, H, dk, dv, eps = 2, 8192, 30, 96, 192, 1e-6
@@ -1184,13 +1186,13 @@ def kernel_head_slots(time_it: bool = True):
         key, (B, S, n), jnp.float32)).astype(jnp.bfloat16)
     x, dq, dkey, dval = (bf(ks[0], width), bf(ks[1], H * sk),
                          bf(ks[2], H * sk), bf(ks[3], H * sv))
-    plan = rotary.slots_plan(x, H, dk, H, dv, 64)
+    plan = gated_delta.slots_plan(x, H, dk, H, dv, 64)
     assert plan == ("direct", None), plan
 
     @jax.jit
     def slots(x):
         out, vjp = jax.vjp(
-            lambda x: rotary.slot_rows(x, H, dk, H, dv, plan), x)
+            lambda x: gated_delta.slot_rows(x, H, dk, H, dv, plan), x)
         return (*out, *vjp((dq, dkey, dval)))
 
     def live(t, d):         # a row's slots (S, H * slot) -> (S, H, d)
@@ -1226,7 +1228,7 @@ def kernel_head_slots(time_it: bool = True):
 
     @jax.jit
     def norm(o_slots, z, w):
-        out, vjp = jax.vjp(lambda *a: rotary.gated_norm_rows(
+        out, vjp = jax.vjp(lambda *a: gated_delta.gated_norm_rows(
             *a, dv, plan, eps=eps), o_slots, z, w)
         return (out,) + vjp(dy)
 

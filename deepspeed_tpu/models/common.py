@@ -654,39 +654,6 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     return nll_sum / jnp.maximum(count, 1)
 
 
-def pallas_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
-                   vocab_size: int, padded_vocab_size: int, dtype,
-                   ignore_index: int = -100, bq: int = 512,
-                   bv: Optional[int] = None,
-                   interpret: bool = False) -> jax.Array:
-    """Tied-head cross-entropy on the Pallas fused kernel
-    (:mod:`..ops.pallas.fused_ce`): logits never reach HBM in either
-    pass.  Same contract as :func:`chunked_lm_loss`."""
-    from ..ops.pallas.fused_ce import _pick_bv, fused_ce_sum
-
-    B, S, E = h.shape
-    N = B * S
-    # Mosaic lane alignment: the (1,1,bq) block layout needs bq to be a
-    # multiple of 128.  Shrink toward N for tiny batches but keep the
-    # 128 floor — padded rows carry ignore_index, so over-padding is
-    # exact (it only adds masked rows).
-    bq = max(128, min(bq, -(-N // 128) * 128))
-    bq -= bq % 128
-    hf = h.reshape(N, E)
-    tf = labels.reshape(N)
-    pad = (-N) % bq
-    if pad:
-        hf = jnp.concatenate([hf, jnp.zeros((pad, E), hf.dtype)])
-        tf = jnp.concatenate(
-            [tf, jnp.full((pad,), ignore_index, tf.dtype)])
-    wteT = wte.astype(dtype).T
-    bv = bv or _pick_bv(padded_vocab_size)
-    nll_sum = fused_ce_sum(hf, wteT, tf, vocab_size, ignore_index, bq, bv,
-                           interpret)
-    count = (tf != ignore_index).sum()
-    return nll_sum / jnp.maximum(count, 1)
-
-
 def shift_labels(input_ids: jax.Array, pad_id: int = -100) -> jax.Array:
     """Next-token labels for causal LM: labels[t] = input_ids[t+1]."""
     return jnp.concatenate(

@@ -87,10 +87,6 @@ class GPT2Config:
     # models/common.py _fused_ce), so there is neither a second product
     # nor O(N·V) of saved logits to choose between.
     loss_chunk: Optional[int] = None
-    # Pallas fused CE head (ops/pallas/fused_ce.py): matmul + online
-    # logsumexp in VMEM, logits never in HBM either pass.  Engages only
-    # with loss_chunk set (the chunked-loss output contract) on TPU.
-    loss_pallas: bool = False
 
     @property
     def padded_vocab_size(self) -> int:
@@ -464,32 +460,14 @@ class GPT2LMHeadModel(nn.Module):
         h = LayerNorm(cfg, name="ln_f")(h)
         if cfg.loss_chunk and labels is not None:
             # memory-bounded head: logits never fully materialize
-            from ..ops.pallas.fused_ce import supported as _ce_supported
-            from .common import chunked_lm_loss, pallas_lm_loss
+            from .common import chunked_lm_loss
 
             tgt = shift_labels(labels) if shift else labels
-            # pallas CE has no shard_map wrapper: its (E,Vp) dw reduction
-            # would replicate on a sharded mesh.  Same dispatch contract
-            # as the flash kernels' — "direct" (single device) only, else the
-            # SPMD-safe chunked XLA head.
-            use_pallas_ce = (cfg.loss_pallas and on_tpu()
-                             and _ce_supported(cfg.padded_vocab_size))
-            if use_pallas_ce:
-                from ..ops.pallas.spmd import kernel_mesh_plan
-
-                verdict, _ = kernel_mesh_plan(h.shape[0])
-                use_pallas_ce = verdict == "direct"
             with trace.device_span("loss_head"):
-                if use_pallas_ce:
-                    loss = pallas_lm_loss(
-                        h, wte, tgt, vocab_size=cfg.vocab_size,
-                        padded_vocab_size=cfg.padded_vocab_size,
-                        dtype=cfg.dtype)
-                else:
-                    loss = chunked_lm_loss(
-                        h, wte, tgt, vocab_size=cfg.vocab_size,
-                        padded_vocab_size=cfg.padded_vocab_size,
-                        chunk=cfg.loss_chunk, dtype=cfg.dtype)
+                loss = chunked_lm_loss(
+                    h, wte, tgt, vocab_size=cfg.vocab_size,
+                    padded_vocab_size=cfg.padded_vocab_size,
+                    chunk=cfg.loss_chunk, dtype=cfg.dtype)
             out = ModelOutput(loss=loss)
             if cfg.moe is not None:
                 out["aux_loss"] = aux_loss

@@ -130,9 +130,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.attention import dot_product_attention
-from ..ops.rotary import (apply_rotary_pos_emb, gated_norm_plan,
-                          gated_norm_rows, rotate_rows, rows_plan, slot_rows,
-                          slots_plan)
+from ..ops.rotary import apply_rotary_pos_emb, rotate_rows, rows_plan
 from ..telemetry import trace
 from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift_labels
 
@@ -1004,37 +1002,17 @@ class GatedDeltaNet(nn.Module):
     every row of a batch starts from a zero state and an empty filter.
     The channels shard as the attention projections' do.
 
-    Both per-head norms stand between kernels that read and write ``(B, S,
-    H d)`` rows (the filter before, the rule between, ``out_proj`` after),
-    and on the chip the ``(B, S, H, d)`` float32 view they are written on
-    above is no bitcast of such rows: each cost a copy in and a reshape
-    out, forward, recomputation and backward.  So where a head is whole
-    lane tiles (128 x 128: Qwen3-Next) both are made on the rows by the
-    kernels of ``ops/pallas/qk_rows.py``: q and k by ``rotate_rows`` under
-    constant scales and no positions (``ops/rotary.py rows_plan``), the
-    gated norm by ``gated_norm_rows`` (``gated_norm_plan``).
-
-    Where a head is NO whole lane tiles (96 x 192: Olmo-Hybrid; PR 55) the
-    rule's kernels read each head from the first lane of a slot of the
-    next multiple of 128 lanes, zeros behind it, and those slots are the
-    layer's layout from the filter's output to ``out_proj``'s operand
-    (``ops/rotary.py slots_plan``): one row kernel (``slot_rows``) reads
-    the filter's ``[q | k | v]`` rows once and writes the normalised q and
-    k and v into slots, ``gated_delta_rule`` takes them and hands ``o``
-    back in slots (``slots=(dk, dv)``; what the ``+flash`` remat policy
-    keeps a layer is that slotted ``o``), and ``gated_norm_rows`` reads
-    ``o`` from them beside the gate ``z`` as ``in_proj`` wrote it and
-    writes ``y`` as ``out_proj`` reads it.  No pad, cut or ``(B, S, H,
-    d)`` view is left around the rule.  Which of the three runs is read
-    from the shapes and the device, never set: the CPU, a mesh that
-    refuses and a rule that keeps XLA's form run the view;
-    ``kernel_dispatch_total{site="qk_rows" | "gated_norm_rows"}`` says
-    which, and why."""
+    Where the normalised heads lie between the filter and ``out_proj`` -
+    rows at whole lane tiles (128 x 128), lane slots (96 x 192) or the
+    ``(B, S, H, d)`` float32 view - is the kernels' matter and is read from
+    the shapes, the device and the mesh by ``ops/gated_delta.py
+    normalised_heads`` / ``heads_rule`` / ``gated_norm``, whose text says
+    which runs where; ``kernel_dispatch_total`` says which ran, and why."""
     cfg: LlamaConfig
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.gated_delta import gated_delta_rule
+        from ..ops import gated_delta
         from ..ops.short_conv import causal_conv_rows
 
         cfg = self.cfg
@@ -1063,57 +1041,21 @@ class GatedDeltaNet(nn.Module):
                 key, shape, dtype, 1e-3, 16.0)), ("heads",)), (Hv,), f32)
         dt_bias = self.param("dt_bias", nn.with_partitioning(
             nn.initializers.ones, ("heads",)), (Hv,), f32)
-        # a head that is no whole lane tiles lies in a lane slot from here
-        # to out_proj's operand, where the kernels take it
-        whole = not (dk % 128 or d % 128)
         with trace.device_span("linear_attn/delta_rule"):
-            def unit(t, H):         # each head's channels to length 1
-                t = t.astype(f32).reshape(B, S, H, dk)
-                return t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True)
-                                         + 1e-6)
-
-            rows = jax.ShapeDtypeStruct((B, S, Hk * dk), qkv.dtype)
-            plan = rows_plan(rows, rows, dk, norm=True) if whole \
-                else slots_plan(qkv, Hk, dk, Hv, d, cfg.linear_chunk_size)
-            slotted = plan is not None and not whole
-            if slotted:
-                q, k, v = slot_rows(qkv, Hk, dk, Hv, d, plan)
-            elif plan is not None:
-                # x / |x| = rms_norm(x, dk^-1/2, eps / dk), on the rows
-                q, k = rotate_rows(
-                    qkv[..., :Hk * dk], qkv[..., Hk * dk:2 * Hk * dk], None,
-                    dk, plan, q_scale=jnp.full((dk,), 1 / dk, f32),
-                    k_scale=jnp.full((dk,), dk ** -0.5, f32), eps=1e-6 / dk)
-            else:
-                q = (unit(qkv[..., :Hk * dk], Hk) * dk ** -0.5).astype(
-                    cfg.dtype)
-                k = unit(qkv[..., Hk * dk:2 * Hk * dk], Hk).astype(cfg.dtype)
+            heads = gated_delta.normalised_heads(qkv, Hk, dk, Hv, d,
+                                                 cfg.linear_chunk_size)
             beta = jax.nn.sigmoid(ba[..., :Hv].astype(f32))
             if cfg.linear_allow_neg_eigval:
                 beta = 2.0 * beta
             g = -jnp.exp(a_log) * jax.nn.softplus(
                 ba[..., Hv:].astype(f32) + dt_bias)
-            o = gated_delta_rule(
-                q.reshape(B, S, -1), k.reshape(B, S, -1),
-                v if slotted else qkv[..., 2 * Hk * dk:], g, beta,
-                key_heads=Hk, chunk=cfg.linear_chunk_size,
-                slots=(dk, d) if slotted else None)
+            o = gated_delta.heads_rule(heads, g, beta)
         w_o = self.param("o_norm", nn.with_partitioning(
             nn.initializers.ones, ("head_dim",)), (d,), cfg.param_dtype)
         with trace.device_span("linear_attn/gated_norm"):
             # norm first, gate second; w from ones whatever the other
             # norms of the model are
-            if whole:
-                plan = gated_norm_plan(o, d)
-            if plan is not None:
-                y = gated_norm_rows(o, z, w_o, d, plan, eps=cfg.rms_norm_eps)
-            else:
-                from .common import rms_norm
-
-                y = rms_norm(o.reshape(B, S, Hv, d).astype(f32), w_o,
-                             cfg.rms_norm_eps)
-                y = (y * jax.nn.silu(
-                    z.reshape(B, S, Hv, d).astype(f32))).astype(cfg.dtype)
+            y = gated_delta.gated_norm(heads, o, z, w_o, cfg.rms_norm_eps)
         with trace.device_span("linear_attn/out_proj"):
             return _dense(y.reshape(B, S, Hv * d), E, ("heads", "embed"),
                           cfg=cfg, name="out_proj", module=self)

@@ -196,7 +196,7 @@ def kernel_refusal(*, n_devices: int, offload: bool, fp16: bool
     SPMD partitioner, so every device has to hold whole leaves (one
     device today); fp16's overflow skip is a ``where(finite, new, old)``
     over the state, which would undo the in-place aliasing."""
-    from . import attention
+    from .pallas.spmd import on_tpu
 
     if offload:
         return "optimizer offload"
@@ -204,7 +204,7 @@ def kernel_refusal(*, n_devices: int, offload: bool, fp16: bool
         return "fp16 overflow skip selects over the state"
     if n_devices > 1:
         return f"mesh of {n_devices} devices"
-    if not attention.on_tpu():
+    if not on_tpu():
         return "not a TPU"
     return None
 
@@ -233,12 +233,11 @@ def kernel_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
     factor and clip reach a leaf as one scalar.  ``opt_state`` is the
     UNCHANGED optax chain state (checkpoints stay compatible).  The
     caller asks :func:`kernel_refusal` first."""
-    from . import attention
     from .pallas.adam8bit_kernel import apply_leaf, leaf_refusal
-    from .pallas.spmd import note_dispatch
+    from .pallas.spmd import note_dispatch, on_tpu
 
     def apply(grads, params, opt_state, grad_norm, factor):
-        interp = not attention.on_tpu()
+        interp = not on_tpu()
         st = _find_state(opt_state)
         if st is None:
             raise ValueError("no Adam8bitState found in opt_state; the "

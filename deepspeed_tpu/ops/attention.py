@@ -62,7 +62,7 @@ def _plan(impl: str, q, k, v, *, window, block, q_rope, dense: bool,
     one); XLA otherwise.  The one caller notes the verdict."""
     from .pallas.flash_attention import (flash_lanes, grouped_in_kernel,
                                          mla_lanes)
-    from .pallas.spmd import kernel_mesh_plan
+    from .pallas.spmd import kernel_mesh_plan, mesh_said
 
     B, S, H, D = q.shape
     group = H // k.shape[2]
@@ -93,14 +93,13 @@ def _plan(impl: str, q, k, v, *, window, block, q_rope, dense: bool,
     plain = block is None and q_rope is None    # written for heads over tp
     verdict, axes = kernel_mesh_plan(B, heads=H, allow_tp=plain)
     if verdict is None:
-        return xla("kernel_mesh_plan refused the mesh")
+        return xla(mesh_said(verdict, axes))
     tp = 1
     if verdict == "shard":
         from ..comm.mesh import get_mesh
 
         tp = get_mesh().shape.get("tp", 1)
-    said = [how, "one device" if verdict == "direct"
-            else f"shard_map over batch axes {axes}",
+    said = [how, mesh_said(verdict, axes),
             flash_lanes(H // tp, D).reason]     # the layout a shard runs
     if q_rope is not None:
         said.append(f"{D} + {q_rope.shape[-1]} shared rope lanes, "

@@ -85,7 +85,7 @@ widths, 96 and 192, also where the operands arrive in slots of 128 and
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -283,63 +283,42 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def kernels_refusal(S: int, chunk: int, Hk: int, Hv: int, dk: int, dv: int,
-                    dtype, auto: bool = True) -> Optional[str]:
+                    dtype) -> Optional[str]:
     """``None`` where the kernels take ``Hk`` key heads of ``dk`` and ``Hv``
-    value heads of ``dv`` channels over ``S`` positions (the mesh apart)
-    and, under ``auto``, ``impl="auto"`` hands them over, else why XLA's
+    value heads of ``dv`` channels over ``S`` positions (the device and the
+    mesh apart: ``ops/pallas/spmd.py plan`` asks those), else why XLA's
     form runs."""
-    from .attention import on_tpu
     from .pallas import gated_delta as kernel
 
     if S % chunk:
         return f"rows of {S} positions are no whole chunks of {chunk}"
-    reason = kernel.supported(S // chunk, chunk, dk, dv, dtype, Hv // Hk)
-    return "no TPU" if reason is None and auto and not on_tpu() else reason
-
-
-def _plan(B, S, Hk, Hv, dk, dv, dtype, chunk: int, impl: str):
-    """``(impl, reason, batch axes of a shard_map or None)``."""
-    from .pallas.spmd import kernel_mesh_plan
-
-    if impl == "xla":
-        return impl, "impl='xla' asked for", None
-    reason = kernels_refusal(S, chunk, Hk, Hv, dk, dv, dtype, impl == "auto")
-    verdict = axes = None
-    if reason is None:
-        verdict, axes = kernel_mesh_plan(B)
-        if verdict is None:
-            reason = "kernel_mesh_plan refused the mesh"
-    if reason is not None:
-        if impl == "pallas":
-            raise NotImplementedError(f"gated_delta impl='pallas': {reason}")
-        return "xla", reason, None
-    r = Hv // Hk
-    of = "" if dk == dv else f"of {dk} "
-    return "pallas", (f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}"
-                      f"x {r} value heads of {dv}, fused; "
-                      + ("one device" if verdict == "direct" else
-                         f"shard_map over batch axes {axes}")), axes
+    return kernel.supported(S // chunk, chunk, dk, dv, dtype, Hv // Hk)
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      beta: jax.Array, *, chunk: int = 64,
                      key_heads: int = None, impl: str = "auto",
-                     interpret: bool = False,
-                     slots: Optional[Tuple[int, int]] = None) -> jax.Array:
+                     interpret: bool = False) -> jax.Array:
     """``o`` (B, S, Hv*dv) of the gated delta rule: ``q``, ``k`` (B, S,
     Hk*dk) normalised and scaled by the caller, ``v`` (B, S, Hv*dv), ``g``
     (B, S, Hv) float32 log-decays (<= 0), ``beta`` (B, S, Hv), as a layer's
     projections and filter wrote them.  ``key_heads`` is ``Hk``; None where
     key and value heads are as wide (``dk = dv``), which then says it.
     Each row of the batch starts from a zero state; ``S`` is a multiple of
-    ``chunk``.  ``slots = (dk, dv)``: the operands hold each head in the
-    kernels' lane slot already (``q``, ``k`` (B, S, Hk slot(dk)), ``v`` (B,
-    S, Hv slot(dv)), a head's own ``dk`` / ``dv`` channels from the slot's
-    first lane, zeros behind) and ``o`` comes back so; only the kernels take
-    them (``kernels_refusal`` says beforehand), the widths are named for
-    the dispatch reason and the gauge.  See the module's text."""
+    ``chunk``.  See the module's text."""
+    return _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret)
+
+
+def _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret,
+              slots: Optional[Tuple[int, int]] = None):
+    """:func:`gated_delta_rule`; under ``slots = (dk, dv)`` the operands
+    hold each head in the kernels' lane slot already (``q``, ``k`` (B, S,
+    Hk slot(dk)), ``v`` (B, S, Hv slot(dv)), a head's own ``dk`` / ``dv``
+    channels from the slot's first lane, zeros behind) and ``o`` comes back
+    so; only the kernels take them (:func:`slots_plan` asks beforehand),
+    the widths are named for the dispatch reason and the gauge."""
+    from .pallas import spmd
     from .pallas.gated_delta import _slot
-    from .pallas.spmd import note_dispatch
 
     if impl not in IMPLS:
         raise ValueError(f"gated_delta impl {impl!r}: one of {IMPLS}")
@@ -364,29 +343,211 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                                       if slots else (Hk * dk, Hv * dv)):
         raise ValueError(f"k {k.shape} and v {v.shape} are no {Hk} and {Hv} "
                          f"lane slots of heads of {slots} channels")
-    impl, reason, axes = _plan(B, S, Hk, Hv, dk, dv, v.dtype, chunk, impl)
-    if slots and impl != "pallas":
+    of = "" if dk == dv else f"of {dk} "
+    plan = spmd.plan(
+        "gated_delta", B, "impl='xla' asked for" if impl == "xla"
+        else kernels_refusal(S, chunk, Hk, Hv, dk, dv, v.dtype),
+        f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}x {Hv // Hk} "
+        f"value heads of {dv}, fused", tpu=impl == "auto",
+        must=impl == "pallas")
+    if slots and plan is None:
         raise NotImplementedError(
-            f"gated_delta_rule: slotted operands are the kernels' layout, "
-            f"and XLA's form runs ({reason})")
-    note_dispatch("gated_delta", impl, reason)
+            "gated_delta_rule: slotted operands are the kernels' layout, "
+            "and XLA's form runs")
     _note_state(dk, dv)
-    fused = interpret if impl == "pallas" else None
-
-    def run(*args):
-        return _rule(*args, chunk, fused, Hk)
-
-    if axes is not None:
-        from jax.sharding import PartitionSpec as P
-
-        from ..comm.mesh import get_mesh
-
-        rows = P(axes if axes else None, None, None)
-        run = jax.shard_map(run, mesh=get_mesh(), in_specs=(rows,) * 5,
-                            out_specs=rows, check_vma=False)
+    fused = interpret if plan is not None else None
     # under the name a ``+flash`` remat policy keeps (``models/common.py
     # resolve_remat_policy``): the output is no dot output, and recomputed it
     # is the whole chunked form a second time (a seventh of a step at the
     # eighth cell's shape) for 8 KB a token a layer kept
-    return checkpoint_name(run(q, k, v, g.astype(jnp.float32), beta),
-                           "gated_delta_out")
+    return checkpoint_name(spmd.over_batch(
+        lambda *args: _rule(*args, chunk, fused, Hk),
+        plan or spmd.Plan("direct"),
+        (q, k, v, g.astype(jnp.float32), beta)), "gated_delta_out")
+
+
+# -- a layer's heads around the rule ----------------------------------------
+#
+# Both per-head norms of a Gated DeltaNet layer stand between kernels that
+# read and write ``(B, S, H d)`` rows (the filter before, the rule between,
+# ``out_proj`` after), and on the chip the ``(B, S, H, d)`` float32 view they
+# are written on is no bitcast of such rows: each cost a copy in and a
+# reshape out, forward, recomputation and backward.  Which of three layouts
+# a layer's heads take is read here from the shapes, the device and the
+# mesh, never set:
+#
+# - a head is whole lane tiles (128 x 128, Qwen3-Next; PR 53): q and k are
+#   normalised on the rows by ``ops/rotary.py rotate_rows`` under constant
+#   scales and no positions, the gated norm by :func:`gated_norm_rows`;
+# - it is not (96 x 192, Olmo-Hybrid; PR 55): the rule's kernels read each
+#   head from the first lane of a slot of the next multiple of 128 lanes,
+#   zeros behind it, and those slots are the layout from the filter's output
+#   to ``out_proj``'s operand (:func:`slots_plan`, one guard for all three):
+#   :func:`slot_rows` writes the normalised q and k and v into slots, the
+#   rule takes them and hands ``o`` back in slots (what a ``+flash`` remat
+#   policy keeps a layer is that slotted ``o``), :func:`gated_norm_rows`
+#   reads it beside the gate as ``in_proj`` wrote it.  No pad, cut or 4-D
+#   view is left around the rule;
+# - the CPU, a mesh that refuses and a rule that keeps XLA's form run the
+#   ``(B, S, H, d)`` float32 lines.
+#
+# ``kernel_dispatch_total{site="qk_rows" | "gated_norm_rows"}`` says which,
+# and why.
+
+class MixerHeads(NamedTuple):
+    """What :func:`normalised_heads` made of a layer's filtered rows, for
+    :func:`heads_rule` and :func:`gated_norm`."""
+    q: jax.Array                    # normalised and scaled, as they lie:
+    k: jax.Array                    # rows, slots or (B, S, Hk, dk)
+    v: Optional[jax.Array]          # in slots, or None: the rows' own
+    rows: jax.Array                 # [q | k | v] as the filter wrote them
+    widths: Tuple[int, int, int, int]       # Hk, dk, Hv, dv
+    chunk: int
+    plan: Optional[tuple]           # of slots_plan where the slots run
+
+
+def normalised_heads(rows: jax.Array, key_heads: int, dk: int,
+                     value_heads: int, dv: int, chunk: int, *,
+                     interpret: bool = False) -> MixerHeads:
+    """Of a Gated DeltaNet's filtered rows ``[q | k | v]`` (B, S, 2 Hk dk +
+    Hv dv): ``q / |q| dk^-1/2`` and ``k / |k|`` a head (eps 1e-6, float32
+    sums), in the layout the rule will read them in."""
+    from .rotary import rotate_rows, rows_plan
+
+    f32 = jnp.float32
+    B, S, _ = rows.shape
+    Hk = key_heads
+    whole = not (dk % 128 or dv % 128)
+    made = functools.partial(MixerHeads, rows=rows, chunk=chunk,
+                             widths=(Hk, dk, value_heads, dv))
+
+    def unit(t):                    # each head's channels to length 1
+        t = t.astype(f32).reshape(B, S, Hk, dk)
+        return t * lax.rsqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+
+    if not whole:
+        plan = slots_plan(rows, Hk, dk, value_heads, dv, chunk)
+        if plan is not None:
+            return made(*slot_rows(rows, Hk, dk, value_heads, dv, plan,
+                                   interpret=interpret), plan=plan)
+    else:
+        one = jax.ShapeDtypeStruct((B, S, Hk * dk), rows.dtype)
+        plan = rows_plan(one, one, dk, norm=True)
+        if plan is not None:
+            # x / |x| = rms_norm(x, dk^-1/2, eps / dk), on the rows
+            return made(*rotate_rows(
+                rows[..., :Hk * dk], rows[..., Hk * dk:2 * Hk * dk], None,
+                dk, plan, q_scale=jnp.full((dk,), 1 / dk, f32),
+                k_scale=jnp.full((dk,), dk ** -0.5, f32), eps=1e-6 / dk,
+                interpret=interpret), None, plan=None)
+    q = (unit(rows[..., :Hk * dk]) * dk ** -0.5).astype(rows.dtype)
+    k = unit(rows[..., Hk * dk:2 * Hk * dk]).astype(rows.dtype)
+    return made(q, k, None, plan=None)
+
+
+def heads_rule(heads: MixerHeads, g: jax.Array, beta: jax.Array, *,
+               impl: str = "auto", interpret: bool = False) -> jax.Array:
+    """:func:`gated_delta_rule` of ``heads``: ``o`` (B, S, Hv dv) rows, or
+    in the slots the heads lie in."""
+    Hk, dk, _, dv = heads.widths
+    B, S, _ = g.shape
+    return _dispatch(
+        heads.q.reshape(B, S, -1), heads.k.reshape(B, S, -1),
+        heads.rows[..., 2 * Hk * dk:] if heads.v is None else heads.v, g,
+        beta, heads.chunk, Hk, impl, interpret,
+        slots=None if heads.v is None else (dk, dv))
+
+
+def gated_norm(heads: MixerHeads, o: jax.Array, z: jax.Array, w: jax.Array,
+               eps: float, *, interpret: bool = False) -> jax.Array:
+    """``rms_norm(o, w, eps) * silu(z)`` a value head (norm first, gate
+    second) of :func:`heads_rule`'s ``o`` and the gate's rows ``z`` (B, S,
+    Hv dv): ``y`` as rows, or (B, S, Hv, dv) off the kernels."""
+    _, dk, Hv, dv = heads.widths
+    plan = heads.plan if dk % 128 or dv % 128 else gated_norm_plan(o, dv)
+    if plan is not None:
+        return gated_norm_rows(o, z, w, dv, plan, eps=eps,
+                               interpret=interpret)
+    f32 = jnp.float32
+    B, S, _ = o.shape
+    of = o.reshape(B, S, Hv, dv).astype(f32)
+    y = of * lax.rsqrt(jnp.mean(of ** 2, axis=-1, keepdims=True) + eps) * w
+    return (y * jax.nn.silu(z.reshape(B, S, Hv, dv).astype(f32))).astype(
+        o.dtype)
+
+
+def gated_norm_plan(o: jax.Array, head_dim: int) -> Optional[tuple]:
+    """Whether a Gated DeltaNet's output ``o`` (B, S, H*D) and its gate stay
+    rows through the gated per-head norm: ``ops/pallas/spmd.py plan``'s
+    verdict for :func:`gated_norm_rows`, counted in
+    ``kernel_dispatch_total{site="gated_norm_rows"}``."""
+    from .pallas import qk_rows, spmd
+
+    return spmd.plan(
+        "gated_norm_rows", o.shape[0],
+        f"head_dim {head_dim} is no multiple of 128" if head_dim % 128
+        else qk_rows.gated_norm_supported(o.shape[1], o.shape[2], o.dtype),
+        f"head_dim {head_dim}, rows {o.shape[2]}")
+
+
+def gated_norm_rows(o: jax.Array, z: jax.Array, w: jax.Array, head_dim: int,
+                    plan: tuple, *, eps: float, interpret: bool = False
+                    ) -> jax.Array:
+    """``rms_norm(o, w, eps) * silu(z)`` over each head of ``head_dim``
+    lanes of the rows ``o`` and ``z`` (B, S, H*D), ``w`` (D,), under a
+    ``plan`` of :func:`gated_norm_plan` (the Pallas pass
+    ``ops/pallas/qk_rows.py gated_norm_rows``, forward and backward).
+    Under a plan of :func:`slots_plan` ``o`` holds a head a lane slot, as
+    the delta rule's kernels wrote it; ``z`` and the result stay rows.
+    Float32 arithmetic, rounded once."""
+    from .pallas import spmd
+    from .pallas.qk_rows import gated_norm_rows as kernel
+
+    return spmd.over_batch(lambda *a: kernel(*a, head_dim, eps, interpret),
+                           plan, (o, z, w), whole=(2,))
+
+
+def slots_plan(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
+               dv: int, chunk: int) -> Optional[tuple]:
+    """Whether a Gated DeltaNet layer whose heads are no whole lane tiles
+    (``dk`` or ``dv`` no multiple of 128) keeps them in LANE SLOTS from the
+    filter's ``rows`` (B, S, 2 Hk dk + Hv dv) to ``out_proj``'s operand.
+    It needs :func:`slot_rows`, the rule's kernels and
+    :func:`gated_norm_rows`, so it is also None where the rule would keep
+    XLA's form (:func:`kernels_refusal`).  Counted in
+    ``kernel_dispatch_total`` under both ``site="qk_rows"`` and
+    ``site="gated_norm_rows"``."""
+    from .pallas import qk_rows, spmd
+
+    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
+    sk, sv = qk_rows.slot(dk), qk_rows.slot(dv)
+    B, S, _ = rows.shape
+    rule = kernels_refusal(S, chunk, key_heads, value_heads, dk, dv,
+                           rows.dtype)
+    refusal = f"the delta rule keeps XLA's form: {rule}" if rule else (
+        qk_rows.slot_rows_supported(S, heads, rows.dtype)
+        or qk_rows.gated_norm_supported(S, value_heads * dv, rows.dtype, dv))
+    plan = spmd.plan(
+        "qk_rows", B, refusal, f"heads of {dk} and {dv} in slots of {sk} and "
+        f"{sv}, rows {heads.width}")
+    spmd.plan("gated_norm_rows", B, refusal, f"head_dim {dv} in slots of "
+              f"{sv}, rows {value_heads * dv}")
+    return plan
+
+
+def slot_rows(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
+              dv: int, plan: tuple, *, eps: float = 1e-6,
+              interpret: bool = False
+              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Of a Gated DeltaNet's filtered rows ``[q | k | v]`` (B, S, 2 Hk dk +
+    Hv dv), under a ``plan`` of :func:`slots_plan`: ``q / |q| dk^-1/2`` and
+    ``k / |k|`` a head (float32 sums over the head's own channels) as (B, S,
+    Hk slot(dk)) and ``v`` as (B, S, Hv slot(dv)), each head from the first
+    lane of its slot, zeros behind (the Pallas pass
+    ``ops/pallas/qk_rows.py slot_rows``, forward and backward)."""
+    from .pallas import qk_rows, spmd
+
+    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
+    return spmd.over_batch(
+        lambda x: qk_rows.slot_rows(x, heads, eps, interpret), plan, (rows,),
+        outs=3)

@@ -70,22 +70,14 @@ def _tiles(m: int, k: int, n: int) -> Optional[Tuple[int, int, int]]:
     return None if None in (tm, tk, tn) else (tm, tk, tn)
 
 
-def _plan(lhs, rhs, per_device: Optional[bool]) -> Tuple[Optional[tuple], str]:
-    from .attention import on_tpu
-    from .pallas.spmd import kernel_mesh_plan
+# what ``per_device=False`` books: the caller's ``shard_map`` did not engage
+GLOBAL = "global arrays of a mesh of several devices"
 
-    if not on_tpu():
-        return None, "no TPU"
-    if per_device is None:
-        per_device = kernel_mesh_plan(lhs.shape[0])[0] == "direct"
-    if not per_device:
-        return None, "global arrays of a mesh of several devices"
-    if lhs.dtype != rhs.dtype or lhs.dtype not in (jnp.bfloat16, jnp.float32):
-        return None, f"operand types {lhs.dtype} x {rhs.dtype}"
-    tiles = _tiles(lhs.shape[0], rhs.shape[1], rhs.shape[2])
-    if tiles is None:
-        return None, f"no tile divides {lhs.shape} x {rhs.shape}"
-    return tiles, f"tiles {tiles}"
+
+def _own(per_device: Optional[bool], rows: int) -> Optional[int]:
+    """The rows ``ops/pallas/spmd.py plan`` asks the mesh about: none where
+    the caller has said whose the operands are."""
+    return rows if per_device is None else None
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
@@ -94,16 +86,24 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     rows sorted by group and ``group_sizes`` (G,) int32 summing to N.
     ``per_device``: the operands are one device's own (inside a
     ``shard_map``, or a single device); ``None`` asks the mesh."""
-    from .pallas.spmd import note_dispatch
+    from .pallas import spmd
 
     group_sizes = group_sizes.astype(jnp.int32)
-    tiles, reason = _plan(lhs, rhs, per_device)
-    if tiles is None:
-        note_dispatch("grouped_matmul", "ragged_dot", reason)
+    tiles = _tiles(lhs.shape[0], rhs.shape[1], rhs.shape[2])
+    if per_device is False:
+        refusal = GLOBAL
+    elif lhs.dtype != rhs.dtype \
+            or lhs.dtype not in (jnp.bfloat16, jnp.float32):
+        refusal = f"operand types {lhs.dtype} x {rhs.dtype}"
+    else:
+        refusal = None if tiles else \
+            f"no tile divides {lhs.shape} x {rhs.shape}"
+    if spmd.plan("grouped_matmul", _own(per_device, lhs.shape[0]), refusal,
+                 f"tiles {tiles}", fallback="ragged_dot", kernel="megablox",
+                 shard=False) is None:
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    note_dispatch("grouped_matmul", "megablox", reason)
     return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
                tiling=tiles)
 
@@ -126,26 +126,17 @@ def _rows_plan(x, k: int, per_device: Optional[bool], absent: bool) -> bool:
     already runs at the rate one DMA a row can be issued (v5e, 65,536
     rows of 2048: 1.35 ms against the kernel's 2.8; PERF.md section 6,
     PR 32)."""
-    from .attention import on_tpu
-    from .pallas import moe_rows
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
+    from .pallas import moe_rows, spmd
 
     S, M = x.shape
-    if not on_tpu():
-        reason = "no TPU"
-    elif not absent:
-        reason = "every row holds a pair"
+    if not absent:
+        refusal = "every row holds a pair"
     else:
-        if per_device is None:
-            per_device = kernel_mesh_plan(S)[0] == "direct"
-        reason = moe_rows.supported(S, k, M, x.dtype) if per_device \
-            else "global arrays of a mesh of several devices"
-    if reason is not None:
-        note_dispatch("moe_rows", "xla", reason)
-        return False
-    note_dispatch("moe_rows", "pallas",
-                  f"rows {S * k} x {M}, block {moe_rows.STEP}")
-    return True
+        refusal = GLOBAL if per_device is False \
+            else moe_rows.supported(S, k, M, x.dtype)
+    return spmd.plan("moe_rows", _own(per_device, S), refusal,
+                     f"rows {S * k} x {M}, block {moe_rows.STEP}",
+                     shard=False) is not None
 
 
 def _live(order: jax.Array, absent: bool) -> jax.Array:

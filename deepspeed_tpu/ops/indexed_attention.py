@@ -263,26 +263,6 @@ def _pallas_form(q, k, v, qi, ki, w, topk, scale, selection, interpret):
                             jax.lax.stop_gradient(counts))
 
 
-def _plan(impl: str, q, interpret: bool):
-    """``(impl, reason)``: the kernels where they were asked for, the
-    shapes tile and the operands are one device's own."""
-    from .attention import on_tpu
-    from .pallas.spmd import kernel_mesh_plan
-
-    B, S, H, D = q.shape
-    if impl == "jnp":
-        return "jnp", "impl='jnp' requested"
-    if impl == "pallas":
-        return "pallas", "impl='pallas' requested"
-    if not (interpret or on_tpu()):
-        return "jnp", "auto: not a TPU"
-    if D % 128 or S % 128:
-        return "jnp", f"auto: head_dim {D} or row {S} is no multiple of 128"
-    if kernel_mesh_plan(B)[0] != "direct":
-        return "jnp", "auto: the operands are not one device's own"
-    return "pallas", "auto: TPU, one device, head_dim and row tile"
-
-
 def indexed_attention(q, k, v, qi, ki, w, *, topk: int,
                       scale: Optional[float] = None, impl: str = "auto",
                       selection=None,
@@ -290,15 +270,26 @@ def indexed_attention(q, k, v, qi, ki, w, *, topk: int,
     """``q`` (B, S, H, D), ``k`` and ``v`` (B, S, KV, D), the indexer's
     ``qi`` (B, S, heads, channels), ``ki`` (B, S, channels) and ``w`` (B,
     S, heads); see the module's docstring."""
-    from .pallas.spmd import note_dispatch
+    from .pallas import spmd
 
     if impl not in IMPLS:
         raise ValueError(f"impl {impl!r}; there are {IMPLS}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    chosen, reason = _plan(impl, q, interpret)
-    note_dispatch("indexed_attention", chosen, reason)
-    if chosen == "jnp":
+    B, S, H, D = q.shape
+    # the kernels where they were asked for, or the shapes tile, this is a
+    # TPU (or the interpreter runs them) and the operands one device's own
+    asked = impl != "auto"
+    if impl == "jnp":
+        refusal = "impl='jnp' requested"
+    elif asked or not (D % 128 or S % 128):
+        refusal = None
+    else:
+        refusal = f"auto: head_dim {D} or row {S} is no multiple of 128"
+    if spmd.plan("indexed_attention", None if asked else B, refusal,
+                 "impl='pallas' requested" if asked
+                 else "auto: TPU, head_dim and row tile", fallback="jnp",
+                 tpu=not (asked or interpret), shard=False) is None:
         return _jnp_form(q, k, v, qi, ki, w, int(topk), scale, selection)
     return _pallas_form(q, k, v, qi, ki, w, int(topk), scale, selection,
                         interpret)

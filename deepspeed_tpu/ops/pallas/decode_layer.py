@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused_ops import _gelu_tanh, _pad_rows
+from .fused_mlp import _gelu_tanh, _pad_rows
 
 # Row padding: Mosaic wants >= 8 (f32) / 16 (bf16) sublanes per tile; the
 # decode row count (n_slots) is tiny either way, so always pad to 16.

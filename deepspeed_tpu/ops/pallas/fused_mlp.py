@@ -25,7 +25,33 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .fused_ops import _gelu_tanh, _gelu_tanh_grad, _pad_rows
+_SQRT_2_OVER_PI = 0.7978845608028654
+
+
+def _pad_rows(x2: jax.Array, block: int) -> Tuple[jax.Array, int]:
+    """Pad the leading (row) dim up to a multiple of ``block`` so odd row
+    counts keep full-size tiles (padded rows carry zero cotangents, so the
+    partial-sum reductions in the backward kernels are unaffected)."""
+    R = x2.shape[0]
+    rem = R % block
+    if rem == 0:
+        return x2, R
+    pad = block - rem
+    return jnp.pad(x2, ((0, pad),) + ((0, 0),) * (x2.ndim - 1)), R
+
+
+def _gelu_tanh(u):
+    inner = _SQRT_2_OVER_PI * (u + 0.044715 * u * u * u)
+    return 0.5 * u * (1.0 + jnp.tanh(inner))
+
+
+def _gelu_tanh_grad(u):
+    u3 = 0.044715 * u * u * u
+    inner = _SQRT_2_OVER_PI * (u + u3)
+    t = jnp.tanh(inner)
+    sech2 = 1.0 - t * t
+    return 0.5 * (1.0 + t) + 0.5 * u * sech2 * _SQRT_2_OVER_PI * \
+        (1.0 + 3.0 * 0.044715 * u * u)
 
 
 def _fwd_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, y_ref):
@@ -259,29 +285,14 @@ def fused_mlp_spmd(x, w1, b1, w2, b2, *, block_rows: int = 128,
     something this kernel cannot handle (caller takes the XLA path); past
     that guard the kernel's errors propagate.  Dispatch policy (pp/sp/tp
     guards, no-mesh multi-device) lives in :mod:`.spmd`."""
-    from .spmd import kernel_mesh_plan, note_dispatch
+    from . import spmd
 
-    verdict, batch_axes = kernel_mesh_plan(x.shape[0], allow_tp=False)
-    if verdict is None:
-        note_dispatch("fused_mlp", "xla", "kernel_mesh_plan refused the mesh")
+    # its callers ask for a TPU (or the interpreter) before they come here
+    plan = spmd.plan("fused_mlp", x.shape[0], None, "both matmuls fused",
+                     kernel="kernel", tpu=False)
+    if plan is None:
         return None
-    note_dispatch("fused_mlp", "kernel", f"mesh plan {verdict!r}")
-    if verdict == "direct":
-        return fused_mlp(x, w1, b1, w2, b2, block_rows=block_rows,
-                         interpret=interpret)
-    from jax.sharding import PartitionSpec as P
-
-    from ...comm.mesh import get_mesh
-
-    xspec = P(batch_axes, *([None] * (x.ndim - 1)))
-    wspec = P(None, None)
-    bspec = P(None)
-    mapped = jax.shard_map(
+    return spmd.over_batch(
         functools.partial(fused_mlp, block_rows=block_rows,
                           interpret=interpret),
-        mesh=get_mesh(),
-        in_specs=(xspec, wspec, bspec, wspec, bspec),
-        out_specs=xspec,
-        check_vma=False,
-    )
-    return mapped(x, w1, b1, w2, b2)
+        plan, (x, w1, b1, w2, b2), whole=(1, 2, 3, 4))

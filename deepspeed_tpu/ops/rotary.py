@@ -24,14 +24,10 @@ decision with the guard that made it.
 
 The same pass without positions is any per-head norm of rows: a Gated
 DeltaNet's l2-norms of q and k are ``rotate_rows`` under constant scales
-(PR 53), and its gated output norm has a guard and a pass of its own,
-:func:`gated_norm_plan` / :func:`gated_norm_rows`
-(``kernel_dispatch_total{site="gated_norm_rows"}``).  Where that layer's
-heads are no whole lane tiles (Olmo-Hybrid's 96 x 192, PR 55) one guard,
-:func:`slots_plan`, decides for both norms and the rule between them: the
-heads then lie in lane slots from :func:`slot_rows` to
-:func:`gated_norm_rows`.  Attention's heads of 64 / 80 / 96 channels keep
-the ``(B, S, H, D)`` functions: a rotation over a slot is not written.
+(PR 53; ``ops/gated_delta.py normalised_heads``, which also holds that
+layer's plans that rotate nothing).  Attention's heads of 64 / 80 / 96
+channels keep the ``(B, S, H, D)`` functions: a rotation over a lane slot is
+not written.
 """
 from __future__ import annotations
 
@@ -171,71 +167,25 @@ def rows_plan(q: jax.Array, k: jax.Array, head_dim: int, *,
               rotary_dim: Optional[int] = None, interleaved: bool = False,
               decode: bool = False, norm: bool = False) -> Optional[tuple]:
     """Whether q (B, S, H*D) and k (B, S, KV*D) stay rows through their
-    rotation (and per-head ``norm``): ``kernel_mesh_plan``'s verdict and
-    batch axes where :func:`rotate_rows` takes them, None where the
+    rotation (and per-head ``norm``): ``ops/pallas/spmd.py plan``'s verdict
+    and batch axes where :func:`rotate_rows` takes them, None where the
     ``(B, S, H, D)`` functions do.  Counted, with the guard that decided,
     in ``kernel_dispatch_total{site="qk_rows"}``."""
-    from .attention import on_tpu
-    from .pallas import qk_rows
+    from .pallas import qk_rows, spmd
 
     if head_dim % 128:
-        reason = f"head_dim {head_dim} is no multiple of 128"
+        refusal = f"head_dim {head_dim} is no multiple of 128"
     elif rotary_dim not in (None, head_dim):
-        reason = f"rotary_dim {rotary_dim} < head_dim {head_dim}"
+        refusal = f"rotary_dim {rotary_dim} < head_dim {head_dim}"
     elif interleaved:
-        reason = "interleaved pairs"
+        refusal = "interleaved pairs"
     elif decode:
-        reason = "decode: the cache keeps (B, S, KV, D)"
-    elif not on_tpu():
-        reason = "no TPU"
+        refusal = "decode: the cache keeps (B, S, KV, D)"
     else:
-        reason = qk_rows.supported(q.shape[1], q.shape[2], k.shape[2],
-                                   q.dtype, norm)
-    return _mesh_plan("qk_rows", reason, q.shape[0],
-                      f"head_dim {head_dim}, rows {q.shape[2]} + {k.shape[2]}")
-
-
-def _mesh_plan(site: str, reason: Optional[str], batch: int, rows: str
-               ) -> Optional[tuple]:
-    """What a row guard ends on: where no ``reason`` has refused the shape,
-    ``kernel_mesh_plan``'s verdict and batch axes for ``batch`` rows, or
-    None; booked under ``site`` with the guard that decided, or with
-    ``rows`` and how the mesh runs them."""
-    from .pallas.spmd import kernel_mesh_plan, note_dispatch
-
-    verdict = axes = None
-    if reason is None:
-        verdict, axes = kernel_mesh_plan(batch)
-        if verdict is None:
-            reason = "kernel_mesh_plan refused the mesh"
-    if reason is not None:
-        note_dispatch(site, "xla", reason)
-        return None
-    note_dispatch(site, "pallas", f"{rows}; " + (
-        "one device" if verdict == "direct"
-        else f"shard_map over batch axes {axes}"))
-    return verdict, axes
-
-
-def _over_batch(kernel, plan: tuple, args: tuple, outs: int):
-    """``kernel(*args)`` under a ``plan``: directly on one device, else a
-    ``shard_map`` over the plan's batch axes.  Of ``args`` (None where
-    absent) those with the batch's leading dimension are split, the rest -
-    scales, a ``(1, S)`` table that serves every row - go to every rank."""
-    verdict, axes = plan
-    if verdict == "direct":
-        return kernel(*args)
-    from jax.sharding import PartitionSpec as P
-
-    from ..comm.mesh import get_mesh
-
-    rows = P(axes if axes else None, None, None)
-    specs = tuple(None if a is None else rows
-                  if a.ndim == 3 and a.shape[0] == args[0].shape[0] else P()
-                  for a in args)
-    return jax.shard_map(kernel, mesh=get_mesh(), in_specs=specs,
-                         out_specs=(rows,) * outs if outs > 1 else rows,
-                         check_vma=False)(*args)
+        refusal = qk_rows.supported(q.shape[1], q.shape[2], k.shape[2],
+                                    q.dtype, norm)
+    return spmd.plan("qk_rows", q.shape[0], refusal, f"head_dim {head_dim}, "
+                     f"rows {q.shape[2]} + {k.shape[2]}")
 
 
 def row_table(positions: jax.Array, head_dim: int, theta: float = 10000.0,
@@ -257,95 +207,17 @@ def rotate_rows(q: jax.Array, k: jax.Array, positions: Optional[jax.Array],
     (D,) where given (``models/common.py rms_norm`` over the head), then
     turned half-split by ``positions`` (B, S), or (1, S) for every row,
     where given.  Float32 arithmetic, rounded once."""
+    from .pallas import spmd
     from .pallas.qk_rows import qk_rows
 
     angles = None if positions is None \
         else row_table(positions, head_dim, theta, table)
-    return _over_batch(lambda *a: qk_rows(*a, head_dim, eps, interpret),
-                       plan, (q, k, angles, q_scale, k_scale), 2)
-
-
-def gated_norm_plan(o: jax.Array, head_dim: int) -> Optional[tuple]:
-    """Whether a Gated DeltaNet's output ``o`` (B, S, H*D) and its gate stay
-    rows through the gated per-head norm: as :func:`rows_plan`, for
-    :func:`gated_norm_rows`, counted in
-    ``kernel_dispatch_total{site="gated_norm_rows"}``."""
-    from .attention import on_tpu
-    from .pallas import qk_rows
-
-    if head_dim % 128:
-        reason = f"head_dim {head_dim} is no multiple of 128"
-    elif not on_tpu():
-        reason = "no TPU"
-    else:
-        reason = qk_rows.gated_norm_supported(o.shape[1], o.shape[2], o.dtype)
-    return _mesh_plan("gated_norm_rows", reason, o.shape[0],
-                      f"head_dim {head_dim}, rows {o.shape[2]}")
-
-
-def gated_norm_rows(o: jax.Array, z: jax.Array, w: jax.Array, head_dim: int,
-                    plan: tuple, *, eps: float, interpret: bool = False
-                    ) -> jax.Array:
-    """``rms_norm(o, w, eps) * silu(z)`` over each head of ``head_dim``
-    lanes of the rows ``o`` and ``z`` (B, S, H*D), ``w`` (D,), under a
-    ``plan`` of :func:`gated_norm_plan` (the Pallas pass
-    ``ops/pallas/qk_rows.py gated_norm_rows``, forward and backward).
-    Under a plan of :func:`slots_plan` ``o`` holds a head a lane slot, as
-    the delta rule's kernels wrote it; ``z`` and the result stay rows.
-    Float32 arithmetic, rounded once."""
-    from .pallas.qk_rows import gated_norm_rows as kernel
-
-    return _over_batch(lambda *a: kernel(*a, head_dim, eps, interpret),
-                       plan, (o, z, w), 1)
-
-
-def slots_plan(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
-               dv: int, chunk: int) -> Optional[tuple]:
-    """Whether a Gated DeltaNet layer whose heads are no whole lane tiles
-    (``dk`` or ``dv`` no multiple of 128) keeps them in LANE SLOTS from the
-    filter's ``rows`` (B, S, 2 Hk dk + Hv dv) to ``out_proj``'s operand:
-    :func:`slot_rows` writes the normalised q, k and v into the slots the
-    delta rule's kernels read (``ops/pallas/gated_delta.py``), the rule takes
-    and returns slots, :func:`gated_norm_rows` reads them.  As
-    :func:`rows_plan`; it needs all three, so it is also None where the
-    rule would keep XLA's form (``ops/gated_delta.py kernels_refusal``),
-    and the ``(B, S, H, d)`` lines run.  Counted in
-    ``kernel_dispatch_total`` under both ``site="qk_rows"`` and
-    ``site="gated_norm_rows"``."""
-    from .gated_delta import kernels_refusal
-    from .pallas import qk_rows
-
-    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
-    sk, sv = qk_rows.slot(dk), qk_rows.slot(dv)
-    B, S, _ = rows.shape
-    rule = kernels_refusal(S, chunk, key_heads, value_heads, dk, dv,
-                           rows.dtype)
-    reason = f"the delta rule keeps XLA's form: {rule}" if rule else (
-        qk_rows.slot_rows_supported(S, heads, rows.dtype)
-        or qk_rows.gated_norm_supported(S, value_heads * dv, rows.dtype, dv))
-    plan = _mesh_plan(
-        "qk_rows", reason, B, f"heads of {dk} and {dv} in slots of {sk} and "
-        f"{sv}, rows {heads.width}")
-    _mesh_plan("gated_norm_rows", reason, B, f"head_dim {dv} in slots of "
-               f"{sv}, rows {value_heads * dv}")
-    return plan
-
-
-def slot_rows(rows: jax.Array, key_heads: int, dk: int, value_heads: int,
-              dv: int, plan: tuple, *, eps: float = 1e-6,
-              interpret: bool = False
-              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Of a Gated DeltaNet's filtered rows ``[q | k | v]`` (B, S, 2 Hk dk +
-    Hv dv), under a ``plan`` of :func:`slots_plan`: ``q / |q| dk^-1/2`` and
-    ``k / |k|`` a head (float32 sums over the head's own channels) as (B, S,
-    Hk slot(dk)) and ``v`` as (B, S, Hv slot(dv)), each head from the first
-    lane of its slot, zeros behind (the Pallas pass
-    ``ops/pallas/qk_rows.py slot_rows``, forward and backward)."""
-    from .pallas import qk_rows
-
-    heads = qk_rows.Heads(key_heads, dk, value_heads, dv)
-    return _over_batch(lambda x: qk_rows.slot_rows(x, heads, eps, interpret),
-                       plan, (rows,), 3)
+    # the scales, and a (1, S) table that serves every row, go to every rank
+    per_row = angles is None or angles.shape[0] == q.shape[0]
+    return spmd.over_batch(
+        lambda *a: qk_rows(*a, head_dim, eps, interpret), plan,
+        (q, k, angles, q_scale, k_scale), outs=2,
+        whole=(3, 4) if per_row else (2, 3, 4))
 
 
 def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,

@@ -72,55 +72,26 @@ def _shift(bcu: jax.Array, w: jax.Array) -> jax.Array:
     return (cg * _filter_shift(bg * u, w.astype(f32))).astype(bcu.dtype)
 
 
-def _plan(x, w, impl, form):
-    """``(impl, reason, batch axes of a shard_map or None)`` for rows ``x``
-    (B, S, width) and what a ``pallas`` row says of the call, ``form``; the
-    gated rows are three thirds of the taps' channels wide."""
-    from .attention import on_tpu
-    from .pallas import short_conv as kernel
-    from .pallas.spmd import kernel_mesh_plan
-
-    if impl == "shift":
-        return impl, "impl='shift' asked for", None
-    C, L = w.shape
-    reason = kernel.supported(x.shape[1], C, L, x.dtype,
-                              gated=x.shape[-1] == 3 * C)
-    if reason is None and impl == "auto" and not on_tpu():
-        reason = "no TPU"
-    verdict = axes = None
-    if reason is None:
-        verdict, axes = kernel_mesh_plan(x.shape[0])
-        if verdict is None:
-            reason = "kernel_mesh_plan refused the mesh"
-    if reason is not None:
-        if impl == "pallas":
-            raise NotImplementedError(f"short_conv impl='pallas': {reason}")
-        return "shift", reason, None
-    return "pallas", (f"{form}; "
-                      + ("one device" if verdict == "direct" else
-                         f"shard_map over batch axes {axes}")), axes
-
-
 def _rows(kernel, x, w, impl, form, shift):
-    """``kernel(x, w)`` on this rank's rows of the batch where
-    :func:`_plan` takes the Pallas form, else ``shift(x, w)``; booked."""
-    from .pallas.spmd import note_dispatch
+    """``kernel(x, w)`` on this rank's rows of the batch where the shapes,
+    the device and the mesh take the Pallas form (``ops/pallas/spmd.py
+    plan``; ``form`` is what its row says of the call; the gated rows are
+    three thirds of the taps' channels wide), else ``shift(x, w)``."""
+    from .pallas import short_conv as kernels
+    from .pallas import spmd
 
     if impl not in IMPLS:
         raise ValueError(f"short_conv impl {impl!r}: one of {IMPLS}")
-    impl, reason, axes = _plan(x, w, impl, form)
-    note_dispatch("short_conv", impl, reason)
-    if impl != "pallas":
+    C, L = w.shape
+    refusal = "impl='shift' asked for" if impl == "shift" \
+        else kernels.supported(x.shape[1], C, L, x.dtype,
+                               gated=x.shape[-1] == 3 * C)
+    plan = spmd.plan("short_conv", x.shape[0], refusal, form,
+                     fallback="shift", tpu=impl == "auto",
+                     must=impl == "pallas")
+    if plan is None:
         return shift(x, w)
-    if axes is None:
-        return kernel(x, w)
-    from jax.sharding import PartitionSpec as P
-
-    from ..comm.mesh import get_mesh
-
-    rows = P(axes if axes else None, None, None)
-    return jax.shard_map(kernel, mesh=get_mesh(), in_specs=(rows, P()),
-                         out_specs=rows, check_vma=False)(x, w)
+    return spmd.over_batch(kernel, plan, (x, w), whole=(1,))
 
 
 def short_conv_rows(bcu: jax.Array, w: jax.Array, impl: str = "auto",

@@ -64,24 +64,18 @@ def w8a16_matmul(x: jax.Array, codes: jax.Array, scale: jax.Array):
     K, N = codes.shape
     G = scale.shape[0]
     g = K // G
-    from .attention import on_tpu
+    from .pallas import spmd
+    from .pallas.w8_matmul import supported, w8a16_matmul_pallas
 
-    if on_tpu():
-        from .pallas.spmd import kernel_mesh_plan, note_dispatch
-        from .pallas.w8_matmul import supported, w8a16_matmul_pallas
-
-        verdict, _ = kernel_mesh_plan(x.shape[0] if x.ndim else 1)
-        if verdict == "direct" and supported(x.shape, codes.shape, G,
-                                             mesh_ok=True):
-            note_dispatch("w8_matmul", "kernel",
-                          "TPU, one device, decode-sized rows")
-            M = int(np.prod(x.shape[:-1]))
-            y = w8a16_matmul_pallas(x.reshape(M, K).astype(jnp.bfloat16),
-                                    codes, scale)
-            return y.reshape(*x.shape[:-1], N).astype(x.dtype)
-        note_dispatch("w8_matmul", "xla",
-                      f"mesh plan {verdict!r} or w8_matmul.supported"
-                      f"({tuple(x.shape)}, {tuple(codes.shape)}) said no")
+    refusal = None if supported(x.shape, codes.shape, G, mesh_ok=True) \
+        else (f"w8_matmul.supported({tuple(x.shape)}, {tuple(codes.shape)}) "
+              f"said no")
+    if spmd.plan("w8_matmul", x.shape[0] if x.ndim else 1, refusal,
+                 "decode-sized rows", kernel="kernel", shard=False):
+        M = int(np.prod(x.shape[:-1]))
+        y = w8a16_matmul_pallas(x.reshape(M, K).astype(jnp.bfloat16),
+                                codes, scale)
+        return y.reshape(*x.shape[:-1], N).astype(x.dtype)
     cdt = x.dtype if jnp.issubdtype(x.dtype, jnp.floating) else jnp.bfloat16
     if int(np.prod(x.shape[:-1])) > 64:
         # prefill regime: dequantize the panel ONCE (a K x N temp, ~10 MB

@@ -47,8 +47,6 @@ and the mesh, never from the environment):
   sums them back into their tokens; what the absent experts would have
   added is left out, and there is no exchange and nothing that stands in
   for one.
-- the opt-in gathered decode path (``DS_TPU_MOE_FAST``) of the capacity
-  layer at <= 32 eval tokens.
 
 AFMoE's routing (Trinity, ``model_type: afmoe``; the DeepSeek-V3 lineage)
 is the dropless path with fields: ``score_func="sigmoid"``, its
@@ -63,7 +61,6 @@ that SwiGLU by ``sigmoid(x . w_g)``, one scalar a token.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -335,7 +332,7 @@ class TopKGate(nn.Module):
     model_dim: int
 
     @nn.compact
-    def __call__(self, x: jax.Array, train: bool, decode_fast: bool = False,
+    def __call__(self, x: jax.Array, train: bool,
                  logits_only: bool = False):
         cfg = self.cfg
         wg = self.param("wg", nn.with_partitioning(
@@ -354,26 +351,6 @@ class TopKGate(nn.Module):
                     nn.initializers.zeros, ("experts_gate",)), (cfg.routed,),
                 jnp.float32)
             return logits, bias
-        if decode_fast:
-            # decode path (the Tutel fast-dispatch analog, reference
-            # sharded_moe.py:501): no capacity queues at a handful of
-            # decode tokens — just top-k indices + renormalized gates,
-            # consumed by the gathered-expert matmul in MoELayer
-            gates = jax.nn.softmax(logits, axis=-1)               # (S, E)
-            idx1 = jnp.argmax(gates, axis=-1)
-            if cfg.top_k == 1:
-                idx = idx1[:, None]                               # (S, 1)
-                w = jnp.ones_like(idx, jnp.float32) * \
-                    jnp.take_along_axis(gates, idx, axis=-1)
-            else:
-                g_wo1 = jnp.where(_one_hot(idx1, cfg.num_experts) > 0,
-                                  -jnp.inf, logits)
-                idx2 = jnp.argmax(g_wo1, axis=-1)
-                idx = jnp.stack([idx1, idx2], axis=-1)            # (S, 2)
-                w = jnp.take_along_axis(gates, idx, axis=-1)
-                w = w / jnp.maximum(w.sum(-1, keepdims=True),
-                                    jnp.finfo(jnp.float32).eps)
-            return jnp.float32(0.0), idx.astype(jnp.int32), w
         S = logits.shape[0]
         factor = cfg.capacity_factor if train else cfg.eval_capacity_factor
         capacity = _capacity(S, cfg.num_experts, factor, cfg.min_capacity,
@@ -511,29 +488,24 @@ class ExpertsMLP(nn.Module):
         return self._weight("wi"), self._weight("wo", down=True)
 
     @nn.compact
-    def __call__(self, x: jax.Array, idx: Optional[jax.Array] = None,
-                 gate_w: Optional[jax.Array] = None,
+    def __call__(self, x: jax.Array,
                  routing: Optional[Tuple[jax.Array, jax.Array]] = None
                  ) -> jax.Array:
         # (E, C, M) capacity-padded batch; or, with ``routing`` = (weights,
         # experts), both (S, k), x (S, M) tokens through the dropless
-        # sorted dispatch; or — when
-        # ``idx``/``gate_w`` are given — the gathered decode path: x (S, M),
-        # idx (S, k) expert ids, gate_w (S, k) renormalized gates
-        # (Tutel-style fast dispatch, reference sharded_moe.py:501 +
-        # moe_inference.py).  Param declarations are IDENTICAL on every
-        # path, so one trained tree serves them all.
+        # sorted dispatch.  Param declarations are IDENTICAL on both
+        # paths, so one trained tree serves them.
         if routing is not None:
             if self.w8:
                 raise NotImplementedError(
                     "int8 expert weights have no grouped-matmul path")
             return sorted_dispatch(x, *routing, self._weights(), self.act,
                                    self.first_expert)
-        if self.act != "gelu" and (self.w8 or idx is not None):
-            raise NotImplementedError(
-                f"{self.act} experts run the capacity einsum or the sorted "
-                f"dispatch; the int8 and gathered decode paths are gelu-only")
         if self.w8:
+            if self.act != "gelu":
+                raise NotImplementedError(
+                    f"{self.act} experts run the capacity einsum or the "
+                    f"sorted dispatch; the int8 path is gelu-only")
             from ..ops.w8 import w8a16_expert_matmul
 
             def qparams(name, K, N, names):
@@ -552,62 +524,11 @@ class ExpertsMLP(nn.Module):
                                  ("experts", "embed", "mlp"))
             wo_q, wo_s = qparams("wo", self.hidden_dim, self.model_dim,
                                  ("experts", "mlp", "embed"))
-            if idx is not None:
-                return self._gathered(x, idx, gate_w,
-                                      lambda f: self._w8_ffn(
-                                          f, wi_q, wi_s, wo_q, wo_s))
             h = nn.gelu(w8a16_expert_matmul(x, wi_q, wi_s),
                         approximate=True)
             return w8a16_expert_matmul(h, wo_q, wo_s)
-        if idx is not None:
-            wi, wo = self._weight("wi"), self._weight("wo", down=True)
-
-            def ffn(flat):
-                wi_g = jnp.take(wi, flat, axis=0)
-                wo_g = jnp.take(wo, flat, axis=0)
-                def apply(xr):   # (Sk, M) → (Sk, M)
-                    h = nn.gelu(jnp.einsum("sm,smh->sh", xr, wi_g),
-                                approximate=True)
-                    return jnp.einsum("sh,shm->sm", h, wo_g)
-                return apply
-            return self._gathered(x, idx, gate_w, ffn)
         return _expert_ffn(self.act, self._weights(), x,
                            lambda a, w: jnp.einsum("ecm,emh->ech", a, w))
-
-    def _gathered(self, x, idx, gate_w, make_apply):
-        """Run each token through its own top-k experts: one vecmat per
-        (token, choice) over gathered weight panels — S·k FFN rows instead
-        of the E·C capacity-padded batch (32× fewer at 8-slot top-1
-        decode)."""
-        S, k = idx.shape
-        flat = idx.reshape(-1)                          # (S*k,)
-        xr = jnp.repeat(x, k, axis=0)                   # (S*k, M)
-        o = make_apply(flat)(xr)                        # (S*k, M)
-        o = o.reshape(S, k, self.model_dim)
-        return (o * gate_w[..., None].astype(o.dtype)).sum(axis=1)
-
-    def _w8_ffn(self, flat, wi_q, wi_s, wo_q, wo_s):
-        """Gathered int8 expert FFN: per-token code panels dequantized in
-        the grouped contraction (never a full-width weight in HBM)."""
-        wi_qg = jnp.take(wi_q, flat, axis=0)            # (Sk, M, H) int8
-        wi_sg = jnp.take(wi_s, flat, axis=0)            # (Sk, G, H)
-        wo_qg = jnp.take(wo_q, flat, axis=0)
-        wo_sg = jnp.take(wo_s, flat, axis=0)
-
-        def one(xr, cq, cs):                            # (Sk, K) tokens
-            K, N = cq.shape[1], cq.shape[2]
-            G = cs.shape[1]
-            g = K // G
-            xg = xr.reshape(-1, G, g)
-            cg = cq.reshape(-1, G, g, N).astype(self.dtype)
-            part = jnp.einsum("sug,sugn->sun", xg.astype(self.dtype), cg)
-            return jnp.einsum("sun,sun->sn", part.astype(jnp.float32),
-                              cs).astype(self.dtype)
-
-        def apply(xr):
-            h = nn.gelu(one(xr, wi_qg, wi_sg), approximate=True)
-            return one(h, wo_qg, wo_sg)
-        return apply
 
 
 class SharedExpert(nn.Module):
@@ -673,7 +594,6 @@ class MoELayer(nn.Module):
         gate = TopKGate(cfg, self.model_dim, name="gate")
         mesh = mesh_lib.get_mesh(required=False)
         ep1 = mesh is None or mesh.shape.get("ep", 1) == 1
-        fast_ok = os.environ.get("DS_TPU_MOE_FAST", "0") == "1"
         S, E, k = x2.shape[0], cfg.routed, cfg.top_k
         l_z = jnp.float32(0.0)
         elsewhere = jnp.int32(0)
@@ -711,19 +631,6 @@ class MoELayer(nn.Module):
                 here = counts[cfg.first_expert:
                               cfg.first_expert + cfg.num_experts]
                 elsewhere = counts.sum() - here.sum()
-        elif not train and ep1 and fast_ok and S <= 32:
-            # gathered per-token experts (no capacity padding, no dispatch
-            # one-hots).  OPT-IN: on TPU the vmapped gather materializes a
-            # per-token copy of each expert panel in HBM and LOSES ~25% to
-            # the weight-stationary einsum at 8-slot decode (round-5 A/B);
-            # the einsum path with the S*k capacity cap is the default.
-            # Only without ep sharding — sharded experts want tokens moved
-            # to weights (all-to-all), not weight panels gathered to
-            # tokens.
-            l_aux, idx, gate_w = gate(x2, train, decode_fast=True)
-            out = experts(x2, idx=idx, gate_w=gate_w)
-            counts = _count_ids(idx.reshape(-1), E)
-            dropped = jnp.int32(0)
         else:
             l_aux, combine, dispatch = gate(x2, train)
             dispatched = jnp.einsum("sec,sm->ecm",

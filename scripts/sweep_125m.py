@@ -44,7 +44,6 @@ def main():
         attn_impl=kv.get("attn", "auto"),
         flash_block=tuple(int(x) for x in fb.split("x")) if fb else None,
         loss_chunk=chunk or None,
-        loss_pallas=kv.get("pl", "0") == "1",
         **({"vocab_size": vocab} if vocab else {}))
     model = GPT2LMHeadModel(cfg)
     gas = int(kv.get("gas", 1))

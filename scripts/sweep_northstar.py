@@ -46,8 +46,7 @@ def main():
         remat_policy=remat if remat != "off" else "nothing_saveable",
         attn_impl=kv.get("attn", "auto"),
         flash_block=tuple(int(x) for x in fb.split("x")) if fb else None,
-        loss_chunk=chunk or None,
-        loss_pallas=kv.get("pl", "0") == "1")
+        loss_chunk=chunk or None)
     model = GPT2LMHeadModel(cfg)
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, config={
         "train_micro_batch_size_per_gpu": micro,

@@ -24,8 +24,46 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
+import contextlib  # noqa: E402
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
+
+# What one test's call may take: a hung test must cost the run this, not the
+# whole of its limit (``pytest-timeout`` is not installed and cannot be).
+TEST_SECONDS = 300
+
+
+@contextlib.contextmanager
+def call_limit(name: str, seconds: float = TEST_SECONDS):
+    """A ``SIGALRM`` timer around a test's call: past ``seconds`` the test
+    FAILS with its ``name`` and the worker goes on to the next.  The main
+    thread only (every xdist worker runs its tests there); the signal is
+    seen when the interpreter next runs, so a call that never returns from
+    native code is out of its reach."""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        pytest.fail(f"{name} ran past the {seconds:g} s a test may take "
+                    f"(tests/conftest.py call_limit)")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    with call_limit(item.nodeid):
+        yield
 
 
 @pytest.fixture(scope="session")

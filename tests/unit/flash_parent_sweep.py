@@ -187,9 +187,13 @@ def assert_equal_to_the_parents(run, names):
 
 def traced_digest(fn, *args) -> str:
     """sha256 of the jaxpr that ``fn(*args)`` traces to, as text without
-    source lines: what a pin computed on another commit can be held to."""
+    source lines and with a ``shard_map``'s set of axes in order (a set
+    prints in the order of its strings' hashes, a process its own): what
+    a pin computed on another commit can be held to."""
     # a function of its own: see kernel_primitives
     text = str(jax.make_jaxpr(lambda *a: fn(*a))(*args))
+    text = re.sub(r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})"
+                  % ", ".join(sorted(m.group(1).split(", "))), text)
     return hashlib.sha256(re.sub(r" at /\S+:\d+", "", text).encode()
                           ).hexdigest()
 

@@ -1,5 +1,5 @@
-"""Parity tests for the fused Pallas op set (layer_norm / bias_gelu /
-attention_softmax / decode_attention) vs jnp references — the analog of the
+"""Parity tests for the fused Pallas op set (fused_mlp / decode_attention)
+vs jnp references — the analog of the
 reference's ``test_cuda_forward.py``/``test_cuda_backward.py`` kernel-parity
 suite (values AND gradients), run in interpret mode on CPU.
 """
@@ -9,77 +9,6 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
-from deepspeed_tpu.ops.pallas.fused_ops import (attention_softmax, bias_gelu,
-                                                layer_norm)
-
-
-def _ref_ln(x, g, b, eps=1e-5):
-    xf = x.astype(jnp.float32)
-    mean = xf.mean(-1, keepdims=True)
-    var = xf.var(-1, keepdims=True)
-    return ((xf - mean) * jax.lax.rsqrt(var + eps) * g + b).astype(x.dtype)
-
-
-def test_layer_norm_fwd_bwd_parity():
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.normal(size=(4, 32, 256)), jnp.float32)
-    g = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
-    b = jnp.asarray(rng.normal(size=(256,)), jnp.float32)
-
-    y = layer_norm(x, g, b, interpret=True)
-    np.testing.assert_allclose(y, _ref_ln(x, g, b), rtol=1e-5, atol=1e-5)
-
-    def loss_pallas(x, g, b):
-        return (layer_norm(x, g, b, interpret=True) ** 2).sum()
-
-    def loss_ref(x, g, b):
-        return (_ref_ln(x, g, b) ** 2).sum()
-
-    gp = jax.grad(loss_pallas, argnums=(0, 1, 2))(x, g, b)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(x, g, b)
-    for a, r in zip(gp, gr):
-        np.testing.assert_allclose(a, r, rtol=2e-4, atol=2e-4)
-
-
-def test_bias_gelu_fwd_bwd_parity():
-    rng = np.random.default_rng(1)
-    x = jnp.asarray(rng.normal(size=(64, 128)), jnp.float32)
-    b = jnp.asarray(rng.normal(size=(128,)), jnp.float32)
-
-    y = bias_gelu(x, b, interpret=True)
-    ref = jax.nn.gelu(x + b, approximate=True)
-    np.testing.assert_allclose(y, ref, rtol=1e-5, atol=1e-5)
-
-    gp = jax.grad(lambda x, b: bias_gelu(x, b, interpret=True).sum(),
-                  argnums=(0, 1))(x, b)
-    gr = jax.grad(lambda x, b: jax.nn.gelu(x + b, approximate=True).sum(),
-                  argnums=(0, 1))(x, b)
-    for a, r in zip(gp, gr):
-        np.testing.assert_allclose(a, r, rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("causal", [True, False])
-def test_attention_softmax_parity(causal):
-    rng = np.random.default_rng(2)
-    s = jnp.asarray(rng.normal(size=(2, 3, 64, 64)), jnp.float32)
-    scale = 0.125
-
-    p = attention_softmax(s, causal=causal, scale=scale, interpret=True)
-
-    sf = s * scale
-    if causal:
-        qp = jnp.arange(64)[:, None]
-        kp = jnp.arange(64)[None, :]
-        sf = jnp.where(qp >= kp, sf, -jnp.inf)
-    ref = jax.nn.softmax(sf, axis=-1)
-    np.testing.assert_allclose(p, ref, rtol=1e-5, atol=1e-6)
-
-    gp = jax.grad(lambda s: (attention_softmax(
-        s, causal=causal, scale=scale, interpret=True) ** 2).sum())(s)
-    gr = jax.grad(lambda s: (jax.nn.softmax(
-        jnp.where(qp >= kp, s * scale, -jnp.inf) if causal else s * scale,
-        axis=-1) ** 2).sum())(s)
-    np.testing.assert_allclose(gp, gr, rtol=1e-4, atol=1e-5)
 
 
 def test_decode_attention_matches_masked_reference():

@@ -297,15 +297,17 @@ def test_the_plan_names_the_fused_form_and_its_tile(monkeypatch):
     from deepspeed_tpu.comm.mesh import build_mesh
 
     mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    before = {r[:3]: r[3] for r in dispatch_report()}
     try:
-        q, k, v, g, beta = _bf16(_inputs(1, 256, 1, 2, 128))
-        impl, reason, axes = ops._plan(1, 256, 1, 2, 128, 128, v.dtype, 64,
-                                       "pallas")
+        jax.eval_shape(lambda *a: gated_delta_rule(
+            *a, chunk=64, impl="pallas", interpret=True),
+            *_bf16(_inputs(1, 256, 1, 2, 128)))
     finally:
         mesh_lib.set_mesh(None)
-    assert impl == "pallas"
-    assert reason.startswith(
-        "4 chunks of 64 x 1 key heads x 2 value heads of 128, fused; ")
+    assert [r[1:3] for r in dispatch_report() if r[0] == "gated_delta"
+            and r[3] > before.get(r[:3], 0)] == [(
+                "pallas", "4 chunks of 64 x 1 key heads x 2 value heads of "
+                "128, fused; one device")]
 
 
 def test_the_dispatch_and_the_chunks_are_booked():

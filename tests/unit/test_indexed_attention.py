@@ -159,7 +159,7 @@ def test_auto_takes_the_plain_form_off_the_chip_and_says_so():
     before = {r[:3]: r[3] for r in dispatch_report()}
     indexed_attention(*ops, topk=8)
     after = {r[:3]: r[3] for r in dispatch_report()}
-    key = ("indexed_attention", "jnp", "auto: not a TPU")
+    key = ("indexed_attention", "jnp", "no TPU")
     assert after[key] == before.get(key, 0) + 1
     with pytest.raises(ValueError, match="impl"):
         indexed_attention(*ops, topk=8, impl="flash")

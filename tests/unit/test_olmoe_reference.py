@@ -403,5 +403,5 @@ def test_grouped_matmul_tiles_and_cpu_dispatch():
     np.testing.assert_allclose(np.asarray(out[:5]), 0.0)
     np.testing.assert_allclose(np.asarray(out[5:]), 16.0)
     assert any(site == "grouped_matmul" and impl == "ragged_dot"
-               and reason == "no TPU" and n > 0
+               and reason == "no tile divides (12, 8) x (3, 8, 4)" and n > 0
                for site, impl, reason, n in dispatch_report())

@@ -7,8 +7,9 @@ every guard's reason, one trace a signature, the three families' attention
 layers with and without the rows path, and what a block's jaxpr holds.
 Since PR 53 also a Gated DeltaNet's two per-head norms: the l2-norm as the
 same pass under constant scales, the gated output norm's own pass
-(``gated_norm_plan`` / ``gated_norm_rows``), and the jaxpr digests of the
-layers of other families that run this code.  Since PR 55 heads that are no
+(``gated_norm_plan`` / ``gated_norm_rows``; in ``ops/gated_delta.py`` with
+the slots' plan since PR 56), and the jaxpr digests of the layers of every
+family that reaches its kernels through ``ops/pallas/spmd.py``.  Since PR 55 heads that are no
 whole lane tiles (Olmo-Hybrid's 96 x 192) in lane slots: ``slot_rows``, the
 gated norm reading ``o`` from slots, ``slots_plan``'s guards, the mixer
 through the slots against the mixer through the ``(B, S, H, d)`` lines, and
@@ -26,7 +27,7 @@ from deepspeed_tpu.comm import mesh as mesh_lib
 from deepspeed_tpu.models.common import rms_norm
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.models.llama import LlamaBlock, LlamaConfig
-from deepspeed_tpu.ops import attention, rotary
+from deepspeed_tpu.ops import attention, gated_delta, rotary
 from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 from deepspeed_tpu.telemetry import get_registry
 
@@ -349,7 +350,7 @@ def _gated_today(o, z, w, d=D):
 
 
 def _gated_rows(o, z, w, d=D, plan=("direct", None)):
-    return rotary.gated_norm_rows(o, z, w, d, plan, eps=GATED_EPS,
+    return gated_delta.gated_norm_rows(o, z, w, d, plan, eps=GATED_EPS,
                                   interpret=True)
 
 
@@ -434,7 +435,7 @@ def _gated_plan(monkeypatch, head_dim=128, tpu=True, heads=4, seq=S,
     monkeypatch.setattr(attention, "on_tpu", lambda: tpu)
     o = jax.ShapeDtypeStruct((B, seq, heads * head_dim), dtype)
     return _booked("gated_norm_rows",
-                   lambda: rotary.gated_norm_plan(o, head_dim))
+                   lambda: gated_delta.gated_norm_plan(o, head_dim))
 
 
 @pytest.mark.parametrize("case,kw,reason", [
@@ -520,7 +521,7 @@ def _slot_run(pair, dtype):
                 zip(ks[1:], (Hk * slot(dk), Hk * slot(dk), Hv * slot(dv))))
 
     def rows(x):
-        return rotary.slot_rows(x, Hk, dk, Hv, dv, ("direct", None),
+        return gated_delta.slot_rows(x, Hk, dk, Hv, dv, ("direct", None),
                                 interpret=True)
 
     def today(x):
@@ -578,7 +579,7 @@ def _gated_slot_run(pair, dtype):
     o, z, w, ct = _gated_operands(B, dtype, d, H)
 
     def rows(o, z, w):
-        return rotary.gated_norm_rows(_slotted(o, H, d), z, w, d,
+        return gated_delta.gated_norm_rows(_slotted(o, H, d), z, w, d,
                                       ("direct", None), eps=GATED_EPS,
                                       interpret=True)
 
@@ -640,7 +641,7 @@ def _slots_plan(monkeypatch, pair="96x192", tpu=True, seq=256,
     monkeypatch.setattr(attention, "on_tpu", lambda: tpu)
     Hk, dk, Hv, dv = heads or SLOTTED[pair]
     rows = jax.ShapeDtypeStruct((B, seq, 2 * Hk * dk + Hv * dv), dtype)
-    guard = lambda: rotary.slots_plan(rows, Hk, dk, Hv, dv, chunk)
+    guard = lambda: gated_delta.slots_plan(rows, Hk, dk, Hv, dv, chunk)
     # one decision, booked under both sites
     norm = {}
     plan, impl, reason = _booked("qk_rows", lambda: norm.update(
@@ -651,7 +652,7 @@ def _slots_plan(monkeypatch, pair="96x192", tpu=True, seq=256,
 
 
 @pytest.mark.parametrize("case,kw,reason", [
-    ("cpu", dict(tpu=False), "the delta rule keeps XLA's form: no TPU"),
+    ("cpu", dict(tpu=False), "no TPU"),
     ("float32", dict(dtype=jnp.float32),
      "the delta rule keeps XLA's form: operands of float32"),
     ("chunk", dict(chunk=16), "the delta rule keeps XLA's form: chunks of 16 "
@@ -699,7 +700,7 @@ def test_slots_sharded_over_the_batch_match_one_device():
                                           devices=jax.devices()[:2]))
     try:
         def call(plan):
-            fn = lambda x: rotary.slot_rows(x, Hk, dk, Hv, dv, plan,
+            fn = lambda x: gated_delta.slot_rows(x, Hk, dk, Hv, dv, plan,
                                             interpret=True)
             return fn(x), jax.grad(lambda x: sum(
                 (o.astype(jnp.float32) ** 2).sum() for o in fn(x)))(x)
@@ -734,8 +735,6 @@ def _olmo_mixer_run():
     """``MIXER_PARTS`` of the mixer through today's lines (the rule's
     kernels behind XLA's pads and cuts) and through the slots (the plan
     forced, every kernel in the interpreter)."""
-    from deepspeed_tpu.ops import gated_delta
-
     cfg, mixer, shape = _olmo_mixer()
     h = jax.random.normal(jax.random.PRNGKey(5), shape.shape,
                           jnp.float32).astype(cfg.dtype)
@@ -754,13 +753,14 @@ def _olmo_mixer_run():
     mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
                                           devices=jax.devices()[:1]))
     try:
-        mp.setattr(gated_delta, "gated_delta_rule", functools.partial(
-            gated_delta.gated_delta_rule, impl="pallas", interpret=True))
+        mp.setattr(gated_delta, "heads_rule", functools.partial(
+            gated_delta.heads_rule, impl="pallas", interpret=True))
         today = measure()
-        mp.setattr(llama, "slots_plan", lambda *a, **kw: ("direct", None))
-        for rows in ("slot_rows", "gated_norm_rows"):
-            mp.setattr(llama, rows, functools.partial(getattr(rotary, rows),
-                                                      interpret=True))
+        mp.setattr(gated_delta, "slots_plan",
+                   lambda *a, **kw: ("direct", None))
+        for part in ("normalised_heads", "gated_norm"):
+            mp.setattr(gated_delta, part, functools.partial(
+                getattr(gated_delta, part), interpret=True))
         slots = measure()
     finally:
         mp.undo()
@@ -1058,26 +1058,45 @@ def test_each_kernel_body_is_traced_once_a_signature():
 
 def _who_else(case):
     """``traced_digest`` of forward and gradient of one remat layer of a
-    family that runs the code PR 53 touched and must not feel it, as the
-    chip traces it (the guards see a TPU; nothing is lowered)."""
-    from deepspeed_tpu.models.llama import GatedDeltaNet
+    family that runs the dispatch code and must not feel a change of it,
+    as the chip traces it (the guards see a TPU; nothing is lowered)."""
+    from deepspeed_tpu.models.llama import GatedDeltaNet, ShortConv
+    from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
     from tests.unit.flash_parent_sweep import traced_digest
 
+    mixer = dict(
+        vocab_size=256, hidden_size=256, intermediate_size=256,
+        num_hidden_layers=1, num_attention_heads=2, head_dim=128,
+        max_position_embeddings=256, linear_num_key_heads=2,
+        linear_conv_kernel_dim=4, scan_layers=False)
+    extra = ()
     if case == "qwen3next_mixer":   # states of 128 x 128: a slot is the head
-        cfg = LlamaConfig(
-            vocab_size=256, hidden_size=256, intermediate_size=256,
-            num_hidden_layers=1, num_attention_heads=2, head_dim=128,
-            max_position_embeddings=256, linear_num_key_heads=2,
-            linear_num_value_heads=4, linear_key_head_dim=128,
-            linear_value_head_dim=128, linear_conv_kernel_dim=4,
-            scan_layers=False)
-        layer = GatedDeltaNet(cfg)
-        args = (jax.ShapeDtypeStruct((B, 256, cfg.hidden_size), cfg.dtype),)
+        cfg = LlamaConfig(linear_num_value_heads=4, linear_key_head_dim=128,
+                          linear_value_head_dim=128, **mixer)
+        layer, rows = GatedDeltaNet(cfg), 256
+    elif case in ("olmo_mixer_slots", "olmo_mixer_view"):
+        # states of 96 x 192 in lane slots; over two chunks, which the
+        # rule's kernels refuse, on the (B, S, H, d) lines
+        cfg, layer, _ = _olmo_mixer()
+        rows = 256 if case == "olmo_mixer_slots" else 128
+    elif case == "lfm2_conv":       # the gated filter's kernels
+        cfg = LlamaConfig(conv_L_cache=3, **{**mixer, "hidden_size": 512})
+        layer, rows = ShortConv(cfg), 256
+    elif case == "moe_share":       # the row kernels and the grouped matmul
+        cfg = LlamaConfig(**mixer)
+        layer = MoELayer(MoEConfig(
+            num_experts=8, top_k=8, drop_tokens=False, norm_topk_prob=True,
+            expert_act="swiglu", routed_experts=32, first_expert=8),
+            model_dim=2048, hidden_dim=128, dtype=jnp.bfloat16)
     else:                               # rotate_rows as its callers call it
         cfg = _family(case[0])
-        layer = LlamaBlock(cfg, kind=case[1])
-        args = (jax.ShapeDtypeStruct((B, 128, cfg.hidden_size), cfg.dtype),
-                (jax.ShapeDtypeStruct((B, 128), jnp.int32), None))
+        layer, rows = LlamaBlock(cfg, kind=case[1]), 128
+        extra = ((jax.ShapeDtypeStruct((B, 128), jnp.int32), None),)
+    if case == "moe_share":
+        args = (jax.ShapeDtypeStruct((2048, 2048), jnp.bfloat16),)
+    else:
+        args = (jax.ShapeDtypeStruct((B, rows, cfg.hidden_size), cfg.dtype),
+                *extra)
     params = meta.unbox(jax.eval_shape(
         layer.init, jax.random.PRNGKey(0), *args)["params"])
 
@@ -1092,26 +1111,55 @@ def _who_else(case):
     return traced_digest(jax.grad(loss, argnums=(0, 1)), params, *args)
 
 
-@pytest.mark.parametrize("case,digest", [
-    ("qwen3next_mixer",
+# case, whether the rows of the batch lie over two devices (fsdp), digest
+PARENT_JAXPRS = [
+    ("qwen3next_mixer", False,
      "f3648aba7f5c371f95d3f0f4bd97b33bd0a8f38b2c14b610edbdaeddd680a2ec"),
-    (("mellum2", "sliding_attention"),
+    (("mellum2", "sliding_attention"), False,
      "f9a3797369c672382678c9734c1f72e2be774d5c1c0da0df97908748d10dedba"),
-    (("trinity", "full_attention"),
+    (("trinity", "full_attention"), False,
      "7a2d63ab09f0b7273aad3f58e63ed2f2b9b99d90a8e4bcb17cb18f467f090828"),
-], ids=["qwen3next_mixer", "mellum2_sliding", "trinity_full"])
+    ("olmo_mixer_slots", False,
+     "775640de3043693b42d1aa51a7519ac3100e3079cc632858ae75d677ce45c30f"),
+    ("olmo_mixer_view", False,
+     "10261ba4ace854d7f005b4eb8621ff1d66406e5c0cbca56bf468323caf4687ac"),
+    ("lfm2_conv", False,
+     "c807d7bd6376176ef17c18903ffed0bb75347b7e4af25b8e66b4932479939e2d"),
+    ("moe_share", False,
+     "a7051f7907f4a0933a3426bef4b15cd5e6007693e045d3cd1623e1ed21609613"),
+    ("qwen3next_mixer", True,
+     "1381897984f2b07a7377c24970bd8627084c98d55e3efba7066ebae0824c8e9c"),
+    ("olmo_mixer_slots", True,
+     "ffa9ae9af6d6e4da3e8d058a941695f8e57af7ed6e0a2798d77b902df3d3c9ee"),
+    ("lfm2_conv", True,
+     "5810e6575d9a67f66c99753b1f512dd35665e12031ea8651c23173596091d4a5"),
+    (("mellum2", "sliding_attention"), True,
+     "97785682b74a4a2242d3081ad84515c5336c45c86df90d8e0d8fdbee42d85c0f"),
+]
+
+
+@pytest.mark.parametrize("case,sharded,digest", PARENT_JAXPRS, ids=[
+    (case if isinstance(case, str) else "_".join(case).replace(
+        "_attention", "")) + ("_fsdp" if sharded else "")
+    for case, sharded, _ in PARENT_JAXPRS])
 def test_who_else_runs_the_code_traces_to_the_parents_jaxpr(monkeypatch,
-                                                            case, digest):
-    """PR 53 put ``GatedDeltaNet``'s norms behind ``rows_plan`` /
-    ``gated_norm_plan`` and ``rotate_rows``' ``shard_map`` behind a helper.
-    The Olmo-Hybrid mixer (heads of 96 and 192 channels: both guards
-    refuse), Mellum 2's rotation and Trinity's norm without rotation still
-    trace, forward and backward, to what commit ``d2fe5c3`` (the parent)
-    traces: the digests were computed there with :func:`_who_else`."""
+                                                            case, sharded,
+                                                            digest):
+    """The layers that reach a Pallas kernel through the dispatch code
+    trace, forward and backward, to what the parent of the PR that last
+    moved that code traces.  PR 53 (parent ``d2fe5c3``) put
+    ``GatedDeltaNet``'s norms behind plans: the first three.  PR 56 (parent
+    ``0e0aa8f``) put every family behind ``ops/pallas/spmd.py plan`` /
+    ``over_batch`` and the mixer's layouts behind ``ops/gated_delta.py``:
+    the mixer on rows, in slots and on the ``(B, S, H, d)`` view, LFM2's
+    gated filter, a share's expert layer, and four of them again with the
+    batch over two devices, where the ``shard_map`` and its specs are in
+    the digest.  All were computed there with :func:`_who_else`."""
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     prev = mesh_lib.get_mesh(required=False)
-    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
-                                          devices=jax.devices()[:1]))
+    mesh_lib.set_mesh(mesh_lib.build_mesh(
+        {"fsdp": 2, "dp": 1} if sharded else {"dp": 1},
+        devices=jax.devices()[:2 if sharded else 1]))
     try:
         assert _who_else(case) == digest
     finally:

@@ -20,7 +20,7 @@ from deepspeed_tpu.comm import mesh as mesh_lib
 from deepspeed_tpu.models import llama
 from deepspeed_tpu.models.llama import (FULL_ATTENTION, GatedDeltaNet,
                                         LlamaAttention, LlamaBlock)
-from deepspeed_tpu.ops import attention, rotary
+from deepspeed_tpu.ops import attention, gated_delta, rotary
 from deepspeed_tpu.ops.pallas import moe_rows
 from deepspeed_tpu.parallel.moe import MoELayer
 from tests.unit.test_qk_rows import _eqns
@@ -88,11 +88,12 @@ def _mixer_on_rows():
     view = measure()
     mp = pytest.MonkeyPatch()
     try:
-        for plan in ("rows_plan", "gated_norm_plan"):
-            mp.setattr(llama, plan, lambda *a, **kw: ("direct", None))
-        for rows in ("rotate_rows", "gated_norm_rows"):
-            mp.setattr(llama, rows, functools.partial(getattr(rotary, rows),
-                                                      interpret=True))
+        for module, plan in ((rotary, "rows_plan"),
+                             (gated_delta, "gated_norm_plan")):
+            mp.setattr(module, plan, lambda *a, **kw: ("direct", None))
+        for part in ("normalised_heads", "gated_norm"):
+            mp.setattr(gated_delta, part, functools.partial(
+                getattr(gated_delta, part), interpret=True))
         rows = measure()
     finally:
         mp.undo()

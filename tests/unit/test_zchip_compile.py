@@ -1048,7 +1048,7 @@ def test_the_norms_row_kernels_compile_at_the_eighth_cells_shape(
 
     from deepspeed_tpu.comm import mesh as mesh_lib
     from deepspeed_tpu.comm.mesh import build_mesh
-    from deepspeed_tpu.ops import attention, rotary
+    from deepspeed_tpu.ops import attention, gated_delta, rotary
     from deepspeed_tpu.ops.pallas.spmd import dispatch_report
 
     B, S, d = 3, 8192, 128
@@ -1073,8 +1073,8 @@ def test_the_norms_row_kernels_compile_at_the_eighth_cells_shape(
         args = (sd((B, S, 32 * d)), sd((B, S, 32 * d)), sd((d,), jnp.float32))
 
         def loss(o, z, w):
-            plan = rotary.gated_norm_plan(o, d)
-            y = rotary.gated_norm_rows(o, z, w, d, plan, eps=1e-6)
+            plan = gated_delta.gated_norm_plan(o, d)
+            y = gated_delta.gated_norm_rows(o, z, w, d, plan, eps=1e-6)
             return (y.astype(jnp.float32) ** 2).sum()
 
         said = f"head_dim {d}, rows 4096; one device"
@@ -1245,18 +1245,18 @@ def test_the_kernels_compile_at_the_ninth_cells_shape(one_chip, monkeypatch):
     # kernel reads the filter's 11,520 lanes and writes q, k (3,840: 30
     # slots of 128) and v (7,680: 30 of 256); the gated norm reads o from
     # its slots and the gate and the result as rows of 5,760 lanes
-    from deepspeed_tpu.ops import rotary
+    from deepspeed_tpu.ops import gated_delta
 
     B = 2
     mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
     try:
         def slots(x):
-            plan = rotary.slots_plan(x, H, dk, H, dv, C)
+            plan = gated_delta.slots_plan(x, H, dk, H, dv, C)
             return sum((o.astype(jnp.float32) ** 2).sum() for o in
-                       rotary.slot_rows(x, H, dk, H, dv, plan))
+                       gated_delta.slot_rows(x, H, dk, H, dv, plan))
 
         def norm(o, z, w):
-            y = rotary.gated_norm_rows(o, z, w, dv, ("direct", None),
+            y = gated_delta.gated_norm_rows(o, z, w, dv, ("direct", None),
                                        eps=1e-6)
             return (y.astype(jnp.float32) ** 2).sum()
 
