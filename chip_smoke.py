@@ -73,11 +73,13 @@ def _check_close(name, got, ref):
 PARENT_CHECKOUT = ".chip_archive/parent"    # where a builder unpacks one
 
 
-def _diff_from_the_parents(name, names, got, run):
+def _diff_from_the_parents(name, names, got, run,
+                           module="flash_attention.py"):
     """Where a checkout of the parent commit is unpacked beside this file
     (``git archive <parent> | tar -x -C .chip_archive/parent``): print the
     largest difference of each of ``got`` (named ``names``) from what
-    ``run(fa)`` gives with ``fa`` that checkout's flash module."""
+    ``run(fa)`` gives with ``fa`` that checkout's flash module (or another
+    ``module`` of its kernels)."""
     import jax.numpy as jnp
 
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -86,7 +88,7 @@ def _diff_from_the_parents(name, names, got, run):
         print(f"  {name}: no parent checkout at {PARENT_CHECKOUT}, nothing "
               f"to compare with", flush=True)
         return
-    old = run(_load_pallas_module("parent", root, "flash_attention.py"))
+    old = run(_load_pallas_module("parent", root, module))
     diffs = {n: float(jnp.abs(a.astype(jnp.float32)
                               - b.astype(jnp.float32)).max())
              for n, a, b in zip(names, got, old)}
@@ -1448,8 +1450,13 @@ def kernel_indexed_attention(S: int = 32768, time_it: bool = True):
     row's KL and the gradients of all six operands under a seeded
     cotangent, with the plain form's selection handed to the kernels (a
     pair that flips at the threshold is the indexer's rounding, not the
-    kernels' fault) and once under their own.  Each kernel's device time a
-    call from a profiler trace."""
+    kernels' fault) and once under their own.  ``indexed_attn_dkv``'s tile
+    stands keys by queries (PR 57): the kept pairs of every causal tile are
+    counted in that orientation and held equal to what the forward counted,
+    and the scores bit for bit to ``_index_tile``'s.  The largest difference
+    of all eight from a parent checkout's kernels, where one is unpacked
+    (:func:`_diff_from_the_parents`).  Each kernel's device time a call from
+    a profiler trace."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1526,6 +1533,12 @@ def kernel_indexed_attention(S: int = 32768, time_it: bool = True):
         for n, g, gr in zip(("out", "kl", "dq", "dk", "dv", "dqI", "dkI",
                              "dw"), got, want):
             _check_close(f"{name} under {label}, {n}", g, gr)
+    _kept_pairs_keys_first(name, qi, ki, w, (tau, cut), counts)
+    _diff_from_the_parents(
+        name, ("out", "kl", "dq", "dk", "dv", "dqI", "dkI", "dw"), got,
+        lambda old: _with_kernels_of(
+            old, lambda: both("pallas")(ops, None, ct, ckl)[0]),
+        module="indexed_attention.py")
     if not time_it:
         return
     out = os.path.join("chiprun_out", "trace_indexed_attention")
@@ -1539,6 +1552,103 @@ def kernel_indexed_attention(S: int = 32768, time_it: bool = True):
         if op.startswith(("indexer_select", "indexed_attn")):
             print(f"  {name}: {op} {np.median(ns) / 1e6:.2f} ms a call "
                   f"({len(ns)} calls)", flush=True)
+
+
+def _with_kernels_of(module, run):
+    """``run()`` with ``ops/indexed_attention.py`` dispatching to the four
+    calls of ``module`` (a parent checkout's kernels) in this tree's place."""
+    from deepspeed_tpu.ops.pallas import indexed_attention as here
+
+    calls = ("select_call", "forward_call", "dq_call", "dkv_call")
+    saved = {n: getattr(here, n) for n in calls}
+    try:
+        for n in calls:
+            setattr(here, n, getattr(module, n))
+        return run()
+    finally:
+        for n, f in saved.items():
+            setattr(here, n, f)
+
+
+def _kept_pairs_keys_first(name, qi, ki, w, sel, forward_counts):
+    """The selection as ``indexed_attn_dkv`` rebuilds it, keys by queries
+    (``_index_tile_t``), against the forward's, queries by keys
+    (``_index_tile``), a program a (block of keys, block of queries) as the
+    kernel walks them: kept pairs a tile in both orientations, and the pairs
+    whose two scores differ in a bit.  The first must equal each other and
+    what ``forward_call`` counted, in every tile; the second must be 0."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    from deepspeed_tpu.ops import indexed_attention as op
+    from deepspeed_tpu.ops.pallas import indexed_attention as ia
+
+    B, S, NI, DI = qi.shape
+    bq, bk = min(op.BLOCK_Q, S), min(op.BLOCK_K, S)
+    nq, nk = S // bq, S // bk
+
+    def kernel(qi_ref, kit_ref, ki_ref, w_ref, wt_ref, tau_ref, cut_ref,
+               taut_ref, cutt_ref, qk_ref, kq_ref, bits_ref):
+        j, i = pl.program_id(1), pl.program_id(2)
+        q0, k0 = i * bq, j * bk
+
+        @pl.when(i == 0)
+        def _():
+            for ref in (qk_ref, kq_ref, bits_ref):
+                ref[...] = jnp.zeros_like(ref)
+
+        @pl.when(i >= (j * bk) // bq)
+        def _():
+            qk = ia._index_tile(qi_ref, kit_ref[0], w_ref[0])
+            kq = ia._index_tile_t(qi_ref, ki_ref[0], wt_ref[0])
+            kept_qk, _ = ia._kept((tau_ref, cut_ref), qk, q0, k0)
+            kept_kq, _ = ia._kept((taut_ref, cutt_ref), kq, q0, k0,
+                                  keys_first=True)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, nq), 1)
+            for ref, n in ((qk_ref, kept_qk), (kq_ref, kept_kq),
+                           (bits_ref, kq.T != qk)):
+                ref[0, 0] = jnp.where(lane == i,
+                                      jnp.sum(n.astype(jnp.float32)),
+                                      ref[0, 0])
+
+    tau, cut = (x.reshape(B, S, 1) for x in sel)
+    turned = lambda x: jnp.swapaxes(x, 1, 2)    # noqa: E731
+    out = pl.BlockSpec((1, 1, 1, nq), lambda b, j, i: (b, j, 0, 0))
+    live = lambda j, i: jnp.maximum(i, (j * bk) // bq)  # noqa: E731
+    query = lambda width: pl.BlockSpec(      # noqa: E731
+        (1, bq, width), lambda b, j, i: (b, live(j, i), 0))
+    lanes = lambda height: pl.BlockSpec(     # noqa: E731
+        (1, height, bq), lambda b, j, i: (b, 0, live(j, i)))
+    w = w.astype(jnp.float32)
+    qk, kq, bits = jax.jit(lambda *ops: pl.pallas_call(
+        kernel, grid=(B, nk, nq),
+        in_specs=[pl.BlockSpec((1, NI, bq, DI),
+                               lambda b, j, i: (b, 0, live(j, i), 0)),
+                  pl.BlockSpec((1, DI, bk), lambda b, j, i: (b, 0, j)),
+                  pl.BlockSpec((1, bk, DI), lambda b, j, i: (b, j, 0)),
+                  query(NI), lanes(NI), query(1), query(1), lanes(1),
+                  lanes(1)],
+        out_specs=[out] * 3,
+        out_shape=[jax.ShapeDtypeStruct((B, nk, 1, nq), jnp.float32)] * 3,
+        compiler_params=ia._params("parallel", "parallel", "arbitrary"),
+        name="kept_pairs_both_ways")(*ops))(
+            jnp.swapaxes(qi, 1, 2), turned(ki), ki, w, turned(w), tau, cut,
+            turned(tau), turned(cut))
+    qk, kq, bits = (np.asarray(x[:, :, 0], np.float64) for x in (qk, kq, bits))
+    # the forward's counts a (512 x 512) statistics tile: sum ours to those
+    T = op.stat_tile(S)
+    fold = lambda x: x.reshape(B, S // T, T // bk, S // T, T // bq).sum(  # noqa: E731
+        (2, 4)).transpose(0, 2, 1)
+    tiles = int((qk > 0).sum())
+    print(f"  {name}: kept pairs a tile keys by queries, {int(kq.sum())} in "
+          f"{tiles} tiles of {bk} x {bq}: {int((kq != qk).sum())} tiles "
+          f"differ from queries by keys, {int(bits.sum())} scores differ in "
+          f"a bit, {int((fold(kq) != forward_counts).sum())} statistics "
+          f"tiles differ from the forward kernel's counts", flush=True)
+    assert (kq == qk).all() and not bits.any()
+    assert (fold(kq) == forward_counts).all()
 
 
 def kernel_qk_rows():

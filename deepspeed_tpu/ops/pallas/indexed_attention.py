@@ -34,6 +34,17 @@ reference's; the step never builds one).
 
 A dense sweep under a mask: every causal tile is multiplied whatever it
 keeps.  What skipping could save is counted (``tile_counts``).
+
+What a tile waits for on the v5e is the unit that moves data across lanes
+and the latency of one head's chain, not the MXU (PR 47 and PR 43 found
+both in the flash kernels; PR 57 carried them here).  So the heads' loops
+run four heads a trip (:func:`_head_trips`), a sum over a tile's keys is kept
+a partial sum a lane and reduced once a program (``flash_attention.py
+_lane_blocks_sum``; a row MAXIMUM stays a reduction), and ``dkv_call``'s
+tile stands KEYS BY QUERIES, so that every product that sums over the
+queries contracts the tile as it stands and a query's numbers (``lse``,
+``delta``, ``tau`` ...) lie along its lanes as rows.
+``indexed_attn_tile_ops_total`` counts, as a body is traced, what is left.
 """
 from __future__ import annotations
 
@@ -43,6 +54,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...telemetry import registry as _registry
+from .flash_attention import _lane_blocks_sum, _sum_lanes
 
 NEG = -1e30
 _INT_MIN = -2 ** 31
@@ -77,10 +91,24 @@ def _index_tile(qi_ref, kit, w):
     return jnp.where(acc == 0.0, 0.0, acc)
 
 
-def _positions(q0, k0, block_q, block_k):
-    rows = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    cols = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    return rows, cols
+def _index_tile_t(qi_ref, ki, wt):
+    """:func:`_index_tile` standing keys by queries: the same products
+    (64 channels, one MXU pass) and the same sum over heads in order, so
+    the same float32 scores (``chip_smoke.kernel_indexed_attention`` counts
+    the kept pairs of every tile in both orientations).  ``ki`` (block_k,
+    channels), ``wt`` (heads, block_q)."""
+    acc = None
+    for j in range(qi_ref.shape[1]):
+        term = wt[j:j + 1] * jnp.maximum(_nt(ki, qi_ref[0, j]), 0.0)
+        acc = term if acc is None else acc + term
+    return jnp.where(acc == 0.0, 0.0, acc)
+
+
+def _positions(q0, k0, shape, keys_first=False):
+    """``(queries', keys')`` positions of a tile of ``shape``."""
+    q_axis = int(keys_first)
+    return (q0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis),
+            k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
 
 
 def _last_live(i, block_q, block_k):
@@ -103,7 +131,7 @@ def _select_kernel(qi_ref, kit_ref, w_ref, tau_ref, cut_ref, panel, *, topk,
     def fill(kt, carry):
         k0 = pl.multiple_of(kt * block_k, block_k)
         scores = _index_tile(qi_ref, kit_ref[0, :, pl.ds(k0, block_k)], w)
-        rows, cols = _positions(q0, k0, block_q, block_k)
+        rows, cols = _positions(q0, k0, (block_q, block_k))
         panel[:, pl.ds(k0, block_k)] = jnp.where(
             cols <= rows, _sort_key(scores), _INT_MIN)
         return carry
@@ -188,11 +216,12 @@ def select_call(qi, kit, w, *, topk, block_q, block_k, interpret):
 # what every attention kernel does with a tile first
 
 
-def _kept(sel_refs, scores, q0, k0):
+def _kept(sel_refs, scores, q0, k0, keys_first=False):
     """``(kept bool, 0 / NEG float32)`` of a tile: the causal pairs the
     selection keeps.  ``sel_refs`` is ``(tau, cut)`` (two numbers a query,
-    against this tile's scores) or ``(mask,)`` (int8, from outside)."""
-    rows, cols = _positions(q0, k0, *scores.shape)
+    against this tile's scores) or ``(mask,)`` (int8, from outside); each
+    stands as the tile does (``keys_first``: keys by queries)."""
+    rows, cols = _positions(q0, k0, scores.shape, keys_first)
     if len(sel_refs) == 1:      # widened first: Mosaic relays no int8 mask
         kept = sel_refs[0][0].astype(jnp.int32) != 0
     else:
@@ -212,16 +241,64 @@ def _nt(a, b):
                                preferred_element_type=jnp.float32)
 
 
-def _tn(a, b):
-    """``a^T b``, float32."""
-    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+def _mm(a, b):
+    """``a b``, float32."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _column(block, h):
-    """Column ``h`` (traced) of a ``(rows, heads)`` block as ``(rows, 1)``."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1)
-    return jnp.sum(jnp.where(lane == h, block, 0.0), axis=1, keepdims=True)
+def _turned(x):
+    """``(B, S, n)`` as ``(B, n, S)``: by XLA, outside the kernels."""
+    return jnp.swapaxes(x, 1, 2)
+
+
+def _note(kernel, op, n=1):
+    _registry.counter(
+        "indexed_attn_tile_ops_total",
+        "what a head's tile of the indexed-attention kernel body being "
+        "traced (fwd, dq, dkv) still asks of the unit that moves data "
+        "across lanes: heads_a_trip (independent heads' chains in one trip "
+        "of the heads' loops), lane_reduction_a_head (sums or maxima over "
+        "lanes a head a tile), transposed_contraction (products a tile that "
+        "contract dimension 0 of both operands); counted at trace time, not "
+        "per call", labelnames=("kernel", "op")).labels(kernel, op).inc(n)
+
+
+def _heads_a_trip(heads):
+    """Four, where the count allows: a call at the tenth cell's shape read
+    -7 / -0 / -15% with one (the other changes of PR 57 alone), -23 / -10 /
+    -22% with two, -30 / -14 / -26% with four and -33 / -15 / -28% with
+    eight (forward / dq / dkv against PR 54's kernels; my chip runs,
+    PR 57); eight compiled 3.5 s longer a program that holds the three, and
+    the cell's cold set-up had 8 s to spare."""
+    return next(n for n in (4, 2, 1) if heads % n == 0)
+
+
+def _note_body(kernel, heads):
+    """A kernel body is being traced: its heads a trip, and a zero said (not
+    left out) for what its tiles may still ask of the cross-lane unit."""
+    _note(kernel, "heads_a_trip", _heads_a_trip(heads))
+    for op in ("lane_reduction_a_head", "transposed_contraction"):
+        _note(kernel, op, 0)
+
+
+def _head_trips(heads, trip, carry):
+    """The heads' loop of a tile: ``trip(hs, carry)`` for the heads ``hs``
+    of each trip (:func:`_heads_a_trip`).  Their chains are independent and
+    stand in one basic block, so one's products run under the others'
+    vector work (a loop's trips do not overlap: PR 43, PR 55; all of them
+    unrolled cost PR 55 15% of its warm set-up)."""
+    per = _heads_a_trip(heads)
+    return jax.lax.fori_loop(
+        0, heads // per,
+        lambda t, c: trip([t * per + u for u in range(per)], c), carry)
+
+
+def _group_lanes(ref, hs, group, width):
+    """The key-value head's lanes of ``ref`` for each head of a trip,
+    loaded once where they share it."""
+    if group % len(hs) == 0:    # a trip starts at a multiple of its length
+        return [_head_lanes(ref, hs[0] // group, width)] * len(hs)
+    return [_head_lanes(ref, h // group, width) for h in hs]
 
 
 # --------------------------------------------------------------------------
@@ -232,12 +309,13 @@ def _forward_kernel(*refs, scale, heads, group, n_sel, block_k):
     (q_ref, k_ref, v_ref, qi_ref, kit_ref, w_ref), rest = refs[:6], refs[6:]
     sel_refs, rest = rest[:n_sel], rest[n_sel:]
     (out_ref, lse_ref, kl_ref, lsei_ref, count_ref,
-     acc, m_ref, l_ref, sums) = rest
+     acc, m_ref, l_ref, sums, mi_ref) = rest
     block_q, D = q_ref.shape[1], q_ref.shape[2] // heads
     i, phase, kt = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     q0, k0 = i * block_q, kt * block_k
     last = _last_live(i, block_q, block_k)
     dtype = q_ref.dtype
+    _note_body("fwd", heads)
 
     @pl.when((phase == 0) & (kt == 0))
     def _():
@@ -245,7 +323,7 @@ def _forward_kernel(*refs, scale, heads, group, n_sel, block_k):
         m_ref[...] = jnp.full_like(m_ref, NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
         sums[...] = jnp.zeros_like(sums)
-        sums[3] = jnp.full((block_q, 1), NEG, jnp.float32)
+        mi_ref[...] = jnp.full_like(mi_ref, NEG)
         count_ref[...] = jnp.zeros_like(count_ref)
 
     def tile():
@@ -253,68 +331,74 @@ def _forward_kernel(*refs, scale, heads, group, n_sel, block_k):
         kept, neg = _kept(sel_refs, scores, q0, k0)
         return scores, kept, neg
 
-    def logits(h, neg):
-        kg = _head_lanes(k_ref, h // group, D)
-        return _nt(_head_lanes(q_ref, h, D), kg) * scale + neg
+    def logits(hs, neg):
+        return [_nt(_head_lanes(q_ref, h, D), kg) * scale + neg
+                for h, kg in zip(hs, _group_lanes(k_ref, hs, group, D))]
 
     @pl.when((phase == 0) & (kt <= last))
     def _():
         _, _, neg = tile()
 
-        def head(h, carry):
-            s = logits(h, neg)
-            m_old = m_ref[h]
-            m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_old - m_new)
-            l_ref[h] = alpha * l_ref[h] + p.sum(axis=1, keepdims=True)
-            m_ref[h] = m_new
-            at = pl.ds(pl.multiple_of(h * D, D), D)
-            acc[:, at] = alpha * acc[:, at] + jnp.dot(
-                p.astype(dtype), _head_lanes(v_ref, h // group, D),
-                preferred_element_type=jnp.float32)
+        def trip(hs, carry):
+            ss = logits(hs, neg)
+            m_old = [m_ref[h] for h in hs]
+            _note("fwd", "lane_reduction_a_head")   # the maximum: PR 47
+            m_new = [jnp.maximum(m, s.max(axis=1, keepdims=True))
+                     for m, s in zip(m_old, ss)]
+            ps = [jnp.exp(s - m) for s, m in zip(ss, m_new)]
+            pv = [_mm(p.astype(dtype), vg)
+                  for p, vg in zip(ps, _group_lanes(v_ref, hs, group, D))]
+            for h, m0, m, p, o in zip(hs, m_old, m_new, ps, pv):
+                alpha = jnp.exp(m0 - m)
+                l_ref[h] = alpha * l_ref[h] + _lane_blocks_sum(p)
+                m_ref[h] = m
+                at = pl.ds(pl.multiple_of(h * D, D), D)
+                acc[:, at] = alpha * acc[:, at] + o
             return carry
 
-        jax.lax.fori_loop(0, heads, head, 0)
+        _head_trips(heads, trip, 0)
 
     @pl.when((phase == 0) & (kt == last))
     def _():
-        for h in range(heads):
+        for h in range(heads):      # over lanes: once a program
+            l = l_ref[h].sum(axis=1, keepdims=True)
             at = slice(h * D, (h + 1) * D)
-            out_ref[0, :, at] = (acc[:, at] / l_ref[h]).astype(out_ref.dtype)
-            m_ref[h] = m_ref[h] + jnp.log(l_ref[h])     # phase 1 reads lse
+            out_ref[0, :, at] = (acc[:, at] / l).astype(out_ref.dtype)
+            m_ref[h] = m_ref[h] + jnp.log(l)            # phase 1 reads lse
             lse_ref[0, :, h:h + 1] = m_ref[h]
 
     @pl.when((phase == 1) & (kt <= last))
     def _():
         scores, kept, neg = tile()
 
-        def head(h, psum):      # as the backward reads them: exp(s - lse)
-            return psum + jnp.exp(logits(h, neg) - m_ref[h])
+        def trip(hs, psum):     # as the backward reads them: exp(s - lse)
+            return psum + sum(jnp.exp(s - m_ref[h])
+                              for h, s in zip(hs, logits(hs, neg)))
 
-        pbar = jax.lax.fori_loop(
-            0, heads, head, jnp.zeros(scores.shape, jnp.float32)) / heads
-        # sum pbar log pbar, sum pbar I, sum pbar; the kept scores' online lse
-        sums[0] += jnp.sum(jnp.where(pbar > 0.0, pbar * jnp.log(
-            jnp.where(pbar > 0.0, pbar, 1.0)), 0.0), axis=1, keepdims=True)
-        sums[1] += jnp.sum(pbar * scores, axis=1, keepdims=True)
-        sums[2] += jnp.sum(pbar, axis=1, keepdims=True)
+        pbar = _head_trips(heads, trip,
+                           jnp.zeros(scores.shape, jnp.float32)) / heads
+        # sum pbar log pbar, sum pbar I, sum pbar, the kept scores' online
+        # sum of exponentials: a partial sum a lane each
+        sums[0] += _lane_blocks_sum(jnp.where(pbar > 0.0, pbar * jnp.log(
+            jnp.where(pbar > 0.0, pbar, 1.0)), 0.0))
+        sums[1] += _lane_blocks_sum(pbar * scores)
+        sums[2] += _lane_blocks_sum(pbar)
         masked = scores + neg
-        m_old = sums[3]
+        m_old = mi_ref[...]
         m_new = jnp.maximum(m_old, masked.max(axis=1, keepdims=True))
-        sums[4] = sums[4] * jnp.exp(m_old - m_new) + jnp.sum(
-            jnp.where(kept, jnp.exp(masked - m_new), 0.0), axis=1,
-            keepdims=True)
-        sums[3] = m_new
+        sums[3] = sums[3] * jnp.exp(m_old - m_new) + _lane_blocks_sum(
+            jnp.where(kept, jnp.exp(masked - m_new), 0.0))
+        mi_ref[...] = m_new
         lane = jax.lax.broadcasted_iota(jnp.int32, count_ref.shape[2:], 1)
         count_ref[0, 0] = jnp.where(
             lane == kt, jnp.sum(kept.astype(jnp.float32)), count_ref[0, 0])
 
     @pl.when((phase == 1) & (kt == last))
     def _():
-        lse_i = sums[3] + jnp.log(sums[4])
+        total = [sums[n].sum(axis=1, keepdims=True) for n in range(4)]
+        lse_i = mi_ref[...] + jnp.log(total[3])
         lsei_ref[0] = lse_i
-        kl_ref[0] = sums[0] - sums[1] + sums[2] * lse_i
+        kl_ref[0] = total[0] - total[1] + total[2] * lse_i
 
 
 def _sel_specs(sel, block_q, block_k, at_q, at_k):
@@ -345,6 +429,7 @@ def forward_call(q, k, v, qi, kit, w, sel, *, heads, kv_heads, scale, block_q,
     B, S, W = q.shape
     NI, DI = qi.shape[1], qi.shape[3]
     nq, nk = S // block_q, S // block_k
+    lanes = _sum_lanes(block_k)
 
     def live(b, i, ph, kt):
         return jnp.minimum(kt, _last_live(i, block_q, block_k))
@@ -383,8 +468,9 @@ def forward_call(q, k, v, qi, kit, w, sel, *, heads, kv_heads, scale, block_q,
                    jax.ShapeDtypeStruct((B, nq, 1, nk), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, W), jnp.float32),
                         pltpu.VMEM((heads, block_q, 1), jnp.float32),
-                        pltpu.VMEM((heads, block_q, 1), jnp.float32),
-                        pltpu.VMEM((5, block_q, 1), jnp.float32)],
+                        pltpu.VMEM((heads, block_q, lanes), jnp.float32),
+                        pltpu.VMEM((4, block_q, lanes), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=_params("parallel", "parallel", "arbitrary",
                                 "arbitrary"),
         cost_estimate=_attention_cost(B, S, heads, W // heads, NI, DI, 3,
@@ -398,21 +484,25 @@ def forward_call(q, k, v, qi, kit, w, sel, *, heads, kv_heads, scale, block_q,
 
 
 def _grad_tile(q_ref, k_ref, v_ref, do_ref, neg, lse_of, delta_of, scale,
-               heads, group, per_head):
-    """The heads' loop of a backward tile: ``per_head(h, p, ds)`` for each,
-    and the sum of the heads' probabilities."""
+               heads, group, per_trip, keys_first=False):
+    """The heads' loop of a backward tile: ``per_trip(hs, [(p, ds), ...])``
+    for the heads of each trip, and the sum of the heads' probabilities.
+    The tile stands as ``neg`` does: queries by keys, or keys by queries."""
     D = q_ref.shape[2] // heads
 
-    def head(h, psum):
-        g = h // group
-        s = _nt(_head_lanes(q_ref, h, D), _head_lanes(k_ref, g, D)) * scale
-        p = jnp.exp(s + neg - lse_of(h))
-        dp = _nt(_head_lanes(do_ref, h, D), _head_lanes(v_ref, g, D))
-        per_head(h, p, p * (dp - delta_of(h)) * scale)
-        return psum + p
+    def trip(hs, psum):
+        made = []
+        for h, kg, vg in zip(hs, _group_lanes(k_ref, hs, group, D),
+                             _group_lanes(v_ref, hs, group, D)):
+            qh, doh = _head_lanes(q_ref, h, D), _head_lanes(do_ref, h, D)
+            s, dp = ((_nt(kg, qh), _nt(vg, doh)) if keys_first
+                     else (_nt(qh, kg), _nt(doh, vg)))
+            p = jnp.exp(s * scale + neg - lse_of(h))
+            made.append((p, p * (dp - delta_of(h)) * scale))
+        per_trip(hs, made)
+        return psum + sum(p for p, _ in made)
 
-    return jax.lax.fori_loop(0, heads, head,
-                             jnp.zeros(neg.shape, jnp.float32))
+    return _head_trips(heads, trip, jnp.zeros(neg.shape, jnp.float32))
 
 
 def _score_grad(scores, kept, pbar, lsei_ref, dkl_ref):
@@ -431,15 +521,17 @@ def _dq_kernel(*refs, scale, heads, group, n_sel, block_k):
     q0, k0 = i * block_q, kt * block_k
     last = _last_live(i, block_q, block_k)
     dtype = q_ref.dtype
+    _note_body("dq", heads)
 
     @pl.when(kt == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         dqi_acc[...] = jnp.zeros_like(dqi_acc)
         dw_acc[...] = jnp.zeros_like(dw_acc)
+        lse, delta = lse_ref[0].T, delta_ref[0].T   # (block_q, heads)
         for h in range(heads):      # a head's column, once a program
-            lse_s[h] = lse_ref[0, :, h:h + 1]
-            delta_s[h] = delta_ref[0, :, h:h + 1]
+            lse_s[h] = lse[:, h:h + 1]
+            delta_s[h] = delta[:, h:h + 1]
 
     @pl.when(kt <= last)
     def _():
@@ -447,25 +539,23 @@ def _dq_kernel(*refs, scale, heads, group, n_sel, block_k):
         scores = _index_tile(qi_ref, kit_ref[0], w)
         kept, neg = _kept(sel_refs, scores, q0, k0)
 
-        def per_head(h, p, ds):
-            at = pl.ds(pl.multiple_of(h * D, D), D)
-            dq_acc[:, at] += jnp.dot(ds.astype(dtype),
-                                     _head_lanes(k_ref, h // group, D),
-                                     preferred_element_type=jnp.float32)
+        def per_trip(hs, made):
+            dqs = [_mm(ds.astype(dtype), kg) for (_, ds), kg in zip(
+                made, _group_lanes(k_ref, hs, group, D))]
+            for h, dq in zip(hs, dqs):
+                dq_acc[:, pl.ds(pl.multiple_of(h * D, D), D)] += dq
 
         pbar = _grad_tile(q_ref, k_ref, v_ref, do_ref, neg,
                           lambda h: lse_s[h], lambda h: delta_s[h], scale,
-                          heads, group, per_head) / heads
+                          heads, group, per_trip) / heads
         d_scores = _score_grad(scores, kept, pbar, lsei_ref, dkl_ref)
         ki = ki_ref[0]
         for j in range(qi_ref.shape[1]):
-            z = jnp.dot(qi_ref[0, j], kit_ref[0],
-                        preferred_element_type=jnp.float32)
+            z = _mm(qi_ref[0, j], kit_ref[0])
             dw_acc[:, j:j + 1] += jnp.sum(d_scores * jnp.maximum(z, 0.0),
                                           axis=1, keepdims=True)
             dz = jnp.where(z > 0.0, d_scores * w[:, j:j + 1], 0.0)
-            dqi_acc[j] += jnp.dot(dz.astype(dtype), ki,
-                                  preferred_element_type=jnp.float32)
+            dqi_acc[j] += _mm(dz.astype(dtype), ki)
 
     @pl.when(kt == last)
     def _():
@@ -480,7 +570,10 @@ def _dq_kernel(*refs, scale, heads, group, n_sel, block_k):
 def dq_call(q, k, v, do, lse, delta, qi, kit, ki, w, lse_i, dkl, sel, *,
             heads, kv_heads, scale, block_q, block_k, interpret):
     """``(dq (B, S, H D), dq_I (B, heads, S, channels) float32, dw (B, S,
-    heads) float32)``."""
+    heads) float32)``.  ``lse`` and ``delta`` reach the kernel as
+    :func:`dkv_call` takes them, ``(B, heads, S)`` (one turn by XLA serves
+    both; ``(B, S, 32)`` float32 is padded to 128 lanes in HBM, four times
+    its bytes), and a program turns its block back once."""
     B, S, W = q.shape
     NI, DI = qi.shape[1], qi.shape[3]
 
@@ -496,13 +589,14 @@ def dq_call(q, k, v, do, lse, delta, qi, kit, ki, w, lse_i, dkl, sel, *,
 
     index_rows = pl.BlockSpec((1, NI, block_q, DI),
                               lambda b, i, kt: (b, 0, i, 0))
+    heads_rows = pl.BlockSpec((1, heads, block_q), lambda b, i, kt: (b, 0, i))
     return pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, heads=heads,
                           group=heads // kv_heads, n_sel=len(sel),
                           block_k=block_k),
         grid=(B, S // block_q, S // block_k),
         in_specs=[rows(W), keys(k.shape[2]), keys(v.shape[2]), rows(W),
-                  rows(heads), rows(heads), index_rows,
+                  heads_rows, heads_rows, index_rows,
                   pl.BlockSpec((1, DI, block_k),
                                lambda *g: (g[0], 0, live(*g))),
                   keys(DI), rows(NI), rows(1), rows(1),
@@ -520,11 +614,16 @@ def dq_call(q, k, v, do, lse, delta, qi, kit, ki, w, lse_i, dkl, sel, *,
         cost_estimate=_attention_cost(B, S, heads, W // heads, NI, DI, 3,
                                       3 * q.size * q.dtype.itemsize),
         interpret=interpret, name="indexed_attn_dq",
-    )(q, k, v, do, lse, delta, qi, kit, ki, w, lse_i, dkl, *sel)
+    )(q, k, v, do, _turned(lse), _turned(delta), qi, kit, ki, w, lse_i, dkl,
+      *sel)
 
 
 def _dkv_kernel(*refs, scale, heads, group, n_sel, block_q):
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, kit_ref, w_ref,
+    """A block of keys against the blocks of queries at or after it; the
+    tile stands keys by queries, a query's numbers are rows along its lanes
+    and a head's a sublane of ``lse_ref`` / ``delta_ref`` (1, heads,
+    block_q)."""
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi_ref, ki_ref, wt_ref,
      lsei_ref, dkl_ref), rest = refs[:11], refs[11:]
     sel_refs, rest = rest[:n_sel], rest[n_sel:]
     dk_ref, dv_ref, dki_ref, dk_acc, dv_acc, dki_acc = rest
@@ -533,6 +632,7 @@ def _dkv_kernel(*refs, scale, heads, group, n_sel, block_q):
     first = (j * block_k) // block_q
     q0, k0 = i * block_q, j * block_k
     dtype = q_ref.dtype
+    _note_body("dkv", heads)
 
     @pl.when(i == 0)
     def _():
@@ -542,26 +642,30 @@ def _dkv_kernel(*refs, scale, heads, group, n_sel, block_q):
 
     @pl.when(i >= first)
     def _():
-        w = w_ref[0]
-        scores = _index_tile(qi_ref, kit_ref[0], w)
-        kept, neg = _kept(sel_refs, scores, q0, k0)
-        lse, delta = lse_ref[0], delta_ref[0]
+        wt, ki = wt_ref[0], ki_ref[0]
+        scores = _index_tile_t(qi_ref, ki, wt)
+        kept, neg = _kept(sel_refs, scores, q0, k0, keys_first=True)
 
-        def per_head(h, p, ds):
-            at = pl.ds(pl.multiple_of((h // group) * D, D), D)
-            dv_acc[:, at] += _tn(p.astype(dtype), _head_lanes(do_ref, h, D))
-            dk_acc[:, at] += _tn(ds.astype(dtype), _head_lanes(q_ref, h, D))
+        def per_trip(hs, made):
+            grads = [(_mm(p.astype(dtype), _head_lanes(do_ref, h, D)),
+                      _mm(ds.astype(dtype), _head_lanes(q_ref, h, D)))
+                     for h, (p, ds) in zip(hs, made)]
+            if group % len(hs) == 0:    # one key-value head's: one add
+                hs, grads = hs[:1], [[sum(g) for g in zip(*grads)]]
+            for h, (dv, dk) in zip(hs, grads):
+                at = pl.ds(pl.multiple_of((h // group) * D, D), D)
+                dv_acc[:, at] += dv
+                dk_acc[:, at] += dk
 
         pbar = _grad_tile(q_ref, k_ref, v_ref, do_ref, neg,
-                          lambda h: _column(lse, h),
-                          lambda h: _column(delta, h), scale, heads, group,
-                          per_head) / heads
+                          lambda h: lse_ref[0, pl.ds(h, 1)],
+                          lambda h: delta_ref[0, pl.ds(h, 1)], scale, heads,
+                          group, per_trip, keys_first=True) / heads
         d_scores = _score_grad(scores, kept, pbar, lsei_ref, dkl_ref)
         for n in range(qi_ref.shape[1]):
-            z = jnp.dot(qi_ref[0, n], kit_ref[0],
-                        preferred_element_type=jnp.float32)
-            dz = jnp.where(z > 0.0, d_scores * w[:, n:n + 1], 0.0)
-            dki_acc[...] += _tn(dz.astype(dtype), qi_ref[0, n])
+            z = _nt(ki, qi_ref[0, n])
+            dz = jnp.where(z > 0.0, d_scores * wt[n:n + 1], 0.0)
+            dki_acc[...] += _mm(dz.astype(dtype), qi_ref[0, n])
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _():
@@ -575,7 +679,12 @@ def _dkv_kernel(*refs, scale, heads, group, n_sel, block_q):
                                              "interpret"), inline=True)
 def dkv_call(q, k, v, do, lse, delta, qi, kit, w, lse_i, dkl, sel, *, heads,
              kv_heads, scale, block_q, block_k, interpret):
-    """``(dk, dv (B, S, KV D), dk_I (B, S, channels) float32)``."""
+    """``(dk, dv (B, S, KV D), dk_I (B, S, channels) float32)``.  What is a
+    number a query (and head) reaches the kernel turned, ``(B, heads | 1,
+    S)`` (a mask keys by queries), so that it lies along the lanes of a
+    tile that stands keys by queries: a few MB, turned by XLA (the whole
+    step reserves 10.872 GiB where it reserved 10.880: sandbox compile,
+    PR 57)."""
     B, S, W = q.shape
     NI, DI = qi.shape[1], qi.shape[3]
 
@@ -586,21 +695,28 @@ def dkv_call(q, k, v, do, lse, delta, qi, kit, w, lse_i, dkl, sel, *, heads,
         return pl.BlockSpec((1, block_q, width),
                             lambda *g: (g[0], live(*g), 0))
 
+    def lanes(height):
+        return pl.BlockSpec((1, height, block_q),
+                            lambda *g: (g[0], 0, live(*g)))
+
     def keys(width):
         return pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, j, 0))
 
+    if len(sel) == 1:
+        sel_specs = [pl.BlockSpec((1, block_k, block_q),
+                                  lambda *g: (g[0], g[1], live(*g)))]
+    else:
+        sel_specs = [lanes(1)] * 2
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, heads=heads,
                           group=heads // kv_heads, n_sel=len(sel),
                           block_q=block_q),
         grid=(B, S // block_k, S // block_q),
         in_specs=[rows(W), keys(k.shape[2]), keys(v.shape[2]), rows(W),
-                  rows(heads), rows(heads),
+                  lanes(heads), lanes(heads),
                   pl.BlockSpec((1, NI, block_q, DI),
                                lambda *g: (g[0], 0, live(*g), 0)),
-                  pl.BlockSpec((1, DI, block_k), lambda b, j, i: (b, 0, j)),
-                  rows(NI), rows(1), rows(1),
-                  *_sel_specs(sel, block_q, block_k, live, lambda *g: g[1])],
+                  keys(DI), lanes(NI), lanes(1), lanes(1), *sel_specs],
         out_specs=[keys(k.shape[2]), keys(v.shape[2]), keys(DI)],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -612,4 +728,5 @@ def dkv_call(q, k, v, do, lse, delta, qi, kit, w, lse_i, dkl, sel, *, heads,
         cost_estimate=_attention_cost(B, S, heads, W // heads, NI, DI, 4,
                                       3 * q.size * q.dtype.itemsize),
         interpret=interpret, name="indexed_attn_dkv",
-    )(q, k, v, do, lse, delta, qi, kit, w, lse_i, dkl, *sel)
+    )(q, k, v, do, _turned(lse), _turned(delta), qi, _turned(kit), _turned(w),
+      _turned(lse_i), _turned(dkl), *map(_turned, sel))

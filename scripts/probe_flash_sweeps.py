@@ -58,13 +58,14 @@ CASES = {
 }
 
 
-def load(name: str, root: str, edits=()):
-    """The flash module of the checkout at ``root`` under a name of its own
-    (its relative imports resolve in this tree's package): fresh jits, so
-    no build answers from another's trace cache.  ``edits`` are ``(old,
-    new)`` pairs replaced in its source first, each of which must be there:
-    a build that leaves a piece of a tile's work out, for its time alone."""
-    path = os.path.join(root, MODULE)
+def load(name: str, root: str, edits=(), module: str = MODULE):
+    """The flash module (or another ``module`` of the kernels' package) of
+    the checkout at ``root`` under a name of its own (its relative imports
+    resolve in this tree's package): fresh jits, so no build answers from
+    another's trace cache.  ``edits`` are ``(old, new)`` pairs replaced in
+    its source first, each of which must be there: a build that leaves a
+    piece of a tile's work out, for its time alone."""
+    path = os.path.join(root, module)
     spec = importlib.util.spec_from_file_location(
         f"deepspeed_tpu.ops.pallas._probe_{name}", path)
     mod = importlib.util.module_from_spec(spec)

@@ -17,11 +17,11 @@ indexed_attention_module = importlib.import_module(
 H, KV, D, NI, DI = 4, 2, 128, 4, 64
 
 
-def _operands(B, S, seed=0, dtype=jnp.bfloat16):
+def _operands(B, S, seed=0, dtype=jnp.bfloat16, heads=H, kv_heads=KV):
     ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    q = jax.random.normal(ks[0], (B, S, H, D), dtype)
-    k = jax.random.normal(ks[1], (B, S, KV, D), dtype)
-    v = jax.random.normal(ks[2], (B, S, KV, D), dtype)
+    q = jax.random.normal(ks[0], (B, S, heads, D), dtype)
+    k = jax.random.normal(ks[1], (B, S, kv_heads, D), dtype)
+    v = jax.random.normal(ks[2], (B, S, kv_heads, D), dtype)
     qi = jax.random.normal(ks[3], (B, S, NI, DI), dtype)
     ki = jax.random.normal(ks[4], (B, S, DI), dtype)
     w = jax.random.normal(ks[5], (B, S, NI), jnp.float32) * 0.1
@@ -55,14 +55,20 @@ def small_blocks(monkeypatch):
     monkeypatch.setattr(indexed_attention_module, "BLOCK_K", 128)
 
 
-@pytest.mark.parametrize("B,S,topk", [
-    (2, 256, 48),       # fewer keys than the row; two rows of other data
-    (1, 128, 200),      # a row shorter than topk: every causal key is kept
-    (1, 256, 5),        # a handful of keys a query
+@pytest.mark.parametrize("B,S,topk,heads,kv_heads,block_k", [
+    (2, 256, 48, H, KV, 128),   # fewer keys than the row; two rows of data
+    (1, 128, 200, H, KV, 128),  # a row shorter than topk: every key is kept
+    (1, 256, 5, H, KV, 128),    # a handful of keys a query
+    (1, 256, 48, 3, 1, 128),    # an odd head count: one head a trip
+    (1, 256, 48, 6, 3, 128),    # two a trip, on one key-value head
+    (1, 256, 48, 4, 4, 128),    # a trip's four heads on four key-value heads
+    (1, 256, 48, H, KV, 64),    # no whole 128-lane block of keys a tile
 ])
-def test_the_kernels_are_the_plain_form(B, S, topk):
-    ops, kc, kk = _operands(B, S)
-    cot = jax.random.normal(kc, (B, S, H, D), jnp.float32)
+def test_the_kernels_are_the_plain_form(B, S, topk, heads, kv_heads, block_k,
+                                        monkeypatch):
+    monkeypatch.setattr(indexed_attention_module, "BLOCK_K", block_k)
+    ops, kc, kk = _operands(B, S, heads=heads, kv_heads=kv_heads)
+    cot = jax.random.normal(kc, (B, S, heads, D), jnp.float32)
     ckl = jax.random.uniform(kk, (B, S), jnp.float32)
     want, g_want = _both("jnp", ops, topk, cot, ckl)
     got, g_got = _both("pallas", ops, topk, cot, ckl, **KERNEL)
@@ -163,3 +169,87 @@ def test_auto_takes_the_plain_form_off_the_chip_and_says_so():
     assert after[key] == before.get(key, 0) + 1
     with pytest.raises(ValueError, match="impl"):
         indexed_attention(*ops, topk=8, impl="flash")
+
+
+# --------------------------------------------------------------------------
+# what a head's tile asks of the unit that moves data across lanes (PR 57)
+
+
+def _kernel_jaxprs(heads, kv_heads, S=384):
+    """``{fwd | dq | dkv: the kernel body's jaxpr}`` traced (not run) for
+    ``heads`` on ``kv_heads``, and what tracing them added to
+    ``indexed_attn_tile_ops_total``.  A row length no other test has: a
+    call that an earlier test traced is answered from jit's cache, and
+    nothing is counted."""
+    from deepspeed_tpu.telemetry import registry
+
+    def samples():
+        entry = registry.get_registry().snapshot().get(
+            "indexed_attn_tile_ops_total", {"samples": []})
+        return {(x["labels"]["kernel"], x["labels"]["op"]): x["value"]
+                for x in entry["samples"]}
+
+    ops, kc, kk = _operands(1, S, heads=heads, kv_heads=kv_heads)
+
+    def loss(*ops):
+        r = indexed_attention(*ops, topk=16, impl="pallas", **KERNEL)
+        return r.out.astype(jnp.float32).sum() + r.kl.sum()
+
+    before = samples()
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=range(6)))(*ops)
+    after = samples()
+    bodies = {}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                bodies[name.replace("indexed_attn_", "")] = eqn.params["jaxpr"]
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return bodies, {k: after[k] - before.get(k, 0) for k in after}
+
+
+def _eqns(jaxpr, inside_loop=False):
+    """``(primitive name, eqn, whether under a loop)`` of every equation."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, eqn, inside_loop
+        loop = inside_loop or eqn.primitive.name in ("while", "scan")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, loop)
+
+
+@pytest.mark.parametrize("heads,kv_heads,per", [(32, 4, 4), (6, 3, 2),
+                                                  (3, 1, 1)])
+def test_a_tile_keeps_off_the_cross_lane_unit(heads, kv_heads, per):
+    """At the cell's head counts (and where only two or one divide the
+    count): four heads a trip of every heads' loop, one
+    reduction over lanes a head a tile forward (the maximum) and none
+    backward, and no product of ``indexed_attn_dkv`` contracts dimension 0
+    of both operands (its tile stands keys by queries), nor does it sum a
+    masked block to read a column."""
+    bodies, counted = _kernel_jaxprs(heads, kv_heads)
+    assert set(bodies) >= {"fwd", "dq", "dkv"}
+    for kernel in ("fwd", "dq", "dkv"):
+        traces = counted[kernel, "heads_a_trip"] // per
+        assert traces >= 1 and counted[kernel, "heads_a_trip"] == per * traces
+        assert counted[kernel, "lane_reduction_a_head"] == (
+            traces if kernel == "fwd" else 0)
+        assert counted[kernel, "transposed_contraction"] == 0
+    # what the counter says is what the bodies hold
+    in_loops = {k: [n for n, _, loop in _eqns(b) if loop and n.startswith(
+        ("reduce_", "argm"))] for k, b in bodies.items()}
+    assert [in_loops[k] for k in ("fwd", "dq", "dkv")] == [
+        ["reduce_max"] * per, [], []]
+    dkv = list(_eqns(bodies["dkv"]))
+    assert not [n for n, _, _ in dkv if n.startswith("reduce_")]
+    dots = [e.params["dimension_numbers"][0] for n, e, _ in dkv
+            if n == "dot_general"]
+    assert dots and all(c != ((0,), (0,)) for c in dots)
+    # every trip's loop runs heads / per times
+    trips = [e.params["length"] for n, e, _ in _eqns(bodies["dq"])
+             if n == "scan"]
+    assert trips == [heads // per]

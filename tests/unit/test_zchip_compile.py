@@ -6,6 +6,8 @@ block off the tiling) it refuses here, at no chip time.  Nothing runs.
 The topology is described inside a fixture and only in this file: the
 TPU library loads in the one worker that is given these tests.
 """
+import math
+
 import pytest
 
 import jax
@@ -1375,10 +1377,10 @@ def test_the_kernels_compile_at_the_tenth_cells_shape(one_chip):
         r = indexed_attention(*ops, topk=K, impl="pallas")
         return r.out.astype(jnp.float32).sum() + r.kl.sum()
 
+    args = (sd((B, S, H, D)), sd((B, S, KV, D)), sd((B, S, KV, D)),
+            sd((B, S, NI, DI)), sd((B, S, DI)), sd((B, S, NI), jnp.float32))
     compiled = jax.jit(jax.value_and_grad(loss, argnums=range(6))).lower(
-        sd((B, S, H, D)), sd((B, S, KV, D)), sd((B, S, KV, D)),
-        sd((B, S, NI, DI)), sd((B, S, DI)),
-        sd((B, S, NI), jnp.float32)).compile()
+        *args).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 4
     for name in ("indexer_select", "indexed_attn_fwd", "indexed_attn_dq",
@@ -1386,6 +1388,32 @@ def test_the_kernels_compile_at_the_tenth_cells_shape(one_chip):
         assert len(re.findall(name + r"[.\d]* = ", text)) == 1, name
     assert f"[{B},{S},{S}]" not in text and f"[{S},{S}]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+    # what each kernel asks of VMEM: its scratch (PR 57: the forward's
+    # softmax denominators a (heads, block_q, 128) partial sum a lane)
+    # beside two buffers of every block, in whole (8, 128) tiles
+    from deepspeed_tpu.ops.pallas.indexed_attention import _VMEM_LIMIT
+
+    def tiled(ref):
+        *lead, rows, lanes = ref.aval.shape
+        return (math.prod(lead) * -(-rows // 8) * 8 * -(-lanes // 128) * 128
+                * max(ref.aval.dtype.itemsize, 4))
+
+    asked = {}
+    stack = list(jax.make_jaxpr(jax.grad(loss, argnums=range(6)))(
+        *args).jaxpr.eqns)
+    while stack:
+        e = stack.pop()
+        if e.primitive.name == "pallas_call":
+            refs = e.params["jaxpr"].invars
+            n = e.params["grid_mapping"].num_scratch_operands
+            asked[e.params["name"]] = (
+                [r.aval.shape for r in refs[-n:]],
+                sum(map(tiled, refs[-n:])) + 2 * sum(map(tiled, refs[:-n])))
+        for sub in jax.core.jaxprs_in_params(e.params):
+            stack.extend(sub.eqns)
+    assert (H, 256, 128) in asked["indexed_attn_fwd"][0]
+    for name, (_, need) in asked.items():
+        assert need < _VMEM_LIMIT, (name, need)
     # the second stage under a selection from outside: an int8 mask tile in
     # tau's and cut's place (Mosaic refused the mask's compare until the
     # tile was widened first: my chip run, PR 54)
@@ -1518,3 +1546,4 @@ def test_the_tenth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert {("indexed_attention", "pallas"), ("grouped_matmul", "megablox"),
             ("qk_rows", "pallas"), ("moe_rows", "pallas")} <= sites, sites
     assert ("indexed_attention", "jnp") not in sites
+    print(f"reserved {reserved:.3f} GiB")
