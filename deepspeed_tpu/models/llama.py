@@ -118,6 +118,26 @@ loss of its own, ``KL(mean-over-heads attention probabilities || softmax of
 its scores)`` over the kept keys, which joins the model's loss under
 ``indexer_loss_weight`` summed over the layers; the selection passes no
 gradient.  No cache leaf holds the indexer's keys: ``decode=True`` raises.
+
+Ling 3.0 (inclusionAI, 2026; ``model_type: bailing_hybrid``) is the first
+stack in which latent attention stands BESIDE linear-state layers:
+``layer_types`` holds ``"kda_attention"`` where the token mixer is Kimi
+Delta Attention (:class:`KimiDeltaAttention`, arXiv:2510.26692: the delta
+rule under a log-decay a KEY CHANNEL, ``ops/gated_delta.py`` with ``g`` of
+``(B, S, H, dk)``; ``num_attention_heads`` states of ``head_dim x
+head_dim``, a filter of ``short_conv_kernel_size`` taps, the decay's gate
+bounded below by ``kda_lower_bound`` under ``kda_safe_gate``) and
+``"full_attention"`` where it is :class:`LlamaLatentAttention`, here
+without a query latent (``q_lora_rank=None``: one projection to ``[q_nope |
+q_rope]``) and with ``attn_gate="head"`` (the output of each head times the
+sigmoid of ONE scalar a head, a projection of ``num_attention_heads``
+columns, before ``o_proj``).  ``partial_rotary_factor`` there only restates
+``qk_rope_head_dim / head_dim``.  The experts route under a group limit
+(``MoEConfig.n_group`` / ``topk_group``, ``parallel/moe.py``); the
+prediction block's mixer is latent attention whatever the stack ends on.
+``expert_swiglu_limit_list`` / ``share_expert_swiglu_limit_list`` name a
+clamp whose form the config does not give: a non-zero entry within the
+depth built raises.  ``decode=True`` raises with any of it.
 """
 from __future__ import annotations
 
@@ -138,10 +158,12 @@ from .common import ModelOutput, cross_entropy_loss, resolve_remat_policy, shift
 SLIDING, FULL_ATTENTION = "sliding_attention", "full_attention"
 CONV = "conv"       # a layer whose token mixer is ShortConv, not attention
 LINEAR = "linear_attention"     # ... is GatedDeltaNet
-MIXERS = (CONV, LINEAR)         # the layer types that are no attention
-# the widths latent attention takes together (their config.json names)
-_MLA_WIDTHS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
-               "qk_rope_head_dim", "v_head_dim")
+KDA = "kda_attention"           # ... is KimiDeltaAttention
+MIXERS = (CONV, LINEAR, KDA)    # the layer types that are no attention
+# the widths latent attention takes together (their config.json names);
+# ``q_lora_rank`` beside them is None where the queries have no latent
+_MLA_WIDTHS = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+               "v_head_dim")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -192,8 +214,12 @@ class LlamaConfig:
     ``linear_num_key_heads``, ``linear_num_value_heads``,
     ``linear_key_head_dim``, ``linear_value_head_dim``,
     ``linear_conv_kernel_dim``, ``linear_allow_neg_eigval``,
-    ``partial_rotary_factor``, ``sa_config``.  The rest are this program's
-    own."""
+    ``partial_rotary_factor``, ``sa_config``, ``q_lora_rank``,
+    ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+    ``v_head_dim``, ``rope_interleave``, ``num_nextn_predict_layers``,
+    ``kda_lower_bound``, ``kda_safe_gate``, ``short_conv_kernel_size``,
+    ``expert_swiglu_limit_list``, ``share_expert_swiglu_limit_list``.  The
+    rest are this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -213,7 +239,8 @@ class LlamaConfig:
     # one entry a layer (more are ignored: a model cut in depth keeps its
     # source's list), "sliding_attention" | "full_attention" | "conv" (a
     # short-convolution mixer in attention's place) | "linear_attention" (a
-    # Gated DeltaNet there); None → all full
+    # Gated DeltaNet there) | "kda_attention" (Kimi Delta Attention there);
+    # None → all full
     layer_types: Optional[tuple] = None
     # a "linear_attention" layer: key heads (q and k), value heads (v, the
     # states, the output; a multiple of the key heads), the channels of
@@ -227,8 +254,19 @@ class LlamaConfig:
     # beta = 2 sigmoid(b) instead of sigmoid(b): the state's transition
     # I - beta k k^T may flip a direction (eigenvalue 1 - beta in (-1, 1))
     linear_allow_neg_eigval: bool = False
-    # positions the delta rule solves together (ops/gated_delta.py)
+    # positions the delta rule solves together (ops/gated_delta.py), of a
+    # "linear_attention" and of a "kda_attention" layer
     linear_chunk_size: int = 64
+    # a "kda_attention" layer (num_attention_heads states of head_dim x
+    # head_dim): the taps of its three filters, and the decay's gate, a
+    # log-decay a key channel: kda_lower_bound * sigmoid(exp(A_log) (h W_f +
+    # dt_bias)) in (kda_lower_bound, 0) under kda_safe_gate, else Gated
+    # DeltaNet's -exp(A_log) softplus(.), unbounded below (the chunked rule
+    # forms its decays 16 positions at a time and overflows float32 below
+    # -5.5 a position: ops/gated_delta.py)
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    kda_safe_gate: bool = True
     # the share of a head's channels, from the first on, that the rotation
     # turns; the rest pass
     partial_rotary_factor: float = 1.0
@@ -275,8 +313,10 @@ class LlamaConfig:
     # rotary (OLMoE); "head": over each head's channels, one scale of
     # head_dim for q and one for k (AFMoE)
     qk_norm: Any = False
-    # attention's output times sigmoid(x W_g), W_g as wide as q, before o_proj
-    attn_gate: bool = False
+    # attention's output times sigmoid(x W_g) before o_proj: True, W_g as
+    # wide as q (a gate a channel); "head", W_g of num_attention_heads
+    # columns (a gate a head; written for latent attention)
+    attn_gate: Any = False
     # the layer types whose q and k are rotated; None: all.  A type left
     # out attends with no positional encoding
     rope_layer_types: Optional[tuple] = None
@@ -303,11 +343,20 @@ class LlamaConfig:
     # rotary over channel pairs (2i, 2i+1) instead of (i, i + d/2);
     # written for the latent attention's rope channels alone
     rope_interleave: bool = False
+    # a clamp inside the routed / the shared experts' SwiGLU, one entry a
+    # layer (the Ling 3.0 family's keys); the config gives its size and not
+    # its form, so only zeros (no clamp) within the depth built are taken
+    expert_swiglu_limit_list: Optional[tuple] = None
+    share_expert_swiglu_limit_list: Optional[tuple] = None
     # multi-token prediction: this many extra blocks after the stack (1 is
     # written), each predicting one token further ahead through the
     # model's own embedding table and head; their cross-entropy joins the
-    # loss under ``mtp_loss_weight`` (this program's name: the family's
-    # config has no key for it)
+    # loss under ``mtp_loss_weight`` (this program's name: the DeepSeek-V3
+    # family's config has no key for it; Ling 3.0's is
+    # ``mtp_loss_scaling_factor``).  At weight 0 the loss and every gradient
+    # of the main model are those without the blocks and the blocks' own
+    # gradients are zero: none is built, and no leaf of one is declared
+    # (:attr:`mtp_blocks`)
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
     # block-diffusion training: a BlockDiffusionConfig, or a dict of its
@@ -347,7 +396,8 @@ class LlamaConfig:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_attention_heads)
         for name in ("layer_types", "rope_parameters", "rope_layer_types",
-                     "sa_config"):
+                     "sa_config", "expert_swiglu_limit_list",
+                     "share_expert_swiglu_limit_list"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm is False, True (the whole projection)"
@@ -365,9 +415,9 @@ class LlamaConfig:
             raise ValueError("num_dense_layers counts the blocks that moe "
                              "leaves dense; there is no moe")
         for t in self.layer_types or ():
-            if t not in (SLIDING, FULL_ATTENTION, CONV, LINEAR):
+            if t not in (SLIDING, FULL_ATTENTION) + MIXERS:
                 raise ValueError(f"layer_types holds {t!r}; {SLIDING!r}, "
-                                 f"{FULL_ATTENTION!r}, {CONV!r} and "
+                                 f"{FULL_ATTENTION!r}, {KDA!r}, {CONV!r} and "
                                  f"{LINEAR!r} are written")
         if self.layer_types is not None:
             if len(self.layer_types) < self.num_hidden_layers:
@@ -394,10 +444,6 @@ class LlamaConfig:
                     "diffusion (block-diffusion training) with a conv "
                     "layer: the filter would run across the two halves "
                     "[noisy ; clean] and no block mask is written for it")
-            if self.mla_fields:
-                raise NotImplementedError(
-                    "latent attention with a conv layer (layer_types): "
-                    "LlamaLatentAttention takes no layer type")
         if LINEAR in self.kinds:
             Hk, Hv = self.linear_num_key_heads, self.linear_num_value_heads
             if Hk < 1 or Hv % Hk:
@@ -414,49 +460,99 @@ class LlamaConfig:
                     f"linear_conv_kernel_dim {self.linear_conv_kernel_dim} "
                     f"and linear_chunk_size {self.linear_chunk_size}: at "
                     f"least one tap and one position a chunk")
+        if KDA in self.kinds:
+            if self.short_conv_kernel_size < 1 or self.linear_chunk_size < 1:
+                raise ValueError(
+                    f"short_conv_kernel_size {self.short_conv_kernel_size} "
+                    f"and linear_chunk_size {self.linear_chunk_size}: at "
+                    f"least one tap and one position a chunk")
+            if not -5.5 <= self.kda_lower_bound < 0.0:
+                raise ValueError(
+                    f"kda_lower_bound {self.kda_lower_bound}: a log-decay a "
+                    f"position in [-5.5, 0), what the chunked rule's blocks "
+                    f"of 16 positions hold in float32")
+            if not self.kda_safe_gate:
+                raise NotImplementedError(
+                    "kda_safe_gate=False (the decay -exp(A_log) softplus(.), "
+                    "unbounded below) with a kda_attention layer: the "
+                    "chunked rule under a decay a key channel is written "
+                    "for a bounded gate (kda_lower_bound)")
+            if self.attn_impl in ("ring", "ulysses"):
+                raise NotImplementedError(
+                    f"a kda_attention layer under sequence parallelism "
+                    f"(attn_impl {self.attn_impl!r}): the delta rule's state "
+                    f"runs along a whole row, on one device")
+        # what neither linear-state mixer has yet
+        for kind, state in ((LINEAR, "a Gated DeltaNet's recurrent state "
+                             "and its filter's tail"),
+                            (KDA, "Kimi Delta Attention's recurrent state "
+                             "and its filters' tails")):
+            if kind not in self.kinds:
+                continue
             if self.decode:
                 raise NotImplementedError(
-                    "decode=True with a linear_attention layer "
-                    "(layer_types): the cache holds keys and values, and a "
-                    "Gated DeltaNet's recurrent state and its filter's tail "
-                    "are no leaves of it yet")
+                    f"decode=True with a {kind} layer (layer_types): the "
+                    f"cache holds keys and values, and {state} are no "
+                    f"leaves of it yet")
             if self.diffusion is not None:
                 raise NotImplementedError(
-                    "diffusion (block-diffusion training) with a "
-                    "linear_attention layer: the state would run across "
-                    "the two halves [noisy ; clean] and no block mask is "
-                    "written for it")
-            if self.mla_fields:
-                raise NotImplementedError(
-                    "latent attention with a linear_attention layer "
-                    "(layer_types): LlamaLatentAttention takes no layer "
-                    "type")
+                    f"diffusion (block-diffusion training) with a {kind} "
+                    f"layer: the state would run across the two halves "
+                    f"[noisy ; clean] and no block mask is written for it")
             if self.scan_layers:
                 raise NotImplementedError(
-                    "scan_layers=True with a linear_attention layer "
-                    "(layer_types): scan_layers scans one kind of block; "
-                    "set scan_layers=False (the stack is then unrolled)")
+                    f"scan_layers=True with a {kind} layer (layer_types): "
+                    f"scan_layers scans one kind of block; set "
+                    f"scan_layers=False (the stack is then unrolled)")
+        clamped = sorted(
+            (i, name) for name in ("expert_swiglu_limit_list",
+                                   "share_expert_swiglu_limit_list")
+            for i, x in enumerate((getattr(self, name) or ())[
+                :self.num_hidden_layers]) if x)
+        if clamped:
+            i, name = clamped[0]
+            raise NotImplementedError(
+                f"{name}[{i}] = {getattr(self, name)[i]}: a clamp inside the "
+                f"experts' SwiGLU of layer {i} whose form the config does "
+                f"not give; only zeros within the {self.num_hidden_layers} "
+                f"layers built are taken")
         if not 0.0 < self.partial_rotary_factor <= 1.0 \
                 or self.rotary_dim % 2:
             raise ValueError(
                 f"partial_rotary_factor {self.partial_rotary_factor} of "
                 f"head_dim {self.head_dim}: a share in (0, 1] that leaves "
                 f"an even number of channels")
-        if self.partial_rotary_factor != 1.0 and (self.rope_interleave
-                                                  or self.mla_fields):
+        # under latent attention the factor can only restate the rope
+        # channels' share of head_dim: nothing partial is left to do
+        if self.partial_rotary_factor != 1.0 and (
+                self.rope_interleave if not self.kv_lora_rank
+                else self.rotary_dim != self.qk_rope_head_dim):
             raise NotImplementedError(
-                "partial_rotary_factor with rope_interleave or latent "
-                "attention: the partial rotation is written for half-split "
-                "pairs of grouped-query attention")
+                "partial_rotary_factor with rope_interleave, or with latent "
+                "attention where it is not qk_rope_head_dim / head_dim: the "
+                "partial rotation is written for half-split pairs of "
+                "grouped-query attention")
         if self.mla_fields and not all(getattr(self, f) for f in _MLA_WIDTHS):
             raise ValueError(
-                f"latent attention takes {', '.join(_MLA_WIDTHS)} "
-                f"together; set: {', '.join(self.mla_fields)}")
-        if self.mla_fields and (self.qk_norm or self.attn_gate
-                                or self.per_layer_type):
+                f"latent attention takes {', '.join(_MLA_WIDTHS)} together "
+                f"(q_lora_rank beside them, or None for no query latent); "
+                f"set: {', '.join(self.mla_fields)}")
+        if self.mla_fields and (
+                self.qk_norm or self.attn_gate is True
+                or SLIDING in self.kinds or self.rope_parameters is not None
+                or self.rope_layer_types is not None):
             raise NotImplementedError(
-                "latent attention with qk_norm, attn_gate, layer_types or "
-                "rope_parameters: none of them is written for it")
+                "latent attention with qk_norm, a gate a channel "
+                "(attn_gate=True; 'head' is written), a sliding window, "
+                "rope_parameters or rope_layer_types: none of them is "
+                "written for it")
+        if self.attn_gate not in (False, True, "head"):
+            raise ValueError(f"attn_gate is False, True (a gate a channel) "
+                             f"or 'head', got {self.attn_gate!r}")
+        if self.attn_gate == "head" and not self.kv_lora_rank:
+            raise NotImplementedError(
+                "attn_gate='head' (one gate a head) without latent "
+                "attention: grouped-query attention's gate is as wide as q")
         if self.num_nextn_predict_layers not in (0, 1):
             raise NotImplementedError(
                 f"num_nextn_predict_layers "
@@ -580,8 +676,13 @@ class LlamaConfig:
     @property
     def mla_fields(self) -> tuple:
         """The latent attention's fields that are set, by name."""
-        return tuple(f for f in _MLA_WIDTHS + ("rope_interleave",)
-                     if getattr(self, f))
+        return tuple(f for f in ("q_lora_rank",) + _MLA_WIDTHS
+                     + ("rope_interleave",) if getattr(self, f))
+
+    @property
+    def mtp_blocks(self) -> int:
+        """Prediction blocks that are built: those the loss weighs."""
+        return self.num_nextn_predict_layers if self.mtp_loss_weight else 0
 
     @property
     def mtp_fields(self) -> tuple:
@@ -895,7 +996,13 @@ class LlamaLatentAttention(nn.Module):
     fixed permutation of the released ``(H, nope + rope)`` and ``(H, nope +
     v)`` orders.  So q_nope, k_nope and v leave their matmuls as the ``(B,
     S, H·128)`` rows the two-product flash kernels read, one head a lane
-    block, and nothing is split, transposed or padded in between."""
+    block, and nothing is split, transposed or padded in between.
+
+    ``q_lora_rank=None`` (Ling 3.0): the queries have no latent, ``[q_nope
+    | q_rope] = x W_q`` is the one leaf ``q_proj`` in the same column
+    order.  ``attn_gate="head"``: each head's output times ``sigmoid(x
+    W_gate)`` of its own column of ``gate_proj`` (E, H), before
+    ``o_proj``."""
     cfg: LlamaConfig
 
     @nn.compact
@@ -914,11 +1021,15 @@ class LlamaLatentAttention(nn.Module):
                 init, names), shape, cfg.param_dtype).astype(cfg.dtype)
 
         with trace.device_span("attn/mla_q"):
-            c_q = RMSNorm(cfg, axis="latent", name="q_a_layernorm")(
-                jnp.dot(x, weight("q_a_proj", ("embed", "latent"),
-                                  (E, cfg.q_lora_rank))))
-            w_qb = weight("q_b_proj", ("latent", "qkv"),
-                          (cfg.q_lora_rank, H * (Dn + Dr)))
+            if cfg.q_lora_rank:
+                c_q = RMSNorm(cfg, axis="latent", name="q_a_layernorm")(
+                    jnp.dot(x, weight("q_a_proj", ("embed", "latent"),
+                                      (E, cfg.q_lora_rank))))
+                w_qb = weight("q_b_proj", ("latent", "qkv"),
+                              (cfg.q_lora_rank, H * (Dn + Dr)))
+            else:
+                c_q = x
+                w_qb = weight("q_proj", ("embed", "qkv"), (E, H * (Dn + Dr)))
             # two products of one leaf: each lands where its reader wants it
             q_nope = jnp.dot(c_q, w_qb[:, :H * Dn])
             q_rope = jnp.dot(c_q, w_qb[:, H * Dn:])
@@ -943,6 +1054,12 @@ class LlamaLatentAttention(nn.Module):
                 scale=(Dn + Dr) ** -0.5, impl=cfg.attn_impl,
                 q_rope=q_rope.reshape(B, S, H, Dr),
                 k_rope=k_rope.reshape(B, S, 1, Dr))
+        if cfg.attn_gate == "head":
+            with trace.device_span("attn/mla_gate"):
+                gate = jax.nn.sigmoid(jnp.dot(
+                    x, weight("gate_proj", ("embed", None), (E, H)),
+                    preferred_element_type=jnp.float32))
+                y = (y.reshape(B, S, H, Dv) * gate[..., None]).astype(y.dtype)
         return jnp.dot(y.reshape(B, S, H * Dv),
                        weight("o_proj", ("heads", "embed"), (H * Dv, E)))
 
@@ -980,7 +1097,9 @@ class GatedDeltaNet(nn.Module):
     """The Qwen3-Next and Olmo-Hybrid families' token mixer of a
     ``"linear_attention"`` layer (released code: ``Qwen3NextGatedDeltaNet``;
     fla's ``GatedDeltaNet``), Hk key heads of dk channels and Hv value heads
-    of dv (128 and 128; 96 and 192)::
+    of dv (128 and 128; 96 and 192), the delta rule under ONE log-decay a
+    value head a position (the Ling 3.0 family's decay a key channel is
+    :class:`KimiDeltaAttention`'s)::
 
         [q | k | v | z] = h W_qkvz      # Hk dk | Hk dk | Hv dv | Hv dv, contiguous
         [b | a]         = h W_ba        # Hv | Hv
@@ -1061,6 +1180,97 @@ class GatedDeltaNet(nn.Module):
                           cfg=cfg, name="out_proj", module=self)
 
 
+class KimiDeltaAttention(nn.Module):
+    """The Ling 3.0 / Kimi Linear families' token mixer of a
+    ``"kda_attention"`` layer (arXiv:2510.26692; fla's
+    ``KimiDeltaAttention`` under ``no_kda_lora``: both gates' projections
+    full rank), H = ``num_attention_heads`` heads of d = ``head_dim``
+    channels, keys and values alike (a state is d x d)::
+
+        [q | k | v] = silu(filter(h [W_q | W_k | W_v]))     # ops/short_conv.py
+        q <- q / |q| * d^-1/2;  k <- k / |k|                # a head, eps 1e-6
+        g = kda_lower_bound * sigmoid(exp(A_log) (h W_f + dt_bias))
+        beta = sigmoid(h W_b)                               # a head
+        o = gated_delta_rule(q, k, v, g, beta)          # ops/gated_delta.py
+        y = (o * rsqrt(mean(o^2) + eps) * w_o) * sigmoid(h W_g)     # a head
+        out = y W_o
+
+    ``g`` is a log-decay a KEY CHANNEL, (B, S, H, d) in (kda_lower_bound,
+    0), float32: ``A_log`` is a number a head, ``dt_bias`` one a channel.
+    What it shares with :class:`GatedDeltaNet`: the filter
+    (``causal_conv_rows``), the heads' norms (``ops/gated_delta.py
+    normalised_heads`` / ``gated_norm``) and the rule.  ``q_proj``,
+    ``k_proj`` and ``v_proj`` are three leaves under the released names,
+    read as ONE product (their columns side by side) so that the filter and
+    the rule read ``[q | k | v]`` rows as GatedDeltaNet's do; the three
+    filters' taps are the one leaf ``conv_kernel`` (3 H d, taps), q's rows,
+    then k's, then v's: a concatenation a loader makes.  No positional
+    encoding, no bias; every row of a batch starts from a zero state and
+    empty filters.  The sigmoid gate keeps the output norm on XLA's lines
+    (the row kernel multiplies by ``silu``):
+    ``kernel_dispatch_total{site="gated_norm_rows"}`` says so."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops import gated_delta
+        from ..ops.short_conv import causal_conv_rows
+
+        cfg = self.cfg
+        B, S, E = x.shape
+        H, d = cfg.num_attention_heads, cfg.head_dim
+        W = H * d
+        init = nn.initializers.normal(cfg.initializer_range)
+        f32 = jnp.float32
+
+        def weight(name, names, shape):
+            return self.param(name + "_kernel", nn.with_partitioning(
+                init, names), shape, cfg.param_dtype).astype(cfg.dtype)
+
+        with trace.device_span("linear_attn/in_proj"):
+            qkv = jnp.dot(x, jnp.concatenate(
+                [weight(n, ("embed", "qkv"), (E, W))
+                 for n in ("q_proj", "k_proj", "v_proj")], axis=1))
+            z = jnp.dot(x, weight("g_proj", ("embed", "qkv"), (E, W)))
+            b = jnp.dot(x, weight("b_proj", ("embed", None), (E, H)),
+                        preferred_element_type=f32)
+        taps = self.param("conv_kernel", nn.with_partitioning(
+            init, ("heads", None)), (3 * W, cfg.short_conv_kernel_size),
+            cfg.param_dtype)
+        with trace.device_span("linear_attn/conv"):
+            qkv = causal_conv_rows(qkv, taps, activation="silu")
+        # the released layer's: A_log = log(U(1, 16)) a head; dt_bias the
+        # inverse softplus of dt ~ logU(1e-3, 1e-1) a channel
+        a_log = self.param("A_log", nn.with_partitioning(
+            lambda key, shape, dtype: jnp.log(jax.random.uniform(
+                key, shape, dtype, 1.0, 16.0)), ("heads",)), (H,), f32)
+
+        def dt_init(key, shape, dtype):
+            dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                         * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+            return dt + jnp.log(-jnp.expm1(-dt))
+
+        dt_bias = self.param("dt_bias", nn.with_partitioning(
+            dt_init, ("heads",)), (W,), f32)
+        with trace.device_span("linear_attn/decay_gate"):
+            f = jnp.dot(x, weight("f_proj", ("embed", "qkv"), (E, W)))
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(
+                jnp.repeat(jnp.exp(a_log), d)
+                * (f.astype(f32) + dt_bias)).reshape(B, S, H, d)
+        with trace.device_span("linear_attn/delta_rule"):
+            heads = gated_delta.normalised_heads(qkv, H, d, H, d,
+                                                 cfg.linear_chunk_size)
+            o = gated_delta.heads_rule(heads, g, jax.nn.sigmoid(b))
+        w_o = self.param("o_norm", nn.with_partitioning(
+            nn.initializers.ones, ("head_dim",)), (d,), cfg.param_dtype)
+        with trace.device_span("linear_attn/gated_norm"):
+            y = gated_delta.gated_norm(heads, o, z, w_o, cfg.rms_norm_eps,
+                                       gate="sigmoid")
+        with trace.device_span("linear_attn/out_proj"):
+            return jnp.dot(y.reshape(B, S, W),
+                           weight("o_proj", ("heads", "embed"), (W, E)))
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
@@ -1117,6 +1327,8 @@ class LlamaBlock(nn.Module):
             attn = ShortConv(cfg, name="conv")(h)
         elif self.kind == LINEAR:
             attn = GatedDeltaNet(cfg, name="linear_attn")(h)
+        elif self.kind == KDA:
+            attn = KimiDeltaAttention(cfg, name="kda_attn")(h)
         else:
             self_attn = LlamaLatentAttention(cfg, name="self_attn") \
                 if cfg.kv_lora_rank \
@@ -1315,7 +1527,7 @@ class LlamaForCausalLM(nn.Module):
                     *per_layer[cfg.num_dense_layers:])
 
         h_mtp = None
-        if cfg.num_nextn_predict_layers and labels is not None:
+        if cfg.mtp_blocks and labels is not None:
             # the stack's output BEFORE the final norm, beside the next
             # token's embedding (the last position has none: its label is
             # ignored below, so what it embeds is never read)
@@ -1539,11 +1751,13 @@ class LlamaForCausalLM(nn.Module):
         if cfg.kv_lora_rank:        # latent attention: five projections
             Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-            attn = (E * cfg.q_lora_rank + cfg.q_lora_rank * H * (Dn + Dr)
-                    + E * (cfg.kv_lora_rank + Dr)
-                    + cfg.kv_lora_rank * H * (Dn + Dv) + H * Dv * E)
+            Rq = cfg.q_lora_rank
+            attn = ((E * Rq + Rq * H * (Dn + Dr)) if Rq
+                    else E * H * (Dn + Dr)) + E * (cfg.kv_lora_rank + Dr) \
+                + cfg.kv_lora_rank * H * (Dn + Dv) + H * Dv * E \
+                + (E * H if cfg.attn_gate == "head" else 0)
             score = Dn + Dr + Dv
-        mtp = cfg.num_nextn_predict_layers   # a block, eh_proj, the head
+        mtp = cfg.mtp_blocks        # a block, eh_proj, the head
         # a conv layer's mixer: in_proj to three thirds, out_proj, the taps
         convs = cfg.kinds.count(CONV)
         conv = 3 * E * E + E * E + E * cfg.conv_L_cache
@@ -1556,9 +1770,14 @@ class LlamaForCausalLM(nn.Module):
         conv_dim = 2 * Hk * dk + Hv * d
         linear = (E * (conv_dim + Hv * d) + E * 2 * Hv
                   + conv_dim * cfg.linear_conv_kernel_dim + Hv * d * E)
+        # a kda_attention layer's: q, k, v, both gates' and the output
+        # projection, beta's, the taps; the recurrence as above
+        kdas = cfg.kinds.count(KDA)
+        kda = (6 * E * H * D + E * H + 3 * H * D * cfg.short_conv_kernel_size
+               + 3 * H * D * D)
         table = (1 if cfg.tie_word_embeddings else 2) * cfg.padded_vocab_size
-        n = (table * E + (L - convs - linears) * attn + convs * conv
-             + linears * (linear + 3 * Hv * dk * d)
+        n = (table * E + (L - convs - linears - kdas) * attn + convs * conv
+             + linears * (linear + 3 * Hv * dk * d) + kdas * kda
              + cfg.num_dense_layers * dense
              + (L - cfg.num_dense_layers) * ffn
              + mtp * (attn + ffn + 2 * E * E + cfg.padded_vocab_size * E))

@@ -1,8 +1,15 @@
-"""The gated delta rule over a sequence (Gated DeltaNet, arXiv:2412.06464;
-the token mixer of three layers in four of ``model_type: qwen3_next`` and
-``olmo_hybrid``): a state a value head, ``S`` (keys x values, ``dk x dv``:
-128 x 128 in the first, 96 x 192 in the second), carried ALONG the
-sequence,
+"""The gated delta rule over a sequence: a state a value head, ``S`` (keys
+x values, ``dk x dv``), carried ALONG the sequence.  Which family runs
+which decay: **one log-decay a head a position**, ``g`` (B, S, Hv) - Gated
+DeltaNet (arXiv:2412.06464), the token mixer of three layers in four of
+``model_type: qwen3_next`` (states 128 x 128) and ``olmo_hybrid`` (96 x
+192), ``models/llama.py GatedDeltaNet``; **one a KEY CHANNEL**, ``g`` (B,
+S, Hv, dk) - Kimi Delta Attention (arXiv:2510.26692), five layers in six of
+``model_type: bailing_hybrid`` (Ling 3.0; 128 x 128), ``models/llama.py
+KimiDeltaAttention``.  One op: the second is the first with ``exp(g_t)``
+read as ``Diag(exp(g_t))``, and with every channel of a head equal it IS
+the first (``tests/unit/test_gated_delta.py`` holds the two to each
+other).  Under a decay a head,
 
     S_t = exp(g_t) S_{t-1} + beta_t k_t (v_t - exp(g_t) S_{t-1}^T k_t)^T
     o_t = S_t^T q_t                                   S_0 = 0 at every row
@@ -36,6 +43,26 @@ catastrophically in float32 once keys correlate (powers of a 64 x 64 ``A``
 with entries near 1 reach 1e18).  Decays, ``gamma``, the solve and ``S``
 are float32; the operands of the large products have the type the call
 arrived in (float32 accumulation).
+
+**Under a decay a key channel** ``gamma`` is a vector a position and
+``K K^T * G`` is no product masked by a ``C x C`` matrix any more: ``A_ij =
+beta_i sum_c k_ic k_jc exp(gamma_ic - gamma_jc)``, i.e. ``(K exp(gamma)) (K
+exp(-gamma))^T`` - whose second factor overflows float32 within a chunk
+(``gamma`` reaches ``-5 x 64``).  :func:`_channel_products` forms it, and
+``Q K^T * G`` beside it, a block of :data:`SOLVE_BLOCK` = 16 rows at a time
+against that block's FIRST row ``r``: ``exp(gamma_i - gamma_r) <= 1`` on
+the rows' side, ``exp(gamma_r - gamma_j)`` on the keys' (at most 1 for the
+keys of earlier blocks, at most ``exp(16 x 5)`` inside the block: the
+caller's gate is bounded below, ``LlamaConfig.kda_lower_bound`` >= -5.5).
+Everything else is the text above with ``exp(gamma)`` a ``(C, dk)`` array:
+``W = ... K * exp(gamma)``, ``Q * exp(gamma)``, ``K * exp(gamma_C - gamma)``
+(all factors <= 1; what underflows is a decay of ``e^-87`` and contributes
+nothing), ``S <- Diag(exp(gamma_C)) S + ...``.  It runs as XLA's program
+under either ``impl`` but ``"pallas"``, which raises: the kernels take ``(B,
+Hk, 8, S)`` of gamma, one number a head a position
+(``kernel_dispatch_total{site="gated_delta"}`` says ``xla`` with ``a decay a
+key channel (...)``); the gauge ``gated_delta_decay_channels`` is 1 or
+``dk`` by which form a traced pass ran.
 
 One ``custom_vjp``: the forward keeps its five inputs and nothing else, so
 a block's ``dots_saveable`` policy sees none of the inner products; the
@@ -107,7 +134,13 @@ def _note_chunks(pass_: str, n: int) -> None:
         labelnames=("pass",)).labels(pass_).inc(n)
 
 
-def _note_state(dk: int, dv: int) -> None:
+def _note_state(dk: int, dv: int, decay_channels: int) -> None:
+    _registry.gauge(
+        "gated_delta_decay_channels",
+        "log-decays a head a position of the gated delta rule that a traced "
+        "pass ran: 1 (a decay a head: Gated DeltaNet) or the key head's "
+        "channels (a decay a key channel: Kimi Delta Attention; set at trace "
+        "time)").set(decay_channels)
     _registry.gauge(
         "gated_delta_state_elems",
         "elements of one state (keys x values) of the gated delta rule that "
@@ -152,14 +185,44 @@ def _solve_unit_lower(a: jax.Array, rhs: jax.Array) -> jax.Array:
     return jnp.concatenate(xs, axis=-2)
 
 
+def _channel_products(q_, k_, gamma, cdt):
+    """``sum_c x_ic k_jc exp(gamma_ic - gamma_jc)`` for ``x`` = ``k_`` and
+    ``q_`` (B, Hv, N, C, dk) under a decay a key channel, ``gamma`` (B, Hv,
+    N, C, dk) float32: two (B, Hv, N, C, C) float32, right where ``i >= j``
+    (above the diagonal they hold finite numbers that the caller masks).
+    A block of :data:`SOLVE_BLOCK` rows ``i`` is formed against its OWN
+    first row ``r``, ``exp(gamma_i - gamma_r)`` on its side and
+    ``exp(gamma_r - gamma_j)`` on the keys' (the module's text has why), so
+    a log-decay below ``-88 / 16 = -5.5`` a position is not this op's."""
+    f32 = jnp.float32
+    C = gamma.shape[-2]
+    b = min(SOLVE_BLOCK, C)
+    kk, qk = [], []
+    for lo in range(0, C, b):
+        hi = lo + b
+        ref = gamma[..., lo:lo + 1, :]
+        near = jnp.exp(gamma[..., lo:hi, :] - ref)
+        far = (k_[..., :hi, :].astype(f32)
+               * jnp.exp(ref - gamma[..., :hi, :])).astype(cdt)
+        for x, rows in ((k_, kk), (q_, qk)):
+            prod = jnp.einsum(
+                "bhnid,bhnjd->bhnij",
+                (x[..., lo:hi, :].astype(f32) * near).astype(cdt), far,
+                preferred_element_type=f32)
+            rows.append(jnp.pad(prod, [(0, 0)] * 4 + [(0, C - hi)]))
+    return jnp.concatenate(kk, axis=-2), jnp.concatenate(qk, axis=-2)
+
+
 def _prepare(q, k, v, g, beta, chunk: int, key_heads=None):
     """What of the chunked form depends on no state, for every chunk at
-    once: ``(u, w, p, qg, kd, g_last)`` as ``(B, Hv, N, C, .)`` (``g_last``
-    ``(B, Hv, N)``), ``u`` and ``g_last`` float32, the others in ``v``'s
-    type: ``U``, ``W``, ``lower_incl(Q K^T * G)``, ``Q * exp(gamma)``, ``K *
-    exp(gamma_C - gamma)`` and ``exp(gamma_C)`` of the module's text."""
+    once: ``(u, w, p, qg, kd, g_last)`` as ``(B, Hv, N, C, .)``, ``u`` and
+    ``g_last`` float32, the others in ``v``'s type: ``U``, ``W``,
+    ``lower_incl(Q K^T * G)``, ``Q * exp(gamma)``, ``K * exp(gamma_C -
+    gamma)`` and ``exp(gamma_C)`` of the module's text; ``g_last`` is ``(B,
+    Hv, N)`` under a decay a head and ``(B, Hv, N, dk)`` under a decay a
+    key channel (``g`` (B, S, Hv, dk))."""
     f32 = jnp.float32
-    B, S, Hv = g.shape
+    B, S, Hv = g.shape[:3]
     dv = v.shape[-1] // Hv
     Hk = key_heads or k.shape[-1] // dv
     C, N = chunk, S // chunk
@@ -176,27 +239,46 @@ def _prepare(q, k, v, g, beta, chunk: int, key_heads=None):
               for x in (q, k))
     v_ = heads(v, Hv)
     beta_ = per_head(beta)
-    gamma = jnp.cumsum(per_head(g), axis=-1)
-    idx = jnp.arange(C)
-    lower = idx[:, None] >= idx[None, :]
-    # exp of a masked difference: above the diagonal the difference is
-    # positive and could overflow before a mask multiplies it away
-    decay = jnp.exp(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :],
-                              -jnp.inf))
-    kk = jnp.einsum("bhnid,bhnjd->bhnij", k_, k_, preferred_element_type=f32)
-    a = jnp.where(idx[:, None] > idx[None, :],
-                  beta_[..., None] * kk * decay, 0.0)
-    e_gamma = jnp.exp(gamma)[..., None]
+    if g.ndim == 4:                 # a decay a key channel
+        gamma = jnp.cumsum(heads(g.astype(f32).reshape(B, S, -1), Hv),
+                           axis=-2)
+        idx = jnp.arange(C)
+        kk, qk = _channel_products(q_, k_, gamma, cdt)
+        a = jnp.where(idx[:, None] > idx[None, :], beta_[..., None] * kk, 0.0)
+        e_gamma = jnp.exp(gamma)
+
+        # lower_incl(Q K^T * G), the decays to the chunk's end, the whole's
+        rest = (lambda: jnp.where(idx[:, None] >= idx[None, :], qk, 0.0),
+                lambda: jnp.exp(gamma[..., -1:, :] - gamma),
+                lambda: jnp.exp(gamma[..., -1, :]))
+    else:                           # a decay a head
+        gamma = jnp.cumsum(per_head(g), axis=-1)
+        idx = jnp.arange(C)
+        lower = idx[:, None] >= idx[None, :]
+        # exp of a masked difference: above the diagonal the difference is
+        # positive and could overflow before a mask multiplies it away
+        decay = jnp.exp(jnp.where(
+            lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+        kk = jnp.einsum("bhnid,bhnjd->bhnij", k_, k_,
+                        preferred_element_type=f32)
+        a = jnp.where(idx[:, None] > idx[None, :],
+                      beta_[..., None] * kk * decay, 0.0)
+        e_gamma = jnp.exp(gamma)[..., None]
+
+        rest = (lambda: jnp.einsum("bhnid,bhnjd->bhnij", q_, k_,
+                                   preferred_element_type=f32) * decay,
+                lambda: jnp.exp(gamma[..., -1:] - gamma)[..., None],
+                lambda: jnp.exp(gamma[..., -1]))
     rhs = jnp.concatenate([v_.astype(f32), k_.astype(f32) * e_gamma],
                           axis=-1) * beta_[..., None]
     uw = _solve_unit_lower(a, rhs)
     u, w = uw[..., :dv], uw[..., dv:].astype(cdt)
-    p = (jnp.einsum("bhnid,bhnjd->bhnij", q_, k_, preferred_element_type=f32)
-         * decay).astype(cdt)
+    # (thunks: each is traced where the decay a head's form always stood)
+    p, to_end, g_last = rest
+    p = p().astype(cdt)
     qg = (q_.astype(f32) * e_gamma).astype(cdt)
-    kd = (k_.astype(f32)
-          * jnp.exp(gamma[..., -1:] - gamma)[..., None]).astype(cdt)
-    return u, w, p, qg, kd, jnp.exp(gamma[..., -1])
+    kd = (k_.astype(f32) * to_end()).astype(cdt)
+    return u, w, p, qg, kd, g_last()
 
 
 def _scan_xla(u, w, p, qg, kd, g_last):
@@ -213,7 +295,9 @@ def _scan_xla(u, w, p, qg, kd, g_last):
         v_new = u_n - mm("bhcd,bhde->bhce", w_n, state)
         o_n = mm("bhcd,bhde->bhce", qg_n, state) \
             + mm("bhic,bhce->bhie", p_n, v_new)
-        state = gl_n[..., None, None] * state \
+        # a decay a head (B, Hv), or a key channel (B, Hv, dk)
+        state = (gl_n[..., None, None] if gl_n.ndim == 2
+                 else gl_n[..., None]) * state \
             + mm("bhcd,bhce->bhde", kd_n, v_new)
         return state, o_n.astype(cdt)
 
@@ -225,7 +309,7 @@ def _scan_xla(u, w, p, qg, kd, g_last):
 
 def _chunked(q, k, v, g, beta, chunk: int, key_heads=None):
     """The module's chunked form by XLA; shapes as :func:`gated_delta_rule`."""
-    B, S, Hv = g.shape
+    B, S = g.shape[:2]
     o = _scan_xla(*_prepare(q, k, v, g, beta, chunk, key_heads))
     return o.transpose(0, 2, 3, 1, 4).reshape(B, S, v.shape[-1])
 
@@ -301,8 +385,10 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """``o`` (B, S, Hv*dv) of the gated delta rule: ``q``, ``k`` (B, S,
     Hk*dk) normalised and scaled by the caller, ``v`` (B, S, Hv*dv), ``g``
-    (B, S, Hv) float32 log-decays (<= 0), ``beta`` (B, S, Hv), as a layer's
-    projections and filter wrote them.  ``key_heads`` is ``Hk``; None where
+    float32 log-decays (<= 0): (B, S, Hv), one a head, or (B, S, Hv, dk),
+    one a key channel (then at least -5.5 each: the module's text),
+    ``beta`` (B, S, Hv), as a layer's projections and filter wrote them.
+    ``key_heads`` is ``Hk``; None where
     key and value heads are as wide (``dk = dv``), which then says it.
     Each row of the batch starts from a zero state; ``S`` is a multiple of
     ``chunk``.  See the module's text."""
@@ -322,15 +408,16 @@ def _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret,
 
     if impl not in IMPLS:
         raise ValueError(f"gated_delta impl {impl!r}: one of {IMPLS}")
-    B, S, Hv = g.shape
-    if beta.shape != g.shape or v.ndim != 3 or v.shape[-1] % Hv \
-            or q.shape != k.shape \
+    B, S, Hv = beta.shape if beta.ndim == 3 else (0, 0, 1)
+    if g.ndim not in (3, 4) or g.shape[:3] != (B, S, Hv) or v.ndim != 3 \
+            or v.shape[-1] % Hv or q.shape != k.shape \
             or k.shape[-1] % (key_heads or v.shape[-1] // Hv) \
             or q.shape[:2] != (B, S) or v.shape[:2] != (B, S):
         raise ValueError(
             f"gated_delta_rule takes q, k (B, S, Hk*dk), v (B, S, Hv*dv), g "
-            f"and beta (B, S, Hv), got {q.shape}, {k.shape}, {v.shape}, "
-            f"{g.shape}, {beta.shape} at key_heads {key_heads}")
+            f"(B, S, Hv) or (B, S, Hv, dk) and beta (B, S, Hv), got "
+            f"{q.shape}, {k.shape}, {v.shape}, {g.shape}, {beta.shape} at "
+            f"key_heads {key_heads}")
     Hk = key_heads or k.shape[-1] // (v.shape[-1] // Hv)
     if Hv % Hk:
         raise ValueError(f"{Hv} value heads are no multiple of {Hk} key "
@@ -344,8 +431,14 @@ def _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret,
         raise ValueError(f"k {k.shape} and v {v.shape} are no {Hk} and {Hv} "
                          f"lane slots of heads of {slots} channels")
     of = "" if dk == dv else f"of {dk} "
+    channels = g.ndim == 4
+    if channels and g.shape[3] != dk:
+        raise ValueError(f"a decay a key channel is (B, S, Hv, dk = {dk}), "
+                         f"got g {g.shape}")
     plan = spmd.plan(
         "gated_delta", B, "impl='xla' asked for" if impl == "xla"
+        else f"a decay a key channel ({Hv} heads x {dk}): the kernels take "
+             f"one decay a head" if channels
         else kernels_refusal(S, chunk, Hk, Hv, dk, dv, v.dtype),
         f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}x {Hv // Hk} "
         f"value heads of {dv}, fused", tpu=impl == "auto",
@@ -354,7 +447,7 @@ def _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret,
         raise NotImplementedError(
             "gated_delta_rule: slotted operands are the kernels' layout, "
             "and XLA's form runs")
-    _note_state(dk, dv)
+    _note_state(dk, dv, dk if channels else 1)
     fused = interpret if plan is not None else None
     # under the name a ``+flash`` remat policy keeps (``models/common.py
     # resolve_remat_policy``): the output is no dot output, and recomputed it
@@ -450,7 +543,7 @@ def heads_rule(heads: MixerHeads, g: jax.Array, beta: jax.Array, *,
     """:func:`gated_delta_rule` of ``heads``: ``o`` (B, S, Hv dv) rows, or
     in the slots the heads lie in."""
     Hk, dk, _, dv = heads.widths
-    B, S, _ = g.shape
+    B, S = g.shape[:2]
     return _dispatch(
         heads.q.reshape(B, S, -1), heads.k.reshape(B, S, -1),
         heads.rows[..., 2 * Hk * dk:] if heads.v is None else heads.v, g,
@@ -459,12 +552,25 @@ def heads_rule(heads: MixerHeads, g: jax.Array, beta: jax.Array, *,
 
 
 def gated_norm(heads: MixerHeads, o: jax.Array, z: jax.Array, w: jax.Array,
-               eps: float, *, interpret: bool = False) -> jax.Array:
+               eps: float, *, gate: str = "silu", interpret: bool = False
+               ) -> jax.Array:
     """``rms_norm(o, w, eps) * silu(z)`` a value head (norm first, gate
     second) of :func:`heads_rule`'s ``o`` and the gate's rows ``z`` (B, S,
-    Hv dv): ``y`` as rows, or (B, S, Hv, dv) off the kernels."""
+    Hv dv): ``y`` as rows, or (B, S, Hv, dv) off the kernels.  ``gate``
+    ``"sigmoid"`` (Kimi Delta Attention's) multiplies by ``sigmoid(z)``
+    instead, on the ``(B, S, H, d)`` float32 lines: the row kernel is
+    written for ``silu``."""
+    from .pallas import spmd
+
     _, dk, Hv, dv = heads.widths
-    plan = heads.plan if dk % 128 or dv % 128 else gated_norm_plan(o, dv)
+    if gate not in ("silu", "sigmoid"):
+        raise ValueError(f"gated_norm gate {gate!r}: 'silu' or 'sigmoid'")
+    if gate == "sigmoid":
+        plan = None
+        spmd.note_dispatch("gated_norm_rows", "xla", "a sigmoid gate: the "
+                           "row kernel multiplies by silu")
+    else:
+        plan = heads.plan if dk % 128 or dv % 128 else gated_norm_plan(o, dv)
     if plan is not None:
         return gated_norm_rows(o, z, w, dv, plan, eps=eps,
                                interpret=interpret)
@@ -472,8 +578,8 @@ def gated_norm(heads: MixerHeads, o: jax.Array, z: jax.Array, w: jax.Array,
     B, S, _ = o.shape
     of = o.reshape(B, S, Hv, dv).astype(f32)
     y = of * lax.rsqrt(jnp.mean(of ** 2, axis=-1, keepdims=True) + eps) * w
-    return (y * jax.nn.silu(z.reshape(B, S, Hv, dv).astype(f32))).astype(
-        o.dtype)
+    act = jax.nn.silu if gate == "silu" else jax.nn.sigmoid
+    return (y * act(z.reshape(B, S, Hv, dv).astype(f32))).astype(o.dtype)
 
 
 def gated_norm_plan(o: jax.Array, head_dim: int) -> Optional[tuple]:

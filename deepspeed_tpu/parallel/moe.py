@@ -122,6 +122,13 @@ class MoEConfig:
     # the shared SwiGLU times sigmoid(x . w_g): a leaf of model_dim, one
     # scalar a token (the Qwen3-Next family's shared_expert_gate)
     shared_expert_gate: bool = False
+    # group-limited routing (DeepSeek-V3's, under its config.json names):
+    # the ROUTED experts lie in ``n_group`` groups of neighbours, a group
+    # scores the sum of its two best selection scores, a token keeps its
+    # ``topk_group`` best groups and takes its top_k among their experts
+    # alone.  1 and 1: no limit, and nothing of it is traced
+    n_group: int = 1
+    topk_group: int = 1
 
     def __post_init__(self):
         if self.score_func not in ("softmax", "sigmoid"):
@@ -136,6 +143,19 @@ class MoEConfig:
         if self.shared_expert_gate and not self.num_shared_experts:
             raise ValueError("shared_expert_gate without a shared expert "
                              "(num_shared_experts)")
+        if not 1 <= self.topk_group <= self.n_group \
+                or self.routed % self.n_group:
+            raise ValueError(
+                f"n_group {self.n_group} and topk_group {self.topk_group}: "
+                f"the {self.routed} routed experts in whole groups, of which "
+                f"a token keeps 1 to n_group")
+        if self.n_group > 1 and (
+                self.top_k > self.routed // self.n_group * self.topk_group
+                or self.routed // self.n_group < 2):
+            raise ValueError(
+                f"top_k {self.top_k} of {self.topk_group} groups of "
+                f"{self.routed // self.n_group} experts: a group has at "
+                f"least two, the kept groups at least top_k")
         if self.routed_experts is None:
             if self.first_expert:
                 raise ValueError("first_expert without routed_experts")
@@ -164,7 +184,8 @@ class MoEConfig:
         return tuple(f for f, off in (
             ("score_func", "softmax"), ("route_scale", 1.0),
             ("bias_update_rate", None), ("num_shared_experts", 0),
-            ("norm_topk_eps", 0.0), ("shared_expert_gate", False))
+            ("norm_topk_eps", 0.0), ("shared_expert_gate", False),
+            ("n_group", 1))
             if getattr(self, f) != off)
 
 
@@ -267,12 +288,34 @@ def top2_gating(logits: jax.Array, capacity: int, rng=None,
     return l_aux, combine, dispatch
 
 
+def group_limit(scores: jax.Array, top_k: int, n_group: int, topk_group: int
+                ) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V3's group-limited selection over the selection ``scores``
+    (S, E) (score + bias; no gradient passes): ``(allowed (S, E) bool,
+    kept)``.  The E experts are ``n_group`` groups of E / n_group
+    neighbours; a group's score is the sum of its two best; ``allowed``
+    marks the experts of each token's ``topk_group`` best groups.  ``kept``
+    is the share of the (token, choice) pairs of the UNRESTRICTED top-k that
+    lie in an allowed group: 1 where the limit changed no choice.  Compares
+    and sums alone: no gather by index (``_count_ids``' reason)."""
+    S, E = scores.shape
+    scores = jax.lax.stop_gradient(scores)
+    best2, _ = jax.lax.top_k(scores.reshape(S, n_group, E // n_group), 2)
+    group_score = best2.sum(-1)                                 # (S, G)
+    cut = jax.lax.top_k(group_score, topk_group)[0][:, -1:]
+    # ties at the cut keep every tied group, as no released weights have them
+    allowed = jnp.repeat(group_score >= cut, E // n_group, axis=1)
+    kth = jax.lax.top_k(scores, top_k)[0][:, -1:]
+    kept = ((scores >= kth) & allowed).sum() / jnp.float32(S * top_k)
+    return allowed, kept
+
+
 def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
                  *, score_func: str = "softmax",
                  bias: Optional[jax.Array] = None, route_scale: float = 1.0,
-                 norm_eps: float = 0.0
-                 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array,
-                            jax.Array]:
+                 norm_eps: float = 0.0, n_group: int = 1,
+                 topk_group: int = 1, return_kept: bool = False
+                 ) -> Tuple[jax.Array, ...]:
     """Dropless top-k routing over float32 ``logits`` (S, E).
 
     Returns ``(weights (S, k), experts (S, k) int32, counts (E,) int32,
@@ -287,12 +330,20 @@ def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
     ``score_func="sigmoid"`` scores each expert on its own.  With ``bias``
     (E,) the k experts are those of the largest ``score + bias`` and the
     weights stay their scores: the bias picks, it does not weigh, and no
-    gradient reaches it.  ``route_scale`` multiplies the weights last."""
+    gradient reaches it.  ``route_scale`` multiplies the weights last.
+
+    ``n_group`` > 1: the k experts are taken among those that
+    :func:`group_limit` allows (of ``score + bias``); ``return_kept`` then
+    adds its ``kept`` share as a sixth result (1.0 without groups)."""
     S, E = logits.shape
     probs = jax.nn.softmax(logits, axis=-1) if score_func == "softmax" \
         else jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(
-        probs if bias is None else probs + jax.lax.stop_gradient(bias), top_k)
+    select = probs if bias is None else probs + jax.lax.stop_gradient(bias)
+    kept = jnp.float32(1.0)
+    if n_group > 1:
+        allowed, kept = group_limit(select, top_k, n_group, topk_group)
+        select = jnp.where(allowed, select, -jnp.inf)
+    _, experts = jax.lax.top_k(select, top_k)
     # each chosen expert's own score, as top_k's values or
     # take_along_axis(probs, experts) give it bit for bit (one term of a
     # sum is not zero), with no gather and, in the backward, no scatter-add
@@ -309,7 +360,8 @@ def topk_routing(logits: jax.Array, top_k: int, norm_topk_prob: bool = False,
     l_balance = E * jnp.sum(counts.astype(jnp.float32) / (S * top_k)
                             * probs.mean(axis=0))
     l_z = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-    return weights, experts.astype(jnp.int32), counts, l_balance, l_z
+    out = weights, experts.astype(jnp.int32), counts, l_balance, l_z
+    return out + (kept,) if return_kept else out
 
 
 # the leaf of a gate that is state and no parameter: the selection bias
@@ -610,10 +662,11 @@ class MoELayer(nn.Module):
                 raise ValueError(f"top_k must be in 1..{E}, got {k}")
             with trace.device_span("moe/route"):
                 logits, bias = gate(x2, train, logits_only=True)
-                weights, chosen, counts, l_aux, l_z = topk_routing(
+                weights, chosen, counts, l_aux, l_z, kept = topk_routing(
                     logits, k, cfg.norm_topk_prob, score_func=cfg.score_func,
                     bias=bias, route_scale=cfg.route_scale,
-                    norm_eps=cfg.norm_topk_eps)
+                    norm_eps=cfg.norm_topk_eps, n_group=cfg.n_group,
+                    topk_group=cfg.topk_group, return_kept=True)
             out = experts(x2, routing=(weights, chosen))
             if cfg.num_shared_experts:
                 with trace.device_span("moe/shared"):
@@ -664,6 +717,8 @@ class MoELayer(nn.Module):
             stats["elsewhere"] = elsewhere
         if bias is not None:
             stats[STATE_LEAF] = bias
+        if cfg.n_group > 1:
+            stats["group_kept_share"] = kept
         return out, aux, stats
 
 
@@ -681,6 +736,8 @@ def record_stats(stats: Dict[str, Any]) -> None:
     ``moe_pairs_elsewhere_total`` (pairs routed to experts that another
     instance holds: not dropped, not multiplied here); gauges
     ``moe_aux_loss`` / ``moe_router_z``, the layer means of the last step.
+    Under a group limit: gauge ``moe_group_kept_share{layer}`` from
+    ``group_kept_share`` (L,).
     With a bias: gauge ``moe_expert_bias{layer, stat=min|max}``, counter
     ``moe_bias_updates_total`` (one a layer a step: the engine applies
     :func:`bias_update` in the step that returned these statistics).
@@ -715,6 +772,15 @@ def record_stats(stats: Dict[str, Any]) -> None:
         registry.counter(
             "moe_bias_updates_total", "selection-bias updates the compiled "
             "step made, one a biased layer a step").inc(float(len(bias)))
+    if "group_kept_share" in stats:
+        kept = registry.gauge(
+            "moe_group_kept_share", "(token, choice) pairs of the "
+            "unrestricted top-k that the group limit (n_group, topk_group) "
+            "left in place, as a share of all pairs: 1 where it changed no "
+            "choice; last finished step", ("layer",))
+        for layer, share in enumerate(
+                np.asarray(stats["group_kept_share"]).reshape(-1)):
+            kept.labels(layer).set(float(share))
     registry.gauge("moe_aux_loss", "load-balancing loss, mean over layers, "
                    "last finished step").set(float(np.mean(stats["balance_loss"])))
     registry.gauge("moe_router_z", "router z-loss, mean over layers, last "

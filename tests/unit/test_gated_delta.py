@@ -482,3 +482,140 @@ def test_the_ungated_filter_against_a_loop_over_taps(L, activation):
         causal_conv_rows(jnp.asarray(x), jnp.asarray(w), "gelu")
     with pytest.raises(ValueError, match=r"\(B, S, C\) rows"):
         causal_conv_rows(jnp.asarray(x), jnp.asarray(w[:4]))
+
+
+# ----------------------------------------------------------------------
+# a decay a KEY CHANNEL (Kimi Delta Attention, PR 58): g (B, S, Hv, dk)
+# ----------------------------------------------------------------------
+kda = load_module(ROOT, "reference", "ling3")
+LOWER = -5.0            # kda_lower_bound: the deepest log-decay a position
+
+
+def _channel_inputs(B, S, Hk, Hv, d, seed=0, pinned=True):
+    """As :func:`_inputs` with ``g`` (B, S, Hv, d) in (LOWER, 0); ``pinned``:
+    over the WHOLE second chunk of 64 a quarter of the channels sit at the
+    bound and a quarter at 0 (``exp(Gamma)`` underflows float32 on the
+    first while ``exp(-Gamma)`` overflows it: a two-sided form's case),
+    and across the first chunk's edge half of them."""
+    q, k, v, _, beta = _inputs(B, S, Hk, Hv, d, seed)
+    rng = np.random.default_rng(seed + 100)
+    g = LOWER / (1.0 + np.exp(-2.0 * rng.standard_normal((B, S, Hv, d))))
+    if pinned:
+        g[:, 64:128, :, :d // 4] = LOWER
+        g[:, 64:128, :, d // 4:d // 2] = 0.0
+        g[:, 56:72, :, d // 2:] = LOWER
+    return q, k, v, jnp.asarray(g, jnp.float32), beta
+
+
+def _channel_recurrence(q, k, v, g, beta):
+    B, S, Hv, dk = g.shape
+    r = Hv // (k.shape[-1] // dk)
+    q, k = (jnp.repeat(x.reshape(B, S, -1, dk), r, axis=2) for x in (q, k))
+    return kda.kda_rule(q, k, v.reshape(B, S, Hv, -1), g,
+                        beta).reshape(v.shape)
+
+
+@pytest.mark.parametrize("Hk,Hv,dtype,tol", [
+    (2, 2, jnp.float32, 2e-5), (1, 2, jnp.float32, 2e-5),
+    (2, 2, jnp.bfloat16, 3e-2),
+])
+def test_a_decay_a_channel_is_the_recurrence_with_decays_at_the_bound(
+        Hk, Hv, dtype, tol):
+    """Two rows of three chunks of 64 (four solve blocks each): decays
+    pinned at the bound and at 0 over a whole chunk, across a chunk's edge,
+    and a second row that must start from zeros; forward and every
+    gradient."""
+    args = _channel_inputs(2, 192, Hk, Hv, 16)
+    probe = jnp.asarray(np.random.default_rng(9).standard_normal(
+        (2, 192, Hv * 16)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = _value_and_grads(_chunked(dtype, 64), probe)(*args)
+        want = _value_and_grads(_channel_recurrence, probe)(*args)
+        out = _chunked(dtype, 64)(*args)
+    assert np.isfinite(np.asarray(out)).all()
+    assert _rel(out, _channel_recurrence(*args)) < tol
+    assert abs(float(got[0]) - float(want[0])) < tol * (
+        1 + abs(float(want[0])))
+    for name, a, b in zip(NAMES, got[1], want[1]):
+        assert a.shape == b.shape, name
+        # in bf16 the decay's gradient is a difference of rounded sums
+        assert _rel(a, b) < (tol if dtype == jnp.float32 or name != "g"
+                             else 0.15), name
+
+
+def test_a_two_sided_decay_overflows_where_the_blocks_do_not():
+    """``(K exp(Gamma)) (K exp(-Gamma))^T`` over a whole chunk: with a
+    channel at the bound for 64 positions ``exp(-Gamma)`` passes float32's
+    largest number and the product is not finite, which is why the op forms
+    it a block of 16 positions at a time against the block's first row."""
+    q, k, v, g, beta = _channel_inputs(1, 128, 1, 1, 16)
+    gamma = jnp.cumsum(g[0, 64:128, 0], axis=0)             # (64, d)
+    kc = k[0, 64:128]
+    two_sided = (kc * jnp.exp(gamma)) @ (kc * jnp.exp(-gamma)).T
+    assert not np.isfinite(np.asarray(two_sided)).all()
+    kk, qk = ops._channel_products(
+        q[0, 64:128][None, None, None], kc[None, None, None],
+        gamma[None, None, None], jnp.float32)
+    assert np.isfinite(np.asarray(kk)).all() \
+        and np.isfinite(np.asarray(qk)).all()
+    want = np.tril(np.einsum(
+        "id,jd,ijd->ij", *(np.asarray(x, np.float64) for x in (kc, kc)),
+        np.exp(np.minimum(np.asarray(gamma, np.float64)[:, None]
+                          - np.asarray(gamma, np.float64)[None], 0.0))))
+    np.testing.assert_allclose(np.tril(np.asarray(kk[0, 0, 0])), want,
+                               rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("Hk,Hv", [(2, 2), (1, 2)])
+def test_equal_channels_are_the_rule_under_a_decay_a_head(Hk, Hv):
+    """One op: ``g`` (B, S, Hv, dk) with every channel of a head equal is
+    ``g`` (B, S, Hv), forward and backward (the channels' cotangents sum to
+    the head's)."""
+    q, k, v, g, beta = _inputs(2, 128, Hk, Hv, 16, seed=3)
+    wide_g = jnp.broadcast_to(g[..., None], g.shape + (16,))
+    probe = jnp.asarray(np.random.default_rng(4).standard_normal(
+        v.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        a = _value_and_grads(_chunked(jnp.float32, 64), probe)(
+            q, k, v, g, beta)
+        b = _value_and_grads(_chunked(jnp.float32, 64), probe)(
+            q, k, v, wide_g, beta)
+    assert abs(float(a[0]) - float(b[0])) < 2e-5 * (1 + abs(float(a[0])))
+    for name, x, y in zip(NAMES, a[1], b[1]):
+        assert _rel(y.sum(-1) if name == "g" else y, x) < 2e-5, name
+
+
+def test_rows_are_independent_under_a_decay_a_channel():
+    args = _channel_inputs(2, 128, 2, 2, 16, seed=7)
+    both = _chunked(jnp.float32, 64)(*args)
+    second = _chunked(jnp.float32, 64)(*(x[1:] for x in args))
+    np.testing.assert_allclose(both[1:], second, rtol=1e-5, atol=1e-6)
+
+
+def test_what_the_rule_refuses_of_a_decay_a_channel():
+    q, k, v, g, beta = _channel_inputs(1, 64, 2, 2, 16, pinned=False)
+    with pytest.raises(ValueError, match="a decay a key channel is"):
+        gated_delta_rule(q, k, v, g[..., :8], beta)
+    with pytest.raises(NotImplementedError,
+                       match="the kernels take one decay a head"):
+        gated_delta_rule(q, k, v, g, beta, impl="pallas", interpret=True)
+    with pytest.raises(ValueError, match="gated_delta_rule takes"):
+        gated_delta_rule(q, k, v, g[..., 0, 0], beta)
+
+
+def test_the_dispatch_says_a_decay_a_channel_and_the_gauge_its_width():
+    def channels():
+        family = get_registry().snapshot().get("gated_delta_decay_channels")
+        return family["samples"][0]["value"] if family else None
+
+    before = {r[:3]: r[3] for r in dispatch_report()}
+    jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=16),
+                   *_channel_inputs(1, 64, 2, 2, 16, pinned=False))
+    assert channels() == 16
+    assert [r[1:3] for r in dispatch_report() if r[0] == "gated_delta"
+            and r[3] > before.get(r[:3], 0)] == [(
+                "xla", "a decay a key channel (2 heads x 16): the kernels "
+                "take one decay a head")]
+    jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=16),
+                   *_inputs(1, 64, 2, 2, 16))
+    assert channels() == 1

@@ -336,9 +336,11 @@ def test_the_engine_trains_it_under_zero3_with_the_new_leaves_sharded():
     (dict(diffusion={"block_length": 4, "mask_token_id": 1}, moe=None,
           num_dense_layers=0), NotImplementedError,
      "block-diffusion training\\) with a conv layer"),
+    # latent attention beside a conv layer runs since PR 58 (the layer
+    # type picks the mixer); with the family's per-head norm it does not
     (dict(q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
-          qk_rope_head_dim=8, v_head_dim=8, qk_norm=False),
-     NotImplementedError, "latent attention with a conv layer"),
+          qk_rope_head_dim=8, v_head_dim=8),
+     NotImplementedError, "latent attention with qk_norm"),
     (dict(conv_L_cache=0), ValueError, "at least one tap"),
     (dict(layer_types=["conv", "mamba"] * 3), ValueError,
      "'conv' and 'linear_attention' are written"),

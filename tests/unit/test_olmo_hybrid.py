@@ -252,9 +252,11 @@ def test_a_block_normalises_after_each_branch_and_not_before(built, hidden,
     (dict(diffusion={"block_length": 4, "mask_token_id": 1}),
      NotImplementedError,
      "block-diffusion training\\) with a linear_attention layer"),
+    # latent attention beside linear-state layers runs since PR 58; with
+    # the family's rope_layer_types it does not
     (dict(q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
           qk_rope_head_dim=8, v_head_dim=8, qk_norm=False),
-     NotImplementedError, "latent attention with a linear_attention layer"),
+     NotImplementedError, "latent attention with qk_norm, a gate a channel"),
     (dict(scan_layers=True), NotImplementedError,
      "scan_layers=True with a linear_attention layer"),
     (dict(linear_value_head_dim=0), ValueError, "at least one channel a head"),

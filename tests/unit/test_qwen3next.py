@@ -154,10 +154,12 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids):
     (dict(diffusion={"block_length": 4, "mask_token_id": 1}),
      NotImplementedError,
      "block-diffusion training\\) with a linear_attention layer"),
+    # latent attention beside linear-state layers runs since PR 58; with
+    # the family's gate a channel it does not
     (dict(q_lora_rank=8, kv_lora_rank=8, qk_nope_head_dim=8,
-          qk_rope_head_dim=8, v_head_dim=8, qk_norm=False, attn_gate=False,
+          qk_rope_head_dim=8, v_head_dim=8, qk_norm=False,
           partial_rotary_factor=1.0),
-     NotImplementedError, "latent attention with a linear_attention layer"),
+     NotImplementedError, "a gate a channel \\(attn_gate=True"),
     (dict(scan_layers=True), NotImplementedError,
      "scan_layers=True with a linear_attention layer"),
     (dict(linear_num_value_heads=3), ValueError,
