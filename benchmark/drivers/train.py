@@ -133,5 +133,6 @@ def run(ctx, reference) -> dict:
                     cfg.n_embd, cfg.n_layer, seq, 3),
             "attention_bytes_per_token":
                 flops.flash_train_bytes_per_token(cfg.n_embd, cfg.n_layer),
+            "instruction_scopes": ctx.step_scopes(engine),
         },
     }

@@ -35,7 +35,9 @@ After it:
 7. beside ``train_lm.run``'s own window checks: the two-product flash
    kernels at site ``attention`` for the latent-attention layer and the XLA
    path for none, the share's rows moved by the Pallas row kernels, and the
-   delta rule resolved to what ``expect_gated_delta_impl`` says.
+   delta rule run under a decay a key channel, whatever implements it
+   (:func:`check_delta_rule`: the site resolved, and the program's gauge
+   reads ``expect_gated_delta_decay_channels`` log-decays a head).
 """
 from __future__ import annotations
 
@@ -46,6 +48,7 @@ import numpy as np
 
 from benchmark.drivers import (train_lm, train_mellum2, train_qwen3next,
                                train_trinity)
+from benchmark.layer_metrics import _program
 
 FAMILIES = train_lm.FAMILIES
 _rel_err = train_mellum2._rel_err
@@ -59,6 +62,7 @@ two_rows = train_qwen3next.two_rows
 _mixer_err = train_qwen3next._mixer_err
 KDA, FULL = "kda_attention", "full_attention"
 MLA_REASON = "shared rope lanes"
+DECAY_GAUGE = "gated_delta_decay_channels"
 SCOPES = ("linear_attn/in_proj", "linear_attn/conv", "linear_attn/decay_gate",
           "linear_attn/delta_rule", "linear_attn/gated_norm",
           "linear_attn/out_proj")
@@ -319,6 +323,37 @@ def scope_split(ctx, engine, batches) -> dict:
     return out
 
 
+def decay_channels(snapshot: dict):
+    """The program's gauge of the log-decays a head a position under which
+    the delta rule's last traced pass ran (1: a decay a head; the key
+    head's channels: a decay a key channel), or ``None`` without it."""
+    entry = snapshot.get(DECAY_GAUGE)
+    return entry["samples"][0]["value"] if entry and entry["samples"] \
+        else None
+
+
+def check_delta_rule(ctx, conf, report, channels) -> str:
+    """The mechanism the cell's ``why`` names, and no implementation of it:
+    site ``gated_delta`` of ``report`` (``dispatch_report()``'s rows that
+    count) resolved, to a kernel or to XLA's program, and it ran under
+    ``expect_gated_delta_decay_channels`` log-decays a head, the key head's
+    channels (one decay a head, Gated DeltaNet's rule, is the fault that
+    comparison 2 reads at 0.393).  Returns what ran in words, for the
+    result line."""
+    ran = [r for r in report if r[0] == "gated_delta"]
+    ctx.check(bool(ran), "the delta rule never resolved: site gated_delta "
+                         f"is in no row of {sorted(r[:2] for r in report)}")
+    want = conf["expect_gated_delta_decay_channels"]
+    ctx.check(channels == want,
+              f"the delta rule ran under {channels} log-decays a head a "
+              f"position (gauge {DECAY_GAUGE}), not the {want} of a decay a "
+              f"key channel")
+    said = "; ".join(f"{impl} x {n} ({reason})"
+                     for _, impl, reason, n in ran) or "never resolved"
+    ctx.log(f"the delta rule under {channels} log-decays a head ran as {said}")
+    return said
+
+
 def run(ctx, reference) -> dict:
     """``train_lm.run`` with this module's comparison in place of its own,
     the engine kept for the comparison after the window and for the scopes,
@@ -363,12 +398,13 @@ def run(ctx, reference) -> dict:
                               f"latent-attention layer: {report}")
         xla = [r for r in report if r[:2] == ("attention", "jnp")]
         ctx.check(not xla, f"attention took the XLA path: {xla}")
-        for site in ("moe_rows", "gated_delta"):
-            want = conf.get(f"expect_{site}_impl")
-            ctx.check(want is None or (site, want) in rows,
-                      f"{site} never resolved to {want}: {sorted(rows)}")
+        want = conf.get("expect_moe_rows_impl")
+        ctx.check(want is None or ("moe_rows", want) in rows,
+                  f"moe_rows never resolved to {want}: {sorted(rows)}")
     train_mellum2.count_what_was_routed_here(ctx, out)
     obs = out["observed"]
+    obs["gated_delta_impl"] = check_delta_rule(
+        ctx, conf, report, decay_channels(_program.registry_snapshot()))
     flops = importlib.import_module("benchmark." + conf["flops"])
     step_tokens = obs["tokens"] // max(obs["steps"], 1) // obs["n_devices"]
     obs["kda_flops_per_step"] = flops.kda_flops_per_step(conf, step_tokens)

@@ -240,6 +240,7 @@ def run(ctx, reference) -> dict:
             flops.causal_attention_flops_per_token(conf, seq, 3),
         "attention_bytes_per_token": flops.flash_train_bytes_per_token(conf),
         moe_load_imbalance.COUNTER: routed,
+        "instruction_scopes": ctx.step_scopes(engine),
     }
     if "num_experts" in conf:
         observed["expert_gemm_flops_per_step"] = \

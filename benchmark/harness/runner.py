@@ -102,6 +102,22 @@ class Context:
             jax.profiler.stop_trace()
             self._tracing = False
 
+    def step_scopes(self, engine) -> dict:
+        """``{instruction name: op_name}`` of the train step that ran, by
+        which a traced run's ``breakdown`` names XLA's fusions
+        (``trace_reduce.booked_times``).  The program parses the executable's
+        text for it, once an executable, so a driver asks after the window,
+        when ``setup_s`` is closed; an untraced run never asks."""
+        if not self.trace:
+            return {}
+        from deepspeed_tpu.telemetry.device_scopes import instruction_scopes
+
+        t0 = time.perf_counter()
+        scopes = instruction_scopes(engine.compiled_step())
+        self.log(f"the step's {len(scopes)} instructions by op_name, for the "
+                 f"breakdown's names: {time.perf_counter() - t0:.2f}s")
+        return scopes
+
 
 def count_compiles(ctx: Context) -> None:
     """From now on, count every executable JAX builds or fetches from its
@@ -169,6 +185,11 @@ def result_line(ctx: Context, out: dict, devices, which: str,
             "failed": int(out["failed"]), "metrics": metrics, "device": device}
     if trace_summary is not None:
         line["breakdown"] = trace_summary.breakdown()
+    # what a driver says of the run in words (which implementation ran a
+    # mechanism the cell holds by other means)
+    said = {k: v for k, v in out["observed"].items() if isinstance(v, str)}
+    if said:
+        line["said"] = said
     if ctx.notes:
         line["notes"] = ctx.notes
     return line
@@ -220,7 +241,9 @@ def main(argv=None, t_process: Optional[float] = None) -> int:
             # a CPU trace has no device plane; nothing is reduced from it
             ctx.log(f"rehearsal trace written: {os.path.getsize(path)} bytes")
         else:
-            summary = trace_reduce.summarize(*trace_reduce.read_xplane(path))
+            summary = trace_reduce.summarize(
+                *trace_reduce.read_xplane(path),
+                scopes=out["observed"].get("instruction_scopes"))
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
     if args.rehearse:
         print(json.dumps({REHEARSAL_KEY: True, "workload": cell.name,

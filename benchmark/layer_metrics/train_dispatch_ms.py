@@ -1,7 +1,8 @@
 """Median ``train/dispatch`` span (ms): the call of the compiled step as
-the host sees it: the recompile watchdog's signature check, the enqueue,
-and the wait for a free slot once the host is ``run_ahead`` steps in
-front of the device."""
+the host sees it: flattening its arguments, the enqueue, and the wait for
+a free slot once the host is ``run_ahead`` steps in front of the device
+(no signature check since PR 28: the recompile watchdog signs the
+arguments only of a call that made an executable)."""
 from benchmark.layer_metrics import _program
 
 

@@ -5,6 +5,14 @@ The arithmetic works on plain event tuples ``(name, start_ns, dur_ns)`` so
 it can be checked on a hand-made list; :func:`read_xplane` turns a JAX
 ``.xplane.pb`` into those tuples with nothing but ``jax.profiler``.
 
+Every reader matches a device event by its CUT name (the instruction less
+its number: ``fusion``, ``gmm``); XLA's fusions carry no name of the
+program's, so ``breakdown`` alone books an event under the program's scope
+(``kda_attn/linear_attn/delta_rule``, ``moe/route``) where :func:`summarize`
+is handed the executable's ``{instruction name: op_name}`` map, which the
+v5e's trace does not carry and the program's ``telemetry/device_scopes.py
+instruction_scopes`` reads from the optimized HLO.
+
 What the v5e's trace looks like (looked at by hand, PR 23): one plane per
 chip, ``/device:TPU:<n>``, with the lines ``XLA Modules`` (one event per
 executable run, named ``jit_<fn>(<fingerprint>)``), ``XLA Ops`` (one event
@@ -28,6 +36,19 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench/"                       # the benchmark's own host spans
+# segments of an ``op_name`` that say how JAX staged the instruction, not
+# where the program was, and a segment that ends in an index
+# (``deepspeed_tpu/telemetry/device_scopes.py``'s, copied: the yardstick
+# keeps its own; ``tests/benchmark`` holds the two to one reading)
+_WRAPPER = re.compile(
+    r".*\(.*\)$|.*\..*|.*->.*|checkpoint$|rematted_computation$|remat\d*$"
+    r"|while$"
+    r"|body$|cond$|closed_call$|core_call$|pjit$|branch_\d+_fun$"
+    r"|custom_[jv][vj]p_call(_jaxpr)?$")
+_INDEXED = re.compile(r"^(.*_)\d+$")
+_TOP_LEVEL = "(step)"
+_DEPTH = 3                                   # names a scope keeps
+_PALLAS = "pallas_call"                      # a kernel's op_name ends in it
 
 
 def merge(intervals: Iterable[Tuple[float, float]]) -> List[Tuple[float, float]]:
@@ -72,6 +93,73 @@ def by_name(events: Iterable[Event]) -> Dict[str, float]:
     for name, _, d in events:
         acc[name] = acc.get(name, 0.0) + d
     return acc
+
+
+class OpName(str):
+    """A device event's cut name, which is all a reader sees, with the whole
+    instruction name (``fusion.412``) beside it for :func:`xla_scope`."""
+    instruction: str
+
+    def __new__(cls, instruction: str):
+        self = super().__new__(cls, re.sub(r"[.\-_]\d+$", "", instruction))
+        self.instruction = instruction
+        return self
+
+
+def scope_name(op_name: str) -> str:
+    """The program's scope of an ``op_name`` as ``device_scopes.scope_of``
+    cuts it (the primitive and the transform wrappers dropped, an index
+    folded, a name that comes again taking the path back to where it first
+    stood), less the leading indexed scope, so that the layers of a stack add
+    up, and then the first three names: ``jit(step_fn)/jvp(M)/layers_3/
+    kda_attn/linear_attn/delta_rule/dot_general`` is
+    ``kda_attn/linear_attn/delta_rule``."""
+    path: List[str] = []
+    for seg in op_name.split("/")[:-1]:
+        if _WRAPPER.match(seg):
+            continue
+        seg = _INDEXED.sub(r"\1*", seg)
+        if seg in path:
+            del path[path.index(seg):]
+        path.append(seg)
+    if len(path) > 1 and path[0].endswith("_*"):
+        del path[0]
+    return "/".join(path[:_DEPTH]) or _TOP_LEVEL
+
+
+def xla_scope(name: str, scopes: Dict[str, str]) -> Optional[str]:
+    """The program's scope of an instruction (its whole name) that ``scopes``
+    names and that
+    is no Pallas custom call; ``None`` for a kernel and for an instruction
+    without an ``op_name`` (the compiler's own copies)."""
+    op_name = scopes.get(name)
+    if op_name is None or op_name.rsplit("/", 1)[-1] == _PALLAS:
+        return None
+    return scope_name(op_name)
+
+
+def booked_times(selfs: Sequence[Event], scopes: Dict[str, str]
+                 ) -> Dict[str, float]:
+    """Self time by what ``breakdown`` calls a device event: a Pallas custom
+    call its kernel's name (its cut name, as ever); any other instruction
+    the program's scope of its ``op_name`` (:func:`xla_scope`); one that has
+    none its cut name.  A kernel is named after the scope it stands in
+    (``attn``, ``self_attn``), so where XLA's instructions of a scope would
+    fall under a kernel's name they are booked as ``<scope>/(xla)`` and the
+    kernel keeps its name to itself."""
+    cut: Dict[str, float] = {}
+    scoped: Dict[str, float] = {}
+    scope_of: Dict[str, Optional[str]] = {}     # an instruction runs a step
+    for name, _, dur in selfs:
+        instruction = getattr(name, "instruction", name)
+        if instruction not in scope_of:
+            scope_of[instruction] = xla_scope(instruction, scopes)
+        scope = scope_of[instruction]
+        into, key = (cut, str(name)) if scope is None else (scoped, scope)
+        into[key] = into.get(key, 0.0) + dur
+    for scope, dur in scoped.items():
+        cut[scope + "/(xla)" if scope in cut else scope] = dur
+    return cut
 
 
 def gaps(busy: Sequence[Tuple[float, float]], lo: float, hi: float
@@ -121,6 +209,8 @@ class TraceSummary:
     module_runs: Dict[str, int]
     gap_s_by_span: Dict[str, float]
     longest_gaps: List[Tuple[str, float]]
+    # device time by booked_times; empty where summarize had no map
+    booked_self_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def ops_matching(self, pattern: str) -> float:
         rx = re.compile(pattern)
@@ -133,7 +223,8 @@ class TraceSummary:
                 sum(self.module_runs[k] for k in keys))
 
     def breakdown(self) -> dict:
-        top = sorted(self.op_self_s.items(), key=lambda kv: -kv[1])[:10]
+        top = sorted((self.booked_self_s or self.op_self_s).items(),
+                     key=lambda kv: -kv[1])[:10]
         return {"device_ops": [[k, v] for k, v in top],
                 "idle_gaps": [[k, v] for k, v in self.longest_gaps[:10]]}
 
@@ -141,10 +232,13 @@ class TraceSummary:
 def summarize(device_ops: Dict[int, Sequence[Event]],
               device_modules: Dict[int, Sequence[Event]],
               host_spans: Sequence[Event],
-              window: Optional[Tuple[float, float]] = None) -> TraceSummary:
+              window: Optional[Tuple[float, float]] = None,
+              scopes: Optional[Dict[str, str]] = None) -> TraceSummary:
     """Reduce per-device op and module events plus the host's spans.  The
     window defaults to the extent of the outermost ``bench/window`` span,
-    else to the extent of all device events."""
+    else to the extent of all device events.  ``scopes`` is the executable's
+    ``{instruction name: op_name}``; it names ``breakdown``'s device
+    operations (:func:`booked_times`) and moves nothing else."""
     if window is None:
         win = [e for e in host_spans if e[0] == SPAN_PREFIX + "window"]
         if win:
@@ -162,6 +256,7 @@ def summarize(device_ops: Dict[int, Sequence[Event]],
     n = len(devs)
     busy_s = 0.0
     op_self: Dict[str, float] = {}
+    booked: Dict[str, float] = {}
     mod_s: Dict[str, float] = {}
     mod_runs: Dict[str, int] = {}
     for d in devs:
@@ -170,6 +265,9 @@ def summarize(device_ops: Dict[int, Sequence[Event]],
         selfs = self_times(evs)
         for k, v in by_name(selfs).items():
             op_self[k] = op_self.get(k, 0.0) + v
+        if scopes:
+            for k, v in booked_times(selfs, scopes).items():
+                booked[k] = booked.get(k, 0.0) + v
         for nm, s, dd in clip(device_modules.get(d, ()), lo, hi):
             key = re.sub(r"\(.*\)$", "", nm)
             mod_s[key] = mod_s.get(key, 0.0) + dd
@@ -187,7 +285,8 @@ def summarize(device_ops: Dict[int, Sequence[Event]],
         module_s={k: v * ns / n for k, v in mod_s.items()},
         module_runs={k: int(round(v / n)) for k, v in mod_runs.items()},
         gap_s_by_span=totals,
-        longest_gaps=sorted(named, key=lambda kv: -kv[1]))
+        longest_gaps=sorted(named, key=lambda kv: -kv[1]),
+        booked_self_s={k: v * ns / n for k, v in booked.items()})
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -198,11 +297,11 @@ def find_xplane(trace_dir: str) -> str:
     return files[-1]
 
 
-def _op_name(event) -> str:
+def _op_name(event) -> OpName:
     """The HLO instruction name with its trailing number cut, so that the
     48 copies of one fusion add up; a Pallas kernel keeps its kernel name
     (the custom call's name is the kernel function's)."""
-    return re.sub(r"[.\-_]\d+$", "", event.name.split(" = ")[0].lstrip("%"))
+    return OpName(event.name.split(" = ")[0].lstrip("%"))
 
 
 def read_xplane(path: str, device_plane=DEVICE_PLANE, ops_line: str = OPS_LINE,

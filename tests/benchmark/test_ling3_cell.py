@@ -257,7 +257,14 @@ def test_the_configuration_file_is_the_catalog_row_cut_four_ways(cell):
     assert conf["trace_names"]["flash"] == "^self_attn_mla$"
     assert (conf["driver"], conf["reference"], conf["flops"]) == (
         "train_ling3", "ling3", "flops_ling3")
-    assert conf["expect_gated_delta_impl"] == "xla"
+    # the cell holds the mechanism its ``why`` names, a decay a KEY CHANNEL,
+    # and no implementation of the delta rule
+    assert conf["expect_gated_delta_decay_channels"] == conf["head_dim"] \
+        == 128
+    small = conf["rehearse"]
+    assert small["expect_gated_delta_decay_channels"] == small["head_dim"]
+    assert not [k for k in conf if k.startswith("expect_gated_delta")
+                and k.endswith("_impl")]
     assert len(conf["compile_said"]) > 40 and "16.01" in conf["compile_said"]
 
 
@@ -466,6 +473,96 @@ def test_the_drivers_split_sums_the_six_scopes():
         "linear_attn/decay_gate": 4.0, "linear_attn/delta_rule": 150.0,
         "linear_attn/gated_norm": 0, "linear_attn/out_proj": 0,
         "linear_attn": 163.0}
+
+
+KDA_REASON = ("a decay a key channel (32 heads x 128): the kernels take one "
+              "decay a head")
+OTHER_SITES = [("attention", "flash", "128 + 64 shared rope lanes", 3),
+               ("moe_rows", "pallas", "rows 65536 x 2560", 30)]
+
+
+def _stub_snapshot(channels):
+    """What the registry's snapshot is to the check: with the gauge
+    ``ops/gated_delta.py _note_state`` sets, or without it."""
+    return {} if channels is None else {"gated_delta_decay_channels": {
+        "type": "gauge", "samples": [{"labels": {}, "value": channels}]}}
+
+
+@pytest.mark.parametrize("rows, channels, refused", [
+    ([("gated_delta", "xla", KDA_REASON, 21)], 128.0, None),
+    ([("gated_delta", "pallas", "128 chunks of 64 x 32 key heads x 1 value "
+       "heads of 128, a decay a key channel, fused; one device", 21)], 128.0,
+     None),
+    ([("gated_delta", "pallas", "fused", 14),
+      ("gated_delta", "xla", "impl='xla' asked for", 7)], 128.0, None),
+    ([("gated_delta", "xla", KDA_REASON, 21)], 1.0, "not the 128"),
+    ([("gated_delta", "pallas", "fused", 21)], 1.0, "not the 128"),
+    ([], 128.0, "never resolved"),
+    ([("gated_delta", "pallas", "fused", 21)], None, "under None"),
+], ids=["xla", "pallas", "both", "one-decay-a-head-xla",
+        "one-decay-a-head-pallas", "site-never-resolved",
+        "program-without-the-gauge"])
+def test_the_window_holds_the_delta_rules_mechanism_not_its_implementation(
+        cell, rows, channels, refused):
+    """The check of the mechanism passes whichever implementation resolved
+    site ``gated_delta``, refuses one decay a head (the gauge reads 1) and
+    refuses a run in which the site never resolved."""
+    sys.path.insert(0, ROOT)
+    from benchmark.drivers import train_ling3
+
+    conf = {k: v for k, v in cell.config.items() if k != "rehearse"}
+    notes, logged = [], []
+    ctx = types.SimpleNamespace(
+        check=lambda ok, what: bool(ok) or notes.append(what),
+        log=logged.append)
+    said = train_ling3.check_delta_rule(
+        ctx, conf, OTHER_SITES + rows,
+        train_ling3.decay_channels(_stub_snapshot(channels)))
+    if refused is None:
+        assert notes == []
+        for _, impl, reason, n in rows:
+            assert f"{impl} x {n} ({reason})" in said
+        assert said in logged[-1]
+    else:
+        assert len(notes) == 1 and refused in notes[0]
+    assert "attention" not in said and "moe_rows" not in said
+
+
+@pytest.mark.parametrize("per_channel", [True, False],
+                         ids=["a-decay-a-key-channel", "a-decay-a-head"])
+def test_the_programs_gauge_tells_the_two_rules_apart(cell, per_channel):
+    """The program's own rule at a tiny shape, on the CPU: under a decay a
+    key channel the gauge reads the key head's channels and the check
+    passes; under one decay a head it reads 1 and the check refuses."""
+    import jax.numpy as jnp
+
+    sys.path.insert(0, ROOT)
+    from benchmark.drivers import train_ling3
+    from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+    from deepspeed_tpu.telemetry import get_registry
+
+    B, S, H, D = 1, 16, 2, 16
+    rng = np.random.default_rng(3)
+    q, k, v = (jnp.asarray(rng.standard_normal((B, S, H * D)) / 4.0,
+                           jnp.float32) for _ in range(3))
+    g = -jnp.asarray(rng.uniform(0.1, 2.0, (B, S, H, D) if per_channel
+                                 else (B, S, H)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.1, 0.9, (B, S, H)), jnp.float32)
+    out = gated_delta_rule(q, k, v, g, beta, chunk=8)
+    assert out.shape == (B, S, H * D) and bool(jnp.isfinite(out).all())
+    notes = []
+    ctx = types.SimpleNamespace(
+        check=lambda ok, what: bool(ok) or notes.append(what),
+        log=lambda msg: None)
+    conf = {"expect_gated_delta_decay_channels": D}
+    train_ling3.check_delta_rule(
+        ctx, conf, [r for r in dispatch_report() if r[3]],
+        train_ling3.decay_channels(get_registry().snapshot()))
+    if per_channel:
+        assert notes == []
+    else:
+        assert len(notes) == 1 and f"not the {D}" in notes[0]
 
 
 def test_a_program_without_the_layer_type_fails_soon_and_cleanly(
