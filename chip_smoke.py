@@ -1057,6 +1057,87 @@ def kernel_gated_delta(time_it: bool = True, wide: bool = False):
                           f"{np.mean(ns) / 1e6:.3f} ms a row", flush=True)
 
 
+def kernel_gated_delta_channels(time_it: bool = True):
+    """The delta rule under a decay a KEY CHANNEL (Kimi Delta Attention; the
+    eleventh cell's 32 heads of 128 x 128, chunk 64, log-decays in (-5, 0)
+    with a quarter of the channels pinned at the bound over a chunk), ``(1,
+    2048)`` against ``benchmark/reference/ling3.py kda_rule``'s recurrence
+    in float32, forward and ``jax.vjp``: the channel kernels (PR 60: what
+    ``auto`` takes on the chip) and the XLA form; then both timed at the
+    cell's ``(1, 8192)``."""
+    import functools
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.harness.manifest import ROOT, load_module
+    from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+
+    reference = load_module(ROOT, "reference", "ling3")
+    B, H, d = 1, 32, 128
+
+    def operands(S):
+        ks = jax.random.split(jax.random.PRNGKey(60), 6)
+
+        def unit(key, scale):
+            x = jax.random.normal(key, (B, S, H, d), jnp.float32)
+            x = x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+            return (x * scale).reshape(B, S, H * d).astype(jnp.bfloat16)
+
+        v, do = (jax.random.normal(kk, (B, S, H * d), jnp.float32).astype(
+            jnp.bfloat16) for kk in ks[2:4])
+        g = -5.0 * jax.nn.sigmoid(
+            2.0 * jax.random.normal(ks[4], (B, S, H, d), jnp.float32))
+        g = g.at[:, 64:128, :, :d // 4].set(-5.0)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, S, H),
+                                                jnp.float32))
+        return (unit(ks[0], d ** -0.5), unit(ks[1], 1.0), v, g, beta), do
+
+    def both(fn, do):
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args)
+            return (out,) + vjp(do.astype(out.dtype))
+        return jax.jit(run)
+
+    def ref(q, k, v, g, beta):
+        f = reference._f32
+        S = q.shape[1]
+        return reference.kda_rule(
+            *(f(t).reshape(B, S, H, d) for t in (q, k, v)), g,
+            beta).reshape(B, S, H * d)
+
+    args, do = operands(2048)
+    want = both(ref, do)(*args)
+    for impl in ("pallas", "xla"):
+        rule = functools.partial(gated_delta_rule, chunk=64, impl=impl)
+        got = jax.block_until_ready(both(rule, do)(*args))
+        for n, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
+            a, b = (np.asarray(t, np.float32) for t in (a, b))
+            err = float(np.linalg.norm(a - b) / np.linalg.norm(b))
+            print(f"  gated_delta channels {impl} {n}: |chunked - "
+                  f"recurrence| / |recurrence| {err:.2e}", flush=True)
+            # the XLA form rounds the decays' cotangents (PERF.md, PR 58)
+            assert np.isfinite(a).all() and err <= (
+                TOL if impl == "pallas" or n != "dg" else 0.1), (n, err)
+    if not time_it:
+        return
+    args, do = operands(8192)
+    for impl in ("pallas", "xla"):
+        rule = functools.partial(gated_delta_rule, chunk=64, impl=impl)
+        for name, fn in (("forward", jax.jit(rule)),
+                         ("forward + backward", both(rule, do))):
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            print(f"  gated_delta channels {impl}: {name} "
+                  f"{(time.perf_counter() - t0) / 5 * 1e3:.3f} ms a "
+                  f"layer-row of 8192", flush=True)
+
+
 def kernel_gated_norm(time_it: bool = True):
     """A Gated DeltaNet layer's gated output norm on the rows (PR 53;
     ``ops/pallas/qk_rows.py gated_norm_rows`` behind ``ops/gated_delta.py
@@ -1823,7 +1904,8 @@ KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
                 kernel_flash_lanes_256, kernel_indexed_attention,
                 kernel_gated_delta,
-                kernel_gated_delta_wide, kernel_gated_norm, kernel_head_slots,
+                kernel_gated_delta_wide, kernel_gated_delta_channels,
+                kernel_gated_norm, kernel_head_slots,
                 kernel_qk_rows,
                 kernel_short_conv,
                 kernel_grouped_matmul,

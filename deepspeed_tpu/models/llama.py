@@ -1206,7 +1206,13 @@ class KimiDeltaAttention(nn.Module):
     filters' taps are the one leaf ``conv_kernel`` (3 H d, taps), q's rows,
     then k's, then v's: a concatenation a loader makes.  No positional
     encoding, no bias; every row of a batch starts from a zero state and
-    empty filters.  The sigmoid gate keeps the output norm on XLA's lines
+    empty filters.  On a TPU in bf16 at heads of whole lane tiles (128) the
+    rule runs as the kernels of a decay a key channel
+    (``ops/pallas/gated_delta.py channel_forward`` / ``channel_backward``,
+    PR 60), reading ``q``, ``k``, ``v`` as the filter and the row norm wrote
+    them and gamma as float32 rows beside ``k``; elsewhere as XLA's program
+    (``kernel_dispatch_total{site="gated_delta"}`` says which and why).
+    The sigmoid gate keeps the output norm on XLA's lines
     (the row kernel multiplies by ``silu``):
     ``kernel_dispatch_total{site="gated_norm_rows"}`` says so."""
     cfg: LlamaConfig

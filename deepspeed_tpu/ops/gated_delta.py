@@ -57,12 +57,27 @@ caller's gate is bounded below, ``LlamaConfig.kda_lower_bound`` >= -5.5).
 Everything else is the text above with ``exp(gamma)`` a ``(C, dk)`` array:
 ``W = ... K * exp(gamma)``, ``Q * exp(gamma)``, ``K * exp(gamma_C - gamma)``
 (all factors <= 1; what underflows is a decay of ``e^-87`` and contributes
-nothing), ``S <- Diag(exp(gamma_C)) S + ...``.  It runs as XLA's program
-under either ``impl`` but ``"pallas"``, which raises: the kernels take ``(B,
-Hk, 8, S)`` of gamma, one number a head a position
-(``kernel_dispatch_total{site="gated_delta"}`` says ``xla`` with ``a decay a
-key channel (...)``); the gauge ``gated_delta_decay_channels`` is 1 or
-``dk`` by which form a traced pass ran.
+nothing), ``S <- Diag(exp(gamma_C)) S + ...``.  Since PR 60 it has kernels
+of its own (``ops/pallas/gated_delta.py channel_forward`` /
+``channel_backward``, HLO custom calls ``gated_delta_channel_fwd`` /
+``gated_delta_channel_bwd``): separate bodies that share the solve and
+nothing of the data path with a decay a head's, chosen by ``g.ndim`` and by
+a guard of their own (``channel_supported``: bf16, chunks of 32 / 64 / 128
+in whole groups of four, heads whose ``dk`` and ``dv`` are whole tiles of
+128 lanes, the VMEM the resident state, the saved blocks and the ``(C,
+dk)`` float32 tiles take).  XLA then makes ``gamma`` as float32 ROWS ``(B,
+S, Hv dk)`` beside ``k`` (the cumulative sum a chunk) and ``beta`` as ``(B,
+Hk, 8, S)``, and turns ``dgamma`` into ``dg`` (the reverse sum);
+:func:`_prepare` and :func:`_channel_products` run zero times.  Where the
+guard refuses, XLA's form runs and
+``kernel_dispatch_total{site="gated_delta", impl="xla"}`` says ``a decay a
+key channel (H heads x dk): <the guard's reason>``; the kernels' reason
+ends ``..., a decay a key channel, fused``; the gauge
+``gated_delta_decay_channels`` is 1 or ``dk`` by which form a traced pass
+ran.  At ``(1, 8192, 32 heads of 128 x 128)`` on the v5e, wall a layer-row:
+XLA's form 20.2 ms forward and 52.0 forward + backward, the kernels 6.2
+forward and about 19.8 forward + backward (13.6 of it the backward call
+alone: the states' walk and the walk back; my chip run, PR 60).
 
 One ``custom_vjp``: the forward keeps its five inputs and nothing else, so
 a block's ``dots_saveable`` policy sees none of the inner products; the
@@ -321,10 +336,19 @@ def _row(chunk: int, fused, key_heads=None):
     if fused is None:
         return lambda *xs: _chunked(*(x[None] for x in xs), chunk,
                                     key_heads)[0]
-    from .pallas.gated_delta import forward
+    return lambda *xs: _kernels(xs[3])[0](
+        *(x[None] for x in xs), chunk=chunk, key_heads=key_heads,
+        interpret=fused)[0]
 
-    return lambda *xs: forward(*(x[None] for x in xs), chunk=chunk,
-                               key_heads=key_heads, interpret=fused)[0]
+
+def _kernels(g):
+    """``(forward, backward)`` of ``ops/pallas/gated_delta.py`` for one
+    row's ``g``: a decay a head's bodies, (S, Hv), or a key channel's, (S,
+    Hv, dk), by what ``g`` says."""
+    from .pallas import gated_delta as kernel
+
+    return (kernel.channel_forward, kernel.channel_backward) \
+        if g.ndim == 3 else (kernel.forward, kernel.backward)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
@@ -352,9 +376,7 @@ def _rule_bwd(chunk, fused, key_heads, res, do):
 
     def one(xs):
         if fused is not None:
-            from .pallas.gated_delta import backward
-
-            return tuple(x[0] for x in backward(
+            return tuple(x[0] for x in _kernels(xs[3])[1](
                 *(x[None] for x in xs), chunk=chunk, key_heads=key_heads,
                 interpret=fused))
         _, pull = jax.vjp(_row(chunk, None, key_heads), *xs[:-1])
@@ -367,16 +389,22 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def kernels_refusal(S: int, chunk: int, Hk: int, Hv: int, dk: int, dv: int,
-                    dtype) -> Optional[str]:
+                    dtype, channels: bool = False) -> Optional[str]:
     """``None`` where the kernels take ``Hk`` key heads of ``dk`` and ``Hv``
     value heads of ``dv`` channels over ``S`` positions (the device and the
-    mesh apart: ``ops/pallas/spmd.py plan`` asks those), else why XLA's
-    form runs."""
+    mesh apart: ``ops/pallas/spmd.py plan`` asks those), under a decay a
+    head or, ``channels``, a key channel (whose reason then names the
+    form); else why XLA's form runs."""
     from .pallas import gated_delta as kernel
 
     if S % chunk:
         return f"rows of {S} positions are no whole chunks of {chunk}"
-    return kernel.supported(S // chunk, chunk, dk, dv, dtype, Hv // Hk)
+    if not channels:
+        return kernel.supported(S // chunk, chunk, dk, dv, dtype, Hv // Hk)
+    refusal = kernel.channel_supported(S // chunk, chunk, dk, dv, dtype,
+                                       Hv // Hk)
+    return refusal and (f"a decay a key channel ({Hv} heads x {dk}): "
+                        f"{refusal}")
 
 
 def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -437,12 +465,11 @@ def _dispatch(q, k, v, g, beta, chunk, key_heads, impl, interpret,
                          f"got g {g.shape}")
     plan = spmd.plan(
         "gated_delta", B, "impl='xla' asked for" if impl == "xla"
-        else f"a decay a key channel ({Hv} heads x {dk}): the kernels take "
-             f"one decay a head" if channels
-        else kernels_refusal(S, chunk, Hk, Hv, dk, dv, v.dtype),
+        else kernels_refusal(S, chunk, Hk, Hv, dk, dv, v.dtype, channels),
         f"{S // chunk} chunks of {chunk} x {Hk} key heads {of}x {Hv // Hk} "
-        f"value heads of {dv}, fused", tpu=impl == "auto",
-        must=impl == "pallas")
+        f"value heads of {dv}, " + ("a decay a key channel, " if channels
+                                    else "") + "fused",
+        tpu=impl == "auto", must=impl == "pallas")
     if slots and plan is None:
         raise NotImplementedError(
             "gated_delta_rule: slotted operands are the kernels' layout, "

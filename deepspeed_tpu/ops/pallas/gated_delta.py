@@ -82,6 +82,60 @@ step.  VMEM a step: the double-buffered blocks (forward 1.3 MB with the
 saved states, backward 2.5 MB at ``C`` 64, ``d`` 128, ``r`` 2) and what the
 compiler keeps of eight chunk-heads' ``C x C`` and ``C x 2d`` float32 tiles
 (~1.5 MB); the limit asked is 64 MB.
+
+**Under a decay a key channel** (Kimi Delta Attention: ``g`` (B, S, Hv,
+dk); PR 60) the rule has bodies of its own, ``gated_delta_channel_fwd`` /
+``gated_delta_channel_bwd``, that share :func:`_inverses`, :func:`_pack`
+and :class:`_Masks` with the above and nothing of the data path: there is
+no ``K K^T`` to multiply by a ``C x C`` decay, and gamma is wanted beside
+``k``, not positions on lanes.  XLA prepares ``gamma`` as float32 ROWS ``(B,
+S, Hv dk)`` (:func:`_chunk_sums`), blocked ``(1, GROUP C, r dk)`` at the
+key head's lane offset as ``v`` is, and ``beta`` alone in the gate tile
+(:func:`_beta_tile`).  A grid step is ``(row, key head, group)`` as above;
+the states are held TRANSPOSED, values x keys ``(r, dv, dk)``, so that a
+state's decay ``Diag(exp(gamma_C))`` is a ``(1, dk)`` row over its lanes
+and ``<dS', S>`` a sum over sublanes.  A chunk-head, a block of
+:data:`SOLVE_BLOCK` = 16 rows ``i`` at a time against the block's OWN first
+row ``r`` (``exp(gamma_i - gamma_r) <= 1`` on the rows' side,
+``exp(gamma_r - gamma_j)`` on the keys' ``j`` up to the block's last row,
+at most ``exp(16 x 5.5)``: nothing overflows float32, and bf16 has its
+exponent):
+
+    rows_b = [K near_b | Q near_b]  (32, dk)    keys_b = K far_b  (C, dk)
+    [K K^T | Q K^T]_b = rows_b keys_b^T         (q's and k's rows stream
+                                    against ONE stationary block of keys)
+    A = strict_lower(beta K K^T)    T = (I + A)^-1      (:func:`_inverses`)
+    [U | W] = T beta [V | K exp(gamma)]         (float32, HIGHEST)
+    V' = U - W S      O = (Q exp(gamma)) S + lower_incl(Q K^T) V'
+    S <- Diag(exp(gamma_C)) S + (K exp(gamma_C - gamma))^T V'
+
+The backward walks back with ``dS`` resident, makes the preparation again
+from the saved entering states (``channel_forward(states=True)``) and
+transposes the scan as the head form does; the block products go back as
+``[dK K^T | dQ K^T]_b keys_b`` (the rows' cotangent, 32 rows) and its
+transpose against ``rows_b`` (the keys'), ``dq`` and ``dk`` summed in float32
+with the decays they were formed under.  **The log-decays' cotangent** is
+no difference of rounded outputs: gamma enters every operand as ``exp(+
+gamma)`` (a block's rows, ``Q e^gamma``, ``K e^gamma`` of ``W``) or
+``exp(-gamma)`` (a block's keys, ``K e^(gamma_C - gamma)``), so each
+operand AS IT WAS ROUNDED times its own float32 cotangent is that
+appearance's share,
+
+    dgamma = sum_b rows_b * drows_b - sum_b keys_b * dkeys_b
+             + Q e^gamma * dQg + K e^gamma * beta dR_w - K e^(gamma_C - gamma) * dKd
+    dgamma_C += e^gamma_C <dS', S> + sum_i (K e^(gamma_C - gamma) * dKd)_i
+
+and what cancels between a block's rows and its keys (``sum_i rows * drows
+= sum_j keys * dkeys``, the same triple sum) cancels to float32's rounding
+as the sums are formed; the blocks' reference rows, of which the result
+does not depend, get nothing (``jax.vjp`` of the XLA form hands them
+rounding noise).  Against the recurrence in float32 ``dg`` reads 8e-7 (the
+XLA form 4.6e-6), in bf16 summed over a head's channels 0.003-0.004 (the
+XLA form 0.008-0.010; ``tests/unit/test_gated_delta.py``).  VMEM a step at
+``C`` 64, ``d`` 128, ``r`` 1: the double-buffered blocks 1.3 MB forward
+and 2.8 MB backward (gamma and ``dgamma`` 128 KB each a buffer), the states
+as above, and what the compiler keeps of four chunk-heads' ``(C, dk)``
+float32 tiles (~30 of 32 KB each): :func:`channel_supported` counts them.
 """
 from __future__ import annotations
 
@@ -116,6 +170,18 @@ def _slot(d: int) -> int:
     return -(-d // _LANES) * _LANES
 
 
+def _chunks_refusal(n_chunks: int, chunk: int) -> Optional[str]:
+    """Why ``n_chunks`` chunks of ``chunk`` positions are no whole grid
+    steps of either form's kernels, or None."""
+    if chunk not in CHUNKS:
+        return (f"chunks of {chunk} positions: the kernels take "
+                f"{', '.join(map(str, CHUNKS))} (a group of {GROUP} fills "
+                f"whole tiles of {_LANES} lanes, 16-row blocks join in pairs)")
+    if n_chunks % GROUP:
+        return f"{n_chunks} chunks are no whole groups of {GROUP}"
+    return None
+
+
 def supported(n_chunks: int, chunk: int, dk: int, dv: int, dtype,
               heads_a_key: int = 1) -> Optional[str]:
     """``None`` where the kernels take ``n_chunks`` chunks of ``chunk``
@@ -130,12 +196,9 @@ def supported(n_chunks: int, chunk: int, dk: int, dv: int, dtype,
                 f"(heads of {dk} and {dv} channels in their lane slots): "
                 f"resident beside two blocks of {GROUP} saved ones they are "
                 f"{held >> 20} MiB of VMEM, more than {_STATE_BYTES >> 20}")
-    if chunk not in CHUNKS:
-        return (f"chunks of {chunk} positions: the kernels take "
-                f"{', '.join(map(str, CHUNKS))} (a group of {GROUP} fills "
-                f"whole tiles of {_LANES} lanes, 16-row blocks join in pairs)")
-    if n_chunks % GROUP:
-        return f"{n_chunks} chunks are no whole groups of {GROUP}"
+    said = _chunks_refusal(n_chunks, chunk)
+    if said:
+        return said
     if 2 * heads_a_key > _GATE_ROWS:
         return (f"{heads_a_key} value heads a key head: their gamma and beta "
                 f"fill more than one tile of {_GATE_ROWS} rows")
@@ -593,3 +656,406 @@ def backward(q, k, v, g, beta, do, *, chunk: int,
     return (_unslots(dq, Hk, dk), _unslots(dk_, Hk, dk),
             _unslots(dv_, Hv, dv), dg.astype(g.dtype),
             dbeta.astype(beta.dtype))
+
+
+# -- a decay a key channel (Kimi Delta Attention) ----------------------------
+#
+# Separate bodies that share the solve (:func:`_inverses`, :func:`_pack`,
+# :class:`_Masks`) and nothing of the data path with the kernels above: the
+# module's text has what a grid step holds and why.
+
+# float32 ``(C, d)`` tiles a chunk-head that the guard counts against VMEM:
+# gamma, its four exponentials' families, q and k in float32 and decayed,
+# the block operands and, in the backward, the accumulators.  An estimate, on
+# the safe side: Mosaic compiles every corner the guard admits and the next
+# ones it refuses (``tests/unit/test_zchip_compile.py -k channel_kernels``, a
+# described v5e; on the chip only chunk 64 at 128 x 128 has run: PR 60)
+_CHANNEL_TILES = 32
+
+
+def channel_supported(n_chunks: int, chunk: int, dk: int, dv: int, dtype,
+                      heads_a_key: int = 1) -> Optional[str]:
+    """:func:`supported` of the kernels under a decay a key channel:
+    ``gamma`` is read as rows beside ``k``, so a head is whole lane tiles."""
+    if dtype != jnp.bfloat16:
+        return f"operands of {jnp.dtype(dtype).name}"
+    if dk % _LANES or dv % _LANES:
+        return (f"heads of {dk} and {dv} channels under a decay a key "
+                f"channel: gamma is read beside k, a head whole tiles of "
+                f"{_LANES} lanes")
+    said = _chunks_refusal(n_chunks, chunk)
+    if said:
+        return said
+    if heads_a_key > _GATE_ROWS:
+        return (f"{heads_a_key} value heads a key head: their beta fills "
+                f"more than one tile of {_GATE_ROWS} rows")
+    held = 4 * heads_a_key * (
+        dk * dv * (1 + 2 * GROUP)
+        + GROUP * chunk * max(dk, dv) * _CHANNEL_TILES)
+    if held > _STATE_BYTES:
+        return (f"{heads_a_key} states of {dk} x {dv} float32 under a decay "
+                f"a key channel: resident beside two blocks of {GROUP} saved "
+                f"ones and {_CHANNEL_TILES} float32 tiles of {chunk} x "
+                f"{max(dk, dv)} a chunk-head they are {held >> 20} MiB of "
+                f"VMEM, more than {_STATE_BYTES >> 20}")
+    return None
+
+
+@dataclasses.dataclass
+class _Block:
+    """A block of :data:`SOLVE_BLOCK` rows ``at`` of a chunk-head against
+    its own first row ``r``: ``near = exp(gamma_i - gamma_r)`` of its rows
+    (n, dk), ``far = exp(gamma_r - gamma_j)`` of the keys up to its last row
+    (C, dk; zeros behind), float32, and the operands they decay: ``rows =
+    [K near | Q near]`` (n or 2n, dk; the states' walk has no ``Q``) and
+    ``keys = K far`` (C, dk), in the arrays' type."""
+    at: slice
+    near: jax.Array
+    far: jax.Array
+    rows: jax.Array
+    keys: jax.Array
+
+
+@dataclasses.dataclass
+class _ChannelHead:
+    """What of a chunk-head under a decay a key channel depends on no
+    state, float32 but the blocks' operands: its rows ``at`` in the step's
+    blocks, its index ``n`` among the key head's value heads and its lanes
+    ``on`` in the ``v`` / ``o`` blocks and ``over`` in gamma's; ``kf`` and
+    ``qf`` (None on the states' walk), the key head's rows in float32;
+    ``gamma`` (C, dk), ``bcol``, the :class:`_Block` s, ``kk`` and ``qk``
+    (``sum_c x_ic k_jc exp(gamma_ic - gamma_jc)``, right where ``i >= j``),
+    ``a``, ``e_gamma``, ``rhs`` (before ``beta``), ``t`` and ``x = [U |
+    W]``."""
+    at: slice
+    n: int
+    on: slice
+    over: slice
+    kf: jax.Array
+    qf: Optional[jax.Array]
+    gamma: jax.Array
+    bcol: jax.Array
+    blocks: list
+    kk: jax.Array
+    qk: Optional[jax.Array]
+    a: jax.Array
+    e_gamma: jax.Array
+    rhs: jax.Array
+    t: Optional[jax.Array] = None
+    x: Optional[jax.Array] = None
+
+
+def _channel_prepare(q_ref, k_ref, v_ref, gamma_ref, beta_ref, m: _Masks,
+                     r: int):
+    """The :class:`_ChannelHead` of each chunk-head of a grid step (a
+    chunk's value heads side by side), all of them a stage of the solve at a
+    time (:func:`_inverses`).  ``q_ref`` None: the states' walk."""
+    C, n = m.eye.shape[0], SOLVE_BLOCK
+    dk, dv = k_ref.shape[2], v_ref.shape[2] // r
+    cdt = k_ref.dtype
+    cols = _columns(beta_ref[0, 0])
+    heads = []
+    for i in range(GROUP):
+        at = slice(i * C, (i + 1) * C)
+        kf = k_ref[0, at, :].astype(_F32)
+        qf = None if q_ref is None else q_ref[0, at, :].astype(_F32)
+        for h in range(r):
+            over = slice(h * dk, (h + 1) * dk)
+            gamma = gamma_ref[0, at, over]
+            blocks, kk, qk = [], [], []
+            for lo in range(0, C, n):
+                hi = lo + n
+                ref = gamma[lo:lo + 1]
+                near = jnp.exp(gamma[lo:hi] - ref)
+                far = jnp.exp(ref - gamma[:hi])
+                if hi < C:
+                    far = jnp.concatenate(
+                        [far, jnp.zeros((C - hi, dk), _F32)], axis=0)
+                rows = (kf[lo:hi] * near).astype(cdt)
+                if qf is not None:
+                    rows = jnp.concatenate(
+                        [rows, (qf[lo:hi] * near).astype(cdt)], axis=0)
+                blk = _Block(slice(lo, hi), near, far, rows,
+                             (kf * far).astype(cdt))
+                prod = _nt(blk.rows, blk.keys)
+                if qf is None:
+                    kk.append(prod)
+                else:
+                    kk.append(prod[:n])
+                    qk.append(prod[n:])
+                blocks.append(blk)
+            kk = jnp.concatenate(kk, axis=0)
+            bcol = cols[at, h:h + 1]
+            e_gamma = jnp.exp(gamma)
+            heads.append(_ChannelHead(
+                at, h, slice(h * dv, (h + 1) * dv), over, kf, qf, gamma,
+                bcol, blocks, kk,
+                None if qf is None else jnp.concatenate(qk, axis=0),
+                a=jnp.where(m.strict, bcol * kk, 0.0), e_gamma=e_gamma,
+                rhs=jnp.concatenate(
+                    [v_ref[0, at, h * dv:(h + 1) * dv].astype(_F32),
+                     kf * e_gamma], axis=1)))
+    for it, t in zip(heads, _inverses([it.a for it in heads], m)):
+        it.t = t
+    for it in heads:
+        it.x = _hi(it.t, it.rhs * it.bcol)
+    return heads
+
+
+def _channel_fwd_kernel(*refs, chunk, r, states):
+    """The states are held TRANSPOSED, values x keys ``(r, dv, dk)``: the
+    decay of a state is then a ``(1, dk)`` row over its lanes."""
+    if states:
+        k_ref, v_ref, gamma_ref, beta_ref, out_ref, state = refs
+        q_ref = None
+    else:
+        q_ref, k_ref, v_ref, gamma_ref, beta_ref, out_ref, state = refs
+    C, dv = chunk, v_ref.shape[2] // r
+    cdt = k_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros(state.shape, state.dtype)
+
+    m = _Masks(C)
+    for it in _channel_prepare(q_ref, k_ref, v_ref, gamma_ref, beta_ref, m,
+                               r):
+        g_last = it.gamma[C - 1:C]
+        s = state[it.n]
+        if states:
+            out_ref[0, it.n, it.at.start // C] = s
+        sb = s.astype(cdt)
+        vb = (it.x[:, :dv] - _nt(it.x[:, dv:].astype(cdt), sb)).astype(cdt)
+        if not states:
+            o = _nt((it.qf * it.e_gamma).astype(cdt), sb) \
+                + _nn(jnp.where(m.lower, it.qk, 0.0).astype(cdt), vb)
+            out_ref[0, it.at, it.on] = o.astype(out_ref.dtype)
+        kd = (it.kf * jnp.exp(g_last - it.gamma)).astype(cdt)
+        state[it.n] = jnp.exp(g_last) * s + _tn(vb, kd)
+
+
+def _channel_bwd_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, s_ref,
+                        do_ref, dq_ref, dk_ref, dv_ref, dgamma_ref,
+                        dbeta_ref, dstate, *, chunk, r):
+    C, dk_, dv = chunk, k_ref.shape[2], v_ref.shape[2] // r
+    n = SOLVE_BLOCK
+    cdt = k_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros(dstate.shape, dstate.dtype)
+
+    m = _Masks(C)
+    heads = _channel_prepare(q_ref, k_ref, v_ref, gamma_ref, beta_ref, m, r)
+    lane = lax.broadcasted_iota(jnp.int32, (C, _LANES), 1)
+    dcols = [None] * GROUP
+    for i in reversed(range(GROUP)):
+        dq = dk = jnp.zeros((C, dk_), _F32)
+        dcol = jnp.zeros((C, _LANES), _F32)
+        for it in heads[i * r:(i + 1) * r]:
+            at, gamma, bcol, x = it.at, it.gamma, it.bcol, it.x
+            kf, qf = it.kf, it.qf
+            g_last = gamma[C - 1:C]
+            e_last, gl = jnp.exp(g_last - gamma), jnp.exp(g_last)
+            w = x[:, dv:].astype(cdt)
+            p = jnp.where(m.lower, it.qk, 0.0).astype(cdt)
+            qg, kd = (qf * it.e_gamma).astype(cdt), (kf * e_last).astype(cdt)
+            # the scan, transposed (states and their cotangents values x keys)
+            s, ds1 = s_ref[0, it.n, i], dstate[it.n]
+            do = do_ref[0, at, it.on]
+            sb, ds1b = s.astype(cdt), ds1.astype(cdt)
+            vb = (x[:, :dv] - _nt(w, sb)).astype(cdt)
+            dv_new = _tn(p, do) + _nt(kd, ds1b)
+            dvb = dv_new.astype(cdt)
+            dw = -_nn(dvb, sb)
+            dp = jnp.where(m.lower, _nt(do, vb), 0.0)
+            dqg, dkd = _nn(do, sb), _nn(vb, ds1b)
+            dgl = jnp.sum(ds1 * s, axis=0, keepdims=True)
+            dstate[it.n] = gl * ds1 + _tn(do, qg) - _tn(dvb, w)
+            # the preparation, transposed
+            dr = _hi(it.t, jnp.concatenate([dv_new, dw], axis=1),
+                     ((0,), (0,)))
+            da = jnp.where(m.strict, -_hi(dr, x, ((1,), (1,))), 0.0)
+            dv_ref[0, at, it.on] = (dr[:, :dv] * bcol).astype(dv_ref.dtype)
+            dbeta = jnp.sum(dr * it.rhs, axis=1, keepdims=True) \
+                + jnp.sum(da * it.kk, axis=1, keepdims=True)
+            # q's and k's cotangents in float32, and gamma's beside them:
+            # each operand AS IT WAS ROUNDED times its own cotangent, + where
+            # it holds exp(+gamma) (a block's rows, Q e^gamma, K e^gamma of
+            # W) and - where exp(-gamma) (a block's keys, K e^(gamma_C -
+            # gamma)), so that what cancels between a block's rows and its
+            # keys cancels to float32's rounding as the sums are formed
+            dkd_e = dkd * e_last
+            d_q = dqg * it.e_gamma
+            d_k = (dr[:, dv:] * bcol) * it.e_gamma
+            dgamma = qf * d_q + kf * (d_k - dkd_e)
+            d_k = d_k + dkd_e
+            dkk, dqk = (da * bcol).astype(cdt), dp.astype(cdt)
+            near_k, near_q, of_rows = [], [], []
+            for blk in it.blocks:
+                g = jnp.concatenate([dkk[blk.at], dqk[blk.at]], axis=0)
+                back, keys = _nn(g, blk.keys), _tn(g, blk.rows)
+                near_k.append(back[:n] * blk.near)
+                near_q.append(back[n:] * blk.near)
+                both = blk.rows.astype(_F32) * back
+                of_rows.append(both[:n] + both[n:])
+                dgamma = dgamma - blk.keys.astype(_F32) * keys
+                d_k = d_k + keys * blk.far
+            d_q = d_q + jnp.concatenate(near_q, axis=0)
+            d_k = d_k + jnp.concatenate(near_k, axis=0)
+            last = gl * dgl + jnp.sum(kf * dkd_e, axis=0, keepdims=True)
+            dgamma_ref[0, at, it.over] = dgamma \
+                + jnp.concatenate(of_rows, axis=0) \
+                + jnp.where(m.last_row, last, 0.0)
+            dq, dk = dq + d_q, dk + d_k
+            dcol = jnp.where(lane == it.n, dbeta, dcol)
+        dq_ref[0, heads[i * r].at, :] = dq.astype(dq_ref.dtype)
+        dk_ref[0, heads[i * r].at, :] = dk.astype(dk_ref.dtype)
+        dcols[i] = dcol
+    # beta's cotangent goes back positions on lanes, as beta came
+    dcols = jnp.concatenate(dcols, axis=0)
+    for t in range(0, GROUP * C, _LANES):
+        dbeta_ref[0, 0, :, t:t + _LANES] = dcols[t:t + _LANES].T[:_GATE_ROWS]
+
+
+def _beta_tile(beta, Hk: int):
+    """``(B, Hk, 8, S)`` float32: a key head's rows are the ``beta`` of its
+    value heads, then zeros."""
+    B, S, Hv = beta.shape
+    rows = beta.astype(_F32).reshape(B, S, Hk, Hv // Hk).transpose(0, 2, 3, 1)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, _GATE_ROWS - Hv // Hk), (0, 0)))
+
+
+def _chunk_sums(x, chunk: int, reverse: bool = False):
+    """The cumulative sums of the rows ``x`` (B, S, W) inside each chunk,
+    float32: ``g -> gamma``, and in ``reverse`` ``dgamma -> dg``.  As a
+    product with a triangle of ones at ``HIGHEST`` (float32 to its
+    rounding: the ones are exact in bf16): XLA's ``cumsum`` over the chunk
+    axis is a windowed reduction between two relayouts of the whole array,
+    2.03 ms against 0.89 at ``(1, 8192, 4096)`` (my chip run, PR 60)."""
+    B, S, W = x.shape
+    ones = jnp.ones((chunk, chunk), _F32)
+    tri = jnp.triu(ones) if reverse else jnp.tril(ones)
+    return jnp.einsum(
+        "ij,bnjw->bniw", tri, x.astype(_F32).reshape(B, S // chunk, chunk, W),
+        precision=_HI).reshape(B, S, W)
+
+
+def _channel_specs(span: int, dk: int, dv: int, r: int, group):
+    """:func:`_specs` under a decay a key channel: ``(q | k, v | o, gamma,
+    beta, states)``, gamma rows beside ``k`` at the value heads' lanes, the
+    states values x keys."""
+    key, values, gates, _ = _specs(span, dk, dv, r, group)
+    return (key, values,
+            pl.BlockSpec((1, span, r * dk), lambda b, j, n: (b, group(n), j)),
+            gates,
+            pl.BlockSpec((1, r, GROUP, dv, dk),
+                         lambda b, j, n: (b, j, group(n), 0, 0)))
+
+
+def _channel_work(C: int, dk: int, dv: int):
+    """``(multiply-adds, exponentials)`` of a chunk-head's preparation and
+    forward scan under a decay a key channel, as the array sees them."""
+    blocks = C // SOLVE_BLOCK
+    return (_products(C, dk, dv) + 2 * SOLVE_BLOCK * blocks * C * dk
+            + C * (3 * dk * dv + C * dv),
+            C * dk * (3 + (blocks + 1) // 2 + 1))
+
+
+def _channel_walk(q, k, v, gamma, tile, *, chunk: int, states: bool,
+                  interpret: bool):
+    """``gated_delta_channel_fwd`` over ``gamma`` (B, S, Hv dk) of
+    :func:`_chunk_sums` and ``tile`` of :func:`_beta_tile``: ``o``, or with
+    ``states`` (``q`` None) the state entering each chunk."""
+    B, S, _ = gamma.shape
+    Hk = tile.shape[1]
+    dk = k.shape[-1] // Hk
+    Hv = gamma.shape[-1] // dk
+    dv = v.shape[-1] // Hv
+    r, C, N = Hv // Hk, chunk, S // chunk
+    key, values, rows, gates, state = _channel_specs(
+        GROUP * C, dk, dv, r, lambda n: n)
+    if states:
+        out_spec, out_shape = state, jax.ShapeDtypeStruct((B, Hv, N, dv, dk),
+                                                          _F32)
+    else:
+        out_spec, out_shape = values, jax.ShapeDtypeStruct(v.shape, v.dtype)
+    heads = B * Hv * N
+    macs, exps = _channel_work(C, dk, dv)
+    return pl.pallas_call(
+        functools.partial(_channel_fwd_kernel, chunk=C, r=r, states=states),
+        grid=(B, Hk, N // GROUP),
+        in_specs=([] if states else [key]) + [key, values, rows, gates],
+        out_specs=out_spec, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((r, dv, dk), _F32)],
+        compiler_params=_params(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * heads * macs, transcendentals=heads * exps,
+            bytes_accessed=2 * B * S * (2 * Hk * dk + 2 * Hv * dv)
+            + 4 * B * S * Hv * dk + 4 * B * Hk * _GATE_ROWS * S
+            + (4 * heads * dk * dv if states else 0)),
+        name="gated_delta_channel_fwd", interpret=interpret,
+    )(*(() if states else (q,)), k, v, gamma, tile)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "key_heads", "states",
+                                             "interpret"))
+def channel_forward(q, k, v, g, beta, *, chunk: int,
+                    key_heads: Optional[int] = None, states: bool = False,
+                    interpret: bool = False):
+    """:func:`forward` under a decay a key channel, ``g`` (B, S, Hv, dk):
+    ``o`` (B, S, Hv*dv), or with ``states`` the state entering each chunk,
+    (B, Hv, N, dv, dk) float32 (values x keys)."""
+    B, S, Hv, dk = g.shape
+    return _channel_walk(
+        q, k, v, _chunk_sums(g.reshape(B, S, Hv * dk), chunk),
+        _beta_tile(beta, key_heads or k.shape[-1] // dk), chunk=chunk,
+        states=states, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "key_heads",
+                                             "interpret"))
+def channel_backward(q, k, v, g, beta, do, *, chunk: int,
+                     key_heads: Optional[int] = None,
+                     interpret: bool = False):
+    """:func:`backward` under a decay a key channel: ``(dq, dk, dv, dg,
+    dbeta)``, ``dg`` (B, S, Hv, dk).  ``gamma`` is made once for the states'
+    walk and the walk back."""
+    B, S, Hv, dk = g.shape
+    dv = v.shape[-1] // Hv
+    Hk = key_heads or k.shape[-1] // dk
+    rows = _chunk_sums(g.reshape(B, S, Hv * dk), chunk)
+    tile = _beta_tile(beta, Hk)
+    saved = _channel_walk(None, k, v, rows, tile, chunk=chunk, states=True,
+                          interpret=interpret)
+    r, C, N = Hv // Hk, chunk, S // chunk
+    steps = N // GROUP
+    key, values, gamma, gates, state = _channel_specs(
+        GROUP * C, dk, dv, r, lambda n: steps - 1 - n)
+    heads = B * Hv * N
+    macs, exps = _channel_work(C, dk, dv)
+    dq, dk_, dv_, dgamma, dbeta = pl.pallas_call(
+        functools.partial(_channel_bwd_kernel, chunk=C, r=r),
+        grid=(B, Hk, steps),
+        in_specs=[key, key, values, gamma, gates, state, values],
+        out_specs=[key, key, values, gamma, gates],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(rows.shape, _F32),
+                   jax.ShapeDtypeStruct((B, Hk, _GATE_ROWS, S), _F32)],
+        scratch_shapes=[pltpu.VMEM((r, dv, dk), _F32)],
+        compiler_params=_params(),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * heads * (macs + 6 * C * C * (dk + dv)
+                               + 3 * SOLVE_BLOCK * (C // SOLVE_BLOCK) * C * dk
+                               + C * (5 * dk * dv + 2 * C * dv)),
+            transcendentals=heads * exps,
+            bytes_accessed=2 * B * S * (4 * Hk * dk + 3 * Hv * dv)
+            + 8 * B * S * Hv * dk + 8 * B * Hk * _GATE_ROWS * S
+            + 4 * heads * dk * dv),
+        name="gated_delta_channel_bwd", interpret=interpret,
+    )(q, k, v, rows, tile, saved, do)
+    dg = _chunk_sums(dgamma, C, reverse=True).reshape(g.shape)
+    dbeta = dbeta[:, :, :r].transpose(0, 3, 1, 2).reshape(B, S, Hv)
+    return dq, dk_, dv_, dg.astype(g.dtype), dbeta.astype(beta.dtype)

@@ -596,8 +596,10 @@ def test_what_the_rule_refuses_of_a_decay_a_channel():
     q, k, v, g, beta = _channel_inputs(1, 64, 2, 2, 16, pinned=False)
     with pytest.raises(ValueError, match="a decay a key channel is"):
         gated_delta_rule(q, k, v, g[..., :8], beta)
+    # float32 heads of 16 channels: what the channel kernels' guard refuses
     with pytest.raises(NotImplementedError,
-                       match="the kernels take one decay a head"):
+                       match=r"a decay a key channel \(2 heads x 16\): "
+                             r"operands of float32"):
         gated_delta_rule(q, k, v, g, beta, impl="pallas", interpret=True)
     with pytest.raises(ValueError, match="gated_delta_rule takes"):
         gated_delta_rule(q, k, v, g[..., 0, 0], beta)
@@ -614,8 +616,148 @@ def test_the_dispatch_says_a_decay_a_channel_and_the_gauge_its_width():
     assert channels() == 16
     assert [r[1:3] for r in dispatch_report() if r[0] == "gated_delta"
             and r[3] > before.get(r[:3], 0)] == [(
-                "xla", "a decay a key channel (2 heads x 16): the kernels "
-                "take one decay a head")]
+                "xla", "a decay a key channel (2 heads x 16): operands of "
+                "float32")]
     jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=16),
                    *_inputs(1, 64, 2, 2, 16))
     assert channels() == 1
+
+
+# ----------------------------------------------------------------------
+# the kernels under a decay a key channel (PR 60), in the interpreter
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("Hk,Hv,chunk", [(2, 2, 64), (1, 2, 64), (1, 1, 32)])
+def test_the_channel_kernels_are_the_xla_form_and_the_recurrence(Hk, Hv,
+                                                                 chunk):
+    """The kernels of ``g`` (B, S, Hv, dk) at the pinned decays of
+    :func:`_channel_inputs` (channels at the bound and at 0 over the whole
+    second 64 positions, and across the first chunk's edge), heads of 128
+    channels, two rows of 512 positions: two or four grid steps a
+    head-sequence, so the resident state and ``dS`` cross a group both
+    ways; one and two value heads a key head; chunks of 64 (four solve
+    blocks, joined twice) and of 32.  Forward and the five gradients: the
+    XLA form's to the rounding of bf16 operands, and as near the per-token
+    recurrence as it is; the log-decays' cotangent, formed from float32 sums
+    before ``dq`` and ``dk`` are rounded, no further from it."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert kernel.channel_supported(512 // chunk, chunk, 128, 128,
+                                    jnp.bfloat16, Hv // Hk) is None
+    args = _bf16(_channel_inputs(2, 512, Hk, Hv, 128))
+    probe = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (2, 512, Hv * 128)), jnp.float32)
+    (want, want_g), (got, got_g) = (_value_and_grads(_fused(chunk, how), probe)(*args)
+                                    for how in (None, True))
+    assert np.isfinite(float(got))
+    assert abs(float(got) - float(want)) < 2e-3 * abs(float(want))
+    with jax.default_matmul_precision("highest"):
+        exact = _value_and_grads(_channel_recurrence, probe)(
+            *(x.astype(jnp.float32) for x in args))[1]
+    for name, a, b, c in zip(NAMES, got_g, want_g, exact):
+        assert a.shape == c.shape and a.dtype == b.dtype, name
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        assert _rel(a, b) < 8e-3, name
+        assert _rel(a, c) < max(6e-3, 1.2 * _rel(b, c)), name
+
+
+def test_the_channel_kernels_in_float32_are_the_recurrence():
+    """The mathematics alone: float32 operands through the same bodies (the
+    guard would refuse them; the interpreter does not care) read the
+    recurrence to float32's rounding, the log-decays' cotangent closer than
+    ``jax.vjp`` of the XLA form, which carries the reference rows' noise."""
+    args = _channel_inputs(1, 256, 1, 1, 128, seed=2)
+    probe = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (1, 256, 128)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = _value_and_grads(_fused(64), probe)(*args)
+        by_xla = _value_and_grads(_fused(64, None), probe)(*args)
+        want = _value_and_grads(_channel_recurrence, probe)(*args)
+    assert abs(float(got[0]) - float(want[0])) < 2e-5 * (
+        1 + abs(float(want[0])))
+    for name, a, b, c in zip(NAMES, got[1], by_xla[1], want[1]):
+        assert _rel(a, c) < 2e-5, name
+    assert _rel(got[1][3], want[1][3]) <= _rel(by_xla[1][3], want[1][3])
+
+
+def test_equal_channels_in_the_kernels_are_the_head_decay_kernels():
+    """One op in the kernels too: ``g`` (B, S, Hv, dk) with every channel of
+    a head equal gives what the head-decay kernels give of ``g`` (B, S,
+    Hv), forward and backward, the channels' cotangents summing to the
+    head's (two value heads a key head)."""
+    q, k, v, g, beta = _bf16(_inputs(1, 256, 1, 2, 128, seed=3))
+    wide_g = jnp.broadcast_to(g[..., None], g.shape + (128,))
+    probe = jnp.asarray(np.random.default_rng(4).standard_normal(
+        v.shape), jnp.float32)
+    a = _value_and_grads(_fused(64), probe)(q, k, v, g, beta)
+    b = _value_and_grads(_fused(64), probe)(q, k, v, wide_g, beta)
+    assert abs(float(a[0]) - float(b[0])) < 2e-3 * (1 + abs(float(a[0])))
+    for name, x, y in zip(NAMES, a[1], b[1]):
+        assert _rel(y.sum(-1) if name == "g" else y, x) < 8e-3, name
+
+
+def test_rows_are_independent_in_the_channel_kernels():
+    """A row beside another gives what it gives alone, to the bit, and so
+    do its cotangents: no state, gamma row or block index crosses rows or
+    key heads."""
+    args = _bf16(_channel_inputs(2, 256, 2, 2, 128, seed=7))
+    run = _fused(64)
+    out = run(*args)
+    alone = run(*(x[1:] for x in args))
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(alone[0]))
+    assert np.abs(np.asarray(out[0] - out[1])).max() > 1e-3
+    grads = jax.jit(jax.grad(lambda *a: run(*a).sum(), range(5)))
+    for both, one in zip(grads(*args), grads(*(x[1:] for x in args))):
+        np.testing.assert_array_equal(np.asarray(both[1], np.float32),
+                                      np.asarray(one[0], np.float32))
+
+
+@pytest.mark.parametrize("n,chunk,dk,dv,dtype,r,said", [
+    (8, 32, 128, 128, jnp.float32, 1, "operands of float32"),
+    (8, 32, 96, 128, jnp.bfloat16, 1, "heads of 96 and 128 channels"),
+    (8, 32, 128, 192, jnp.bfloat16, 1, "heads of 128 and 192 channels"),
+    (8, 16, 128, 128, jnp.bfloat16, 1, "chunks of 16 positions"),
+    (8, 256, 128, 128, jnp.bfloat16, 1, "chunks of 256 positions"),
+    (6, 32, 128, 128, jnp.bfloat16, 1, "6 chunks are no whole groups of 4"),
+    (8, 32, 128, 128, jnp.bfloat16, 16, "16 value heads a key head"),
+    (8, 64, 512, 512, jnp.bfloat16, 2, "2 states of 512 x 512 float32"),
+])
+def test_what_the_channel_kernels_refuse_falls_to_xla_and_says_why(
+        n, chunk, dk, dv, dtype, r, said):
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert said in kernel.channel_supported(n, chunk, dk, dv, dtype, r)
+    S = n * chunk
+    args = [jax.ShapeDtypeStruct(shape, t) for shape, t in (
+        ((1, S, dk), dtype), ((1, S, dk), dtype), ((1, S, r * dv), dtype),
+        ((1, S, r, dk), jnp.float32), ((1, S, r), jnp.float32))]
+    with pytest.raises(NotImplementedError, match=said):
+        jax.eval_shape(lambda *a: gated_delta_rule(
+            *a, chunk=chunk, key_heads=1, impl="pallas"), *args)
+    jax.eval_shape(lambda *a: gated_delta_rule(*a, chunk=chunk, key_heads=1),
+                   *args)
+    assert any(site == "gated_delta" and impl == "xla" and said in why
+               and why.startswith(f"a decay a key channel ({r} heads x {dk})")
+               for site, impl, why, _ in dispatch_report())
+
+
+def test_the_plan_names_the_channel_form_and_its_tile():
+    """On one device the plan takes the channel kernels and says so in the
+    counter's label (what the driver's ``said.gated_delta_impl`` prints);
+    the gauge reads the key head's channels."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.comm.mesh import build_mesh
+
+    mesh_lib.set_mesh(build_mesh({"dp": 1}, devices=jax.devices()[:1]))
+    before = {r[:3]: r[3] for r in dispatch_report()}
+    try:
+        jax.eval_shape(lambda *a: gated_delta_rule(
+            *a, chunk=64, impl="pallas", interpret=True),
+            *_bf16(_channel_inputs(1, 256, 2, 2, 128, pinned=False)))
+    finally:
+        mesh_lib.set_mesh(None)
+    assert [r[1:3] for r in dispatch_report() if r[0] == "gated_delta"
+            and r[3] > before.get(r[:3], 0)] == [(
+                "pallas", "4 chunks of 64 x 2 key heads x 1 value heads of "
+                "128, a decay a key channel, fused; one device")]
+    family = get_registry().snapshot()["gated_delta_decay_channels"]
+    assert family["samples"][0]["value"] == 128

@@ -384,6 +384,50 @@ def test_the_new_scopes_and_counters_show_in_one_step(setup):
         "samples"]) >= n_sparse
 
 
+@pytest.mark.parametrize("head_dim,impl,said", [
+    (128, "pallas", "4 chunks of 64 x 2 key heads x 1 value heads of 128, a "
+                    "decay a key channel, fused; one device"),
+    (16, "xla", "a decay a key channel (2 heads x 16): heads of 16 and 16 "
+                "channels under a decay a key channel"),
+])
+def test_the_mixer_on_a_tpu_takes_the_channel_kernels(monkeypatch, head_dim,
+                                                      impl, said):
+    """``KimiDeltaAttention`` as the chip traces it (the guards see a TPU;
+    nothing is lowered): heads of 128 channels in bf16 resolve site
+    ``gated_delta`` to the kernels of a decay a key channel and the gauge
+    reads 128; heads of 16, the rehearsal's, keep XLA's form by the guard's
+    name and the gauge reads 16."""
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg = _config(num_attention_heads=2, head_dim=head_dim,
+                  qk_rope_head_dim=head_dim // 2, linear_chunk_size=64,
+                  dtype=jnp.bfloat16)
+    module = KimiDeltaAttention(cfg)
+    h = jax.ShapeDtypeStruct((1, 256, 64), jnp.bfloat16)
+    prev = mesh_lib.get_mesh(required=False)
+    mesh_lib.set_mesh(mesh_lib.build_mesh({"dp": 1},
+                                          devices=jax.devices()[:1]))
+    before = {r[:3]: r[3] for r in dispatch_report()}
+    try:
+        params = meta.unbox(jax.eval_shape(
+            module.init, jax.random.PRNGKey(0), h)["params"])
+        jax.eval_shape(jax.grad(lambda p, h: module.apply(
+            {"params": p}, h).astype(jnp.float32).sum()), params, h)
+    finally:
+        mesh_lib.set_mesh(prev)
+    ran = {r[1:3] for r in dispatch_report() if r[0] == "gated_delta"
+           and r[3] > before.get(r[:3], 0)}
+    assert len(ran) == 1, ran
+    got_impl, got_said = ran.pop()
+    assert got_impl == impl and got_said.startswith(said), got_said
+    snap = get_registry().snapshot()
+    assert snap["gated_delta_decay_channels"]["samples"][0][
+        "value"] == head_dim
+
+
 # ----------------------------------------------------------------------
 # what is not written raises by name
 # ----------------------------------------------------------------------

@@ -77,6 +77,49 @@ def test_compile_cache_default_is_one_in_checkout_path():
     assert paths[0] == paths[1] == os.path.join(ROOT, ".jax_cache")
 
 
+def test_a_run_does_not_evict_its_own_entries_from_a_capped_cache(tmp_path):
+    """Under a size cap JAX's cache evicts the least recently used entries
+    for a new one; ``enable_compile_cache`` makes it leave out an entry that
+    does not fit beside what this process has read or written and is larger
+    than all of that: it would evict them, the next run would write them
+    over it, and neither would ever hit (PR 60).  A smaller one is kept and
+    evicts as before, whichever came first; another process's entries are
+    no reason to refuse; without a cap nothing is refused."""
+    from jax._src.lru_cache import LRUCache
+
+    from deepspeed_tpu.utils import compile_cache
+
+    assert compile_cache._leave_room() and compile_cache._leave_room()
+    cache = LRUCache(str(tmp_path / "capped"), max_size=1000)
+    cache.put("small-a", b"a" * 200)
+    cache.put("small-b", b"b" * 200)
+    cache.put("large", b"c" * 700)          # 700 + 400 > 1000, 700 > 400
+    assert cache.get("large") is None
+    assert cache.get("small-a") == b"a" * 200
+    cache.put("third", b"d" * 350)          # fits beside them: kept
+    cache.put("fourth", b"e" * 350)         # does not, but is the smaller
+    assert cache.get("fourth") == b"e" * 350 and cache.get("small-b") is None
+    # the larger part first: it is kept until the smaller parts need the room
+    first = LRUCache(str(tmp_path / "first"), max_size=1000)
+    first.put("large", b"c" * 700)
+    first.put("small-a", b"a" * 200)
+    first.put("small-b", b"b" * 200)
+    assert first.get("large") is None and first.get("small-a") is not None
+    # the next process of the same program: what it reads counts as used
+    again = LRUCache(str(tmp_path / "first"), max_size=1000)
+    assert again.get("small-a") and again.get("small-b")
+    again.put("large", b"c" * 700)
+    assert again.get("large") is None and again.get("small-b") is not None
+    # what another process left there is evicted as JAX does
+    other = LRUCache(str(tmp_path / "first"), max_size=1000)
+    other.put("large", b"c" * 700)
+    assert other.get("large") == b"c" * 700 and other.get("small-a") is None
+    free = LRUCache(str(tmp_path / "free"), max_size=-1)
+    free.put("small", b"a" * 400)
+    free.put("large", b"c" * 950)
+    assert free.get("large") == b"c" * 950
+
+
 @pytest.fixture()
 def one_device_mesh():
     mesh_mod.set_mesh(mesh_mod.build_mesh({"dp": 1},

@@ -1549,13 +1549,61 @@ def test_the_tenth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     print(f"reserved {reserved:.3f} GiB")
 
 
+# (chunk, key heads, value heads, dk, dv, positions): the eleventh cell's own
+# shape, the three corners a reviewer of PR 60 named (chunk 128; two value
+# heads a key head; chunk 32), then - slow - the widest heads
+# ``channel_supported`` admits a chunk and a ratio, where its estimate of the
+# VMEM a grid step takes is nearest its limit
+_CHANNEL_CORNERS = [
+    (64, 32, 32, 128, 128, 8192), (128, 2, 2, 128, 128, 2048),
+    (64, 2, 4, 128, 128, 2048), (32, 2, 2, 128, 128, 2048)] + [
+    pytest.param(*c, 2048, marks=pytest.mark.slow) for c in (
+        (128, 2, 2, 256, 256), (128, 2, 4, 128, 128), (64, 2, 2, 512, 256),
+        (64, 2, 2, 256, 512), (64, 2, 4, 256, 256), (64, 2, 8, 128, 128),
+        (32, 2, 2, 512, 512), (32, 2, 4, 512, 128), (32, 2, 4, 256, 256),
+        (32, 2, 8, 256, 128), (32, 2, 8, 128, 256), (32, 2, 16, 128, 128))]
+
+
+@pytest.mark.parametrize("C,Hk,Hv,dk,dv,S", _CHANNEL_CORNERS)
+def test_the_channel_kernels_compile_at_every_corner_the_guard_admits(
+        one_chip, C, Hk, Hv, dk, dv, S):
+    """What ``channel_supported`` admits Mosaic compiles (PR 60): before the
+    kernels every shape under a decay a key channel ran XLA's form under
+    ``auto``, so a shape the guard lets through and VMEM does not hold would
+    fail to compile where it used to fall back.  Forward and backward are
+    three custom calls (``gated_delta_channel_fwd`` for ``o`` and again for
+    the states entering the chunks, ``gated_delta_channel_bwd``)."""
+    import re
+
+    from deepspeed_tpu.ops import gated_delta as ops
+    from deepspeed_tpu.ops.pallas import gated_delta as kernel
+
+    assert kernel.channel_supported(S // C, C, dk, dv, jnp.bfloat16,
+                                    Hv // Hk) is None
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (sd((1, S, Hk * dk), jnp.bfloat16), sd((1, S, Hk * dk),
+                                                  jnp.bfloat16),
+            sd((1, S, Hv * dv), jnp.bfloat16), sd((1, S, Hv, dk), jnp.float32),
+            sd((1, S, Hv), jnp.float32))
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: ops._rule(*a, C, False, Hk).astype(jnp.float32).sum(),
+        range(5))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert len(re.findall(r"gated_delta_channel_fwd[.\d]* = ", text)) == 2
+    assert len(re.findall(r"gated_delta_channel_bwd[.\d]* = ", text)) == 1
+
+
 @pytest.mark.slow
 def test_the_eleventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     """``train-ling3-kda-8k-1chip`` (PR 58) as the benchmark builds it, its
     whole train step compiled for the described chip: five
     Kimi-Delta-Attention blocks whose delta rule (a decay a key channel) is
-    XLA's program, one gated latent-attention block through the two-product
-    flash kernels, 8 of 512 group-routed experts held; 767,336,736
+    the channel kernels ``gated_delta_channel_fwd`` / ``_bwd`` since PR 60
+    (XLA's program before), one gated latent-attention block through the
+    two-product flash kernels, 8 of 512 group-routed experts held; 767,336,736
     parameters in the leaves (the issue's 767,009,056 and the 64 padded rows
     of the table and the head); and what the step reserves at one packed
     8,192-token row stays under the chip's 15.75 GiB (two rows ask 16.01:
@@ -1596,16 +1644,22 @@ def test_the_eleventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
     assert 0.25 * 15.75 < reserved < 15.75, reserved
     text = compiled.as_text()
-    # the latent-attention layer's kernels once a pass; no delta-rule kernel
+    # the latent-attention layer's kernels once a pass; the delta rule's
+    # under a decay a key channel, and none of a decay a head's
     assert len(re.findall(r'kernel_name = "self_attn_mla', text)) >= 1 \
         or "self_attn_mla" in text
+    assert "gated_delta_channel_fwd" in text \
+        and "gated_delta_channel_bwd" in text
     assert "gated_delta_fwd" not in text and "gated_delta_bwd" not in text
     rows_of = {(s, i): r for s, i, r, n in dispatch_report() if n}
     assert {("attention", "flash"), ("grouped_matmul", "megablox"),
             ("moe_rows", "pallas"), ("qk_rows", "pallas"),
-            ("short_conv", "pallas"), ("gated_delta", "xla")} <= set(rows_of)
-    assert "a decay a key channel (32 heads x 128)" in rows_of[
-        ("gated_delta", "xla")]
+            ("short_conv", "pallas"), ("gated_delta", "pallas")} <= set(
+                rows_of)
+    assert ("gated_delta", "xla") not in rows_of
+    assert rows_of[("gated_delta", "pallas")].startswith(
+        "128 chunks of 64 x 32 key heads x 1 value heads of 128, a decay a "
+        "key channel, fused")
     assert "shared rope lanes" in rows_of[("attention", "flash")]
     assert ("attention", "jnp") not in rows_of
     print(f"reserved {reserved:.3f} GiB")
