@@ -1900,6 +1900,76 @@ def kernel_short_conv(time_it: bool = True):
         del want, got, rows, dy
 
 
+def kernel_moe_swiglu(R: int = 262144, F: int = 896, live_share=0.248):
+    """The SwiGLU between a share's grouped matmuls (PR 61;
+    ``ops/pallas/moe_rows.py swiglu_rows`` / ``swiglu_rows_back``) at
+    Mellum 2's shape, ``[a | b]`` (262144, 1792) with 24.8% of the rows
+    holding a pair and NaN in the others: both passes against ``jax.numpy``
+    in float32 over the live rows, then timed from a profiler trace beside
+    XLA's elementwise form over the whole buffer (what the program ran
+    before), each with its GB/s over the bytes of the LIVE rows."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas import moe_rows
+
+    n_live = int(R * live_share) // 16 * 16 + 8     # ends inside a block
+    live = jnp.full((1,), n_live, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(61), 2)
+    dead = (jnp.arange(R) >= n_live)[:, None]
+    ab = jnp.where(dead, jnp.nan, 2 * jax.random.normal(
+        ks[0], (R, 2 * F), jnp.float32)).astype(jnp.bfloat16)
+    dh = jnp.where(dead, jnp.nan, jax.random.normal(
+        ks[1], (R, F), jnp.float32)).astype(jnp.bfloat16)
+
+    def plain(ab):
+        a, b = jnp.split(ab, 2, axis=1)
+        return jax.nn.silu(a) * b
+
+    def ref(ab, dh):
+        out, vjp = jax.vjp(plain, ab[:n_live].astype(jnp.float32))
+        return out, vjp(dh[:n_live].astype(jnp.float32))[0]
+
+    def xla(ab, dh):
+        out, vjp = jax.vjp(plain, ab)
+        return out, vjp(dh)[0]
+
+    def rows(ab, dh):
+        return (moe_rows.swiglu_rows(ab, live),
+                moe_rows.swiglu_rows_back(dh, ab, live))
+
+    want = jax.jit(ref)(ab, dh)
+    got = jax.block_until_ready(jax.jit(rows)(ab, dh))
+    for n, g, w in zip(("h", "d[a | b]"), got, want):
+        _check_close(f"moe_swiglu {n}", g[:n_live], w)
+    out = tempfile.mkdtemp(prefix="moe_swiglu_trace_")
+    runs = {"rows": jax.jit(rows), "xla": jax.jit(xla)}
+    for run in runs.values():
+        jax.block_until_ready(run(ab, dh))
+    with jax.profiler.trace(out):
+        for _ in range(5):
+            for run in runs.values():
+                jax.block_until_ready(run(ab, dh))
+    moved = {"fwd": 6 * n_live * F, "bwd": 14 * n_live * F}
+    for call, ns in sorted(_traced_op_times(out).items()):
+        if len(ns) < 5 or np.mean(ns) < 5e4:
+            continue
+        ms = np.mean(ns) / 1e6
+        pas = "bwd" if "back" in call else "fwd"
+        print(f"  moe_swiglu: {call} {len(ns)} calls, {ms:.3f} ms a call"
+              + (f", {moved[pas] / ms / 1e6:.0f} GB/s over the {pas} pass's "
+                 f"live bytes" if call.startswith("moe_swiglu") else ""),
+              flush=True)
+    print(f"  moe_swiglu: {n_live} of {R} rows live; bytes of the live rows "
+          f"forward {moved['fwd'] / 1e6:.0f} MB, backward "
+          f"{moved['bwd'] / 1e6:.0f} MB (at 819 GB/s: "
+          f"{moved['fwd'] / 819e6:.3f} and {moved['bwd'] / 819e6:.3f} ms)",
+          flush=True)
+
+
 KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_two_products, kernel_flash_blockdiff,
                 kernel_flash_lanes_256, kernel_indexed_attention,
@@ -1909,7 +1979,8 @@ KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_qk_rows,
                 kernel_short_conv,
                 kernel_grouped_matmul,
-                kernel_share_dispatch, kernel_full_dispatch, kernel_adam8bit,
+                kernel_share_dispatch, kernel_full_dispatch, kernel_moe_swiglu,
+                kernel_adam8bit,
                 kernel_decode_attention, kernel_paged_attention,
                 kernel_decode_layer, kernel_w8_matmul)
 

@@ -8,14 +8,15 @@ rows costs nothing, forward or backward.
 
 On one TPU device it is the Pallas grouped matmul that ships with JAX
 (``jax.experimental.pallas.ops.tpu.megablox``: ``gmm`` forward and
-d-rows, ``tgmm`` d-weights, the names the device trace shows) at
-:data:`TILES`.  PR 26 measured it on the v5e against ``jax.lax.ragged_dot``
-(which the TPU compiler lowers to a Mosaic grouped matmul of its own,
-``ragged-dot-none`` in the trace) at OLMoE's shapes, (65536, 2048) x
-(64, 2048, 1024): alone 5.40 against 8.01 ms forward + backward with even
-groups and 7.42 against 10.6 ms with uneven ones; end to end 33,120
-against 28,938 / 29,741 tokens/s/chip, and steady where the ragged dot's
-time moved 2.7% with the routing (PERF.md section 6).  A Pallas call is
+d-rows, ``tgmm`` d-weights, the names the device trace shows; since PR 61
+the d-weights first, :func:`_megablox`) at :data:`TILES`.  PR 26 measured
+it on the v5e against ``jax.lax.ragged_dot`` (which the TPU compiler lowers
+to a Mosaic grouped matmul of its own, ``ragged-dot-none`` in the trace)
+at OLMoE's shapes, (65536, 2048) x (64, 2048, 1024): alone 5.40 against
+8.01 ms forward + backward with even groups and 7.42 against 10.6 ms with
+uneven ones; end to end 33,120 against 28,938 / 29,741 tokens/s/chip, and
+steady where the ragged dot's time moved 2.7% with the routing (PERF.md
+section 6).  A Pallas call is
 opaque to the partitioner, so the kernel takes one device's own rows: the
 caller says ``per_device`` when it is on one device or inside a
 ``shard_map`` (``parallel/moe.py sorted_dispatch`` runs it so on every
@@ -40,6 +41,13 @@ are ``jnp.take``; ``kernel_dispatch_total{site="moe_rows"}`` says which,
 and why.  A row without a pair reads zeros, with one exception: going out,
 the kernel writes nothing past the block that holds the last pair, because
 the one reader of those rows, the grouped matmul, skips them.
+
+Between the grouped matmuls of a share's SwiGLU experts stands ONE buffer
+``[a | b]`` (:func:`swiglu_plan`): one product with ``[gate | up]``, the
+activation a Pallas row kernel over the rows that hold a pair
+(:func:`swiglu_rows`), and in the backward one product for the rows'
+cotangent - summed over ``2F`` in float32 where two products were rounded
+and added - and one for ``d[gate | up]``.
 """
 from __future__ import annotations
 
@@ -102,10 +110,40 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                  f"tiles {tiles}", fallback="ragged_dot", kernel="megablox",
                  shard=False) is None:
         return jax.lax.ragged_dot(lhs, rhs, group_sizes)
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    return _megablox(lhs, rhs, group_sizes, tiles)
 
-    return gmm(lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-               tiling=tiles)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _megablox(lhs, rhs, sizes, tiles):
+    """megablox's ``gmm`` with its two backward products in an order: the
+    weights' gradient (``tgmm``) first and, behind an optimization barrier,
+    the rows' (``gmm``).  Left to itself XLA's scheduler puts off the
+    weight gradients of the first layer it differentiates, and the rows
+    they read - (S k, M) each, most of a share's dead - stay allocated
+    under the rows' cotangent: Qwen3-Next's step reserved 10.10 GiB of
+    temporaries so and 8.64 in this order (sandbox compiles for the
+    described v5e, PR 61)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(lhs, rhs, sizes, lhs.dtype, tiles)
+
+
+def _megablox_fwd(lhs, rhs, sizes, tiles):
+    return _megablox(lhs, rhs, sizes, tiles), (lhs, rhs, sizes)
+
+
+def _megablox_bwd(tiles, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    lhs, rhs, sizes = res
+    d_rhs = tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, tiles,
+                 num_actual_groups=rhs.shape[0])
+    g, d_rhs = jax.lax.optimization_barrier((g, d_rhs))
+    return gmm(g, rhs, sizes, lhs.dtype, tiles, transpose_rhs=True), \
+        d_rhs, None
+
+
+_megablox.defvjp(_megablox_fwd, _megablox_bwd)
 
 
 def _rows(a: jax.Array, index: jax.Array, absent: bool) -> jax.Array:
@@ -287,3 +325,63 @@ def _combine_pallas_bwd(absent, res, g):
 
 
 _combine_pallas.defvjp(_combine_pallas_fwd, _combine_pallas_bwd)
+
+
+def swiglu_plan(x, width: int, per_device: Optional[bool],
+                absent: bool) -> bool:
+    """Whether a SwiGLU expert FFN over the rows ``x`` (R, M) in expert
+    order keeps ONE buffer ``[a | b]`` (R, 2 ``width``) between its
+    grouped matmuls - one product with ``[gate | up]``, then
+    :func:`swiglu_rows` - or a product each and XLA's ``silu(a) * b``;
+    counted, with the guard that decided, in
+    ``kernel_dispatch_total{site="moe_swiglu"}``.  The one buffer is a
+    share's (``absent``) on one TPU device: XLA's elementwise passes and
+    the sum of the rows' two cotangents run over every row of the buffer,
+    dead or live, and three rows in four hold no pair.  A full permutation
+    has nothing to skip, and the copies that make ``[gate | up]`` and split
+    its gradient (XLA fuses neither with the leaves' casts) cost its many
+    experts more than the sum they spare (OLMoE: 2.1 against 1.3 GB a
+    layer; PERF.md section 6, PR 61)."""
+    from .pallas import moe_rows, spmd
+
+    R = x.shape[0]
+    if not absent:
+        refusal = "every row holds a pair"
+    else:
+        refusal = GLOBAL if per_device is False \
+            else moe_rows.swiglu_supported(R, 2 * width, x.dtype)
+    return spmd.plan("moe_swiglu", _own(per_device, R), refusal,
+                     f"rows {R} x {2 * width}, block {moe_rows.GLU}",
+                     shard=False) is not None
+
+
+def swiglu_rows(ab: jax.Array, order: jax.Array) -> jax.Array:
+    """``silu(a) * b`` of ``ab = [a | b]`` (R, 2F), the rows in expert
+    order as :func:`repeat_gather` left a share's (``order``: its partial
+    permutation) -> (R, F), where :func:`swiglu_plan` said so: the Pallas
+    row kernel over the blocks that hold a pair, forward and backward (HLO
+    custom calls ``moe_swiglu_rows`` / ``moe_swiglu_rows_back``).  The
+    blocks past them are neither read nor written: their readers, the
+    grouped matmuls, skip those rows."""
+    return _swiglu_pallas(ab, _live(order, True))
+
+
+@jax.custom_vjp
+def _swiglu_pallas(ab, live):
+    from .pallas import moe_rows
+
+    return moe_rows.swiglu_rows(ab, live)
+
+
+def _swiglu_pallas_fwd(ab, live):
+    return _swiglu_pallas(ab, live), (ab, live)
+
+
+def _swiglu_pallas_bwd(res, dh):
+    from .pallas import moe_rows
+
+    ab, live = res
+    return moe_rows.swiglu_rows_back(dh, ab, live), None
+
+
+_swiglu_pallas.defvjp(_swiglu_pallas_fwd, _swiglu_pallas_bwd)

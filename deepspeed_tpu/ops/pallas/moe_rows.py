@@ -31,6 +31,13 @@ it serves); here the conversion happens in VMEM, beside the DMAs:
   cotangent instead and summed over the width:
   ``dw[s, j] = <g[s], y[inv[s, j]]>``.  No ``(S, k, M)`` array exists.
 
+Between a share's grouped matmuls (PR 61) :func:`swiglu_rows` and
+:func:`swiglu_rows_back` run ``silu(a) * b`` and its cotangent over ONE
+2-D buffer ``[a | b]``, bf16 in and out and float32 inside, a block of
+:data:`GLU` rows a grid step and, like :func:`pack_rows`, only the blocks
+that hold a live row: XLA's elementwise passes run over every row of the
+buffer, and three in four hold no pair.
+
 Indices arrive in SMEM a block of :data:`STEP` a grid step (XLA lays
 ``s32[n]`` out in tiles of 1024 and the whole of 262,144 indices is the
 chip's entire 1 MB of SMEM, so they cannot be scalar-prefetched whole);
@@ -90,6 +97,8 @@ SUB = 256
 PACK = 256
 # rows one semaphore wait awaits
 WAIT = 16
+# rows a block of the SwiGLU kernels
+GLU = 256
 _HIGH = 0xFFFF0000
 
 
@@ -516,3 +525,107 @@ def combine_rows(src: jax.Array, idx: jax.Array, weights: jax.Array,
         name=name, interpret=interpret,
     )(counts, entries, src, weights, *((g,) if dw else ()))
     return out[:, :k_given] if dw else out
+
+
+def _live_pieces(live_ref, rows, width, piece):
+    """``piece(at, lo, hi)`` for every 16 rows ``at`` and 128 lanes ``lo``
+    of ``a`` (``hi``: the same lanes of ``b``) of a block of ``[a | b]``
+    that holds a live row."""
+    def group(i, carry):
+        at = pl.ds(pl.multiple_of(i * 16, 16), 16)
+        for col in range(0, width, 128):
+            piece(at, pl.ds(col, 128), pl.ds(width + col, 128))
+        return carry
+
+    @pl.when(pl.program_id(0) * rows < live_ref[0])
+    def _():
+        lax.fori_loop(0, rows // 16, group, 0)
+
+
+def _swiglu_kernel(live_ref, ab_ref, out_ref):
+    def piece(at, lo, hi):
+        a = ab_ref[at, lo].astype(jnp.float32)
+        b = ab_ref[at, hi].astype(jnp.float32)
+        out_ref[at, lo] = (a * jax.nn.sigmoid(a) * b).astype(out_ref.dtype)
+
+    _live_pieces(live_ref, *out_ref.shape, piece)
+
+
+def _swiglu_back_kernel(live_ref, dh_ref, ab_ref, out_ref):
+    def piece(at, lo, hi):
+        a = ab_ref[at, lo].astype(jnp.float32)
+        b = ab_ref[at, hi].astype(jnp.float32)
+        dh = dh_ref[at, lo].astype(jnp.float32)
+        s = jax.nn.sigmoid(a)
+        # silu'(a) = s + a s (1 - s), from the forward's sigmoid
+        out_ref[at, lo] = (dh * b * (s * (1 + a * (1 - s)))).astype(
+            out_ref.dtype)
+        out_ref[at, hi] = (dh * (a * s)).astype(out_ref.dtype)
+
+    _live_pieces(live_ref, *dh_ref.shape, piece)
+
+
+def swiglu_supported(rows: int, width: int, dtype) -> Optional[str]:
+    """``None`` where the SwiGLU kernels take ``[a | b]`` (``rows``,
+    ``width``), else the reason they do not."""
+    if dtype != jnp.bfloat16:
+        return f"rows of {jnp.dtype(dtype).name}"
+    if width % 256:
+        return f"[a | b] of {width} is no two halves of whole 128-lane tiles"
+    if rows % GLU:
+        return f"{rows} rows are no whole blocks of {GLU}"
+    return None
+
+
+def _live_rows(kernel, live, operands, out_width, flops, name, interpret,
+               donor=None):
+    """``kernel`` over (:data:`GLU`, width) blocks of 2-D ``operands`` with
+    as many rows, ``[a | b]`` the last, the blocks past the ``live`` rows
+    left alone.  ``donor``: the operand whose buffer the output takes (a
+    block is read before it is written)."""
+    R = operands[0].shape[0]
+    widths = [operand.shape[1] for operand in operands]
+    block = _live_block(GLU)
+
+    def spec(width):
+        return pl.BlockSpec((GLU, width), lambda i, live: (block(i, live), 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(R // GLU,),
+            in_specs=[spec(w) for w in widths], out_specs=spec(out_width)),
+        out_shape=jax.ShapeDtypeStruct((R, out_width), jnp.bfloat16),
+        cost_estimate=pl.CostEstimate(
+            flops=flops, transcendentals=R * widths[-1] // 2,
+            bytes_accessed=2 * R * (sum(widths) + out_width)),
+        input_output_aliases={} if donor is None else {1 + donor: 0},
+        name=name, interpret=interpret,
+    )(live, *operands)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def swiglu_rows(ab: jax.Array, live: jax.Array, *,
+                interpret: bool = False) -> jax.Array:
+    """``silu(a) * b`` of ``ab = [a | b]`` (R, 2F) bf16 as (R, F) bf16,
+    float32 inside.  Blocks of :data:`GLU` rows wholly past the first
+    ``live`` (1,) int32 rows are neither read nor written (they hold
+    whatever memory held): their one reader, the grouped matmul, reads no
+    row past its groups."""
+    R, width = ab.shape
+    _note_trace("swiglu", R, width)
+    return _live_rows(_swiglu_kernel, live, (ab,), width // 2, 2 * R * width,
+                      "moe_swiglu_rows", interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def swiglu_rows_back(dh: jax.Array, ab: jax.Array, live: jax.Array, *,
+                     interpret: bool = False) -> jax.Array:
+    """:func:`swiglu_rows`'s cotangent ``d[a | b]`` (R, 2F) bf16 of ``dh``
+    (R, F): ``[dh b silu'(a) | dh silu(a)]``, with the same blocks left
+    alone."""
+    R, width = ab.shape
+    _note_trace("swiglu_back", R, width)
+    return _live_rows(_swiglu_back_kernel, live, (dh, ab), width,
+                      5 * R * width, "moe_swiglu_rows_back", interpret,
+                      donor=1)

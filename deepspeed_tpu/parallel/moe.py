@@ -70,7 +70,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..comm import mesh as mesh_lib
-from ..ops.grouped_matmul import combine_rows, grouped_matmul, repeat_gather
+from ..ops.grouped_matmul import (combine_rows, grouped_matmul, repeat_gather,
+                                  swiglu_plan, swiglu_rows)
 from ..telemetry import registry, trace
 
 
@@ -418,12 +419,18 @@ class TopKGate(nn.Module):
             f"top-k dispatch")
 
 
-def _expert_ffn(act: str, ws, x, matmul):
+def _expert_ffn(act: str, ws, x, matmul, swiglu=None):
     """The expert FFN over ``matmul(x, w)``, which pairs every row of ``x``
-    with its own expert's matrix of the (E, ., .) leaf ``w``."""
+    with its own expert's matrix of the (E, ., .) leaf ``w``.  ``swiglu``
+    (the sorted dispatch of a share: ``ops/grouped_matmul.py swiglu_plan``)
+    is ``silu(a) * b`` of ``[a | b]``, ONE product with ``[gate | up]``;
+    without it gate and up are a product each."""
     if act == "swiglu":
         gate, up, down = ws
-        return matmul(nn.silu(matmul(x, gate)) * matmul(x, up), down)
+        if swiglu is None:
+            return matmul(nn.silu(matmul(x, gate)) * matmul(x, up), down)
+        return matmul(swiglu(matmul(x, jnp.concatenate([gate, up], axis=2))),
+                      down)
     wi, wo = ws
     return matmul(nn.gelu(matmul(x, wi), approximate=True), wo)
 
@@ -453,7 +460,11 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
     holds a pair, none for the three in four that hold none, the weighted
     sum in VMEM with no ``(S, k, M)`` array); with every expert held, or
     anywhere else, XLA's gathers (``kernel_dispatch_total{site="moe_rows"}``
-    says which and why).
+    says which and why).  Between the grouped matmuls of a share's SwiGLU
+    experts stands one buffer ``[a | b]``: one product with
+    ``[gate | up]`` and the row kernel ``swiglu_rows`` over the rows that
+    hold a pair, where a full permutation has a product each and XLA's
+    ``silu(a) * b`` (``kernel_dispatch_total{site="moe_swiglu"}``).
 
     Tokens do not interact, so under data parallelism each rank does this
     for its own tokens inside a ``shard_map`` over the batch axes (its own
@@ -490,8 +501,12 @@ def sorted_dispatch(x: jax.Array, weights: jax.Array, chosen: jax.Array,
             rows = repeat_gather(x, order, inv, share,            # (S*k, M)
                                  per_device=own)
         with trace.device_span("moe/experts"):
-            rows = _expert_ffn(act, ws, rows, lambda a, w: grouped_matmul(
-                a, w, sizes, per_device=own))
+            one_buffer = act == "swiglu" and swiglu_plan(
+                rows, ws[0].shape[2], own, share)
+            rows = _expert_ffn(
+                act, ws, rows,
+                lambda a, w: grouped_matmul(a, w, sizes, per_device=own),
+                (lambda ab: swiglu_rows(ab, order)) if one_buffer else None)
         with trace.device_span("moe/combine"):
             return combine_rows(rows, weights, order, inv, share,
                                 per_device=own)

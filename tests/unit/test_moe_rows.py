@@ -25,9 +25,20 @@ def _interpreted(monkeypatch):
     """The kernel path on this CPU: the guard sees a TPU, the kernels run
     in the interpreter."""
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    for name in ("pack_rows", "gather_rows", "combine_rows"):
+    for name in ("pack_rows", "gather_rows", "combine_rows", "swiglu_rows",
+                 "swiglu_rows_back"):
         monkeypatch.setattr(moe_rows, name, functools.partial(
             getattr(moe_rows, name), interpret=True))
+
+
+def _forced(monkeypatch):
+    """One device's own operands and a TPU, whatever this host is: the
+    guards choose the kernels' path."""
+    from deepspeed_tpu.ops.pallas import spmd
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(spmd, "kernel_mesh_plan",
+                        lambda *a, **kw: ("direct", None))
 
 
 def _routing(absent, seed=0):
@@ -177,6 +188,87 @@ def test_gather_rows_writes_no_block_past_the_live_rows():
     assert not rows[live:2 * moe_rows.STEP].any()
 
 
+GLU_ROWS = 4 * moe_rows.GLU
+# no row, inside a block, a block's edge, every row
+GLU_LIVE = [0, 300, 2 * moe_rows.GLU, GLU_ROWS]
+
+
+@functools.lru_cache(maxsize=None)
+def _swiglu(F, live):
+    """Both passes of the SwiGLU row kernels in the interpreter over
+    ``[a | b]`` (GLU_ROWS, 2F) whose rows from ``live`` on hold NaN, as
+    does ``dh``, and ``jax.numpy``'s float32 arithmetic over the live
+    rows."""
+    ks = jax.random.split(jax.random.PRNGKey(F + live), 2)
+    dead = (jnp.arange(GLU_ROWS) >= live)[:, None]
+    ab = jnp.where(dead, jnp.nan, 2 * jax.random.normal(
+        ks[0], (GLU_ROWS, 2 * F), jnp.float32)).astype(jnp.bfloat16)
+    dh = jnp.where(dead, jnp.nan, jax.random.normal(
+        ks[1], (GLU_ROWS, F), jnp.float32)).astype(jnp.bfloat16)
+    n = jnp.array([live], jnp.int32)
+    got = (moe_rows.swiglu_rows(ab, n, interpret=True),
+           moe_rows.swiglu_rows_back(dh, ab, n, interpret=True))
+
+    def plain(ab):
+        return jax.nn.silu(ab[:, :F]) * ab[:, F:]
+
+    h, vjp = jax.vjp(plain, ab[:live].astype(jnp.float32))
+    want = h, vjp(dh[:live].astype(jnp.float32))[0]
+    return ([np.asarray(g, np.float32) for g in got],
+            [np.asarray(w.astype(jnp.bfloat16), np.float32) for w in want])
+
+
+@pytest.mark.parametrize("back", [False, True])
+@pytest.mark.parametrize("live", GLU_LIVE)
+@pytest.mark.parametrize("F", [512, 768, 896, 1024, 1536])
+def test_swiglu_rows(F, live, back):
+    """bf16 in, float32 inside, bf16 out, at the cells' expert widths: the
+    live rows are ``jax.numpy``'s to a bf16 ulp (no NaN of a dead row
+    reaches one)."""
+    got, want = (side[back] for side in _swiglu(F, live))
+    assert got.shape == (GLU_ROWS, 2 * F if back else F)
+    np.testing.assert_allclose(got[:live], want, rtol=2.0 ** -7, atol=1e-30)
+
+
+def test_swiglu_rows_leaves_the_blocks_past_the_live_rows():
+    """Every row of the operands finite: the two blocks that hold the 300
+    live rows are written whole, the two past them not at all - forward
+    they hold what memory held (in the interpreter NaN), backward what
+    ``[a | b]`` held, whose buffer ``d[a | b]`` takes."""
+    F, live = 512, 300
+    ks = jax.random.split(jax.random.PRNGKey(61), 2)
+    ab = jax.random.normal(ks[0], (GLU_ROWS, 2 * F), jnp.bfloat16)
+    dh = jax.random.normal(ks[1], (GLU_ROWS, F), jnp.bfloat16)
+    n = jnp.array([live], jnp.int32)
+    past = 2 * moe_rows.GLU
+    h = np.asarray(moe_rows.swiglu_rows(ab, n, interpret=True), np.float32)
+    assert np.isfinite(h[:past]).all() and np.isnan(h[past:]).all()
+    d = np.asarray(moe_rows.swiglu_rows_back(dh, ab, n, interpret=True),
+                   np.float32)
+    sentinel = np.asarray(ab, np.float32)
+    assert (d[past:] == sentinel[past:]).all()
+    assert not (d[:past] == sentinel[:past]).all(axis=1).any()
+
+
+def test_swiglu_rows_keeps_dead_rows_to_themselves():
+    """NaN in the dead rows of ``ab`` and ``dh``, also those that share
+    the last live block: the live rows read as with zeros there."""
+    F, live = 768, 300
+    (h, d), _ = _swiglu(F, live)
+    ks = jax.random.split(jax.random.PRNGKey(F + live), 2)
+    dead = (jnp.arange(GLU_ROWS) >= live)[:, None]
+    ab = jnp.where(dead, 0, 2 * jax.random.normal(
+        ks[0], (GLU_ROWS, 2 * F), jnp.float32)).astype(jnp.bfloat16)
+    dh = jnp.where(dead, 0, jax.random.normal(
+        ks[1], (GLU_ROWS, F), jnp.float32)).astype(jnp.bfloat16)
+    n = jnp.array([live], jnp.int32)
+    assert np.isfinite(h[:live]).all() and np.isfinite(d[:live]).all()
+    assert (h[:live] == np.asarray(moe_rows.swiglu_rows(
+        ab, n, interpret=True), np.float32)[:live]).all()
+    assert (d[:live] == np.asarray(moe_rows.swiglu_rows_back(
+        dh, ab, n, interpret=True), np.float32)[:live]).all()
+
+
 def _reasons():
     return {(impl, reason) for site, impl, reason, _ in dispatch_report()
             if site == "moe_rows"}
@@ -221,18 +313,14 @@ def test_a_model_traces_each_kernel_once_a_signature(monkeypatch):
     context (the calls sit behind ``jax.jit``, whose cache also keys on
     the context: the plain trace and the one under ``grad`` of a remat
     block differ) - not once a layer and pass, which would be 9 to 21
-    entries a kernel - and there are six signatures: the row form of
+    entries a kernel - and there are eight signatures: the row form of
     (S, M) and of (S k, M), the gather plain and scaled, the combine and
-    its d-weights."""
+    its d-weights, the SwiGLU between the products and its backward
+    (PR 61)."""
     from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from deepspeed_tpu.parallel.moe import MoEConfig
 
-    from deepspeed_tpu.ops.pallas import spmd
-
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    # one device's own operands, whatever this host's device count
-    monkeypatch.setattr(spmd, "kernel_mesh_plan",
-                        lambda *a, **kw: ("direct", None))
+    _forced(monkeypatch)
     moe = MoEConfig(num_experts=2, routed_experts=8, first_expert=2, top_k=4,
                     drop_tokens=False, norm_topk_prob=True,
                     expert_act="swiglu")
@@ -256,8 +344,9 @@ def test_a_model_traces_each_kernel_once_a_signature(monkeypatch):
     new = {key: n - before.get(key, 0) for key, n in _traces().items()
            if n - before.get(key, 0)}
     assert new and all(n <= 2 for n in new.values()), new
-    assert len(new) == 6, new
-    assert {kernel for kernel, _ in new} == {"pack", "gather", "combine"}
+    assert len(new) == 8, new
+    assert {kernel for kernel, _ in new} == {"pack", "gather", "combine",
+                                             "swiglu", "swiglu_back"}
 
 
 # ----------------------------------------------------------------------
@@ -481,11 +570,14 @@ def test_the_probes_book_the_starts_from_a_steps_counts(monkeypatch):
 
 
 # sha256 of what one expert layer with EVERY expert held (OLMoE's kind: a
-# full permutation, which the guard leaves to XLA) traces to on a TPU,
-# forward + backward with its statistics, taken at the parent commit
-# (db2bc0b): nothing of this PR reaches a program without the kernels
+# full permutation, which the guards leave to XLA's gathers, a product each
+# for gate and up and XLA's SwiGLU) traces to on a TPU, forward + backward
+# with its statistics.  Re-pinned by PR 61 (d81e2655... at its parent,
+# 5f07282): the grouped matmul's backward is now ``ops/grouped_matmul.py
+# _megablox_bwd``, the same two kernels with the weights' gradient first;
+# nothing else of PRs 46 and 61 reaches a program without the row kernels
 PARENT_FULL_LAYER = \
-    "d81e26556fe03270e6e4704e46f108e7bff7d0878cf2450795057de26343b44f"
+    "18e851c7d67d23dc5f13de994fec0b63b8bbaeea921213b1d8525b6cc5591fb0"
 
 
 @pytest.mark.parametrize("share", [False, True])
@@ -496,12 +588,9 @@ def test_a_layers_program_counts_no_starts(monkeypatch, share):
     import hashlib
     import re
 
-    from deepspeed_tpu.ops.pallas import spmd
     from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
 
-    monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    monkeypatch.setattr(spmd, "kernel_mesh_plan",
-                        lambda *a, **kw: ("direct", None))
+    _forced(monkeypatch)
     held = dict(routed_experts=32, first_expert=8) if share else {}
     cfg = MoEConfig(num_experts=8, top_k=8 if share else 4,
                     drop_tokens=False, norm_topk_prob=True,
@@ -523,3 +612,188 @@ def test_a_layers_program_counts_no_starts(monkeypatch, share):
     jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, has_aux=True))(p, x)
     text = re.sub(r" at /\S+:\d+", "", str(jaxpr))
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_FULL_LAYER
+
+
+def _kernel_calls(fn, *args):
+    """``{name: count}`` of the kernel calls ``fn`` traces to, inner
+    jaxprs included: a ``pallas_call`` of this repo by its own name, one
+    of megablox's (which names none) by the ``jax.jit`` around it."""
+    import collections
+
+    from jax.extend import core as jex
+
+    counts = collections.Counter()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" or (
+                    eqn.primitive.name in ("jit", "pjit")
+                    and eqn.params["name"] in ("gmm", "tgmm")):
+                counts[eqn.params["name"]] += 1
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (tuple, list)) \
+                        else (value,):
+                    if isinstance(sub, jex.ClosedJaxpr):
+                        walk(sub.jaxpr)
+                    elif isinstance(sub, jex.Jaxpr):
+                        walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return counts
+
+
+@pytest.mark.parametrize("share", [False, True])
+def test_a_share_keeps_one_buffer_between_its_products(monkeypatch, share):
+    """PR 61: a share's SwiGLU layer multiplies by ``[gate | up]`` once and
+    runs the row kernel over ``[a | b]`` - 2 ``gmm`` forward, 2 ``gmm`` and
+    2 ``tgmm`` backward, where a product each makes 3, 3 and 3 - and a
+    layer that holds every expert keeps a product each; the guard that
+    decided is in ``kernel_dispatch_total{site="moe_swiglu"}``."""
+    from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
+
+    _forced(monkeypatch)
+    held = dict(routed_experts=32, first_expert=8) if share else {}
+    cfg = MoEConfig(num_experts=8, top_k=8 if share else 4,
+                    drop_tokens=False, norm_topk_prob=True,
+                    expert_act="swiglu", **held)
+    layer = MoELayer(cfg, model_dim=2048, hidden_dim=128, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2048, 2048), jnp.bfloat16)
+    p = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"]
+
+    def loss(p, x):
+        out, aux = layer.apply({"params": p}, x, train=True)
+        return out.astype(jnp.float32).sum() + aux
+
+    forward = _kernel_calls(loss, p, x)
+    both = _kernel_calls(jax.value_and_grad(loss, argnums=(0, 1)), p, x)
+    products = {name: (forward[name], both[name] - forward[name])
+                for name in ("gmm", "tgmm")}
+    rows = {name: both[name] for name in both if "swiglu" in str(name)}
+    said = {(impl, reason) for site, impl, reason, _ in dispatch_report()
+            if site == "moe_swiglu"}
+    if share:
+        assert products == {"gmm": (2, 2), "tgmm": (0, 2)}
+        assert rows == {"moe_swiglu_rows": 1, "moe_swiglu_rows_back": 1}
+        assert ("pallas", "rows 16384 x 256, block 256") in said
+    else:
+        assert products == {"gmm": (3, 3), "tgmm": (0, 3)}
+        assert not rows
+        assert ("xla", "every row holds a pair") in said
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_both_ways(share):
+    """One SwiGLU layer's output and gradients (of ``x``, the router's
+    ``wg`` - through the routing weights - ``gate``, ``up``, ``down``) on
+    the kernels' path and with a product each, and its variables' tree
+    both ways.  The grouped matmul is ``ragged_dot`` with NaN in the rows
+    past the groups, which the kernel leaves as memory held them."""
+    import flax.linen as nn
+
+    from deepspeed_tpu.parallel import moe
+
+    mp = pytest.MonkeyPatch()
+    held = dict(routed_experts=16, first_expert=4) if share else {}
+    cfg = moe.MoEConfig(num_experts=4, top_k=4 if share else 2,
+                        drop_tokens=False, norm_topk_prob=True,
+                        expert_act="swiglu", **held)
+    layer = moe.MoELayer(cfg, model_dim=256, hidden_dim=128,
+                         dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.PRNGKey(1), (256, 256), jnp.bfloat16)
+    ct = jax.random.normal(jax.random.PRNGKey(2), x.shape, jnp.float32)
+
+    def matmul(a, w, sizes, per_device=None):
+        out = jax.lax.ragged_dot(a, w, sizes)
+        past = (jnp.arange(a.shape[0]) >= sizes.sum())[:, None]
+        return jnp.where(past, jnp.nan, out)
+
+    def measure():
+        variables = layer.init(jax.random.PRNGKey(0), x)
+        params = nn.unbox(variables)["params"]
+
+        def loss(params, x):
+            out, aux = layer.apply({"params": params}, x, train=True)
+            return (out.astype(jnp.float32) * ct).sum() + aux, out
+
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
+                                             has_aux=True)(params, x)
+        tree = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
+        return out, grads, (tree, nn.get_partition_spec(variables))
+
+    try:
+        _forced(mp)
+        _interpreted(mp)
+        mp.setattr(moe, "grouped_matmul", matmul)
+        before = dict((r[:3], r[3]) for r in dispatch_report())
+        one = measure()
+        said = {r[1:3] for r in dispatch_report()
+                if r[0] == "moe_swiglu" and r[3] > before.get(r[:3], 0)}
+        mp.setattr(moe, "swiglu_plan", lambda *a: False)
+        each = measure()
+    finally:
+        mp.undo()
+    return one, each, said
+
+
+@pytest.mark.parametrize("what", ["out", "x", "wg", "gate", "up", "down",
+                                  "tree"])
+@pytest.mark.parametrize("share", [False, True])
+def test_one_buffer_equals_a_product_each(share, what):
+    """The layer with ``[gate | up]`` and the row kernel against the same
+    layer with three products and XLA's ``silu(a) * b``, to bf16
+    tolerance; one tree of parameters (names, shapes, partitioning) serves
+    both."""
+    (out, (d_p, d_x), tree), (out3, (d_p3, d_x3), tree3), said = \
+        _layer_both_ways(share)
+    assert {impl for impl, _ in said} == ({"pallas"} if share else {"xla"})
+    if what == "tree":
+        assert tree == tree3
+        assert set(d_p["experts"]) == {"gate", "up", "down"}
+    elif what == "out":
+        _close(out, out3, 2e-2)
+    elif what == "x":
+        _close(d_x, d_x3, 2e-2)
+    elif what == "wg":
+        _close(d_p["gate"]["wg"], d_p3["gate"]["wg"], 2e-2)
+    else:
+        _close(d_p["experts"][what], d_p3["experts"][what], 2e-2)
+
+
+@functools.lru_cache(maxsize=None)
+def _ordered_backward():
+    import importlib
+
+    backend = importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+    mp = pytest.MonkeyPatch()
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    lhs = jax.random.normal(ks[0], (512, 256), jnp.float32)
+    rhs = jax.random.normal(ks[1], (4, 256, 128), jnp.float32)
+    ct = jax.random.normal(ks[2], (512, 128), jnp.float32)
+    sizes = jnp.array([100, 0, 300, 50], jnp.int32)    # 62 rows past them
+
+    def grads(matmul):
+        return jax.grad(lambda a, b: (matmul(a, b) * ct).sum(),
+                        argnums=(0, 1))(lhs, rhs)
+
+    try:
+        for name in ("gmm", "tgmm"):
+            mp.setattr(backend, name, functools.partial(
+                getattr(backend, name), interpret=True))
+        got = grads(lambda a, b: gm._megablox(a, b, sizes, (128, 128, 128)))
+    finally:
+        mp.undo()
+    want = grads(lambda a, b: jax.lax.ragged_dot(a, b, sizes,
+                                                 precision="highest"))
+    return [(np.asarray(g), np.asarray(w)) for g, w in zip(got, want)]
+
+
+@pytest.mark.parametrize("which", [0, 1], ids=["d_rows", "d_weights"])
+def test_the_grouped_matmuls_ordered_backward(which):
+    """PR 61: ``_megablox_bwd`` runs megablox's two backward kernels, the
+    weights' gradient first and the rows' behind a barrier; both are
+    ``ragged_dot``'s own, uneven and empty groups included, over the rows
+    of the groups (the kernel leaves the rows past them alone)."""
+    got, want = _ordered_backward()[which]
+    live = 450 if which == 0 else None
+    _close(got[:live], want[:live], 1e-5)

@@ -1125,8 +1125,11 @@ PARENT_JAXPRS = [
      "10261ba4ace854d7f005b4eb8621ff1d66406e5c0cbca56bf468323caf4687ac"),
     ("lfm2_conv", False,
      "c807d7bd6376176ef17c18903ffed0bb75347b7e4af25b8e66b4932479939e2d"),
+    # PR 61's tree: it changes a share's expert layer by design (one
+    # product with [gate | up], the SwiGLU row kernels, the grouped
+    # matmul's backward ordered); a7051f79... at its parent, 5f07282
     ("moe_share", False,
-     "a7051f7907f4a0933a3426bef4b15cd5e6007693e045d3cd1623e1ed21609613"),
+     "4c97ec5a0789a9a7f5026c0af271fd7b771d42c87e7cd4b479a18cba7e392559"),
     ("qwen3next_mixer", True,
      "1381897984f2b07a7377c24970bd8627084c98d55e3efba7066ebae0824c8e9c"),
     ("olmo_mixer_slots", True,
@@ -1154,7 +1157,8 @@ def test_who_else_runs_the_code_traces_to_the_parents_jaxpr(monkeypatch,
     the mixer on rows, in slots and on the ``(B, S, H, d)`` view, LFM2's
     gated filter, a share's expert layer, and four of them again with the
     batch over two devices, where the ``shard_map`` and its specs are in
-    the digest.  All were computed there with :func:`_who_else`."""
+    the digest.  All were computed there with :func:`_who_else`, but the
+    share's expert layer, which PR 61 changed and re-pinned on its tree."""
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     prev = mesh_lib.get_mesh(required=False)
     mesh_lib.set_mesh(mesh_lib.build_mesh(

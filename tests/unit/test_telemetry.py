@@ -233,11 +233,13 @@ def test_span_parents_and_self_time_on_a_nested_trio():
     assert len({leaf.id, mid_s.id, root_s.id}) == 3 and mid.id == mid_s.id
     tot = trace.totals()
     # self = duration minus what child spans cover
-    assert tot["trio/leaf"]["self_seconds"] == pytest.approx(leaf.dur_s)
+    # (two clock differences cancel: an absolute tolerance, not a relative)
+    assert tot["trio/leaf"]["self_seconds"] == pytest.approx(leaf.dur_s,
+                                                             abs=1e-6)
     assert tot["trio/mid"]["self_seconds"] == pytest.approx(
-        mid_s.dur_s - leaf.dur_s)
+        mid_s.dur_s - leaf.dur_s, abs=1e-6)
     assert tot["trio/root"]["self_seconds"] == pytest.approx(
-        root_s.dur_s - mid_s.dur_s)
+        root_s.dur_s - mid_s.dur_s, abs=1e-6)
     assert tot["trio/mid"]["self_seconds"] >= 0.004
     assert tot["trio/root"]["self_seconds"] < 0.004
     # the Chrome file carries the same ids when it is on

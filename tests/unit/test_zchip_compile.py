@@ -274,6 +274,29 @@ def test_the_row_kernels_compile_at_the_share_cells_shapes(
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("rows,width", [(262144, 896), (196608, 1024),
+                                        (262144, 768), (131072, 1536),
+                                        (245760, 512)])
+def test_the_swiglu_row_kernels_compile_at_the_share_cells_shapes(
+        one_chip, rows, width):
+    """PR 61: the SwiGLU between a share's grouped matmuls, forward and
+    backward, over ``[a | b]`` of Mellum 2, Trinity, SDAR (Keye, JoyAI and
+    Ling are as wide), LFM2 and Qwen3-Next through Mosaic for the described
+    v5e; the backward writes ``d[a | b]`` into ``[a | b]``'s buffer."""
+    from deepspeed_tpu.ops.pallas import moe_rows
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ab, live = arg((rows, 2 * width)), arg((1,), jnp.int32)
+    forward = jax.jit(moe_rows.swiglu_rows).lower(ab, live).compile()
+    assert forward.as_text().count("tpu_custom_call") == 1
+    backward = jax.jit(moe_rows.swiglu_rows_back, donate_argnums=1).lower(
+        arg((rows, width)), ab, live).compile()
+    assert backward.as_text().count("tpu_custom_call") == 1
+    assert backward.memory_analysis().temp_size_in_bytes == 0
+
+
 # The matrices of the two train cells: GPT-2-XL's block, table and
 # positions; OLMoE's expert stacks, attention projections, table and head.
 # (6400, 1600), (50304, 1600) and (1024, 1600) are stored column-major on
