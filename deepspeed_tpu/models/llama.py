@@ -138,16 +138,32 @@ prediction block's mixer is latent attention whatever the stack ends on.
 ``expert_swiglu_limit_list`` / ``share_expert_swiglu_limit_list`` name a
 clamp whose form the config does not give: a non-zero entry within the
 depth built raises.  ``decode=True`` raises with any of it.
+
+**The Xing 4.0 family** (``Xing4.0-29B-A4B``) is the DeepSeek-V3 block above
+on a residual stream of ``hc_mult`` lanes (manifold-constrained
+hyper-connections, arXiv:2512.24880; :class:`HyperConnection`,
+``ops/hyper_connection.py``): the table's row is copied into every lane,
+each sublayer reads ``H_pre @ X`` and the stream becomes ``H_res @ X +
+H_post^T y`` under per-token maps (``H_res`` made doubly stochastic by
+``hc_sinkhorn_iters`` Sinkhorn sweeps), the lanes are summed before the
+final norm, and the prediction block widens and sums its own.  The stream
+is carried flat, ``(B, S, hc_mult * E)``.  Its latent attention turns its
+rope channels by the YaRN table of ``rope_scaling`` and scales the softmax
+by ``mscale^2`` (:attr:`LlamaConfig.latent_rotary`).  ``decode=True``,
+``scan_layers``, ``sa_config``, ``diffusion`` and sequence parallelism raise
+with more than one lane.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.attention import dot_product_attention
 from ..ops.rotary import apply_rotary_pos_emb, rotate_rows, rows_plan
@@ -198,6 +214,10 @@ class SparseAttentionConfig:
 # what an indexed attention layer hands up beside its output, a layer
 _INDEXER_STATS = ("indexer_loss", "indexer_kept_share",
                   "indexer_live_tile_share")
+# what a block under hyper-connections hands up of its two sublayers' maps
+# (ops/hyper_connection.py gauges)
+_MHC_STATS = ("mhc_res_marginal_err", "mhc_res_offdiag", "mhc_pre_mean",
+              "mhc_post_mean")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,8 +238,10 @@ class LlamaConfig:
     ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
     ``v_head_dim``, ``rope_interleave``, ``num_nextn_predict_layers``,
     ``kda_lower_bound``, ``kda_safe_gate``, ``short_conv_kernel_size``,
-    ``expert_swiglu_limit_list``, ``share_expert_swiglu_limit_list``.  The
-    rest are this program's own."""
+    ``expert_swiglu_limit_list``, ``share_expert_swiglu_limit_list``,
+    ``rope_scaling``, ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
+    ``mhc_h_res_clamp_min``, ``mhc_h_res_clamp_max``.  The rest are this
+    program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -287,6 +309,23 @@ class LlamaConfig:
     # rotary_table's arguments), or one such dict for every layer;
     # None → rope_theta alone
     rope_parameters: Optional[Any] = None
+    # the DeepSeek-V2/V3 family's scaling entry, read by LATENT attention
+    # alone ({"type": "yarn", factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim}: :attr:`latent_rotary`);
+    # None, or type "default", rotates by rope_theta alone.  Grouped-query
+    # attention reads ``rope_parameters``
+    rope_scaling: Optional[Any] = None
+    # manifold-constrained hyper-connections (ops/hyper_connection.py): the
+    # residual stream has ``hc_mult`` lanes, every sublayer reads a learned
+    # mix of them and writes back through a learned map beside a lane-to-
+    # lane map that ``hc_sinkhorn_iters`` sweeps (``hc_eps`` in their
+    # denominators) make doubly stochastic, its logits clamped first.  None
+    # or 1: one lane, ``x + f(x)``, and nothing of this is traced
+    hc_mult: Optional[int] = None
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     initializer_range: float = 0.02
@@ -396,7 +435,7 @@ class LlamaConfig:
             object.__setattr__(self, "head_dim",
                                self.hidden_size // self.num_attention_heads)
         for name in ("layer_types", "rope_parameters", "rope_layer_types",
-                     "sa_config", "expert_swiglu_limit_list",
+                     "rope_scaling", "sa_config", "expert_swiglu_limit_list",
                      "share_expert_swiglu_limit_list"):
             object.__setattr__(self, name, frozen(getattr(self, name)))
         if self.qk_norm not in (False, True, "head"):
@@ -546,6 +585,46 @@ class LlamaConfig:
                 "(attn_gate=True; 'head' is written), a sliding window, "
                 "rope_parameters or rope_layer_types: none of them is "
                 "written for it")
+        if self.rope_scaling is not None and not self.kv_lora_rank \
+                and self._rope_scaling_type != "default":
+            raise NotImplementedError(
+                f"rope_scaling of type {self._rope_scaling_type!r} without "
+                f"latent attention: grouped-query attention's scaled tables "
+                f"are rope_parameters'")
+        if self.kv_lora_rank and self._rope_scaling_type not in (
+                "default", "yarn"):
+            raise NotImplementedError(
+                f"rope_scaling of type {self._rope_scaling_type!r}: "
+                f"'default' and 'yarn' are written")
+        if self.lanes > 1:
+            if self.hc_sinkhorn_iters < 1 or self.hc_eps < 0.0 or not \
+                    self.mhc_h_res_clamp_min < self.mhc_h_res_clamp_max:
+                raise ValueError(
+                    f"hc_sinkhorn_iters {self.hc_sinkhorn_iters}, hc_eps "
+                    f"{self.hc_eps}, mhc_h_res_clamp_min / _max "
+                    f"{self.mhc_h_res_clamp_min} / "
+                    f"{self.mhc_h_res_clamp_max}: at least one sweep, a "
+                    f"denominator's eps >= 0 and a clamp that leaves room")
+            for on, what in (
+                    (self.decode, "decode=True: the fused decode path and "
+                     "the cache's blocks take a (B, 1, E) stream"),
+                    (self.scan_layers, "scan_layers=True: the scanned "
+                     "carry is one lane; set scan_layers=False"),
+                    (self.sa_config is not None, "sa_config"),
+                    (self.diffusion is not None, "diffusion (block-"
+                     "diffusion training)"),
+                    (self.attn_impl in ("ring", "ulysses"),
+                     f"attn_impl {self.attn_impl!r} (sequence "
+                     f"parallelism)"),
+                    (self.sandwich_norm or self.reordered_norm,
+                     "sandwich_norm / reordered_norm: a sublayer under "
+                     "hyper-connections carries its own norm BEFORE it")):
+                if on:
+                    raise NotImplementedError(
+                        f"hc_mult {self.hc_mult} (a residual stream of "
+                        f"several lanes) with {what}: the lanes are written "
+                        f"for the unrolled training stack alone (pipeline "
+                        f"stages: this family has no pipeline_fns at all)")
         if self.attn_gate not in (False, True, "head"):
             raise ValueError(f"attn_gate is False, True (a gate a channel) "
                              f"or 'head', got {self.attn_gate!r}")
@@ -659,6 +738,42 @@ class LlamaConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_key_value_heads or self.num_attention_heads
+
+    @property
+    def lanes(self) -> int:
+        """Lanes of the residual stream (``hc_mult``; 1 without)."""
+        return int(self.hc_mult or 1)
+
+    @property
+    def _rope_scaling_type(self) -> str:
+        entry = dict(self.rope_scaling or ())
+        return entry.get("rope_type", entry.get("type", "default"))
+
+    @property
+    def latent_rotary(self) -> tuple:
+        """``(table, factor)`` of latent attention under ``rope_scaling``:
+        the ``ops.rotary.RotaryTable`` its rope channels turn by (None:
+        ``rope_theta`` alone) and what multiplies the softmax scale.  The
+        DeepSeek-V2/V3 released code's rule: YaRN's frequencies, cos and
+        sin times ``mscale(factor, mscale) / mscale(factor,
+        mscale_all_dim)``, the softmax scale times ``mscale(factor,
+        mscale_all_dim)^2``."""
+        if self._rope_scaling_type == "default":
+            return None, 1.0
+        from ..ops.rotary import rotary_table, yarn_mscale
+
+        rs = dict(self.rope_scaling)
+        factor = float(rs["factor"])
+        all_dim = yarn_mscale(factor, float(rs.get("mscale_all_dim", 0.0)))
+        table = rotary_table(
+            self.qk_rope_head_dim, "yarn", rope_theta=float(self.rope_theta),
+            factor=factor, original_max_position_embeddings=rs[
+                "original_max_position_embeddings"],
+            beta_fast=float(rs.get("beta_fast", 32.0)),
+            beta_slow=float(rs.get("beta_slow", 1.0)),
+            attention_factor=yarn_mscale(factor, float(rs.get("mscale", 1.0)))
+            / all_dim)
+        return table, all_dim * all_dim
 
     @property
     def expert_size(self) -> int:
@@ -1043,15 +1158,18 @@ class LlamaLatentAttention(nn.Module):
                            (cfg.kv_lora_rank, H * (Dn + Dv)))
             k_nope = jnp.dot(c_kv, w_kvb[:, :H * Dn])
             v = jnp.dot(c_kv, w_kvb[:, H * Dn:])
+        table, scaled = cfg.latent_rotary
+        yarn = {} if table is None else {"table": table}
         with trace.device_span("rope/mla"):
             q_rope, k_rope = (rotate_rope_rows(
                 t, position_ids, Dr, theta=cfg.rope_theta,
-                interleaved=cfg.rope_interleave) for t in (q_rope, k_rope))
+                interleaved=cfg.rope_interleave, **yarn)
+                for t in (q_rope, k_rope))
         with trace.device_span("self_attn_mla"):
             y = dot_product_attention(
                 q_nope.reshape(B, S, H, Dn), k_nope.reshape(B, S, H, Dn),
                 v.reshape(B, S, H, Dv), causal=True, mask=attn_mask,
-                scale=(Dn + Dr) ** -0.5, impl=cfg.attn_impl,
+                scale=(Dn + Dr) ** -0.5 * scaled, impl=cfg.attn_impl,
                 q_rope=q_rope.reshape(B, S, H, Dr),
                 k_rope=k_rope.reshape(B, S, 1, Dr))
         if cfg.attn_gate == "head":
@@ -1277,6 +1395,59 @@ class KimiDeltaAttention(nn.Module):
                            weight("o_proj", ("heads", "embed"), (W, E)))
 
 
+class HyperConnection(nn.Module):
+    """One sublayer's manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880 section 4; ``ops/hyper_connection.py`` has the
+    equations): called on the stream ``(B, S, hc_mult * E)`` it returns what
+    the sublayer reads, ``(B, S, E)``, and the token's maps; :meth:`post`
+    writes the sublayer's output back.  Leaves: ``phi`` (n E, n^2 + 2n), the
+    gains ``a_pre``, ``a_post``, ``a_res`` (one number each) and the biases
+    ``b_pre``, ``b_post`` (n,), ``b_res`` (n, n), from the paper's
+    near-identity start: ``H_pre`` = 1/n, ``H_post`` = 1, ``H_res`` ~ I."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ..ops import hyper_connection as mhc
+
+        cfg, n = self.cfg, self.cfg.lanes
+        const = nn.initializers.constant
+
+        def leaf(name, init, shape, names=None):
+            init = init if names is None else nn.with_partitioning(init, names)
+            return self.param(name, init, shape, cfg.param_dtype)
+
+        phi = leaf("phi", nn.initializers.normal(cfg.initializer_range),
+                   (x.shape[-1], n * n + 2 * n), ("embed", None))
+        gains = tuple(leaf(a, const(0.01), (1,))
+                      for a in ("a_pre", "a_post", "a_res"))
+        biases = (leaf("b_pre", const(-math.log(n - 1.0)), (n,)),
+                  leaf("b_post", const(0.0), (n,)),
+                  leaf("b_res", lambda key, shape, dtype: 8.0 * jnp.eye(
+                      n, dtype=dtype), (n, n)))
+        maps = mhc.maps(
+            x, phi, gains, biases, n=n, iters=cfg.hc_sinkhorn_iters,
+            eps=cfg.hc_eps, rms_eps=cfg.rms_norm_eps,
+            clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max))
+        return mhc.pre(x, maps.pre), maps
+
+    @staticmethod
+    def post(x, y, maps):
+        from ..ops import hyper_connection as mhc
+
+        return mhc.post(x, y, maps.res, maps.post)
+
+
+def _mhc_stats(attn_maps, mlp_maps) -> dict:
+    """A block's ``_MHC_STATS``: the worse marginal of its two sublayers,
+    the others' mean."""
+    from ..ops.hyper_connection import gauges
+
+    a, b = gauges(attn_maps), gauges(mlp_maps)
+    return {k: jnp.maximum(a[k], b[k]) if k == _MHC_STATS[0]
+            else 0.5 * (a[k] + b[k]) for k in _MHC_STATS}
+
+
 class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     deterministic: bool = True
@@ -1327,6 +1498,10 @@ class LlamaBlock(nn.Module):
                     y, x, wo, None, ns2, None, (wg, wu, wd), swiglu=True,
                     rms=True, eps=cfg.rms_norm_eps, interpret=interp)
                 return x, None
+        stream = attn_maps = mlp_maps = None
+        if cfg.lanes > 1:       # x: the stream's lanes; the sublayer reads u
+            stream = x
+            x, attn_maps = HyperConnection(cfg, name="attn_hc")(stream)
         # under the reordered norm a branch reads the residual stream itself
         h = x if cfg.reordered_norm else RMSNorm(cfg, name="input_norm")(x)
         if self.kind == CONV:       # the mixer reads no position and no mask
@@ -1344,7 +1519,11 @@ class LlamaBlock(nn.Module):
         indexed = None
         if cfg.sa_config is not None:
             attn, indexed = attn
-        if cfg.reordered_norm:
+        if stream is not None:
+            stream = HyperConnection.post(stream, attn, attn_maps)
+            x, mlp_maps = HyperConnection(cfg, name="mlp_hc")(stream)
+            h = RMSNorm(cfg, name="post_attention_norm")(x)
+        elif cfg.reordered_norm:
             h = x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
         elif cfg.sandwich_norm:
             x = x + RMSNorm(cfg, name="post_attention_norm")(attn)
@@ -1369,6 +1548,9 @@ class LlamaBlock(nn.Module):
             ff = RMSNorm(cfg, name="post_mlp_norm")(ff)
         if indexed is not None:
             ys = dict(ys or {}, **indexed)
+        if stream is not None:
+            return HyperConnection.post(stream, ff, mlp_maps), dict(
+                ys or {}, **_mhc_stats(attn_maps, mlp_maps))
         return x + ff, ys
 
 
@@ -1378,7 +1560,9 @@ class MTPModule(nn.Module):
     ``x_i = [enorm(E[t_{i+1}]) ; hnorm(h_i)] eh_proj``, one whole block of
     the model's sparse kind, ``shared_head_norm``.  The table that embeds
     ``t_{i+1}`` and the head that reads the result are the CALLER's: the
-    main model's own leaves, whose gradient is the sum of both uses."""
+    main model's own leaves, whose gradient is the sum of both uses.
+    Under hyper-connections ``h`` is the stack's lane sum, ``x`` is copied
+    into the block's own lanes and the block's lanes are summed again."""
     cfg: LlamaConfig
     deterministic: bool = True
 
@@ -1392,8 +1576,14 @@ class MTPModule(nn.Module):
             x = _dense(x, cfg.hidden_size, ("mlp", "embed"), cfg=cfg,
                        name="eh_proj", module=self)
         with trace.device_span("mtp/block"):
+            if cfg.lanes > 1:
+                from ..ops import hyper_connection as mhc
+
+                x = mhc.widen(x, cfg.lanes)
             x, ys = LlamaBlock(cfg, self.deterministic, name="block")(
                 x, inputs)
+            if cfg.lanes > 1:
+                x = mhc.collapse(x, cfg.lanes)
         return RMSNorm(cfg, name="shared_head_norm")(x), ys
 
 
@@ -1479,6 +1669,10 @@ class LlamaForCausalLM(nn.Module):
         h = embed.astype(cfg.dtype)[input_ids]
         if cfg.mup_enabled:
             h = h * (cfg.hidden_size ** 0.5)
+        if cfg.lanes > 1:       # the table's row in every lane
+            from ..ops import hyper_connection as mhc
+
+            h = mhc.widen(h, cfg.lanes)
         mask = None
         if attention_mask is not None:
             mask = attention_mask[:, None, None, :].astype(bool)
@@ -1490,6 +1684,7 @@ class LlamaForCausalLM(nn.Module):
                 prevent_cse=cfg.remat_prevent_cse)
         kinds = cfg.kinds
         indexed = []        # the layers' _INDEXER_STATS, then stacked
+        lane_stats = []     # the blocks' _MHC_STATS, the prediction's last
         if cfg.scan_layers:
             if len(set(kinds)) > 1 or cfg.num_dense_layers:
                 raise NotImplementedError(
@@ -1523,6 +1718,10 @@ class LlamaForCausalLM(nn.Module):
                     ys = dict(ys)
                     indexed.append({k: ys.pop(k) for k in _INDEXER_STATS})
                     ys = ys or None
+                if cfg.lanes > 1:
+                    ys = dict(ys)
+                    lane_stats.append({k: ys.pop(k) for k in _MHC_STATS})
+                    ys = ys or None
                 per_layer.append(ys)
             if indexed:
                 indexed = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
@@ -1532,6 +1731,8 @@ class LlamaForCausalLM(nn.Module):
                     lambda *xs: jnp.stack(xs),
                     *per_layer[cfg.num_dense_layers:])
 
+        if cfg.lanes > 1:       # the lanes' sum: what both heads read
+            h = mhc.collapse(h, cfg.lanes)
         h_mtp = None
         if cfg.mtp_blocks and labels is not None:
             # the stack's output BEFORE the final norm, beside the next
@@ -1546,6 +1747,10 @@ class LlamaForCausalLM(nn.Module):
                 [input_ids[:, 1:], jnp.zeros_like(input_ids[:, :1])], axis=1)
             h_mtp, ys = mtp_cls(cfg, deterministic, name="mtp_0")(
                 h, embed.astype(cfg.dtype)[next_ids], (position_ids, mask))
+            if cfg.lanes > 1:
+                ys = dict(ys)
+                lane_stats.append({k: ys.pop(k) for k in _MHC_STATS})
+                ys = ys or None
             if cfg.moe is not None:     # the block's row follows the stack's
                 ys = jax.tree_util.tree_map(lambda x: x[None], ys)
                 per_layer = jax.tree_util.tree_map(
@@ -1569,6 +1774,11 @@ class LlamaForCausalLM(nn.Module):
             out["stats"] = dict(
                 out.get("stats") or {}, indexer_loss=indexer_loss,
                 **{k: indexed[k].mean() for k in _INDEXER_STATS[1:]})
+
+        if lane_stats:      # a value a block, the prediction block's last
+            out["stats"] = dict(
+                out.get("stats") or {}, **jax.tree_util.tree_map(
+                    lambda *xs: jnp.stack(xs), *lane_stats))
 
         weighted = {}
         if dif is not None and labels is not None:
@@ -1689,10 +1899,40 @@ class LlamaForCausalLM(nn.Module):
                 "(what block skipping could skip is the rest), mean of the "
                 "layers, last finished step").set(
                 float(stats["indexer_live_tile_share"]))
+        if _MHC_STATS[0] in stats:
+            from ..telemetry import registry
+
+            for name, what in zip(_MHC_STATS, (
+                    "largest |row sum - 1| and |column sum - 1| of a "
+                    "hyper-connection's lane-to-lane map over the tokens",
+                    "1 - trace(H_res) / n, the share of a lane that comes "
+                    "from other lanes (0: a plain residual), mean over the "
+                    "tokens",
+                    "mean of H_pre, what a sublayer reads of a lane",
+                    "mean of H_post, what a lane takes of a sublayer's "
+                    "output")):
+                gauge = registry.gauge(
+                    name, what + "; a block's two sublayers together (the "
+                    "prediction block's row last), last finished step",
+                    ("layer",))
+                for i, v in enumerate(np.asarray(stats[name]).ravel()):
+                    gauge.labels(str(i)).set(float(v))
         if "tokens_per_expert" in stats:
             from ..parallel.moe import record_stats
 
             record_stats(stats)
+
+    @property
+    def is_undecayed_leaf(self):
+        """``path -> bool`` over a leaf's path of dict keys: the leaves
+        weight decay leaves alone (``runtime/optimizers.py decay_mask``), a
+        hyper-connection's gains and biases, a few numbers each whose rest
+        values are not 0 (``b_res`` starts at 8 I).  None with one lane:
+        every leaf decays, as it always did."""
+        if self.cfg.lanes == 1:
+            return None
+        return lambda path: len(path) > 1 and path[-1] != "phi" \
+            and path[-2] in ("attn_hc", "mlp_hc")
 
     @staticmethod
     def is_state_leaf(path: tuple) -> bool:

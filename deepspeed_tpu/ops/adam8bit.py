@@ -219,7 +219,8 @@ def note_refusal(params, reason: str) -> None:
 
 def kernel_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
                          b2: float, eps: float, weight_decay: float = 0.0,
-                         l2: float = 0.0, clip: float = 0.0):
+                         l2: float = 0.0, clip: float = 0.0,
+                         mask: Optional[Callable] = None):
     """Build ``apply(grads, params, opt_state, grad_norm, factor) →
     (new_params, new_opt_state)``: the build_tx chain ``clip → [L2] →
     adam8bit moments → [AdamW decay] → lr`` of the ``adamw8bit`` family
@@ -228,6 +229,8 @@ def kernel_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
 
     ``grads`` are the raw gradient sums in the dtype the backward wrote
     (no gradient-sized buffer may stand between it and the kernel);
+    ``mask`` (``params -> tree of bools``, ``runtime/optimizers.py
+    decay_mask``) names the leaves that decay; None: all.
     ``factor`` is what the engine would have multiplied them by
     (``1 / (denom * loss scale)``) and ``grad_norm`` the norm after it:
     factor and clip reach a leaf as one scalar.  ``opt_state`` is the
@@ -255,25 +258,27 @@ def kernel_apply_factory(*, learning_rate: ScalarOrSchedule, b1: float,
         scalars = jnp.stack([gscale, jnp.asarray(lr, jnp.float32),
                              c1, c2]).astype(jnp.float32)
 
-        def leaf(g, p, mc, rc, sc):
+        def leaf(g, p, mc, rc, sc, decays=True):
+            wd, l2_ = (weight_decay, l2) if decays else (0.0, 0.0)
             why = leaf_refusal(p.shape, p.dtype, g.dtype.itemsize)
             if why is None:
                 note_dispatch(SITE, "kernel", "one device, whole leaves")
                 return apply_leaf(
                     g, p, mc, rc, sc, scalars, b1=b1, b2=b2, eps=eps,
-                    wd=weight_decay, l2=l2, interpret=interp)
+                    wd=wd, l2=l2_, interpret=interp)
             note_dispatch(SITE, "xla", why)
             g = g.astype(jnp.float32) * gscale
-            if l2:
-                g = g + l2 * p
+            if l2_:
+                g = g + l2_ * p
             upd, mc2, rc2, sc2 = _leaf_moments(
                 g, mc, rc, sc, b1=b1, b2=b2, c1=c1, c2=c2, eps=eps)
-            if weight_decay:
-                upd = upd + weight_decay * p
+            if wd:
+                upd = upd + wd * p
             return p - lr * upd, mc2, rc2, sc2
 
-        out = jax.tree_util.tree_map(leaf, grads, params, st.m_codes,
-                                     st.r_codes, st.scales)
+        out = jax.tree_util.tree_map(
+            leaf, grads, params, st.m_codes, st.r_codes, st.scales,
+            *(() if mask is None else (mask(params),)))
         treedef = jax.tree_util.tree_structure(params)
         new_p, m_codes, r_codes, scales_t = jax.tree_util.tree_transpose(
             treedef, jax.tree_util.tree_structure((0, 0, 0, {"m": 0, "r": 0})),

@@ -358,6 +358,20 @@ def _stage(half):
             pltpu.SemaphoreType.DMA((2,))]
 
 
+def _wide_rows(half: int) -> dict:
+    """``pallas_call`` arguments for :func:`gather_rows` where its
+    double-buffered ``(STEP, width)`` block, the two stages and the scales
+    pass Mosaic's default 16 MiB scope: rows wider than ~2,900 channels
+    (3,584: 17.5 MiB asked) get a scope of 32 of the v5e's 128 MiB.  {}
+    where they fit, and the call is then what it always was (:data:`STEP`
+    cannot shrink instead: an SMEM block of indices is 1,024 long)."""
+    need = 2 * STEP * 2 * half * 2 + 2 * SUB * half * 4 + (1 << 20)
+    if need <= 15 << 20:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=32 << 20)}
+
+
 @functools.partial(jax.jit, static_argnames=("name", "interpret"))
 def gather_rows(src: jax.Array, idx: jax.Array, live: jax.Array,
                 scale: Optional[jax.Array] = None, *, name: str,
@@ -392,7 +406,7 @@ def gather_rows(src: jax.Array, idx: jax.Array, live: jax.Array,
         cost_estimate=pl.CostEstimate(
             flops=2 * R * half * (scale is not None), transcendentals=0,
             bytes_accessed=8 * R * half + 8 * R),
-        name=name, interpret=interpret,
+        name=name, interpret=interpret, **_wide_rows(half),
     )(live, idx, src, *(() if scale is None else (scale,)))
 
 

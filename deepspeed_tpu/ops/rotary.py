@@ -63,7 +63,10 @@ def rotary_table(rotary_dim: int, rope_type: str = "default",
     original context keep their frequency, those that turn less than
     ``beta_slow`` times are slowed by ``factor``, a linear ramp between
     them; cos and sin are scaled by ``attention_factor`` (``0.1 ln factor
-    + 1`` where the entry gives none)."""
+    + 1`` where the entry gives none).  The latent path passes its own:
+    the DeepSeek-V2/V3 rule puts ``mscale^2`` on the softmax scale and
+    ``mscale / mscale_all_dim`` on cos and sin (:func:`yarn_mscale`,
+    ``LlamaConfig.latent_rotary``), not this default."""
     half = rotary_dim // 2
     extrap = float(rope_theta) ** (-np.arange(half, dtype=np.float64) * 2
                                    / rotary_dim)
@@ -92,6 +95,13 @@ def rotary_table(rotary_dim: int, rope_type: str = "default",
     if attention_factor is None:
         attention_factor = 0.1 * math.log(factor) + 1.0 if factor > 1 else 1.0
     return RotaryTable(tuple(inv_freq.tolist()), float(attention_factor))
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 mscale ln factor + 1`` (1 at ``factor <= 1``): the DeepSeek
+    family's YaRN magnitude, which its latent attention squares onto the
+    softmax scale (``mscale_all_dim``) instead of scaling cos and sin."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def rotary_angles(positions: jax.Array, rotary_dim: int,
@@ -221,13 +231,14 @@ def rotate_rows(q: jax.Array, k: jax.Array, positions: Optional[jax.Array],
 
 
 def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,
-                     theta: float = 10000.0,
-                     interleaved: bool = False) -> jax.Array:
+                     theta: float = 10000.0, interleaved: bool = False,
+                     table: Optional[RotaryTable] = None) -> jax.Array:
     """Rotate ``x`` (B, S, n·rotary_dim), the rows a projection writes for
     ``n`` heads of ``rotary_dim`` channels each (n = 1: one key for all
     heads), every channel of a head by its position: pairs ``(2i, 2i+1)``
     when ``interleaved``, ``(i, i + rotary_dim/2)`` otherwise, by
-    ``theta^(-2i/rotary_dim)``.  Elementwise on the rows as they lie (a
+    ``theta^(-2i/rotary_dim)``, or by a ``table``'s frequencies with its
+    factor on cos and sin.  Elementwise on the rows as they lie (a
     channel's partner is one lane roll away), in float32: no ``(B, S, n,
     d/2, 2)`` view is formed, which on the chip is a copy each way.  What
     the latent attention's rope channels take; ``rotate_rows`` is the
@@ -240,10 +251,19 @@ def rotate_rope_rows(x: jax.Array, positions: jax.Array, rotary_dim: int, *,
         shift, first, freq = half, lane < half, lane % half
     # one head's table, a channel a lane (no gather: the chip runs one an
     # index at a time), then the same for every head
-    inv_freq = jnp.asarray(theta ** (-2.0 * freq / rotary_dim), jnp.float32)
+    inv_freq = jnp.asarray(
+        theta ** (-2.0 * freq / rotary_dim) if table is None
+        else np.asarray(table.inv_freq)[freq], jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * inv_freq   # (B, S, d)
-    cos = jnp.tile(jnp.cos(ang), (1, 1, n))
-    sin = jnp.tile(jnp.where(first, -jnp.sin(ang), jnp.sin(ang)), (1, 1, n))
+    scale = 1.0 if table is None else table.scale
+    if scale != 1.0:
+        ang_cos, ang_sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+        cos = jnp.tile(ang_cos, (1, 1, n))
+        sin = jnp.tile(jnp.where(first, -ang_sin, ang_sin), (1, 1, n))
+    else:
+        cos = jnp.tile(jnp.cos(ang), (1, 1, n))
+        sin = jnp.tile(jnp.where(first, -jnp.sin(ang), jnp.sin(ang)),
+                       (1, 1, n))
     xf = x.astype(jnp.float32)
     partner = jnp.where(np.tile(first, n), jnp.roll(xf, -shift, axis=-1),
                         jnp.roll(xf, shift, axis=-1))

@@ -47,7 +47,7 @@ from . import precision, state_leaves
 from .config import Config
 from .dataloader import DeepSpeedDataLoader, RepeatingLoader
 from .lr_schedules import get_lr_schedule
-from .optimizers import build_tx, clip_by_global_norm
+from .optimizers import build_tx, clip_by_global_norm, decay_mask
 
 
 @flax.struct.dataclass
@@ -222,6 +222,7 @@ class Engine:
                     f"dp/fsdp axes only; got extra mesh axes {non_data}")
         if self.offload_device != "none" and self.config.fp16.enabled:
             raise NotImplementedError("fp16 + optimizer offload: use bf16")
+        undecayed = decay_mask(model)   # None: weight decay on every leaf
         if self.offload_device != "none":
             # ZeRO-Offload: device step produces grads only; the update runs
             # in the C++ CPU-Adam kernel on host master weights
@@ -233,7 +234,8 @@ class Engine:
                 self.tx = optax.chain(
                     clip_by_global_norm(self.config.gradient_clipping), self.tx)
         else:
-            self.tx = build_tx(self.config, learning_rate=self.lr_scheduler)
+            self.tx = build_tx(self.config, learning_rate=self.lr_scheduler,
+                               mask=undecayed)
         if self._onebit_comm:
             # opt_state IS the 1-bit comm state (per-worker momentum +
             # error buffers); the update runs inside the shard_map step,
@@ -282,7 +284,8 @@ class Engine:
                     eps=ocfg.eps,
                     weight_decay=ocfg.weight_decay if decoupled else 0.0,
                     l2=0.0 if decoupled else ocfg.weight_decay,
-                    clip=self.config.gradient_clipping or 0.0)
+                    clip=self.config.gradient_clipping or 0.0,
+                    mask=undecayed)
 
         # ---- loss fn -------------------------------------------------
         self._user_loss_fn = loss_fn
@@ -1778,8 +1781,53 @@ class Engine:
     # ------------------------------------------------------------------
     # public API
     # ------------------------------------------------------------------
+    def _batch_sharding(self, shape) -> NamedSharding:
+        """Where a batch leaf of ``shape`` lies: rows over the data axes,
+        and under sequence parallelism the positions over ``sp``."""
+        dims = [DATA_AXES] + [None] * (len(shape) - 1)
+        if self.mesh.shape["sp"] > 1 and len(shape) >= 2 \
+                and shape[1] % self.mesh.shape["sp"] == 0:
+            dims[1] = "sp"
+        return NamedSharding(self.mesh, P(*dims))
+
+    def prepare_train_step(self, example_batch) -> "threading.Thread":
+        """Start making the train step's executable for global batches
+        shaped like ``example_batch`` (arrays or ``ShapeDtypeStruct``s) on a
+        thread of its own, beside what the caller does next (``init_params``,
+        an evaluation, a reference check): the first ``train_batch`` then
+        finds it made and books it as its compile
+        (``telemetry/recompile.py _Staged.prepare``).  Returns the started
+        thread: join it before that first step, or the step compiles too.
+        The plain step alone (no pipeline, 1-bit or offloaded update)."""
+        import threading
+
+        if self.pp_size > 1 or self._onebit_comm \
+                or self.offload_device != "none" \
+                or self._param_offload is not None \
+                or self.progressive_layer_drop is not None:
+            raise NotImplementedError(
+                "prepare_train_step makes the plain compiled train step: "
+                "pipeline stages, the 1-bit collective, an offloaded update "
+                "and progressive layer drop take other steps or arguments")
+        state = self.abstract_state(example_batch)      # on this thread: it
+        batch = jax.tree_util.tree_map(                 # builds the specs
+            lambda x: jax.ShapeDtypeStruct(
+                np.shape(x), x.dtype,
+                sharding=self._batch_sharding(np.shape(x))), example_batch)
+        site = self._compiled_train_step
+
+        def make():
+            try:
+                site.prepare(state, batch)
+            except Exception as e:      # the first step compiles as always
+                logger.warning(f"prepare_train_step: {type(e).__name__}: {e}")
+
+        thread = threading.Thread(target=make, name="prepare-train-step",
+                                  daemon=True)
+        thread.start()
+        return thread
+
     def _shard_batch(self, batch):
-        sp = self.mesh.shape["sp"]
         seen = {}   # aliased leaves (labels=input_ids) transfer once
 
         def put(x):
@@ -1794,11 +1842,7 @@ class Engine:
                     f"batch leading dim {np.shape(x)} must be divisible by the "
                     f"data-parallel world size {self.dp_world} "
                     f"(mesh dp×fsdp×ep); expected a multiple of {self.dp_world} rows")
-            dims = [DATA_AXES] + [None] * (np.ndim(x) - 1)
-            # sequence parallelism: shard the seq dim over 'sp'
-            if sp > 1 and np.ndim(x) >= 2 and np.shape(x)[1] % sp == 0:
-                dims[1] = "sp"
-            sharding = NamedSharding(self.mesh, P(*dims))
+            sharding = self._batch_sharding(np.shape(x))
             # already-placed leaves skip the transfer entirely: a host
             # round trip per leaf per step is pure overhead
             if isinstance(x, jax.Array) and getattr(x, "sharding", None) \

@@ -21,9 +21,26 @@ from .config import Config, OptimizerConfig
 ScalarOrSchedule = Union[float, Callable]
 
 
+def decay_mask(model) -> Optional[Callable]:
+    """``params -> tree of bools`` (True: the leaf decays) for a model whose
+    ``is_undecayed_leaf`` is a predicate over a leaf's path of dict keys;
+    None where every leaf decays (no such attribute, or None), and the
+    chain is then built as it always was."""
+    skip = getattr(model, "is_undecayed_leaf", None)
+    if skip is None:
+        return None
+    import jax
+
+    return lambda params: jax.tree_util.tree_map_with_path(
+        lambda path, _: not skip(tuple(getattr(k, "key", k) for k in path)),
+        params)
+
+
 def build_optimizer(cfg: OptimizerConfig,
-                    learning_rate: Optional[ScalarOrSchedule] = None
+                    learning_rate: Optional[ScalarOrSchedule] = None,
+                    mask: Optional[Callable] = None
                     ) -> optax.GradientTransformation:
+    """``mask`` (:func:`decay_mask`): which leaves weight decay touches."""
     lr = learning_rate if learning_rate is not None else cfg.lr
     b1, b2 = cfg.betas
     name = cfg.type
@@ -31,10 +48,10 @@ def build_optimizer(cfg: OptimizerConfig,
         adam_w_mode = cfg.extra.get("adam_w_mode", name == C.ADAMW_OPTIMIZER)
         if adam_w_mode or cfg.weight_decay == 0.0:
             return optax.adamw(lr, b1=b1, b2=b2, eps=cfg.eps,
-                               weight_decay=cfg.weight_decay)
+                               weight_decay=cfg.weight_decay, mask=mask)
         # plain Adam + L2 (decay inside the gradient), reference cpu_adam's
         # non-decoupled mode
-        return optax.chain(optax.add_decayed_weights(cfg.weight_decay),
+        return optax.chain(optax.add_decayed_weights(cfg.weight_decay, mask),
                            optax.adam(lr, b1=b1, b2=b2, eps=cfg.eps))
     if name in (C.ADAM8BIT_OPTIMIZER, C.ADAMW8BIT_OPTIMIZER):
         # int8 Adam moments (ops/adam8bit.py): the single-chip analog of
@@ -42,20 +59,23 @@ def build_optimizer(cfg: OptimizerConfig,
         from ..ops.adam8bit import adamw_8bit
         wd = cfg.weight_decay if name == C.ADAMW8BIT_OPTIMIZER or \
             cfg.extra.get("adam_w_mode", False) else 0.0
-        tx = adamw_8bit(lr, b1=b1, b2=b2, eps=cfg.eps, weight_decay=wd)
+        tx = adamw_8bit(lr, b1=b1, b2=b2, eps=cfg.eps, weight_decay=wd,
+                        mask=mask)
         if name == C.ADAM8BIT_OPTIMIZER and cfg.weight_decay and not wd:
-            tx = optax.chain(optax.add_decayed_weights(cfg.weight_decay), tx)
+            tx = optax.chain(optax.add_decayed_weights(cfg.weight_decay, mask),
+                             tx)
         return tx
     if name == C.LAMB_OPTIMIZER:
         return optax.lamb(lr, b1=b1, b2=b2, eps=cfg.eps,
-                          weight_decay=cfg.weight_decay)
+                          weight_decay=cfg.weight_decay, mask=mask)
     if name == C.SGD_OPTIMIZER:
         return optax.sgd(lr, momentum=cfg.extra.get("momentum", 0.0),
                          nesterov=bool(cfg.extra.get("nesterov", False)))
     if name == C.ADAGRAD_OPTIMIZER:
         return optax.adagrad(lr, eps=cfg.eps)
     if name == C.LION_OPTIMIZER:
-        return optax.lion(lr, b1=b1, b2=b2, weight_decay=cfg.weight_decay)
+        return optax.lion(lr, b1=b1, b2=b2, weight_decay=cfg.weight_decay,
+                          mask=mask)
     if name in (C.ONEBIT_ADAM_OPTIMIZER, C.ONEBIT_LAMB_OPTIMIZER,
                 C.ZERO_ONE_ADAM_OPTIMIZER):
         try:
@@ -80,7 +100,8 @@ def clip_by_global_norm(max_norm: float) -> optax.GradientTransformation:
     return optax.GradientTransformation(clip.init, update)
 
 
-def build_tx(config: Config, learning_rate: Optional[ScalarOrSchedule] = None
+def build_tx(config: Config, learning_rate: Optional[ScalarOrSchedule] = None,
+             mask: Optional[Callable] = None
              ) -> optax.GradientTransformation:
     """Full gradient-transformation chain: clip → optimizer.
 
@@ -91,5 +112,5 @@ def build_tx(config: Config, learning_rate: Optional[ScalarOrSchedule] = None
     parts = []
     if config.gradient_clipping and config.gradient_clipping > 0:
         parts.append(clip_by_global_norm(config.gradient_clipping))
-    parts.append(build_optimizer(config.optimizer, learning_rate))
+    parts.append(build_optimizer(config.optimizer, learning_rate, mask))
     return optax.chain(*parts) if len(parts) > 1 else parts[0]

@@ -174,14 +174,28 @@ class _Staged(_Watched):
     """A watched ``jax.jit`` that keeps the executable it runs.  The
     steady call is the kept ``Compiled`` and nothing else: no flatten, no
     hash, no signature (its C++ fast path checks the arguments as
-    ``jax.jit``'s does)."""
+    ``jax.jit``'s does).
 
-    __slots__ = ("compiled", "_made")
+    :meth:`prepare` makes an executable AHEAD of the first call, on the
+    caller's thread (one of its own, beside other set-up): the first call
+    whose arguments it accepts runs it instead of compiling, and books it
+    as that call's compile (signature, memory, collectives) as if it had
+    made it."""
+
+    __slots__ = ("compiled", "_made", "_prepared")
 
     def __init__(self, fn, name: str, warn: bool, dog: "RecompileWatchdog"):
         super().__init__(fn, name, warn, dog)
         self.compiled = None           # the executable the last call ran
         self._made = {}                # signature -> executables made for it
+        self._prepared = []            # made ahead of a call, not yet run
+
+    def prepare(self, *args, **kwargs) -> None:
+        """Trace, lower and compile for arguments like these: arrays, or
+        ``jax.ShapeDtypeStruct``s that carry the shardings the call's will
+        have.  What a later call does not accept costs that call nothing but
+        its own compile."""
+        self._prepared.append(self._fn.trace(*args, **kwargs).lower().compile())
 
     def __call__(self, *args, **kwargs):
         compiled = self.compiled
@@ -219,8 +233,18 @@ class _Staged(_Watched):
         tls = _compile_tls
         before = tls.unclaimed
         t0 = time.perf_counter()
-        compiled = self._fn.trace(*args, **kwargs).lower().compile()
-        out = compiled(*args, **kwargs)
+        compiled = None
+        for ready in list(self._prepared):
+            try:        # the argument check refuses before anything is donated
+                out = ready(*args, **kwargs)
+            except (TypeError, ValueError):
+                continue
+            self._prepared.remove(ready)
+            compiled = ready
+            break
+        if compiled is None:
+            compiled = self._fn.trace(*args, **kwargs).lower().compile()
+            out = compiled(*args, **kwargs)
         self._made.setdefault(sig, []).append(compiled)
         self.compiled = compiled
         tls.unclaimed = before         # as _Watched: the executable is ours

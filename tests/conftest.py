@@ -219,6 +219,11 @@ _SUPERSEDED = [
      ("test_the_two_new_metrics_stand_last_and_list_this_cell_alone",),
      "pins the last two per-layer metrics to the sixth cell's; superseded "
      "by test_lfm2_cell.py (PR 45)"),
+    ("test_ling3_cell.py",
+     ("test_the_four_new_metrics_list_this_cell_alone",),
+     "pins the last four per-layer metrics to the eleventh cell's; "
+     "superseded by test_xing4_cell.py (PR 62), which holds the same four "
+     "entries and the cell's list by name and order, at no position"),
 ]
 
 

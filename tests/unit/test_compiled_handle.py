@@ -266,3 +266,78 @@ def test_the_train_step_is_made_once_and_every_site_has_its_handle(engine):
         or "while" in engine.compiled_step("engine.multi_step[2]").as_text()
     engine.reseed(7)                         # drops the step closures
     assert engine.compiled_step() is None
+
+
+def test_a_prepared_executable_is_what_the_first_call_runs():
+    """``prepare`` (PR 62) makes the executable ahead of the call, from
+    shapes alone and on another thread; the first call it accepts runs it,
+    makes none of its own and books it as that call's compile; arguments it
+    refuses cost their own compile and leave it waiting."""
+    import threading
+
+    reg = Registry()
+    f = _staged(reg, "unit.prepared", donate_argnums=(0,))
+    shapes = ({"w": jax.ShapeDtypeStruct((4,), jnp.float32),
+               "n": jax.ShapeDtypeStruct((), jnp.int32)},
+              jax.ShapeDtypeStruct((4,), jnp.float32))
+    thread = threading.Thread(target=f.prepare, args=shapes)
+    thread.start()
+    thread.join()
+    assert len(f._prepared) == 1 and f.compiled is None
+    ready = f._prepared[0]
+    batch = jnp.ones((4,), jnp.float32)
+    other = f(_state(8), jnp.ones((8,), jnp.float32))      # refused by it
+    assert f.compiled is not ready and len(f._prepared) == 1
+    assert int(other[0]["n"]) == 1
+    before = _executables()
+    state, metrics = f(_state(), batch)
+    assert f.compiled is ready and f._prepared == []
+    assert _executables() == before                 # nothing was made
+    assert float(state["w"][0]) == 4.0 and float(metrics["loss"]) == 1.0
+    assert _site_value(reg, "xla_compiled_signatures_total",
+                       "unit.prepared") == 2
+    assert reg.gauge("hbm_exec_reserved_bytes", labelnames=("site",)).labels(
+        site="unit.prepared").value > 0
+    state, _ = f(state, batch)                      # the steady call
+    assert f.compiled is ready and int(state["n"]) == 2
+
+
+def test_the_engine_prepares_its_train_step_beside_init_params():
+    """``Engine.prepare_train_step``: the step's executable made from the
+    batch's shapes on a thread of the engine's while ``init_params`` runs;
+    the first ``train_batch`` runs it (no executable of its own) and the
+    loss falls as in a run that compiled in place."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+
+    def engine_of():
+        cfg = LlamaConfig(vocab_size=160, hidden_size=64, num_hidden_layers=2,
+                          num_attention_heads=4, intermediate_size=112,
+                          max_position_embeddings=32, scan_layers=False,
+                          attn_impl="jnp", vocab_pad_multiple=32)
+        return deepspeed_tpu.initialize(model=LlamaForCausalLM(cfg), config={
+            "optimizer": {"type": "adamw8bit", "params": {"lr": 1e-3}},
+            "zero_optimization": {"stage": 3}, "mesh": {"fsdp": -1},
+            "train_micro_batch_size_per_gpu": 1, "steps_per_print": 10**9})[0]
+
+    engine = engine_of()
+    rows = engine.train_batch_size
+    ids = np.random.default_rng(1).integers(0, 160, (rows, 32)).astype(
+        np.int32)
+    batch = {"input_ids": ids, "labels": ids}
+    thread = engine.prepare_train_step({
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()})
+    engine.init_params()
+    thread.join()
+    site = engine._compiled_train_step
+    assert len(site._prepared) == 1
+    ready = site._prepared[0]
+    losses = [float(engine.train_batch(data_iter=iter([batch] * 3)))
+              for _ in range(3)]
+    assert site.compiled is ready and site._prepared == []
+    assert engine.compiled_step() is ready
+    plain = engine_of()
+    plain.init_params()
+    want = [float(plain.train_batch(data_iter=iter([batch] * 3)))
+            for _ in range(3)]
+    assert losses == want and losses[-1] < losses[0]

@@ -1686,3 +1686,133 @@ def test_the_eleventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert "shared rope lanes" in rows_of[("attention", "flash")]
     assert ("attention", "jnp") not in rows_of
     print(f"reserved {reserved:.3f} GiB")
+
+
+@pytest.mark.parametrize("kernel", ["gather", "gather_scaled", "combine",
+                                    "combine_dw"])
+def test_the_row_kernels_compile_at_the_twelfth_cells_width(one_chip, kernel):
+    """Rows of 3,584 channels, the widest a cell moves (PR 62; 2,560 before):
+    ``gather_rows``' double-buffered ``(1024, 3584)`` block and its stages
+    asked 17.5 MiB of Mosaic's 16 MiB default scope, so ``_wide_rows`` gives
+    the call 32; at the older widths it adds nothing and the calls are what
+    they were."""
+    from deepspeed_tpu.ops.pallas import moe_rows
+
+    assert moe_rows._wide_rows(2560 // 2) == {} \
+        and moe_rows._wide_rows(2304 // 2) == {}
+    assert moe_rows._wide_rows(3584 // 2)[
+        "compiler_params"].vmem_limit_bytes == 32 << 20
+    tokens, k, width = 8192, 4, 3584
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = tokens * k
+    idx, live = arg((rows,), jnp.int32), arg((1,), jnp.int32)
+    weights = arg((tokens, k), jnp.float32)
+    fn, args = {
+        "gather": (moe_rows.gather_rows, (
+            arg((tokens, 1, width // 2), jnp.uint32), idx, live)),
+        "gather_scaled": (moe_rows.gather_rows, (
+            arg((tokens, 1, width // 2), jnp.uint32), idx, live,
+            arg((rows, 1), jnp.float32))),
+        "combine": (moe_rows.combine_rows, (
+            arg((rows, 1, width // 2), jnp.uint32), idx, weights)),
+        "combine_dw": (moe_rows.combine_rows, (
+            arg((rows, 1, width // 2), jnp.uint32), idx, weights,
+            arg((tokens, width), jnp.bfloat16))),
+    }[kernel]
+    text = jax.jit(lambda *a: fn(*a, name="moe_rows_back")).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_a_hyper_connection_compiles_with_one_loop_a_pass(one_chip):
+    """One sublayer's hyper-connection at the twelfth cell's shape, (1,
+    8192, 4 x 3584) in bf16, forward and backward for the described v5e: the
+    20 Sinkhorn sweeps are ONE while loop a pass (forward and its reverse
+    scan), not 20 unrolled copies, and no ``(T, 4, 4)`` array is formed -
+    the maps keep tokens on the lane axis."""
+    import re
+
+    from deepspeed_tpu.models.llama import HyperConnection, LlamaConfig
+
+    cfg = LlamaConfig(hidden_size=3584, num_attention_heads=32, hc_mult=4,
+                      scan_layers=False, rms_norm_eps=1e-6)
+    module = HyperConnection(cfg)
+    X = jax.ShapeDtypeStruct((1, 8192, 4 * 3584), jnp.bfloat16,
+                             sharding=one_chip)
+    y = jax.ShapeDtypeStruct((1, 8192, 3584), jnp.bfloat16, sharding=one_chip)
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0),
+                            jnp.zeros(X.shape, X.dtype)))["params"]
+    from flax.core import meta
+
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        meta.unbox(shapes))
+
+    def loss(p, X, y):
+        u, maps = module.apply({"params": p}, X)
+        out = HyperConnection.post(X, (y + u).astype(X.dtype), maps)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        params, X, y).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 2
+    assert not re.search(r"f32\[8192,4,4\]", text)
+    assert "tpu_custom_call" not in text        # XLA's fusions, no kernel yet
+
+
+@pytest.mark.slow
+def test_the_twelfth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
+    """``train-xing4-mhc-8k-1chip`` (PR 62) as the benchmark builds it, its
+    whole train step compiled for the described chip: six latent-attention
+    blocks (the prediction block's among them) through the two-product flash
+    kernels, each sublayer under a hyper-connection over four lanes, 8 of 64
+    experts held; 913,473,668 parameters in the leaves; one packed
+    8,192-token row reserves 12.2 of the chip's 15.75 GiB (two ask 18.10:
+    ``compile_said`` in the configuration file holds both readings).
+    Marked slow, as the ninth's to the eleventh's are: ~95 s of compile."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-xing4-mhc-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.lanes, cfg.mtp_blocks) == (1, 8192, 4, 1)
+    assert sum(int(x.size) for x in jax.tree_util.tree_leaves(
+        state.params)) == 913_473_668
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 12.0 < reserved < 12.5, reserved
+    text = compiled.as_text()
+    assert "self_attn_mla" in text
+    # a Sinkhorn loop a sublayer a pass (12 x forward, recompute, backward)
+    # beside the head's and the kernels' own loops: tens, not hundreds
+    assert 36 <= len(re.findall(r" while\(", text)) <= 64
+    rows_of = {(s, i) for s, i, r, n in dispatch_report() if n}
+    assert {("attention", "flash"), ("grouped_matmul", "megablox"),
+            ("moe_rows", "pallas")} <= rows_of
+    print(f"reserved {reserved:.3f} GiB")
