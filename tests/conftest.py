@@ -31,6 +31,12 @@ import threading  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+# The run's budget (PR 63): 1,470 s for the whole ``-m 'not slow'`` suite on
+# six xdist workers, bound by the SUM of its tests: a PR that adds tests
+# states their test-seconds (ROADMAP.md C14), and a model file compares with
+# its reference through ``tests/unit/reference_compare.py`` (one ``jax.jit``
+# a side), not through a copy of another file: bare costs 2.5 x compiled.
+#
 # What one test's call may take: a hung test must cost the run this, not the
 # whole of its limit (``pytest-timeout`` is not installed and cannot be).
 TEST_SECONDS = 300
@@ -39,7 +45,8 @@ TEST_SECONDS = 300
 @contextlib.contextmanager
 def call_limit(name: str, seconds: float = TEST_SECONDS):
     """A ``SIGALRM`` timer around a test's call: past ``seconds`` the test
-    FAILS with its ``name`` and the worker goes on to the next.  The main
+    FAILS with its ``name`` and the worker goes on to the next (300 s a call
+    of the run's 1,470 s: a guard against a hang, no room bought).  The main
     thread only (every xdist worker runs its tests there); the signal is
     seen when the interpreter next runs, so a call that never returns from
     native code is out of its reach."""

@@ -1,9 +1,12 @@
 """Tiny model/data fixtures — analog of reference ``tests/unit/simple_model.py``
 (``SimpleModel`` :12, ``random_dataloader``, ``args_from_dict``)."""
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flax.core import meta
 
 
 class SimpleModel(nn.Module):
@@ -46,6 +49,33 @@ class EmbedModel(nn.Module):
     def dummy_inputs(self, batch_size=2, seq_len=8):
         ids = jnp.zeros((batch_size, seq_len), jnp.int32)
         return {"input_ids": ids, "labels": ids}
+
+
+@functools.lru_cache(maxsize=None)
+def _seeded(model):
+    return meta.unbox(jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+
+def seeded_params(model):
+    """Unboxed leaves of ``model.init`` at key 0 on a ``(1, 8)`` row of zeros,
+    ONE compiled program a model (a flax module hashes by its fields) a
+    process: thirty serving tests built them bare, sixty executables a call.
+    The arrays are shared; the dicts that hold them are the caller's own."""
+    return jax.tree_util.tree_map(lambda a: a, _seeded(model))
+
+
+def tiny_gpt2_engine(cfg_over=None, mp_size=1, **kwargs):
+    """``init_inference`` of gpt2-tiny in float32 on :func:`seeded_params`:
+    the engine a dozen serving files built by a copy each."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+    model = GPT2LMHeadModel(gpt2_config("gpt2-tiny", dtype=jnp.float32,
+                                        **(cfg_over or {})))
+    return deepspeed_tpu.init_inference(
+        model=model, mp_size=mp_size, dtype=jnp.float32,
+        params=seeded_params(model), **kwargs)
 
 
 def random_dataset(total_samples: int, hidden_dim: int, seed: int = 0):

@@ -30,6 +30,8 @@ def test_autotuner_probes_and_picks():
         micro_batches=[1, 2],
         zero_stages=[0, 2],
         remat_options=[False],
+        # the kernel knobs (x3 here) have their own test, the next but one
+        kernel_options=[{}],
         seq_len=32)
     best = tuner.tune()
     assert "train_micro_batch_size_per_gpu" in best
@@ -162,7 +164,7 @@ def test_northstar_space_probes_and_picks():
         model,
         base_config={"optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
                      "steps_per_print": 10**9},
-        micro_batches=[1, 2],
+        micro_batches=[2],      # the first test probes the micro-batches
         remat_options=[False],
         kernel_options=[{"scan_layers": False, "loss_chunk": None},
                         {"scan_layers": False, "loss_chunk": 64}],

@@ -9,6 +9,8 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.bert import BertForPreTraining, bert_config
 
+from . import reference_compare as compare
+
 
 @pytest.fixture(autouse=True)
 def fresh_mesh():
@@ -48,7 +50,7 @@ def test_bert_sparse_attention_variant():
                       dtype=jnp.float32)
     model = BertForPreTraining(cfg)
     ids = np.random.default_rng(0).integers(0, 512, size=(2, 128)).astype(np.int32)
-    params = model.init(jax.random.PRNGKey(0), jnp.asarray(ids))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.asarray(ids))
     out = model.apply(params, jnp.asarray(ids))
     assert out["logits"].shape == (2, 128, 512)
     assert np.isfinite(np.asarray(out["logits"], np.float32)).all()
@@ -71,7 +73,7 @@ def test_hf_bert_parity():
     ids = np.random.default_rng(1).integers(0, 128, size=(2, 12))
     with torch.no_grad():
         hf_out = hf_model(torch.tensor(ids))
-    out = model.apply({"params": params}, jnp.asarray(ids, jnp.int32))
+    out = compare.apply(model, params, jnp.asarray(ids, jnp.int32))
     np.testing.assert_allclose(
         np.asarray(out["logits"][:, :, :128], np.float32),
         hf_out.prediction_logits.numpy(), rtol=2e-3, atol=2e-3)

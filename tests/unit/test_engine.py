@@ -247,10 +247,9 @@ def test_chunked_lm_loss_matches_dense():
     def loss_and_gradsum(chunk):
         cfg = gpt2_config("gpt2-tiny", scan_layers=True, loss_chunk=chunk)
         m = GPT2LMHeadModel(cfg)
-        params = m.init(jax.random.PRNGKey(0), ids)["params"]
-        loss = m.apply({"params": params}, ids, labels=ids)["loss"]
-        g = jax.grad(lambda p: m.apply(
-            {"params": p}, ids, labels=ids)["loss"])(params)
+        params = jax.jit(m.init)(jax.random.PRNGKey(0), ids)["params"]
+        loss, g = jax.jit(jax.value_and_grad(lambda p: m.apply(
+            {"params": p}, ids, labels=ids)["loss"]))(params)
         gsum = jax.tree_util.tree_reduce(
             lambda a, b: a + float(jnp.sum(jnp.abs(b))), g, 0.0)
         return float(loss), float(gsum)

@@ -44,8 +44,8 @@ def test_flash_backward_matches_dense(causal):
     def f_ref(q, k, v):
         return jnp.sum(_ref(q, k, v, causal) ** 2)
 
-    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -186,10 +186,11 @@ def test_flash_layout_parity(flash_dispatch, H, D, layout, S, Sk, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref(q, k, v)),
                                rtol=2e-5, atol=2e-5)
     w = jnp.asarray(rng.normal(size=out.shape), jnp.float32)
-    g_flash = jax.grad(lambda *a: jnp.sum(attend(*a, causal=causal) * w),
-                       argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(lambda *a: jnp.sum(ref(*a) * w),
-                     argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(
+        lambda *a: jnp.sum(attend(*a, causal=causal) * w),
+        argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(ref(*a) * w),
+                             argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_flash, g_ref):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -215,8 +216,8 @@ _SCHEDULE_SEQS = [128, 512, 1024, 1536, 2560]
 
 
 def _assert_grads_close(f_flash, f_ref, args):
-    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(*args)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(*args)
+    g_flash = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(*args)
+    g_ref = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(*args)
     for a, b in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
@@ -440,8 +441,8 @@ def test_a_short_sweep_is_the_parents_program():
     with parent_sweeps():
         old = text()
     assert new == old and "pallas_call" in new
-    run = lambda: jax.grad(lambda q: flash_attention(
-        q, k, v, interpret=True).sum())(q)
+    run = lambda: jax.jit(jax.grad(lambda q: flash_attention(
+        q, k, v, interpret=True).sum()))(q)
     assert "while" not in kernel_primitives(run)
 
 

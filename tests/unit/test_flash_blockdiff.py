@@ -234,16 +234,21 @@ def test_noisy_and_clean_halves_match_the_dense_mask(one_device, L, H, KV,
         *a, block_diffusion=g, impl="flash", interpret=True,
         flash_opts={"block_q": tile, "block_k": tile})
     plain = lambda *a: _plain(*a, mask)
-    (out, pull), (want, pull_plain) = (jax.vjp(f, q, k, v)
-                                       for f in (kern, plain))
+
+    def run(f):     # forward and the gradients of (out * w).sum(), one program
+        def both(q, k, v):
+            out, pull = jax.vjp(f, q, k, v)
+            return out, pull(w)
+        return jax.jit(both)(q, k, v)
+
+    (out, got), (want, want_grads) = run(kern), run(plain)
     np.testing.assert_allclose(out, want, atol=2e-5)
     assert _flash_dispatches(g) == before + 1       # the kernels ran
     # the first block's rows keep their own block's keys and no clean key
     np.testing.assert_allclose(out[:, :g], want[:, :g], atol=2e-5,
                                err_msg="first block")
     assert float(jnp.abs(out[0] - out[1]).max()) > 0.1      # two contents
-    got, want = pull(w), pull_plain(w)   # the gradients of (out * w).sum()
-    for name, a, b in zip("qkv", got, want):
+    for name, a, b in zip("qkv", got, want_grads):
         assert bool(jnp.isfinite(a).all()), name
         for half, x, y in zip(("noisy", "clean"), np.split(a, 2, 1),
                               np.split(b, 2, 1)):

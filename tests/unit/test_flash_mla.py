@@ -58,8 +58,10 @@ def test_forward_and_all_five_gradients_match_jnp(B, S, block):
     kern = lambda *a: _two_products(
         *a, interpret=True, block_q=block, block_k=block)
     np.testing.assert_allclose(kern(*ops), _plain(*ops), atol=2e-5)
-    got = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=range(5))(*ops)
-    want = jax.grad(lambda *a: (_plain(*a) * w).sum(), argnums=range(5))(*ops)
+    got = jax.jit(jax.grad(lambda *a: (kern(*a) * w).sum(),
+                           argnums=range(5)))(*ops)
+    want = jax.jit(jax.grad(lambda *a: (_plain(*a) * w).sum(),
+                            argnums=range(5)))(*ops)
     for name, g, x in zip(NAMES, got, want):
         assert g.shape == x.shape, name
         np.testing.assert_allclose(g, x, atol=5e-5, err_msg=name)
@@ -73,8 +75,9 @@ def test_two_rows_with_different_contents_do_not_mix():
                                     block_k=128)
     one = [x[1:] for x in ops]
     np.testing.assert_allclose(kern(*ops)[1:], kern(*one), atol=1e-6)
-    both = jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=3)(*ops)
-    alone = jax.grad(lambda *a: (kern(*a) * w[1:]).sum(), argnums=3)(*one)
+    both = jax.jit(jax.grad(lambda *a: (kern(*a) * w).sum(), argnums=3))(*ops)
+    alone = jax.jit(jax.grad(lambda *a: (kern(*a) * w[1:]).sum(),
+                             argnums=3))(*one)
     np.testing.assert_allclose(both[1:], alone, atol=1e-5)
     assert float(jnp.abs(both[0] - both[1]).max()) > 0.1
 
@@ -84,8 +87,8 @@ def test_bf16_operands_keep_their_types():
     kern = lambda *a: _two_products(*a, interpret=True)
     out = kern(*ops)
     assert out.dtype == jnp.bfloat16
-    grads = jax.grad(lambda *a: kern(*a).astype(jnp.float32).sum(),
-                     argnums=range(5))(*ops)
+    grads = jax.jit(jax.grad(lambda *a: kern(*a).astype(jnp.float32).sum(),
+                             argnums=range(5)))(*ops)
     assert [g.dtype for g in grads] == [jnp.bfloat16] * 5
     want = _plain(*[x.astype(jnp.float32) for x in ops])
     assert float(jnp.abs(out.astype(jnp.float32) - want).max()) < 3e-2
@@ -109,10 +112,10 @@ def test_the_tile_counter_reads_as_at_the_same_S_without_the_second_product():
     S = 768      # its own length: tiles are counted when a call is traced
     *ops, _ = _operands(1, S, seed=1)
     q, k, v = ops[0], ops[2], ops[4]
-    plain = delta(lambda: jax.grad(lambda q: fa.flash_attention(
-        q, k, v, interpret=True).sum())(q))
-    two = delta(lambda: jax.grad(lambda q: _two_products(
-        q, ops[1], k, ops[3], v, interpret=True).sum())(q))
+    plain = delta(lambda: jax.jit(jax.grad(lambda q: fa.flash_attention(
+        q, k, v, interpret=True).sum()))(q))
+    two = delta(lambda: jax.jit(jax.grad(lambda q: _two_products(
+        q, ops[1], k, ops[3], v, interpret=True).sum()))(q))
     assert plain == two and plain
 
 

@@ -42,9 +42,10 @@ def test_flash_matches_xla_forward_and_backward(S, block, H, KV, window):
     want = _xla(q, k, v, window)
     assert got.shape == q.shape
     np.testing.assert_allclose(got, want, atol=2e-5)
-    grads = jax.grad(lambda *a: (flash_attention(*a, **kw) * g).sum(),
-                     (0, 1, 2))(q, k, v)
-    wants = jax.grad(lambda *a: (_xla(*a, window) * g).sum(), (0, 1, 2))(q, k, v)
+    grads = jax.jit(jax.grad(lambda *a: (flash_attention(*a, **kw) * g).sum(),
+                             (0, 1, 2)))(q, k, v)
+    wants = jax.jit(jax.grad(lambda *a: (_xla(*a, window) * g).sum(),
+                             (0, 1, 2)))(q, k, v)
     for name, a, b in zip("qkv", grads, wants):
         assert a.shape == b.shape       # dk, dv at the key-value heads
         np.testing.assert_allclose(a, b, atol=5e-5, err_msg=f"d{name}")
@@ -58,9 +59,10 @@ def test_looped_sweep_with_a_window_and_a_group():
     kw = dict(window=1024, interpret=True)
     np.testing.assert_allclose(flash_attention(q, k, v, **kw),
                                _xla(q, k, v, 1024), atol=2e-5)
-    grads = jax.grad(lambda *a: (flash_attention(*a, **kw) * g).sum(),
-                     (0, 1, 2))(q, k, v)
-    wants = jax.grad(lambda *a: (_xla(*a, 1024) * g).sum(), (0, 1, 2))(q, k, v)
+    grads = jax.jit(jax.grad(lambda *a: (flash_attention(*a, **kw) * g).sum(),
+                             (0, 1, 2)))(q, k, v)
+    wants = jax.jit(jax.grad(lambda *a: (_xla(*a, 1024) * g).sum(),
+                             (0, 1, 2)))(q, k, v)
     for a, b in zip(grads, wants):
         np.testing.assert_allclose(a, b, atol=1e-4)
 

@@ -49,9 +49,11 @@ def test_fused_mlp_fwd_bwd_parity():
     def loss_f(fn):
         return lambda *a: (fn(*a) ** 2).sum()
 
-    gp = jax.grad(loss_f(lambda *a: fused_mlp(*a, block_rows=32, interpret=True)),
-                  argnums=(0, 1, 2, 3, 4))(x, w1, b1, w2, b2)
-    gr = jax.grad(loss_f(ref), argnums=(0, 1, 2, 3, 4))(x, w1, b1, w2, b2)
+    gp = jax.jit(jax.grad(
+        loss_f(lambda *a: fused_mlp(*a, block_rows=32, interpret=True)),
+        argnums=(0, 1, 2, 3, 4)))(x, w1, b1, w2, b2)
+    gr = jax.jit(jax.grad(loss_f(ref), argnums=(0, 1, 2, 3, 4)))(
+        x, w1, b1, w2, b2)
     for a, r in zip(gp, gr):
         np.testing.assert_allclose(a, r, rtol=3e-4, atol=3e-4)
 
@@ -72,9 +74,11 @@ def test_fused_mlp_multi_tile_accumulation():
         return jax.nn.gelu(x @ w1 + b1, approximate=True) @ w2 + b2
 
     # block 16 → 8 tiles
-    gp = jax.grad(lambda *a: fused_mlp(*a, block_rows=16, interpret=True).sum(),
-                  argnums=(1, 3))(x, w1, b1, w2, b2)
-    gr = jax.grad(lambda *a: ref(*a).sum(), argnums=(1, 3))(x, w1, b1, w2, b2)
+    gp = jax.jit(jax.grad(
+        lambda *a: fused_mlp(*a, block_rows=16, interpret=True).sum(),
+        argnums=(1, 3)))(x, w1, b1, w2, b2)
+    gr = jax.jit(jax.grad(lambda *a: ref(*a).sum(), argnums=(1, 3)))(
+        x, w1, b1, w2, b2)
     np.testing.assert_allclose(gp[0], gr[0], rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(gp[1], gr[1], rtol=3e-4, atol=3e-4)
 
@@ -97,11 +101,11 @@ def test_fused_mlp_multi_f_tile(monkeypatch):
     def ref(x, w1, b1, w2, b2):
         return jax.nn.gelu(x @ w1 + b1, approximate=True) @ w2 + b2
 
-    gp = jax.grad(lambda *a: (fm.fused_mlp(*a, block_rows=32,
-                                           interpret=True) ** 2).sum(),
-                  argnums=(0, 1, 2, 3))(x, w1, b1, w2, b2)
-    gr = jax.grad(lambda *a: (ref(*a) ** 2).sum(),
-                  argnums=(0, 1, 2, 3))(x, w1, b1, w2, b2)
+    gp = jax.jit(jax.grad(lambda *a: (fm.fused_mlp(*a, block_rows=32,
+                                                   interpret=True) ** 2).sum(),
+                          argnums=(0, 1, 2, 3)))(x, w1, b1, w2, b2)
+    gr = jax.jit(jax.grad(lambda *a: (ref(*a) ** 2).sum(),
+                          argnums=(0, 1, 2, 3)))(x, w1, b1, w2, b2)
     for a, r in zip(gp, gr):
         np.testing.assert_allclose(a, r, rtol=3e-4, atol=3e-4)
 

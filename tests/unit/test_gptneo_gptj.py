@@ -14,7 +14,8 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gptj import GPTJForCausalLM, gptj_config
 from deepspeed_tpu.models.gptneo import GPTNeoForCausalLM, gptneo_config
 
-from .simple_model import token_batch
+from . import reference_compare as compare
+from .simple_model import seeded_params, token_batch
 
 
 @pytest.fixture(autouse=True)
@@ -50,17 +51,13 @@ def test_gptneo_local_attention_window():
     cfg = gptneo_config("neo-tiny", num_layers=1, attention_types=("local",),
                         window_size=4, dtype=jnp.float32)
     model = GPTNeoForCausalLM(cfg)
-    ids = jnp.zeros((1, 32), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
-    import flax.linen as nn
-
-    params = nn.meta.unbox(params)
-    base = np.asarray(model.apply({"params": params}, jnp.asarray(
+    params = seeded_params(model)
+    base = np.asarray(compare.apply(model, params, jnp.asarray(
         np.random.default_rng(0).integers(0, 512, (1, 32)), jnp.int32))["logits"])
     # perturbing a token >window back must not change the last position
     ids2 = np.random.default_rng(0).integers(0, 512, (1, 32))
     ids2[0, 5] = (ids2[0, 5] + 1) % 512
-    out2 = np.asarray(model.apply({"params": params},
+    out2 = np.asarray(compare.apply(model, params,
                                   jnp.asarray(ids2, jnp.int32))["logits"])
     np.testing.assert_allclose(base[0, -1], out2[0, -1], rtol=1e-5, atol=1e-5)
     assert not np.allclose(base[0, 6], out2[0, 6], rtol=1e-5, atol=1e-5)
@@ -110,7 +107,7 @@ def test_hf_gptneo_parity():
     ids = np.random.default_rng(1).integers(0, 128, size=(2, 12))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply({"params": params}, jnp.asarray(ids, jnp.int32))
+    ours = compare.apply(model, params, jnp.asarray(ids, jnp.int32))
     np.testing.assert_allclose(np.asarray(ours["logits"][:, :, :128], np.float32),
                                hf_logits, rtol=2e-3, atol=2e-3)
 
@@ -130,7 +127,7 @@ def test_hf_gptj_parity():
     ids = np.random.default_rng(1).integers(0, 128, size=(2, 12))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply({"params": params}, jnp.asarray(ids, jnp.int32))
+    ours = compare.apply(model, params, jnp.asarray(ids, jnp.int32))
     np.testing.assert_allclose(np.asarray(ours["logits"][:, :, :128], np.float32),
                                hf_logits, rtol=2e-3, atol=2e-3)
 
@@ -140,8 +137,7 @@ def test_gptj_generate():
     model = GPTJForCausalLM(cfg)
     import flax.linen as nn
 
-    params = nn.meta.unbox(
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(model=model, params=params,
                                        dtype=jnp.float32)
     ids = np.random.default_rng(0).integers(0, 512, size=(1, 4)).astype(np.int32)

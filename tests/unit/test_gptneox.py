@@ -2,14 +2,14 @@
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gptneox import GPTNeoXForCausalLM, gptneox_config
 
-from .simple_model import token_batch
+from . import reference_compare as compare
+from .simple_model import seeded_params, token_batch
 
 
 @pytest.fixture(autouse=True)
@@ -85,7 +85,7 @@ def test_hf_gptneox_parity():
     ids = np.random.default_rng(1).integers(0, 128, size=(2, 10))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply({"params": params}, jnp.asarray(ids, jnp.int32))
+    ours = compare.apply(model, params, jnp.asarray(ids, jnp.int32))
     np.testing.assert_allclose(np.asarray(ours["logits"][:, :, :128], np.float32),
                                hf_logits, rtol=2e-3, atol=2e-3)
 
@@ -93,10 +93,7 @@ def test_hf_gptneox_parity():
 def test_neox_generate():
     cfg = gptneox_config("neox-tiny", dtype=jnp.float32)
     model = GPTNeoXForCausalLM(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(model=model, params=params,
                                       dtype=jnp.float32)
     ids = np.random.default_rng(0).integers(0, 512, size=(1, 4)).astype(np.int32)

@@ -1,6 +1,8 @@
 """Inference engine + HF parity tests — analogs of reference
 ``tests/unit/test_inference.py`` and the kernel-parity role of
 ``test_cuda_forward.py`` (oracle = HF transformers on CPU torch)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -9,7 +11,9 @@ import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
-from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel, gpt2_config
+from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+from .simple_model import seeded_params, tiny_gpt2_engine
 
 
 @pytest.fixture(autouse=True)
@@ -19,16 +23,17 @@ def fresh_mesh():
     mesh_mod.set_mesh(None)
 
 
+@functools.lru_cache(maxsize=None)
+def _built(mp_size, **cfg_over):
+    mesh_mod.set_mesh(None)
+    return tiny_gpt2_engine(cfg_over, mp_size)
+
+
 def _tiny_engine(mp_size=1, **cfg_over):
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32, **cfg_over)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    eng = deepspeed_tpu.init_inference(model=model, mp_size=mp_size,
-                                       dtype=jnp.float32, params=params)
+    """ONE engine a configuration for the file's tests, which only read it;
+    its mesh is made current again after ``fresh_mesh`` took it away."""
+    eng = _built(mp_size, **cfg_over)
+    mesh_mod.set_mesh(eng.mesh)
     return eng
 
 
@@ -253,11 +258,7 @@ def test_moe_inference_ep_sharded():
         moe=MoEConfig(num_experts=4, top_k=2, capacity_factor=2.0,
                       eval_capacity_factor=2.0))
     model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(model=model, params=params,
                                        dtype=jnp.float32, ep_size=4)
     assert eng.mesh.shape["ep"] == 4

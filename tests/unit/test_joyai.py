@@ -23,6 +23,9 @@ from deepspeed_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
 from deepspeed_tpu.parallel.moe import STATE_LEAF, MoEConfig, MoELayer
 from deepspeed_tpu.runtime import state_leaves
 
+from . import reference_compare as compare
+from .reference_compare import rel as _rel
+
 reference = load_module(ROOT, "reference", "joyai")
 
 S, VOCAB, ROUTED, HELD, TOP_K, RATE, EPS = 64, 160, 16, 4, 4, 0.001, 1e-6
@@ -81,10 +84,7 @@ def _gates(params):
 def _params(model, ids, scale=6.0):
     """Seeded weights, scaled up so that attention is not near-uniform and
     the router's choices are not near-ties; a bias that is not zero."""
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), ids,
-                                   labels=ids)["params"])
-    params = jax.tree_util.tree_map(
-        lambda a: a * scale if a.ndim >= 2 else a, params)
+    params = compare.init(model, ids, labels=ids, scale=scale)
     for i, gate in enumerate(_gates(params)):
         gate[STATE_LEAF] = _bias(i)
     return params
@@ -99,14 +99,19 @@ def setup():
     return cfg, model, ids, _params(model, ids)
 
 
-def _rel(a, b):
-    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+@pytest.fixture(scope="module")
+def program(setup):
+    """``(out, grads)``: the forward's outputs and the gradient of every
+    trained leaf, one compiled program for the tests that read either."""
+    _, model, ids, params = setup
+    trained, held = state_leaves.split(params, model.is_state_leaf)
+    return compare.forward_and_gradients(lambda p: model.apply(
+        {"params": state_leaves.merge(p, held)}, ids, labels=ids), trained)
 
 
-def test_loss_and_both_parts_match_the_reference(setup):
+def test_loss_and_both_parts_match_the_reference(setup, program):
     cfg, model, ids, params = setup
-    out = model.apply({"params": params}, ids, labels=ids)
+    out = program[0]
     main, second = reference.loss_parts(params, ids, **_reference_kwargs(cfg))
     assert abs(float(out["lm_loss"]) - float(main)) < 2e-5
     assert abs(float(out["mtp_loss"]) - float(second)) < 2e-5
@@ -121,50 +126,47 @@ def test_loss_and_both_parts_match_the_reference(setup):
     assert out["stats"]["tokens_per_expert"].shape == (3, ROUTED)
 
 
-def test_chunked_head_gives_the_same_two_losses(setup):
+def test_chunked_head_gives_the_same_two_losses(setup, program):
     cfg, _, ids, params = setup
-    whole = LlamaForCausalLM(cfg).apply({"params": params}, ids, labels=ids)
-    chunked = LlamaForCausalLM(_config(loss_chunk=32)).apply(
-        {"params": params}, ids, labels=ids)
+    whole = program[0]
+    chunked = compare.apply(LlamaForCausalLM(_config(loss_chunk=32)), params,
+                            ids, labels=ids)
     for key in ("loss", "lm_loss", "mtp_loss"):
         assert float(chunked[key]) == pytest.approx(float(whole[key]),
                                                     abs=1e-5)
     assert "logits" not in chunked
 
 
-def test_every_gradient_matches_the_reference(setup):
+def test_every_gradient_matches_the_reference(setup, program):
     """Every leaf, the table and the head among them: each is used by both
     losses and has ONE gradient, the sum."""
     cfg, model, ids, params = setup
     trained, held = state_leaves.split(params, model.is_state_leaf)
-
-    def program(p):
-        return model.apply({"params": state_leaves.merge(p, held)}, ids,
-                           labels=ids)["loss"]
-
-    def plain(p):
-        return reference.training_loss(state_leaves.merge(p, held), ids,
-                                       mtp_weight=0.3,
-                                       **_reference_kwargs(cfg))
-
-    got, want = jax.grad(program)(trained), jax.grad(plain)(trained)
-    flat_got = jax.tree_util.tree_leaves_with_path(got)
-    flat_want = jax.tree_util.tree_leaves(want)
-    assert len(flat_got) == len(flat_want) > 40
-    for (path, g), w in zip(flat_got, flat_want):
-        assert np.linalg.norm(w) > 0, path
-        assert _rel(g, w) < 2e-3, (jax.tree_util.keystr(path), _rel(g, w))
+    # the reference's side bare: op by op its lines are the cheaper
+    want = jax.grad(lambda p: reference.training_loss(
+        state_leaves.merge(p, held), ids, mtp_weight=0.3,
+        **_reference_kwargs(cfg)))(trained)
+    paths, _ = compare.compare_leaves(program[1], want, tol=2e-3,
+                                      measure="norm")
+    assert len(paths) > 40
     # the head's gradient is not the main loss's alone
-    alone = jax.grad(lambda p: model.apply(
+    alone = jax.jit(jax.grad(lambda p: model.apply(
         {"params": state_leaves.merge(p, held)}, ids,
-        labels=ids)["lm_loss"])(trained)
+        labels=ids)["lm_loss"]))(trained)
     for leaf in ("embed_tokens", "lm_head"):
         assert _rel(alone[leaf], want[leaf]) > 1e-2
 
 
 def _attention_alone(cfg, p_attn, h):
     pos = jnp.arange(h.shape[1])[None, :]
-    return LlamaLatentAttention(cfg).apply({"params": p_attn}, h, pos, None)
+    return compare.apply(LlamaLatentAttention(cfg), p_attn, h, pos, None)
+
+
+@pytest.fixture(scope="module")
+def attention_1(setup, hiddens):
+    """The program's layer-1 attention, once for every fault it refuses."""
+    return _attention_alone(setup[0], setup[3]["layers_1"]["self_attn"],
+                            hiddens[1][1])
 
 
 def _attn_ref(cfg, p_attn, h, fault=None):
@@ -194,14 +196,14 @@ def test_one_attention_layer_alone_matches(setup, hiddens):
 
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
-def test_the_attention_refuses_each_assumed_item_done_wrong(setup, hiddens,
-                                                            fault):
+def test_the_attention_refuses_each_assumed_item_done_wrong(
+        setup, hiddens, attention_1, fault):
     """Rotary on the nope channels, halves where pairs are meant on q or on
     k alone, the scale of the nope width, the rope key of the next
     position, a latent norm left out, bf16 accumulation."""
     cfg, _, _, params = setup
     p, h = params["layers_1"]["self_attn"], hiddens[1][1]
-    err = _rel(_attention_alone(cfg, p, h), _attn_ref(cfg, p, h, fault))
+    err = _rel(attention_1, _attn_ref(cfg, p, h, fault))
     assert err > (1e-3 if fault == "bf16_accumulation" else 2e-2), err
 
 

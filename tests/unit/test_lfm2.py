@@ -12,7 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from benchmark.harness.manifest import ROOT, load_module
 from deepspeed_tpu.comm import mesh as mesh_lib
@@ -21,6 +20,9 @@ from deepspeed_tpu.models.llama import (CONV, FULL_ATTENTION, LlamaAttention,
                                         ShortConv)
 from deepspeed_tpu.parallel.moe import (STATE_LEAF, MoEConfig, MoELayer,
                                         topk_routing)
+
+from . import reference_compare as compare
+from .reference_compare import rel as _rel
 
 reference = load_module(ROOT, "reference", "lfm2")
 
@@ -68,17 +70,10 @@ def _bias(layer, scale=0.2):
 def _params(model, ids, scale=6.0):
     """Seeded weights, scaled up so that attention is not near-uniform and
     the router's choices are not near-ties; a bias that is not zero."""
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
-    params = jax.tree_util.tree_map(
-        lambda a: a * scale if a.ndim >= 2 else a, params)
+    params = compare.init(model, ids, scale=scale)
     for i in range(model.cfg.num_dense_layers, model.cfg.num_hidden_layers):
         params[f"layers_{i}"]["moe"]["gate"][STATE_LEAF] = _bias(i)
     return params
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
 @pytest.fixture(scope="module")
@@ -103,7 +98,8 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids, first, held):
         params["layers_2"] and "self_attn" not in params["layers_1"]
     assert "moe" not in params["layers_0"] and "moe" in params["layers_1"]
     kw = _reference_kwargs(cfg)
-    out = model.apply({"params": params}, ids, labels=ids)
+    out, got = compare.forward_and_gradients(
+        lambda p: model.apply({"params": p}, ids, labels=ids), params)
     counts = []
     want = reference.logits(params, ids, counts=counts, **kw)
     np.testing.assert_allclose(out["logits"][..., :VOCAB], want[..., :VOCAB],
@@ -115,27 +111,18 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids, first, held):
                                reference.training_loss(params, ids, **kw),
                                rtol=1e-5)
     assert out["stats"]["tokens_per_expert"].shape == (4, ROUTED)
-    got = jax.grad(lambda p: model.apply({"params": p}, ids,
-                                         labels=ids)["loss"])(params)
+    # the reference's side bare: op by op its lines are the cheaper
     ref = jax.grad(lambda p: reference.training_loss(p, ids, **kw))(params)
-    flat_got = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    for path, r in jax.tree_util.tree_flatten_with_path(ref)[0]:
-        name = jax.tree_util.keystr(path)
-        g = flat_got[path]
-        if STATE_LEAF in name:
-            assert not np.any(g) and not np.any(r), name    # no gradient
-            continue
-        assert float(jnp.abs(r).max()) > 0, name
-        np.testing.assert_allclose(
-            g, r, atol=2e-4 * float(jnp.abs(r).max()), err_msg=name)
+    compare.compare_leaves(got, ref, tol=2e-4, measure="max",
+                           no_gradient=(STATE_LEAF,))
     # the tied table's gradient is the sum of both uses: rows no input
     # ever embedded still move, through the head alone
     table = np.asarray(got["embed_tokens"])
     assert np.abs(table[VOCAB // 2:VOCAB]).max() > 0
     untied = LlamaForCausalLM(_config(first, held, tie_word_embeddings=False))
     p2 = dict(params, lm_head=params["embed_tokens"].T)
-    g2 = jax.grad(lambda p: untied.apply({"params": p}, ids,
-                                         labels=ids)["loss"])(p2)
+    g2 = jax.jit(jax.grad(lambda p: untied.apply(
+        {"params": p}, ids, labels=ids)["loss"]))(p2)
     assert not np.any(np.asarray(g2["embed_tokens"])[VOCAB // 2:VOCAB])
     np.testing.assert_allclose(
         table, np.asarray(g2["embed_tokens"]) + np.asarray(g2["lm_head"]).T,
@@ -146,7 +133,7 @@ def test_the_chunked_head_and_bf16_follow(ids):
     cfg = _config(loss_chunk=16, dtype=jnp.bfloat16)
     model = LlamaForCausalLM(cfg)
     params = _params(model, ids, scale=1.0)
-    out = model.apply({"params": params}, ids, labels=ids)
+    out = compare.apply(model, params, ids, labels=ids)
     assert "logits" not in out
     want = reference.training_loss(params, ids, **_reference_kwargs(cfg))
     assert abs(float(out["loss"]) - float(want)) < 0.03
@@ -168,25 +155,29 @@ def layers_alone(ids):
     for name in ("q_norm", "k_norm"):
         attn[name]["scale"] = jnp.asarray(rng.uniform(0.5, 3.0, 8),
                                           jnp.float32)
-    return cfg, params, hidden
+    # the program's side once for every fault: two conv mixers, the attention
+    got = {layer: compare.apply(ShortConv(cfg), params[f"layers_{layer}"][
+        "conv"], hidden[layer]) for layer in (0, 3)}
+    got[2] = compare.apply(LlamaAttention(cfg, FULL_ATTENTION), attn,
+                           hidden[2], jnp.arange(S)[None, :], None)
+    return cfg, params, hidden, got
 
 
 @pytest.mark.parametrize("fault", [None, *reference.CONV_FAULTS])
 def test_the_conv_mixer_alone_against_each_named_fault(layers_alone, fault):
-    cfg, params, hidden = layers_alone
+    cfg, params, hidden, got = layers_alone
     for layer in (0, 3):        # the dense block's and a sparse block's
         p, h = params[f"layers_{layer}"]["conv"], hidden[layer]
         assert h.shape == (2, S, 32)    # two rows: a leak between them shows
-        got = ShortConv(cfg).apply({"params": p}, h)
-        err = _rel(got, reference.short_conv(p, h, fault=fault))
+        err = _rel(got[layer], reference.short_conv(p, h, fault=fault))
         if fault is None:
             assert err < 1e-5, (layer, err)
         else:
             assert err > 1e-2, (fault, layer, err)
     if fault is None:           # and its backward, every leaf
         probe = jax.random.normal(jax.random.PRNGKey(5), h.shape)
-        dh, dp = jax.grad(lambda h, p: (ShortConv(cfg).apply(
-            {"params": p}, h) * probe).sum(), (0, 1))(h, p)
+        dh, dp = jax.jit(jax.grad(lambda h, p: (ShortConv(cfg).apply(
+            {"params": p}, h) * probe).sum(), (0, 1)))(h, p)
         rh, rp = reference.short_conv_grads(p, h, probe)
         assert _rel(dh, rh) < 1e-5
         for leaf in p:
@@ -195,11 +186,9 @@ def test_the_conv_mixer_alone_against_each_named_fault(layers_alone, fault):
 
 @pytest.mark.parametrize("fault", [None, *reference.FAULTS])
 def test_attention_alone_against_each_named_fault(layers_alone, fault):
-    cfg, params, hidden = layers_alone
+    cfg, params, hidden, got = layers_alone
     p, h = params["layers_2"]["self_attn"], hidden[2]
-    got = LlamaAttention(cfg, FULL_ATTENTION).apply(
-        {"params": p}, h, jnp.arange(S)[None, :], None)
-    err = _rel(got, reference.attention(
+    err = _rel(got[2], reference.attention(
         FULL_ATTENTION, p, h, n_head=4, n_kv_head=2, head_dim=8,
         rope_theta=100.0, eps=cfg.rms_norm_eps, fault=fault))
     assert (err < 1e-5) if fault is None else (err > 1e-2), (fault, err)
@@ -210,7 +199,7 @@ def test_the_expert_layer_alone_against_each_named_fault(fault):
     M, I = 32, 24
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, M))
     layer = MoELayer(_moe(2, 4), model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = meta.unbox(layer.init(jax.random.PRNGKey(0), x)["params"])
+    p = compare.init(layer, x)
     p = jax.tree_util.tree_map(lambda a: a * 20 if a.ndim >= 2 else a, p)
     p["gate"][STATE_LEAF] = _bias(7, 0.3)
     assert "shared" not in p                    # no shared expert
@@ -252,7 +241,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, M))
     full = dataclasses.replace(_moe(), num_experts=R, top_k=k)
     whole = MoELayer(full, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = meta.unbox(whole.init(jax.random.PRNGKey(0), x)["params"])
+    p = compare.init(whole, x)
     p = jax.tree_util.tree_map(lambda a: a * 20 if a.ndim >= 2 else a, p)
     p["gate"][STATE_LEAF] = jnp.asarray(
         np.random.default_rng(3).normal(0, 0.3, R), jnp.float32)

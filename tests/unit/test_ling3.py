@@ -5,6 +5,7 @@ one latent-attention layer without a query latent and with a gate a head,
 group-limited routing over a share of the experts, the prediction block.
 Float32 throughout: what is compared is the mathematics, not a rounding.
 """
+import functools
 import json
 import os
 
@@ -24,6 +25,9 @@ from deepspeed_tpu.parallel import moe as moe_lib
 from deepspeed_tpu.parallel.moe import (MoEConfig, MoELayer, group_limit,
                                         topk_routing)
 from deepspeed_tpu.telemetry import get_registry
+
+from . import reference_compare as compare
+from .reference_compare import rel as _rel
 
 reference = load_module(ROOT, "reference", "ling3")
 FILE = os.path.join(ROOT, "benchmark", "configs",
@@ -100,8 +104,7 @@ def _setup(mtp):
     model = LlamaForCausalLM(cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 512, (2, 32)),
                       jnp.int32)
-    params = _moved(meta.unbox(model.init(jax.random.PRNGKey(0), ids,
-                                          labels=ids)["params"]))
+    params = _moved(compare.init(model, ids, labels=ids))
     # the hidden states reach the routers spread out, as under init_scale
     params = dict(params, embed_tokens=params["embed_tokens"] * 50.0)
     return cfg, model, ids, params
@@ -112,19 +115,24 @@ def setup(request):
     return _setup(request.param)
 
 
-def _rel(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+@pytest.fixture(scope="module")
+def program(setup):
+    """``(out, grads)`` of the model on the fixture's weights: the forward
+    and the backward of both tests below, one compiled program a case."""
+    _, model, ids, params = setup
+    with jax.default_matmul_precision("highest"):
+        return compare.forward_and_gradients(
+            lambda p: model.apply({"params": p}, ids, labels=ids), params)
 
 
 # ----------------------------------------------------------------------
 # the model against the reference
 # ----------------------------------------------------------------------
-def test_loss_and_logits_are_the_references(setup):
+def test_loss_and_logits_are_the_references(setup, program):
     cfg, model, ids, params = setup
     kw = _reference_kwargs(cfg)
+    out = program[0]
     with jax.default_matmul_precision("highest"):
-        out = model.apply({"params": params}, ids, labels=ids)
         main, second = reference.loss_parts(params, ids, **kw)
         want = reference.logits(params, ids, **{
             k: v for k, v in kw.items() if k != "mtp_layers"})
@@ -141,29 +149,20 @@ def test_loss_and_logits_are_the_references(setup):
                                atol=2e-4 * float(np.abs(got).max()))
 
 
-def test_every_gradient_is_the_references(setup):
+def test_every_gradient_is_the_references(setup, program):
     cfg, model, ids, params = setup
     kw = _reference_kwargs(cfg)
+    # the reference's side bare: op by op its lines are the cheaper
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(lambda p: model.apply(
-            {"params": p}, ids, labels=ids)["loss"])(params)
         want = jax.grad(lambda p: reference.training_loss(
             p, ids, mtp_weight=0.3, **kw))(params)
-    seen = set()
-    for (path, a), (_, b) in zip(
-            jax.tree_util.tree_flatten_with_path(got)[0],
-            jax.tree_util.tree_flatten_with_path(want)[0]):
-        name = jax.tree_util.keystr(path)
-        if name.endswith("['expert_bias']"):    # picks, never weighs
-            assert float(jnp.abs(a).max()) == float(jnp.abs(b).max()) == 0
-            continue
-        assert float(jnp.abs(b).max()) > 0, name
-        np.testing.assert_allclose(a, b, atol=5e-4 * float(jnp.abs(b).max()),
-                                   err_msg=name)
-        seen.add(path[-1].key)
+    paths, _ = compare.compare_leaves(
+        program[1], want, tol=5e-4, measure="max",
+        no_gradient=("['expert_bias']",))       # picks, never weighs
     assert {"A_log", "dt_bias", "f_proj_kernel", "b_proj_kernel",
             "g_proj_kernel", "conv_kernel", "o_norm", "gate_proj_kernel",
-            "q_proj_kernel", "kv_b_proj_kernel", "wg"} <= seen
+            "q_proj_kernel", "kv_b_proj_kernel", "wg"} <= {
+        path[-1].key for path in paths}
 
 
 def test_the_mixer_alone_is_the_references_recurrence():
@@ -176,10 +175,15 @@ def test_the_mixer_alone_is_the_references_recurrence():
     rng = np.random.default_rng(3)
     h = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.float32)
     probe = jnp.asarray(rng.standard_normal((2, 128, 64)), jnp.float32)
-    p = _moved(meta.unbox(module.init(jax.random.PRNGKey(1), h)["params"]), 1)
-    with jax.default_matmul_precision("highest"):
+    p = _moved(compare.init(module, h, seed=1), 1)
+
+    @jax.jit
+    def run(h, p):
         y, pull = jax.vjp(lambda h, p: module.apply({"params": p}, h), h, p)
-        dh, dp = pull(probe)
+        return (y, *pull(probe))
+
+    with jax.default_matmul_precision("highest"):
+        y, dh, dp = run(h, p)
         ry, rh, rp = reference.kda_grads(p, h, probe, n_head=4,
                                          lower_bound=-5.0, eps=1e-6)
     assert _rel(y, ry) < 2e-5 and _rel(dh, rh) < 2e-5
@@ -195,16 +199,22 @@ def test_the_mixer_alone_is_the_references_recurrence():
     assert float(g.min()) < -4.5 and float(g.max()) > -0.5
 
 
+@functools.lru_cache(maxsize=None)
+def _mixer_at_a_chunk_of_16():
+    """``(leaves, input, output)``, compiled once for the four faults."""
+    module = KimiDeltaAttention(_config(linear_chunk_size=16))
+    h = jnp.asarray(np.random.default_rng(3).standard_normal((1, 64, 64)),
+                    jnp.float32)
+    p = _moved(compare.init(module, h, seed=1), 1)
+    with jax.default_matmul_precision("highest"):
+        return p, h, compare.apply(module, p, h)
+
+
 @pytest.mark.parametrize("fault", ["decay_head_mean", "gate_silu",
                                    "no_dt_bias", "softplus_gate"])
 def test_the_mixer_is_not_a_named_wrong_thing(fault):
-    cfg = _config(linear_chunk_size=16)
-    module = KimiDeltaAttention(cfg)
-    h = jnp.asarray(np.random.default_rng(3).standard_normal((1, 64, 64)),
-                    jnp.float32)
-    p = _moved(meta.unbox(module.init(jax.random.PRNGKey(1), h)["params"]), 1)
+    p, h, y = _mixer_at_a_chunk_of_16()
     with jax.default_matmul_precision("highest"):
-        y = module.apply({"params": p}, h)
         wrong = reference.kda(p, h, n_head=4, lower_bound=-5.0, fault=fault)
     assert _rel(y, wrong) > 0.02, fault
 
@@ -215,8 +225,7 @@ def test_latent_attention_without_a_query_latent_and_with_a_gate_a_head():
     rng = np.random.default_rng(5)
     h = jnp.asarray(rng.standard_normal((2, 64, 64)), jnp.float32)
     pos = jnp.arange(64)[None, :]
-    p = _moved(meta.unbox(module.init(jax.random.PRNGKey(2), h, pos,
-                                      None)["params"]), 2)
+    p = _moved(compare.init(module, h, pos, None, seed=2), 2)
     assert set(p) == {"q_proj_kernel", "kv_a_proj_with_mqa_kernel",
                       "kv_a_layernorm", "kv_b_proj_kernel", "gate_proj_kernel",
                       "o_proj_kernel"}
@@ -225,7 +234,7 @@ def test_latent_attention_without_a_query_latent_and_with_a_gate_a_head():
     kw = dict(n_head=4, kv_lora_rank=16, qk_nope_head_dim=16,
               qk_rope_head_dim=8, v_head_dim=16, rope_theta=6e6, eps=1e-6)
     with jax.default_matmul_precision("highest"):
-        y = module.apply({"params": p}, h, pos, None)
+        y = compare.apply(module, p, h, pos, None)
         assert _rel(y, reference.attention(h, p, **kw)) < 2e-5
         for fault in ("no_gate", "gate_before_softmax_scale"):
             assert _rel(y, reference.attention(h, p, fault=fault, **kw)) > .05
@@ -320,7 +329,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                      dtype=jnp.float32)
     h = jnp.asarray(np.random.default_rng(6).standard_normal((2, 48, 64)),
                     jnp.float32)
-    p = meta.unbox(whole.init(jax.random.PRNGKey(3), h)["params"])
+    p = compare.init(whole, h, seed=3)
     p["gate"]["expert_bias"] = _logits(seed=8)[1]
     route = dict(top_k=TOP_K, route_scale=2.5, n_group=GROUPS,
                  topk_group=KEPT)
@@ -362,7 +371,7 @@ def test_the_gauge_of_the_group_limit_is_booked():
 # ----------------------------------------------------------------------
 # spans and counters
 # ----------------------------------------------------------------------
-def test_the_new_scopes_and_counters_show_in_one_step(setup):
+def test_the_new_scopes_and_counters_show_in_one_step(setup, program):
     cfg, model, ids, params = setup
     text = jax.jit(lambda p: model.apply({"params": p}, ids, labels=ids)[
         "loss"]).lower(params).as_text(debug_info=True)
@@ -376,7 +385,7 @@ def test_the_new_scopes_and_counters_show_in_one_step(setup):
     assert snap["gated_delta_decay_channels"]["samples"][0]["value"] == 16
     assert any(s["labels"] == {"dk": "16", "dv": "16"}
                for s in snap["gated_delta_state_elems"]["samples"])
-    out = model.apply({"params": params}, ids, labels=ids)
+    out = program[0]
     n_sparse = 5 + cfg.num_nextn_predict_layers
     assert out["stats"]["group_kept_share"].shape == (n_sparse,)
     LlamaForCausalLM.record_step_stats(jax.device_get(out["stats"]))
@@ -525,13 +534,12 @@ def test_at_the_published_loss_factor_no_prediction_block_is_built():
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 512, (1, 32)),
                       jnp.int32)
     with_key, without = (LlamaForCausalLM(c) for c in (cfg, _config(0)))
-    params = meta.unbox(with_key.init(jax.random.PRNGKey(0), ids,
-                                      labels=ids)["params"])
+    params = compare.init(with_key, ids, labels=ids)
     assert "mtp_0" not in params
     assert jax.tree_util.tree_structure(params) \
-        == jax.tree_util.tree_structure(meta.unbox(without.init(
-            jax.random.PRNGKey(0), ids, labels=ids)["params"]))
-    a, b = (m.apply({"params": params}, ids, labels=ids)
+        == jax.tree_util.tree_structure(meta.unbox(jax.eval_shape(
+            without.init, jax.random.PRNGKey(0), ids, labels=ids)["params"]))
+    a, b = (compare.apply(m, params, ids, labels=ids)
             for m in (with_key, without))
     assert float(a["loss"]) == float(b["loss"]) and "mtp_loss" not in a
     assert with_key.flops_per_token() == without.flops_per_token()

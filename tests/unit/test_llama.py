@@ -9,7 +9,8 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.llama import LlamaForCausalLM, llama_config
 
-from .simple_model import token_batch
+from . import reference_compare as compare
+from .simple_model import seeded_params, token_batch
 
 
 @pytest.fixture(autouse=True)
@@ -49,7 +50,7 @@ def test_hf_llama_parity():
     ids = np.random.default_rng(1).integers(0, 128, size=(2, 10))
     with torch.no_grad():
         hf_logits = hf_model(torch.tensor(ids)).logits.numpy()
-    ours = model.apply({"params": params}, jnp.asarray(ids, jnp.int32))
+    ours = compare.apply(model, params, jnp.asarray(ids, jnp.int32))
     np.testing.assert_allclose(np.asarray(ours["logits"][:, :, :128], np.float32),
                                hf_logits, rtol=2e-3, atol=2e-3)
 
@@ -57,10 +58,7 @@ def test_hf_llama_parity():
 def test_llama_generate_matches_forward():
     cfg = llama_config("llama-tiny", dtype=jnp.float32)
     model = LlamaForCausalLM(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(model=model, params=params,
                                       dtype=jnp.float32)
     ids = np.random.default_rng(0).integers(0, 512, size=(1, 4)).astype(np.int32)
@@ -81,11 +79,7 @@ def test_llama_continuous_batcher_fp_and_int8():
         mesh_mod.set_mesh(None)
         cfg = llama_config("llama-tiny")
         model = LlamaForCausalLM(cfg)
-        params = jax.tree_util.tree_map(
-            lambda x: getattr(x, "value", x),
-            model.init(jax.random.PRNGKey(0),
-                       np.zeros((1, 8), np.int32))["params"],
-            is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+        params = seeded_params(model)
         eng = deepspeed_tpu.init_inference(model=model, params=params,
                                            quant=quant, max_tokens=32)
         # rotary family: max_tokens resizes the cache itself

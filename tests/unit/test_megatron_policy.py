@@ -9,11 +9,13 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.module_inject.policies import convert_megatron_gpt2
+
+from . import reference_compare as compare
+from .simple_model import seeded_params
 
 
 @pytest.fixture(autouse=True)
@@ -74,18 +76,15 @@ def test_megatron_policy_roundtrip(interleave):
     model = GPT2LMHeadModel(cfg)
     ids = np.random.default_rng(0).integers(
         0, cfg.vocab_size, size=(2, 16)).astype(np.int32)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0), ids)["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    ref_logits = model.apply({"params": params}, ids)["logits"]
+    params = seeded_params(model)
+    ref_logits = compare.apply(model, params, ids)["logits"]
 
     sd = _zoo_to_megatron_sd(params, cfg.n_head, interleave=interleave)
     model2, params2 = convert_megatron_gpt2(
         sd, n_head=cfg.n_head, interleaved_qkv=interleave)
     assert model2.cfg.n_layer == cfg.n_layer
     assert model2.cfg.vocab_size == cfg.vocab_size
-    out = model2.apply({"params": params2}, ids)["logits"]
+    out = compare.apply(model2, params2, ids)["logits"]
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref_logits, np.float32),
                                rtol=2e-2, atol=2e-2)
@@ -94,11 +93,7 @@ def test_megatron_policy_roundtrip(interleave):
 def test_megatron_policy_rejects_ragged_layers():
     cfg = gpt2_config("gpt2-tiny", vocab_pad_multiple=1)
     model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 8), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     sd = _zoo_to_megatron_sd(params, cfg.n_head)
     sd = {k: v for k, v in sd.items() if ".layers.0." not in k
           or "input_layernorm" in k}   # drop most of layer 0

@@ -20,6 +20,8 @@ from deepspeed_tpu.ops.grouped_matmul import TILES, _tiles
 from deepspeed_tpu.ops.rotary import rotary_table
 from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer, record_stats
 
+from . import reference_compare as compare
+
 reference = load_module(ROOT, "reference", "mellum2")
 
 ROPE = {
@@ -68,12 +70,7 @@ def _reference_kwargs(cfg):
 def _params(model, ids, scale=6.0):
     """Seeded weights, scaled up so that attention is not near-uniform and
     the router's choices are not near-ties."""
-    boxed = model.init(jax.random.PRNGKey(0), ids)["params"]
-    params = jax.tree_util.tree_map(
-        lambda a: a.value if hasattr(a, "value") else a, boxed,
-        is_leaf=lambda a: hasattr(a, "value"))
-    return jax.tree_util.tree_map(
-        lambda a: a * scale if a.ndim >= 2 else a, params)
+    return compare.init(model, ids, scale=scale)
 
 
 @pytest.fixture(scope="module")
@@ -92,25 +89,15 @@ def test_loss_and_every_leaf_kinds_gradient_match_the_reference(ids, first,
     assert params["layers_0"]["moe"]["experts"]["gate"].shape == (held, 32, 24)
     assert params["layers_0"]["moe"]["gate"]["wg"].shape == (32, ROUTED)
 
-    def loss(p):
-        return model.apply({"params": p}, ids, labels=ids)["loss"]
-
-    def want_loss(p):
-        return reference.training_loss(p, ids, **_reference_kwargs(cfg))
-
-    got, grads = jax.value_and_grad(loss)(params)
-    want, wants = jax.value_and_grad(want_loss)(params)
+    out, grads = compare.forward_and_gradients(
+        lambda p: model.apply({"params": p}, ids, labels=ids), params)
+    # the reference's side bare: op by op its lines are the cheaper
+    want, wants = jax.value_and_grad(lambda p: reference.training_loss(
+        p, ids, **_reference_kwargs(cfg)))(params)
     # float32 on both sides: what is left is the order of the sums
-    assert abs(float(got) - float(want)) < 2e-5, (got, want)
-    flat = jax.tree_util.tree_leaves_with_path(grads)
-    flat_w = dict(jax.tree_util.tree_leaves_with_path(wants))
-    kinds = set()
-    for path, g in flat:
-        w = flat_w[path]
-        err = float(jnp.linalg.norm(g - w) / (jnp.linalg.norm(w) + 1e-30))
-        assert err < 2e-3, (jax.tree_util.keystr(path), err)
-        assert float(jnp.linalg.norm(w)) > 0, jax.tree_util.keystr(path)
-        kinds.add(jax.tree_util.keystr(path[-2:]))
+    assert abs(float(out["loss"]) - float(want)) < 2e-5, (out["loss"], want)
+    paths, _ = compare.compare_leaves(grads, wants, tol=2e-3, measure="norm")
+    kinds = {jax.tree_util.keystr(path[-2:]) for path in paths}
     assert len(kinds) >= 11     # embed, head, 3 norms, q k v o, wg, 3 experts
 
 
@@ -121,8 +108,8 @@ def test_every_tile_kind_occurs_and_the_window_matters(ids):
     cfg = _config()
     model = LlamaForCausalLM(cfg)
     params = _params(model, ids)
-    loss = lambda c: float(LlamaForCausalLM(c).apply(
-        {"params": params}, ids, labels=ids)["loss"])
+    loss = lambda c: float(compare.apply(LlamaForCausalLM(c), params, ids,
+                                         labels=ids)["loss"])
     base = loss(cfg)
     no_window = dataclasses.replace(cfg, layer_types=None, rope_parameters=None,
                                     rope_theta=100.0)
@@ -172,7 +159,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     whole = MoELayer(full, model_dim=M, hidden_dim=I, dtype=jnp.float32)
     p = jax.tree_util.tree_map(
         lambda a: a.value if hasattr(a, "value") else a,
-        whole.init(jax.random.PRNGKey(0), x)["params"],
+        jax.jit(whole.init)(jax.random.PRNGKey(0), x)["params"],
         is_leaf=lambda a: hasattr(a, "value"))
     p = {"gate": {"wg": p["gate"]["wg"] * 30},
          "experts": {n: w * 20 for n, w in p["experts"].items()}}
@@ -208,7 +195,7 @@ def test_pairs_held_elsewhere_are_booked_apart_from_dropped_ones():
                     drop_tokens=False, expert_act="swiglu")
     x = jax.random.normal(jax.random.PRNGKey(2), (T, M))
     layer = MoELayer(cfg, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     _, _, stats = layer.apply(params, x, return_stats=True)
     held = int(stats["tokens_per_expert"][2:4].sum())
     assert 0 < held < T * k and int(stats["dropped"]) == 0
@@ -235,13 +222,13 @@ def test_rows_the_grouped_matmul_skips_reach_no_token(monkeypatch):
                     expert_act="swiglu")
     x = jax.random.normal(jax.random.PRNGKey(3), (T, M))
     layer = MoELayer(cfg, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
 
     def loss(params, x):
         out, aux = layer.apply(params, x, train=True)
         return (out ** 2).sum() + aux
 
-    want = jax.grad(loss, argnums=(0, 1))(params, x)
+    want = jax.jit(jax.grad(loss, argnums=(0, 1)))(params, x)
     clean = moe.grouped_matmul
 
     def fill(ahead, back):
@@ -263,7 +250,9 @@ def test_rows_the_grouped_matmul_skips_reach_no_token(monkeypatch):
             clean(fill(0.0, jnp.nan)(lhs, live), rhs, sizes, **kw), live)
 
     monkeypatch.setattr(moe, "grouped_matmul", skips_rows)
-    got = jax.grad(loss, argnums=(0, 1))(params, x)
+    # another function object: what the first call compiled is not reused
+    got = jax.jit(jax.grad(lambda params, x: loss(params, x),
+                           argnums=(0, 1)))(params, x)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert np.isfinite(np.asarray(a)).all()
@@ -292,8 +281,8 @@ def test_what_is_not_written_raises_by_name(ids):
     # one kind of layer scans: all sliding
     one_kind = LlamaForCausalLM(_config(
         scan_layers=True, layer_types=[SLIDING] * 8))
-    out = one_kind.apply(one_kind.init(jax.random.PRNGKey(0), ids), ids,
-                         labels=ids)
+    out = compare.apply(one_kind, compare.init(one_kind, ids), ids,
+                        labels=ids)
     assert np.isfinite(float(out["loss"]))
 
 

@@ -74,7 +74,7 @@ def test_moe_layer_forward_and_shapes():
     cfg = MoEConfig(num_experts=4, top_k=1, capacity_factor=2.0)
     layer = MoELayer(cfg, model_dim=16, hidden_dim=32, dtype=jnp.float32)
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 10, 16)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     (out, l_aux), _ = jax.jit(
         lambda p, x: (layer.apply(p, x, train=False), 0))(params, x)
     assert out.shape == x.shape
@@ -85,7 +85,7 @@ def test_moe_layer_residual():
     cfg = MoEConfig(num_experts=2, top_k=1, use_residual=True)
     layer = MoELayer(cfg, model_dim=8, hidden_dim=16, dtype=jnp.float32)
     x = jnp.ones((4, 8))
-    params = layer.init(jax.random.PRNGKey(0), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     out, l_aux = layer.apply(params, x)
     assert out.shape == x.shape
     assert "coefficient" in params["params"]
@@ -97,7 +97,7 @@ def test_moe_capacity_scaling_all_dispatched():
     cfg = MoEConfig(num_experts=4, top_k=1, capacity_factor=4.0)
     layer = MoELayer(cfg, model_dim=8, hidden_dim=8, dtype=jnp.float32)
     x = jnp.asarray(np.random.default_rng(3).normal(size=(4, 8, 8)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)
     out, _ = layer.apply(params, x)
     assert not np.allclose(np.asarray(out), 0.0)
 
@@ -113,7 +113,7 @@ def test_moe_w8_experts_match_fp_on_dequantized_weights():
     fp = MoELayer(cfg, model_dim=16, hidden_dim=32, dtype=jnp.float32)
     x = jnp.asarray(np.random.default_rng(9).normal(size=(5, 16)),
                     jnp.float32)
-    params = fp.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(fp.init)(jax.random.PRNGKey(1), x)
     qtree = quantize_dense_tree(
         jax.tree_util.tree_map(lambda l: getattr(l, "value", l), params,
                                is_leaf=lambda l: hasattr(l, "value")),

@@ -708,7 +708,7 @@ def _layer_both_ways(share):
         return jnp.where(past, jnp.nan, out)
 
     def measure():
-        variables = layer.init(jax.random.PRNGKey(0), x)
+        variables = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
         params = nn.unbox(variables)["params"]
 
         def loss(params, x):

@@ -24,6 +24,8 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer, topk_routing
 
+from . import reference_compare as compare
+
 TOL = 2e-5
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 V, E, L, H, I, S = 500, 64, 2, 4, 32, 48
@@ -61,10 +63,7 @@ def build(norm_topk_prob=False, scan=False, loss_chunk=0, **moe_kw):
     model = LlamaForCausalLM(cfg)
     rng = np.random.default_rng(7)
     ids = jnp.asarray(rng.integers(0, V, (2, S)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(3), ids)["params"]
-    from flax.core import meta
-
-    params = meta.unbox(params)
+    params = compare.init(model, ids, seed=3)
     # random norm scales, so a norm that is missing or misplaced shows
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     keys = jax.random.split(jax.random.PRNGKey(11), len(leaves))
@@ -119,11 +118,9 @@ def test_loss_and_gradients_match_reference(norm, biased, scan, chunk):
         params = bias_router(params, scan)
     kw = ref_kw(cfg)
 
-    def sys_loss(p):
-        out = model.apply({"params": p}, ids, labels=ids)
-        return out["loss"], dict(out)
-
-    (loss, out), grads = jax.value_and_grad(sys_loss, has_aux=True)(params)
+    out, grads = compare.forward_and_gradients(
+        lambda p: model.apply({"params": p}, ids, labels=ids), params)
+    loss = out["loss"]
     want, want_grads = jax.value_and_grad(
         lambda p: ref.training_loss(p, ids, **kw))(params)
     ce, aux = ref.loss_parts(params, ids, **kw)
@@ -243,10 +240,10 @@ def test_sorted_dispatch_equals_capacity_path(top_k, act):
     srt = MoELayer(MoEConfig(drop_tokens=False, norm_topk_prob=top_k == 2,
                              **common),
                    model_dim=M, hidden_dim=Hd, dtype=jnp.float32)
-    params = cap.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(cap.init)(jax.random.PRNGKey(1), x)
     params = jax.tree_util.tree_map(lambda p: p * 10.0, params)
     assert jax.tree_util.tree_structure(params) == jax.tree_util.tree_structure(
-        srt.init(jax.random.PRNGKey(1), x))
+        jax.eval_shape(srt.init, jax.random.PRNGKey(1), x))
     out_c, aux_c, st_c = cap.apply(params, x, return_stats=True)
     out_s, aux_s, st_s = srt.apply(params, x, return_stats=True)
     assert int(st_c["dropped"]) == 0 and int(st_s["dropped"]) == 0
@@ -256,8 +253,8 @@ def test_sorted_dispatch_equals_capacity_path(top_k, act):
                                atol=1e-5, rtol=1e-5)
     if top_k == 1:      # GShard's aux counts first choices: same at k = 1
         np.testing.assert_allclose(float(aux_s), float(aux_c), rtol=1e-5)
-    g_c = jax.grad(lambda p: cap.apply(p, x)[0].sum())(params)
-    g_s = jax.grad(lambda p: srt.apply(p, x)[0].sum())(params)
+    g_c = jax.jit(jax.grad(lambda p: cap.apply(p, x)[0].sum()))(params)
+    g_s = jax.jit(jax.grad(lambda p: srt.apply(p, x)[0].sum()))(params)
     for a, b in zip(jax.tree_util.tree_leaves(g_s),
                     jax.tree_util.tree_leaves(g_c)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -273,7 +270,7 @@ def test_dropped_counts_pairs_outside_every_group():
     cap = MoELayer(MoEConfig(num_experts=Ex, top_k=2, capacity_factor=0.5,
                              eval_capacity_factor=0.5, min_capacity=1),
                    model_dim=M, hidden_dim=Hd, dtype=jnp.float32)
-    params = cap.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(cap.init)(jax.random.PRNGKey(1), x)
     _, _, st = cap.apply(params, x, return_stats=True)
     kept = int(st["tokens_per_expert"].sum())
     assert kept <= Ex * 5 < Sx * 2 and int(st["dropped"]) == Sx * 2 - kept

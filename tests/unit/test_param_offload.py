@@ -9,7 +9,7 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-from .simple_model import token_batch
+from .simple_model import seeded_params, token_batch
 
 
 @pytest.fixture(autouse=True)
@@ -20,11 +20,8 @@ def fresh_mesh():
 
 
 def _host_params(model):
-    return jax.tree_util.tree_map(
-        lambda x: np.asarray(getattr(x, "value", x), np.float32),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 16), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    return jax.tree_util.tree_map(lambda x: np.asarray(x, np.float32),
+                                  seeded_params(model))
 
 
 def _cfg(extra_zero, gas=1, clip=0.0, lr=1e-3):

@@ -10,7 +10,7 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-from .simple_model import token_batch
+from .simple_model import seeded_params, token_batch
 
 
 def _partial_manual_axis_index_lowers() -> bool:
@@ -227,11 +227,7 @@ def test_1f1b_memory_independent_of_microbatches():
     mesh = mesh_mod.build_mesh({"pp": 4})
     mesh_mod.set_mesh(mesh)
     embed_fn, stage_fn, loss_fn, split_params, _ = model.pipeline_fns(4)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 32), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     shared, stage = split_params(params)
 
     def temp_bytes(fn, M):

@@ -7,28 +7,18 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
-import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference.serving import ContinuousBatcher
 from deepspeed_tpu.models import common as model_common
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+from .simple_model import tiny_gpt2_engine
 
 
 @pytest.fixture(scope="module")
 def eng():
     mesh_mod.set_mesh(None)
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    engine = deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                          dtype=jnp.float32, params=params)
-    yield engine
+    yield tiny_gpt2_engine()
     mesh_mod.set_mesh(None)
 
 

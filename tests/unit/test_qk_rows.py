@@ -738,7 +738,7 @@ def _olmo_mixer_run():
     cfg, mixer, shape = _olmo_mixer()
     h = jax.random.normal(jax.random.PRNGKey(5), shape.shape,
                           jnp.float32).astype(cfg.dtype)
-    p = meta.unbox(mixer.init(jax.random.PRNGKey(0), h)["params"])
+    p = meta.unbox(jax.jit(mixer.init)(jax.random.PRNGKey(0), h)["params"])
     p = dict(p, o_norm=p["o_norm"] * (1 + 0.1 * jnp.cos(jnp.arange(192.0))))
     ct = jax.random.normal(jax.random.PRNGKey(6), h.shape)
 
@@ -899,8 +899,8 @@ def _block_run(name, kind):
                                           devices=jax.devices()[:1]))
     mp = pytest.MonkeyPatch()
     try:
-        params = meta.unbox(block.init(jax.random.PRNGKey(0), x,
-                                       (pos, None))["params"])
+        params = meta.unbox(jax.jit(block.init)(jax.random.PRNGKey(0), x,
+                                                (pos, None))["params"])
         # scales away from one, or their gradient is all the check sees
         params = jax.tree_util.tree_map_with_path(
             lambda p, v: v * (1 + 0.1 * jnp.cos(jnp.arange(v.size))).reshape(

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
 from benchmark.harness.manifest import ROOT, load_module
 from deepspeed_tpu.comm import mesh as mesh_lib
@@ -24,6 +23,8 @@ from deepspeed_tpu.models.llama import (FULL_ATTENTION, LINEAR, SLIDING,
                                         LlamaBlock, LlamaConfig,
                                         LlamaForCausalLM)
 from deepspeed_tpu.parallel.moe import MoEConfig
+
+from . import reference_compare as compare
 
 reference = load_module(ROOT, "reference", "qwen3next")
 
@@ -77,16 +78,6 @@ def _moved(tree, seed=7, scale=6.0):
         else a + jnp.asarray(rng.normal(0, 0.3, a.shape), a.dtype), tree)
 
 
-def _params(model, ids, scale=6.0):
-    return _moved(meta.unbox(model.init(jax.random.PRNGKey(0), ids)[
-        "params"]), scale=scale)
-
-
-def _rel(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
-
-
 @pytest.fixture(scope="module")
 def ids():
     return jnp.asarray(np.random.default_rng(1).integers(0, VOCAB, (2, S)),
@@ -99,8 +90,7 @@ def ids():
 def test_logits_loss_and_every_gradient_match_the_reference(ids):
     cfg = _config(4, 4)
     model = LlamaForCausalLM(cfg)
-    fresh = meta.unbox(jax.jit(model.init)(jax.random.PRNGKey(0), ids)[
-        "params"])
+    fresh = compare.init(model, ids)
     # w from zeros under norm_zero_centered; the DeltaNet layer's gated norm
     # from ones whatever the flag says; A = exp(A_log) in (0, 16]
     for leaf in (fresh["norm"]["scale"],
@@ -122,8 +112,8 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids):
         (32, 64)
     assert params["layers_0"]["moe"]["shared"]["token_gate"].shape == (32,)
     kw = _reference_kwargs(cfg)
-    out = jax.jit(lambda p: {k: v for k, v in model.apply(
-        {"params": p}, ids, labels=ids).items() if k != "stats"})(params)
+    out, got = compare.forward_and_gradients(
+        lambda p: model.apply({"params": p}, ids, labels=ids), params)
     np.testing.assert_allclose(out["logits"][..., :VOCAB],
                                reference.logits(params, ids, **{
                                    k: v for k, v in kw.items()
@@ -133,16 +123,9 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids):
                                reference.training_loss(params, ids, **kw),
                                rtol=1e-5)
     assert float(out["aux_loss"]) > 0
-    got = jax.jit(jax.grad(lambda p: model.apply(
-        {"params": p}, ids, labels=ids)["loss"]))(params)
+    # the reference's side bare: op by op its lines are the cheaper
     ref = jax.grad(lambda p: reference.training_loss(p, ids, **kw))(params)
-    flat_got = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    for path, r in jax.tree_util.tree_flatten_with_path(ref)[0]:
-        name = jax.tree_util.keystr(path)
-        assert float(jnp.abs(r).max()) > 0, name
-        np.testing.assert_allclose(
-            flat_got[path], r, atol=5e-4 * float(jnp.abs(r).max()),
-            err_msg=name)
+    compare.compare_leaves(got, ref, tol=5e-4, measure="max")
 
 
 # ----------------------------------------------------------------------

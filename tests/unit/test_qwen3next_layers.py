@@ -17,15 +17,17 @@ import pytest
 from flax.core import meta
 
 from deepspeed_tpu.comm import mesh as mesh_lib
-from deepspeed_tpu.models import llama
 from deepspeed_tpu.models.llama import (FULL_ATTENTION, GatedDeltaNet,
                                         LlamaAttention, LlamaBlock)
 from deepspeed_tpu.ops import attention, gated_delta, rotary
 from deepspeed_tpu.ops.pallas import moe_rows
 from deepspeed_tpu.parallel.moe import MoELayer
 from tests.unit.test_qk_rows import _eqns
-from tests.unit.test_qwen3next import (ROUTED, TOP_K, _config, _moe, _moved, _rel,
-                            reference)
+from tests.unit.test_qwen3next import (ROUTED, TOP_K, _config, _moe, _moved,
+                                       reference)
+
+from . import reference_compare as compare
+from .reference_compare import rel as _rel
 
 
 # ----------------------------------------------------------------------
@@ -33,27 +35,26 @@ from tests.unit.test_qwen3next import (ROUTED, TOP_K, _config, _moe, _moved, _re
 # ----------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
 def _layers_alone():
-    """``(cfg, moved leaves, a mixer's input a layer)``: seeded normal
-    hidden states of 128 positions, two rows."""
+    """``(cfg, (leaves, input, the program's output) of the DeltaNet mixer and
+    of the gated attention)``: seeded normal hidden states of 128 positions,
+    two rows; each output compiled once for every fault it is held against."""
     cfg = _config()
     h = jax.random.normal(jax.random.PRNGKey(5), (2, 128, cfg.hidden_size))
-    key, pos = jax.random.PRNGKey(0), jnp.arange(128)[None, :]
-    params = _moved(meta.unbox({
-        "layers_0": {"linear_attn": GatedDeltaNet(cfg).init(key, h)[
-            "params"]},
-        "layers_1": {"self_attn": LlamaAttention(cfg, FULL_ATTENTION).init(
-            key, h, pos, None)["params"]}}))
-    return cfg, params, [h, h * 0.7 + 0.1]
+    pos = jnp.arange(128)[None, :]
+    mixer, attention = GatedDeltaNet(cfg), LlamaAttention(cfg, FULL_ATTENTION)
+    params = _moved({"linear_attn": compare.init(mixer, h),
+                     "self_attn": compare.init(attention, h, pos, None)})
+    # decays slow enough for a state to outlive a chunk and a row
+    lin = dict(params["linear_attn"],
+               A_log=jnp.log(jnp.asarray([0.01, 0.05, 0.2, 0.5])))
+    attn, h1 = params["self_attn"], h * 0.7 + 0.1
+    return cfg, (lin, h, compare.apply(mixer, lin, h)), (
+        attn, h1, compare.apply(attention, attn, h1, pos, None))
 
 
 @pytest.mark.parametrize("fault", [None, *reference.LINEAR_FAULTS])
 def test_the_deltanet_mixer_alone_against_each_named_fault(fault):
-    cfg, params, mixer_in = _layers_alone()
-    # decays slow enough for a state to outlive a chunk and a row
-    p = dict(params["layers_0"]["linear_attn"],
-             A_log=jnp.log(jnp.asarray([0.01, 0.05, 0.2, 0.5])))
-    h = mixer_in[0]
-    got = GatedDeltaNet(cfg).apply({"params": p}, h)
+    cfg, (p, h, got), _ = _layers_alone()
     want = reference.linear_attention(
         p, h, n_k_heads=2, n_v_heads=4, eps=cfg.rms_norm_eps, fault=fault)
     err = _rel(got, want)
@@ -76,7 +77,7 @@ def _mixer_on_rows():
     cfg = _config(linear_key_head_dim=128, linear_value_head_dim=128)
     mixer = GatedDeltaNet(cfg)
     h = jax.random.normal(jax.random.PRNGKey(5), (2, 32, cfg.hidden_size))
-    p = _moved(meta.unbox(mixer.init(jax.random.PRNGKey(0), h)["params"]))
+    p = _moved(compare.init(mixer, h))
     ct = jax.random.normal(jax.random.PRNGKey(6), h.shape)
 
     def measure():
@@ -159,11 +160,7 @@ def test_no_norm_of_a_linear_block_sees_b_s_h_d(monkeypatch):
 
 @pytest.mark.parametrize("fault", [None, *reference.FAULTS])
 def test_gated_attention_alone_against_each_named_fault(fault):
-    cfg, params, mixer_in = _layers_alone()
-    p, h = params["layers_1"]["self_attn"], mixer_in[1]
-    pos = jnp.arange(h.shape[1])[None, :]
-    got = LlamaAttention(cfg, FULL_ATTENTION).apply({"params": p}, h, pos,
-                                                    None)
+    cfg, _, (p, h, got) = _layers_alone()
     want = reference.attention(
         FULL_ATTENTION, p, h, n_head=4, n_kv_head=2, head_dim=16,
         rope_theta=100.0, partial_rotary_factor=0.25, eps=cfg.rms_norm_eps,
@@ -175,20 +172,19 @@ def test_gated_attention_alone_against_each_named_fault(fault):
     assert err > 0.02, (fault, err)
 
 
+@functools.lru_cache(maxsize=None)
 def _expert_layer(R=ROUTED, k=TOP_K):
     M, I = 32, 16
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, M))
     full = dataclasses.replace(_moe(), num_experts=R, top_k=k)
     whole = MoELayer(full, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = _moved(meta.unbox(whole.init(jax.random.PRNGKey(0), x)["params"]),
-               scale=20.0)
-    return x, full, whole, p
+    p = _moved(compare.init(whole, x), scale=20.0)
+    return x, full, whole, p, compare.apply(whole, p, x)[0]
 
 
 @pytest.mark.parametrize("fault", [None, *reference.EXPERT_FAULTS])
 def test_the_expert_layer_alone_against_each_named_fault(fault):
-    x, full, whole, p = _expert_layer()
-    got = whole.apply({"params": p}, x)[0]
+    x, full, whole, p, got = _expert_layer()
     want = reference.expert_ffn(p, x, top_k=TOP_K, first_expert=0,
                                 fault=fault)
     err = _rel(got, want)
@@ -203,10 +199,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
     gated shared expert ONCE (every share computes it whole), are the
     uncut reference's 16-expert layer; program and reference agree on every
     share; every pair is multiplied somewhere exactly once."""
-    x, full, whole, p = _expert_layer()
+    x, full, whole, p, got = _expert_layer()
     uncut = reference.expert_ffn(p, x, top_k=TOP_K, first_expert=0)
-    np.testing.assert_allclose(whole.apply({"params": p}, x)[0], uncut,
-                               atol=5e-5)
+    np.testing.assert_allclose(got, uncut, atol=5e-5)
     no_experts = dict(p, experts={n: w[:0] for n, w in p["experts"].items()})
     shared = reference.expert_ffn(no_experts, x, top_k=TOP_K, first_expert=0)
     assert float(jnp.abs(shared).max()) > 1e-3

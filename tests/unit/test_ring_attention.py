@@ -74,7 +74,7 @@ def test_ring_attention_grads_flow():
     ref_loss = lambda q, k, v: jnp.sum(_jnp_attention(
         q, k, v, causal=True, bias=None, mask=None, dropout_rate=0.0,
         dropout_rng=None, scale=None) ** 2)
-    g_ref = jax.grad(ref_loss)(q, k, v)
+    g_ref = jax.jit(jax.grad(ref_loss))(q, k, v)
     np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), rtol=2e-3, atol=2e-4)
 
 
@@ -105,17 +105,17 @@ def test_ring_flash_matches_full_attention():
         mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
         check_vma=False)
 
-    out = mapped(q, k, v)
+    out = jax.jit(mapped)(q, k, v)
     ref = _jnp_attention(q, k, v, causal=True, bias=None, mask=None,
                          dropout_rate=0.0, dropout_rng=None, scale=None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
-    g1 = jax.grad(lambda q, k, v: (mapped(q, k, v) ** 2).sum(),
-                  argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda q, k, v: (_jnp_attention(
+    g1 = jax.jit(jax.grad(lambda q, k, v: (mapped(q, k, v) ** 2).sum(),
+                          argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(lambda q, k, v: (_jnp_attention(
         q, k, v, causal=True, bias=None, mask=None, dropout_rate=0.0,
-        dropout_rng=None, scale=None) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        dropout_rng=None, scale=None) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     for a, r in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    rtol=3e-4, atol=3e-4)
@@ -143,7 +143,7 @@ def test_ring_flash_non_causal():
                 interpret=True),
         mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
         check_vma=False)
-    out = mapped(q, k, v)
+    out = jax.jit(mapped)(q, k, v)
     ref = _jnp_attention(q, k, v, causal=False, bias=None, mask=None,
                          dropout_rate=0.0, dropout_rng=None, scale=None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -201,17 +201,17 @@ def test_ring_flash_with_dp_and_tp_axes():
                 interpret=True),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
 
-    out = mapped(q, k, v)
+    out = jax.jit(mapped)(q, k, v)
     ref = _jnp_attention(q, k, v, causal=True, bias=None, mask=None,
                          dropout_rate=0.0, dropout_rng=None, scale=None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
-    g1 = jax.grad(lambda q, k, v: (mapped(q, k, v) ** 2).sum(),
-                  argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(lambda q, k, v: (_jnp_attention(
+    g1 = jax.jit(jax.grad(lambda q, k, v: (mapped(q, k, v) ** 2).sum(),
+                          argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(lambda q, k, v: (_jnp_attention(
         q, k, v, causal=True, bias=None, mask=None, dropout_rate=0.0,
-        dropout_rng=None, scale=None) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+        dropout_rng=None, scale=None) ** 2).sum(), argnums=(0, 1, 2)))(q, k, v)
     for a, r in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    rtol=3e-4, atol=3e-4)
@@ -243,7 +243,7 @@ def test_ulysses_flash_with_dp_and_tp_axes():
         partial(ulysses_attention, axis_name="sp", causal=True,
                 attend_fn=partial(flash_attention, interpret=True)),
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
-    out = mapped(q, k, v)
+    out = jax.jit(mapped)(q, k, v)
     ref = _jnp_attention(q, k, v, causal=True, bias=None, mask=None,
                          dropout_rate=0.0, dropout_rng=None, scale=None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),

@@ -26,6 +26,8 @@ from deepspeed_tpu.models.llama import (BlockDiffusionConfig, LlamaBlock,
 from deepspeed_tpu.parallel.moe import MoEConfig, MoELayer
 from deepspeed_tpu.telemetry import get_registry
 
+from . import reference_compare as compare
+
 reference = load_module(ROOT, "reference", "sdar")
 
 L, G, VOCAB, MASK_ID, ROUTED, HELD, TOP_K, EPS = 32, 4, 500, 499, 8, 4, 2, 1e-6
@@ -71,8 +73,7 @@ def setup():
     model = LlamaForCausalLM(cfg)
     rng = np.random.default_rng(0)
     ids = jnp.asarray(rng.integers(0, MASK_ID, (2, L)), jnp.int32)
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), ids,
-                                   labels=ids)["params"])
+    params = compare.init(model, ids, labels=ids)
     # norms away from 1 and matrices large enough that every part shows
     params = jax.tree_util.tree_map(
         lambda x: x + 0.05 * jax.random.normal(jax.random.PRNGKey(3), x.shape),
@@ -81,15 +82,22 @@ def setup():
     return cfg, model, ids, params, mask, t
 
 
-def _rel(a, b):
-    return float(jnp.linalg.norm(a - b) / (jnp.linalg.norm(b) + 1e-12))
+@pytest.fixture(scope="module")
+def program(setup):
+    """``(out, grads)`` under the fixture's noise: the weighted loss and
+    every gradient from one compiled program."""
+    cfg, model, ids, params, mask, t = setup
+    with jax.default_matmul_precision("highest"):
+        return compare.forward_and_gradients(lambda p: model.apply(
+            {"params": p}, ids, labels=ids, diffusion_mask=mask,
+            diffusion_t=t), params)
 
 
 def test_the_logits_of_both_halves_match_the_reference(setup):
     cfg, model, ids, params, mask, t = setup
     with jax.default_matmul_precision("highest"):
-        got = model.apply({"params": params}, ids, diffusion_mask=mask,
-                          diffusion_t=t)["logits"]
+        got = compare.apply(model, params, ids, diffusion_mask=mask,
+                            diffusion_t=t)["logits"]
     want = reference.logits(params, ids, mask, **_reference_kwargs(cfg))
     assert got.shape == (2, 2 * L, cfg.padded_vocab_size)
     for half, a, b in zip(("noisy", "clean"), jnp.split(got, 2, 1),
@@ -101,24 +109,20 @@ def test_the_logits_of_both_halves_match_the_reference(setup):
 
 
 @pytest.fixture(scope="module")
-def losses(setup):
+def losses(setup, program):
     cfg, model, ids, params, mask, t = setup
-    with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(lambda p: model.apply(
-            {"params": p}, ids, labels=ids, diffusion_mask=mask,
-            diffusion_t=t)["loss"])(params)
     want = reference.loss_and_grads(params, ids, mask, t, aux_loss_weight=AUX,
                                     **_reference_kwargs(cfg))
-    return got, want
+    return (program[0]["loss"], program[1]), want
 
 
 def test_the_weighted_loss_matches_the_reference(setup, losses):
     (got, _), (want, _) = losses
     assert float(got) == pytest.approx(float(want), abs=2e-5)
     cfg, model, ids, params, mask, t = setup
-    chunked = LlamaForCausalLM(_config(loss_chunk=16)).apply(
-        {"params": params}, ids, labels=ids, diffusion_mask=mask,
-        diffusion_t=t)
+    chunked = compare.apply(LlamaForCausalLM(_config(loss_chunk=16)), params,
+                            ids, labels=ids, diffusion_mask=mask,
+                            diffusion_t=t)
     assert "logits" not in chunked
     assert float(chunked["loss"]) == pytest.approx(float(want), abs=2e-4)
 
@@ -143,8 +147,8 @@ def test_the_gradient_of_a_leaf_matches_the_reference(losses, path):
     (_, got), (_, want) = losses
     for key in path:
         got, want = got[key], want[key]
-    assert float(jnp.abs(want).max()) > 0
-    assert _rel(got, want) < 2e-5, path
+    compare.compare_leaves({path: got}, {path: want}, tol=2e-5,
+                           measure="norm")
 
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
@@ -173,9 +177,11 @@ def test_one_key_draws_one_noise_and_the_next_step_another(setup):
     cfg, model, ids, params, _, _ = setup
     key = jax.random.PRNGKey(7)
 
+    run = jax.jit(lambda k: dict(model.apply(
+        {"params": params}, ids, labels=ids, rngs={"diffusion": k})))
+
     def step(k):
-        out = model.apply({"params": params}, ids, labels=ids,
-                          rngs={"diffusion": k})
+        out = run(k)
         return float(out["loss"]), int(out["stats"]["diffusion_masked"]), \
             float(out["stats"]["diffusion_t_mean"])
 
@@ -183,8 +189,7 @@ def test_one_key_draws_one_noise_and_the_next_step_another(setup):
     assert step(key) != step(jax.random.fold_in(key, 1))
     loss, masked, t_mean = step(key)
     assert 0 < masked < ids.size and 0.0 < t_mean <= 1.0
-    out = model.apply({"params": params}, ids, labels=ids,
-                      rngs={"diffusion": key})
+    out = run(key)
     assert int(out["stats"]["diffusion_masked"]) \
         + int(out["stats"]["diffusion_kept"]) == ids.size
 
@@ -209,29 +214,28 @@ def test_t_one_masks_everything_and_the_loss_is_a_plain_mean(setup):
     cfg, model, ids, params, _, _ = setup
     one = jnp.ones((2, L // G))
     drawn = jnp.ones((2, L), bool)      # what uniform() < 1 always gives
-    out = model.apply({"params": params}, ids, labels=ids,
-                      diffusion_mask=drawn, diffusion_t=one)
+    out = compare.apply(model, params, ids, labels=ids,
+                        diffusion_mask=drawn, diffusion_t=one)
     assert int(out["stats"]["diffusion_masked"]) == ids.size
-    logits = model.apply({"params": params}, ids, diffusion_mask=drawn,
-                         diffusion_t=one)["logits"][:, :L, :VOCAB]
+    both_halves = jax.jit(lambda tokens: model.apply(
+        {"params": params}, tokens, diffusion_mask=drawn,
+        diffusion_t=one)["logits"])
+    logits = both_halves(ids)[:, :L, :VOCAB]
     nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
         logits, ids[..., None], -1)[..., 0]
     assert float(out["loss"] - out["aux_loss"]) == pytest.approx(
         float(nll.mean()), abs=2e-5)
     # every noisy position embeds the mask id: no clean token is read there
     other = ids.at[:, -G:].set((ids[:, -G:] + 1) % MASK_ID)
-    again = model.apply({"params": params}, other, diffusion_mask=drawn,
-                        diffusion_t=one)["logits"]
-    np.testing.assert_allclose(again[:, :L - G], model.apply(
-        {"params": params}, ids, diffusion_mask=drawn,
-        diffusion_t=one)["logits"][:, :L - G], atol=1e-5)
+    np.testing.assert_allclose(both_halves(other)[:, :L - G],
+                               both_halves(ids)[:, :L - G], atol=1e-5)
 
 
 def test_a_label_of_minus_100_takes_a_position_out(setup):
     cfg, model, ids, params, mask, t = setup
     labels = jnp.where(jnp.arange(L)[None] < 8, -100, ids)
-    a = model.apply({"params": params}, ids, labels=labels,
-                    diffusion_mask=mask, diffusion_t=t)
+    a = compare.apply(model, params, ids, labels=labels,
+                      diffusion_mask=mask, diffusion_t=t)
     weight = (mask & (labels != -100)) / jnp.repeat(t, G, 1)
     logits = a["logits"][..., :VOCAB]
     assert logits.shape[1] == L         # the head saw the noisy half alone
@@ -247,8 +251,8 @@ def test_the_weights_other_form_is_one_a_masked_token(setup):
     cfg, model, ids, params, mask, t = setup
     plain = LlamaForCausalLM(_config(diffusion={
         "block_length": G, "mask_token_id": MASK_ID, "loss_weight": "one"}))
-    out = plain.apply({"params": params}, ids, labels=ids,
-                      diffusion_mask=mask, diffusion_t=t)
+    out = compare.apply(plain, params, ids, labels=ids,
+                        diffusion_mask=mask, diffusion_t=t)
     logits = out["logits"][..., :VOCAB]
     nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
         logits, ids[..., None], -1)[..., 0]
@@ -268,7 +272,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
     full = MoEConfig(num_experts=R, top_k=k, drop_tokens=False,
                      norm_topk_prob=True, expert_act="swiglu")
     whole = MoELayer(full, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = meta.unbox(whole.init(jax.random.PRNGKey(0), x)["params"])
+    p = compare.init(whole, x)
     p = {"gate": {"wg": p["gate"]["wg"] * 30},
          "experts": {n: w * 20 for n, w in p["experts"].items()}}
     uncut = reference.expert_ffn(p, x, top_k=k, first_expert=0)
@@ -489,8 +493,7 @@ def test_the_head_products_read_as_before():
 
     model = LlamaForCausalLM(_config(loss_chunk=16))
     ids = jnp.zeros((2, L), jnp.int32)
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), ids,
-                                   labels=ids)["params"])
+    params = compare.init(model, ids, labels=ids)
     common._fused_ce.cache_clear()
     before = read()
     jax.make_jaxpr(jax.grad(lambda p: model.apply(

@@ -63,9 +63,10 @@ def test_forward_and_every_gradient_match_the_loop(L):
     np.testing.assert_allclose(got, _loop(*ops), atol=1e-5)
     probe = jnp.asarray(np.random.default_rng(9).normal(0, 1, (B, S, C)),
                         jnp.float32)
-    grads = jax.grad(lambda *o: (short_conv(*o) * probe).sum(),
-                     (0, 1, 2, 3))(*ops)
-    want = jax.grad(lambda *o: (_loop(*o) * probe).sum(), (0, 1, 2, 3))(*ops)
+    grads = jax.jit(jax.grad(lambda *o: (short_conv(*o) * probe).sum(),
+                             (0, 1, 2, 3)))(*ops)
+    want = jax.jit(jax.grad(lambda *o: (_loop(*o) * probe).sum(),
+                            (0, 1, 2, 3)))(*ops)
     for name, g, r in zip(("dbg", "dcg", "du", "dw"), grads, want):
         np.testing.assert_allclose(g, r, atol=1e-4, err_msg=name)
 
@@ -78,8 +79,8 @@ def test_bf16_operands_give_a_bf16_result_near_the_float32_one():
     err = np.linalg.norm(np.asarray(got, np.float32) - want) \
         / np.linalg.norm(want)
     assert err < 4e-3, err      # one rounding of the result
-    dw = jax.grad(lambda w: short_conv(*ops[:3], w).astype(
-        jnp.float32).sum())(ops[3])
+    dw = jax.jit(jax.grad(lambda w: short_conv(*ops[:3], w).astype(
+        jnp.float32).sum()))(ops[3])
     assert dw.dtype == jnp.bfloat16 and np.isfinite(
         np.asarray(dw, np.float32)).all()
 
@@ -160,8 +161,10 @@ def test_the_row_kernels_match_the_loop_across_blocks(form, L, S, C):
     edges = [kernel.BLOCK - 1, kernel.BLOCK, kernel.BLOCK + 1, 0, S - 1]
     np.testing.assert_allclose(got[:, edges], want[:, edges],
                                atol=2e-5 * room)
-    grads = jax.grad(lambda r, w: (run(r, w) * probe).sum(), (0, 1))(rows, w)
-    ref = jax.grad(lambda r, w: (loop(r, w) * probe).sum(), (0, 1))(rows, w)
+    grads = jax.jit(jax.grad(lambda r, w: (run(r, w) * probe).sum(),
+                             (0, 1)))(rows, w)
+    ref = jax.jit(jax.grad(lambda r, w: (loop(r, w) * probe).sum(),
+                           (0, 1)))(rows, w)
     assert grads[0].shape == rows.shape and grads[1].shape == w.shape
     for name, g, r in zip(("d rows", "d taps"), grads, ref):
         err = np.abs(np.asarray(g - r)).max() / np.abs(np.asarray(r)).max()
@@ -189,11 +192,11 @@ def test_the_row_kernels_round_once_from_float32(form, L):
     other = np.asarray(shift, np.float32)
     assert np.linalg.norm(np.asarray(got, np.float32) - other) \
         / np.linalg.norm(other) < 4e-3     # the sum's order, one ulp
-    db, dw = jax.grad(lambda r, w: (run(r, w).astype(jnp.float32)
-                                    * probe).sum(), (0, 1))(rows, w)
+    db, dw = jax.jit(jax.grad(lambda r, w: (run(r, w).astype(jnp.float32)
+                                            * probe).sum(), (0, 1)))(rows, w)
     assert db.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
-    rb, rw = jax.grad(lambda r, w: (loop(r, w) * probe).sum(),
-                      (0, 1))(rows.astype(jnp.float32), w)
+    rb, rw = jax.jit(jax.grad(lambda r, w: (loop(r, w) * probe).sum(),
+                              (0, 1)))(rows.astype(jnp.float32), w)
     assert np.linalg.norm(np.asarray(db, np.float32) - rb) \
         / np.linalg.norm(rb) < 4e-3
     # the cotangent arrives rounded to bf16; the sum over positions is float32

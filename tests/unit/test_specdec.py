@@ -9,33 +9,19 @@ alphabetical window."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
-import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference import specdec
 from deepspeed_tpu.inference.serving import ContinuousBatcher
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-
-def _make_engine(**kwargs):
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    return deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                        dtype=jnp.float32, params=params,
-                                        **kwargs)
+from .simple_model import tiny_gpt2_engine
 
 
 @pytest.fixture(scope="module")
 def eng():
     mesh_mod.set_mesh(None)
-    engine = _make_engine()
+    engine = tiny_gpt2_engine()
     yield engine
     mesh_mod.set_mesh(None)
 
@@ -112,7 +98,7 @@ def test_resolve_env_enables_but_explicit_false_wins(eng, monkeypatch):
 def test_resolve_engine_config(monkeypatch):
     monkeypatch.delenv(specdec.SPECDEC_ENV, raising=False)
     mesh_mod.set_mesh(None)
-    engine = _make_engine(specdec={"k": 3})
+    engine = tiny_gpt2_engine(specdec={"k": 3})
     try:
         sd = specdec.resolve_specdec(engine, None)
         assert sd is not None and sd.cfg.k == 3

@@ -3,7 +3,6 @@ Prometheus text rendering, Chrome-trace JSON validity, and the XLA
 recompilation watchdog (fires exactly once per forced shape change,
 stays silent on a stable hot loop)."""
 import json
-import os
 
 import numpy as np
 import pytest
@@ -13,6 +12,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.telemetry import recompile, trace
 from deepspeed_tpu.telemetry.registry import Registry, get_registry
+
+from .simple_model import tiny_gpt2_engine
 
 
 @pytest.fixture(autouse=True)
@@ -396,15 +397,7 @@ def test_batcher_step_leaves_the_serve_tree_with_the_requests_uid():
         from deepspeed_tpu.inference.serving import ContinuousBatcher
         from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-        cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-        model = GPT2LMHeadModel(cfg)
-        params = jax.tree_util.tree_map(
-            lambda x: getattr(x, "value", x),
-            model.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 8), jnp.int32))["params"],
-            is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-        eng = deepspeed_tpu.init_inference(
-            model=model, mp_size=1, dtype=jnp.float32, params=params)
+        eng = tiny_gpt2_engine()
         batcher = ContinuousBatcher(eng, n_slots=2)
         prompt = np.arange(5, dtype=np.int32)
         uid = batcher.submit(prompt, max_new_tokens=3)
@@ -807,15 +800,7 @@ def test_train_serve_smoke_emits_trace_and_metrics(tmp_path):
         from deepspeed_tpu.inference.serving import ContinuousBatcher
         from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-        cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-        model = GPT2LMHeadModel(cfg)
-        params = jax.tree_util.tree_map(
-            lambda x: getattr(x, "value", x),
-            model.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 8), jnp.int32))["params"],
-            is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-        eng = deepspeed_tpu.init_inference(
-            model=model, mp_size=1, dtype=jnp.float32, params=params)
+        eng = tiny_gpt2_engine()
         batcher = ContinuousBatcher(eng, n_slots=2)
         prompts = [rng.integers(0, 512, size=(5,)).astype(np.int32)
                    for _ in range(2)]
@@ -859,15 +844,7 @@ def test_serving_parked_batch_shrinks_to_single_row():
         from deepspeed_tpu.inference.serving import ContinuousBatcher
         from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 
-        cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-        model = GPT2LMHeadModel(cfg)
-        params = jax.tree_util.tree_map(
-            lambda x: getattr(x, "value", x),
-            model.init(jax.random.PRNGKey(0),
-                       jnp.zeros((1, 8), jnp.int32))["params"],
-            is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-        eng = deepspeed_tpu.init_inference(
-            model=model, mp_size=1, dtype=jnp.float32, params=params)
+        eng = tiny_gpt2_engine()
         rng = np.random.default_rng(3)
         prompts = [rng.integers(0, 512, size=(6,)).astype(np.int32)
                    for _ in range(4)]

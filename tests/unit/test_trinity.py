@@ -26,6 +26,9 @@ from deepspeed_tpu.parallel.moe import (STATE_LEAF, MoEConfig, MoELayer,
                                         topk_routing)
 from deepspeed_tpu.runtime import state_leaves
 
+from . import reference_compare as compare
+from .reference_compare import rel as _rel
+
 reference = load_module(ROOT, "reference", "trinity")
 
 KINDS = [SLIDING, SLIDING, SLIDING, FULL_ATTENTION] * 2
@@ -72,9 +75,7 @@ def _bias(layer, scale=0.2):
 def _params(model, ids, scale=6.0):
     """Seeded weights, scaled up so that attention is not near-uniform and
     the router's choices are not near-ties; a bias that is not zero."""
-    params = meta.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
-    params = jax.tree_util.tree_map(
-        lambda a: a * scale if a.ndim >= 2 else a, params)
+    params = compare.init(model, ids, scale=scale)
     for i in range(model.cfg.num_dense_layers, model.cfg.num_hidden_layers):
         params[f"layers_{i}"]["moe"]["gate"][STATE_LEAF] = _bias(i)
     return params
@@ -95,7 +96,8 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids, first, held):
     model = LlamaForCausalLM(cfg)
     params = _params(model, ids)
     kw = _reference_kwargs(cfg)
-    out = model.apply({"params": params}, ids, labels=ids)
+    out, got = compare.forward_and_gradients(
+        lambda p: model.apply({"params": p}, ids, labels=ids), params)
     counts = []
     want = reference.logits(params, ids, counts=counts, **kw)
     np.testing.assert_allclose(out["logits"][..., :VOCAB], want[..., :VOCAB],
@@ -110,26 +112,17 @@ def test_logits_loss_and_every_gradient_match_the_reference(ids, first, held):
     # the per-layer statistics are stacked over the MoE layers alone
     assert out["stats"]["tokens_per_expert"].shape == (4, ROUTED)
     assert out["stats"][STATE_LEAF].shape == (4, ROUTED)
-    got = jax.grad(lambda p: model.apply({"params": p}, ids,
-                                         labels=ids)["loss"])(params)
+    # the reference's side bare: op by op its lines are the cheaper
     ref = jax.grad(lambda p: reference.training_loss(p, ids, **kw))(params)
-    flat_got = dict(jax.tree_util.tree_flatten_with_path(got)[0])
-    for path, r in jax.tree_util.tree_flatten_with_path(ref)[0]:
-        name = jax.tree_util.keystr(path)
-        g = flat_got[path]
-        if STATE_LEAF in name:
-            assert not np.any(g) and not np.any(r), name    # no gradient
-            continue
-        assert float(jnp.abs(r).max()) > 0, name
-        np.testing.assert_allclose(
-            g, r, atol=2e-4 * float(jnp.abs(r).max()), err_msg=name)
+    compare.compare_leaves(got, ref, tol=2e-4, measure="max",
+                           no_gradient=(STATE_LEAF,))
 
 
 def test_the_chunked_head_and_bf16_follow(ids):
     cfg = _config(loss_chunk=16, dtype=jnp.bfloat16)
     model = LlamaForCausalLM(cfg)
     params = _params(model, ids, scale=1.0)
-    out = model.apply({"params": params}, ids, labels=ids)
+    out = compare.apply(model, params, ids, labels=ids)
     want = reference.training_loss(params, ids, **_reference_kwargs(cfg))
     assert abs(float(out["loss"]) - float(want)) < 0.03
 
@@ -137,11 +130,6 @@ def test_the_chunked_head_and_bf16_follow(ids):
 # ----------------------------------------------------------------------
 # each mechanism alone against its fault
 # ----------------------------------------------------------------------
-def _rel(a, b):
-    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
-
-
 @pytest.fixture(scope="module")
 def attention_alone(ids):
     cfg = _config()
@@ -158,19 +146,23 @@ def attention_alone(ids):
                                               jnp.float32)
         attn["k_norm"]["scale"] = jnp.asarray(rng.uniform(0.5, 3.0, 16),
                                               jnp.float32)
-    return cfg, params, hidden
+    # the program's side once for every fault: a sliding and a full layer
+    got = {kind: compare.apply(
+        LlamaAttention(cfg, kind), params[f"layers_{layer}"]["self_attn"],
+        hidden[layer], jnp.arange(S)[None, :], None)
+        for kind, layer in ((SLIDING, 0), (FULL_ATTENTION, 3))}
+    return cfg, params, hidden, got
 
 
 @pytest.mark.parametrize("fault", [None, *reference.FAULTS])
 def test_attention_alone_against_each_named_fault(attention_alone, fault):
-    cfg, params, hidden = attention_alone
+    cfg, params, hidden, got = attention_alone
     akw = dict(n_head=4, n_kv_head=2, head_dim=16, sliding_window=WINDOW,
                rope_theta=cfg.rope_theta, eps=cfg.rms_norm_eps)
-    pos = jnp.arange(S)[None, :]
     for kind, layer in ((SLIDING, 0), (FULL_ATTENTION, 3)):
         p, h = params[f"layers_{layer}"]["self_attn"], hidden[layer]
-        got = LlamaAttention(cfg, kind).apply({"params": p}, h, pos, None)
-        err = _rel(got, reference.attention(kind, p, h, fault=fault, **akw))
+        err = _rel(got[kind], reference.attention(kind, p, h, fault=fault,
+                                                  **akw))
         applies = fault is not None and not (
             fault in ("rope_on_full",) and kind == SLIDING
             or fault in ("no_rope_on_sliding", "window+1")
@@ -187,7 +179,7 @@ def test_the_expert_layer_alone_against_each_named_fault(fault):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, M))
     cfg = _moe(2, 4)
     layer = MoELayer(cfg, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = meta.unbox(layer.init(jax.random.PRNGKey(0), x)["params"])
+    p = compare.init(layer, x)
     p = jax.tree_util.tree_map(lambda a: a * 20 if a.ndim >= 2 else a, p)
     p["gate"][STATE_LEAF] = _bias(7, 0.3)
     got = layer.apply({"params": p}, x)[0]
@@ -243,7 +235,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, M))
     full = dataclasses.replace(_moe(), num_experts=R, top_k=k)
     whole = MoELayer(full, model_dim=M, hidden_dim=I, dtype=jnp.float32)
-    p = meta.unbox(whole.init(jax.random.PRNGKey(0), x)["params"])
+    p = compare.init(whole, x)
     p = jax.tree_util.tree_map(lambda a: a * 20 if a.ndim >= 2 else a, p)
     p["gate"][STATE_LEAF] = jnp.asarray(
         np.random.default_rng(3).normal(0, 0.3, R), jnp.float32)
@@ -296,6 +288,22 @@ def _engine(gas=1, **opt):
     return engine, cfg, {"input_ids": ids, "labels": ids}
 
 
+@pytest.fixture(scope="module")
+def built():
+    """ONE engine at ``gas`` 1 and a copy of its state as built (the step
+    donates what it is given): a test that trains takes it back to that
+    state (:func:`_as_built`) and compiles no step a second time."""
+    engine, cfg, batch = _engine()
+    return engine, cfg, batch, jax.tree_util.tree_map(jnp.copy, engine.state)
+
+
+def _as_built(built):
+    engine, cfg, batch, state = built
+    engine.drain_step_stats(wait=True)
+    engine._state = jax.tree_util.tree_map(jnp.copy, state)
+    return engine, cfg, batch
+
+
 def _biases(engine):
     return np.stack([np.asarray(
         engine.state.params[f"layers_{i}"]["moe"]["gate"][STATE_LEAF])
@@ -311,8 +319,8 @@ def _seed_biases(engine):
     engine._state = engine.state.replace(params=params)
 
 
-def test_the_optimizer_never_sees_the_state_leaf():
-    engine, cfg, batch = _engine()
+def test_the_optimizer_never_sees_the_state_leaf(built):
+    engine = built[0]
     trained, held = engine._split_state_leaves(engine.state.params)
     assert [jax.tree_util.keystr(p) for p, _ in
             jax.tree_util.tree_flatten_with_path(held)[0]] == [
@@ -340,11 +348,11 @@ def test_the_optimizer_never_sees_the_state_leaf():
 
 @pytest.mark.parametrize("gas", [1, 2])
 def test_the_step_moves_the_bias_by_the_rate_and_nothing_else_does(
-        gas, monkeypatch):
+        gas, monkeypatch, built):
     """Exactly ``reference.bias_update`` of the step's counts, summed over
     the micro-batches BEFORE the sign, from a bias that is not zero: weight
     decay (0.5 here) or an Adam update would show in the last bit."""
-    engine, cfg, batch = _engine(gas)
+    engine, cfg, batch = _as_built(built) if gas == 1 else _engine(gas)
     _seed_biases(engine)
     steps, book = [], moe_lib.record_stats
     monkeypatch.setattr(moe_lib, "record_stats", lambda stats: (
@@ -412,15 +420,15 @@ def test_the_registry_and_the_dispatch_report_show_the_state_leaf(
     assert snap["moe_dropped_tokens_total"]["samples"][0]["value"] == 0
 
 
-def test_the_bias_round_trips_a_checkpoint(tmp_path):
-    engine, cfg, batch = _engine()
+def test_the_bias_round_trips_a_checkpoint(tmp_path, built):
+    engine, cfg, batch = _as_built(built)
     for _ in range(2):
         engine.train_batch(batch)
     want = _biases(engine)
     assert np.abs(want).max() > 0
     engine.save_checkpoint(str(tmp_path), tag="t")
-    fresh, _, _ = _engine()
-    assert not np.any(_biases(fresh))
+    fresh, _, _ = _as_built(built)
+    assert not np.any(_biases(fresh)) and float(fresh.state.step) == 0
     fresh.load_checkpoint(str(tmp_path), tag="t")
     np.testing.assert_array_equal(_biases(fresh), want)
     assert float(fresh.state.step) == 2

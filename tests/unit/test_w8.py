@@ -11,6 +11,9 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.ops.w8 import quantize_weight, w8a16_matmul
 
+from . import reference_compare as compare
+from .simple_model import seeded_params
+
 
 @pytest.fixture(autouse=True)
 def fresh_mesh():
@@ -43,18 +46,10 @@ def test_w8a16_stacked_layers():
     assert float(jnp.linalg.norm(y - ref) / jnp.linalg.norm(ref)) < 0.02
 
 
-def _tiny_params(model, cfg):
-    return jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 8), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-
-
 def test_init_inference_int8_real_storage():
     cfg = gpt2_config("gpt2-tiny")
     model = GPT2LMHeadModel(cfg)
-    params = _tiny_params(model, cfg)
+    params = seeded_params(model)
 
     eng_fp = deepspeed_tpu.init_inference(model=model, params=params)
     mesh_mod.set_mesh(None)
@@ -98,7 +93,7 @@ def test_init_inference_int8_real_storage():
 def test_quant_bits4_keeps_fake_path():
     cfg = gpt2_config("gpt2-tiny")
     model = GPT2LMHeadModel(cfg)
-    params = _tiny_params(model, cfg)
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(
         model=GPT2LMHeadModel(cfg), params=params,
         config={"quant": {"enabled": True, "bits": 4, "groups": 16}})
@@ -113,11 +108,7 @@ def test_llama_int8_serving():
 
     cfg = llama_config("llama-tiny")
     model = LlamaForCausalLM(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 8), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
 
     eng_fp = deepspeed_tpu.init_inference(
         model=LlamaForCausalLM(cfg), params=params)
@@ -149,7 +140,7 @@ def test_w8_serving_all_decoder_families(family):
            "gptneox": "GPTNeoXForCausalLM"}[family]
     Model = getattr(mod, cls)
     cfg = cfg_fn()  # tiny preset default
-    params = _tiny_params(Model(cfg), cfg)
+    params = seeded_params(Model(cfg))
 
     eng_fp = deepspeed_tpu.init_inference(model=Model(cfg), params=params)
     mesh_mod.set_mesh(None)
@@ -180,15 +171,12 @@ def test_w8_bert_encoder_forward():
     cfg = bert_config("bert-tiny")
     model = BertModel(cfg)
     ids = np.zeros((1, 16), np.int32)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0), ids)["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    out_fp = model.apply({"params": params}, ids)
+    params = seeded_params(model)
+    out_fp = compare.apply(model, params, ids)
     q_model = BertModel(dataclasses.replace(cfg, w8=True))
     q_params = quantize_dense_tree(
         jax.tree_util.tree_map(np.asarray, params))
-    out_q8 = q_model.apply({"params": q_params}, ids)
+    out_q8 = compare.apply(q_model, q_params, ids)
     a = np.asarray(jax.tree_util.tree_leaves(out_fp)[0], np.float32)
     b = np.asarray(jax.tree_util.tree_leaves(out_q8)[0], np.float32)
     rel = np.linalg.norm(a - b) / max(np.linalg.norm(a), 1e-6)
@@ -203,7 +191,7 @@ def test_moe_expert_int8_serving():
                       moe=MoEConfig(num_experts=2, top_k=1,
                                     capacity_factor=2.0))
     model = GPT2LMHeadModel(cfg)
-    params = _tiny_params(model, cfg)
+    params = seeded_params(model)
 
     eng_fp = deepspeed_tpu.init_inference(
         model=GPT2LMHeadModel(cfg), params=params)
@@ -238,7 +226,7 @@ def test_gptneox_moe_int8_serving():
     cfg = gptneox_config(moe=MoEConfig(num_experts=2, top_k=1,
                                        capacity_factor=2.0))
     model = GPTNeoXForCausalLM(cfg)
-    params = _tiny_params(model, cfg)
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(
         model=GPTNeoXForCausalLM(cfg), params=params,
         config={"quant": {"enabled": True, "bits": 8}})

@@ -41,11 +41,11 @@ def _by_hand(h, w, t, wt, denominator):
 def test_weight_one_and_the_count_reproduce_todays_value_bit_for_bit(chunk):
     h, w, t, _ = _case()
     count = (t != -100).sum()
-    plain = jax.value_and_grad(lambda h, w: _chunked(h, w, t, chunk),
-                               (0, 1))(h, w)
-    ones = jax.value_and_grad(lambda h, w: _chunked(
+    plain = jax.jit(jax.value_and_grad(lambda h, w: _chunked(h, w, t, chunk),
+                                       (0, 1)))(h, w)
+    ones = jax.jit(jax.value_and_grad(lambda h, w: _chunked(
         h, w, t, chunk, weights=jnp.ones((B, S)), denominator=count),
-        (0, 1))(h, w)
+        (0, 1)))(h, w)
     assert plain[0] == ones[0]
     for a, b in zip(plain[1], ones[1]):
         assert bool((a == b).all())
@@ -54,10 +54,10 @@ def test_weight_one_and_the_count_reproduce_todays_value_bit_for_bit(chunk):
 @pytest.mark.parametrize("chunk", [32, 40, 4096])
 def test_weighted_chunked_loss_and_gradients_match_a_hand_built_sum(chunk):
     h, w, t, wt = _case(1)
-    got = jax.value_and_grad(lambda h, w: _chunked(
-        h, w, t, chunk, weights=wt, denominator=float(B * S)), (0, 1))(h, w)
-    want = jax.value_and_grad(lambda h, w: _by_hand(h, w, t, wt, B * S),
-                              (0, 1))(h, w)
+    got = jax.jit(jax.value_and_grad(lambda h, w: _chunked(
+        h, w, t, chunk, weights=wt, denominator=float(B * S)), (0, 1)))(h, w)
+    want = jax.jit(jax.value_and_grad(
+        lambda h, w: _by_hand(h, w, t, wt, B * S), (0, 1)))(h, w)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for a, b in zip(got[1], want[1]):
         np.testing.assert_allclose(a, b, atol=1e-6)
@@ -85,8 +85,8 @@ def test_a_zero_weight_takes_a_token_out_and_the_weights_get_no_gradient():
     f = lambda h, wt: _chunked(h, w, t, weights=wt, denominator=float(B * S))
     assert f(h, zeroed) == f(moved, zeroed)
     assert f(h, wt.at[1, 7].set(1.0)) != f(moved, wt.at[1, 7].set(1.0))
-    assert float(jnp.abs(jax.grad(f, 1)(h, wt)).max()) == 0.0
-    dh = jax.grad(f, 0)(h, zeroed)
+    assert float(jnp.abs(jax.jit(jax.grad(f, 1))(h, wt)).max()) == 0.0
+    dh = jax.jit(jax.grad(f, 0))(h, zeroed)
     assert float(jnp.abs(dh[1, 7]).max()) == 0.0
 
 

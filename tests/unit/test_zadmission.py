@@ -17,31 +17,19 @@ import urllib.request
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
-import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference.serving import ContinuousBatcher
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.telemetry import anomaly, exporter, flightrec, loadgen
 from deepspeed_tpu.telemetry import registry as telemetry_registry
 from deepspeed_tpu.testing import chaos
+
+from .simple_model import tiny_gpt2_engine
 
 VOCAB = 64
 
 
 def _make_engine(**kwargs):
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    return deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                        dtype=jnp.float32, params=params,
-                                        max_tokens=64, **kwargs)
+    return tiny_gpt2_engine(max_tokens=64, **kwargs)
 
 
 @pytest.fixture(scope="module")

@@ -23,6 +23,8 @@ from deepspeed_tpu.telemetry import anomaly, flightrec, recompile
 from deepspeed_tpu.telemetry import registry as telemetry_registry
 from deepspeed_tpu.telemetry.exporter import TelemetryExporter
 
+from .simple_model import seeded_params
+
 
 @pytest.fixture(autouse=True)
 def _fresh_anomaly(monkeypatch):
@@ -39,11 +41,7 @@ def _fresh_anomaly(monkeypatch):
 def _build_batcher(n_slots=2, max_tokens=64, **kw):
     cfg = gpt2_config("gpt2-tiny")
     model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   np.zeros((1, 8), np.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     paged = {"prefix_cache": {"page_tokens": 8, "n_pages": 64}} \
         if kw.get("paged_decode") else {}
     eng = deepspeed_tpu.init_inference(model=model, params=params,

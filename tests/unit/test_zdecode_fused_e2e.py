@@ -22,6 +22,8 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.telemetry import registry as telemetry_registry
 
+from .simple_model import seeded_params
+
 
 @pytest.fixture(autouse=True)
 def fresh_mesh():
@@ -54,10 +56,10 @@ def _greedy_rollout(model, params, cache, tok, steps=2):
 def _model_parity(Model, base, expect_fused=True, steps=2, **init_kw):
     fused_cfg = dataclasses.replace(base, decode_fused=True)
     m0, m1 = Model(base), Model(fused_cfg)
-    v0 = m0.init(jax.random.PRNGKey(0), jnp.zeros((2, 1), jnp.int32),
-                 position_ids=jnp.zeros((1, 1), jnp.int32))
-    v1 = m1.init(jax.random.PRNGKey(0), jnp.zeros((2, 1), jnp.int32),
-                 position_ids=jnp.zeros((1, 1), jnp.int32))
+    v0 = jax.jit(m0.init)(jax.random.PRNGKey(0), jnp.zeros((2, 1), jnp.int32),
+                          position_ids=jnp.zeros((1, 1), jnp.int32))
+    v1 = jax.jit(m1.init)(jax.random.PRNGKey(0), jnp.zeros((2, 1), jnp.int32),
+                          position_ids=jnp.zeros((1, 1), jnp.int32))
     # the fused path must declare the IDENTICAL param tree (checkpoints
     # load interchangeably)
     assert jax.tree_util.tree_structure(v0["params"]) == \
@@ -133,11 +135,7 @@ def _tiny_engine(**kw):
     cfg = GPT2Config(vocab_size=512, n_positions=64, n_embd=128, n_layer=2,
                      n_head=2, dtype=jnp.float32)
     model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     return deepspeed_tpu.init_inference(model=model, mp_size=1,
                                         dtype=jnp.float32, params=params,
                                         **kw)

@@ -16,6 +16,7 @@ from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.parallel import zero
 
+from . import reference_compare as compare
 from .simple_model import token_batch
 
 
@@ -41,7 +42,7 @@ def test_zero_init_materializes_sharded():
                    jax.sharding.NamedSharding(mesh, P()), np.ndim(l))]
     assert sharded, "zero.Init produced only replicated leaves"
     # logits usable directly
-    out = model.apply({"params": params}, jnp.zeros((1, 16), jnp.int32))
+    out = compare.apply(model, params, jnp.zeros((1, 16), jnp.int32))
     assert out["logits"].shape[0] == 1
 
 
@@ -81,9 +82,9 @@ def test_tiled_linear_matches_dense():
     layer = TiledLinear(features=24, in_splits=4, out_splits=3)
     import flax.linen as nn
 
-    vs = layer.init(jax.random.PRNGKey(1), x)
+    vs = jax.jit(layer.init)(jax.random.PRNGKey(1), x)
     params = nn.meta.unbox(vs["params"])
-    y = layer.apply({"params": params}, x)
+    y = compare.apply(layer, params, x)
     assert y.shape == (3, 5, 24)
     # same math as an untiled matmul on the re-assembled kernel
     k = np.asarray(params["kernel"])            # (in_s, out_s, it, ot)
@@ -163,7 +164,8 @@ def test_tiled_linear_init_matches_dense_fan():
 
     layer = TiledLinear(features=256, in_splits=4, out_splits=4)
     params = nn.meta.unbox(
-        layer.init(jax.random.PRNGKey(0), jnp.zeros((1, 256)))["params"])
+        jax.jit(layer.init)(jax.random.PRNGKey(0),
+                            jnp.zeros((1, 256)))["params"])
     std = float(np.asarray(params["kernel"]).std())
     expect = 1.0 / np.sqrt(256)   # lecun_normal on fan_in=256
     assert abs(std - expect) / expect < 0.1, (std, expect)

@@ -12,24 +12,16 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference import kvreuse
 from deepspeed_tpu.inference.serving import ContinuousBatcher
 from deepspeed_tpu.models import common as model_common
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+from .simple_model import tiny_gpt2_engine
 
 
 def _make_engine(**cfg_over):
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32, **cfg_over)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    return deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                        dtype=jnp.float32, params=params)
+    return tiny_gpt2_engine(cfg_over)
 
 
 @pytest.fixture(scope="module")
@@ -254,16 +246,7 @@ def test_resolve_config_and_env(eng, monkeypatch):
 def test_init_inference_prefix_cache_config():
     """init_inference(prefix_cache=...) flows through to the batcher."""
     mesh_mod.set_mesh(None)
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    engine = deepspeed_tpu.init_inference(
-        model=model, dtype=jnp.float32, params=params,
-        prefix_cache={"page_tokens": 4, "n_pages": 4})
+    engine = tiny_gpt2_engine(prefix_cache={"page_tokens": 4, "n_pages": 4})
     b = ContinuousBatcher(engine, n_slots=1)
     assert isinstance(b.prefix_cache, kvreuse.RadixPrefixCache)
     assert b.prefix_cache.pool.n_pages == 4

@@ -11,15 +11,15 @@ in the alphabetical tier-1 order and the window's breadth is preserved."""
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference import kvreuse
 from deepspeed_tpu.inference.serving import ContinuousBatcher
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.telemetry import registry
+
+from .simple_model import seeded_params, tiny_gpt2_engine
 
 
 @pytest.fixture(autouse=True, scope="module")
@@ -30,16 +30,8 @@ def _no_mesh():
 
 
 def _make_engine(**kw):
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
     kw.setdefault("max_tokens", 64)
-    return deepspeed_tpu.init_inference(model=model, dtype=jnp.float32,
-                                        params=params, **kw)
+    return tiny_gpt2_engine(**kw)
 
 
 def _paged_engine(**kw):
@@ -174,11 +166,7 @@ def test_noncontract_family_falls_back_to_gather():
 
     cfg = gptneo_config("neo-tiny", dtype=jnp.float32)
     model = GPTNeoForCausalLM(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
+    params = seeded_params(model)
     eng = deepspeed_tpu.init_inference(
         model=model, dtype=jnp.float32, params=params, max_tokens=64,
         prefix_cache={"page_tokens": 8, "n_pages": 64})

@@ -11,16 +11,13 @@ convention)."""
 import numpy as np
 import pytest
 
-import jax
-import jax.numpy as jnp
-
-import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference.router import (ReplicaServer, Router,
                                             replay_routed)
 from deepspeed_tpu.inference.serving import ContinuousBatcher
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
 from deepspeed_tpu.telemetry import fleet, loadgen, reqtrace
+
+from .simple_model import tiny_gpt2_engine
 
 MAX_TOKENS = 64
 
@@ -28,17 +25,7 @@ MAX_TOKENS = 64
 @pytest.fixture(scope="module")
 def eng():
     mesh_mod.set_mesh(None)
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    params = jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, 8), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-    engine = deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                          dtype=jnp.float32, params=params,
-                                          max_tokens=MAX_TOKENS)
-    yield engine
+    yield tiny_gpt2_engine(max_tokens=MAX_TOKENS)
     mesh_mod.set_mesh(None)
 
 

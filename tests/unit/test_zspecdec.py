@@ -9,32 +9,16 @@ to preserve the fixed window's breadth; the fast host-side units live in
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import deepspeed_tpu
 from deepspeed_tpu.comm import mesh as mesh_mod
 from deepspeed_tpu.inference import specdec
 from deepspeed_tpu.inference.serving import ContinuousBatcher
-from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_config
+
+from .simple_model import seeded_params, tiny_gpt2_engine
 
 VOCAB = 512
-
-
-def _unbox(model, seq=8):
-    return jax.tree_util.tree_map(
-        lambda x: getattr(x, "value", x),
-        model.init(jax.random.PRNGKey(0),
-                   jnp.zeros((1, seq), jnp.int32))["params"],
-        is_leaf=lambda x: hasattr(x, "names") and hasattr(x, "value"))
-
-
-def _make_gpt2_engine():
-    cfg = gpt2_config("gpt2-tiny", dtype=jnp.float32)
-    model = GPT2LMHeadModel(cfg)
-    return deepspeed_tpu.init_inference(model=model, mp_size=1,
-                                        dtype=jnp.float32,
-                                        params=_unbox(model))
 
 
 def _make_llama_engine():
@@ -44,13 +28,13 @@ def _make_llama_engine():
     model = LlamaForCausalLM(cfg)
     return deepspeed_tpu.init_inference(model=model, mp_size=1,
                                         dtype=jnp.float32,
-                                        params=_unbox(model))
+                                        params=seeded_params(model))
 
 
 @pytest.fixture(scope="module")
 def eng():
     mesh_mod.set_mesh(None)
-    engine = _make_gpt2_engine()
+    engine = tiny_gpt2_engine()
     yield engine
     mesh_mod.set_mesh(None)
 
