@@ -508,7 +508,7 @@ def _note_head_products(pass_: str, n: int) -> None:
 
 @functools.lru_cache(maxsize=None)
 def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
-              h_dtype, w_dtype, weighted: bool = False):
+              h_dtype, w_dtype, weighted: bool = False, rows: bool = False):
     """Build the custom-vjp chunked cross-entropy core (cached per config
     and the operands' types, which the backward rule rounds to).
 
@@ -532,6 +532,14 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
     cotangents (block diffusion's ``1 / t`` on the masked positions, 0
     elsewhere); it has no gradient.  Without it the rule is traced as it
     was: no operand, no multiply.
+
+    ``rows``: the result is ``(sum, nll)`` with each token's OWN negative
+    log-likelihood beside the sum, chunked as the labels are, before its
+    weight and 0 where the label is ignored (the forward has them in hand: N
+    floats, no logits kept).  They are a READING: their cotangent is dropped,
+    so a caller whose weights are learned takes the weights' gradient from
+    them itself (:func:`chunked_lm_loss`).  Without it the rule is traced as
+    it was.
     """
     Vp = padded_vocab_size
 
@@ -547,9 +555,11 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
         safe = jnp.where(valid, tc, 0)
         logz = jax.nn.logsumexp(logits, axis=-1)
         lbl = jnp.take_along_axis(logits, safe[:, None], axis=-1)[:, 0]
-        nll = jnp.where(valid, logz - lbl, 0.0)
+        nll = own = jnp.where(valid, logz - lbl, 0.0)
         if wc is not None:
             valid, nll = valid * wc, nll * wc
+        if rows:        # the token's own, before its weight
+            return logits, logz, valid, safe, own, nll
         return logits, logz, valid, safe, nll
 
     @jax.custom_vjp
@@ -557,9 +567,11 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
         _note_head_products("primal", 1)
 
         def body(acc, xs):
-            return acc + _chunk(xs[0], wteT, *xs[1:])[-1].sum(), None
+            out = _chunk(xs[0], wteT, *xs[1:])
+            return acc + out[-1].sum(), out[-2] if rows else None
 
-        return jax.lax.scan(body, jnp.float32(0.0), (hf, tf) + wf)[0]
+        total, own = jax.lax.scan(body, jnp.float32(0.0), (hf, tf) + wf)
+        return (total, own) if rows else total
 
     def ce_fwd(hf, wteT, tf, *wf):
         _note_head_products("forward", 3)
@@ -567,7 +579,8 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
         def body(carry, xs):
             acc, dwteT = carry
             hc, tc = xs[:2]
-            logits, logz, valid, safe, nll = _chunk(hc, wteT, tc, *xs[2:])
+            logits, logz, valid, safe, *own, nll = _chunk(hc, wteT, tc,
+                                                          *xs[2:])
             p = jnp.exp(logits - logz[:, None])              # softmax rows
             onehot = (jnp.arange(Vp)[None, :] == safe[:, None])
             dlog = (p - onehot) * valid[:, None]             # (C, Vp) fp32
@@ -578,16 +591,18 @@ def _fused_ce(vocab_size: int, padded_vocab_size: int, ignore_index: int,
                 preferred_element_type=jnp.float32)          # (C, E)
             dwteT = dwteT + jnp.dot(hc.T, dlogb,
                                     preferred_element_type=jnp.float32)
-            return (acc + nll.sum(), dwteT), dh_c
+            return (acc + nll.sum(), dwteT), (dh_c, *own)
 
-        (nll_sum, dwteT), dhs = jax.lax.scan(
+        (nll_sum, dwteT), (dhs, *own) = jax.lax.scan(
             body, (jnp.float32(0.0), jnp.zeros(wteT.shape, jnp.float32)),
             (hf, tf) + wf)
-        return nll_sum, (dhs, dwteT)
+        return ((nll_sum, *own) if rows else nll_sum), (dhs, dwteT)
 
     def ce_bwd(res, g):
         _note_head_products("backward", 0)       # the label reads 0
         dhs, dwteT = res
+        if rows:        # the rows are a reading: their cotangent is dropped
+            g = g[0]
         # each rounded to its operand's type once, after the scale; both
         # finished before either is used: a scan of one trip is inlined,
         # and XLA's scheduler, left free, puts dW off to the end of the step
@@ -608,8 +623,9 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
                     vocab_size: int, padded_vocab_size: int, chunk: int,
                     dtype, ignore_index: int = -100,
                     weights: Optional[jax.Array] = None,
-                    denominator=None) -> jax.Array:
-    """Tied-head cross-entropy WITHOUT materializing the (B, S, V) fp32
+                    denominator=None, rows: bool = False):
+    """Cross-entropy through a head ``wte`` ``(V, E)``, the tied table or an
+    untied head's transpose, WITHOUT materializing the (B, S, V) fp32
     logits or their cotangent, and with the logits multiplied out once a
     step (see :func:`_fused_ce`: the forward makes ``dh`` and ``dW``, the
     backward scales them).  Exact same loss as the dense path (fp32
@@ -620,10 +636,17 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     vjp, no per-chunk remat).
 
     ``weights`` ``(B, S)`` multiplies each token's negative log-likelihood
-    (float32; no gradient flows to it) and ``denominator`` divides the sum
-    in place of the count of labelled tokens: ``sum_i w_i nll_i /
-    denominator``.  Weight 1 everywhere and the count as denominator is
-    the unweighted value bit for bit."""
+    (float32; this function stops the gradient at it) and ``denominator``
+    divides the sum in place of the count of labelled tokens: ``sum_i w_i
+    nll_i / denominator``.  Weight 1 everywhere and the count as denominator
+    is the unweighted value bit for bit.
+
+    ``rows``: returns ``(loss, nll)``, ``nll`` ``(B, S)`` float32 each
+    token's own negative log-likelihood (before its weight; 0 where the label
+    is ignored) with no gradient through it.  A caller whose weights are
+    LEARNED adds ``sum_i (w_i - stop_gradient(w_i)) nll_i / denominator``:
+    the value stays and the weights get their exact gradient, ``nll_i /
+    denominator`` (``models/llama.py``'s exit distribution)."""
     B, S, E = h.shape
     N = B * S
     chunk = min(chunk, N)
@@ -637,21 +660,23 @@ def chunked_lm_loss(h: jax.Array, wte: jax.Array, labels: jax.Array, *,
     hf = hf.reshape(-1, chunk, E)
     tf = tf.reshape(-1, chunk)
     wteT = wte.astype(dtype).T        # (E, V)
-    if weights is None:
-        ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
-                       hf.dtype, wteT.dtype)
-        nll_sum = ce(hf, wteT, tf)
-    else:
+    operands = (hf, wteT, tf)
+    if weights is not None:
         wf = jax.lax.stop_gradient(weights.astype(jnp.float32)).reshape(N)
         if pad:
             wf = jnp.concatenate([wf, jnp.zeros((pad,), wf.dtype)])
-        ce = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
-                       hf.dtype, wteT.dtype, True)
-        nll_sum = ce(hf, wteT, tf, wf.reshape(-1, chunk))
+        operands += (wf.reshape(-1, chunk),)
+    nll_sum = _fused_ce(vocab_size, padded_vocab_size, ignore_index,
+                        hf.dtype, wteT.dtype, weights is not None,
+                        rows)(*operands)
+    if rows:
+        nll_sum, own = nll_sum
+        own = jax.lax.stop_gradient(own).reshape(-1)[:N].reshape(B, S)
     if denominator is not None:
-        return nll_sum / denominator
-    count = (tf != ignore_index).sum()
-    return nll_sum / jnp.maximum(count, 1)
+        loss = nll_sum / denominator
+    else:
+        loss = nll_sum / jnp.maximum((tf != ignore_index).sum(), 1)
+    return (loss, own) if rows else loss
 
 
 def shift_labels(input_ids: jax.Array, pad_id: int = -100) -> jax.Array:

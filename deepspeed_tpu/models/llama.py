@@ -240,8 +240,8 @@ class LlamaConfig:
     ``kda_lower_bound``, ``kda_safe_gate``, ``short_conv_kernel_size``,
     ``expert_swiglu_limit_list``, ``share_expert_swiglu_limit_list``,
     ``rope_scaling``, ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps``,
-    ``mhc_h_res_clamp_min``, ``mhc_h_res_clamp_max``.  The rest are this
-    program's own."""
+    ``mhc_h_res_clamp_min``, ``mhc_h_res_clamp_max``, ``total_ut_steps``.
+    The rest are this program's own."""
     vocab_size: int = 32000
     max_position_embeddings: int = 2048
     # decode KV-cache length override: serving with a short
@@ -326,6 +326,16 @@ class LlamaConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    # a looped stack (the Ouro family's key): the SAME blocks and the same
+    # final norm are applied ``total_ut_steps`` times over the same leaves,
+    # the normed stream of a pass feeding the next; after every pass a gate
+    # (``exit_gate``, hidden_size -> 1 with a bias) and the one head.  With
+    # labels the loss is the expectation of the passes' cross-entropies under
+    # the gates' exit distribution less ``exit_entropy_weight`` times that
+    # distribution's entropy (this program's name: the config has no key).
+    # 1: one pass, no gate, and nothing of this is traced
+    total_ut_steps: int = 1
+    exit_entropy_weight: float = 0.05
     rms_norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     initializer_range: float = 0.02
@@ -625,6 +635,37 @@ class LlamaConfig:
                         f"several lanes) with {what}: the lanes are written "
                         f"for the unrolled training stack alone (pipeline "
                         f"stages: this family has no pipeline_fns at all)")
+        if self.total_ut_steps < 1 or self.exit_entropy_weight < 0.0:
+            raise ValueError(
+                f"total_ut_steps {self.total_ut_steps}, exit_entropy_weight "
+                f"{self.exit_entropy_weight}: at least one pass and a weight "
+                f">= 0")
+        if self.total_ut_steps > 1:
+            for on, what in (
+                    (self.decode, "decode=True: the cache would hold a key "
+                     "and a value a pass a layer, and early exit by the gate "
+                     "is not written"),
+                    (self.moe is not None, "moe: the routing statistics and "
+                     "the selection bias's update would count a layer once a "
+                     "pass"),
+                    (self.mtp_blocks, "a multi-token-prediction block "
+                     "(num_nextn_predict_layers)"),
+                    (self.diffusion is not None, "diffusion (block-"
+                     "diffusion training)"),
+                    (self.sa_config is not None, "sa_config: the indexer's "
+                     "loss would be gathered once a pass"),
+                    (self.lanes > 1, f"hc_mult {self.hc_mult} (a residual "
+                     f"stream of several lanes)"),
+                    (self.attn_impl in ("ring", "ulysses"),
+                     f"attn_impl {self.attn_impl!r} (sequence "
+                     f"parallelism)")):
+                if on:
+                    raise NotImplementedError(
+                        f"total_ut_steps {self.total_ut_steps} (a looped "
+                        f"stack) with {what}: the loop is written for "
+                        f"training a dense stack on whole rows (pipeline "
+                        f"stages, where the loop is a ring: this family has "
+                        f"no pipeline_fns at all)")
         if self.attn_gate not in (False, True, "head"):
             raise ValueError(f"attn_gate is False, True (a gate a channel) "
                              f"or 'head', got {self.attn_gate!r}")
@@ -1587,6 +1628,35 @@ class MTPModule(nn.Module):
         return RMSNorm(cfg, name="shared_head_norm")(x), ys
 
 
+_EXIT_LOG_EPS = 1e-20      # inside the exit distribution's entropy's log
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate: the logit of ``g = sigmoid(h . w + b)``,
+    one float32 number a token, from a pass's normed stream ``(B, S, E)``."""
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        kernel = self.param("kernel", nn.with_partitioning(
+            nn.initializers.normal(cfg.initializer_range), ("embed", None)),
+            (h.shape[-1], 1), cfg.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros, (1,),
+                          cfg.param_dtype)
+        return (h.astype(jnp.float32) * kernel[:, 0].astype(jnp.float32)
+                ).sum(-1) + bias.astype(jnp.float32)
+
+
+def _exit_distribution(gate_logits):
+    """``p`` (T, ...) from the first T - 1 passes' gate logits (T - 1, ...):
+    ``p_t = g_t prod_{j<t} (1 - g_j)``, and the last pass takes what is
+    left, ``prod_{j<T} (1 - g_j)``."""
+    g = jax.nn.sigmoid(gate_logits)
+    left = jnp.cumprod(1.0 - g, axis=0)         # after pass t
+    return jnp.concatenate([g[:1], g[1:] * left[:-1], left[-1:]])
+
+
 class LlamaForCausalLM(nn.Module):
     cfg: LlamaConfig
 
@@ -1685,6 +1755,7 @@ class LlamaForCausalLM(nn.Module):
         kinds = cfg.kinds
         indexed = []        # the layers' _INDEXER_STATS, then stacked
         lane_stats = []     # the blocks' _MHC_STATS, the prediction's last
+        # the stack is built once; a looped stack calls it once a pass
         if cfg.scan_layers:
             if len(set(kinds)) > 1 or cfg.num_dense_layers:
                 raise NotImplementedError(
@@ -1692,28 +1763,56 @@ class LlamaForCausalLM(nn.Module):
                     f"layer_types mixes {sorted(set(kinds))} with "
                     f"{cfg.num_dense_layers} leading dense blocks: set "
                     f"scan_layers=False (the stack is then unrolled)")
-            stack = nn.scan(block_cls,
-                            variable_axes={"params": 0, "cache": 0},
-                            split_rngs={"params": True, "dropout": True,
-                                        "gating": True, "pld": True},
-                            length=cfg.num_hidden_layers,
-                            in_axes=nn.broadcast,
-                            metadata_params={nn.meta.PARTITION_NAME: "layers"})
-            h, per_layer = stack(cfg, deterministic, *kinds[:1],
-                                 name="layers", blockdiff=dif is not None)(
-                h, (position_ids, mask))
+            scanned = nn.scan(
+                block_cls, variable_axes={"params": 0, "cache": 0},
+                split_rngs={"params": True, "dropout": True,
+                            "gating": True, "pld": True},
+                length=cfg.num_hidden_layers, in_axes=nn.broadcast,
+                metadata_params={nn.meta.PARTITION_NAME: "layers"})
+            stack = scanned(cfg, deterministic, *kinds[:1], name="layers",
+                            blockdiff=dif is not None)
+        else:
+            stack = [block_cls(cfg, deterministic, *kinds[i:i + 1],
+                               name=f"layers_{i}",
+                               **({} if cfg.sparse(i) or cfg.moe is None
+                                  else {"sparse": False}),
+                               blockdiff=dif is not None)
+                     for i in range(cfg.num_hidden_layers)]
+        norm = RMSNorm(cfg, name="norm")
+
+        def one_pass(h):
+            """``(the stream after the stack, what its layers report)``."""
+            if cfg.scan_layers:
+                return stack(h, (position_ids, mask))
+            reports = []
+            for block in stack:
+                h, ys = block(h, (position_ids, mask))
+                reports.append(ys)
+            return h, reports
+
+        exits = None
+        if cfg.total_ut_steps > 1:
+            # h_t = norm(blocks(h_{t-1})): the normed stream is what the next
+            # pass, the gate and the head read
+            exits = []
+            for t in range(cfg.total_ut_steps):
+                with trace.device_span(f"ut/pass_{t}"):
+                    h = norm(one_pass(h)[0])
+                exits.append(h)
+            with trace.device_span("ut/exit_gate"):
+                # the last pass takes what is left: its gate is never read
+                gate = ExitGate(cfg, name="exit_gate")
+                exit_p = _exit_distribution(
+                    jnp.stack([gate(x) for x in exits[:-1]]))
+        elif cfg.scan_layers:
+            h, per_layer = one_pass(h)
             if cfg.sa_config is not None:
                 per_layer = dict(per_layer)
                 indexed = {k: per_layer.pop(k) for k in _INDEXER_STATS}
         else:
+            h, reports = one_pass(h)
             per_layer = []
-            for i in range(cfg.num_hidden_layers):
-                dense = {} if cfg.sparse(i) or cfg.moe is None \
-                    else {"sparse": False}
-                h, ys = block_cls(cfg, deterministic, *kinds[i:i + 1],
-                                  name=f"layers_{i}", **dense,
-                                  blockdiff=dif is not None)(
-                    h, (position_ids, mask))
+            for ys in reports:
                 if cfg.sa_config is not None:   # every layer has these
                     ys = dict(ys)
                     indexed.append({k: ys.pop(k) for k in _INDEXER_STATS})
@@ -1796,7 +1895,8 @@ class LlamaForCausalLM(nn.Module):
                 diffusion_masked=masked.sum().astype(jnp.int32),
                 diffusion_kept=(~masked).sum().astype(jnp.int32),
                 diffusion_t_mean=t.mean())
-        h = RMSNorm(cfg, name="norm")(h)
+        if exits is None:
+            h = norm(h)
         if cfg.tie_word_embeddings:     # the head reads the table
             lm_head = embed.T
         else:
@@ -1807,7 +1907,23 @@ class LlamaForCausalLM(nn.Module):
         tgt = None
         if labels is not None:
             tgt = shift_labels(labels) if shift else labels
-        if cfg.loss_chunk and tgt is not None:
+
+        def head(h):
+            logits = jnp.dot(h, lm_head.astype(cfg.dtype))
+            if cfg.padded_vocab_size != cfg.vocab_size:
+                pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
+                logits = jnp.where(pad_mask, logits,
+                                   jnp.finfo(logits.dtype).min)
+            return logits
+
+        if exits is not None:
+            with trace.device_span("loss_head"):
+                if tgt is None:
+                    loss, out["logits"] = None, head(h)     # the last pass's
+                else:
+                    loss = self._exit_loss(exits, exit_p, lm_head, tgt, head,
+                                           out)
+        elif cfg.loss_chunk and tgt is not None:
             from .common import chunked_lm_loss
 
             with trace.device_span("loss_head"):
@@ -1825,14 +1941,6 @@ class LlamaForCausalLM(nn.Module):
                         padded_vocab_size=cfg.padded_vocab_size,
                         chunk=cfg.loss_chunk, dtype=cfg.dtype)
         else:
-            def head(h):
-                logits = jnp.dot(h, lm_head.astype(cfg.dtype))
-                if cfg.padded_vocab_size != cfg.vocab_size:
-                    pad_mask = jnp.arange(cfg.padded_vocab_size) < cfg.vocab_size
-                    logits = jnp.where(pad_mask, logits,
-                                       jnp.finfo(logits.dtype).min)
-                return logits
-
             with trace.device_span("loss_head"):
                 logits = out["logits"] = head(h)
                 loss = None if tgt is None \
@@ -1852,16 +1960,83 @@ class LlamaForCausalLM(nn.Module):
             out["loss"] = loss if aux_loss is None else loss + aux_loss
         return out
 
+    def _exit_loss(self, exits, p, lm_head, tgt, head, out):
+        """A looped stack's loss: over the labelled tokens, the mean of
+        ``sum_t p_t nll_t - exit_entropy_weight H(p)``, the passes' streams
+        ``exits`` (T of (B, S, E)) through the ONE head, ``p`` (T, B, S) the
+        exit distribution; its parts go into ``out["stats"]``.
+
+        Under ``loss_chunk`` the T B S rows pass the chunked head once, each
+        weighted by its ``p`` held constant (one ``dW``, no logits kept), and
+        ``p``'s gradient, each row's own nll, is added at value 0; without
+        it the last pass's logits go into ``out["logits"]``."""
+        cfg = self.cfg
+        T, (B, S) = len(exits), tgt.shape
+        valid = tgt != -100
+        count = jnp.maximum(valid.sum(), 1).astype(jnp.float32)
+        if cfg.loss_chunk:
+            from .common import chunked_lm_loss
+
+            held = jax.lax.stop_gradient(p)
+            expected, nll = chunked_lm_loss(
+                jnp.concatenate(exits), lm_head.T, jnp.tile(tgt, (T, 1)),
+                vocab_size=cfg.vocab_size,
+                padded_vocab_size=cfg.padded_vocab_size,
+                chunk=cfg.loss_chunk, dtype=cfg.dtype,
+                weights=held.reshape(T * B, S), denominator=count, rows=True)
+            nll = nll.reshape(T, B, S)
+            expected = expected + ((p - held) * nll).sum() / count
+        else:
+            each = [head(x) for x in exits]
+            out["logits"] = each[-1]
+            lg = jnp.stack(each).astype(jnp.float32)
+            label = jnp.take_along_axis(
+                lg, jnp.where(valid, tgt, 0)[None, ..., None], -1)[..., 0]
+            nll = jnp.where(valid, jax.nn.logsumexp(lg, -1) - label, 0.0)
+            expected = (p * nll).sum() / count
+        p = p * valid
+        entropy = -(p * jnp.log(p + _EXIT_LOG_EPS)).sum() / count
+        exit_p = p.sum((1, 2)) / count
+        exit_nll = jax.lax.stop_gradient(nll).sum((1, 2)) / count
+        out["stats"] = dict(
+            out.get("stats") or {}, exit_p=exit_p, exit_nll=exit_nll,
+            exit_step_mean=(exit_p * jnp.arange(1, T + 1)).sum(),
+            exit_entropy=entropy, lm_loss=exit_nll[-1])
+        return expected - cfg.exit_entropy_weight * entropy
+
     @staticmethod
     def record_step_stats(stats) -> None:
         """The engine hands back the host copy of ``out["stats"]`` of each
         finished step; the routing counters live with the MoE layer."""
-        if "mtp_loss" in stats:
+        if "exit_p" in stats:
+            from ..telemetry import registry
+
+            for name, what in (
+                    ("exit_p", "share of the exit distribution on a pass of "
+                     "a looped stack, mean over the labelled tokens"),
+                    ("exit_nll", "next-token cross-entropy of a pass's exit "
+                     "through the one head")):
+                gauge = registry.gauge(
+                    "ut_" + name, what + ", last finished step", ("step",))
+                for t, v in enumerate(np.asarray(stats[name]).ravel()):
+                    gauge.labels(str(t)).set(float(v))
+            registry.gauge(
+                "ut_exit_step_mean", "mean pass a token exits a looped "
+                "stack at under the exit distribution (1 is the first), "
+                "last finished step").set(float(stats["exit_step_mean"]))
+            registry.gauge(
+                "ut_exit_entropy", "entropy of the exit distribution, mean "
+                "over the labelled tokens, last finished step").set(
+                float(stats["exit_entropy"]))
+        if "lm_loss" in stats:      # beside a prediction block's or the exits'
             from ..telemetry import registry
 
             registry.gauge("lm_loss", "next-token cross-entropy of the main "
                            "head, last finished step").set(
                 float(stats["lm_loss"]))
+        if "mtp_loss" in stats:
+            from ..telemetry import registry
+
             registry.gauge(
                 "mtp_loss", "cross-entropy of a multi-token-prediction "
                 "block (depth d predicts the token d + 1 ahead), last "
@@ -1927,8 +2102,11 @@ class LlamaForCausalLM(nn.Module):
         """``path -> bool`` over a leaf's path of dict keys: the leaves
         weight decay leaves alone (``runtime/optimizers.py decay_mask``), a
         hyper-connection's gains and biases, a few numbers each whose rest
-        values are not 0 (``b_res`` starts at 8 I).  None with one lane:
-        every leaf decays, as it always did."""
+        values are not 0 (``b_res`` starts at 8 I), and a looped stack's
+        ``exit_gate`` bias.  None with one lane and one pass: every leaf
+        decays, as it always did."""
+        if self.cfg.total_ut_steps > 1:     # a looped stack: the gate's bias
+            return lambda path: path[-2:] == ("exit_gate", "bias")
         if self.cfg.lanes == 1:
             return None
         return lambda path: len(path) > 1 and path[-1] != "phi" \

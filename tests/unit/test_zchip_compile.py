@@ -1816,3 +1816,62 @@ def test_the_twelfth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert {("attention", "flash"), ("grouped_matmul", "megablox"),
             ("moe_rows", "pallas")} <= rows_of
     print(f"reserved {reserved:.3f} GiB")
+
+
+@pytest.mark.slow
+def test_the_thirteenth_cells_step_compiles_and_fits_the_chip(topo,
+                                                              monkeypatch):
+    """``train-ouro-loop4-8k-1chip`` (PR 64) as the benchmark builds it, its
+    whole train step compiled for the described chip: six blocks applied four
+    times over the same leaves (24 flash forward calls, and no second copy of
+    a weight a pass), a norm and a gate after every pass, the four exits
+    through ONE chunked head (one ``while``); 333,500,417 parameters in the
+    leaves; one packed 8,192-token row reserves 12.9 of the chip's 15.75 GiB,
+    11.1 of it temporaries - the activations, not the parameters
+    (``compile_said`` in the configuration file: two rows ask 20.05).  Marked
+    slow, as the ninth's to the twelfth's are: ~1 min of compile."""
+    import re
+    import types
+
+    from benchmark.harness import manifest as M
+    from deepspeed_tpu.comm import mesh as mesh_lib
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas.spmd import dispatch_report
+
+    devs = topo.devices[:1]
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: devs)
+    monkeypatch.setattr(jax, "local_devices", lambda *a, **k: devs)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cell = M.load_cell(M.load_manifest(M.ROOT), "train-ouro-loop4-8k-1chip",
+                       M.ROOT)
+    ctx = types.SimpleNamespace(
+        seed=1, cell=cell, rehearse=False,
+        sized=lambda sec: {k: v for k, v in sec.items() if k != "rehearse"})
+    try:
+        engine, cfg, conf = cell.driver().train_lm.build(ctx)
+        rows, seq = conf["micro_per_device"], cell.traffic["seq_len"]
+        batch = {name: jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+                 for name in ("input_ids", "labels")}
+        state = engine.abstract_state(batch)
+        compiled = engine._compiled_train_step.lower(state, batch).compile()
+    finally:
+        mesh_lib.set_mesh(None)
+    assert (rows, seq, cfg.total_ut_steps, cfg.num_hidden_layers) == (
+        1, 8192, 4, 6)
+    assert sum(int(x.size) for x in jax.tree_util.tree_leaves(
+        state.params)) == 333_500_417
+    ma = compiled.memory_analysis()
+    reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
+    assert 12.6 < reserved < 13.3, reserved
+    assert ma.argument_size_in_bytes / 2**30 < 1.9      # 6 B a parameter
+    text = compiled.as_text()
+    for t in range(4):
+        assert f"ut/pass_{t}/" in text
+    assert "ut/exit_gate" in text and "loss_head" in text
+    assert len(re.findall(r" while\(", text)) == 1      # the head's chunks
+    # no (rows, V) float32 logits of all four exits together
+    assert not re.search(r"f32\[32768,6144\]", text)
+    rows_of = {(s, i) for s, i, r, n in dispatch_report() if n}
+    assert ("attention", "flash") in rows_of
+    print(f"reserved {reserved:.3f} GiB")
