@@ -653,9 +653,9 @@ ROW_KERNEL_SHAPES = {         # tokens, top-k, row width, routed experts
 }
 
 
-def _traced_op_times(out):
-    """``{op name: [ns, ...]}`` of the first device in the newest profiler
-    trace under ``out``."""
+def _traced_ops(out):
+    """``[(op name, start ns, ns), ...]`` of the first device in the newest
+    profiler trace under ``out``."""
     import glob
 
     from benchmark.trace_reduce import read_xplane
@@ -663,8 +663,13 @@ def _traced_op_times(out):
     path = max(glob.glob(os.path.join(out, "plugins", "profile", "*",
                                       "*.xplane.pb")), key=os.path.getmtime)
     dev_ops, _, _ = read_xplane(path)
+    return next(iter(dev_ops.values()))
+
+
+def _traced_op_times(out):
+    """``{op name: [ns, ...]}`` of :func:`_traced_ops`."""
     times = {}
-    for name, _, dur in next(iter(dev_ops.values())):
+    for name, _, dur in _traced_ops(out):
         times.setdefault(name, []).append(dur)
     return times
 
@@ -1238,6 +1243,173 @@ def kernel_gated_norm(time_it: bool = True):
                               f"{np.mean(ns) / 1e6:.3f} ms a call",
                               flush=True)
         del want, got
+
+
+def kernel_mhc_rows(time_it: bool = True, variants=(),
+                    shape=(1, 8192, 4, 3584)):
+    """The hyper-connections' four row kernels (PR 65,
+    ``ops/pallas/mhc_rows.py`` behind ``ops/hyper_connection.py read`` /
+    ``write``) at the twelfth cell's shape, one packed row of 8,192 tokens x
+    4 lanes of 3,584 channels in bf16, under gains and biases away from
+    their near-identity start: ``u``, the three maps, ``X'`` and the
+    gradients of a seeded scalar in ``X``, ``y``, ``phi``, the gains and the
+    biases, the kernels and the ``jax.numpy`` form in bf16 each against the
+    ``jax.numpy`` form on the same values in float32.  Timed from profiler
+    traces: the read pass and the write-back pass, forward and (forward +
+    backward) - forward, each form's whole device time, and the four custom
+    calls one by one beside the bytes each must move at 819 GB/s.
+    The passes are each timed forward and as the ``jax.vjp`` alone, which
+    holds what of the forward the backward needs (of the kernels' read pass
+    ``mhc_read``; the write-back's needs nothing).  ``variants``: settings of
+    the module's constants (``{"GROUP": 8, "TILE": 256}``) to time beside
+    the ones it has; ``shape``: ``(B, S, lanes, E)``."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops import hyper_connection as mhc
+    from deepspeed_tpu.ops.pallas import mhc_rows
+    from deepspeed_tpu.telemetry.device_scopes import _self_times
+
+    B, S, n, E = shape
+    k = mhc_rows.numbers(n)
+    kw = dict(n=n, iters=20, eps=1e-6, clamp=(-10.0, 10.0), rms_eps=1e-6)
+    ks = jax.random.split(jax.random.PRNGKey(65), 8)
+    normal = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
+    x = normal(ks[0], B, S, n * E).astype(jnp.bfloat16)
+    y = normal(ks[1], B, S, E).astype(jnp.bfloat16)
+    phi = 0.02 * normal(ks[2], n * E, k)
+    gains = tuple(jnp.full((1,), a, jnp.float32) for a in (0.7, 0.5, 0.9))
+    biases = (normal(ks[3], n), normal(ks[4], n),
+              normal(ks[5], n, n) + 3.0 * jnp.eye(n))
+    target = normal(ks[6], B, S, n * E).astype(jnp.bfloat16)
+    plan = mhc._plan(x, n)
+    assert plan == ("direct", None), plan
+    spec = mhc._Spec(**kw, plan=plan, interpret=False)
+
+    def plain_read(x, phi, gains, biases):
+        made = mhc.maps(x, phi, gains, biases, **kw)
+        return mhc.pre(x, made.pre), tuple(made[:3])
+
+    def plain_write(x, y, res, post):
+        return mhc.post(x, y, res, post)
+
+    def kernel_read(x, phi, gains, biases):
+        return mhc._read(x, phi, gains, biases, spec)[:2]
+
+    def kernel_write(x, y, res, post):
+        return mhc._write(x, y, res, post, spec)
+
+    def whole(public, x, y, phi, gains, biases, target):
+        """Both passes around ``F(u) = y + u`` as a model calls them."""
+        if public:
+            u, made = mhc.read(x, phi, gains, biases, **kw)
+            out = mhc.write(x, (y + u).astype(x.dtype), made)
+        else:
+            u, made = plain_read(x, phi, gains, biases)
+            out = plain_write(x, (y + u).astype(x.dtype), *made[1:][::-1])
+        loss = (out.astype(jnp.float32) * target.astype(jnp.float32)).sum()
+        return loss, (u, *made[:3], out)
+
+    def run(public, x, y):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: whole(public, *a), argnums=(0, 1, 2, 3, 4),
+            has_aux=True))(x, y, phi, gains, biases, target)
+
+    names = ("u", "H_pre", "H_post", "H_res", "X'", "dX", "dy", "dphi",
+             "da_pre", "da_post", "da_res", "db_pre", "db_post", "db_res")
+
+    def parts(result):
+        (_, values), grads = result
+        return [np.asarray(t, np.float64) for t in
+                (*values, *jax.tree_util.tree_leaves(grads))]
+
+    want = parts(run(False, x.astype(jnp.float32), y.astype(jnp.float32)))
+    errs = {}
+    for form, public in (("jax.numpy", False), ("kernels", True)):
+        got = parts(jax.block_until_ready(run(public, x, y)))
+        assert len(got) == len(names) == len(want)
+        assert all(np.isfinite(g).all() for g in got), form
+        errs[form] = [np.linalg.norm(g - w) / np.linalg.norm(w)
+                      for g, w in zip(got, want)]
+        print(f"  mhc_rows {form} bf16 |. - float32| / |float32|: "
+              + " ".join(f"{nm} {e:.1e}" for nm, e in zip(names, errs[form])),
+              flush=True)
+    # a gain's gradient is one number, a sum that cancels: the kernels are
+    # held to what the same form in bf16 reads there
+    for nm, mine, plain in zip(names, errs["kernels"], errs["jax.numpy"]):
+        assert mine <= max(TOL, 3 * plain), (nm, mine, plain)
+    del want, got
+    if not time_it:
+        return
+
+    def device_ms(fn, args, reps=5):
+        """``(device ms a call, {custom call: ms a call})`` of jitted
+        ``fn`` from a profiler trace."""
+        jax.block_until_ready(fn(*args))
+        out = tempfile.mkdtemp(prefix="mhc_rows_trace_")
+        with jax.profiler.trace(out):
+            for _ in range(reps):
+                jax.block_until_ready(fn(*args))
+        calls = {c: np.mean(ns) / 1e6
+                 for c, ns in _traced_op_times(out).items()
+                 if c.startswith("mhc_")}
+        # a while loop's event spans its body's: self times
+        busy = sum(ns for _, _, ns in _self_times(_traced_ops(out)))
+        return busy / reps / 1e6, calls
+
+    made = jax.block_until_ready(jax.jit(plain_read)(x, phi, gains, biases))
+    u, (_, post, res) = made
+    cot = jax.tree_util.tree_map(
+        lambda t: normal(ks[7], *t.shape).astype(t.dtype), made)
+    moved = {"mhc_read": n + 1, "mhc_post": 2 * n + 1,
+             "mhc_post_back": 2 * n + 2, "mhc_read_back": 3 * n + 1}
+
+    def back(fn):
+        """``fn``'s ``jax.vjp`` alone; the cotangent is the last argument (a
+        closed-over one would be baked into the executable, 235 MB)."""
+        def run(*args):
+            out, vjp = jax.vjp(fn, *args[:-1])
+            grads = vjp(args[-1])
+            # the kernels hand dX' on as it came: no copy of it is timed
+            return grads if isinstance(out, tuple) else grads[1:]
+        return run
+
+    def timings(label):
+        table = {}
+        for form, read, write in (("kernels", kernel_read, kernel_write),
+                                  ("jax.numpy", plain_read, plain_write)):
+            if label and form != "kernels":
+                continue
+            for piece, fn, args, ct in (
+                    ("read", read, (x, phi, gains, biases), cot),
+                    ("write", write, (x, y, res, post), target)):
+                fwd, calls = device_ms(jax.jit(fn), args)
+                vjp, more = device_ms(jax.jit(back(fn)), (*args, ct))
+                table[form, piece] = (fwd, vjp)
+                for call, ms in sorted({**calls, **more}.items()):
+                    least = moved[call] * B * S * E * 2 / 819e9 * 1e3
+                    print(f"  mhc_rows{label} {call}: {ms:.3f} ms a call, "
+                          f"{100 * least / ms:.1f}% of 819 GB/s over the "
+                          f"{moved[call]} E it must move ({least:.3f} ms)",
+                          flush=True)
+        for (form, piece), (fwd, vjp) in table.items():
+            print(f"  mhc_rows{label} {form} {piece} pass: forward "
+                  f"{fwd:.3f} ms, its vjp alone {vjp:.3f} ms of device time",
+                  flush=True)
+
+    timings("")
+    for variant in variants:
+        had = {name: getattr(mhc_rows, name) for name in variant}
+        for name, value in variant.items():
+            setattr(mhc_rows, name, value)
+        jax.clear_caches()
+        timings(" " + " ".join(f"{k}={v}" for k, v in variant.items()))
+        for name, value in had.items():
+            setattr(mhc_rows, name, value)
+    jax.clear_caches()
 
 
 def kernel_head_slots(time_it: bool = True):
@@ -1975,7 +2147,7 @@ KERNEL_CASES = (kernel_flash, kernel_flash_window_gqa,
                 kernel_flash_lanes_256, kernel_indexed_attention,
                 kernel_gated_delta,
                 kernel_gated_delta_wide, kernel_gated_delta_channels,
-                kernel_gated_norm, kernel_head_slots,
+                kernel_gated_norm, kernel_mhc_rows, kernel_head_slots,
                 kernel_qk_rows,
                 kernel_short_conv,
                 kernel_grouped_matmul,

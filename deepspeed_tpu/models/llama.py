@@ -1466,17 +1466,16 @@ class HyperConnection(nn.Module):
                   leaf("b_post", const(0.0), (n,)),
                   leaf("b_res", lambda key, shape, dtype: 8.0 * jnp.eye(
                       n, dtype=dtype), (n, n)))
-        maps = mhc.maps(
+        return mhc.read(
             x, phi, gains, biases, n=n, iters=cfg.hc_sinkhorn_iters,
             eps=cfg.hc_eps, rms_eps=cfg.rms_norm_eps,
             clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max))
-        return mhc.pre(x, maps.pre), maps
 
     @staticmethod
     def post(x, y, maps):
         from ..ops import hyper_connection as mhc
 
-        return mhc.post(x, y, maps.res, maps.post)
+        return mhc.write(x, y, maps)
 
 
 def _mhc_stats(attn_maps, mlp_maps) -> dict:

@@ -6,6 +6,7 @@ block off the tiling) it refuses here, at no chip time.  Nothing runs.
 The topology is described inside a fixture and only in this file: the
 TPU library loads in the one worker that is given these tests.
 """
+import functools
 import math
 
 import pytest
@@ -1689,14 +1690,16 @@ def test_the_eleventh_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
 
 
 @pytest.mark.parametrize("kernel", ["gather", "gather_scaled", "combine",
-                                    "combine_dw"])
+                                    "combine_dw", "mhc_read", "mhc_post",
+                                    "mhc_post_back", "mhc_read_back"])
 def test_the_row_kernels_compile_at_the_twelfth_cells_width(one_chip, kernel):
     """Rows of 3,584 channels, the widest a cell moves (PR 62; 2,560 before):
     ``gather_rows``' double-buffered ``(1024, 3584)`` block and its stages
     asked 17.5 MiB of Mosaic's 16 MiB default scope, so ``_wide_rows`` gives
     the call 32; at the older widths it adds nothing and the calls are what
-    they were."""
-    from deepspeed_tpu.ops.pallas import moe_rows
+    they were.  And the hyper-connections' four passes over one packed row
+    of four such lanes (PR 65, ``ops/pallas/mhc_rows.py``)."""
+    from deepspeed_tpu.ops.pallas import mhc_rows, moe_rows
 
     assert moe_rows._wide_rows(2560 // 2) == {} \
         and moe_rows._wide_rows(2304 // 2) == {}
@@ -1721,22 +1724,50 @@ def test_the_row_kernels_compile_at_the_twelfth_cells_width(one_chip, kernel):
         "combine_dw": (moe_rows.combine_rows, (
             arg((rows, 1, width // 2), jnp.uint32), idx, weights,
             arg((tokens, width), jnp.bfloat16))),
+        **{"mhc_" + name: (functools.partial(call, n=4, **more), args)
+           for name, call, more, args in _mhc_calls(arg, mhc_rows)},
     }[kernel]
-    text = jax.jit(lambda *a: fn(*a, name="moe_rows_back")).lower(
-        *args).compile().as_text()
+    if not kernel.startswith("mhc_"):
+        fn = functools.partial(fn, name="moe_rows_back")
+    text = jax.jit(fn).lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") == 1
 
 
-def test_a_hyper_connection_compiles_with_one_loop_a_pass(one_chip):
+def _mhc_calls(arg, mhc_rows, B=1, S=8192, n=4, E=3584):
+    """``(name, call, its other keywords, the operands' shapes)`` of the
+    hyper-connections' four row kernels at the twelfth cell's shape."""
+    stream, lane = arg((B, S, n * E), jnp.bfloat16), arg((B, S, E),
+                                                         jnp.bfloat16)
+    row = arg((B, S, mhc_rows.COLS), jnp.float32)
+    phi_t = arg((mhc_rows.COLS, n * E), jnp.bfloat16)
+    ab = arg((2, mhc_rows.COLS), jnp.float32)
+    return (("read", mhc_rows.read_call, {"rms_eps": 1e-6},
+             (stream, phi_t, ab)),
+            ("post", mhc_rows.post_call, {}, (stream, lane, row)),
+            ("post_back", mhc_rows.post_back_call, {},
+             (stream, stream, lane, row)),
+            ("read_back", mhc_rows.read_back_call, {},
+             (stream, stream, lane, row, row, row, phi_t, ab)))
+
+
+def test_a_hyper_connection_compiles_with_one_loop_a_pass(one_chip,
+                                                          monkeypatch):
     """One sublayer's hyper-connection at the twelfth cell's shape, (1,
     8192, 4 x 3584) in bf16, forward and backward for the described v5e: the
     20 Sinkhorn sweeps are ONE while loop a pass (forward and its reverse
-    scan), not 20 unrolled copies, and no ``(T, 4, 4)`` array is formed -
-    the maps keep tokens on the lane axis."""
+    scan), not 20 unrolled copies, no ``(T, 4, 4)`` array is formed - the
+    maps keep tokens on the lane axis - and the passes over the lanes are
+    the four row kernels (PR 65): read and write back, and the backward of
+    each."""
     import re
 
     from deepspeed_tpu.models.llama import HyperConnection, LlamaConfig
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import spmd
 
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(spmd, "kernel_mesh_plan",
+                        lambda batch, **kw: ("direct", None))
     cfg = LlamaConfig(hidden_size=3584, num_attention_heads=32, hc_mult=4,
                       scan_layers=False, rms_norm_eps=1e-6)
     module = HyperConnection(cfg)
@@ -1757,11 +1788,16 @@ def test_a_hyper_connection_compiles_with_one_loop_a_pass(one_chip):
         out = HyperConnection.post(X, (y + u).astype(X.dtype), maps)
         return out.astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
         params, X, y).compile().as_text()
     assert len(re.findall(r" while\(", text)) == 2
     assert not re.search(r"f32\[8192,4,4\]", text)
-    assert "tpu_custom_call" not in text        # XLA's fusions, no kernel yet
+    assert text.count("tpu_custom_call") == 4
+    for name in ("mhc_read", "mhc_post", "mhc_post_back", "mhc_read_back"):
+        assert len(re.findall(rf'/{name}/pallas_call"', text)) == 1, name
+    # every kernel, the two of the backward too, under a scope that says mhc/
+    assert len(re.findall(r'op_name="[^"]*mhc/(maps|post)[^"]*/pallas_call"',
+                          text)) == 4
 
 
 @pytest.mark.slow
@@ -1771,8 +1807,9 @@ def test_the_twelfth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     blocks (the prediction block's among them) through the two-product flash
     kernels, each sublayer under a hyper-connection over four lanes, 8 of 64
     experts held; 913,473,668 parameters in the leaves; one packed
-    8,192-token row reserves 12.2 of the chip's 15.75 GiB (two ask 18.10:
-    ``compile_said`` in the configuration file holds both readings).
+    8,192-token row reserves 11.78 of the chip's 15.75 GiB (12.225 before
+    PR 65's row kernels; ``compile_said`` in the configuration file holds
+    the older reading and two rows' 18.10).
     Marked slow, as the ninth's to the eleventh's are: ~95 s of compile."""
     import re
     import types
@@ -1806,7 +1843,7 @@ def test_the_twelfth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     ma = compiled.memory_analysis()
     reserved = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 - ma.alias_size_in_bytes + ma.temp_size_in_bytes) / 2**30
-    assert 12.0 < reserved < 12.5, reserved
+    assert 11.5 < reserved < 12.0, reserved     # 12.225 before PR 65
     text = compiled.as_text()
     assert "self_attn_mla" in text
     # a Sinkhorn loop a sublayer a pass (12 x forward, recompute, backward)
@@ -1814,7 +1851,14 @@ def test_the_twelfth_cells_step_compiles_and_fits_the_chip(topo, monkeypatch):
     assert 36 <= len(re.findall(r" while\(", text)) <= 64
     rows_of = {(s, i) for s, i, r, n in dispatch_report() if n}
     assert {("attention", "flash"), ("grouped_matmul", "megablox"),
-            ("moe_rows", "pallas")} <= rows_of
+            ("moe_rows", "pallas"), ("mhc_rows", "pallas")} <= rows_of
+    # the four row kernels a sublayer (PR 65): the read pass forward and
+    # again under remat, the write back again only where the block goes on
+    # to read its result (a block's last is the next block's kept input),
+    # and the backward of each once
+    for name, calls in (("mhc_read", 24), ("mhc_post", 19),
+                        ("mhc_post_back", 12), ("mhc_read_back", 12)):
+        assert len(re.findall(rf'/{name}/pallas_call"', text)) == calls, name
     print(f"reserved {reserved:.3f} GiB")
 
 
